@@ -22,6 +22,20 @@ import time
 import numpy as np
 
 
+def _pick_size(args, on_tpu: bool) -> str:
+    """The llama3 preset to serve: ``--size`` when given, else ``1b`` on a
+    TPU. Off TPU there is no default — a bare invocation without a chip
+    fails instead of benchmarking the tiny preset on the host."""
+    if args.size:
+        return args.size
+    if not on_tpu:
+        raise SystemExit(
+            "bench_inference.py: no TPU found and no --size given; "
+            "refusing to fall back to a host run. A CPU correctness run "
+            "is explicit: --size tiny")
+    return "1b"
+
+
 def _timed(fn) -> float:
     t0 = time.perf_counter()
     fn()
@@ -121,7 +135,7 @@ def bench_shared_prefix(args) -> None:
     approach 2x; the CI floor is 1.5x. Prints ONE JSON line."""
     import jax
     on_tpu = jax.devices()[0].platform == "tpu"
-    size = args.size or ("1b" if on_tpu else "tiny")
+    size = _pick_size(args, on_tpu)
 
     import deepspeed_tpu as ds
     from deepspeed_tpu.inference import RaggedInferenceEngineTPU
@@ -220,7 +234,7 @@ def bench_router(args) -> None:
     from deepspeed_tpu.serving import LocalReplica, Router, ServingFrontend
 
     on_tpu = jax.devices()[0].platform == "tpu"
-    size = args.size or ("1b" if on_tpu else "tiny")
+    size = _pick_size(args, on_tpu)
     ds.build_mesh(data=1, devices=jax.devices()[:1])
     seq_cap = 256
     model = llama3_config(size, max_seq_len=seq_cap, tie_embeddings=True)
@@ -250,12 +264,18 @@ def bench_router(args) -> None:
     def run_pool(hedge: bool) -> dict:
         """One fresh pool + router over the stream; per-mode counter
         deltas so A/B modes don't bleed into each other."""
-        replicas = [
-            LocalReplica(f"r{i}", ServingFrontend(
-                RaggedInferenceEngineTPU(model, dict(eng_cfg),
-                                         params=params),
-                max_queue=n_req, enable_prefix_cache=False))
-            for i in range(args.replicas)]
+        # replica i on local device i % n: one process, one replica per
+        # chip (all on the one device when there is only one)
+        devices = jax.local_devices()
+        replicas = []
+        for i in range(args.replicas):
+            dev = devices[i % len(devices)]
+            with jax.default_device(dev):
+                eng = RaggedInferenceEngineTPU(
+                    model, dict(eng_cfg),
+                    params=jax.device_put(params, dev))
+            replicas.append(LocalReplica(f"r{i}", ServingFrontend(
+                eng, max_queue=n_req, enable_prefix_cache=False)))
         router = Router(replicas, hedge=hedge,
                         hedge_delay_s=args.hedge_delay)
         # warm every replica's compile buckets before arming chaos so
@@ -359,7 +379,7 @@ def bench_returning_sessions(args) -> None:
     from deepspeed_tpu.serving import ServingFrontend
 
     on_tpu = jax.devices()[0].platform == "tpu"
-    size = args.size or ("1b" if on_tpu else "tiny")
+    size = _pick_size(args, on_tpu)
     ds.build_mesh(data=1, devices=jax.devices()[:1])
     seq_cap = 256
     model = llama3_config(size, max_seq_len=seq_cap, tie_embeddings=True)
@@ -498,7 +518,7 @@ def bench_diurnal(args) -> None:
                                        ServingFrontend)
 
     on_tpu = jax.devices()[0].platform == "tpu"
-    size = args.size or ("1b" if on_tpu else "tiny")
+    size = _pick_size(args, on_tpu)
     ds.build_mesh(data=1, devices=jax.devices()[:1])
     # goodput ledger over the drill: serving/engine_step spans attribute
     # token work vs idle; the stamp lands in extra.goodput below
@@ -800,6 +820,8 @@ def main() -> None:
                          "(dispatch/host_calls deltas) into the JSON")
     args = ap.parse_args()
 
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.from_config:
         with open(args.from_config) as fh:
             cfg = json.load(fh)
@@ -819,7 +841,7 @@ def main() -> None:
 
     import jax
     on_tpu = jax.devices()[0].platform == "tpu"
-    size = args.size or ("1b" if on_tpu else "tiny")
+    size = _pick_size(args, on_tpu)
 
     import deepspeed_tpu as ds
     from deepspeed_tpu.inference import (RaggedInferenceEngineTPU,
@@ -897,8 +919,8 @@ def main() -> None:
             v1.generate(padded, max_new_tokens=batch_new)
 
     run_padded()                                      # compile real shapes
-    # best-of-3: the generation loop is host-dispatch-bound on remote
-    # runtimes, so single runs carry ±15% scheduler noise
+    # best-of-3: the generation loop is host-dispatch-bound, so single
+    # runs carry scheduler noise
     t_padded = min(_timed(run_padded) for _ in range(3))
 
     # ---- ragged v2: continuous batching over the true lengths

@@ -6,10 +6,6 @@ Provides the capabilities of DeepSpeed (reference: deepspeed/__init__.py —
 ICI/DCN, Pallas kernels for hot ops.
 """
 
-from deepspeed_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from deepspeed_tpu.version import __version__
 from deepspeed_tpu import comm  # noqa: F401
 from deepspeed_tpu.config import AUTO, DeepSpeedTPUConfig  # noqa: F401

@@ -126,9 +126,6 @@ def main(argv=None) -> int:
     import argparse
     import json
 
-    from deepspeed_tpu.utils.platform import sync_jax_platform_env
-    sync_jax_platform_env()
-
     parser = argparse.ArgumentParser(
         prog="dstpu_bench_comm",
         description="collective bandwidth sweep over the device mesh "

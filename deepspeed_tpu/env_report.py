@@ -78,9 +78,6 @@ def op_report(build: bool = False, file=None) -> bool:
 def device_report(file=None) -> None:
     import jax
     from deepspeed_tpu.accelerator import get_accelerator
-    from deepspeed_tpu.utils.platform import sync_jax_platform_env
-
-    sync_jax_platform_env()
 
     accel = get_accelerator()
     print("-" * 58, file=file)

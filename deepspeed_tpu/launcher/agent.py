@@ -22,6 +22,7 @@ setup, supervision, bounded restarts, and signal forwarding:
   file while no worker is alive.
 """
 
+import glob
 import json
 import os
 import signal
@@ -31,7 +32,7 @@ import sys
 import time
 from typing import Callable, Dict, List, Optional
 
-from deepspeed_tpu.utils.logging import log_dist, logger
+from deepspeed_tpu.utils.logging import logger
 
 
 class LaunchAgent:
@@ -104,9 +105,13 @@ class LaunchAgent:
                 # itself (a launcher-scoped hang or preempt)
                 from deepspeed_tpu.resilience.faults import fault_injector
                 fault_injector.fire("launcher")
-                log_dist(f"launch agent: starting worker "
-                         f"(attempt {attempt + 1}): "
-                         f"{' '.join(self.cmd)}")
+                # plain logger, never log_dist: that asks jax for the
+                # process index, which initialises the backend — the
+                # supervisor would then hold every local chip and its
+                # worker could take none
+                logger.info(f"launch agent: starting worker "
+                            f"(attempt {attempt + 1}): "
+                            f"{' '.join(self.cmd)}")
                 self._child = subprocess.Popen(
                     self.cmd, env=self.env, start_new_session=True)
                 self._beat("worker_started", worker_pid=self._child.pid,
@@ -158,6 +163,27 @@ class LaunchAgent:
             signal.signal(signal.SIGINT, prev_int)
 
 
+def _local_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from their device nodes.
+    The supervisor must not ask JAX: a process that has touched JAX holds
+    the chips, and its children could then take none."""
+    return len(glob.glob("/dev/accel[0-9]*")) or \
+        len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _one_chip_env(chip: int) -> Dict[str, str]:
+    """Environment that shows a child exactly one local chip (libtpu's
+    own variables; two such processes ran side by side on a four-chip
+    v5e host in PR 25). Without it every child takes all local chips,
+    and on a locally attached TPU the second one dies at backend init
+    ("The TPU is already in use by process ...")."""
+    return {"TPU_VISIBLE_DEVICES": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{8476 + chip}",
+            "TPU_MESH_CONTROLLER_PORT": str(8476 + chip)}
+
+
 class ReplicaPoolAgent:
     """Spawn and supervise a local pool of N serving-replica processes —
     the multi-process backend for the serving router
@@ -165,7 +191,11 @@ class ReplicaPoolAgent:
 
     Each child runs ``cmd`` with ``DSTPU_REPLICA_NAME=r<i>`` and, when
     ``base_port > 0``, ``DSTPU_HTTP_PORT=base_port+i`` (the replica's
-    /metrics + /healthz endpoint the router's breaker polls). Unlike
+    /metrics + /healthz endpoint the router's breaker polls). On a host
+    with several TPU chips each child is shown ONE chip (the lowest no
+    live sibling holds; a restart keeps its own), unless the caller's
+    environment already chose (``TPU_VISIBLE_DEVICES``) or runs the
+    replicas off the TPU (``JAX_PLATFORMS``). Unlike
     :class:`LaunchAgent` this supervisor is poll-driven and installs no
     signal handlers, so it can run off the main thread or embedded in a
     router process; restarts share one rolling per-replica budget so a
@@ -202,6 +232,13 @@ class ReplicaPoolAgent:
         self._draining: set = set()
         self.restarts = 0
         self._next_idx = n
+        #: one chip per child on a multi-chip TPU host (0 = leave the
+        #: environment alone: no TPU here, or the caller already chose)
+        platforms = self.env.get("JAX_PLATFORMS") or "tpu"
+        self._chips = _local_tpu_chips() \
+            if "tpu" in platforms.split(",") and \
+            "TPU_VISIBLE_DEVICES" not in self.env else 0
+        self._chip_of: Dict[str, int] = {}
 
     def _beat(self, name: str, phase: str, **extra) -> None:
         """Per-replica agent heartbeat (atomic write, best effort) —
@@ -228,11 +265,31 @@ class ReplicaPoolAgent:
         env["DSTPU_REPLICA_NAME"] = name
         if self.base_port > 0:
             env["DSTPU_HTTP_PORT"] = str(self.base_port + i)
+        if self._chips > 1:
+            env.update(_one_chip_env(self._claim_chip(name)))
         child = subprocess.Popen(self.cmd, env=env, start_new_session=True)
         self._children[name] = child
-        log_dist(f"replica pool: started {name} pid={child.pid}" +
-                 (f" port={self.base_port + i}" if self.base_port else ""))
+        logger.info(f"replica pool: started {name} pid={child.pid}" +
+                    (f" port={self.base_port + i}" if self.base_port else "")
+                    + (f" chip={self._chip_of[name]}"
+                       if name in self._chip_of else ""))
         return child
+
+    def _claim_chip(self, name: str) -> int:
+        """The chip ``name`` runs on: its own from before (a restart), else
+        the lowest one no live sibling holds."""
+        held = {self._chip_of[n] for n, c in self._children.items()
+                if n != name and n in self._chip_of
+                and c is not None and c.poll() is None}
+        chip = self._chip_of.get(name)
+        if chip is None or chip in held:
+            free = [c for c in range(self._chips) if c not in held]
+            if not free:
+                raise RuntimeError(
+                    f"replica pool: no free chip for {name} — this host "
+                    f"has {self._chips}, each held by a live replica")
+            chip = self._chip_of[name] = free[0]
+        return chip
 
     def start(self) -> "ReplicaPoolAgent":
         for name in self.names:
@@ -298,6 +355,8 @@ class ReplicaPoolAgent:
         autoscaler's ``spawn_fn`` seam for process pools). Names never
         recycle — ``r<next>`` keeps doctor timelines unambiguous."""
         name = f"r{self._next_idx}"
+        if self._chips > 1:
+            self._claim_chip(name)      # refuses before anything changes
         self._next_idx += 1
         self.names.append(name)
         self._children[name] = None

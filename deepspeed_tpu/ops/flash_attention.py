@@ -164,6 +164,7 @@ def _fwd(q, k, v, scale, causal, q_offset, block_q, block_k, window,
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -319,6 +320,7 @@ def _fwd_xl(q, k, v, scale, causal, q_offset, block_q, block_k, window,
             pltpu.VMEM((block_q,), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd_xl",
     )(q, k, v)
     return out, lse
 
@@ -462,6 +464,7 @@ def _bwd(q, k, v, out, lse, do, scale, causal, q_offset, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv per q-head, summed over the GQA group afterwards
@@ -487,6 +490,7 @@ def _bwd(q, k, v, out, lse, do, scale, causal, q_offset, block_q, block_k,
             jax.ShapeDtypeStruct((bh, tk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     if g > 1:
@@ -625,6 +629,7 @@ def _bwd_xl(q, k, v, out, lse, do, scale, causal, q_offset, block_q,
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq_xl",
     )(q, k, v, do, lse, delta)
 
     q_idx = _xl_q_index(block_q, block_k, q_offset, causal, window, num_qb)
@@ -655,6 +660,7 @@ def _bwd_xl(q, k, v, out, lse, do, scale, causal, q_offset, block_q,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv_xl",
     )(q, k, v, do, lse, delta)
 
     if g > 1:
@@ -886,8 +892,7 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     if b % max(bdiv, 1):
         batch_axes, bdiv = (), 1
 
-    manual = set(batch_axes) | set(head_axes)
-    if not manual:
+    if not (batch_axes or head_axes):
         return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
                                **kw)
 
@@ -898,11 +903,17 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     spec = P(bspec, None, hspec, None)
 
     local = partial(flash_attention, causal=causal, q_offset=q_offset, **kw)
+    # every mesh axis is named manual, the size-1 ones included: Mosaic
+    # lowers only under a fully manual mesh (a partial-manual shard_map
+    # fails on the chip with "Mosaic kernels cannot be automatically
+    # partitioned"). An axis > 1 the spec does not mention sees replicated
+    # operands and computes the same attention on each of its shards.
     # check_vma=False: pallas_call outputs carry no varying-axes metadata;
     # the kernel is embarrassingly parallel over the manual axes anyway
     fn = jax.shard_map(lambda a, b_, c: local(a, b_, c),
                        mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, axis_names=manual, check_vma=False)
+                       out_specs=spec, axis_names=set(mesh.axis_names),
+                       check_vma=False)
     out = fn(q, k, v)
     if out.shape[2] != orig_h:
         out = out[:, :, :orig_h, :]   # drop padded query heads

@@ -764,17 +764,6 @@ def _dw_ragged(lhs, grad, sizes_padded, num_experts):
     if os.environ.get("DSTPU_GMM_DW") == "zero":
         return jnp.zeros((num_experts, lhs.shape[1], grad.shape[1]),
                          lhs.dtype)
-    if not hasattr(lax, "ragged_dot_general"):
-        # older jax: no ragged-CONTRACTION primitive — fall back to a
-        # segment-masked einsum (exact: padding rows are zero in both
-        # operands; rows past the total land in no segment)
-        ends = jnp.cumsum(sizes_padded)
-        row = jnp.arange(lhs.shape[0], dtype=ends.dtype)[:, None]
-        seg = ((row >= ends - sizes_padded) & (row < ends)
-               ).astype(jnp.float32)                     # [R, E]
-        return jnp.einsum("re,rd,rf->edf", seg,
-                          lhs.astype(jnp.float32),
-                          grad.astype(jnp.float32)).astype(lhs.dtype)
     dims = lax.RaggedDotDimensionNumbers(
         dot_dimension_numbers=(((0,), (0,)), ((), ())),
         lhs_ragged_dimensions=[0], rhs_group_dimensions=[])

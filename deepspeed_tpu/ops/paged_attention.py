@@ -362,8 +362,8 @@ def paged_attention(q: jax.Array, arena_k: jax.Array, arena_v: jax.Array,
             in_specs=[
                 pl.BlockSpec((1, 1, rows, dh),
                              lambda s, kh, pt, st, ct: (s, kh, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, rows, dh),
@@ -377,6 +377,7 @@ def paged_attention(q: jax.Array, arena_k: jax.Array, arena_v: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((n, kvh, rows, dh), q.dtype),
         interpret=interpret,
+        name="paged_attn",
     )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
       counts.astype(jnp.int32), qk, arena_k, arena_v)
 
@@ -414,8 +415,8 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
             in_specs=[
                 pl.BlockSpec((1, 1, rows, dh),
                              lambda s, kh, pt, st, ct: (s, kh, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, rows, dh),
@@ -433,6 +434,7 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((n, kvh, rows, dh), q.dtype),
                    jax.ShapeDtypeStruct((n, kvh, rows, 1), jnp.float32)],
         interpret=interpret,
+        name="paged_attn_lse",
     )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
       counts.astype(jnp.int32), qk, arena_k, arena_v)
 

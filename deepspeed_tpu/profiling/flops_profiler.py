@@ -90,7 +90,7 @@ def module_profile(dec_cfg, batch_size: int = 1,
     ``measure=True`` additionally RUNS each component jitted on the
     current backend with random concrete inputs and attaches measured
     wall time (``ms`` per row, iteration-chained inside one jit with a
-    scalar fetch so remote-runtime dispatch noise does not pollute the
+    scalar fetch so per-call dispatch noise does not pollute the
     number — the reference profiler's measured per-module duration,
     profiler.py:511). Costs one compile + ``measure_iters`` runs per
     component.

@@ -214,6 +214,7 @@ class DeepSpeedTPUEngine:
             config.fp16.hysteresis) if self.fp16_enabled else \
             LossScaleState(jnp.float32(1.0), jnp.zeros((), jnp.int32),
                            jnp.zeros((), jnp.int32))
+        self.loss_scale_state = self._replicate(self.loss_scale_state)
         self.dynamic_loss_scale = self.fp16_enabled and config.fp16.loss_scale == 0
 
         # -- jitted functions ----------------------------------------------
@@ -358,6 +359,13 @@ class DeepSpeedTPUEngine:
         self._state_shardings = state_sh
 
     # ------------------------------------------------------------- jit build
+
+    def _replicate(self, tree: Pytree) -> Pytree:
+        """Commit a small state pytree to the mesh, replicated — the layout
+        the step programs hand it back in. A first call with uncommitted
+        leaves has a different jit cache key than every later call, and
+        traces (and compiles) the whole step a second time."""
+        return jax.device_put(tree, NamedSharding(self.mesh, P()))
 
     def _batch_sharding(self, batch_like) -> Pytree:
         """Shard batch dim over DP axes (and seq dim over 'seq' if SP>1)."""
@@ -1644,8 +1652,9 @@ class DeepSpeedTPUEngine:
                     out_shardings=self._state_shardings)(self.params)
         if "loss_scale" in state:
             ls = state["loss_scale"]
-            self.loss_scale_state = LossScaleState(*jax.tree.leaves(ls)) \
-                if not isinstance(ls, LossScaleState) else ls
+            self.loss_scale_state = self._replicate(
+                LossScaleState(*jax.tree.leaves(ls))
+                if not isinstance(ls, LossScaleState) else ls)
         self.global_steps = meta.get("global_steps", 0)
         self.micro_steps = meta.get("micro_steps", 0)
         self.skipped_steps = meta.get("skipped_steps", 0)

@@ -156,8 +156,8 @@ class ZenFlowCoordinator:
         def zf_step(params, state, batch, rng, lr):
             """One ZenFlow train step: grads, importance, selective Adam on
             the K important blocks, flat grad out for host accumulation."""
-            acc, losses = eng._accumulate_grads(params, batch,
-                                               jnp.float32(1.0), rng)
+            acc, losses, _fwd = eng._accumulate_grads(params, batch,
+                                                     jnp.float32(1.0), rng)
             acc = jax.tree.map(lambda g: g * (1.0 / gas), acc)
             flat_g32 = layout.flatten_device(acc, jnp.float32)
             gb = to_blocks(flat_g32)

@@ -1370,9 +1370,11 @@ class Router:
 def _build_local_pool(n: int, size: str, http_ports: bool,
                       seed: int = 0, pools: Optional[List[str]] = None,
                       ) -> List[LocalReplica]:
-    """N in-process replicas over tiny CPU engines sharing one param
-    tree (each replica owns its engine + KV arena, exactly the state a
-    real replica process would lose on a kill). ``pools`` assigns each
+    """N in-process replicas over tiny engines with identical params
+    (each replica owns its engine + KV arena, exactly the state a real
+    replica process would lose on a kill). Replica ``i`` lives on local
+    device ``i % n_devices`` — one process can run one replica per chip;
+    with a single device they all share it. ``pools`` assigns each
     replica's pool (``prefill``/``decode``/``any``) for a disaggregated
     fleet; default is a monolithic ``any`` pool."""
     import jax
@@ -1388,8 +1390,12 @@ def _build_local_pool(n: int, size: str, http_ports: bool,
                "max_seq_len": 256, "prefill_chunk": 16,
                "max_batch_tokens": 128, "max_sequences": 16}
     out = []
+    devices = jax.local_devices()
     for i in range(n):
-        eng = RaggedInferenceEngineTPU(cfg, dict(eng_cfg), params=params)
+        dev = devices[i % len(devices)]
+        with jax.default_device(dev):     # the arena lands here too
+            eng = RaggedInferenceEngineTPU(
+                cfg, dict(eng_cfg), params=jax.device_put(params, dev))
         fe = ServingFrontend(eng, max_queue=256,
                              http_port=(0 if http_ports else None))
         pool = pools[i] if pools else "any"

@@ -145,6 +145,10 @@ def collective_stats_from_hlo(hlo_text: str) -> Dict[str, Dict[str, float]]:
             continue                      # async pair: count the start only
         if op.endswith("-start"):
             op = op[:-len("-start")]
+        if op == "fusion" and "calls=%all-reduce-scatter" in line:
+            # the TPU compiler emits a reduce-scatter as a custom fusion
+            # around an ``all-reduce-scatter`` computation
+            op = "reduce-scatter"
         if op not in _COLLECTIVE_OPS:
             continue
         best = 0.0

@@ -86,6 +86,10 @@ def warn_unknown_platform(name: str, context: str = "roofline") -> bool:
 
 
 def _lookup(table: Dict[str, float], device: Any) -> float:
+    """Spec-table entry for ``device``'s kind. A TPU whose ``device_kind``
+    matches no key is an error — a peak of 0 would make MFU and every
+    roofline share read 0 instead of failing. Non-TPU devices (CPU, the
+    tests' stubs) read 0.0: those numbers are not meaningful there."""
     if device is None:
         try:
             import jax
@@ -96,18 +100,25 @@ def _lookup(table: Dict[str, float], device: Any) -> float:
     for key, val in table.items():
         if key in kind:
             return val
+    if getattr(device, "platform", None) == "tpu":
+        raise ValueError(
+            f"TPU device_kind {getattr(device, 'device_kind', None)!r} "
+            f"matches no entry of the peak tables (known: "
+            f"{', '.join(known_platforms())}); add its published peaks to "
+            f"telemetry/sampler.py")
     return 0.0
 
 
 def peak_flops(device: Any = None) -> float:
     """Peak bf16 FLOPs/s for ``device`` (default: first jax device).
-    0.0 for CPU/unknown platforms — MFU is not meaningful there."""
+    0.0 off TPU — MFU is not meaningful there; raises for a TPU that is
+    not in the table."""
     return _lookup(PEAK_FLOPS_BF16, device)
 
 
 def peak_hbm_bw(device: Any = None) -> float:
     """Peak HBM bytes/s for ``device`` (default: first jax device).
-    0.0 for CPU/unknown platforms."""
+    0.0 off TPU; raises for a TPU that is not in the table."""
     return _lookup(PEAK_HBM_BW, device)
 
 

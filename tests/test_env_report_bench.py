@@ -75,13 +75,12 @@ class TestCommBench:
     def test_correctness_allreduce_values(self, devices):
         """The timed jitted collective computes the right thing."""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         mesh = build_mesh(data=8, devices=devices[:8])
         fn = comm_bench._collective_fn("allreduce", "data", 8)
-        mapped = jax.jit(shard_map(
+        mapped = jax.jit(jax.shard_map(
             fn, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-            check_rep=False))
+            check_vma=False))
         x = jax.device_put(jnp.arange(16, dtype=jnp.float32),
                            NamedSharding(mesh, P("data")))
         out = mapped(x)

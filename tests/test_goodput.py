@@ -160,7 +160,9 @@ def test_chaos_drill_attributes_fault_recovery(clean_recovery_ledger):
         end - start, abs=1e-3)
     assert s["recovery_kinds"] == {"io_error": 1}
     total = s["goodput_s"] + sum(s["badput"].values())
-    assert total == pytest.approx(s["uptime_s"], abs=1e-6)
+    # every term of the summary is rounded to a microsecond on its own, so
+    # on real clock readings the sum may sit a few of them off the total
+    assert total == pytest.approx(s["uptime_s"], abs=5e-6)
     # dominant badput names the drill (init is the only competitor and
     # the tracer was born right before the injection)
     assert s["dominant_badput"] in ("fault_recovery", "init")

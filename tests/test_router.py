@@ -452,6 +452,11 @@ def test_dstpu_top_renders_per_replica_router_states():
     from deepspeed_tpu.telemetry.fleet import (HostSample, poll_host,
                                                render_table,
                                                router_states)
+    # the registry is process-wide: drop the replicas that whichever test
+    # file ran before this one in the same xdist worker left behind
+    for name in telemetry.registry.names():
+        if name.startswith("router/replica/"):
+            telemetry.registry.unregister(name)
     telemetry.registry.gauge("router/replica/r0/state").set(0.0)
     telemetry.registry.gauge("router/replica/r1/state").set(2.0)
     telemetry.registry.gauge("router/replica/r2/state").set(3.0)
@@ -492,6 +497,46 @@ def test_replica_pool_agent_spawn_kill_restart_stop():
     finally:
         pool.stop(grace_s=2.0)
     assert all(p == "down" for p in pool.poll().values())
+
+
+def test_replica_pool_agent_one_chip_per_child(tmp_path, monkeypatch):
+    """On a host with several TPU chips every child is shown one chip —
+    the lowest no live sibling holds; a restart keeps its own; a replica
+    beyond the chip count is refused; a caller who already chose
+    (TPU_VISIBLE_DEVICES) or runs off the TPU is left alone."""
+    from deepspeed_tpu.launcher import agent
+    monkeypatch.setattr(agent, "_local_tpu_chips", lambda: 2)
+    monkeypatch.delenv("TPU_VISIBLE_DEVICES", raising=False)
+    code = ("import os, time; open(os.path.join(%r, "
+            "os.environ['DSTPU_REPLICA_NAME'] + '.' + str(os.getpid())), "
+            "'w').write(os.environ.get('TPU_VISIBLE_DEVICES', 'unset') + ' '"
+            " + os.environ.get('TPU_PROCESS_BOUNDS', 'unset')); "
+            "time.sleep(60)" % str(tmp_path))
+
+    def seen(name, n=1):
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            vals = sorted(p.read_text() for p in tmp_path.glob(name + ".*"))
+            if len(vals) == n and all(vals):
+                return vals
+            time.sleep(0.05)
+        raise AssertionError(f"{name}: {n} process(es) never wrote their "
+                             f"environment")
+
+    pool = agent.ReplicaPoolAgent(["python", "-c", code], 2,
+                                  env={"JAX_PLATFORMS": "tpu,cpu"}).start()
+    try:
+        assert seen("r0") == ["0 1,1,1"] and seen("r1") == ["1 1,1,1"]
+        with pytest.raises(RuntimeError, match="no free chip"):
+            pool.add_replica()
+        pool.kill("r0", restart=True)
+        pool.poll()                              # restarts r0 on ITS chip
+        assert seen("r0", 2) == ["0 1,1,1", "0 1,1,1"]
+    finally:
+        pool.stop(grace_s=2.0)
+    # the CPU suite's own environment (JAX_PLATFORMS=cpu): hands off
+    off = agent.ReplicaPoolAgent(["python", "-c", code], 1)
+    assert off._chips == 0
 
 
 # ---------------------------------------------------------------------------
@@ -607,3 +652,22 @@ def test_router_fleet_drill_three_replicas_acceptance(devices, monkeypatch):
     finally:
         fault_injector.disarm()
         router.close()
+
+
+def test_local_pool_places_one_replica_per_device(devices):
+    """``_build_local_pool`` puts replica i's params AND KV arena on local
+    device i % n_devices (one process can serve one replica per chip); a
+    pool larger than the device count wraps around."""
+    from deepspeed_tpu.serving.router import _build_local_pool
+    n_dev = len(jax.local_devices())
+    pool = _build_local_pool(n_dev + 1, "tiny", http_ports=False)
+    try:
+        for i, rep in enumerate(pool):
+            eng = rep.frontend.engine
+            want = {jax.local_devices()[i % n_dev]}
+            got = {d for leaf in jax.tree.leaves((eng.params, eng.arena))
+                   for d in leaf.devices()}
+            assert got == want, (i, got, want)
+    finally:
+        for rep in pool:
+            rep.close()

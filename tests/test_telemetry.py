@@ -240,6 +240,20 @@ def test_peak_flops_table():
     assert peak_flops(jax.devices()[0]) == 0.0     # test mesh is CPU
 
 
+def test_peak_flops_unknown_tpu_kind_raises():
+    """A device whose platform is ``tpu`` and whose ``device_kind`` is in
+    no table is an error (a peak of 0 would make MFU read 0 instead of
+    failing); the attached chip's kind still resolves."""
+    class Dev:
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+    assert peak_flops(Dev("TPU v5 lite")) == 197e12
+    with pytest.raises(ValueError, match="matches no entry"):
+        peak_flops(Dev("TPU v99 imaginary"))
+
+
 def test_sampler_cpu_noop():
     """On the CPU backend memory_stats is unavailable — every probe must
     degrade cleanly, and sample() must still publish what it CAN get."""
@@ -406,7 +420,7 @@ def test_bench_trace_flag(tmp_path):
         [sys.executable, os.path.join(root, "bench.py"), "--size", "tiny",
          "--seq", "64", "--batch", "2", "--steps", "1", "--trace", trace],
         capture_output=True, text=True, timeout=300,
-        env={**os.environ, "JAX_PLATFORMS": "cpu", "DSTPU_BENCH_SUITE": "0"})
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stderr[-2000:]
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     assert result["unit"] == "tokens/s/chip"

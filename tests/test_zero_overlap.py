@@ -102,6 +102,24 @@ def test_collective_stats_counts_chunks():
     assert collective_stats_from_hlo("") == {}
 
 
+def test_collective_stats_tpu_reduce_scatter_fusion():
+    """The TPU compiler writes a reduce-scatter as a custom fusion around
+    an ``all-reduce-scatter`` computation (seen compiling the ZeRO-3 step
+    for a described v5e:2x2); it counts as a reduce-scatter of the
+    fusion's result, and an ordinary fusion counts as nothing."""
+    from deepspeed_tpu.telemetry.explain import collective_stats_from_hlo
+    hlo = "\n".join([
+        "  %fusion.409 = bf16[128256,512]{1,0:T(8,128)(2,1)} "
+        "fusion(%convolution_bitcast_fusion.10), kind=kCustom, "
+        "calls=%all-reduce-scatter.clone.clone",
+        "  %fusion.410 = bf16[128,512]{1,0} fusion(%p), kind=kLoop, "
+        "calls=%fused_computation.3",
+    ])
+    stats = collective_stats_from_hlo(hlo)
+    assert stats == {"reduce-scatter": {
+        "bytes": pytest.approx(128256 * 512 * 2), "count": 1}}
+
+
 def test_append_chunked_exact_accounting():
     """Coalesced per-chunk records keep the byte/call accounting EXACT
     (flight-recorder deltas are computed from these counters) while the
