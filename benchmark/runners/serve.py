@@ -1,0 +1,282 @@
+"""The serving runner: ``RaggedInferenceEngineTPU`` under
+``ServingFrontend`` (after ``chip_smoke.server_phase``, which ran on the
+v5e in PR 25), driven by ONE thread: submit what is due, ``fe.step()``,
+stamp each token as ``stream_cb`` delivers it.
+
+Set-up walks the engine's whole grid of step programs by construction
+(rows bucketed to powers of two up to ``max_sequences``; chunk width 1 or
+``prefill_chunk``; fresh / split / decode), then ramps the mix's own
+traffic for ``ramp_seconds``. The ramp is not drained: the window opens on
+a running system and closes by the clock, at the end of the step that
+crosses ``seconds``."""
+
+import time
+from dataclasses import dataclass, field
+from typing import List, Optional
+
+import numpy as np
+
+from benchmark.lib import model as model_lib
+from benchmark.lib import stats, traffic
+from benchmark.reference import dense_decoder as reference
+
+#: a generated token may differ from the float32 reference's argmax only
+#: where the reference scores it within this many logits of its maximum.
+#: With random weights the top two logits are closer than bf16 rounding
+#: for about one token in ten; the worst such miss seen on the chip was
+#: 0.089 (PR 25). A forward in a lower precision than bf16 (int8 / fp8:
+#: logit error of the order of 0.3 and more at these widths) fails it.
+NEAR_TIE_LOGITS = 0.25
+#: ... and at least this share of checked tokens ARE the reference argmax
+#: (a sanity floor, not the test: 0.90–0.98 measured on the chip, PR 25)
+MIN_EXACT_ARGMAX = 0.75
+#: the benchmark's own host span around each call into the server
+SPAN = "benchmark/serve_step"
+#: finished requests checked against the reference, outside the window
+CHECKED_REQUESTS = 8
+
+
+@dataclass
+class Live:
+    planned: traffic.Planned
+    req: object
+    submitted: float
+    stamps: List[float] = field(default_factory=list)
+    ended: Optional[float] = None
+    reason: Optional[str] = None
+
+
+@dataclass
+class StepRecord:
+    t0: float
+    t1: float
+    decode_only: bool
+    rows: int
+    context_tokens: int
+
+
+def warm_program_grid(eng, mode) -> int:
+    """Run every step program the scheduler can ask for once, on a batch
+    of padding rows (zero valid tokens, page table all trash): the same
+    shapes and statics ``engine_v2._run`` uses, so the jit cache holds
+    them all before any request arrives. Largest first, so a program that
+    does not fit fails at once."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference.ragged import RaggedBatch
+
+    rows, nb = [], 1
+    while nb < eng.config.max_sequences:
+        rows.append(nb)
+        nb *= 2
+    rows.append(nb)
+    chunk = int(eng.config.prefill_chunk)
+    count = 0
+    for nb in reversed(rows):
+        for cb, fresh in ((chunk, "split"), (chunk, "fresh"), (1, False)):
+            z = np.zeros(0, np.int32)
+            empty = RaggedBatch(uids=[], token_ids=np.zeros((0, cb), np.int32),
+                                token_counts=z, start_positions=z, slots=z)
+            packed = jnp.asarray(eng._pack(empty, nb, cb))
+            out, eng._rng_dev, eng.arena = eng._step_fn(nb, cb, mode, fresh)(
+                eng.params, eng.arena, packed, eng._rng_dev)
+            np.asarray(out)
+            count += 1
+    return count
+
+
+def run(ctx) -> dict:
+    import jax
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.inference import RaggedInferenceEngineTPU
+    from deepspeed_tpu.serving import ServingFrontend
+    from deepspeed_tpu.serving.queue import AdmissionError
+    from deepspeed_tpu.telemetry import tracer
+    from deepspeed_tpu.telemetry.registry import registry
+
+    conf, mix, device = ctx.conf, ctx.mix, ctx.devices[0]
+    ds.build_mesh(data=1, devices=[device])
+    model = model_lib.build_model(conf, ctx.rehearse)
+    eng = RaggedInferenceEngineTPU(model, dict(conf["engine"]),
+                                   rng=model_lib.prng_key(ctx.seed))
+    jax.block_until_ready((eng.params, eng.arena))
+    if device.platform == "tpu" and not eng.use_pallas:
+        raise RuntimeError("engine.use_pallas is off on the TPU")
+    cap = int(mix["max_total_tokens"])
+    if cap > eng.config.max_seq_len:
+        raise ValueError(f"the mix allows {cap} tokens a request, the "
+                         f"engine {eng.config.max_seq_len}")
+    fe = ServingFrontend(eng, **conf.get("frontend", {}))
+    c_init = ctx.ledger.snapshot()
+    n_programs = warm_program_grid(eng, fe.mode)
+    ctx.log({"phase": "grid_warm", "programs": n_programs,
+             "compile": ctx.ledger.delta(c_init, ctx.ledger.snapshot()),
+             "arena_bytes": int(sum(a.nbytes for a in eng.arena.values())),
+             "t": round(time.monotonic() - ctx.t_start, 2)})
+
+    clock = time.monotonic
+    arrivals = traffic.Arrivals(mix, ctx.seed, model.vocab_size, clock())
+    live: List[Live] = []
+    done: List[Live] = []
+    retry: List[traffic.Planned] = []
+    steps: List[StepRecord] = []
+    admission_retries = 0
+    host_calls = registry.counter("dispatch/host_calls")
+
+    def submit_due(now: float) -> None:
+        nonlocal admission_retries, retry
+        todo, retry = retry + arrivals.due(now), []
+        for pl in todo:
+            rec = Live(pl, None, now)
+            try:
+                rec.req = fe.submit(
+                    pl.prompt, max_new_tokens=pl.max_new_tokens,
+                    stream_cb=lambda _tok, r=rec: r.stamps.append(clock()))
+            except AdmissionError:
+                admission_retries += 1
+                retry.append(pl)       # the client asks again; due stays
+                continue
+            live.append(rec)
+
+    def pump() -> None:
+        """One turn of the loop: submit, step, retire what ended."""
+        now = clock()
+        submit_due(now)
+        waiting = any(not r.stamps for r in live) or bool(retry)
+        context = sum(len(r.planned.prompt) + len(r.stamps) for r in live)
+        rows = len(live)
+        t0 = clock()
+        with ctx.annotate(SPAN):
+            worked = fe.step()
+        t1 = clock()
+        steps.append(StepRecord(t0, t1, not waiting, rows, context))
+        still = []
+        for r in live:
+            if r.req.finish_reason is None:
+                still.append(r)
+                continue
+            r.reason = r.req.finish_reason
+            r.ended = r.stamps[-1] if r.stamps else t1
+            done.append(r)
+            arrivals.finished(r.planned, r.ended)
+        live[:] = still
+        if not worked and not live:
+            nxt = arrivals.next_due()
+            if nxt is not None:
+                time.sleep(max(0.0, min(nxt - clock(), 0.001)))
+
+    # ramp: the mix's own traffic, not drained
+    t_ramp = clock()
+    while clock() - t_ramp < float(mix["ramp_seconds"]):
+        pump()
+    ctx.log({"phase": "ramped", "finished": len(done), "live": len(live),
+             "steps": len(steps),
+             "t": round(time.monotonic() - ctx.t_start, 2)})
+
+    if ctx.trace:
+        tracer.configure(enabled=True, jax_annotations=True)
+        tracer.clear()
+    steps.clear()
+    c0 = ctx.ledger.snapshot()
+    calls0 = host_calls.value
+    t_open = ctx.open_window()
+    trace_at = t_open + 1.0 if ctx.trace else None
+    traced = None
+    while True:
+        now = clock()
+        if now - t_open >= ctx.seconds:
+            break
+        if trace_at is not None and traced is None and now >= trace_at:
+            ctx.start_device_trace()
+            traced = [clock(), None, len(steps)]
+        if traced is not None and traced[1] is None and \
+                now - traced[0] >= float(mix["trace_seconds"]):
+            ctx.stop_device_trace()
+            traced[1] = clock()
+            traced.append(len(steps))
+        pump()
+    t_close = clock()
+    if traced is not None and traced[1] is None:
+        ctx.stop_device_trace()
+        traced[1] = clock()
+        traced.append(len(steps))
+    calls = int(host_calls.value - calls0)
+    compiles = ctx.ledger.delta(c0, ctx.ledger.snapshot())
+    spans = tracer.events() if ctx.trace else []
+    fe.terminate_inflight("window_closed")
+    fe.close()
+
+    # --- the window's arithmetic -----------------------------------------
+    everything = done + live
+    window_s = t_close - t_open
+    tokens = sum(len(stats.in_window(r.stamps, t_open, t_close))
+                 for r in everything)
+    ttft = [r.stamps[0] - r.planned.due for r in everything
+            if r.stamps and t_open <= r.stamps[0] < t_close]
+    gaps = [g for r in everything
+            for g in stats.gaps_in_window(r.stamps, t_open, t_close)]
+    ended = [r for r in done if t_open <= r.ended < t_close]
+    failed = [r for r in ended if r.reason != "length"]
+    late = [r.submitted - r.planned.due for r in everything
+            if t_open <= r.submitted < t_close]
+    ctx.log({"phase": "window", "window_s": window_s, "tokens": tokens,
+             "steps": len(steps), "host_calls": calls,
+             "requests_ended": len(ended),
+             "requests_failed": len(failed),
+             "end_reasons": sorted({r.reason for r in ended}),
+             "admission_retries": admission_retries,
+             "generator_lateness_ms": {
+                 "p50": 1e3 * (stats.percentile(late, 50) or 0.0),
+                 "max": 1e3 * max(late, default=0.0)},
+             "ttft_ms_p50": 1e3 * (stats.percentile(ttft, 50) or 0.0),
+             "itl_ms_p50": 1e3 * (stats.percentile(gaps, 50) or 0.0),
+             "compiles_in_window": compiles,
+             "samples": {"serve_tokens_per_s": tokens,
+                         "ttft_p90_ms": len(ttft), "itl_p95_ms": len(gaps)}})
+
+    # --- correctness, outside the window ---------------------------------
+    good = [r for r in done if r.reason == "length"]
+    rng = np.random.default_rng([int(ctx.seed), 2])
+    pick = [good[i] for i in rng.permutation(len(good))[:CHECKED_REQUESTS]]
+    flat = reference.argmax_gaps(
+        reference.Widths.from_hf(
+            model_lib.reference_widths(conf, ctx.rehearse)), eng.params,
+        [r.planned.prompt for r in pick],
+        [list(r.req.tokens_out) for r in pick], device) \
+        if pick else np.zeros(0)
+    exact = float((flat == 0.0).mean()) if len(flat) else 0.0
+    worst = float(flat.max()) if len(flat) else float("inf")
+    checks = {
+        "requests_checked": len(pick) >= (1 if ctx.rehearse
+                                          else CHECKED_REQUESTS),
+        "tokens_within_near_tie": bool(np.isfinite(flat).all())
+            and worst <= NEAR_TIE_LOGITS,
+        "exact_argmax_floor": exact >= MIN_EXACT_ARGMAX,
+        "every_request_full_length": all(
+            len(r.req.tokens_out) == r.planned.max_new_tokens and
+            len(r.stamps) == r.planned.max_new_tokens for r in good),
+        "no_failed_request": not failed,
+        "no_compile_in_window": compiles["compiles"] == 0,
+        "tails_have_samples": len(ttft) > 0 and len(gaps) > 0,
+    }
+    ctx.log({"phase": "checks", "checked_tokens": int(len(flat)),
+             "exact_argmax_share": exact,
+             "max_gap_below_ref_argmax": worst,
+             "near_tie_tolerance": NEAR_TIE_LOGITS, **checks})
+    return {
+        "correct": all(checks.values()),
+        "attempted": len(ended), "failed": len(failed),
+        "end_to_end": {
+            "serve_tokens_per_s": tokens / window_s,
+            "ttft_p90_ms": 1e3 * stats.percentile(ttft, 90)
+                if ttft else None,
+            "itl_p95_ms": 1e3 * stats.percentile(gaps, 95)
+                if gaps else None},
+        "span_name": SPAN,
+        "gap_spans": [SPAN, "serving/engine_step"],
+        "facts": {"kind": "serve", "model": model, "steps": steps,
+                  "ttft_s": ttft,
+                  "spans": spans, "host_calls": calls, "tokens": tokens,
+                  "traced_host_window": tuple(traced[:2]) if traced else None,
+                  "traced_step_range": (traced[2], traced[3])
+                      if traced else None},
+    }
