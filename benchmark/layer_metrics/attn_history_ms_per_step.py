@@ -1,0 +1,14 @@
+"""Device self time of the operations under the ``attn_history`` scope (the
+split program's attention over the PRE-write arena, plain XLA today) per
+traced server step: ``trace/scopes.py`` puts each operation of device 0
+down to its program by the enclosing ``XLA Modules`` event and to its scope
+by the program's own table (``compile_monitor.scopes``)."""
+
+from benchmark.trace import scopes
+
+LAYER = "step programs"
+MOVES = "itl_p95_ms"
+
+
+def read(run):
+    return scopes.scope_ms_per_step(run, ('attn_history',))

@@ -350,10 +350,9 @@ def server_phase(args, ledger, device) -> None:
     # what each compiled program carries (re-lowered from the live arrays:
     # same module, so with the persistent cache on this is a cache hit)
     programs, missing, kinds = {}, [], set()
-    mb = eng.mb
     for (nb, cb, mode, fresh), fn in sorted(eng._step_fns.items(), key=str):
         packed = jax.ShapeDtypeStruct(
-            (nb * cb + nb + nb + nb * mb + 2,), np.int32)
+            (eng._packed_len(nb, cb),), np.int32)
         text = fn.lower(eng.params, eng.arena, packed,
                         eng._rng_dev).compile().as_text()
         kind = "decode" if cb == 1 else str(fresh)
@@ -366,15 +365,17 @@ def server_phase(args, ledger, device) -> None:
         want = "paged_attn" if kind in ("decode", "False") else "flash_fwd"
         if want not in found["named"]:
             missing.append(f"step n={nb} c={cb} {kind}: no {want}")
-    for (nb, sb, mode), fn in sorted(eng._fused_fns.items(), key=str):
+    # the key's fourth element is the width of the page table the call
+    # passed (one compiled program a width, PR 28)
+    for (nb, sb, mode, pw), fn in sorted(eng._fused_fns.items(), key=str):
         i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32)
         f32 = jax.ShapeDtypeStruct((), np.float32)
         text = fn.lower(eng.params, eng.arena, i32(nb), i32(nb), i32(nb),
-                        i32(nb, mb), i32(), i32(nb), i32(nb), f32, f32,
+                        i32(nb, pw), i32(), i32(nb), i32(nb), f32, f32,
                         eng._rng_dev).compile().as_text()
         # reported, not asserted: the fused chunk reads history through
         # the XLA gather on purpose (engine_v2._fused_decode_fn)
-        programs[f"fused n={nb} steps={sb}"] = kernels_in(
+        programs[f"fused n={nb} steps={sb} pages={pw}"] = kernels_in(
             text, FLASH_KERNELS + PAGED_KERNELS)
     emit({"phase": "server/programs", "programs": programs,
           "compile": ledger.delta(c0, ledger.snapshot()),

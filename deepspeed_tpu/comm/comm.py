@@ -113,9 +113,8 @@ AxisName = Union[str, Sequence[str]]
 
 def _timed(op: str, x: jax.Array, axis: AxisName, run) -> jax.Array:
     """Register the collective with the CommsLogger, and — on the
-    synchronous path in verbose mode — record its MEASURED wall time so
-    the goodput ledger's ``comm_exposed`` attribution has a ground-truth
-    cross-check against the roofline estimate. Inside shard_map/pmap
+    synchronous path in verbose mode — record its MEASURED wall time (a
+    cross-check for the roofline's estimate). Inside shard_map/pmap
     ``x`` is an abstract tracer: timing a trace-time call would clock
     XLA's lowering, not the collective, so those register untimed (the
     roofline remains the estimate there). The timed path blocks on the

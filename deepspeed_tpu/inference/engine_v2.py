@@ -116,33 +116,40 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
             # no write→read serialization. Fresh rows mixed in have
             # empty history (lse ≈ -1e30 → weight 0); decode rows ride
             # along as width-1 chunks.
-            out_h, lse_h = pa.paged_attention_hist_xla(
-                q, ak, av, pt_l, starts)
-        ak, av = pa.write_kv(ak, av, k, v, pt_l, starts, counts,
-                             trash_block=off + stride - 1)
+            with jax.named_scope("attn_history"):
+                out_h, lse_h = pa.paged_attention_hist_xla(
+                    q, ak, av, pt_l, starts)
+        with jax.named_scope("kv_write"):
+            ak, av = pa.write_kv(ak, av, k, v, pt_l, starts, counts,
+                                 trash_block=off + stride - 1)
         if fresh_prefill == "fresh":
             # starts == 0 everywhere: the chunk IS the whole history —
             # plain causal attention over it; padded-tail rows produce
             # garbage outputs nothing reads (their KV went to trash)
-            if use_pallas:
-                from deepspeed_tpu.ops.flash_attention import flash_attention
-                out = flash_attention(q, k, v, causal=True)
-            else:
-                from deepspeed_tpu.models.transformer import \
-                    dot_product_attention
-                out = dot_product_attention(q, k, v, causal=True)
+            with jax.named_scope("attn_core"):
+                if use_pallas:
+                    from deepspeed_tpu.ops.flash_attention import \
+                        flash_attention
+                    out = flash_attention(q, k, v, causal=True)
+                else:
+                    from deepspeed_tpu.models.transformer import \
+                        dot_product_attention
+                    out = dot_product_attention(q, k, v, causal=True)
         elif split:
-            if use_pallas:
-                from deepspeed_tpu.ops.flash_attention import \
-                    flash_attention_with_lse
-                out_c, lse_c = flash_attention_with_lse(q, k, v,
-                                                        causal=True)
-            else:
-                out_c, lse_c = pa.causal_attention_with_lse(q, k, v)
-            out = pa.merge_attention(out_h, lse_h, out_c,
-                                     lse_c).astype(q.dtype)
+            with jax.named_scope("attn_core"):
+                if use_pallas:
+                    from deepspeed_tpu.ops.flash_attention import \
+                        flash_attention_with_lse
+                    out_c, lse_c = flash_attention_with_lse(q, k, v,
+                                                            causal=True)
+                else:
+                    out_c, lse_c = pa.causal_attention_with_lse(q, k, v)
+            with jax.named_scope("attn_merge"):
+                out = pa.merge_attention(out_h, lse_h, out_c,
+                                         lse_c).astype(q.dtype)
         else:
-            out = attend(q, ak, av, pt_l, starts, counts)
+            with jax.named_scope("attn_core"):
+                out = attend(q, ak, av, pt_l, starts, counts)
         attn_out = attn_out_project(cfg, lp["attn"], out)
         h_out, _aux = block_combine(cfg, lp, x, h_in, attn_out, moe_fn)
         return (h_out, ak, av), None
@@ -151,8 +158,9 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
         body, (x, arena["k"], arena["v"]),
         (params["layers"], jnp.arange(num_layers, dtype=jnp.int32)))
     x = _norm(cfg, params["final_norm"], x)
-    last = jnp.maximum(counts - 1, 0)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
+    with jax.named_scope("lm_head"):       # the rows the head projects
+        last = jnp.maximum(counts - 1, 0)
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
     logits = lm_logits(cfg, params, x_last)[:, 0]
     return logits, {"k": ak, "v": av}
 
@@ -171,6 +179,31 @@ def _dispatch_count(name: str, by: int = 1) -> None:
     registry.counter(name).inc(by)
 
 
+def _step_kind(cb: int, fresh) -> str:
+    """Which step program a batch runs: ``decode`` (one token a row),
+    ``fresh`` / ``split`` (a prefill chunk attending inside the chunk /
+    also the pre-write arena) or ``paged`` (a chunk through the single
+    paged read, the DSTPU_NO_SPLIT_PREFILL escape hatch). The tag of the
+    ``serving/dispatch`` span and of ``dispatch/steps.<kind>``, and the
+    prefix of the program's name after ``serve_``."""
+    if cb == 1:
+        return "decode"
+    return fresh if fresh in ("fresh", "split") else "paged"
+
+
+def _mode_suffix(mode) -> str:
+    """The statics of a step program's sampling mode as part of its name,
+    so that one name stays one compiled module; greedy token ids, the
+    serving default, add nothing."""
+    if mode is None:
+        return "_logits"
+    if mode[0] == "argmax":
+        return ""
+    _, top_k, use_top_p = mode
+    return f"_sample_k{top_k}" + ("_p" if use_top_p else "")
+
+
+@jax.named_scope("sample")
 def _sample_tokens(logits, mode, temperature, top_p, rng):
     """Shared on-device sampling (mode is STATIC: ('argmax',) or
     ('sample', top_k, use_top_p); temperature/top_p are traced scalars so
@@ -343,6 +376,9 @@ class RaggedInferenceEngineTPU:
         self._fused_fns: Dict[Any, Any] = {}
         #: jit for prefix-cache copy-on-write page duplication
         self._copy_pages_fn = None
+        #: kind of the last device program launched (``_step_kind`` or
+        #: ``megastep``): the ``program`` of ``serving/engine_step``
+        self.last_program: Optional[str] = None
         self._rng_dev = rng          # defaulted to PRNGKey(0) above
         self._temperature = 1.0      # dynamic sampling scalars, packed
         self._top_p = 1.0            # into the step upload
@@ -350,7 +386,8 @@ class RaggedInferenceEngineTPU:
                  f"{config.block_size} pallas={self.use_pallas} "
                  f"dtype={config.dtype}")
 
-    def _step_fn(self, nb: int, cb: int, mode, fresh: bool = False):
+    def _step_fn(self, nb: int, cb: int, mode,
+                 fresh: Union[bool, str] = False):
         """mode: None → raw logits; ("argmax",) → greedy token ids;
         ("sample", top_k, use_top_p) → sampled token ids. Token modes
         fetch [n] int32 instead of the [n, V] fp32 logits (8 MB per step
@@ -358,7 +395,10 @@ class RaggedInferenceEngineTPU:
         inside the step (no per-step key upload). Temperature/top_p are
         DYNAMIC scalars bitcast into the packed vector, so changing them
         per request does NOT recompile the model forward (only top_k and
-        the top-p on/off switch are static)."""
+        the top-p on/off switch are static). ``fresh``: False, "fresh" or
+        "split" as ``ragged_forward``'s ``fresh_prefill``."""
+        if fresh is True:   # pre-three-mode boolean API: one key, one name
+            fresh = "fresh"
         key = (nb, cb, mode, fresh)
         if key in self._step_fns:
             return self._step_fns[key]
@@ -370,6 +410,10 @@ class RaggedInferenceEngineTPU:
                                        "mode": str(mode), "fresh": fresh})
         mb = self.mb
         model = self.model_config
+        # the module's name in a device trace (after ``jit_``): kind and
+        # static shape, so one name is one compiled module
+        name = f"serve_{_step_kind(cb, fresh)}_r{nb}" + \
+            (f"_c{cb}" if cb > 1 else "") + _mode_suffix(mode)
 
         def fn(params, arena, packed, rng):
             off = 0
@@ -394,7 +438,12 @@ class RaggedInferenceEngineTPU:
                                       rng)
             return out, rng, arena
 
+        fn.__name__ = fn.__qualname__ = name
         jitted = jax.jit(fn, donate_argnums=(1,))
+        compile_monitor.register_program(name, jitted, (
+            self.params, self.arena,
+            jax.ShapeDtypeStruct((self._packed_len(nb, cb),), jnp.int32),
+            self._rng_dev))
         self._step_fns[key] = jitted
         return jitted
 
@@ -418,6 +467,11 @@ class RaggedInferenceEngineTPU:
             blocks = self.state.seqs[uid].blocks
             pt[i, :len(blocks)] = blocks
         return pt
+
+    def _packed_len(self, nb: int, cb: int) -> int:
+        """Length of :meth:`_pack`'s vector: tokens | counts | starts |
+        page table | the two sampling scalars."""
+        return nb * cb + 2 * nb + nb * self.mb + 2
 
     def _pack(self, batch: RaggedBatch, nb: int, cb: int) -> np.ndarray:
         n = len(batch.uids)
@@ -637,20 +691,33 @@ class RaggedInferenceEngineTPU:
         mb_need = int(-(-(int(starts0.max()) + limit) // bs))
         mb_b = min(self.mb, -(-mb_need // 4) * 4)
         pt = pt[:, :mb_b]
-        from deepspeed_tpu import telemetry
-        with telemetry.tracer.span("serving/megastep", n=n, k=int(limit),
-                                   scan_bucket=sb):
+        from deepspeed_tpu.telemetry.tracer import tracer
+        with tracer.span("serving/pack"):
+            args = (jnp.asarray(tokens0), jnp.asarray(starts0),
+                    jnp.asarray(live), jnp.asarray(pt), jnp.int32(limit),
+                    jnp.asarray(bud), jnp.asarray(eos),
+                    jnp.float32(self._temperature),
+                    jnp.float32(self._top_p))
+        with tracer.span("serving/dispatch", program="megastep",
+                         k=int(limit)) as sp:
             ys, counts, self._rng_dev, self.arena = self._fused_decode_fn(
-                nb, sb, mode)(
-                    self.params, self.arena, jnp.asarray(tokens0),
-                    jnp.asarray(starts0), jnp.asarray(live),
-                    jnp.asarray(pt), jnp.int32(limit), jnp.asarray(bud),
-                    jnp.asarray(eos), jnp.float32(self._temperature),
-                    jnp.float32(self._top_p), self._rng_dev)
+                nb, sb, mode, mb_b)(
+                    self.params, self.arena, *args, self._rng_dev)
+        with tracer.span("serving/fetch"):
             ys, counts = jax.device_get((ys, counts))   # ONE sync for K
         ys = np.asarray(ys)
         counts = np.asarray(counts)
-        _dispatch_count("dispatch/host_calls")
+        # what the launch did is known only now: a row emitted counts[j]
+        # tokens, its i-th one attending starts0[j] + i + 1 cached tokens;
+        # the program ran sb scan steps over nb rows, each attending the
+        # whole sliced page table
+        c64 = counts[:n].astype(np.int64)
+        work = self._count_dispatch(
+            "megastep", n, nb, 1, mb_b, int(c64.sum()),
+            int((c64 * starts0[:n] + c64 * (c64 + 1) // 2).sum()),
+            scan_steps=sb)
+        if sp is not None:      # still the recorded event's arguments
+            sp.update(work)
         _dispatch_count("dispatch/scan_steps", sb)
         _dispatch_count("dispatch/dead_steps", sb - limit)
         _dispatch_count("dispatch/megastep_launches")
@@ -778,12 +845,46 @@ class RaggedInferenceEngineTPU:
             fresh = "fresh"
         else:
             fresh = "split"
-        packed = jnp.asarray(self._pack(batch, nb, cb))   # ONE upload
-        out, self._rng_dev, self.arena = self._step_fn(nb, cb, mode,
-                                                       fresh)(
-            self.params, self.arena, packed, self._rng_dev)
-        _dispatch_count("dispatch/host_calls")
-        return np.asarray(jax.device_get(out))[:n]
+        from deepspeed_tpu.telemetry.tracer import tracer
+        with tracer.span("serving/pack"):
+            packed = jnp.asarray(self._pack(batch, nb, cb))  # ONE upload
+        work = self._count_dispatch(
+            _step_kind(cb, fresh), n, nb, cb, self.mb,
+            int(batch.token_counts.sum()),
+            int((batch.start_positions + batch.token_counts).sum()))
+        with tracer.span("serving/dispatch",
+                         **(work if tracer.enabled else {})):
+            out, self._rng_dev, self.arena = self._step_fn(
+                nb, cb, mode, fresh)(
+                self.params, self.arena, packed, self._rng_dev)
+        with tracer.span("serving/fetch"):           # waits for the device
+            return np.asarray(jax.device_get(out))[:n]
+
+    def _count_dispatch(self, program: str, rows: int, nb: int, chunk: int,
+                        page_width: int, tokens: int, context_tokens: int,
+                        scan_steps: int = 1) -> Dict[str, Any]:
+        """Count one device program launch where its batch is packed: the
+        useful work (``tokens`` fed, ``context_tokens`` of live KV they
+        attend) against the work attempted (``slots`` = bucketed rows x
+        chunk width; ``context_slots`` = bucketed rows x the page table's
+        width in tokens; both times the scan steps of a megastep).
+        Always-on ``dispatch/*`` counters; the same numbers are the
+        ``serving/dispatch`` span's arguments."""
+        from deepspeed_tpu.telemetry.registry import registry
+        slots = nb * chunk * scan_steps
+        context_slots = nb * page_width * self.config.block_size * \
+            scan_steps
+        self.last_program = program
+        for name, by in (("host_calls", 1), ("tokens", tokens),
+                         ("token_slots", slots),
+                         ("context_tokens", context_tokens),
+                         ("context_slots", context_slots),
+                         (f"steps.{program}", 1)):
+            registry.counter("dispatch/" + name).inc(by)
+        return {"program": program, "rows": rows, "rows_bucket": nb,
+                "chunk": chunk, "tokens": tokens, "slots": slots,
+                "context_tokens": context_tokens,
+                "context_slots": context_slots}
 
     # -- fused decode loop (generate fast path) ----------------------------
 
@@ -793,7 +894,7 @@ class RaggedInferenceEngineTPU:
     #: all rows dead (KV to trash, outputs discarded) — ≤31 wasted steps
     _FUSED_STEP_BUCKET = 32
 
-    def _fused_decode_fn(self, nb: int, sb: int, mode):
+    def _fused_decode_fn(self, nb: int, sb: int, mode, pw: int):
         """jit: up to `sb` single-token decode iterations in ONE device
         program — the per-token host round-trips of the stepwise loop
         (2+ per token) collapse to one upload + one [sb, nb] fetch.
@@ -821,16 +922,20 @@ class RaggedInferenceEngineTPU:
         produces the same sample stream whether it runs as one program
         or several (megastep chunking invariance).
 
+        ``pw`` is the width of the (sliced) page table the call passes:
+        a shape, so part of the jit-cache key and of the program's name.
+
         Returns ``(ys [sb, nb], counts [nb], rng, arena)``."""
-        key = (nb, sb, mode)
+        key = (nb, sb, mode, pw)
         if key in self._fused_fns:
             return self._fused_fns[key]
         if os.environ.get("DSTPU_FUSED_V1"):
-            return self._fused_decode_fn_v1(nb, sb, mode)
+            return self._fused_decode_fn_v1(nb, sb, mode, pw)
         from deepspeed_tpu.telemetry import compile_monitor
         compile_monitor.count_trace(
             "serving/fused_decode_fn",
-            detail={"n_bucket": nb, "steps": sb, "mode": str(mode)})
+            detail={"n_bucket": nb, "steps": sb, "mode": str(mode),
+                    "page_width": pw})
         model = self.model_config
         from deepspeed_tpu.ops.paged_attention import _masked_attention
 
@@ -882,30 +987,34 @@ class RaggedInferenceEngineTPU:
                     # at n=16 on v5e); opt in via DSTPU_FUSED_PALLAS_HIST
                     # for wide-batch/long-context serving where walking
                     # only the true pages wins back the gather padding
-                    if self.use_pallas and \
-                            os.environ.get("DSTPU_FUSED_PALLAS_HIST"):
-                        out_h, lse_h = pa.paged_attention_with_lse(
-                            q, ak_c, av_c, pt_l, starts0,
-                            jnp.zeros_like(starts0))
-                    else:
-                        out_h, lse_h = pa.paged_attention_hist_xla(
-                            q, ak_c, av_c, pt_l, starts0)
+                    with jax.named_scope("attn_history"):
+                        if self.use_pallas and \
+                                os.environ.get("DSTPU_FUSED_PALLAS_HIST"):
+                            out_h, lse_h = pa.paged_attention_with_lse(
+                                q, ak_c, av_c, pt_l, starts0,
+                                jnp.zeros_like(starts0))
+                        else:
+                            out_h, lse_h = pa.paged_attention_hist_xla(
+                                q, ak_c, av_c, pt_l, starts0)
                     # decode window: this loop's own tokens (incl. self)
-                    kbuf = lax.dynamic_update_slice(
-                        kbuf, k[:, 0][None, None].astype(kbuf.dtype),
-                        (l_idx, i, 0, 0, 0))
-                    vbuf = lax.dynamic_update_slice(
-                        vbuf, v[:, 0][None, None].astype(vbuf.dtype),
-                        (l_idx, i, 0, 0, 0))
+                    with jax.named_scope("kv_write"):
+                        kbuf = lax.dynamic_update_slice(
+                            kbuf, k[:, 0][None, None].astype(kbuf.dtype),
+                            (l_idx, i, 0, 0, 0))
+                        vbuf = lax.dynamic_update_slice(
+                            vbuf, v[:, 0][None, None].astype(vbuf.dtype),
+                            (l_idx, i, 0, 0, 0))
                     kd = lax.dynamic_index_in_dim(
                         kbuf, l_idx, 0, keepdims=False)       # [sb,nb,..]
                     vd = lax.dynamic_index_in_dim(vbuf, l_idx, 0,
                                                   keepdims=False)
-                    out_d, lse_d = _masked_attention(
-                        q, kd.transpose(1, 2, 0, 3),
-                        vd.transpose(1, 2, 0, 3), dec_mask, True)
-                    out = pa.merge_attention(out_h, lse_h, out_d,
-                                             lse_d).astype(q.dtype)
+                    with jax.named_scope("attn_core"):
+                        out_d, lse_d = _masked_attention(
+                            q, kd.transpose(1, 2, 0, 3),
+                            vd.transpose(1, 2, 0, 3), dec_mask, True)
+                    with jax.named_scope("attn_merge"):
+                        out = pa.merge_attention(out_h, lse_h, out_d,
+                                                 lse_d).astype(q.dtype)
                     attn_out = attn_out_project(model, lp["attn"], out)
                     h_out, _aux = block_combine(model, lp, xl, h_in,
                                                 attn_out, self._moe_fn)
@@ -955,10 +1064,11 @@ class RaggedInferenceEngineTPU:
                 ak, av = carry
                 kb, vb, l_idx = inp                  # kb [sb, nb, kvh, dh]
                 pt_l = pt + l_idx * stride
-                ak, av = pa.write_kv(
-                    ak, av, kb.transpose(1, 0, 2, 3),
-                    vb.transpose(1, 0, 2, 3), pt_l, starts0, counts_wb,
-                    trash_block=l_idx * stride + stride - 1)
+                with jax.named_scope("kv_write"):
+                    ak, av = pa.write_kv(
+                        ak, av, kb.transpose(1, 0, 2, 3),
+                        vb.transpose(1, 0, 2, 3), pt_l, starts0, counts_wb,
+                        trash_block=l_idx * stride + stride - 1)
                 return (ak, av), None
 
             (ak, av), _ = lax.scan(
@@ -966,23 +1076,43 @@ class RaggedInferenceEngineTPU:
                 (kbuf, vbuf, jnp.arange(num_layers, dtype=jnp.int32)))
             return ys, counts, rng, {"k": ak, "v": av}
 
-        jitted = jax.jit(fn, donate_argnums=(1,))
+        jitted = self._name_megastep(fn, key)
         self._fused_fns[key] = jitted
         return jitted
 
-    def _fused_decode_fn_v1(self, nb: int, sb: int, mode):
+    def _name_megastep(self, fn, key):
+        """jit a fused decode loop under ``serve_megastep_r<rows>_k<scan
+        steps>_p<page-table width>`` and register it for the scope table
+        (``key``: the jit-cache key, a fifth element marks the v1 loop)."""
+        from deepspeed_tpu.telemetry import compile_monitor
+        nb, sb, mode, pw = key[:4]
+        name = f"serve_megastep_r{nb}_k{sb}_p{pw}" + _mode_suffix(mode) + \
+            "".join(f"_{tag}" for tag in key[4:])
+        fn.__name__ = fn.__qualname__ = name
+        jitted = jax.jit(fn, donate_argnums=(1,))
+        rows = jax.ShapeDtypeStruct((nb,), jnp.int32)
+        compile_monitor.register_program(name, jitted, (
+            self.params, self.arena, rows, rows, rows,
+            jax.ShapeDtypeStruct((nb, pw), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32), rows, rows,
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.ShapeDtypeStruct((), jnp.float32), self._rng_dev))
+        return jitted
+
+    def _fused_decode_fn_v1(self, nb: int, sb: int, mode, pw: int):
         """The r4 arena-carrying loop (XLA attend, arena copied per
         iteration) — kept for A/B via DSTPU_FUSED_V1. Signature-identical
         to :meth:`_fused_decode_fn` including the per-row budget/eos
         dead-masking (here dead rows write no KV at all: ragged_forward
         clips by the per-row counts)."""
-        key = (nb, sb, mode, "v1")
+        key = (nb, sb, mode, pw, "v1")
         if key in self._fused_fns:
             return self._fused_fns[key]
         from deepspeed_tpu.telemetry import compile_monitor
         compile_monitor.count_trace(
             "serving/fused_decode_fn_v1",
-            detail={"n_bucket": nb, "steps": sb, "mode": str(mode)})
+            detail={"n_bucket": nb, "steps": sb, "mode": str(mode),
+                    "page_width": pw})
         model = self.model_config
 
         def fn(params, arena, tokens0, starts0, live, pt, limit, budgets,
@@ -1008,7 +1138,7 @@ class RaggedInferenceEngineTPU:
                 jnp.arange(sb, dtype=jnp.int32))
             return ys, counts, rng, arena
 
-        jitted = jax.jit(fn, donate_argnums=(1,))
+        jitted = self._name_megastep(fn, key)
         self._fused_fns[key] = jitted
         return jitted
 
@@ -1084,7 +1214,7 @@ class RaggedInferenceEngineTPU:
         mb_b = min(self.mb, -(-mb_need // 4) * 4)
         pt = pt[:, :mb_b]
         ys, counts, self._rng_dev, self.arena = self._fused_decode_fn(
-            nb, sb, mode)(
+            nb, sb, mode, mb_b)(
                 self.params, self.arena, jnp.asarray(tokens0),
                 jnp.asarray(starts0), jnp.asarray(live), jnp.asarray(pt),
                 jnp.int32(steps), jnp.asarray(bud), jnp.asarray(eos),

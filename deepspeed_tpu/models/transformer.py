@@ -17,7 +17,14 @@ designed TPU-first:
   (deepspeed/sequence/layer.py analogue), or ring attention — selected by
   the engine from the config;
 - supports GPT-2 (learned pos, LayerNorm, gelu MLP, biases) and Llama
-  (RoPE, RMSNorm, SwiGLU, no biases, GQA) families from one code path.
+  (RoPE, RMSNorm, SwiGLU, no biases, GQA) families from one code path;
+- the layer parts carry ``jax.named_scope``s from ONE fixed vocabulary
+  (``telemetry/explain.SCOPE_VOCABULARY``: ``embed``, ``norm``,
+  ``attn_qkv``, ``attn_core``, ``attn_out``, ``mlp``, ``moe``,
+  ``lm_head``, ``loss`` here), shared by the trainer and the servers, so
+  a device trace can be summed under the program's own words
+  (``compile_monitor.scopes``). A scope lives in HLO metadata only: it
+  changes no program and costs nothing at run time.
 """
 
 import dataclasses
@@ -257,6 +264,7 @@ class DecoderConfig:
 # Normalization (Pallas-accelerated versions live in deepspeed_tpu/ops)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("norm")
 def _norm(cfg: DecoderConfig, params: Params, x: jax.Array) -> jax.Array:
     x32 = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
@@ -278,6 +286,7 @@ def _norm_params(cfg: DecoderConfig, shape_prefix=()) -> Params:
     return p
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: DecoderConfig, em: Params, tokens: jax.Array,
                  positions: jax.Array,
                  embed_norm: Optional[Params] = None,
@@ -306,6 +315,7 @@ def embed_tokens(cfg: DecoderConfig, em: Params, tokens: jax.Array,
 # Rotary embeddings
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attn_qkv")
 def rope_table(cfg: DecoderConfig, positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """positions: [B, T] int32 → (sin, cos) each [B, T, rope_dim//2]
     (rope_dim == head_dim unless rotary_pct < 1 — GPT-NeoX partial
@@ -567,6 +577,7 @@ def linear_2d(x: jax.Array, p: Params, name: str) -> jax.Array:
     return out.reshape(*lead, w.shape[-1])
 
 
+@jax.named_scope("mlp")
 def _mlp(cfg: DecoderConfig, p: Params, x: jax.Array) -> jax.Array:
     if cfg.is_glu:
         gate = linear_2d(x, p, "wg")
@@ -589,6 +600,7 @@ def _mlp(cfg: DecoderConfig, p: Params, x: jax.Array) -> jax.Array:
     return out
 
 
+@jax.named_scope("attn_qkv")
 def qkv_project(cfg: DecoderConfig, p: Params, x: jax.Array, sin, cos
                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Shared projection for training and KV-cached inference:
@@ -610,6 +622,7 @@ def qkv_project(cfg: DecoderConfig, p: Params, x: jax.Array, sin, cos
     return q, k, v
 
 
+@jax.named_scope("attn_out")
 def attn_out_project(cfg: DecoderConfig, p: Params, out: jax.Array
                      ) -> jax.Array:
     b, t = out.shape[:2]
@@ -623,8 +636,9 @@ def _attention_block(cfg: DecoderConfig, p: Params, x: jax.Array,
                      sin, cos, attn_fn: AttentionFn,
                      layer_window: Optional[jax.Array] = None) -> jax.Array:
     q, k, v = qkv_project(cfg, p, x, sin, cos)
-    out = attn_fn(q, k, v) if layer_window is None \
-        else attn_fn(q, k, v, window=layer_window)
+    with jax.named_scope("attn_core"):
+        out = attn_fn(q, k, v) if layer_window is None \
+            else attn_fn(q, k, v, window=layer_window)
     return attn_out_project(cfg, p, out)
 
 
@@ -671,7 +685,8 @@ def block_combine(cfg: DecoderConfig, p: Params, x: jax.Array,
     """
     def ffn(src):
         if cfg.num_experts and moe_fn is not None:
-            ret = moe_fn(cfg, p["moe"], src)
+            with jax.named_scope("moe"):
+                ret = moe_fn(cfg, p["moe"], src)
             out, aux = ret[0], ret[1]
             # 3rd element = router-health stats, present iff the moe
             # layer saw cfg.health_taps (parallel/moe.py)
@@ -931,6 +946,7 @@ def mlm_transform(cfg: DecoderConfig, mh: Params, x: jax.Array) -> jax.Array:
     return _norm(cfg, mh["ln"], x)
 
 
+@jax.named_scope("lm_head")
 def lm_logits(cfg: DecoderConfig, params: Params, x: jax.Array,
               pre_transformed: bool = False) -> jax.Array:
     """Final projection: hidden [B,T,D] → logits [B,T,V] fp32.
@@ -1014,6 +1030,7 @@ def _pick_chunk(t: int, b: int, v: int,
     return best
 
 
+@jax.named_scope("loss")
 def chunked_cross_entropy(cfg: DecoderConfig, params: Params, x: jax.Array,
                           targets: jax.Array, ignore_index: int = -100,
                           chunk_size: Optional[int] = None,
@@ -1092,6 +1109,7 @@ def chunked_cross_entropy(cfg: DecoderConfig, params: Params, x: jax.Array,
     return nll / jnp.maximum(cnt, 1)
 
 
+@jax.named_scope("loss")
 def cross_entropy_loss(logits: jax.Array, targets: jax.Array,
                        ignore_index: int = -100) -> jax.Array:
     """Token-mean CE in fp32 (reference: sequence/cross_entropy.py

@@ -562,8 +562,13 @@ def _dw_pair(xs, dg, du, g_of_tile, live_tiles, num_experts, bm,
 
 def _grid_call(kernel, grid, in_specs, out_specs, out_shape, interpret,
                group_of_tile, live_tiles, *args, scratch=None):
+    # the kernel's name is the HLO instruction's in a device trace:
+    # gmm_gate_up, gmm_down, gmm_down_w, gmm_dgdu, gmm_dgdu_rc, gmm_dxs,
+    # gmm_dw_pair
+    body = getattr(kernel, "func", kernel).__name__
     return pl.pallas_call(
         kernel,
+        name="gmm" + body.removesuffix("_kernel"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid,
             in_specs=in_specs, out_specs=out_specs,

@@ -258,6 +258,7 @@ def _qmm(x: jax.Array, w: jax.Array, scale: jax.Array, bm: int, bn: int,
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         functools.partial(_qmm_kernel, nk=nk),
+        name="qmm",
         grid=(m // bm, n // bn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -387,6 +388,7 @@ def _packed_qmm(x, w_q, scale, *, mode: str, interpret: bool, out_dtype,
                 dimension_semantics=("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
         kern, grid=grid,
+        name=f"qmm_{mode}" + ("_batched" if batched else ""),
         in_specs=x_specs + [w_spec, s_spec],
         out_specs=out_spec, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
@@ -591,6 +593,7 @@ def qmatmul_batched(x: jax.Array, w_q: jax.Array, scale: jax.Array,
                                  "arbitrary"))
     out = pl.pallas_call(
         functools.partial(_qmm_batched_kernel, nk=nk),
+        name="qmm_batched",
         grid=(g, mp // bm, n // bn, nk),
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda gg, i, j, kk: (gg, i, kk)),

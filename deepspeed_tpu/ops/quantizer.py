@@ -149,6 +149,7 @@ def quantize_blocks_pallas(x: jax.Array, block: int = DEFAULT_BLOCK,
         rp -= 1
     q, s = pl.pallas_call(
         functools.partial(_quant_kernel, qmax=127.0),
+        name="quantize_blocks",
         grid=(nb // rp,),
         in_specs=[pl.BlockSpec((rp, block), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((rp, block), lambda i: (i, 0)),

@@ -393,50 +393,62 @@ class DeepSpeedTPUEngine:
 
     def _apply_update(self, params, opt_state, scaler, grads, step, gas,
                       fwd_metrics=None):
+        # named scopes (telemetry/explain.SCOPE_VOCABULARY): the unscale,
+        # the global norm and the clip are ``grad_clip``, the update is
+        # ``optimizer``, the loss scaler and the health outputs
+        # ``step_misc``
         cfg = self.config
-        inv = 1.0 / (scaler.scale * gas)
-        grads = jax.tree.map(lambda g: g.astype(jnp.float32) * inv, grads)
-        overflow = check_overflow(grads) if self.fp16_enabled else \
-            jnp.zeros((), bool)
-        # global grad norm (reference get_global_norm + clip_grad_norm_)
-        sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                 for g in jax.tree.leaves(grads))
-        grad_norm = jnp.sqrt(sq)
-        # per-layer health norms use the same pre-clip convention as the
-        # global grad norm above
-        unclipped = grads
-        if cfg.gradient_clipping > 0:
-            clip = jnp.minimum(1.0, cfg.gradient_clipping /
-                               (grad_norm + 1e-6))
-            grads = jax.tree.map(lambda g: g * clip, grads)
-        lr = self.lr_schedule(step)
-        new_params, new_opt = self.optimizer.update(
-            grads, opt_state, params, lr)
-        if self.fp16_enabled:
-            new_params = jax.tree.map(
-                lambda n, o: jnp.where(overflow, o, n), new_params, params)
-            new_opt = jax.tree.map(
-                lambda n, o: jnp.where(overflow, o, n), new_opt, opt_state)
-            scaler = update_scale(
-                scaler, overflow, dynamic=self.dynamic_loss_scale,
-                scale_window=cfg.fp16.loss_scale_window,
-                min_scale=cfg.fp16.min_loss_scale,
-                delayed_shift=cfg.fp16.hysteresis,
-                consecutive_hysteresis=cfg.fp16.consecutive_hysteresis)
-        new_params = jax.lax.with_sharding_constraint(
-            new_params, self._param_shardings)
-        metrics = {"lr": lr, "grad_norm": grad_norm,
-                   "loss_scale": scaler.scale,
-                   "overflow": overflow.astype(jnp.int32)}
-        if fwd_metrics and "aux_loss" in fwd_metrics:
-            metrics["aux_loss"] = fwd_metrics["aux_loss"]
-        if getattr(self, "_health_enabled", False):
-            health = self._per_layer_health(params, unclipped, new_params)
-            fh = (fwd_metrics or {}).get("health")
-            if fh:
-                health = {**health, **fh}
-            if health:
-                metrics["health"] = health
+        with jax.named_scope("grad_clip"):
+            inv = 1.0 / (scaler.scale * gas)
+            grads = jax.tree.map(lambda g: g.astype(jnp.float32) * inv,
+                                 grads)
+            overflow = check_overflow(grads) if self.fp16_enabled else \
+                jnp.zeros((), bool)
+            # global grad norm (reference get_global_norm +
+            # clip_grad_norm_)
+            sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                     for g in jax.tree.leaves(grads))
+            grad_norm = jnp.sqrt(sq)
+            # per-layer health norms use the same pre-clip convention as
+            # the global grad norm above
+            unclipped = grads
+            if cfg.gradient_clipping > 0:
+                clip = jnp.minimum(1.0, cfg.gradient_clipping /
+                                   (grad_norm + 1e-6))
+                grads = jax.tree.map(lambda g: g * clip, grads)
+        with jax.named_scope("optimizer"):
+            lr = self.lr_schedule(step)
+            new_params, new_opt = self.optimizer.update(
+                grads, opt_state, params, lr)
+        with jax.named_scope("step_misc"):
+            if self.fp16_enabled:
+                new_params = jax.tree.map(
+                    lambda n, o: jnp.where(overflow, o, n), new_params,
+                    params)
+                new_opt = jax.tree.map(
+                    lambda n, o: jnp.where(overflow, o, n), new_opt,
+                    opt_state)
+                scaler = update_scale(
+                    scaler, overflow, dynamic=self.dynamic_loss_scale,
+                    scale_window=cfg.fp16.loss_scale_window,
+                    min_scale=cfg.fp16.min_loss_scale,
+                    delayed_shift=cfg.fp16.hysteresis,
+                    consecutive_hysteresis=cfg.fp16.consecutive_hysteresis)
+            new_params = jax.lax.with_sharding_constraint(
+                new_params, self._param_shardings)
+            metrics = {"lr": lr, "grad_norm": grad_norm,
+                       "loss_scale": scaler.scale,
+                       "overflow": overflow.astype(jnp.int32)}
+            if fwd_metrics and "aux_loss" in fwd_metrics:
+                metrics["aux_loss"] = fwd_metrics["aux_loss"]
+            if getattr(self, "_health_enabled", False):
+                health = self._per_layer_health(params, unclipped,
+                                                new_params)
+                fh = (fwd_metrics or {}).get("health")
+                if fh:
+                    health = {**health, **fh}
+                if health:
+                    metrics["health"] = health
         return new_params, new_opt, scaler, metrics
 
     @staticmethod
@@ -494,6 +506,8 @@ class DeepSpeedTPUEngine:
 
     def _build_step_functions(self) -> None:
         gas = int(self.config.gradient_accumulation_steps)
+        #: the fused step registers for the scope table at its first call
+        self._fused_step_registered = False
         #: ZeRO-3 chunked-overlap plan; stays None on every path that
         #: doesn't run the standard fused step (zeropp/onebit/offload/
         #: pipeline fall through to monolithic collectives)
@@ -594,6 +608,9 @@ class DeepSpeedTPUEngine:
                 metrics["loss"] = loss
                 return params, opt_state, scaler, metrics
 
+            # the module's name in a device trace: the fused step's kind
+            pipe_step.__name__ = pipe_step.__qualname__ = \
+                "fused_step_pipeline"
             self._fused_step = jax.jit(pipe_step, donate_argnums=(0, 1, 2))
             self._grad_step = None
             self._acc_add = None
@@ -733,7 +750,7 @@ class DeepSpeedTPUEngine:
             self.global_steps += 1
             self.global_samples += int(self.config.train_batch_size)
             self._last_metrics = metrics
-            self._close_step_span()
+            self._end_step()
             self._write_monitor(metrics)
             return
         with telemetry.tracer.span("train/optimizer", step=self.global_steps):
@@ -748,30 +765,45 @@ class DeepSpeedTPUEngine:
             self.skipped_steps += 1
         metrics = self._note_health(metrics)
         self._last_metrics = metrics
-        self._close_step_span()
+        self._end_step()
         self._write_monitor(metrics)
 
     def train_batch(self, data_iter: Optional[Iterator[Batch]] = None
                     ) -> jax.Array:
         """Fused whole-step path (reference PipelineEngine.train_batch:337 —
-        here the non-pipeline fast path; pipeline engine overrides)."""
+        here the non-pipeline fast path; pipeline engine overrides).
+
+        One ``train/step`` span (a ``StepTraceAnnotation`` with the step
+        number under ``jax_annotations``) from the top of the call, with
+        children ``train/batch`` (fetch, stack, place), ``train/dispatch``
+        (the fused step program's call) and ``train/bookkeeping`` (timer,
+        step telemetry, monitor). The call returns before the device
+        ends, so these are host times."""
+        with telemetry.tracer.span("train/step", step=self.global_steps):
+            return self._train_batch(data_iter)
+
+    def _train_batch(self, data_iter: Optional[Iterator[Batch]]
+                     ) -> jax.Array:
         gas = int(self.config.gradient_accumulation_steps)
         own_data = data_iter is None
-        it = data_iter if data_iter is not None else self._own_data_iterator()
-        # chaos hook (resilience/faults.py): a scheduled preempt delivers
-        # SIGTERM here — this step completes and the elastic agent commits
-        # at its boundary; a nonfinite_grad advisory poisons THIS step
-        # (handled after the batch is consumed, like an overflow skip)
-        chaos = fault_injector.fire("train_step", step=self.global_steps)
-        micros = [next(it) for _ in range(gas)]
-        batch = jax.tree.map(lambda *xs: jnp.stack(xs), *micros)
-        if self.config.check_nan_inf:
-            self._check_batch_consistency(micros, local=own_data)
-        batch = self._place_stacked_batch(batch, local=own_data)
+        self._step_t0 = telemetry.tracer.now()
+        with telemetry.tracer.span("train/batch"):
+            it = data_iter if data_iter is not None \
+                else self._own_data_iterator()
+            # chaos hook (resilience/faults.py): a scheduled preempt
+            # delivers SIGTERM here — this step completes and the elastic
+            # agent commits at its boundary; a nonfinite_grad advisory
+            # poisons THIS step (handled after the batch is consumed, like
+            # an overflow skip)
+            chaos = fault_injector.fire("train_step", step=self.global_steps)
+            micros = [next(it) for _ in range(gas)]
+            batch = jax.tree.map(lambda *xs: jnp.stack(xs), *micros)
+            if self.config.check_nan_inf:
+                self._check_batch_consistency(micros, local=own_data)
+            batch = self._place_stacked_batch(batch, local=own_data)
         if "nonfinite_grad" in chaos:
             return self._skip_poisoned_step(gas)
         self.tput_timer.start()
-        self._step_t0 = telemetry.tracer.now()
         if self._watchdog is not None:
             self._watchdog.arm("train_batch", step=self.global_steps)
         self._rng, sub = jax.random.split(self._rng)
@@ -784,7 +816,7 @@ class DeepSpeedTPUEngine:
             if self.curriculum_scheduler is not None:
                 self.curriculum_scheduler.update_difficulty(self.global_steps)
             self.tput_timer.stop(sync=loss)
-            self._close_step_span()
+            self._end_step()
             self._write_monitor(self._last_metrics)
             return loss
         if self.offload_enabled:
@@ -826,26 +858,37 @@ class DeepSpeedTPUEngine:
                 self.curriculum_scheduler.update_difficulty(self.global_steps)
             self._last_metrics = metrics
             self.tput_timer.stop(sync=loss)
-            self._close_step_span()
+            self._end_step()
             self._write_monitor(metrics)
             return loss
-        self.params, self.opt_state, self.loss_scale_state, metrics = \
-            self._fused_step(self.params, self.opt_state,
-                             self.loss_scale_state, batch,
-                             jnp.int32(self.global_steps), sub)
-        self.global_steps += 1
-        self.micro_steps += gas
-        self.global_samples += int(self.config.train_batch_size)
-        if self.curriculum_scheduler is not None:
-            self.curriculum_scheduler.update_difficulty(self.global_steps)
-        if self.fp16_enabled and int(jax.device_get(metrics["overflow"])):
-            self.skipped_steps += 1
-        metrics = self._note_health(metrics)
-        self._last_metrics = metrics
-        loss = metrics["loss"]
-        self.tput_timer.stop(sync=loss)
-        self._close_step_span()
-        self._write_monitor(metrics)
+        args = (self.params, self.opt_state, self.loss_scale_state, batch,
+                jnp.int32(self.global_steps), sub)
+        if not self._fused_step_registered:
+            # for the scope table (compile_monitor.scopes): abstract
+            # arguments only, before the call donates the real ones
+            telemetry.compile_monitor.register_program(
+                self._fused_step.__name__, self._fused_step, args)
+            self._fused_step_registered = True
+        with telemetry.tracer.span("train/dispatch"):
+            self.params, self.opt_state, self.loss_scale_state, metrics = \
+                self._fused_step(*args)
+        del args
+        with telemetry.tracer.span("train/bookkeeping"):
+            self.global_steps += 1
+            self.micro_steps += gas
+            self.global_samples += int(self.config.train_batch_size)
+            if self.curriculum_scheduler is not None:
+                self.curriculum_scheduler.update_difficulty(
+                    self.global_steps)
+            if self.fp16_enabled and \
+                    int(jax.device_get(metrics["overflow"])):
+                self.skipped_steps += 1
+            metrics = self._note_health(metrics)
+            self._last_metrics = metrics
+            loss = metrics["loss"]
+            self.tput_timer.stop(sync=loss)
+            self._end_step()
+            self._write_monitor(metrics)
         return loss
 
     def _skip_poisoned_step(self, gas: int) -> jax.Array:
@@ -866,7 +909,7 @@ class DeepSpeedTPUEngine:
                    "overflow": 1}
         self._last_metrics = metrics
         record_recovery("skip_nonfinite", step=self.global_steps)
-        self._close_step_span()
+        self._end_step()
         self._write_monitor(metrics)
         return jnp.float32(float("nan"))
 
@@ -1172,10 +1215,6 @@ class DeepSpeedTPUEngine:
         # logged (pure metadata, no compile); the full roofline explain —
         # one extra XLA compile of the step — is opt-in
         self._roofline_predicted_s = 0.0
-        # roofline terms kept for the overlap-fraction gauge: achieved
-        # compute/comm overlap needs modeled compute_s and comm_s
-        self._roofline_compute_s = 0.0
-        self._roofline_comm_s = 0.0
         from deepspeed_tpu.telemetry import explain as _explain
         try:
             _explain.startup_budget(self)
@@ -1186,15 +1225,9 @@ class DeepSpeedTPUEngine:
                 report = _explain.explain_engine(self)
                 _explain.publish_gauges(report)
                 self._roofline_predicted_s = report.roofline.predicted_s
-                self._roofline_compute_s = report.roofline.compute_s
-                self._roofline_comm_s = report.roofline.comm_s
                 log_dist("\n" + _explain.render(report))
             except Exception as e:                   # noqa: BLE001
                 logger.warning(f"explain_startup failed (non-fatal): {e}")
-        # goodput ledger: feed it the modeled compute/comm split so the
-        # comm_exposed category can be carved out of train-step time
-        telemetry.goodput_ledger.set_roofline(self._roofline_compute_s,
-                                              self._roofline_comm_s)
         # -- model-health taps (telemetry/health.py): stats are computed
         # in-graph EVERY step behind a static build-time flag (identical
         # program on- and off-cadence → zero retraces); ``every`` only
@@ -1289,17 +1322,6 @@ class DeepSpeedTPUEngine:
                     "roofline/pct",
                     help="predicted/measured step time, percent"
                 ).set(100.0 * self._roofline_predicted_s / dt_s)
-            if getattr(self, "_overlap_plan", None) is not None:
-                from deepspeed_tpu.runtime.zero.overlap import (
-                    overlap_fraction)
-                frac = overlap_fraction(self._roofline_compute_s,
-                                        self._roofline_comm_s, dt_s)
-                if frac is not None:
-                    reg.gauge(
-                        "overlap/fraction",
-                        help="achieved compute/comm overlap, 0-1 "
-                             "(hidden share of min(compute_s, comm_s))"
-                    ).set(frac)
         if self._mem_sampler is not None and \
                 self.global_steps % max(1, self.config.steps_per_print) == 0:
             self._mem_sampler.sample()
@@ -1345,17 +1367,16 @@ class DeepSpeedTPUEngine:
             telemetry.anomaly_detector.report_nonfinite(
                 self.global_steps, path, what="params")
 
-    def _close_step_span(self) -> None:
-        """Close the whole-step window opened by the first forward() of the
-        accumulation window (or by train_batch): emit the ``train/step``
-        span and the per-step registry metrics."""
+    def _end_step(self) -> None:
+        """End the whole-step window opened by the first forward() of the
+        accumulation window (or by train_batch): the per-step registry
+        metrics. (The ``train/step`` span is train_batch's own context;
+        the 3-call API has its forward / backward / optimizer spans.)"""
         t1 = telemetry.tracer.now()
         t0 = self._step_t0 if self._step_t0 is not None else t1
         self._step_t0 = None
         if self._watchdog is not None:
             self._watchdog.disarm()
-        telemetry.tracer.complete("train/step", t0, t1,
-                                  step=self.global_steps)
         self._record_step_telemetry(t1 - t0)
         self._scoped_finite_check()
 

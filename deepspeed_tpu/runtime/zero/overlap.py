@@ -425,22 +425,8 @@ def build_overlap_plan(mesh: Mesh, layer_specs: Pytree,
 
 
 # ---------------------------------------------------------------------------
-# overlap fraction + scheduler flags
+# scheduler flags
 # ---------------------------------------------------------------------------
-
-def overlap_fraction(compute_s: float, comm_s: float,
-                     measured_s: float) -> Optional[float]:
-    """Achieved compute/comm overlap from the roofline terms and a
-    measured step: a fully serialized step takes ``compute+comm``; a
-    fully hidden one takes ``max(compute, comm)``. The fraction is how
-    much of the hideable ``min(compute, comm)`` was actually hidden,
-    clamped to [0, 1]. ``None`` when any term is missing (CPU without
-    modeled peaks) — callers must treat that as "no signal", not 0."""
-    if compute_s <= 0 or comm_s <= 0 or measured_s <= 0:
-        return None
-    hideable = min(compute_s, comm_s)
-    return max(0.0, min(1.0, (compute_s + comm_s - measured_s) / hideable))
-
 
 def _flag_keys(flags: str) -> set:
     """Flag NAMES present in an ``XLA_FLAGS`` string — exact tokens, not

@@ -200,6 +200,8 @@ def build_zeropp_step(engine) -> None:
                    "overflow": jnp.zeros((), jnp.int32)}
         return new_flat, new_opt, scaler, metrics
 
+    # the module's name in a device trace: the fused step's kind
+    fused_step.__name__ = fused_step.__qualname__ = "fused_step_zeropp"
     engine._fused_step = jax.jit(fused_step, donate_argnums=(0, 1))
     engine._grad_step = None      # 3-call parity API unsupported here
     engine._acc_add = None

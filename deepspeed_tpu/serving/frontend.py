@@ -463,8 +463,15 @@ class ServingFrontend:
 
     def step(self) -> bool:
         """One pump iteration: shed → cancel → admit → engine step →
-        fan tokens out. Returns True while there is (or was) work."""
-        now = self.clock()
+        fan tokens out. Returns True while there is (or was) work.
+
+        One ``serving/step`` span with children ``serving/admit``,
+        ``serving/engine_step`` (the engine's ``serving/pack`` /
+        ``dispatch`` / ``fetch`` inside it) and ``serving/fanout``."""
+        with telemetry.tracer.span("serving/step"):
+            return self._step()
+
+    def _admit(self, now: float) -> bool:
         progressed = False
         for r in self.queue.shed_expired(now):
             self.metrics.bump("shed")
@@ -486,6 +493,12 @@ class ServingFrontend:
             float(len(self.queue)),
             exemplar=head.trace.trace_id
             if head is not None and head.trace else None)
+        return progressed
+
+    def _step(self) -> bool:
+        now = self.clock()
+        with telemetry.tracer.span("serving/admit"):
+            progressed = self._admit(now)
         k = self._pick_megastep(now)
         row_limits = eos_map = None
         if k > 1:
@@ -501,7 +514,7 @@ class ServingFrontend:
         try:
             with telemetry.tracer.span("serving/engine_step",
                                        batch=len(self._running),
-                                       max_steps=k):
+                                       max_steps=k) as span_args:
                 # chaos hook: an engine_error entry raises HERE so the
                 # injected fault exercises the same except-path a real
                 # engine failure takes
@@ -517,6 +530,11 @@ class ServingFrontend:
                                                    max_steps=k,
                                                    row_limits=row_limits,
                                                    eos_ids=eos_map)
+                if span_args is not None and out is not None:
+                    # which device program the step ran (decode / fresh /
+                    # split / paged / megastep): known only now
+                    span_args["program"] = getattr(self.engine,
+                                                   "last_program", None)
         except Exception as e:                       # noqa: BLE001
             # serving failure domain: one engine fault must cost at most
             # one retry per in-flight request, never a wedged replica
@@ -538,6 +556,29 @@ class ServingFrontend:
             int(telemetry.registry.counter("serving/engine_steps").value),
             kind="serving", dur_s=time.monotonic() - t0,
             batch=len(self._running), tokens=len(out))
+        with telemetry.tracer.span("serving/fanout"):
+            self._fan_out(out)
+        if self.emit_every and self.metrics.counters["engine_steps"] % \
+                self.emit_every == 0:
+            self.emit_metrics()
+        # metric history + SLO evaluation on its own cadence: one
+        # registry snapshot feeds the history file, the slo/* burn
+        # gauges, /healthz, and the flight recorder together
+        if self._history is not None and \
+                self.metrics.counters["engine_steps"] % \
+                self._history_every == 0:
+            telemetry.registry.flush_to_monitor(
+                None, self.metrics.counters["engine_steps"],
+                history=self._history)
+        # re-evaluate AFTER fan-out: the step that finishes the last
+        # retried request must flip /healthz back to healthy — no later
+        # pump is guaranteed once the replica drains idle
+        self._update_degraded()
+        return True
+
+    def _fan_out(self, out: Dict[int, Any]) -> None:
+        """Hand the step's tokens to their requests: stamp first tokens,
+        stream, finish on eos / length, feed the last token back."""
         now = self.clock()
         for uid, toks in out.items():
             req = self._running.get(uid)
@@ -594,23 +635,6 @@ class ServingFrontend:
                     else:
                         self._finish(req, "kv_exhausted",
                                      RequestState.FINISHED, now)
-        if self.emit_every and self.metrics.counters["engine_steps"] % \
-                self.emit_every == 0:
-            self.emit_metrics()
-        # metric history + SLO evaluation on its own cadence: one
-        # registry snapshot feeds the history file, the slo/* burn
-        # gauges, /healthz, and the flight recorder together
-        if self._history is not None and \
-                self.metrics.counters["engine_steps"] % \
-                self._history_every == 0:
-            telemetry.registry.flush_to_monitor(
-                None, self.metrics.counters["engine_steps"],
-                history=self._history)
-        # re-evaluate AFTER fan-out: the step that finishes the last
-        # retried request must flip /healthz back to healthy — no later
-        # pump is guaranteed once the replica drains idle
-        self._update_degraded()
-        return True
 
     def _finish(self, req: Request, reason: str, state: RequestState,
                 now: float) -> None:

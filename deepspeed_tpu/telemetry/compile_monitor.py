@@ -16,6 +16,14 @@ makes each compile loud and attributable:
   miss, attributing the compile to the request's bucket shape) or wrap a
   function with :meth:`instrument` — the wrapper body only executes while
   jax is *tracing*, i.e. exactly once per compilation cache miss.
+- The scope table: a named step program registers, at its cache miss,
+  what is needed to lower it again (:meth:`register_program`: the jitted
+  function, held WEAKLY unless the tracer is on, and the ABSTRACT
+  arguments of a call). On demand, :meth:`scopes` compiles it once more
+  and returns ``{instruction name: {"scope", "backward", "remat",
+  "inherited"}}`` from the optimized HLO's ``op_name`` metadata, so a
+  device trace can be summed under the program's own ``jax.named_scope``
+  names. Nothing is lowered until asked.
 - Storm detection: when one function/site retraces more than
   ``storm_threshold`` times, a single loud warning fires and the storm is
   recorded for the flight recorder / ``dstpu-doctor``.
@@ -23,6 +31,7 @@ makes each compile loud and attributable:
 
 import threading
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional
 
 from deepspeed_tpu.utils.logging import logger
@@ -47,6 +56,11 @@ class CompileMonitor:
         self._functions: Dict[str, int] = {}
         self._details: Dict[str, List[Any]] = {}
         self._storms: List[str] = []
+        #: name -> (weakref to the jitted function, abstract args), and the
+        #: functions held strongly while the tracer is on: register_program
+        self._programs: Dict[str, Any] = {}
+        self._held: Dict[str, Callable] = {}
+        self._scope_tables: Dict[str, Dict[str, Dict[str, Any]]] = {}
 
     # -- jax.monitoring bridge ----------------------------------------------
 
@@ -152,6 +166,86 @@ class CompileMonitor:
         traced.__wrapped__ = fn
         return traced
 
+    # -- the scope table ------------------------------------------------------
+
+    def register_program(self, name: str, jitted: Callable,
+                         args: tuple) -> None:
+        """Remember how to lower the named step program again: the jitted
+        function and the abstract form (``explain.abstractify``: shape,
+        dtype, mesh sharding) of the arguments of one call. Nothing is
+        lowered here and no argument buffer is kept.
+
+        The function's closure holds its engine, and the engine its device
+        state, so the function is held WEAKLY: a process that drops an
+        engine frees it, registered or not. Only while the tracer is on is
+        it held strongly (:meth:`hold_programs`): a traced run asks for
+        the table after its work, when the caller may hold the engine no
+        longer (the benchmark's readers run after the runner returned).
+        One name is one compiled module: the name is the one the trace's
+        ``XLA Modules`` line shows after ``jit_``."""
+        from deepspeed_tpu.telemetry.explain import abstractify
+        from deepspeed_tpu.telemetry.tracer import tracer
+        with self._lock:
+            self._programs[name] = (weakref.ref(jitted), abstractify(args))
+            self._scope_tables.pop(name, None)
+            self._held.pop(name, None)
+            if tracer.enabled:
+                self._held[name] = jitted
+
+    def hold_programs(self, hold: bool) -> None:
+        """The tracer was switched on (``hold``) or off: keep every
+        registered step program that is still alive until its table was
+        asked for or the tracer goes off, or let them all go.
+        ``tracer.configure`` calls this; nothing else needs to."""
+        with self._lock:
+            if not hold:
+                self._held.clear()
+                return
+            for name, (ref, _args) in self._programs.items():
+                jitted = ref()
+                if jitted is not None and name not in self._scope_tables:
+                    self._held[name] = jitted
+
+    def programs(self) -> List[str]:
+        """Names :meth:`scopes` can answer for: a table was made, or the
+        program's function is still alive."""
+        with self._lock:
+            return sorted(n for n, (ref, _args) in self._programs.items()
+                          if n in self._scope_tables or ref() is not None)
+
+    def scopes(self, program: str) -> Dict[str, Dict[str, Any]]:
+        """``{instruction name: {"scope", "backward", "remat",
+        "inherited"}}`` of a registered program
+        (``telemetry/explain.scope_table_from_hlo``); raises ``KeyError``
+        for a name never registered, or whose function was freed before a
+        table was asked for (the tracer was off).
+
+        The first ask compiles the program again, under a persistent-cache
+        key that holds the metadata (:func:`_compile_with_current_metadata`):
+        jax leaves metadata out of the cache's key, so the executable that
+        ran may have been compiled before a scope existed, with the old
+        ``op_name``s. Same input, same compiler: the instruction names are
+        those of the executable that ran. On a v5e 4 to 23 s for the first
+        traced run of a source in its directory, under half a second for
+        the next (it reads the entry back; PERF.md, PR 28); callers ask
+        after their measurement, never on the step path. The table stays;
+        the function is let go."""
+        with self._lock:
+            if program in self._scope_tables:
+                return self._scope_tables[program]
+            ref, args = self._programs[program]
+            jitted = ref()
+        if jitted is None:
+            raise KeyError(f"{program}: its function was freed before a "
+                           f"scope table was asked for")
+        from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+        table = scope_table_from_hlo(
+            _compile_with_current_metadata(jitted, args))
+        with self._lock:
+            self._scope_tables[program] = table
+            self._held.pop(program, None)
+        return table
+
     # -- export --------------------------------------------------------------
 
     def retrace_count(self, name: str) -> int:
@@ -174,6 +268,35 @@ class CompileMonitor:
             self._functions.clear()
             self._details.clear()
             del self._storms[:]
+            self._programs.clear()
+            self._held.clear()
+            self._scope_tables.clear()
+
+
+def _compile_with_current_metadata(jitted: Callable, args: tuple) -> str:
+    """Optimized HLO text of ``jitted`` for ``args`` whose ``op_name``s are
+    those of the source as it is now. Two caches stand in the way, and
+    neither is switched off:
+
+    - the persistent compilation cache, whose key leaves metadata out by
+      default, so a hit may carry another source's ``op_name``s. For this
+      one compile, in this thread only (a jax config context, so a server
+      thread compiling at the same moment keeps its keys), the key holds
+      the metadata: a hit under it was compiled from these very scopes, a
+      miss compiles and leaves the entry for the next traced run.
+    - the executable the jitted function already holds in memory for these
+      arguments, the very one that may have come from the persistent cache
+      under the default key (0.0 s and a stale table on the chip, PR 28).
+      A compiler option set to its own default changes nothing in the
+      program and makes jax build the executable anew.
+
+    Verified against jax 0.9.0 (``jax._src.config`` is where the context
+    manager of ``jax_compilation_cache_include_metadata_in_key`` lives;
+    tests/test_scopes.py stages the stale entry and counts the compile)."""
+    from jax._src import config as jax_config
+    with jax_config.compilation_cache_include_metadata_in_key(True):
+        return jitted.lower(*args).compile(
+            compiler_options={"xla_dump_hlo_as_text": False}).as_text()
 
 
 #: process-wide compile monitor

@@ -167,6 +167,129 @@ def collective_stats_from_hlo(hlo_text: str) -> Dict[str, Dict[str, float]]:
     return stats
 
 
+#: the ONE vocabulary of ``jax.named_scope`` names the step programs carry
+#: at their layer-part boundaries (models/transformer.py, inference/
+#: engine_v2.py, runtime/engine.py); docs/observability.md says what each
+#: covers. Trainer and servers share the model functions, so they share
+#: the names.
+SCOPE_VOCABULARY = (
+    "embed", "norm", "attn_qkv", "attn_core", "attn_history", "attn_merge",
+    "kv_write", "attn_out", "mlp", "moe", "lm_head", "loss", "grad_clip",
+    "optimizer", "sample", "step_misc")
+
+_HLO_NAME_RE = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_HLO_COMPUTATION_RE = re.compile(
+    r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_HLO_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_REF_RE = re.compile(r"%([\w.\-]+)")
+_IDENT_RE = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
+
+
+def scope_of_op_name(op_name: str) -> Dict[str, Any]:
+    """``{"scope", "backward", "remat"}`` of one HLO ``op_name`` path.
+
+    ``scope`` is the INNERMOST vocabulary word on the path, or None. jax
+    writes a scope either as a component of its own (``.../while/body/
+    mlp/dot_general``) or inside the transforms that wrap it
+    (``transpose(jvp(loss))/mul``), so every identifier of every component
+    is looked at, last first. ``backward``: the path holds ``transpose(``;
+    ``remat``: it holds ``rematted_computation`` (a forward operation
+    computed again for the backward pass)."""
+    scope = None
+    for part in reversed(op_name.split("/")):
+        for word in reversed(_IDENT_RE.findall(part)):
+            if word in SCOPE_VOCABULARY:
+                scope = word
+                break
+        if scope is not None:
+            break
+    return {"scope": scope, "backward": "transpose(" in op_name,
+            "remat": "rematted_computation" in op_name}
+
+
+def scope_table_from_hlo(hlo_text: str) -> Dict[str, Dict[str, Any]]:
+    """``{instruction name: {"scope", "backward", "remat", "inherited"}}``
+    for every instruction of an optimized HLO module's text (fusions, convolutions,
+    custom-calls, the instructions inside fused computations too: names
+    are unique in a module, and the profiler prints the same ones).
+
+    The compiler drops or never writes the metadata of some instructions
+    it builds, so one without a vocabulary word of its own inherits, in
+    this order: (1) a fusion takes the scope of the computation it
+    ``calls=`` -- its root's, else the commonest among its instructions (a
+    scatter into the KV arena becomes a ``kCustom`` fusion whose root has
+    no metadata); (2) what is still unnamed takes the commonest scope of
+    the instructions that USE it, three rounds for chains: a relayout
+    copy, or the layer loop's slice of a stacked weight, is made for its
+    consumer and is that layer part's cost. The rest maps to scope None.
+    ``inherited`` says which it was: False where the scope is a word of
+    the instruction's OWN ``op_name`` (or there is no scope), True where
+    rule (1) or (2) assigned it -- a reader can so tell the time the
+    compiler's metadata names from the time this heuristic names."""
+    table: Dict[str, Dict[str, Any]] = {}
+    members: Dict[str, List[Dict[str, Any]]] = {}
+    roots: Dict[str, Dict[str, Any]] = {}
+    calls: Dict[str, str] = {}
+    own_path: Dict[str, str] = {}
+    refs: Dict[str, List[str]] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        cm = _HLO_COMPUTATION_RE.match(line)
+        if cm is not None:
+            current = cm.group(1)
+            continue
+        m = _HLO_NAME_RE.match(line)
+        if m is None or "(" not in line:
+            continue
+        name = m.group(2)
+        om = _HLO_OP_NAME_RE.search(line)
+        own_path[name] = om.group(1) if om else ""
+        entry = table[name] = dict(scope_of_op_name(own_path[name]),
+                                   inherited=False)
+        members.setdefault(current, []).append(entry)
+        if m.group(1):
+            roots[current] = entry
+        called = _HLO_CALLS_RE.search(line)
+        if called is not None:
+            calls[name] = called.group(1)
+        refs[name] = _HLO_REF_RE.findall(line[m.end():])
+
+    def commonest(entries):
+        counts: Dict[str, int] = {}
+        for e in entries:
+            counts[e["scope"]] = counts.get(e["scope"], 0) + 1
+        best = max(counts, key=counts.get)
+        return next(e for e in entries if e["scope"] == best)
+
+    def inherit(name, donor):
+        # with a path of its own, the instruction's own direction stands
+        table[name] = dict(donor if not own_path[name] else table[name],
+                           scope=donor["scope"], inherited=True)
+
+    for name, comp in calls.items():
+        if table[name]["scope"] is not None:
+            continue
+        named = [e for e in members.get(comp, ()) if e["scope"] is not None]
+        root = roots.get(comp)
+        if named:
+            inherit(name, root if root is not None and
+                    root["scope"] is not None else commonest(named))
+    for _round in range(3):
+        users: Dict[str, List[Dict[str, Any]]] = {}
+        for user, operands in refs.items():
+            if table[user]["scope"] is None:
+                continue
+            for op in operands:
+                if op in table and table[op]["scope"] is None:
+                    users.setdefault(op, []).append(table[user])
+        if not users:
+            break
+        for name, entries in users.items():
+            inherit(name, commonest(entries))
+    return table
+
+
 def collective_bytes_from_hlo(hlo_text: str) -> float:
     """Total bytes moved by collectives in optimized HLO text (the
     largest buffer of each collective instruction, summed). An
@@ -875,10 +998,10 @@ def explain_serving(engine, mode=("argmax",),
     aparams = abstractify(engine.params)
     aarena = abstractify(engine.arena)
     arng = abstractify(jax.random.PRNGKey(0))
-    for label, cb, fresh in (("prefill", int(cfg.prefill_chunk), True),
+    for label, cb, fresh in (("prefill", int(cfg.prefill_chunk), "fresh"),
                              ("decode", 1, False)):
-        packed = jax.ShapeDtypeStruct(
-            (nb * cb + nb + nb + nb * mb + 2,), np.int32)
+        packed = jax.ShapeDtypeStruct((engine._packed_len(nb, cb),),
+                                      np.int32)
         try:
             jitted = engine._step_fn(nb, cb, mode, fresh=fresh)
             fc = analyze_lowerable(f"serving_{label}", jitted,

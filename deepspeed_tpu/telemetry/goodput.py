@@ -3,15 +3,17 @@
 The one question a fleet owner asks that no other telemetry layer
 answers: *of every wall-clock second we pay for, how many produced
 tokens or gradient steps?* The raw signals already exist — spans in the
-tracer ring, the roofline compute/comm split, the resilience ledger's
-injection→recovery pairs — but none of them closes the accounting.
-This module does, the way T3 argues exposed-communication time must be
-**attributed**, not just measured, before anyone can optimize it.
+tracer ring, the resilience ledger's injection→recovery pairs — but none
+of them closes the accounting. This module does. (Communication a step
+did not hide behind compute is a DEVICE time: it is measured from a
+device trace -- the benchmark's ``collective_exposed_ms_per_step`` --
+and no longer carved out of goodput from a roofline's prediction.)
 
 The :class:`GoodputLedger` classifies every second of process lifetime
 into exactly one category (``CATEGORIES``):
 
-- ``goodput`` — productive compute: ``train/step`` spans, and
+- ``goodput`` — productive compute: ``train/step`` spans (and the
+  3-call API's ``train/forward`` / ``backward`` / ``optimizer``), and
   ``serving/engine_step`` spans with a non-empty running batch;
 - ``init`` — process start until the first productive/compile/ckpt work;
 - ``compile`` — XLA compilation (``compile/*`` spans emitted by the
@@ -19,12 +21,9 @@ into exactly one category (``CATEGORIES``):
 - ``ckpt`` — checkpoint save/restore (``checkpoint/*`` spans);
 - ``fault_recovery`` — injection→recovery intervals from the resilience
   ledger (:func:`deepspeed_tpu.resilience.faults.recovery_intervals`);
-- ``comm_exposed`` — the roofline's per-step comm time minus the share
-  the ``overlap/fraction`` gauge says was hidden under compute, carved
-  OUT of goodput (T3-style: exposed communication is not goodput even
-  though it happens inside a train step);
-- ``input_stall`` — gaps between train steps on a training host
-  (dataloader / host-input wait);
+- ``input_stall`` — ``train/batch`` spans (fetching, stacking and
+  placing the step's batch) and gaps between train steps on a training
+  host (dataloader / host-input wait);
 - ``idle`` — serving pumps with an empty running set, and gaps on a
   serving host (no admitted work);
 - ``other`` — the residual that forces the ledger to sum to 100%.
@@ -65,12 +64,17 @@ from deepspeed_tpu.telemetry.tracer import Tracer, tracer as _global_tracer
 #: documented in docs/observability.md (tools/check_metric_names.py
 #: lints this, mirroring the resilience fault catalog).
 CATEGORIES = ("goodput", "init", "compile", "ckpt", "fault_recovery",
-              "comm_exposed", "input_stall", "idle", "other")
+              "input_stall", "idle", "other")
 
 #: sweep priority when intervals overlap: a named cause beats generic
 #: productivity (a recovery or compile spanning a train step is badput)
 _PRIORITY = {"fault_recovery": 0, "compile": 1, "ckpt": 2,
-             "goodput": 3, "idle": 4}
+             "input_stall": 3, "goodput": 4, "idle": 5}
+
+#: spans that are a train step's productive time: the fused path's
+#: envelope and the 3-call API's three parts
+_TRAIN_SPANS = ("train/step", "train/forward", "train/backward",
+                "train/optimizer")
 
 #: fleet/doctor alarm line: a fraction below this names its dominant
 #: badput in the dstpu-doctor verdict ladder
@@ -80,8 +84,10 @@ LOW_GOODPUT_FRACTION = 0.5
 def _classify_span(ev: Dict[str, Any]) -> Optional[str]:
     """Span event → ledger category (None: not an attribution source)."""
     name = ev.get("name", "")
-    if name == "train/step":
+    if name in _TRAIN_SPANS:
         return "goodput"
+    if name == "train/batch":
+        return "input_stall"
     if name == "serving/engine_step":
         args = ev.get("args") or {}
         batch = args.get("batch")
@@ -107,8 +113,8 @@ def attribute(events: Sequence[Dict[str, Any]], t0: float, t1: float,
 
     Returns ``{"seconds": {category: s}, "train_steps": n,
     "kinds": {...}, "first_work": t|None}`` with the guarantee
-    ``sum(seconds.values()) == t1 - t0`` (within float epsilon) before
-    any ``comm_exposed`` carving — conservation by construction.
+    ``sum(seconds.values()) == t1 - t0`` (within float epsilon) —
+    conservation by construction.
     """
     sec = {c: 0.0 for c in CATEGORIES}
     if t1 <= t0:
@@ -130,7 +136,7 @@ def attribute(events: Sequence[Dict[str, Any]], t0: float, t1: float,
         e = s + float(ev.get("dur", 0.0)) / 1e6
         if ev.get("name") == "serving/engine_step":
             serving_seen = True
-        elif ev.get("name") == "train/step":
+        elif ev.get("name") in _TRAIN_SPANS:
             train_seen = True
         if cat in ("goodput", "compile", "ckpt"):
             first_work = s if first_work is None else min(first_work, s)
@@ -297,8 +303,6 @@ class GoodputLedger:
         self.seconds: Dict[str, float] = {c: 0.0 for c in CATEGORIES}
         self.recovery_kinds: Dict[str, int] = {}
         self._first_work: Optional[float] = None
-        self._roofline_compute_s = 0.0
-        self._roofline_comm_s = 0.0
         #: (ts, cumulative goodput_s) samples for the windowed fraction
         self._samples: deque = deque(maxlen=4096)
         self._min_interval_s = 1.0
@@ -322,13 +326,6 @@ class GoodputLedger:
                                    duration_ms=capture_duration_ms,
                                    dir=capture_dir)
 
-    def set_roofline(self, compute_s: float, comm_s: float) -> None:
-        """Feed the modeled per-step compute/comm split (the engine's
-        explain pass holds these privately — no gauge carries them)."""
-        with self._lock:
-            self._roofline_compute_s = float(compute_s or 0.0)
-            self._roofline_comm_s = float(comm_s or 0.0)
-
     def reset(self) -> None:
         with self._lock:
             self._t0 = self._last = self._first_work = None
@@ -341,23 +338,6 @@ class GoodputLedger:
     @property
     def _tr(self) -> Tracer:
         return self._tracer if self._tracer is not None else _global_tracer
-
-    def _exposed_comm_per_step(self) -> float:
-        """T3-style exposed communication per train step: modeled comm
-        time minus the share the achieved ``overlap/fraction`` gauge
-        says was hidden under compute."""
-        comm = self._roofline_comm_s
-        if comm <= 0:
-            return 0.0
-        frac = 0.0
-        try:
-            from deepspeed_tpu.telemetry.registry import registry
-            g = registry.get("overlap/fraction")
-            if g is not None:
-                frac = min(1.0, max(0.0, float(g.value)))
-        except Exception:                            # noqa: BLE001
-            pass
-        return max(0.0, comm - frac * min(self._roofline_compute_s, comm))
 
     def maybe_update(self, now: Optional[float] = None
                      ) -> Optional[Dict[str, Any]]:
@@ -397,13 +377,6 @@ class GoodputLedger:
                                     if self._first_work is None
                                     else min(self._first_work,
                                              res["first_work"]))
-            # carve exposed communication OUT of goodput, capped so the
-            # ledger keeps conserving wall clock
-            exposed = min(delta["goodput"],
-                          self._exposed_comm_per_step()
-                          * res["train_steps"])
-            delta["goodput"] -= exposed
-            delta["comm_exposed"] += exposed
             for c in CATEGORIES:
                 self.seconds[c] += delta[c]
             for k, n in res["kinds"].items():

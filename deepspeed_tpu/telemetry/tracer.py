@@ -58,6 +58,13 @@ class Tracer:
                 self.enabled = bool(enabled)
             if jax_annotations is not None:
                 self.jax_annotations = bool(jax_annotations)
+        if enabled is not None:
+            # a traced run sums its device time under the step programs'
+            # scopes after the work: the monitor keeps the programs alive
+            # for that while tracing is on, and only then
+            from deepspeed_tpu.telemetry.compile_monitor import \
+                compile_monitor
+            compile_monitor.hold_programs(self.enabled)
 
     def now(self) -> float:
         """Seconds on the tracer's clock (``time.perf_counter``)."""
@@ -123,24 +130,33 @@ class Tracer:
         how Chrome/Perfetto render the flame graph. ``ctx`` (a
         :class:`~deepspeed_tpu.telemetry.reqtrace.TraceContext`) stamps
         the span with trace_id/span_id/parent_span_id args so it joins a
-        request-scoped distributed trace."""
+        request-scoped distributed trace.
+
+        Yields the span's argument dict (``None`` while tracing is off):
+        what is known only when the block ends -- which program a step
+        ran -- is added there, behind an ``is not None`` check so that
+        nothing is computed for a disabled tracer. A dict that held an
+        argument when the block ended IS the recorded event's ``args``,
+        so it may still be completed right after (a megastep's emitted
+        tokens are known only after the fetch that follows its launch)."""
         if not self.enabled:
-            yield
+            yield None
             return
         ann = self._annotation(name, step)
         if ann is not None:
             ann.__enter__()
         t0 = time.perf_counter()
         try:
-            yield
+            yield args
         finally:
             t1 = time.perf_counter()
             if ann is not None:
                 ann.__exit__(None, None, None)
             if step is not None:
-                args = {**args, "step": step}
+                args["step"] = step
             if ctx is not None:
-                args = {**ctx.tags(), **args}
+                for key, tag in ctx.tags().items():
+                    args.setdefault(key, tag)
             ev = self._event(name, "X", (t0 - self._t0) * 1e6, None, args)
             ev["dur"] = (t1 - t0) * 1e6
             self._append(ev)

@@ -112,25 +112,24 @@ def test_attribution_priority_and_gap_classes():
     assert res2["seconds"]["init"] == pytest.approx(1.0)
 
 
-def test_ledger_carves_exposed_comm_from_goodput():
-    """T3-style: the roofline's comm share not hidden by the measured
-    overlap fraction moves from goodput into comm_exposed — and the
-    ledger still conserves."""
+def test_ledger_batch_fetch_is_input_stall_inside_the_step():
+    """``train/step`` opens before the batch is fetched: its
+    ``train/batch`` child is an input stall, not goodput; the 3-call
+    API's forward / backward / optimizer spans are goodput without an
+    envelope -- and the ledger still conserves."""
     tr = _tracer()
     t0 = tr._t0
     for i in range(4):
         tr.complete("train/step", t0 + i, t0 + i + 1.0, step=i)
+        tr.complete("train/batch", t0 + i, t0 + i + 0.1)
+    tr.complete("train/forward", t0 + 4.0, t0 + 4.5, step=4)
+    tr.complete("train/optimizer", t0 + 4.6, t0 + 5.0, step=4)
     led = GoodputLedger(tracer=tr)
     led.configure(enabled=True)
-    led.set_roofline(compute_s=0.8, comm_s=0.2)
-    telemetry.registry.gauge("overlap/fraction").set(0.5)
-    try:
-        s = led.update(t0 + 4.0)
-    finally:
-        telemetry.registry.gauge("overlap/fraction").set(0.0)
-    # exposed per step = 0.2 - 0.5 * min(0.8, 0.2) = 0.1; 4 steps
-    assert s["badput"]["comm_exposed"] == pytest.approx(0.4, abs=1e-6)
-    assert s["goodput_s"] == pytest.approx(3.6, abs=1e-6)
+    s = led.update(t0 + 5.0)
+    assert "comm_exposed" not in s["badput"]
+    assert s["badput"]["input_stall"] == pytest.approx(0.5, abs=1e-6)
+    assert s["goodput_s"] == pytest.approx(4.5, abs=1e-6)
     total = s["goodput_s"] + sum(s["badput"].values())
     assert total == pytest.approx(s["uptime_s"], abs=1e-6)
 

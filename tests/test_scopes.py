@@ -1,0 +1,464 @@
+"""The chip's time under the program's own names (PR 28): the scope parser
+on a checked-in excerpt of optimized TPU HLO, the step programs' names and
+their registration for the scope table, the host span tree of a traced
+train step and a traced serving step with the work each launch did, and the
+always-on ``dispatch/*`` work counters. All on the CPU (conftest forces it);
+the compiles for a described v5e are in test_tpu_compile.py."""
+
+import importlib
+import os
+
+import numpy as np
+import pytest
+import jax
+
+from deepspeed_tpu import telemetry
+from deepspeed_tpu.telemetry.explain import (SCOPE_VOCABULARY,
+                                             scope_of_op_name,
+                                             scope_table_from_hlo)
+
+# ``telemetry.compile_monitor`` is the instance; this is its module
+monitor_module = importlib.import_module(
+    "deepspeed_tpu.telemetry.compile_monitor")
+HERE = os.path.dirname(os.path.abspath(__file__))
+ENG_CFG = {"dtype": "float32", "num_blocks": 32, "block_size": 8,
+           "max_seq_len": 128, "prefill_chunk": 8, "max_batch_tokens": 64,
+           "max_sequences": 16}
+WORK_COUNTERS = ("dispatch/tokens", "dispatch/token_slots",
+                 "dispatch/context_tokens", "dispatch/context_slots",
+                 "dispatch/host_calls")
+
+
+def _engine(**over):
+    from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
+    from deepspeed_tpu.models.llama import llama3_config
+    from deepspeed_tpu.models.transformer import init_params
+    from deepspeed_tpu.parallel.mesh import build_mesh
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg = llama3_config("tiny", max_seq_len=256, vocab_size=256)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    return RaggedInferenceEngineTPU(cfg, {**ENG_CFG, **over}, params=params)
+
+
+@pytest.fixture()
+def traced():
+    """The process-wide tracer on and empty, put back as it was."""
+    tr = telemetry.tracer
+    was = tr.enabled
+    tr.configure(enabled=True)
+    tr.clear()
+    yield tr
+    tr.configure(enabled=was)
+    tr.clear()
+
+
+def _spans(events, name):
+    return sorted((e for e in events
+                   if e["name"] == name and e["ph"] == "X"),
+                  key=lambda e: e["ts"])
+
+
+def _inside(child, parent):
+    return parent["ts"] <= child["ts"] and \
+        child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + 1e-3
+
+
+def _counters():
+    return {n: telemetry.registry.counter(n).value for n in WORK_COUNTERS}
+
+
+# -- the parser ---------------------------------------------------------------
+
+#: instruction of tests/data/v5e_hlo_excerpt.txt -> (scope, backward, remat);
+#: INHERITED names those whose scope is not a word of their own ``op_name``
+EXCERPT = {
+    # forward, under a scope of its own inside the layer loop
+    "convolution.116": ("mlp", False, False),
+    # a Pallas kernel: the custom-call instruction under attn_core
+    "flash_fwd.6": ("attn_core", False, False),
+    # the forward computed AGAIN for the backward pass
+    "convolution.119": ("mlp", True, True),
+    "fusion.449": ("mlp", True, True),
+    # backward proper: transpose(jvp()) with no rematted_computation
+    "convolution.122": ("mlp", True, False),
+    # a scope entered directly under the transform: jvp(loss), and its
+    # backward transpose(jvp(loss))
+    "convolution.33.clone.3": ("loss", False, False),
+    "fusion.31.clone.3": ("loss", True, False),
+    "fusion.294": ("optimizer", False, False),
+    # a kCustom fusion whose root lost its metadata: the scope of the
+    # computation it calls
+    "fusion.195": ("kv_write", False, False),
+    # a relayout fusion with no metadata at all: its user's scope
+    "fusion.169": ("attn_history", False, False),
+    # nothing to go by: no vocabulary word, no callee, no scoped user
+    "custom-call.24": (None, False, False),
+    "while.13": (None, False, False),
+}
+
+
+INHERITED = {"fusion.195", "fusion.169"}
+
+
+@pytest.fixture(scope="module")
+def excerpt_table():
+    with open(os.path.join(HERE, "data", "v5e_hlo_excerpt.txt")) as fh:
+        return scope_table_from_hlo(fh.read())
+
+
+@pytest.mark.parametrize("instruction", sorted(EXCERPT))
+def test_scope_table_on_tpu_hlo_excerpt(instruction, excerpt_table):
+    scope, backward, remat = EXCERPT[instruction]
+    assert excerpt_table[instruction] == {
+        "scope": scope, "backward": backward, "remat": remat,
+        "inherited": instruction in INHERITED}
+
+
+def test_scope_is_the_innermost_vocabulary_word():
+    path = "jit(serve_split)/while/body/closed_call/moe/mlp/dot_general"
+    assert scope_of_op_name(path)["scope"] == "mlp"
+    assert scope_of_op_name("jit(f)/embed/norm/mul")["scope"] == "norm"
+    # an einsum's letters and a kernel's name are not the vocabulary
+    assert scope_of_op_name(
+        "jit(f)/attn_history/td,sd->ts/dot_general")["scope"] == \
+        "attn_history"
+    assert scope_of_op_name("jit(f)/flash_fwd/pallas_call")["scope"] is None
+    assert scope_of_op_name("")["scope"] is None
+    assert len(set(SCOPE_VOCABULARY)) == len(SCOPE_VOCABULARY) == 16
+
+
+# -- names and registration -----------------------------------------------------
+
+#: kind -> (_step_fn arguments after the row bucket, the program's name)
+STEP_PROGRAMS = {
+    "decode": ((1, ("argmax",), False), "serve_decode_r4"),
+    "fresh": ((8, ("argmax",), "fresh"), "serve_fresh_r4_c8"),
+    "split": ((8, ("argmax",), "split"), "serve_split_r4_c8"),
+    # the pre-three-mode boolean is the fresh program, name and all
+    "legacy_true": ((8, ("argmax",), True), "serve_fresh_r4_c8"),
+    "paged": ((8, ("argmax",), False), "serve_paged_r4_c8"),
+    "logits": ((1, None, False), "serve_decode_r4_logits"),
+    "sample": ((1, ("sample", 5, True), False),
+               "serve_decode_r4_sample_k5_p"),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(STEP_PROGRAMS))
+def test_step_program_registers_under_its_own_name(kind, monkeypatch):
+    """Every kind of step program is jitted under a stable name of its kind
+    and static shape (the module's name in a device trace) and registers
+    for the scope table at its cache miss; nothing is lowered until
+    ``scopes()`` is asked, and then once."""
+    (cb, mode, fresh), name = STEP_PROGRAMS[kind]
+    compiles = []
+    real = monitor_module._compile_with_current_metadata
+    monkeypatch.setattr(
+        monitor_module, "_compile_with_current_metadata",
+        lambda jitted, args: compiles.append(1) or real(jitted, args))
+    eng = _engine()
+    jitted = eng._step_fn(4, cb, mode, fresh)
+    assert jitted.__name__ == name
+    assert name in telemetry.compile_monitor.programs()
+    assert eng._step_fn(4, cb, mode, fresh) is jitted
+    if kind == "legacy_true":
+        assert eng._step_fn(4, cb, mode, "fresh") is jitted
+    assert not compiles
+    _ref, args = telemetry.compile_monitor._programs[name]
+    table = telemetry.compile_monitor.scopes(name)
+    assert len(compiles) == 1
+    assert telemetry.compile_monitor.scopes(name) is table
+    assert len(compiles) == 1
+    found = {e["scope"] for e in table.values()} - {None}
+    assert found <= set(SCOPE_VOCABULARY)
+    want = {"mlp", "attn_qkv", "norm", "kv_write", "lm_head"}
+    if mode is not None:
+        want.add("sample")
+    if kind == "split":
+        want |= {"attn_history", "attn_merge"}
+    assert want <= found, want - found
+    # the module carries the name too
+    assert f"jit_{name}" in jitted.lower(*args).as_text()[:200]
+
+
+def test_megastep_program_is_named_by_rows_scan_steps_and_page_width():
+    eng = _engine()
+    jitted = eng._fused_decode_fn(4, 8, ("argmax",), 4)
+    assert jitted.__name__ == "serve_megastep_r4_k8_p4"
+    assert "serve_megastep_r4_k8_p4" in telemetry.compile_monitor.programs()
+    # another page-table width is another module, so another name
+    assert eng._fused_decode_fn(4, 8, ("argmax",), 8).__name__ == \
+        "serve_megastep_r4_k8_p8"
+    table = telemetry.compile_monitor.scopes("serve_megastep_r4_k8_p4")
+    assert {"attn_history", "attn_core", "attn_merge", "kv_write", "mlp",
+            "sample"} <= {e["scope"] for e in table.values()}
+
+
+def _dropped_engine_after_a_step():
+    """(name of the step program it ran, weak references to a serving
+    engine and to one of its weight buffers), after the engine ran a
+    prefill step and the caller let go of it."""
+    import gc
+    import weakref
+    eng = _engine()
+    eng._put_tokens([7], [[1, 2, 3]])
+    (jitted,) = eng._step_fns.values()
+    name = jitted.__name__
+    assert name in telemetry.compile_monitor.programs()
+    refs = (weakref.ref(eng), weakref.ref(jax.tree.leaves(eng.params)[0]))
+    del eng, jitted
+    gc.collect()
+    return name, refs
+
+
+def _alive(refs):
+    import gc
+    gc.collect()
+    return [r() is not None for r in refs]
+
+
+def test_an_untraced_engine_is_freed_when_its_caller_drops_it():
+    """Registration pins nothing: a process that rebuilds an engine (a
+    reload, an autotuner, bench.py's two runs) never holds two device
+    states because of the scope table."""
+    assert not telemetry.tracer.enabled
+    name, refs = _dropped_engine_after_a_step()
+    assert _alive(refs) == [False, False]
+    assert name not in telemetry.compile_monitor.programs()
+    with pytest.raises(KeyError):
+        telemetry.compile_monitor.scopes(name)
+
+
+def test_a_traced_run_keeps_the_program_until_its_table_was_asked(traced):
+    """The table is asked for after the work, when the caller may hold the
+    engine no longer (the benchmark's readers run after the runner
+    returned): while the tracer is on the monitor keeps the program, and
+    lets it go once the table is made."""
+    name, refs = _dropped_engine_after_a_step()
+    assert _alive(refs) == [True, True]
+    table = telemetry.compile_monitor.scopes(name)
+    assert "mlp" in {e["scope"] for e in table.values()}
+    assert _alive(refs) == [False, False]
+    assert name in telemetry.compile_monitor.programs()
+    assert telemetry.compile_monitor.scopes(name) is table
+
+
+def test_switching_the_tracer_on_holds_what_registered_before_it():
+    """The benchmark's order: warm-up with the tracer off (the cache miss,
+    the registration), then the tracer on for the window."""
+    import gc
+    import weakref
+    tr = telemetry.tracer
+    assert not tr.enabled
+    eng = _engine()
+    eng._step_fn(2, 1, ("argmax",), False)
+    alive = weakref.ref(eng)
+    tr.configure(enabled=True)
+    try:
+        del eng
+        gc.collect()
+        assert alive() is not None
+        assert "serve_decode_r2" in telemetry.compile_monitor.programs()
+    finally:
+        tr.configure(enabled=False)
+        tr.clear()
+    gc.collect()
+    assert alive() is None
+
+
+def test_scope_table_is_of_the_source_as_it_is_now():
+    """The hazard, staged: jax leaves metadata out of the persistent
+    cache's key, so a program whose scope was renamed hits the entry
+    compiled before, and the executable it then holds in memory carries
+    the OLD ``op_name``s. The table names the new scope all the same."""
+    import jax.numpy as jnp
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+    def make(scope):
+        def scope_probe(x, w):
+            with jax.named_scope(scope):
+                return jnp.tanh(x @ w).sum()
+        return jax.jit(scope_probe)
+    x = jnp.ones((48, 48), jnp.float32)
+    try:
+        make("mlp")(x, x).block_until_ready()       # leaves the entry
+        renamed = make("attn_out")
+        renamed(x, x).block_until_ready()           # hits it
+        held = renamed.lower(x, x).compile().as_text()
+        assert "/mlp/" in held and "/attn_out/" not in held, \
+            "jax no longer hands back a stale executable: the hazard " \
+            "compile_monitor._compile_with_current_metadata exists for"
+        telemetry.compile_monitor.register_program(
+            "scope_probe", renamed, (x, x))
+        table = telemetry.compile_monitor.scopes("scope_probe")
+        assert {e["scope"] for e in table.values()} - {None} == {"attn_out"}
+        # the key that holds metadata was this thread's, for that compile
+        assert not jax.config.jax_compilation_cache_include_metadata_in_key
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+
+
+def test_scopes_of_an_unknown_program_is_a_key_error():
+    with pytest.raises(KeyError):
+        telemetry.compile_monitor.scopes("no_such_program")
+
+
+def test_span_yields_its_arguments_only_while_tracing(traced):
+    with traced.span("probe/late", a=1) as args:
+        args["b"] = 2
+    assert _spans(traced.events(), "probe/late")[0]["args"] == \
+        {"a": 1, "b": 2}
+    traced.configure(enabled=False)
+    with traced.span("probe/off", a=1) as args:
+        assert args is None
+    assert not _spans(traced.events(), "probe/off")
+
+
+# -- the trainer's span tree ------------------------------------------------------
+
+def test_traced_train_step_has_its_span_tree(traced):
+    from deepspeed_tpu.models.gpt import gpt2_config
+    from deepspeed_tpu.parallel.mesh import build_mesh
+    from deepspeed_tpu.runtime.engine import initialize
+    build_mesh(data=8)
+    engine, *_ = initialize(
+        model=gpt2_config("tiny", max_seq_len=32, vocab_size=128),
+        config={"train_micro_batch_size_per_gpu": 1,
+                "optimizer": {"type": "adam", "params": {"lr": 1e-3}}},
+        rng=jax.random.PRNGKey(0))
+    batch = {"input_ids": np.random.default_rng(0).integers(
+        0, 128, size=(8, 32), dtype=np.int32)}
+    first = engine.global_steps
+    for _ in range(2):
+        engine.train_batch(iter([batch]))
+    events = traced.events()
+    steps = _spans(events, "train/step")
+    assert [s["args"]["step"] for s in steps] == [first, first + 1]
+    for name in ("train/batch", "train/dispatch", "train/bookkeeping"):
+        kids = _spans(events, name)
+        assert len(kids) == 2, name
+        assert all(_inside(k, s) for k, s in zip(kids, steps)), name
+    # children in order, and no retroactive second envelope
+    b, d, k = (_spans(events, n)[0] for n in
+               ("train/batch", "train/dispatch", "train/bookkeeping"))
+    assert b["ts"] + b["dur"] <= d["ts"] and d["ts"] + d["dur"] <= k["ts"]
+    # the fused step registered for the scope table under its module name
+    assert "fused_step" in telemetry.compile_monitor.programs()
+    # (the CPU compiler keeps far less metadata than the TPU's: what the
+    # table holds there is asserted in test_tpu_compile.py)
+    table = telemetry.compile_monitor.scopes("fused_step")
+    assert "mlp" in {e["scope"] for e in table.values()}
+    assert any(e["backward"] for e in table.values())
+
+
+# -- the server's span tree, the work of a launch, the counters ---------------------
+
+def _serve(steps, prompts, max_new_tokens=4):
+    from deepspeed_tpu.serving import ServingFrontend
+    eng = _engine()
+    fe = ServingFrontend(eng)
+    for p in prompts:
+        fe.submit(list(p), max_new_tokens=max_new_tokens)
+    for _ in range(steps):
+        fe.step()
+    return eng, fe
+
+
+def test_traced_serving_step_has_its_span_tree_and_work(traced):
+    rng = np.random.default_rng(0)
+    before = _counters()
+    # 20 and 3 prompt tokens at chunk 8: fresh chunks first, then chunks
+    # with history beside decode rows, then decode-only steps
+    _serve(6, [rng.integers(1, 255, 20), rng.integers(1, 255, 3)])
+    events = traced.events()
+    steps = _spans(events, "serving/step")
+    assert len(steps) == 6
+    tree = {"serving/step": ("serving/admit", "serving/engine_step",
+                             "serving/fanout"),
+            "serving/engine_step": ("serving/pack", "serving/dispatch",
+                                    "serving/fetch")}
+    for parent, kids in tree.items():
+        parents = _spans(events, parent)
+        for name in kids:
+            spans = _spans(events, name)
+            assert len(spans) == 6, name
+            assert all(_inside(k, p) for k, p in zip(spans, parents)), name
+    launches = _spans(events, "serving/dispatch")
+    programs = [e["args"]["program"] for e in launches]
+    assert programs[0] == "fresh" and "split" in programs and \
+        programs[-1] == "decode"
+    assert [e["args"]["program"] for e in
+            _spans(events, "serving/engine_step")] == programs
+    for e in launches:
+        a = e["args"]
+        assert a["rows"] <= a["rows_bucket"]
+        assert a["slots"] == a["rows_bucket"] * a["chunk"]
+        assert 0 < a["tokens"] <= a["slots"]
+        assert a["tokens"] <= a["context_tokens"] <= a["context_slots"]
+        # the page table of ENG_CFG: 128 / 8 pages of 8 tokens a row
+        assert a["context_slots"] == a["rows_bucket"] * 16 * 8
+    first = launches[0]["args"]
+    assert (first["tokens"], first["context_tokens"]) == (8 + 3, 8 + 3)
+    # the always-on counters advanced by the launches' own sums
+    after = _counters()
+    for counter, key in (("dispatch/tokens", "tokens"),
+                         ("dispatch/token_slots", "slots"),
+                         ("dispatch/context_tokens", "context_tokens"),
+                         ("dispatch/context_slots", "context_slots")):
+        assert after[counter] - before[counter] == \
+            sum(e["args"][key] for e in launches), counter
+    assert after["dispatch/host_calls"] - before["dispatch/host_calls"] == 6
+    by_program = {p: programs.count(p) for p in set(programs)}
+    assert all(telemetry.registry.counter(f"dispatch/steps.{p}").value >= n
+               for p, n in by_program.items())
+
+
+def test_untraced_serving_step_counts_and_computes_no_argument(monkeypatch):
+    """With the tracer off the counters still advance by the packed
+    batch's sums, and the span arguments are never unpacked."""
+    from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
+
+    class Untouchable(dict):
+        def keys(self):
+            raise AssertionError("span arguments built with the tracer off")
+
+    real = RaggedInferenceEngineTPU._count_dispatch
+    monkeypatch.setattr(
+        RaggedInferenceEngineTPU, "_count_dispatch",
+        lambda self, *a, **k: Untouchable(real(self, *a, **k)))
+    tr = telemetry.tracer
+    was = tr.enabled
+    tr.configure(enabled=False)
+    tr.clear()
+    try:
+        before = _counters()
+        eng, _fe = _serve(2, [np.arange(1, 6)], max_new_tokens=8)
+        after = _counters()
+    finally:
+        tr.configure(enabled=was)
+    assert not tr.events()
+    # step 1: the 5 prompt tokens in one fresh chunk of 8 slots; step 2:
+    # one decode token that attends 5 cached tokens and itself
+    assert after["dispatch/tokens"] - before["dispatch/tokens"] == 5 + 1
+    assert after["dispatch/token_slots"] - \
+        before["dispatch/token_slots"] == 8 + 1
+    assert after["dispatch/context_tokens"] - \
+        before["dispatch/context_tokens"] == 5 + 6
+    assert after["dispatch/context_slots"] - \
+        before["dispatch/context_slots"] == 2 * 16 * 8
+    assert eng.last_program == "decode"
+
+
+def test_megastep_launch_counts_the_tokens_it_emitted(traced):
+    from deepspeed_tpu.serving import ServingFrontend
+    eng = _engine()
+    fe = ServingFrontend(eng, megastep_tokens=4)
+    fe.submit(list(range(1, 6)), max_new_tokens=12)
+    for _ in range(4):
+        fe.step()
+    mega = [e["args"] for e in _spans(traced.events(), "serving/dispatch")
+            if e["args"]["program"] == "megastep"]
+    assert mega, [e["args"]["program"] for e in
+                  _spans(traced.events(), "serving/dispatch")]
+    for a in mega:
+        assert 0 < a["tokens"] <= a["slots"]
+        assert a["tokens"] <= a["context_tokens"] <= a["context_slots"]

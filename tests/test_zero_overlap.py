@@ -1,6 +1,6 @@
 """Chunked, overlap-scheduled ZeRO-3 collectives (runtime/zero/overlap.py).
 
-Unit layer: spec surgery, bucketing, overlap-fraction math, scheduler-flag
+Unit layer: spec surgery, bucketing, scheduler-flag
 helpers, chunk-aware HLO attribution and comms-logger coalescing. Engine
 layer (dp=8 CPU mesh): numerical parity of the chunked path against the
 monolithic stage-3 step across bucket sizes {1 layer, 4 layers, whole
@@ -15,7 +15,7 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.runtime.zero import overlap as ov
 from deepspeed_tpu.runtime.zero.overlap import (
     OverlapPlan, build_overlap_plan, chunk_bounds, dense_spec,
-    ensure_scheduler_flags, overlap_fraction, scheduler_flag_status)
+    ensure_scheduler_flags, scheduler_flag_status)
 
 
 # ------------------------------------------------------------- spec surgery
@@ -40,23 +40,7 @@ def test_chunk_bounds():
     assert chunk_bounds(0, 100, 0) == []
 
 
-# --------------------------------------------------------- fraction + flags
-
-def test_overlap_fraction():
-    # fully serialized: measured == compute + comm → 0
-    assert overlap_fraction(1.0, 0.5, 1.5) == pytest.approx(0.0)
-    # fully hidden: measured == max(compute, comm) → 1
-    assert overlap_fraction(1.0, 0.5, 1.0) == pytest.approx(1.0)
-    # halfway
-    assert overlap_fraction(1.0, 0.5, 1.25) == pytest.approx(0.5)
-    # clamped, never out of [0, 1]
-    assert overlap_fraction(1.0, 0.5, 0.2) == 1.0
-    assert overlap_fraction(1.0, 0.5, 9.0) == 0.0
-    # missing terms (CPU without modeled peaks) → None, not 0
-    assert overlap_fraction(0.0, 0.5, 1.0) is None
-    assert overlap_fraction(1.0, 0.0, 1.0) is None
-    assert overlap_fraction(1.0, 0.5, 0.0) is None
-
+# --------------------------------------------------------------------- flags
 
 def test_scheduler_flag_helpers():
     env = {"XLA_FLAGS": "--xla_foo=1"}
