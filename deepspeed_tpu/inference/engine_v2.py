@@ -71,6 +71,19 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     remove the per-layer write→read dependency on the ~GB arena, which
     XLA otherwise serializes (measured 395 → ~200 ms on a 16x512
     prefill step, v5e 1.27B).
+
+    In the "split" program (``c > 1``) the arena is READ-ONLY during the
+    layer loop: the scan carries ``x`` alone, each layer emits its chunk's
+    ``k, v`` as scan outputs ([L, n, c, kvh, dh]) and ONE second scan,
+    with the arena as its only carry, writes them back. Nothing in a
+    split step reads what the same step wrote, and an arena that rides
+    the layer scan's carry beside a Pallas reader is relaid whole every
+    layer (two arena-shaped copies in the loop body, docs/kernels.md).
+    The history reader follows ``use_pallas``: the paged kernel
+    (:func:`paged_attention_with_lse`, ``counts = 0``) walks only each
+    row's ``ceil(start / block_size)`` live pages; the XLA gather
+    (:func:`paged_attention_hist_xla`, CPU or a head size the kernel
+    refuses) reads the page table's whole width.
     """
     if fresh_prefill is True:   # pre-three-mode boolean API
         fresh_prefill = "fresh"
@@ -102,26 +115,33 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     num_layers = cfg.num_layers
     stride = arena["k"].shape[1] // num_layers          # num_blocks + 1
 
+    split = fresh_prefill == "split" and c > 1
+    layers = (params["layers"], jnp.arange(num_layers, dtype=jnp.int32))
+
     def body(carry, layer):
-        x, ak, av = carry
+        # split: the arena is a read-only input of the loop (docstring)
+        x, ak, av = (carry, arena["k"], arena["v"]) if split else carry
         lp, l_idx = layer
         off = l_idx * stride
         pt_l = page_table + off       # padded entries → this layer's trash
         h_in = _norm(cfg, lp["ln1"], x)
         q, k, v = qkv_project(cfg, lp["attn"], h_in, sin, cos)
-        split = fresh_prefill == "split" and c > 1
         if split:
             # continuation / SplitFuse-mixed chunk: the history part
-            # reads the PRE-write arena — computed BEFORE the write so
-            # no write→read serialization. Fresh rows mixed in have
-            # empty history (lse ≈ -1e30 → weight 0); decode rows ride
-            # along as width-1 chunks.
+            # reads the PRE-write arena. Fresh rows mixed in have empty
+            # history (lse ≈ -1e30 → weight 0); decode rows ride along
+            # as width-1 chunks.
             with jax.named_scope("attn_history"):
-                out_h, lse_h = pa.paged_attention_hist_xla(
-                    q, ak, av, pt_l, starts)
-        with jax.named_scope("kv_write"):
-            ak, av = pa.write_kv(ak, av, k, v, pt_l, starts, counts,
-                                 trash_block=off + stride - 1)
+                if use_pallas:
+                    out_h, lse_h = pa.paged_attention_with_lse(
+                        q, ak, av, pt_l, starts, jnp.zeros_like(starts))
+                else:
+                    out_h, lse_h = pa.paged_attention_hist_xla(
+                        q, ak, av, pt_l, starts)
+        else:
+            with jax.named_scope("kv_write"):
+                ak, av = pa.write_kv(ak, av, k, v, pt_l, starts, counts,
+                                     trash_block=off + stride - 1)
         if fresh_prefill == "fresh":
             # starts == 0 everywhere: the chunk IS the whole history —
             # plain causal attention over it; padded-tail rows produce
@@ -152,11 +172,26 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
                 out = attend(q, ak, av, pt_l, starts, counts)
         attn_out = attn_out_project(cfg, lp["attn"], out)
         h_out, _aux = block_combine(cfg, lp, x, h_in, attn_out, moe_fn)
+        if split:
+            return h_out, (k.astype(ak.dtype), v.astype(av.dtype))
         return (h_out, ak, av), None
 
-    (x, ak, av), _ = lax.scan(
-        body, (x, arena["k"], arena["v"]),
-        (params["layers"], jnp.arange(num_layers, dtype=jnp.int32)))
+    if split:
+        x, (k_new, v_new) = lax.scan(body, x, layers)
+
+        def write_back(carry, layer_kv):
+            k, v, l_idx = layer_kv
+            off = l_idx * stride
+            with jax.named_scope("kv_write"):
+                return pa.write_kv(*carry, k, v, page_table + off, starts,
+                                   counts,
+                                   trash_block=off + stride - 1), None
+
+        (ak, av), _ = lax.scan(write_back, (arena["k"], arena["v"]),
+                               (k_new, v_new, layers[1]))
+    else:
+        (x, ak, av), _ = lax.scan(body, (x, arena["k"], arena["v"]),
+                                  layers)
     x = _norm(cfg, params["final_norm"], x)
     with jax.named_scope("lm_head"):       # the rows the head projects
         last = jnp.maximum(counts - 1, 0)
@@ -848,10 +883,18 @@ class RaggedInferenceEngineTPU:
         from deepspeed_tpu.telemetry.tracer import tracer
         with tracer.span("serving/pack"):
             packed = jnp.asarray(self._pack(batch, nb, cb))  # ONE upload
+        context_slots = None
+        if fresh == "split" and self.use_pallas:
+            # the paged reader walks each row's live pages, then the
+            # chunk attends its own keys
+            bs = self.config.block_size
+            context_slots = nb * cb + \
+                int((-(-batch.start_positions // bs)).sum()) * bs
         work = self._count_dispatch(
             _step_kind(cb, fresh), n, nb, cb, self.mb,
             int(batch.token_counts.sum()),
-            int((batch.start_positions + batch.token_counts).sum()))
+            int((batch.start_positions + batch.token_counts).sum()),
+            context_slots=context_slots)
         with tracer.span("serving/dispatch",
                          **(work if tracer.enabled else {})):
             out, self._rng_dev, self.arena = self._step_fn(
@@ -862,18 +905,23 @@ class RaggedInferenceEngineTPU:
 
     def _count_dispatch(self, program: str, rows: int, nb: int, chunk: int,
                         page_width: int, tokens: int, context_tokens: int,
-                        scan_steps: int = 1) -> Dict[str, Any]:
+                        scan_steps: int = 1,
+                        context_slots: Optional[int] = None
+                        ) -> Dict[str, Any]:
         """Count one device program launch where its batch is packed: the
         useful work (``tokens`` fed, ``context_tokens`` of live KV they
         attend) against the work attempted (``slots`` = bucketed rows x
-        chunk width; ``context_slots`` = bucketed rows x the page table's
-        width in tokens; both times the scan steps of a megastep).
+        chunk width; ``context_slots`` = what the attention reads: bucketed
+        rows x the page table's width in tokens unless the caller knows
+        better, as for a split step whose history goes through the paged
+        kernel; both times the scan steps of a megastep).
         Always-on ``dispatch/*`` counters; the same numbers are the
         ``serving/dispatch`` span's arguments."""
         from deepspeed_tpu.telemetry.registry import registry
         slots = nb * chunk * scan_steps
-        context_slots = nb * page_width * self.config.block_size * \
-            scan_steps
+        if context_slots is None:
+            context_slots = nb * page_width * self.config.block_size * \
+                scan_steps
         self.last_program = program
         for name, by in (("host_calls", 1), ("tokens", tokens),
                          ("token_slots", slots),

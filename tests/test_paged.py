@@ -383,6 +383,118 @@ def test_split_history_merge_matches_paged(devices):
                                    rtol=2e-5, atol=2e-5, err_msg=f"row {i}")
 
 
+#: row -> tokens already cached, at the serving cell's chunk (128), page
+#: (128) and head geometry (4 query heads a KV head): the page boundaries
+#: from both sides, a full table, and padded rows whose table is all trash
+_HIST_STARTS = {"start0": 0, "start1": 1, "start127": 127, "start128": 128,
+                "start129": 129, "full_table": 512, "padded_a": 0,
+                "padded_b": 0}
+
+
+@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+def hist_readers(request):
+    """(out, lse) of the two history readers over one arena: the paged
+    kernel with ``counts = 0`` (interpret mode) and the XLA gather."""
+    dtype = jnp.dtype(request.param)
+    rng = np.random.default_rng(7)
+    kvh, groups, dh, bs, c, mb = 2, 4, 128, 128, 128, 4
+    starts = np.asarray(list(_HIST_STARTS.values()), np.int32)
+    n, nb = len(starts), 10
+    pt = np.full((n, mb), nb, np.int32)
+    free = iter(rng.permutation(nb))                # pages out of order
+    for i, (name, start) in enumerate(_HIST_STARTS.items()):
+        if not name.startswith("padded"):
+            for b in range(-(-start // bs)):
+                pt[i, b] = next(free)
+    # every page holds finite noise, the trash page too: what a row has
+    # not cached must not reach its output
+    shape = (kvh, nb + 1, bs, dh)
+    ak = jnp.asarray(rng.standard_normal(shape), dtype)
+    av = jnp.asarray(rng.standard_normal(shape), dtype)
+    q = jnp.asarray(rng.standard_normal((n, c, kvh * groups, dh)), dtype)
+    args = (q, ak, av, jnp.asarray(pt), jnp.asarray(starts))
+    got = pa.paged_attention_with_lse(*args, jnp.zeros((n,), jnp.int32),
+                                      interpret=True)
+    want = pa.paged_attention_hist_xla(*args)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    return jax.tree.map(lambda a: np.asarray(a, np.float32),
+                        (got, want)), tol
+
+
+@pytest.mark.parametrize("row", list(_HIST_STARTS))
+def test_paged_history_kernel_matches_xla_reader(hist_readers, row):
+    """``paged_attention_with_lse(counts=0)`` is the split program's
+    history reader: keys [0, start) and nothing else, whatever the table's
+    dead entries point at."""
+    ((out, lse), (out_x, lse_x)), tol = hist_readers
+    i = list(_HIST_STARTS).index(row)
+    live = lse_x[i] > -1e29
+    assert live.all() == (_HIST_STARTS[row] > 0) and \
+        live.any() == live.all()
+    np.testing.assert_array_equal(lse[i] > -1e29, live)
+    np.testing.assert_allclose(lse[i][live], lse_x[i][live], rtol=tol,
+                               atol=tol)
+    np.testing.assert_allclose(out[i][live], out_x[i][live], rtol=tol,
+                               atol=tol)
+    # an empty history weighs nothing in the merge, so its out never shows
+    assert np.isfinite(out[i]).all()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("reader", ["xla", "kernel"])
+def test_split_step_matches_in_loop_write(devices, monkeypatch, reader,
+                                          dtype):
+    """One ``split`` step (read-only arena in the layer loop, one
+    write-back after it) against the single paged read with the write
+    inside the loop: same logits, and the same arena page for page, each
+    layer's trash page left out. A continuation row, a fresh row, a
+    decode row riding along and a padded row."""
+    import functools
+    from deepspeed_tpu.models.transformer import init_params
+    dtype = jnp.dtype(dtype)
+    cfg = llama3_config("tiny")
+    params = jax.tree.map(lambda a: a.astype(dtype),
+                          init_params(cfg, jax.random.PRNGKey(3)))
+    nb, bs, mb, n, c = 15, 8, 8, 4, 16
+    arena = pa.init_arena(cfg.num_layers, cfg.kv_heads, nb, bs,
+                          cfg.head_dim, dtype)
+    rng = np.random.default_rng(5)
+    starts = np.asarray([24, 0, 40, 0], np.int32)
+    counts = np.asarray([16, 16, 1, 0], np.int32)
+    pt = np.full((n, mb), nb, np.int32)
+    pt[0, :5], pt[1, :2], pt[2, :6] = [3, 9, 1, 12, 7], [0, 5], \
+        [2, 4, 6, 8, 10, 11]
+    toks = lambda *shape: jnp.asarray(
+        rng.integers(0, cfg.vocab_size, shape), jnp.int32)
+    # the history, through the program this PR leaves alone
+    _, arena = ragged_forward(cfg, params, arena, toks(n, 40),
+                              jnp.asarray(starts), jnp.zeros((n,), jnp.int32),
+                              jnp.asarray(pt))
+    step = (toks(n, c), jnp.asarray(counts), jnp.asarray(starts),
+            jnp.asarray(pt))
+    want_logits, want = ragged_forward(cfg, params, arena, *step)
+    if reader == "kernel":
+        monkeypatch.setattr(pa, "paged_attention_with_lse",
+                            functools.partial(pa.paged_attention_with_lse,
+                                              interpret=True))
+    got_logits, got = ragged_forward(cfg, params, arena, *step,
+                                     use_pallas=reader == "kernel",
+                                     fresh_prefill="split")
+    tol = 2e-4 if dtype == jnp.float32 else 5e-2
+    f32 = lambda a: np.asarray(a, np.float32)
+    np.testing.assert_allclose(f32(got_logits)[:3], f32(want_logits)[:3],
+                               rtol=tol, atol=tol)
+    pages = [l * (nb + 1) + b for l in range(cfg.num_layers)
+             for b in range(nb)]
+    for name in ("k", "v"):
+        np.testing.assert_allclose(f32(got[name])[:, pages],
+                                   f32(want[name])[:, pages],
+                                   rtol=tol, atol=tol, err_msg=name)
+        # the step wrote: the continuation row's pages changed
+        assert not np.array_equal(f32(got[name])[:, pages],
+                                  f32(arena[name])[:, pages])
+
+
 def test_flash_attention_with_lse_matches_xla(devices):
     from deepspeed_tpu.ops.flash_attention import flash_attention_with_lse
     from deepspeed_tpu.ops.paged_attention import causal_attention_with_lse

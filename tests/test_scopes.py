@@ -412,6 +412,58 @@ def test_traced_serving_step_has_its_span_tree_and_work(traced):
                for p, n in by_program.items())
 
 
+@pytest.mark.parametrize("reader", ["paged", "gather"])
+def test_split_launch_counts_what_its_history_reader_reads(traced,
+                                                           monkeypatch,
+                                                           reader):
+    """``context_slots`` of a split launch: the pages the paged kernel
+    walks plus the chunk's own keys when that reader is chosen
+    (``use_pallas`` forced; the kernels in interpret mode), the page
+    table's padded width under the XLA gather. Every other program keeps
+    the padded width."""
+    import functools
+    from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
+    from deepspeed_tpu.ops import paged_attention as pa
+    from deepspeed_tpu.serving import ServingFrontend
+    for kernel in ("paged_attention", "paged_attention_with_lse"):
+        monkeypatch.setattr(pa, kernel, functools.partial(
+            getattr(pa, kernel), interpret=True))
+    starts = []
+    real = RaggedInferenceEngineTPU._pack
+    monkeypatch.setattr(
+        RaggedInferenceEngineTPU, "_pack",
+        lambda self, batch, nb, cb: starts.append(
+            np.array(batch.start_positions)) or real(self, batch, nb, cb))
+    fe = ServingFrontend(_engine(use_pallas=reader == "paged"))
+    rng = np.random.default_rng(0)
+    before = _counters()
+    for prompt in (rng.integers(1, 255, 20), rng.integers(1, 255, 3)):
+        fe.submit(list(prompt), max_new_tokens=4)
+    for _ in range(4):
+        fe.step()
+    launches = [e["args"] for e in
+                _spans(traced.events(), "serving/dispatch")]
+    assert [a["program"] for a in launches] == \
+        ["fresh", "split", "split", "decode"]
+    for a, st in zip(launches, starts):
+        padded = a["rows_bucket"] * 16 * 8      # ENG_CFG's table, in tokens
+        if a["program"] == "split" and reader == "paged":
+            want = int((-(-st // 8) * 8).sum()) + a["rows_bucket"] * 8
+            assert want < padded
+        else:
+            want = padded
+        assert a["context_slots"] == want, a
+        assert a["tokens"] <= a["context_tokens"] <= a["context_slots"]
+    # the first split step: 8 cached tokens of the long prompt (one page),
+    # the short prompt's 3 (one page, partly live), two rows of 8 chunk keys
+    assert launches[1]["context_slots"] == \
+        (8 + 8 + 2 * 8 if reader == "paged" else 2 * 16 * 8)
+    after = _counters()
+    assert after["dispatch/context_slots"] - \
+        before["dispatch/context_slots"] == \
+        sum(a["context_slots"] for a in launches)
+
+
 def test_untraced_serving_step_counts_and_computes_no_argument(monkeypatch):
     """With the tracer off the counters still advance by the packed
     batch's sums, and the span arguments are never unpacked."""

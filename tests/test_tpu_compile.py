@@ -70,14 +70,15 @@ def _flash(seq, batch, grad):
     return fn, (q, kv, kv), 3 if grad else 1
 
 
-def _paged(n, c, with_lse):
+def _paged(n, c, with_lse, heads=16, pages=8):
     """Paged attention over the default serving arena: 16 layers of
-    512 pages (+1 trash page each) of 128 tokens, 8 KV heads of 128."""
+    512 pages (+1 trash page each) of 128 tokens, 8 KV heads of 128;
+    ``pages`` a row (8: ``max_seq_len`` 1024)."""
     from deepspeed_tpu.ops import paged_attention as pa
     kern = pa.paged_attention_with_lse if with_lse else pa.paged_attention
     arena = ((8, 16 * (512 + 1), 128, 128), jnp.bfloat16)
-    q = ((n, c, 16, 128), jnp.bfloat16)
-    pt = ((n, 8), jnp.int32)               # max_seq_len 1024 = 8 pages
+    q = ((n, c, heads, 128), jnp.bfloat16)
+    pt = ((n, pages), jnp.int32)
     vec = ((n,), jnp.int32)
     return functools.partial(kern, interpret=False), \
         (q, arena, arena, pt, vec, vec), 1
@@ -127,6 +128,10 @@ CASES = {
     "paged_decode_n16": lambda: _paged(16, 1, with_lse=False),
     "paged_decode_n16_lse": lambda: _paged(16, 1, with_lse=True),
     "paged_prefill_n4_c256": lambda: _paged(4, 256, with_lse=False),
+    # the split step's history reader at the serving cell's shapes:
+    # Mistral's 32 / 8 heads, 64 rows of chunk 128, max_seq_len 4096
+    "paged_hist_n64_c128_lse": lambda: _paged(64, 128, with_lse=True,
+                                              heads=32, pages=32),
     "dequant_int8": lambda: _dequant("int8"),
     "dequant_fp8": lambda: _dequant("fp8"),
     "dequant_int4": lambda: _dequant("int4"),
@@ -145,6 +150,7 @@ CASES = {
 KERNEL_NAMES = {
     "flash_fwd_2k": ("flash_fwd",),
     "flash_fwd_bwd_2k": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+    "paged_hist_n64_c128_lse": ("paged_attn_lse",),
     "dequant_int8": ("qmm",),
     "dequant_fp8": ("qmm",),
     "dequant_int4": ("qmm_int4",),
@@ -345,3 +351,59 @@ def test_step_program_maps_to_scopes_on_v5e(program, one_chip,
         assert "mlp" in remat and "loss" in remat
     else:
         assert not backward - {None} and not remat - {None}
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
+_CALLED = re.compile(r"\b(?:body|condition|calls|to_apply)=%([\w.\-]+)")
+
+
+def _in_while_bodies(text):
+    """The lines of every computation a ``while`` body reaches (the body,
+    the fusions and calls inside it, nested loops)."""
+    lines, calls, bodies, name = {}, {}, set(), None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            name = head.group(1)
+            lines[name], calls[name] = [], set()
+        elif name is not None:
+            lines[name].append(line)
+            calls[name].update(_CALLED.findall(line))
+            bodies.update(re.findall(r"\bbody=%([\w.\-]+)", line))
+    reached, todo = set(), list(bodies)
+    while todo:
+        comp = todo.pop()
+        if comp not in reached:
+            reached.add(comp)
+            todo.extend(calls[comp])
+    assert bodies and reached <= set(lines)
+    return [line for comp in reached for line in lines[comp]]
+
+
+def test_serve_split_reads_live_pages_from_a_read_only_arena(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The split step at the serving cell's shapes: its history goes
+    through the paged kernel under ``attn_history``; the arena stays out
+    of the layer loop's carry, so NO arena-shaped copy runs inside a loop
+    (carried beside the kernel it is relaid whole twice a layer: ISSUE 29,
+    docs/kernels.md); and without the gathered float32 scores (6.08 GB of
+    temporaries at two layers) the program's temporaries stay under 2 GB."""
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jitted, args = _serve_split(one_chip)
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    table = scope_table_from_hlo(text)
+    kernels = [n for n in table if n.startswith("paged_attn_lse")]
+    assert kernels and all(table[n]["scope"] == "attn_history"
+                           for n in kernels), kernels
+    arena = args[1]["k"]
+    shape = "bf16[" + ",".join(map(str, arena.shape)) + "]"
+    arena_copy = re.compile(rf" = {re.escape(shape)}\S* copy\(")
+    copies = [line.strip()[:160] for line in _in_while_bodies(text)
+              if arena_copy.search(line)]
+    assert not copies, copies
+    # ... and the pattern does find the entry's relayouts (in for the
+    # scatter's layout, out again), so an empty list above means something
+    assert arena_copy.search(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
