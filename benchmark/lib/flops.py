@@ -3,6 +3,13 @@ of ``mfu`` and of every ``*_roofline`` metric. Kept with the benchmark so
 no PR that claims a gain can change what counts as useful work.
 Recomputed operations never count.
 
+What is particular to an architecture's block — the matmul parameters one
+token multiplies — is counted by its reference module
+(``matmul_params_per_token``, from the configuration FILE's widths); what
+every decoder shares stays here: the attention FLOPs over visible pairs,
+the byte counts, the roofline share, and ``matmul_params``, the dense count
+that ``reference/dense_decoder.py`` answers with.
+
 ``cfg`` is anything with the DecoderConfig's width attributes
 (``hidden_size, num_layers, num_heads, kv_heads, head_dim,
 intermediate_size, vocab_size, sliding_window``)."""
@@ -51,12 +58,17 @@ def attention_flops_fwd(cfg, seq_len: int) -> int:
     return cfg.num_layers * 4 * cfg.num_heads * cfg.head_dim * pairs
 
 
-def train_flops_per_token(cfg, seq_len: int) -> float:
+def train_flops_per_token(cfg, seq_len: int,
+                          matmul_params_per_token: Optional[int] = None
+                          ) -> float:
     """Forward + backward of one token at this sequence length: 6 FLOPs a
-    matmul parameter, and attention at three times its forward (backward
-    needs dV, dP, dQ, dK: four matmuls to the forward's two). No
-    recompute: neither remat's nor the flash backward's second QK^T."""
-    return 6.0 * matmul_params(cfg) + \
+    matmul parameter (the architecture's own count, else the dense one),
+    and attention at three times its forward (backward needs dV, dP, dQ,
+    dK: four matmuls to the forward's two). No recompute: neither remat's
+    nor the flash backward's second QK^T."""
+    if matmul_params_per_token is None:
+        matmul_params_per_token = matmul_params(cfg)
+    return 6.0 * matmul_params_per_token + \
         3.0 * attention_flops_fwd(cfg, seq_len) / seq_len
 
 

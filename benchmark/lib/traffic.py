@@ -72,7 +72,9 @@ KEYS = {
 
 def check_mix(mix: dict, where: str = "mix") -> dict:
     if mix.get("kind") not in KEYS:
-        raise ValueError(f"{where}: kind must be 'train' or 'serve'")
+        # a kind is a runner and its mix keys together: a new one takes a
+        # `benchmark` issue (README.md), never a file beside these
+        raise ValueError(f"{where}: kind must be one of {sorted(KEYS)}")
     unknown = set(mix) - KEYS[mix["kind"]]
     if unknown:
         raise ValueError(f"{where}: unknown keys {sorted(unknown)}")

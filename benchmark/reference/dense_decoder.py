@@ -17,15 +17,39 @@ memory); weights arrive in the program's tree layout (``[in, out]``
 matrices stacked over layers), which this file reads and nothing else.
 
 Independent of the code under test: it imports nothing from
-``deepspeed_tpu``."""
+``deepspeed_tpu``.
+
+**The reference contract.** A configuration file names its reference
+module (``"reference": "<module>"`` finds ``benchmark/reference/<module>.py``;
+without the key, this one). The runners use these four names of it and
+nothing else, so a new architecture is a new file here:
+
+- ``Widths.from_hf(hf)``: the sizes, from the FILE's published keys
+  (hashable, so a jitted layer can take it as a static argument);
+- ``matmul_params_per_token(w)``: the matmul parameters one token
+  multiplies, forward, whatever the block's mathematics makes them (for
+  sparse experts: the experts a token is routed to, and the router) — the
+  numerator of ``mfu``, kept with the benchmark;
+- ``loss(w, params, batch, device)``: the loss the trainer reports for a
+  ``[B, T]`` batch at the program's parameter tree, as a float;
+- ``argmax_gaps(w, params, prompts, outputs, device)``: for every generated
+  token of every request, flattened, how far this reference scores it
+  below its own argmax at that position.
+
+float32 under ``jax.default_matmul_precision("highest")``, inputs from the
+seed and the program's parameter tree only. The limits that decide
+``correct`` are the runners' and the same for every reference."""
 
 from dataclasses import dataclass
 from functools import partial
+from types import SimpleNamespace
 from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from benchmark.lib import flops
 
 QUERY_BLOCK = 512
 
@@ -59,6 +83,15 @@ class Widths:
                    eps=float(hf["rms_norm_eps"]),
                    theta=float(hf["rope_theta"]),
                    window=hf.get("sliding_window"))
+
+
+def matmul_params_per_token(w: Widths) -> int:
+    """This block's count is the dense one ``lib/flops.py`` keeps: the
+    layers' projections and one GLU each, and the untied output head."""
+    return flops.matmul_params(SimpleNamespace(
+        hidden_size=w.hidden, num_heads=w.heads, kv_heads=w.kv_heads,
+        head_dim=w.head_dim, intermediate_size=w.ffn, num_layers=w.layers,
+        vocab_size=w.vocab))
 
 
 def _rms_norm(x, scale, eps):
@@ -104,9 +137,9 @@ def _attention(q, k, v, window):
     return out.reshape(t, h, dh)
 
 
-@partial(jax.jit, static_argnames=("w",))
-def _layer(x, lp, w: Widths):
-    """One decoder block on one sequence. x [T, D] float32."""
+def attention_block(x, lp, w: Widths):
+    """The block's first half on one sequence: x [T, D] float32 →
+    x + attention(RMSNorm(x))."""
     t = x.shape[0]
     pos = jnp.arange(t)
     a = lp["attn"]
@@ -116,7 +149,13 @@ def _layer(x, lp, w: Widths):
     v = (hin @ a["wv"]).reshape(t, w.kv_heads, w.head_dim)
     q, k = _rope(q, pos, w.theta), _rope(k, pos, w.theta)
     o = _attention(q, k, v, w.window).reshape(t, w.heads * w.head_dim)
-    x = x + o @ a["wo"]
+    return x + o @ a["wo"]
+
+
+@partial(jax.jit, static_argnames=("w",))
+def _layer(x, lp, w: Widths):
+    """One decoder block on one sequence. x [T, D] float32."""
+    x = attention_block(x, lp, w)
     m = lp["mlp"]
     hin = _rms_norm(x, lp["ln2"]["scale"], w.eps)
     return x + (jax.nn.silu(hin @ m["wg"]) * (hin @ m["wi"])) @ m["wo"]
@@ -149,29 +188,33 @@ def _padded(tokens: Sequence[int]) -> np.ndarray:
     return out
 
 
-def final_hidden(w: Widths, params, token_rows: List[np.ndarray], device):
+def final_hidden(w: Widths, params, token_rows: List[np.ndarray], device,
+                 layer=_layer):
     """Last-layer hidden states, one [T, D] float32 array per sequence, all
     on ``device`` (sharded parameters are gathered to it a layer at a
     time). Layer-major: each layer's weights are cast once and used for
     every sequence. Spreading the sequences over four chips was tried and
     was slower (four copies of every layer to move and cast: 55 s against
-    27 s for 8 x 4096 tokens through 16 layers; my chip run, PR 27)."""
+    27 s for 8 x 4096 tokens through 16 layers; my chip run, PR 27).
+    ``layer(x, lp, w)`` is the block: another architecture's reference
+    that shares this file's embedding, head and loss hands its own in."""
     emb = params["embed"]["tokens"]
     xs = [jax.device_put(emb[jnp.asarray(r)], device).astype(jnp.float32)
           for r in token_rows]
     with jax.default_matmul_precision("highest"):
         for i in range(w.layers):
             lp = _f32(params["layers"], device, i)
-            xs = [_layer(x, lp, w) for x in xs]
+            xs = [layer(x, lp, w) for x in xs]
             del lp
     return xs
 
 
-def loss(w: Widths, params, batch: np.ndarray, device) -> float:
+def loss(w: Widths, params, batch: np.ndarray, device,
+         layer=_layer) -> float:
     """Mean next-token cross-entropy over a [B, T] batch (every position
     but each row's last), as the trainer defines its loss."""
     rows = [np.asarray(r, np.int32) for r in batch]
-    xs = final_hidden(w, params, rows, device)
+    xs = final_hidden(w, params, rows, device, layer)
     scale = _f32(params["final_norm"]["scale"], device)
     head = _f32(params["lm_head"], device)
     total = 0.0
@@ -185,12 +228,13 @@ def loss(w: Widths, params, batch: np.ndarray, device) -> float:
     return total / sum(len(r) - 1 for r in rows)
 
 
-def argmax_gaps(w: Widths, params, prompts, outputs, device) -> np.ndarray:
+def argmax_gaps(w: Widths, params, prompts, outputs, device,
+                layer=_layer) -> np.ndarray:
     """Teacher-forced check of generated tokens: for every generated token
     of every request (flattened), how far the reference scores it below
     its own argmax at that position (0.0: it IS the argmax)."""
     rows = [_padded(list(p) + list(o)) for p, o in zip(prompts, outputs)]
-    xs = final_hidden(w, params, rows, device)
+    xs = final_hidden(w, params, rows, device, layer)
     scale = _f32(params["final_norm"]["scale"], device)
     head = _f32(params["lm_head"], device)
     gaps = []

@@ -11,9 +11,11 @@ end-to-end metrics, with ``--trace 1`` its per-layer metrics.
 
 Driven by data: the cell names a configuration (``configs/<name>.json``)
 and a traffic mix (``traffic/<name>.json``); the mix's ``kind`` picks the
-runner (``runners/<kind>.py``); each per-layer metric the cell reports has
-a reader of its own (``layer_metrics/<metric>.py``). No cell's name occurs
-in code.
+runner (``runners/<kind>.py``); the configuration names the plain
+reference of its architecture (``reference/<name>.py``); each per-layer
+metric the cell reports has a reader of its own
+(``layer_metrics/<metric>.py``). No cell's name occurs in code, and no
+architecture's beyond the default of that one lookup.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits 2 and
 prints no result. ``--rehearse`` is the only way to a CPU run: tiny widths,
@@ -82,12 +84,13 @@ def load_reader(metric: str):
 class Context:
     """What a runner gets from the harness."""
 
-    def __init__(self, args, cell, conf, mix, devices, ledger):
+    def __init__(self, args, cell, conf, mix, devices, ledger, reference):
         self.seed = int(args.seed)
         self.seconds = float(args.seconds)
         self.trace = bool(args.trace)
         self.rehearse = bool(args.rehearse)
         self.cell, self.conf, self.mix = cell, conf, mix
+        self.reference = reference
         self.devices = devices
         self.ledger = ledger
         self.t_start = T_START
@@ -207,15 +210,17 @@ def main(argv=None) -> int:
     ledger = CompileLedger()
     conf = model_lib.load_config(cell["config"])
     mix = traffic.load_mix(cell["traffic"])
+    reference = model_lib.load_reference(conf)
     emit({"phase": "start", "cell": cell["name"], "config": cell["config"],
-          "traffic": cell["traffic"], "seed": args.seed,
+          "traffic": cell["traffic"], "reference": reference.__name__,
+          "seed": args.seed,
           "seconds": args.seconds, "trace": args.trace,
           "platform": found[0].platform, "device_kind": found[0].device_kind,
           "devices": len(found), "chips_used": chips,
           "compile_cache_dir": cache_dir, "jax": jax.__version__,
           # imports and the backend's start, before any of the program
           "t": round(time.monotonic() - T_START, 2)})
-    ctx = Context(args, cell, conf, mix, devices, ledger)
+    ctx = Context(args, cell, conf, mix, devices, ledger, reference)
     runner = importlib.import_module(f"benchmark.runners.{mix['kind']}")
     result = runner.run(ctx)
 

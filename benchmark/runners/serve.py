@@ -18,7 +18,7 @@ import numpy as np
 
 from benchmark.lib import model as model_lib
 from benchmark.lib import stats, traffic
-from benchmark.reference import dense_decoder as reference
+from benchmark.trace import scopes
 
 #: a generated token may differ from the float32 reference's argmax only
 #: where the reference scores it within this many logits of its maximum.
@@ -94,6 +94,7 @@ def run(ctx) -> dict:
     from deepspeed_tpu.telemetry.registry import registry
 
     conf, mix, device = ctx.conf, ctx.mix, ctx.devices[0]
+    reference = ctx.reference
     ds.build_mesh(data=1, devices=[device])
     model = model_lib.build_model(conf, ctx.rehearse)
     eng = RaggedInferenceEngineTPU(model, dict(conf["engine"]),
@@ -239,7 +240,7 @@ def run(ctx) -> dict:
     pick = [good[i] for i in rng.permutation(len(good))[:CHECKED_REQUESTS]]
     flat = reference.argmax_gaps(
         reference.Widths.from_hf(
-            model_lib.reference_widths(conf, ctx.rehearse)), eng.params,
+            model_lib.published_keys(conf, ctx.rehearse)), eng.params,
         [r.planned.prompt for r in pick],
         [list(r.req.tokens_out) for r in pick], device) \
         if pick else np.zeros(0)
@@ -272,7 +273,9 @@ def run(ctx) -> dict:
             "itl_p95_ms": 1e3 * stats.percentile(gaps, 95)
                 if gaps else None},
         "span_name": SPAN,
-        "gap_spans": [SPAN, "serving/engine_step"],
+        # an idle gap of the device goes to the innermost of these
+        "gap_spans": [SPAN] + [n for n in scopes.PROGRAM_SPANS
+                               if n.startswith("serving/")],
         "facts": {"kind": "serve", "model": model, "steps": steps,
                   "ttft_s": ttft,
                   "spans": spans, "host_calls": calls, "tokens": tokens,

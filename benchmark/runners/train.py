@@ -18,7 +18,7 @@ import numpy as np
 from benchmark.lib import flops as flops_lib
 from benchmark.lib import model as model_lib
 from benchmark.lib import traffic
-from benchmark.reference import dense_decoder as reference
+from benchmark.trace import scopes
 
 #: |loss of the first step - the float32 reference's loss on the same batch
 #: at the initial parameters|. The trainer computes in bf16 with bf16
@@ -90,6 +90,7 @@ def run(ctx) -> dict:
     from deepspeed_tpu.telemetry import tracer
 
     conf, mix, devices = ctx.conf, ctx.mix, ctx.devices
+    reference = ctx.reference
     n_dev = len(devices)
     seq = int(mix["seq_len"]) if not ctx.rehearse else 256
     mix = dict(mix, seq_len=seq)
@@ -108,15 +109,19 @@ def run(ctx) -> dict:
         raise RuntimeError(f"ZeRO stage {engine.zero_stage}, the "
                            f"configuration says {want_stage}")
     batches = traffic.train_batches(mix, ctx.seed, model.vocab_size)
+    widths = reference.Widths.from_hf(
+        model_lib.published_keys(conf, ctx.rehearse))
+    # useful work: the architecture's own matmul count, from the file
+    flops_per_token = flops_lib.train_flops_per_token(
+        model, seq, reference.matmul_params_per_token(widths))
     ctx.log({"phase": "initialized", "params": int(model.num_params()),
              "devices": n_dev, "zero_stage": int(engine.zero_stage),
              "seq": seq, "global_batch": gb,
+             "flops_per_token": flops_per_token,
              "t": round(time.monotonic() - ctx.t_start, 2)})
 
     # the reference, before the first step changes (and donates) the
     # parameters: its loss on batch 0 at the initial parameters
-    widths = reference.Widths.from_hf(
-        model_lib.reference_widths(conf, ctx.rehearse))
     ref_loss = reference.loss(widths, engine.params, batches[0], devices[0])
 
     def step(i: int) -> float:
@@ -219,11 +224,14 @@ def run(ctx) -> dict:
         "attempted": steps, "failed": nonfinite,
         "end_to_end": {"train_tokens_per_s_per_chip": rate},
         "span_name": SPAN,
+        # an idle gap of the device goes to the innermost of these
+        "gap_spans": [SPAN] + [n for n in scopes.PROGRAM_SPANS
+                               if n.startswith("train/")],
         "facts": {
             "kind": "train", "model": model, "seq_len": seq,
             "global_batch": gb, "chips": n_dev,
             "tokens_per_s_per_chip": rate,
-            "flops_per_token": flops_lib.train_flops_per_token(model, seq),
+            "flops_per_token": flops_per_token,
             "flash_flops_per_step_per_chip":
                 flops_lib.flash_train_flops_per_step(model, seq,
                                                      per_chip_seqs),

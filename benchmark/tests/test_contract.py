@@ -6,6 +6,8 @@ import json
 import os
 import re
 
+from benchmark.lib import model
+
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -75,16 +77,20 @@ def test_configs_name_their_files_and_cut_no_width():
         for key in c["reduced"]:
             assert NAME.match(key) and not WIDTH.search(key), key
             assert key in conf["changed"]
-        # Mistral-7B-v0.1 as published, but for what `reduced` lists
-        published = {"hidden_size": 4096, "intermediate_size": 14336,
-                     "num_attention_heads": 32, "num_key_value_heads": 8,
-                     "num_hidden_layers": 32, "vocab_size": 32000,
-                     "rms_norm_eps": 1e-05, "rope_theta": 10000.0,
-                     "sliding_window": 4096, "max_position_embeddings": 32768,
-                     "tie_word_embeddings": False, "hidden_act": "silu"}
-        for key, val in published.items():
-            if key not in c["reduced"]:
-                assert conf[key] == val, (c["name"], key)
+        # the source's config.json as published (configs/published/), but
+        # for what `reduced` lists: no family's numbers stand in this test
+        published = model.load_published(conf)
+        assert published["source"] == c["source"]
+        shared = (set(published) & set(conf)) - model.BOOKKEEPING_KEYS
+        assert shared >= {"model_type", "hidden_size", "num_hidden_layers",
+                          "vocab_size"}, c["name"]
+        for key in shared:
+            if key in c["reduced"]:
+                assert conf["changed"][key] == {"published": published[key],
+                                                "run": conf[key]}, key
+            else:
+                assert conf[key] == published[key], (c["name"], key)
+        assert set(conf["changed"]) == set(c["reduced"])
 
 
 def test_every_cell_and_metric_finds_its_files():

@@ -1,9 +1,15 @@
 """The FLOPs and bytes functions against hand counts for the three
 configurations the benchmark runs."""
 
+import json
+import os
+
 import pytest
 
 from benchmark.lib import flops, model
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 # one Mistral-7B layer, by hand: q 4096x4096, k and v 4096x1024 each,
 # o 4096x4096, three GLU matrices 4096x14336
@@ -72,15 +78,56 @@ def test_unknown_device_kind_is_an_error():
         flops.peaks("TPU v9 imaginary")
 
 
-def test_the_files_keys_are_the_models_the_program_builds():
+def configs():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+        return [c["name"] for c in json.load(fh)["configs"]]
+
+
+@pytest.mark.parametrize("name", configs())
+def test_the_files_keys_are_the_models_the_program_builds(name):
     from deepspeed_tpu.models.mistral import mistral_config
     import dataclasses
-    for name, layers in [("mistral7b-l4-train", 4),
-                         ("mistral7b-l12-serve", 12),
-                         ("mistral7b-l16-train-zero3", 16)]:
-        conf = model.load_config(name)
-        built = dataclasses.asdict(model.build_model(conf))
-        preset = dataclasses.asdict(mistral_config(
-            "7b", num_layers=layers,
-            max_seq_len=conf["max_position_embeddings"]))
-        assert built == preset
+    conf = model.load_config(name)
+    built = model.build_model(conf)             # the width check, whole
+    if conf["model_type"] == "mistral":
+        preset = mistral_config(
+            "7b", num_layers=conf["num_hidden_layers"],
+            max_seq_len=conf["max_position_embeddings"])
+        assert dataclasses.asdict(built) == dataclasses.asdict(preset)
+    # the architecture's own count, from the FILE's widths, is what `mfu`
+    # multiplies; the dense reference answers with the dense count
+    reference = model.load_reference(conf)
+    widths = reference.Widths.from_hf(model.published_keys(conf))
+    count = reference.matmul_params_per_token(widths)
+    assert 0 < count <= built.num_active_params()
+    if "reference" not in conf:
+        assert count == flops.matmul_params(built)
+        assert flops.train_flops_per_token(built, 4096, count) == \
+            flops.train_flops_per_token(built, 4096)
+
+
+def test_the_program_is_held_to_the_files_expert_keys():
+    mixtral = {"model_type": "mixtral", "hidden_size": 256,
+               "intermediate_size": 512, "num_attention_heads": 2,
+               "num_key_value_heads": 1, "num_hidden_layers": 2,
+               "vocab_size": 512, "num_local_experts": 8,
+               "num_experts_per_tok": 2, "layer_types": ["full_attention"],
+               "stands_for": "a test", "rehearsal": {"num_local_experts": 4}}
+    # lists reach the program's reader, bookkeeping keys do not
+    assert model.published_keys(mixtral)["layer_types"] == ["full_attention"]
+    assert "stands_for" not in model.published_keys(mixtral)
+    assert model.build_model(mixtral).num_experts == 8
+    # a file's `rehearsal` block shrinks what the dense table does not know
+    assert model.build_model(mixtral, rehearse=True).num_experts == 4
+    # a reader that drops the experts (here: the dense family's) is refused
+    with pytest.raises(ValueError, match="num_local_experts"):
+        model.build_model(dict(mixtral, model_type="mistral"))
+    # experts with a width of their own: the dense width is of no layer
+    shared = {"model_type": "qwen2_moe", "hidden_size": 256,
+              "intermediate_size": 1024, "moe_intermediate_size": 128,
+              "shared_expert_intermediate_size": 512,
+              "num_attention_heads": 2, "num_key_value_heads": 1,
+              "num_hidden_layers": 2, "vocab_size": 512, "num_experts": 4,
+              "num_experts_per_tok": 2}
+    built = model.build_model(shared)
+    assert (built.intermediate_size, built.shared_expert_size) == (128, 512)
