@@ -34,6 +34,7 @@ from deepspeed_tpu.models.transformer import (DecoderConfig, _mlp, _norm,
                                               init_params,
                                               lm_logits, qkv_project,
                                               rope_table)
+from deepspeed_tpu.models import typed_layers as tl
 from deepspeed_tpu.ops import paged_attention as pa
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -87,6 +88,10 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     """
     if fresh_prefill is True:   # pre-three-mode boolean API
         fresh_prefill = "fresh"
+    if cfg.typed:
+        return _ragged_forward_typed(cfg, params, arena, tokens, counts,
+                                     starts, page_table, use_pallas,
+                                     moe_fn, fresh_prefill)
     if cfg.pos_emb == "alibi":
         # the paged kernels have no score-bias port; serving BLOOM-class
         # models needs the v1 cached engine (forward_with_cache applies
@@ -200,6 +205,105 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     return logits, {"k": ak, "v": av}
 
 
+def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
+                          tokens: jax.Array, counts: jax.Array,
+                          starts: jax.Array, page_table: jax.Array,
+                          use_pallas: bool, moe_fn, fresh_prefill):
+    """:func:`ragged_forward` for a typed layer stack (models/
+    typed_layers.py has the equations): the same three modes over the
+    same page table, the layer loop unrolled over the list of layers.
+
+    The arena is a flat dict with a token-major pool per attention kind
+    and per K/V (``pa.init_arena_typed``; its scatter writes rows in place,
+    so no step relays a pool): a layer reads and writes its kind's pools
+    at the offset of its index AMONG THE LAYERS OF ITS KIND. A window
+    layer keeps its whole history in its pages, and READS only the pages
+    its window touches (the XLA forms gather that page range; the paged
+    kernel starts its walk there); its learned sink joins the softmax at
+    the merge of the history and chunk partials, or over the one softmax
+    of a fresh or decode step. Where the K pool is wider than the heads
+    (a head of 192 padded to 256 lanes for the kernel) q and k are
+    zero-padded to it, and the scores keep the true width's scale.
+
+    On the chip: the split step's history attention is the paged kernel
+    (``paged_attn_lse``) for both kinds; the chunk's own attention, the
+    fresh step's, and the decode step's paged read are the XLA forms (the
+    flash kernels take one head width for Q, K and V; the decode read of a
+    window layer is two pages a row)."""
+    n, c = tokens.shape
+    positions = starts[:, None] + jnp.broadcast_to(
+        jnp.arange(c, dtype=jnp.int32)[None], (n, c))
+    x, dtype = tl.residual_stream(      # float32, whatever the weights'
+        embed_tokens(cfg, params["embed"], tokens, positions))
+    tables = tl.rope_tables(cfg, positions)
+    valid = jnp.arange(c, dtype=jnp.int32)[None] < counts[:, None]
+    split = fresh_prefill == "split" and c > 1
+    scale = cfg.head_dim ** -0.5
+    pools = dict(arena)
+    of_kind = {a: sum(1 for b in cfg.layer_kinds if b == a)
+               for a in set(cfg.layer_kinds)}
+    seen = dict.fromkeys(of_kind, 0)
+    written = []          # split: the chunk's k, v wait for the loop's end
+    for kind, lp in zip(cfg.layer_kinds, params["layers"]):
+        kname, vname = pa.KIND_POOLS[kind]
+        stride = pools[kname].shape[0] // of_kind[kind]   # num_blocks + 1
+        off = seen[kind] * stride
+        seen[kind] += 1
+        pt_l = page_table + off       # padded entries → this layer's trash
+        window, sink = cfg.kind_window(kind), lp["attn"].get("sink")
+        h_in = _norm(cfg, lp["ln1"], x).astype(dtype)
+        q, k, v = tl.typed_qkv(cfg, kind, lp["attn"], h_in, *tables[kind])
+        pad = pools[kname].shape[-1] // k.shape[2] - cfg.head_dim
+        if pad:
+            q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, pad),)) for a in (q, k))
+        if split:
+            with jax.named_scope("attn_history"):
+                if use_pallas:
+                    out_h, lse_h = pa.paged_attention_with_lse(
+                        q, pools[kname], pools[vname], pt_l, starts,
+                        jnp.zeros_like(starts), window=window, scale=scale,
+                        token_major=True)
+                else:
+                    out_h, lse_h = pa.paged_attention_hist_xla(
+                        q, pools[kname], pools[vname], pt_l, starts,
+                        window=window, scale=scale, token_major=True)
+            with jax.named_scope("attn_core"):
+                out_c, lse_c = pa.causal_attention_with_lse(
+                    q, k, v, window=window, scale=scale)
+            with jax.named_scope("attn_merge"):
+                out = pa.merge_attention(out_h, lse_h, out_c, lse_c,
+                                         sink).astype(q.dtype)
+            written.append((kname, vname, k, v, pt_l, off + stride - 1))
+        else:
+            with jax.named_scope("kv_write"):
+                pools[kname], pools[vname] = pa.write_kv(
+                    pools[kname], pools[vname], k, v, pt_l, starts, counts,
+                    trash_block=off + stride - 1, token_major=True)
+            with jax.named_scope("attn_core"):
+                if fresh_prefill == "fresh":
+                    out, lse = pa.causal_attention_with_lse(
+                        q, k, v, window=window, scale=scale)
+                else:
+                    out, lse = pa.paged_attention_xla(
+                        q, pools[kname], pools[vname], pt_l, starts, counts,
+                        window=window, scale=scale, with_lse=True,
+                        token_major=True)
+                out = tl.apply_sink(out, lse, sink)
+        x = x + tl.typed_attn_out(cfg, lp["attn"], out)
+        x = x + tl.typed_ffn(cfg, lp, _norm(cfg, lp["ln2"], x), moe_fn,
+                             valid, dtype)
+    for kname, vname, k, v, pt_l, trash in written:
+        with jax.named_scope("kv_write"):
+            pools[kname], pools[vname] = pa.write_kv(
+                pools[kname], pools[vname], k, v, pt_l, starts, counts,
+                trash_block=trash, token_major=True)
+    x = _norm(cfg, params["final_norm"], x).astype(dtype)
+    with jax.named_scope("lm_head"):       # the rows the head projects
+        last = jnp.maximum(counts - 1, 0)
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
+    return lm_logits(cfg, params, x_last)[:, 0], pools
+
+
 def _bucket(n: int) -> int:
     b = 1
     while b < n:
@@ -292,7 +396,12 @@ class RaggedInferenceEngineTPU:
                 f"causal={model.causal}, layer_window_pattern="
                 f"{model.layer_window_pattern}); use InferenceEngineTPU "
                 "for GPT-Neo-class models")
-        if model.sliding_window is not None and \
+        if model.typed and config.weight_quant:
+            raise NotImplementedError(
+                "weight_quant on a typed layer stack (DecoderConfig."
+                "layer_kinds) is not built: the quantized-tree walkers "
+                "read the stacked layer tree")
+        if model.sliding_window is not None and not model.typed and \
                 config.max_seq_len > model.sliding_window:
             # the paged kernels attend the full page table; beyond the
             # window that silently diverges from the training forward
@@ -300,7 +409,8 @@ class RaggedInferenceEngineTPU:
                 f"ragged/paged inference has no sliding-window mask: "
                 f"max_seq_len {config.max_seq_len} exceeds sliding_window "
                 f"{model.sliding_window}; cap max_seq_len at the window "
-                f"or use InferenceEngineTPU")
+                f"or use InferenceEngineTPU (a typed layer stack, "
+                f"DecoderConfig.layer_kinds, has the mask per layer)")
         self.model_config = model
         self.config = config
         from deepspeed_tpu.ops.quantized_linear import validate_weight_quant
@@ -326,11 +436,18 @@ class RaggedInferenceEngineTPU:
                     "InferenceEngineTPU for TP serving.")
         self.dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
                       "float16": jnp.float16}[config.dtype]
+        # a typed stack's K heads are zero-padded to whole 128-lane tiles
+        # for the paged kernel (192 -> 256); the uniform stack's are as wide
+        # as the kernel takes them, or it is refused
+        lanes = -(-model.head_dim // 128) * 128 if model.typed \
+            else model.head_dim
         if config.use_pallas is None:
-            self.use_pallas = pa.supported(model.head_dim,
-                                           config.block_size)
+            self.use_pallas = pa.supported(lanes, config.block_size) and \
+                model.v_dim % 128 == 0
         else:
             self.use_pallas = bool(config.use_pallas)
+        #: width of the K pool: the heads', or the padded lanes
+        self.k_width = lanes if self.use_pallas else model.head_dim
 
         self.state = DSStateManager(max_sequences=config.max_sequences,
                                     num_blocks=config.num_blocks,
@@ -386,11 +503,23 @@ class RaggedInferenceEngineTPU:
             if config.weight_quant:
                 self.params = quantize_param_tree(self.params,
                                                   mode=config.weight_quant)
-        self.arena = pa.init_arena(model.num_layers, model.kv_heads,
-                                   config.num_blocks, config.block_size,
-                                   model.head_dim, self.dtype)
+        if model.typed:
+            # a pool per attention kind and per K/V, one page table
+            self.arena = pa.init_arena_typed(
+                model.layer_kinds,
+                {a: model.kind_kv_heads(a) for a in set(model.layer_kinds)},
+                config.num_blocks, config.block_size, self.k_width,
+                model.v_dim, self.dtype)
+        else:
+            self.arena = pa.init_arena(model.num_layers, model.kv_heads,
+                                       config.num_blocks, config.block_size,
+                                       model.head_dim, self.dtype)
         moe_fn = None
-        if model.num_experts:
+        if model.typed:
+            # routes over every expert, computes the held ones' part
+            from deepspeed_tpu.parallel.moe import held_experts_moe_layer
+            moe_fn = held_experts_moe_layer
+        elif model.num_experts:
             from deepspeed_tpu.parallel.moe import serving_moe_fn
             from deepspeed_tpu.parallel.mesh import get_mesh, has_mesh
             # same EP guard as the v1 engine: an ambient expert axis > 1
@@ -668,7 +797,10 @@ class RaggedInferenceEngineTPU:
         lives outside the descriptor.
         """
         n = len(batch.uids)
-        if n == 0 or batch.token_ids.shape[1] != 1:
+        if n == 0 or batch.token_ids.shape[1] != 1 or \
+                self.model_config.typed:
+            # (a typed layer stack has no fused decode loop yet: its
+            # decode-only selections take the stepwise program)
             return None
         for i, uid in enumerate(batch.uids):
             if int(batch.token_counts[i]) != 1 or \
@@ -788,7 +920,8 @@ class RaggedInferenceEngineTPU:
         if self._copy_pages_fn is None:
             self._copy_pages_fn = jax.jit(
                 partial(pa.copy_pages,
-                        num_layers=self.model_config.num_layers),
+                        stride=self.config.num_blocks + 1,
+                        token_major=self.model_config.typed),
                 donate_argnums=(0,))
         self.arena = self._copy_pages_fn(
             self.arena, jnp.asarray([src_block], jnp.int32),
@@ -806,6 +939,7 @@ class RaggedInferenceEngineTPU:
         ``{"k", "v"}`` as ``[kvh, L, m, bs, dh]`` host arrays — the
         importing engine must have identical model geometry (it checks).
         """
+        self._refuse_typed("export_pages (KV tiering / page handoff)")
         L = self.model_config.num_layers
         stride = self.arena["k"].shape[1] // L          # nb + 1
         ids = np.asarray(blocks, np.int32)
@@ -825,6 +959,7 @@ class RaggedInferenceEngineTPU:
         ``blocks`` — the adoption half of page handoff. Raises
         ``ValueError`` on a geometry mismatch rather than silently
         writing garbage KV."""
+        self._refuse_typed("import_pages (KV tiering / page handoff)")
         L = self.model_config.num_layers
         stride = self.arena["k"].shape[1] // L
         ids = np.asarray(blocks, np.int32)
@@ -847,12 +982,18 @@ class RaggedInferenceEngineTPU:
         """Host-side bytes of ONE exported KV page (all layers, k + v) —
         what a tier/handoff consumer budgets per page (the uncompressed
         ``export_pages`` payload size for a single block)."""
-        L = self.model_config.num_layers
-        total = 0
-        for key in ("k", "v"):
-            kvh, _, bs, dh = self.arena[key].shape
-            total += kvh * L * bs * dh * self.arena[key].dtype.itemsize
-        return total
+        stride = self.config.num_blocks + 1
+        axis = 0 if self.model_config.typed else 1      # the pages' axis
+        return sum(a.nbytes // a.shape[axis] * (a.shape[axis] // stride)
+                   for a in self.arena.values())
+
+    def _refuse_typed(self, what: str) -> None:
+        if self.model_config.typed:
+            raise NotImplementedError(
+                f"{what} is not built for a typed layer stack "
+                f"(DecoderConfig.layer_kinds): its arena is a pool per "
+                f"attention kind, and the page bundle's layout is one "
+                f"pool's")
 
     def _buckets(self, batch: RaggedBatch):
         nb = _bucket(len(batch.uids))
@@ -894,7 +1035,8 @@ class RaggedInferenceEngineTPU:
             _step_kind(cb, fresh), n, nb, cb, self.mb,
             int(batch.token_counts.sum()),
             int((batch.start_positions + batch.token_counts).sum()),
-            context_slots=context_slots)
+            context_slots=context_slots,
+            kv_window=self._kv_window_tokens(batch))
         with tracer.span("serving/dispatch",
                          **(work if tracer.enabled else {})):
             out, self._rng_dev, self.arena = self._step_fn(
@@ -903,11 +1045,27 @@ class RaggedInferenceEngineTPU:
         with tracer.span("serving/fetch"):           # waits for the device
             return np.asarray(jax.device_get(out))[:n]
 
+    def _kv_window_tokens(self, batch: RaggedBatch):
+        """(live, held) tokens of the batch's rows in ONE window layer
+        after this step, or None where the model has no window kind. Held:
+        every token of the row (a window layer keeps its whole history in
+        its pages); live: those some query of this step can still see,
+        ``min(held, window + fed - 1)`` a row. Host arithmetic on the
+        batch's lengths: what a later PR that frees pages behind the
+        window would free is held - live."""
+        model = self.model_config
+        if not model.typed or 1 not in model.layer_kinds:
+            return None
+        held = batch.start_positions + batch.token_counts
+        live = np.minimum(held, model.sliding_window +
+                          batch.token_counts - 1)
+        return int(live.sum()), int(held.sum())
+
     def _count_dispatch(self, program: str, rows: int, nb: int, chunk: int,
                         page_width: int, tokens: int, context_tokens: int,
                         scan_steps: int = 1,
-                        context_slots: Optional[int] = None
-                        ) -> Dict[str, Any]:
+                        context_slots: Optional[int] = None,
+                        kv_window=None) -> Dict[str, Any]:
         """Count one device program launch where its batch is packed: the
         useful work (``tokens`` fed, ``context_tokens`` of live KV they
         attend) against the work attempted (``slots`` = bucketed rows x
@@ -916,7 +1074,12 @@ class RaggedInferenceEngineTPU:
         better, as for a split step whose history goes through the paged
         kernel; both times the scan steps of a megastep).
         Always-on ``dispatch/*`` counters; the same numbers are the
-        ``serving/dispatch`` span's arguments."""
+        ``serving/dispatch`` span's arguments. ``kv_window`` (a model with
+        window layers only: :meth:`_kv_window_tokens`) adds
+        ``dispatch/kv_window_live_tokens`` / ``..._held_tokens`` and the
+        span's ``kv_tokens_full`` (what a full layer holds for the rows:
+        ``context_tokens``), ``kv_tokens_window_live`` and
+        ``kv_tokens_window_held``."""
         from deepspeed_tpu.telemetry.registry import registry
         slots = nb * chunk * scan_steps
         if context_slots is None:
@@ -929,10 +1092,18 @@ class RaggedInferenceEngineTPU:
                          ("context_slots", context_slots),
                          (f"steps.{program}", 1)):
             registry.counter("dispatch/" + name).inc(by)
-        return {"program": program, "rows": rows, "rows_bucket": nb,
+        work = {"program": program, "rows": rows, "rows_bucket": nb,
                 "chunk": chunk, "tokens": tokens, "slots": slots,
                 "context_tokens": context_tokens,
                 "context_slots": context_slots}
+        if kv_window is not None:
+            live, held = kv_window
+            registry.counter("dispatch/kv_window_live_tokens").inc(live)
+            registry.counter("dispatch/kv_window_held_tokens").inc(held)
+            work.update(kv_tokens_full=context_tokens,
+                        kv_tokens_window_live=live,
+                        kv_tokens_window_held=held)
+        return work
 
     # -- fused decode loop (generate fast path) ----------------------------
 
@@ -1210,6 +1381,11 @@ class RaggedInferenceEngineTPU:
         n = len(uids)
         if n == 0:
             raise FusedDecodeUnavailable("empty batch")
+        if self.model_config.typed:
+            # generate() / serve() fall back to the stepwise loop
+            raise FusedDecodeUnavailable(
+                "the fused decode loop is not built for a typed layer "
+                "stack (DecoderConfig.layer_kinds)")
         nb = _bucket(n)
         bs = self.state.allocator.block_size
         # per-row effective window: a row never runs past its own budget,

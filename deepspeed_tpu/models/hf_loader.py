@@ -33,7 +33,7 @@ Params = Any
 _FAMILIES = ("llama", "mistral", "mixtral", "qwen", "qwen2", "qwen2_moe",
               "gpt_neox", "gemma", "gpt2", "opt", "bloom", "falcon",
               "phi", "phi3", "gpt_bigcode", "gptj", "bert", "distilbert",
-              "gpt_neo", "internlm")
+              "gpt_neo", "internlm", "mimo_v2")
 
 
 def _map_hf_act(act: str) -> str:
@@ -53,6 +53,8 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
     if mt not in _FAMILIES:
         raise ValueError(f"unsupported model_type '{mt}'; "
                          f"supported: {_FAMILIES}")
+    if mt == "mimo_v2":
+        return _mimo_v2_config(hf)
     if mt == "bert":
         return DecoderConfig(
             hidden_size=hf["hidden_size"],
@@ -362,6 +364,71 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
     return DecoderConfig(**kw)
 
 
+def _mimo_v2_config(hf: Dict[str, Any]) -> DecoderConfig:
+    """MiMo-V2 / V2.5's language model (``modeling_mimo_v2``): a typed
+    layer stack (models/typed_layers.py has the equations).
+    ``hybrid_layer_pattern`` (0 full, 1 window) and ``moe_layer_freq``
+    (0 dense, 1 sparse) may be longer than ``num_hidden_layers`` (a
+    depth-cut file keeps the published lists): the first
+    ``num_hidden_layers`` entries are read. The plain keys describe the
+    full kind, the ``swa_*`` keys the window kind. ``expert_share``
+    (not a published key: ``{"router_experts", "first_expert"}``) says the
+    file's ``n_routed_experts`` experts are ONE chip's share of an
+    expert-parallel layer whose router is ``router_experts`` wide. Not
+    built: the vision / audio towers and the multi-token-prediction
+    layers (no key of the language model's config names them)."""
+    L = int(hf["num_hidden_layers"])
+    heads, dk = int(hf["num_attention_heads"]), int(hf["head_dim"])
+    for key, want in (("swa_num_attention_heads", heads),
+                      ("swa_head_dim", dk),
+                      ("swa_v_head_dim", hf.get("v_head_dim", dk)),
+                      ("sliding_window_size", hf.get("sliding_window")),
+                      ("scoring_func", "sigmoid"),
+                      ("topk_method", "noaux_tc"), ("n_group", 1),
+                      ("topk_group", 1), ("hidden_act", "silu")):
+        if key in hf and hf[key] != want:
+            raise ValueError(f"mimo_v2: {key}={hf[key]!r} is not built "
+                             f"(expected {want!r})")
+    for key in ("n_shared_experts", "routed_scaling_factor",
+                "hybrid_block_size", "add_full_attention_sink_bias",
+                "attention_bias"):
+        if hf.get(key):
+            raise ValueError(f"mimo_v2: {key}={hf[key]!r} is not built")
+    scaling = hf.get("rope_scaling") or {}
+    if scaling.get("rope_type", scaling.get("type", "default")) != "default":
+        raise ValueError(f"mimo_v2: rope_scaling {scaling!r} is not built")
+    held = int(hf["n_routed_experts"])
+    share = hf.get("expert_share")
+    return DecoderConfig(
+        hidden_size=hf["hidden_size"], num_layers=L, num_heads=heads,
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim_override=dk, v_head_dim=int(hf.get("v_head_dim", dk)),
+        value_scale=float(hf.get("attention_value_scale") or 1.0),
+        intermediate_size=hf["moe_intermediate_size"],
+        dense_intermediate_size=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_seq_len=hf.get("max_position_embeddings", 4096),
+        norm="rmsnorm", activation="silu_glu", pos_emb="rope",
+        norm_eps=float(hf.get("layernorm_epsilon", 1e-5)),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rotary_pct=float(hf.get("partial_rotary_factor", 1.0)),
+        use_bias=False,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        layer_kinds=tuple(int(a) for a in hf["hybrid_layer_pattern"][:L]),
+        layer_sparse=tuple(int(a) for a in hf["moe_layer_freq"][:L]),
+        sliding_window=int(hf["sliding_window"]),
+        window_kv_heads=int(hf.get("swa_num_key_value_heads",
+                                   hf["num_key_value_heads"])),
+        window_rope_theta=float(hf.get("swa_rope_theta",
+                                       hf.get("rope_theta", 10000.0))),
+        window_sink=bool(hf.get("add_swa_attention_sink_bias", False)),
+        num_experts=int(share["router_experts"]) if share else held,
+        experts_held=(int(share["first_expert"]), held) if share else None,
+        num_experts_per_tok=int(hf["num_experts_per_tok"]),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        router_scoring="sigmoid", router_select_bias=True)
+
+
 def _is_gemma_layout(cfg: DecoderConfig) -> bool:
     return cfg.activation == "gelu_glu" and cfg.scale_embeddings
 
@@ -399,6 +466,9 @@ def config_to_hf(cfg: DecoderConfig) -> Dict[str, Any]:
             return "relu"
         return exact_name if cfg.activation == "gelu_exact" else tanh_name
 
+    if cfg.typed:
+        raise NotImplementedError(
+            "config_to_hf: a typed layer stack (mimo_v2) has no exporter")
     if not cfg.causal or not cfg.prenorm:
         # encoder layouts (BERT/DistilBERT): both flags flip together
         if cfg.causal or cfg.prenorm or cfg.pos_emb != "learned" \
@@ -730,6 +800,11 @@ def params_from_state(cfg: DecoderConfig, hf_cfg: Dict[str, Any], get, names,
     """
     L = cfg.num_layers
     mt = hf_cfg.get("model_type")
+    if cfg.typed:
+        raise NotImplementedError(
+            f"loading {mt!r} weights into a typed layer stack is not "
+            f"built (the published tensor names were not at hand): the "
+            f"configuration is read, the weights are random from a seed")
     if mt == "bert":
         return _load_bert(cfg, get, names, dtype)
     if mt == "distilbert":

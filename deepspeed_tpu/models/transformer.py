@@ -149,6 +149,41 @@ class DecoderConfig:
     #: to the dense MLP path only (MoE layers dispatch per token
     #: already); inference paths ignore it (decode is 1 token).
     ffn_chunk: int = 0
+    # -- typed layers (models/typed_layers.py; ROADMAP C2) ------------------
+    #: one entry a layer, 0 = full causal attention, 1 = window attention
+    #: (MiMo-V2 ``hybrid_layer_pattern``). Set → the stack is NOT one
+    #: scanned block: ``params["layers"]`` is a list of per-layer trees
+    #: whose shapes follow the layer's kind, ``sliding_window`` /
+    #: ``window_*`` describe the window kind only, and ``num_kv_heads`` /
+    #: ``rope_theta`` the full kind. None → the uniform stack.
+    layer_kinds: Optional[Tuple[int, ...]] = None
+    #: one entry a layer, 1 = sparse experts, 0 = a dense MLP of
+    #: ``dense_intermediate_size`` (MiMo-V2 ``moe_layer_freq``: leading
+    #: dense layers). None with ``layer_kinds`` set → every layer follows
+    #: ``num_experts``.
+    layer_sparse: Optional[Tuple[int, ...]] = None
+    window_kv_heads: Optional[int] = None      #: None → ``kv_heads``
+    window_rope_theta: Optional[float] = None  #: None → ``rope_theta``
+    #: a learned logit per query head that joins the softmax of a window
+    #: layer as one more column: it takes mass and gives no value
+    window_sink: bool = False
+    #: value heads narrower than query/key heads; None → ``head_dim``
+    v_head_dim: Optional[int] = None
+    #: V = value_scale * (x @ wv)
+    value_scale: float = 1.0
+    #: width of the dense layers of ``layer_sparse``; None → ``ffn_size``
+    dense_intermediate_size: Optional[int] = None
+    #: router scores: 'softmax' over all experts (Mixtral) | 'sigmoid'
+    #: per expert, renormalised over the selected (DeepSeek-V3 / MiMo-V2)
+    router_scoring: str = "softmax"
+    #: a per-expert bias added to the scores for the SELECTION only; the
+    #: weights stay the unbiased scores (``topk_method: noaux_tc``)
+    router_select_bias: bool = False
+    #: expert-parallel share: (first expert held, experts held). The
+    #: router keeps ``num_experts`` outputs and ``num_experts_per_tok``;
+    #: the expert weights hold the share, and the layer computes the part
+    #: of the result its own experts give (parallel/moe.py). None → all.
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         if self.mlm_head and not self.tie_embeddings:
@@ -156,6 +191,46 @@ class DecoderConfig:
             # (HF cls.predictions.decoder); an untied lm_head would make
             # lm_logits and the chunked-CE loss decode different heads
             raise ValueError("mlm_head requires tie_embeddings=True")
+        for name in ("layer_kinds", "layer_sparse"):
+            per_layer = getattr(self, name)
+            if per_layer is not None and len(per_layer) != self.num_layers:
+                raise ValueError(
+                    f"{name} has {len(per_layer)} entries for "
+                    f"{self.num_layers} layers")
+        if self.layer_sparse is not None and self.layer_kinds is None:
+            raise ValueError("layer_sparse needs layer_kinds (the typed "
+                             "stack); the uniform stack is all-dense or "
+                             "all-sparse by num_experts")
+
+    @property
+    def typed(self) -> bool:
+        """The stack is a list of typed layers, not one scanned block."""
+        return self.layer_kinds is not None
+
+    @property
+    def v_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def num_held_experts(self) -> int:
+        return self.experts_held[1] if self.experts_held else \
+            self.num_experts
+
+    def kind_kv_heads(self, kind: int) -> int:
+        return (self.window_kv_heads or self.kv_heads) if kind \
+            else self.kv_heads
+
+    def kind_rope_theta(self, kind: int) -> float:
+        return (self.window_rope_theta or self.rope_theta) if kind \
+            else self.rope_theta
+
+    def kind_window(self, kind: int) -> Optional[int]:
+        return self.sliding_window if kind else None
+
+    def layer_is_sparse(self, layer: int) -> bool:
+        if self.layer_sparse is not None:
+            return bool(self.layer_sparse[layer])
+        return bool(self.num_experts)
 
     @property
     def kv_heads(self) -> int:
@@ -735,7 +810,11 @@ def block_combine(cfg: DecoderConfig, p: Params, x: jax.Array,
 
 def init_params(cfg: DecoderConfig, rng: jax.Array,
                 dtype=jnp.float32) -> Params:
-    """Initialize the full parameter pytree (stacked layers)."""
+    """Initialize the full parameter pytree (stacked layers; a typed
+    stack's list of layers is ``typed_layers.init_typed_params``)."""
+    if cfg.typed:
+        from deepspeed_tpu.models.typed_layers import init_typed_params
+        return init_typed_params(cfg, rng, dtype)
     d, v, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
     h = cfg.ffn_size
     kd = cfg.kv_heads * cfg.head_dim
@@ -878,6 +957,19 @@ def forward_hidden(cfg: DecoderConfig, params: Params, tokens: jax.Array,
     ``key_mask`` (the masked/chunked paths do; Pallas flash is
     causal-only and never selected for encoders).
     """
+    if cfg.typed:
+        # a list of typed layers, unrolled (models/typed_layers.py): its
+        # attention is its own (window, sink, unequal K/V widths), it has
+        # no balance loss, and it is not trained yet
+        if attn_fn is not None or remat_policy or layer_loop is not None \
+                or attention_mask is not None or token_type_ids is not None:
+            raise NotImplementedError(
+                "a typed layer stack (DecoderConfig.layer_kinds) takes no "
+                "attn_fn / remat_policy / layer_loop / attention_mask: it "
+                "is a serving model (models/typed_layers.py)")
+        from deepspeed_tpu.models.typed_layers import forward_hidden_typed
+        return forward_hidden_typed(cfg, params, tokens, moe_fn,
+                                    positions), jnp.zeros((), jnp.float32)
     if attn_fn is None:
         attn_fn = default_attention(cfg)
     if attention_mask is not None:
@@ -1186,6 +1278,11 @@ def forward_with_cache(cfg: DecoderConfig, params: Params, tokens: jax.Array,
     """tokens: [B, t] (prefill t>1 or decode t==1) → (logits of the LAST
     position [B, V] fp32, updated cache). cache_len: tokens already held.
     """
+    if cfg.typed:
+        raise NotImplementedError(
+            "forward_with_cache (the v1 contiguous KV cache) has no typed "
+            "layer stack (DecoderConfig.layer_kinds): serve it with "
+            "RaggedInferenceEngineTPU")
     b, t = tokens.shape
     positions = cache_len + jnp.broadcast_to(
         jnp.arange(t, dtype=jnp.int32)[None], (b, t))
@@ -1235,6 +1332,11 @@ def partition_specs(cfg: DecoderConfig, zero_stage: int = 0,
     over ('data','expert') so FSDP and TP compose. Stages 0-2 leave params
     replicated (grads/opt-state sharding is handled by the engine).
     """
+    if cfg.typed:
+        raise NotImplementedError(
+            "partition_specs: a typed layer stack (DecoderConfig."
+            "layer_kinds; MiMo-V2) is served on one shard and not trained "
+            "yet — no sharding plan exists for its list of layers")
     # MiCS (reference runtime/zero/mics.py:63): param shards live within
     # the (data_inner, expert) sub-group and replicate across 'data', so
     # stage-3 allgathers stay inside the cheap sub-group links

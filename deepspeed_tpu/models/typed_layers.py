@@ -1,0 +1,187 @@
+"""Typed layer stacks: a decoder whose layers are NOT one scanned block.
+
+``DecoderConfig.layer_kinds`` names each layer's attention kind (0 = full
+causal, 1 = window) and ``layer_sparse`` whether its feed-forward is
+sparse experts or a dense MLP (leading dense layers). The kinds differ in
+SHAPE — KV heads, rotary base, a learned sink on the window kind, the dense
+width — so the layers cannot share one stacked tree: ``params["layers"]``
+is a list of per-layer trees and the layer loop is unrolled. The first
+family built this way is MiMo-V2 (``hf_loader``: ``mimo_v2``); the
+equations, for layer ``l`` of kind ``a``:
+
+- ``h = norm(x)``; ``q = h·Wq → [T, H, Dk]``, ``k = h·Wk → [T, KV_a, Dk]``,
+  ``v = value_scale · (h·Wv) → [T, KV_a, Dv]``; rotate-half RoPE with base
+  ``θ_a`` on the first ``rope_dim`` dims of every q and k head;
+- scores ``q_i·k_j / √Dk`` for ``0 ≤ i − j`` and, on window layers,
+  ``i − j < sliding_window``; a window layer's learned ``sink[h]`` joins
+  the softmax as one more column that takes mass and gives no value:
+  ``p_ij = exp(s_ij) / (exp(sink_h) + Σ_j' exp(s_ij'))``;
+- ``x ← x + o·Wo``; ``x ← x + ffn(norm(x))``, a SiLU-GLU of
+  ``dense_intermediate_size`` or the experts (``parallel/moe.py``).
+
+**The residual stream is float32** whatever the parameters' dtype
+(:func:`residual_stream`): the matmuls take the norms' outputs cast to the
+compute dtype, their results are added in float32, and the router reads
+its norm's float32 output. A top-8-of-256 selection flips when upstream
+rounding moves a router logit past its neighbour: on the v5e the program's
+eight differed from the float32 reference's in 11.6% of (token, layer)
+selections with a bf16 stream and in 10.5-10.8% with this one (the bf16
+matmuls' own rounding, 0.3-0.5% of every increment, is most of it: inherent
+to bf16 serving: rounding K and V into a bf16 cache alone flips 4-6% in a
+CPU replica at these widths), and the float32 stream was also the faster
+(PERF.md, PR 31). The benchmark's reference therefore judges the tokens
+whose routing its own margins decide (``benchmark/reference/
+mimo_v2_decoder.py``).
+
+This module is the uncached forward (``transformer.forward`` routes here)
+and the pieces the paged engine shares with it (``engine_v2``)."""
+
+import dataclasses
+import math
+from typing import Any, Callable, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models import transformer as tf
+from deepspeed_tpu.ops import paged_attention as pa
+
+
+def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
+    """The parameter tree of a typed stack: ``embed``, ``layers`` (a LIST,
+    one tree a layer: ``ln1``, ``attn`` {wq, wk, wv, wo, sink?}, ``ln2``,
+    and ``mlp`` {wg, wi, wo} or ``moe`` {router, router_bias?, wg, wi,
+    wo over the HELD experts}), ``final_norm``, ``lm_head``."""
+    if cfg.norm != "rmsnorm" or not cfg.is_glu or cfg.use_bias or \
+            cfg.pos_emb != "rope" or cfg.tie_embeddings:
+        raise NotImplementedError(
+            "typed layer stacks are built for bias-free RMSNorm / RoPE / "
+            "GLU decoders with an untied head (MiMo-V2)")
+    d, v, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
+    dk, dv, H = cfg.head_dim, cfg.v_dim, cfg.num_heads
+    out_std = cfg.init_std / math.sqrt(2 * L)
+    keys = iter(jax.random.split(rng, 10 * L + 2))
+
+    def w(shape, std=cfg.init_std):
+        return (jax.random.normal(next(keys), shape, jnp.float32) * std
+                ).astype(dtype)
+
+    layers = []
+    for l, kind in enumerate(cfg.layer_kinds):
+        kvh = cfg.kind_kv_heads(kind)
+        attn = {"wq": w((d, H * dk)), "wk": w((d, kvh * dk)),
+                "wv": w((d, kvh * dv)), "wo": w((H * dv, d), out_std)}
+        if kind and cfg.window_sink:
+            # not zero at init: a zero sink would make a test of it vacuous
+            attn["sink"] = w((H,), 1.0)
+        lp = {"ln1": tf._norm_params(cfg), "attn": attn,
+              "ln2": tf._norm_params(cfg)}
+        if cfg.layer_is_sparse(l):
+            E, held, f = cfg.num_experts, cfg.num_held_experts, cfg.ffn_size
+            moe = {"router": w((d, E)), "wg": w((held, d, f)),
+                   "wi": w((held, d, f)), "wo": w((held, f, d), out_std)}
+            if cfg.router_select_bias:
+                moe["router_bias"] = jnp.zeros((E,), dtype)
+            lp["moe"] = moe
+        else:
+            f = cfg.dense_intermediate_size or cfg.ffn_size
+            lp["mlp"] = {"wg": w((d, f)), "wi": w((d, f)),
+                         "wo": w((f, d), out_std)}
+        layers.append(lp)
+    return {"embed": {"tokens": w((v, d))}, "layers": layers,
+            "final_norm": tf._norm_params(cfg), "lm_head": w((d, v))}
+
+
+def rope_tables(cfg, positions: jax.Array) -> dict:
+    """{kind: (sin, cos)} for the kinds the stack has: one table a rotary
+    base, computed once a step."""
+    return {kind: tf.rope_table(
+        dataclasses.replace(cfg, rope_theta=cfg.kind_rope_theta(kind)),
+        positions) for kind in sorted(set(cfg.layer_kinds))}
+
+
+@jax.named_scope("attn_qkv")
+def typed_qkv(cfg, kind: int, p, x: jax.Array, sin, cos
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x [B, t, D] → q [B, t, H, Dk], k [B, t, KV_kind, Dk],
+    v [B, t, KV_kind, Dv] (scaled), RoPE applied to q and k."""
+    b, t = x.shape[:2]
+    kvh = cfg.kind_kv_heads(kind)
+    q = tf.linear_2d(x, p, "wq").reshape(b, t, cfg.num_heads, cfg.head_dim)
+    k = tf.linear_2d(x, p, "wk").reshape(b, t, kvh, cfg.head_dim)
+    v = tf.linear_2d(x, p, "wv").reshape(b, t, kvh, cfg.v_dim)
+    if cfg.value_scale != 1.0:
+        v = (v * cfg.value_scale).astype(v.dtype)
+    return tf.apply_rope(q, sin, cos), tf.apply_rope(k, sin, cos), v
+
+
+@jax.named_scope("attn_out")
+def typed_attn_out(cfg, p, out: jax.Array) -> jax.Array:
+    b, t = out.shape[:2]
+    return tf.linear_2d(out.reshape(b, t, cfg.num_heads * cfg.v_dim), p,
+                        "wo")
+
+
+def apply_sink(out: jax.Array, lse: jax.Array,
+               sink: Optional[jax.Array]) -> jax.Array:
+    """Let a sink column into a finished softmax: ``out`` [n, c, h, Dv]
+    was normalised by ``exp(lse)`` ([n, c, h] float32); with the sink the
+    denominator is ``exp(lse) + exp(sink_h)``, so the output shrinks by
+    ``sigmoid(lse − sink_h)``. None → unchanged."""
+    if sink is None:
+        return out
+    shrink = jax.nn.sigmoid(lse - sink.astype(jnp.float32))
+    return (out.astype(jnp.float32) * shrink[..., None]).astype(out.dtype)
+
+
+def residual_stream(x: jax.Array) -> Tuple[jax.Array, Any]:
+    """(the embedding in float32, the compute dtype it came in)."""
+    return x.astype(jnp.float32), x.dtype
+
+
+def typed_ffn(cfg, lp, h: jax.Array, moe_fn: Optional[Callable],
+              valid: Optional[jax.Array] = None, dtype=None) -> jax.Array:
+    """The layer's second half on its normed input ``h`` (float32): the
+    dense SiLU-GLU of a dense layer in the compute ``dtype``, or the
+    experts (``moe_fn(cfg, p, x, valid=)``: the router reads ``h`` as it
+    is, the experts cast it)."""
+    if "moe" not in lp:
+        return tf._mlp(cfg, lp["mlp"], h.astype(dtype or h.dtype))
+    if moe_fn is None:
+        from deepspeed_tpu.parallel.moe import held_experts_moe_layer
+        moe_fn = held_experts_moe_layer
+    with jax.named_scope("moe"):
+        return moe_fn(cfg, lp["moe"], h, valid=valid)[0]
+
+
+def _attention(cfg, kind: int, sink, q, k, v) -> jax.Array:
+    """Uncached attention of one layer: q [B, T, H, Dk], k [B, T, KV, Dk],
+    v [B, T, KV, Dv] → [B, T, H, Dv]; causal, the kind's window, the
+    sink."""
+    out, lse = pa.causal_attention_with_lse(
+        q, k, v, window=cfg.kind_window(kind))
+    return apply_sink(out, lse, sink)
+
+
+def forward_hidden_typed(cfg, params, tokens: jax.Array,
+                         moe_fn: Optional[Callable] = None,
+                         positions: Optional[jax.Array] = None
+                         ) -> jax.Array:
+    """tokens [B, T] → final-norm hidden [B, T, D]; the layer loop
+    unrolled over the list of typed layers."""
+    b, t = tokens.shape
+    if positions is None:
+        positions = jnp.broadcast_to(
+            jnp.arange(t, dtype=jnp.int32)[None], (b, t))
+    x, dtype = residual_stream(
+        tf.embed_tokens(cfg, params["embed"], tokens, positions))
+    tables = rope_tables(cfg, positions)
+    for kind, lp in zip(cfg.layer_kinds, params["layers"]):
+        h = tf._norm(cfg, lp["ln1"], x).astype(dtype)
+        q, k, v = typed_qkv(cfg, kind, lp["attn"], h, *tables[kind])
+        with jax.named_scope("attn_core"):
+            o = _attention(cfg, kind, lp["attn"].get("sink"), q, k, v)
+        x = x + typed_attn_out(cfg, lp["attn"], o)
+        x = x + typed_ffn(cfg, lp, tf._norm(cfg, lp["ln2"], x), moe_fn,
+                          dtype=dtype)
+    return tf._norm(cfg, params["final_norm"], x).astype(dtype)

@@ -14,6 +14,18 @@ entries all point at it, so scatter/gather stay branch-free and
 static-shape. Block size and head_dim are chosen to satisfy the (8, 128)
 tile rule on the last two dims.
 
+K and V pools may differ in width (``head_dim`` of K, of V). A model whose
+layers are of two attention kinds (full and window: different KV head
+counts) has a pool per kind and per K/V (:func:`init_arena_typed`), TOKEN-
+MAJOR: ``[blocks, block_size, kv_heads * head_dim]``, a token's heads side
+by side on the lanes — the layout a row scatter writes in place, so no
+step relays the pool for its write (``token_major=True`` on the functions
+below; the kernel reads a head's page as a lane slice, which is why the
+heads share the LAST axis: ``[.., kv_heads, head_dim]`` would tile heads
+over sublanes). The page table stays one per
+sequence. A window layer keeps its whole history in its pages; its readers
+visit only the pages the window touches.
+
 Two implementations with identical semantics (tested against each other):
 
 - :func:`paged_attention_xla` — gather + masked softmax in pure XLA.
@@ -56,6 +68,30 @@ def init_arena(num_layers: int, kv_heads: int, num_blocks: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+#: pool names of a typed arena by attention kind (0 full, 1 window)
+KIND_POOLS = {0: ("k", "v"), 1: ("k_win", "v_win")}
+
+
+def init_arena_typed(layer_kinds, kv_heads_by_kind: dict, num_blocks: int,
+                     block_size: int, k_width: int, v_width: int,
+                     dtype=jnp.bfloat16) -> dict:
+    """The arena of a typed layer stack: a FLAT dict of pools, one per
+    attention kind present and per K/V (``KIND_POOLS``), each TOKEN-MAJOR
+    ``[layers of the kind * (num_blocks + 1), bs, kv_heads of the kind *
+    width]`` — :func:`init_arena`'s flat block numbering within a kind: the
+    i-th layer OF ITS KIND owns pages ``i*(num_blocks+1) + b``. One page
+    table addresses every pool (logical page b is the same tokens in
+    all)."""
+    arena = {}
+    for kind in sorted(set(layer_kinds)):
+        pages = sum(1 for a in layer_kinds if a == kind) * (num_blocks + 1)
+        kname, vname = KIND_POOLS[kind]
+        kvh = kv_heads_by_kind[kind]
+        arena[kname] = jnp.zeros((pages, block_size, kvh * k_width), dtype)
+        arena[vname] = jnp.zeros((pages, block_size, kvh * v_width), dtype)
+    return arena
+
+
 def layer_page_offset(layer: jax.Array, num_blocks: int) -> jax.Array:
     """Absolute block id offset of ``layer``'s region in the flat pool."""
     return layer * (num_blocks + 1)
@@ -63,8 +99,10 @@ def layer_page_offset(layer: jax.Array, num_blocks: int) -> jax.Array:
 
 def write_kv(arena_k: jax.Array, arena_v: jax.Array, k: jax.Array,
              v: jax.Array, page_table: jax.Array, starts: jax.Array,
-             counts: jax.Array, trash_block=None):
-    """Scatter a ragged chunk of new KV into the arena.
+             counts: jax.Array, trash_block=None,
+             token_major: bool = False):
+    """Scatter a ragged chunk of new KV into the arena (``token_major``:
+    pools ``[NB, bs, kvh * d]``, each token's row written whole).
 
     arena_k/arena_v: [kvh, NB, bs, dh] (one layer's region of the flat
     pool, or the whole pool with absolute page-table ids); k/v:
@@ -73,7 +111,11 @@ def write_kv(arena_k: jax.Array, arena_v: jax.Array, k: jax.Array,
     anything — padded tokens route to ``trash_block``, default the pool's
     last block); starts: [n] tokens already in KV per sequence.
     """
-    kvh, nbp1, bs, dh = arena_k.shape
+    if token_major:
+        nbp1, bs, _ = arena_k.shape
+        kvh, dh = k.shape[2:]
+    else:
+        kvh, nbp1, bs, dh = arena_k.shape
     n, c, _, _ = k.shape
     if trash_block is None:
         trash_block = nbp1 - 1
@@ -87,6 +129,13 @@ def write_kv(arena_k: jax.Array, arena_v: jax.Array, k: jax.Array,
     phys = jnp.where(valid, phys, trash_block)                     # → trash
     bi = phys.reshape(-1)
     oi = offset.reshape(-1)
+    if token_major:
+        return (arena_k.at[bi, oi].set(
+                    k.reshape(n * c, kvh * dh).astype(arena_k.dtype),
+                    mode="drop"),
+                arena_v.at[bi, oi].set(
+                    v.reshape(n * c, -1).astype(arena_v.dtype),
+                    mode="drop"))
     k_rows = k.reshape(n * c, kvh, dh).transpose(1, 0, 2)          # [kvh,nc,dh]
     v_rows = v.reshape(n * c, kvh, dh).transpose(1, 0, 2)
     arena_k = arena_k.at[:, bi, oi, :].set(
@@ -96,47 +145,61 @@ def write_kv(arena_k: jax.Array, arena_v: jax.Array, k: jax.Array,
     return arena_k, arena_v
 
 
-def copy_pages(arena: dict, src: jax.Array, dst: jax.Array,
-               num_layers: int) -> dict:
+def copy_pages(arena: dict, src: jax.Array, dst: jax.Array, stride: int,
+               token_major: bool = False) -> dict:
     """Copy whole KV pages ``src[i] → dst[i]`` across every layer's region.
 
     The copy-on-write half of prefix caching: page tables are plain
-    physical-id arrays, so several uids may reference the SAME page
-    (full shared-prefix pages need no copy at all — the per-sequence
-    ``starts``/``counts`` masking already keeps each row's reads inside
-    its own context). Only a shared *partial* last page must be
+    device arrays of physical page ids, so "sharing" a cached prefix page
+    is just listing the same id in two sequences' tables — the paged
+    kernels read through the table and never care who owns a page. The
+    only time bytes move is when a PARTIAL cached page must be
     duplicated before its new owner appends into it, which is this op:
-    one gather+scatter over the flat pool per {k, v}.
+    one gather+scatter over each flat pool.
 
-    arena: {"k","v"} flat pools [kvh, L*(nb+1), bs, dh]; src/dst: [m]
-    logical page ids (< nb, layer-relative).
+    arena: flat pools [kvh, L*(nb+1), bs, dh] ({"k","v"}), or a typed
+    arena's token-major pools [L_kind*(nb+1), bs, kvh*d] (``token_major``:
+    every pool, each with its own layer count); src/dst: [m] logical page
+    ids (< nb, layer-relative); ``stride`` = nb + 1, a layer's pages.
     """
-    k = arena["k"]
-    stride = k.shape[1] // num_layers            # nb + 1
-    offs = jnp.arange(num_layers, dtype=jnp.int32)[:, None] * stride
-    s = (offs + jnp.asarray(src, jnp.int32)[None, :]).reshape(-1)
-    d = (offs + jnp.asarray(dst, jnp.int32)[None, :]).reshape(-1)
-    return {"k": k.at[:, d].set(k[:, s]),
-            "v": arena["v"].at[:, d].set(arena["v"][:, s])}
+    out = {}
+    for name, pool in arena.items():
+        pages = pool.shape[0 if token_major else 1]
+        offs = jnp.arange(pages // stride, dtype=jnp.int32)[:, None] * stride
+        s = (offs + jnp.asarray(src, jnp.int32)[None, :]).reshape(-1)
+        d = (offs + jnp.asarray(dst, jnp.int32)[None, :]).reshape(-1)
+        out[name] = pool.at[d].set(pool[s]) if token_major \
+            else pool.at[:, d].set(pool[:, s])
+    return out
 
 
 # ---------------------------------------------------------------------------
 # XLA reference path (also the prefill path)
 # ---------------------------------------------------------------------------
 
-def _gather_pages(arena: jax.Array, page_table: jax.Array):
-    """[kvh, nb+1, bs, dh] x [n, mb] → [n, kvh, mb*bs, dh]."""
-    kvh, _, bs, dh = arena.shape
+def _gather_pages(arena: jax.Array, page_table: jax.Array,
+                  kv_heads: int = 0):
+    """[kvh, nb+1, bs, dh] (token-major, ``kv_heads`` given: [nb+1, bs,
+    kvh*dh]) x [n, mb] → [n, kvh, mb*bs, dh]."""
     n, mb = page_table.shape
+    if kv_heads:
+        bs = arena.shape[1]
+        return arena[page_table].reshape(n, mb * bs, kv_heads, -1) \
+            .transpose(0, 2, 1, 3)
+    kvh, _, bs, dh = arena.shape
     return arena[:, page_table].transpose(1, 0, 2, 3, 4) \
         .reshape(n, kvh, mb * bs, dh)
 
 
 def _masked_attention(q: jax.Array, kg: jax.Array, vg: jax.Array,
-                      mask: jax.Array, with_lse: bool):
-    """Shared gathered-softmax core: q [n,c,h,dh], kg/vg [n,kvh,S,dh],
-    mask broadcastable to [n,kvh,g,c,S]. Returns out [n,c,h,dh]
-    (+ lse [n,c,h] fp32 when with_lse)."""
+                      mask: jax.Array, with_lse: bool,
+                      scale: Optional[float] = None):
+    """Shared gathered-softmax core: q [n,c,h,dk], kg [n,kvh,S,dk], vg
+    [n,kvh,S,dv], mask broadcastable to [n,kvh,g,c,S]. Returns out
+    [n,c,h,dv] (+ lse [n,c,h] fp32 when with_lse; a row with no visible
+    key gives lse ≈ -1e30, a weight of 0 in a merge). ``scale``: the
+    scores' factor, default ``dk ** -0.5`` (a caller that zero-pads the
+    heads passes the true width's)."""
     n, c, h, dh = q.shape
     kvh = kg.shape[1]
     if h % kvh:
@@ -145,45 +208,82 @@ def _masked_attention(q: jax.Array, kg: jax.Array, vg: jax.Array,
     groups = h // kvh
     qg = q.reshape(n, c, kvh, groups, dh)
     s = jnp.einsum("nckgd,nksd->nkgcs", qg, kg.astype(q.dtype),
-                   preferred_element_type=jnp.float32) / math.sqrt(dh)
+                   preferred_element_type=jnp.float32)
+    s = s / math.sqrt(dh) if scale is None else s * scale
     s = jnp.where(mask, s, _NEG_INF)
     m = jnp.max(s, axis=-1)                                     # [n,k,g,c]
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, axis=-1)
     out = jnp.einsum("nkgcs,nksd->nckgd", p.astype(vg.dtype), vg) \
         / jnp.maximum(l, 1e-30).transpose(0, 3, 1, 2)[..., None]
-    out = out.reshape(n, c, h, dh).astype(q.dtype)
+    out = out.reshape(n, c, h, vg.shape[-1]).astype(q.dtype)
     if not with_lse:
         return out
     lse = m + jnp.log(jnp.maximum(l, 1e-30))                    # [n,k,g,c]
     return out, lse.transpose(0, 3, 1, 2).reshape(n, c, h)
 
 
+def _window_pages(page_table: jax.Array, lowest: jax.Array, span: int,
+                  bs: int):
+    """The pages a window touches: ``lowest`` [n] is each row's lowest
+    visible key position (may be negative), ``span`` the most positions a
+    row's queries see from there. Returns (page ids [n, np], key
+    positions [n, np*bs]); a page past the table's width repeats the last
+    entry under positions no query can see."""
+    n, mb = page_table.shape
+    pages = min(mb, (max(span, 1) + bs - 2) // bs + 1)
+    first = jnp.maximum(lowest, 0) // bs                          # [n]
+    idx = first[:, None] + jnp.arange(pages, dtype=jnp.int32)[None]
+    ids = jnp.take_along_axis(page_table, jnp.minimum(idx, mb - 1), axis=1)
+    kpos = (idx[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32)
+            ).reshape(n, pages * bs)
+    far = jnp.iinfo(jnp.int32).max
+    return ids, jnp.where(jnp.repeat(idx < mb, bs, axis=1), kpos, far)
+
+
 def paged_attention_xla(q: jax.Array, arena_k: jax.Array,
                         arena_v: jax.Array, page_table: jax.Array,
-                        starts: jax.Array, counts: jax.Array) -> jax.Array:
+                        starts: jax.Array, counts: jax.Array,
+                        window: Optional[int] = None,
+                        scale: Optional[float] = None,
+                        with_lse: bool = False,
+                        token_major: bool = False):
     """Gather-then-attend over the paged arena (reference semantics).
 
-    q: [n, c, H, dh] (query rows j >= counts[i] give garbage rows — the
-    caller discards them); arena: [kvh, nb+1, bs, dh]; page_table: [n, mb];
-    starts/counts: [n]. Returns [n, c, H, dh].
+    q: [n, c, H, dk] (query rows j >= counts[i] give garbage rows — the
+    caller discards them); arena: [kvh, nb+1, bs, dk / dv]; page_table:
+    [n, mb]; starts/counts: [n]. Returns [n, c, H, dv] (and lse [n, c, H]
+    with ``with_lse``). ``window``: key j is visible to query i only when
+    ``i - j < window``, and only the pages such keys lie in are gathered.
     """
-    bs = arena_k.shape[2]
+    bs = arena_k.shape[1 if token_major else 2]
     n, c = q.shape[:2]
     mb = page_table.shape[1]
-    kg = _gather_pages(arena_k, page_table)
-    vg = _gather_pages(arena_v, page_table)
     qpos = starts[:, None] + jnp.arange(c, dtype=jnp.int32)[None]  # [n, c]
-    kpos = jnp.arange(mb * bs, dtype=jnp.int32)                    # [S]
     ctx = starts + counts                                          # [n]
-    mask = (kpos[None, None] <= qpos[..., None]) & \
-        (kpos[None, None] < ctx[:, None, None])                    # [n, c, S]
-    return _masked_attention(q, kg, vg, mask[:, None, None], False)
+    if window is None:
+        ids = page_table
+        kpos = jnp.arange(mb * bs, dtype=jnp.int32)                # [S]
+        mask = (kpos[None, None] <= qpos[..., None]) & \
+            (kpos[None, None] < ctx[:, None, None])                # [n, c, S]
+    else:
+        ids, kpos = _window_pages(page_table, starts - (window - 1),
+                                  window + c - 1, bs)
+        kpos = kpos[:, None]                                       # [n, 1, S]
+        mask = (kpos <= qpos[..., None]) & (kpos < ctx[:, None, None]) & \
+            (kpos > qpos[..., None] - window)
+    kvh = arena_k.shape[-1] // q.shape[-1] if token_major else 0
+    return _masked_attention(
+        q, _gather_pages(arena_k, ids, kvh), _gather_pages(arena_v, ids, kvh),
+        mask[:, None, None], with_lse, scale)
 
 
 def paged_attention_hist_xla(q: jax.Array, arena_k: jax.Array,
                              arena_v: jax.Array, page_table: jax.Array,
-                             starts: jax.Array):
+                             starts: jax.Array,
+                             window: Optional[int] = None,
+                             scale: Optional[float] = None,
+                             token_major: bool = False):
     """HISTORY-only attention: row i's queries attend keys [0, starts[i])
     — the tokens already in the arena BEFORE the current chunk's write.
     Returns (out [n,c,h,dh], lse [n,c,h] fp32).
@@ -193,39 +293,63 @@ def paged_attention_hist_xla(q: jax.Array, arena_k: jax.Array,
     within-chunk causal part is computed separately and merged by
     logsumexp. Empty-history rows produce lse ≈ -1e30, so their (garbage)
     out vanishes in the merge — no special-casing for fresh rows mixed
-    into a continuation batch.
+    into a continuation batch. ``window``: query j of a row (position
+    ``starts + j``) sees only the history keys within the window, and only
+    the pages those lie in are gathered.
     """
-    bs = arena_k.shape[2]
+    bs = arena_k.shape[1 if token_major else 2]
     mb = page_table.shape[1]
-    kg = _gather_pages(arena_k, page_table)
-    vg = _gather_pages(arena_v, page_table)
-    kpos = jnp.arange(mb * bs, dtype=jnp.int32)
-    mask = kpos[None, :] < starts[:, None]                      # [n, S]
-    return _masked_attention(q, kg, vg, mask[:, None, None, None, :],
-                             True)
+    if window is None:
+        ids = page_table
+        kpos = jnp.arange(mb * bs, dtype=jnp.int32)
+        mask = (kpos[None, :] < starts[:, None])[:, None, None, None, :]
+    else:
+        ids, kpos = _window_pages(page_table, starts - (window - 1),
+                                  window - 1, bs)
+        qpos = starts[:, None] + jnp.arange(q.shape[1],
+                                            dtype=jnp.int32)[None]  # [n, c]
+        kpos = kpos[:, None]                                        # [n,1,S]
+        mask = ((kpos < starts[:, None, None]) &
+                (kpos > qpos[..., None] - window))[:, None, None]
+    kvh = arena_k.shape[-1] // q.shape[-1] if token_major else 0
+    return _masked_attention(
+        q, _gather_pages(arena_k, ids, kvh), _gather_pages(arena_v, ids, kvh),
+        mask, True, scale)
 
 
-def merge_attention(out_a, lse_a, out_b, lse_b):
+def merge_attention(out_a, lse_a, out_b, lse_b, sink=None):
     """Combine two attention partials over DISJOINT key sets via their
     logsumexps (the flash-attention merge): outs [n,c,h,dh], lses
-    [n,c,h] → merged out."""
+    [n,c,h] → merged out. ``sink`` [h]: a learned logit that joins the
+    softmax as one more column — it takes mass (the denominator grows by
+    ``exp(sink)``) and gives no value."""
     m = jnp.maximum(lse_a, lse_b)
+    if sink is not None:
+        sink = sink.astype(jnp.float32)
+        m = jnp.maximum(m, sink)
     wa = jnp.exp(lse_a - m)
     wb = jnp.exp(lse_b - m)
-    denom = jnp.maximum(wa + wb, 1e-30)[..., None]
+    total = wa + wb if sink is None else wa + wb + jnp.exp(sink - m)
+    denom = jnp.maximum(total, 1e-30)[..., None]
     return (out_a.astype(jnp.float32) * wa[..., None]
             + out_b.astype(jnp.float32) * wb[..., None]) / denom
 
 
-def causal_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array):
+def causal_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
+                              window: Optional[int] = None,
+                              scale: Optional[float] = None):
     """Plain causal attention over one chunk returning (out, lse) for the
-    history merge — XLA path ([n,c,h,dh] layout, GQA via head groups)."""
+    history merge — XLA path ([n,c,h,dh] layout, GQA via head groups; K
+    and V may differ in width). ``window``: key j visible to query i only
+    when ``i - j < window``."""
     c = q.shape[1]
     kg = k.transpose(0, 2, 1, 3)                                # [n,kvh,c,d]
     vg = v.transpose(0, 2, 1, 3)
     i = jnp.arange(c, dtype=jnp.int32)
-    mask = (i[None, :] <= i[:, None])[None, None, None]
-    return _masked_attention(q, kg, vg, mask, True)
+    mask = i[None, :] <= i[:, None]
+    if window is not None:
+        mask = mask & (i[None, :] > i[:, None] - window)
+    return _masked_attention(q, kg, vg, mask[None, None, None], True, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -235,7 +359,9 @@ def causal_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array):
 def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
                   o_ref, *rest, block_size: int,
                   chunk: int, scale: float, mb: int,
-                  with_lse: bool = False):
+                  with_lse: bool = False,
+                  window: Optional[int] = None,
+                  token_major: bool = False):
     """Grid (n_seq, kvh): ONE program per (sequence, kv head) that walks
     this sequence's pages with double-buffered manual DMAs from the
     HBM-resident arena.
@@ -251,6 +377,13 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
     v_buf: [2, bs, dh] VMEM double buffers. With ``with_lse`` an extra
     [1, 1, rows] f32 output carries each row's logsumexp (the
     partial-attention merge needs it — fused decode's history part).
+    K and V may differ in width (k_buf [2, bs, dk], v_buf [2, bs, dv]; the
+    output is dv wide). ``window`` (static): key j is visible to query i
+    only when ``i - j < window``, and the walk STARTS at the page that
+    holds the lowest key any query of the row can see. ``token_major``
+    (static): the pools are ``[NB, bs, kvh * d]`` (a token's heads side by
+    side, the layout a row scatter writes without a relayout) and a head's
+    page is the lane slice ``[kh * d, (kh + 1) * d)`` of it.
     """
     if with_lse:
         lse_ref, k_buf, v_buf, sem_k, sem_v = rest
@@ -263,18 +396,30 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
     ctx = start + counts_ref[s_idx]
     npages = jnp.minimum(lax.div(ctx + block_size - 1,
                                  jnp.int32(block_size)), mb)
+    if window is None:
+        first, first_slot = 0, 0
+    else:
+        first = lax.div(jnp.maximum(start - (window - 1), 0),
+                        jnp.int32(block_size))
+        first_slot = lax.rem(first, 2)
+
+    def head_page(hbm, buf, page):
+        if not token_major:
+            return hbm.at[kh, page]
+        width = buf.shape[-1]
+        return hbm.at[page, :, pl.ds(pl.multiple_of(kh * width, 128), width)]
 
     def copy_in(page_i, slot):
         page = pt_ref[s_idx, page_i]
-        pltpu.make_async_copy(k_hbm.at[kh, page], k_buf.at[slot],
+        pltpu.make_async_copy(head_page(k_hbm, k_buf, page), k_buf.at[slot],
                               sem_k.at[slot]).start()
-        pltpu.make_async_copy(v_hbm.at[kh, page], v_buf.at[slot],
+        pltpu.make_async_copy(head_page(v_hbm, v_buf, page), v_buf.at[slot],
                               sem_v.at[slot]).start()
 
-    @pl.when(npages > 0)
+    @pl.when(npages > first)
     def _run():
-        copy_in(0, 0)
-        q = q_ref[0, 0]                                     # [rows, dh]
+        copy_in(first, first_slot)
+        q = q_ref[0, 0]                                     # [rows, dk]
 
         def body(b, carry):
             acc, m_prev, l_prev = carry
@@ -284,9 +429,9 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
             def _prefetch():
                 copy_in(b + 1, lax.rem(b + 1, 2))
 
-            pltpu.make_async_copy(k_hbm.at[kh, 0], k_buf.at[slot],
+            pltpu.make_async_copy(head_page(k_hbm, k_buf, 0), k_buf.at[slot],
                                   sem_k.at[slot]).wait()
-            pltpu.make_async_copy(v_hbm.at[kh, 0], v_buf.at[slot],
+            pltpu.make_async_copy(head_page(v_hbm, v_buf, 0), v_buf.at[slot],
                                   sem_v.at[slot]).wait()
             k_blk = k_buf[slot]
             v_blk = v_buf[slot]
@@ -297,7 +442,10 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
             qpos = start + j
             kpos = b * block_size + \
                 lax.broadcasted_iota(jnp.int32, (rows, block_size), 1)
-            s = jnp.where((kpos <= qpos) & (kpos < ctx), s, _NEG_INF)
+            visible = (kpos <= qpos) & (kpos < ctx)
+            if window is not None:
+                visible = visible & (kpos > qpos - window)
+            s = jnp.where(visible, s, _NEG_INF)
 
             blk_max = jnp.max(s, axis=1)
             m_new = jnp.maximum(m_prev, blk_max)
@@ -313,17 +461,17 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
             l = l_prev * corr + jnp.sum(p, axis=1)
             return acc, m_new, l
 
-        acc0 = jnp.zeros((rows, q_ref.shape[3]), jnp.float32)
+        acc0 = jnp.zeros((rows, o_ref.shape[3]), jnp.float32)
         m0 = jnp.full((rows,), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((rows,), jnp.float32)
-        acc, m, l = lax.fori_loop(0, npages, body, (acc0, m0, l0))
+        acc, m, l = lax.fori_loop(first, npages, body, (acc0, m0, l0))
         l = jnp.maximum(l, 1e-30)
         o_ref[0, 0] = (acc / l[:, None]).astype(o_ref.dtype)
         if with_lse:
             lse_ref[0, 0] = jnp.where(m > _NEG_INF / 2, m + jnp.log(l),
                                       _NEG_INF)[:, None]
 
-    @pl.when(npages == 0)
+    @pl.when(npages <= first)
     def _empty():
         o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
         if with_lse:
@@ -389,12 +537,29 @@ def paged_attention(q: jax.Array, arena_k: jax.Array, arena_v: jax.Array,
 def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
                              arena_v: jax.Array, page_table: jax.Array,
                              starts: jax.Array, counts: jax.Array, *,
-                             interpret: bool = False):
+                             interpret: bool = False,
+                             window: Optional[int] = None,
+                             scale: Optional[float] = None,
+                             token_major: bool = False):
     """Pallas paged attention returning (out, lse [n, c, h] fp32) for the
     partial-attention merge. ``counts=0`` gives HISTORY-only semantics
     (keys [0, starts)) — the fused decode loop's arena part, where the
-    arena is a read-only input rather than a carried/donated buffer."""
-    kvh, nbp1, bs, dh = arena_k.shape
+    arena is a read-only input rather than a carried/donated buffer.
+    K and V may differ in width (q as wide as a K head, the output as
+    wide as a V head); ``window``: key j is visible to query i only when
+    ``i - j < window`` and the walk starts at the window's first page;
+    ``scale``: the scores' factor, default ``dk ** -0.5`` (a caller that
+    zero-pads the heads passes the true width's). ``token_major``: the
+    pools are ``[NB, bs, kvh * d]`` (:func:`init_arena_typed`), read in
+    place by lane slices; q is as wide as one K head."""
+    if token_major:
+        nbp1, bs, lanes = arena_k.shape
+        dh = q.shape[-1]
+        kvh = lanes // dh
+        dv = arena_v.shape[-1] // kvh
+    else:
+        kvh, nbp1, bs, dh = arena_k.shape
+        dv = arena_v.shape[-1]
     n, c, h, _ = q.shape
     groups = h // kvh
     mb = page_table.shape[1]
@@ -404,9 +569,10 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
         .reshape(n, kvh, rows, dh)
 
     grid = (n, kvh)
-    kernel = functools.partial(_paged_kernel, block_size=bs, chunk=c,
-                               scale=1.0 / math.sqrt(dh), mb=mb,
-                               with_lse=True)
+    kernel = functools.partial(
+        _paged_kernel, block_size=bs, chunk=c, mb=mb, with_lse=True,
+        window=window, token_major=token_major,
+        scale=1.0 / math.sqrt(dh) if scale is None else scale)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -419,27 +585,27 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, rows, dh),
+                pl.BlockSpec((1, 1, rows, dv),
                              lambda s, kh, pt, st, ct: (s, kh, 0, 0)),
                 pl.BlockSpec((1, 1, rows, 1),
                              lambda s, kh, pt, st, ct: (s, kh, 0, 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((2, bs, dh), arena_k.dtype),
-                pltpu.VMEM((2, bs, dh), arena_v.dtype),
+                pltpu.VMEM((2, bs, dv), arena_v.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
             ],
         ),
-        out_shape=[jax.ShapeDtypeStruct((n, kvh, rows, dh), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((n, kvh, rows, dv), q.dtype),
                    jax.ShapeDtypeStruct((n, kvh, rows, 1), jnp.float32)],
         interpret=interpret,
         name="paged_attn_lse",
     )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
       counts.astype(jnp.int32), qk, arena_k, arena_v)
 
-    out = out.reshape(n, kvh, groups, c, dh).transpose(0, 3, 1, 2, 4) \
-        .reshape(n, c, h, dh)
+    out = out.reshape(n, kvh, groups, c, dv).transpose(0, 3, 1, 2, 4) \
+        .reshape(n, c, h, dv)
     lse = lse.reshape(n, kvh, groups, c).transpose(0, 3, 1, 2) \
         .reshape(n, c, h)
     return out, lse

@@ -479,3 +479,126 @@ def moe_layer(cfg, p, x: jax.Array,
     if stats is not None:
         return out.reshape(b, t, d), aux * aux_loss_coef, stats
     return out.reshape(b, t, d), aux * aux_loss_coef
+
+
+# ---------------------------------------------------------------------------
+# Sigmoid / selection-bias routing and the expert-parallel SHARE (serving)
+# ---------------------------------------------------------------------------
+
+#: capacity of one round of the share's dispatch: rows an expert's buffer
+#: holds (one MXU tile of rows). Up to this many tokens the held experts
+#: simply compute every token (the weights' bytes bound a decode step, not
+#: the rows); beyond, assignments are sorted by expert and served in rounds
+HELD_ROUND_ROWS = 128
+
+
+@jax.named_scope("moe_router")
+def route_tokens(cfg, p, xf: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The sigmoid router over ALL ``cfg.num_experts`` (DeepSeek-V3 /
+    MiMo-V2: a score per expert; the softmax router of the uniform stack
+    is :func:`moe_layer`'s): xf [S, d] → (weights [S, k] float32, expert
+    ids [S, k] int32, best first).
+
+    float32 throughout, as the published gates are (a bf16 logit flips a
+    near-tied selection, and a flipped expert is a different token): the
+    input is upcast, the matmul runs at ``Precision.HIGHEST`` (on a TPU a
+    float32 matmul is otherwise bf16 passes). ``p["router_bias"]``
+    (``router_select_bias``, ``noaux_tc``) is added for the SELECTION
+    only: the weights are the unbiased scores. ``norm_topk_prob``: the
+    kept weights renormalised to sum to 1."""
+    if cfg.router_scoring != "sigmoid":
+        raise NotImplementedError(
+            f"route_tokens is the sigmoid router; router_scoring="
+            f"{cfg.router_scoring!r} goes through moe_layer")
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "sd,de->se", xf.astype(jnp.float32), p["router"].astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    pick = scores
+    if "router_bias" in p:
+        pick = scores + p["router_bias"].astype(jnp.float32)
+    _, topi = lax.top_k(pick, cfg.num_experts_per_tok)
+    topw = jnp.take_along_axis(scores, topi, axis=-1)
+    if cfg.norm_topk_prob:
+        topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
+    return topw, topi.astype(jnp.int32)
+
+
+@jax.named_scope("moe_experts")
+def _held_glu(p, buf: jax.Array) -> jax.Array:
+    """buf [H, C, d] → [H, C, d]: each held expert's SiLU-GLU on its rows."""
+    gate = jnp.einsum("ecd,edh->ech", buf, p["wg"])
+    up = jnp.einsum("ecd,edh->ech", buf, p["wi"])
+    return jnp.einsum("ech,ehd->ecd", jax.nn.silu(gate) * up, p["wo"])
+
+
+def held_experts_moe_layer(cfg, p, x: jax.Array,
+                           valid: Optional[jax.Array] = None
+                           ) -> Tuple[jax.Array, jax.Array]:
+    """A ``moe_fn`` for an expert layer that is TOLD which experts it
+    holds (``cfg.experts_held`` = (first, count); None = all): it routes
+    every token over all ``cfg.num_experts``, and computes the part of
+    the result that its own experts give. Assignments to absent experts
+    are dropped before any buffer is built; what those experts would have
+    added is left out (on their chips it is computed, and an exchange
+    this layer does not have sums the parts). One chip: no collective.
+
+    p: ``router [d, E]``, optionally ``router_bias [E]``, and the HELD
+    experts' ``wg / wi [H, d, f]``, ``wo [H, f, d]``. x [B, T, d];
+    ``valid`` [B, T] bool marks real tokens (padding slots of a packed
+    step are dropped like absent experts). No token of a held expert is
+    ever dropped: up to ``HELD_ROUND_ROWS`` tokens every held expert
+    computes every token (weights bound that shape); beyond, the held
+    assignments are sorted by expert and served ``HELD_ROUND_ROWS`` rows
+    an expert at a time, in as many rounds as the fullest expert needs
+    (one, unless routing is badly skewed). Returns (out, 0.0): serving
+    has no balance loss."""
+    b, t, d = x.shape
+    s = b * t
+    first, held = cfg.experts_held or (0, cfg.num_experts)
+    k = cfg.num_experts_per_tok
+    xf = x.reshape(s, d)
+    topw, topi = route_tokens(cfg, p, xf)     # x as it is (float32 stream)
+    xf = xf.astype(p["wg"].dtype)             # the experts' compute dtype
+    local = topi - first                                       # [S, k]
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine = mine & valid.reshape(s, 1)
+    zero = jnp.zeros((), jnp.float32)
+
+    if s <= HELD_ROUND_ROWS:
+        # every held expert on every token; the combine weights carry the
+        # routing (0 where a token did not pick the expert)
+        comb = jnp.sum(
+            jnp.where(mine[..., None],
+                      topw[..., None] * jax.nn.one_hot(
+                          local, held, dtype=jnp.float32), 0.0),
+            axis=1)                                            # [S, H]
+        y = _held_glu(p, jnp.broadcast_to(xf[None], (held, s, d)))
+        out = jnp.einsum("esd,se->sd", y, comb,
+                         preferred_element_type=jnp.float32)
+        return out.astype(x.dtype).reshape(b, t, d), zero
+
+    cap = HELD_ROUND_ROWS
+    key = jnp.where(mine, local, held).reshape(-1)             # [S*k]
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    begin = jnp.cumsum(sizes) - sizes                          # [H]
+    w_flat = topw.reshape(-1)
+    slot = jnp.arange(cap, dtype=jnp.int32)[None, :]
+
+    def one_round(r, out):
+        pos = r * cap + slot                                   # [1, cap]
+        live = pos < sizes[:, None]                            # [H, cap]
+        src = order[jnp.minimum(begin[:, None] + pos, s * k - 1)]
+        tok = src // k
+        w = jnp.where(live, w_flat[src], 0.0)
+        y = _held_glu(p, xf[tok])                              # [H, cap, d]
+        part = (y.astype(jnp.float32) * w[..., None]).reshape(-1, d)
+        # a dead slot adds its zero to row ``s``, which does not exist
+        return out.at[jnp.where(live, tok, s).reshape(-1)].add(
+            part, mode="drop")
+
+    rounds = (jnp.max(sizes) + cap - 1) // cap
+    out = lax.fori_loop(0, rounds, one_round,
+                        jnp.zeros((s, d), jnp.float32))
+    return out.astype(x.dtype).reshape(b, t, d), zero

@@ -232,6 +232,14 @@ def decoder_model_spec(dec_cfg: DecoderConfig,
     """
     from deepspeed_tpu.runtime.engine import ModelSpec
 
+    if dec_cfg.typed:
+        raise NotImplementedError(
+            "ds.initialize: training a typed layer stack (DecoderConfig."
+            "layer_kinds: window and full attention layers, leading dense "
+            "layers, an expert share — MiMo-V2) is not built yet: no "
+            "backward for the share's dispatch, no sharding plan for a "
+            "list of layers. Serve it with RaggedInferenceEngineTPU")
+
     if (ds_cfg.moe.use_residual and dec_cfg.num_experts
             and not dec_cfg.moe_residual):
         # Residual-MoE via the DeepSpeed config knob (reference

@@ -43,6 +43,18 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(autouse=True)
+def no_ambient_mesh(monkeypatch):
+    """Every program here is compiled for ONE described chip. A global
+    mesh that another test file left on this xdist worker (eight CPU
+    devices) would send the trainer's attention through
+    ``flash_attention_sharded`` over it, and the lowering then refuses
+    the mix of devices: the whole run failed so once in two (PRs 30, 31),
+    by which files shared the worker."""
+    from deepspeed_tpu.parallel import mesh
+    monkeypatch.setattr(mesh, "_CURRENT_MESH", None)
+
+
 @pytest.fixture()
 def no_persistent_cache():
     from jax.experimental.compilation_cache import compilation_cache
@@ -82,6 +94,26 @@ def _paged(n, c, with_lse, heads=16, pages=8):
     vec = ((n,), jnp.int32)
     return functools.partial(kern, interpret=False), \
         (q, arena, arena, pt, vec, vec), 1
+
+
+def _paged_typed(kvh):
+    """The split step's history reader over a TYPED arena at MiMo-V2.5's
+    widths: 64 query heads, K heads of 192 padded to 256 lanes, V heads of
+    128, token-major pools ``[blocks, 128, kvh * width]`` (5 window layers
+    of 8 KV heads with a window of 128; 2 full layers of 4), 64 rows of
+    chunk 128, ``max_seq_len`` 1024."""
+    from deepspeed_tpu.ops.paged_attention import paged_attention_with_lse
+    layers, window = (5, 128) if kvh == 8 else (2, None)
+    blocks = layers * 513
+
+    def fn(q, ak, av, pt, starts):
+        return paged_attention_with_lse(
+            q, ak, av, pt, starts, jnp.zeros_like(starts), window=window,
+            scale=192 ** -0.5, token_major=True)
+    bf = jnp.bfloat16
+    return fn, (((64, 128, 64, 256), bf), ((blocks, 128, kvh * 256), bf),
+                ((blocks, 128, kvh * 128), bf), ((64, 8), jnp.int32),
+                ((64,), jnp.int32)), 1
 
 
 def _dequant(mode):
@@ -132,6 +164,10 @@ CASES = {
     # Mistral's 32 / 8 heads, 64 rows of chunk 128, max_seq_len 4096
     "paged_hist_n64_c128_lse": lambda: _paged(64, 128, with_lse=True,
                                               heads=32, pages=32),
+    # ... and over a typed arena (MiMo-V2.5: unequal K / V widths, a
+    # window, token-major pools read by lane slices), both layer kinds
+    "paged_hist_typed_window_kv8_lse": lambda: _paged_typed(8),
+    "paged_hist_typed_full_kv4_lse": lambda: _paged_typed(4),
     "dequant_int8": lambda: _dequant("int8"),
     "dequant_fp8": lambda: _dequant("fp8"),
     "dequant_int4": lambda: _dequant("int4"),
@@ -151,6 +187,8 @@ KERNEL_NAMES = {
     "flash_fwd_2k": ("flash_fwd",),
     "flash_fwd_bwd_2k": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
     "paged_hist_n64_c128_lse": ("paged_attn_lse",),
+    "paged_hist_typed_window_kv8_lse": ("paged_attn_lse",),
+    "paged_hist_typed_full_kv4_lse": ("paged_attn_lse",),
     "dequant_int8": ("qmm",),
     "dequant_fp8": ("qmm",),
     "dequant_int4": ("qmm_int4",),
