@@ -18,7 +18,7 @@ so jit traces a handful of programs, not one per batch composition.
 import os
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, List, Optional, Union
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -46,17 +46,97 @@ class RaggedInferenceConfig(TPUConfigModel):
     num_blocks: int = 512            #: KV arena pages
     block_size: int = 128            #: tokens per page
     max_seq_len: int = 4096          #: page-table width = ceil(/block_size)
-    max_batch_tokens: int = 2048     #: scheduler token budget per step
+    #: scheduler token budget per step; also what a chunk step whose rows
+    #: hold more slots packs its tokens into (_token_capacities)
+    max_batch_tokens: int = 2048
     prefill_chunk: int = 256         #: SplitFuse chunk width
     use_pallas: Optional[bool] = None  #: None = auto (TPU only)
     weight_quant: Optional[str] = None  #: "int8"|"fp8"|"int4"|"fp6" weight-only
+
+
+class _TokenLayout:
+    """Where a ragged batch's tokens sit for the sublayers that act on a
+    token alone (embedding, norms, projections, RoPE, MLP / MoE, residual
+    adds): in ROWS ``[n, c, ...]`` as they arrive (``capacity`` None), or
+    PACKED ``[1, capacity, ...]``, row after row with no padding between,
+    so that those sublayers work on ``capacity`` slots and not on
+    ``n * c``. Attention and the KV write keep the row form. Row ``r``'s
+    tokens are the packed slots ``offsets[r] .. offsets[r] + counts[r]``;
+    ``capacity`` (STATIC) must hold ``counts.sum()``, the caller's
+    promise. ``positions`` and ``valid`` are in the token-wise form."""
+
+    def __init__(self, counts: jax.Array, starts: jax.Array, c: int,
+                 capacity: Optional[int]):
+        self.n, self.c = counts.shape[0], c
+        self.counts, self.capacity = counts, capacity
+        if capacity is None:
+            cols = jnp.arange(c, dtype=jnp.int32)[None]
+            self.positions = starts[:, None] + jnp.broadcast_to(
+                cols, (self.n, c))
+            self.valid = cols < counts[:, None]
+            return
+        ends = jnp.cumsum(counts)
+        self.offsets = ends - counts
+        t = jnp.arange(capacity, dtype=jnp.int32)
+        # slot t belongs to the first row that ends after it (rows with
+        # no token are passed over); slots past the batch's tokens read
+        # the last row's tail and are never read back
+        row = jnp.minimum(jnp.sum(t[:, None] >= ends[None], axis=1,
+                                  dtype=jnp.int32), self.n - 1)
+        col = jnp.minimum(t - self.offsets[row], c - 1)
+        self.slot = row * c + col           # [capacity] into [n * c]
+        self.source = jnp.minimum(          # [n, c] into [capacity]
+            self.offsets[:, None] + jnp.arange(c, dtype=jnp.int32)[None],
+            capacity - 1)
+        self.positions = (starts[row] + col)[None]
+        self.valid = (t < ends[-1])[None]
+
+    def to_tokens(self, rows: jax.Array) -> jax.Array:
+        """[n, c, ...] → the token-wise form (a gather of tokens)."""
+        if self.capacity is None:
+            return rows
+        return rows.reshape((self.n * self.c,) + rows.shape[2:])[
+            self.slot][None]
+
+    def to_rows(self, x: jax.Array) -> jax.Array:
+        """The token-wise form → [n, c, ...]: row r's slot j reads packed
+        slot ``offsets[r] + j`` (a gather; nothing is scattered). The
+        slots a row does not fill so hold what follows it — the next
+        rows' tokens — as a row-form step's hold the activations of token
+        id 0: finite, and nothing reads them (a chunk's attention is
+        causal, so a live query sees live keys alone; the KV write takes
+        ``counts``). Masking them would be one more pass over
+        ``[n, c, heads, d]`` a layer."""
+        if self.capacity is None:
+            return x
+        return x[0][self.source]
+
+    def last(self, x: jax.Array) -> jax.Array:
+        """[n, 1, D]: each row's last token (a row without one: garbage)."""
+        if self.capacity is None:
+            last = jnp.maximum(self.counts - 1, 0)
+            return jnp.take_along_axis(x, last[:, None, None], axis=1)
+        return x[0][jnp.maximum(self.offsets + self.counts - 1, 0)][:, None]
+
+
+def _at_capacity(capacities, total: jax.Array, run):
+    """``run(capacity)`` at the smallest of the STATIC ``capacities``
+    (ascending) that holds ``total`` tokens: one branch each of a
+    ``lax.switch`` inside the ONE program, so a batch's token count picks
+    the work and no program key is added. ``run``'s outputs have the same
+    shapes at every capacity. No capacity: the row form."""
+    if len(capacities) <= 1:
+        return run(capacities[0] if capacities else None)
+    index = sum((total > cap).astype(jnp.int32) for cap in capacities[:-1])
+    return lax.switch(index, [partial(run, cap) for cap in capacities])
 
 
 def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
                    counts: jax.Array, starts: jax.Array,
                    page_table: jax.Array, use_pallas: bool = False,
                    moe_fn=None,
-                   fresh_prefill: Union[bool, str] = False):
+                   fresh_prefill: Union[bool, str] = False,
+                   token_capacities: Tuple[int, ...] = ()):
     """One forward over a ragged batch against the paged KV arena.
 
     tokens: [n, c] (row i valid for j < counts[i]); starts: [n] tokens
@@ -73,6 +153,25 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     XLA otherwise serializes (measured 395 → ~200 ms on a 16x512
     prefill step, v5e 1.27B).
 
+    ``token_capacities`` (STATIC, ascending, each under ``n * c``; the
+    caller promises ``counts.sum()`` never exceeds the last): a step that
+    carries a prompt chunk gives EVERY row the chunk's width, and most
+    rows are decode rows with one live token, so ``n * c`` slots hold a
+    few hundred tokens. Given capacities, the sublayers that act on a
+    token alone — embedding, RoPE tables, norms, the QKV and output
+    projections, MLP / MoE, the residual adds, the final norm — run over
+    the batch's tokens PACKED into ``[1, capacity, hidden]``
+    (:class:`_TokenLayout`); q, k, v are unpacked to ``[n, c, heads, d]``
+    for attention, which alone keeps the row form (as do the ``k, v`` the
+    KV write consumes), and its result is packed back before the output
+    projection. The head projects each row's last packed token. With
+    several capacities the "split" program holds one instance of the layer
+    loop for each and the batch's token count picks the smallest that
+    holds it (:func:`_at_capacity`): the arena is a read-only operand of
+    the branches and the write-back stays outside them. The other modes
+    carry the arena through the loop and take ONE capacity. ``()``: the
+    row form throughout, which is also what ``c == 1`` always is.
+
     In the "split" program (``c > 1``) the arena is READ-ONLY during the
     layer loop: the scan carries ``x`` alone, each layer emits its chunk's
     ``k, v`` as scan outputs ([L, n, c, kvh, dh]) and ONE second scan,
@@ -88,10 +187,19 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     """
     if fresh_prefill is True:   # pre-three-mode boolean API
         fresh_prefill = "fresh"
+    n, c = tokens.shape
+    split = fresh_prefill == "split" and c > 1
+    token_capacities = tuple(token_capacities)
+    if any(cap >= n * c for cap in token_capacities) or \
+            (len(token_capacities) > 1 and not split):
+        raise ValueError(
+            f"token_capacities {token_capacities} for a [{n}, {c}] batch: "
+            f"each must be under {n * c} slots, and only a split step "
+            f"takes more than one")
     if cfg.typed:
         return _ragged_forward_typed(cfg, params, arena, tokens, counts,
                                      starts, page_table, use_pallas,
-                                     moe_fn, fresh_prefill)
+                                     moe_fn, fresh_prefill, token_capacities)
     if cfg.pos_emb == "alibi":
         # the paged kernels have no score-bias port; serving BLOOM-class
         # models needs the v1 cached engine (forward_with_cache applies
@@ -99,91 +207,107 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
         raise NotImplementedError(
             "ragged/paged inference does not support ALiBi models; use "
             "InferenceEngineTPU (v1 KV-cache path) for BLOOM-class models")
-    n, c = tokens.shape
-    positions = starts[:, None] + jnp.broadcast_to(
-        jnp.arange(c, dtype=jnp.int32)[None], (n, c))
-    if cfg.pos_emb == "learned":
-        emb_pos = jnp.minimum(positions, params["embed"]["pos"].shape[0] - 1)
-    else:
-        emb_pos = positions
-    x = embed_tokens(cfg, params["embed"], tokens, emb_pos,
-                     params.get("embed_norm"))
-    if cfg.pos_emb == "rope":
-        sin, cos = rope_table(cfg, positions)
-    else:
-        sin = cos = jnp.zeros((n, c, 0), x.dtype)
-
     attend = pa.paged_attention if use_pallas else pa.paged_attention_xla
     # per-layer page stride in the FLAT block pool (init_arena docstring:
     # the pool is a scan CARRY so decode updates it in place; a stacked
     # per-layer arena would be copied wholesale every step)
     num_layers = cfg.num_layers
     stride = arena["k"].shape[1] // num_layers          # num_blocks + 1
-
-    split = fresh_prefill == "split" and c > 1
     layers = (params["layers"], jnp.arange(num_layers, dtype=jnp.int32))
 
-    def body(carry, layer):
-        # split: the arena is a read-only input of the loop (docstring)
-        x, ak, av = (carry, arena["k"], arena["v"]) if split else carry
-        lp, l_idx = layer
-        off = l_idx * stride
-        pt_l = page_table + off       # padded entries → this layer's trash
-        h_in = _norm(cfg, lp["ln1"], x)
-        q, k, v = qkv_project(cfg, lp["attn"], h_in, sin, cos)
-        if split:
-            # continuation / SplitFuse-mixed chunk: the history part
-            # reads the PRE-write arena. Fresh rows mixed in have empty
-            # history (lse ≈ -1e30 → weight 0); decode rows ride along
-            # as width-1 chunks.
-            with jax.named_scope("attn_history"):
-                if use_pallas:
-                    out_h, lse_h = pa.paged_attention_with_lse(
-                        q, ak, av, pt_l, starts, jnp.zeros_like(starts))
-                else:
-                    out_h, lse_h = pa.paged_attention_hist_xla(
-                        q, ak, av, pt_l, starts)
+    def run(capacity):
+        """Embedding to final norm at one capacity → (each row's last
+        hidden state [n, 1, D]; split: the chunk's (k, v) of every layer,
+        else the written arena's (k, v))."""
+        with jax.named_scope("embed"):     # where each token sits, too
+            lay = _TokenLayout(counts, starts, c, capacity)
+            toks = lay.to_tokens(tokens)
+        positions = lay.positions
+        if cfg.pos_emb == "learned":
+            emb_pos = jnp.minimum(positions,
+                                  params["embed"]["pos"].shape[0] - 1)
         else:
-            with jax.named_scope("kv_write"):
-                ak, av = pa.write_kv(ak, av, k, v, pt_l, starts, counts,
-                                     trash_block=off + stride - 1)
-        if fresh_prefill == "fresh":
-            # starts == 0 everywhere: the chunk IS the whole history —
-            # plain causal attention over it; padded-tail rows produce
-            # garbage outputs nothing reads (their KV went to trash)
-            with jax.named_scope("attn_core"):
-                if use_pallas:
-                    from deepspeed_tpu.ops.flash_attention import \
-                        flash_attention
-                    out = flash_attention(q, k, v, causal=True)
-                else:
-                    from deepspeed_tpu.models.transformer import \
-                        dot_product_attention
-                    out = dot_product_attention(q, k, v, causal=True)
-        elif split:
-            with jax.named_scope("attn_core"):
-                if use_pallas:
-                    from deepspeed_tpu.ops.flash_attention import \
-                        flash_attention_with_lse
-                    out_c, lse_c = flash_attention_with_lse(q, k, v,
-                                                            causal=True)
-                else:
-                    out_c, lse_c = pa.causal_attention_with_lse(q, k, v)
-            with jax.named_scope("attn_merge"):
-                out = pa.merge_attention(out_h, lse_h, out_c,
-                                         lse_c).astype(q.dtype)
+            emb_pos = positions
+        x = embed_tokens(cfg, params["embed"], toks, emb_pos,
+                         params.get("embed_norm"))
+        if cfg.pos_emb == "rope":
+            sin, cos = rope_table(cfg, positions)
         else:
-            with jax.named_scope("attn_core"):
-                out = attend(q, ak, av, pt_l, starts, counts)
-        attn_out = attn_out_project(cfg, lp["attn"], out)
-        h_out, _aux = block_combine(cfg, lp, x, h_in, attn_out, moe_fn)
-        if split:
-            return h_out, (k.astype(ak.dtype), v.astype(av.dtype))
-        return (h_out, ak, av), None
+            sin = cos = jnp.zeros(positions.shape + (0,), x.dtype)
 
+        def body(carry, layer):
+            # split: the arena is a read-only input of the loop (docstring)
+            x, ak, av = (carry, arena["k"], arena["v"]) if split else carry
+            lp, l_idx = layer
+            off = l_idx * stride
+            pt_l = page_table + off   # padded entries → this layer's trash
+            h_in = _norm(cfg, lp["ln1"], x)
+            q, k, v = qkv_project(cfg, lp["attn"], h_in, sin, cos)
+            with jax.named_scope("attn_qkv"):     # attention sees rows
+                q, k, v = (lay.to_rows(a) for a in (q, k, v))
+            if split:
+                # continuation / SplitFuse-mixed chunk: the history part
+                # reads the PRE-write arena. Fresh rows mixed in have empty
+                # history (lse ≈ -1e30 → weight 0); decode rows ride along
+                # as width-1 chunks.
+                with jax.named_scope("attn_history"):
+                    if use_pallas:
+                        out_h, lse_h = pa.paged_attention_with_lse(
+                            q, ak, av, pt_l, starts, jnp.zeros_like(starts))
+                    else:
+                        out_h, lse_h = pa.paged_attention_hist_xla(
+                            q, ak, av, pt_l, starts)
+            else:
+                with jax.named_scope("kv_write"):
+                    ak, av = pa.write_kv(ak, av, k, v, pt_l, starts, counts,
+                                         trash_block=off + stride - 1)
+            if fresh_prefill == "fresh":
+                # starts == 0 everywhere: the chunk IS the whole history —
+                # plain causal attention over it; padded-tail rows produce
+                # garbage outputs nothing reads (their KV went to trash)
+                with jax.named_scope("attn_core"):
+                    if use_pallas:
+                        from deepspeed_tpu.ops.flash_attention import \
+                            flash_attention
+                        out = flash_attention(q, k, v, causal=True)
+                    else:
+                        from deepspeed_tpu.models.transformer import \
+                            dot_product_attention
+                        out = dot_product_attention(q, k, v, causal=True)
+            elif split:
+                with jax.named_scope("attn_core"):
+                    if use_pallas:
+                        from deepspeed_tpu.ops.flash_attention import \
+                            flash_attention_with_lse
+                        out_c, lse_c = flash_attention_with_lse(q, k, v,
+                                                                causal=True)
+                    else:
+                        out_c, lse_c = pa.causal_attention_with_lse(q, k, v)
+                with jax.named_scope("attn_merge"):
+                    out = pa.merge_attention(out_h, lse_h, out_c,
+                                             lse_c).astype(q.dtype)
+            else:
+                with jax.named_scope("attn_core"):
+                    out = attend(q, ak, av, pt_l, starts, counts)
+            with jax.named_scope("attn_out"):     # ... and tokens again
+                out = lay.to_tokens(out)
+            attn_out = attn_out_project(cfg, lp["attn"], out)
+            h_out, _aux = block_combine(cfg, lp, x, h_in, attn_out, moe_fn)
+            if split:
+                return h_out, (k.astype(ak.dtype), v.astype(av.dtype))
+            return (h_out, ak, av), None
+
+        if split:
+            x, kv = lax.scan(body, x, layers)
+        else:
+            (x, *kv), _ = lax.scan(body, (x, arena["k"], arena["v"]),
+                                   layers)
+        x = _norm(cfg, params["final_norm"], x)
+        with jax.named_scope("lm_head"):       # the rows the head projects
+            return lay.last(x), tuple(kv)
+
+    x_last, (ak, av) = _at_capacity(token_capacities, counts.sum(), run)
     if split:
-        x, (k_new, v_new) = lax.scan(body, x, layers)
-
         def write_back(carry, layer_kv):
             k, v, l_idx = layer_kv
             off = l_idx * stride
@@ -192,15 +316,12 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
                                    counts,
                                    trash_block=off + stride - 1), None
 
-        (ak, av), _ = lax.scan(write_back, (arena["k"], arena["v"]),
-                               (k_new, v_new, layers[1]))
-    else:
-        (x, ak, av), _ = lax.scan(body, (x, arena["k"], arena["v"]),
-                                  layers)
-    x = _norm(cfg, params["final_norm"], x)
-    with jax.named_scope("lm_head"):       # the rows the head projects
-        last = jnp.maximum(counts - 1, 0)
-        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
+        # the loop itself carries the word, so the relayouts the compiler
+        # makes FOR it (the arena into the scatter's layout on entry) are
+        # their consumer's cost in the scope table, not "(no scope)"
+        with jax.named_scope("kv_write"):
+            (ak, av), _ = lax.scan(write_back, (arena["k"], arena["v"]),
+                                   (ak, av, layers[1]))
     logits = lm_logits(cfg, params, x_last)[:, 0]
     return logits, {"k": ak, "v": av}
 
@@ -208,10 +329,12 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
 def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                           tokens: jax.Array, counts: jax.Array,
                           starts: jax.Array, page_table: jax.Array,
-                          use_pallas: bool, moe_fn, fresh_prefill):
+                          use_pallas: bool, moe_fn, fresh_prefill,
+                          token_capacities: Tuple[int, ...] = ()):
     """:func:`ragged_forward` for a typed layer stack (models/
     typed_layers.py has the equations): the same three modes over the
-    same page table, the layer loop unrolled over the list of layers.
+    same page table and the same token layouts, the layer loop unrolled
+    over the list of layers.
 
     The arena is a flat dict with a token-major pool per attention kind
     and per K/V (``pa.init_arena_typed``; its scatter writes rows in place,
@@ -230,77 +353,99 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     fresh step's, and the decode step's paged read are the XLA forms (the
     flash kernels take one head width for Q, K and V; the decode read of a
     window layer is two pages a row)."""
-    n, c = tokens.shape
-    positions = starts[:, None] + jnp.broadcast_to(
-        jnp.arange(c, dtype=jnp.int32)[None], (n, c))
-    x, dtype = tl.residual_stream(      # float32, whatever the weights'
-        embed_tokens(cfg, params["embed"], tokens, positions))
-    tables = tl.rope_tables(cfg, positions)
-    valid = jnp.arange(c, dtype=jnp.int32)[None] < counts[:, None]
+    c = tokens.shape[1]
     split = fresh_prefill == "split" and c > 1
     scale = cfg.head_dim ** -0.5
-    pools = dict(arena)
     of_kind = {a: sum(1 for b in cfg.layer_kinds if b == a)
                for a in set(cfg.layer_kinds)}
     seen = dict.fromkeys(of_kind, 0)
-    written = []          # split: the chunk's k, v wait for the loop's end
-    for kind, lp in zip(cfg.layer_kinds, params["layers"]):
+    places = []     # a layer's pools, and where its pages lie in them
+    for kind in cfg.layer_kinds:
         kname, vname = pa.KIND_POOLS[kind]
-        stride = pools[kname].shape[0] // of_kind[kind]   # num_blocks + 1
+        stride = arena[kname].shape[0] // of_kind[kind]   # num_blocks + 1
         off = seen[kind] * stride
         seen[kind] += 1
-        pt_l = page_table + off       # padded entries → this layer's trash
-        window, sink = cfg.kind_window(kind), lp["attn"].get("sink")
-        h_in = _norm(cfg, lp["ln1"], x).astype(dtype)
-        q, k, v = tl.typed_qkv(cfg, kind, lp["attn"], h_in, *tables[kind])
-        pad = pools[kname].shape[-1] // k.shape[2] - cfg.head_dim
-        if pad:
-            q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, pad),)) for a in (q, k))
-        if split:
-            with jax.named_scope("attn_history"):
-                if use_pallas:
-                    out_h, lse_h = pa.paged_attention_with_lse(
-                        q, pools[kname], pools[vname], pt_l, starts,
-                        jnp.zeros_like(starts), window=window, scale=scale,
-                        token_major=True)
-                else:
-                    out_h, lse_h = pa.paged_attention_hist_xla(
-                        q, pools[kname], pools[vname], pt_l, starts,
-                        window=window, scale=scale, token_major=True)
-            with jax.named_scope("attn_core"):
-                out_c, lse_c = pa.causal_attention_with_lse(
-                    q, k, v, window=window, scale=scale)
-            with jax.named_scope("attn_merge"):
-                out = pa.merge_attention(out_h, lse_h, out_c, lse_c,
-                                         sink).astype(q.dtype)
-            written.append((kname, vname, k, v, pt_l, off + stride - 1))
-        else:
-            with jax.named_scope("kv_write"):
-                pools[kname], pools[vname] = pa.write_kv(
-                    pools[kname], pools[vname], k, v, pt_l, starts, counts,
-                    trash_block=off + stride - 1, token_major=True)
-            with jax.named_scope("attn_core"):
-                if fresh_prefill == "fresh":
-                    out, lse = pa.causal_attention_with_lse(
-                        q, k, v, window=window, scale=scale)
-                else:
-                    out, lse = pa.paged_attention_xla(
-                        q, pools[kname], pools[vname], pt_l, starts, counts,
-                        window=window, scale=scale, with_lse=True,
-                        token_major=True)
-                out = tl.apply_sink(out, lse, sink)
-        x = x + tl.typed_attn_out(cfg, lp["attn"], out)
-        x = x + tl.typed_ffn(cfg, lp, _norm(cfg, lp["ln2"], x), moe_fn,
-                             valid, dtype)
-    for kname, vname, k, v, pt_l, trash in written:
+        # padded entries of the page table → this layer's trash
+        places.append((kname, vname, page_table + off, off + stride - 1))
+
+    def write(pools, place, k, v):
+        kname, vname, pt_l, trash = place
         with jax.named_scope("kv_write"):
             pools[kname], pools[vname] = pa.write_kv(
                 pools[kname], pools[vname], k, v, pt_l, starts, counts,
                 trash_block=trash, token_major=True)
-    x = _norm(cfg, params["final_norm"], x).astype(dtype)
-    with jax.named_scope("lm_head"):       # the rows the head projects
-        last = jnp.maximum(counts - 1, 0)
-        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
+
+    def run(capacity):
+        """Embedding to final norm at one capacity → (each row's last
+        hidden state [n, 1, D]; split: every layer's chunk (k, v), which
+        wait for the loop's end, else the written pools)."""
+        with jax.named_scope("embed"):     # where each token sits, too
+            lay = _TokenLayout(counts, starts, c, capacity)
+            toks = lay.to_tokens(tokens)
+        x, dtype = tl.residual_stream(      # float32, whatever the weights'
+            embed_tokens(cfg, params["embed"], toks, lay.positions))
+        tables = tl.rope_tables(cfg, lay.positions)
+        pools = dict(arena)
+        chunk_kv = []
+        for kind, lp, place in zip(cfg.layer_kinds, params["layers"],
+                                   places):
+            kname, vname, pt_l, _ = place
+            window, sink = cfg.kind_window(kind), lp["attn"].get("sink")
+            h_in = _norm(cfg, lp["ln1"], x).astype(dtype)
+            q, k, v = tl.typed_qkv(cfg, kind, lp["attn"], h_in,
+                                   *tables[kind])
+            with jax.named_scope("attn_qkv"):     # attention sees rows
+                pad = pools[kname].shape[-1] // k.shape[2] - cfg.head_dim
+                if pad:
+                    q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, pad),))
+                            for a in (q, k))
+                q, k, v = (lay.to_rows(a) for a in (q, k, v))
+            if split:
+                with jax.named_scope("attn_history"):
+                    if use_pallas:
+                        out_h, lse_h = pa.paged_attention_with_lse(
+                            q, pools[kname], pools[vname], pt_l, starts,
+                            jnp.zeros_like(starts), window=window,
+                            scale=scale, token_major=True)
+                    else:
+                        out_h, lse_h = pa.paged_attention_hist_xla(
+                            q, pools[kname], pools[vname], pt_l, starts,
+                            window=window, scale=scale, token_major=True)
+                with jax.named_scope("attn_core"):
+                    out_c, lse_c = pa.causal_attention_with_lse(
+                        q, k, v, window=window, scale=scale)
+                with jax.named_scope("attn_merge"):
+                    out = pa.merge_attention(out_h, lse_h, out_c, lse_c,
+                                             sink).astype(q.dtype)
+                chunk_kv.append((k, v))
+            else:
+                write(pools, place, k, v)
+                with jax.named_scope("attn_core"):
+                    if fresh_prefill == "fresh":
+                        out, lse = pa.causal_attention_with_lse(
+                            q, k, v, window=window, scale=scale)
+                    else:
+                        out, lse = pa.paged_attention_xla(
+                            q, pools[kname], pools[vname], pt_l, starts,
+                            counts, window=window, scale=scale,
+                            with_lse=True, token_major=True)
+                    out = tl.apply_sink(out, lse, sink)
+            with jax.named_scope("attn_out"):     # ... and tokens again
+                out = lay.to_tokens(out)
+            x = x + tl.typed_attn_out(cfg, lp["attn"], out)
+            x = x + tl.typed_ffn(cfg, lp, _norm(cfg, lp["ln2"], x), moe_fn,
+                                 lay.valid, dtype)
+        x = _norm(cfg, params["final_norm"], x).astype(dtype)
+        with jax.named_scope("lm_head"):       # the rows the head projects
+            return lay.last(x), (chunk_kv if split else pools)
+
+    x_last, out = _at_capacity(token_capacities, counts.sum(), run)
+    if split:
+        pools = dict(arena)
+        for place, (k, v) in zip(places, out):
+            write(pools, place, k, v)
+    else:
+        pools = out
     return lm_logits(cfg, params, x_last)[:, 0], pools
 
 
@@ -574,6 +719,7 @@ class RaggedInferenceEngineTPU:
                                        "mode": str(mode), "fresh": fresh})
         mb = self.mb
         model = self.model_config
+        capacities = self._token_capacities(nb, cb, fresh)
         # the module's name in a device trace (after ``jit_``): kind and
         # static shape, so one name is one compiled module
         name = f"serve_{_step_kind(cb, fresh)}_r{nb}" + \
@@ -592,7 +738,7 @@ class RaggedInferenceEngineTPU:
             logits, arena = ragged_forward(
                 model, params, arena, tokens, counts, starts, pt,
                 use_pallas=self.use_pallas, moe_fn=self._moe_fn,
-                fresh_prefill=fresh)
+                fresh_prefill=fresh, token_capacities=capacities)
             if mode is None:
                 return logits, rng, arena
             temperature = lax.bitcast_convert_type(packed[off],
@@ -739,7 +885,9 @@ class RaggedInferenceEngineTPU:
                          eos_ids: Optional[Dict[int, int]] = None
                          ) -> Optional[Dict[int, Any]]:
         """One engine step packing at most ``budget`` tokens (None → the
-        scheduler's max_batch_tokens). The serving frontend's entry point:
+        scheduler's max_batch_tokens; a batch over max_batch_tokens is
+        refused where its step program packs tokens, :meth:`_run`). The
+        serving frontend's entry point:
         the SplitFuse policy installed on ``self.scheduler`` decides the
         prefill/decode mix, this just runs whatever it packed. Returns
         {uid: next_token_id} (or {uid: logits} with mode=None) for rows
@@ -1005,6 +1153,32 @@ class RaggedInferenceEngineTPU:
         cb = 1 if c == 1 else self.config.prefill_chunk
         return nb, cb
 
+    def _token_capacities(self, nb: int, cb: int, fresh) -> Tuple[int, ...]:
+        """``ragged_forward``'s ``token_capacities`` for the ``(nb, cb,
+        fresh)`` step programs — statics derived from what the engine
+        knows, so the program grid and its keys stay as they are. The
+        scheduler hands a step at most ``max_batch_tokens`` tokens, so
+        where the rows hold more slots than that the token-wise sublayers
+        work on ``max_batch_tokens`` packed slots; else ``()``, the row
+        form. A split program whose rows hold at least FOUR times the
+        budget also holds a second, smaller instance of its layer loop at
+        16 slots a row (1,024 of the 64 x 128 program's 8,192): a step of
+        decode rows and the prompt chunks of a few arrivals is a few
+        hundred tokens — under 1,024 in 98.6% / 99.7% of the split steps
+        of the chat and the reasoning traffic of ``benchmark/`` (PERF.md
+        §6, PR 32) — and takes that one. The second instance is 15–17% of
+        those steps and about 2 s of set-up a program (lowering and
+        loading it), which is why rows at twice the budget, already
+        halved by packing, do without. :meth:`_run` applies the same rule
+        to count the slots."""
+        top = self.config.max_batch_tokens
+        if cb == 1 or top >= nb * cb:
+            return ()
+        small = 16 * nb
+        if fresh == "split" and small < top and 4 * top <= nb * cb:
+            return (small, top)
+        return (top,)
+
     def _run(self, batch: RaggedBatch, mode=None) -> np.ndarray:
         n = len(batch.uids)
         nb, cb = self._buckets(batch)
@@ -1021,6 +1195,15 @@ class RaggedInferenceEngineTPU:
             fresh = "fresh"
         else:
             fresh = "split"
+        tokens = batch.total_tokens
+        capacities = self._token_capacities(nb, cb, fresh)
+        if capacities and tokens > capacities[-1]:
+            raise ValueError(
+                f"a batch of {tokens} tokens is over max_batch_tokens="
+                f"{self.config.max_batch_tokens}: the {nb}-row step program "
+                f"packs its tokens into that many slots (the scheduler's "
+                f"budget; a budget= / token_budget= above it cannot be "
+                f"served)")
         from deepspeed_tpu.telemetry.tracer import tracer
         with tracer.span("serving/pack"):
             packed = jnp.asarray(self._pack(batch, nb, cb))  # ONE upload
@@ -1032,11 +1215,12 @@ class RaggedInferenceEngineTPU:
             context_slots = nb * cb + \
                 int((-(-batch.start_positions // bs)).sum()) * bs
         work = self._count_dispatch(
-            _step_kind(cb, fresh), n, nb, cb, self.mb,
-            int(batch.token_counts.sum()),
+            _step_kind(cb, fresh), n, nb, cb, self.mb, tokens,
             int((batch.start_positions + batch.token_counts).sum()),
             context_slots=context_slots,
-            kv_window=self._kv_window_tokens(batch))
+            kv_window=self._kv_window_tokens(batch),
+            # the device's own rule (_at_capacity): the smallest that holds
+            token_slots=next((t for t in capacities if tokens <= t), None))
         with tracer.span("serving/dispatch",
                          **(work if tracer.enabled else {})):
             out, self._rng_dev, self.arena = self._step_fn(
@@ -1065,14 +1249,18 @@ class RaggedInferenceEngineTPU:
                         page_width: int, tokens: int, context_tokens: int,
                         scan_steps: int = 1,
                         context_slots: Optional[int] = None,
-                        kv_window=None) -> Dict[str, Any]:
+                        kv_window=None,
+                        token_slots: Optional[int] = None) -> Dict[str, Any]:
         """Count one device program launch where its batch is packed: the
         useful work (``tokens`` fed, ``context_tokens`` of live KV they
-        attend) against the work attempted (``slots`` = bucketed rows x
-        chunk width; ``context_slots`` = what the attention reads: bucketed
-        rows x the page table's width in tokens unless the caller knows
-        better, as for a split step whose history goes through the paged
-        kernel; both times the scan steps of a megastep).
+        attend) against the work attempted (``slots`` = what the sublayers
+        that act on a token alone ran over: ``token_slots``, the capacity
+        the launch packed its tokens into, or bucketed rows x chunk width
+        where it did not pack; ``row_slots`` = bucketed rows x chunk width,
+        what attention works on; ``context_slots`` = what the attention
+        reads: bucketed rows x the page table's width in tokens unless the
+        caller knows better, as for a split step whose history goes through
+        the paged kernel; all times the scan steps of a megastep).
         Always-on ``dispatch/*`` counters; the same numbers are the
         ``serving/dispatch`` span's arguments. ``kv_window`` (a model with
         window layers only: :meth:`_kv_window_tokens`) adds
@@ -1081,7 +1269,8 @@ class RaggedInferenceEngineTPU:
         ``context_tokens``), ``kv_tokens_window_live`` and
         ``kv_tokens_window_held``."""
         from deepspeed_tpu.telemetry.registry import registry
-        slots = nb * chunk * scan_steps
+        row_slots = nb * chunk * scan_steps
+        slots = row_slots if token_slots is None else token_slots
         if context_slots is None:
             context_slots = nb * page_width * self.config.block_size * \
                 scan_steps
@@ -1094,7 +1283,7 @@ class RaggedInferenceEngineTPU:
             registry.counter("dispatch/" + name).inc(by)
         work = {"program": program, "rows": rows, "rows_bucket": nb,
                 "chunk": chunk, "tokens": tokens, "slots": slots,
-                "context_tokens": context_tokens,
+                "row_slots": row_slots, "context_tokens": context_tokens,
                 "context_slots": context_slots}
         if kv_window is not None:
             live, held = kv_window

@@ -495,6 +495,174 @@ def test_split_step_matches_in_loop_write(devices, monkeypatch, reader,
                                   f32(arena[name])[:, pages])
 
 
+def _packed_stack(stack):
+    """(cfg, float32 params, arena maker) of a stack the packed step
+    runs on: the tiny Llama block (one scanned layer tree, head-major
+    arena) or MiMo-V2.5's typed stack at the benchmark's rehearsal widths
+    (a full and a window kind with its sink, K heads of 192 and V of 128,
+    a dense layer, then a top-8-of-16 router with 4 experts held)."""
+    from deepspeed_tpu.models.transformer import init_params
+    if stack == "uniform":
+        cfg = llama3_config("tiny")
+        params = init_params(cfg, jax.random.PRNGKey(3))
+        return cfg, params, lambda nb, bs: pa.init_arena(
+            cfg.num_layers, cfg.kv_heads, nb, bs, cfg.head_dim, jnp.float32)
+    import dataclasses
+    import json
+    import os
+    from benchmark.lib import model as model_lib
+    conf = json.load(open(os.path.join(
+        os.path.dirname(model_lib.__file__), "..", "configs",
+        "mimo-v2.5-l7-e16-serve.json")))
+    # a window of 24: the histories below pass it, the chunks straddle it
+    cfg = dataclasses.replace(model_lib.build_model(conf, rehearse=True),
+                              init_std=0.1, sliding_window=24)
+    assert cfg.typed and set(cfg.layer_kinds) == {0, 1} and \
+        cfg.experts_held and cfg.head_dim == 192 and cfg.v_dim == 128
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    for i, lp in enumerate(params["layers"]):
+        if "moe" in lp:        # a bias that selects, as the trained one does
+            lp["moe"]["router_bias"] = 0.3 * jax.random.normal(
+                jax.random.PRNGKey(10 + i), lp["moe"]["router_bias"].shape)
+    return cfg, params, lambda nb, bs: pa.init_arena_typed(
+        cfg.layer_kinds,
+        {a: cfg.kind_kv_heads(a) for a in set(cfg.layer_kinds)}, nb, bs,
+        cfg.head_dim, cfg.v_dim, jnp.float32)
+
+
+#: case -> (stack, fresh_prefill, tokens a row feeds, token_capacities);
+#: the chunk is 16 wide, so a row of 1 is a decode row riding along
+_PACKED_CASES = {
+    "decode61_chunks3": ("uniform", "split", [1] * 61 + [16] * 3,
+                         (128, 256)),
+    "partial_chunks": ("uniform", "split", [5, 16, 9, 1, 1, 3, 16, 1],
+                       (64, 96)),
+    "rows_without_tokens": ("uniform", "split", [0, 16, 0, 1, 7, 0, 1, 0],
+                            (32, 64)),
+    "fills_the_capacity": ("uniform", "split", [16] * 6 + [3, 1], (32, 100)),
+    "at_the_small_capacity": ("uniform", "split",
+                              [16, 8, 1, 1, 1, 1, 3, 1], (32, 64)),
+    "one_over_the_small_capacity": ("uniform", "split",
+                                    [16, 8, 1, 1, 1, 1, 4, 1], (32, 64)),
+    "one_capacity": ("uniform", "split", [16, 1, 1, 7, 0, 1, 2, 1], (40,)),
+    "fresh": ("uniform", "fresh", [16, 5, 0, 9, 16, 1, 2, 7], (64,)),
+    "paged_escape_hatch": ("uniform", False, [16, 5, 0, 9, 1, 1, 2, 7],
+                           (48,)),
+    "typed_small_capacity": ("typed", "split", [1, 16, 1, 0, 9, 1, 1, 1],
+                             (32, 64)),
+    "typed_large_capacity": ("typed", "split", [16, 16, 1, 0, 9, 1, 1, 1],
+                             (32, 64)),
+    "typed_fresh": ("typed", "fresh", [16, 5, 0, 9, 1, 1, 2, 7], (48,)),
+}
+
+
+_TAKES_THE_LARGE = {"fills_the_capacity", "one_over_the_small_capacity",
+                    "typed_large_capacity"}
+
+
+@pytest.mark.parametrize("case", list(_PACKED_CASES))
+def test_packed_chunk_step_matches_row_form(devices, case):
+    """A chunk step whose token-wise sublayers run over the batch's tokens
+    packed into ``token_capacities`` slots (attention alone on rows)
+    against the same step in row form (``token_capacities=()``, every
+    sublayer over ``[rows, chunk]``): the logits of every row that fed a
+    token and every page of the arena but each layer's trash page. Both
+    sides of the capacity switch, a batch that fills its capacity to the
+    last slot, rows with no token first, between and last."""
+    stack, mode, counts, capacities = _PACKED_CASES[case]
+    cfg, params, make_arena = _packed_stack(stack)
+    rng = np.random.default_rng(len(case))
+    counts = np.asarray(counts, np.int32)
+    n, c, bs, mb = len(counts), 16, 8, 8
+    assert sum(counts) <= capacities[-1] < n * c
+    if len(capacities) == 2:       # the side of the switch the name says
+        assert (sum(counts) > capacities[0]) == (case in _TAKES_THE_LARGE)
+    # histories of 1..48 tokens (past the typed stack's window of 24), a
+    # fresh row among them; a fresh step has none
+    starts = np.zeros(n, np.int32) if mode == "fresh" else \
+        rng.integers(1, 48, n).astype(np.int32) * (np.arange(n) != 1)
+    pages = -(-(starts + counts) // bs)
+    nb = int(pages.sum())
+    pt = np.full((n, mb), nb, np.int32)
+    free = iter(rng.permutation(nb))
+    for i in range(n):
+        pt[i, :pages[i]] = [next(free) for _ in range(pages[i])]
+    toks = lambda *shape: jnp.asarray(
+        rng.integers(0, cfg.vocab_size, shape), jnp.int32)
+    arena = make_arena(nb, bs)
+    if starts.any():        # the history, through the row-form paged read
+        _, arena = ragged_forward(cfg, params, arena, toks(n, 48),
+                                  jnp.asarray(starts),
+                                  jnp.zeros((n,), jnp.int32),
+                                  jnp.asarray(pt))
+    step = (toks(n, c), jnp.asarray(counts), jnp.asarray(starts),
+            jnp.asarray(pt))
+    want_logits, want = ragged_forward(cfg, params, arena, *step,
+                                       fresh_prefill=mode)
+    got_logits, got = ragged_forward(cfg, params, arena, *step,
+                                     fresh_prefill=mode,
+                                     token_capacities=capacities)
+    live = counts > 0
+    assert np.abs(np.asarray(want_logits)[live]).max() > 0.1
+    np.testing.assert_allclose(np.asarray(got_logits)[live],
+                               np.asarray(want_logits)[live],
+                               rtol=2e-4, atol=2e-4)
+    assert set(got) == set(want)
+    for name in want:
+        axis = 0 if cfg.typed else 1                   # the pages' axis
+        kept = np.arange(want[name].shape[axis]) % (nb + 1) != nb
+        a, b, before = (np.compress(kept, np.asarray(x[name]), axis)
+                        for x in (got, want, arena))
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
+                                   err_msg=name)
+        assert not np.array_equal(a, before), name     # the step wrote
+
+
+def test_capacities_the_rows_already_hold_are_refused(devices):
+    """``token_capacities`` are under the rows' slots (at or over them the
+    row form is the program), and only a split step switches between two."""
+    cfg = llama3_config("tiny")
+    from deepspeed_tpu.models.transformer import init_params
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    arena = pa.init_arena(cfg.num_layers, cfg.kv_heads, 4, 8, cfg.head_dim,
+                          jnp.float32)
+    z = jnp.zeros((2,), jnp.int32)
+    args = (cfg, params, arena, jnp.zeros((2, 16), jnp.int32), z, z,
+            jnp.full((2, 4), 4, jnp.int32))
+    for mode, capacities in (("split", (32,)), ("split", (8, 40)),
+                             ("fresh", (8, 16)), (False, (8, 16))):
+        with pytest.raises(ValueError, match="token_capacities"):
+            ragged_forward(*args, fresh_prefill=mode,
+                           token_capacities=capacities)
+
+
+def test_a_batch_over_the_token_capacity_is_refused_by_name(devices):
+    """A 8-row x 8-wide step of this engine packs its tokens into
+    ``max_batch_tokens`` = 32 slots; the scheduler's own budget never
+    passes that, a caller's ``budget=`` can: refused before any launch,
+    and nothing of the batch consumed."""
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg = llama3_config("tiny", max_seq_len=128, vocab_size=256)
+    eng = RaggedInferenceEngineTPU(
+        cfg, {"dtype": "float32", "num_blocks": 32, "block_size": 16,
+              "max_seq_len": 64, "prefill_chunk": 8, "max_batch_tokens": 32,
+              "max_sequences": 8})
+    assert eng._token_capacities(8, 8, "split") == (32,)
+    assert eng._token_capacities(4, 8, "split") == ()      # 32 slots: rows
+    assert eng._token_capacities(8, 1, False) == ()        # decode: rows
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 256, size=(8,), dtype=np.int32)
+               for _ in range(8)]
+    eng.scheduler.put(list(range(8)), prompts)
+    with pytest.raises(ValueError, match="max_batch_tokens=32"):
+        eng.step_with_budget(budget=64)
+    assert all(seq.seen_tokens == 0 for seq in eng.state.seqs.values())
+    # under the engine's own budget the same queue drains, packed
+    while eng.step_with_budget() is not None:
+        pass
+    assert all(seq.pending == 0 for seq in eng.state.seqs.values())
+
+
 def test_flash_attention_with_lse_matches_xla(devices):
     from deepspeed_tpu.ops.flash_attention import flash_attention_with_lse
     from deepspeed_tpu.ops.paged_attention import causal_attention_with_lse

@@ -391,7 +391,7 @@ def test_traced_serving_step_has_its_span_tree_and_work(traced):
     for e in launches:
         a = e["args"]
         assert a["rows"] <= a["rows_bucket"]
-        assert a["slots"] == a["rows_bucket"] * a["chunk"]
+        assert a["slots"] == a["row_slots"] == a["rows_bucket"] * a["chunk"]
         assert 0 < a["tokens"] <= a["slots"]
         assert a["tokens"] <= a["context_tokens"] <= a["context_slots"]
         # the page table of ENG_CFG: 128 / 8 pages of 8 tokens a row
@@ -462,6 +462,43 @@ def test_split_launch_counts_what_its_history_reader_reads(traced,
     assert after["dispatch/context_slots"] - \
         before["dispatch/context_slots"] == \
         sum(a["context_slots"] for a in launches)
+
+
+def test_packed_launch_counts_the_capacity_it_ran_at(traced):
+    """``slots`` of a launch whose rows hold more slots than the step's
+    token budget is the capacity its token-wise sublayers packed the
+    tokens into — ``max_batch_tokens``, or 16 a row when a split step's
+    tokens fit that (the program's own rule) — and ``row_slots`` what
+    attention still works on; ``dispatch/token_slots`` grows by the
+    former. 4 rows x chunk 96 = 384 slots over a budget of 80."""
+    from deepspeed_tpu.serving import ServingFrontend
+    eng = _engine(prefill_chunk=96, max_batch_tokens=80, max_sequences=4)
+    assert eng._token_capacities(4, 96, "split") == (64, 80)
+    assert eng._token_capacities(4, 96, "fresh") == (80,)
+    assert eng._token_capacities(2, 96, "split") == (80,)  # 2.4 x the budget
+    fe = ServingFrontend(eng)
+    rng = np.random.default_rng(0)
+    before = _counters()
+    for n in (30, 30, 30, 30):
+        fe.submit(list(rng.integers(1, 255, n)), max_new_tokens=8)
+    for _ in range(4):
+        fe.step()
+    launches = [e["args"] for e in
+                _spans(traced.events(), "serving/dispatch")]
+    got = [(a["program"], a["rows_bucket"], a["tokens"], a["slots"],
+            a["row_slots"]) for a in launches]
+    assert got == [("fresh", 4, 80, 80, 384),     # 30 + 30 + 20 of 30
+                   ("split", 4, 2 + 10 + 30, 64, 384),   # fits 16 a row
+                   ("decode", 4, 4, 4, 4),
+                   ("decode", 4, 4, 4, 4)], got
+    after = _counters()
+    assert after["dispatch/token_slots"] - before["dispatch/token_slots"] \
+        == 80 + 64 + 4 + 4
+    assert after["dispatch/tokens"] - before["dispatch/tokens"] == \
+        80 + 42 + 4 + 4
+    assert not [n for n in telemetry.registry.names()
+                if n.startswith("dispatch/steps.") and n.split(".")[1] not in
+                ("fresh", "split", "decode", "paged", "megastep")]
 
 
 def test_untraced_serving_step_counts_and_computes_no_argument(monkeypatch):

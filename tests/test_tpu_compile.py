@@ -250,9 +250,16 @@ def _abstract_params(model, sharding):
                        jax.random.PRNGKey(0)))
 
 
-def _serve_split(one_chip):
+#: the 64-row x 128-wide split program's token capacities under the
+#: engine's defaults (``_token_capacities``: 16 a row, ``max_batch_tokens``)
+_SPLIT_CAPACITIES = (1024, 2048)
+
+
+def _serve_split(one_chip, token_capacities=_SPLIT_CAPACITIES):
     """The 64-row ``split`` step of the benchmark's serving cell: chunk 128
-    over the default arena (512 pages of 128, ``max_seq_len`` 4096)."""
+    over the default arena (512 pages of 128, ``max_seq_len`` 4096), its
+    token-wise sublayers over the packed tokens at the cell's capacities
+    (``()``: the row form, 8,192 slots)."""
     from deepspeed_tpu.inference import engine_v2
     from deepspeed_tpu.ops import paged_attention as pa
     model = _mistral_2l()
@@ -261,7 +268,8 @@ def _serve_split(one_chip):
     def serve_split(params, arena, tokens, counts, starts, pt):
         logits, arena = engine_v2.ragged_forward(
             model, params, arena, tokens, counts, starts, pt,
-            use_pallas=True, fresh_prefill="split")
+            use_pallas=True, fresh_prefill="split",
+            token_capacities=token_capacities)
         out, _ = engine_v2._sample_tokens(logits, ("argmax",), 1.0, 1.0,
                                           None)
         return out, arena
@@ -393,12 +401,15 @@ def test_step_program_maps_to_scopes_on_v5e(program, one_chip,
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
 _CALLED = re.compile(r"\b(?:body|condition|calls|to_apply)=%([\w.\-]+)")
+_BRANCHES = re.compile(r"\b(?:branch_computations=\{([^}]*)\}|"
+                       r"(?:true|false)_computation=(%[\w.\-]+))")
 
 
-def _in_while_bodies(text):
-    """The lines of every computation a ``while`` body reaches (the body,
-    the fusions and calls inside it, nested loops)."""
-    lines, calls, bodies, name = {}, {}, set(), None
+def _in_loops_and_branches(text):
+    """The lines of every computation a ``while`` body or a branch of a
+    ``conditional`` reaches (the body or branch, the fusions and calls
+    inside it, nested loops and conditionals); and the branches' names."""
+    lines, calls, bodies, branches, name = {}, {}, set(), set(), None
     for line in text.splitlines():
         head = _COMPUTATION.match(line)
         if head:
@@ -406,26 +417,35 @@ def _in_while_bodies(text):
             lines[name], calls[name] = [], set()
         elif name is not None:
             lines[name].append(line)
-            calls[name].update(_CALLED.findall(line))
+            here = {b.strip().lstrip("%") for m in _BRANCHES.finditer(line)
+                    for b in (m.group(1) or m.group(2)).split(",")}
+            branches |= here
+            calls[name].update(_CALLED.findall(line), here)
             bodies.update(re.findall(r"\bbody=%([\w.\-]+)", line))
-    reached, todo = set(), list(bodies)
+    reached, todo = set(), list(bodies | branches)
     while todo:
         comp = todo.pop()
         if comp not in reached:
             reached.add(comp)
             todo.extend(calls[comp])
     assert bodies and reached <= set(lines)
-    return [line for comp in reached for line in lines[comp]]
+    return [line for comp in reached for line in lines[comp]], branches
 
 
 def test_serve_split_reads_live_pages_from_a_read_only_arena(
         one_chip, no_persistent_cache, monkeypatch):
     """The split step at the serving cell's shapes: its history goes
     through the paged kernel under ``attn_history``; the arena stays out
-    of the layer loop's carry, so NO arena-shaped copy runs inside a loop
-    (carried beside the kernel it is relaid whole twice a layer: ISSUE 29,
-    docs/kernels.md); and without the gathered float32 scores (6.08 GB of
-    temporaries at two layers) the program's temporaries stay under 2 GB."""
+    of the layer loop's carry AND out of what the capacity switch's
+    branches write, so NO arena-shaped copy runs inside a loop or a
+    conditional branch (carried beside the kernel it is relaid whole twice
+    a layer: ISSUE 29, docs/kernels.md); without the gathered float32
+    scores (6.08 GB of temporaries at two layers) the program's
+    temporaries stay under 2 GB; and with the token-wise sublayers over
+    2,048 packed slots where the rows hold 8,192, the program counts at
+    most 0.35 of the row form's FLOPs (3.593e12 at two layers, this
+    compiler's own count of ``_serve_split(one_chip, ())``; measured
+    0.254: the matmuls a quarter, attention as it was)."""
     from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     jitted, args = _serve_split(one_chip)
@@ -438,10 +458,14 @@ def test_serve_split_reads_live_pages_from_a_read_only_arena(
     arena = args[1]["k"]
     shape = "bf16[" + ",".join(map(str, arena.shape)) + "]"
     arena_copy = re.compile(rf" = {re.escape(shape)}\S* copy\(")
-    copies = [line.strip()[:160] for line in _in_while_bodies(text)
+    inside, branches = _in_loops_and_branches(text)
+    # one instance of the layer loop a capacity, in ONE executable
+    assert len(branches) == len(_SPLIT_CAPACITIES), branches
+    copies = [line.strip()[:160] for line in inside
               if arena_copy.search(line)]
     assert not copies, copies
     # ... and the pattern does find the entry's relayouts (in for the
     # scatter's layout, out again), so an empty list above means something
     assert arena_copy.search(text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    assert compiled.cost_analysis()["flops"] <= 0.35 * 3.593e12
