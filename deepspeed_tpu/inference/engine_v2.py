@@ -15,8 +15,6 @@ bucketed (batch rows to powers of two, chunk width to {1, prefill_chunk})
 so jit traces a handful of programs, not one per batch composition.
 """
 
-import os
-from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple, Union
 
@@ -185,8 +183,6 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     (:func:`paged_attention_hist_xla`, CPU or a head size the kernel
     refuses) reads the page table's whole width.
     """
-    if fresh_prefill is True:   # pre-three-mode boolean API
-        fresh_prefill = "fresh"
     n, c = tokens.shape
     split = fresh_prefill == "split" and c > 1
     token_capacities = tuple(token_capacities)
@@ -467,7 +463,8 @@ def _step_kind(cb: int, fresh) -> str:
     """Which step program a batch runs: ``decode`` (one token a row),
     ``fresh`` / ``split`` (a prefill chunk attending inside the chunk /
     also the pre-write arena) or ``paged`` (a chunk through the single
-    paged read, the DSTPU_NO_SPLIT_PREFILL escape hatch). The tag of the
+    paged read: the reference the other two are tested against, which the
+    engine itself never selects). The tag of the
     ``serving/dispatch`` span and of ``dispatch/steps.<kind>``, and the
     prefix of the program's name after ``serve_``."""
     if cb == 1:
@@ -508,17 +505,6 @@ def _sample_tokens(logits, mode, temperature, top_p, rng):
         lg = jnp.where(lg < cutoff, -1e30, lg)
     rng, sub = jax.random.split(rng)
     return jax.random.categorical(sub, lg, axis=-1).astype(jnp.int32), rng
-
-
-class FusedDecodeUnavailable(RuntimeError):
-    """Raised when the fused decode fast path can't serve a request.
-    ``doomed=True`` means the stepwise loop would ALSO fail (the decode
-    window overruns max_seq_len with no early exit possible), so the
-    caller should error out cleanly instead of falling back."""
-
-    def __init__(self, msg: str, doomed: bool = False):
-        super().__init__(msg)
-        self.doomed = doomed
 
 
 class RaggedInferenceEngineTPU:
@@ -706,8 +692,6 @@ class RaggedInferenceEngineTPU:
         per request does NOT recompile the model forward (only top_k and
         the top-p on/off switch are static). ``fresh``: False, "fresh" or
         "split" as ``ragged_forward``'s ``fresh_prefill``."""
-        if fresh is True:   # pre-three-mode boolean API: one key, one name
-            fresh = "fresh"
         key = (nb, cb, mode, fresh)
         if key in self._step_fns:
             return self._step_fns[key]
@@ -814,11 +798,12 @@ class RaggedInferenceEngineTPU:
 
     # -- the engine step (reference put():107) ------------------------------
 
-    def _validate_put(self, uids: List[int], tokens_list) -> None:
-        # enforce max_seq_len up front: past it the page table row would
-        # overflow (and write_kv's index clamp would misroute KV silently).
-        # Totals accumulate WITHIN this call too, so duplicate uids in one
-        # put() can't slip past the check.
+    def _put_validated(self, uids: List[int], tokens_list) -> None:
+        """Queue tokens for the scheduler, none of them if any would pass
+        max_seq_len: past it the page table row would overflow (and
+        write_kv's index clamp would misroute KV silently). Totals
+        accumulate WITHIN this call too, so duplicate uids in one put()
+        can't slip past the check."""
         pending: Dict[int, int] = {}
         for uid, toks in zip(uids, tokens_list):
             have = pending.get(
@@ -831,53 +816,29 @@ class RaggedInferenceEngineTPU:
                     f"max_seq_len={self.config.max_seq_len}; flush it or "
                     f"raise max_seq_len")
             pending[uid] = total
+        self.scheduler.put(uids, tokens_list)
 
     def put(self, uids: List[int], tokens_list) -> Dict[int, np.ndarray]:
         """Queue new tokens, then run engine steps until every queued token
         has been consumed; returns {uid: last-token logits} for sequences
         whose pending tokens were exhausted this call."""
-        self._validate_put(uids, tokens_list)
-        self.scheduler.put(uids, tokens_list)
-        out: Dict[int, np.ndarray] = {}
-        while True:
-            res = self.step()
-            if res is None:
-                break
-            out.update(res)
-        return out
+        return self._put_tokens(uids, tokens_list, mode=None)
 
     def _put_tokens(self, uids: List[int], tokens_list,
-                    mode=("argmax",)) -> Dict[int, int]:
+                    mode=("argmax",)) -> Dict[int, Any]:
         """put() for serving: samples ON DEVICE and returns
         {uid: next_token_id} — fetching [n] int32 per step instead of the
         [n, vocab] logits (8 MB/step for a 128k vocab)."""
-        self._validate_put(uids, tokens_list)
-        self.scheduler.put(uids, tokens_list)
-        out: Dict[int, int] = {}
-        while True:
-            batch = self.scheduler.next_batch()
-            if batch is None:
-                break
-            toks = self._run(batch, mode=mode)
-            self.scheduler.mark_scheduled(batch)
-            for i, uid in enumerate(batch.uids):
-                if self.state.seqs[uid].pending == 0:
-                    out[uid] = int(toks[i])
+        self._put_validated(uids, tokens_list)
+        out: Dict[int, Any] = {}
+        while (res := self.step_with_budget(mode=mode)) is not None:
+            out.update(res)
         return out
 
     def step(self) -> Optional[Dict[int, np.ndarray]]:
         """One ragged forward over the next scheduled batch; None when no
         work is pending."""
-        batch = self.scheduler.next_batch()
-        if batch is None:
-            return None
-        logits = self._run(batch)
-        self.scheduler.mark_scheduled(batch)
-        out: Dict[int, np.ndarray] = {}
-        for i, uid in enumerate(batch.uids):
-            if self.state.seqs[uid].pending == 0:
-                out[uid] = logits[i]
-        return out
+        return self.step_with_budget(mode=None)
 
     def step_with_budget(self, budget: Optional[int] = None,
                          mode=("argmax",), max_steps: int = 1,
@@ -886,10 +847,11 @@ class RaggedInferenceEngineTPU:
                          ) -> Optional[Dict[int, Any]]:
         """One engine step packing at most ``budget`` tokens (None → the
         scheduler's max_batch_tokens; a batch over max_batch_tokens is
-        refused where its step program packs tokens, :meth:`_run`). The
-        serving frontend's entry point:
-        the SplitFuse policy installed on ``self.scheduler`` decides the
-        prefill/decode mix, this just runs whatever it packed. Returns
+        refused where its step program packs tokens, :meth:`_run`). THE
+        step entry: the serving frontend's, and what :meth:`put`,
+        :meth:`step` and :meth:`generate` loop over. The policy installed
+        on ``self.scheduler`` decides the prefill/decode mix, this just
+        runs whatever it packed. Returns
         {uid: next_token_id} (or {uid: logits} with mode=None) for rows
         whose pending tokens were exhausted; None when idle.
 
@@ -938,11 +900,9 @@ class RaggedInferenceEngineTPU:
         would double-advance the SplitFuse round-robin).
 
         Applicable iff the selection is pure decode: every row is a
-        single-token chunk covering its whole pending queue. Serving
-        descriptors hold the fed token IN ``seq.tokens`` (the frontend
-        extends before scheduling), so starts/page math here differs from
-        ``_fused_decode``'s generate-path convention where the fed token
-        lives outside the descriptor.
+        single-token chunk covering its whole pending queue (the caller
+        extended the descriptor by the fed token before scheduling), the
+        window has at least two steps, and the arena has its pages.
         """
         n = len(batch.uids)
         if n == 0 or batch.token_ids.shape[1] != 1 or \
@@ -985,9 +945,9 @@ class RaggedInferenceEngineTPU:
                     self.state.allocator.allocate(c))
 
         nb = _bucket(n)
-        # pow2 scan buckets (not _FUSED_STEP_BUCKET multiples): the rng
-        # splits once per scan slot incl. dead ones, so aligned pow2
-        # windows keep sampled streams identical across K choices
+        # pow2 scan buckets: the rng splits once per scan slot incl. dead
+        # ones, so aligned pow2 windows keep sampled streams identical
+        # across K choices
         sb = _bucket(limit)
         tokens0 = np.zeros((nb,), np.int32)
         starts0 = np.zeros((nb,), np.int32)
@@ -1186,10 +1146,8 @@ class RaggedInferenceEngineTPU:
         # on the ~GB arena serializes the whole layer scan): first-chunk-
         # only batches attend within the chunk ("fresh"); continuation /
         # SplitFuse-mixed batches split history (pre-write arena) +
-        # within-chunk and merge by logsumexp ("split"). Env
-        # DSTPU_NO_SPLIT_PREFILL restores the single paged read (A/B +
-        # escape hatch).
-        if cb == 1 or os.environ.get("DSTPU_NO_SPLIT_PREFILL"):
+        # within-chunk and merge by logsumexp ("split").
+        if cb == 1:
             fresh = False
         elif bool((batch.start_positions == 0).all()):
             fresh = "fresh"
@@ -1294,12 +1252,11 @@ class RaggedInferenceEngineTPU:
                         kv_tokens_window_held=held)
         return work
 
-    # -- fused decode loop (generate fast path) ----------------------------
+    # -- fused decode loop (the megastep's program) ------------------------
 
-    #: fused scan lengths are bucketed to multiples of this so distinct
-    #: max_new_tokens values share compiles (each fused program is a
-    #: full-model compile); iterations beyond the traced `limit` run with
-    #: all rows dead (KV to trash, outputs discarded) — ≤31 wasted steps
+    #: the decode window :meth:`generate` asks of a step: a power of two,
+    #: so whole windows are whole scans and only a call's last window has
+    #: dead iterations (every row dead: KV to trash, outputs discarded)
     _FUSED_STEP_BUCKET = 32
 
     def _fused_decode_fn(self, nb: int, sb: int, mode, pw: int):
@@ -1337,8 +1294,6 @@ class RaggedInferenceEngineTPU:
         key = (nb, sb, mode, pw)
         if key in self._fused_fns:
             return self._fused_fns[key]
-        if os.environ.get("DSTPU_FUSED_V1"):
-            return self._fused_decode_fn_v1(nb, sb, mode, pw)
         from deepspeed_tpu.telemetry import compile_monitor
         compile_monitor.count_trace(
             "serving/fused_decode_fn",
@@ -1389,21 +1344,13 @@ class RaggedInferenceEngineTPU:
                     q, k, v = qkv_project(model, lp["attn"], h_in, sin,
                                           cos)
                     # history: keys [0, starts0) straight from the
-                    # arena. XLA gather-attend by default: the Pallas
+                    # arena, through the XLA gather-attend: the Pallas
                     # kernel's (seq, head) grid is launch-overhead-bound
                     # at decode widths (268 vs 70 us/layer-step profiled
-                    # at n=16 on v5e); opt in via DSTPU_FUSED_PALLAS_HIST
-                    # for wide-batch/long-context serving where walking
-                    # only the true pages wins back the gather padding
+                    # at n=16 on v5e)
                     with jax.named_scope("attn_history"):
-                        if self.use_pallas and \
-                                os.environ.get("DSTPU_FUSED_PALLAS_HIST"):
-                            out_h, lse_h = pa.paged_attention_with_lse(
-                                q, ak_c, av_c, pt_l, starts0,
-                                jnp.zeros_like(starts0))
-                        else:
-                            out_h, lse_h = pa.paged_attention_hist_xla(
-                                q, ak_c, av_c, pt_l, starts0)
+                        out_h, lse_h = pa.paged_attention_hist_xla(
+                            q, ak_c, av_c, pt_l, starts0)
                     # decode window: this loop's own tokens (incl. self)
                     with jax.named_scope("kv_write"):
                         kbuf = lax.dynamic_update_slice(
@@ -1428,27 +1375,18 @@ class RaggedInferenceEngineTPU:
                                                 attn_out, self._moe_fn)
                     return (h_out, kbuf, vbuf), None
 
-                if os.environ.get("DSTPU_FUSED_SCAN_LAYERS"):
-                    (x, kbuf, vbuf), _ = lax.scan(
-                        layer_body, (x, kbuf, vbuf),
-                        (params["layers"],
-                         jnp.arange(num_layers, dtype=jnp.int32)))
-                else:
-                    # UNROLLED layer loop: under lax.scan every layer's
-                    # (packed) weights are dynamic-sliced out of the
-                    # stacked params into fresh buffers each step —
-                    # pure copy traffic that roughly doubles the
-                    # weight-bound decode cost. Unrolling lets XLA feed
-                    # the kernels from the stacked arrays directly;
-                    # compile time stays modest because the decode
-                    # graph is small.
-                    carry_l = (x, kbuf, vbuf)
-                    for l in range(num_layers):
-                        lp = jax.tree.map(lambda a: a[l],
-                                          params["layers"])
-                        carry_l, _ = layer_body(
-                            carry_l, (lp, jnp.int32(l)))
-                    x, kbuf, vbuf = carry_l
+                # UNROLLED layer loop: under lax.scan every layer's
+                # (packed) weights are dynamic-sliced out of the stacked
+                # params into fresh buffers each step — pure copy traffic
+                # that roughly doubles the weight-bound decode cost.
+                # Unrolling lets XLA feed the kernels from the stacked
+                # arrays directly; compile time stays modest because the
+                # decode graph is small.
+                carry_l = (x, kbuf, vbuf)
+                for l in range(num_layers):
+                    lp = jax.tree.map(lambda a: a[l], params["layers"])
+                    carry_l, _ = layer_body(carry_l, (lp, jnp.int32(l)))
+                x, kbuf, vbuf = carry_l
                 x = _norm(model, params["final_norm"], x)
                 logits = lm_logits(model, params, x)[:, 0]
                 nxt, rng = _sample_tokens(logits, mode, temp, top_p, rng)
@@ -1484,18 +1422,9 @@ class RaggedInferenceEngineTPU:
                 (kbuf, vbuf, jnp.arange(num_layers, dtype=jnp.int32)))
             return ys, counts, rng, {"k": ak, "v": av}
 
-        jitted = self._name_megastep(fn, key)
-        self._fused_fns[key] = jitted
-        return jitted
-
-    def _name_megastep(self, fn, key):
-        """jit a fused decode loop under ``serve_megastep_r<rows>_k<scan
-        steps>_p<page-table width>`` and register it for the scope table
-        (``key``: the jit-cache key, a fifth element marks the v1 loop)."""
-        from deepspeed_tpu.telemetry import compile_monitor
-        nb, sb, mode, pw = key[:4]
-        name = f"serve_megastep_r{nb}_k{sb}_p{pw}" + _mode_suffix(mode) + \
-            "".join(f"_{tag}" for tag in key[4:])
+        # ``serve_megastep_r<rows>_k<scan steps>_p<page-table width>``,
+        # registered for the scope table
+        name = f"serve_megastep_r{nb}_k{sb}_p{pw}" + _mode_suffix(mode)
         fn.__name__ = fn.__qualname__ = name
         jitted = jax.jit(fn, donate_argnums=(1,))
         rows = jax.ShapeDtypeStruct((nb,), jnp.int32)
@@ -1505,159 +1434,12 @@ class RaggedInferenceEngineTPU:
             jax.ShapeDtypeStruct((), jnp.int32), rows, rows,
             jax.ShapeDtypeStruct((), jnp.float32),
             jax.ShapeDtypeStruct((), jnp.float32), self._rng_dev))
-        return jitted
-
-    def _fused_decode_fn_v1(self, nb: int, sb: int, mode, pw: int):
-        """The r4 arena-carrying loop (XLA attend, arena copied per
-        iteration) — kept for A/B via DSTPU_FUSED_V1. Signature-identical
-        to :meth:`_fused_decode_fn` including the per-row budget/eos
-        dead-masking (here dead rows write no KV at all: ragged_forward
-        clips by the per-row counts)."""
-        key = (nb, sb, mode, pw, "v1")
-        if key in self._fused_fns:
-            return self._fused_fns[key]
-        from deepspeed_tpu.telemetry import compile_monitor
-        compile_monitor.count_trace(
-            "serving/fused_decode_fn_v1",
-            detail={"n_bucket": nb, "steps": sb, "mode": str(mode),
-                    "page_width": pw})
-        model = self.model_config
-
-        def fn(params, arena, tokens0, starts0, live, pt, limit, budgets,
-               eos_ids, temp, top_p, rng):
-            alive0 = live.astype(bool)
-            counts0 = jnp.zeros_like(starts0)
-
-            def body(carry, i):
-                tokens, starts, arena, rng, alive, counts = carry
-                live_i = (alive & (i < limit)).astype(jnp.int32)
-                logits, arena = ragged_forward(
-                    model, params, arena, tokens[:, None], live_i, starts,
-                    pt, use_pallas=False, moe_fn=self._moe_fn)
-                nxt, rng = _sample_tokens(logits, mode, temp, top_p, rng)
-                counts = counts + live_i
-                alive = (live_i > 0) & (nxt != eos_ids) & \
-                    (counts < budgets)
-                return (nxt, starts + live_i, arena, rng, alive,
-                        counts), nxt
-
-            (_, _, arena, rng, _, counts), ys = lax.scan(
-                body, (tokens0, starts0, arena, rng, alive0, counts0),
-                jnp.arange(sb, dtype=jnp.int32))
-            return ys, counts, rng, arena
-
-        jitted = self._name_megastep(fn, key)
         self._fused_fns[key] = jitted
         return jitted
 
-    def _fused_decode(self, uids: List[int], first_tokens: List[int],
-                      steps: int, mode,
-                      budgets: Optional[List[int]] = None,
-                      eos_token_id: Optional[int] = None,
-                      sb: Optional[int] = None):
-        """Pre-allocate KV pages for the decode window, then run the
-        fused loop. Returns ``(tok_mat [steps, n], counts [n])`` — row
-        ``j`` of the batch emitted ``counts[j]`` valid tokens
-        (``tok_mat[:counts[j], j]``) and wrote exactly that many KV
-        entries; rows stop early on their per-row ``budgets[j]`` or on
-        sampling ``eos_token_id`` (both optional — default is the old
-        run-out-the-window behavior). ``sb`` overrides the scan-length
-        bucket (megastep uses pow2 buckets so chunked RNG streams line
-        up; the generate path keeps ``_FUSED_STEP_BUCKET`` multiples).
-        Raises FusedDecodeUnavailable when length (doomed=True — the
-        stepwise loop would also overrun max_seq_len) or page capacity
-        (doomed=False — fall back) can't cover the full decode."""
-        n = len(uids)
-        if n == 0:
-            raise FusedDecodeUnavailable("empty batch")
-        if self.model_config.typed:
-            # generate() / serve() fall back to the stepwise loop
-            raise FusedDecodeUnavailable(
-                "the fused decode loop is not built for a typed layer "
-                "stack (DecoderConfig.layer_kinds)")
-        nb = _bucket(n)
-        bs = self.state.allocator.block_size
-        # per-row effective window: a row never runs past its own budget,
-        # so pages (and the doomed check) only need to cover min(steps,
-        # budget) — without this, per-row budgets shorter than the chunk
-        # would pre-allocate pages the dead-masked tail never fills
-        eff = [steps if budgets is None else min(steps, int(budgets[j]))
-               for j in range(n)]
-        need: List[int] = []
-        for u, e in zip(uids, eff):
-            seq = self.state.seqs[u]
-            final = len(seq.tokens) + e
-            if final > self.config.max_seq_len:
-                raise FusedDecodeUnavailable(
-                    f"sequence {u} would reach {final} tokens, over "
-                    f"max_seq_len={self.config.max_seq_len}", doomed=True)
-            need.append(-(-final // bs) - len(seq.blocks))
-        if sum(need) > self.state.allocator.free_blocks:
-            raise FusedDecodeUnavailable("KV arena too full to pre-"
-                                         "allocate the decode window")
-        for u, k in zip(uids, need):
-            if k > 0:
-                self.state.seqs[u].blocks.extend(
-                    self.state.allocator.allocate(k))
+    # -- convenience generation loop ---------------------------------------
 
-        if sb is None:
-            sb = -(-steps // self._FUSED_STEP_BUCKET) * \
-                self._FUSED_STEP_BUCKET
-        tokens0 = np.zeros((nb,), np.int32)
-        tokens0[:n] = first_tokens
-        starts0 = np.zeros((nb,), np.int32)
-        live = np.zeros((nb,), np.int32)
-        live[:n] = 1
-        # padding rows carry budget 0 (they are dead from step 0 anyway);
-        # eos -1 never matches a sampled id, so "no eos" needs no
-        # separate compile
-        bud = np.zeros((nb,), np.int32)
-        bud[:n] = eff
-        eos = np.full((nb,), -1, np.int32)
-        if eos_token_id is not None:
-            eos[:n] = int(eos_token_id)
-        pt = self._page_table(uids, nb)
-        for i, u in enumerate(uids):
-            starts0[i] = len(self.state.seqs[u].tokens)
-        # slice the page table to the pages this batch can actually
-        # touch (bucketed to limit recompiles): the history gather
-        # fetches mb*block_size keys per row, and the full max_seq_len
-        # table width costs ~2x the true KV traffic on typical mixes
-        mb_need = int(-(-(int(starts0.max()) + steps) // bs))
-        mb_b = min(self.mb, -(-mb_need // 4) * 4)
-        pt = pt[:, :mb_b]
-        ys, counts, self._rng_dev, self.arena = self._fused_decode_fn(
-            nb, sb, mode, mb_b)(
-                self.params, self.arena, jnp.asarray(tokens0),
-                jnp.asarray(starts0), jnp.asarray(live), jnp.asarray(pt),
-                jnp.int32(steps), jnp.asarray(bud), jnp.asarray(eos),
-                jnp.float32(self._temperature),
-                jnp.float32(self._top_p), self._rng_dev)
-        _dispatch_count("dispatch/host_calls")
-        _dispatch_count("dispatch/scan_steps", sb)
-        # scan iterations past `limit` run with every row dead — pure
-        # bucket-rounding waste dstpu-explain surfaces when it dominates
-        _dispatch_count("dispatch/dead_steps", sb - steps)
-        ys, counts = jax.device_get((ys, counts))    # ONE sync
-        return np.asarray(ys)[:steps, :n], np.asarray(counts)[:n]
-
-    # -- convenience serving loop ------------------------------------------
-
-    def _consume_first(self, u: int, t: int, seqs, remaining, cur_tok,
-                       active: List[int], eos_token_id) -> None:
-        """Shared post-sample bookkeeping: append token t to sequence u,
-        spend budget, retire (flush) on exhaustion/eos, else keep u
-        active with t as the next fed token."""
-        seqs[u].append(t)
-        remaining[u] -= 1
-        if remaining[u] <= 0 or (eos_token_id is not None
-                                 and t == eos_token_id):
-            self.flush(u)
-        else:
-            active.append(u)
-            cur_tok[u] = t
-
-    def _validate_lengths(self, prompts, budget_list, caller: str) -> None:
+    def _validate_lengths(self, prompts, budget_list) -> None:
         """Fail BEFORE any compute when a request cannot fit max_seq_len
         even in principle — the chunked loop would otherwise burn most
         of the workload and then discard every sequence's output."""
@@ -1665,150 +1447,9 @@ class RaggedInferenceEngineTPU:
             total = len(np.asarray(p).reshape(-1)) + max(0, m)
             if total > self.config.max_seq_len:
                 raise ValueError(
-                    f"{caller}(): request {i} would reach {total} tokens,"
+                    f"generate(): request {i} would reach {total} tokens,"
                     f" over max_seq_len={self.config.max_seq_len}; lower "
                     f"max_new_tokens or raise max_seq_len")
-
-    def _run_fused_chunk(self, active: List[int], cur_tok: Dict[int, int],
-                         remaining: Dict[int, int],
-                         seqs: Dict[int, list], eos_token_id, mode):
-        """One device-resident decode chunk over ``active`` rows:
-        decode, consume, retire finished sequences (flush). Mutates
-        cur_tok/remaining/seqs; returns (still_active, None) or
-        (active, exc) when the fused path is unavailable."""
-        chunk = min(self._FUSED_STEP_BUCKET,
-                    max(remaining[u] for u in active))
-        try:
-            tok_mat, _counts = self._fused_decode(
-                active, [cur_tok[u] for u in active], chunk, mode,
-                budgets=[remaining[u] for u in active],
-                eos_token_id=eos_token_id)
-        except FusedDecodeUnavailable as e:
-            return active, e
-        still: List[int] = []
-        for j, u in enumerate(active):
-            take = min(chunk, remaining[u])
-            done = remaining[u] <= chunk
-            fed = cur_tok[u]
-            for s_i in range(take):
-                t = int(tok_mat[s_i, j])
-                seqs[u].append(t)
-                remaining[u] -= 1
-                if eos_token_id is not None and t == eos_token_id:
-                    done = True
-                    break
-            if done:
-                self.flush(u)
-            else:
-                # the chunk's KV is already in the arena (pages
-                # pre-allocated by _fused_decode): advance the host
-                # descriptor to match — the tokens whose KV landed are
-                # the fed token plus all but the last sampled one,
-                # which seeds the next chunk
-                seq = self.state.seqs[u]
-                seq.tokens.extend([fed] + [int(t) for t in
-                                           tok_mat[:chunk - 1, j]])
-                seq.seen_tokens = len(seq.tokens)
-                still.append(u)
-                cur_tok[u] = int(tok_mat[chunk - 1, j])
-        return still, None
-
-    def serve(self, prompts, max_new_tokens: Union[int, List[int]] = 64,
-              max_concurrency: int = 16,
-              eos_token_id: Optional[int] = None,
-              temperature: float = 0.0, top_k: int = 0,
-              top_p: float = 1.0) -> List[np.ndarray]:
-        """Continuous-batching SERVER loop over a request stream.
-
-        Processes ``prompts`` (any number) with at most
-        ``max_concurrency`` sequences resident: queued requests are
-        admitted the moment a slot frees, so the decode batch stays full
-        while long-tail requests run out their budgets. This is the
-        workload shape behind the reference FastGen throughput claim
-        (blogs/deepspeed-fastgen: 2.3x effective throughput) — a padded
-        static engine must run each batch to ITS longest request and
-        only then start the next batch. Returns full sequences in input
-        order.
-        """
-        from collections import deque
-        if temperature == 0.0:
-            mode = ("argmax",)
-        else:
-            mode = ("sample", int(top_k), top_p < 1.0)
-            self._temperature = float(temperature)
-            self._top_p = float(top_p)
-        n = len(prompts)
-        if isinstance(max_new_tokens, (int, np.integer)):
-            budget_list = [int(max_new_tokens)] * n
-        else:
-            if len(max_new_tokens) != n:
-                raise ValueError("per-sequence max_new_tokens must match "
-                                 "the number of prompts")
-            budget_list = [int(m) for m in max_new_tokens]
-        self._validate_lengths(prompts, budget_list, "serve")
-        base = max(self.state.seqs.keys(), default=-1) + 1
-        # zero-budget requests pass through untouched
-        queue = deque(i for i in range(n) if budget_list[i] > 0)
-        seqs: Dict[int, list] = {
-            base + i: list(np.asarray(prompts[i]).reshape(-1)
-                           .astype(np.int32)) for i in range(n)}
-        remaining: Dict[int, int] = {}
-        cur_tok: Dict[int, int] = {}
-        active: List[int] = []
-        try:
-            while queue or active:
-                admit: List[int] = []
-                while queue and len(active) + len(admit) < max_concurrency:
-                    i = queue[0]
-                    # admission is capacity-gated so one oversized
-                    # request can't abort the stream mid-flight; it
-                    # waits for retirements to free pages instead
-                    if not self.state.can_schedule(len(seqs[base + i])):
-                        break
-                    queue.popleft()
-                    u = base + i
-                    remaining[u] = budget_list[i]
-                    admit.append(u)
-                if queue and not admit and not active:
-                    i = queue[0]
-                    raise ValueError(
-                        f"serve(): request {i} ({len(seqs[base + i])} "
-                        f"tokens) cannot be scheduled even on an empty "
-                        f"engine; raise num_blocks/max_sequences")
-                if admit:
-                    pending = self._put_tokens(
-                        admit, [seqs[u] for u in admit], mode)
-                    for u in admit:
-                        self._consume_first(u, pending[u], seqs,
-                                            remaining, cur_tok, active,
-                                            eos_token_id)
-                if not active:
-                    continue
-                if os.environ.get("DSTPU_NO_FUSED_DECODE"):
-                    err: Optional[Exception] = FusedDecodeUnavailable(
-                        "disabled")
-                else:
-                    active, err = self._run_fused_chunk(
-                        active, cur_tok, remaining, seqs, eos_token_id,
-                        mode)
-                if err is not None:
-                    # stepwise fallback for one token per active row,
-                    # then re-enter the loop (slots may free / arena
-                    # pressure may ease)
-                    pending = self._put_tokens(
-                        active, [[cur_tok[u]] for u in active], mode)
-                    still: List[int] = []
-                    for u in active:
-                        self._consume_first(u, pending[u], seqs,
-                                            remaining, cur_tok, still,
-                                            eos_token_id)
-                    active = still
-        except Exception:
-            for u in list(self.state.seqs):
-                if u >= base:
-                    self.flush(u)
-            raise
-        return [np.asarray(seqs[base + i], np.int32) for i in range(n)]
 
     def generate(self, prompts, max_new_tokens: Union[int, List[int]] = 64,
                  eos_token_id: Optional[int] = None,
@@ -1817,13 +1458,24 @@ class RaggedInferenceEngineTPU:
         """Continuous-batching generation (greedy by default; temperature/
         top-k/top-p sampled on device). ``prompts`` is a list of 1-D int
         arrays (ragged lengths); ``max_new_tokens`` may be per-sequence.
-        Returns the full token sequences. Sequences join/leave the batch
-        independently — the continuous batching the padded v1 engine
-        can't do: the fused decode runs in device-resident CHUNKS and
-        finished sequences RETIRE between chunks (budget exhausted or
-        eos), so a long-tail generation mix only pays for the tokens it
-        actually produces, while a padded static batch computes every
-        row out to the longest request."""
+        Returns the full token sequences. A client of
+        :meth:`step_with_budget`, as the serving frontend is, in ROUNDS:
+        step until the scheduler has nothing queued, then feed every
+        row's last token back at once. The call's rows therefore finish
+        their prompts before any of them decodes and enter decode
+        together; a decode round is a megastep of up to
+        ``_FUSED_STEP_BUCKET`` device-resident tokens a row, and finished
+        sequences RETIRE between rounds (budget exhausted or eos), so a
+        long-tail generation mix only pays for the tokens it actually
+        produces, while a padded static batch computes every row out to
+        the longest request — the continuous batching the padded v1
+        engine can't do."""
+        if any(seq.pending for seq in self.state.seqs.values()):
+            # the loop below runs whatever the scheduler holds and keeps
+            # only its own rows' tokens: another caller's would be lost
+            raise RuntimeError(
+                "generate() while sequences of the streaming put() API "
+                "have tokens queued; step them to the end first")
         if temperature == 0.0:
             mode = ("argmax",)
         else:
@@ -1836,92 +1488,45 @@ class RaggedInferenceEngineTPU:
         base = max(self.state.seqs.keys(), default=-1) + 1
         uids = [base + i for i in range(len(prompts))]
         if isinstance(max_new_tokens, (int, np.integer)):
-            budgets = {u: int(max_new_tokens) for u in uids}
+            remaining = {u: int(max_new_tokens) for u in uids}
         else:
             if len(max_new_tokens) != len(prompts):
                 raise ValueError("per-sequence max_new_tokens must match "
                                  "the number of prompts")
-            budgets = {u: int(m) for u, m in zip(uids, max_new_tokens)}
+            remaining = {u: int(m) for u, m in zip(uids, max_new_tokens)}
         if eos_token_id is None:
             # without eos there is no early exit: a request that cannot
             # fit max_seq_len must fail BEFORE any compute, not after
-            # the chunked loop has burned most of the workload
-            self._validate_lengths(prompts, [budgets[u] for u in uids],
-                                   "generate")
+            # the loop has burned most of the workload
+            self._validate_lengths(prompts, [remaining[u] for u in uids])
         seqs = {u: list(np.asarray(p).reshape(-1).astype(np.int32))
                 for u, p in zip(uids, prompts)}
-        remaining = dict(budgets)
-        pending = self._put_tokens(uids, [seqs[u] for u in uids], mode)
-        # fast path: every sequence is now in pure decode — run
-        # device-resident chunks (one upload + one fetch per chunk
-        # instead of 2+ round-trips per token), retiring finished rows
-        # between chunks. DSTPU_NO_FUSED_DECODE restores the stepwise
-        # loop.
-        if uids and len(pending) == len(uids) \
-                and max(remaining.values(), default=0) > 1 \
-                and not os.environ.get("DSTPU_NO_FUSED_DECODE"):
-            active: List[int] = []
-            cur_tok: Dict[int, int] = {}
-            for u in uids:
-                self._consume_first(u, pending[u], seqs, remaining,
-                                    cur_tok, active, eos_token_id)
-            fused_failed = False
-            while active and not fused_failed:
-                active, err = self._run_fused_chunk(
-                    active, cur_tok, remaining, seqs, eos_token_id, mode)
-                if err is not None:
-                    if err.doomed and eos_token_id is None:
-                        # the stepwise loop would hit the same wall mid-
-                        # generation, after burning steps and LEAKING
-                        # the sequences' pages — fail cleanly up front
-                        for u in uids:
-                            if u in self.state.seqs:
-                                self.flush(u)
-                        raise ValueError(
-                            f"generate(): {err}; lower max_new_tokens or "
-                            f"raise max_seq_len") from err
-                    log_dist(f"fused decode unavailable ({err}); using "
-                             f"the stepwise loop")
-                    fused_failed = True
-            if not fused_failed:
-                for u in uids:
-                    if u in self.state.seqs:
-                        self.flush(u)
-                return [np.asarray(seqs[u], np.int32) for u in uids]
-            # stepwise continuation from the current chunked state: the
-            # rows still active have their last sampled token NOT yet
-            # fed — exactly the `pending` shape the loop below consumes.
-            # (The first-token appends already happened above, so hand
-            # the loop a pending map of the still-unfed tokens only.)
-            pending = {u: cur_tok[u] for u in active}
-            # the loop's first action is to append pending tokens; ours
-            # are already appended — drop them from seqs to avoid the
-            # double-append, keeping remaining consistent
-            for u in active:
-                seqs[u].pop()
-                remaining[u] += 1
+        eos_ids = None if eos_token_id is None else \
+            dict.fromkeys(uids, int(eos_token_id))
         try:
-            while pending:
-                active_uids, toks = [], []
-                for u, t in list(pending.items()):
-                    seqs[u].append(t)
-                    remaining[u] -= 1
-                    if remaining[u] <= 0 or (eos_token_id is not None
-                                             and t == eos_token_id):
+            self._put_validated(uids, [seqs[u] for u in uids])
+            fed = True
+            while fed:
+                got: Dict[int, List[int]] = {}
+                while (out := self.step_with_budget(
+                        mode=mode, max_steps=self._FUSED_STEP_BUCKET,
+                        row_limits=remaining, eos_ids=eos_ids)) is not None:
+                    got.update(out)     # a row reports once a round
+                fed = False
+                for u, toks in got.items():
+                    seqs[u].extend(toks)
+                    remaining[u] -= len(toks)
+                    # the step ends a row at its eos or at its budget, so
+                    # only the last token can be either
+                    if remaining[u] <= 0 or toks[-1] == eos_token_id:
                         self.flush(u)
-                        del pending[u]
                     else:
-                        active_uids.append(u)
-                        toks.append([t])
-                if not active_uids:
-                    break
-                pending = self._put_tokens(active_uids, toks, mode)
-        except Exception:
-            # mid-loop failures (arena exhausted, over-length) must not
-            # leak this call's sequences — their pages/slots would be
-            # lost to every later request
+                        self._put_validated([u], [toks[-1:]])
+                        fed = True
+        finally:
+            # a failure mid-loop (arena exhausted, over-length) must not
+            # leak this call's sequences — their pages/slots would be lost
+            # to every later request; after a whole run none is left
             for u in uids:
-                if u in self.state.seqs:
-                    self.flush(u)
-            raise
+                self.flush(u)
         return [np.asarray(seqs[u], np.int32) for u in uids]

@@ -20,7 +20,7 @@ What this buys on TPU — measured honestly on v5e (1.27B llama, batch
   (0.71x). A future >2x win needs int8 DMA to outpace bf16 — revisit
   per libtpu generation.
 - **int4**: quarter the weight HBM; end-to-end serving measured
-  slightly FASTER than bf16 on v5e (bench_inference, 1B llama, 8
+  slightly FASTER than bf16 on v5e (a pre-round record, 1B llama, 8
   mixed prompts, 32 new tokens: padded 870 vs 831 tok/s, ragged 700
   vs 606) — the nibble unpack is free next to the halved weight DMA.
   15-level grid though: validate task quality before shipping int4.

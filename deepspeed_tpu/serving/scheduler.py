@@ -1,7 +1,7 @@
 """SplitFuse token-budget scheduling policy.
 
-Generalizes the selection logic that ``RaggedScheduler.next_batch`` /
-``engine_v2._run_fused_chunk`` hard-coded into a policy object the
+Generalizes the selection logic that ``RaggedScheduler.next_batch``
+hard-codes into a policy object the
 frontend installs on the engine's scheduler (``scheduler.policy = ...``).
 Each engine step packs a fixed token budget mixing single-token decodes of
 running sequences with prefill chunks of newly admitted ones (Dynamic

@@ -10,7 +10,7 @@ noise band, exit-code-first so it drops straight into CI::
 Each side may be:
 
 - **BENCH JSONL** — lines of ``{"metric": ..., "value": ..., "unit":
-  ...}`` as printed by ``bench.py`` / ``bench_inference.py`` (a driver
+  ...}`` as printed by ``bench.py`` (a driver
   wrapper object with the stdout under ``"tail"`` also works);
 - **metric history** — a :mod:`~deepspeed_tpu.telemetry.timeseries`
   JSONL file (detected by the ``"m"`` record key). History compare

@@ -630,10 +630,9 @@ def dispatch_waste() -> Optional[Dict[str, float]]:
     counters, or None when no fused launch has run in this process.
 
     ``dead_fraction`` is the share of scan iterations burned on bucket
-    rounding: fused scan lengths round up (``_FUSED_STEP_BUCKET``
-    multiples on the generate path, pow2 on megasteps) so distinct window
-    sizes share compiles, and every iteration past the traced ``limit``
-    runs the full model forward with all rows dead."""
+    rounding: fused scan lengths round up to a power of two so distinct
+    window sizes share compiles, and every iteration past the traced
+    ``limit`` runs the full model forward with all rows dead."""
     scan = _registry.get("dispatch/scan_steps")
     dead = _registry.get("dispatch/dead_steps")
     if scan is None or not scan.value:

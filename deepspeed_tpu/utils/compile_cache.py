@@ -8,7 +8,7 @@ moves between runs never hits. So the rule is one of two fixed places —
 - unset: ``default_dir``, one fixed path inside the checkout
   (``<repo>/.jax_cache``, git-ignored). Never a temp dir, a pid or a time.
 
-Entry points (``chip_smoke.py``, ``bench.py``, ``bench_inference.py``,
+Entry points (``chip_smoke.py``, ``bench.py``, ``benchmark/run.py``,
 ``examples/_common.py``, ``tests/conftest.py``) call
 :func:`enable_compile_cache` once, before their first compile.
 """

@@ -7,7 +7,8 @@ K ∈ {1, 8, 32} — the ISSUE acceptance bar), EOS retires a row mid-window
 without trailing garbage, retirement/cancellation happen at megastep
 boundaries, and the sampled-mode RNG stream is invariant to how the
 window is chunked (the fused scan splits the rng once per scan slot,
-dead or not, and megastep scan lengths are pow2 buckets).
+dead or not, and megastep scan lengths are pow2 buckets). generate()
+is one more client of the same window.
 
 All deterministic under JAX_PLATFORMS=cpu (conftest forces it)."""
 
@@ -222,12 +223,12 @@ def test_dead_steps_counter_and_note(devices):
     eng = _engine(devices)
     scan0 = registry.counter("dispatch/scan_steps").value
     dead0 = registry.counter("dispatch/dead_steps").value
-    # generate() buckets the fused scan to _FUSED_STEP_BUCKET multiples:
-    # 5 decode steps after the first token → 27 dead iterations
+    # generate()'s decode window is a megastep, its scan a power of two:
+    # 5 decode steps after the first token → 8 slots, 3 dead iterations
     eng.generate([_prompts(1)[0]], max_new_tokens=6)
     scan_d = registry.counter("dispatch/scan_steps").value - scan0
     dead_d = registry.counter("dispatch/dead_steps").value - dead0
-    assert scan_d == 32 and dead_d == 27
+    assert scan_d == 8 and dead_d == 3
     w = explain.dispatch_waste()
     assert w is not None and 0.0 < w["dead_fraction"] < 1.0
     # the process-wide fraction includes other tests' launches; the note

@@ -400,6 +400,28 @@ def test_what_is_not_built_refuses_by_name(tiny):
     assert list(stepped) == [3] and len(stepped[3]) == 1
 
 
+def test_generate_on_a_typed_stack_launches_no_megastep(tiny):
+    """generate() asks every step for a decode window; a typed stack has
+    no fused loop, so its decode-only selections take the stepwise
+    program, and the tokens are the reference's argmax."""
+    from deepspeed_tpu.telemetry.registry import registry
+    cfg, params, w = tiny
+    eng = _engine(cfg, params)
+    launches = registry.counter("dispatch/megastep_launches")
+    before = launches.value
+    prompt = np.random.default_rng(2).integers(0, VOCAB, 11).tolist()
+    (out,) = eng.generate([prompt], max_new_tokens=5)
+    assert launches.value == before and not eng._fused_fns
+    assert {fn.__name__ for fn in eng._step_fns.values()} == {
+        "serve_fresh_r1_c8", "serve_split_r1_c8", "serve_decode_r1"}
+    assert out[:11].tolist() == prompt and len(out) == 16
+    ref, dev = reference(), jax.devices()[0]
+    for i in range(11, 16):
+        logits = ref.logits_of(w, params, out[:i].tolist(), dev)[-1]
+        assert logits[out[i]] > logits.max() - TOL
+    assert not eng.state.seqs and eng.state.allocator.free_blocks == 32
+
+
 def test_config_from_hf_reads_the_cells_file_and_the_published_file():
     """(e) the benchmark's configuration file (7 layers, 16 of 256 experts
     held, an eighth of the vocabulary) and the source's config as

@@ -232,36 +232,87 @@ def test_ragged_sampling_modes(devices):
     assert not np.array_equal(s1, greedy)       # sampling actually samples
 
 
-def test_fused_decode_matches_stepwise(devices, monkeypatch):
+def _generate_stepwise(eng, prompts, budgets, temperature=0.0, top_k=0,
+                       top_p=1.0):
+    """generate()'s contract driven from outside, ONE token a step: the
+    prompts are queued, ``step_with_budget()`` (no megastep armed) runs
+    whatever the scheduler packs until nothing is queued, then every
+    row's token is fed back at once, until its budget is spent — the
+    rounds of generate(), so rows enter decode together. The reference
+    generate()'s fused windows are held to."""
+    mode = ("argmax",)
+    if temperature:
+        mode = ("sample", int(top_k), top_p < 1.0)
+        eng._temperature, eng._top_p = float(temperature), float(top_p)
+    uids = list(range(len(prompts)))
+    seqs = {u: [int(t) for t in p] for u, p in zip(uids, prompts)}
+    left = dict(zip(uids, budgets))
+    eng.scheduler.put(uids, [seqs[u] for u in uids])
+    fed = True
+    while fed:
+        got = {}
+        while (out := eng.step_with_budget(mode=mode)) is not None:
+            got.update(out)
+        fed = False
+        for u, tok in got.items():
+            seqs[u].append(tok)
+            left[u] -= 1
+            if left[u] <= 0:
+                eng.flush(u)
+            else:
+                eng.scheduler.put([u], [[tok]])
+                fed = True
+    return [np.asarray(seqs[u], np.int32) for u in uids]
+
+
+#: ragged prompts of one and of three prefill chunks, in every mode: the
+#: sampled streams must agree through the mixed prefill steps too
+_FUSED_VS_STEPWISE = {
+    "argmax": {"temperature": 0.0},
+    "top_k": {"temperature": 0.8, "top_k": 8},
+    "top_p": {"temperature": 0.7, "top_p": 0.9},
+}
+
+
+@pytest.mark.parametrize("case", list(_FUSED_VS_STEPWISE))
+def test_fused_decode_matches_stepwise(devices, case):
     """The fused on-device decode loop must produce token-for-token the
-    same output as the stepwise loop (argmax and sampled modes; the
-    sampled comparison pins the device RNG via a fresh engine)."""
+    same output as the engine stepped one token at a time (argmax and
+    sampled modes; the sampled comparison pins the device RNG via a fresh
+    engine)."""
     build_mesh(data=1, devices=jax.devices()[:1])
     cfg = llama3_config("tiny", max_seq_len=128, vocab_size=256)
     from deepspeed_tpu.models.transformer import init_params
+    from deepspeed_tpu.telemetry.registry import registry
     params = init_params(cfg, jax.random.PRNGKey(5))
     rng = np.random.default_rng(6)
-    prompts = [rng.integers(0, 256, size=(n,), dtype=np.int32)
-               for n in (7, 19)]
     eng_cfg = {"dtype": "float32", "num_blocks": 32, "block_size": 16,
                "max_seq_len": 96, "prefill_chunk": 8,
                "max_batch_tokens": 64}
+    launches = registry.counter("dispatch/megastep_launches")
+    kwargs = _FUSED_VS_STEPWISE[case]
+    prompts = [rng.integers(0, 256, size=(n,), dtype=np.int32)
+               for n in (7, 19)]
 
-    for kwargs in ({"temperature": 0.0},
-                   {"temperature": 0.8, "top_k": 8},
-                   {"temperature": 0.7, "top_p": 0.9}):
-        fused_eng = RaggedInferenceEngineTPU(
-            cfg, eng_cfg, params=params, rng=jax.random.PRNGKey(1))
-        fused = fused_eng.generate(prompts, max_new_tokens=8, **kwargs)
+    fused_eng = RaggedInferenceEngineTPU(
+        cfg, eng_cfg, params=params, rng=jax.random.PRNGKey(1))
+    before = launches.value
+    fused = fused_eng.generate(prompts, max_new_tokens=8, **kwargs)
+    # a decode-only selection goes through the one fused program
+    assert launches.value > before
+    assert fused_eng._fused_fns and all(
+        fn.__name__.startswith("serve_megastep_r2_k8_")
+        for fn in fused_eng._fused_fns.values())
+    assert fused_eng.last_program == "megastep"
 
-        monkeypatch.setenv("DSTPU_NO_FUSED_DECODE", "1")
-        step_eng = RaggedInferenceEngineTPU(
-            cfg, eng_cfg, params=params, rng=jax.random.PRNGKey(1))
-        stepwise = step_eng.generate(prompts, max_new_tokens=8, **kwargs)
-        monkeypatch.delenv("DSTPU_NO_FUSED_DECODE")
+    step_eng = RaggedInferenceEngineTPU(
+        cfg, eng_cfg, params=params, rng=jax.random.PRNGKey(1))
+    before = launches.value
+    stepwise = _generate_stepwise(step_eng, prompts, [8, 8], **kwargs)
+    assert launches.value == before and not step_eng._fused_fns
 
-        for f, s in zip(fused, stepwise):
-            np.testing.assert_array_equal(f, s)
+    for f, s in zip(fused, stepwise):
+        np.testing.assert_array_equal(f, s)
 
 
 def test_fused_decode_eos_truncation(devices):
@@ -290,29 +341,38 @@ def test_fused_decode_eos_truncation(devices):
 
 
 def test_fused_decode_falls_back_when_unavailable(devices, monkeypatch):
-    """When pre-allocation can't cover the decode window, generate()
-    falls back to the stepwise loop instead of failing."""
-    from deepspeed_tpu.inference.engine_v2 import FusedDecodeUnavailable
+    """When the arena cannot cover a decode window's pages the megastep
+    declines (None) and generate() goes on one token a step, with the
+    full output, instead of failing; the window is taken again once a
+    retired row's pages make room, and every page is free at the end."""
     build_mesh(data=1, devices=jax.devices()[:1])
     cfg = llama3_config("tiny", max_seq_len=128, vocab_size=256)
-    eng = RaggedInferenceEngineTPU(
-        cfg, {"dtype": "float32", "num_blocks": 32, "block_size": 16,
-              "max_seq_len": 64, "prefill_chunk": 8,
-              "max_batch_tokens": 64}, rng=jax.random.PRNGKey(0))
-    # the real raise: window overruns max_seq_len
-    eng.state.extend(99, list(range(10)))
-    with pytest.raises(FusedDecodeUnavailable, match="tokens"):
-        eng._fused_decode([99], [1], steps=60, mode=("argmax",))
-    eng.flush(99)
+    eng_cfg = {"dtype": "float32", "num_blocks": 5, "block_size": 8,
+               "max_seq_len": 64, "prefill_chunk": 8,
+               "max_batch_tokens": 64}
+    eng = RaggedInferenceEngineTPU(cfg, eng_cfg, rng=jax.random.PRNGKey(0))
+    # two rows of 2 pages each leave 1 free; row 1's window of 23 tokens
+    # wants 2 more. Row 0 retires after 7 steps and its pages cover it
+    prompts, budgets = [[1] * 8, [2] * 8], [8, 24]
+    real, windows = eng._try_megastep, []
 
-    # end-to-end: force the fast path to decline and check the stepwise
-    # loop still produces the full output
-    monkeypatch.setattr(
-        eng, "_fused_decode",
-        lambda *a, **k: (_ for _ in ()).throw(
-            FusedDecodeUnavailable("forced")))
-    outs = eng.generate([[1, 2, 3]], max_new_tokens=8)
-    assert len(outs[0]) == 11
+    def spy(*args):
+        windows.append(real(*args))
+        return windows[-1]
+
+    monkeypatch.setattr(eng, "_try_megastep", spy)
+    outs = eng.generate(prompts, max_new_tokens=budgets)
+    assert [len(o) for o in outs] == [16, 32]
+    assert windows[0] is None and len(windows) > 2
+    assert windows[-1] is not None and len(windows[-1][1]) == 16
+    assert not eng.state.seqs
+    assert eng.state.allocator.free_blocks == 5
+
+    ref = _generate_stepwise(
+        RaggedInferenceEngineTPU(cfg, eng_cfg, params=eng.params),
+        prompts, budgets)
+    for got, want in zip(outs, ref):
+        np.testing.assert_array_equal(got, want)
 
 
 def test_stepwise_failure_does_not_leak_pages(devices):
@@ -678,7 +738,7 @@ def test_flash_attention_with_lse_matches_xla(devices):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_chunked_retirement_per_seq_budgets(devices, monkeypatch):
+def test_chunked_retirement_per_seq_budgets(devices):
     """Per-sequence max_new_tokens with chunk-boundary retirement must
     produce token-for-token the same output as solo dense generation —
     across MULTIPLE fused chunks (budgets straddle the 32-step chunk
@@ -709,47 +769,15 @@ def test_chunked_retirement_per_seq_budgets(devices, monkeypatch):
     # all pages released after generate
     assert len(v2.state.seqs) == 0
 
-    # the stepwise path agrees too (fused disabled)
-    monkeypatch.setenv("DSTPU_NO_FUSED_DECODE", "1")
-    outs2 = v2.generate(prompts, max_new_tokens=budgets)
+    # one token a step agrees too (no window armed)
+    outs2 = _generate_stepwise(v2, prompts, budgets)
+    assert v2.state.allocator.free_blocks == 96
     for a, b in zip(outs, outs2):
         np.testing.assert_array_equal(a, b)
 
 
-def test_serve_stream_matches_solo(devices):
-    """serve(): a request stream at max_concurrency < n must produce
-    token-for-token solo-engine outputs, admit queued requests as slots
-    free, and release every page at the end."""
-    build_mesh(data=1, devices=jax.devices()[:1])
-    cfg = llama3_config("tiny", max_seq_len=256, vocab_size=256)
-    from deepspeed_tpu.models.transformer import init_params
-    params = init_params(cfg, jax.random.PRNGKey(5))
-
-    rng = np.random.default_rng(9)
-    n = 10
-    prompts = [rng.integers(0, 256, size=(int(l),), dtype=np.int32)
-               for l in rng.integers(4, 24, size=n)]
-    budgets = [int(b) for b in rng.integers(2, 40, size=n)]
-
-    v2 = RaggedInferenceEngineTPU(
-        cfg, {"dtype": "float32", "num_blocks": 64, "block_size": 16,
-              "max_seq_len": 128, "prefill_chunk": 8,
-              "max_batch_tokens": 64, "max_sequences": 8},
-        params=params)
-    outs = v2.serve(prompts, max_new_tokens=budgets, max_concurrency=4)
-
-    v1 = init_inference(cfg, {"dtype": "float32"}, params=params)
-    for p, m, got in zip(prompts, budgets, outs):
-        assert len(got) == len(p) + m
-        ref = v1.generate(p[None, :], max_new_tokens=m)[0]
-        np.testing.assert_array_equal(got, ref[:len(p) + m])
-    assert len(v2.state.seqs) == 0
-    assert v2.state.allocator.free_blocks == 64
-
-
-def test_serve_validation_and_zero_budget(devices):
-    """Oversized requests fail BEFORE any compute; zero-budget requests
-    pass through untouched."""
+def test_generate_refuses_oversized_before_compute(devices):
+    """Oversized requests fail BEFORE any compute."""
     build_mesh(data=1, devices=jax.devices()[:1])
     cfg = llama3_config("tiny", max_seq_len=128, vocab_size=256)
     v2 = RaggedInferenceEngineTPU(
@@ -759,13 +787,14 @@ def test_serve_validation_and_zero_budget(devices):
     rng = np.random.default_rng(1)
     big = rng.integers(0, 256, size=(40,), dtype=np.int32)
     with pytest.raises(ValueError, match="over max_seq_len"):
-        v2.serve([big], max_new_tokens=40)
-    with pytest.raises(ValueError, match="over max_seq_len"):
         v2.generate([big], max_new_tokens=40)
-    assert len(v2.state.seqs) == 0
-
-    small = rng.integers(0, 256, size=(6,), dtype=np.int32)
-    outs = v2.serve([small, big], max_new_tokens=[4, 0])
-    assert len(outs[0]) == 10
-    np.testing.assert_array_equal(outs[1], big)   # untouched
-    assert len(v2.state.seqs) == 0
+    assert len(v2.state.seqs) == 0 and not v2._step_fns
+    # generate() keeps only its own rows' tokens, so it refuses to step
+    # a streaming caller's queued tokens away (and leaves them queued)
+    v2.scheduler.put([5], [[1, 2, 3]])
+    with pytest.raises(RuntimeError, match="streaming"):
+        v2.generate([[4, 5]], max_new_tokens=2)
+    assert v2.state.seqs[5].pending == 3 and not v2._step_fns
+    v2.step_with_budget()
+    assert len(v2.generate([[4, 5]], max_new_tokens=2)[0]) == 4
+    assert list(v2.state.seqs) == [5]

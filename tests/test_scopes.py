@@ -134,9 +134,11 @@ STEP_PROGRAMS = {
     "decode": ((1, ("argmax",), False), "serve_decode_r4"),
     "fresh": ((8, ("argmax",), "fresh"), "serve_fresh_r4_c8"),
     "split": ((8, ("argmax",), "split"), "serve_split_r4_c8"),
-    # the pre-three-mode boolean is the fresh program, name and all
-    "legacy_true": ((8, ("argmax",), True), "serve_fresh_r4_c8"),
+    # the single paged read: no batch of the engine's selects it, it is
+    # the reference of tests/test_paged.py and keeps its name
     "paged": ((8, ("argmax",), False), "serve_paged_r4_c8"),
+    "sample_top_k": ((1, ("sample", 5, False), False),
+                     "serve_decode_r4_sample_k5"),
     "logits": ((1, None, False), "serve_decode_r4_logits"),
     "sample": ((1, ("sample", 5, True), False),
                "serve_decode_r4_sample_k5_p"),
@@ -160,8 +162,6 @@ def test_step_program_registers_under_its_own_name(kind, monkeypatch):
     assert jitted.__name__ == name
     assert name in telemetry.compile_monitor.programs()
     assert eng._step_fn(4, cb, mode, fresh) is jitted
-    if kind == "legacy_true":
-        assert eng._step_fn(4, cb, mode, "fresh") is jitted
     assert not compiles
     _ref, args = telemetry.compile_monitor._programs[name]
     table = telemetry.compile_monitor.scopes(name)
@@ -191,6 +191,23 @@ def test_megastep_program_is_named_by_rows_scan_steps_and_page_width():
     table = telemetry.compile_monitor.scopes("serve_megastep_r4_k8_p4")
     assert {"attn_history", "attn_core", "attn_merge", "kv_write", "mlp",
             "sample"} <= {e["scope"] for e in table.values()}
+
+
+def test_the_engine_chooses_no_path_by_the_environment():
+    """Which program a batch runs follows from the batch and the config:
+    the ragged engine reads no environment variable."""
+    import inspect
+    import re
+    from deepspeed_tpu.inference import engine_v2
+    source = inspect.getsource(engine_v2)
+    assert not re.search(r"\benviron\b|\bgetenv\b|^\s*import os\b|DSTPU_",
+                         source, re.M)
+    # ... and the batch alone decides between the three step programs
+    eng = _engine()
+    first = eng._put_tokens([1, 2], [[1, 2, 3], list(range(1, 12))])
+    eng._put_tokens([1], [[first[1]]])
+    assert sorted(fn.__name__ for fn in eng._step_fns.values()) == [
+        "serve_decode_r1", "serve_fresh_r2_c8", "serve_split_r1_c8"]
 
 
 def _dropped_engine_after_a_step():

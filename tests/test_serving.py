@@ -418,6 +418,44 @@ def test_frontend_stream_matches_generate(devices):
     assert fe.cache.pages_cached > 0
 
 
+def test_frontend_stream_matches_solo(devices):
+    """A request stream with fewer sequence slots than requests must
+    produce token-for-token solo dense-engine outputs, admit queued
+    requests as slots free, and release every page at the end."""
+    from deepspeed_tpu.inference.engine import init_inference
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg = llama3_config("tiny", max_seq_len=256, vocab_size=256)
+    from deepspeed_tpu.models.transformer import init_params
+    params = init_params(cfg, jax.random.PRNGKey(5))
+
+    rng = np.random.default_rng(9)
+    n = 10
+    prompts = [rng.integers(0, 256, size=(int(l),), dtype=np.int32)
+               for l in rng.integers(4, 24, size=n)]
+    budgets = [int(b) for b in rng.integers(2, 40, size=n)]
+
+    v2 = RaggedInferenceEngineTPU(
+        cfg, {"dtype": "float32", "num_blocks": 64, "block_size": 16,
+              "max_seq_len": 128, "prefill_chunk": 8,
+              "max_batch_tokens": 64, "max_sequences": 4},
+        params=params)
+    fe = ServingFrontend(v2, enable_prefix_cache=False)
+    resident = []
+    reqs = [fe.submit([int(t) for t in p], max_new_tokens=m)
+            for p, m in zip(prompts, budgets)]
+    while fe.step():
+        resident.append(len(v2.state.seqs))
+    assert max(resident) == 4              # 4 resident, the rest queued
+
+    v1 = init_inference(cfg, {"dtype": "float32"}, params=params)
+    for p, m, req in zip(prompts, budgets, reqs):
+        assert req.state is RequestState.FINISHED
+        ref = v1.generate(p[None, :], max_new_tokens=m)[0]
+        assert req.tokens_out == [int(t) for t in ref[len(p):len(p) + m]]
+    assert len(v2.state.seqs) == 0
+    assert v2.state.allocator.free_blocks == 64
+
+
 def test_frontend_prefix_hit_skips_prefill_steps(devices):
     """Second request with a shared prompt adopts cached pages: its
     sequence starts with seen_tokens > 0 and generates the same tokens."""
