@@ -174,9 +174,13 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     layer loop: the scan carries ``x`` alone, each layer emits its chunk's
     ``k, v`` as scan outputs ([L, n, c, kvh, dh]) and ONE second scan,
     with the arena as its only carry, writes them back. Nothing in a
-    split step reads what the same step wrote, and an arena that rides
-    the layer scan's carry beside a Pallas reader is relaid whole every
-    layer (two arena-shaped copies in the loop body, docs/kernels.md).
+    split step reads what the same step wrote, so no layer's reader waits
+    for an earlier layer's scatter, and the capacity branches take the
+    arena as an operand and return none. The decode program (``c == 1``)
+    reads what it has just written, and carries the arena through its
+    layer scan: scatter and kernel work on the one token-major layout, so
+    the carry aliases (no arena-shaped copy in any step program:
+    docs/kernels.md, tests/test_tpu_compile.py).
     The history reader follows ``use_pallas``: the paged kernel
     (:func:`paged_attention_with_lse`, ``counts = 0``) walks only each
     row's ``ceil(start / block_size)`` live pages; the XLA gather
@@ -208,7 +212,7 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     # the pool is a scan CARRY so decode updates it in place; a stacked
     # per-layer arena would be copied wholesale every step)
     num_layers = cfg.num_layers
-    stride = arena["k"].shape[1] // num_layers          # num_blocks + 1
+    stride = arena["k"].shape[0] // num_layers          # num_blocks + 1
     layers = (params["layers"], jnp.arange(num_layers, dtype=jnp.int32))
 
     def run(capacity):
@@ -312,9 +316,8 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
                                    counts,
                                    trash_block=off + stride - 1), None
 
-        # the loop itself carries the word, so the relayouts the compiler
-        # makes FOR it (the arena into the scatter's layout on entry) are
-        # their consumer's cost in the scope table, not "(no scope)"
+        # the loop itself carries the word, so what the compiler adds FOR
+        # it is its consumer's cost in the scope table, not "(no scope)"
         with jax.named_scope("kv_write"):
             (ak, av), _ = lax.scan(write_back, (arena["k"], arena["v"]),
                                    (ak, av, layers[1]))
@@ -332,9 +335,8 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     same page table and the same token layouts, the layer loop unrolled
     over the list of layers.
 
-    The arena is a flat dict with a token-major pool per attention kind
-    and per K/V (``pa.init_arena_typed``; its scatter writes rows in place,
-    so no step relays a pool): a layer reads and writes its kind's pools
+    The arena is a flat dict with a pool per attention kind and per K/V
+    (``pa.init_arena_typed``): a layer reads and writes its kind's pools
     at the offset of its index AMONG THE LAYERS OF ITS KIND. A window
     layer keeps its whole history in its pages, and READS only the pages
     its window touches (the XLA forms gather that page range; the paged
@@ -369,7 +371,7 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         with jax.named_scope("kv_write"):
             pools[kname], pools[vname] = pa.write_kv(
                 pools[kname], pools[vname], k, v, pt_l, starts, counts,
-                trash_block=trash, token_major=True)
+                trash_block=trash)
 
     def run(capacity):
         """Embedding to final norm at one capacity → (each row's last
@@ -402,11 +404,11 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                         out_h, lse_h = pa.paged_attention_with_lse(
                             q, pools[kname], pools[vname], pt_l, starts,
                             jnp.zeros_like(starts), window=window,
-                            scale=scale, token_major=True)
+                            scale=scale)
                     else:
                         out_h, lse_h = pa.paged_attention_hist_xla(
                             q, pools[kname], pools[vname], pt_l, starts,
-                            window=window, scale=scale, token_major=True)
+                            window=window, scale=scale)
                 with jax.named_scope("attn_core"):
                     out_c, lse_c = pa.causal_attention_with_lse(
                         q, k, v, window=window, scale=scale)
@@ -424,7 +426,7 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                         out, lse = pa.paged_attention_xla(
                             q, pools[kname], pools[vname], pt_l, starts,
                             counts, window=window, scale=scale,
-                            with_lse=True, token_major=True)
+                            with_lse=True)
                     out = tl.apply_sink(out, lse, sink)
             with jax.named_scope("attn_out"):     # ... and tokens again
                 out = lay.to_tokens(out)
@@ -1027,9 +1029,7 @@ class RaggedInferenceEngineTPU:
         dst = self.state.allocator.allocate(1)[0]
         if self._copy_pages_fn is None:
             self._copy_pages_fn = jax.jit(
-                partial(pa.copy_pages,
-                        stride=self.config.num_blocks + 1,
-                        token_major=self.model_config.typed),
+                partial(pa.copy_pages, stride=self.config.num_blocks + 1),
                 donate_argnums=(0,))
         self.arena = self._copy_pages_fn(
             self.arena, jnp.asarray([src_block], jnp.int32),
@@ -1044,21 +1044,26 @@ class RaggedInferenceEngineTPU:
         (the same ids page tables hold); the flat pool stores layer
         ``l``'s copy of page ``b`` at ``l*(nb+1)+b``, so one fancy-index
         gather per {k, v} pulls all ``L`` copies at once. Returns
-        ``{"k", "v"}`` as ``[kvh, L, m, bs, dh]`` host arrays — the
+        ``{"k", "v"}`` as ``[L, m, bs, kvh*dh]`` host arrays — the
         importing engine must have identical model geometry (it checks).
         """
         self._refuse_typed("export_pages (KV tiering / page handoff)")
+        idx = self._page_rows(blocks)
+        return {key: np.asarray(self.arena[key][idx]).reshape(
+                    self._bundle_shape(key, len(blocks)))
+                for key in ("k", "v")}
+
+    def _page_rows(self, blocks: List[int]) -> np.ndarray:
+        """Flat pool rows of ``blocks`` in every layer's region, layer by
+        layer: [L * m]."""
         L = self.model_config.num_layers
-        stride = self.arena["k"].shape[1] // L          # nb + 1
-        ids = np.asarray(blocks, np.int32)
-        idx = (np.arange(L, dtype=np.int32)[:, None] * stride +
-               ids[None, :]).reshape(-1)
-        out = {}
-        for key in ("k", "v"):
-            kvh, _, bs, dh = self.arena[key].shape
-            flat = np.asarray(self.arena[key][:, idx])  # [kvh, L*m, bs, dh]
-            out[key] = flat.reshape(kvh, L, len(blocks), bs, dh)
-        return out
+        stride = self.arena["k"].shape[0] // L          # nb + 1
+        return (np.arange(L, dtype=np.int32)[:, None] * stride +
+                np.asarray(blocks, np.int32)[None, :]).reshape(-1)
+
+    def _bundle_shape(self, key: str, m: int) -> Tuple[int, ...]:
+        """A page bundle of ``m`` pages of pool ``key``."""
+        return (self.model_config.num_layers, m) + self.arena[key].shape[1:]
 
     def import_pages(self, pages: Dict[str, np.ndarray],
                      blocks: List[int]) -> None:
@@ -1068,14 +1073,9 @@ class RaggedInferenceEngineTPU:
         ``ValueError`` on a geometry mismatch rather than silently
         writing garbage KV."""
         self._refuse_typed("import_pages (KV tiering / page handoff)")
-        L = self.model_config.num_layers
-        stride = self.arena["k"].shape[1] // L
-        ids = np.asarray(blocks, np.int32)
-        idx = (np.arange(L, dtype=np.int32)[:, None] * stride +
-               ids[None, :]).reshape(-1)
+        idx = self._page_rows(blocks)
         for key in ("k", "v"):
-            kvh, _, bs, dh = self.arena[key].shape
-            want = (kvh, L, len(blocks), bs, dh)
+            want = self._bundle_shape(key, len(blocks))
             got = tuple(pages[key].shape)
             if got != want:
                 raise ValueError(
@@ -1083,16 +1083,15 @@ class RaggedInferenceEngineTPU:
                     f"arena (want {want}) — replicas must share model "
                     f"geometry")
             data = jnp.asarray(pages[key], self.arena[key].dtype) \
-                .reshape(kvh, L * len(blocks), bs, dh)
-            self.arena[key] = self.arena[key].at[:, idx].set(data)
+                .reshape((len(idx),) + want[2:])
+            self.arena[key] = self.arena[key].at[idx].set(data)
 
     def kv_page_nbytes(self) -> int:
         """Host-side bytes of ONE exported KV page (all layers, k + v) —
         what a tier/handoff consumer budgets per page (the uncompressed
         ``export_pages`` payload size for a single block)."""
         stride = self.config.num_blocks + 1
-        axis = 0 if self.model_config.typed else 1      # the pages' axis
-        return sum(a.nbytes // a.shape[axis] * (a.shape[axis] // stride)
+        return sum(a.nbytes // a.shape[0] * (a.shape[0] // stride)
                    for a in self.arena.values())
 
     def _refuse_typed(self, what: str) -> None:
@@ -1307,7 +1306,7 @@ class RaggedInferenceEngineTPU:
 
         def fn(params, arena, tokens0, starts0, live, pt, limit, budgets,
                eos_ids, temp, top_p, rng):
-            stride = arena["k"].shape[1] // num_layers
+            stride = arena["k"].shape[0] // num_layers
             ak_c, av_c = arena["k"], arena["v"]       # read-only in loop
             kbuf0 = jnp.zeros((num_layers, sb, nb, kvh, dh), self.dtype)
             vbuf0 = jnp.zeros_like(kbuf0)
@@ -1365,8 +1364,8 @@ class RaggedInferenceEngineTPU:
                                                   keepdims=False)
                     with jax.named_scope("attn_core"):
                         out_d, lse_d = _masked_attention(
-                            q, kd.transpose(1, 2, 0, 3),
-                            vd.transpose(1, 2, 0, 3), dec_mask, True)
+                            q, kd.transpose(1, 0, 2, 3),
+                            vd.transpose(1, 0, 2, 3), dec_mask, True)
                     with jax.named_scope("attn_merge"):
                         out = pa.merge_attention(out_h, lse_h, out_d,
                                                  lse_d).astype(q.dtype)

@@ -8,23 +8,23 @@ scalar-prefetch operand, so each KV block's DMA source address is computed
 *from the page table itself* inside the BlockSpec index map — the arena is
 never gathered into a contiguous buffer in HBM.
 
-Arena layout (one layer): ``[kv_heads, num_blocks + 1, block_size, head_dim]``.
-The final block is a TRASH block: padded token slots and padded page-table
-entries all point at it, so scatter/gather stay branch-free and
-static-shape. Block size and head_dim are chosen to satisfy the (8, 128)
-tile rule on the last two dims.
+Arena layout: every pool is TOKEN-MAJOR, ``[blocks, block_size, kv_heads *
+head_dim]``: a token's heads side by side on the lanes. It is the layout a
+row scatter writes in place and the one the kernel reads in place (a head's
+page is the lane slice ``[kh * d, (kh + 1) * d)``, which is why the heads
+share the LAST axis: ``[.., kv_heads, head_dim]`` would tile heads over
+sublanes), so no step program relays a pool between its write and its read
+(tests/test_tpu_compile.py counts the arena-shaped copies: none). A layer's
+region is ``num_blocks + 1`` pages; the last is a TRASH page: padded token
+slots and padded page-table entries all point at it, so scatter/gather stay
+branch-free and static-shape. Block size and head_dim are chosen to satisfy
+the (8, 128) tile rule.
 
 K and V pools may differ in width (``head_dim`` of K, of V). A model whose
 layers are of two attention kinds (full and window: different KV head
-counts) has a pool per kind and per K/V (:func:`init_arena_typed`), TOKEN-
-MAJOR: ``[blocks, block_size, kv_heads * head_dim]``, a token's heads side
-by side on the lanes — the layout a row scatter writes in place, so no
-step relays the pool for its write (``token_major=True`` on the functions
-below; the kernel reads a head's page as a lane slice, which is why the
-heads share the LAST axis: ``[.., kv_heads, head_dim]`` would tile heads
-over sublanes). The page table stays one per
-sequence. A window layer keeps its whole history in its pages; its readers
-visit only the pages the window touches.
+counts) has a pool per kind and per K/V (:func:`init_arena_typed`). The page
+table stays one per sequence. A window layer keeps its whole history in its
+pages; its readers visit only the pages the window touches.
 
 Two implementations with identical semantics (tested against each other):
 
@@ -56,16 +56,17 @@ def init_arena(num_layers: int, kv_heads: int, num_blocks: int,
                block_size: int, head_dim: int, dtype=jnp.bfloat16):
     """Paged KV arena with one extra trash block per layer.
 
-    Returns {"k": A, "v": A} with A: [kvh, L*(num_blocks+1), bs, dh] —
+    Returns {"k": A, "v": A} with A: [L*(num_blocks+1), bs, kvh*dh] —
     ONE flat block pool for all layers (layer l's logical block b lives at
     l*(num_blocks+1)+b; see :func:`layer_page_offset`). Flat so the
     engine's layer scan can thread the WHOLE arena as a carry and update
     it in place — a per-layer stacked arena would ride the scan as
     xs/ys, which cannot alias, forcing XLA to copy the full (multi-GB)
-    arena every decode step.
+    arena every decode step. The one-kind case of
+    :func:`init_arena_typed`.
     """
-    shape = (kv_heads, num_layers * (num_blocks + 1), block_size, head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return init_arena_typed((0,) * num_layers, {0: kv_heads}, num_blocks,
+                            block_size, head_dim, head_dim, dtype)
 
 
 #: pool names of a typed arena by attention kind (0 full, 1 window)
@@ -76,7 +77,7 @@ def init_arena_typed(layer_kinds, kv_heads_by_kind: dict, num_blocks: int,
                      block_size: int, k_width: int, v_width: int,
                      dtype=jnp.bfloat16) -> dict:
     """The arena of a typed layer stack: a FLAT dict of pools, one per
-    attention kind present and per K/V (``KIND_POOLS``), each TOKEN-MAJOR
+    attention kind present and per K/V (``KIND_POOLS``), each
     ``[layers of the kind * (num_blocks + 1), bs, kv_heads of the kind *
     width]`` — :func:`init_arena`'s flat block numbering within a kind: the
     i-th layer OF ITS KIND owns pages ``i*(num_blocks+1) + b``. One page
@@ -99,24 +100,19 @@ def layer_page_offset(layer: jax.Array, num_blocks: int) -> jax.Array:
 
 def write_kv(arena_k: jax.Array, arena_v: jax.Array, k: jax.Array,
              v: jax.Array, page_table: jax.Array, starts: jax.Array,
-             counts: jax.Array, trash_block=None,
-             token_major: bool = False):
-    """Scatter a ragged chunk of new KV into the arena (``token_major``:
-    pools ``[NB, bs, kvh * d]``, each token's row written whole).
+             counts: jax.Array, trash_block=None):
+    """Scatter a ragged chunk of new KV into the arena, each token's row
+    (its heads side by side) written whole.
 
-    arena_k/arena_v: [kvh, NB, bs, dh] (one layer's region of the flat
+    arena_k/arena_v: [NB, bs, kvh * d] (one layer's region of the flat
     pool, or the whole pool with absolute page-table ids); k/v:
-    [n, c, kvh, dh] new tokens (row i valid for j < counts[i]);
+    [n, c, kvh, d] new tokens (row i valid for j < counts[i]);
     page_table: [n, mb] physical block ids (padded entries may be
     anything — padded tokens route to ``trash_block``, default the pool's
     last block); starts: [n] tokens already in KV per sequence.
     """
-    if token_major:
-        nbp1, bs, _ = arena_k.shape
-        kvh, dh = k.shape[2:]
-    else:
-        kvh, nbp1, bs, dh = arena_k.shape
-    n, c, _, _ = k.shape
+    nbp1, bs, _ = arena_k.shape
+    n, c = k.shape[:2]
     if trash_block is None:
         trash_block = nbp1 - 1
     j = jnp.arange(c, dtype=jnp.int32)[None, :]                    # [1, c]
@@ -129,24 +125,14 @@ def write_kv(arena_k: jax.Array, arena_v: jax.Array, k: jax.Array,
     phys = jnp.where(valid, phys, trash_block)                     # → trash
     bi = phys.reshape(-1)
     oi = offset.reshape(-1)
-    if token_major:
-        return (arena_k.at[bi, oi].set(
-                    k.reshape(n * c, kvh * dh).astype(arena_k.dtype),
-                    mode="drop"),
-                arena_v.at[bi, oi].set(
-                    v.reshape(n * c, -1).astype(arena_v.dtype),
-                    mode="drop"))
-    k_rows = k.reshape(n * c, kvh, dh).transpose(1, 0, 2)          # [kvh,nc,dh]
-    v_rows = v.reshape(n * c, kvh, dh).transpose(1, 0, 2)
-    arena_k = arena_k.at[:, bi, oi, :].set(
-        k_rows.astype(arena_k.dtype), mode="drop")
-    arena_v = arena_v.at[:, bi, oi, :].set(
-        v_rows.astype(arena_v.dtype), mode="drop")
-    return arena_k, arena_v
+    return (arena_k.at[bi, oi].set(
+                k.reshape(n * c, -1).astype(arena_k.dtype), mode="drop"),
+            arena_v.at[bi, oi].set(
+                v.reshape(n * c, -1).astype(arena_v.dtype), mode="drop"))
 
 
-def copy_pages(arena: dict, src: jax.Array, dst: jax.Array, stride: int,
-               token_major: bool = False) -> dict:
+def copy_pages(arena: dict, src: jax.Array, dst: jax.Array,
+               stride: int) -> dict:
     """Copy whole KV pages ``src[i] → dst[i]`` across every layer's region.
 
     The copy-on-write half of prefix caching: page tables are plain
@@ -157,19 +143,17 @@ def copy_pages(arena: dict, src: jax.Array, dst: jax.Array, stride: int,
     duplicated before its new owner appends into it, which is this op:
     one gather+scatter over each flat pool.
 
-    arena: flat pools [kvh, L*(nb+1), bs, dh] ({"k","v"}), or a typed
-    arena's token-major pools [L_kind*(nb+1), bs, kvh*d] (``token_major``:
-    every pool, each with its own layer count); src/dst: [m] logical page
-    ids (< nb, layer-relative); ``stride`` = nb + 1, a layer's pages.
+    arena: pools [L_pool*(nb+1), bs, kvh*d] (every pool of the dict, each
+    with its own layer count); src/dst: [m] logical page ids (< nb,
+    layer-relative); ``stride`` = nb + 1, a layer's pages.
     """
     out = {}
     for name, pool in arena.items():
-        pages = pool.shape[0 if token_major else 1]
-        offs = jnp.arange(pages // stride, dtype=jnp.int32)[:, None] * stride
+        offs = jnp.arange(pool.shape[0] // stride,
+                          dtype=jnp.int32)[:, None] * stride
         s = (offs + jnp.asarray(src, jnp.int32)[None, :]).reshape(-1)
         d = (offs + jnp.asarray(dst, jnp.int32)[None, :]).reshape(-1)
-        out[name] = pool.at[d].set(pool[s]) if token_major \
-            else pool.at[:, d].set(pool[:, s])
+        out[name] = pool.at[d].set(pool[s])
     return out
 
 
@@ -177,45 +161,69 @@ def copy_pages(arena: dict, src: jax.Array, dst: jax.Array, stride: int,
 # XLA reference path (also the prefill path)
 # ---------------------------------------------------------------------------
 
-def _gather_pages(arena: jax.Array, page_table: jax.Array,
-                  kv_heads: int = 0):
-    """[kvh, nb+1, bs, dh] (token-major, ``kv_heads`` given: [nb+1, bs,
-    kvh*dh]) x [n, mb] → [n, kvh, mb*bs, dh]."""
+def _gather_pages(arena: jax.Array, page_table: jax.Array, kv_heads: int):
+    """[nb+1, bs, kvh*d] x [n, mb] → [n, mb*bs, kvh, d]: the tokens as the
+    pool holds them."""
     n, mb = page_table.shape
-    if kv_heads:
-        bs = arena.shape[1]
-        return arena[page_table].reshape(n, mb * bs, kv_heads, -1) \
-            .transpose(0, 2, 1, 3)
-    kvh, _, bs, dh = arena.shape
-    return arena[:, page_table].transpose(1, 0, 2, 3, 4) \
-        .reshape(n, kvh, mb * bs, dh)
+    bs = arena.shape[1]
+    return arena[page_table].reshape(n, mb * bs, kv_heads, -1)
 
 
 def _masked_attention(q: jax.Array, kg: jax.Array, vg: jax.Array,
                       mask: jax.Array, with_lse: bool,
                       scale: Optional[float] = None):
-    """Shared gathered-softmax core: q [n,c,h,dk], kg [n,kvh,S,dk], vg
-    [n,kvh,S,dv], mask broadcastable to [n,kvh,g,c,S]. Returns out
-    [n,c,h,dv] (+ lse [n,c,h] fp32 when with_lse; a row with no visible
-    key gives lse ≈ -1e30, a weight of 0 in a merge). ``scale``: the
-    scores' factor, default ``dk ** -0.5`` (a caller that zero-pads the
-    heads passes the true width's)."""
+    """Shared gathered-softmax core: q [n,c,h,dk], kg [n,S,kvh,dk], vg
+    [n,S,kvh,dv] (a token's heads side by side, as the pools hold them),
+    mask broadcastable to [n,kvh,g,c,S]. Returns out [n,c,h,dv] (+ lse
+    [n,c,h] fp32 when with_lse; a row with no visible key gives lse ≈
+    -1e30, a weight of 0 in a merge). ``scale``: the scores' factor,
+    default ``dk ** -0.5`` (a caller that zero-pads the heads passes the
+    true width's).
+
+    One query a row (``c == 1``, a decode step) leaves the keys' lanes
+    whole: q is spread block-diagonally over the kv heads' lanes and a row
+    is one ``[h, kvh*dk] x [kvh*dk, S]`` matmul, its result's own-head
+    lanes picked afterwards. Splitting the lanes into ``[kvh, d]`` for a
+    matmul a head relays every gathered page (a head is a tile COLUMN of a
+    token-major page), which at a decode step's four query rows a head is
+    most of the read: 1.96 → 1.15 ms a layer at 64 rows of 8 pages on a
+    v5e (PERF.md §6, PR 34). The zeros cost ``kvh`` times the FLOPs, which
+    a decode row has to spare and a chunk has not: ``c > 1`` contracts a
+    head at a time."""
     n, c, h, dh = q.shape
-    kvh = kg.shape[1]
+    S, kvh = kg.shape[1:3]
     if h % kvh:
         raise ValueError(f"GQA requires kv heads to divide q heads "
                          f"(h={h}, kvh={kvh})")
     groups = h // kvh
+    lanes_whole = c == 1
     qg = q.reshape(n, c, kvh, groups, dh)
-    s = jnp.einsum("nckgd,nksd->nkgcs", qg, kg.astype(q.dtype),
-                   preferred_element_type=jnp.float32)
+    if lanes_whole:
+        eye = jnp.eye(kvh, dtype=q.dtype)
+        q_bd = qg[:, 0, :, :, None, :] * eye[:, None, :, None]  # [n,k,g,k',d]
+        s = jnp.einsum("nrl,nsl->nrs", q_bd.reshape(n, h, kvh * dh),
+                       kg.reshape(n, S, kvh * dh).astype(q.dtype),
+                       preferred_element_type=jnp.float32) \
+            .reshape(n, kvh, groups, 1, S)
+    else:
+        s = jnp.einsum("nckgd,nksd->nkgcs", qg,
+                       kg.transpose(0, 2, 1, 3).astype(q.dtype),
+                       preferred_element_type=jnp.float32)
     s = s / math.sqrt(dh) if scale is None else s * scale
     s = jnp.where(mask, s, _NEG_INF)
     m = jnp.max(s, axis=-1)                                     # [n,k,g,c]
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, axis=-1)
-    out = jnp.einsum("nkgcs,nksd->nckgd", p.astype(vg.dtype), vg) \
-        / jnp.maximum(l, 1e-30).transpose(0, 3, 1, 2)[..., None]
+    if lanes_whole:
+        full = jnp.einsum("nrs,nsl->nrl", p.reshape(n, h, S).astype(vg.dtype),
+                          vg.reshape(n, S, -1))                # [n,h,k'*dv]
+        out = jnp.einsum("nkgjd,kj->nkgd",
+                         full.reshape(n, kvh, groups, kvh, -1),
+                         eye.astype(full.dtype))[:, None]       # [n,1,k,g,dv]
+    else:
+        out = jnp.einsum("nkgcs,nksd->nckgd", p.astype(vg.dtype),
+                         vg.transpose(0, 2, 1, 3))
+    out = out / jnp.maximum(l, 1e-30).transpose(0, 3, 1, 2)[..., None]
     out = out.reshape(n, c, h, vg.shape[-1]).astype(q.dtype)
     if not with_lse:
         return out
@@ -246,17 +254,16 @@ def paged_attention_xla(q: jax.Array, arena_k: jax.Array,
                         starts: jax.Array, counts: jax.Array,
                         window: Optional[int] = None,
                         scale: Optional[float] = None,
-                        with_lse: bool = False,
-                        token_major: bool = False):
+                        with_lse: bool = False):
     """Gather-then-attend over the paged arena (reference semantics).
 
     q: [n, c, H, dk] (query rows j >= counts[i] give garbage rows — the
-    caller discards them); arena: [kvh, nb+1, bs, dk / dv]; page_table:
+    caller discards them); arena: [nb+1, bs, kvh * dk / dv]; page_table:
     [n, mb]; starts/counts: [n]. Returns [n, c, H, dv] (and lse [n, c, H]
     with ``with_lse``). ``window``: key j is visible to query i only when
     ``i - j < window``, and only the pages such keys lie in are gathered.
     """
-    bs = arena_k.shape[1 if token_major else 2]
+    bs = arena_k.shape[1]
     n, c = q.shape[:2]
     mb = page_table.shape[1]
     qpos = starts[:, None] + jnp.arange(c, dtype=jnp.int32)[None]  # [n, c]
@@ -272,7 +279,7 @@ def paged_attention_xla(q: jax.Array, arena_k: jax.Array,
         kpos = kpos[:, None]                                       # [n, 1, S]
         mask = (kpos <= qpos[..., None]) & (kpos < ctx[:, None, None]) & \
             (kpos > qpos[..., None] - window)
-    kvh = arena_k.shape[-1] // q.shape[-1] if token_major else 0
+    kvh = arena_k.shape[-1] // q.shape[-1]
     return _masked_attention(
         q, _gather_pages(arena_k, ids, kvh), _gather_pages(arena_v, ids, kvh),
         mask[:, None, None], with_lse, scale)
@@ -282,8 +289,7 @@ def paged_attention_hist_xla(q: jax.Array, arena_k: jax.Array,
                              arena_v: jax.Array, page_table: jax.Array,
                              starts: jax.Array,
                              window: Optional[int] = None,
-                             scale: Optional[float] = None,
-                             token_major: bool = False):
+                             scale: Optional[float] = None):
     """HISTORY-only attention: row i's queries attend keys [0, starts[i])
     — the tokens already in the arena BEFORE the current chunk's write.
     Returns (out [n,c,h,dh], lse [n,c,h] fp32).
@@ -297,7 +303,7 @@ def paged_attention_hist_xla(q: jax.Array, arena_k: jax.Array,
     ``starts + j``) sees only the history keys within the window, and only
     the pages those lie in are gathered.
     """
-    bs = arena_k.shape[1 if token_major else 2]
+    bs = arena_k.shape[1]
     mb = page_table.shape[1]
     if window is None:
         ids = page_table
@@ -311,7 +317,7 @@ def paged_attention_hist_xla(q: jax.Array, arena_k: jax.Array,
         kpos = kpos[:, None]                                        # [n,1,S]
         mask = ((kpos < starts[:, None, None]) &
                 (kpos > qpos[..., None] - window))[:, None, None]
-    kvh = arena_k.shape[-1] // q.shape[-1] if token_major else 0
+    kvh = arena_k.shape[-1] // q.shape[-1]
     return _masked_attention(
         q, _gather_pages(arena_k, ids, kvh), _gather_pages(arena_v, ids, kvh),
         mask, True, scale)
@@ -343,13 +349,11 @@ def causal_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     and V may differ in width). ``window``: key j visible to query i only
     when ``i - j < window``."""
     c = q.shape[1]
-    kg = k.transpose(0, 2, 1, 3)                                # [n,kvh,c,d]
-    vg = v.transpose(0, 2, 1, 3)
     i = jnp.arange(c, dtype=jnp.int32)
     mask = i[None, :] <= i[:, None]
     if window is not None:
         mask = mask & (i[None, :] > i[:, None] - window)
-    return _masked_attention(q, kg, vg, mask[None, None, None], True, scale)
+    return _masked_attention(q, k, v, mask[None, None, None], True, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -360,8 +364,7 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
                   o_ref, *rest, block_size: int,
                   chunk: int, scale: float, mb: int,
                   with_lse: bool = False,
-                  window: Optional[int] = None,
-                  token_major: bool = False):
+                  window: Optional[int] = None):
     """Grid (n_seq, kvh): ONE program per (sequence, kv head) that walks
     this sequence's pages with double-buffered manual DMAs from the
     HBM-resident arena.
@@ -373,17 +376,17 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
     blocked_flash/paged-KV structure.
 
     q_ref block: [1, 1, rows, dh] (row = g*chunk + j); k_hbm/v_hbm: the
-    FULL arena [kvh, NB, bs, dh] left in ANY/HBM memory space; k_buf/
-    v_buf: [2, bs, dh] VMEM double buffers. With ``with_lse`` an extra
-    [1, 1, rows] f32 output carries each row's logsumexp (the
-    partial-attention merge needs it — fused decode's history part).
+    FULL arena [NB, bs, kvh * d] left in ANY/HBM memory space (a token's
+    heads side by side, the layout a row scatter writes without a
+    relayout), of which head ``kh``'s page is the lane slice ``[kh * d,
+    (kh + 1) * d)``; k_buf/v_buf: [2, bs, d] VMEM double buffers. With
+    ``with_lse`` an extra [1, 1, rows] f32 output carries each row's
+    logsumexp (the partial-attention merge needs it — fused decode's
+    history part).
     K and V may differ in width (k_buf [2, bs, dk], v_buf [2, bs, dv]; the
     output is dv wide). ``window`` (static): key j is visible to query i
     only when ``i - j < window``, and the walk STARTS at the page that
-    holds the lowest key any query of the row can see. ``token_major``
-    (static): the pools are ``[NB, bs, kvh * d]`` (a token's heads side by
-    side, the layout a row scatter writes without a relayout) and a head's
-    page is the lane slice ``[kh * d, (kh + 1) * d)`` of it.
+    holds the lowest key any query of the row can see.
     """
     if with_lse:
         lse_ref, k_buf, v_buf, sem_k, sem_v = rest
@@ -404,8 +407,6 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
         first_slot = lax.rem(first, 2)
 
     def head_page(hbm, buf, page):
-        if not token_major:
-            return hbm.at[kh, page]
         width = buf.shape[-1]
         return hbm.at[page, :, pl.ds(pl.multiple_of(kh * width, 128), width)]
 
@@ -478,6 +479,70 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
             lse_ref[0, 0] = jnp.full_like(lse_ref[0, 0], _NEG_INF)
 
 
+def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
+                with_lse: bool, interpret: bool, window=None, scale=None):
+    """The ``pallas_call`` of both wrappers below → (out [n, c, h, dv],
+    lse [n, c, h] fp32 or None). The kernel's name in a device trace is
+    ``paged_attn_lse`` with the logsumexp output, ``paged_attn`` without."""
+    bs, lanes = arena_k.shape[1:]
+    n, c, h, dh = q.shape
+    kvh = lanes // dh
+    dv = arena_v.shape[-1] // kvh
+    groups = h // kvh
+    mb = page_table.shape[1]
+    rows = groups * c
+
+    # [n, c, kvh, g, dh] → [n, kvh, g*c, dh] with row index = g*c + j
+    qk = q.reshape(n, c, kvh, groups, dh).transpose(0, 2, 3, 1, 4) \
+        .reshape(n, kvh, rows, dh)
+
+    def rows_of(width):
+        return pl.BlockSpec((1, 1, rows, width),
+                            lambda s, kh, pt, st, ct: (s, kh, 0, 0))
+
+    kernel = functools.partial(
+        _paged_kernel, block_size=bs, chunk=c, mb=mb, with_lse=with_lse,
+        window=window,
+        scale=1.0 / math.sqrt(dh) if scale is None else scale)
+    out_specs = [rows_of(dv)]
+    out_shape = [jax.ShapeDtypeStruct((n, kvh, rows, dv), q.dtype)]
+    if with_lse:
+        out_specs.append(rows_of(1))
+        out_shape.append(jax.ShapeDtypeStruct((n, kvh, rows, 1),
+                                              jnp.float32))
+    out, *lse = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n, kvh),
+            in_specs=[
+                rows_of(dh),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((2, bs, dh), arena_k.dtype),
+                pltpu.VMEM((2, bs, dv), arena_v.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=out_shape,
+        interpret=interpret,
+        name="paged_attn_lse" if with_lse else "paged_attn",
+    )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
+      counts.astype(jnp.int32), qk, arena_k, arena_v)
+
+    # [n, kvh, g*c, dv] → [n, c, h, dv]
+    out = out.reshape(n, kvh, groups, c, dv).transpose(0, 3, 1, 2, 4) \
+        .reshape(n, c, h, dv)
+    if not with_lse:
+        return out, None
+    return out, lse[0].reshape(n, kvh, groups, c).transpose(0, 3, 1, 2) \
+        .reshape(n, c, h)
+
+
 def paged_attention(q: jax.Array, arena_k: jax.Array, arena_v: jax.Array,
                     page_table: jax.Array, starts: jax.Array,
                     counts: jax.Array, *, interpret: bool = False
@@ -489,49 +554,8 @@ def paged_attention(q: jax.Array, arena_k: jax.Array, arena_v: jax.Array,
     no per-page grid step. Dead pages (beyond a sequence's context length)
     are skipped by the dynamic in-kernel loop bound.
     """
-    kvh, nbp1, bs, dh = arena_k.shape
-    n, c, h, _ = q.shape
-    groups = h // kvh
-    mb = page_table.shape[1]
-    rows = groups * c
-
-    # [n, c, kvh, g, dh] → [n, kvh, g*c, dh] with row index = g*c + j
-    qk = q.reshape(n, c, kvh, groups, dh).transpose(0, 2, 3, 1, 4) \
-        .reshape(n, kvh, rows, dh)
-
-    grid = (n, kvh)
-    kernel = functools.partial(_paged_kernel, block_size=bs, chunk=c,
-                               scale=1.0 / math.sqrt(dh), mb=mb)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, rows, dh),
-                             lambda s, kh, pt, st, ct: (s, kh, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, rows, dh),
-                lambda s, kh, pt, st, ct: (s, kh, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, bs, dh), arena_k.dtype),
-                pltpu.VMEM((2, bs, dh), arena_v.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((n, kvh, rows, dh), q.dtype),
-        interpret=interpret,
-        name="paged_attn",
-    )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
-      counts.astype(jnp.int32), qk, arena_k, arena_v)
-
-    # [n, kvh, g*c, dh] → [n, c, h, dh]
-    return out.reshape(n, kvh, groups, c, dh).transpose(0, 3, 1, 2, 4) \
-        .reshape(n, c, h, dh)
+    return _paged_call(q, arena_k, arena_v, page_table, starts, counts,
+                       with_lse=False, interpret=interpret)[0]
 
 
 def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
@@ -539,8 +563,7 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
                              starts: jax.Array, counts: jax.Array, *,
                              interpret: bool = False,
                              window: Optional[int] = None,
-                             scale: Optional[float] = None,
-                             token_major: bool = False):
+                             scale: Optional[float] = None):
     """Pallas paged attention returning (out, lse [n, c, h] fp32) for the
     partial-attention merge. ``counts=0`` gives HISTORY-only semantics
     (keys [0, starts)) — the fused decode loop's arena part, where the
@@ -549,66 +572,10 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
     wide as a V head); ``window``: key j is visible to query i only when
     ``i - j < window`` and the walk starts at the window's first page;
     ``scale``: the scores' factor, default ``dk ** -0.5`` (a caller that
-    zero-pads the heads passes the true width's). ``token_major``: the
-    pools are ``[NB, bs, kvh * d]`` (:func:`init_arena_typed`), read in
-    place by lane slices; q is as wide as one K head."""
-    if token_major:
-        nbp1, bs, lanes = arena_k.shape
-        dh = q.shape[-1]
-        kvh = lanes // dh
-        dv = arena_v.shape[-1] // kvh
-    else:
-        kvh, nbp1, bs, dh = arena_k.shape
-        dv = arena_v.shape[-1]
-    n, c, h, _ = q.shape
-    groups = h // kvh
-    mb = page_table.shape[1]
-    rows = groups * c
-
-    qk = q.reshape(n, c, kvh, groups, dh).transpose(0, 2, 3, 1, 4) \
-        .reshape(n, kvh, rows, dh)
-
-    grid = (n, kvh)
-    kernel = functools.partial(
-        _paged_kernel, block_size=bs, chunk=c, mb=mb, with_lse=True,
-        window=window, token_major=token_major,
-        scale=1.0 / math.sqrt(dh) if scale is None else scale)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, rows, dh),
-                             lambda s, kh, pt, st, ct: (s, kh, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, rows, dv),
-                             lambda s, kh, pt, st, ct: (s, kh, 0, 0)),
-                pl.BlockSpec((1, 1, rows, 1),
-                             lambda s, kh, pt, st, ct: (s, kh, 0, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((2, bs, dh), arena_k.dtype),
-                pltpu.VMEM((2, bs, dv), arena_v.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((n, kvh, rows, dv), q.dtype),
-                   jax.ShapeDtypeStruct((n, kvh, rows, 1), jnp.float32)],
-        interpret=interpret,
-        name="paged_attn_lse",
-    )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
-      counts.astype(jnp.int32), qk, arena_k, arena_v)
-
-    out = out.reshape(n, kvh, groups, c, dv).transpose(0, 3, 1, 2, 4) \
-        .reshape(n, c, h, dv)
-    lse = lse.reshape(n, kvh, groups, c).transpose(0, 3, 1, 2) \
-        .reshape(n, c, h)
-    return out, lse
+    zero-pads the heads passes the true width's)."""
+    return _paged_call(q, arena_k, arena_v, page_table, starts, counts,
+                       with_lse=True, interpret=interpret, window=window,
+                       scale=scale)
 
 
 def supported(head_dim: int, block_size: int) -> bool:

@@ -33,13 +33,18 @@ from typing import Dict, List, Optional
 import numpy as np
 
 
+#: the pages' axis of an ``engine.export_pages`` payload
+#: (``[L, m, bs, kvh*dh]``): what a bundle counts and the tier joins along
+PAGE_AXIS = 1
+
+
 @dataclass
 class PageBundle:
     """One prefill's cached KV pages in transit.
 
     ``tokens`` are the prompt tokens the pages cover (full pages first,
     then the partial last page's span); ``pages`` is the
-    ``engine.export_pages`` payload (``{"k","v"}: [kvh, L, m, bs, dh]``);
+    ``engine.export_pages`` payload (``{"k","v"}: [L, m, bs, kvh*dh]``);
     ``checksum`` is CRC32 over the payload bytes — :func:`verify_bundle`
     is the torn-transfer detector."""
     tokens: List[int]
@@ -49,7 +54,7 @@ class PageBundle:
 
     @property
     def num_pages(self) -> int:
-        return int(self.pages["k"].shape[2]) if self.pages else 0
+        return int(self.pages["k"].shape[PAGE_AXIS]) if self.pages else 0
 
     @property
     def nbytes(self) -> int:

@@ -57,8 +57,8 @@ import numpy as np
 from deepspeed_tpu.io.async_io import AsyncIOEngine, atomic_write, \
     pread_retry
 from deepspeed_tpu.resilience.faults import fault_injector, record_recovery
-from deepspeed_tpu.serving.handoff import PageBundle, _checksum, \
-    verify_bundle
+from deepspeed_tpu.serving.handoff import PAGE_AXIS, PageBundle, \
+    _checksum, verify_bundle
 
 #: spill file header magic — a file that doesn't start with it is torn
 _MAGIC = b"DSKV"
@@ -578,7 +578,7 @@ class KVTier:
         if not adopted:
             self.counters["misses"] += 1
             return 0
-        pages = {k: np.concatenate([p[k] for p in payloads], axis=2)
+        pages = {k: np.concatenate([p[k] for p in payloads], axis=PAGE_AXIS)
                  for k in payloads[0]}
         tokens = list(adopted[-1].key)
         blocks = alloc.allocate(len(adopted))

@@ -92,8 +92,8 @@ def _stub_disagg(**kw):
 # ---------------------------------------------------------------------------
 
 def test_bundle_checksum_detects_torn_payload():
-    pages = {"k": np.arange(24, dtype=np.float32).reshape(1, 2, 2, 2, 3),
-             "v": np.ones((1, 2, 2, 2, 3), np.float32)}
+    pages = {"k": np.arange(24, dtype=np.float32).reshape(2, 2, 2, 3),
+             "v": np.ones((2, 2, 2, 3), np.float32)}
     from deepspeed_tpu.serving.handoff import _checksum
     bundle = PageBundle(tokens=[1, 2, 3, 4], block_size=2, pages=pages,
                         checksum=_checksum(pages))
@@ -101,9 +101,9 @@ def test_bundle_checksum_detects_torn_payload():
     assert bundle.nbytes == pages["k"].nbytes + pages["v"].nbytes
     assert verify_bundle(bundle)
     # torn in transit: any flipped byte fails verification
-    bundle.pages["v"][0, 1, 1, 0, 2] += 1.0
+    bundle.pages["v"][1, 1, 0, 2] += 1.0
     assert not verify_bundle(bundle)
-    bundle.pages["v"][0, 1, 1, 0, 2] -= 1.0
+    bundle.pages["v"][1, 1, 0, 2] -= 1.0
     assert verify_bundle(bundle)
     bundle.checksum ^= 0x1
     assert not verify_bundle(bundle)
@@ -113,8 +113,8 @@ def test_bundle_export_adopt_degrade_gracefully_without_cache():
     fe = _StubFrontend()                     # cache is None
     assert export_bundle(fe, [1, 2, 3]) is None
     bundle = PageBundle(tokens=[1], block_size=8,
-                        pages={"k": np.zeros((1, 1, 1, 8, 2), np.float32),
-                               "v": np.zeros((1, 1, 1, 8, 2), np.float32)})
+                        pages={"k": np.zeros((1, 1, 8, 2), np.float32),
+                               "v": np.zeros((1, 1, 8, 2), np.float32)})
     assert adopt_bundle(fe, bundle) == 0
 
 

@@ -64,9 +64,9 @@ class _StubEngine:
         m = len(blocks)
         out = {}
         for key, bias in (("k", 0.0), ("v", 0.5)):
-            a = np.empty((1, 2, m, BS, 2), np.float32)
+            a = np.empty((2, m, BS, 2), np.float32)
             for j, b in enumerate(blocks):
-                a[:, :, j] = float(b) + bias
+                a[:, j] = float(b) + bias
             out[key] = a
         return out
 
@@ -93,8 +93,8 @@ def _keyed(prompt):
 @pytest.mark.parametrize("mode", ["none", "fp16", "int8"])
 def test_encode_decode_roundtrip(mode):
     rng = np.random.default_rng(0)
-    pages = {"k": rng.standard_normal((1, 2, 3, BS, 2)).astype(np.float32),
-             "v": rng.standard_normal((1, 2, 3, BS, 2)).astype(np.float32)}
+    pages = {"k": rng.standard_normal((2, 3, BS, 2)).astype(np.float32),
+             "v": rng.standard_normal((2, 3, BS, 2)).astype(np.float32)}
     payload, meta = _encode(pages, mode)
     back = _decode(payload, meta)
     assert set(back) == {"k", "v"}
@@ -192,7 +192,7 @@ def test_capture_spill_prefetch_adopt_roundtrip(tmp_path):
     cache = PrefixCache(alloc)
     # one page fits under high*dram_bytes, so every capture spills the
     # PREVIOUS page — both chain pages end on NVMe after a third capture
-    page_bytes = 2 * (1 * 2 * 1 * BS * 2) * 4
+    page_bytes = 2 * (2 * 1 * BS * 2) * 4
     tier = _tier(eng, tmp_path, dram_bytes=2 * page_bytes,
                  high_watermark=0.5, low_watermark=0.25)
     cache.tier = tier
@@ -215,10 +215,10 @@ def test_capture_spill_prefetch_adopt_roundtrip(tmp_path):
     added = tier.adopt(prompt, cache)
     assert added == 2
     pages, blocks = eng.imported[-1]
-    assert pages["k"].shape == (1, 2, 2, BS, 2)
-    assert np.all(pages["k"][:, :, 0] == 5.0)       # byte-exact, in order
-    assert np.all(pages["k"][:, :, 1] == 6.0)
-    assert np.all(pages["v"][:, :, 1] == 6.5)
+    assert pages["k"].shape == (2, 2, BS, 2)
+    assert np.all(pages["k"][:, 0] == 5.0)          # byte-exact, in order
+    assert np.all(pages["k"][:, 1] == 6.0)
+    assert np.all(pages["v"][:, 1] == 6.5)
     # the cache is now the pages' only owner
     assert cache.pages_cached == 2
     assert alloc.live_blocks == 2 and alloc.total_refs() == 2
@@ -237,7 +237,7 @@ def test_lru_watermark_order_deterministic(tmp_path):
     """Watermark enforcement always takes the least-recently-used entry
     first, and a match refreshes recency — deterministically."""
     eng = _StubEngine()
-    page_bytes = 2 * (1 * 2 * 1 * BS * 2) * 4
+    page_bytes = 2 * (2 * 1 * BS * 2) * 4
     tier = _tier(eng, tmp_path, dram_bytes=3 * page_bytes,
                  high_watermark=0.67, low_watermark=0.34)
     ka = list(range(BS))
@@ -340,7 +340,7 @@ def test_torn_dram_bundle_falls_back():
     tier = _tier(eng)
     tokens = list(range(BS))
     tier.capture(tokens, 5)
-    tier._entries[tuple(tokens)].bundle.pages["k"][0, 0, 0, 0, 0] += 1.0
+    tier._entries[tuple(tokens)].bundle.pages["k"][0, 0, 0, 0] += 1.0
     assert tier.adopt(tokens + [1], cache) == 0
     assert tier.total_pages == 0
     assert tier.counters["torn_spills"] == 1
@@ -417,10 +417,10 @@ def test_engine_evict_adopt_byte_exact(devices, tmp_path):
 
     rng = np.random.default_rng(1)
     blocks = alloc.allocate(2)
-    kvh, _, pbs, dh = eng.arena["k"].shape
     L = eng.model_config.num_layers
     pages = {k: rng.standard_normal(
-        (kvh, L, 2, pbs, dh)).astype(np.float32) for k in ("k", "v")}
+        (L, 2) + eng.arena[k].shape[1:]).astype(np.float32)
+        for k in ("k", "v")}
     eng.import_pages(pages, blocks)
     tokens = list(range(2 * bs))
     assert cache.insert(tokens, blocks) == 2

@@ -302,7 +302,7 @@ def test_many_tokens_are_served_in_rounds_and_none_is_dropped(skewed):
 
 @pytest.mark.parametrize("window", [None, 8])
 def test_the_paged_kernel_with_unequal_widths_and_a_window(window):
-    """``paged_attn_lse`` (interpret mode) over token-major pools, K 256
+    """``paged_attn_lse`` (interpret mode) over pools whose K is 256
     lanes wide (heads of 24 zero-padded) and V 128, history-only, with and
     without the window, against the XLA form at the true width's scale."""
     from deepspeed_tpu.ops import paged_attention as pa
@@ -317,17 +317,10 @@ def test_the_paged_kernel_with_unequal_widths_and_a_window(window):
     starts = jnp.asarray([0, 5, 19], jnp.int32)
     scale = 24 ** -0.5
     want, want_lse = pa.paged_attention_hist_xla(
-        q, ak, av, pt, starts, window=window, scale=scale, token_major=True)
+        q, ak, av, pt, starts, window=window, scale=scale)
     got, got_lse = pa.paged_attention_with_lse(
         q, ak, av, pt, starts, jnp.zeros_like(starts), interpret=True,
-        window=window, scale=scale, token_major=True)
-    # ... and the head-major pools the uniform stack keeps read the same
-    same, same_lse = pa.paged_attention_with_lse(
-        q, ak.reshape(nb + 1, bs, kvh, dk).transpose(2, 0, 1, 3),
-        av.reshape(nb + 1, bs, kvh, dv).transpose(2, 0, 1, 3), pt, starts,
-        jnp.zeros_like(starts), interpret=True, window=window, scale=scale)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
-    np.testing.assert_array_equal(np.asarray(got_lse), np.asarray(same_lse))
+        window=window, scale=scale)
     seen = np.asarray(want_lse) > -1e29          # rows with some history
     assert seen.any() and not seen.all()
     np.testing.assert_allclose(np.asarray(got)[seen], np.asarray(want)[seen],

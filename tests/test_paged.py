@@ -60,7 +60,7 @@ def test_pallas_matches_xla_chunk():
     rng = np.random.default_rng(1)
     kvh, dh, h, n, c = 2, 128, 4, 4, 8
     ak, av, pt3, starts3 = _random_arena_state(rng, kvh=kvh, dh=dh, n=3)
-    nb = ak.shape[1] - 1
+    nb = ak.shape[0] - 1
     pt = np.full((n, pt3.shape[1]), nb, np.int32)
     pt[:3] = pt3
     starts = np.zeros((n,), np.int32)
@@ -98,10 +98,10 @@ def test_trash_block_isolation():
                          jnp.zeros((1,), jnp.int32),
                          jnp.asarray([2], np.int32))
     a = np.asarray(ak)
-    assert np.all(a[:, 0, :2] == 7.0)        # valid writes
-    assert np.all(a[:, 0, 2:] == 0.0)        # rest of live block untouched
-    assert np.all(a[:, 1] == 0.0)            # next live block untouched
-    assert np.all(a[:, 2:nb] == 0.0)         # unrelated blocks untouched
+    assert np.all(a[0, :2] == 7.0)           # valid writes
+    assert np.all(a[0, 2:] == 0.0)           # rest of live block untouched
+    assert np.all(a[1] == 0.0)               # next live block untouched
+    assert np.all(a[2:nb] == 0.0)            # unrelated blocks untouched
 
 
 def test_ragged_forward_matches_cached(devices):
@@ -468,7 +468,7 @@ def hist_readers(request):
                 pt[i, b] = next(free)
     # every page holds finite noise, the trash page too: what a row has
     # not cached must not reach its output
-    shape = (kvh, nb + 1, bs, dh)
+    shape = (nb + 1, bs, kvh * dh)
     ak = jnp.asarray(rng.standard_normal(shape), dtype)
     av = jnp.asarray(rng.standard_normal(shape), dtype)
     q = jnp.asarray(rng.standard_normal((n, c, kvh * groups, dh)), dtype)
@@ -547,18 +547,18 @@ def test_split_step_matches_in_loop_write(devices, monkeypatch, reader,
     pages = [l * (nb + 1) + b for l in range(cfg.num_layers)
              for b in range(nb)]
     for name in ("k", "v"):
-        np.testing.assert_allclose(f32(got[name])[:, pages],
-                                   f32(want[name])[:, pages],
+        np.testing.assert_allclose(f32(got[name])[pages],
+                                   f32(want[name])[pages],
                                    rtol=tol, atol=tol, err_msg=name)
         # the step wrote: the continuation row's pages changed
-        assert not np.array_equal(f32(got[name])[:, pages],
-                                  f32(arena[name])[:, pages])
+        assert not np.array_equal(f32(got[name])[pages],
+                                  f32(arena[name])[pages])
 
 
 def _packed_stack(stack):
     """(cfg, float32 params, arena maker) of a stack the packed step
-    runs on: the tiny Llama block (one scanned layer tree, head-major
-    arena) or MiMo-V2.5's typed stack at the benchmark's rehearsal widths
+    runs on: the tiny Llama block (one scanned layer tree, one K and one
+    V pool) or MiMo-V2.5's typed stack at the benchmark's rehearsal widths
     (a full and a window kind with its sink, K heads of 192 and V of 128,
     a dense layer, then a top-8-of-16 router with 4 experts held)."""
     from deepspeed_tpu.models.transformer import init_params
@@ -669,9 +669,8 @@ def test_packed_chunk_step_matches_row_form(devices, case):
                                rtol=2e-4, atol=2e-4)
     assert set(got) == set(want)
     for name in want:
-        axis = 0 if cfg.typed else 1                   # the pages' axis
-        kept = np.arange(want[name].shape[axis]) % (nb + 1) != nb
-        a, b, before = (np.compress(kept, np.asarray(x[name]), axis)
+        kept = np.arange(want[name].shape[0]) % (nb + 1) != nb
+        a, b, before = (np.asarray(x[name])[kept]
                         for x in (got, want, arena))
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
                                    err_msg=name)
@@ -798,3 +797,234 @@ def test_generate_refuses_oversized_before_compute(devices):
     v2.step_with_budget()
     assert len(v2.generate([[4, 5]], max_new_tokens=2)[0]) == 4
     assert list(v2.state.seqs) == [5]
+
+
+#: the greedy tokens of :func:`test_golden_greedy_tokens`'s flow, a row a
+#: line (first token after the chunked prefill, three from decode steps,
+#: six from one megastep), RECORDED FROM THE PARENT OF PR 34 (head-major
+#: pools) before the arena went token-major: a layout moves no token
+_GOLDEN_GREEDY = [
+    [153, 69, 181, 12, 181, 12, 69, 236, 86, 229],
+    [132, 53, 66, 89, 195, 184, 126, 31, 133, 171],
+    [213, 143, 101, 129, 119, 102, 6, 169, 101, 129],
+    [40, 233, 21, 122, 39, 23, 228, 154, 178, 172],
+]
+
+
+def test_golden_greedy_tokens(devices):
+    """A small uniform-stack engine over a fixed ragged batch — prompts of
+    one to four prefill chunks (a ``fresh`` step, then ``split`` steps
+    that mix rows with and without history), three single-token decode
+    steps (the arena in the layer scan's carry, write then read), then one
+    megastep (read-only arena, per-loop buffer, one write-back) — emits
+    the tokens it emitted before PR 34 changed the arena's layout."""
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg = llama3_config("tiny", max_seq_len=128, vocab_size=256)
+    from deepspeed_tpu.models.transformer import init_params
+    eng = RaggedInferenceEngineTPU(
+        cfg, {"dtype": "float32", "num_blocks": 32, "block_size": 16,
+              "max_seq_len": 96, "prefill_chunk": 8, "max_batch_tokens": 64},
+        params=init_params(cfg, jax.random.PRNGKey(34)))
+    rng = np.random.default_rng(34)
+    uids = [0, 1, 2, 3]
+    got = {u: [] for u in uids}
+    eng.scheduler.put(uids, [[int(t) for t in rng.integers(0, 256, size=n)]
+                             for n in (5, 11, 23, 30)])
+    programs = []
+
+    def drain(**kwargs):
+        while (out := eng.step_with_budget(**kwargs)) is not None:
+            programs.append(eng.last_program)
+            for u, toks in out.items():
+                got[u].extend(toks if isinstance(toks, list) else [toks])
+
+    drain()                                     # the chunked prefill
+    for _ in range(3):                          # decode, one token a step
+        eng.scheduler.put(uids, [got[u][-1:] for u in uids])
+        drain()
+    eng.scheduler.put(uids, [got[u][-1:] for u in uids])
+    drain(max_steps=8, row_limits=dict.fromkeys(uids, 6))
+    assert programs == ["fresh"] + ["split"] * 3 + ["decode"] * 3 + \
+        ["megastep"]
+    assert [got[u] for u in uids] == _GOLDEN_GREEDY
+
+
+# -- one layout: pools [pages, block_size, kv_heads * head_dim] (PR 34) ----
+
+def _dense_attention(q, k, v, starts, counts):
+    """Row i's queries (positions ``starts[i] + j``, j < counts[i]) over
+    its own contiguous keys ``k[i, :starts[i] + counts[i]]``, causally, one
+    (row, head) at a time in float64 numpy: out [n, c, h, dv] and the
+    logsumexp [n, c, h]. Rows past ``counts`` stay zero."""
+    n, c, h, dk = q.shape
+    kvh = k.shape[2]
+    out = np.zeros((n, c, h, v.shape[-1]))
+    lse = np.zeros((n, c, h))
+    for i in range(n):
+        for j in range(counts[i]):
+            visible = starts[i] + j + 1
+            for head in range(h):
+                kh = head // (h // kvh)
+                s = k[i, :visible, kh].astype(np.float64) @ \
+                    q[i, j, head].astype(np.float64) / np.sqrt(dk)
+                p = np.exp(s - s.max())
+                out[i, j, head] = p @ v[i, :visible, kh] / p.sum()
+                lse[i, j, head] = s.max() + np.log(p.sum())
+    return out, lse
+
+
+#: reader case -> (chunk width, each row's new tokens): the decode
+#: program's reader, the same kernel over a chunk, the lse form
+_READER_CASES = {"decode": (1, [1, 1, 1, 0]), "chunk": (8, [8, 3, 8, 0]),
+                 "chunk_lse": (8, [8, 3, 8, 0])}
+
+
+@pytest.mark.parametrize("case", list(_READER_CASES))
+def test_paged_readers_match_a_dense_reference(case):
+    """``write_kv`` into a two-layer pool through an out-of-order page
+    table, then the Pallas readers (interpret mode: ``paged_attention`` at
+    c = 1 and c > 1, ``paged_attention_with_lse``) and the XLA reader over
+    the second layer's region, against plain attention over each row's
+    contiguous keys. Heads of 128 side by side on the lanes; a padded row
+    among the live ones."""
+    c, counts = _READER_CASES[case]
+    rng = np.random.default_rng(34)
+    kvh, h, dh, bs, nb, mb, n = 2, 4, 128, 16, 9, 3, 4
+    starts = np.asarray([5, 30, 17, 0], np.int32)
+    counts = np.asarray(counts, np.int32)
+    arena = pa.init_arena(2, kvh, nb, bs, dh, jnp.float32)
+    assert arena["k"].shape == (2 * (nb + 1), bs, kvh * dh)
+    off = int(pa.layer_page_offset(1, nb))
+    pt = np.full((n, mb), nb, np.int32)
+    free = iter(rng.permutation(nb))
+    for i in range(3):
+        pages = -(-(starts[i] + counts[i]) // bs)
+        pt[i, :pages] = [next(free) for _ in range(pages)]
+    pt_l = jnp.asarray(pt + off)
+    k = rng.standard_normal((n, mb * bs, kvh, dh)).astype(np.float32)
+    v = rng.standard_normal((n, mb * bs, kvh, dh)).astype(np.float32)
+    # the history, then the step's own tokens: two writes, as the engine's
+    ak, av = pa.write_kv(arena["k"], arena["v"], jnp.asarray(k),
+                         jnp.asarray(v), pt_l, jnp.zeros((n,), jnp.int32),
+                         jnp.asarray(starts), trash_block=off + nb)
+    new = np.stack([np.stack([x[i, starts[i]:starts[i] + c] for i in range(n)])
+                    for x in (k, v)])
+    ak, av = pa.write_kv(ak, av, jnp.asarray(new[0]), jnp.asarray(new[1]),
+                         pt_l, jnp.asarray(starts), jnp.asarray(counts),
+                         trash_block=off + nb)
+    assert not np.asarray(ak)[:off].any()             # layer 0 untouched
+    q = rng.standard_normal((n, c, h, dh)).astype(np.float32)
+    want, want_lse = _dense_attention(q, k, v, starts, counts)
+    args = (jnp.asarray(q), ak, av, pt_l, jnp.asarray(starts),
+            jnp.asarray(counts))
+    xla, xla_lse = pa.paged_attention_xla(*args, with_lse=True)
+    if case == "chunk_lse":
+        got, got_lse = pa.paged_attention_with_lse(*args, interpret=True)
+    else:
+        got, got_lse = pa.paged_attention(*args, interpret=True), None
+    live = np.arange(c)[None] < counts[:, None]
+    assert live.any(axis=1).tolist() == [True, True, True, False]
+    for name, out, lse in (("xla", xla, xla_lse), ("pallas", got, got_lse)):
+        np.testing.assert_allclose(np.asarray(out)[live], want[live],
+                                   rtol=2e-5, atol=2e-5, err_msg=name)
+        if lse is not None:
+            np.testing.assert_allclose(np.asarray(lse)[live], want_lse[live],
+                                       rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+def test_write_then_gather_round_trip_and_the_layers_trash_page():
+    """What ``write_kv`` scatters, ``_gather_pages`` reads back head by
+    head; a row's padded tokens and a padded row land in the trash page
+    the caller names (the LAYER's, not the pool's last) and nowhere
+    else."""
+    rng = np.random.default_rng(3)
+    kvh, dh, bs, nb, n, c = 2, 16, 4, 5, 3, 6
+    arena = pa.init_arena(3, kvh, nb, bs, dh, jnp.float32)
+    off, trash = nb + 1, 2 * nb + 1               # layer 1 of 3
+    pt = jnp.asarray([[2, 4], [0, 1], [nb, nb]], jnp.int32) + off
+    k = rng.standard_normal((n, c, kvh, dh)).astype(np.float32)
+    v = rng.standard_normal((n, c, kvh, dh)).astype(np.float32)
+    counts = np.asarray([6, 2, 0], np.int32)
+    ak, av = pa.write_kv(arena["k"], arena["v"], jnp.asarray(k),
+                         jnp.asarray(v), pt, jnp.zeros((n,), jnp.int32),
+                         jnp.asarray(counts), trash_block=trash)
+    for pool, new in ((ak, k), (av, v)):
+        back = np.asarray(pa._gather_pages(pool, pt, kvh))  # [n,S,kvh,dh]
+        for i in range(n - 1):
+            np.testing.assert_array_equal(back[i, :counts[i]],
+                                          new[i, :counts[i]])
+            assert not back[i, counts[i]:].any()
+        pool = np.asarray(pool)
+        written = {int(p) for p in np.flatnonzero(pool.any(axis=(1, 2)))}
+        assert written == {off + 2, off + 4, off + 0, trash}
+
+
+def test_copy_pages_copies_a_page_in_every_layer_of_every_pool():
+    """``copy_pages`` over pools of different layer counts and widths (a
+    typed arena's): page ``src`` of each layer's region lands on ``dst``,
+    nothing else moves."""
+    rng = np.random.default_rng(5)
+    nb, bs = 4, 2
+    arena = {"k": rng.standard_normal((3 * (nb + 1), bs, 8)),
+             "v_win": rng.standard_normal((2 * (nb + 1), bs, 4))}
+    arena = {name: jnp.asarray(a, jnp.float32) for name, a in arena.items()}
+    got = pa.copy_pages(arena, jnp.asarray([1, 3]), jnp.asarray([0, 2]),
+                        stride=nb + 1)
+    for name, pool in arena.items():
+        want = np.array(pool)
+        for layer in range(pool.shape[0] // (nb + 1)):
+            base = layer * (nb + 1)
+            want[base + 0], want[base + 2] = want[base + 1], want[base + 3]
+        np.testing.assert_array_equal(np.asarray(got[name]), want)
+
+
+def test_exported_pages_continue_in_a_second_engine(devices):
+    """``export_pages`` after a chunked prefill, ``import_pages`` at other
+    page ids of a second engine with the same weights, and a sequence
+    adopted over them decodes the tokens the first engine goes on to
+    decode. A bundle that does not fit — another page count, the
+    five-axis ``[kvh, L, m, bs, dh]`` bundle of the head-major arena — is
+    refused by name."""
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg = llama3_config("tiny", max_seq_len=128, vocab_size=256)
+    from deepspeed_tpu.models.transformer import init_params
+    params = init_params(cfg, jax.random.PRNGKey(8))
+    eng_cfg = {"dtype": "float32", "num_blocks": 16, "block_size": 8,
+               "max_seq_len": 64, "prefill_chunk": 8, "max_batch_tokens": 32}
+    prompt = [int(t) for t in
+              np.random.default_rng(9).integers(0, 256, size=21)]
+
+    def decode(eng, uid, first, steps):
+        toks = [first]
+        for _ in range(steps):
+            eng.scheduler.put([uid], [toks[-1:]])
+            toks.append(eng.step_with_budget()[uid])
+        return toks
+
+    src = RaggedInferenceEngineTPU(cfg, eng_cfg, params=params)
+    src.scheduler.put([0], [prompt])
+    while (out := src.step_with_budget()) is not None:
+        first = out.get(0)
+    blocks = list(src.state.seqs[0].blocks)
+    assert len(blocks) == 3 and first is not None
+    pages = src.export_pages(blocks)
+    L, kvh, dh = cfg.num_layers, cfg.kv_heads, cfg.head_dim
+    assert pages["k"].shape == pages["v"].shape == (L, 3, 8, kvh * dh)
+    assert pages["k"].nbytes + pages["v"].nbytes == 3 * src.kv_page_nbytes()
+    want = decode(src, 0, first, 4)
+
+    dst = RaggedInferenceEngineTPU(cfg, eng_cfg, params=params)
+    alloc = dst.state.allocator
+    held = alloc.allocate(5)                    # other page ids than src's
+    there = alloc.allocate(3)
+    assert there != blocks
+    dst.import_pages(pages, there)
+    dst.state.adopt(7, prompt, there, len(prompt))
+    assert decode(dst, 7, first, 4) == want
+    alloc.free(held)
+
+    for bad in ({key: a[:, :2] for key, a in pages.items()},
+                {key: a.reshape(L, 3, 8, kvh, dh).transpose(3, 0, 1, 2, 4)
+                 for key, a in pages.items()}):
+        with pytest.raises(ValueError, match="does not fit this arena"):
+            dst.import_pages(bad, there)

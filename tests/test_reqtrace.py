@@ -491,7 +491,7 @@ def test_kvtier_prefetch_adopt_and_fallback_spans(rt, tmp_path):
 
         def export_pages(self, blocks):
             m = len(blocks)
-            return {k: np.full((1, 2, m, BS, 2), 1.0, np.float32)
+            return {k: np.full((2, m, BS, 2), 1.0, np.float32)
                     for k in ("k", "v")}
 
         def import_pages(self, pages, blocks):
@@ -499,7 +499,7 @@ def test_kvtier_prefetch_adopt_and_fallback_spans(rt, tmp_path):
 
     eng = _Eng()
     cache = PrefixCache(eng.state.allocator)
-    page_bytes = 2 * (1 * 2 * 1 * BS * 2) * 4
+    page_bytes = 2 * (2 * 1 * BS * 2) * 4
     tier = KVTier(eng, dram_bytes=2 * page_bytes, high_watermark=0.5,
                   low_watermark=0.25, nvme_dir=str(tmp_path / "nvme"))
     k1 = list(range(BS))

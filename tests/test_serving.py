@@ -308,18 +308,18 @@ def test_cow_block_copies_all_layers(devices):
     # stamp the source page across every layer's region
     import jax.numpy as jnp
     nl = eng.model_config.num_layers
-    stride = eng.arena["k"].shape[1] // nl
+    stride = eng.arena["k"].shape[0] // nl
     k = np.array(eng.arena["k"])           # writable host copy
     for layer in range(nl):
-        k[:, layer * stride + src] = float(layer + 1)
+        k[layer * stride + src] = float(layer + 1)
     eng.arena = {"k": jnp.asarray(k), "v": eng.arena["v"]}
     dst = eng.cow_block(src)
     assert dst != src and alloc.refcount(dst) == 1
     got = np.asarray(eng.arena["k"])
     for layer in range(nl):
-        np.testing.assert_array_equal(got[:, layer * stride + dst],
-                                      got[:, layer * stride + src])
-        assert np.all(got[:, layer * stride + dst] == float(layer + 1))
+        np.testing.assert_array_equal(got[layer * stride + dst],
+                                      got[layer * stride + src])
+        assert np.all(got[layer * stride + dst] == float(layer + 1))
     alloc.free([src, dst])
 
 

@@ -84,11 +84,11 @@ def _flash(seq, batch, grad):
 
 def _paged(n, c, with_lse, heads=16, pages=8):
     """Paged attention over the default serving arena: 16 layers of
-    512 pages (+1 trash page each) of 128 tokens, 8 KV heads of 128;
-    ``pages`` a row (8: ``max_seq_len`` 1024)."""
+    512 pages (+1 trash page each) of 128 tokens, 8 KV heads of 128 side
+    by side on the lanes; ``pages`` a row (8: ``max_seq_len`` 1024)."""
     from deepspeed_tpu.ops import paged_attention as pa
     kern = pa.paged_attention_with_lse if with_lse else pa.paged_attention
-    arena = ((8, 16 * (512 + 1), 128, 128), jnp.bfloat16)
+    arena = ((16 * (512 + 1), 128, 8 * 128), jnp.bfloat16)
     q = ((n, c, heads, 128), jnp.bfloat16)
     pt = ((n, pages), jnp.int32)
     vec = ((n,), jnp.int32)
@@ -99,7 +99,7 @@ def _paged(n, c, with_lse, heads=16, pages=8):
 def _paged_typed(kvh):
     """The split step's history reader over a TYPED arena at MiMo-V2.5's
     widths: 64 query heads, K heads of 192 padded to 256 lanes, V heads of
-    128, token-major pools ``[blocks, 128, kvh * width]`` (5 window layers
+    128, pools ``[blocks, 128, kvh * width]`` (5 window layers
     of 8 KV heads with a window of 128; 2 full layers of 4), 64 rows of
     chunk 128, ``max_seq_len`` 1024."""
     from deepspeed_tpu.ops.paged_attention import paged_attention_with_lse
@@ -109,7 +109,7 @@ def _paged_typed(kvh):
     def fn(q, ak, av, pt, starts):
         return paged_attention_with_lse(
             q, ak, av, pt, starts, jnp.zeros_like(starts), window=window,
-            scale=192 ** -0.5, token_major=True)
+            scale=192 ** -0.5)
     bf = jnp.bfloat16
     return fn, (((64, 128, 64, 256), bf), ((blocks, 128, kvh * 256), bf),
                 ((blocks, 128, kvh * 128), bf), ((64, 8), jnp.int32),
@@ -165,7 +165,7 @@ CASES = {
     "paged_hist_n64_c128_lse": lambda: _paged(64, 128, with_lse=True,
                                               heads=32, pages=32),
     # ... and over a typed arena (MiMo-V2.5: unequal K / V widths, a
-    # window, token-major pools read by lane slices), both layer kinds
+    # window), both layer kinds
     "paged_hist_typed_window_kv8_lse": lambda: _paged_typed(8),
     "paged_hist_typed_full_kv4_lse": lambda: _paged_typed(4),
     "dequant_int8": lambda: _dequant("int8"),
@@ -250,26 +250,35 @@ def _abstract_params(model, sharding):
                        jax.random.PRNGKey(0)))
 
 
-#: the 64-row x 128-wide split program's token capacities under the
-#: engine's defaults (``_token_capacities``: 16 a row, ``max_batch_tokens``)
-_SPLIT_CAPACITIES = (1024, 2048)
+#: the serving cell's three 64-row step programs -> (chunk width,
+#: ``fresh_prefill``, token capacities under the engine's defaults
+#: (``_token_capacities``: 16 a row and ``max_batch_tokens``; a decode step
+#: keeps the row form), most temporaries at two layers (measured 0.00,
+#: 0.56 and under 0.15 GB, ISSUE 34))
+_SERVE_STEPS = {
+    "decode": (1, False, (), 0.3e9),
+    "split": (128, "split", (1024, 2048), 1.0e9),
+    "fresh": (128, "fresh", (2048,), 0.3e9),
+}
 
 
-def _serve_split(one_chip, token_capacities=_SPLIT_CAPACITIES):
-    """The 64-row ``split`` step of the benchmark's serving cell: chunk 128
-    over the default arena (512 pages of 128, ``max_seq_len`` 4096), its
-    token-wise sublayers over the packed tokens at the cell's capacities
-    (``()``: the row form, 8,192 slots)."""
+def _serve_step(one_chip, kind="split"):
+    """A 64-row step of the benchmark's serving cell (``serve_decode_r64``,
+    ``serve_split_r64_c128``, ``serve_fresh_r64_c128``): chunk 128 or one
+    token a row over the default arena (512 pages of 128, ``max_seq_len``
+    4096), a chunk's token-wise sublayers over the packed tokens at the
+    cell's capacities."""
     from deepspeed_tpu.inference import engine_v2
     from deepspeed_tpu.ops import paged_attention as pa
     model = _mistral_2l()
-    nb, cb, mb = 64, 128, 32
+    cb, fresh, capacities, _ = _SERVE_STEPS[kind]
+    nb, mb = 64, 32
 
-    def serve_split(params, arena, tokens, counts, starts, pt):
+    def serve_step(params, arena, tokens, counts, starts, pt):
         logits, arena = engine_v2.ragged_forward(
             model, params, arena, tokens, counts, starts, pt,
-            use_pallas=True, fresh_prefill="split",
-            token_capacities=token_capacities)
+            use_pallas=True, fresh_prefill=fresh,
+            token_capacities=capacities)
         out, _ = engine_v2._sample_tokens(logits, ("argmax",), 1.0, 1.0,
                                           None)
         return out, arena
@@ -282,7 +291,7 @@ def _serve_split(one_chip, token_capacities=_SPLIT_CAPACITIES):
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    return jax.jit(serve_split, donate_argnums=(1,)), (
+    return jax.jit(serve_step, donate_argnums=(1,)), (
         _abstract_params(model, one_chip), arena, i32(nb, cb), i32(nb),
         i32(nb), i32(nb, mb))
 
@@ -347,7 +356,7 @@ def _fused_step(one_chip):
 
 #: program -> (builder, scopes that must each name some instruction)
 PROGRAMS = {
-    "serve_split": (_serve_split, (
+    "serve_split": (_serve_step, (
         "embed", "norm", "attn_qkv", "attn_core", "attn_history",
         "attn_merge", "kv_write", "attn_out", "mlp", "lm_head", "sample")),
     "fused_step": (_fused_step, (
@@ -399,73 +408,58 @@ def test_step_program_maps_to_scopes_on_v5e(program, one_chip,
         assert not backward - {None} and not remat - {None}
 
 
-_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
-_CALLED = re.compile(r"\b(?:body|condition|calls|to_apply)=%([\w.\-]+)")
 _BRANCHES = re.compile(r"\b(?:branch_computations=\{([^}]*)\}|"
                        r"(?:true|false)_computation=(%[\w.\-]+))")
 
 
-def _in_loops_and_branches(text):
-    """The lines of every computation a ``while`` body or a branch of a
-    ``conditional`` reaches (the body or branch, the fusions and calls
-    inside it, nested loops and conditionals); and the branches' names."""
-    lines, calls, bodies, branches, name = {}, {}, set(), set(), None
-    for line in text.splitlines():
-        head = _COMPUTATION.match(line)
-        if head:
-            name = head.group(1)
-            lines[name], calls[name] = [], set()
-        elif name is not None:
-            lines[name].append(line)
-            here = {b.strip().lstrip("%") for m in _BRANCHES.finditer(line)
-                    for b in (m.group(1) or m.group(2)).split(",")}
-            branches |= here
-            calls[name].update(_CALLED.findall(line), here)
-            bodies.update(re.findall(r"\bbody=%([\w.\-]+)", line))
-    reached, todo = set(), list(bodies | branches)
-    while todo:
-        comp = todo.pop()
-        if comp not in reached:
-            reached.add(comp)
-            todo.extend(calls[comp])
-    assert bodies and reached <= set(lines)
-    return [line for comp in reached for line in lines[comp]], branches
+def _branches(text):
+    """The names of the computations some ``conditional`` branches to."""
+    return {b.strip().lstrip("%") for m in _BRANCHES.finditer(text)
+            for b in (m.group(1) or m.group(2)).split(",")}
 
 
-def test_serve_split_reads_live_pages_from_a_read_only_arena(
-        one_chip, no_persistent_cache, monkeypatch):
-    """The split step at the serving cell's shapes: its history goes
-    through the paged kernel under ``attn_history``; the arena stays out
-    of the layer loop's carry AND out of what the capacity switch's
-    branches write, so NO arena-shaped copy runs inside a loop or a
-    conditional branch (carried beside the kernel it is relaid whole twice
-    a layer: ISSUE 29, docs/kernels.md); without the gathered float32
-    scores (6.08 GB of temporaries at two layers) the program's
-    temporaries stay under 2 GB; and with the token-wise sublayers over
-    2,048 packed slots where the rows hold 8,192, the program counts at
-    most 0.35 of the row form's FLOPs (3.593e12 at two layers, this
-    compiler's own count of ``_serve_split(one_chip, ())``; measured
-    0.254: the matmuls a quarter, attention as it was)."""
+@pytest.mark.parametrize("kind", list(_SERVE_STEPS))
+def test_no_serve_step_moves_the_arena(kind, one_chip, no_persistent_cache,
+                                       monkeypatch):
+    """The serving cell's three 64-row step programs: NO arena-shaped
+    ``copy`` anywhere in the module, ENTRY included — the scatter writes
+    the pools in the layout the kernels read, so the decode program's
+    carry (write then kernel read, every layer) aliases and no program
+    relays a pool on entry or exit (head-major pools took 6 / 4 / 4 such
+    copies and 3.2 GB of temporaries at twelve layers: ISSUE 34,
+    docs/kernels.md) — and temporaries far under a pool's size. The split
+    step's history goes through the paged kernel under ``attn_history``,
+    over a read-only arena: one branch a capacity, none of which writes
+    it; with the token-wise sublayers over 2,048 packed slots where the
+    rows hold 8,192, it counts at most 0.35 of the row form's FLOPs
+    (3.593e12 at two layers, this compiler's own count of the same program
+    with ``token_capacities=()``; measured 0.254: the matmuls a quarter,
+    attention as it was)."""
     from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    jitted, args = _serve_split(one_chip)
+    jitted, args = _serve_step(one_chip, kind)
     compiled = jitted.lower(*args).compile()
     text = compiled.as_text()
+    arena = args[1]["k"]
+    shape = "bf16[" + ",".join(map(str, arena.shape)) + "]"
+    arena_copy = re.compile(rf" = {re.escape(shape)}\S* copy\(")
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if arena_copy.search(line)]
+    assert not copies, copies
+    # ... and the pattern can match: a pool relaid and relaid back, for
+    # the same chip, holds such a copy
+    relaid = jax.jit(lambda a: jax.lax.optimization_barrier(
+        a.transpose(2, 0, 1)).transpose(1, 2, 0)).lower(arena).compile()
+    assert arena_copy.search(relaid.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        _SERVE_STEPS[kind][3]
+    if kind != "split":
+        return
     table = scope_table_from_hlo(text)
     kernels = [n for n in table if n.startswith("paged_attn_lse")]
     assert kernels and all(table[n]["scope"] == "attn_history"
                            for n in kernels), kernels
-    arena = args[1]["k"]
-    shape = "bf16[" + ",".join(map(str, arena.shape)) + "]"
-    arena_copy = re.compile(rf" = {re.escape(shape)}\S* copy\(")
-    inside, branches = _in_loops_and_branches(text)
     # one instance of the layer loop a capacity, in ONE executable
-    assert len(branches) == len(_SPLIT_CAPACITIES), branches
-    copies = [line.strip()[:160] for line in inside
-              if arena_copy.search(line)]
-    assert not copies, copies
-    # ... and the pattern does find the entry's relayouts (in for the
-    # scatter's layout, out again), so an empty list above means something
-    assert arena_copy.search(text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    branches = _branches(text)
+    assert len(branches) == len(_SERVE_STEPS[kind][2]), branches
     assert compiled.cost_analysis()["flops"] <= 0.35 * 3.593e12
