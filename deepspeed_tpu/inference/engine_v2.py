@@ -350,28 +350,148 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     (``paged_attn_lse``) for both kinds; the chunk's own attention, the
     fresh step's, and the decode step's paged read are the XLA forms (the
     flash kernels take one head width for Q, K and V; the decode read of a
-    window layer is two pages a row)."""
+    window layer is two pages a row).
+
+    A LATENT layer (kind 2) has one pool of one row a token (``[c ;
+    k_rope]``, zero lanes up to the pool's width) and two forms of one
+    attention (``latent_attention`` below): whatever reads the pool — the
+    decode step, the split step's history — is ABSORBED (``mla_decode`` on
+    the chip, walking each row's live pages; the XLA readers with
+    ``v_lanes`` elsewhere), and a chunk's own attention is EXPANDED from
+    the chunk's own latents; ``merge_attention`` joins the two partials of
+    a split step in the heads' space, on the packed tokens. The step's
+    shape picks the form: no option does."""
     c = tokens.shape[1]
     split = fresh_prefill == "split" and c > 1
-    scale = cfg.head_dim ** -0.5
+    scale = cfg.attn_scale
     of_kind = {a: sum(1 for b in cfg.layer_kinds if b == a)
                for a in set(cfg.layer_kinds)}
     seen = dict.fromkeys(of_kind, 0)
     places = []     # a layer's pools, and where its pages lie in them
     for kind in cfg.layer_kinds:
-        kname, vname = pa.KIND_POOLS[kind]
-        stride = arena[kname].shape[0] // of_kind[kind]   # num_blocks + 1
+        names = pa.KIND_POOLS[kind]
+        stride = arena[names[0]].shape[0] // of_kind[kind]  # num_blocks + 1
         off = seen[kind] * stride
         seen[kind] += 1
         # padded entries of the page table → this layer's trash
-        places.append((kname, vname, page_table + off, off + stride - 1))
+        places.append((names, page_table + off, off + stride - 1))
 
-    def write(pools, place, k, v):
-        kname, vname, pt_l, trash = place
+    def write(pools, place, *kv):
+        """A layer's chunk into its pools: (k, v), or a latent layer's one
+        row a token."""
+        names, pt_l, trash = place
         with jax.named_scope("kv_write"):
-            pools[kname], pools[vname] = pa.write_kv(
-                pools[kname], pools[vname], k, v, pt_l, starts, counts,
-                trash_block=trash)
+            if len(names) == 1:
+                pools[names[0]] = pa.write_rows(
+                    pools[names[0]], *kv, pt_l, starts, counts,
+                    trash_block=trash)
+            else:
+                pools[names[0]], pools[names[1]] = pa.write_kv(
+                    pools[names[0]], pools[names[1]], *kv, pt_l, starts,
+                    counts, trash_block=trash)
+
+    def heads_attention(lay, kind, a, place, h_in, table, pools, chunk_kv):
+        """A full or window layer's attention on its normed input
+        (token-wise form) → the heads' outputs in the same form."""
+        (kname, vname), pt_l, _ = place
+        window, sink = cfg.kind_window(kind), a.get("sink")
+        q, k, v = tl.typed_qkv(cfg, kind, a, h_in, *table)
+        with jax.named_scope("attn_qkv"):     # attention sees rows
+            pad = pools[kname].shape[-1] // k.shape[2] - cfg.head_dim
+            if pad:
+                q, k = (jnp.pad(t, ((0, 0),) * 3 + ((0, pad),))
+                        for t in (q, k))
+            q, k, v = (lay.to_rows(t) for t in (q, k, v))
+        if split:
+            with jax.named_scope("attn_history"):
+                if use_pallas:
+                    out_h, lse_h = pa.paged_attention_with_lse(
+                        q, pools[kname], pools[vname], pt_l, starts,
+                        jnp.zeros_like(starts), window=window, scale=scale)
+                else:
+                    out_h, lse_h = pa.paged_attention_hist_xla(
+                        q, pools[kname], pools[vname], pt_l, starts,
+                        window=window, scale=scale)
+            with jax.named_scope("attn_core"):
+                out_c, lse_c = pa.causal_attention_with_lse(
+                    q, k, v, window=window, scale=scale)
+            with jax.named_scope("attn_merge"):
+                out = pa.merge_attention(out_h, lse_h, out_c, lse_c,
+                                         sink).astype(q.dtype)
+            chunk_kv.append((k, v))
+        else:
+            write(pools, place, k, v)
+            with jax.named_scope("attn_core"):
+                if fresh_prefill == "fresh":
+                    out, lse = pa.causal_attention_with_lse(
+                        q, k, v, window=window, scale=scale)
+                else:
+                    out, lse = pa.paged_attention_xla(
+                        q, pools[kname], pools[vname], pt_l, starts, counts,
+                        window=window, scale=scale, with_lse=True)
+                out = tl.apply_sink(out, lse, sink)
+        with jax.named_scope("attn_out"):     # ... and tokens again
+            return lay.to_tokens(out)
+
+    def latent_attention(lay, _kind, a, place, h_in, table, pools,
+                         chunk_kv):
+        """A latent layer's attention on its normed input (token-wise
+        form) → the heads' outputs [.., H, v] in the same form. What READS
+        THE CACHE is absorbed (the decode step, the split step's history:
+        queries in the latent space against the pool's rows, ``W_UV``
+        after); a chunk's OWN attention is expanded from the chunk's own
+        latents (short: ``W_kvb`` over its tokens, heads of nope + rope)."""
+        (pool,), pt_l, _ = place
+        kl, dtype = cfg.kv_lora_rank, h_in.dtype
+        q_nope, q_rope, latent = tl.latent_qkv(cfg, a, h_in, *table)
+        own = split or fresh_prefill == "fresh"
+        with jax.named_scope("attn_qkv"):     # attention sees rows
+            latent_rows = lay.to_rows(latent)
+        if own:
+            q, k, v = tl.latent_expand_kv(cfg, a, q_nope, q_rope, latent)
+            with jax.named_scope("attn_qkv"):
+                q, k, v = (lay.to_rows(t) for t in (q, k, v))
+        if fresh_prefill != "fresh":
+            q_lat = tl.latent_absorb_q(cfg, a, q_nope, q_rope,
+                                       pools[pool].shape[-1])
+            with jax.named_scope("attn_qkv"):
+                q_lat = lay.to_rows(q_lat)
+        if split:
+            with jax.named_scope("attn_history"):
+                if use_pallas:
+                    out_h, lse_h = pa.mla_decode(
+                        q_lat, pools[pool], pt_l, starts,
+                        jnp.zeros_like(starts), counts, v_lanes=kl,
+                        scale=scale)
+                else:
+                    out_h, lse_h = pa.paged_attention_hist_xla(
+                        q_lat, pools[pool], None, pt_l, starts, scale=scale,
+                        v_lanes=kl)
+            with jax.named_scope("attn_core"):
+                out_c, lse_c = pa.causal_attention_with_lse(q, k, v,
+                                                            scale=scale)
+            with jax.named_scope("attn_out"):     # ... and tokens again
+                out_h, lse_h, out_c, lse_c = (
+                    lay.to_tokens(t) for t in (out_h, lse_h, out_c, lse_c))
+            out_h = tl.latent_expand_out(cfg, a, out_h)
+            with jax.named_scope("attn_merge"):
+                out = pa.merge_attention(out_h, lse_h, out_c, lse_c)
+            chunk_kv.append((latent_rows,))
+            return out.astype(dtype)
+        write(pools, place, latent_rows)
+        with jax.named_scope("attn_core"):
+            if own:
+                out = pa.causal_attention_with_lse(q, k, v, scale=scale)[0]
+            elif use_pallas:
+                out = pa.mla_decode(q_lat, pools[pool], pt_l, starts, counts,
+                                    counts, v_lanes=kl, scale=scale)[0]
+            else:
+                out = pa.paged_attention_xla(
+                    q_lat, pools[pool], None, pt_l, starts, counts,
+                    scale=scale, v_lanes=kl)
+        with jax.named_scope("attn_out"):
+            out = lay.to_tokens(out)
+        return out if own else tl.latent_expand_out(cfg, a, out)
 
     def run(capacity):
         """Embedding to final norm at one capacity → (each row's last
@@ -387,49 +507,10 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         chunk_kv = []
         for kind, lp, place in zip(cfg.layer_kinds, params["layers"],
                                    places):
-            kname, vname, pt_l, _ = place
-            window, sink = cfg.kind_window(kind), lp["attn"].get("sink")
             h_in = _norm(cfg, lp["ln1"], x).astype(dtype)
-            q, k, v = tl.typed_qkv(cfg, kind, lp["attn"], h_in,
-                                   *tables[kind])
-            with jax.named_scope("attn_qkv"):     # attention sees rows
-                pad = pools[kname].shape[-1] // k.shape[2] - cfg.head_dim
-                if pad:
-                    q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, pad),))
-                            for a in (q, k))
-                q, k, v = (lay.to_rows(a) for a in (q, k, v))
-            if split:
-                with jax.named_scope("attn_history"):
-                    if use_pallas:
-                        out_h, lse_h = pa.paged_attention_with_lse(
-                            q, pools[kname], pools[vname], pt_l, starts,
-                            jnp.zeros_like(starts), window=window,
-                            scale=scale)
-                    else:
-                        out_h, lse_h = pa.paged_attention_hist_xla(
-                            q, pools[kname], pools[vname], pt_l, starts,
-                            window=window, scale=scale)
-                with jax.named_scope("attn_core"):
-                    out_c, lse_c = pa.causal_attention_with_lse(
-                        q, k, v, window=window, scale=scale)
-                with jax.named_scope("attn_merge"):
-                    out = pa.merge_attention(out_h, lse_h, out_c, lse_c,
-                                             sink).astype(q.dtype)
-                chunk_kv.append((k, v))
-            else:
-                write(pools, place, k, v)
-                with jax.named_scope("attn_core"):
-                    if fresh_prefill == "fresh":
-                        out, lse = pa.causal_attention_with_lse(
-                            q, k, v, window=window, scale=scale)
-                    else:
-                        out, lse = pa.paged_attention_xla(
-                            q, pools[kname], pools[vname], pt_l, starts,
-                            counts, window=window, scale=scale,
-                            with_lse=True)
-                    out = tl.apply_sink(out, lse, sink)
-            with jax.named_scope("attn_out"):     # ... and tokens again
-                out = lay.to_tokens(out)
+            attend = latent_attention if kind == 2 else heads_attention
+            out = attend(lay, kind, lp["attn"], place, h_in, tables[kind],
+                         pools, chunk_kv)
             x = x + tl.typed_attn_out(cfg, lp["attn"], out)
             x = x + tl.typed_ffn(cfg, lp, _norm(cfg, lp["ln2"], x), moe_fn,
                                  lay.valid, dtype)
@@ -440,8 +521,8 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     x_last, out = _at_capacity(token_capacities, counts.sum(), run)
     if split:
         pools = dict(arena)
-        for place, (k, v) in zip(places, out):
-            write(pools, place, k, v)
+        for place, kv in zip(places, out):
+            write(pools, place, *kv)
     else:
         pools = out
     return lm_logits(cfg, params, x_last)[:, 0], pools
@@ -571,16 +652,21 @@ class RaggedInferenceEngineTPU:
                       "float16": jnp.float16}[config.dtype]
         # a typed stack's K heads are zero-padded to whole 128-lane tiles
         # for the paged kernel (192 -> 256); the uniform stack's are as wide
-        # as the kernel takes them, or it is refused
-        lanes = -(-model.head_dim // 128) * 128 if model.typed \
-            else model.head_dim
+        # as the kernel takes them, or it is refused. A latent stack's pool
+        # holds one row a token, padded likewise (576 -> 640: the kernel
+        # copies whole pages, and a DMA wants whole lane tiles), and what
+        # the kernel sums is the row's first kv_lora_rank lanes
+        latent = model.latent
+        width = model.latent_dim if latent else model.head_dim
+        lanes = -(-width // 128) * 128 if model.typed else width
         if config.use_pallas is None:
             self.use_pallas = pa.supported(lanes, config.block_size) and \
-                model.v_dim % 128 == 0
+                (model.kv_lora_rank if latent else model.v_dim) % 128 == 0
         else:
             self.use_pallas = bool(config.use_pallas)
-        #: width of the K pool: the heads', or the padded lanes
-        self.k_width = lanes if self.use_pallas else model.head_dim
+        #: width of the K pool (a latent stack: of its one pool): the
+        #: heads' (the row's), or the padded lanes
+        self.k_width = lanes if self.use_pallas else width
 
         self.state = DSStateManager(max_sequences=config.max_sequences,
                                     num_blocks=config.num_blocks,
@@ -1224,7 +1310,9 @@ class RaggedInferenceEngineTPU:
         ``dispatch/kv_window_live_tokens`` / ``..._held_tokens`` and the
         span's ``kv_tokens_full`` (what a full layer holds for the rows:
         ``context_tokens``), ``kv_tokens_window_live`` and
-        ``kv_tokens_window_held``."""
+        ``kv_tokens_window_held``. A latent stack's span carries
+        ``kv_tokens_latent``: the cached rows ONE latent layer holds for
+        the batch's rows after the launch (``context_tokens``)."""
         from deepspeed_tpu.telemetry.registry import registry
         row_slots = nb * chunk * scan_steps
         slots = row_slots if token_slots is None else token_slots
@@ -1249,6 +1337,8 @@ class RaggedInferenceEngineTPU:
             work.update(kv_tokens_full=context_tokens,
                         kv_tokens_window_live=live,
                         kv_tokens_window_held=held)
+        if self.model_config.latent:
+            work["kv_tokens_latent"] = context_tokens
         return work
 
     # -- fused decode loop (the megastep's program) ------------------------

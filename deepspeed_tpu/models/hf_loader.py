@@ -33,7 +33,7 @@ Params = Any
 _FAMILIES = ("llama", "mistral", "mixtral", "qwen", "qwen2", "qwen2_moe",
               "gpt_neox", "gemma", "gpt2", "opt", "bloom", "falcon",
               "phi", "phi3", "gpt_bigcode", "gptj", "bert", "distilbert",
-              "gpt_neo", "internlm", "mimo_v2")
+              "gpt_neo", "internlm", "mimo_v2", "deepseek_v3")
 
 
 def _map_hf_act(act: str) -> str:
@@ -55,6 +55,8 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
                          f"supported: {_FAMILIES}")
     if mt == "mimo_v2":
         return _mimo_v2_config(hf)
+    if mt == "deepseek_v3":
+        return _deepseek_v3_config(hf)
     if mt == "bert":
         return DecoderConfig(
             hidden_size=hf["hidden_size"],
@@ -382,23 +384,18 @@ def _mimo_v2_config(hf: Dict[str, Any]) -> DecoderConfig:
     for key, want in (("swa_num_attention_heads", heads),
                       ("swa_head_dim", dk),
                       ("swa_v_head_dim", hf.get("v_head_dim", dk)),
-                      ("sliding_window_size", hf.get("sliding_window")),
-                      ("scoring_func", "sigmoid"),
-                      ("topk_method", "noaux_tc"), ("n_group", 1),
-                      ("topk_group", 1), ("hidden_act", "silu")):
+                      ("sliding_window_size", hf.get("sliding_window"))):
         if key in hf and hf[key] != want:
             raise ValueError(f"mimo_v2: {key}={hf[key]!r} is not built "
                              f"(expected {want!r})")
-    for key in ("n_shared_experts", "routed_scaling_factor",
-                "hybrid_block_size", "add_full_attention_sink_bias",
-                "attention_bias"):
+    # (its published module has no shared expert: the key is null there)
+    for key in ("hybrid_block_size", "add_full_attention_sink_bias",
+                "attention_bias", "n_shared_experts"):
         if hf.get(key):
             raise ValueError(f"mimo_v2: {key}={hf[key]!r} is not built")
-    scaling = hf.get("rope_scaling") or {}
-    if scaling.get("rope_type", scaling.get("type", "default")) != "default":
-        raise ValueError(f"mimo_v2: rope_scaling {scaling!r} is not built")
-    held = int(hf["n_routed_experts"])
-    share = hf.get("expert_share")
+    if _rope_type(hf) != "default":
+        raise ValueError(f"mimo_v2: rope_scaling {hf['rope_scaling']!r} is "
+                         f"not built")
     return DecoderConfig(
         hidden_size=hf["hidden_size"], num_layers=L, num_heads=heads,
         num_kv_heads=hf["num_key_value_heads"],
@@ -422,11 +419,100 @@ def _mimo_v2_config(hf: Dict[str, Any]) -> DecoderConfig:
         window_rope_theta=float(hf.get("swa_rope_theta",
                                        hf.get("rope_theta", 10000.0))),
         window_sink=bool(hf.get("add_swa_attention_sink_bias", False)),
+        **_sigmoid_router(hf, "mimo_v2"))
+
+
+def _rope_type(hf: Dict[str, Any]) -> str:
+    scaling = hf.get("rope_scaling") or {}
+    return scaling.get("rope_type", scaling.get("type", "default"))
+
+
+def _sigmoid_router(hf: Dict[str, Any], family: str) -> Dict[str, Any]:
+    """The router keys the sigmoid families share (DeepSeek-V3's gate, which
+    MiMo-V2 took over) → DecoderConfig fields: a score per expert, a
+    selection-only bias (``noaux_tc``), group-limited selection
+    (``n_group`` / ``topk_group``), ``norm_topk_prob``,
+    ``routed_scaling_factor`` and ``n_shared_experts`` shared experts of
+    ``moe_intermediate_size`` each. ``expert_share`` (not a published key:
+    ``{"router_experts", "first_expert"}``) says the file's
+    ``n_routed_experts`` experts are ONE chip's share of an expert-parallel
+    layer whose router is ``router_experts`` wide."""
+    for key, want in (("scoring_func", "sigmoid"),
+                      ("topk_method", "noaux_tc"), ("hidden_act", "silu")):
+        if key in hf and hf[key] != want:
+            raise ValueError(f"{family}: {key}={hf[key]!r} is not built "
+                             f"(expected {want!r})")
+    held = int(hf["n_routed_experts"])
+    share = hf.get("expert_share")
+    return dict(
         num_experts=int(share["router_experts"]) if share else held,
         experts_held=(int(share["first_expert"]), held) if share else None,
         num_experts_per_tok=int(hf["num_experts_per_tok"]),
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
-        router_scoring="sigmoid", router_select_bias=True)
+        router_scoring="sigmoid", router_select_bias=True,
+        router_groups=int(hf.get("n_group") or 1),
+        router_groups_kept=int(hf.get("topk_group") or 1),
+        routed_scale=float(hf.get("routed_scaling_factor") or 1.0),
+        shared_expert_size=int(hf.get("n_shared_experts") or 0) *
+        int(hf["moe_intermediate_size"]))
+
+
+def _deepseek_v3_config(hf: Dict[str, Any]) -> DecoderConfig:
+    """DeepSeek-V3's block (HF ``deepseek_v3``; GigaChat3.1 publishes it
+    with a V head of 192): a typed stack of LATENT attention layers
+    (models/typed_layers.py has the equations), ``first_k_dense_replace``
+    leading dense layers, then sparse layers every ``moe_layer_freq``-th:
+    a group-limited sigmoid router, the kept weights scaled, a shared
+    expert. ``rope_scaling``: YaRN or none. ``num_key_value_heads``
+    answers as published whatever the cache holds (one latent row a
+    token). Not built, and accepted: ``num_nextn_predict_layers`` (the
+    multi-token-prediction module; HF's ``deepseek_v3`` drops those weights
+    on load as well). ``expert_share``: :func:`_sigmoid_router`."""
+    for key, want in (("attention_bias", False), ("ep_size", 1)):
+        if hf.get(key, want) != want:
+            raise ValueError(f"deepseek_v3: {key}={hf[key]!r} is not built "
+                             f"(expected {want!r})")
+    for key in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim"):
+        if not hf.get(key):
+            raise ValueError(f"deepseek_v3: {key}={hf.get(key)!r} is not "
+                             f"built (a latent layer needs every width)")
+    rope_type, yarn = _rope_type(hf), None
+    if rope_type == "yarn":
+        sc = hf["rope_scaling"]
+        yarn = (float(sc["factor"]),
+                int(sc["original_max_position_embeddings"]),
+                *(float(sc.get(key) or default) for key, default in (
+                    ("beta_fast", 32), ("beta_slow", 1), ("mscale", 0),
+                    ("mscale_all_dim", 0))))
+    elif rope_type != "default":
+        raise ValueError(f"deepseek_v3: rope_scaling {hf['rope_scaling']!r} "
+                         f"is not built (yarn or none)")
+    L = int(hf["num_hidden_layers"])
+    dense, freq = int(hf.get("first_k_dense_replace", 0)), \
+        int(hf.get("moe_layer_freq", 1))
+    nope, rope = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
+    return DecoderConfig(
+        hidden_size=hf["hidden_size"], num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf.get("num_key_value_heads"),
+        head_dim_override=nope + rope, v_head_dim=int(hf["v_head_dim"]),
+        q_lora_rank=int(hf["q_lora_rank"]),
+        kv_lora_rank=int(hf["kv_lora_rank"]),
+        qk_nope_head_dim=nope, qk_rope_head_dim=rope,
+        intermediate_size=hf["moe_intermediate_size"],
+        dense_intermediate_size=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_seq_len=hf.get("max_position_embeddings", 4096),
+        norm="rmsnorm", activation="silu_glu", pos_emb="rope",
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        rope_theta=float(hf.get("rope_theta", 10000.0)), rope_yarn=yarn,
+        use_bias=False,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        layer_kinds=(2,) * L,
+        layer_sparse=tuple(int(l >= dense and l % freq == 0)
+                           for l in range(L)),
+        **_sigmoid_router(hf, "deepseek_v3"))
 
 
 def _is_gemma_layout(cfg: DecoderConfig) -> bool:

@@ -151,7 +151,9 @@ class DecoderConfig:
     ffn_chunk: int = 0
     # -- typed layers (models/typed_layers.py; ROADMAP C2) ------------------
     #: one entry a layer, 0 = full causal attention, 1 = window attention
-    #: (MiMo-V2 ``hybrid_layer_pattern``). Set → the stack is NOT one
+    #: (MiMo-V2 ``hybrid_layer_pattern``), 2 = latent attention (DeepSeek-V3
+    #: MLA: the ``*_lora_rank`` / ``qk_*_head_dim`` widths below; a latent
+    #: stack is all latent). Set → the stack is NOT one
     #: scanned block: ``params["layers"]`` is a list of per-layer trees
     #: whose shapes follow the layer's kind, ``sliding_window`` /
     #: ``window_*`` describe the window kind only, and ``num_kv_heads`` /
@@ -184,6 +186,25 @@ class DecoderConfig:
     #: the expert weights hold the share, and the layer computes the part
     #: of the result its own experts give (parallel/moe.py). None → all.
     experts_held: Optional[Tuple[int, int]] = None
+    #: group-limited selection (DeepSeek-V3 ``n_group`` / ``topk_group``):
+    #: the router's experts in ``router_groups`` equal groups, a group
+    #: scored by the sum of its two highest picks, the top-k taken inside
+    #: the ``router_groups_kept`` best groups. 1 → a flat top-k.
+    router_groups: int = 1
+    router_groups_kept: int = 1
+    #: ``routed_scaling_factor``: multiplies the kept (normalised) weights
+    routed_scale: float = 1.0
+    # -- latent attention (kind 2): the cache holds ONE row a token,
+    # [RMSNorm(c_kv) (kv_lora_rank) ; RoPE(k_r) (qk_rope_head_dim)], read by
+    # every head; ``head_dim`` = qk_nope + qk_rope, ``v_head_dim`` the V head
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    #: YaRN rotary scaling (HF ``rope_scaling`` with ``rope_type: yarn``):
+    #: (factor, original_max_position_embeddings, beta_fast, beta_slow,
+    #: mscale, mscale_all_dim). None → plain ``theta ** (-i / half)``.
+    rope_yarn: Optional[Tuple[float, int, float, float, float, float]] = None
 
     def __post_init__(self):
         if self.mlm_head and not self.tie_embeddings:
@@ -201,6 +222,17 @@ class DecoderConfig:
             raise ValueError("layer_sparse needs layer_kinds (the typed "
                              "stack); the uniform stack is all-dense or "
                              "all-sparse by num_experts")
+        if self.layer_kinds is not None and 2 in self.layer_kinds and \
+                (set(self.layer_kinds) != {2} or not (
+                    self.q_lora_rank and self.kv_lora_rank and
+                    self.qk_nope_head_dim and self.qk_rope_head_dim)):
+            raise ValueError(
+                "latent attention (layer kind 2) needs q_lora_rank, "
+                "kv_lora_rank, qk_nope_head_dim and qk_rope_head_dim, and a "
+                "stack whose layers are all latent")
+        if self.num_experts and self.num_experts % self.router_groups:
+            raise ValueError(f"router_groups {self.router_groups} does not "
+                             f"divide num_experts {self.num_experts}")
 
     @property
     def typed(self) -> bool:
@@ -216,16 +248,38 @@ class DecoderConfig:
         return self.experts_held[1] if self.experts_held else \
             self.num_experts
 
+    @property
+    def latent(self) -> bool:
+        """The stack's layers are latent attention layers (kind 2)."""
+        return self.typed and 2 in self.layer_kinds
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a latent layer caches a token: the normed latent and the
+        one rotary key every head shares."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def attn_scale(self) -> float:
+        """The scores' factor: ``head_dim ** -0.5``, times YaRN's
+        ``mscale(factor, mscale_all_dim) ** 2`` where that is set (HF
+        ``deepseek_v3``: the sin / cos carry ``mscale / mscale_all_dim``,
+        the softmax the rest)."""
+        scale = self.head_dim ** -0.5
+        if self.rope_yarn is not None and self.rope_yarn[5]:
+            scale *= yarn_mscale(self.rope_yarn[0], self.rope_yarn[5]) ** 2
+        return scale
+
     def kind_kv_heads(self, kind: int) -> int:
-        return (self.window_kv_heads or self.kv_heads) if kind \
+        return (self.window_kv_heads or self.kv_heads) if kind == 1 \
             else self.kv_heads
 
     def kind_rope_theta(self, kind: int) -> float:
-        return (self.window_rope_theta or self.rope_theta) if kind \
+        return (self.window_rope_theta or self.rope_theta) if kind == 1 \
             else self.rope_theta
 
     def kind_window(self, kind: int) -> Optional[int]:
-        return self.sliding_window if kind else None
+        return self.sliding_window if kind == 1 else None
 
     def layer_is_sparse(self, layer: int) -> bool:
         if self.layer_sparse is not None:
@@ -284,7 +338,10 @@ class DecoderConfig:
 
     @property
     def rope_dim(self) -> int:
-        """Dims per head that get RoPE (even; rotary_pct of head_dim)."""
+        """Dims per head that get RoPE (even; rotary_pct of head_dim; a
+        latent layer's ``qk_rope_head_dim``)."""
+        if self.qk_rope_head_dim:
+            return self.qk_rope_head_dim
         r = int(self.head_dim * self.rotary_pct)
         return r - (r % 2)
 
@@ -390,15 +447,56 @@ def embed_tokens(cfg: DecoderConfig, em: Params, tokens: jax.Array,
 # Rotary embeddings
 # ---------------------------------------------------------------------------
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """HF ``yarn_get_mscale``: ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_frequencies(theta: float, rope_dim: int, factor: float,
+                     original_len: int, beta_fast: float, beta_slow: float
+                     ) -> jax.Array:
+    """The ``rope_dim // 2`` YaRN frequencies (HF
+    ``_compute_yarn_parameters``): pair ``i`` turns at ``f_i = theta **
+    (-2i / rope_dim)``; the pairs that turn more than ``beta_fast`` times
+    over the original length keep ``f_i``, those that turn fewer than
+    ``beta_slow`` times take ``f_i / factor``, a linear ramp between."""
+    half = rope_dim // 2
+    f = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+
+    def correction_dim(rotations: float) -> float:
+        return rope_dim * math.log(original_len / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rope_dim - 1)
+    if low == high:
+        high += 0.001
+    keep = 1.0 - jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    return f / factor * (1.0 - keep) + f * keep
+
+
 @jax.named_scope("attn_qkv")
 def rope_table(cfg: DecoderConfig, positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """positions: [B, T] int32 → (sin, cos) each [B, T, rope_dim//2]
     (rope_dim == head_dim unless rotary_pct < 1 — GPT-NeoX partial
-    rotary)."""
+    rotary). ``cfg.rope_yarn``: YaRN frequencies, and the sin / cos scaled
+    by ``mscale / mscale_all_dim`` (1 where they are equal)."""
     half = cfg.rope_dim // 2
-    freqs = cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B,T,half]
-    return jnp.sin(angles), jnp.cos(angles)
+    if cfg.rope_yarn is None:
+        freqs = cfg.rope_theta ** (
+            -jnp.arange(0, half, dtype=jnp.float32) / half)
+        angles = positions[..., None].astype(jnp.float32) * freqs
+        return jnp.sin(angles), jnp.cos(angles)                # [B,T,half]
+    factor, original_len, fast, slow, mscale, all_dim = cfg.rope_yarn
+    freqs = yarn_frequencies(cfg.rope_theta, cfg.rope_dim, factor,
+                             original_len, fast, slow)
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    mag = yarn_mscale(factor, mscale) / yarn_mscale(factor, all_dim) \
+        if mscale and all_dim else yarn_mscale(factor, 1.0)
+    if mag == 1.0:
+        return jnp.sin(angles), jnp.cos(angles)
+    return jnp.sin(angles) * mag, jnp.cos(angles) * mag
 
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:

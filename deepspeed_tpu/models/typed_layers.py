@@ -1,7 +1,7 @@
 """Typed layer stacks: a decoder whose layers are NOT one scanned block.
 
 ``DecoderConfig.layer_kinds`` names each layer's attention kind (0 = full
-causal, 1 = window) and ``layer_sparse`` whether its feed-forward is
+causal, 1 = window, 2 = latent) and ``layer_sparse`` whether its feed-forward is
 sparse experts or a dense MLP (leading dense layers). The kinds differ in
 SHAPE — KV heads, rotary base, a learned sink on the window kind, the dense
 width — so the layers cannot share one stacked tree: ``params["layers"]``
@@ -18,6 +18,26 @@ equations, for layer ``l`` of kind ``a``:
   ``p_ij = exp(s_ij) / (exp(sink_h) + Σ_j' exp(s_ij'))``;
 - ``x ← x + o·Wo``; ``x ← x + ffn(norm(x))``, a SiLU-GLU of
   ``dense_intermediate_size`` or the experts (``parallel/moe.py``).
+
+A LATENT layer (kind 2; DeepSeek-V3's MLA, ``hf_loader``: ``deepseek_v3``)
+projects through two bottlenecks and caches the second:
+
+- ``c_q = RMSNorm(h·W_qa)``; ``q = c_q·W_qb → [T, H, nope + rope]``;
+  ``[c_kv ; k_r] = h·W_kva``; ``c = RMSNorm(c_kv)``; rotate-half RoPE
+  (``rope_table``: YaRN frequencies where configured) on each head's
+  ``q_rope`` and on the ONE ``k_r`` every head shares. The cache row is
+  ``[c ; k_rope]`` (``cfg.latent_dim`` values).
+- EXPANDED (:func:`latent_expand_kv`; the uncached forward, a chunk's own
+  attention): ``[k_nope_h ; v_h] = c·W_kvb`` a head, ``s = scale·(q_nope_h·
+  k_nope_h + q_rope_h·k_rope)``, ``o_h = Σ p·v_h``.
+- ABSORBED (:func:`latent_absorb_q`, :func:`latent_expand_out`; every read
+  of the cache): ``q̃_h = q_nope_h·W_UK_hᵀ`` in the latent space, ``s =
+  scale·([q̃_h ; q_rope_h]·[c ; k_rope])``, ``õ_h = Σ p·c``, ``o_h = õ_h·
+  W_UV_h``, with ``W_UK_h`` / ``W_UV_h`` the column blocks of ``W_kvb`` for
+  head ``h``: the history is never expanded to heads. Equal in exact
+  arithmetic; ``scale = cfg.attn_scale`` (YaRN's ``mscale²`` included).
+- a sparse layer adds ``Shared(h)``, a SiLU-GLU every token takes, beside
+  the routed experts (``cfg.shared_expert_size``; scope ``moe_shared``).
 
 **The residual stream is float32** whatever the parameters' dtype
 (:func:`residual_stream`): the matmuls take the norms' outputs cast to the
@@ -49,9 +69,11 @@ from deepspeed_tpu.ops import paged_attention as pa
 
 def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     """The parameter tree of a typed stack: ``embed``, ``layers`` (a LIST,
-    one tree a layer: ``ln1``, ``attn`` {wq, wk, wv, wo, sink?}, ``ln2``,
+    one tree a layer: ``ln1``, ``attn`` {wq, wk, wv, wo, sink?} — a latent
+    layer's {wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo} —, ``ln2``,
     and ``mlp`` {wg, wi, wo} or ``moe`` {router, router_bias?, wg, wi,
-    wo over the HELD experts}), ``final_norm``, ``lm_head``."""
+    wo over the HELD experts} with, where the model has one, ``shared``
+    {wg, wi, wo}), ``final_norm``, ``lm_head``."""
     if cfg.norm != "rmsnorm" or not cfg.is_glu or cfg.use_bias or \
             cfg.pos_emb != "rope" or cfg.tie_embeddings:
         raise NotImplementedError(
@@ -60,7 +82,10 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     d, v, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
     dk, dv, H = cfg.head_dim, cfg.v_dim, cfg.num_heads
     out_std = cfg.init_std / math.sqrt(2 * L)
-    keys = iter(jax.random.split(rng, 10 * L + 2))
+    # (a layer draws at most 9 keys, 12 with latent attention or a shared
+    # expert; the count is part of what a seed gives)
+    draws = 13 if cfg.latent or cfg.shared_expert_size else 10
+    keys = iter(jax.random.split(rng, draws * L + 2))
 
     def w(shape, std=cfg.init_std):
         return (jax.random.normal(next(keys), shape, jnp.float32) * std
@@ -69,9 +94,19 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     layers = []
     for l, kind in enumerate(cfg.layer_kinds):
         kvh = cfg.kind_kv_heads(kind)
-        attn = {"wq": w((d, H * dk)), "wk": w((d, kvh * dk)),
-                "wv": w((d, kvh * dv)), "wo": w((H * dv, d), out_std)}
-        if kind and cfg.window_sink:
+        if kind == 2:
+            ql, kl, nope = cfg.q_lora_rank, cfg.kv_lora_rank, \
+                cfg.qk_nope_head_dim
+            inner = lambda r: {"scale": jnp.ones((r,), jnp.float32)}
+            attn = {"wq_a": w((d, ql)), "q_norm": inner(ql),
+                    "wq_b": w((ql, H * dk)),
+                    "wkv_a": w((d, cfg.latent_dim)), "kv_norm": inner(kl),
+                    "wkv_b": w((kl, H * (nope + dv))),
+                    "wo": w((H * dv, d), out_std)}
+        else:
+            attn = {"wq": w((d, H * dk)), "wk": w((d, kvh * dk)),
+                    "wv": w((d, kvh * dv)), "wo": w((H * dv, d), out_std)}
+        if kind == 1 and cfg.window_sink:
             # not zero at init: a zero sink would make a test of it vacuous
             attn["sink"] = w((H,), 1.0)
         lp = {"ln1": tf._norm_params(cfg), "attn": attn,
@@ -83,6 +118,10 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
             if cfg.router_select_bias:
                 moe["router_bias"] = jnp.zeros((E,), dtype)
             lp["moe"] = moe
+            if cfg.shared_expert_size:
+                fs = cfg.shared_expert_size
+                lp["shared"] = {"wg": w((d, fs)), "wi": w((d, fs)),
+                                "wo": w((fs, d), out_std)}
         else:
             f = cfg.dense_intermediate_size or cfg.ffn_size
             lp["mlp"] = {"wg": w((d, f)), "wi": w((d, f)),
@@ -115,6 +154,65 @@ def typed_qkv(cfg, kind: int, p, x: jax.Array, sin, cos
     return tf.apply_rope(q, sin, cos), tf.apply_rope(k, sin, cos), v
 
 
+@jax.named_scope("attn_qkv")
+def latent_qkv(cfg, p, x: jax.Array, sin, cos):
+    """A latent layer's projections: x [B, t, D] → (q_nope [B, t, H, nope],
+    q_rope [B, t, H, rope], latent [B, t, kv_lora + rope]): RoPE applied to
+    q_rope and to the shared rotary key; ``latent`` = ``[RMSNorm(c_kv) ;
+    k_rope]`` is the row the cache holds."""
+    b, t = x.shape[:2]
+    nope, kl = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    c_q = tf._norm(cfg, p["q_norm"], tf.linear_2d(x, p, "wq_a"))
+    q = tf.linear_2d(c_q, p, "wq_b").reshape(b, t, cfg.num_heads,
+                                             cfg.head_dim)
+    kv = tf.linear_2d(x, p, "wkv_a")
+    c = tf._norm(cfg, p["kv_norm"], kv[..., :kl])
+    k_rope = tf.apply_rope(kv[..., None, kl:], sin, cos)[..., 0, :]
+    return q[..., :nope], tf.apply_rope(q[..., nope:], sin, cos), \
+        jnp.concatenate([c, k_rope], axis=-1)
+
+
+def _wkv_b(cfg, p) -> jax.Array:
+    """``W_kvb`` as [kv_lora, H, nope + v]: ``[..., :nope]`` is ``W_UK``,
+    the rest ``W_UV``."""
+    return p["wkv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads,
+                              cfg.qk_nope_head_dim + cfg.v_dim)
+
+
+@jax.named_scope("attn_latent")
+def latent_absorb_q(cfg, p, q_nope: jax.Array, q_rope: jax.Array,
+                    width: int) -> jax.Array:
+    """The absorbed query: ``[q_nope_h·W_UK_hᵀ ; q_rope_h ; 0…]`` →
+    [B, t, H, width] (``width`` the latent pool's lanes): its dot with a
+    cached row is the expanded form's ``q_h·k_h``."""
+    w_uk = _wkv_b(cfg, p)[..., :cfg.qk_nope_head_dim]
+    q_lat = jnp.einsum("bthd,lhd->bthl", q_nope, w_uk)
+    return jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
+                   ((0, 0),) * 3 + ((0, width - cfg.latent_dim),))
+
+
+@jax.named_scope("attn_latent")
+def latent_expand_out(cfg, p, o_lat: jax.Array) -> jax.Array:
+    """``õ_h·W_UV_h``: the weighted sum of latents [B, t, H, kv_lora] →
+    the heads' outputs [B, t, H, v]."""
+    w_uv = _wkv_b(cfg, p)[..., cfg.qk_nope_head_dim:]
+    return jnp.einsum("bthl,lhv->bthv", o_lat, w_uv)
+
+
+@jax.named_scope("attn_latent")
+def latent_expand_kv(cfg, p, q_nope: jax.Array, q_rope: jax.Array,
+                     latent: jax.Array):
+    """The expanded form of a chunk's own tokens: (q [B, t, H, nope+rope],
+    k [B, t, H, nope+rope] — ``c·W_UK`` a head beside the shared rotary key
+    —, v [B, t, H, v])."""
+    kl = cfg.kv_lora_rank
+    kv = jnp.einsum("btl,lhd->bthd", latent[..., :kl], _wkv_b(cfg, p))
+    k_rope = jnp.broadcast_to(latent[..., None, kl:], q_rope.shape)
+    nope = cfg.qk_nope_head_dim
+    return jnp.concatenate([q_nope, q_rope], axis=-1), \
+        jnp.concatenate([kv[..., :nope], k_rope], axis=-1), kv[..., nope:]
+
+
 @jax.named_scope("attn_out")
 def typed_attn_out(cfg, p, out: jax.Array) -> jax.Array:
     b, t = out.shape[:2]
@@ -144,14 +242,21 @@ def typed_ffn(cfg, lp, h: jax.Array, moe_fn: Optional[Callable],
     """The layer's second half on its normed input ``h`` (float32): the
     dense SiLU-GLU of a dense layer in the compute ``dtype``, or the
     experts (``moe_fn(cfg, p, x, valid=)``: the router reads ``h`` as it
-    is, the experts cast it)."""
+    is, the experts cast it) and, where the layer has one, the shared
+    expert every token takes (compute dtype; on every chip of an
+    expert-parallel deployment, so it counts once across the shares)."""
     if "moe" not in lp:
         return tf._mlp(cfg, lp["mlp"], h.astype(dtype or h.dtype))
-    if moe_fn is None:
-        from deepspeed_tpu.parallel.moe import held_experts_moe_layer
-        moe_fn = held_experts_moe_layer
+    from deepspeed_tpu.parallel import moe
     with jax.named_scope("moe"):
-        return moe_fn(cfg, lp["moe"], h, valid=valid)[0]
+        out = (moe_fn or moe.held_experts_moe_layer)(
+            cfg, lp["moe"], h, valid=valid)[0]
+    if "shared" not in lp:
+        return out
+    with jax.named_scope("moe_shared"):
+        hs = h.astype(dtype or h.dtype)
+        return out + moe._shared_expert(
+            lp["shared"], hs.reshape(-1, hs.shape[-1])).reshape(hs.shape)
 
 
 def _attention(cfg, kind: int, sink, q, k, v) -> jax.Array:
@@ -159,7 +264,7 @@ def _attention(cfg, kind: int, sink, q, k, v) -> jax.Array:
     v [B, T, KV, Dv] → [B, T, H, Dv]; causal, the kind's window, the
     sink."""
     out, lse = pa.causal_attention_with_lse(
-        q, k, v, window=cfg.kind_window(kind))
+        q, k, v, window=cfg.kind_window(kind), scale=cfg.attn_scale)
     return apply_sink(out, lse, sink)
 
 
@@ -178,7 +283,11 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
     tables = rope_tables(cfg, positions)
     for kind, lp in zip(cfg.layer_kinds, params["layers"]):
         h = tf._norm(cfg, lp["ln1"], x).astype(dtype)
-        q, k, v = typed_qkv(cfg, kind, lp["attn"], h, *tables[kind])
+        if kind == 2:       # the expanded form: nothing is cached here
+            q, k, v = latent_expand_kv(cfg, lp["attn"], *latent_qkv(
+                cfg, lp["attn"], h, *tables[kind]))
+        else:
+            q, k, v = typed_qkv(cfg, kind, lp["attn"], h, *tables[kind])
         with jax.named_scope("attn_core"):
             o = _attention(cfg, kind, lp["attn"].get("sink"), q, k, v)
         x = x + typed_attn_out(cfg, lp["attn"], o)

@@ -504,8 +504,12 @@ def route_tokens(cfg, p, xf: jax.Array) -> Tuple[jax.Array, jax.Array]:
     input is upcast, the matmul runs at ``Precision.HIGHEST`` (on a TPU a
     float32 matmul is otherwise bf16 passes). ``p["router_bias"]``
     (``router_select_bias``, ``noaux_tc``) is added for the SELECTION
-    only: the weights are the unbiased scores. ``norm_topk_prob``: the
-    kept weights renormalised to sum to 1."""
+    only: the weights are the unbiased scores. ``router_groups`` > 1
+    (``n_group`` / ``topk_group``): the picks in equal groups, a group
+    scored by the sum of its two highest, the picks outside the
+    ``router_groups_kept`` best groups set to 0.0 before the top-k (as
+    HF's ``deepseek_v3`` gate masks them). ``norm_topk_prob``: the kept
+    weights renormalised to sum to 1; ``routed_scale`` multiplies them."""
     if cfg.router_scoring != "sigmoid":
         raise NotImplementedError(
             f"route_tokens is the sigmoid router; router_scoring="
@@ -516,10 +520,19 @@ def route_tokens(cfg, p, xf: jax.Array) -> Tuple[jax.Array, jax.Array]:
     pick = scores
     if "router_bias" in p:
         pick = scores + p["router_bias"].astype(jnp.float32)
+    if cfg.router_groups > 1:
+        grouped = pick.reshape(pick.shape[0], cfg.router_groups, -1)
+        _, kept = lax.top_k(lax.top_k(grouped, 2)[0].sum(-1),
+                            cfg.router_groups_kept)            # [S, kept]
+        in_kept = jax.nn.one_hot(kept, cfg.router_groups,
+                                 dtype=jnp.bool_).any(axis=1)  # [S, groups]
+        pick = jnp.where(in_kept[..., None], grouped, 0.0).reshape(pick.shape)
     _, topi = lax.top_k(pick, cfg.num_experts_per_tok)
     topw = jnp.take_along_axis(scores, topi, axis=-1)
     if cfg.norm_topk_prob:
         topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
+    if cfg.routed_scale != 1.0:
+        topw = topw * cfg.routed_scale
     return topw, topi.astype(jnp.int32)
 
 

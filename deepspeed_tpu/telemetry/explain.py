@@ -175,7 +175,8 @@ def collective_stats_from_hlo(hlo_text: str) -> Dict[str, Dict[str, float]]:
 SCOPE_VOCABULARY = (
     "embed", "norm", "attn_qkv", "attn_core", "attn_history", "attn_merge",
     "kv_write", "attn_out", "mlp", "moe", "moe_router", "moe_experts",
-    "lm_head", "loss", "grad_clip", "optimizer", "sample", "step_misc")
+    "lm_head", "loss", "grad_clip", "optimizer", "sample", "step_misc",
+    "attn_latent", "moe_shared")
 
 _HLO_NAME_RE = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')

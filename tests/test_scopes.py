@@ -124,7 +124,13 @@ def test_scope_is_the_innermost_vocabulary_word():
         "attn_history"
     assert scope_of_op_name("jit(f)/flash_fwd/pallas_call")["scope"] is None
     assert scope_of_op_name("")["scope"] is None
-    assert len(set(SCOPE_VOCABULARY)) == len(SCOPE_VOCABULARY) == 18
+    assert len(set(SCOPE_VOCABULARY)) == len(SCOPE_VOCABULARY) == 20
+    # a latent layer's words, inside and beside the older ones
+    assert scope_of_op_name(
+        "jit(f)/attn_latent/bthd,lhd->bthl/dot_general")["scope"] == \
+        "attn_latent"
+    assert scope_of_op_name("jit(f)/moe_shared/sd,dh->sh/dot_general")[
+        "scope"] == "moe_shared"
 
 
 # -- names and registration -----------------------------------------------------

@@ -463,3 +463,99 @@ def test_no_serve_step_moves_the_arena(kind, one_chip, no_persistent_cache,
     branches = _branches(text)
     assert len(branches) == len(_SERVE_STEPS[kind][2]), branches
     assert compiled.cost_analysis()["flops"] <= 0.35 * 3.593e12
+
+
+# -- the latent stack (GigaChat3.1 / DeepSeek-V3 widths), two layers ---------
+
+#: kind -> (chunk, fresh_prefill, token capacities, most temporaries at two
+#: layers). The split step's are the row form of one layer's attention: the
+#: absorbed queries [64, 128, 64, 640] and the kernel's latent-space output
+#: [64, 128, 64, 512] (0.67 + 0.54 GB), the expanded q / k / v of the
+#: chunk (3 x 0.20 GB) and its scores (docs/kernels.md)
+_LATENT_STEPS = {
+    "decode": (1, False, (), 0.4e9),
+    "split": (128, "split", (1024, 2048), 4.0e9),
+}
+
+
+def _latent_2l():
+    """One dense and one sparse latent layer at the published widths, 16
+    of the 256 experts held (benchmark/configs/gigachat3.1-l5-e16-serve)."""
+    from deepspeed_tpu.models.hf_loader import config_from_hf
+    return config_from_hf({
+        "model_type": "deepseek_v3", "vocab_size": 16032,
+        "hidden_size": 7168, "intermediate_size": 18432,
+        "moe_intermediate_size": 2048, "num_hidden_layers": 2,
+        "num_attention_heads": 64, "num_key_value_heads": 64,
+        "n_shared_experts": 1, "n_routed_experts": 16,
+        "routed_scaling_factor": 2.5, "kv_lora_rank": 512,
+        "q_lora_rank": 1536, "qk_rope_head_dim": 64, "v_head_dim": 192,
+        "qk_nope_head_dim": 128, "topk_method": "noaux_tc", "n_group": 8,
+        "topk_group": 4, "num_experts_per_tok": 8, "moe_layer_freq": 1,
+        "first_k_dense_replace": 1, "norm_topk_prob": True,
+        "scoring_func": "sigmoid", "hidden_act": "silu",
+        "rms_norm_eps": 1e-6, "rope_theta": 100000,
+        "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 64,
+                         "mscale": 1, "mscale_all_dim": 1,
+                         "original_max_position_embeddings": 4096,
+                         "rope_type": "yarn"},
+        "expert_share": {"router_experts": 256, "first_expert": 0}})
+
+
+@pytest.mark.parametrize("kind", list(_LATENT_STEPS))
+def test_latent_step_reads_the_pool_absorbed(kind, one_chip,
+                                             no_persistent_cache,
+                                             monkeypatch):
+    """The latent stack's 64-row decode and split programs over the cell's
+    arena (2,176 pages of 128, 34 a row, pool rows of 640 lanes): the
+    ``mla_decode`` kernel reads the pool (under ``attn_core`` in the decode
+    program, ``attn_history`` in the split one); NO pool-shaped copy; and
+    the decode program holds no expansion of the history to heads — no
+    tensor with the context's 4,352 slots beside the 64 heads (a gathered,
+    expanded K or V would be ``[64, 4352, 64, 128|192]``)."""
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.ops import paged_attention as pa
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = _latent_2l()
+    cb, fresh, capacities, most = _LATENT_STEPS[kind]
+    nb, mb = 64, 34
+
+    def serve_step(params, arena, tokens, counts, starts, pt):
+        logits, arena = engine_v2.ragged_forward(
+            model, params, arena, tokens, counts, starts, pt,
+            use_pallas=True, moe_fn=None, fresh_prefill=fresh,
+            token_capacities=capacities)
+        out, _ = engine_v2._sample_tokens(logits, ("argmax",), 1.0, 1.0,
+                                          None)
+        return out, arena
+
+    arena = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: pa.init_arena_typed(
+            model.layer_kinds, {2: 1}, 2176, 128, 640, 0, jnp.bfloat16)))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    compiled = jax.jit(serve_step, donate_argnums=(1,)).lower(
+        _abstract_params(model, one_chip), arena, i32(nb, cb), i32(nb),
+        i32(nb), i32(nb, mb)).compile()
+    text = compiled.as_text()
+    pool = arena["latent"]
+    shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf" = {re.escape(shape)}\S* copy\(", line)]
+    assert not copies, copies
+    table = scope_table_from_hlo(text)
+    kernels = [n for n in table if n.startswith("mla_decode")]
+    want = "attn_core" if kind == "decode" else "attn_history"
+    assert kernels and all(table[n]["scope"] == want for n in kernels), \
+        [(n, table[n]["scope"]) for n in kernels]
+    assert {"attn_latent", "moe_shared"} <= \
+        {e["scope"] for e in table.values()}
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < most, temp
+    if kind == "decode":
+        expanded = re.findall(r"\[64,4352,64,\d+\]|\[64,64,4352,\d+\]",
+                              text)
+        assert not expanded, sorted(set(expanded))
