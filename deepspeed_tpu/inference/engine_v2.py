@@ -53,41 +53,51 @@ class RaggedInferenceConfig(TPUConfigModel):
 
 
 class _TokenLayout:
-    """Where a ragged batch's tokens sit for the sublayers that act on a
-    token alone (embedding, norms, projections, RoPE, MLP / MoE, residual
-    adds): in ROWS ``[n, c, ...]`` as they arrive (``capacity`` None), or
-    PACKED ``[1, capacity, ...]``, row after row with no padding between,
-    so that those sublayers work on ``capacity`` slots and not on
-    ``n * c``. Attention and the KV write keep the row form. Row ``r``'s
-    tokens are the packed slots ``offsets[r] .. offsets[r] + counts[r]``;
-    ``capacity`` (STATIC) must hold ``counts.sum()``, the caller's
-    promise. ``positions`` and ``valid`` are in the token-wise form."""
+    """Where a ragged batch's tokens sit for whatever acts on a token
+    alone (embedding, norms, projections, RoPE, MLP / MoE, residual adds,
+    the KV write): in ROWS ``[n, c, ...]`` as they arrive (``capacity``
+    None), or PACKED ``[1, capacity, ...]``, row after row with no padding
+    between, so that those work on ``capacity`` slots and not on
+    ``n * c``. Attention alone keeps the row form. Row ``r``'s tokens are
+    the packed slots ``offsets[r] .. offsets[r] + counts[r]``; ``capacity``
+    (STATIC) must hold ``counts.sum()``, the caller's promise. ``row``,
+    ``positions`` (``starts[row]`` + the slot's column in its row) and
+    ``valid`` say of each slot, in the token-wise form, whose token it
+    is, which one, and whether it holds a token at all."""
 
     def __init__(self, counts: jax.Array, starts: jax.Array, c: int,
                  capacity: Optional[int]):
         self.n, self.c = counts.shape[0], c
         self.counts, self.capacity = counts, capacity
         if capacity is None:
-            cols = jnp.arange(c, dtype=jnp.int32)[None]
-            self.positions = starts[:, None] + jnp.broadcast_to(
-                cols, (self.n, c))
-            self.valid = cols < counts[:, None]
+            self.row, self.positions, self.valid = pa.row_slots(
+                starts, counts, c)
             return
         ends = jnp.cumsum(counts)
         self.offsets = ends - counts
         t = jnp.arange(capacity, dtype=jnp.int32)
         # slot t belongs to the first row that ends after it (rows with
-        # no token are passed over); slots past the batch's tokens read
-        # the last row's tail and are never read back
+        # no token are passed over); a slot past the batch's tokens reads
+        # the last row's tail, is never read back, and is NOT valid: its
+        # clipped row / col are a live token's, and only ``valid`` keeps
+        # the KV write from landing there
         row = jnp.minimum(jnp.sum(t[:, None] >= ends[None], axis=1,
                                   dtype=jnp.int32), self.n - 1)
         col = jnp.minimum(t - self.offsets[row], c - 1)
+        self.row = row[None]
         self.slot = row * c + col           # [capacity] into [n * c]
         self.source = jnp.minimum(          # [n, c] into [capacity]
             self.offsets[:, None] + jnp.arange(c, dtype=jnp.int32)[None],
             capacity - 1)
         self.positions = (starts[row] + col)[None]
         self.valid = (t < ends[-1])[None]
+
+    def kv_slots(self):
+        """``(row, pos, valid)`` as ``pa.write_kv`` / ``pa.write_rows``
+        take them, each ``[slots]``: one update a slot of the token-wise
+        form."""
+        return tuple(a.reshape(-1)
+                     for a in (self.row, self.positions, self.valid))
 
     def to_tokens(self, rows: jax.Array) -> jax.Array:
         """[n, c, ...] → the token-wise form (a gather of tokens)."""
@@ -103,7 +113,7 @@ class _TokenLayout:
         rows' tokens — as a row-form step's hold the activations of token
         id 0: finite, and nothing reads them (a chunk's attention is
         causal, so a live query sees live keys alone; the KV write takes
-        ``counts``). Masking them would be one more pass over
+        the packed tokens). Masking them would be one more pass over
         ``[n, c, heads, d]`` a layer."""
         if self.capacity is None:
             return x
@@ -127,6 +137,56 @@ def _at_capacity(capacities, total: jax.Array, run):
         return run(capacities[0] if capacities else None)
     index = sum((total > cap).astype(jnp.int32) for cap in capacities[:-1])
     return lax.switch(index, [partial(run, cap) for cap in capacities])
+
+
+def _slot_major(t: jax.Array, slots: int) -> jax.Array:
+    """A token-wise tensor (``[1, T, ...]`` packed, ``[n, c, ...]`` rows)
+    → ``[slots, lanes]``, slot after slot with a token's values side by
+    side as a pool holds them, zero slots after the last: how a split
+    step's chunk K/V wait for the write-back, one shape at every
+    capacity."""
+    t = t.reshape(t.shape[0] * t.shape[1], -1)
+    return jnp.pad(t, ((0, slots - t.shape[0]), (0, 0)))
+
+
+def _write_back_slots(capacities, row_slots: int) -> Tuple[int, int]:
+    """``(block, held)`` of a split step's write-back (:func:`_write_back`):
+    the slots ONE scatter updates — the smallest capacity; the row form's
+    ``row_slots`` where the step does not pack — and the slots a layer's
+    chunk K/V are held in until then: the top capacity in whole blocks."""
+    if not capacities:
+        return row_slots, row_slots
+    return capacities[0], -(-capacities[-1] // capacities[0]) * capacities[0]
+
+
+def _write_back(counts: jax.Array, starts: jax.Array, c: int, capacities,
+                pools, write_layers):
+    """A split step's chunk K/V of every layer into ``pools``, after the
+    layer loop: block after block of :func:`_write_back_slots` slots until
+    the batch's tokens are written, so a launch's scatters perform the
+    updates of the capacity the step took (1,024 for most steps of a
+    program that also holds 2,048) and none for slots past it. The pools
+    are the carry of ONE loop whose trip count the token count sets: they
+    alias through it, where a second ``lax.switch`` over write-backs of
+    each capacity copies a pool in every branch's layer loop
+    (tests/test_tpu_compile.py). ``write_layers(pools, slots, take)``
+    writes one block of every layer: ``slots`` are the block's
+    ``kv_slots()``, ``take`` cuts its ``[block, lanes]`` out of a layer's
+    held ``[held, lanes]``."""
+    block, held = _write_back_slots(capacities, counts.shape[0] * c)
+    slots = _TokenLayout(counts, starts, c,
+                         held if capacities else None).kv_slots()
+
+    def write_block(b, pools):
+        def take(t):
+            return lax.dynamic_slice_in_dim(t, b * block, block, axis=0)
+        return write_layers(pools, tuple(take(a) for a in slots), take)
+
+    # the loop itself carries the word, so what the compiler adds FOR it
+    # is its consumer's cost in the scope table, not "(no scope)"
+    with jax.named_scope("kv_write"):
+        return lax.fori_loop(0, -(-counts.sum() // block), write_block,
+                             pools)
 
 
 def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
@@ -160,20 +220,22 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     projections, MLP / MoE, the residual adds, the final norm — run over
     the batch's tokens PACKED into ``[1, capacity, hidden]``
     (:class:`_TokenLayout`); q, k, v are unpacked to ``[n, c, heads, d]``
-    for attention, which alone keeps the row form (as do the ``k, v`` the
-    KV write consumes), and its result is packed back before the output
-    projection. The head projects each row's last packed token. With
+    for attention, which alone keeps the row form, and its result is
+    packed back before the output projection; the KV write takes ``k, v``
+    as they left the projections, one scatter update a packed slot. The
+    head projects each row's last packed token. With
     several capacities the "split" program holds one instance of the layer
     loop for each and the batch's token count picks the smallest that
     holds it (:func:`_at_capacity`): the arena is a read-only operand of
-    the branches and the write-back stays outside them. The other modes
+    the branches and the write-back (:func:`_write_back`) stays outside
+    them. The other modes
     carry the arena through the loop and take ONE capacity. ``()``: the
     row form throughout, which is also what ``c == 1`` always is.
 
     In the "split" program (``c > 1``) the arena is READ-ONLY during the
     layer loop: the scan carries ``x`` alone, each layer emits its chunk's
-    ``k, v`` as scan outputs ([L, n, c, kvh, dh]) and ONE second scan,
-    with the arena as its only carry, writes them back. Nothing in a
+    ``k, v`` as scan outputs (token-wise, [L, slots, kvh * dh]) and ONE
+    write-back, with the arena as its only carry, scatters them. Nothing in a
     split step reads what the same step wrote, so no layer's reader waits
     for an earlier layer's scatter, and the capacity branches take the
     arena as an operand and return none. The decode program (``c == 1``)
@@ -215,10 +277,12 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     stride = arena["k"].shape[0] // num_layers          # num_blocks + 1
     layers = (params["layers"], jnp.arange(num_layers, dtype=jnp.int32))
 
+    held_slots = _write_back_slots(token_capacities, n * c)[1]
+
     def run(capacity):
         """Embedding to final norm at one capacity → (each row's last
         hidden state [n, 1, D]; split: the chunk's (k, v) of every layer,
-        else the written arena's (k, v))."""
+        [L, held_slots, kvh * dh], else the written arena's (k, v))."""
         with jax.named_scope("embed"):     # where each token sits, too
             lay = _TokenLayout(counts, starts, c, capacity)
             toks = lay.to_tokens(tokens)
@@ -243,6 +307,7 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
             pt_l = page_table + off   # padded entries → this layer's trash
             h_in = _norm(cfg, lp["ln1"], x)
             q, k, v = qkv_project(cfg, lp["attn"], h_in, sin, cos)
+            new_kv = (k, v)           # the write takes them token-wise
             with jax.named_scope("attn_qkv"):     # attention sees rows
                 q, k, v = (lay.to_rows(a) for a in (q, k, v))
             if split:
@@ -259,7 +324,8 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
                             q, ak, av, pt_l, starts)
             else:
                 with jax.named_scope("kv_write"):
-                    ak, av = pa.write_kv(ak, av, k, v, pt_l, starts, counts,
+                    ak, av = pa.write_kv(ak, av, *new_kv, pt_l,
+                                         *lay.kv_slots(),
                                          trash_block=off + stride - 1)
             if fresh_prefill == "fresh":
                 # starts == 0 everywhere: the chunk IS the whole history —
@@ -294,7 +360,10 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
             attn_out = attn_out_project(cfg, lp["attn"], out)
             h_out, _aux = block_combine(cfg, lp, x, h_in, attn_out, moe_fn)
             if split:
-                return h_out, (k.astype(ak.dtype), v.astype(av.dtype))
+                with jax.named_scope("kv_write"):
+                    return h_out, tuple(
+                        _slot_major(t.astype(pool.dtype), held_slots)
+                        for t, pool in zip(new_kv, (ak, av)))
             return (h_out, ak, av), None
 
         if split:
@@ -308,19 +377,19 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
 
     x_last, (ak, av) = _at_capacity(token_capacities, counts.sum(), run)
     if split:
-        def write_back(carry, layer_kv):
-            k, v, l_idx = layer_kv
-            off = l_idx * stride
-            with jax.named_scope("kv_write"):
-                return pa.write_kv(*carry, k, v, page_table + off, starts,
-                                   counts,
-                                   trash_block=off + stride - 1), None
+        def write_layers(pools, slots, take):
+            def write(carry, layer_kv):
+                k, v, l_idx = layer_kv
+                off = l_idx * stride
+                with jax.named_scope("kv_write"):
+                    return pa.write_kv(*carry, take(k), take(v),
+                                       page_table + off, *slots,
+                                       trash_block=off + stride - 1), None
 
-        # the loop itself carries the word, so what the compiler adds FOR
-        # it is its consumer's cost in the scope table, not "(no scope)"
-        with jax.named_scope("kv_write"):
-            (ak, av), _ = lax.scan(write_back, (arena["k"], arena["v"]),
-                                   (ak, av, layers[1]))
+            return lax.scan(write, pools, (ak, av, layers[1]))[0]
+
+        ak, av = _write_back(counts, starts, c, token_capacities,
+                             (arena["k"], arena["v"]), write_layers)
     logits = lm_logits(cfg, params, x_last)[:, 0]
     return logits, {"k": ak, "v": av}
 
@@ -376,19 +445,30 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         # padded entries of the page table → this layer's trash
         places.append((names, page_table + off, off + stride - 1))
 
-    def write(pools, place, *kv):
-        """A layer's chunk into its pools: (k, v), or a latent layer's one
-        row a token."""
+    held_slots = _write_back_slots(token_capacities,
+                                   tokens.shape[0] * c)[1]
+
+    def write(pools, place, slots, *kv):
+        """A layer's chunk into its pools, token-wise (``slots``: the
+        layout's ``kv_slots()``): (k, v), or a latent layer's one row a
+        token."""
         names, pt_l, trash = place
         with jax.named_scope("kv_write"):
             if len(names) == 1:
                 pools[names[0]] = pa.write_rows(
-                    pools[names[0]], *kv, pt_l, starts, counts,
-                    trash_block=trash)
+                    pools[names[0]], *kv, pt_l, *slots, trash_block=trash)
             else:
                 pools[names[0]], pools[names[1]] = pa.write_kv(
-                    pools[names[0]], pools[names[1]], *kv, pt_l, starts,
-                    counts, trash_block=trash)
+                    pools[names[0]], pools[names[1]], *kv, pt_l, *slots,
+                    trash_block=trash)
+
+    def keep(chunk_kv, pools, names, *kv):
+        """A split step's chunk of one layer, as it waits for the
+        write-back."""
+        with jax.named_scope("kv_write"):
+            chunk_kv.append(tuple(
+                _slot_major(t.astype(pools[name].dtype), held_slots)
+                for t, name in zip(kv, names)))
 
     def heads_attention(lay, kind, a, place, h_in, table, pools, chunk_kv):
         """A full or window layer's attention on its normed input
@@ -401,6 +481,7 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             if pad:
                 q, k = (jnp.pad(t, ((0, 0),) * 3 + ((0, pad),))
                         for t in (q, k))
+            new_kv = (k, v)           # the write takes them token-wise
             q, k, v = (lay.to_rows(t) for t in (q, k, v))
         if split:
             with jax.named_scope("attn_history"):
@@ -418,9 +499,9 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             with jax.named_scope("attn_merge"):
                 out = pa.merge_attention(out_h, lse_h, out_c, lse_c,
                                          sink).astype(q.dtype)
-            chunk_kv.append((k, v))
+            keep(chunk_kv, pools, place[0], *new_kv)
         else:
-            write(pools, place, k, v)
+            write(pools, place, lay.kv_slots(), *new_kv)
             with jax.named_scope("attn_core"):
                 if fresh_prefill == "fresh":
                     out, lse = pa.causal_attention_with_lse(
@@ -445,8 +526,6 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         kl, dtype = cfg.kv_lora_rank, h_in.dtype
         q_nope, q_rope, latent = tl.latent_qkv(cfg, a, h_in, *table)
         own = split or fresh_prefill == "fresh"
-        with jax.named_scope("attn_qkv"):     # attention sees rows
-            latent_rows = lay.to_rows(latent)
         if own:
             q, k, v = tl.latent_expand_kv(cfg, a, q_nope, q_rope, latent)
             with jax.named_scope("attn_qkv"):
@@ -476,9 +555,9 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             out_h = tl.latent_expand_out(cfg, a, out_h)
             with jax.named_scope("attn_merge"):
                 out = pa.merge_attention(out_h, lse_h, out_c, lse_c)
-            chunk_kv.append((latent_rows,))
+            keep(chunk_kv, pools, place[0], latent)
             return out.astype(dtype)
-        write(pools, place, latent_rows)
+        write(pools, place, lay.kv_slots(), latent)
         with jax.named_scope("attn_core"):
             if own:
                 out = pa.causal_attention_with_lse(q, k, v, scale=scale)[0]
@@ -519,12 +598,17 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             return lay.last(x), (chunk_kv if split else pools)
 
     x_last, out = _at_capacity(token_capacities, counts.sum(), run)
-    if split:
-        pools = dict(arena)
+    if not split:
+        return lm_logits(cfg, params, x_last)[:, 0], out
+
+    def write_layers(pools, slots, take):
+        pools = dict(pools)
         for place, kv in zip(places, out):
-            write(pools, place, *kv)
-    else:
-        pools = out
+            write(pools, place, slots, *(take(t) for t in kv))
+        return pools
+
+    pools = _write_back(counts, starts, c, token_capacities, dict(arena),
+                        write_layers)
     return lm_logits(cfg, params, x_last)[:, 0], pools
 
 
@@ -1257,13 +1341,16 @@ class RaggedInferenceEngineTPU:
             bs = self.config.block_size
             context_slots = nb * cb + \
                 int((-(-batch.start_positions // bs)).sum()) * bs
+        write_block = _write_back_slots(capacities, nb * cb)[0]
         work = self._count_dispatch(
             _step_kind(cb, fresh), n, nb, cb, self.mb, tokens,
             int((batch.start_positions + batch.token_counts).sum()),
             context_slots=context_slots,
             kv_window=self._kv_window_tokens(batch),
-            # the device's own rule (_at_capacity): the smallest that holds
-            token_slots=next((t for t in capacities if tokens <= t), None))
+            # the device's own rules (_at_capacity: the smallest that
+            # holds; _write_back: whole blocks until the tokens are written)
+            token_slots=next((t for t in capacities if tokens <= t), None),
+            kv_write_slots=-(-tokens // write_block) * write_block)
         with tracer.span("serving/dispatch",
                          **(work if tracer.enabled else {})):
             out, self._rng_dev, self.arena = self._step_fn(
@@ -1293,14 +1380,19 @@ class RaggedInferenceEngineTPU:
                         scan_steps: int = 1,
                         context_slots: Optional[int] = None,
                         kv_window=None,
-                        token_slots: Optional[int] = None) -> Dict[str, Any]:
+                        token_slots: Optional[int] = None,
+                        kv_write_slots: Optional[int] = None
+                        ) -> Dict[str, Any]:
         """Count one device program launch where its batch is packed: the
         useful work (``tokens`` fed, ``context_tokens`` of live KV they
         attend) against the work attempted (``slots`` = what the sublayers
         that act on a token alone ran over: ``token_slots``, the capacity
         the launch packed its tokens into, or bucketed rows x chunk width
         where it did not pack; ``row_slots`` = bucketed rows x chunk width,
-        what attention works on; ``context_slots`` = what the attention
+        what attention works on; ``kv_write_slots`` = the updates the
+        launch's KV scatter performs a pool and layer: the packed slots it
+        wrote back, ``row_slots`` where it did not pack;
+        ``context_slots`` = what the attention
         reads: bucketed rows x the page table's width in tokens unless the
         caller knows better, as for a split step whose history goes through
         the paged kernel; all times the scan steps of a megastep).
@@ -1316,19 +1408,23 @@ class RaggedInferenceEngineTPU:
         from deepspeed_tpu.telemetry.registry import registry
         row_slots = nb * chunk * scan_steps
         slots = row_slots if token_slots is None else token_slots
+        if kv_write_slots is None:
+            kv_write_slots = row_slots
         if context_slots is None:
             context_slots = nb * page_width * self.config.block_size * \
                 scan_steps
         self.last_program = program
         for name, by in (("host_calls", 1), ("tokens", tokens),
                          ("token_slots", slots),
+                         ("kv_write_slots", kv_write_slots),
                          ("context_tokens", context_tokens),
                          ("context_slots", context_slots),
                          (f"steps.{program}", 1)):
             registry.counter("dispatch/" + name).inc(by)
         work = {"program": program, "rows": rows, "rows_bucket": nb,
                 "chunk": chunk, "tokens": tokens, "slots": slots,
-                "row_slots": row_slots, "context_tokens": context_tokens,
+                "row_slots": row_slots, "kv_write_slots": kv_write_slots,
+                "context_tokens": context_tokens,
                 "context_slots": context_slots}
         if kv_window is not None:
             live, held = kv_window
@@ -1502,7 +1598,8 @@ class RaggedInferenceEngineTPU:
                 with jax.named_scope("kv_write"):
                     ak, av = pa.write_kv(
                         ak, av, kb.transpose(1, 0, 2, 3),
-                        vb.transpose(1, 0, 2, 3), pt_l, starts0, counts_wb,
+                        vb.transpose(1, 0, 2, 3), pt_l,
+                        *pa.row_slots(starts0, counts_wb, sb),
                         trash_block=l_idx * stride + stride - 1)
                 return (ak, av), None
 

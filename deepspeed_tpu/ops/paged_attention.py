@@ -109,55 +109,68 @@ def layer_page_offset(layer: jax.Array, num_blocks: int) -> jax.Array:
     return layer * (num_blocks + 1)
 
 
+def row_slots(starts: jax.Array, counts: jax.Array, c: int):
+    """The slots of a chunk in ROWS ``[n, c]``: ``(row, pos, valid)``, each
+    ``[n, c]`` — slot ``(i, j)`` is token ``starts[i] + j`` of sequence
+    ``i`` and holds a token where ``j < counts[i]``. What
+    :func:`write_kv` / :func:`write_rows` take for tokens that arrive as
+    rows (every decode program: ``c == 1``)."""
+    n = starts.shape[0]
+    col = jnp.broadcast_to(jnp.arange(c, dtype=jnp.int32)[None], (n, c))
+    row = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, c))
+    return row, starts[:, None] + col, col < counts[:, None]
+
+
 def write_kv(arena_k: jax.Array, arena_v: jax.Array, k: jax.Array,
-             v: jax.Array, page_table: jax.Array, starts: jax.Array,
-             counts: jax.Array, trash_block=None):
-    """Scatter a ragged chunk of new KV into the arena, each token's row
-    (its heads side by side) written whole.
+             v: jax.Array, page_table: jax.Array, row: jax.Array,
+             pos: jax.Array, valid: jax.Array, trash_block=None):
+    """Scatter new KV into the arena, ONE update a token slot, each
+    token's row (its heads side by side) written whole.
 
     arena_k/arena_v: [NB, bs, kvh * d] (one layer's region of the flat
-    pool, or the whole pool with absolute page-table ids); k/v:
-    [n, c, kvh, d] new tokens (row i valid for j < counts[i]);
-    page_table: [n, mb] physical block ids (padded entries may be
-    anything — padded tokens route to ``trash_block``, default the pool's
-    last block); starts: [n] tokens already in KV per sequence.
+    pool, or the whole pool with absolute page-table ids); k/v: [*S, kvh,
+    d], a token a slot, in whatever form the step holds its tokens —
+    packed ``[1, T, ..]`` or rows ``[n, c, ..]`` (:func:`row_slots`) — and
+    ``row`` / ``pos`` / ``valid`` [*S] say of each slot whose token it
+    is, at which position of its sequence, and whether it holds one at
+    all. page_table: [n, mb] physical block ids (padded entries may be
+    anything). A slot that holds no token routes to ``trash_block``
+    (default the pool's last block) BY ``valid``: a packed slot past the
+    batch's tokens carries a clipped ``row`` / ``pos`` that alias a live
+    token's.
     """
-    n, c = k.shape[:2]
-    bi, oi = _token_slots(arena_k.shape, c, page_table, starts, counts,
+    bi, oi = _token_slots(arena_k.shape, page_table, row, pos, valid,
                           trash_block)
     return (arena_k.at[bi, oi].set(
-                k.reshape(n * c, -1).astype(arena_k.dtype), mode="drop"),
+                k.reshape(bi.shape[0], -1).astype(arena_k.dtype),
+                mode="drop"),
             arena_v.at[bi, oi].set(
-                v.reshape(n * c, -1).astype(arena_v.dtype), mode="drop"))
+                v.reshape(bi.shape[0], -1).astype(arena_v.dtype),
+                mode="drop"))
 
 
-def _token_slots(pool_shape, c: int, page_table: jax.Array,
-                 starts: jax.Array, counts: jax.Array, trash_block):
-    """(page, offset) of every slot of an ``[n, c]`` chunk, flattened: the
-    scatter :func:`write_kv` and :func:`write_rows` share."""
+def _token_slots(pool_shape, page_table: jax.Array, row: jax.Array,
+                 pos: jax.Array, valid: jax.Array, trash_block):
+    """(page, offset) of every token slot, flattened: the scatter
+    :func:`write_kv` and :func:`write_rows` share."""
     nbp1, bs, _ = pool_shape
     if trash_block is None:
         trash_block = nbp1 - 1
-    j = jnp.arange(c, dtype=jnp.int32)[None, :]                    # [1, c]
-    pos = starts[:, None] + j                                      # [n, c]
-    logical = pos // bs                                            # [n, c]
-    offset = pos % bs
-    phys = jnp.take_along_axis(page_table, jnp.minimum(
-        logical, page_table.shape[1] - 1), axis=1)                 # [n, c]
-    valid = j < counts[:, None]
-    phys = jnp.where(valid, phys, trash_block)                     # → trash
-    return phys.reshape(-1), offset.reshape(-1)
+    row, pos, valid = (a.reshape(-1) for a in (row, pos, valid))
+    phys = page_table[row, jnp.minimum(pos // bs, page_table.shape[1] - 1)]
+    return jnp.where(valid, phys, trash_block), pos % bs            # → trash
 
 
 def write_rows(pool: jax.Array, rows: jax.Array, page_table: jax.Array,
-               starts: jax.Array, counts: jax.Array, trash_block=None):
+               row: jax.Array, pos: jax.Array, valid: jax.Array,
+               trash_block=None):
     """:func:`write_kv` for a pool that holds ONE tensor a token (a latent
-    layer's): rows [n, c, w] with ``w`` at most the pool's lanes; the lanes
+    layer's): rows [*S, w] with ``w`` at most the pool's lanes; the lanes
     past ``w`` are written zero."""
-    n, c, w = rows.shape
-    bi, oi = _token_slots(pool.shape, c, page_table, starts, counts,
+    w = rows.shape[-1]
+    bi, oi = _token_slots(pool.shape, page_table, row, pos, valid,
                           trash_block)
-    rows = rows.reshape(n * c, w).astype(pool.dtype)
+    rows = rows.reshape(-1, w).astype(pool.dtype)
     if pool.shape[-1] > w:
         rows = jnp.pad(rows, ((0, 0), (0, pool.shape[-1] - w)))
     return pool.at[bi, oi].set(rows, mode="drop")

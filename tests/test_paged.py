@@ -14,6 +14,12 @@ from deepspeed_tpu.ops import paged_attention as pa
 from deepspeed_tpu.parallel.mesh import build_mesh
 
 
+def _write_chunk(ak, av, k, v, page_table, starts, counts, **kw):
+    """``write_kv`` of a chunk that arrives as rows ``[n, c, kvh, d]``."""
+    return pa.write_kv(ak, av, k, v, page_table,
+                       *pa.row_slots(starts, counts, k.shape[1]), **kw)
+
+
 def _random_arena_state(rng, kvh=2, nb=8, bs=16, dh=128, n=3, mb=4):
     """Build an arena holding random contexts for n sequences."""
     arena = pa.init_arena(1, kvh, nb, bs, dh, jnp.float32)
@@ -27,7 +33,7 @@ def _random_arena_state(rng, kvh=2, nb=8, bs=16, dh=128, n=3, mb=4):
         pt[i, :nblk] = blocks
         k = rng.standard_normal((1, ctx, kvh, dh)).astype(np.float32)
         v = rng.standard_normal((1, ctx, kvh, dh)).astype(np.float32)
-        ak, av = pa.write_kv(ak, av, jnp.asarray(k), jnp.asarray(v),
+        ak, av = _write_chunk(ak, av, jnp.asarray(k), jnp.asarray(v),
                              jnp.asarray(pt[i:i + 1]),
                              jnp.zeros((1,), jnp.int32),
                              jnp.asarray([ctx], np.int32))
@@ -42,7 +48,7 @@ def test_pallas_matches_xla_decode():
     counts = np.ones((n,), np.int32)
     k_new = rng.standard_normal((n, 1, kvh, dh)).astype(np.float32)
     v_new = rng.standard_normal((n, 1, kvh, dh)).astype(np.float32)
-    ak, av = pa.write_kv(ak, av, jnp.asarray(k_new), jnp.asarray(v_new),
+    ak, av = _write_chunk(ak, av, jnp.asarray(k_new), jnp.asarray(v_new),
                          jnp.asarray(pt), jnp.asarray(starts),
                          jnp.asarray(counts))
     q = rng.standard_normal((n, 1, h, dh)).astype(np.float32)
@@ -68,7 +74,7 @@ def test_pallas_matches_xla_chunk():
     counts = np.array([c, c, 3, 0], np.int32)   # ragged + padded row
     k_new = rng.standard_normal((n, c, kvh, dh)).astype(np.float32)
     v_new = rng.standard_normal((n, c, kvh, dh)).astype(np.float32)
-    ak, av = pa.write_kv(ak, av, jnp.asarray(k_new), jnp.asarray(v_new),
+    ak, av = _write_chunk(ak, av, jnp.asarray(k_new), jnp.asarray(v_new),
                          jnp.asarray(pt), jnp.asarray(starts),
                          jnp.asarray(counts))
     q = rng.standard_normal((n, c, h, dh)).astype(np.float32)
@@ -94,7 +100,7 @@ def test_trash_block_isolation():
     k = jnp.ones((1, 4, kvh, dh), jnp.float32) * 7.0
     v = jnp.ones((1, 4, kvh, dh), jnp.float32) * 7.0
     # only 2 of the 4 tokens are valid
-    ak, av = pa.write_kv(ak, av, k, v, jnp.asarray(pt),
+    ak, av = _write_chunk(ak, av, k, v, jnp.asarray(pt),
                          jnp.zeros((1,), jnp.int32),
                          jnp.asarray([2], np.int32))
     a = np.asarray(ak)
@@ -405,7 +411,7 @@ def test_split_history_merge_matches_paged(devices):
     continuation row, and a decode-like row (count=1)."""
     from deepspeed_tpu.ops.paged_attention import (
         causal_attention_with_lse, init_arena, merge_attention,
-        paged_attention_hist_xla, paged_attention_xla, write_kv)
+        paged_attention_hist_xla, paged_attention_xla)
     rng = np.random.default_rng(0)
     kvh, bs, dh, h, c = 2, 8, 64, 4, 16
     arena = init_arena(1, kvh, num_blocks=31, block_size=bs, head_dim=dh,
@@ -419,7 +425,7 @@ def test_split_history_merge_matches_paged(devices):
     # pre-populate history for rows 1/2
     hist_k = jnp.asarray(rng.normal(size=(n, 64, kvh, dh)), jnp.float32)
     hist_v = jnp.asarray(rng.normal(size=(n, 64, kvh, dh)), jnp.float32)
-    ak, av = write_kv(ak, av, hist_k, hist_v, pt,
+    ak, av = _write_chunk(ak, av, hist_k, hist_v, pt,
                       jnp.zeros((n,), jnp.int32), starts)
 
     q = jnp.asarray(rng.normal(size=(n, c, h, dh)), jnp.float32)
@@ -427,7 +433,7 @@ def test_split_history_merge_matches_paged(devices):
     v = jnp.asarray(rng.normal(size=(n, c, kvh, dh)), jnp.float32)
 
     # reference: write then one paged read
-    ak2, av2 = write_kv(ak, av, k, v, pt, starts, counts)
+    ak2, av2 = _write_chunk(ak, av, k, v, pt, starts, counts)
     ref = paged_attention_xla(q, ak2, av2, pt, starts, counts)
 
     # split: history from the PRE-write arena + within-chunk causal
@@ -558,9 +564,11 @@ def test_split_step_matches_in_loop_write(devices, monkeypatch, reader,
 def _packed_stack(stack):
     """(cfg, float32 params, arena maker) of a stack the packed step
     runs on: the tiny Llama block (one scanned layer tree, one K and one
-    V pool) or MiMo-V2.5's typed stack at the benchmark's rehearsal widths
+    V pool), MiMo-V2.5's typed stack at the benchmark's rehearsal widths
     (a full and a window kind with its sink, K heads of 192 and V of 128,
-    a dense layer, then a top-8-of-16 router with 4 experts held)."""
+    a dense layer, then a top-8-of-16 router with 4 experts held) or
+    GigaChat3.1's latent stack at its rehearsal widths (one pool of one
+    row a token; the engine makes its arena)."""
     from deepspeed_tpu.models.transformer import init_params
     if stack == "uniform":
         cfg = llama3_config("tiny")
@@ -571,9 +579,17 @@ def _packed_stack(stack):
     import json
     import os
     from benchmark.lib import model as model_lib
+    configs = os.path.join(os.path.dirname(model_lib.__file__), "..",
+                           "configs")
+    if stack == "latent":
+        conf = json.load(open(os.path.join(
+            configs, "gigachat3.1-l5-e16-serve.json")))
+        cfg = dataclasses.replace(
+            model_lib.build_model(conf, rehearse=True), init_std=0.1)
+        assert cfg.typed and cfg.latent and set(cfg.layer_kinds) == {2}
+        return cfg, init_params(cfg, jax.random.PRNGKey(3)), None
     conf = json.load(open(os.path.join(
-        os.path.dirname(model_lib.__file__), "..", "configs",
-        "mimo-v2.5-l7-e16-serve.json")))
+        configs, "mimo-v2.5-l7-e16-serve.json")))
     # a window of 24: the histories below pass it, the chunks straddle it
     cfg = dataclasses.replace(model_lib.build_model(conf, rehearse=True),
                               init_std=0.1, sliding_window=24)
@@ -675,6 +691,66 @@ def test_packed_chunk_step_matches_row_form(devices, case):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
                                    err_msg=name)
         assert not np.array_equal(a, before), name     # the step wrote
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("stack", ["uniform", "typed", "latent"])
+def test_packing_engine_serves_and_caches_what_a_row_form_engine_does(
+        devices, stack, dtype):
+    """An engine whose 4-row chunk programs pack (``max_batch_tokens`` 80
+    under 4 x 96 row slots: the split program at 64 and 80 slots, the
+    fresh one at 80) against one whose programs keep the row form (a
+    budget of 384, held to 80 tokens a step by the caller, so both pack
+    the same batches): the same greedy tokens, and every pool the same
+    outside the layers' trash pages — the packed write scatters what the
+    row write scatters, where it scatters it."""
+    from deepspeed_tpu import telemetry
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg, params, _ = _packed_stack(stack)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in (100, 30, 70, 40)]
+
+    def serve(max_batch_tokens):
+        eng = RaggedInferenceEngineTPU(
+            cfg, {"dtype": dtype, "max_sequences": 4, "num_blocks": 48,
+                  "block_size": 8, "max_seq_len": 128, "prefill_chunk": 96,
+                  "max_batch_tokens": max_batch_tokens}, params=params)
+        before = telemetry.registry.counter("dispatch/kv_write_slots").value
+        eng.scheduler.put([0, 1, 2, 3], prompts)
+        tokens, slots = {uid: [] for uid in range(4)}, []
+        for _ in range(10):
+            out = eng.step_with_budget(budget=80)
+            now = telemetry.registry.counter("dispatch/kv_write_slots").value
+            slots.append((eng.last_program, now - before))
+            before = now
+            for uid, tok in out.items():
+                tokens[uid].append(int(tok))
+                eng.scheduler.put([uid], [[int(tok)]])
+        return eng, tokens, slots
+
+    packing, got, slots = serve(80)
+    rows, want, row_slots = serve(384)
+    assert packing._token_capacities(4, 96, "split") == (64, 80) and \
+        rows._token_capacities(4, 96, "split") == ()
+    # 80 of the first prompt alone (one row: one capacity); two steps of
+    # 80 tokens over three and four rows (the top capacity: two blocks of
+    # the small one); the last prompt's end beside decode rows (the small)
+    assert slots[:5] == [("fresh", 80), ("split", 128), ("split", 128),
+                         ("split", 64), ("decode", 4)], slots
+    assert [s for _, s in row_slots[:5]] == [96, 384, 384, 384, 4]
+    assert got == want and all(len(t) >= 6 for t in got.values())
+    nb = packing.config.num_blocks
+    tol = 2e-4 if dtype == "float32" else 2e-2
+    assert set(packing.arena) == set(rows.arena)
+    for name, pool in packing.arena.items():
+        kept = np.arange(pool.shape[0]) % (nb + 1) != nb
+        a, b = (np.asarray(x, np.float32)[kept]
+                for x in (pool, rows.arena[name]))
+        assert np.abs(a).max() > 0.01, name
+        # the values are the two forms' own (a matmul over [1, 80] slots
+        # against one over [4, 96]): equal to their rounding
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
 
 
 def test_capacities_the_rows_already_hold_are_refused(devices):
@@ -904,12 +980,12 @@ def test_paged_readers_match_a_dense_reference(case):
     k = rng.standard_normal((n, mb * bs, kvh, dh)).astype(np.float32)
     v = rng.standard_normal((n, mb * bs, kvh, dh)).astype(np.float32)
     # the history, then the step's own tokens: two writes, as the engine's
-    ak, av = pa.write_kv(arena["k"], arena["v"], jnp.asarray(k),
+    ak, av = _write_chunk(arena["k"], arena["v"], jnp.asarray(k),
                          jnp.asarray(v), pt_l, jnp.zeros((n,), jnp.int32),
                          jnp.asarray(starts), trash_block=off + nb)
     new = np.stack([np.stack([x[i, starts[i]:starts[i] + c] for i in range(n)])
                     for x in (k, v)])
-    ak, av = pa.write_kv(ak, av, jnp.asarray(new[0]), jnp.asarray(new[1]),
+    ak, av = _write_chunk(ak, av, jnp.asarray(new[0]), jnp.asarray(new[1]),
                          pt_l, jnp.asarray(starts), jnp.asarray(counts),
                          trash_block=off + nb)
     assert not np.asarray(ak)[:off].any()             # layer 0 untouched
@@ -945,7 +1021,7 @@ def test_write_then_gather_round_trip_and_the_layers_trash_page():
     k = rng.standard_normal((n, c, kvh, dh)).astype(np.float32)
     v = rng.standard_normal((n, c, kvh, dh)).astype(np.float32)
     counts = np.asarray([6, 2, 0], np.int32)
-    ak, av = pa.write_kv(arena["k"], arena["v"], jnp.asarray(k),
+    ak, av = _write_chunk(arena["k"], arena["v"], jnp.asarray(k),
                          jnp.asarray(v), pt, jnp.zeros((n,), jnp.int32),
                          jnp.asarray(counts), trash_block=trash)
     for pool, new in ((ak, k), (av, v)):
@@ -957,6 +1033,104 @@ def test_write_then_gather_round_trip_and_the_layers_trash_page():
         pool = np.asarray(pool)
         written = {int(p) for p in np.flatnonzero(pool.any(axis=(1, 2)))}
         assert written == {off + 2, off + 4, off + 0, trash}
+
+
+#: how a step holds its tokens -> ``_TokenLayout``'s capacity: rows as
+#: they arrive, or packed into the small / the top capacity of a program
+_SLOT_FORMS = {"rows": None, "small_capacity": 40, "top_capacity": 64}
+#: batch -> (tokens a row feeds, tokens it has cached), pages of 8 under a
+#: chunk of 16: decode rows over a history, rows with no token, a chunk
+#: that straddles a page boundary and ends exactly on the next (5 + 11), one
+#: that straddles another (13 + 7), a fresh one, a full chunk of two pages
+_SLOT_BATCHES = {
+    "ends_in_a_full_chunk": ([1, 0, 11, 1, 7, 0, 3, 16],
+                             [9, 0, 5, 23, 13, 0, 0, 8]),
+    "ends_in_rows_without_tokens": ([0, 16, 11, 1, 7, 3, 1, 0, 0],
+                                    [0, 8, 5, 23, 13, 0, 9, 0, 0]),
+}
+
+
+def _slot_write(writer, form, batch, mask=True):
+    """(pools written through ``writer`` with the layout's slots, the same
+    pools written token by token in numpy, the trash page): layer 1 of a
+    two-layer pool, pages out of order, pools that hold something
+    already; a packed buffer's slots past the batch's tokens hold values
+    of their own. ``mask=False``: every slot claims a token."""
+    from deepspeed_tpu.inference.engine_v2 import _TokenLayout
+    counts, starts = (np.asarray(a, np.int32) for a in _SLOT_BATCHES[batch])
+    rng = np.random.default_rng(11)
+    n, c, bs, mb, nb = len(counts), 16, 8, 4, 20
+    off, trash = nb + 1, 2 * nb + 1
+    pt = np.full((n, mb), nb, np.int32)
+    free = iter(rng.permutation(nb))
+    for i in range(n):
+        pages = -(-(starts[i] + counts[i]) // bs) if counts[i] else 0
+        pt[i, :pages] = [next(free) for _ in range(pages)]
+    pt += off
+    # write_kv: two pools of two heads of 8; write_rows: rows of 20 values
+    # into a pool 24 lanes wide
+    shapes, lanes = (((2, 8), (2, 8)), (16, 16)) if writer == "write_kv" \
+        else (((20,),), (24,))
+    pools = [rng.standard_normal((2 * (nb + 1), bs, w)).astype(np.float32)
+             for w in lanes]
+    rows = [rng.standard_normal((n, c) + sh).astype(np.float32)
+            for sh in shapes]
+    want = [p.copy() for p in pools]
+    for pool, new in zip(want, rows):
+        for i in range(n):
+            for j in range(counts[i]):
+                pos = starts[i] + j
+                row = new[i, j].reshape(-1)
+                pool[pt[i, pos // bs], pos % bs] = np.pad(
+                    row, (0, pool.shape[-1] - row.size))
+    capacity = _SLOT_FORMS[form]
+    if capacity is None:
+        held = rows
+    else:       # row after row, then slots that hold no token
+        held = [np.concatenate(
+            [new[i, :counts[i]] for i in range(n)] +
+            [rng.standard_normal((capacity - counts.sum(),) + new.shape[2:])
+             .astype(np.float32)])[None] for new in rows]
+    row, pos, valid = _TokenLayout(jnp.asarray(counts), jnp.asarray(starts),
+                                   c, capacity).kv_slots()
+    assert row.shape == (capacity or n * c,) and \
+        int(valid.sum()) == counts.sum()
+    if not mask:
+        valid = jnp.ones_like(valid)
+    write = getattr(pa, writer)
+    got = write(*map(jnp.asarray, pools), *map(jnp.asarray, held),
+                jnp.asarray(pt), row, pos, valid, trash_block=trash)
+    got = got if isinstance(got, tuple) else (got,)
+    return [np.asarray(g) for g in got], want, trash
+
+
+@pytest.mark.parametrize("batch", list(_SLOT_BATCHES))
+@pytest.mark.parametrize("form", list(_SLOT_FORMS))
+@pytest.mark.parametrize("writer", ["write_kv", "write_rows"])
+def test_token_slot_write_matches_a_token_by_token_write(writer, form, batch):
+    """``write_kv`` / ``write_rows`` with ONE update a slot of the
+    token-wise form — rows ``[n, c]``, or packed at either capacity —
+    leave every pool, outside the layer's trash page, as a token-by-token
+    write leaves it: each token's values at its page and offset (the lanes
+    past a narrower row zero), nothing else moved. So the packed write
+    equals the row write, pool for pool."""
+    got, want, trash = _slot_write(writer, form, batch)
+    for g, w in zip(got, want):
+        keep = np.arange(g.shape[0]) != trash
+        np.testing.assert_array_equal(g[keep], w[keep])
+
+
+@pytest.mark.parametrize("writer", ["write_kv", "write_rows"])
+def test_an_unmasked_slot_past_the_tokens_overwrites_a_live_token(writer):
+    """The control of the comparison above: a packed slot past the batch's
+    tokens carries the clipped row / column of the LAST live token, so
+    with ``valid`` ignored its update lands on that token's KV — routing
+    by ``valid`` is what keeps it off."""
+    got, want, trash = _slot_write(writer, "top_capacity",
+                                   "ends_in_a_full_chunk", mask=False)
+    keep = np.arange(got[0].shape[0]) != trash
+    assert all(not np.array_equal(g[keep], w[keep])
+               for g, w in zip(got, want))
 
 
 def test_copy_pages_copies_a_page_in_every_layer_of_every_pool():

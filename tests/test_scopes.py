@@ -524,6 +524,54 @@ def test_packed_launch_counts_the_capacity_it_ran_at(traced):
                 ("fresh", "split", "decode", "paged", "megastep")]
 
 
+#: launch -> (engine overrides, prompt lengths, which launch, what it is:
+#: program, tokens, ``kv_write_slots``). The packing engine's 4-row split
+#: program holds 64 and 80 slots and writes back in blocks of 64
+_PACKING = {"prefill_chunk": 96, "max_batch_tokens": 80, "max_sequences": 4}
+_KV_WRITE_LAUNCHES = {
+    "decode": ({}, (5,), 1, ("decode", 1, 1)),
+    "row_form_split": ({}, (20, 3), 1, ("split", 8 + 1, 2 * 8)),
+    "row_form_fresh": ({}, (20, 3), 0, ("fresh", 8 + 3, 2 * 8)),
+    "fresh_at_its_capacity": (_PACKING, (30, 30, 30, 30), 0,
+                              ("fresh", 80, 80)),
+    "split_at_the_small_capacity": (_PACKING, (30, 30, 30, 30), 1,
+                                    ("split", 42, 64)),
+    "split_at_the_top_capacity": (_PACKING, (100, 30, 70, 40), 1,
+                                  ("split", 80, 2 * 64)),
+}
+
+
+@pytest.mark.parametrize("launch", list(_KV_WRITE_LAUNCHES))
+def test_launch_counts_the_updates_its_kv_write_performs(traced, launch):
+    """``kv_write_slots`` of a launch, and ``dispatch/kv_write_slots``: the
+    updates a pool and layer its KV scatter performs — one a row in a
+    decode step, rows x chunk where the step keeps the row form, the
+    capacity a fresh step packed into, and whole blocks of the small
+    capacity until the tokens are written in a split step that packs (so
+    ``token_slots`` wherever the top capacity is a multiple of the small
+    one, as 1,024 and 2,048 are)."""
+    from deepspeed_tpu.serving import ServingFrontend
+    over, prompts, index, (program, tokens, slots) = \
+        _KV_WRITE_LAUNCHES[launch]
+    fe = ServingFrontend(_engine(**over))
+    rng = np.random.default_rng(0)
+    before = telemetry.registry.counter("dispatch/kv_write_slots").value
+    for n in prompts:
+        fe.submit(list(rng.integers(1, 255, n)), max_new_tokens=8)
+    for _ in range(index + 1):
+        fe.step()
+    launches = [e["args"] for e in
+                _spans(traced.events(), "serving/dispatch")]
+    a = launches[index]
+    assert (a["program"], a["tokens"], a["kv_write_slots"]) == \
+        (program, tokens, slots), a
+    assert a["tokens"] <= a["kv_write_slots"] <= a["row_slots"]
+    if not over:
+        assert a["kv_write_slots"] == a["slots"] == a["row_slots"]
+    assert telemetry.registry.counter("dispatch/kv_write_slots").value - \
+        before == sum(x["kv_write_slots"] for x in launches)
+
+
 def test_untraced_serving_step_counts_and_computes_no_argument(monkeypatch):
     """With the tracer off the counters still advance by the packed
     batch's sums, and the span arguments are never unpacked."""

@@ -24,6 +24,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -254,11 +255,12 @@ def _abstract_params(model, sharding):
 #: ``fresh_prefill``, token capacities under the engine's defaults
 #: (``_token_capacities``: 16 a row and ``max_batch_tokens``; a decode step
 #: keeps the row form), most temporaries at two layers (measured 0.00,
-#: 0.56 and under 0.15 GB, ISSUE 34))
+#: 0.46 and 0.11 GB since the chunk's K/V leave the layer loop packed,
+#: ISSUE 36; 0.56 and 0.14 while they left it as rows))
 _SERVE_STEPS = {
     "decode": (1, False, (), 0.3e9),
-    "split": (128, "split", (1024, 2048), 1.0e9),
-    "fresh": (128, "fresh", (2048,), 0.3e9),
+    "split": (128, "split", (1024, 2048), 0.5e9),
+    "fresh": (128, "fresh", (2048,), 0.125e9),
 }
 
 
@@ -418,6 +420,31 @@ def _branches(text):
             for b in (m.group(1) or m.group(2)).split(",")}
 
 
+_SHAPE_OF = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = \w+\[([\d,]*)\]", re.M)
+_SCATTER = re.compile(r" = \w+\[([\d,]*)\]\S* scatter\(%[\w.\-]+, %[\w.\-]+, "
+                      r"%([\w.\-]+)\)")
+
+
+def _kv_scatter_updates(text, pools):
+    """The number of updates of every ``scatter`` INTO a pool of the arena
+    (its result holds a pool's elements, pages flattened or not) of a
+    compiled module: the rows of its ``updates`` operand, ``[slots,
+    lanes]``. (A sparse layer's dispatch scatters too: not a KV write.)"""
+    sizes = {int(np.prod(pool.shape)) for pool in pools}
+    shapes = {m.group(1): m.group(2) for m in _SHAPE_OF.finditer(text)}
+    return [int(shapes[m.group(2)].split(",")[0])
+            for m in _SCATTER.finditer(text)
+            if int(np.prod(list(map(int, m.group(1).split(","))))) in sizes]
+
+
+def _top_slots(step, nb=64):
+    """The most updates a 64-row program's KV scatter may perform: its top
+    capacity, the rows of a decode step. ``step``: an entry of
+    ``_SERVE_STEPS`` / ``_LATENT_STEPS``."""
+    cb, _, capacities = step[:3]
+    return capacities[-1] if capacities else nb * cb
+
+
 @pytest.mark.parametrize("kind", list(_SERVE_STEPS))
 def test_no_serve_step_moves_the_arena(kind, one_chip, no_persistent_cache,
                                        monkeypatch):
@@ -453,6 +480,11 @@ def test_no_serve_step_moves_the_arena(kind, one_chip, no_persistent_cache,
     assert arena_copy.search(relaid.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < \
         _SERVE_STEPS[kind][3]
+    # the KV write scatters the slots the tokens were packed into, not
+    # the rows' 8,192 (ISSUE 36)
+    updates = _kv_scatter_updates(text, args[1].values())
+    assert len(updates) == 2 and \
+        max(updates) <= _top_slots(_SERVE_STEPS[kind]), updates
     if kind != "split":
         return
     table = scope_table_from_hlo(text)
@@ -475,6 +507,7 @@ def test_no_serve_step_moves_the_arena(kind, one_chip, no_persistent_cache,
 _LATENT_STEPS = {
     "decode": (1, False, (), 0.4e9),
     "split": (128, "split", (1024, 2048), 4.0e9),
+    "fresh": (128, "fresh", (2048,), 4.0e9),
 }
 
 
@@ -502,24 +535,15 @@ def _latent_2l():
         "expert_share": {"router_experts": 256, "first_expert": 0}})
 
 
-@pytest.mark.parametrize("kind", list(_LATENT_STEPS))
-def test_latent_step_reads_the_pool_absorbed(kind, one_chip,
-                                             no_persistent_cache,
-                                             monkeypatch):
-    """The latent stack's 64-row decode and split programs over the cell's
-    arena (2,176 pages of 128, 34 a row, pool rows of 640 lanes): the
-    ``mla_decode`` kernel reads the pool (under ``attn_core`` in the decode
-    program, ``attn_history`` in the split one); NO pool-shaped copy; and
-    the decode program holds no expansion of the history to heads — no
-    tensor with the context's 4,352 slots beside the 64 heads (a gathered,
-    expanded K or V would be ``[64, 4352, 64, 128|192]``)."""
+def _typed_step(one_chip, model, step, mb, make_arena):
+    """A 64-row step program of a typed stack compiled for the chip, and
+    its text: ``step`` = (chunk, ``fresh_prefill``, capacities, ...) over
+    the abstract ``make_arena()`` and a page table ``mb`` pages wide. NO
+    pool-shaped copy in the module, and no KV scatter of more updates
+    than the step's top capacity (a decode step: its rows)."""
     from deepspeed_tpu.inference import engine_v2
-    from deepspeed_tpu.ops import paged_attention as pa
-    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model = _latent_2l()
-    cb, fresh, capacities, most = _LATENT_STEPS[kind]
-    nb, mb = 64, 34
+    cb, fresh, capacities = step[:3]
+    nb = 64
 
     def serve_step(params, arena, tokens, counts, starts, pt):
         logits, arena = engine_v2.ragged_forward(
@@ -532,8 +556,7 @@ def test_latent_step_reads_the_pool_absorbed(kind, one_chip,
 
     arena = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        jax.eval_shape(lambda: pa.init_arena_typed(
-            model.layer_kinds, {2: 1}, 2176, 128, 640, 0, jnp.bfloat16)))
+        jax.eval_shape(make_arena))
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
@@ -541,15 +564,75 @@ def test_latent_step_reads_the_pool_absorbed(kind, one_chip,
         _abstract_params(model, one_chip), arena, i32(nb, cb), i32(nb),
         i32(nb), i32(nb, mb)).compile()
     text = compiled.as_text()
-    pool = arena["latent"]
-    shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
-    copies = [line.strip()[:160] for line in text.splitlines()
-              if re.search(rf" = {re.escape(shape)}\S* copy\(", line)]
-    assert not copies, copies
+    for pool in arena.values():
+        shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(rf" = {re.escape(shape)}\S* copy\(", line)]
+        assert not copies, copies
+    updates = _kv_scatter_updates(text, arena.values())
+    assert len(updates) == len(arena) * (
+        model.num_layers // len(set(model.layer_kinds))) and \
+        max(updates) <= _top_slots(step, nb), updates
+    return compiled, text
+
+
+@pytest.mark.parametrize("kind", list(_SERVE_STEPS))
+def test_mimo_step_scatters_its_token_slots(kind, one_chip,
+                                            no_persistent_cache,
+                                            monkeypatch):
+    """The 64-row decode, split and fresh programs (``_SERVE_STEPS``' chunk
+    and capacities) of MiMo-V2.5's stack at the published widths (benchmark/configs/mimo-v2.5-l7-e16-serve, cut to
+    its first full and first window layer: the dense FFN and the held
+    experts), four pools under one page table of 8 pages a row, the K
+    pools wider than the head: every pool's KV scatter performs at most
+    the step's top capacity of updates — 2,048 packed slots, where the
+    rows hold 8,192 — under ``kv_write``, and no pool is copied."""
+    import json
+    import os
+    from benchmark.lib import model as model_lib
+    from deepspeed_tpu.ops import paged_attention as pa
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    conf = json.load(open(os.path.join(
+        os.path.dirname(model_lib.__file__), "..", "configs",
+        "mimo-v2.5-l7-e16-serve.json")))
+    model = model_lib.build_model({**conf, "num_hidden_layers": 2})
+    assert model.layer_kinds == (0, 1) and model.head_dim == 192
+    _, text = _typed_step(
+        one_chip, model, _SERVE_STEPS[kind], 8, lambda: pa.init_arena_typed(
+            model.layer_kinds,
+            {a: model.kind_kv_heads(a) for a in set(model.layer_kinds)},
+            512, 128, 256, model.v_dim, jnp.bfloat16))
+    assert "kv_write" in {e["scope"] for e in
+                          scope_table_from_hlo(text).values()}
+
+
+@pytest.mark.parametrize("kind", list(_LATENT_STEPS))
+def test_latent_step_reads_the_pool_absorbed(kind, one_chip,
+                                             no_persistent_cache,
+                                             monkeypatch):
+    """The latent stack's 64-row decode, split and fresh programs over the
+    cell's arena (2,176 pages of 128, 34 a row, pool rows of 640 lanes): the
+    ``mla_decode`` kernel reads the pool (under ``attn_core`` in the decode
+    program, ``attn_history`` in the split one; the fresh one reads no
+    pool); NO pool-shaped copy, no KV scatter over its top capacity; and
+    the decode program holds no expansion of the history to heads — no
+    tensor with the context's 4,352 slots beside the 64 heads (a gathered,
+    expanded K or V would be ``[64, 4352, 64, 128|192]``)."""
+    from deepspeed_tpu.ops import paged_attention as pa
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = _latent_2l()
+    most = _LATENT_STEPS[kind][3]
+    compiled, text = _typed_step(
+        one_chip, model, _LATENT_STEPS[kind], 34, lambda: pa.init_arena_typed(
+            model.layer_kinds, {2: 1}, 2176, 128, 640, 0, jnp.bfloat16))
     table = scope_table_from_hlo(text)
     kernels = [n for n in table if n.startswith("mla_decode")]
-    want = "attn_core" if kind == "decode" else "attn_history"
-    assert kernels and all(table[n]["scope"] == want for n in kernels), \
+    # (a fresh step reads no pool: its chunk is its whole history)
+    want = {"decode": "attn_core", "split": "attn_history"}.get(kind)
+    assert bool(kernels) == (kind != "fresh") and \
+        all(table[n]["scope"] == want for n in kernels), \
         [(n, table[n]["scope"]) for n in kernels]
     assert {"attn_latent", "moe_shared"} <= \
         {e["scope"] for e in table.values()}
