@@ -586,13 +586,13 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         chunk_kv = []
         for kind, lp, place in zip(cfg.layer_kinds, params["layers"],
                                    places):
-            h_in = _norm(cfg, lp["ln1"], x).astype(dtype)
+            h = _norm(cfg, lp["ln1"], x)
             attend = latent_attention if kind == 2 else heads_attention
-            out = attend(lay, kind, lp["attn"], place, h_in, tables[kind],
-                         pools, chunk_kv)
-            x = x + tl.typed_attn_out(cfg, lp["attn"], out)
-            x = x + tl.typed_ffn(cfg, lp, _norm(cfg, lp["ln2"], x), moe_fn,
-                                 lay.valid, dtype)
+            out = attend(lay, kind, lp["attn"], place, h.astype(dtype),
+                         tables[kind], pools, chunk_kv)
+            x = tl.block_residual(
+                cfg, lp, x, h, tl.typed_attn_out(cfg, lp["attn"], out),
+                moe_fn, lay.valid, dtype)
         x = _norm(cfg, params["final_norm"], x).astype(dtype)
         with jax.named_scope("lm_head"):       # the rows the head projects
             return lay.last(x), (chunk_kv if split else pools)
@@ -1347,6 +1347,7 @@ class RaggedInferenceEngineTPU:
             int((batch.start_positions + batch.token_counts).sum()),
             context_slots=context_slots,
             kv_window=self._kv_window_tokens(batch),
+            attn_pairs=self._attn_pairs(batch),
             # the device's own rules (_at_capacity: the smallest that
             # holds; _write_back: whole blocks until the tokens are written)
             token_slots=next((t for t in capacities if tokens <= t), None),
@@ -1375,11 +1376,41 @@ class RaggedInferenceEngineTPU:
                           batch.token_counts - 1)
         return int(live.sum()), int(held.sum())
 
+    def _attn_pairs(self, batch: RaggedBatch):
+        """Live (query, key) pairs of the launch in ONE layer of each
+        kind, or None where the model has no window kind: every fed token
+        times the keys it sees — all of its row up to itself in a full
+        layer (``attn_pairs_full``), at most ``sliding_window`` of them in
+        a window layer (``attn_pairs_window``) — and, of those, the pairs
+        INSIDE the fed chunk (``attn_pairs_own_full`` / ``..._own_window``:
+        what a split step's chunk attention takes; the rest is its
+        history reader's). Host arithmetic on the batch's lengths (the
+        numerators of a roofline over the attention's FLOPs)."""
+        model = self.model_config
+        if not model.typed or 1 not in model.layer_kinds:
+            return None
+        w = model.sliding_window
+        start = batch.start_positions.astype(np.int64)
+        fed = batch.token_counts.astype(np.int64)
+
+        def seen(first, n):
+            """Σ over n queries of min(keys before and at the query, w),
+            the first query having ``first`` keys before it."""
+            whole = np.clip(w - first, 0, n)   # queries that see them all
+            return int((whole * first + whole * (whole + 1) // 2 +
+                        (n - whole) * w).sum())
+
+        return {"attn_pairs_full":
+                    int((fed * start + fed * (fed + 1) // 2).sum()),
+                "attn_pairs_window": seen(start, fed),
+                "attn_pairs_own_full": int((fed * (fed + 1) // 2).sum()),
+                "attn_pairs_own_window": seen(np.zeros_like(start), fed)}
+
     def _count_dispatch(self, program: str, rows: int, nb: int, chunk: int,
                         page_width: int, tokens: int, context_tokens: int,
                         scan_steps: int = 1,
                         context_slots: Optional[int] = None,
-                        kv_window=None,
+                        kv_window=None, attn_pairs=None,
                         token_slots: Optional[int] = None,
                         kv_write_slots: Optional[int] = None
                         ) -> Dict[str, Any]:
@@ -1402,7 +1433,9 @@ class RaggedInferenceEngineTPU:
         ``dispatch/kv_window_live_tokens`` / ``..._held_tokens`` and the
         span's ``kv_tokens_full`` (what a full layer holds for the rows:
         ``context_tokens``), ``kv_tokens_window_live`` and
-        ``kv_tokens_window_held``. A latent stack's span carries
+        ``kv_tokens_window_held``; ``attn_pairs`` (:meth:`_attn_pairs`) a
+        span argument of each of its names (no counter: its one reader
+        takes the traced launches' spans). A latent stack's span carries
         ``kv_tokens_latent``: the cached rows ONE latent layer holds for
         the batch's rows after the launch (``context_tokens``)."""
         from deepspeed_tpu.telemetry.registry import registry
@@ -1433,6 +1466,8 @@ class RaggedInferenceEngineTPU:
             work.update(kv_tokens_full=context_tokens,
                         kv_tokens_window_live=live,
                         kv_tokens_window_held=held)
+        if attn_pairs is not None:
+            work.update(attn_pairs)
         if self.model_config.latent:
             work["kv_tokens_latent"] = context_tokens
         return work

@@ -33,7 +33,8 @@ Params = Any
 _FAMILIES = ("llama", "mistral", "mixtral", "qwen", "qwen2", "qwen2_moe",
               "gpt_neox", "gemma", "gpt2", "opt", "bloom", "falcon",
               "phi", "phi3", "gpt_bigcode", "gptj", "bert", "distilbert",
-              "gpt_neo", "internlm", "mimo_v2", "deepseek_v3")
+              "gpt_neo", "internlm", "mimo_v2", "deepseek_v3",
+              "cohere2_moe")
 
 
 def _map_hf_act(act: str) -> str:
@@ -57,6 +58,8 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
         return _mimo_v2_config(hf)
     if mt == "deepseek_v3":
         return _deepseek_v3_config(hf)
+    if mt == "cohere2_moe":
+        return _cohere2_moe_config(hf)
     if mt == "bert":
         return DecoderConfig(
             hidden_size=hf["hidden_size"],
@@ -423,7 +426,7 @@ def _mimo_v2_config(hf: Dict[str, Any]) -> DecoderConfig:
 
 
 def _rope_type(hf: Dict[str, Any]) -> str:
-    scaling = hf.get("rope_scaling") or {}
+    scaling = hf.get("rope_scaling") or hf.get("rope_parameters") or {}
     return scaling.get("rope_type", scaling.get("type", "default"))
 
 
@@ -515,6 +518,90 @@ def _deepseek_v3_config(hf: Dict[str, Any]) -> DecoderConfig:
         **_sigmoid_router(hf, "deepseek_v3"))
 
 
+def _cohere2_moe_config(hf: Dict[str, Any]) -> DecoderConfig:
+    """Cohere2-MoE's language model (Command A+; ``model_type:
+    cohere2_moe``): a typed stack (models/typed_layers.py has the
+    equations) of PARALLEL blocks under one bias-free LayerNorm
+    (``layer_norm_eps``; the published ``rms_norm_eps`` is null and a file
+    may leave it out) — ``layer_types`` names each layer
+    ``sliding_attention`` (window ``sliding_window``, interleaved rotary:
+    ``position_embedding_type: rope_gptj``, ``rotary_pct`` of the head) or
+    ``full_attention`` (NO positional term), and may be longer than
+    ``num_hidden_layers`` (a depth-cut file keeps the published list: the
+    first ``num_hidden_layers`` entries are read). Every layer is sparse:
+    a sigmoid router ``num_experts`` wide with NO selection bias, top-k
+    renormalised, experts of ``intermediate_size``, ``num_shared_experts``
+    shared experts of the same width AVERAGED; the head is tied. ONE
+    published key names both the router's width and the expert count, so
+    a share is told apart by ``expert_share`` (not a published key:
+    ``{"router_experts", "first_expert", "held_experts"}``): the router
+    keeps ``num_experts`` outputs and the weights hold ``held_experts``.
+    Refused by name: QK norm, attention biases, a ``logit_scale`` other
+    than 1, leading dense layers (``first_k_dense_replace`` > 0; with none,
+    ``prefix_dense_*`` name no layer), any ``rope_type`` but ``default``.
+    Not built: the vision tower (no key of the language model's config)."""
+    fam = "cohere2_moe"
+    for key, want in (("use_qk_norm", False), ("attention_bias", False),
+                      ("logit_scale", 1), ("first_k_dense_replace", 0),
+                      ("use_parallel_block", True),
+                      ("use_gated_activation", True),
+                      ("hidden_act", "silu"),
+                      ("expert_selection_fn", "sigmoid"),
+                      ("position_embedding_type", "rope_gptj"),
+                      ("shared_expert_combination_strategy", "average"),
+                      ("tie_word_embeddings", True)):
+        if hf.get(key, want) != want:
+            raise ValueError(f"{fam}: {key}={hf[key]!r} is not built "
+                             f"(expected {want!r})")
+    if _rope_type(hf) != "default":
+        raise ValueError(f"{fam}: rope_type {_rope_type(hf)!r} is not "
+                         f"built (expected 'default')")
+    L = int(hf["num_hidden_layers"])
+    names = {"full_attention": 0, "sliding_attention": 1}
+    if len(hf["layer_types"]) < L:
+        raise ValueError(f"{fam}: layer_types has "
+                         f"{len(hf['layer_types'])} entries for {L} layers")
+    for name in hf["layer_types"][:L]:
+        if name not in names:
+            raise ValueError(f"{fam}: layer type {name!r} is not built "
+                             f"(expected one of {sorted(names)})")
+    share = hf.get("expert_share")
+    E = int(hf["num_experts"])
+    if share and int(share["router_experts"]) != E:
+        raise ValueError(
+            f"{fam}: expert_share.router_experts="
+            f"{share['router_experts']!r} is not num_experts={E} (the one "
+            f"published key is the router's width; the share's count is "
+            f"expert_share.held_experts)")
+    shared_n = int(hf.get("num_shared_experts") or 0)
+    width = int(hf["intermediate_size"])
+    theta = float((hf.get("rope_parameters") or {}).get(
+        "rope_theta", hf.get("rope_theta", 10000.0)))
+    return DecoderConfig(
+        hidden_size=hf["hidden_size"], num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim_override=int(hf.get(
+            "head_dim", hf["hidden_size"] // hf["num_attention_heads"])),
+        intermediate_size=width, vocab_size=hf["vocab_size"],
+        max_seq_len=hf.get("max_position_embeddings", 8192),
+        norm="layernorm", norm_bias=False, use_bias=False,
+        norm_eps=float(hf.get("layer_norm_eps", 1e-5)),
+        activation="silu_glu", pos_emb="rope", rope_theta=theta,
+        rotary_pct=float(hf.get("rotary_pct", 1.0)),
+        rope_interleaved=True, full_attn_rope=False,
+        parallel_block=True, parallel_block_norms=1, tie_embeddings=True,
+        layer_kinds=tuple(names[n] for n in hf["layer_types"][:L]),
+        sliding_window=int(hf["sliding_window"]),
+        num_experts=E, num_experts_per_tok=int(hf["num_experts_per_tok"]),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        router_scoring="sigmoid", router_select_bias=False,
+        experts_held=(int(share["first_expert"]),
+                      int(share["held_experts"])) if share else None,
+        shared_expert_size=shared_n * width,
+        shared_experts_averaged=max(shared_n, 1))
+
+
 def _is_gemma_layout(cfg: DecoderConfig) -> bool:
     return cfg.activation == "gelu_glu" and cfg.scale_embeddings
 
@@ -554,7 +641,8 @@ def config_to_hf(cfg: DecoderConfig) -> Dict[str, Any]:
 
     if cfg.typed:
         raise NotImplementedError(
-            "config_to_hf: a typed layer stack (mimo_v2) has no exporter")
+            "config_to_hf: a typed layer stack (mimo_v2, deepseek_v3, "
+            "cohere2_moe) has no exporter")
     if not cfg.causal or not cfg.prenorm:
         # encoder layouts (BERT/DistilBERT): both flags flip together
         if cfg.causal or cfg.prenorm or cfg.pos_emb != "learned" \

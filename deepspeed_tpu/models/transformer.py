@@ -205,6 +205,17 @@ class DecoderConfig:
     #: (factor, original_max_position_embeddings, beta_fast, beta_slow,
     #: mscale, mscale_all_dim). None → plain ``theta ** (-i / half)``.
     rope_yarn: Optional[Tuple[float, int, float, float, float, float]] = None
+    # -- Cohere2-MoE's block on the typed stack (``parallel_block`` above) --
+    #: rotary pairs are NEIGHBOURS ``(2i, 2i+1)`` (GPT-J's convention;
+    #: Cohere ``position_embedding_type: rope_gptj``), not the two halves
+    rope_interleaved: bool = False
+    #: False → the FULL kind (0) of a typed stack has no positional term at
+    #: all (Cohere2's global layers); the window kind keeps its rotary
+    full_attn_rope: bool = True
+    #: the shared GLU of ``shared_expert_size`` is this many shared experts
+    #: side by side and its output their MEAN (Cohere2-MoE
+    #: ``shared_expert_combination_strategy: average``); 1 → the sum
+    shared_experts_averaged: int = 1
 
     def __post_init__(self):
         if self.mlm_head and not self.tie_embeddings:
@@ -274,9 +285,11 @@ class DecoderConfig:
         return (self.window_kv_heads or self.kv_heads) if kind == 1 \
             else self.kv_heads
 
-    def kind_rope_theta(self, kind: int) -> float:
-        return (self.window_rope_theta or self.rope_theta) if kind == 1 \
-            else self.rope_theta
+    def kind_rope_theta(self, kind: int) -> Optional[float]:
+        """The kind's rotary base; None: the kind has no positional term."""
+        if kind == 1:
+            return self.window_rope_theta or self.rope_theta
+        return self.rope_theta if self.full_attn_rope else None
 
     def kind_window(self, kind: int) -> Optional[int]:
         return self.sliding_window if kind == 1 else None
@@ -499,17 +512,25 @@ def rope_table(cfg: DecoderConfig, positions: jax.Array) -> Tuple[jax.Array, jax
     return jnp.sin(angles) * mag, jnp.cos(angles) * mag
 
 
-def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
-    """x: [B, T, H, Dh]; rotate-half convention (Llama). When the table
-    covers fewer dims than Dh (partial rotary), the tail passes through
-    unrotated (GPT-NeoX/GPT-J convention)."""
+def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array,
+               interleaved: bool = False) -> jax.Array:
+    """x: [B, T, H, Dh]; rotate-half convention (Llama): pair ``i`` is
+    dims ``(i, i + rot/2)``. ``interleaved``: pair ``i`` is the neighbours
+    ``(2i, 2i + 1)`` (GPT-J; ``DecoderConfig.rope_interleaved``). When the
+    table covers fewer dims than Dh (partial rotary), the tail passes
+    through unrotated (GPT-NeoX/GPT-J convention)."""
     rot = 2 * sin.shape[-1]
     x_rot, x_pass = x[..., :rot], x[..., rot:]
-    x1, x2 = jnp.split(x_rot, 2, axis=-1)
+    if interleaved:
+        pairs = x_rot.reshape(*x_rot.shape[:-1], rot // 2, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1, x2 = jnp.split(x_rot, 2, axis=-1)
     sin = sin[:, :, None, :]
     cos = cos[:, :, None, :]
-    rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                              axis=-1)
+    halves = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    rotated = jnp.stack(halves, axis=-1).reshape(x_rot.shape) \
+        if interleaved else jnp.concatenate(halves, axis=-1)
     if x_pass.shape[-1]:
         rotated = jnp.concatenate([rotated, x_pass], axis=-1)
     return rotated.astype(x.dtype)
@@ -1433,8 +1454,9 @@ def partition_specs(cfg: DecoderConfig, zero_stage: int = 0,
     if cfg.typed:
         raise NotImplementedError(
             "partition_specs: a typed layer stack (DecoderConfig."
-            "layer_kinds; MiMo-V2) is served on one shard and not trained "
-            "yet — no sharding plan exists for its list of layers")
+            "layer_kinds: mimo_v2, deepseek_v3, cohere2_moe) is served on "
+            "one shard and not trained yet — no sharding plan exists for "
+            "its list of layers")
     # MiCS (reference runtime/zero/mics.py:63): param shards live within
     # the (data_inner, expert) sub-group and replicate across 'data', so
     # stage-3 allgathers stay inside the cheap sub-group links

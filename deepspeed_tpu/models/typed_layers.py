@@ -39,6 +39,23 @@ projects through two bottlenecks and caches the second:
 - a sparse layer adds ``Shared(h)``, a SiLU-GLU every token takes, beside
   the routed experts (``cfg.shared_expert_size``; scope ``moe_shared``).
 
+A PARALLEL block (``cfg.parallel_block``; Cohere2-MoE's, ``hf_loader``:
+``cohere2_moe``) has ONE norm a layer and no ``ln2``: attention and the
+experts both read the same ``h``, and nothing re-normalises the stream
+between them:
+
+- ``h = LayerNorm(x)``: ``(x − mean)·rsqrt(var + eps)·scale``, no bias, on
+  the float32 stream (``cfg.norm == "layernorm"``);
+- window layers: rotary on the whole head in INTERLEAVED pairs ``(2i,
+  2i + 1)`` (``cfg.rope_interleaved``); full layers: NO positional term
+  (``cfg.full_attn_rope`` False; ``rope_tables`` holds no table for them);
+  every layer 16 query heads a KV head at the published widths;
+- ``x ← x + Attn(h)·Wo + Experts(h) + Shared(h) / n``: ``n =
+  cfg.shared_experts_averaged`` shared experts side by side in one GLU of
+  ``shared_expert_size = n × width``, their outputs AVERAGED;
+- final LayerNorm; ``logits = x·Eᵀ`` over the embedding's rows (a TIED head:
+  the tree has no ``lm_head``; ``tf.lm_logits``).
+
 **The residual stream is float32** whatever the parameters' dtype
 (:func:`residual_stream`): the matmuls take the norms' outputs cast to the
 compute dtype, their results are added in float32, and the router reads
@@ -70,15 +87,20 @@ from deepspeed_tpu.ops import paged_attention as pa
 def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     """The parameter tree of a typed stack: ``embed``, ``layers`` (a LIST,
     one tree a layer: ``ln1``, ``attn`` {wq, wk, wv, wo, sink?} — a latent
-    layer's {wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo} —, ``ln2``,
-    and ``mlp`` {wg, wi, wo} or ``moe`` {router, router_bias?, wg, wi,
-    wo over the HELD experts} with, where the model has one, ``shared``
-    {wg, wi, wo}), ``final_norm``, ``lm_head``."""
-    if cfg.norm != "rmsnorm" or not cfg.is_glu or cfg.use_bias or \
-            cfg.pos_emb != "rope" or cfg.tie_embeddings:
+    layer's {wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo} —, ``ln2``
+    (a parallel block has none), and ``mlp`` {wg, wi, wo} or ``moe``
+    {router, router_bias?, wg, wi, wo over the HELD experts} with, where
+    the model has one, ``shared`` {wg, wi, wo}), ``final_norm``, and
+    ``lm_head`` unless the head is tied to ``embed``."""
+    if not cfg.is_glu or cfg.use_bias or cfg.ln_bias or \
+            cfg.pos_emb != "rope" or \
+            (cfg.parallel_block and cfg.parallel_block_norms != 1):
         raise NotImplementedError(
-            "typed layer stacks are built for bias-free RMSNorm / RoPE / "
-            "GLU decoders with an untied head (MiMo-V2)")
+            "typed layer stacks are built for bias-free GLU decoders with "
+            "rotary positions (or none on the full kind): RMSNorm or "
+            "LayerNorm, a sequential block or a parallel one under ONE "
+            "norm, a tied or an untied head (mimo_v2, deepseek_v3, "
+            "cohere2_moe)")
     d, v, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
     dk, dv, H = cfg.head_dim, cfg.v_dim, cfg.num_heads
     out_std = cfg.init_std / math.sqrt(2 * L)
@@ -109,8 +131,9 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
         if kind == 1 and cfg.window_sink:
             # not zero at init: a zero sink would make a test of it vacuous
             attn["sink"] = w((H,), 1.0)
-        lp = {"ln1": tf._norm_params(cfg), "attn": attn,
-              "ln2": tf._norm_params(cfg)}
+        lp = {"ln1": tf._norm_params(cfg), "attn": attn}
+        if not cfg.parallel_block:
+            lp["ln2"] = tf._norm_params(cfg)
         if cfg.layer_is_sparse(l):
             E, held, f = cfg.num_experts, cfg.num_held_experts, cfg.ffn_size
             moe = {"router": w((d, E)), "wg": w((held, d, f)),
@@ -127,23 +150,31 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
             lp["mlp"] = {"wg": w((d, f)), "wi": w((d, f)),
                          "wo": w((f, d), out_std)}
         layers.append(lp)
-    return {"embed": {"tokens": w((v, d))}, "layers": layers,
-            "final_norm": tf._norm_params(cfg), "lm_head": w((d, v))}
+    params = {"embed": {"tokens": w((v, d))}, "layers": layers,
+              "final_norm": tf._norm_params(cfg)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = w((d, v))
+    return params
 
 
 def rope_tables(cfg, positions: jax.Array) -> dict:
     """{kind: (sin, cos)} for the kinds the stack has: one table a rotary
-    base, computed once a step."""
-    return {kind: tf.rope_table(
-        dataclasses.replace(cfg, rope_theta=cfg.kind_rope_theta(kind)),
-        positions) for kind in sorted(set(cfg.layer_kinds))}
+    base, computed once a step; (None, None) for a kind without a
+    positional term (``cfg.full_attn_rope`` False)."""
+    tables = {}
+    for kind in sorted(set(cfg.layer_kinds)):
+        theta = cfg.kind_rope_theta(kind)
+        tables[kind] = (None, None) if theta is None else tf.rope_table(
+            dataclasses.replace(cfg, rope_theta=theta), positions)
+    return tables
 
 
 @jax.named_scope("attn_qkv")
 def typed_qkv(cfg, kind: int, p, x: jax.Array, sin, cos
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x [B, t, D] → q [B, t, H, Dk], k [B, t, KV_kind, Dk],
-    v [B, t, KV_kind, Dv] (scaled), RoPE applied to q and k."""
+    v [B, t, KV_kind, Dv] (scaled), RoPE applied to q and k (``sin`` None:
+    the kind has no positional term)."""
     b, t = x.shape[:2]
     kvh = cfg.kind_kv_heads(kind)
     q = tf.linear_2d(x, p, "wq").reshape(b, t, cfg.num_heads, cfg.head_dim)
@@ -151,7 +182,10 @@ def typed_qkv(cfg, kind: int, p, x: jax.Array, sin, cos
     v = tf.linear_2d(x, p, "wv").reshape(b, t, kvh, cfg.v_dim)
     if cfg.value_scale != 1.0:
         v = (v * cfg.value_scale).astype(v.dtype)
-    return tf.apply_rope(q, sin, cos), tf.apply_rope(k, sin, cos), v
+    if sin is None:
+        return q, k, v
+    return tf.apply_rope(q, sin, cos, cfg.rope_interleaved), \
+        tf.apply_rope(k, sin, cos, cfg.rope_interleaved), v
 
 
 @jax.named_scope("attn_qkv")
@@ -255,8 +289,26 @@ def typed_ffn(cfg, lp, h: jax.Array, moe_fn: Optional[Callable],
         return out
     with jax.named_scope("moe_shared"):
         hs = h.astype(dtype or h.dtype)
-        return out + moe._shared_expert(
+        shared = moe._shared_expert(
             lp["shared"], hs.reshape(-1, hs.shape[-1])).reshape(hs.shape)
+        if cfg.shared_experts_averaged > 1:
+            return out + shared.astype(jnp.float32) * \
+                (1.0 / cfg.shared_experts_averaged)
+        return out + shared
+
+
+def block_residual(cfg, lp, x: jax.Array, h: jax.Array, attn_out: jax.Array,
+                   moe_fn: Optional[Callable], valid, dtype) -> jax.Array:
+    """The float32 stream after a layer, given ``h`` (the layer's first
+    norm of ``x``, float32: what attention read) and the attention
+    branch's output: sequential (``x + a``, then the feed-forward on
+    ``norm2`` of that) or PARALLEL (``cfg.parallel_block``: the
+    feed-forward reads the SAME ``h``, and both are added)."""
+    if cfg.parallel_block:
+        return x + attn_out + typed_ffn(cfg, lp, h, moe_fn, valid, dtype)
+    x = x + attn_out
+    return x + typed_ffn(cfg, lp, tf._norm(cfg, lp["ln2"], x), moe_fn, valid,
+                         dtype)
 
 
 def _attention(cfg, kind: int, sink, q, k, v) -> jax.Array:
@@ -282,7 +334,8 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
         tf.embed_tokens(cfg, params["embed"], tokens, positions))
     tables = rope_tables(cfg, positions)
     for kind, lp in zip(cfg.layer_kinds, params["layers"]):
-        h = tf._norm(cfg, lp["ln1"], x).astype(dtype)
+        h32 = tf._norm(cfg, lp["ln1"], x)
+        h = h32.astype(dtype)
         if kind == 2:       # the expanded form: nothing is cached here
             q, k, v = latent_expand_kv(cfg, lp["attn"], *latent_qkv(
                 cfg, lp["attn"], h, *tables[kind]))
@@ -290,7 +343,7 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
             q, k, v = typed_qkv(cfg, kind, lp["attn"], h, *tables[kind])
         with jax.named_scope("attn_core"):
             o = _attention(cfg, kind, lp["attn"].get("sink"), q, k, v)
-        x = x + typed_attn_out(cfg, lp["attn"], o)
-        x = x + typed_ffn(cfg, lp, tf._norm(cfg, lp["ln2"], x), moe_fn,
-                          dtype=dtype)
+        x = block_residual(cfg, lp, x, h32,
+                           typed_attn_out(cfg, lp["attn"], o), moe_fn, None,
+                           dtype)
     return tf._norm(cfg, params["final_norm"], x).astype(dtype)
