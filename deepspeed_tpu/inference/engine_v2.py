@@ -244,8 +244,10 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     the carry aliases (no arena-shaped copy in any step program:
     docs/kernels.md, tests/test_tpu_compile.py).
     The history reader follows ``use_pallas``: the paged kernel
-    (:func:`paged_attention_with_lse`, ``counts = 0``) walks only each
-    row's ``ceil(start / block_size)`` live pages; the XLA gather
+    (:func:`paged_attention_with_lse`, ``counts = 0``, ``qcounts`` = the
+    step's counts: a decode row riding along computes one small tile of
+    its query block) walks only each row's ``ceil(start / block_size)``
+    live pages; the XLA gather
     (:func:`paged_attention_hist_xla`, CPU or a head size the kernel
     refuses) reads the page table's whole width.
     """
@@ -318,7 +320,8 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
                 with jax.named_scope("attn_history"):
                     if use_pallas:
                         out_h, lse_h = pa.paged_attention_with_lse(
-                            q, ak, av, pt_l, starts, jnp.zeros_like(starts))
+                            q, ak, av, pt_l, starts, jnp.zeros_like(starts),
+                            qcounts=counts)
                     else:
                         out_h, lse_h = pa.paged_attention_hist_xla(
                             q, ak, av, pt_l, starts)
@@ -488,7 +491,8 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                 if use_pallas:
                     out_h, lse_h = pa.paged_attention_with_lse(
                         q, pools[kname], pools[vname], pt_l, starts,
-                        jnp.zeros_like(starts), window=window, scale=scale)
+                        jnp.zeros_like(starts), window=window, scale=scale,
+                        qcounts=counts)
                 else:
                     out_h, lse_h = pa.paged_attention_hist_xla(
                         q, pools[kname], pools[vname], pt_l, starts,
@@ -1334,20 +1338,21 @@ class RaggedInferenceEngineTPU:
         from deepspeed_tpu.telemetry.tracer import tracer
         with tracer.span("serving/pack"):
             packed = jnp.asarray(self._pack(batch, nb, cb))  # ONE upload
-        context_slots = None
+        context_slots = query_tiles = None
         if fresh == "split" and self.use_pallas:
             # the paged reader walks each row's live pages, then the
             # chunk attends its own keys
             bs = self.config.block_size
             context_slots = nb * cb + \
                 int((-(-batch.start_positions // bs)).sum()) * bs
+            query_tiles = self._query_tiles(batch, cb)
         write_block = _write_back_slots(capacities, nb * cb)[0]
         work = self._count_dispatch(
             _step_kind(cb, fresh), n, nb, cb, self.mb, tokens,
             int((batch.start_positions + batch.token_counts).sum()),
             context_slots=context_slots,
             kv_window=self._kv_window_tokens(batch),
-            attn_pairs=self._attn_pairs(batch),
+            attn_pairs=self._attn_pairs(batch), query_tiles=query_tiles,
             # the device's own rules (_at_capacity: the smallest that
             # holds; _write_back: whole blocks until the tokens are written)
             token_slots=next((t for t in capacities if tokens <= t), None),
@@ -1375,6 +1380,26 @@ class RaggedInferenceEngineTPU:
         live = np.minimum(held, model.sliding_window +
                           batch.token_counts - 1)
         return int(live.sum()), int(held.sum())
+
+    def _query_tiles(self, batch: RaggedBatch, chunk: int):
+        """(held, computed) query tiles of a split launch's history reader
+        in ONE layer and KV head, or None for a latent stack (another
+        kernel): of the rows that reach ``paged_attn_lse`` with a history
+        and a token, the tiles of ``TILE_Q`` queries their blocks hold
+        (``chunk / TILE_Q`` a row: what the kernel computed before it took
+        ``qcounts``), and the tiles it computes — ONE for a row whose live
+        queries fit the small tile (a decode row), all of them for a row of
+        more (``paged_attention._paged_kernel``). ``TILE_Q`` is the full
+        kind's (``paged_attention.tile_queries``). Host arithmetic on the
+        batch's lengths."""
+        model = self.model_config
+        if model.latent:
+            return None
+        tile_q = pa.tile_queries(chunk, model.num_heads // model.kv_heads)
+        fed = batch.token_counts[(batch.start_positions > 0) &
+                                 (batch.token_counts > 0)]
+        whole = chunk // tile_q
+        return len(fed) * whole, int(np.where(fed <= tile_q, 1, whole).sum())
 
     def _attn_pairs(self, batch: RaggedBatch):
         """Live (query, key) pairs of the launch in ONE layer of each
@@ -1410,7 +1435,7 @@ class RaggedInferenceEngineTPU:
                         page_width: int, tokens: int, context_tokens: int,
                         scan_steps: int = 1,
                         context_slots: Optional[int] = None,
-                        kv_window=None, attn_pairs=None,
+                        kv_window=None, attn_pairs=None, query_tiles=None,
                         token_slots: Optional[int] = None,
                         kv_write_slots: Optional[int] = None
                         ) -> Dict[str, Any]:
@@ -1437,7 +1462,12 @@ class RaggedInferenceEngineTPU:
         span argument of each of its names (no counter: its one reader
         takes the traced launches' spans). A latent stack's span carries
         ``kv_tokens_latent``: the cached rows ONE latent layer holds for
-        the batch's rows after the launch (``context_tokens``)."""
+        the batch's rows after the launch (``context_tokens``).
+        ``query_tiles`` (:meth:`_query_tiles`: a split launch under the
+        paged kernel) adds ``dispatch/query_tiles`` /
+        ``dispatch/query_tiles_live`` — the query tiles the history
+        reader's rows hold and those it computes — and the span's
+        arguments of those names."""
         from deepspeed_tpu.telemetry.registry import registry
         row_slots = nb * chunk * scan_steps
         slots = row_slots if token_slots is None else token_slots
@@ -1468,6 +1498,10 @@ class RaggedInferenceEngineTPU:
                         kv_tokens_window_held=held)
         if attn_pairs is not None:
             work.update(attn_pairs)
+        if query_tiles is not None:
+            work["query_tiles"], work["query_tiles_live"] = query_tiles
+            for name in ("query_tiles", "query_tiles_live"):
+                registry.counter("dispatch/" + name).inc(work[name])
         if self.model_config.latent:
             work["kv_tokens_latent"] = context_tokens
         return work

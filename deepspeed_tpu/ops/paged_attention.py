@@ -420,11 +420,26 @@ def causal_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
 # Pallas kernel (decode / short-chunk path)
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
-                  o_ref, *rest, block_size: int,
-                  chunk: int, scale: float, mb: int,
-                  with_lse: bool = False,
-                  window: Optional[int] = None):
+def tile_queries(c: int, groups: int) -> int:
+    """``TILE_Q``: the queries of a row's SMALL tile — what a row of at most
+    that many live queries (a decode row riding in a chunk-wide step: one)
+    computes a page turn in place of its whole ``groups * c`` block. The
+    fewest queries, a power of two, whose ``TILE_Q * groups`` matmul rows
+    fill whole bf16 sublane tiles (16 rows: 4 queries at 4 queries a KV
+    head, 2 at 8, 1 at 16): a turn's cost grows with the tile's rows (the
+    table of :func:`_paged_kernel`), and the rows a chunk-wide step carries
+    beside its prompt chunks hold ONE live query. The whole chunk where no
+    such tile divides it."""
+    t = 1
+    while t < c and (c % t or (t * groups) % 16):
+        t *= 2
+    return t if c % t == 0 else c
+
+
+def _paged_kernel(pt_ref, starts_ref, counts_ref, qcounts_ref, q_ref, k_hbm,
+                  v_hbm, o_ref, *rest, block_size: int, groups: int,
+                  tile_q: int, scale: float, mb: int,
+                  with_lse: bool = False, window: Optional[int] = None):
     """Grid (n_seq, kvh): ONE program per (sequence, kv head) that walks
     this sequence's pages with double-buffered manual DMAs from the
     HBM-resident arena.
@@ -435,28 +450,74 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
     DMA in flight while the current one computes — the reference
     blocked_flash/paged-KV structure.
 
-    q_ref block: [1, 1, rows, dh] (row = g*chunk + j); k_hbm/v_hbm: the
+    q_ref block: [1, 1, rows, dh], QUERY-MAJOR (row = j * groups + g: query
+    j of the chunk, head g of the KV head's group), so a row's live queries
+    ``j < qcounts[s]`` are the LEADING rows of its block; k_hbm/v_hbm: the
     FULL arena [NB, bs, kvh * d] left in ANY/HBM memory space (a token's
     heads side by side, the layout a row scatter writes without a
     relayout), of which head ``kh``'s page is the lane slice ``[kh * d,
     (kh + 1) * d)``; k_buf/v_buf: [2, bs, d] VMEM double buffers. With
-    ``with_lse`` an extra [1, 1, rows] f32 output carries each row's
+    ``with_lse`` an extra [1, 1, rows, 1] f32 output carries each row's
     logsumexp (the partial-attention merge needs it — fused decode's
     history part).
     K and V may differ in width (k_buf [2, bs, dk], v_buf [2, bs, dv]; the
     output is dv wide). ``window`` (static): key j is visible to query i
     only when ``i - j < window``, and the walk STARTS at the page that
     holds the lowest key any query of the row can see.
-    """
+
+    The work follows the row's LIVE queries, and a page is fetched once
+    either way: a row of at most ``tile_q`` live queries walks its pages
+    with ONE tile of ``tile_q * groups`` matmul rows (scores, mask, softmax
+    and both matmuls over those rows alone), a row of more with its whole
+    block — two instances of one walk, of which a program runs one. Every
+    query past the live ones, and every query of a row with no live query
+    or no visible page, gets zeros and an lse of -1e30 (a weight of 0 in
+    :func:`merge_attention`).
+
+    On a v5e (``tools/bench_paged_hist.py``: the kernel alone with the
+    wrapper's reshapes, seed 3800000011; my chip runs, PR 38), us a PAGE
+    TURN (ms a call), the kernel before ``qcounts`` → this one, small tile
+    16 matmul rows at every shape:
+
+    - Mistral-7B (64 rows, 32 / 8 heads of 128, contexts 128–2,500, a block
+      of 512 rows). One live query a row: 1.06 → 0.65 (5.27 → 3.26); 61
+      rows of one + 3 of 128: 1.06 → 0.66 (5.26 → 3.30); every row all 128
+      live: 1.09 → 1.12 (4.88 → 5.02).
+    - MiMo-V2.5's window-128 layer (64 rows, 8 KV heads, K 256 / V 128
+      lanes, 1,024 rows; 1–2 pages a row, so a call is its blocks). One:
+      5.32 → 3.94 (4.73 → 3.50); all: 9.96 → 10.1 (3.83 → 3.89). Its full
+      layer (4 KV heads, 2,048 rows). One: 6.69 → 4.06 (5.51 → 3.34); all:
+      8.26 → 8.27 (4.72 → 4.73).
+    - Command A+'s window-4,096 layer (16 rows, 128 / 8 heads of 128,
+      contexts 2.5K–10K, 2,048 rows). One: 2.58 → 0.64 (10.4 → 2.57); 12
+      rows of one + 4 of 128: 2.58 → 1.07 (10.3 → 4.28); all: 2.59 → 2.54
+      (10.1 → 9.88). Its full layer. One: 2.46 → 0.55 (16.1 → 3.59); 12 +
+      4: 2.46 → 0.84 (16.0 → 5.43); all: 2.47 → 2.44 (15.8 → 15.6).
+    - The decode programs' ``paged_attn`` (c = 1, its block is one tile):
+      0.46 → 0.46 (2.32 → 2.32 at the first shape).
+
+    With NO live query in any row (nothing walked: the grid, the q / out /
+    lse blocks, the reshapes) a call is 0.90 / 2.76 / 2.82 / 0.88 / 0.88
+    ms at the five shapes: what is left of a one-live-query call is the
+    page fetch and those blocks. A one-live turn by the small tile's rows:
+    0.55 / 0.56 / 0.57 / 0.59 / 0.69 / 0.94 at 16 / 32 / 64 / 128 / 256 /
+    512 (Command A+ full), 0.65 at 4–16 and 0.66 / 0.68 / 0.71 at 32 / 64 /
+    128 (Mistral). Measured and NOT built: an inner loop over the live
+    tiles, ``acc, m, l`` in VMEM scratch, takes a fully live row 2.0–6.3
+    times the whole block's turn (6.88 us at 32-row tiles and 2.18 at 128
+    for Mistral's 1.09; 7.4–8.1 at 128-row tiles and 4.9–5.0 at 512 for
+    Command A+'s 2.5: every tile reloads the page into the MXU and waits
+    out both matmuls); a grid axis over tiles walks the pages once a
+    tile."""
+    rows = q_ref.shape[2]
     if with_lse:
-        lse_ref, k_buf, v_buf, sem_k, sem_v = rest
-    else:
-        k_buf, v_buf, sem_k, sem_v = rest
+        lse_ref, *rest = rest
+    k_buf, v_buf, sem_k, sem_v = rest
     s_idx = pl.program_id(0)
     kh = pl.program_id(1)
-    rows = q_ref.shape[2]
     start = starts_ref[s_idx]
     ctx = start + counts_ref[s_idx]
+    qcount = qcounts_ref[s_idx]
     npages = jnp.minimum(lax.div(ctx + block_size - 1,
                                  jnp.int32(block_size)), mb)
     if window is None:
@@ -465,6 +526,7 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
         first = lax.div(jnp.maximum(start - (window - 1), 0),
                         jnp.int32(block_size))
         first_slot = lax.rem(first, 2)
+    live = (npages > first) & (qcount > 0)
 
     def head_page(hbm, buf, page):
         width = buf.shape[-1]
@@ -477,10 +539,22 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
         pltpu.make_async_copy(head_page(v_hbm, v_buf, page), v_buf.at[slot],
                               sem_v.at[slot]).start()
 
-    @pl.when(npages > first)
-    def _run():
+    def write_dead(lo):
+        """Zeros and -1e30 for the block's rows from ``lo`` on."""
+        o_ref[0, 0, lo:, :] = jnp.zeros((rows - lo, o_ref.shape[3]),
+                                        o_ref.dtype)
+        if with_lse:
+            lse_ref[0, 0, lo:, :] = jnp.full((rows - lo, 1), _NEG_INF,
+                                             jnp.float32)
+
+    def walk(r):
+        """The block's first ``r`` matmul rows over the row's pages."""
         copy_in(first, first_slot)
-        q = q_ref[0, 0]                                     # [rows, dk]
+        q = q_ref[0, 0, :r, :]                              # [r, dk]
+        # the chunk offset of each matmul row's query
+        j = lax.div(lax.broadcasted_iota(jnp.int32, (r, 1), 0),
+                    jnp.int32(groups))
+        qpos = start + j
 
         def body(b, carry):
             acc, m_prev, l_prev = carry
@@ -498,52 +572,57 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, q_ref, k_hbm, v_hbm,
             v_blk = v_buf[slot]
             s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-            r = lax.broadcasted_iota(jnp.int32, (rows, block_size), 0)
-            j = lax.rem(r, chunk)                           # query offset
-            qpos = start + j
             kpos = b * block_size + \
-                lax.broadcasted_iota(jnp.int32, (rows, block_size), 1)
+                lax.broadcasted_iota(jnp.int32, (r, block_size), 1)
             visible = (kpos <= qpos) & (kpos < ctx)
             if window is not None:
                 visible = visible & (kpos > qpos - window)
             s = jnp.where(visible, s, _NEG_INF)
 
-            blk_max = jnp.max(s, axis=1)
-            m_new = jnp.maximum(m_prev, blk_max)
-            p = jnp.exp(s - m_new[:, None])
-            # float mask arithmetic, NOT a bool broadcast: Mosaic can't
-            # insert a minor dim on i1 vectors
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # float mask arithmetic: a row that has seen no key yet keeps
+            # p and its correction at exactly 0
             alive = (m_new > _NEG_INF / 2).astype(jnp.float32)
-            p = p * alive[:, None]
+            p = jnp.exp(s - m_new) * alive
             corr = jnp.exp(m_prev - m_new) * alive
-            acc = acc * corr[:, None] + lax.dot_general(
+            acc = acc * corr + lax.dot_general(
                 p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            l = l_prev * corr + jnp.sum(p, axis=1)
-            return acc, m_new, l
+            return acc, m_new, l_prev * corr + jnp.sum(p, axis=1,
+                                                       keepdims=True)
 
-        acc0 = jnp.zeros((rows, o_ref.shape[3]), jnp.float32)
-        m0 = jnp.full((rows,), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((rows,), jnp.float32)
-        acc, m, l = lax.fori_loop(first, npages, body, (acc0, m0, l0))
+        acc, m, l = lax.fori_loop(
+            first, npages, body,
+            (jnp.zeros((r, o_ref.shape[3]), jnp.float32),
+             jnp.full((r, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((r, 1), jnp.float32)))
         l = jnp.maximum(l, 1e-30)
-        o_ref[0, 0] = (acc / l[:, None]).astype(o_ref.dtype)
+        is_live = j < qcount
+        o_ref[0, 0, :r, :] = jnp.where(is_live, acc / l, 0.0) \
+            .astype(o_ref.dtype)
         if with_lse:
-            lse_ref[0, 0] = jnp.where(m > _NEG_INF / 2, m + jnp.log(l),
-                                      _NEG_INF)[:, None]
+            lse_ref[0, 0, :r, :] = jnp.where(
+                is_live & (m > _NEG_INF / 2), m + jnp.log(l), _NEG_INF)
+        if r < rows:
+            write_dead(r)
 
-    @pl.when(npages <= first)
-    def _empty():
-        o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
-        if with_lse:
-            lse_ref[0, 0] = jnp.full_like(lse_ref[0, 0], _NEG_INF)
+    whole = live
+    if tile_q * groups < rows:
+        pl.when(live & (qcount <= tile_q))(lambda: walk(tile_q * groups))
+        whole = live & (qcount > tile_q)
+    pl.when(whole)(lambda: walk(rows))
+    pl.when(jnp.logical_not(live))(lambda: write_dead(0))
 
 
 def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
-                with_lse: bool, interpret: bool, window=None, scale=None):
+                with_lse: bool, interpret: bool, window=None, scale=None,
+                qcounts=None, tile_q=None):
     """The ``pallas_call`` of both wrappers below → (out [n, c, h, dv],
     lse [n, c, h] fp32 or None). The kernel's name in a device trace is
-    ``paged_attn_lse`` with the logsumexp output, ``paged_attn`` without."""
+    ``paged_attn_lse`` with the logsumexp output, ``paged_attn`` without.
+    ``qcounts`` [n]: each row's live queries (None: all ``c``);
+    ``tile_q``: another small tile than :func:`tile_queries`', for
+    ``tools/bench_paged_hist.py``'s sweep alone."""
     bs, lanes = arena_k.shape[1:]
     n, c, h, dh = q.shape
     kvh = lanes // dh
@@ -551,17 +630,20 @@ def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
     groups = h // kvh
     mb = page_table.shape[1]
     rows = groups * c
+    if qcounts is None:
+        qcounts = jnp.full((n,), c, jnp.int32)
 
-    # [n, c, kvh, g, dh] → [n, kvh, g*c, dh] with row index = g*c + j
-    qk = q.reshape(n, c, kvh, groups, dh).transpose(0, 2, 3, 1, 4) \
+    # [n, c, kvh, g, dh] → [n, kvh, c*g, dh], row index = j*groups + g
+    qk = q.reshape(n, c, kvh, groups, dh).transpose(0, 2, 1, 3, 4) \
         .reshape(n, kvh, rows, dh)
 
     def rows_of(width):
         return pl.BlockSpec((1, 1, rows, width),
-                            lambda s, kh, pt, st, ct: (s, kh, 0, 0))
+                            lambda s, kh, pt, st, ct, qc: (s, kh, 0, 0))
 
     kernel = functools.partial(
-        _paged_kernel, block_size=bs, chunk=c, mb=mb, with_lse=with_lse,
+        _paged_kernel, block_size=bs, groups=groups, mb=mb,
+        tile_q=tile_q or tile_queries(c, groups), with_lse=with_lse,
         window=window,
         scale=1.0 / math.sqrt(dh) if scale is None else scale)
     out_specs = [rows_of(dv)]
@@ -573,7 +655,7 @@ def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
     out, *lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(n, kvh),
             in_specs=[
                 rows_of(dh),
@@ -592,14 +674,15 @@ def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
         interpret=interpret,
         name="paged_attn_lse" if with_lse else "paged_attn",
     )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
-      counts.astype(jnp.int32), qk, arena_k, arena_v)
+      counts.astype(jnp.int32), qcounts.astype(jnp.int32), qk, arena_k,
+      arena_v)
 
-    # [n, kvh, g*c, dv] → [n, c, h, dv]
-    out = out.reshape(n, kvh, groups, c, dv).transpose(0, 3, 1, 2, 4) \
+    # [n, kvh, c*g, dv] → [n, c, h, dv]
+    out = out.reshape(n, kvh, c, groups, dv).transpose(0, 2, 1, 3, 4) \
         .reshape(n, c, h, dv)
     if not with_lse:
         return out, None
-    return out, lse[0].reshape(n, kvh, groups, c).transpose(0, 3, 1, 2) \
+    return out, lse[0].reshape(n, kvh, c, groups).transpose(0, 2, 1, 3) \
         .reshape(n, c, h)
 
 
@@ -623,7 +706,8 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
                              starts: jax.Array, counts: jax.Array, *,
                              interpret: bool = False,
                              window: Optional[int] = None,
-                             scale: Optional[float] = None):
+                             scale: Optional[float] = None,
+                             qcounts: Optional[jax.Array] = None):
     """Pallas paged attention returning (out, lse [n, c, h] fp32) for the
     partial-attention merge. ``counts=0`` gives HISTORY-only semantics
     (keys [0, starts)) — the fused decode loop's arena part, where the
@@ -632,10 +716,14 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
     wide as a V head); ``window``: key j is visible to query i only when
     ``i - j < window`` and the walk starts at the window's first page;
     ``scale``: the scores' factor, default ``dk ** -0.5`` (a caller that
-    zero-pads the heads passes the true width's)."""
+    zero-pads the heads passes the true width's). ``qcounts`` [n]: the
+    LIVE queries of each row, the leading ``qcounts[i]`` of its ``c``
+    (default: all ``c``); the kernel's work follows them
+    (:func:`_paged_kernel`), and a query past them gets zeros and an lse
+    of -1e30."""
     return _paged_call(q, arena_k, arena_v, page_table, starts, counts,
                        with_lse=True, interpret=interpret, window=window,
-                       scale=scale)
+                       scale=scale, qcounts=qcounts)
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,8 @@
 """Paged attention + ragged engine tests (reference:
 tests/unit/inference/v2/ragged/ + kernels/ragged_ops tests)."""
 
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -506,6 +508,74 @@ def test_paged_history_kernel_matches_xla_reader(hist_readers, row):
     assert np.isfinite(out[i]).all()
 
 
+@pytest.mark.parametrize("c, groups, want", [
+    (128, 4, 4), (128, 8, 2), (128, 16, 1),     # the three serving cells
+    (32, 4, 4), (96, 4, 4), (128, 1, 16), (128, 2, 8),
+    (1, 4, 1), (1, 16, 1), (8, 1, 8), (2, 4, 2)])   # one tile: the chunk
+def test_small_tile_is_16_matmul_rows_or_the_whole_chunk(c, groups, want):
+    """``TILE_Q`` is a function of the call's shapes: the fewest queries
+    whose matmul rows fill whole bf16 sublane tiles, and it divides the
+    chunk; where no such tile is smaller than the chunk the block is one
+    tile (every decode program's)."""
+    tile_q = pa.tile_queries(c, groups)
+    assert tile_q == want and c % tile_q == 0
+    assert tile_q == c or (tile_q * groups) % 16 == 0
+
+
+#: live queries of the rows of one batch, at a chunk of 32 (a small tile is
+#: 8 queries): a padded row, a decode row, a partial tile, a tile boundary,
+#: one query past it, the whole chunk, and a decode row with no history
+_LIVE_QUERIES = (0, 1, 5, 8, 9, 32, 1)
+
+
+@pytest.mark.parametrize("widths", ["k128_v128", "k256_v128"])
+@pytest.mark.parametrize("groups", [4, 8, 16])
+@pytest.mark.parametrize("window", [None, 40])
+def test_history_kernel_computes_the_live_queries(window, groups, widths):
+    """``paged_attention_with_lse(counts=0, qcounts=)`` against the XLA
+    history reader: a row's LIVE queries (its leading ``qcounts``) within
+    the readers' tolerance, every query past them exactly zero with an lse
+    of -1e30, and ``qcounts=None`` the same as ``qcounts = c``. Histories
+    of 0–90 tokens over pages of 16: a window of 40 starts inside them."""
+    dk, dv = (128, 128) if widths == "k128_v128" else (256, 128)
+    rng = np.random.default_rng(groups + dk)
+    kvh, bs, c, mb, nb = 2, 16, 32, 6, 40
+    qcounts = np.asarray(_LIVE_QUERIES, np.int32)
+    n = len(qcounts)
+    starts = np.asarray([50, 90, 33, 64, 17, 48, 0], np.int32)
+    pt = np.full((n, mb), nb, np.int32)
+    free = iter(rng.permutation(nb))                # pages out of order
+    for i in range(n):
+        for b in range(-(-starts[i] // bs)):
+            pt[i, b] = next(free)
+    ak = jnp.asarray(rng.standard_normal((nb + 1, bs, kvh * dk)), jnp.float32)
+    av = jnp.asarray(rng.standard_normal((nb + 1, bs, kvh * dv)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((n, c, kvh * groups, dk)),
+                    jnp.float32)
+    args = (q, ak, av, jnp.asarray(pt), jnp.asarray(starts))
+    kernel = functools.partial(
+        pa.paged_attention_with_lse, *args, jnp.zeros((n,), jnp.int32),
+        interpret=True, window=window, scale=0.1)
+    out, lse = (np.asarray(a) for a in kernel(qcounts=jnp.asarray(qcounts)))
+    out_x, lse_x = (np.asarray(a) for a in pa.paged_attention_hist_xla(
+        *args, window=window, scale=0.1))
+    live = np.arange(c)[None] < qcounts[:, None]                  # [n, c]
+    assert (out[~live] == 0).all() and (lse[~live] <= -1e29).all()
+    seen = live[..., None] & (lse_x > -1e29)     # ... and sees some key
+    assert seen[:6].any(axis=(1, 2)).tolist() == [False] + [True] * 5
+    np.testing.assert_array_equal(lse > -1e29, seen)
+    np.testing.assert_allclose(lse[seen], lse_x[seen], rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(out[seen], out_x[seen], rtol=2e-5, atol=2e-5)
+    assert np.isfinite(out).all()
+    whole = [np.asarray(a) for a in kernel()]
+    for a, b in zip(whole, kernel(qcounts=jnp.full((n,), c, jnp.int32))):
+        np.testing.assert_array_equal(a, np.asarray(b))
+    # a row computed whole and the same row computed by its live tiles
+    # agree bit for bit on the live queries
+    np.testing.assert_array_equal(whole[0][live], out[live])
+    np.testing.assert_array_equal(whole[1][live], lse[live])
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("reader", ["xla", "kernel"])
 def test_split_step_matches_in_loop_write(devices, monkeypatch, reader,
@@ -751,6 +821,71 @@ def test_packing_engine_serves_and_caches_what_a_row_form_engine_does(
         # the values are the two forms' own (a matmul over [1, 80] slots
         # against one over [4, 96]): equal to their rounding
         np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("stack", ["uniform", "typed"])
+def test_engine_serves_through_the_history_kernel_what_the_xla_reader_does(
+        devices, monkeypatch, stack):
+    """The engine with the paged kernels forced on (interpret mode) against
+    the engine on the XLA readers, float32: the split steps' history goes
+    through ``paged_attn_lse`` with each row's live-query count — prompt
+    chunks of 32 and of a partial tile beside decode rows of one — and the
+    greedy tokens and the pools come out the same."""
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg, params, _ = _packed_stack(stack)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in (100, 30, 70, 41)]
+    calls = []
+
+    def history_kernel(*args, **kw):
+        calls.append(kw.get("qcounts") is not None)
+        return kernel(*args, interpret=True, **kw)
+
+    kernel = pa.paged_attention_with_lse
+    monkeypatch.setattr(pa, "paged_attention_with_lse", history_kernel)
+    monkeypatch.setattr(pa, "paged_attention", functools.partial(
+        pa.paged_attention, interpret=True))
+
+    def serve(use_pallas):
+        eng = RaggedInferenceEngineTPU(
+            cfg, {"dtype": "float32", "max_sequences": 4, "num_blocks": 48,
+                  "block_size": 8, "max_seq_len": 128, "prefill_chunk": 32,
+                  "max_batch_tokens": 80, "use_pallas": use_pallas},
+            params=params)
+        eng.scheduler.put([0, 1, 2, 3], prompts)
+        tokens, programs = {uid: [] for uid in range(4)}, []
+        for _ in range(9):
+            out = eng.step_with_budget(budget=80)
+            programs.append(eng.last_program)
+            for uid, tok in out.items():
+                tokens[uid].append(int(tok))
+                eng.scheduler.put([uid], [[int(tok)]])
+        return eng, tokens, programs
+
+    from deepspeed_tpu import telemetry
+    tiles = lambda: [telemetry.registry.counter(
+        "dispatch/query_tiles" + kind).value for kind in ("", "_live")]
+    before = tiles()
+    xla, want, _ = serve(False)
+    assert not calls and tiles() == before     # the XLA reader: no tiles
+    got_eng, got, programs = serve(True)
+    assert calls and all(calls) and programs.count("split") >= 3
+    held, computed = (now - was for now, was in zip(tiles(), before))
+    # rows of one live query rode beside the chunks: one tile each
+    assert 0 < computed < held and held % (32 // pa.tile_queries(
+        32, cfg.num_heads // cfg.kv_heads)) == 0
+    assert got == want and all(len(t) >= 4 for t in got.values())
+    nb = xla.config.num_blocks
+    for name, pool in got_eng.arena.items():
+        kept = np.arange(pool.shape[0]) % (nb + 1) != nb
+        a, b = np.asarray(pool)[kept], np.asarray(xla.arena[name])[kept]
+        if a.shape != b.shape:      # the kernel's K pool: whole lane tiles
+            heads = cfg.kind_kv_heads(1 if name.endswith("_win") else 0)
+            a = a.reshape(*a.shape[:2], heads, -1)[..., :cfg.head_dim] \
+                .reshape(b.shape)
+        assert np.abs(a).max() > 0.01, name
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
 
 
 def test_capacities_the_rows_already_hold_are_refused(devices):

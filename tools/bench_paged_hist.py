@@ -1,0 +1,212 @@
+#!/usr/bin/env python3
+"""Microbenchmark of the split step's history kernel ALONE, at the serving
+cells' shapes: ``paged_attn_lse`` (``ops/paged_attention.py``) over a seeded
+arena, page table and batch of rows — ms a call and us a PAGE TURN (one
+page of one KV head of one row walked) by the rows' live queries.
+
+    chiprun --chips 1 -- python3 tools/bench_paged_hist.py \
+        --parent-file .parent_tree/deepspeed_tpu/ops/paged_attention.py --sweep
+
+``--parent-file``: another tree's ``paged_attention.py`` (``git archive
+<commit> | tar -x -C .parent_tree``), measured beside this tree's in the
+same process, same inputs; a module without ``qcounts`` is called without.
+``--sweep``: this tree's kernel at other small tiles (``_paged_call(tile_q=)``)
+beside the code's choice. ``--rehearse``: tiny shapes in interpret mode on
+the CPU, control flow only — no time it prints is a device's.
+
+Shapes (PERF.md §4): cell 2 ``mistral7b-l12-serve-chat-closed64`` (64 rows
+of chunk 128, 32 / 8 heads of 128, contexts 128–2,500), cell 4
+``mimo-v2.5-l7-e16-serve-reason-closed64`` (64 query heads, K 192 padded to
+256 lanes beside V 128; a window-128 layer of 8 KV heads, a full layer of
+4; contexts to 768), cell 6 ``command-a-plus-l4-e16-serve-rag-closed16`` (16
+rows, 128 / 8 heads of 128, window 4,096 and none, contexts 2.5K–10K).
+Mixes: ``cell`` = the live queries of the cell's split step (cell 2: 61
+decode rows of ONE live query + 3 rows of 128; cell 4: every row one; cell
+6: 12 of one + 4 of 128), ``one`` = every row one, ``all`` = every row all
+``c``, ``none`` = no row any (what the grid and the q / out / lse blocks
+cost with no page walked). One JSON object a line; the lines also go to
+``--out``."""
+
+import argparse
+import importlib.util
+import inspect
+import json
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu.ops import paged_attention as pa_here
+
+BS = 128
+#: name -> (rows, chunk, query heads, kv heads, K lanes a head, V lanes a
+#: head, true head width (the scale's), pages a row, arena pages, window,
+#: context range, rows that carry a whole live chunk in the cell's mix)
+SHAPES = {
+    "cell2": (64, 128, 32, 8, 128, 128, 128, 32, 512, None, (128, 2500), 3),
+    "cell4_window": (64, 128, 64, 8, 256, 128, 192, 8, 512, 128,
+                     (16, 768), 0),
+    "cell4_full": (64, 128, 64, 4, 256, 128, 192, 8, 512, None,
+                   (16, 768), 0),
+    "cell6_window": (16, 128, 128, 8, 128, 128, 128, 86, 1376, 4096,
+                     (2560, 10240), 4),
+    "cell6_full": (16, 128, 128, 8, 128, 128, 128, 86, 1376, None,
+                   (2560, 10240), 4),
+}
+TINY = {"tiny": (4, 16, 8, 2, 128, 128, 128, 4, 16, None, (8, 60), 1),
+        "tiny_window": (4, 16, 8, 2, 128, 128, 128, 4, 16, 24, (8, 60), 1)}
+SWEEP = (1, 2, 4, 8, 16, 32)
+
+
+def load(path):
+    spec = importlib.util.spec_from_file_location("pa_parent", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def inputs(shape, mix, seed, block=BS):
+    n, c, h, kvh, dk, dv, _, mb, pages, _, (lo, hi), chunk_rows = shape
+    rng = np.random.default_rng(seed)
+    live = {"cell": [c] * chunk_rows + [1] * (n - chunk_rows),
+            "one": [1] * n, "all": [c] * n, "none": [0] * n}[mix]
+    hi = min(hi, mb * block - c)
+    starts = rng.integers(lo, hi + 1, n)
+    # a row that feeds a whole chunk has fed whole chunks before it
+    starts = np.where(np.array(live) == c, starts // c * c, starts)
+    table = np.stack([rng.permutation(pages)[:mb] for _ in range(n)])
+    key = jax.random.PRNGKey(seed % (2 ** 31))
+    kq, kk, kv = jax.random.split(key, 3)
+    bf = jnp.bfloat16
+    return (jax.random.normal(kq, (n, c, h, dk), bf),
+            jax.random.normal(kk, (pages + 1, block, kvh * dk), bf),
+            jax.random.normal(kv, (pages + 1, block, kvh * dv), bf),
+            jnp.asarray(table, jnp.int32), jnp.asarray(starts, jnp.int32),
+            jnp.asarray(live, jnp.int32))
+
+
+def turns(shape, starts, block=BS):
+    """Page turns of one call: every row's visible pages x KV heads."""
+    kvh, window = shape[3], shape[9]
+    last = -(-starts // block)
+    first = 0 if window is None else np.maximum(starts - (window - 1),
+                                                0) // block
+    return int((last - first).sum()) * kvh
+
+
+def timed(fn, args, reps, rounds):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    took = []
+    for _ in range(rounds):
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        took.append((time.perf_counter() - t0) / reps)
+    return statistics.median(took), out
+
+
+def reader(mod, shape, interpret, tile_q=None):
+    window, scale = shape[9], shape[6] ** -0.5
+    if "qcounts" not in inspect.signature(
+            mod.paged_attention_with_lse).parameters:
+        return jax.jit(lambda q, ak, av, pt, st, qc:
+                       mod.paged_attention_with_lse(
+                           q, ak, av, pt, st, jnp.zeros_like(st),
+                           interpret=interpret, window=window, scale=scale))
+    return jax.jit(lambda q, ak, av, pt, st, qc: mod._paged_call(
+        q, ak, av, pt, st, jnp.zeros_like(st), with_lse=True,
+        interpret=interpret, window=window, scale=scale, qcounts=qc,
+        tile_q=tile_q))
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--parent-file")
+    ap.add_argument("--sweep", action="store_true")
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--seed", type=int, default=3800000011)
+    ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--rounds", type=int, default=5)
+    ap.add_argument("--shapes", default="")
+    ap.add_argument("--out", default="chiprun_out/bench_paged_hist.jsonl")
+    a = ap.parse_args()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not a.rehearse:
+        sys.exit("no TPU here: --rehearse runs the control flow on the CPU")
+    shapes = TINY if a.rehearse else SHAPES
+    if a.shapes:
+        shapes = {k: shapes[k] for k in a.shapes.split(",")}
+    block = 16 if a.rehearse else BS
+    mods = [("change", pa_here, None)]
+    if a.parent_file:
+        mods.insert(0, ("parent", load(a.parent_file), None))
+    if a.sweep:
+        mods += [(f"tile_q{t}", pa_here, t) for t in SWEEP]
+    os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
+    lines = []
+
+    def say(**line):
+        line["device"] = {"platform": dev.platform, "kind": dev.device_kind}
+        lines.append(line)
+        print(json.dumps(line), flush=True)
+
+    for name, shape in shapes.items():
+        c, h, kvh = shape[1:4]
+        for mix in ("cell", "one", "all", "none"):
+            if mix == "cell" and shape[11] == 0:
+                continue                    # the cell's mix IS "one"
+            args = inputs(shape, mix, a.seed, block)
+            work = 0 if mix == "none" else \
+                turns(shape, np.asarray(args[4]), block)
+            base = None
+            for label, mod, tile_q in mods:
+                if tile_q and (c % tile_q or mix in ("all", "none")):
+                    continue        # those mixes never take the small tile
+                try:
+                    sec, (out, lse) = timed(
+                        reader(mod, shape, a.rehearse, tile_q), args,
+                        a.reps, a.rounds)
+                except Exception as e:              # noqa: BLE001
+                    say(shape=name, mix=mix, kernel=label,
+                        error=str(e)[:300])
+                    continue
+                tile_q = tile_q or pa_here.tile_queries(c, h // kvh)
+                # the live queries' out and lse (an empty history's -1e30
+                # clipped) agree with the first kernel measured
+                live = np.arange(c)[None] < np.asarray(args[5])[:, None]
+                got = np.concatenate(
+                    [np.asarray(out, np.float32)[live].ravel(),
+                     np.maximum(np.asarray(lse), -99.0)[live].ravel()])
+                base = got if base is None else base
+                diff = float(np.abs(got - base).max(initial=0))
+                say(shape=name, mix=mix, kernel=label, ms_a_call=sec * 1e3,
+                    page_turns=work,
+                    us_a_turn=sec * 1e6 / work if work else None,
+                    tile_rows=tile_q * (h // kvh), max_diff_live=diff)
+    if not a.rehearse:
+        # the decode programs' reader: the same kernel at c = 1
+        shape = SHAPES["cell2"]
+        q, ak, av, pt, st, _ = inputs(shape, "one", a.seed)
+        one = jnp.ones_like(st)
+        for label, mod, tile_q in mods:
+            if tile_q:
+                continue
+            sec, _ = timed(jax.jit(mod.paged_attention),
+                           (q[:, :1], ak, av, pt, st, one), a.reps, a.rounds)
+            work = int((-(-(np.asarray(st) + 1) // BS)).sum()) * shape[3]
+            say(shape="cell2_decode_c1", kernel=label, ms_a_call=sec * 1e3,
+                page_turns=work, us_a_turn=sec * 1e6 / work)
+    with open(a.out, "w") as f:
+        f.write("".join(json.dumps(l) + "\n" for l in lines))
+
+
+if __name__ == "__main__":
+    main()
