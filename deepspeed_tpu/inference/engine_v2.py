@@ -15,6 +15,7 @@ bucketed (batch rows to powers of two, chunk width to {1, prefill_chunk})
 so jit traces a handful of programs, not one per batch composition.
 """
 
+import time
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple, Union
 
@@ -850,6 +851,15 @@ class RaggedInferenceEngineTPU:
         #: kind of the last device program launched (``_step_kind`` or
         #: ``megastep``): the ``program`` of ``serving/engine_step``
         self.last_program: Optional[str] = None
+        #: when the last launch's ``device_get`` returned (``perf_counter``);
+        #: None once the scheduler has had nothing to run, so that an idle
+        #: server's waiting is not counted as host time (:meth:`_fetch`)
+        self._fetch_returned: Optional[float] = None
+        # the process-wide span tracer, looked up once and not once a
+        # span (here and not at import: telemetry pulls in the whole
+        # diagnostics stack, which must not load at engine-import time)
+        from deepspeed_tpu.telemetry.tracer import tracer
+        self._tracer = tracer
         self._rng_dev = rng          # defaulted to PRNGKey(0) above
         self._temperature = 1.0      # dynamic sampling scalars, packed
         self._top_p = 1.0            # into the step upload
@@ -1046,8 +1056,11 @@ class RaggedInferenceEngineTPU:
         lists still returned when ``max_steps > 1`` was requested, so
         callers see ONE shape).
         """
-        batch = self.scheduler.next_batch(budget=budget)
+        tracer = self._tracer
+        with tracer.span("serving/schedule"):
+            batch = self.scheduler.next_batch(budget=budget)
         if batch is None:
+            self._fetch_returned = None
             return None
         megastep = max_steps > 1 and mode is not None
         if megastep:
@@ -1056,14 +1069,16 @@ class RaggedInferenceEngineTPU:
             if out is not None:
                 return out
         res = self._run(batch, mode=mode)
-        self.scheduler.mark_scheduled(batch)
-        out = {}
-        for i, uid in enumerate(batch.uids):
-            if self.state.seqs[uid].pending == 0:
-                if mode is None:
-                    out[uid] = res[i]
-                else:
-                    out[uid] = [int(res[i])] if megastep else int(res[i])
+        with tracer.span("serving/retire"):
+            self.scheduler.mark_scheduled(batch)
+            out = {}
+            for i, uid in enumerate(batch.uids):
+                if self.state.seqs[uid].pending == 0:
+                    if mode is None:
+                        out[uid] = res[i]
+                    else:
+                        out[uid] = [int(res[i])] if megastep \
+                            else int(res[i])
         return out
 
     def _try_megastep(self, batch: RaggedBatch, k: int, mode,
@@ -1080,70 +1095,72 @@ class RaggedInferenceEngineTPU:
         extended the descriptor by the fed token before scheduling), the
         window has at least two steps, and the arena has its pages.
         """
-        n = len(batch.uids)
-        if n == 0 or batch.token_ids.shape[1] != 1 or \
-                self.model_config.typed:
-            # (a typed layer stack has no fused decode loop yet: its
-            # decode-only selections take the stepwise program)
-            return None
-        for i, uid in enumerate(batch.uids):
-            if int(batch.token_counts[i]) != 1 or \
-                    self.state.seqs[uid].pending != 1:
-                return None
-        # per-row window: requested k, clipped by the row's remaining
-        # token budget and by max_seq_len headroom (len(tokens) already
-        # counts the fed token, and a continuing row feeds one more)
-        lim: List[int] = []
-        for uid in batch.uids:
-            seq = self.state.seqs[uid]
-            r = k
-            if row_limits is not None and uid in row_limits:
-                r = min(r, int(row_limits[uid]))
-            r = min(r, self.config.max_seq_len - len(seq.tokens))
-            if r < 1:
-                return None
-            lim.append(r)
-        limit = max(lim)
-        if limit < 2:
-            return None              # degenerate megastep — stepwise wins
-        bs = self.state.allocator.block_size
-        need: List[int] = []
-        for uid, r in zip(batch.uids, lim):
-            seq = self.state.seqs[uid]
-            # KV high-water mark: seen_tokens rows exist, the window adds
-            # up to r more (fed token + r-1 continuation feeds)
-            need.append(-(-(seq.seen_tokens + r) // bs) - len(seq.blocks))
-        if sum(need) > self.state.allocator.free_blocks:
-            return None
-        for uid, c in zip(batch.uids, need):
-            if c > 0:
-                self.state.seqs[uid].blocks.extend(
-                    self.state.allocator.allocate(c))
-
-        nb = _bucket(n)
-        # pow2 scan buckets: the rng splits once per scan slot incl. dead
-        # ones, so aligned pow2 windows keep sampled streams identical
-        # across K choices
-        sb = _bucket(limit)
-        tokens0 = np.zeros((nb,), np.int32)
-        starts0 = np.zeros((nb,), np.int32)
-        live = np.zeros((nb,), np.int32)
-        bud = np.zeros((nb,), np.int32)
-        eos = np.full((nb,), -1, np.int32)
-        for i, uid in enumerate(batch.uids):
-            seq = self.state.seqs[uid]
-            tokens0[i] = seq.tokens[-1]
-            starts0[i] = seq.seen_tokens
-            live[i] = 1
-            bud[i] = lim[i]
-            if eos_ids is not None and eos_ids.get(uid) is not None:
-                eos[i] = int(eos_ids[uid])
-        pt = self._page_table(batch.uids, nb)
-        mb_need = int(-(-(int(starts0.max()) + limit) // bs))
-        mb_b = min(self.mb, -(-mb_need // 4) * 4)
-        pt = pt[:, :mb_b]
-        from deepspeed_tpu.telemetry.tracer import tracer
+        tracer = self._tracer
+        # the whole way to the launch is serving/pack: whether the window
+        # applies, its pages, the arrays and their upload
         with tracer.span("serving/pack"):
+            n = len(batch.uids)
+            if n == 0 or batch.token_ids.shape[1] != 1 or \
+                    self.model_config.typed:
+                # (a typed layer stack has no fused decode loop yet: its
+                # decode-only selections take the stepwise program)
+                return None
+            for i, uid in enumerate(batch.uids):
+                if int(batch.token_counts[i]) != 1 or \
+                        self.state.seqs[uid].pending != 1:
+                    return None
+            # per-row window: requested k, clipped by the row's remaining
+            # token budget and by max_seq_len headroom (len(tokens) already
+            # counts the fed token, and a continuing row feeds one more)
+            lim: List[int] = []
+            for uid in batch.uids:
+                seq = self.state.seqs[uid]
+                r = k
+                if row_limits is not None and uid in row_limits:
+                    r = min(r, int(row_limits[uid]))
+                r = min(r, self.config.max_seq_len - len(seq.tokens))
+                if r < 1:
+                    return None
+                lim.append(r)
+            limit = max(lim)
+            if limit < 2:
+                return None          # degenerate megastep — stepwise wins
+            bs = self.state.allocator.block_size
+            need: List[int] = []
+            for uid, r in zip(batch.uids, lim):
+                seq = self.state.seqs[uid]
+                # KV high-water mark: seen_tokens rows exist, the window adds
+                # up to r more (fed token + r-1 continuation feeds)
+                need.append(-(-(seq.seen_tokens + r) // bs) - len(seq.blocks))
+            if sum(need) > self.state.allocator.free_blocks:
+                return None
+            for uid, c in zip(batch.uids, need):
+                if c > 0:
+                    self.state.seqs[uid].blocks.extend(
+                        self.state.allocator.allocate(c))
+
+            nb = _bucket(n)
+            # pow2 scan buckets: the rng splits once per scan slot incl. dead
+            # ones, so aligned pow2 windows keep sampled streams identical
+            # across K choices
+            sb = _bucket(limit)
+            tokens0 = np.zeros((nb,), np.int32)
+            starts0 = np.zeros((nb,), np.int32)
+            live = np.zeros((nb,), np.int32)
+            bud = np.zeros((nb,), np.int32)
+            eos = np.full((nb,), -1, np.int32)
+            for i, uid in enumerate(batch.uids):
+                seq = self.state.seqs[uid]
+                tokens0[i] = seq.tokens[-1]
+                starts0[i] = seq.seen_tokens
+                live[i] = 1
+                bud[i] = lim[i]
+                if eos_ids is not None and eos_ids.get(uid) is not None:
+                    eos[i] = int(eos_ids[uid])
+            pt = self._page_table(batch.uids, nb)
+            mb_need = int(-(-(int(starts0.max()) + limit) // bs))
+            mb_b = min(self.mb, -(-mb_need // 4) * 4)
+            pt = pt[:, :mb_b]
             args = (jnp.asarray(tokens0), jnp.asarray(starts0),
                     jnp.asarray(live), jnp.asarray(pt), jnp.int32(limit),
                     jnp.asarray(bud), jnp.asarray(eos),
@@ -1154,43 +1171,65 @@ class RaggedInferenceEngineTPU:
             ys, counts, self._rng_dev, self.arena = self._fused_decode_fn(
                 nb, sb, mode, mb_b)(
                     self.params, self.arena, *args, self._rng_dev)
-        with tracer.span("serving/fetch"):
-            ys, counts = jax.device_get((ys, counts))   # ONE sync for K
-        ys = np.asarray(ys)
-        counts = np.asarray(counts)
-        # what the launch did is known only now: a row emitted counts[j]
-        # tokens, its i-th one attending starts0[j] + i + 1 cached tokens;
-        # the program ran sb scan steps over nb rows, each attending the
-        # whole sliced page table
-        c64 = counts[:n].astype(np.int64)
-        work = self._count_dispatch(
-            "megastep", n, nb, 1, mb_b, int(c64.sum()),
-            int((c64 * starts0[:n] + c64 * (c64 + 1) // 2).sum()),
-            scan_steps=sb)
-        if sp is not None:      # still the recorded event's arguments
-            sp.update(work)
-        _dispatch_count("dispatch/scan_steps", sb)
-        _dispatch_count("dispatch/dead_steps", sb - limit)
-        _dispatch_count("dispatch/megastep_launches")
-        self.scheduler.mark_scheduled(batch)          # fed token consumed
-        out: Dict[int, List[int]] = {}
-        emitted_total = 0
-        for j, uid in enumerate(batch.uids):
-            c = int(counts[j])
-            emitted = [int(t) for t in ys[:c, j]]
-            emitted_total += c
-            seq = self.state.seqs[uid]
-            if c > 1:
-                # every emitted token except the LAST has its KV in the
-                # arena already; record them on the descriptor so
-                # seen == len(tokens) == KV rows. The last token follows
-                # the single-token contract: the caller decides whether
-                # to feed it back (state.extend) or retire the row.
-                seq.tokens.extend(emitted[:-1])
-                seq.seen_tokens = len(seq.tokens)
-            out[uid] = emitted
-        _dispatch_count("dispatch/megastep_tokens", emitted_total)
+        ys, counts = self._fetch((ys, counts))          # ONE sync for K
+        with tracer.span("serving/count"):
+            ys = np.asarray(ys)
+            counts = np.asarray(counts)
+            # what the launch did is known only now: a row emitted
+            # counts[j] tokens, its i-th one attending starts0[j] + i + 1
+            # cached tokens; the program ran sb scan steps over nb rows,
+            # each attending the whole sliced page table
+            c64 = counts[:n].astype(np.int64)
+            work = self._count_dispatch(
+                "megastep", n, nb, 1, mb_b, int(c64.sum()),
+                int((c64 * starts0[:n] + c64 * (c64 + 1) // 2).sum()),
+                scan_steps=sb)
+            if sp is not None:      # still the recorded event's arguments
+                sp.update(work)
+            _dispatch_count("dispatch/scan_steps", sb)
+            _dispatch_count("dispatch/dead_steps", sb - limit)
+            _dispatch_count("dispatch/megastep_launches")
+        with tracer.span("serving/retire"):
+            self.scheduler.mark_scheduled(batch)      # fed token consumed
+            out: Dict[int, List[int]] = {}
+            emitted_total = 0
+            for j, uid in enumerate(batch.uids):
+                c = int(counts[j])
+                emitted = [int(t) for t in ys[:c, j]]
+                emitted_total += c
+                seq = self.state.seqs[uid]
+                if c > 1:
+                    # every emitted token except the LAST has its KV in
+                    # the arena already; record them on the descriptor so
+                    # seen == len(tokens) == KV rows. The last token
+                    # follows the single-token contract: the caller decides
+                    # whether to feed it back (state.extend) or retire the
+                    # row.
+                    seq.tokens.extend(emitted[:-1])
+                    seq.seen_tokens = len(seq.tokens)
+                out[uid] = emitted
+            _dispatch_count("dispatch/megastep_tokens", emitted_total)
         return out
+
+    def _fetch(self, out):
+        """``jax.device_get(out)`` under ``serving/fetch``: the pump's one
+        wait for the device. Two always-on counters where the wait happens:
+        ``dispatch/fetch_wait_seconds`` (seconds inside the ``device_get``)
+        and ``dispatch/host_seconds`` (seconds from the last launch's fetch
+        returning to this one's start: everything the host did between two
+        programs, its own launch included). Only launches back to back
+        count: :meth:`step_with_budget` drops the stamp when the scheduler
+        has nothing to run."""
+        with self._tracer.span("serving/fetch"):           # waits for the device
+            asked = time.perf_counter()
+            got = jax.device_get(out)
+            back = time.perf_counter()
+            if self._fetch_returned is not None:
+                _dispatch_count("dispatch/host_seconds",
+                                asked - self._fetch_returned)
+            _dispatch_count("dispatch/fetch_wait_seconds", back - asked)
+            self._fetch_returned = back
+        return got
 
     def cow_block(self, src_block: int) -> int:
         """Copy-on-write duplicate of one KV page across all layers.
@@ -1313,57 +1352,69 @@ class RaggedInferenceEngineTPU:
         return (top,)
 
     def _run(self, batch: RaggedBatch, mode=None) -> np.ndarray:
-        n = len(batch.uids)
-        nb, cb = self._buckets(batch)
-        # chunk batches avoid the arena READ in attention (the write→read
-        # on the ~GB arena serializes the whole layer scan): first-chunk-
-        # only batches attend within the chunk ("fresh"); continuation /
-        # SplitFuse-mixed batches split history (pre-write arena) +
-        # within-chunk and merge by logsumexp ("split").
-        if cb == 1:
-            fresh = False
-        elif bool((batch.start_positions == 0).all()):
-            fresh = "fresh"
-        else:
-            fresh = "split"
-        tokens = batch.total_tokens
-        capacities = self._token_capacities(nb, cb, fresh)
-        if capacities and tokens > capacities[-1]:
-            raise ValueError(
-                f"a batch of {tokens} tokens is over max_batch_tokens="
-                f"{self.config.max_batch_tokens}: the {nb}-row step program "
-                f"packs its tokens into that many slots (the scheduler's "
-                f"budget; a budget= / token_budget= above it cannot be "
-                f"served)")
-        from deepspeed_tpu.telemetry.tracer import tracer
+        """One step program over ``batch``: pack and upload, launch, count
+        what was launched, wait. The accounting (``serving/count``) comes
+        AFTER the jitted call, so the device works while the host counts; a
+        launch that raises is therefore not counted."""
+        tracer = self._tracer
         with tracer.span("serving/pack"):
+            n = len(batch.uids)
+            nb, cb = self._buckets(batch)
+            # chunk batches avoid the arena READ in attention (the
+            # write→read on the ~GB arena serializes the whole layer scan):
+            # first-chunk-only batches attend within the chunk ("fresh");
+            # continuation / SplitFuse-mixed batches split history
+            # (pre-write arena) + within-chunk and merge by logsumexp
+            # ("split").
+            if cb == 1:
+                fresh = False
+            elif bool((batch.start_positions == 0).all()):
+                fresh = "fresh"
+            else:
+                fresh = "split"
+            tokens = batch.total_tokens
+            capacities = self._token_capacities(nb, cb, fresh)
+            if capacities and tokens > capacities[-1]:
+                raise ValueError(
+                    f"a batch of {tokens} tokens is over max_batch_tokens="
+                    f"{self.config.max_batch_tokens}: the {nb}-row step "
+                    f"program packs its tokens into that many slots (the "
+                    f"scheduler's budget; a budget= / token_budget= above "
+                    f"it cannot be served)")
             packed = jnp.asarray(self._pack(batch, nb, cb))  # ONE upload
-        context_slots = query_tiles = None
-        if fresh == "split" and self.use_pallas:
-            # the paged reader walks each row's live pages, then the
-            # chunk attends its own keys
-            bs = self.config.block_size
-            context_slots = nb * cb + \
-                int((-(-batch.start_positions // bs)).sum()) * bs
-            query_tiles = self._query_tiles(batch, cb)
-        write_block = _write_back_slots(capacities, nb * cb)[0]
-        work = self._count_dispatch(
-            _step_kind(cb, fresh), n, nb, cb, self.mb, tokens,
-            int((batch.start_positions + batch.token_counts).sum()),
-            context_slots=context_slots,
-            kv_window=self._kv_window_tokens(batch),
-            attn_pairs=self._attn_pairs(batch), query_tiles=query_tiles,
-            # the device's own rules (_at_capacity: the smallest that
-            # holds; _write_back: whole blocks until the tokens are written)
-            token_slots=next((t for t in capacities if tokens <= t), None),
-            kv_write_slots=-(-tokens // write_block) * write_block)
-        with tracer.span("serving/dispatch",
-                         **(work if tracer.enabled else {})):
+            program = _step_kind(cb, fresh)
+        with tracer.span("serving/dispatch", program=program) as sp:
             out, self._rng_dev, self.arena = self._step_fn(
                 nb, cb, mode, fresh)(
                 self.params, self.arena, packed, self._rng_dev)
-        with tracer.span("serving/fetch"):           # waits for the device
-            return np.asarray(jax.device_get(out))[:n]
+        with tracer.span("serving/count"):
+            context_slots = query_tiles = None
+            if fresh == "split" and self.use_pallas:
+                # the paged reader walks each row's live pages, then the
+                # chunk attends its own keys
+                bs = self.config.block_size
+                context_slots = nb * cb + \
+                    int((-(-batch.start_positions // bs)).sum()) * bs
+                query_tiles = self._query_tiles(batch, cb)
+            write_block = _write_back_slots(capacities, nb * cb)[0]
+            work = self._count_dispatch(
+                program, n, nb, cb, self.mb, tokens,
+                int((batch.start_positions + batch.token_counts).sum()),
+                context_slots=context_slots,
+                kv_window=self._kv_window_tokens(batch),
+                # span arguments only: nothing to compute for no span
+                attn_pairs=self._attn_pairs(batch) if sp is not None
+                else None,
+                query_tiles=query_tiles,
+                # the device's own rules (_at_capacity: the smallest that
+                # holds; _write_back: whole blocks until the tokens are
+                # written)
+                token_slots=next((t for t in capacities if tokens <= t),
+                                 None),
+                kv_write_slots=-(-tokens // write_block) * write_block)
+            if sp is not None:      # still the recorded event's arguments
+                sp.update(work)
+        return np.asarray(self._fetch(out))[:n]
 
     def _kv_window_tokens(self, batch: RaggedBatch):
         """(live, held) tokens of the batch's rows in ONE window layer
@@ -1439,7 +1490,9 @@ class RaggedInferenceEngineTPU:
                         token_slots: Optional[int] = None,
                         kv_write_slots: Optional[int] = None
                         ) -> Dict[str, Any]:
-        """Count one device program launch where its batch is packed: the
+        """Count one device program launch, right after its jitted call
+        returned (``serving/count``: the device is at work by then; a
+        launch that raises is not counted): the
         useful work (``tokens`` fed, ``context_tokens`` of live KV they
         attend) against the work attempted (``slots`` = what the sublayers
         that act on a token alone ran over: ``token_slots``, the capacity

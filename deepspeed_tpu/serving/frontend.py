@@ -325,54 +325,55 @@ class ServingFrontend:
         frontend's spans join the fleet-wide trace); with request tracing
         enabled and no upstream context, the frontend is the entry point
         and mints the trace itself."""
-        now = self.clock()
-        prompt = [int(t) for t in prompt]
-        req = Request(prompt=prompt, max_new_tokens=int(max_new_tokens),
-                      priority=priority, stream_cb=stream_cb,
-                      deadline=(now + timeout if timeout is not None
-                                else deadline),
-                      eos_token_id=eos_token_id)
-        total = len(prompt) + req.max_new_tokens
-        if not prompt or total > self.engine.config.max_seq_len:
-            req.state = RequestState.REJECTED
-            req.finish_reason = "too_long"
-            self.metrics.bump("rejected_too_long")
-            raise AdmissionError(
-                "too_long", f"{total} tokens vs max_seq_len="
-                f"{self.engine.config.max_seq_len}")
-        bs = self.engine.state.allocator.block_size
-        need = -(-total // bs)
-        avail = self.engine.state.allocator.free_blocks + \
-            (self.cache.evictable_pages() if self.cache else 0)
-        if need > avail:
-            req.state = RequestState.REJECTED
-            req.finish_reason = "kv_exhausted"
-            self.metrics.bump("rejected_kv_exhausted")
-            raise AdmissionError(
-                "kv_exhausted", f"need {need} pages, {avail} reclaimable")
-        self._slo_check(req, now)
-        try:
-            victim = self.queue.submit(req, now)
-        except AdmissionError:
-            self.metrics.bump("rejected_queue_full")
-            raise
-        if victim is not None:
-            # the queue shed a past-deadline entry to make room; give it
-            # the same terminal treatment shed_expired victims get — a
-            # "deadline" finish the client can observe and a shed count
-            victim.finish_ts = now
-            self.metrics.bump("shed")
-            self._trace_lifecycle(victim, "deadline", now)
-        self.metrics.bump("admitted")
-        from deepspeed_tpu.telemetry.reqtrace import reqtrace
-        req.trace = ctx if ctx is not None else \
-            reqtrace.mint(entry="frontend", uid=req.uid)
-        if self.kvtier is not None:
-            # returning conversation: start the NVMe preads NOW (the PR 6
-            # issue/complete split) so the bytes climb to DRAM while the
-            # request waits in admission; the complete half runs at admit
-            self.kvtier.issue_prefetch(prompt, ctx=req.trace)
-        return req
+        with telemetry.tracer.span("serving/submit"):
+            now = self.clock()
+            prompt = [int(t) for t in prompt]
+            req = Request(prompt=prompt, max_new_tokens=int(max_new_tokens),
+                          priority=priority, stream_cb=stream_cb,
+                          deadline=(now + timeout if timeout is not None
+                                    else deadline),
+                          eos_token_id=eos_token_id)
+            total = len(prompt) + req.max_new_tokens
+            if not prompt or total > self.engine.config.max_seq_len:
+                req.state = RequestState.REJECTED
+                req.finish_reason = "too_long"
+                self.metrics.bump("rejected_too_long")
+                raise AdmissionError(
+                    "too_long", f"{total} tokens vs max_seq_len="
+                    f"{self.engine.config.max_seq_len}")
+            bs = self.engine.state.allocator.block_size
+            need = -(-total // bs)
+            avail = self.engine.state.allocator.free_blocks + \
+                (self.cache.evictable_pages() if self.cache else 0)
+            if need > avail:
+                req.state = RequestState.REJECTED
+                req.finish_reason = "kv_exhausted"
+                self.metrics.bump("rejected_kv_exhausted")
+                raise AdmissionError(
+                    "kv_exhausted", f"need {need} pages, {avail} reclaimable")
+            self._slo_check(req, now)
+            try:
+                victim = self.queue.submit(req, now)
+            except AdmissionError:
+                self.metrics.bump("rejected_queue_full")
+                raise
+            if victim is not None:
+                # the queue shed a past-deadline entry to make room; give it
+                # the same terminal treatment shed_expired victims get — a
+                # "deadline" finish the client can observe and a shed count
+                victim.finish_ts = now
+                self.metrics.bump("shed")
+                self._trace_lifecycle(victim, "deadline", now)
+            self.metrics.bump("admitted")
+            from deepspeed_tpu.telemetry.reqtrace import reqtrace
+            req.trace = ctx if ctx is not None else \
+                reqtrace.mint(entry="frontend", uid=req.uid)
+            if self.kvtier is not None:
+                # returning conversation: start the NVMe preads NOW (the PR 6
+                # issue/complete split) so the bytes climb to DRAM while the
+                # request waits in admission; the complete half runs at admit
+                self.kvtier.issue_prefetch(prompt, ctx=req.trace)
+            return req
 
     def cancel(self, req: Request) -> None:
         req.cancel()
@@ -465,9 +466,14 @@ class ServingFrontend:
         """One pump iteration: shed → cancel → admit → engine step →
         fan tokens out. Returns True while there is (or was) work.
 
-        One ``serving/step`` span with children ``serving/admit``,
-        ``serving/engine_step`` (the engine's ``serving/pack`` /
-        ``dispatch`` / ``fetch`` inside it) and ``serving/fanout``."""
+        One ``serving/step`` span whose children tile it: ``serving/admit``,
+        ``serving/plan``, ``serving/engine_step`` (the engine's
+        ``serving/schedule`` / ``pack`` / ``dispatch`` / ``count`` /
+        ``fetch`` / ``retire`` tile that one), ``serving/bookkeeping``,
+        ``serving/fanout``, ``serving/bookkeeping`` again: outside them a
+        step holds a few attribute reads, so that a reader can put every
+        idle moment of the device down to one phase
+        (``docs/observability.md``)."""
         with telemetry.tracer.span("serving/step"):
             return self._step()
 
@@ -496,21 +502,22 @@ class ServingFrontend:
         return progressed
 
     def _step(self) -> bool:
-        now = self.clock()
         with telemetry.tracer.span("serving/admit"):
+            now = self.clock()
             progressed = self._admit(now)
-        k = self._pick_megastep(now)
-        row_limits = eos_map = None
-        if k > 1:
-            row_limits = {uid: req.max_new_tokens - len(req.tokens_out)
-                          for uid, req in self._running.items()}
-            eos_map = {uid: req.eos_token_id
-                       for uid, req in self._running.items()
-                       if req.eos_token_id is not None}
-        if self.watchdog is not None:
-            self.watchdog.arm("serving_step")
-        t0 = time.monotonic()
-        self._pump_steps += 1
+        with telemetry.tracer.span("serving/plan"):
+            k = self._pick_megastep(now)
+            row_limits = eos_map = None
+            if k > 1:
+                row_limits = {uid: req.max_new_tokens - len(req.tokens_out)
+                              for uid, req in self._running.items()}
+                eos_map = {uid: req.eos_token_id
+                           for uid, req in self._running.items()
+                           if req.eos_token_id is not None}
+            if self.watchdog is not None:
+                self.watchdog.arm("serving_step")
+            t0 = time.monotonic()
+            self._pump_steps += 1
         try:
             with telemetry.tracer.span("serving/engine_step",
                                        batch=len(self._running),
@@ -538,42 +545,47 @@ class ServingFrontend:
         except Exception as e:                       # noqa: BLE001
             # serving failure domain: one engine fault must cost at most
             # one retry per in-flight request, never a wedged replica
-            self._on_engine_fault(e, self.clock())
-            self._update_degraded()
+            with telemetry.tracer.span("serving/bookkeeping"):
+                self._on_engine_fault(e, self.clock())
+                self._update_degraded()
             return True
         finally:
             if self.watchdog is not None:
                 self.watchdog.disarm()
-        self._update_degraded()
-        # goodput ledger sweep (rate-limited internally; no-op unless
-        # telemetry.goodput is on) — BEFORE the out-is-None early return
-        # so idle pumps keep attributing idle seconds
-        telemetry.goodput_ledger.maybe_update()
+        with telemetry.tracer.span("serving/bookkeeping"):
+            self._update_degraded()
+            # goodput ledger sweep (rate-limited internally; no-op unless
+            # telemetry.goodput is on) — BEFORE the out-is-None early
+            # return so idle pumps keep attributing idle seconds
+            telemetry.goodput_ledger.maybe_update()
+            if out is not None:
+                self.metrics.bump("engine_steps")
+                telemetry.flight_recorder.record_step(
+                    int(telemetry.registry.counter(
+                        "serving/engine_steps").value),
+                    kind="serving", dur_s=time.monotonic() - t0,
+                    batch=len(self._running), tokens=len(out))
         if out is None:
             return progressed or bool(self._running or len(self.queue))
-        self.metrics.bump("engine_steps")
-        telemetry.flight_recorder.record_step(
-            int(telemetry.registry.counter("serving/engine_steps").value),
-            kind="serving", dur_s=time.monotonic() - t0,
-            batch=len(self._running), tokens=len(out))
         with telemetry.tracer.span("serving/fanout"):
             self._fan_out(out)
-        if self.emit_every and self.metrics.counters["engine_steps"] % \
-                self.emit_every == 0:
-            self.emit_metrics()
-        # metric history + SLO evaluation on its own cadence: one
-        # registry snapshot feeds the history file, the slo/* burn
-        # gauges, /healthz, and the flight recorder together
-        if self._history is not None and \
-                self.metrics.counters["engine_steps"] % \
-                self._history_every == 0:
-            telemetry.registry.flush_to_monitor(
-                None, self.metrics.counters["engine_steps"],
-                history=self._history)
-        # re-evaluate AFTER fan-out: the step that finishes the last
-        # retried request must flip /healthz back to healthy — no later
-        # pump is guaranteed once the replica drains idle
-        self._update_degraded()
+        with telemetry.tracer.span("serving/bookkeeping"):
+            if self.emit_every and self.metrics.counters["engine_steps"] % \
+                    self.emit_every == 0:
+                self.emit_metrics()
+            # metric history + SLO evaluation on its own cadence: one
+            # registry snapshot feeds the history file, the slo/* burn
+            # gauges, /healthz, and the flight recorder together
+            if self._history is not None and \
+                    self.metrics.counters["engine_steps"] % \
+                    self._history_every == 0:
+                telemetry.registry.flush_to_monitor(
+                    None, self.metrics.counters["engine_steps"],
+                    history=self._history)
+            # re-evaluate AFTER fan-out: the step that finishes the last
+            # retried request must flip /healthz back to healthy — no
+            # later pump is guaranteed once the replica drains idle
+            self._update_degraded()
         return True
 
     def _fan_out(self, out: Dict[int, Any]) -> None:

@@ -22,10 +22,67 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
 DEFAULT_BUFFER_EVENTS = 100_000
+
+
+class _NoSpan:
+    """What :meth:`Tracer.span` hands out while tracing is off: one
+    shared, stateless context manager, so a disabled span costs a call
+    and an attribute check."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_OFF = _NoSpan()
+
+
+class _Span:
+    """One span being recorded (:meth:`Tracer.span` with tracing on)."""
+    __slots__ = ("tracer", "name", "step", "ctx", "args", "ann", "t0")
+
+    def __enter__(self):
+        # the fewest calls between the last sibling's end and the
+        # annotation's start: under a capture every call here is idle
+        # time that no span accounts for
+        ann, tracer = None, self.tracer
+        if tracer.jax_annotations:
+            profiler = tracer._jprof or tracer._profiler()
+            if profiler is not None:
+                ann = profiler.TraceAnnotation(self.name) \
+                    if self.step is None else \
+                    profiler.StepTraceAnnotation(self.name,
+                                                 step_num=self.step)
+                ann.__enter__()
+        self.ann = ann
+        self.t0 = time.perf_counter()
+        return self.args
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        try:
+            tracer, args = self.tracer, self.args
+            if self.step is not None:
+                args["step"] = self.step
+            if self.ctx is not None:
+                for key, tag in self.ctx.tags().items():
+                    args.setdefault(key, tag)
+            ev = tracer._event(self.name, "X",
+                               (self.t0 - tracer._t0) * 1e6, None, args)
+            ev["dur"] = (t1 - self.t0) * 1e6
+            tracer._append(ev)
+        finally:
+            # last, so that the annotation covers the recording (siblings
+            # then tile their parent), and whatever the recording raised
+            if self.ann is not None:
+                self.ann.__exit__(None, None, None)
+        return False
 
 
 class Tracer:
@@ -44,6 +101,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._buf: deque = deque(maxlen=buffer_events)
         self._dropped = 0
+        self._jprof = None
 
     # -- configuration ------------------------------------------------------
 
@@ -109,57 +167,47 @@ class Tracer:
             ev["args"] = args
         return ev
 
-    def _annotation(self, name: str, step: Optional[int]):
-        """jax.profiler annotation object, or None when passthrough is off
-        or jax is unavailable. Annotations are inert outside an active
-        profiler capture, so entering them unconditionally is safe."""
-        if not self.jax_annotations:
-            return None
-        try:
-            from jax import profiler as jprof
-            if step is not None:
-                return jprof.StepTraceAnnotation(name, step_num=step)
-            return jprof.TraceAnnotation(name)
-        except Exception:
-            return None
+    def _profiler(self):
+        """``jax.profiler`` for the annotation passthrough, None where jax
+        is unavailable; looked up once, not once a span. Annotations are
+        inert outside an active profiler capture, so entering them
+        unconditionally is safe."""
+        if self._jprof is None:
+            try:
+                from jax import profiler
+            except Exception:
+                return None
+            self._jprof = profiler
+        return self._jprof
 
-    @contextmanager
     def span(self, name: str, step: Optional[int] = None, ctx=None, **args):
-        """Record the enclosed block as a complete span. Nestable; nesting
+        """Record the enclosed block as a complete span (``with
+        tracer.span(...) as args:``). Nestable; nesting
         is reconstructed from ts/dur containment (same pid/tid), which is
         how Chrome/Perfetto render the flame graph. ``ctx`` (a
         :class:`~deepspeed_tpu.telemetry.reqtrace.TraceContext`) stamps
         the span with trace_id/span_id/parent_span_id args so it joins a
         request-scoped distributed trace.
 
-        Yields the span's argument dict (``None`` while tracing is off):
-        what is known only when the block ends -- which program a step
-        ran -- is added there, behind an ``is not None`` check so that
+        The block gets the span's argument dict (``None`` while tracing is
+        off): what is known only when the block ends -- which program a
+        step ran -- is added there, behind an ``is not None`` check so that
         nothing is computed for a disabled tracer. A dict that held an
         argument when the block ended IS the recorded event's ``args``,
-        so it may still be completed right after (a megastep's emitted
-        tokens are known only after the fetch that follows its launch)."""
+        so it may still be completed right after (a launch's work is
+        counted after its jitted call, a megastep's emitted tokens are
+        known only after the fetch that follows its launch).
+
+        Spans that follow one another TILE their parent: the profiler
+        annotation opens before the clock is read and closes after the
+        event is in the ring, so what lies between two siblings is the
+        two ``with`` statements and nothing of the tracer's own."""
         if not self.enabled:
-            yield None
-            return
-        ann = self._annotation(name, step)
-        if ann is not None:
-            ann.__enter__()
-        t0 = time.perf_counter()
-        try:
-            yield args
-        finally:
-            t1 = time.perf_counter()
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            if step is not None:
-                args["step"] = step
-            if ctx is not None:
-                for key, tag in ctx.tags().items():
-                    args.setdefault(key, tag)
-            ev = self._event(name, "X", (t0 - self._t0) * 1e6, None, args)
-            ev["dur"] = (t1 - t0) * 1e6
-            self._append(ev)
+            return _OFF
+        span = _Span()      # filled here: an __init__ is one call more
+        span.tracer, span.name, span.step = self, name, step
+        span.ctx, span.args = ctx, args
+        return span
 
     def instant(self, name: str, tid: Optional[int] = None, ctx=None,
                 **args) -> None:

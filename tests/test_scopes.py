@@ -622,3 +622,315 @@ def test_megastep_launch_counts_the_tokens_it_emitted(traced):
     for a in mega:
         assert 0 < a["tokens"] <= a["slots"]
         assert a["tokens"] <= a["context_tokens"] <= a["context_slots"]
+
+
+# -- the leaves tile the pump (PR 39) -------------------------------------------
+
+FRONT = ("serving/admit", "serving/plan")
+BACK = ("serving/bookkeeping", "serving/fanout", "serving/bookkeeping")
+#: the leaves of a step that launched, in order, on either path (a
+#: megastep's work is known only after its fetch, so it is counted there)
+LEAVES = {
+    "run": FRONT + ("serving/schedule", "serving/pack", "serving/dispatch",
+                    "serving/count", "serving/fetch", "serving/retire")
+    + BACK,
+    "megastep": FRONT + ("serving/schedule", "serving/pack",
+                         "serving/dispatch", "serving/fetch",
+                         "serving/count", "serving/retire") + BACK}
+ENGINE_LEAVES = {"serving/schedule", "serving/pack", "serving/dispatch",
+                 "serving/count", "serving/fetch", "serving/retire"}
+#: what the parent of PR 39 recorded for this batch sequence: (program,
+#: tokens, slots, kv_write_slots, context_tokens, context_slots) a launch,
+#: the ``dispatch/*`` counters' increase, the greedy tokens
+PARENT = {
+    "run": {
+        "launches": [("fresh", 11, 16, 16, 11, 256),
+                     ("split", 9, 16, 16, 20, 256),
+                     ("split", 5, 16, 16, 25, 256),
+                     ("decode", 2, 2, 2, 27, 256),
+                     ("decode", 2, 2, 2, 29, 256),
+                     ("decode", 2, 2, 2, 31, 256),
+                     ("decode", 1, 1, 1, 24, 128),
+                     ("decode", 1, 1, 1, 25, 128)],
+        "counters": {"context_slots": 1792, "context_tokens": 192,
+                     "host_calls": 8, "kv_write_slots": 56,
+                     "steps.decode": 5, "steps.fresh": 1, "steps.split": 2,
+                     "token_slots": 56, "tokens": 33}},
+    "megastep": {
+        "launches": [("fresh", 11, 16, 16, 11, 256),
+                     ("split", 9, 16, 16, 20, 256),
+                     ("split", 5, 16, 16, 25, 256),
+                     ("megastep", 7, 8, 8, 111, 256),
+                     ("decode", 1, 1, 1, 25, 128)],
+        "counters": {"context_slots": 1152, "context_tokens": 192,
+                     "host_calls": 5, "kv_write_slots": 57,
+                     "megastep_launches": 1, "megastep_tokens": 7,
+                     "scan_steps": 4, "steps.decode": 1, "steps.fresh": 1,
+                     "steps.megastep": 1, "steps.split": 2,
+                     "token_slots": 57, "tokens": 33}},
+}
+PARENT_TOKENS = [[182, 208, 191, 135, 209, 53], [3, 214, 9, 36, 181, 9]]
+HOST_COUNTERS = ("dispatch/host_seconds", "dispatch/fetch_wait_seconds")
+
+
+def _dispatch_counters():
+    return {n[len("dispatch/"):]: telemetry.registry.counter(n).value
+            for n in telemetry.registry.names()
+            if n.startswith("dispatch/") and n not in HOST_COUNTERS}
+
+
+def _pump(path, steps=8, max_new_tokens=6):
+    from deepspeed_tpu.serving import ServingFrontend
+    fe = ServingFrontend(
+        _engine(), **({"megastep_tokens": 4} if path == "megastep" else {}))
+    rng = np.random.default_rng(0)
+    reqs = [fe.submit([int(t) for t in p], max_new_tokens=max_new_tokens)
+            for p in (rng.integers(1, 255, 20), rng.integers(1, 255, 3))]
+    for _ in range(steps):
+        fe.step()
+    return fe, reqs
+
+
+@pytest.mark.parametrize("path", sorted(LEAVES))
+def test_a_step_that_launched_holds_the_leaves_once_in_order(traced, path):
+    """Each ``serving/step`` that launched a program holds every leaf once
+    (``serving/bookkeeping`` twice), in the pump's order, one ending before
+    the next begins; the engine's six lie inside ``serving/engine_step``;
+    ``serving/submit`` stands outside every step."""
+    _pump(path)
+    events = [e for e in traced.events() if e["ph"] == "X"]
+    steps = _spans(events, "serving/step")
+    assert len(steps) == 8
+    names = set(LEAVES["run"])
+    seen = set()
+    for step in steps:
+        inside = sorted((e for e in events if e["name"] in names and
+                         _inside(e, step)), key=lambda e: e["ts"])
+        order = tuple(e["name"] for e in inside)
+        if "serving/dispatch" not in order:
+            # the pump ran dry: no program, no fan-out
+            assert order == FRONT + ("serving/schedule",
+                                     "serving/bookkeeping")
+            continue
+        (launch,) = (e for e in inside if e["name"] == "serving/dispatch")
+        kind = "megastep" if launch["args"]["program"] == "megastep" \
+            else "run"
+        seen.add(kind)
+        assert order == LEAVES[kind]
+        for a, b in zip(inside, inside[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1e-3, (a, b)
+        (engine,) = (e for e in _spans(events, "serving/engine_step")
+                     if _inside(e, step))
+        for e in inside:
+            assert _inside(e, engine) == (e["name"] in ENGINE_LEAVES), e
+    assert seen == ({"run", "megastep"} if path == "megastep" else {"run"})
+    submits = _spans(events, "serving/submit")
+    assert len(submits) == 2
+    assert not any(_inside(s, step) for s in submits for step in steps)
+
+
+def test_each_phase_of_the_pump_runs_under_its_leaf(traced, monkeypatch):
+    """Tiling, by what runs where: the scheduler's selection under
+    ``serving/schedule``, the packing under ``serving/pack``, the
+    accounting under ``serving/count`` and AFTER the jitted call, the
+    device_get under ``serving/fetch``, ``mark_scheduled`` under
+    ``serving/retire``, the frontend's own work under ``serving/plan`` and
+    ``serving/bookkeeping``."""
+    import jax as jax_module
+    from deepspeed_tpu.inference import engine_v2, ragged
+    from deepspeed_tpu.serving.frontend import ServingFrontend
+    calls = []
+
+    def stamped(owner, attr, leaf):
+        real = getattr(owner, attr)
+
+        def spy(*a, **k):
+            calls.append((leaf, attr, (traced.now() - traced._t0) * 1e6))
+            return real(*a, **k)
+        monkeypatch.setattr(owner, attr, spy)
+
+    eng = engine_v2.RaggedInferenceEngineTPU
+    stamped(ragged.RaggedScheduler, "next_batch", "serving/schedule")
+    stamped(ragged.RaggedScheduler, "mark_scheduled", "serving/retire")
+    stamped(eng, "_buckets", "serving/pack")
+    stamped(eng, "_pack", "serving/pack")
+    stamped(eng, "_step_fn", "serving/dispatch")
+    for attr in ("_kv_window_tokens", "_attn_pairs", "_count_dispatch"):
+        stamped(eng, attr, "serving/count")
+    stamped(jax_module, "device_get", "serving/fetch")
+    stamped(ServingFrontend, "_pick_megastep", "serving/plan")
+    stamped(ServingFrontend, "_update_degraded", "serving/bookkeeping")
+    stamped(ServingFrontend, "_fan_out", "serving/fanout")
+    _pump("run", steps=4)
+    events = traced.events()
+    assert {attr for _leaf, attr, _t in calls} >= {
+        "next_batch", "mark_scheduled", "_pack", "_step_fn", "_attn_pairs",
+        "_count_dispatch", "device_get", "_pick_megastep",
+        "_update_degraded", "_fan_out"}
+    for leaf, attr, at in calls:
+        assert any(s["ts"] <= at <= s["ts"] + s["dur"]
+                   for s in _spans(events, leaf)), (attr, leaf)
+    # the launch comes first, then its accounting
+    order = [attr for _leaf, attr, _t in calls
+             if attr in ("_step_fn", "_count_dispatch")]
+    assert order == ["_step_fn", "_count_dispatch"] * 4
+
+
+@pytest.mark.parametrize("path", sorted(PARENT))
+def test_launch_arguments_counters_and_tokens_are_the_parents(traced, path):
+    """Counting after the launch changed nothing that is counted: for a
+    fixed batch sequence the ``serving/dispatch`` spans' arguments, every
+    ``dispatch/*`` counter and the greedy tokens are what the tree before
+    PR 39 gave."""
+    before = _dispatch_counters()
+    _fe, reqs = _pump(path)
+    after = _dispatch_counters()
+    launches = [e["args"] for e in
+                _spans(traced.events(), "serving/dispatch")]
+    assert [(a["program"], a["tokens"], a["slots"], a["kv_write_slots"],
+             a["context_tokens"], a["context_slots"])
+            for a in launches] == PARENT[path]["launches"]
+    assert all(list(a)[0] == "program" for a in launches)
+    grew = {n: after[n] - before.get(n, 0) for n in after
+            if after[n] != before.get(n, 0)}
+    assert grew == PARENT[path]["counters"]
+    assert [list(r.tokens_out) for r in reqs] == PARENT_TOKENS
+
+
+def test_untraced_launch_computes_no_span_argument_and_still_counts(
+        monkeypatch):
+    """``_attn_pairs`` is span arguments only: with the tracer off it is
+    not called, and the always-on counters advance as they do traced."""
+    from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
+    called = []
+    monkeypatch.setattr(RaggedInferenceEngineTPU, "_attn_pairs",
+                        lambda self, batch: called.append(1))
+    tr = telemetry.tracer
+    was = tr.enabled
+    grew = {}
+    try:
+        for on in (False, True):
+            tr.configure(enabled=on)
+            tr.clear()
+            del called[:]
+            before = _dispatch_counters()
+            _pump("run")
+            after = _dispatch_counters()
+            grew[on] = {n: after[n] - before.get(n, 0) for n in after}
+            assert len(called) == (8 if on else 0)
+    finally:
+        tr.configure(enabled=was)
+        tr.clear()
+    assert grew[False] == grew[True]
+    assert grew[False]["tokens"] == 33 and grew[False]["host_calls"] == 8
+
+
+def test_host_and_wait_seconds_tile_the_time_between_fetches(monkeypatch):
+    """``dispatch/host_seconds`` + ``dispatch/fetch_wait_seconds`` is the
+    wall time from the first fetch's start to the last one's return of
+    launches back to back, on the engine's own clock reads; a pump that
+    ran dry drops the stamp, so waiting for work is no host time."""
+    import time
+    import types
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.serving import ServingFrontend
+    reads = []
+
+    def clock():
+        reads.append(time.perf_counter())
+        return reads[-1]
+    monkeypatch.setattr(engine_v2, "time",
+                        types.SimpleNamespace(perf_counter=clock))
+
+    def seconds():
+        return [telemetry.registry.counter(n).value for n in HOST_COUNTERS]
+    fe = ServingFrontend(_engine())
+    before = seconds()
+    fe.submit(list(range(1, 12)), max_new_tokens=4)
+    for _ in range(5):                      # 2 chunks, then 3 decode steps
+        fe.step()
+    host, wait = (b - a for a, b in zip(before, seconds()))
+    assert len(reads) == 2 * 5              # two clock reads a launch
+    assert host > 0 and wait > 0
+    assert host + wait == pytest.approx(reads[-1] - reads[0], abs=1e-9)
+    assert wait == pytest.approx(sum(reads[1::2]) - sum(reads[0::2]))
+    # dry: nothing to schedule, nothing counted, and the stamp is dropped
+    assert fe.step() is False and fe.engine._fetch_returned is None
+    assert len(reads) == 10
+    time.sleep(0.05)
+    fe.submit(list(range(1, 5)), max_new_tokens=2)
+    mid = seconds()
+    fe.step()
+    host2, wait2 = (b - a for a, b in zip(mid, seconds()))
+    assert host2 == 0.0 and wait2 == pytest.approx(reads[-1] - reads[-2])
+
+
+def test_a_spans_annotation_opens_first_and_closes_after_the_event_is_kept():
+    """Siblings tile their parent in a profiler capture: the annotation
+    covers the clock reads AND the recording, a step number makes it a
+    step annotation, and a disabled tracer hands out one shared no-op."""
+    import types
+    from deepspeed_tpu.telemetry.tracer import Tracer
+    log = []
+    tr = Tracer()
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            self.what = (name, kw)
+
+        def __enter__(self):
+            log.append(("enter", self.what, len(tr.events())))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.what, len(tr.events())))
+
+    tr._jprof = types.SimpleNamespace(TraceAnnotation=Annotation,
+                                      StepTraceAnnotation=Annotation)
+    assert tr.span("probe/off") is tr.span("probe/off2")    # tracing off
+    tr.enabled = tr.jax_annotations = True      # (a tracer of its own:
+    with tr.span("probe/a", k=1) as args:       # nothing to put back)
+        args["late"] = 2
+    with pytest.raises(KeyError):
+        with tr.span("probe/b", step=7):
+            raise KeyError("the span is kept all the same")
+    assert log == [("enter", ("probe/a", {}), 0),
+                   ("exit", ("probe/a", {}), 1),
+                   ("enter", ("probe/b", {"step_num": 7}), 1),
+                   ("exit", ("probe/b", {"step_num": 7}), 2)]
+    a, b = tr.events()
+    assert a["args"] == {"k": 1, "late": 2} and b["args"] == {"step": 7}
+
+
+def test_a_span_whose_recording_raises_still_closes_its_annotation():
+    """The annotation closes after the event is built, in a ``finally``:
+    a request context whose tags raise leaves no annotation open in the
+    capture (the error is the caller's to see)."""
+    import types
+    from deepspeed_tpu.telemetry.tracer import Tracer
+    log = []
+    tr = Tracer()
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    class Broken:
+        def tags(self):
+            raise RuntimeError("no tags today")
+
+    tr._jprof = types.SimpleNamespace(TraceAnnotation=Annotation,
+                                      StepTraceAnnotation=Annotation)
+    tr.enabled = tr.jax_annotations = True
+    with pytest.raises(RuntimeError, match="no tags today"):
+        with tr.span("probe/outer"):
+            with tr.span("probe/broken", ctx=Broken()):
+                pass
+    assert log == [("enter", "probe/outer"), ("enter", "probe/broken"),
+                   ("exit", "probe/broken"), ("exit", "probe/outer")]
+    assert [e["name"] for e in tr.events()] == ["probe/outer"]

@@ -1,0 +1,62 @@
+#!/usr/bin/env python3
+"""One run of a serving cell THROUGH THE HARNESS, then the engine's two
+always-on host-path counters over the measured window alone: every argument
+is ``benchmark/run.py``'s, and the last line printed is one more,
+
+    {"phase": "host_counters", "launches", "host_s", "fetch_wait_s",
+     "host_ms_per_launch", "fetch_wait_ms_per_launch", "host_share"}
+
+``dispatch/host_seconds`` and ``dispatch/fetch_wait_seconds``
+(``engine_v2._fetch``) less what they read when the window opened, over the
+window's ``dispatch/host_calls``. The harness has no reader of the two: it
+prints per-layer metrics in traced runs only, and there the capture's own
+start and stop stall the pump between two launches for seconds that
+``dispatch/host_seconds`` takes for host time (PERF.md section 7). Run
+with ``--trace 0`` this gives a replica's host share undisturbed; the
+cost of the instrumentation is read from runs with the tracer on against
+off at one seed (PERF.md section 6, PR 39).
+
+    chiprun --chips 1 -- python3 tools/host_path_probe.py \
+        --workload <cell> --seed <n> --trace <0|1>
+"""
+import json
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from benchmark import run as bench_run                # noqa: E402
+
+NAMES = ("dispatch/host_seconds", "dispatch/fetch_wait_seconds",
+         "dispatch/host_calls")
+
+
+def counters():
+    from deepspeed_tpu.telemetry.registry import registry
+    return [registry.counter(n).value for n in NAMES]
+
+
+def main() -> int:
+    at_open = []
+    open_window = bench_run.Context.open_window
+
+    def opened(self):
+        at_open[:] = counters()
+        return open_window(self)
+    bench_run.Context.open_window = opened
+    rc = bench_run.main()
+    if at_open:
+        host, wait, calls = (b - a for a, b in zip(at_open, counters()))
+        print(json.dumps({
+            "phase": "host_counters", "launches": int(calls),
+            "host_s": host, "fetch_wait_s": wait,
+            "host_ms_per_launch": 1e3 * host / max(1, calls),
+            "fetch_wait_ms_per_launch": 1e3 * wait / max(1, calls),
+            "host_share": 100.0 * host / (host + wait)
+            if host + wait else None}), flush=True)
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main())
