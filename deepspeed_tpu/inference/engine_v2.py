@@ -17,7 +17,8 @@ so jit traces a handful of programs, not one per batch composition.
 
 import time
 from functools import partial
-from typing import Any, Dict, List, Optional, Tuple, Union
+from typing import (Any, Dict, List, NamedTuple, Optional, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -53,23 +54,61 @@ class RaggedInferenceConfig(TPUConfigModel):
     weight_quant: Optional[str] = None  #: "int8"|"fp8"|"int4"|"fp6" weight-only
 
 
+class _RowGroup(NamedTuple):
+    """Rows of a step as attention sees them (:meth:`_TokenLayout.groups`):
+    ``ids`` [m] names the batch's rows (None: all ``n``, in order), each
+    ``c`` slots wide with ``counts`` [m] live queries; ``source`` [m, c]
+    are the packed slots its row slots read (None: the row form)."""
+    ids: Optional[jax.Array]
+    c: int
+    counts: jax.Array
+    source: Optional[jax.Array]
+
+    def of(self, per_row: jax.Array) -> jax.Array:
+        """A per-row operand (``starts``, the page table) of these rows."""
+        return per_row if self.ids is None else per_row[self.ids]
+
+    def take(self, x: jax.Array) -> jax.Array:
+        """The token-wise form → these rows ``[m, c, ...]``: a gather of
+        packed slots, nothing is scattered. The slots a row does not fill
+        hold what follows it — the next rows' tokens — as a row-form
+        step's hold the activations of token id 0: finite, and nothing
+        reads them (a chunk's attention is causal, so a live query sees
+        live keys alone; the KV write takes the packed tokens)."""
+        return x if self.source is None else x[0][self.source]
+
+
 class _TokenLayout:
     """Where a ragged batch's tokens sit for whatever acts on a token
     alone (embedding, norms, projections, RoPE, MLP / MoE, residual adds,
     the KV write): in ROWS ``[n, c, ...]`` as they arrive (``capacity``
     None), or PACKED ``[1, capacity, ...]``, row after row with no padding
     between, so that those work on ``capacity`` slots and not on
-    ``n * c``. Attention alone keeps the row form. Row ``r``'s tokens are
-    the packed slots ``offsets[r] .. offsets[r] + counts[r]``; ``capacity``
-    (STATIC) must hold ``counts.sum()``, the caller's promise. ``row``,
-    ``positions`` (``starts[row]`` + the slot's column in its row) and
-    ``valid`` say of each slot, in the token-wise form, whose token it
-    is, which one, and whether it holds a token at all."""
+    ``n * c``. Row ``r``'s tokens are the packed slots ``offsets[r] ..
+    offsets[r] + counts[r]``; ``capacity`` (STATIC) must hold
+    ``counts.sum()``, the caller's promise. ``row``, ``positions``
+    (``starts[row]`` + the slot's column in its row) and ``valid`` say of
+    each slot, in the token-wise form, whose token it is, which one, and
+    whether it holds a token at all.
+
+    Attention works on rows, and a split step's on ROW GROUPS
+    (:meth:`groups`, :func:`_split_attention`). ``chunk_rows`` (STATIC,
+    ``P``; None or ``n``: every row) is how many rows a packed step gives
+    the chunk's width: with ``P < n`` the rows that hold MORE THAN ONE
+    token — at most ``P``, the caller's promise — are gathered into a
+    chunk group ``[P, c, ...]`` and every row is also read as a row of ONE
+    query, its first packed token (``[n, 1, ...]``); a packed slot then
+    takes its row's chunk-group result if its row is in that group, else
+    its row's one-token result (:meth:`from_groups`). What still keeps
+    the row form of all ``n`` rows at the chunk's width (:meth:`to_rows`):
+    the fresh and paged modes' attention, a split step in the row form,
+    and a packed split step at ``P = n``."""
 
     def __init__(self, counts: jax.Array, starts: jax.Array, c: int,
-                 capacity: Optional[int]):
+                 capacity: Optional[int], chunk_rows: Optional[int] = None):
         self.n, self.c = counts.shape[0], c
         self.counts, self.capacity = counts, capacity
+        self.chunk_rows = None
         if capacity is None:
             self.row, self.positions, self.valid = pa.row_slots(
                 starts, counts, c)
@@ -92,6 +131,22 @@ class _TokenLayout:
             capacity - 1)
         self.positions = (starts[row] + col)[None]
         self.valid = (t < ends[-1])[None]
+        if chunk_rows is not None and chunk_rows < self.n:
+            self.chunk_rows = chunk_rows
+            wide = counts > 1
+            place = jnp.cumsum(wide) - 1    # a wide row's place in the group
+            p = jnp.arange(chunk_rows, dtype=jnp.int32)
+            # the group's p-th row: the wide row whose place is p; past the
+            # last one, row 0 riding along with no live query
+            self.chunk_ids = jnp.sum(
+                jnp.where(wide[None] & (place[None] == p[:, None]),
+                          jnp.arange(self.n, dtype=jnp.int32)[None], 0),
+                axis=1, dtype=jnp.int32)
+            self.chunk_counts = jnp.where(p <= place[-1],
+                                          counts[self.chunk_ids], 0)
+            self.in_chunk = wide[row]       # [capacity]
+            self.chunk_slot = jnp.minimum(place[row], chunk_rows - 1) * c \
+                + col                       # [capacity] into [P * c]
 
     def kv_slots(self):
         """``(row, pos, valid)`` as ``pa.write_kv`` / ``pa.write_rows``
@@ -109,16 +164,35 @@ class _TokenLayout:
 
     def to_rows(self, x: jax.Array) -> jax.Array:
         """The token-wise form → [n, c, ...]: row r's slot j reads packed
-        slot ``offsets[r] + j`` (a gather; nothing is scattered). The
-        slots a row does not fill so hold what follows it — the next
-        rows' tokens — as a row-form step's hold the activations of token
-        id 0: finite, and nothing reads them (a chunk's attention is
-        causal, so a live query sees live keys alone; the KV write takes
-        the packed tokens). Masking them would be one more pass over
-        ``[n, c, heads, d]`` a layer."""
-        if self.capacity is None:
-            return x
-        return x[0][self.source]
+        slot ``offsets[r] + j`` (:meth:`_RowGroup.take` of every row)."""
+        return x if self.capacity is None else x[0][self.source]
+
+    def groups(self) -> Tuple[_RowGroup, ...]:
+        """The row groups a split step's attention works on: every row at
+        the chunk's width; or (``chunk_rows``) the chunk group, then all
+        ``n`` rows as rows of one query — a row of the chunk group or
+        with no token rides along there with no live query, its result
+        never read."""
+        if self.chunk_rows is None:
+            return (_RowGroup(None, self.c, self.counts, None if
+                              self.capacity is None else self.source),)
+        first = jnp.minimum(self.offsets, self.capacity - 1)[:, None]
+        return (_RowGroup(self.chunk_ids, self.c, self.chunk_counts,
+                          self.source[self.chunk_ids]),
+                _RowGroup(None, 1, (self.counts == 1).astype(jnp.int32),
+                          first))
+
+    def from_groups(self, parts) -> jax.Array:
+        """The groups' results (``[m, c, ...]`` each, as :meth:`groups`
+        orders them) → the token-wise form: two gathers and a select over
+        the packed slots where there are two groups."""
+        if len(parts) == 1:
+            return self.to_tokens(parts[0])
+        chunk, one = parts
+        chunk = chunk.reshape((-1,) + chunk.shape[2:])[self.chunk_slot]
+        one = one[:, 0][self.row[0]]
+        pick = self.in_chunk.reshape((-1,) + (1,) * (chunk.ndim - 1))
+        return jnp.where(pick, chunk, one)[None]
 
     def last(self, x: jax.Array) -> jax.Array:
         """[n, 1, D]: each row's last token (a row without one: garbage)."""
@@ -128,16 +202,36 @@ class _TokenLayout:
         return x[0][jnp.maximum(self.offsets + self.counts - 1, 0)][:, None]
 
 
-def _at_capacity(capacities, total: jax.Array, run):
-    """``run(capacity)`` at the smallest of the STATIC ``capacities``
-    (ascending) that holds ``total`` tokens: one branch each of a
-    ``lax.switch`` inside the ONE program, so a batch's token count picks
-    the work and no program key is added. ``run``'s outputs have the same
-    shapes at every capacity. No capacity: the row form."""
-    if len(capacities) <= 1:
-        return run(capacities[0] if capacities else None)
-    index = sum((total > cap).astype(jnp.int32) for cap in capacities[:-1])
-    return lax.switch(index, [partial(run, cap) for cap in capacities])
+def _instances(capacities, n: int, c: int) -> Tuple[Tuple[int, int], ...]:
+    """``(capacity, chunk_rows)`` of each instance of a packed step's layer
+    loop (:func:`_at_capacity`): the top capacity keeps every row a chunk
+    row (``n``), a smaller one gives the chunk's width to as many rows as
+    its slots hold whole chunks (8 of the 64 x 128 program's rows at 1,024
+    slots) and reads the others as rows of one query."""
+    return tuple((cap, n if cap == capacities[-1] else max(1, cap // c))
+                 for cap in capacities)
+
+
+def _instance_index(instances, tokens, chunk_rows):
+    """Which of ``instances`` (ascending) a batch takes: the first whose
+    slots hold its ``tokens`` AND whose chunk group holds its
+    ``chunk_rows`` (rows of more than one token). One rule for the
+    program (traced scalars) and for the host's accounting (ints)."""
+    return sum(1 * ((tokens > cap) | (chunk_rows > rows))
+               for cap, rows in instances[:-1])
+
+
+def _at_capacity(instances, counts: jax.Array, run):
+    """``run(capacity, chunk_rows)`` at the first of the STATIC
+    ``instances`` (:func:`_instances`) that holds the batch
+    (:func:`_instance_index`): one branch each of a ``lax.switch`` inside
+    the ONE program, so what a batch holds picks the work and no program
+    key is added. ``run``'s outputs have the same shapes at every
+    instance. No instance: the row form."""
+    if len(instances) <= 1:
+        return run(*(instances[0] if instances else (None, None)))
+    index = _instance_index(instances, counts.sum(), (counts > 1).sum())
+    return lax.switch(index, [partial(run, *inst) for inst in instances])
 
 
 def _slot_major(t: jax.Array, slots: int) -> jax.Array:
@@ -190,6 +284,57 @@ def _write_back(counts: jax.Array, starts: jax.Array, c: int, capacities,
                              pools)
 
 
+def _split_attention(lay: _TokenLayout, qkv, history, own, *,
+                     scale: Optional[float] = None, sink=None,
+                     q_history=None, expand=None) -> jax.Array:
+    """A split step's attention of ONE layer, token-wise form in and out:
+    for each of the layout's row groups (:meth:`_TokenLayout.groups`)
+    unpack ``qkv`` to its rows, read the rows' history — ``history(q,
+    group)`` → (out, lse) over the PRE-write pool; ``q_history``: another
+    query than ``qkv``'s for it (a latent layer's absorbed one), ``expand``
+    its result into the heads' space —, attend the rows' own chunk —
+    ``own(q, k, v)`` → (out, lse), causal; a group of one-query rows calls
+    nothing: its one key is its own (:func:`pa.one_key_attention_with_lse`)
+    —, merge the two partials by their logsumexps in float32 (``sink``: a
+    learned logit beside them), and pack the groups' results back. The
+    layers differ in the reader and the chunk attention they pass; the
+    scopes are the same for all."""
+    q_dtype = qkv[0].dtype
+    groups = lay.groups()
+
+    def merged(out_h, lse_h, out_c, lse_c):
+        if expand is not None:
+            out_h = expand(out_h)
+        with jax.named_scope("attn_merge"):
+            return pa.merge_attention(out_h, lse_h, out_c, lse_c,
+                                      sink).astype(q_dtype)
+
+    # every row at the chunk's width and a history to expand: the partials
+    # are packed FIRST, so that ``expand`` and the merge run over the
+    # packed slots (2,048 for the rows' 8,192; in rows the latent stack's
+    # 64-row program holds 0.37 GB more temporaries at two layers)
+    pack_first = expand is not None and len(groups) == 1
+    outs = []
+    for group in groups:
+        with jax.named_scope("attn_qkv"):     # attention sees rows
+            q, k, v = (group.take(t) for t in qkv)
+            q_h = q if q_history is None else group.take(q_history)
+        # fresh rows mixed in have an empty history (lse ≈ -1e30 → weight
+        # 0); a row with no live query in a group gets the same
+        with jax.named_scope("attn_history"):
+            out_h, lse_h = history(q_h, group)
+        with jax.named_scope("attn_core"):
+            out_c, lse_c = own(q, k, v) if group.c > 1 else \
+                pa.one_key_attention_with_lse(q, k, v, scale)
+        partials = (out_h, lse_h, out_c, lse_c)
+        outs.append(partials if pack_first else merged(*partials))
+    with jax.named_scope("attn_out"):         # ... and tokens again
+        if not pack_first:
+            return lay.from_groups(outs)
+        partials = [lay.to_tokens(t) for t in outs[0]]
+    return merged(*partials)
+
+
 def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
                    counts: jax.Array, starts: jax.Array,
                    page_table: jax.Array, use_pallas: bool = False,
@@ -220,18 +365,31 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     token alone — embedding, RoPE tables, norms, the QKV and output
     projections, MLP / MoE, the residual adds, the final norm — run over
     the batch's tokens PACKED into ``[1, capacity, hidden]``
-    (:class:`_TokenLayout`); q, k, v are unpacked to ``[n, c, heads, d]``
-    for attention, which alone keeps the row form, and its result is
-    packed back before the output projection; the KV write takes ``k, v``
-    as they left the projections, one scatter update a packed slot. The
-    head projects each row's last packed token. With
-    several capacities the "split" program holds one instance of the layer
-    loop for each and the batch's token count picks the smallest that
-    holds it (:func:`_at_capacity`): the arena is a read-only operand of
-    the branches and the write-back (:func:`_write_back`) stays outside
-    them. The other modes
-    carry the arena through the loop and take ONE capacity. ``()``: the
-    row form throughout, which is also what ``c == 1`` always is.
+    (:class:`_TokenLayout`); attention works on rows, so q, k, v are
+    unpacked for it and its result is packed back before the output
+    projection; the KV write takes ``k, v`` as they left the projections,
+    one scatter update a packed slot. The head projects each row's last
+    packed token. With several capacities the "split" program holds one
+    instance of the layer loop for each (:func:`_instances`) and what the
+    batch holds picks the first that holds it (:func:`_at_capacity`): the
+    arena is a read-only operand of the branches and the write-back
+    (:func:`_write_back`) stays outside them. The other modes carry the
+    arena through the loop and take ONE capacity. ``()``: the row form
+    throughout, which is also what ``c == 1`` always is.
+
+    What attention's rows are. The fresh and paged modes, a split step in
+    the row form and the TOP instance of a packed split step unpack all
+    ``n`` rows at the chunk's width, ``[n, c, heads, d]``. A SMALLER
+    instance of a packed split step works on two row groups
+    (:func:`_split_attention`): the at most ``P = capacity // c`` rows
+    that hold more than one token, gathered into ``[P, c, ...]`` and
+    attended exactly as the row form attends them; and all ``n`` rows as
+    rows of ONE query ``[n, 1, ...]`` — the same history reader at
+    ``c = 1``, no attention call for the row's own key (``out = v``,
+    ``lse = scale * q.k``), the same merge. A batch takes that instance
+    iff its tokens fit the capacity AND its rows of more than one token
+    fit ``P``; any other batch takes the top instance, where every row is
+    a chunk row (``P = n``).
 
     In the "split" program (``c > 1``) the arena is READ-ONLY during the
     layer loop: the scan carries ``x`` alone, each layer emits its chunk's
@@ -246,9 +404,9 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     docs/kernels.md, tests/test_tpu_compile.py).
     The history reader follows ``use_pallas``: the paged kernel
     (:func:`paged_attention_with_lse`, ``counts = 0``, ``qcounts`` = the
-    step's counts: a decode row riding along computes one small tile of
-    its query block) walks only each row's ``ceil(start / block_size)``
-    live pages; the XLA gather
+    group's live queries: a decode row riding along at the chunk's width
+    computes one small tile of its query block) walks only each row's
+    ``ceil(start / block_size)`` live pages; the XLA gather
     (:func:`paged_attention_hist_xla`, CPU or a head size the kernel
     refuses) reads the page table's whole width.
     """
@@ -273,6 +431,14 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
             "ragged/paged inference does not support ALiBi models; use "
             "InferenceEngineTPU (v1 KV-cache path) for BLOOM-class models")
     attend = pa.paged_attention if use_pallas else pa.paged_attention_xla
+    if use_pallas:
+        from deepspeed_tpu.ops.flash_attention import (
+            flash_attention as causal, flash_attention_with_lse)
+        own_chunk = partial(flash_attention_with_lse, causal=True)
+    else:
+        from deepspeed_tpu.models.transformer import \
+            dot_product_attention as causal
+        own_chunk = pa.causal_attention_with_lse
     # per-layer page stride in the FLAT block pool (init_arena docstring:
     # the pool is a scan CARRY so decode updates it in place; a stacked
     # per-layer arena would be copied wholesale every step)
@@ -282,12 +448,12 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
 
     held_slots = _write_back_slots(token_capacities, n * c)[1]
 
-    def run(capacity):
-        """Embedding to final norm at one capacity → (each row's last
+    def run(capacity, chunk_rows):
+        """Embedding to final norm at one instance → (each row's last
         hidden state [n, 1, D]; split: the chunk's (k, v) of every layer,
         [L, held_slots, kvh * dh], else the written arena's (k, v))."""
         with jax.named_scope("embed"):     # where each token sits, too
-            lay = _TokenLayout(counts, starts, c, capacity)
+            lay = _TokenLayout(counts, starts, c, capacity, chunk_rows)
             toks = lay.to_tokens(tokens)
         positions = lay.positions
         if cfg.pos_emb == "learned":
@@ -311,56 +477,33 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
             h_in = _norm(cfg, lp["ln1"], x)
             q, k, v = qkv_project(cfg, lp["attn"], h_in, sin, cos)
             new_kv = (k, v)           # the write takes them token-wise
-            with jax.named_scope("attn_qkv"):     # attention sees rows
-                q, k, v = (lay.to_rows(a) for a in (q, k, v))
             if split:
                 # continuation / SplitFuse-mixed chunk: the history part
-                # reads the PRE-write arena. Fresh rows mixed in have empty
-                # history (lse ≈ -1e30 → weight 0); decode rows ride along
-                # as width-1 chunks.
-                with jax.named_scope("attn_history"):
-                    if use_pallas:
-                        out_h, lse_h = pa.paged_attention_with_lse(
-                            q, ak, av, pt_l, starts, jnp.zeros_like(starts),
-                            qcounts=counts)
-                    else:
-                        out_h, lse_h = pa.paged_attention_hist_xla(
-                            q, ak, av, pt_l, starts)
+                # reads the PRE-write arena
+                def history(q, rows):
+                    return pa.paged_history_with_lse(
+                        q, ak, av, rows.of(pt_l), rows.of(starts),
+                        rows.counts, kernel=use_pallas)
+
+                out = _split_attention(lay, (q, k, v), history, own_chunk)
             else:
                 with jax.named_scope("kv_write"):
                     ak, av = pa.write_kv(ak, av, *new_kv, pt_l,
                                          *lay.kv_slots(),
                                          trash_block=off + stride - 1)
-            if fresh_prefill == "fresh":
-                # starts == 0 everywhere: the chunk IS the whole history —
-                # plain causal attention over it; padded-tail rows produce
-                # garbage outputs nothing reads (their KV went to trash)
+                with jax.named_scope("attn_qkv"):     # attention sees rows
+                    q, k, v = (lay.to_rows(a) for a in (q, k, v))
                 with jax.named_scope("attn_core"):
-                    if use_pallas:
-                        from deepspeed_tpu.ops.flash_attention import \
-                            flash_attention
-                        out = flash_attention(q, k, v, causal=True)
+                    if fresh_prefill == "fresh":
+                        # starts == 0 everywhere: the chunk IS the whole
+                        # history — plain causal attention over it;
+                        # padded-tail rows produce garbage outputs nothing
+                        # reads (their KV went to trash)
+                        out = causal(q, k, v, causal=True)
                     else:
-                        from deepspeed_tpu.models.transformer import \
-                            dot_product_attention
-                        out = dot_product_attention(q, k, v, causal=True)
-            elif split:
-                with jax.named_scope("attn_core"):
-                    if use_pallas:
-                        from deepspeed_tpu.ops.flash_attention import \
-                            flash_attention_with_lse
-                        out_c, lse_c = flash_attention_with_lse(q, k, v,
-                                                                causal=True)
-                    else:
-                        out_c, lse_c = pa.causal_attention_with_lse(q, k, v)
-                with jax.named_scope("attn_merge"):
-                    out = pa.merge_attention(out_h, lse_h, out_c,
-                                             lse_c).astype(q.dtype)
-            else:
-                with jax.named_scope("attn_core"):
-                    out = attend(q, ak, av, pt_l, starts, counts)
-            with jax.named_scope("attn_out"):     # ... and tokens again
-                out = lay.to_tokens(out)
+                        out = attend(q, ak, av, pt_l, starts, counts)
+                with jax.named_scope("attn_out"):     # ... and tokens again
+                    out = lay.to_tokens(out)
             attn_out = attn_out_project(cfg, lp["attn"], out)
             h_out, _aux = block_combine(cfg, lp, x, h_in, attn_out, moe_fn)
             if split:
@@ -379,7 +522,8 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
         with jax.named_scope("lm_head"):       # the rows the head projects
             return lay.last(x), tuple(kv)
 
-    x_last, (ak, av) = _at_capacity(token_capacities, counts.sum(), run)
+    x_last, (ak, av) = _at_capacity(_instances(token_capacities, n, c),
+                                    counts, run)
     if split:
         def write_layers(pools, slots, take):
             def write(carry, layer_kv):
@@ -480,42 +624,35 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         (kname, vname), pt_l, _ = place
         window, sink = cfg.kind_window(kind), a.get("sink")
         q, k, v = tl.typed_qkv(cfg, kind, a, h_in, *table)
-        with jax.named_scope("attn_qkv"):     # attention sees rows
+        with jax.named_scope("attn_qkv"):
             pad = pools[kname].shape[-1] // k.shape[2] - cfg.head_dim
             if pad:
                 q, k = (jnp.pad(t, ((0, 0),) * 3 + ((0, pad),))
                         for t in (q, k))
-            new_kv = (k, v)           # the write takes them token-wise
-            q, k, v = (lay.to_rows(t) for t in (q, k, v))
         if split:
-            with jax.named_scope("attn_history"):
-                if use_pallas:
-                    out_h, lse_h = pa.paged_attention_with_lse(
-                        q, pools[kname], pools[vname], pt_l, starts,
-                        jnp.zeros_like(starts), window=window, scale=scale,
-                        qcounts=counts)
-                else:
-                    out_h, lse_h = pa.paged_attention_hist_xla(
-                        q, pools[kname], pools[vname], pt_l, starts,
-                        window=window, scale=scale)
-            with jax.named_scope("attn_core"):
-                out_c, lse_c = pa.causal_attention_with_lse(
+            def history(q, rows):
+                return pa.paged_history_with_lse(
+                    q, pools[kname], pools[vname], rows.of(pt_l),
+                    rows.of(starts), rows.counts, kernel=use_pallas,
+                    window=window, scale=scale)
+
+            keep(chunk_kv, pools, place[0], k, v)
+            return _split_attention(
+                lay, (q, k, v), history,
+                partial(pa.causal_attention_with_lse, window=window,
+                        scale=scale), scale=scale, sink=sink)
+        write(pools, place, lay.kv_slots(), k, v)
+        with jax.named_scope("attn_qkv"):     # attention sees rows
+            q, k, v = (lay.to_rows(t) for t in (q, k, v))
+        with jax.named_scope("attn_core"):
+            if fresh_prefill == "fresh":
+                out, lse = pa.causal_attention_with_lse(
                     q, k, v, window=window, scale=scale)
-            with jax.named_scope("attn_merge"):
-                out = pa.merge_attention(out_h, lse_h, out_c, lse_c,
-                                         sink).astype(q.dtype)
-            keep(chunk_kv, pools, place[0], *new_kv)
-        else:
-            write(pools, place, lay.kv_slots(), *new_kv)
-            with jax.named_scope("attn_core"):
-                if fresh_prefill == "fresh":
-                    out, lse = pa.causal_attention_with_lse(
-                        q, k, v, window=window, scale=scale)
-                else:
-                    out, lse = pa.paged_attention_xla(
-                        q, pools[kname], pools[vname], pt_l, starts, counts,
-                        window=window, scale=scale, with_lse=True)
-                out = tl.apply_sink(out, lse, sink)
+            else:
+                out, lse = pa.paged_attention_xla(
+                    q, pools[kname], pools[vname], pt_l, starts, counts,
+                    window=window, scale=scale, with_lse=True)
+            out = tl.apply_sink(out, lse, sink)
         with jax.named_scope("attn_out"):     # ... and tokens again
             return lay.to_tokens(out)
 
@@ -528,61 +665,49 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         after); a chunk's OWN attention is expanded from the chunk's own
         latents (short: ``W_kvb`` over its tokens, heads of nope + rope)."""
         (pool,), pt_l, _ = place
-        kl, dtype = cfg.kv_lora_rank, h_in.dtype
+        kl = cfg.kv_lora_rank
         q_nope, q_rope, latent = tl.latent_qkv(cfg, a, h_in, *table)
         own = split or fresh_prefill == "fresh"
         if own:
-            q, k, v = tl.latent_expand_kv(cfg, a, q_nope, q_rope, latent)
-            with jax.named_scope("attn_qkv"):
-                q, k, v = (lay.to_rows(t) for t in (q, k, v))
+            qkv = tl.latent_expand_kv(cfg, a, q_nope, q_rope, latent)
         if fresh_prefill != "fresh":
             q_lat = tl.latent_absorb_q(cfg, a, q_nope, q_rope,
                                        pools[pool].shape[-1])
-            with jax.named_scope("attn_qkv"):
-                q_lat = lay.to_rows(q_lat)
         if split:
-            with jax.named_scope("attn_history"):
-                if use_pallas:
-                    out_h, lse_h = pa.mla_decode(
-                        q_lat, pools[pool], pt_l, starts,
-                        jnp.zeros_like(starts), counts, v_lanes=kl,
-                        scale=scale)
-                else:
-                    out_h, lse_h = pa.paged_attention_hist_xla(
-                        q_lat, pools[pool], None, pt_l, starts, scale=scale,
-                        v_lanes=kl)
-            with jax.named_scope("attn_core"):
-                out_c, lse_c = pa.causal_attention_with_lse(q, k, v,
-                                                            scale=scale)
-            with jax.named_scope("attn_out"):     # ... and tokens again
-                out_h, lse_h, out_c, lse_c = (
-                    lay.to_tokens(t) for t in (out_h, lse_h, out_c, lse_c))
-            out_h = tl.latent_expand_out(cfg, a, out_h)
-            with jax.named_scope("attn_merge"):
-                out = pa.merge_attention(out_h, lse_h, out_c, lse_c)
+            def history(q, rows):
+                return pa.paged_history_with_lse(
+                    q, pools[pool], None, rows.of(pt_l), rows.of(starts),
+                    rows.counts, kernel=use_pallas, scale=scale, v_lanes=kl)
+
             keep(chunk_kv, pools, place[0], latent)
-            return out.astype(dtype)
+            return _split_attention(
+                lay, qkv, history,
+                partial(pa.causal_attention_with_lse, scale=scale),
+                scale=scale, q_history=q_lat,
+                expand=partial(tl.latent_expand_out, cfg, a))
         write(pools, place, lay.kv_slots(), latent)
+        with jax.named_scope("attn_qkv"):     # attention sees rows
+            q, *kv = (lay.to_rows(t) for t in (qkv if own else (q_lat,)))
         with jax.named_scope("attn_core"):
             if own:
-                out = pa.causal_attention_with_lse(q, k, v, scale=scale)[0]
+                out = pa.causal_attention_with_lse(q, *kv, scale=scale)[0]
             elif use_pallas:
-                out = pa.mla_decode(q_lat, pools[pool], pt_l, starts, counts,
+                out = pa.mla_decode(q, pools[pool], pt_l, starts, counts,
                                     counts, v_lanes=kl, scale=scale)[0]
             else:
                 out = pa.paged_attention_xla(
-                    q_lat, pools[pool], None, pt_l, starts, counts,
+                    q, pools[pool], None, pt_l, starts, counts,
                     scale=scale, v_lanes=kl)
         with jax.named_scope("attn_out"):
             out = lay.to_tokens(out)
         return out if own else tl.latent_expand_out(cfg, a, out)
 
-    def run(capacity):
-        """Embedding to final norm at one capacity → (each row's last
+    def run(capacity, chunk_rows):
+        """Embedding to final norm at one instance → (each row's last
         hidden state [n, 1, D]; split: every layer's chunk (k, v), which
         wait for the loop's end, else the written pools)."""
         with jax.named_scope("embed"):     # where each token sits, too
-            lay = _TokenLayout(counts, starts, c, capacity)
+            lay = _TokenLayout(counts, starts, c, capacity, chunk_rows)
             toks = lay.to_tokens(tokens)
         x, dtype = tl.residual_stream(      # float32, whatever the weights'
             embed_tokens(cfg, params["embed"], toks, lay.positions))
@@ -602,7 +727,8 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         with jax.named_scope("lm_head"):       # the rows the head projects
             return lay.last(x), (chunk_kv if split else pools)
 
-    x_last, out = _at_capacity(token_capacities, counts.sum(), run)
+    x_last, out = _at_capacity(
+        _instances(token_capacities, tokens.shape[0], c), counts, run)
     if not split:
         return lm_logits(cfg, params, x_last)[:, 0], out
 
@@ -1388,14 +1514,23 @@ class RaggedInferenceEngineTPU:
                 nb, cb, mode, fresh)(
                 self.params, self.arena, packed, self._rng_dev)
         with tracer.span("serving/count"):
+            # the device's own rules: the instance that holds the batch
+            # (_at_capacity), and the write-back's whole blocks until the
+            # tokens are written (_write_back)
+            chunk_rows = int((batch.token_counts > 1).sum())
+            instances = _instances(capacities, nb, cb)
+            capacity, group_rows = instances[_instance_index(
+                instances, tokens, chunk_rows)] if instances else (None, nb)
+            grouped = group_rows < nb
+            attn_row_slots = group_rows * cb + nb if grouped else nb * cb
             context_slots = query_tiles = None
             if fresh == "split" and self.use_pallas:
                 # the paged reader walks each row's live pages, then the
-                # chunk attends its own keys
+                # rows attend their own keys
                 bs = self.config.block_size
-                context_slots = nb * cb + \
+                context_slots = attn_row_slots + \
                     int((-(-batch.start_positions // bs)).sum()) * bs
-                query_tiles = self._query_tiles(batch, cb)
+                query_tiles = self._query_tiles(batch, cb, grouped)
             write_block = _write_back_slots(capacities, nb * cb)[0]
             work = self._count_dispatch(
                 program, n, nb, cb, self.mb, tokens,
@@ -1405,13 +1540,10 @@ class RaggedInferenceEngineTPU:
                 # span arguments only: nothing to compute for no span
                 attn_pairs=self._attn_pairs(batch) if sp is not None
                 else None,
-                query_tiles=query_tiles,
-                # the device's own rules (_at_capacity: the smallest that
-                # holds; _write_back: whole blocks until the tokens are
-                # written)
-                token_slots=next((t for t in capacities if tokens <= t),
-                                 None),
-                kv_write_slots=-(-tokens // write_block) * write_block)
+                query_tiles=query_tiles, token_slots=capacity,
+                kv_write_slots=-(-tokens // write_block) * write_block,
+                chunk_rows=chunk_rows,
+                attn_row_slots=attn_row_slots if grouped else None)
             if sp is not None:      # still the recorded event's arguments
                 sp.update(work)
         return np.asarray(self._fetch(out))[:n]
@@ -1432,17 +1564,18 @@ class RaggedInferenceEngineTPU:
                           batch.token_counts - 1)
         return int(live.sum()), int(held.sum())
 
-    def _query_tiles(self, batch: RaggedBatch, chunk: int):
+    def _query_tiles(self, batch: RaggedBatch, chunk: int, grouped: bool):
         """(held, computed) query tiles of a split launch's history reader
         in ONE layer and KV head, or None for a latent stack (another
         kernel): of the rows that reach ``paged_attn_lse`` with a history
         and a token, the tiles of ``TILE_Q`` queries their blocks hold
-        (``chunk / TILE_Q`` a row: what the kernel computed before it took
-        ``qcounts``), and the tiles it computes — ONE for a row whose live
-        queries fit the small tile (a decode row), all of them for a row of
-        more (``paged_attention._paged_kernel``). ``TILE_Q`` is the full
-        kind's (``paged_attention.tile_queries``). Host arithmetic on the
-        batch's lengths."""
+        (``chunk / TILE_Q`` a row of the chunk's width: what the kernel
+        computed before it took ``qcounts``; ONE for a row read as a row of
+        one query, ``grouped``), and the tiles it computes — ONE for a row
+        whose live queries fit the small tile (a decode row), all of them
+        for a row of more (``paged_attention._paged_kernel``). ``TILE_Q``
+        is the full kind's (``paged_attention.tile_queries``). Host
+        arithmetic on the batch's lengths."""
         model = self.model_config
         if model.latent:
             return None
@@ -1450,7 +1583,8 @@ class RaggedInferenceEngineTPU:
         fed = batch.token_counts[(batch.start_positions > 0) &
                                  (batch.token_counts > 0)]
         whole = chunk // tile_q
-        return len(fed) * whole, int(np.where(fed <= tile_q, 1, whole).sum())
+        held = np.where((fed > 1) | (not grouped), whole, 1)
+        return int(held.sum()), int(np.where(fed <= tile_q, 1, whole).sum())
 
     def _attn_pairs(self, batch: RaggedBatch):
         """Live (query, key) pairs of the launch in ONE layer of each
@@ -1488,7 +1622,9 @@ class RaggedInferenceEngineTPU:
                         context_slots: Optional[int] = None,
                         kv_window=None, attn_pairs=None, query_tiles=None,
                         token_slots: Optional[int] = None,
-                        kv_write_slots: Optional[int] = None
+                        kv_write_slots: Optional[int] = None,
+                        chunk_rows: int = 0,
+                        attn_row_slots: Optional[int] = None
                         ) -> Dict[str, Any]:
         """Count one device program launch, right after its jitted call
         returned (``serving/count``: the device is at work by then; a
@@ -1497,8 +1633,13 @@ class RaggedInferenceEngineTPU:
         attend) against the work attempted (``slots`` = what the sublayers
         that act on a token alone ran over: ``token_slots``, the capacity
         the launch packed its tokens into, or bucketed rows x chunk width
-        where it did not pack; ``row_slots`` = bucketed rows x chunk width,
-        what attention works on; ``kv_write_slots`` = the updates the
+        where it did not pack; ``row_slots`` = what attention works on:
+        bucketed rows x chunk width, or ``attn_row_slots`` — the ``P x
+        chunk + rows`` of a split launch that took a GROUPED instance
+        (:func:`_instances`), which ``dispatch/split_grouped_steps`` counts
+        (not under ``dispatch/steps.``: those are launches);
+        ``chunk_rows`` = the rows that hold more than one token;
+        ``kv_write_slots`` = the updates the
         launch's KV scatter performs a pool and layer: the packed slots it
         wrote back, ``row_slots`` where it did not pack;
         ``context_slots`` = what the attention
@@ -1526,6 +1667,9 @@ class RaggedInferenceEngineTPU:
         slots = row_slots if token_slots is None else token_slots
         if kv_write_slots is None:
             kv_write_slots = row_slots
+        if attn_row_slots is not None:
+            registry.counter("dispatch/split_grouped_steps").inc()
+            row_slots = attn_row_slots
         if context_slots is None:
             context_slots = nb * page_width * self.config.block_size * \
                 scan_steps
@@ -1535,11 +1679,14 @@ class RaggedInferenceEngineTPU:
                          ("kv_write_slots", kv_write_slots),
                          ("context_tokens", context_tokens),
                          ("context_slots", context_slots),
+                         ("chunk_rows", chunk_rows),
+                         ("attn_row_slots", row_slots),
                          (f"steps.{program}", 1)):
             registry.counter("dispatch/" + name).inc(by)
         work = {"program": program, "rows": rows, "rows_bucket": nb,
                 "chunk": chunk, "tokens": tokens, "slots": slots,
-                "row_slots": row_slots, "kv_write_slots": kv_write_slots,
+                "row_slots": row_slots, "chunk_rows": chunk_rows,
+                "kv_write_slots": kv_write_slots,
                 "context_tokens": context_tokens,
                 "context_slots": context_slots}
         if kv_window is not None:

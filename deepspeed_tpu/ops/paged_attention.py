@@ -416,6 +416,22 @@ def causal_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     return _masked_attention(q, k, v, mask[None, None, None], True, scale)
 
 
+def one_key_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
+                               scale: Optional[float] = None):
+    """:func:`causal_attention_with_lse` of chunks of ONE token ([n, 1, h,
+    dk], [n, 1, kvh, dk], [n, 1, kvh, dv]) in closed form: a query whose
+    only key is its own has ``out = v`` (the KV head's, for each query head
+    of its group) and ``lse = scale * q·k``, float32 — no softmax, no
+    matmul over keys."""
+    n, _, h, dh = q.shape
+    kvh = k.shape[2]
+    qg = q.reshape(n, 1, kvh, h // kvh, dh)
+    s = jnp.einsum("nckgd,nckd->nckg", qg, k.astype(q.dtype),
+                   preferred_element_type=jnp.float32).reshape(n, 1, h)
+    s = s / math.sqrt(dh) if scale is None else s * scale
+    return jnp.repeat(v, h // kvh, axis=2).astype(q.dtype), s
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernel (decode / short-chunk path)
 # ---------------------------------------------------------------------------
@@ -614,6 +630,13 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, qcounts_ref, q_ref, k_hbm,
     pl.when(jnp.logical_not(live))(lambda: write_dead(0))
 
 
+# jitted (as mla_decode is): a program whose layers are unrolled calls the
+# kernel once a layer and row group, and a pallas_call traces its kernel
+# body anew every call — under jit the calls of one shape share ONE trace
+# and one lowered function (0.15-0.25 s a call on the serving host; the
+# typed 64-row split program calls it 21 times for 6 shapes)
+@functools.partial(jax.jit, static_argnames=(
+    "with_lse", "interpret", "window", "scale", "tile_q"))
 def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
                 with_lse: bool, interpret: bool, window=None, scale=None,
                 qcounts=None, tile_q=None):
@@ -838,6 +861,7 @@ def _mla_kernel(pt_ref, starts_ref, kcounts_ref, qcounts_ref, q_ref,
         lse_ref[0] = jnp.full_like(lse_ref[0], _NEG_INF)
 
 
+@functools.partial(jax.jit, static_argnames=("v_lanes", "scale", "interpret"))
 def mla_decode(q: jax.Array, pool: jax.Array, page_table: jax.Array,
                starts: jax.Array, kcounts: jax.Array, qcounts: jax.Array, *,
                v_lanes: int, scale: float, interpret: bool = False):
@@ -884,6 +908,34 @@ def mla_decode(q: jax.Array, pool: jax.Array, page_table: jax.Array,
       kcounts.astype(jnp.int32), qcounts.astype(jnp.int32),
       q.reshape(n, c * h, w), pool)
     return out.reshape(n, c, h, v_lanes), lse.reshape(n, c, h)
+
+
+def paged_history_with_lse(q: jax.Array, arena_k: jax.Array,
+                           arena_v: Optional[jax.Array],
+                           page_table: jax.Array, starts: jax.Array,
+                           qcounts: jax.Array, *, kernel: bool,
+                           window: Optional[int] = None,
+                           scale: Optional[float] = None,
+                           v_lanes: Optional[int] = None):
+    """A split step's HISTORY reader under one signature → (out, lse [n,
+    c, h] float32): row i's queries (the leading ``qcounts[i]`` are live)
+    over the keys ``[0, starts[i])`` the pools held before the step.
+    ``kernel``: the paged kernels, which walk each row's live pages and
+    compute its live queries alone — :func:`paged_attention_with_lse`, or
+    :func:`mla_decode` over a latent pool (``v_lanes``; ``arena_v`` None)
+    —; else :func:`paged_attention_hist_xla`, which gathers the page
+    table's width and computes every query."""
+    if not kernel:
+        return paged_attention_hist_xla(q, arena_k, arena_v, page_table,
+                                        starts, window=window, scale=scale,
+                                        v_lanes=v_lanes)
+    none = jnp.zeros_like(starts)
+    if v_lanes is not None:
+        return mla_decode(q, arena_k, page_table, starts, none, qcounts,
+                          v_lanes=v_lanes, scale=scale)
+    return paged_attention_with_lse(q, arena_k, arena_v, page_table, starts,
+                                    none, window=window, scale=scale,
+                                    qcounts=qcounts)
 
 
 def supported(head_dim: int, block_size: int) -> bool:

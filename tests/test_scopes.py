@@ -493,7 +493,10 @@ def test_packed_launch_counts_the_capacity_it_ran_at(traced):
     tokens into — ``max_batch_tokens``, or 16 a row when a split step's
     tokens fit that (the program's own rule) — and ``row_slots`` what
     attention still works on; ``dispatch/token_slots`` grows by the
-    former. 4 rows x chunk 96 = 384 slots over a budget of 80."""
+    former. 4 rows x chunk 96 = 384 slots over a budget of 80. The small
+    instance holds ONE row at the chunk's width (64 slots are no whole
+    chunk), so a step with two rows of more than one token takes the top
+    one whatever its tokens."""
     from deepspeed_tpu.serving import ServingFrontend
     eng = _engine(prefill_chunk=96, max_batch_tokens=80, max_sequences=4)
     assert eng._token_capacities(4, 96, "split") == (64, 80)
@@ -511,12 +514,12 @@ def test_packed_launch_counts_the_capacity_it_ran_at(traced):
     got = [(a["program"], a["rows_bucket"], a["tokens"], a["slots"],
             a["row_slots"]) for a in launches]
     assert got == [("fresh", 4, 80, 80, 384),     # 30 + 30 + 20 of 30
-                   ("split", 4, 2 + 10 + 30, 64, 384),   # fits 16 a row
+                   ("split", 4, 2 + 10 + 30, 80, 384),   # two chunk rows
                    ("decode", 4, 4, 4, 4),
                    ("decode", 4, 4, 4, 4)], got
     after = _counters()
     assert after["dispatch/token_slots"] - before["dispatch/token_slots"] \
-        == 80 + 64 + 4 + 4
+        == 80 + 80 + 4 + 4
     assert after["dispatch/tokens"] - before["dispatch/tokens"] == \
         80 + 42 + 4 + 4
     assert not [n for n in telemetry.registry.names()
@@ -652,7 +655,8 @@ PARENT = {
                      ("decode", 2, 2, 2, 31, 256),
                      ("decode", 1, 1, 1, 24, 128),
                      ("decode", 1, 1, 1, 25, 128)],
-        "counters": {"context_slots": 1792, "context_tokens": 192,
+        "counters": {"attn_row_slots": 56, "chunk_rows": 4,
+                     "context_slots": 1792, "context_tokens": 192,
                      "host_calls": 8, "kv_write_slots": 56,
                      "steps.decode": 5, "steps.fresh": 1, "steps.split": 2,
                      "token_slots": 56, "tokens": 33}},
@@ -662,7 +666,8 @@ PARENT = {
                      ("split", 5, 16, 16, 25, 256),
                      ("megastep", 7, 8, 8, 111, 256),
                      ("decode", 1, 1, 1, 25, 128)],
-        "counters": {"context_slots": 1152, "context_tokens": 192,
+        "counters": {"attn_row_slots": 57, "chunk_rows": 4,
+                     "context_slots": 1152, "context_tokens": 192,
                      "host_calls": 5, "kv_write_slots": 57,
                      "megastep_launches": 1, "megastep_tokens": 7,
                      "scan_steps": 4, "steps.decode": 1, "steps.fresh": 1,
@@ -781,7 +786,8 @@ def test_launch_arguments_counters_and_tokens_are_the_parents(traced, path):
     """Counting after the launch changed nothing that is counted: for a
     fixed batch sequence the ``serving/dispatch`` spans' arguments, every
     ``dispatch/*`` counter and the greedy tokens are what the tree before
-    PR 39 gave."""
+    PR 39 gave (``attn_row_slots`` and ``chunk_rows`` came with PR 40: in
+    the row form what attention works on is ``token_slots``)."""
     before = _dispatch_counters()
     _fe, reqs = _pump(path)
     after = _dispatch_counters()

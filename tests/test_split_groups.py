@@ -1,0 +1,315 @@
+"""A packed split step's attention on two row groups (PR 40): the rows of
+more than one token in a chunk group ``[P, c]``, every row as a row of ONE
+query ``[n, 1]`` — against the same step with every row at the chunk's
+width (``engine_v2._TokenLayout.groups``, ``_split_attention``,
+``_at_capacity``)."""
+
+import functools
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference import engine_v2
+from deepspeed_tpu.inference.engine_v2 import (RaggedInferenceEngineTPU,
+                                               ragged_forward)
+from deepspeed_tpu.ops import paged_attention as pa
+from deepspeed_tpu.parallel.mesh import build_mesh
+from tests.test_paged import _packed_stack
+
+N, C, BS, MB = 8, 16, 8, 8
+#: the 8 x 16 program's instances: 32 slots with TWO rows at the chunk's
+#: width, 64 slots with all eight
+CAPACITIES = (32, 64)
+P = 2
+#: mix -> (tokens a row feeds, tokens it has cached, does the small
+#: instance take it). Histories pass the typed stack's window of 24
+MIXES = {
+    "no_chunk_row": ([1, 1, 1, 0, 1, 1, 1, 1],
+                     [30, 7, 41, 0, 9, 16, 3, 25], True),
+    "exactly_p": ([16, 1, 9, 1, 0, 1, 1, 1],
+                  [16, 7, 41, 12, 0, 16, 3, 25], True),
+    "p_plus_1": ([5, 1, 9, 1, 0, 3, 1, 1],
+                 [16, 7, 41, 12, 0, 16, 3, 25], False),
+    "fresh_chunk_beside_decode_rows": ([1, 12, 1, 1, 1, 1, 1, 1],
+                                       [30, 0, 41, 12, 5, 16, 3, 25], True),
+    "rows_without_tokens_first_and_last": ([0, 16, 1, 0, 2, 1, 1, 0],
+                                           [0, 8, 41, 0, 33, 16, 3, 0], True),
+    # positions 16..31 over 16 cached: the window of 24 ends inside the
+    # chunk's own keys for its last queries and inside the history for its
+    # first
+    "chunk_crosses_the_windows_edge": ([1, 1, 16, 1, 1, 10, 1, 1],
+                                       [30, 7, 16, 12, 5, 20, 3, 25], True),
+    "tokens_over_the_small_capacity": ([16, 1, 16, 1, 1, 1, 1, 1],
+                                       [16, 7, 32, 12, 5, 20, 3, 25], False),
+}
+
+
+def _pages(counts, starts, rng, nb=40):
+    """(page table, pages of the arena): the rows' pages out of order; one
+    arena size for every mix, so a stack's programs compile once."""
+    pages = -(-(starts + counts) // BS)
+    assert pages.sum() <= nb
+    pt = np.full((N, MB), nb, np.int32)
+    free = iter(rng.permutation(nb))
+    for i in range(N):
+        pt[i, :pages[i]] = [next(free) for _ in range(pages[i])]
+    return pt, nb
+
+
+def test_instance_rule_is_one_for_the_program_and_the_host():
+    """``_at_capacity`` inside a program picks the instance
+    ``_instance_index`` names on the host, for every mix: the small one iff
+    the tokens fit its slots AND the rows of more than one token fit its
+    chunk group."""
+    instances = engine_v2._instances(CAPACITIES, N, C)
+    assert instances == ((32, P), (64, N))
+    assert engine_v2._instances((1024, 2048), 64, 128) == \
+        ((1024, 8), (2048, 64))
+    assert engine_v2._instances((2048,), 32, 128) == ((2048, 32),)
+    ran = jax.jit(lambda counts: engine_v2._at_capacity(
+        instances, counts, lambda cap, rows: jnp.int32(cap)))
+    for name, (counts, _starts, small) in MIXES.items():
+        counts = np.asarray(counts, np.int32)
+        index = engine_v2._instance_index(
+            instances, int(counts.sum()), int((counts > 1).sum()))
+        assert index == (0 if small else 1), name
+        assert int(ran(jnp.asarray(counts))) == instances[index][0], name
+
+
+@functools.lru_cache(maxsize=None)
+def _stack_steps(stack, dtype):
+    """(cfg, params, arena maker, history writer, step(capacities)) of a
+    stack in a compute dtype, each program jitted once for all mixes."""
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg, params, make_arena = _packed_stack(stack)
+    if make_arena is None:      # the latent stack: one pool
+        def make_arena(nb, bs):
+            return pa.init_arena_typed(
+                cfg.layer_kinds, {2: 1}, nb, bs, cfg.latent_dim, cfg.v_dim,
+                jnp.float32)
+    if dtype == "bfloat16":
+        params = jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16)
+            if a.dtype == jnp.float32 else a, params)
+    history = jax.jit(lambda arena, toks, counts, pt: ragged_forward(
+        cfg, params, arena, toks, counts, jnp.zeros_like(counts), pt)[1])
+
+    @functools.lru_cache(maxsize=None)
+    def step(capacities):
+        return jax.jit(lambda arena, *a: ragged_forward(
+            cfg, params, arena, *a, fresh_prefill="split",
+            token_capacities=capacities))
+    return cfg, make_arena, history, step
+
+
+@pytest.mark.parametrize("mix", list(MIXES))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("stack", ["uniform", "typed", "latent"])
+def test_grouped_split_step_matches_the_all_rows_instance(devices, stack,
+                                                          dtype, mix):
+    """A packed split step of a program that holds a grouped instance (32
+    slots, 2 chunk rows) and the all-rows one (64 slots), against the same
+    step through a program that holds the all-rows instance alone: the
+    logits of every row that fed a token, and every pool outside the
+    layers' trash pages."""
+    cfg, make_arena, history, step = _stack_steps(stack, dtype)
+    counts, starts, _small = (np.asarray(a) for a in MIXES[mix])
+    counts, starts = counts.astype(np.int32), starts.astype(np.int32)
+    rng = np.random.default_rng(len(mix))
+    pt, nb = _pages(counts, starts, rng)
+    toks = lambda *shape: jnp.asarray(
+        rng.integers(0, cfg.vocab_size, shape), jnp.int32)
+    arena = make_arena(nb, BS)
+    if dtype == "bfloat16":
+        arena = {k: v.astype(jnp.bfloat16) for k, v in arena.items()}
+    arena = history(arena, toks(N, 48), jnp.asarray(starts), jnp.asarray(pt))
+    args = (toks(N, C), jnp.asarray(counts), jnp.asarray(starts),
+            jnp.asarray(pt))
+    want_logits, want = step(CAPACITIES[1:])(arena, *args)
+    got_logits, got = step(CAPACITIES)(arena, *args)
+    live = counts > 0
+    tol = 2e-4 if dtype == "float32" else 6e-2
+    assert np.abs(np.asarray(want_logits)[live]).max() > 0.1
+    np.testing.assert_allclose(np.asarray(got_logits)[live],
+                               np.asarray(want_logits)[live],
+                               rtol=tol, atol=tol)
+    assert set(got) == set(want)
+    for name in want:
+        kept = np.arange(want[name].shape[0]) % (nb + 1) != nb
+        a, b, before = (np.asarray(x[name], np.float32)[kept]
+                        for x in (got, want, arena))
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
+        assert not np.array_equal(a, before), name     # the step wrote
+
+
+#: layer kind -> (query heads, kv heads, K width, V width, window, sink,
+#: latent pool (value lanes) or None): what the three stacks hand
+#: ``_split_attention``
+_KINDS = {"uniform": (4, 2, 16, 16, None, False, None),
+          "window_sink": (4, 2, 24, 16, 24, True, None),
+          "latent": (4, 4, 24, 16, None, False, 16)}
+
+
+@pytest.mark.parametrize("mix", [m for m, v in MIXES.items() if v[2]])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", list(_KINDS))
+def test_chunk_rows_attention_is_bit_equal_in_a_group(kind, dtype, mix):
+    """``_split_attention`` over the grouped layout against the all-rows
+    layout at ONE capacity, the same packed q, k, v and pools: a packed
+    slot of a chunk row holds the same bits (its row's computation is the
+    row form's, in a group of ``P`` rows for ``n``), a slot of a one-token
+    row the same number to the rounding of one dot product; a slot past
+    the tokens is nobody's."""
+    h, kvh, dk, dv, window, has_sink, v_lanes = _KINDS[kind]
+    counts, starts, _ = (np.asarray(a, np.int32) for a in MIXES[mix])
+    rng = np.random.default_rng(len(mix) + h)
+    dt = jnp.dtype(dtype)
+    pt, nb = _pages(counts, starts, rng)
+    cap, scale = CAPACITIES[0], 0.2
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), dt)
+    qkv = (normal(1, cap, h, dk), normal(1, cap, kvh, dk),
+           normal(1, cap, kvh, dv))
+    sink = normal(h) if has_sink else None
+    kw = {}
+    if v_lanes is None:
+        pools = (normal(nb + 1, BS, kvh * dk), normal(nb + 1, BS, kvh * dv))
+    else:       # absorbed queries over one pool, W_UV after
+        pools = (normal(nb + 1, BS, dk), None)
+        w_uv = normal(v_lanes, h, dv)
+        kw = {"q_history": normal(1, cap, h, dk),
+              "expand": lambda o: jnp.einsum("bthl,lhv->bthv", o, w_uv)}
+
+    def history(q, rows):
+        return pa.paged_history_with_lse(
+            q, *pools, rows.of(jnp.asarray(pt)), rows.of(jnp.asarray(starts)),
+            rows.counts, kernel=False, window=window, scale=scale,
+            v_lanes=v_lanes)
+
+    def attend(chunk_rows):
+        lay = engine_v2._TokenLayout(jnp.asarray(counts),
+                                     jnp.asarray(starts), C, cap, chunk_rows)
+        assert len(lay.groups()) == (1 if chunk_rows is None else 2)
+        return np.asarray(engine_v2._split_attention(
+            lay, qkv, history,
+            functools.partial(pa.causal_attention_with_lse, window=window,
+                              scale=scale),
+            scale=scale, sink=sink, **kw)[0], np.float32), lay
+
+    want, lay = attend(None)
+    got, _ = attend(P)
+    assert got.shape == want.shape == (cap, h, dv)
+    row = np.asarray(lay.row[0])
+    valid = np.asarray(lay.valid[0])
+    wide = (counts > 1)[row] & valid
+    assert wide.sum() == counts[counts > 1].sum()
+    np.testing.assert_array_equal(got[wide], want[wide])
+    one = valid & ~wide
+    assert one.sum() == (counts == 1).sum() and np.abs(want[one]).max() > .01
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(got[one], want[one], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("groups", [4, 16])
+@pytest.mark.parametrize("window", [None, 40])
+def test_history_kernel_at_one_query_a_row_matches_the_xla_reader(window,
+                                                                  groups):
+    """The one-token group's history call — ``paged_history_with_lse`` of
+    ``[n, 1]`` queries through the kernel (interpret mode), ``window=``
+    and all — against ``paged_attention_hist_xla``: live rows within the
+    readers' tolerance, a row that rides along (no live query) zeros and
+    an lse of -1e30, as a row with no history."""
+    rng = np.random.default_rng(groups)
+    kvh, bs, mb, nb, dk, dv = 2, 16, 6, 40, 256, 128
+    live = np.asarray([1, 1, 0, 1, 1, 0, 1], np.int32)
+    starts = np.asarray([50, 90, 33, 64, 17, 48, 0], np.int32)
+    n = len(live)
+    pt = np.full((n, mb), nb, np.int32)
+    free = iter(rng.permutation(nb))                # pages out of order
+    for i in range(n):
+        for b in range(-(-starts[i] // bs)):
+            pt[i, b] = next(free)
+    ak = jnp.asarray(rng.standard_normal((nb + 1, bs, kvh * dk)), jnp.float32)
+    av = jnp.asarray(rng.standard_normal((nb + 1, bs, kvh * dv)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((n, 1, kvh * groups, dk)),
+                    jnp.float32)
+    args = (q, ak, av, jnp.asarray(pt), jnp.asarray(starts),
+            jnp.asarray(live))
+    real = pa.paged_attention_with_lse
+    try:
+        pa.paged_attention_with_lse = functools.partial(real, interpret=True)
+        out, lse = (np.asarray(a) for a in pa.paged_history_with_lse(
+            *args, kernel=True, window=window, scale=0.1))
+    finally:
+        pa.paged_attention_with_lse = real
+    out_x, lse_x = (np.asarray(a) for a in pa.paged_history_with_lse(
+        *args, kernel=False, window=window, scale=0.1))
+    seen = (live > 0) & (starts > 0)
+    assert seen.tolist() == [True, True, False, True, True, False, False]
+    assert (out[~seen] == 0).all() and (lse[~seen] <= -1e29).all()
+    assert (lse_x[-1] <= -1e29).all()
+    np.testing.assert_allclose(lse[seen], lse_x[seen], rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(out[seen], out_x[seen], rtol=2e-5, atol=2e-5)
+
+
+#: launch -> (prompts that arrive beside 12 decode rows, is the launch
+#: grouped). 16 rows x chunk 96 over a budget of 320: instances of 256
+#: slots with 2 chunk rows and 320 with 16
+_ENGINE_LAUNCHES = {"one_chunk_row": ((20,), True),
+                    "exactly_p": ((20, 40), True),
+                    "p_plus_1": ((20, 40, 7), False)}
+
+
+@pytest.mark.parametrize("launch", list(_ENGINE_LAUNCHES))
+def test_engine_counts_the_instance_its_split_launch_took(devices, launch):
+    """``dispatch/split_grouped_steps``, ``dispatch/chunk_rows``,
+    ``dispatch/attn_row_slots`` (and ``token_slots``, ``context_slots``'
+    own keys) of a split launch by the program's own rule, and the tokens
+    it samples: those of an engine whose programs keep the row form."""
+    from deepspeed_tpu import telemetry
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg, params, _ = _packed_stack("uniform")
+    arrivals, grouped = _ENGINE_LAUNCHES[launch]
+    rng = np.random.default_rng(7)
+    decoding = [rng.integers(0, cfg.vocab_size, 3).tolist()
+                for _ in range(12)]
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in arrivals]
+    names = ("split_grouped_steps", "chunk_rows", "attn_row_slots",
+             "token_slots", "steps.split")
+
+    def counters():
+        return {n: telemetry.registry.counter("dispatch/" + n).value
+                for n in names}
+
+    def serve(max_batch_tokens):
+        eng = RaggedInferenceEngineTPU(
+            cfg, {"dtype": "float32", "max_sequences": 16, "num_blocks": 64,
+                  "block_size": 8, "max_seq_len": 128, "prefill_chunk": 96,
+                  "max_batch_tokens": max_batch_tokens}, params=params)
+        uids = list(range(12))
+        eng.scheduler.put(uids, decoding)
+        out = eng.step_with_budget(budget=320)
+        assert eng.last_program == "fresh"
+        eng.scheduler.put(uids, [[int(out[u])] for u in uids])
+        eng.scheduler.put([12 + i for i in range(len(prompts))], prompts)
+        before = counters()
+        out = eng.step_with_budget(budget=320)
+        assert eng.last_program == "split"
+        return eng, {u: int(t) for u, t in out.items()}, \
+            {n: v - before[n] for n, v in counters().items()}
+
+    eng, got, grew = serve(320)
+    assert eng._token_capacities(16, 96, "split") == (256, 320)
+    assert engine_v2._instances((256, 320), 16, 96) == ((256, 2), (320, 16))
+    _rows, want, row_form = serve(16 * 96)
+    assert got == want and len(got) == 12 + len(arrivals)
+    assert grew == {"split_grouped_steps": int(grouped),
+                    "chunk_rows": len(arrivals),
+                    "attn_row_slots": 2 * 96 + 16 if grouped else 16 * 96,
+                    "token_slots": 256 if grouped else 320,
+                    "steps.split": 1}
+    assert row_form == {"split_grouped_steps": 0,
+                        "chunk_rows": len(arrivals),
+                        "attn_row_slots": 16 * 96, "token_slots": 16 * 96,
+                        "steps.split": 1}

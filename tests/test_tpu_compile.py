@@ -298,6 +298,31 @@ _SERVE_STEPS = {
 }
 
 
+#: stack -> temporaries of its 64-row split program at two layers before
+#: PR 40 (bytes; this compiler, the tree at PR 39). The instance at 1,024
+#: slots now runs attention on two row groups — 8 rows at the chunk's width
+#: and 64 rows of one query — and must not grow them: measured +0.5, +0.6
+#: and +1.1 MB (the groups' index arrays), so 2 MB of room
+_SPLIT_TEMPS_PR39 = {"uniform": 457330176, "mimo": 1046920704,
+                     "latent": 1631744000}
+
+
+def _check_split_groups(stack, compiled, text, kernel, layer_loops):
+    """The 64-row split program of ``stack``: two instances of the layer
+    loop in one executable, the history ``kernel`` called three times a
+    layer loop's layer (all 64 rows at the chunk's width in the top
+    instance; the chunk group and the one-query rows in the small one),
+    and temporaries no larger than before the groups."""
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    assert len(_branches(text)) == 2, _branches(text)
+    table = scope_table_from_hlo(text)
+    kernels = [n for n in table if n.startswith(kernel)]
+    assert len(kernels) == 3 * layer_loops and \
+        all(table[n]["scope"] == "attn_history" for n in kernels), kernels
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= _SPLIT_TEMPS_PR39[stack] + 2e6, temp
+
+
 def _serve_step(one_chip, kind="split"):
     """A 64-row step of the benchmark's serving cell (``serve_decode_r64``,
     ``serve_split_r64_c128``, ``serve_fresh_r64_c128``): chunk 128 or one
@@ -521,13 +546,9 @@ def test_no_serve_step_moves_the_arena(kind, one_chip, no_persistent_cache,
         max(updates) <= _top_slots(_SERVE_STEPS[kind]), updates
     if kind != "split":
         return
-    table = scope_table_from_hlo(text)
-    kernels = [n for n in table if n.startswith("paged_attn_lse")]
-    assert kernels and all(table[n]["scope"] == "attn_history"
-                           for n in kernels), kernels
-    # one instance of the layer loop a capacity, in ONE executable
-    branches = _branches(text)
-    assert len(branches) == len(_SERVE_STEPS[kind][2]), branches
+    # one instance of the layer loop a capacity, in ONE executable (the
+    # layers are one scan: one kernel call a row group)
+    _check_split_groups("uniform", compiled, text, "paged_attn_lse", 1)
     assert compiled.cost_analysis()["flops"] <= 0.35 * 3.593e12
 
 
@@ -632,13 +653,15 @@ def test_mimo_step_scatters_its_token_slots(kind, one_chip,
         "mimo-v2.5-l7-e16-serve.json")))
     model = model_lib.build_model({**conf, "num_hidden_layers": 2})
     assert model.layer_kinds == (0, 1) and model.head_dim == 192
-    _, text = _typed_step(
+    compiled, text = _typed_step(
         one_chip, model, _SERVE_STEPS[kind], 8, lambda: pa.init_arena_typed(
             model.layer_kinds,
             {a: model.kind_kv_heads(a) for a in set(model.layer_kinds)},
             512, 128, 256, model.v_dim, jnp.bfloat16))
     assert "kv_write" in {e["scope"] for e in
                           scope_table_from_hlo(text).values()}
+    if kind == "split":
+        _check_split_groups("mimo", compiled, text, "paged_attn_lse", 2)
 
 
 @pytest.mark.parametrize("kind", list(_LATENT_STEPS))
@@ -672,6 +695,8 @@ def test_latent_step_reads_the_pool_absorbed(kind, one_chip,
         {e["scope"] for e in table.values()}
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < most, temp
+    if kind == "split":
+        _check_split_groups("latent", compiled, text, "mla_decode", 2)
     if kind == "decode":
         expanded = re.findall(r"\[64,4352,64,\d+\]|\[64,64,4352,\d+\]",
                               text)

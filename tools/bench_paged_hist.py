@@ -25,7 +25,17 @@ decode rows of ONE live query + 3 rows of 128; cell 4: every row one; cell
 6: 12 of one + 4 of 128), ``one`` = every row one, ``all`` = every row all
 ``c``, ``none`` = no row any (what the grid and the q / out / lse blocks
 cost with no page walked). One JSON object a line; the lines also go to
-``--out``."""
+``--out``.
+
+``--groups`` (PR 40): what a GROUPED split step calls in place of the row
+form's one ``[rows, chunk]`` call, at the same inputs — ``c1``: every row
+as a row of ONE query (``[rows, 1]``, the rows of one live token live),
+``chunk8``: the chunk group ``[8, chunk]`` (the cell's rows of a whole live
+chunk, the rest riding along dead; cells 4 and 5, whose prompts are one
+chunk: two fresh rows, no history) — beside ``rows``, the row form's call
+at the cell's mix; also cell 5
+``gigachat3.1-l5-e16-serve-reason-long-closed64``'s ``mla_decode`` (64
+heads over a latent pool 640 lanes wide, contexts 128–4,200)."""
 
 import argparse
 import importlib.util
@@ -127,37 +137,68 @@ def reader(mod, shape, interpret, tile_q=None):
         tile_q=tile_q))
 
 
-def main():
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--parent-file")
-    ap.add_argument("--sweep", action="store_true")
-    ap.add_argument("--rehearse", action="store_true")
-    ap.add_argument("--seed", type=int, default=3800000011)
-    ap.add_argument("--reps", type=int, default=10)
-    ap.add_argument("--rounds", type=int, default=5)
-    ap.add_argument("--shapes", default="")
-    ap.add_argument("--out", default="chiprun_out/bench_paged_hist.jsonl")
-    a = ap.parse_args()
-    dev = jax.devices()[0]
-    if dev.platform != "tpu" and not a.rehearse:
-        sys.exit("no TPU here: --rehearse runs the control flow on the CPU")
-    shapes = TINY if a.rehearse else SHAPES
-    if a.shapes:
-        shapes = {k: shapes[k] for k in a.shapes.split(",")}
-    block = 16 if a.rehearse else BS
-    mods = [("change", pa_here, None)]
-    if a.parent_file:
-        mods.insert(0, ("parent", load(a.parent_file), None))
-    if a.sweep:
-        mods += [(f"tile_q{t}", pa_here, t) for t in SWEEP]
-    os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
-    lines = []
+#: the latent cell: (rows, chunk, heads, pool lanes, value lanes, pages a
+#: row, arena pages, context range, softmax scale)
+LATENT = {"cell5_latent": (64, 128, 64, 640, 512, 34, 2176, (128, 4200),
+                           192 ** -0.5)}
+TINY_LATENT = {"tiny_latent": (4, 16, 4, 128, 64, 4, 16, (8, 40), 0.1)}
+GROUP_ROWS = 8
 
-    def say(**line):
-        line["device"] = {"platform": dev.platform, "kind": dev.device_kind}
-        lines.append(line)
-        print(json.dumps(line), flush=True)
 
+def group_calls(q, pt, st, qc, c):
+    """(label, q, page table, starts, live queries) of the row form's call
+    and of the two a grouped step makes in its place."""
+    n = q.shape[0]
+    wide = np.flatnonzero(np.asarray(qc) > 1)
+    ids = np.zeros(min(GROUP_ROWS, n), np.int32)
+    ids[:len(wide)] = wide
+    live = jnp.asarray(np.arange(len(ids)) < len(wide))
+    if not len(wide):       # a one-chunk prompt: fresh rows, no history
+        g_st = jnp.zeros(len(ids), jnp.int32)
+        g_qc = jnp.asarray([c, c] + [0] * (len(ids) - 2), jnp.int32)
+    else:
+        g_st, g_qc = st[ids], jnp.where(live, qc[ids], 0)
+    return (("rows", q, pt, st, qc),
+            ("c1", q[:, :1], pt, st, (qc == 1).astype(jnp.int32)),
+            (f"chunk{len(ids)}", q[ids], pt[ids], g_st, g_qc))
+
+
+def groups_section(a, say, block):
+    heads = {k: v for k, v in (TINY if a.rehearse else SHAPES).items()
+             if not k.startswith("cell6")}
+    for name, shape in heads.items():
+        c = shape[1]
+        mix = "cell" if shape[11] else "one"
+        q, ak, av, pt, st, qc = inputs(shape, mix, a.seed, block)
+        fn = reader(pa_here, shape, a.rehearse)
+        for label, gq, gpt, gst, gqc in group_calls(q, pt, st, qc, c):
+            sec, _ = timed(fn, (gq, ak, av, gpt, gst, gqc), a.reps,
+                           a.rounds)
+            say(shape=name, mix=mix, call=label, rows=list(gq.shape[:2]),
+                ms_a_call=sec * 1e3)
+    for name, (n, c, h, w, vl, mb, pages, (lo, hi), scale) in \
+            (TINY_LATENT if a.rehearse else LATENT).items():
+        rng = np.random.default_rng(a.seed)
+        st = jnp.asarray(rng.integers(lo, min(hi, mb * block - c) + 1, n),
+                         jnp.int32)
+        pt = jnp.asarray(np.stack([rng.permutation(pages)[:mb]
+                                   for _ in range(n)]), jnp.int32)
+        kq, kp = jax.random.split(jax.random.PRNGKey(a.seed % (2 ** 31)))
+        q = jax.random.normal(kq, (n, c, h, w), jnp.bfloat16)
+        pool = jax.random.normal(kp, (pages + 1, block, w), jnp.bfloat16)
+        fn = jax.jit(lambda q, pool, pt, st, qc: pa_here.mla_decode(
+            q, pool, pt, st, jnp.zeros_like(st), qc, v_lanes=vl,
+            scale=scale, interpret=a.rehearse))
+        for label, gq, gpt, gst, gqc in group_calls(
+                q, pt, st, jnp.ones_like(st), c):
+            sec, _ = timed(fn, (gq, pool, gpt, gst, gqc), a.reps, a.rounds)
+            say(shape=name, mix="one", call=label, rows=list(gq.shape[:2]),
+                ms_a_call=sec * 1e3)
+
+
+def turns_section(a, say, shapes, mods, block):
+    """PR 38's table: the row form's call by the rows' live queries, every
+    kernel of ``mods`` on the same inputs, then the decode programs' reader."""
     for name, shape in shapes.items():
         c, h, kvh = shape[1:4]
         for mix in ("cell", "one", "all", "none"):
@@ -204,6 +245,44 @@ def main():
             work = int((-(-(np.asarray(st) + 1) // BS)).sum()) * shape[3]
             say(shape="cell2_decode_c1", kernel=label, ms_a_call=sec * 1e3,
                 page_turns=work, us_a_turn=sec * 1e6 / work)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--parent-file")
+    ap.add_argument("--sweep", action="store_true")
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--groups", action="store_true")
+    ap.add_argument("--seed", type=int, default=3800000011)
+    ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--rounds", type=int, default=5)
+    ap.add_argument("--shapes", default="")
+    ap.add_argument("--out", default="chiprun_out/bench_paged_hist.jsonl")
+    a = ap.parse_args()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not a.rehearse:
+        sys.exit("no TPU here: --rehearse runs the control flow on the CPU")
+    shapes = TINY if a.rehearse else SHAPES
+    if a.shapes:
+        shapes = {k: shapes[k] for k in a.shapes.split(",")}
+    block = 16 if a.rehearse else BS
+    mods = [("change", pa_here, None)]
+    if a.parent_file:
+        mods.insert(0, ("parent", load(a.parent_file), None))
+    if a.sweep:
+        mods += [(f"tile_q{t}", pa_here, t) for t in SWEEP]
+    os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
+    lines = []
+
+    def say(**line):
+        line["device"] = {"platform": dev.platform, "kind": dev.device_kind}
+        lines.append(line)
+        print(json.dumps(line), flush=True)
+
+    if a.groups:
+        groups_section(a, say, block)
+    else:
+        turns_section(a, say, shapes, mods, block)
     with open(a.out, "w") as f:
         f.write("".join(json.dumps(l) + "\n" for l in lines))
 

@@ -4,7 +4,8 @@ always-on host-path counters over the measured window alone: every argument
 is ``benchmark/run.py``'s, and the last line printed is one more,
 
     {"phase": "host_counters", "launches", "host_s", "fetch_wait_s",
-     "host_ms_per_launch", "fetch_wait_ms_per_launch", "host_share"}
+     "host_ms_per_launch", "fetch_wait_ms_per_launch", "host_share",
+     "window": {<the WORK counters' growth over the window>}}
 
 ``dispatch/host_seconds`` and ``dispatch/fetch_wait_seconds``
 (``engine_v2._fetch``) less what they read when the window opened, over the
@@ -14,7 +15,11 @@ start and stop stall the pump between two launches for seconds that
 ``dispatch/host_seconds`` takes for host time (PERF.md section 7). Run
 with ``--trace 0`` this gives a replica's host share undisturbed; the
 cost of the instrumentation is read from runs with the tracer on against
-off at one seed (PERF.md section 6, PR 39).
+off at one seed (PERF.md section 6, PR 39). ``window`` holds what the
+launches of the window alone counted (``dispatch/steps.split``,
+``split_grouped_steps``, ``chunk_rows``, ``attn_row_slots``, ``tokens``,
+``token_slots``): the share of split launches that took a grouped instance
+and what attention worked on (PR 40; a tree without a counter reads 0).
 
     chiprun --chips 1 -- python3 tools/host_path_probe.py \
         --workload <cell> --seed <n> --trace <0|1>
@@ -30,11 +35,14 @@ from benchmark import run as bench_run                # noqa: E402
 
 NAMES = ("dispatch/host_seconds", "dispatch/fetch_wait_seconds",
          "dispatch/host_calls")
+WORK = ("steps.split", "split_grouped_steps", "chunk_rows",
+        "attn_row_slots", "tokens", "token_slots")
 
 
 def counters():
     from deepspeed_tpu.telemetry.registry import registry
-    return [registry.counter(n).value for n in NAMES]
+    return [registry.counter(n).value
+            for n in NAMES + tuple("dispatch/" + w for w in WORK)]
 
 
 def main() -> int:
@@ -47,14 +55,16 @@ def main() -> int:
     bench_run.Context.open_window = opened
     rc = bench_run.main()
     if at_open:
-        host, wait, calls = (b - a for a, b in zip(at_open, counters()))
+        host, wait, calls, *work = (b - a for a, b in
+                                    zip(at_open, counters()))
         print(json.dumps({
             "phase": "host_counters", "launches": int(calls),
             "host_s": host, "fetch_wait_s": wait,
             "host_ms_per_launch": 1e3 * host / max(1, calls),
             "fetch_wait_ms_per_launch": 1e3 * wait / max(1, calls),
             "host_share": 100.0 * host / (host + wait)
-            if host + wait else None}), flush=True)
+            if host + wait else None,
+            "window": dict(zip(WORK, work))}), flush=True)
     return rc
 
 
