@@ -36,6 +36,7 @@ from deepspeed_tpu.models.transformer import (DecoderConfig, _mlp, _norm,
                                               rope_table)
 from deepspeed_tpu.models import typed_layers as tl
 from deepspeed_tpu.ops import paged_attention as pa
+from deepspeed_tpu.ops import ssm
 from deepspeed_tpu.utils.logging import log_dist
 
 
@@ -221,17 +222,36 @@ def _instance_index(instances, tokens, chunk_rows):
                for cap, rows in instances[:-1])
 
 
-def _at_capacity(instances, counts: jax.Array, run):
-    """``run(capacity, chunk_rows)`` at the first of the STATIC
+def _at_capacity(instances, counts: jax.Array, run, *carried):
+    """``run(capacity, chunk_rows, *carried)`` at the first of the STATIC
     ``instances`` (:func:`_instances`) that holds the batch
     (:func:`_instance_index`): one branch each of a ``lax.switch`` inside
     the ONE program, so what a batch holds picks the work and no program
     key is added. ``run``'s outputs have the same shapes at every
-    instance. No instance: the row form."""
+    instance. ``carried`` (a recurrent stack's state pools): what the
+    instance UPDATES and hands back as its LAST output. A conditional does
+    not pass a pool through in place — the compiler copies it out of the
+    branch and again into the donated buffer, 1.6 GB each at the hybrid
+    cell's widths (tests/test_tpu_compile.py) — where a loop's carry does:
+    with ``carried``, each instance is the body of a loop of ONE trip if it
+    is the batch's and none if not, the pools and the outputs its carry.
+    No instance: the row form."""
     if len(instances) <= 1:
-        return run(*(instances[0] if instances else (None, None)))
+        return run(*(instances[0] if instances else (None, None)), *carried)
     index = _instance_index(instances, counts.sum(), (counts > 1).sum())
-    return lax.switch(index, [partial(run, *inst) for inst in instances])
+    if not carried:
+        return lax.switch(index,
+                          [partial(run, *inst) for inst in instances])
+    shapes = jax.eval_shape(partial(run, *instances[0]), *carried)
+    outs = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes[:-1])
+    for k, inst in enumerate(instances):
+        def trip(_, carry, inst=inst):
+            *outs, state = run(*inst, *carry[0])
+            return (state,), tuple(outs)
+
+        carried, outs = lax.fori_loop(
+            0, (index == k).astype(jnp.int32), trip, (carried, outs))
+    return (*outs, *carried)
 
 
 def _slot_major(t: jax.Array, slots: int) -> jax.Array:
@@ -340,13 +360,16 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
                    page_table: jax.Array, use_pallas: bool = False,
                    moe_fn=None,
                    fresh_prefill: Union[bool, str] = False,
-                   token_capacities: Tuple[int, ...] = ()):
+                   token_capacities: Tuple[int, ...] = (),
+                   slots: Optional[jax.Array] = None):
     """One forward over a ragged batch against the paged KV arena.
 
     tokens: [n, c] (row i valid for j < counts[i]); starts: [n] tokens
     already cached; page_table: [n, mb]. Returns (last-token logits [n, V]
     fp32, updated arena). Rows with counts == 0 produce garbage logits the
-    caller ignores.
+    caller ignores. ``slots`` [n] (a recurrent stack only,
+    ``cfg.recurrent``): each row's slot of the state pools, padding rows
+    the pools' trash slot.
 
     ``fresh_prefill`` (STATIC): False → every chunk attends through the
     paged arena (the original path). "fresh" → promise that every row
@@ -422,7 +445,8 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     if cfg.typed:
         return _ragged_forward_typed(cfg, params, arena, tokens, counts,
                                      starts, page_table, use_pallas,
-                                     moe_fn, fresh_prefill, token_capacities)
+                                     moe_fn, fresh_prefill, token_capacities,
+                                     slots)
     if cfg.pos_emb == "alibi":
         # the paged kernels have no score-bias port; serving BLOOM-class
         # models needs the v1 cached engine (forward_with_cache applies
@@ -546,7 +570,8 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                           tokens: jax.Array, counts: jax.Array,
                           starts: jax.Array, page_table: jax.Array,
                           use_pallas: bool, moe_fn, fresh_prefill,
-                          token_capacities: Tuple[int, ...] = ()):
+                          token_capacities: Tuple[int, ...] = (),
+                          slots: Optional[jax.Array] = None):
     """:func:`ragged_forward` for a typed layer stack (models/
     typed_layers.py has the equations): the same three modes over the
     same page table and the same token layouts, the layer loop unrolled
@@ -577,7 +602,24 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     ``v_lanes`` elsewhere), and a chunk's own attention is EXPANDED from
     the chunk's own latents; ``merge_attention`` joins the two partials of
     a split step in the heads' space, on the packed tokens. The step's
-    shape picks the form: no option does."""
+    shape picks the form: no option does.
+
+    A STATE-SPACE layer (kind 3) has no pages: what its rows carry lives in
+    the state pools (``ops/ssm.init_state_pools``), a slot a sequence
+    (``slots``), read and written by every launch that holds the row, in
+    every mode; a row at position 0 starts from zero whatever its slot
+    held. The layout's row groups pick the FORM of its scan as they pick
+    attention's (``state_space`` below): a row of the chunk's width takes
+    the chunk form from its carried state, a row of one query the
+    recurrence. The pools are carried THROUGH the capacity switch
+    (:func:`_at_capacity`), each instance updating them in place: the new
+    states of 64 rows are as large as the pool, so they cannot wait for the
+    loop's end as a chunk's K/V do. A layer with no mixer (kind -1) is its
+    feed-forward part on ``ln1``'s output; a layer with no feed-forward
+    part ends at its mixer (``tl.block_residual``)."""
+    if cfg.recurrent and slots is None:
+        raise ValueError("a recurrent stack (state-space layers) needs each "
+                         "row's slot of the state pools: slots=[n]")
     c = tokens.shape[1]
     split = fresh_prefill == "split" and c > 1
     scale = cfg.attn_scale
@@ -586,6 +628,15 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     seen = dict.fromkeys(of_kind, 0)
     places = []     # a layer's pools, and where its pages lie in them
     for kind in cfg.layer_kinds:
+        if kind == 3:
+            # (first slot of the layer's region, the region's slots)
+            stride = arena["ssm"].shape[0] // of_kind[kind]
+            places.append((seen[kind] * stride, stride))
+            seen[kind] += 1
+            continue
+        if kind not in pa.KIND_POOLS:
+            places.append(None)
+            continue
         names = pa.KIND_POOLS[kind]
         stride = arena[names[0]].shape[0] // of_kind[kind]  # num_blocks + 1
         off = seen[kind] * stride
@@ -702,45 +753,126 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             out = lay.to_tokens(out)
         return out if own else tl.latent_expand_out(cfg, a, out)
 
-    def run(capacity, chunk_rows):
+    def state_space(lay, p, place, h_in, pools):
+        """A state-space layer's mixer on its normed input (token-wise
+        form) → its output in the same form; ``pools`` holds the state
+        pools it reads and writes. Rows of ONE query first, in SLOT order:
+        the layer's whole region takes one elementwise pass in place (a
+        slot with no live row has ``Δ = 0`` and keeps its state; a wide
+        row's is reset or left as it is), the rows' inputs scattered to
+        their slots and their outputs gathered back, both small. Then the
+        rows of the chunk's width: their states gathered, the chunk form,
+        the results scattered (a row riding along with no live query in
+        the chunk group writes nothing)."""
+        lo, region = place
+        z, xbc, dt = tl.ssm_in(cfg, p, h_in)
+        fresh_row = ssm.fresh_rows(starts)
+        groups = lay.groups()
+        outs = [None] * len(groups)
+        for i, group in sorted(enumerate(groups), key=lambda g: g[1].c):
+            at, reset = group.of(slots), group.of(fresh_row)
+            live = group.counts
+            with jax.named_scope("ssm_state"):
+                tail = ssm.tail_rows(cfg, ssm.carried(
+                    pools["conv"][lo + at], reset))
+                if group.c > 1:
+                    state = ssm.carried(pools["ssm"][lo + at], reset)
+            with jax.named_scope("ssm_conv"):
+                u, tail = ssm.conv_rows(cfg, p, group.take(xbc), tail, live)
+                dt_g = group.take(dt)
+            if group.c > 1:
+                with jax.named_scope("ssm_scan"):
+                    outs[i], state = ssm.scan_chunk(cfg, p, u, dt_g, state,
+                                                    live)
+                with jax.named_scope("ssm_state"):
+                    to = lo + at if group.ids is None else jnp.where(
+                        live > 0, lo + at, pools["ssm"].shape[0])
+                    pools["ssm"] = pools["ssm"].at[to].set(state,
+                                                           mode="drop")
+                    pools["conv"] = pools["conv"].at[to].set(
+                        tail.reshape(tail.shape[0], -1), mode="drop")
+                continue
+            with jax.named_scope("ssm_state"):
+                pools["conv"] = pools["conv"].at[lo + at].set(
+                    tail.reshape(tail.shape[0], -1))
+
+                def by_slot(rows):
+                    return jnp.zeros((region,) + rows.shape[1:],
+                                     rows.dtype).at[at].set(rows)
+
+                u, dt_g, live, reset = (by_slot(t)
+                                        for t in (u, dt_g, live, reset))
+            with jax.named_scope("ssm_scan"):
+                # (the reset rides in the decay: ``ssm.carried`` over the
+                # region would be a second pass over it)
+                y, state = ssm.scan_step(
+                    cfg, p, u, dt_g, lax.slice_in_dim(pools["ssm"], lo,
+                                                      lo + region), live,
+                    reset)
+            with jax.named_scope("ssm_state"):
+                pools["ssm"] = lax.dynamic_update_slice_in_dim(
+                    pools["ssm"], state, lo, axis=0)
+                outs[i] = y[at]
+        with jax.named_scope("ssm_scan"):
+            y = lay.from_groups(outs)
+        return tl.ssm_out(cfg, p, y, z)
+
+    carried = tuple(name for name in ssm.STATE_POOLS if name in arena)
+
+    def run(capacity, chunk_rows, state=None):
         """Embedding to final norm at one instance → (each row's last
-        hidden state [n, 1, D]; split: every layer's chunk (k, v), which
-        wait for the loop's end, else the written pools)."""
+        hidden state [n, 1, D]; split: every attention layer's chunk (k,
+        v), which wait for the loop's end, and the state pools ``state``
+        as the instance leaves them; else the written pools)."""
         with jax.named_scope("embed"):     # where each token sits, too
             lay = _TokenLayout(counts, starts, c, capacity, chunk_rows)
             toks = lay.to_tokens(tokens)
         x, dtype = tl.residual_stream(      # float32, whatever the weights'
             embed_tokens(cfg, params["embed"], toks, lay.positions))
         tables = tl.rope_tables(cfg, lay.positions)
-        pools = dict(arena)
+        pools = dict(arena, **(state or {}))
         chunk_kv = []
         for kind, lp, place in zip(cfg.layer_kinds, params["layers"],
                                    places):
             h = _norm(cfg, lp["ln1"], x)
-            attend = latent_attention if kind == 2 else heads_attention
-            out = attend(lay, kind, lp["attn"], place, h.astype(dtype),
-                         tables[kind], pools, chunk_kv)
-            x = tl.block_residual(
-                cfg, lp, x, h, tl.typed_attn_out(cfg, lp["attn"], out),
-                moe_fn, lay.valid, dtype)
+            if kind == 3:
+                out = state_space(lay, lp["ssm"], place, h.astype(dtype),
+                                  pools)
+            elif kind < 0:
+                out = None
+            else:
+                attend = latent_attention if kind == 2 else heads_attention
+                out = tl.typed_attn_out(cfg, lp["attn"], attend(
+                    lay, kind, lp["attn"], place, h.astype(dtype),
+                    tables[kind], pools, chunk_kv))
+            x = tl.block_residual(cfg, lp, x, h, out, moe_fn, lay.valid,
+                                  dtype)
         x = _norm(cfg, params["final_norm"], x).astype(dtype)
         with jax.named_scope("lm_head"):       # the rows the head projects
-            return lay.last(x), (chunk_kv if split else pools)
+            if not split:
+                return lay.last(x), pools
+            return lay.last(x), chunk_kv, {name: pools[name]
+                                           for name in carried}
 
-    x_last, out = _at_capacity(
-        _instances(token_capacities, tokens.shape[0], c), counts, run)
+    x_last, *out = _at_capacity(
+        _instances(token_capacities, tokens.shape[0], c), counts, run,
+        *(({name: arena[name] for name in carried},) if carried else ()))
     if not split:
-        return lm_logits(cfg, params, x_last)[:, 0], out
+        return lm_logits(cfg, params, x_last)[:, 0], out[0]
+    chunk_kv, state = out
+    paged = [place for kind, place in zip(cfg.layer_kinds, places)
+             if kind in pa.KIND_POOLS]
 
     def write_layers(pools, slots, take):
         pools = dict(pools)
-        for place, kv in zip(places, out):
+        for place, kv in zip(paged, chunk_kv):
             write(pools, place, slots, *(take(t) for t in kv))
         return pools
 
-    pools = _write_back(counts, starts, c, token_capacities, dict(arena),
-                        write_layers)
-    return lm_logits(cfg, params, x_last)[:, 0], pools
+    pools = _write_back(counts, starts, c, token_capacities,
+                        {name: pool for name, pool in arena.items()
+                         if name not in carried}, write_layers)
+    return lm_logits(cfg, params, x_last)[:, 0], {**pools, **state}
 
 
 def _bucket(n: int) -> int:
@@ -885,7 +1017,8 @@ class RaggedInferenceEngineTPU:
 
         self.state = DSStateManager(max_sequences=config.max_sequences,
                                     num_blocks=config.num_blocks,
-                                    block_size=config.block_size)
+                                    block_size=config.block_size,
+                                    recurrent=model.recurrent)
         self.scheduler = RaggedScheduler(
             self.state, max_batch_tokens=config.max_batch_tokens,
             prefill_chunk=config.prefill_chunk)
@@ -944,6 +1077,12 @@ class RaggedInferenceEngineTPU:
                 {a: model.kind_kv_heads(a) for a in set(model.layer_kinds)},
                 config.num_blocks, config.block_size, self.k_width,
                 model.v_dim, self.dtype)
+            if model.recurrent:
+                # beside the pages: a float32 state and a convolution tail
+                # a sequence slot and state-space layer; the pool's size
+                # follows max_sequences
+                self.arena.update(ssm.init_state_pools(
+                    model, config.max_sequences, self.dtype))
         else:
             self.arena = pa.init_arena(model.num_layers, model.kv_heads,
                                        config.num_blocks, config.block_size,
@@ -1034,7 +1173,9 @@ class RaggedInferenceEngineTPU:
             logits, arena = ragged_forward(
                 model, params, arena, tokens, counts, starts, pt,
                 use_pallas=self.use_pallas, moe_fn=self._moe_fn,
-                fresh_prefill=fresh, token_capacities=capacities)
+                fresh_prefill=fresh, token_capacities=capacities,
+                slots=packed[off + 2:off + 2 + nb] if model.recurrent
+                else None)
             if mode is None:
                 return logits, rng, arena
             temperature = lax.bitcast_convert_type(packed[off],
@@ -1076,8 +1217,10 @@ class RaggedInferenceEngineTPU:
 
     def _packed_len(self, nb: int, cb: int) -> int:
         """Length of :meth:`_pack`'s vector: tokens | counts | starts |
-        page table | the two sampling scalars."""
-        return nb * cb + 2 * nb + nb * self.mb + 2
+        page table | the two sampling scalars | a recurrent stack's state
+        slots."""
+        return nb * cb + 2 * nb + nb * self.mb + 2 + \
+            (nb if self.model_config.recurrent else 0)
 
     def _pack(self, batch: RaggedBatch, nb: int, cb: int) -> np.ndarray:
         n = len(batch.uids)
@@ -1091,8 +1234,13 @@ class RaggedInferenceEngineTPU:
         pt = self._page_table(batch.uids, nb)
         sampling = np.asarray([self._temperature, self._top_p],
                               np.float32).view(np.int32)
-        return np.concatenate([tokens.ravel(), counts, starts, pt.ravel(),
-                               sampling])
+        parts = [tokens.ravel(), counts, starts, pt.ravel(), sampling]
+        if self.model_config.recurrent:
+            # each row's slot of the state pools; padding rows: the trash
+            slots = np.full((nb,), self.config.max_sequences, np.int32)
+            slots[:n] = batch.slots
+            parts.append(slots)
+        return np.concatenate(parts)
 
     # -- capacity API (reference engine_v2.py:158–184) ----------------------
 
@@ -1229,7 +1377,8 @@ class RaggedInferenceEngineTPU:
             if n == 0 or batch.token_ids.shape[1] != 1 or \
                     self.model_config.typed:
                 # (a typed layer stack has no fused decode loop yet: its
-                # decode-only selections take the stepwise program)
+                # decode-only selections take the stepwise program; a
+                # recurrent stack's loop would have to carry its state)
                 return None
             for i, uid in enumerate(batch.uids):
                 if int(batch.token_counts[i]) != 1 or \
@@ -1365,6 +1514,8 @@ class RaggedInferenceEngineTPU:
         shared pages are aliased in the page table instead (no copy).
         Returns the new physical page id (refcount 1, owned by caller).
         """
+        if self.model_config.recurrent:
+            self._refuse_typed("cow_block (a prefix-cache handout)")
         dst = self.state.allocator.allocate(1)[0]
         if self._copy_pages_fn is None:
             self._copy_pages_fn = jax.jit(
@@ -1431,9 +1582,16 @@ class RaggedInferenceEngineTPU:
         ``export_pages`` payload size for a single block)."""
         stride = self.config.num_blocks + 1
         return sum(a.nbytes // a.shape[0] * (a.shape[0] // stride)
-                   for a in self.arena.values())
+                   for name, a in self.arena.items()
+                   if name not in ssm.STATE_POOLS)
 
     def _refuse_typed(self, what: str) -> None:
+        if self.model_config.recurrent:
+            raise NotImplementedError(
+                f"{what} is not built for a recurrent stack (state-space "
+                f"layers, DecoderConfig.layer_kinds 3): a sequence carries "
+                f"a state beside its pages, and pages alone are not its "
+                f"history")
         if self.model_config.typed:
             raise NotImplementedError(
                 f"{what} is not built for a typed layer stack "
@@ -1543,10 +1701,32 @@ class RaggedInferenceEngineTPU:
                 query_tiles=query_tiles, token_slots=capacity,
                 kv_write_slots=-(-tokens // write_block) * write_block,
                 chunk_rows=chunk_rows,
-                attn_row_slots=attn_row_slots if grouped else None)
+                attn_row_slots=attn_row_slots if grouped else None,
+                state=self._state_work(batch, cb, grouped))
             if sp is not None:      # still the recorded event's arguments
                 sp.update(work)
         return np.asarray(self._fetch(out))[:n]
+
+    def _state_work(self, batch: RaggedBatch, chunk: int, grouped: bool):
+        """(rows, resets, chunk tokens) of a launch of a recurrent stack in
+        ONE state-space layer, or None for any other: the rows whose state
+        the launch read and wrote, those of them that began at position 0
+        (their state zeroed in the program), and the tokens that took the
+        chunk form — every token of a launch at the chunk's width, but for
+        the one-token rows of a grouped instance, which step the
+        recurrence. Host arithmetic on the batch's lengths."""
+        if not self.model_config.recurrent:
+            return None
+        fed = batch.token_counts
+        if chunk == 1:
+            formed = 0
+        elif grouped:
+            formed = fed[fed > 1].sum()
+        else:
+            formed = fed.sum()
+        return (len(batch.uids),
+                int(((batch.start_positions == 0) & (fed > 0)).sum()),
+                int(formed))
 
     def _kv_window_tokens(self, batch: RaggedBatch):
         """(live, held) tokens of the batch's rows in ONE window layer
@@ -1624,8 +1804,8 @@ class RaggedInferenceEngineTPU:
                         token_slots: Optional[int] = None,
                         kv_write_slots: Optional[int] = None,
                         chunk_rows: int = 0,
-                        attn_row_slots: Optional[int] = None
-                        ) -> Dict[str, Any]:
+                        attn_row_slots: Optional[int] = None,
+                        state=None) -> Dict[str, Any]:
         """Count one device program launch, right after its jitted call
         returned (``serving/count``: the device is at work by then; a
         launch that raises is not counted): the
@@ -1661,7 +1841,10 @@ class RaggedInferenceEngineTPU:
         paged kernel) adds ``dispatch/query_tiles`` /
         ``dispatch/query_tiles_live`` — the query tiles the history
         reader's rows hold and those it computes — and the span's
-        arguments of those names."""
+        arguments of those names. ``state`` (:meth:`_state_work`: a
+        recurrent stack) adds ``dispatch/state_rows``,
+        ``dispatch/state_resets`` and ``dispatch/ssm_chunk_tokens`` and the
+        span's arguments of those names."""
         from deepspeed_tpu.telemetry.registry import registry
         row_slots = nb * chunk * scan_steps
         slots = row_slots if token_slots is None else token_slots
@@ -1704,6 +1887,11 @@ class RaggedInferenceEngineTPU:
                 registry.counter("dispatch/" + name).inc(work[name])
         if self.model_config.latent:
             work["kv_tokens_latent"] = context_tokens
+        if state is not None:
+            for name, by in zip(("state_rows", "state_resets",
+                                 "ssm_chunk_tokens"), state):
+                work[name] = by
+                registry.counter("dispatch/" + name).inc(by)
         return work
 
     # -- fused decode loop (the megastep's program) ------------------------

@@ -111,8 +111,12 @@ class DSStateManager:
     """Tracks live sequences + KV pages (reference ragged_manager.py:19)."""
 
     def __init__(self, max_sequences: int = 64, num_blocks: int = 512,
-                 block_size: int = 128):
+                 block_size: int = 128, recurrent: bool = False):
         self.max_sequences = max_sequences
+        #: the model holds recurrent (state-space) layers: a sequence's
+        #: ``slot`` also names its row of the engine's state pools, and its
+        #: pages are NOT its whole history (:meth:`adopt`)
+        self.recurrent = recurrent
         self.allocator = BlockedAllocator(num_blocks, block_size)
         self.seqs: Dict[int, SequenceDescriptor] = {}
         self._slots: List[int] = list(range(max_sequences - 1, -1, -1))
@@ -150,6 +154,11 @@ class DSStateManager:
         """
         if uid in self.seqs:
             raise ValueError(f"uid {uid} already live; cannot adopt")
+        if seen_tokens and self.recurrent:
+            raise ValueError(
+                f"cannot adopt {seen_tokens} cached tokens into a recurrent "
+                f"stack: its state-space layers' history is a state a "
+                f"sequence, which no page holds")
         seq = self.get_or_create_sequence(uid)
         seq.blocks.extend(blocks)
         seq.seen_tokens = seen_tokens

@@ -34,7 +34,7 @@ _FAMILIES = ("llama", "mistral", "mixtral", "qwen", "qwen2", "qwen2_moe",
               "gpt_neox", "gemma", "gpt2", "opt", "bloom", "falcon",
               "phi", "phi3", "gpt_bigcode", "gptj", "bert", "distilbert",
               "gpt_neo", "internlm", "mimo_v2", "deepseek_v3",
-              "cohere2_moe")
+              "cohere2_moe", "nemotron_h")
 
 
 def _map_hf_act(act: str) -> str:
@@ -60,6 +60,8 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
         return _deepseek_v3_config(hf)
     if mt == "cohere2_moe":
         return _cohere2_moe_config(hf)
+    if mt == "nemotron_h":
+        return _nemotron_h_config(hf)
     if mt == "bert":
         return DecoderConfig(
             hidden_size=hf["hidden_size"],
@@ -602,6 +604,73 @@ def _cohere2_moe_config(hf: Dict[str, Any]) -> DecoderConfig:
         shared_experts_averaged=max(shared_n, 1))
 
 
+def _nemotron_h_config(hf: Dict[str, Any]) -> DecoderConfig:
+    """Nemotron-H's hybrid stack (Nemotron 3 Nano; ``model_type:
+    nemotron_h``): a typed stack (models/typed_layers.py has the equations)
+    whose ``hybrid_override_pattern`` gives each layer ONE part under one
+    RMSNorm (``norm_eps``): ``M`` a Mamba-2 mixer (``mamba_num_heads`` heads
+    of ``mamba_head_dim``, ``n_groups``, ``ssm_state_size``,
+    ``conv_kernel``; the inner width is heads x head size, NOT ``expand`` x
+    hidden), ``*`` attention (``num_attention_heads`` / ``num_key_value_
+    heads`` of ``head_dim``, NO positional term: the family's attention
+    builds none, so ``rope_theta`` / ``partial_rotary_factor`` are read by
+    nothing), ``E`` the experts (a sigmoid router with a selection bias,
+    top-``num_experts_per_tok`` renormalised x ``routed_scaling_factor``;
+    UN-GATED ``relu2`` experts of ``moe_intermediate_size``; one shared
+    expert of ``moe_shared_expert_intermediate_size``); an untied head. The
+    pattern may be longer than ``num_hidden_layers`` (a depth-cut file may
+    keep it whole): the first ``num_hidden_layers`` letters are read, and
+    an unknown letter is refused by name (``-``, the family's dense MLP
+    layer, is not built). ``expert_share``: :func:`_sigmoid_router`.
+    Refused by name: biases other than the convolution's, gated or other
+    activations, a ``time_step_limit`` (a clamp on the step), more than one
+    shared expert."""
+    fam = "nemotron_h"
+    for key, want in (("attention_bias", False), ("mlp_bias", False),
+                      ("mamba_proj_bias", False), ("use_bias", False),
+                      ("use_conv_bias", True), ("mlp_hidden_act", "relu2"),
+                      ("mamba_hidden_act", "silu"), ("n_shared_experts", 1),
+                      ("tie_word_embeddings", False),
+                      ("time_step_limit", None)):
+        if hf.get(key, want) != want:
+            raise ValueError(f"{fam}: {key}={hf[key]!r} is not built "
+                             f"(expected {want!r})")
+    L = int(hf["num_hidden_layers"])
+    pattern = str(hf["hybrid_override_pattern"])
+    if len(pattern) < L:
+        raise ValueError(f"{fam}: hybrid_override_pattern has "
+                         f"{len(pattern)} letters for {L} layers")
+    parts = {"M": (3, -1), "*": (0, -1), "E": (-1, 1)}
+    for l, letter in enumerate(pattern[:L]):
+        if letter not in parts:
+            raise ValueError(
+                f"{fam}: hybrid_override_pattern letter {letter!r} (layer "
+                f"{l}) is not built (expected one of {sorted(parts)})")
+    kinds, sparse = zip(*(parts[letter] for letter in pattern[:L]))
+    router = _sigmoid_router(hf, fam)
+    router["shared_expert_size"] = int(
+        hf["moe_shared_expert_intermediate_size"])
+    return DecoderConfig(
+        hidden_size=hf["hidden_size"], num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim_override=int(hf["head_dim"]),
+        intermediate_size=hf["moe_intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        max_seq_len=hf.get("max_position_embeddings", 4096),
+        norm="rmsnorm", activation="relu2", pos_emb="rope",
+        norm_eps=float(hf.get("norm_eps", hf.get("layer_norm_epsilon",
+                                                 1e-5))),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        full_attn_rope=False, use_bias=False, tie_embeddings=False,
+        layer_kinds=kinds, layer_sparse=sparse,
+        ssm_heads=int(hf["mamba_num_heads"]),
+        ssm_head_dim=int(hf["mamba_head_dim"]),
+        ssm_groups=int(hf["n_groups"]),
+        ssm_state_size=int(hf["ssm_state_size"]),
+        ssm_conv_kernel=int(hf["conv_kernel"]), **router)
+
+
 def _is_gemma_layout(cfg: DecoderConfig) -> bool:
     return cfg.activation == "gelu_glu" and cfg.scale_embeddings
 
@@ -642,7 +711,7 @@ def config_to_hf(cfg: DecoderConfig) -> Dict[str, Any]:
     if cfg.typed:
         raise NotImplementedError(
             "config_to_hf: a typed layer stack (mimo_v2, deepseek_v3, "
-            "cohere2_moe) has no exporter")
+            "cohere2_moe, nemotron_h) has no exporter")
     if not cfg.causal or not cfg.prenorm:
         # encoder layouts (BERT/DistilBERT): both flags flip together
         if cfg.causal or cfg.prenorm or cfg.pos_emb != "learned" \

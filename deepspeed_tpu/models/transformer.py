@@ -54,6 +54,7 @@ class DecoderConfig:
     #: 'gelu' (tanh approx — HF gelu_new/gelu_pytorch_tanh) | 'gelu_exact'
     #: (erf — HF "gelu": Falcon, NeoX) | 'relu' | 'silu_glu' (Llama
     #: SwiGLU) | 'gelu_glu' (Gemma GeGLU)
+    #: | 'relu2' (``relu(x)²``, no gate: Nemotron-H's experts, typed stacks)
     activation: str = "gelu"
     pos_emb: str = "learned"               # 'learned' | 'rope' | 'alibi'
     rope_theta: float = 10000.0
@@ -153,7 +154,11 @@ class DecoderConfig:
     #: one entry a layer, 0 = full causal attention, 1 = window attention
     #: (MiMo-V2 ``hybrid_layer_pattern``), 2 = latent attention (DeepSeek-V3
     #: MLA: the ``*_lora_rank`` / ``qk_*_head_dim`` widths below; a latent
-    #: stack is all latent). Set → the stack is NOT one
+    #: stack is all latent), 3 = a Mamba-2 state-space mixer (the ``ssm_*``
+    #: widths below; what it carries is a fixed-size state a sequence, not
+    #: pages), -1 = NO mixer: the layer is its feed-forward part alone,
+    #: under the layer's one norm (Nemotron-H's ``E`` layers).
+    #: Set → the stack is NOT one
     #: scanned block: ``params["layers"]`` is a list of per-layer trees
     #: whose shapes follow the layer's kind, ``sliding_window`` /
     #: ``window_*`` describe the window kind only, and ``num_kv_heads`` /
@@ -161,8 +166,9 @@ class DecoderConfig:
     layer_kinds: Optional[Tuple[int, ...]] = None
     #: one entry a layer, 1 = sparse experts, 0 = a dense MLP of
     #: ``dense_intermediate_size`` (MiMo-V2 ``moe_layer_freq``: leading
-    #: dense layers). None with ``layer_kinds`` set → every layer follows
-    #: ``num_experts``.
+    #: dense layers), -1 = NO feed-forward part: the layer is its mixer
+    #: alone (Nemotron-H's ``M`` and ``*`` layers). None with
+    #: ``layer_kinds`` set → every layer follows ``num_experts``.
     layer_sparse: Optional[Tuple[int, ...]] = None
     window_kv_heads: Optional[int] = None      #: None → ``kv_heads``
     window_rope_theta: Optional[float] = None  #: None → ``rope_theta``
@@ -216,6 +222,16 @@ class DecoderConfig:
     #: side by side and its output their MEAN (Cohere2-MoE
     #: ``shared_expert_combination_strategy: average``); 1 → the sum
     shared_experts_averaged: int = 1
+    # -- state-space layers (kind 3; ops/ssm.py): ``ssm_heads`` heads of
+    # ``ssm_head_dim`` (their product is the mixer's inner width, whatever
+    # the hidden size), B and C in ``ssm_groups`` groups of
+    # ``ssm_state_size``, a causal depthwise convolution of
+    # ``ssm_conv_kernel`` taps over [x | B | C]
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state_size: int = 0
+    ssm_conv_kernel: int = 4
 
     def __post_init__(self):
         if self.mlm_head and not self.tie_embeddings:
@@ -241,6 +257,18 @@ class DecoderConfig:
                 "latent attention (layer kind 2) needs q_lora_rank, "
                 "kv_lora_rank, qk_nope_head_dim and qk_rope_head_dim, and a "
                 "stack whose layers are all latent")
+        if self.recurrent and not (
+                self.ssm_heads and self.ssm_head_dim and self.ssm_state_size
+                and self.ssm_heads % self.ssm_groups == 0):
+            raise ValueError(
+                "a state-space layer (layer kind 3) needs ssm_heads, "
+                "ssm_head_dim and ssm_state_size, and ssm_groups has to "
+                "divide ssm_heads")
+        if self.layer_kinds is not None and any(
+                kind == -1 and not self.layer_has_ffn(l)
+                for l, kind in enumerate(self.layer_kinds)):
+            raise ValueError("a layer with no mixer (layer kind -1) and no "
+                             "feed-forward part (layer_sparse -1) is empty")
         if self.num_experts and self.num_experts % self.router_groups:
             raise ValueError(f"router_groups {self.router_groups} does not "
                              f"divide num_experts {self.num_experts}")
@@ -263,6 +291,23 @@ class DecoderConfig:
     def latent(self) -> bool:
         """The stack's layers are latent attention layers (kind 2)."""
         return self.typed and 2 in self.layer_kinds
+
+    @property
+    def recurrent(self) -> bool:
+        """The stack holds state-space layers (kind 3): a sequence carries
+        a recurrent state beside its pages, and a prefix of its pages alone
+        is NOT a prefix of the sequence."""
+        return self.typed and 3 in self.layer_kinds
+
+    @property
+    def ssm_inner(self) -> int:
+        """A state-space mixer's inner width ``d = H·P``."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """What the mixer's convolution runs over: ``[x | B | C]``."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
 
     @property
     def latent_dim(self) -> int:
@@ -289,6 +334,8 @@ class DecoderConfig:
         """The kind's rotary base; None: the kind has no positional term."""
         if kind == 1:
             return self.window_rope_theta or self.rope_theta
+        if kind not in (0, 2):      # no attention in the layer
+            return None
         return self.rope_theta if self.full_attn_rope else None
 
     def kind_window(self, kind: int) -> Optional[int]:
@@ -296,8 +343,12 @@ class DecoderConfig:
 
     def layer_is_sparse(self, layer: int) -> bool:
         if self.layer_sparse is not None:
-            return bool(self.layer_sparse[layer])
+            return self.layer_sparse[layer] == 1
         return bool(self.num_experts)
+
+    def layer_has_ffn(self, layer: int) -> bool:
+        """False: the layer is its mixer alone (``layer_sparse`` -1)."""
+        return self.layer_sparse is None or self.layer_sparse[layer] >= 0
 
     @property
     def kv_heads(self) -> int:
@@ -785,6 +836,8 @@ def _mlp(cfg: DecoderConfig, p: Params, x: jax.Array) -> jax.Array:
             hidden = hidden + p["bi"]
         if cfg.activation == "relu":
             hidden = jax.nn.relu(hidden)
+        elif cfg.activation == "relu2":
+            hidden = jnp.square(jax.nn.relu(hidden))
         else:
             hidden = jax.nn.gelu(
                 hidden, approximate=cfg.activation != "gelu_exact")
@@ -1454,7 +1507,7 @@ def partition_specs(cfg: DecoderConfig, zero_stage: int = 0,
     if cfg.typed:
         raise NotImplementedError(
             "partition_specs: a typed layer stack (DecoderConfig."
-            "layer_kinds: mimo_v2, deepseek_v3, cohere2_moe) is served on "
+            "layer_kinds: mimo_v2, deepseek_v3, cohere2_moe, nemotron_h) is served on "
             "one shard and not trained yet — no sharding plan exists for "
             "its list of layers")
     # MiCS (reference runtime/zero/mics.py:63): param shards live within

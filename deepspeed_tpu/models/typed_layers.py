@@ -1,8 +1,9 @@
 """Typed layer stacks: a decoder whose layers are NOT one scanned block.
 
-``DecoderConfig.layer_kinds`` names each layer's attention kind (0 = full
-causal, 1 = window, 2 = latent) and ``layer_sparse`` whether its feed-forward is
-sparse experts or a dense MLP (leading dense layers). The kinds differ in
+``DecoderConfig.layer_kinds`` names each layer's mixer (0 = full causal
+attention, 1 = window, 2 = latent, 3 = a Mamba-2 state-space mixer, -1 =
+none) and ``layer_sparse`` its feed-forward part (1 = sparse experts, 0 = a
+dense MLP: leading dense layers, -1 = none). The kinds differ in
 SHAPE — KV heads, rotary base, a learned sink on the window kind, the dense
 width — so the layers cannot share one stacked tree: ``params["layers"]``
 is a list of per-layer trees and the layer loop is unrolled. The first
@@ -56,6 +57,30 @@ between them:
 - final LayerNorm; ``logits = x·Eᵀ`` over the embedding's rows (a TIED head:
   the tree has no ``lm_head``; ``tf.lm_logits``).
 
+A HYBRID stack (Nemotron-H's, ``hf_loader``: ``nemotron_h``) gives each
+layer ONE part under ONE norm, ``x ← x + Part_l(RMSNorm(x))``: a
+state-space mixer (kind 3, no feed-forward part), attention alone (kind 0
+with ``full_attn_rope`` False: no positional term anywhere, the state-space
+layers carry order), or the experts alone (kind -1). The STATE-SPACE mixer
+(Mamba-2; ``ops/ssm.py``), with ``H = ssm_heads`` heads of ``P =
+ssm_head_dim``, ``d = H·P``, ``G = ssm_groups``, ``N = ssm_state_size``,
+``K = ssm_conv_kernel``, head ``h`` in group ``g(h) = h // (H / G)``:
+
+- ``[z | xBC | dt] = h·W_in`` (widths ``d``, ``d + 2GN``, ``H``);
+- ``u_t = silu(Σ_{i<K} w[:, i]·xBC_{t−K+1+i} + b)``: a causal depthwise
+  convolution over time; ``u → x_t [H, P], B_t [G, N], C_t [G, N]``;
+- ``Δ_t = softplus(dt_t + dt_bias)``, ``a_t = exp(Δ_t·A)``, ``A =
+  −exp(A_log)`` a head; ``S_t = a_t·S_{t−1} + Δ_t·x_t ⊗ B_t^{g(h)}`` (``S``
+  ``[P, N]`` a head, float32), ``y_t = S_t·C_t^{g(h)} + D_h·x_t``;
+- ``o = w ⊙ GroupRMS(y ⊙ silu(z))``: the gate BEFORE the norm, the norm in
+  ``G`` groups of ``d / G``; out ``o·W_out``. No bias but the convolution's.
+
+A sequence CARRIES ``S`` and the last ``K − 1`` rows of ``xBC``: served,
+they live in state pools a slot a sequence (``ops/ssm.init_state_pools``),
+beside the pages of the attention layers. The experts of this family are
+UN-GATED: ``W_down·relu(W_up·h)²`` (``activation == "relu2"``: the trees
+hold ``wi``, ``wo`` and no ``wg``), the shared expert likewise.
+
 **The residual stream is float32** whatever the parameters' dtype
 (:func:`residual_stream`): the matmuls take the norms' outputs cast to the
 compute dtype, their results are added in float32, and the router reads
@@ -82,6 +107,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.models import transformer as tf
 from deepspeed_tpu.ops import paged_attention as pa
+from deepspeed_tpu.ops import ssm
 
 
 def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
@@ -91,16 +117,20 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     (a parallel block has none), and ``mlp`` {wg, wi, wo} or ``moe``
     {router, router_bias?, wg, wi, wo over the HELD experts} with, where
     the model has one, ``shared`` {wg, wi, wo}), ``final_norm``, and
-    ``lm_head`` unless the head is tied to ``embed``."""
-    if not cfg.is_glu or cfg.use_bias or cfg.ln_bias or \
-            cfg.pos_emb != "rope" or \
+    ``lm_head`` unless the head is tied to ``embed``. A state-space layer
+    has ``ssm`` {w_in, conv_w, conv_b, dt_bias, A_log, D, norm, w_out} in
+    place of ``attn``; a layer with no mixer has neither; a layer with no
+    feed-forward part has no ``mlp`` / ``moe``; un-gated (``relu2``)
+    experts have no ``wg``."""
+    if not (cfg.is_glu or cfg.activation == "relu2") or cfg.use_bias or \
+            cfg.ln_bias or cfg.pos_emb != "rope" or \
             (cfg.parallel_block and cfg.parallel_block_norms != 1):
         raise NotImplementedError(
             "typed layer stacks are built for bias-free GLU decoders with "
             "rotary positions (or none on the full kind): RMSNorm or "
             "LayerNorm, a sequential block or a parallel one under ONE "
             "norm, a tied or an untied head (mimo_v2, deepseek_v3, "
-            "cohere2_moe)")
+            "cohere2_moe), or for un-gated relu2 experts (nemotron_h)")
     d, v, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
     dk, dv, H = cfg.head_dim, cfg.v_dim, cfg.num_heads
     out_std = cfg.init_std / math.sqrt(2 * L)
@@ -108,6 +138,7 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     # expert; the count is part of what a seed gives)
     draws = 13 if cfg.latent or cfg.shared_expert_size else 10
     keys = iter(jax.random.split(rng, draws * L + 2))
+    glu = ("wg", "wi") if cfg.is_glu else ("wi",)
 
     def w(shape, std=cfg.init_std):
         return (jax.random.normal(next(keys), shape, jnp.float32) * std
@@ -116,7 +147,10 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     layers = []
     for l, kind in enumerate(cfg.layer_kinds):
         kvh = cfg.kind_kv_heads(kind)
-        if kind == 2:
+        lp = {"ln1": tf._norm_params(cfg)}
+        if kind == 3:
+            lp["ssm"] = _init_ssm(cfg, w, next(keys), out_std)
+        elif kind == 2:
             ql, kl, nope = cfg.q_lora_rank, cfg.kv_lora_rank, \
                 cfg.qk_nope_head_dim
             inner = lambda r: {"scale": jnp.ones((r,), jnp.float32)}
@@ -125,29 +159,34 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
                     "wkv_a": w((d, cfg.latent_dim)), "kv_norm": inner(kl),
                     "wkv_b": w((kl, H * (nope + dv))),
                     "wo": w((H * dv, d), out_std)}
-        else:
+        elif kind >= 0:
             attn = {"wq": w((d, H * dk)), "wk": w((d, kvh * dk)),
                     "wv": w((d, kvh * dv)), "wo": w((H * dv, d), out_std)}
         if kind == 1 and cfg.window_sink:
             # not zero at init: a zero sink would make a test of it vacuous
             attn["sink"] = w((H,), 1.0)
-        lp = {"ln1": tf._norm_params(cfg), "attn": attn}
-        if not cfg.parallel_block:
+        if kind in (0, 1, 2):
+            lp["attn"] = attn
+        if not cfg.layer_has_ffn(l):
+            layers.append(lp)
+            continue
+        if not cfg.parallel_block and kind >= 0:
             lp["ln2"] = tf._norm_params(cfg)
         if cfg.layer_is_sparse(l):
             E, held, f = cfg.num_experts, cfg.num_held_experts, cfg.ffn_size
-            moe = {"router": w((d, E)), "wg": w((held, d, f)),
-                   "wi": w((held, d, f)), "wo": w((held, f, d), out_std)}
+            moe = {"router": w((d, E)),
+                   **{name: w((held, d, f)) for name in glu},
+                   "wo": w((held, f, d), out_std)}
             if cfg.router_select_bias:
                 moe["router_bias"] = jnp.zeros((E,), dtype)
             lp["moe"] = moe
             if cfg.shared_expert_size:
                 fs = cfg.shared_expert_size
-                lp["shared"] = {"wg": w((d, fs)), "wi": w((d, fs)),
+                lp["shared"] = {**{name: w((d, fs)) for name in glu},
                                 "wo": w((fs, d), out_std)}
         else:
             f = cfg.dense_intermediate_size or cfg.ffn_size
-            lp["mlp"] = {"wg": w((d, f)), "wi": w((d, f)),
+            lp["mlp"] = {**{name: w((d, f)) for name in glu},
                          "wo": w((f, d), out_std)}
         layers.append(lp)
     params = {"embed": {"tokens": w((v, d))}, "layers": layers,
@@ -155,6 +194,26 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     if not cfg.tie_embeddings:
         params["lm_head"] = w((d, v))
     return params
+
+
+def _init_ssm(cfg, w, key, out_std: float):
+    """A state-space layer's tree (``w(shape, std)`` draws). As Mamba-2
+    initialises them: ``A = 1 .. H``, ``D = 1``, ``dt_bias`` the inverse
+    softplus of a step drawn log-uniformly in [0.001, 0.1]; the
+    convolution's taps and bias at ``K ** -0.5``, not the matrices' 0.02
+    (the mixer's output is normalised: with small taps ``D·x`` would be all
+    of it, and a test of the state vacuous)."""
+    d, cd, h, k = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_heads, \
+        cfg.ssm_conv_kernel
+    step = jnp.exp(jax.random.uniform(key, (h,), jnp.float32,
+                                      math.log(1e-3), math.log(1e-1)))
+    return {"w_in": w((cfg.hidden_size, d + cd + h)),
+            "conv_w": w((cd, k), k ** -0.5), "conv_b": w((cd,), k ** -0.5),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "A_log": jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32)),
+            "D": jnp.ones((h,), jnp.float32),
+            "norm": {"scale": jnp.ones((d,), jnp.float32)},
+            "w_out": w((d, cfg.hidden_size), out_std)}
 
 
 def rope_tables(cfg, positions: jax.Array) -> dict:
@@ -297,18 +356,84 @@ def typed_ffn(cfg, lp, h: jax.Array, moe_fn: Optional[Callable],
         return out + shared
 
 
-def block_residual(cfg, lp, x: jax.Array, h: jax.Array, attn_out: jax.Array,
+def block_residual(cfg, lp, x: jax.Array, h: jax.Array,
+                   mixer_out: Optional[jax.Array],
                    moe_fn: Optional[Callable], valid, dtype) -> jax.Array:
     """The float32 stream after a layer, given ``h`` (the layer's first
-    norm of ``x``, float32: what attention read) and the attention
-    branch's output: sequential (``x + a``, then the feed-forward on
-    ``norm2`` of that) or PARALLEL (``cfg.parallel_block``: the
-    feed-forward reads the SAME ``h``, and both are added)."""
+    norm of ``x``, float32: what the mixer read) and the mixer's output
+    (attention's or a state-space mixer's; None: the layer has none, and
+    its feed-forward part reads ``h``): sequential (``x + a``, then the
+    feed-forward on ``norm2`` of that) or PARALLEL (``cfg.parallel_block``:
+    the feed-forward reads the SAME ``h``, and both are added). A layer
+    whose tree has no feed-forward part is ``x + a``."""
+    if "moe" not in lp and "mlp" not in lp:
+        return x + mixer_out
+    if mixer_out is None:
+        return x + typed_ffn(cfg, lp, h, moe_fn, valid, dtype)
     if cfg.parallel_block:
-        return x + attn_out + typed_ffn(cfg, lp, h, moe_fn, valid, dtype)
-    x = x + attn_out
+        return x + mixer_out + typed_ffn(cfg, lp, h, moe_fn, valid, dtype)
+    x = x + mixer_out
     return x + typed_ffn(cfg, lp, tf._norm(cfg, lp["ln2"], x), moe_fn, valid,
                          dtype)
+
+
+def ssm_in(cfg, p, h: jax.Array):
+    """A state-space layer's input projection, token-wise: h [.., D] →
+    (z [.., d], xBC [.., d + 2GN], dt [.., H])."""
+    with jax.named_scope("ssm_in"):
+        return ssm.split_in(cfg, tf.linear_2d(h, p, "w_in"))
+
+
+def ssm_out(cfg, p, y: jax.Array, z: jax.Array) -> jax.Array:
+    """The gated norm and the output projection, token-wise: the scan's y
+    [.., d] float32 and the gate z → [.., D]."""
+    with jax.named_scope("ssm_norm"):
+        o = ssm.gated_norm(cfg, p, y, z, z.dtype)
+    with jax.named_scope("ssm_out"):
+        return tf.linear_2d(o, p, "w_out")
+
+
+def ssm_rows(cfg, p, xbc: jax.Array, dt: jax.Array, tail: jax.Array,
+             state: jax.Array, counts: jax.Array):
+    """Convolution and scan of ROWS [m, c, ..] from what they carried in →
+    (y [m, c, d] float32, the tail and the state they carry on)."""
+    with jax.named_scope("ssm_conv"):
+        u, tail = ssm.conv_rows(cfg, p, xbc, tail, counts)
+    with jax.named_scope("ssm_scan"):
+        y, state = ssm.scan_rows(cfg, p, u, dt, state, counts)
+    return y, tail, state
+
+
+#: positions a step of the uncached scan takes at once (Mamba-2's chunk)
+SSM_CHUNK = 128
+
+
+def _ssm_mixer(cfg, p, h: jax.Array) -> jax.Array:
+    """Uncached: whole sequences [B, T, D] from a zero state, ``SSM_CHUNK``
+    positions at a time, the tail and the state carried between them."""
+    b, t = h.shape[:2]
+    z, xbc, dt = ssm_in(cfg, p, h)
+    c = min(t, SSM_CHUNK)
+    steps = -(-t // c)
+
+    def chunks(a):      # [B, T, w] → [steps, B, c, w]
+        a = jnp.pad(a, ((0, 0), (0, steps * c - t), (0, 0)))
+        return a.reshape(b, steps, c, -1).swapaxes(0, 1)
+
+    def step(carry, inp):
+        xbc_c, dt_c, i = inp
+        counts = jnp.full((b,), jnp.clip(t - i * c, 0, c), jnp.int32)
+        y, *carry = ssm_rows(cfg, p, xbc_c, dt_c, *carry, counts)
+        return tuple(carry), y
+
+    carry = (jnp.zeros((b, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim),
+                       h.dtype),
+             jnp.zeros((b, cfg.ssm_heads, cfg.ssm_head_dim,
+                        cfg.ssm_state_size), jnp.float32))
+    _, y = jax.lax.scan(step, carry, (chunks(xbc), chunks(dt),
+                                      jnp.arange(steps, dtype=jnp.int32)))
+    y = y.swapaxes(0, 1).reshape(b, steps * c, -1)[:, :t]
+    return ssm_out(cfg, p, y, z)
 
 
 def _attention(cfg, kind: int, sink, q, k, v) -> jax.Array:
@@ -336,6 +461,11 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
     for kind, lp in zip(cfg.layer_kinds, params["layers"]):
         h32 = tf._norm(cfg, lp["ln1"], x)
         h = h32.astype(dtype)
+        if kind in (3, -1):
+            x = block_residual(
+                cfg, lp, x, h32, _ssm_mixer(cfg, lp["ssm"], h)
+                if kind == 3 else None, moe_fn, None, dtype)
+            continue
         if kind == 2:       # the expanded form: nothing is cached here
             q, k, v = latent_expand_kv(cfg, lp["attn"], *latent_qkv(
                 cfg, lp["attn"], h, *tables[kind]))

@@ -89,9 +89,10 @@ def init_arena_typed(layer_kinds, kv_heads_by_kind: dict, num_blocks: int,
     i-th layer OF ITS KIND owns pages ``i*(num_blocks+1) + b``. One page
     table addresses every pool (logical page b is the same tokens in
     all). The latent kind's one pool is ``k_width`` lanes wide (its row is
-    the key; ``v_width`` is not used)."""
+    the key; ``v_width`` is not used). A layer with no attention (a
+    state-space mixer, 3; no mixer at all, -1) has no pages."""
     arena = {}
-    for kind in sorted(set(layer_kinds)):
+    for kind in sorted(set(layer_kinds) & set(KIND_POOLS)):
         pages = sum(1 for a in layer_kinds if a == kind) * (num_blocks + 1)
         if kind == 2:
             arena[KIND_POOLS[kind][0]] = jnp.zeros(

@@ -129,8 +129,13 @@ def _shared_expert(sh, xf: jax.Array) -> jax.Array:
 
     xf [S,d] → [S,d]; handles int8/fp8 weight_quant leaves (scale-suffix
     convention, ops/quantized_linear.py) and the optional sigmoid gate.
-    ONE implementation shared by the capacity and dropless paths."""
+    ONE implementation shared by the capacity and dropless paths. A tree
+    with no ``wg`` is an UN-GATED ``relu²`` unit, ``relu(x·wi)²·wo``
+    (Nemotron-H; a typed stack's, never quantized)."""
     from deepspeed_tpu.ops.quantized_linear import SCALE_SUFFIX
+    if "wg" not in sh:
+        return jnp.einsum("sh,hd->sd", _relu2(jnp.einsum(
+            "sd,dh->sh", xf, sh["wi"])), sh["wo"])
     if "wg" + SCALE_SUFFIX in sh:
         # qmatmul_tp so int8/fp8 shared-expert weights TP-shard like the
         # dense MLP (col gate/up, row down); only reached from the
@@ -154,6 +159,10 @@ def _shared_expert(sh, xf: jax.Array) -> jax.Array:
             jnp.einsum("sd,do->so", xf.astype(jnp.float32),
                        sh["gate"].astype(jnp.float32))).astype(xf.dtype)
     return s_out
+
+
+def _relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(x))
 
 
 def _use_pallas_gmm(d: int, f: int) -> bool:
@@ -538,7 +547,12 @@ def route_tokens(cfg, p, xf: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 @jax.named_scope("moe_experts")
 def _held_glu(p, buf: jax.Array) -> jax.Array:
-    """buf [H, C, d] → [H, C, d]: each held expert's SiLU-GLU on its rows."""
+    """buf [H, C, d] → [H, C, d]: each held expert's SiLU-GLU on its rows;
+    a tree with no ``wg`` holds un-gated ``relu²`` experts, two matrices
+    each: ``relu(x·wi)²·wo``."""
+    if "wg" not in p:
+        return jnp.einsum("ech,ehd->ecd", _relu2(jnp.einsum(
+            "ecd,edh->ech", buf, p["wi"])), p["wo"])
     gate = jnp.einsum("ecd,edh->ech", buf, p["wg"])
     up = jnp.einsum("ecd,edh->ech", buf, p["wi"])
     return jnp.einsum("ech,ehd->ecd", jax.nn.silu(gate) * up, p["wo"])
@@ -556,7 +570,8 @@ def held_experts_moe_layer(cfg, p, x: jax.Array,
     this layer does not have sums the parts). One chip: no collective.
 
     p: ``router [d, E]``, optionally ``router_bias [E]``, and the HELD
-    experts' ``wg / wi [H, d, f]``, ``wo [H, f, d]``. x [B, T, d];
+    experts' ``wg / wi [H, d, f]``, ``wo [H, f, d]`` (no ``wg``: un-gated
+    ``relu²`` experts, :func:`_held_glu`). x [B, T, d];
     ``valid`` [B, T] bool marks real tokens (padding slots of a packed
     step are dropped like absent experts). No token of a held expert is
     ever dropped: up to ``HELD_ROUND_ROWS`` tokens every held expert
@@ -571,7 +586,7 @@ def held_experts_moe_layer(cfg, p, x: jax.Array,
     k = cfg.num_experts_per_tok
     xf = x.reshape(s, d)
     topw, topi = route_tokens(cfg, p, xf)     # x as it is (float32 stream)
-    xf = xf.astype(p["wg"].dtype)             # the experts' compute dtype
+    xf = xf.astype(p["wo"].dtype)             # the experts' compute dtype
     local = topi - first                                       # [S, k]
     mine = (local >= 0) & (local < held)
     if valid is not None:

@@ -237,7 +237,7 @@ def decoder_model_spec(dec_cfg: DecoderConfig,
             "ds.initialize: training a typed layer stack (DecoderConfig."
             "layer_kinds: window and full attention layers, leading dense "
             "layers, latent attention, a parallel block, an expert share — "
-            "mimo_v2, deepseek_v3, cohere2_moe) is not built yet: no "
+            "mimo_v2, deepseek_v3, cohere2_moe, nemotron_h) is not built yet: no "
             "backward for the share's dispatch, no sharding plan for a "
             "list of layers. Serve it with RaggedInferenceEngineTPU")
 

@@ -38,7 +38,11 @@ def adopt_cached(engine, cache, uid: int, prompt: List[int]) -> int:
     the uncached tail (never the pages being handed out). Returns the
     number of prompt tokens served from the cache; raises RuntimeError
     when the arena cannot fit even after eviction (nothing is leaked).
+    A RECURRENT stack (state-space layers) is handed no pages, whatever
+    the cache holds: its pages are not its history.
     """
+    if engine.state.recurrent:
+        cache = None
     alloc = engine.state.allocator
     bs = alloc.block_size
     aliased: List[int] = []
@@ -103,8 +107,11 @@ class ServingFrontend:
         self.policy = TokenBudgetPolicy()
         engine.scheduler.policy = self.policy
         self.queue = AdmissionQueue(max_queue)
+        # a recurrent stack (state-space layers) gets no prefix cache: a
+        # sequence carries a state beside its pages, which no page holds
         self.cache = (PrefixCache(engine.state.allocator, cache_pages)
-                      if enable_prefix_cache else None)
+                      if enable_prefix_cache and not engine.state.recurrent
+                      else None)
         self.metrics = ServingMetrics()
         self.monitor = monitor
         self.mode = mode

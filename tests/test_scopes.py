@@ -124,13 +124,19 @@ def test_scope_is_the_innermost_vocabulary_word():
         "attn_history"
     assert scope_of_op_name("jit(f)/flash_fwd/pallas_call")["scope"] is None
     assert scope_of_op_name("")["scope"] is None
-    assert len(set(SCOPE_VOCABULARY)) == len(SCOPE_VOCABULARY) == 20
+    assert len(set(SCOPE_VOCABULARY)) == len(SCOPE_VOCABULARY) == 26
     # a latent layer's words, inside and beside the older ones
     assert scope_of_op_name(
         "jit(f)/attn_latent/bthd,lhd->bthl/dot_general")["scope"] == \
         "attn_latent"
     assert scope_of_op_name("jit(f)/moe_shared/sd,dh->sh/dot_general")[
         "scope"] == "moe_shared"
+    # a state-space layer's six, the pools' gather inside the loop's body
+    assert scope_of_op_name(
+        "jit(f)/while/body/ssm_state/gather")["scope"] == "ssm_state"
+    assert scope_of_op_name(
+        "jit(f)/ssm_scan/mtsgh,msghp->mtghp/dot_general")["scope"] == \
+        "ssm_scan"
 
 
 # -- names and registration -----------------------------------------------------

@@ -701,3 +701,91 @@ def test_latent_step_reads_the_pool_absorbed(kind, one_chip,
         expanded = re.findall(r"\[64,4352,64,\d+\]|\[64,64,4352,\d+\]",
                               text)
         assert not expanded, sorted(set(expanded))
+
+
+# -- the hybrid stack (benchmark/configs/nemotron3-nano-l26-e16-serve): state
+# pools beside the pages
+
+#: step -> (chunk, ``fresh_prefill``, capacities, most temporaries at the
+#: four layers ``ME*M``: measured 0.15, 0.89 and 0.81 GB)
+_HYBRID_STEPS = {
+    "decode": (1, False, (), 0.3e9),
+    "split": (128, "split", (1024, 2048), 1.2e9),
+    "fresh": (128, "fresh", (2048,), 1.1e9),
+}
+
+
+@pytest.mark.parametrize("kind", list(_HYBRID_STEPS))
+def test_hybrid_step_updates_its_state_pools_in_place(
+        kind, one_chip, no_persistent_cache, monkeypatch):
+    """The 64-row decode, split and fresh programs of Nemotron 3 Nano's
+    stack at the published widths, cut to ``ME*M`` (two state-space layers,
+    the held experts, an attention layer), over the cell's arena and a
+    state pool of 2 x 65 slots of 2 MiB: NO copy of a state pool or of a KV
+    pool anywhere in the module — the split program carries the pools
+    through one-trip LOOPS where a conditional copied them twice
+    (``engine_v2._at_capacity``) —, every one of the six ``ssm_*`` scopes
+    on some instruction, the paged kernel under ``attn_history`` in the
+    split program, and temporaries under the measured ones."""
+    import json
+    import os
+    from benchmark.lib import model as model_lib
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.ops import paged_attention as pa
+    from deepspeed_tpu.ops import ssm
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    conf = json.load(open(os.path.join(
+        os.path.dirname(model_lib.__file__), "..", "configs",
+        "nemotron3-nano-l26-e16-serve.json")))
+    model = model_lib.build_model({**conf, "num_hidden_layers": 4,
+                                   "hybrid_override_pattern": "ME*M"})
+    assert model.layer_kinds == (3, -1, 0, 3) and model.recurrent
+    cb, fresh, capacities, most = _HYBRID_STEPS[kind]
+    nb, mb = 64, 32
+
+    def serve_step(params, arena, tokens, counts, starts, pt, slots):
+        logits, arena = engine_v2.ragged_forward(
+            model, params, arena, tokens, counts, starts, pt,
+            use_pallas=True, moe_fn=None, fresh_prefill=fresh,
+            token_capacities=capacities, slots=slots)
+        out, _ = engine_v2._sample_tokens(logits, ("argmax",), 1.0, 1.0,
+                                          None)
+        return out, arena
+
+    def make_arena():
+        arena = pa.init_arena_typed(model.layer_kinds, {0: model.kv_heads},
+                                    512, 128, 128, 128, jnp.bfloat16)
+        arena.update(ssm.init_state_pools(model, 64, jnp.bfloat16))
+        return arena
+
+    arena = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(make_arena))
+    assert arena["ssm"].shape == (130, 64, 64, 128) and \
+        arena["ssm"].dtype == jnp.float32 and \
+        arena["conv"].shape == (130, 3 * 6144)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    compiled = jax.jit(serve_step, donate_argnums=(1,)).lower(
+        _abstract_params(model, one_chip), arena, i32(nb, cb), i32(nb),
+        i32(nb), i32(nb, mb), i32(nb)).compile()
+    text = compiled.as_text()
+    for pool in arena.values():
+        shape = {"float32": "f32", "bfloat16": "bf16"}[str(pool.dtype)] + \
+            "[" + ",".join(map(str, pool.shape)) + "]"
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(rf" = {re.escape(shape)}\S* copy\(", line)]
+        assert not copies, copies
+    table = scope_table_from_hlo(text)
+    scopes = {e["scope"] for e in table.values()}
+    assert {"ssm_in", "ssm_conv", "ssm_scan", "ssm_state", "ssm_norm",
+            "ssm_out", "moe_shared", "kv_write"} <= scopes, scopes
+    kernels = [n for n in table if n.startswith("paged_attn_lse")]
+    assert bool(kernels) == (kind == "split") and \
+        all(table[n]["scope"] == "attn_history" for n in kernels)
+    # (the split program's two instances are loop bodies, not branches)
+    assert not _branches(text) or kind != "split", _branches(text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < most, temp
