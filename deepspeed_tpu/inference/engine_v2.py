@@ -16,6 +16,7 @@ so jit traces a handful of programs, not one per batch composition.
 """
 
 import time
+from collections import deque
 from functools import partial
 from typing import (Any, Dict, List, NamedTuple, Optional, Tuple,
                     Union)
@@ -882,6 +883,37 @@ def _bucket(n: int) -> int:
     return b
 
 
+#: the arena's one entry that is NO pool: ``[max_sequences + 1]`` int32, the
+#: token each sequence SLOT sampled last (the last row: the trash slot of
+#: padding rows). Every token-mode step program writes its ``out`` there,
+#: so a row's fed-back token need not pass through the host
+#: (:meth:`RaggedInferenceEngineTPU.launch`). It rides in the arena because
+#: the programs already donate and return that, so their signature and
+#: their grid stay as they are; what walks the arena as pools takes
+#: :func:`_pools`.
+FED_TOKENS = "fed_tokens"
+#: a packed token id that says "this row's token is its slot's of
+#: ``FED_TOKENS``" (first column of a row only); in ``seq.tokens`` the
+#: placeholder of a token that is still on the device
+FED_SENTINEL = -1
+
+
+def _pools(arena: dict) -> dict:
+    """The arena's pools: everything but the slot buffer."""
+    return {name: a for name, a in arena.items() if name != FED_TOKENS}
+
+
+class _Launch(NamedTuple):
+    """A step program that was launched and not yet collected: its tokens
+    (or logits) on the device, its sampling mode, and the rows whose pending
+    tokens it exhausted — ``{uid: (row, descriptor, at)}``, ``at`` the index
+    in ``descriptor.tokens`` of the placeholder the row was continued by
+    (None: not continued)."""
+    out: Any
+    mode: Any
+    emits: Dict[int, Tuple[int, Any, Optional[int]]]
+
+
 def _dispatch_count(name: str, by: int = 1) -> None:
     """Bump a ``dispatch/*`` counter (lazy import: telemetry pulls in the
     whole diagnostics stack, which must not load at engine-import time)."""
@@ -935,6 +967,52 @@ def _sample_tokens(logits, mode, temperature, top_p, rng):
         lg = jnp.where(lg < cutoff, -1e30, lg)
     rng, sub = jax.random.split(rng)
     return jax.random.categorical(sub, lg, axis=-1).astype(jnp.int32), rng
+
+
+def _step_program(model: DecoderConfig, nb: int, cb: int, mb: int, mode,
+                  fresh, capacities, use_pallas: bool, moe_fn):
+    """The body of a step program (:meth:`RaggedInferenceEngineTPU._step_fn`
+    jits it): ``(params, arena, packed, rng) → (out, rng, arena)`` over
+    ``nb`` rows of ``cb`` tokens and a page table ``mb`` wide, ``packed``
+    as :meth:`RaggedInferenceEngineTPU._pack` lays it out. A row whose
+    first packed token is ``FED_SENTINEL`` takes its slot's token of the
+    arena's ``FED_TOKENS``; a token mode writes every row's ``out`` there
+    by slot. Data, not a trace-time branch: the one program serves a
+    caller that feeds tokens back through the host and one that does
+    not."""
+    def fn(params, arena, packed, rng):
+        off = 0
+        tokens = packed[off:off + nb * cb].reshape(nb, cb)
+        off += nb * cb
+        counts = packed[off:off + nb]
+        off += nb
+        starts = packed[off:off + nb]
+        off += nb
+        pt = packed[off:off + nb * mb].reshape(nb, mb)
+        off += nb * mb
+        slots = packed[off + 2:off + 2 + nb]
+        fed = arena[FED_TOKENS]
+        with jax.named_scope("embed"):
+            # a row whose token is still on the device: its slot's
+            first = tokens[:, 0]
+            tokens = tokens.at[:, 0].set(
+                jnp.where(first == FED_SENTINEL, fed[slots], first))
+        logits, arena = ragged_forward(
+            model, params, _pools(arena), tokens, counts, starts, pt,
+            use_pallas=use_pallas, moe_fn=moe_fn, fresh_prefill=fresh,
+            token_capacities=capacities,
+            slots=slots if model.recurrent else None)
+        if mode is None:
+            return logits, rng, {**arena, FED_TOKENS: fed}
+        temperature = lax.bitcast_convert_type(packed[off], jnp.float32)
+        top_p = lax.bitcast_convert_type(packed[off + 1], jnp.float32)
+        out, rng = _sample_tokens(logits, mode, temperature, top_p, rng)
+        with jax.named_scope("sample"):
+            # every row's, by slot (padding rows: the trash slot); a
+            # row with tokens still pending writes what nothing reads
+            fed = fed.at[slots].set(out)
+        return out, rng, {**arena, FED_TOKENS: fed}
+    return fn
 
 
 class RaggedInferenceEngineTPU:
@@ -1087,6 +1165,8 @@ class RaggedInferenceEngineTPU:
             self.arena = pa.init_arena(model.num_layers, model.kv_heads,
                                        config.num_blocks, config.block_size,
                                        model.head_dim, self.dtype)
+        self.arena[FED_TOKENS] = jnp.zeros((config.max_sequences + 1,),
+                                           jnp.int32)
         moe_fn = None
         if model.typed:
             # routes over every expert, computes the held ones' part
@@ -1120,6 +1200,10 @@ class RaggedInferenceEngineTPU:
         #: None once the scheduler has had nothing to run, so that an idle
         #: server's waiting is not counted as host time (:meth:`_fetch`)
         self._fetch_returned: Optional[float] = None
+        #: launches not yet collected, oldest first (:meth:`launch` /
+        #: :meth:`collect`): at most the one being collected and the one
+        #: made ahead of it
+        self._launched: deque = deque()
         # the process-wide span tracer, looked up once and not once a
         # span (here and not at import: telemetry pulls in the whole
         # diagnostics stack, which must not load at engine-import time)
@@ -1160,31 +1244,8 @@ class RaggedInferenceEngineTPU:
         name = f"serve_{_step_kind(cb, fresh)}_r{nb}" + \
             (f"_c{cb}" if cb > 1 else "") + _mode_suffix(mode)
 
-        def fn(params, arena, packed, rng):
-            off = 0
-            tokens = packed[off:off + nb * cb].reshape(nb, cb)
-            off += nb * cb
-            counts = packed[off:off + nb]
-            off += nb
-            starts = packed[off:off + nb]
-            off += nb
-            pt = packed[off:off + nb * mb].reshape(nb, mb)
-            off += nb * mb
-            logits, arena = ragged_forward(
-                model, params, arena, tokens, counts, starts, pt,
-                use_pallas=self.use_pallas, moe_fn=self._moe_fn,
-                fresh_prefill=fresh, token_capacities=capacities,
-                slots=packed[off + 2:off + 2 + nb] if model.recurrent
-                else None)
-            if mode is None:
-                return logits, rng, arena
-            temperature = lax.bitcast_convert_type(packed[off],
-                                                   jnp.float32)
-            top_p = lax.bitcast_convert_type(packed[off + 1], jnp.float32)
-            out, rng = _sample_tokens(logits, mode, temperature, top_p,
-                                      rng)
-            return out, rng, arena
-
+        fn = _step_program(model, nb, cb, mb, mode, fresh, capacities,
+                           self.use_pallas, self._moe_fn)
         fn.__name__ = fn.__qualname__ = name
         jitted = jax.jit(fn, donate_argnums=(1,))
         compile_monitor.register_program(name, jitted, (
@@ -1217,10 +1278,8 @@ class RaggedInferenceEngineTPU:
 
     def _packed_len(self, nb: int, cb: int) -> int:
         """Length of :meth:`_pack`'s vector: tokens | counts | starts |
-        page table | the two sampling scalars | a recurrent stack's state
-        slots."""
-        return nb * cb + 2 * nb + nb * self.mb + 2 + \
-            (nb if self.model_config.recurrent else 0)
+        page table | the two sampling scalars | the rows' sequence slots."""
+        return nb * cb + 3 * nb + nb * self.mb + 2
 
     def _pack(self, batch: RaggedBatch, nb: int, cb: int) -> np.ndarray:
         n = len(batch.uids)
@@ -1234,13 +1293,12 @@ class RaggedInferenceEngineTPU:
         pt = self._page_table(batch.uids, nb)
         sampling = np.asarray([self._temperature, self._top_p],
                               np.float32).view(np.int32)
-        parts = [tokens.ravel(), counts, starts, pt.ravel(), sampling]
-        if self.model_config.recurrent:
-            # each row's slot of the state pools; padding rows: the trash
-            slots = np.full((nb,), self.config.max_sequences, np.int32)
-            slots[:n] = batch.slots
-            parts.append(slots)
-        return np.concatenate(parts)
+        # each row's slot of the fed-token buffer (and of a recurrent
+        # stack's state pools); padding rows: the trash
+        slots = np.full((nb,), self.config.max_sequences, np.int32)
+        slots[:n] = batch.slots
+        return np.concatenate([tokens.ravel(), counts, starts, pt.ravel(),
+                               sampling, slots])
 
     # -- capacity API (reference engine_v2.py:158–184) ----------------------
 
@@ -1329,12 +1387,18 @@ class RaggedInferenceEngineTPU:
         ``max_steps == 1`` all take the unchanged stepwise path (with
         lists still returned when ``max_steps > 1`` was requested, so
         callers see ONE shape).
+
+        A :meth:`launch` and its :meth:`collect` back to back: this entry
+        waits for the program it launched, and continues no row (the caller
+        feeds the tokens back). A launch still in flight is the caller's to
+        collect first.
         """
-        tracer = self._tracer
-        with tracer.span("serving/schedule"):
-            batch = self.scheduler.next_batch(budget=budget)
+        if self._launched:
+            raise RuntimeError(
+                "step_with_budget waits for its own program: collect() the "
+                "launch in flight first")
+        batch = self._schedule(budget)
         if batch is None:
-            self._fetch_returned = None
             return None
         megastep = max_steps > 1 and mode is not None
         if megastep:
@@ -1342,18 +1406,124 @@ class RaggedInferenceEngineTPU:
                                      eos_ids)
             if out is not None:
                 return out
-        res = self._run(batch, mode=mode)
-        with tracer.span("serving/retire"):
-            self.scheduler.mark_scheduled(batch)
-            out = {}
-            for i, uid in enumerate(batch.uids):
-                if self.state.seqs[uid].pending == 0:
-                    if mode is None:
-                        out[uid] = res[i]
-                    else:
-                        out[uid] = [int(res[i])] if megastep \
-                            else int(res[i])
+        self._launch(batch, mode)
+        out, _ = self.collect()
+        if megastep:
+            out = {uid: [tok] for uid, tok in out.items()}
         return out
+
+    # -- the step as a launch and a collect ---------------------------------
+
+    @property
+    def in_flight(self) -> int:
+        """Launches made and not yet collected."""
+        return len(self._launched)
+
+    def _schedule(self, budget: Optional[int]) -> Optional[RaggedBatch]:
+        with self._tracer.span("serving/schedule"):
+            batch = self.scheduler.next_batch(budget=budget)
+        if batch is None and not self._launched:
+            self._fetch_returned = None
+        return batch
+
+    def launch(self, budget: Optional[int] = None, mode=("argmax",),
+               row_limits: Optional[Dict[int, int]] = None) -> bool:
+        """The first half of a step, up to where the device's work begins:
+        schedule, pack, dispatch, count, and the rows' bookkeeping — with
+        no wait for the program. False when the scheduler has nothing to
+        run. :meth:`collect` is the other half; a caller that launches step
+        n+1 BEFORE it collects step n (``ServingFrontend._step``) keeps the
+        device at work while the host fetches, fans out and schedules.
+
+        What makes that possible is the CONTINUATION. A decode row's next
+        input is the token the program in flight is sampling, and nothing
+        else of the next batch depends on its value. So each row of
+        ``row_limits`` (uid → tokens it may still emit, as the caller counts
+        them: tokens of launches in flight not taken off) whose pending
+        tokens this launch exhausts, and that may emit another after this
+        one, is extended by ONE placeholder (``FED_SENTINEL``, its page
+        allocated as ``state.extend`` does): the next pick sees ``pending
+        == 1``, :meth:`_pack` packs the sentinel, and the program reads the
+        row's token from the slot buffer (``FED_TOKENS``) the one before it
+        wrote. :meth:`collect` patches the placeholder with the fetched
+        value. A row without an entry, one at the end of its budget or of
+        ``max_seq_len``, and one whose page cannot be allocated are not
+        continued: the caller feeds their token back, as after
+        :meth:`step_with_budget`. Programs run in launch order over the
+        donated arena, so the caller may ``flush`` a continued row at any
+        time: its token is dropped at the collect
+        (``dispatch/ahead_rows_dropped``)."""
+        batch = self._schedule(budget)
+        if batch is None:
+            return False
+        self._launch(batch, mode, row_limits)
+        return True
+
+    def _launch(self, batch: RaggedBatch, mode,
+                row_limits: Optional[Dict[int, int]] = None) -> None:
+        out = self._run(batch, mode=mode)
+        with self._tracer.span("serving/retire"):
+            self.scheduler.mark_scheduled(batch)
+            emits = {}
+            for i, uid in enumerate(batch.uids):
+                seq = self.state.seqs[uid]
+                if seq.pending == 0:
+                    emits[uid] = (i, seq, self._continue(seq, row_limits)
+                                  if mode is not None else None)
+            if self._launched:
+                _dispatch_count("dispatch/launches_ahead")
+            self._launched.append(_Launch(out, mode, emits))
+
+    def _continue(self, seq, row_limits: Optional[Dict[int, int]]
+                  ) -> Optional[int]:
+        """Extend ``seq`` by the placeholder of the token its launch is
+        sampling, if it may emit another after that one; where the
+        placeholder sits in ``seq.tokens``, or None."""
+        if row_limits is None or seq.uid not in row_limits:
+            return None
+        owed = 1 + sum(seq.uid in fl.emits for fl in self._launched)
+        if row_limits[seq.uid] <= owed or \
+                len(seq.tokens) >= self.config.max_seq_len:
+            return None
+        try:
+            self.state.extend(seq.uid, [FED_SENTINEL])
+        except RuntimeError:        # no page: the caller's answer stands
+            return None
+        return len(seq.tokens) - 1
+
+    def collect(self) -> Optional[Tuple[Dict[int, Any], set]]:
+        """The second half of the OLDEST launch in flight: wait for its
+        program (``serving/fetch``), patch the continued rows' placeholders
+        with the fetched tokens, and return ``({uid: next_token_id}, the
+        uids that were continued)`` — or ``{uid: logits}`` with mode=None.
+        None with nothing in flight. A row flushed since the launch (or
+        whose uid is another sequence's by now) yields nothing."""
+        if not self._launched:
+            return None
+        fl = self._launched.popleft()
+        res = np.asarray(self._fetch(fl.out))
+        with self._tracer.span("serving/retire"):
+            out: Dict[int, Any] = {}
+            continued = set()
+            dropped = 0
+            for uid, (i, seq, at) in fl.emits.items():
+                if self.state.seqs.get(uid) is not seq:
+                    dropped += at is not None
+                elif fl.mode is None:
+                    out[uid] = res[i]
+                else:
+                    out[uid] = int(res[i])
+                    if at is not None:
+                        seq.tokens[at] = out[uid]
+                        continued.add(uid)
+            if dropped:
+                _dispatch_count("dispatch/ahead_rows_dropped", dropped)
+        return out, continued
+
+    def abandon(self) -> None:
+        """Forget every launch in flight without waiting for it (after a
+        fault: the caller flushes the rows it had continued)."""
+        self._launched.clear()
 
     def _try_megastep(self, batch: RaggedBatch, k: int, mode,
                       row_limits: Optional[Dict[int, int]],
@@ -1490,11 +1660,15 @@ class RaggedInferenceEngineTPU:
         """``jax.device_get(out)`` under ``serving/fetch``: the pump's one
         wait for the device. Two always-on counters where the wait happens:
         ``dispatch/fetch_wait_seconds`` (seconds inside the ``device_get``)
-        and ``dispatch/host_seconds`` (seconds from the last launch's fetch
+        and ``dispatch/host_seconds`` (seconds from the last fetch's
         returning to this one's start: everything the host did between two
-        programs, its own launch included). Only launches back to back
-        count: :meth:`step_with_budget` drops the stamp when the scheduler
-        has nothing to run."""
+        fetches, a launch included). Under a caller that waits for each
+        launch (:meth:`step_with_budget`) that is time the device idles;
+        under one that launches ahead (:meth:`launch` before
+        :meth:`collect`) the device runs the next program meanwhile, and it
+        is only what the host has to fit inside a program's time. Only
+        launches back to back count: :meth:`_schedule` drops the stamp when
+        the scheduler has nothing to run and nothing is in flight."""
         with self._tracer.span("serving/fetch"):           # waits for the device
             asked = time.perf_counter()
             got = jax.device_get(out)
@@ -1518,8 +1692,11 @@ class RaggedInferenceEngineTPU:
             self._refuse_typed("cow_block (a prefix-cache handout)")
         dst = self.state.allocator.allocate(1)[0]
         if self._copy_pages_fn is None:
+            stride = self.config.num_blocks + 1
             self._copy_pages_fn = jax.jit(
-                partial(pa.copy_pages, stride=self.config.num_blocks + 1),
+                lambda arena, src, dst: {
+                    **arena,
+                    **pa.copy_pages(_pools(arena), src, dst, stride)},
                 donate_argnums=(0,))
         self.arena = self._copy_pages_fn(
             self.arena, jnp.asarray([src_block], jnp.int32),
@@ -1582,7 +1759,7 @@ class RaggedInferenceEngineTPU:
         ``export_pages`` payload size for a single block)."""
         stride = self.config.num_blocks + 1
         return sum(a.nbytes // a.shape[0] * (a.shape[0] // stride)
-                   for name, a in self.arena.items()
+                   for name, a in _pools(self.arena).items()
                    if name not in ssm.STATE_POOLS)
 
     def _refuse_typed(self, what: str) -> None:
@@ -1635,9 +1812,10 @@ class RaggedInferenceEngineTPU:
             return (small, top)
         return (top,)
 
-    def _run(self, batch: RaggedBatch, mode=None) -> np.ndarray:
+    def _run(self, batch: RaggedBatch, mode=None):
         """One step program over ``batch``: pack and upload, launch, count
-        what was launched, wait. The accounting (``serving/count``) comes
+        what was launched; the program's tokens (or logits), still on the
+        device. The accounting (``serving/count``) comes
         AFTER the jitted call, so the device works while the host counts; a
         launch that raises is therefore not counted."""
         tracer = self._tracer
@@ -1705,7 +1883,7 @@ class RaggedInferenceEngineTPU:
                 state=self._state_work(batch, cb, grouped))
             if sp is not None:      # still the recorded event's arguments
                 sp.update(work)
-        return np.asarray(self._fetch(out))[:n]
+        return out
 
     def _state_work(self, batch: RaggedBatch, chunk: int, grouped: bool):
         """(rows, resets, chunk tokens) of a launch of a recurrent stack in
@@ -2063,7 +2241,7 @@ class RaggedInferenceEngineTPU:
             (ak, av), _ = lax.scan(
                 wb, (arena["k"], arena["v"]),
                 (kbuf, vbuf, jnp.arange(num_layers, dtype=jnp.int32)))
-            return ys, counts, rng, {"k": ak, "v": av}
+            return ys, counts, rng, {**arena, "k": ak, "v": av}
 
         # ``serve_megastep_r<rows>_k<scan steps>_p<page-table width>``,
         # registered for the scope table

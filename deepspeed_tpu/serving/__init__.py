@@ -5,7 +5,8 @@ The layer the reference ships as DeepSpeed-MII on top of FastGen
 a radix prefix cache over ref-counted KV pages, a SplitFuse token-budget
 scheduling policy, and per-token streaming with TTFT/TPOT observability.
 Here it drives :class:`~deepspeed_tpu.inference.engine_v2.
-RaggedInferenceEngineTPU` through its ``step_with_budget`` entry point —
+RaggedInferenceEngineTPU` through its step's two halves, ``launch`` and
+``collect`` (``step_with_budget`` is the two back to back) —
 the engine stays a pure batch machine; everything traffic-shaped lives in
 this package. See docs/serving.md.
 """

@@ -1,10 +1,12 @@
 """ServingFrontend — the single-threaded serving pump.
 
 Owns the admission queue, the prefix cache, the SplitFuse policy and the
-metrics, and drives :meth:`RaggedInferenceEngineTPU.step_with_budget` in a
-loop. Single-threaded by design (T3-style: all host scheduling happens
-while the device runs the previous step's program; a thread pool would
-only add locks to a loop whose wall clock is the device's).
+metrics, and drives the engine's step in a loop — as a launch and a
+collect (:meth:`RaggedInferenceEngineTPU.launch` / ``collect``), step n+1
+launched BEFORE step n is collected, so that admission, scheduling,
+packing, the fetch and the fan-out all happen while the device runs a
+program. Single-threaded by design (a thread pool would only add locks to
+a loop whose wall clock is the device's).
 
 Request path: ``submit`` → bounded queue (reject ``queue_full`` /
 ``kv_exhausted`` / ``too_long``) → admission matches the prompt against
@@ -250,7 +252,9 @@ class ServingFrontend:
     def close(self) -> None:
         """Release frontend-owned resources (the /metrics server, the
         KV tier's I/O engine and spill files); idempotent, safe to call
-        on a frontend that never opened either."""
+        on a frontend that never opened either. What is in flight is
+        collected and delivered first."""
+        self.drain()
         if self._http is not None:
             self._http.close()
             self._http = None
@@ -279,6 +283,7 @@ class ServingFrontend:
             self._trace_lifecycle(req, reason, now)
             n += 1
         self.queue._q.clear()
+        self.drain()             # their rows' tokens in flight: dropped
         if n:
             self.metrics.bump("terminated_inflight", n)
         return n
@@ -470,13 +475,21 @@ class ServingFrontend:
         return max(1, k)
 
     def step(self) -> bool:
-        """One pump iteration: shed → cancel → admit → engine step →
-        fan tokens out. Returns True while there is (or was) work.
+        """One pump iteration: shed → cancel → admit → LAUNCH the next
+        engine step → COLLECT the one before it → fan its tokens out.
+        Returns True while there is (or was) work, a launch in flight
+        included. The launch made in a call is collected in the NEXT call,
+        while the one after it runs: a token reaches its request one
+        ``step()`` after the program that sampled it was launched, and the
+        device does not wait for the host in between. (A frontend with
+        ``megastep_tokens > 1`` collects each launch at once: the fused
+        window's length is planned from every row's last token.)
 
         One ``serving/step`` span whose children tile it: ``serving/admit``,
         ``serving/plan``, ``serving/engine_step`` (the engine's
         ``serving/schedule`` / ``pack`` / ``dispatch`` / ``count`` /
-        ``fetch`` / ``retire`` tile that one), ``serving/bookkeeping``,
+        ``retire`` of the launch, then ``serving/fetch`` / ``retire`` of
+        the collect, tile that one), ``serving/bookkeeping``,
         ``serving/fanout``, ``serving/bookkeeping`` again: outside them a
         step holds a few attribute reads, so that a reader can put every
         idle moment of the device down to one phase
@@ -514,10 +527,13 @@ class ServingFrontend:
             progressed = self._admit(now)
         with telemetry.tracer.span("serving/plan"):
             k = self._pick_megastep(now)
-            row_limits = eos_map = None
+            eos_map = None
+            # what a row may still emit, as this pump counts it: a length
+            # end is known BEFORE the launch, so the engine continues no
+            # row past its budget
+            row_limits = {uid: req.max_new_tokens - len(req.tokens_out)
+                          for uid, req in self._running.items()}
             if k > 1:
-                row_limits = {uid: req.max_new_tokens - len(req.tokens_out)
-                              for uid, req in self._running.items()}
                 eos_map = {uid: req.eos_token_id
                            for uid, req in self._running.items()
                            if req.eos_token_id is not None}
@@ -539,14 +555,10 @@ class ServingFrontend:
                 fault_injector.fire("serving_step",
                                     serving_step=self._pump_steps,
                                     advisory=False)
-                out = self.engine.step_with_budget(budget=self.token_budget,
-                                                   mode=self.mode,
-                                                   max_steps=k,
-                                                   row_limits=row_limits,
-                                                   eos_ids=eos_map)
-                if span_args is not None and out is not None:
-                    # which device program the step ran (decode / fresh /
-                    # split / paged / megastep): known only now
+                launched, got = self._engine_step(k, row_limits, eos_map)
+                if span_args is not None and launched:
+                    # which device program the step launched (decode /
+                    # fresh / split / paged / megastep): known only now
                     span_args["program"] = getattr(self.engine,
                                                    "last_program", None)
         except Exception as e:                       # noqa: BLE001
@@ -562,20 +574,22 @@ class ServingFrontend:
         with telemetry.tracer.span("serving/bookkeeping"):
             self._update_degraded()
             # goodput ledger sweep (rate-limited internally; no-op unless
-            # telemetry.goodput is on) — BEFORE the out-is-None early
+            # telemetry.goodput is on) — BEFORE the nothing-collected early
             # return so idle pumps keep attributing idle seconds
             telemetry.goodput_ledger.maybe_update()
-            if out is not None:
+            if launched:
                 self.metrics.bump("engine_steps")
                 telemetry.flight_recorder.record_step(
                     int(telemetry.registry.counter(
                         "serving/engine_steps").value),
                     kind="serving", dur_s=time.monotonic() - t0,
-                    batch=len(self._running), tokens=len(out))
-        if out is None:
-            return progressed or bool(self._running or len(self.queue))
+                    batch=len(self._running),
+                    tokens=len(got[0]) if got else 0)
+        if got is None:
+            return progressed or launched or \
+                bool(self._running or len(self.queue))
         with telemetry.tracer.span("serving/fanout"):
-            self._fan_out(out)
+            self._fan_out(*got)
         with telemetry.tracer.span("serving/bookkeeping"):
             if self.emit_every and self.metrics.counters["engine_steps"] % \
                     self.emit_every == 0:
@@ -595,9 +609,47 @@ class ServingFrontend:
             self._update_degraded()
         return True
 
-    def _fan_out(self, out: Dict[int, Any]) -> None:
+    def _engine_step(self, k: int, row_limits, eos_map):
+        """``(launched, collected)`` of one pump iteration: launch the next
+        engine step, then collect the launch that was in flight BEFORE it
+        (``(tokens, continued uids)``, or None without one). A launch with
+        nothing in flight before it is collected in the next iteration —
+        unless this frontend plans megasteps, whose window needs every
+        row's last token: then each launch is collected at once, and the
+        engine continues no row."""
+        eng = self.engine
+        if k > 1:
+            out = eng.step_with_budget(budget=self.token_budget,
+                                       mode=self.mode, max_steps=k,
+                                       row_limits=row_limits,
+                                       eos_ids=eos_map)
+            return out is not None, None if out is None else (out, ())
+        ahead = self.megastep_tokens <= 1
+        waiting = eng.in_flight
+        launched = eng.launch(budget=self.token_budget, mode=self.mode,
+                              row_limits=row_limits if ahead else None)
+        return launched, eng.collect() if waiting or not ahead else None
+
+    def drain(self) -> None:
+        """Collect every launch in flight and deliver its tokens: nothing
+        is left on the device that a request waits for. What
+        :meth:`run_until_idle`, :meth:`stream`, :meth:`terminate_inflight`,
+        :meth:`close` and the page hand-off end with."""
+        while self.engine.in_flight:
+            try:
+                got = self.engine.collect()
+            except Exception as e:                   # noqa: BLE001
+                self._on_engine_fault(e, self.clock())
+                return
+            self._fan_out(*got)
+
+    def _fan_out(self, out: Dict[int, Any], continued=()) -> None:
         """Hand the step's tokens to their requests: stamp first tokens,
-        stream, finish on eos / length, feed the last token back."""
+        stream, finish on eos / length, feed the last token back — but for
+        the rows the engine ``continued`` at the launch: their token never
+        left the device, and the next program may hold it already. A row
+        finished here that was continued is flushed like any other; the
+        token its next program samples is dropped at that collect."""
         now = self.clock()
         for uid, toks in out.items():
             req = self._running.get(uid)
@@ -642,7 +694,7 @@ class ServingFrontend:
                     self._finish(req, "length", RequestState.FINISHED, now)
                     finished = True
                     break
-            if not finished:
+            if not finished and uid not in continued:
                 # feed the block's LAST token back — every earlier one
                 # already has KV in the arena (megastep wrote it device-
                 # side; the engine advanced the descriptor to match)
@@ -693,6 +745,9 @@ class ServingFrontend:
             "serving_engine_fault", error=f"{type(err).__name__}: {err}",
             batch=len(self._running), pump_step=self._pump_steps)
         requeued = errored = 0
+        # a fault at the launch or at the collect: what else is in flight
+        # goes with the rows, whose tokens the retry samples again
+        self.engine.abandon()
         for uid, req in list(self._running.items()):
             try:
                 self.engine.flush(uid)
@@ -806,6 +861,7 @@ class ServingFrontend:
         """Pump until every admitted request reached a terminal state."""
         for _ in range(max_steps):
             if not (self._running or len(self.queue)):
+                self.drain()     # the launch a row that ended was part of
                 return
             self.step()
         raise RuntimeError(f"serving loop did not drain in {max_steps} steps")
@@ -828,6 +884,7 @@ class ServingFrontend:
                 yield req.tokens_out[emitted]
                 emitted += 1
             if req.done:
+                self.drain()
                 return
             if self.step():
                 idle_since = None

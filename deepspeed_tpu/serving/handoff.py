@@ -84,6 +84,9 @@ def export_bundle(frontend, prompt: List[int]) -> Optional[PageBundle]:
     cache = getattr(frontend, "cache", None)
     if cache is None:
         return None
+    # nothing of the source's pump is left in flight while its pages are
+    # read (the read itself is ordered after every program launched)
+    frontend.drain()
     bs = cache.block_size
     m = cache.match(prompt)
     blocks = list(m.full_blocks)

@@ -175,8 +175,10 @@ class CompileMonitor:
         dtype, mesh sharding) of the arguments of one call. Nothing is
         lowered here and no argument buffer is kept.
 
-        The function's closure holds its engine, and the engine its device
-        state, so the function is held WEAKLY: a process that drops an
+        The function belongs to its engine, and its closure may hold that
+        engine and so its device state (the megastep's does; a step
+        program's holds the model's statics alone), so the function is held
+        WEAKLY: a process that drops an
         engine frees it, registered or not. Only while the tracer is on is
         it held strongly (:meth:`hold_programs`): a traced run asks for
         the table after its work, when the caller may hold the engine no

@@ -175,7 +175,8 @@ def test_prefill_in_chunks_then_decode_through_the_latent_pool(tiny):
     rows. The pool is ONE tensor of 40 values a token."""
     cfg, params, w = tiny
     eng = _engine(cfg, params)
-    assert set(eng.arena) == {"latent"} and not eng.use_pallas
+    from deepspeed_tpu.inference.engine_v2 import _pools
+    assert set(_pools(eng.arena)) == {"latent"} and not eng.use_pallas
     assert eng.arena["latent"].shape == (3 * 33, 8, 40)
     programs = _serve_and_check(eng, cfg, params, w)
     assert programs == {n + "_logits" for n in (

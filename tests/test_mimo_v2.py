@@ -125,7 +125,8 @@ def test_prefill_in_chunks_then_decode_through_the_paged_cache(tiny):
     ref, dev = reference(), jax.devices()[0]
     rng = np.random.default_rng(1)
     eng = _engine(cfg, params)
-    assert set(eng.arena) == {"k", "v", "k_win", "v_win"}
+    from deepspeed_tpu.inference.engine_v2 import _pools
+    assert set(_pools(eng.arena)) == {"k", "v", "k_win", "v_win"}
     # token-major pools: [layers of the kind x (blocks + 1), bs, kvh * d]
     assert eng.arena["k"].shape == (2 * 33, 8, 1 * 24)
     assert eng.arena["v_win"].shape == (2 * 33, 8, 2 * 16)
@@ -360,7 +361,8 @@ def test_copy_on_write_copies_a_page_in_every_pool(tiny):
     src = eng.state.seqs[1].blocks[0]
     dst = eng.cow_block(src)
     assert dst != src
-    for name, pool in eng.arena.items():
+    from deepspeed_tpu.inference.engine_v2 import _pools
+    for name, pool in _pools(eng.arena).items():
         for layer in range(2):                   # two layers of each kind
             a = np.asarray(pool[layer * 33 + src])
             assert np.abs(a).max() > 0, name

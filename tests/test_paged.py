@@ -813,8 +813,12 @@ def test_packing_engine_serves_and_caches_what_a_row_form_engine_does(
     nb = packing.config.num_blocks
     tol = 2e-4 if dtype == "float32" else 2e-2
     assert set(packing.arena) == set(rows.arena)
+    from deepspeed_tpu.inference.engine_v2 import FED_TOKENS
     for name, pool in packing.arena.items():
-        kept = np.arange(pool.shape[0]) % (nb + 1) != nb
+        # every pool but its trash pages; the slot buffer but its trash slot
+        kept = np.arange(pool.shape[0]) % (nb + 1) != nb \
+            if name != FED_TOKENS else np.arange(pool.shape[0]) < \
+            packing.config.max_sequences
         a, b = (np.asarray(x, np.float32)[kept]
                 for x in (pool, rows.arena[name]))
         assert np.abs(a).max() > 0.01, name

@@ -224,8 +224,9 @@ def test_the_engine_chooses_no_path_by_the_environment():
 
 def _dropped_engine_after_a_step():
     """(name of the step program it ran, weak references to a serving
-    engine and to one of its weight buffers), after the engine ran a
-    prefill step and the caller let go of it."""
+    engine, to one of its weight buffers and to the program's jitted
+    function), after the engine ran a prefill step and the caller let go of
+    it."""
     import gc
     import weakref
     eng = _engine()
@@ -233,7 +234,8 @@ def _dropped_engine_after_a_step():
     (jitted,) = eng._step_fns.values()
     name = jitted.__name__
     assert name in telemetry.compile_monitor.programs()
-    refs = (weakref.ref(eng), weakref.ref(jax.tree.leaves(eng.params)[0]))
+    refs = (weakref.ref(eng), weakref.ref(jax.tree.leaves(eng.params)[0]),
+            weakref.ref(jitted))
     del eng, jitted
     gc.collect()
     return name, refs
@@ -251,7 +253,7 @@ def test_an_untraced_engine_is_freed_when_its_caller_drops_it():
     states because of the scope table."""
     assert not telemetry.tracer.enabled
     name, refs = _dropped_engine_after_a_step()
-    assert _alive(refs) == [False, False]
+    assert _alive(refs) == [False, False, False]
     assert name not in telemetry.compile_monitor.programs()
     with pytest.raises(KeyError):
         telemetry.compile_monitor.scopes(name)
@@ -263,10 +265,13 @@ def test_a_traced_run_keeps_the_program_until_its_table_was_asked(traced):
     returned): while the tracer is on the monitor keeps the program, and
     lets it go once the table is made."""
     name, refs = _dropped_engine_after_a_step()
-    assert _alive(refs) == [True, True]
+    # what is kept is the program: its body closes over the model's statics
+    # and no engine, and it is lowered again from the arguments' abstract
+    # form, so the device state goes with its caller (ISSUE 44)
+    assert _alive(refs) == [False, False, True]
     table = telemetry.compile_monitor.scopes(name)
     assert "mlp" in {e["scope"] for e in table.values()}
-    assert _alive(refs) == [False, False]
+    assert _alive(refs) == [False, False, False]
     assert name in telemetry.compile_monitor.programs()
     assert telemetry.compile_monitor.scopes(name) is table
 
@@ -279,8 +284,7 @@ def test_switching_the_tracer_on_holds_what_registered_before_it():
     tr = telemetry.tracer
     assert not tr.enabled
     eng = _engine()
-    eng._step_fn(2, 1, ("argmax",), False)
-    alive = weakref.ref(eng)
+    alive = weakref.ref(eng._step_fn(2, 1, ("argmax",), False))
     tr.configure(enabled=True)
     try:
         del eng
@@ -401,16 +405,20 @@ def test_traced_serving_step_has_its_span_tree_and_work(traced):
     events = traced.events()
     steps = _spans(events, "serving/step")
     assert len(steps) == 6
-    tree = {"serving/step": ("serving/admit", "serving/engine_step",
-                             "serving/fanout"),
-            "serving/engine_step": ("serving/pack", "serving/dispatch",
-                                    "serving/fetch")}
-    for parent, kids in tree.items():
+    # a step launches its program and THEN collects the one before it: the
+    # first step holds no fetch and no fan-out
+    tree = {"serving/step": ("serving/admit", "serving/engine_step"),
+            "serving/engine_step": ("serving/pack", "serving/dispatch")}
+    behind = {"serving/step": ("serving/fanout",),
+              "serving/engine_step": ("serving/fetch",)}
+    for parent in tree:
         parents = _spans(events, parent)
-        for name in kids:
+        for name in tree[parent] + behind[parent]:
             spans = _spans(events, name)
-            assert len(spans) == 6, name
-            assert all(_inside(k, p) for k, p in zip(spans, parents)), name
+            late = name in behind[parent]
+            assert len(spans) == 6 - late, name
+            assert all(_inside(k, p) for k, p in
+                       zip(spans, parents[late:])), name
     launches = _spans(events, "serving/dispatch")
     programs = [e["args"]["program"] for e in launches]
     assert programs[0] == "fresh" and "split" in programs and \
@@ -637,17 +645,27 @@ def test_megastep_launch_counts_the_tokens_it_emitted(traced):
 
 FRONT = ("serving/admit", "serving/plan")
 BACK = ("serving/bookkeeping", "serving/fanout", "serving/bookkeeping")
-#: the leaves of a step that launched, in order, on either path (a
+#: the leaves of a step that launched AND collected, in order, on either
+#: path: the launch (whose ``serving/retire`` marks the rows scheduled and
+#: continues them), then the fetch of the launch BEFORE it — of its own
+#: launch, in a frontend that plans megasteps — and that one's tokens (a
 #: megastep's work is known only after its fetch, so it is counted there)
 LEAVES = {
     "run": FRONT + ("serving/schedule", "serving/pack", "serving/dispatch",
-                    "serving/count", "serving/fetch", "serving/retire")
-    + BACK,
+                    "serving/count", "serving/retire", "serving/fetch",
+                    "serving/retire") + BACK,
     "megastep": FRONT + ("serving/schedule", "serving/pack",
                          "serving/dispatch", "serving/fetch",
                          "serving/count", "serving/retire") + BACK}
 ENGINE_LEAVES = {"serving/schedule", "serving/pack", "serving/dispatch",
                  "serving/count", "serving/fetch", "serving/retire"}
+#: a step with nothing in flight before its launch: nothing to fan out
+LAUNCH_ONLY = FRONT + ("serving/schedule", "serving/pack",
+                       "serving/dispatch", "serving/count",
+                       "serving/retire", "serving/bookkeeping")
+#: ... and one with nothing left to launch, that collects the last launch
+COLLECT_ONLY = FRONT + ("serving/schedule", "serving/fetch",
+                        "serving/retire") + BACK
 #: what the parent of PR 39 recorded for this batch sequence: (program,
 #: tokens, slots, kv_write_slots, context_tokens, context_slots) a launch,
 #: the ``dispatch/*`` counters' increase, the greedy tokens
@@ -664,6 +682,9 @@ PARENT = {
         "counters": {"attn_row_slots": 56, "chunk_rows": 4,
                      "context_slots": 1792, "context_tokens": 192,
                      "host_calls": 8, "kv_write_slots": 56,
+                     # every launch but the first: made before the one
+                     # ahead of it was collected (ISSUE 44)
+                     "launches_ahead": 7,
                      "steps.decode": 5, "steps.fresh": 1, "steps.split": 2,
                      "token_slots": 56, "tokens": 33}},
     "megastep": {
@@ -690,7 +711,8 @@ def _dispatch_counters():
             if n.startswith("dispatch/") and n not in HOST_COUNTERS}
 
 
-def _pump(path, steps=8, max_new_tokens=6):
+def _pump(path, steps=9, max_new_tokens=6):
+    """Eight launches in eight steps; the ninth collects the last."""
     from deepspeed_tpu.serving import ServingFrontend
     fe = ServingFrontend(
         _engine(), **({"megastep_tokens": 4} if path == "megastep" else {}))
@@ -704,28 +726,39 @@ def _pump(path, steps=8, max_new_tokens=6):
 
 @pytest.mark.parametrize("path", sorted(LEAVES))
 def test_a_step_that_launched_holds_the_leaves_once_in_order(traced, path):
-    """Each ``serving/step`` that launched a program holds every leaf once
-    (``serving/bookkeeping`` twice), in the pump's order, one ending before
-    the next begins; the engine's six lie inside ``serving/engine_step``;
-    ``serving/submit`` stands outside every step."""
+    """Each ``serving/step`` that launched a program and collected one
+    holds every leaf once (``serving/retire`` and ``serving/bookkeeping``
+    twice), in the pump's order, one ending before
+    the next begins; the engine's lie inside ``serving/engine_step``;
+    ``serving/submit`` stands outside every step. The pump that runs ahead
+    opens with a step that only launches and closes with one that only
+    collects."""
     _pump(path)
     events = [e for e in traced.events() if e["ph"] == "X"]
     steps = _spans(events, "serving/step")
-    assert len(steps) == 8
+    assert len(steps) == 9
     names = set(LEAVES["run"])
     seen = set()
     for step in steps:
         inside = sorted((e for e in events if e["name"] in names and
                          _inside(e, step)), key=lambda e: e["ts"])
         order = tuple(e["name"] for e in inside)
-        if "serving/dispatch" not in order:
+        if "serving/dispatch" not in order and "serving/fetch" not in order:
             # the pump ran dry: no program, no fan-out
             assert order == FRONT + ("serving/schedule",
                                      "serving/bookkeeping")
             continue
+        if "serving/dispatch" not in order:
+            assert path == "run" and step is steps[-1]
+            assert order == COLLECT_ONLY
+            continue
         (launch,) = (e for e in inside if e["name"] == "serving/dispatch")
         kind = "megastep" if launch["args"]["program"] == "megastep" \
             else "run"
+        if "serving/fetch" not in order:
+            assert path == "run" and step is steps[0]
+            assert order == LAUNCH_ONLY
+            continue
         seen.add(kind)
         assert order == LEAVES[kind]
         for a, b in zip(inside, inside[1:]):
@@ -859,10 +892,10 @@ def test_host_and_wait_seconds_tile_the_time_between_fetches(monkeypatch):
     fe = ServingFrontend(_engine())
     before = seconds()
     fe.submit(list(range(1, 12)), max_new_tokens=4)
-    for _ in range(5):                      # 2 chunks, then 3 decode steps
+    for _ in range(6):      # 2 chunks, 3 decode steps; the last's collect
         fe.step()
     host, wait = (b - a for a, b in zip(before, seconds()))
-    assert len(reads) == 2 * 5              # two clock reads a launch
+    assert len(reads) == 2 * 5              # two clock reads a fetch
     assert host > 0 and wait > 0
     assert host + wait == pytest.approx(reads[-1] - reads[0], abs=1e-9)
     assert wait == pytest.approx(sum(reads[1::2]) - sum(reads[0::2]))
@@ -872,7 +905,8 @@ def test_host_and_wait_seconds_tile_the_time_between_fetches(monkeypatch):
     time.sleep(0.05)
     fe.submit(list(range(1, 5)), max_new_tokens=2)
     mid = seconds()
-    fe.step()
+    fe.step()               # the launch
+    fe.step()               # ... and its fetch: the first since the stamp
     host2, wait2 = (b - a for a, b in zip(mid, seconds()))
     assert host2 == 0.0 and wait2 == pytest.approx(reads[-1] - reads[-2])
 

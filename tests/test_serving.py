@@ -312,7 +312,7 @@ def test_cow_block_copies_all_layers(devices):
     k = np.array(eng.arena["k"])           # writable host copy
     for layer in range(nl):
         k[layer * stride + src] = float(layer + 1)
-    eng.arena = {"k": jnp.asarray(k), "v": eng.arena["v"]}
+    eng.arena = {**eng.arena, "k": jnp.asarray(k)}
     dst = eng.cow_block(src)
     assert dst != src and alloc.refcount(dst) == 1
     got = np.asarray(eng.arena["k"])

@@ -552,6 +552,55 @@ def test_no_serve_step_moves_the_arena(kind, one_chip, no_persistent_cache,
     assert compiled.cost_analysis()["flops"] <= 0.35 * 3.593e12
 
 
+@pytest.mark.parametrize("kind", list(_SERVE_STEPS))
+def test_step_program_carries_the_fed_tokens_in_place(kind, one_chip,
+                                                      no_persistent_cache,
+                                                      monkeypatch):
+    """The engine's OWN step program (``engine_v2._step_program``: what
+    ``_step_fn`` jits, the packed vector in, the slot buffer riding in the
+    donated arena; ISSUE 44) at the serving cell's 64 rows: it compiles for
+    the chip, the buffer is read by a gather and written by ONE scatter of
+    64 updates under ``sample``, the pools are
+    moved no more than without it (no arena-shaped copy, the same bound on
+    temporaries) and every arena entry is updated in place (aliased with
+    its donated input)."""
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.ops import paged_attention as pa
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = _mistral_2l()
+    cb, fresh, capacities, most_temp = _SERVE_STEPS[kind]
+    nb, mb, slots = 64, 32, 64
+    fn = engine_v2._step_program(model, nb, cb, mb, ("argmax",), fresh,
+                                 capacities, True, None)
+
+    def make_arena():
+        arena = pa.init_arena(model.num_layers, model.kv_heads, 512, 128,
+                              model.head_dim, jnp.bfloat16)
+        arena[engine_v2.FED_TOKENS] = jnp.zeros((slots + 1,), jnp.int32)
+        return arena
+    arena = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(make_arena))
+    packed = jax.ShapeDtypeStruct((nb * cb + 3 * nb + nb * mb + 2,),
+                                  jnp.int32, sharding=one_chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        _abstract_params(model, one_chip), arena, packed, rng).compile()
+    text = compiled.as_text()
+    pool = arena["k"]
+    shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    assert not re.search(rf" = {re.escape(shape)}\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < most_temp
+    (updates,) = _kv_scatter_updates(text, [arena[engine_v2.FED_TOKENS]])
+    assert updates == nb
+    # ... written under ``sample``, where the tokens are made
+    assert re.search(rf"= s32\[{slots + 1}\]\S* scatter\(.*"
+                     rf"op_name=\"jit\(fn\)/sample/scatter\"", text)
+    # three arena entries, each an output that aliases its donated input
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert aliases.group(1).count("-alias)") == 3, aliases.group(1)
+
+
 # -- the latent stack (GigaChat3.1 / DeepSeek-V3 widths), two layers ---------
 
 #: kind -> (chunk, fresh_prefill, token capacities, most temporaries at two
