@@ -629,10 +629,8 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     seen = dict.fromkeys(of_kind, 0)
     places = []     # a layer's pools, and where its pages lie in them
     for kind in cfg.layer_kinds:
-        if kind == 3:
-            # (first slot of the layer's region, the region's slots)
-            stride = arena["ssm"].shape[0] // of_kind[kind]
-            places.append((seen[kind] * stride, stride))
+        if kind == 3:       # the layer's own two pools (ops/ssm.py)
+            places.append(ssm.pool_names(seen[kind]))
             seen[kind] += 1
             continue
         if kind not in pa.KIND_POOLS:
@@ -756,16 +754,25 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
 
     def state_space(lay, p, place, h_in, pools):
         """A state-space layer's mixer on its normed input (token-wise
-        form) → its output in the same form; ``pools`` holds the state
-        pools it reads and writes. Rows of ONE query first, in SLOT order:
-        the layer's whole region takes one elementwise pass in place (a
-        slot with no live row has ``Δ = 0`` and keeps its state; a wide
-        row's is reset or left as it is), the rows' inputs scattered to
-        their slots and their outputs gathered back, both small. Then the
-        rows of the chunk's width: their states gathered, the chunk form,
-        the results scattered (a row riding along with no live query in
-        the chunk group writes nothing)."""
-        lo, region = place
+        form) → its output in the same form; ``pools`` holds the layer's
+        two state pools (``place``: their names), which it reads and
+        writes. Rows of ONE query first, in SLOT order: the layer's whole
+        pool takes one elementwise pass (a slot with no live row has ``Δ =
+        0`` and keeps its state; a wide row's is reset or left as it is),
+        the rows' inputs scattered to their slots and their outputs
+        gathered back, both small. Then the rows of the chunk's width:
+        their states gathered, the chunk form, the results scattered (a
+        row riding along with no live query in the chunk group writes
+        nothing)."""
+        sname, cname = place
+        region = pools[sname].shape[0]
+        with jax.named_scope("ssm_state"):
+            # a layer's pools are read at ITS turn: free of the stream, the
+            # compiler gathers every layer's rows at the program's start,
+            # side by side (64 chunk rows x 9 layers of 4 MiB states: 2.3
+            # GB of temporaries at Granite 4.0-H's widths)
+            pools[sname], pools[cname], h_in = lax.optimization_barrier(
+                (pools[sname], pools[cname], h_in))
         z, xbc, dt = tl.ssm_in(cfg, p, h_in)
         fresh_row = ssm.fresh_rows(starts)
         groups = lay.groups()
@@ -774,10 +781,10 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             at, reset = group.of(slots), group.of(fresh_row)
             live = group.counts
             with jax.named_scope("ssm_state"):
-                tail = ssm.tail_rows(cfg, ssm.carried(
-                    pools["conv"][lo + at], reset))
+                tail = ssm.tail_rows(cfg, ssm.carried(pools[cname][at],
+                                                      reset))
                 if group.c > 1:
-                    state = ssm.carried(pools["ssm"][lo + at], reset)
+                    state = ssm.carried(pools[sname][at], reset)
             with jax.named_scope("ssm_conv"):
                 u, tail = ssm.conv_rows(cfg, p, group.take(xbc), tail, live)
                 dt_g = group.take(dt)
@@ -786,15 +793,15 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                     outs[i], state = ssm.scan_chunk(cfg, p, u, dt_g, state,
                                                     live)
                 with jax.named_scope("ssm_state"):
-                    to = lo + at if group.ids is None else jnp.where(
-                        live > 0, lo + at, pools["ssm"].shape[0])
-                    pools["ssm"] = pools["ssm"].at[to].set(state,
+                    to = at if group.ids is None else jnp.where(
+                        live > 0, at, region)
+                    pools[sname] = pools[sname].at[to].set(state,
                                                            mode="drop")
-                    pools["conv"] = pools["conv"].at[to].set(
+                    pools[cname] = pools[cname].at[to].set(
                         tail.reshape(tail.shape[0], -1), mode="drop")
                 continue
             with jax.named_scope("ssm_state"):
-                pools["conv"] = pools["conv"].at[lo + at].set(
+                pools[cname] = pools[cname].at[at].set(
                     tail.reshape(tail.shape[0], -1))
 
                 def by_slot(rows):
@@ -805,20 +812,16 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                                         for t in (u, dt_g, live, reset))
             with jax.named_scope("ssm_scan"):
                 # (the reset rides in the decay: ``ssm.carried`` over the
-                # region would be a second pass over it)
-                y, state = ssm.scan_step(
-                    cfg, p, u, dt_g, lax.slice_in_dim(pools["ssm"], lo,
-                                                      lo + region), live,
-                    reset)
+                # pool would be a second pass over it)
+                y, pools[sname] = ssm.scan_step(cfg, p, u, dt_g,
+                                                pools[sname], live, reset)
             with jax.named_scope("ssm_state"):
-                pools["ssm"] = lax.dynamic_update_slice_in_dim(
-                    pools["ssm"], state, lo, axis=0)
                 outs[i] = y[at]
         with jax.named_scope("ssm_scan"):
             y = lay.from_groups(outs)
         return tl.ssm_out(cfg, p, y, z)
 
-    carried = tuple(name for name in ssm.STATE_POOLS if name in arena)
+    carried = tuple(name for name in arena if ssm.is_state_pool(name))
 
     def run(capacity, chunk_rows, state=None):
         """Embedding to final norm at one instance → (each row's last
@@ -1182,6 +1185,11 @@ class RaggedInferenceEngineTPU:
             moe_fn = serving_moe_fn(model, config.weight_quant,
                                     self.params, ep=ep)
         self._moe_fn = moe_fn
+        #: (token, expert) assignments ONE fed token makes over the stack's
+        #: sparse layers (``dispatch/moe_assignments``); 0: no experts
+        self._moe_assignments_per_token = model.num_experts_per_tok * sum(
+            model.layer_is_sparse(l) for l in range(model.num_layers)) \
+            if model.num_experts else 0
         #: jit cache keyed on (n_bucket, c_bucket, mode, fresh) — the
         #: fresh=True/False split legitimately doubles prefill-shape
         #: compiles (arena-reading vs within-chunk attention programs).
@@ -1760,7 +1768,7 @@ class RaggedInferenceEngineTPU:
         stride = self.config.num_blocks + 1
         return sum(a.nbytes // a.shape[0] * (a.shape[0] // stride)
                    for name, a in _pools(self.arena).items()
-                   if name not in ssm.STATE_POOLS)
+                   if not ssm.is_state_pool(name))
 
     def _refuse_typed(self, what: str) -> None:
         if self.model_config.recurrent:
@@ -2022,7 +2030,11 @@ class RaggedInferenceEngineTPU:
         arguments of those names. ``state`` (:meth:`_state_work`: a
         recurrent stack) adds ``dispatch/state_rows``,
         ``dispatch/state_resets`` and ``dispatch/ssm_chunk_tokens`` and the
-        span's arguments of those names."""
+        span's arguments of those names. A stack with experts adds
+        ``dispatch/moe_assignments`` and the span's ``moe_assignments``:
+        fed tokens x experts a token x sparse layers, what the launch's
+        routers hand the experts' dispatch (all of them, held here or
+        not)."""
         from deepspeed_tpu.telemetry.registry import registry
         row_slots = nb * chunk * scan_steps
         slots = row_slots if token_slots is None else token_slots
@@ -2070,6 +2082,11 @@ class RaggedInferenceEngineTPU:
                                  "ssm_chunk_tokens"), state):
                 work[name] = by
                 registry.counter("dispatch/" + name).inc(by)
+        if self._moe_assignments_per_token:
+            work["moe_assignments"] = tokens * \
+                self._moe_assignments_per_token
+            registry.counter("dispatch/moe_assignments").inc(
+                work["moe_assignments"])
         return work
 
     # -- fused decode loop (the megastep's program) ------------------------

@@ -34,7 +34,7 @@ _FAMILIES = ("llama", "mistral", "mixtral", "qwen", "qwen2", "qwen2_moe",
               "gpt_neox", "gemma", "gpt2", "opt", "bloom", "falcon",
               "phi", "phi3", "gpt_bigcode", "gptj", "bert", "distilbert",
               "gpt_neo", "internlm", "mimo_v2", "deepseek_v3",
-              "cohere2_moe", "nemotron_h")
+              "cohere2_moe", "nemotron_h", "granitemoehybrid")
 
 
 def _map_hf_act(act: str) -> str:
@@ -62,6 +62,8 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
         return _cohere2_moe_config(hf)
     if mt == "nemotron_h":
         return _nemotron_h_config(hf)
+    if mt == "granitemoehybrid":
+        return _granitemoehybrid_config(hf)
     if mt == "bert":
         return DecoderConfig(
             hidden_size=hf["hidden_size"],
@@ -538,13 +540,14 @@ def _cohere2_moe_config(hf: Dict[str, Any]) -> DecoderConfig:
     a share is told apart by ``expert_share`` (not a published key:
     ``{"router_experts", "first_expert", "held_experts"}``): the router
     keeps ``num_experts`` outputs and the weights hold ``held_experts``.
-    Refused by name: QK norm, attention biases, a ``logit_scale`` other
-    than 1, leading dense layers (``first_k_dense_replace`` > 0; with none,
+    ``logit_scale`` multiplies the logits (``logits_scaling`` = its
+    inverse). Refused by name: QK norm, attention biases, leading dense
+    layers (``first_k_dense_replace`` > 0; with none,
     ``prefix_dense_*`` name no layer), any ``rope_type`` but ``default``.
     Not built: the vision tower (no key of the language model's config)."""
     fam = "cohere2_moe"
     for key, want in (("use_qk_norm", False), ("attention_bias", False),
-                      ("logit_scale", 1), ("first_k_dense_replace", 0),
+                      ("first_k_dense_replace", 0),
                       ("use_parallel_block", True),
                       ("use_gated_activation", True),
                       ("hidden_act", "silu"),
@@ -601,7 +604,8 @@ def _cohere2_moe_config(hf: Dict[str, Any]) -> DecoderConfig:
         experts_held=(int(share["first_expert"]),
                       int(share["held_experts"])) if share else None,
         shared_expert_size=shared_n * width,
-        shared_experts_averaged=max(shared_n, 1))
+        shared_experts_averaged=max(shared_n, 1),
+        logits_scaling=1.0 / float(hf.get("logit_scale") or 1))
 
 
 def _nemotron_h_config(hf: Dict[str, Any]) -> DecoderConfig:
@@ -671,6 +675,98 @@ def _nemotron_h_config(hf: Dict[str, Any]) -> DecoderConfig:
         ssm_conv_kernel=int(hf["conv_kernel"]), **router)
 
 
+def _granitemoehybrid_config(hf: Dict[str, Any]) -> DecoderConfig:
+    """GraniteMoeHybrid's stack (Granite 4.0-H; ``model_type:
+    granitemoehybrid``): a typed stack (models/typed_layers.py has the
+    equations) whose EVERY layer is a mixer AND the experts under two
+    RMSNorms (``rms_norm_eps``). ``layer_types`` names each layer's mixer:
+    ``mamba`` a Mamba-2 mixer (``mamba_n_heads`` heads of ``mamba_d_head``
+    — which has to be ``mamba_expand`` x hidden —, ``mamba_n_groups``,
+    ``mamba_d_state``, ``mamba_d_conv``) or ``attention``
+    (``num_attention_heads`` / ``num_key_value_heads`` heads of hidden /
+    heads, ``position_embedding_type: nope``: NO positional term, so
+    ``rope_theta`` is held and read by nothing); the list may be longer
+    than ``num_hidden_layers`` (the first ``num_hidden_layers`` entries are
+    read). Every layer ends in ``num_local_experts`` SiLU-GLU experts of
+    ``intermediate_size`` — ``num_experts_per_tok`` a token, weighed by the
+    softmax over the kept router logits — beside one shared expert of
+    ``shared_intermediate_size``. Four scalars: ``embedding_multiplier``,
+    ``attention_multiplier`` (the scores' factor), ``residual_multiplier``
+    (each branch sum), ``logits_scaling`` (a divisor); the head is tied.
+    ONE published key names both the router's width and the expert count,
+    so a share is ``expert_share`` (not a published key:
+    ``{"first_expert", "held_experts"}``; ``router_experts``, where given,
+    has to be ``num_local_experts``). Refused by name: biases other than
+    the convolution's, a ``position_embedding_type`` other than ``nope``,
+    ``rope_scaling``, another activation or norm, an untied head, a stack
+    with no shared expert, an unknown layer type."""
+    fam = "granitemoehybrid"
+    for key, want in (("attention_bias", False), ("mamba_proj_bias", False),
+                      ("mamba_conv_bias", True), ("hidden_act", "silu"),
+                      ("normalization_function", "rmsnorm"),
+                      ("position_embedding_type", "nope"),
+                      ("rope_scaling", None),
+                      ("tie_word_embeddings", True)):
+        if hf.get(key, want) != want:
+            raise ValueError(f"{fam}: {key}={hf[key]!r} is not built "
+                             f"(expected {want!r})")
+    L = int(hf["num_hidden_layers"])
+    names = {"mamba": 3, "attention": 0}
+    if len(hf["layer_types"]) < L:
+        raise ValueError(f"{fam}: layer_types has "
+                         f"{len(hf['layer_types'])} entries for {L} layers")
+    for name in hf["layer_types"][:L]:
+        if name not in names:
+            raise ValueError(f"{fam}: layer type {name!r} is not built "
+                             f"(expected one of {sorted(names)})")
+    heads, p_dim = int(hf["mamba_n_heads"]), int(hf["mamba_d_head"])
+    if heads * p_dim != int(hf["mamba_expand"]) * int(hf["hidden_size"]):
+        raise ValueError(
+            f"{fam}: mamba_n_heads x mamba_d_head = {heads * p_dim} is not "
+            f"mamba_expand x hidden_size = "
+            f"{int(hf['mamba_expand']) * int(hf['hidden_size'])}")
+    if not int(hf.get("shared_intermediate_size") or 0):
+        raise ValueError(f"{fam}: shared_intermediate_size="
+                         f"{hf.get('shared_intermediate_size')!r} is not "
+                         f"built (every layer has one shared expert)")
+    E = int(hf["num_local_experts"])
+    share = hf.get("expert_share")
+    if share and int(share.get("router_experts", E)) != E:
+        raise ValueError(
+            f"{fam}: expert_share.router_experts="
+            f"{share['router_experts']!r} is not num_local_experts={E} "
+            f"(the one published key is the router's width; the share's "
+            f"count is expert_share.held_experts)")
+    return DecoderConfig(
+        hidden_size=hf["hidden_size"], num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        intermediate_size=int(hf["intermediate_size"]),
+        vocab_size=hf["vocab_size"],
+        max_seq_len=hf.get("max_position_embeddings", 4096),
+        norm="rmsnorm", activation="silu_glu", pos_emb="rope",
+        norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        full_attn_rope=False, use_bias=False, tie_embeddings=True,
+        layer_kinds=tuple(names[n] for n in hf["layer_types"][:L]),
+        layer_sparse=(1,) * L,
+        ssm_heads=heads, ssm_head_dim=p_dim,
+        ssm_groups=int(hf["mamba_n_groups"]),
+        ssm_state_size=int(hf["mamba_d_state"]),
+        ssm_conv_kernel=int(hf["mamba_d_conv"]),
+        num_experts=E, num_experts_per_tok=int(hf["num_experts_per_tok"]),
+        router_scoring="softmax", norm_topk_prob=True,
+        router_select_bias=False,
+        experts_held=(int(share["first_expert"]),
+                      int(share["held_experts"])) if share else None,
+        shared_expert_size=int(hf["shared_intermediate_size"]),
+        embedding_multiplier=float(hf.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(hf.get("residual_multiplier", 1.0)),
+        logits_scaling=float(hf.get("logits_scaling", 1.0)),
+        attention_multiplier=float(hf["attention_multiplier"])
+        if hf.get("attention_multiplier") is not None else None)
+
+
 def _is_gemma_layout(cfg: DecoderConfig) -> bool:
     return cfg.activation == "gelu_glu" and cfg.scale_embeddings
 
@@ -711,7 +807,7 @@ def config_to_hf(cfg: DecoderConfig) -> Dict[str, Any]:
     if cfg.typed:
         raise NotImplementedError(
             "config_to_hf: a typed layer stack (mimo_v2, deepseek_v3, "
-            "cohere2_moe, nemotron_h) has no exporter")
+            "cohere2_moe, nemotron_h, granitemoehybrid) has no exporter")
     if not cfg.causal or not cfg.prenorm:
         # encoder layouts (BERT/DistilBERT): both flags flip together
         if cfg.causal or cfg.prenorm or cfg.pos_emb != "learned" \

@@ -232,6 +232,19 @@ class DecoderConfig:
     ssm_groups: int = 1
     ssm_state_size: int = 0
     ssm_conv_kernel: int = 4
+    # -- Granite's four scalar multipliers (``hf_loader``:
+    # ``granitemoehybrid``); 1.0 / None add no operation to a program --
+    #: the token embedding times this on the way in (a free factor:
+    #: ``scale_embeddings`` is Gemma's sqrt(hidden))
+    embedding_multiplier: float = 1.0
+    #: each branch sum (the mixer's, the feed-forward's) times this before
+    #: it joins the residual stream (typed stacks: ``tl.block_residual``)
+    residual_multiplier: float = 1.0
+    #: the head's logits DIVIDED by this on the way out
+    logits_scaling: float = 1.0
+    #: the scores' factor where the model states one; None →
+    #: ``head_dim ** -0.5`` (:attr:`attn_scale`)
+    attention_multiplier: Optional[float] = None
 
     def __post_init__(self):
         if self.mlm_head and not self.tie_embeddings:
@@ -317,11 +330,13 @@ class DecoderConfig:
 
     @property
     def attn_scale(self) -> float:
-        """The scores' factor: ``head_dim ** -0.5``, times YaRN's
+        """The scores' factor: ``attention_multiplier`` where the model
+        states one, else ``head_dim ** -0.5``, times YaRN's
         ``mscale(factor, mscale_all_dim) ** 2`` where that is set (HF
         ``deepseek_v3``: the sin / cos carry ``mscale / mscale_all_dim``,
         the softmax the rest)."""
-        scale = self.head_dim ** -0.5
+        scale = self.head_dim ** -0.5 if self.attention_multiplier is None \
+            else float(self.attention_multiplier)
         if self.rope_yarn is not None and self.rope_yarn[5]:
             scale *= yarn_mscale(self.rope_yarn[0], self.rope_yarn[5]) ** 2
         return scale
@@ -436,13 +451,27 @@ class DecoderConfig:
             if self.moe_residual:
                 mlp += dense_mlp + 2 * d + 2   # dense MLP + coefficient
         per_layer = attn + mlp + 2 * d
+        layers = l * per_layer
+        if self.recurrent:
+            # a hybrid stack: a state-space layer's mixer is its two
+            # projections, convolution and per-head vectors; a layer may
+            # have no mixer or no feed-forward part
+            ssm = d * (self.ssm_inner + self.ssm_conv_dim + self.ssm_heads) \
+                + self.ssm_inner * d + self.ssm_inner \
+                + self.ssm_conv_dim * (self.ssm_conv_kernel + 1) \
+                + 3 * self.ssm_heads
+            layers = sum(
+                (ssm if kind == 3 else attn if kind >= 0 else 0)
+                + (mlp + d if self.layer_has_ffn(i) else 0)
+                + (d if kind >= 0 or not self.layer_has_ffn(i) else 0)
+                for i, kind in enumerate(self.layer_kinds))
         emb = v * d + (self.max_seq_len * d if self.pos_emb == "learned"
                        else 0) + self.type_vocab_size * d
         head = 0 if self.tie_embeddings else v * d + (v if self.lm_head_bias
                                                       else 0)
         if self.mlm_head:
             head += d * d + 3 * d + v
-        return l * per_layer + emb + head + d
+        return layers + emb + head + d
 
     def num_active_params(self) -> int:
         """Parameters touched per token (== num_params for dense models;
@@ -453,7 +482,9 @@ class DecoderConfig:
         d, h = self.hidden_size, self.ffn_size
         expert = (3 if self.is_glu else 2) * d * h
         inactive = (self.num_experts - self.num_experts_per_tok) * expert
-        return self.num_params() - self.num_layers * inactive
+        sparse = sum(self.layer_is_sparse(i) for i in range(self.num_layers)
+                     ) if self.recurrent else self.num_layers
+        return self.num_params() - sparse * inactive
 
 
 # ---------------------------------------------------------------------------
@@ -488,13 +519,16 @@ def embed_tokens(cfg: DecoderConfig, em: Params, tokens: jax.Array,
                  embed_norm: Optional[Params] = None,
                  token_type_ids: Optional[jax.Array] = None) -> jax.Array:
     """The ONE home for token-embedding semantics (Gemma sqrt(d) scaling,
-    learned positions, BLOOM word_embeddings_layernorm, BERT token-type
+    Granite's ``embedding_multiplier``, learned positions, BLOOM word_embeddings_layernorm, BERT token-type
     segments) — shared by forward_hidden, forward_with_cache, the
     pipeline stages, and the ragged inference engine so a new
     embed-affecting knob can't silently diverge between paths."""
     x = em["tokens"][tokens]
     if cfg.scale_embeddings:
         x = (x.astype(jnp.float32) * math.sqrt(cfg.hidden_size)
+             ).astype(x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = (x.astype(jnp.float32) * cfg.embedding_multiplier
              ).astype(x.dtype)
     if cfg.pos_emb == "learned":
         x = x + em["pos"][positions]
@@ -1191,7 +1225,11 @@ def forward_hidden(cfg: DecoderConfig, params: Params, tokens: jax.Array,
 
 
 def _softcap(cfg: DecoderConfig, logits: jax.Array) -> jax.Array:
-    """Gemma2 final_logit_softcapping: c·tanh(logits/c)."""
+    """What a head's raw logits still go through: Granite's
+    ``logits_scaling`` (a division), Gemma2's final_logit_softcapping
+    ``c·tanh(logits/c)``."""
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.logit_softcap:
         c = cfg.logit_softcap
         return c * jnp.tanh(logits / c)

@@ -81,6 +81,29 @@ beside the pages of the attention layers. The experts of this family are
 UN-GATED: ``W_down·relu(W_up·h)²`` (``activation == "relu2"``: the trees
 hold ``wi``, ``wo`` and no ``wg``), the shared expert likewise.
 
+A TWO-PART hybrid layer (Granite 4.0-H's, ``hf_loader``:
+``granitemoehybrid``) is a mixer AND the experts under two norms, every
+layer, with four scalar multipliers (``cfg.embedding_multiplier`` ``e``,
+``residual_multiplier`` ``r``, ``attention_multiplier`` ``a``,
+``logits_scaling`` ``s``):
+
+- ``x₀ = e · E[ids]``; ``h = RMSNorm(x)``; ``x ← x + r · Mixer(h)``: the
+  state-space mixer above (kind 3; ONE group: ``B`` and ``C`` shared by all
+  heads, the gated norm over the whole inner width) or attention (kind 0)
+  with NO positional term and scores ``a · q·k`` (``cfg.attn_scale`` is the
+  configured factor, not ``1/√Dk``: 1/128 at a head of 128);
+- ``h₂ = RMSNorm(x)``; the router keeps the ``k`` largest LOGITS of
+  ``h₂·W_r`` and weighs them by the softmax over THOSE ``k`` (float32;
+  ``router_scoring == "softmax"`` with ``norm_topk_prob``:
+  ``parallel/moe.route_tokens``); ``x ← x + r · (Σ_k gate_k · GLU_{e_k}(h₂)
+  + Shared(h₂))``, SiLU-GLUs of ``intermediate_size`` and
+  ``shared_expert_size``;
+- final RMSNorm; ``logits = x·Eᵀ / s`` (a tied head).
+
+A state-space layer with a feed-forward part has ``ln2`` and ``moe``
+(``shared``) beside ``ssm`` in its tree: :func:`block_residual` and the
+engine's layer loop take both parts by the tree's shape.
+
 **The residual stream is float32** whatever the parameters' dtype
 (:func:`residual_stream`): the matmuls take the norms' outputs cast to the
 compute dtype, their results are added in float32, and the router reads
@@ -130,7 +153,8 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
             "rotary positions (or none on the full kind): RMSNorm or "
             "LayerNorm, a sequential block or a parallel one under ONE "
             "norm, a tied or an untied head (mimo_v2, deepseek_v3, "
-            "cohere2_moe), or for un-gated relu2 experts (nemotron_h)")
+            "cohere2_moe, granitemoehybrid), or for un-gated relu2 experts "
+            "(nemotron_h)")
     d, v, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
     dk, dv, H = cfg.head_dim, cfg.v_dim, cfg.num_heads
     out_std = cfg.init_std / math.sqrt(2 * L)
@@ -189,8 +213,15 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
             lp["mlp"] = {**{name: w((d, f)) for name in glu},
                          "wo": w((f, d), out_std)}
         layers.append(lp)
-    params = {"embed": {"tokens": w((v, d))}, "layers": layers,
-              "final_norm": tf._norm_params(cfg)}
+    # the embedding at ``init_std / embedding_multiplier``: the stream then
+    # STARTS at the scale the other stacks' does. At 0.02 x 12 a tied head
+    # scores the input token itself 46 logit spreads over every other token
+    # (``12·|E[id]|²`` against ``x·E[j]``), and a served token is its
+    # prompt's last whatever the layers compute: a null model for every
+    # comparison of tokens
+    params = {"embed": {"tokens": w((v, d), cfg.init_std /
+                                    cfg.embedding_multiplier)},
+              "layers": layers, "final_norm": tf._norm_params(cfg)}
     if not cfg.tie_embeddings:
         params["lm_head"] = w((d, v))
     return params
@@ -356,6 +387,18 @@ def typed_ffn(cfg, lp, h: jax.Array, moe_fn: Optional[Callable],
         return out + shared
 
 
+def _branch(cfg, lp, part: str, out: jax.Array) -> jax.Array:
+    """A branch sum on its way into the stream: times
+    ``cfg.residual_multiplier`` in float32 under its part's scope (``part``
+    "mixer" or "ffn"); at 1.0 as it is, no operation."""
+    if cfg.residual_multiplier == 1.0:
+        return out
+    scope = ("ssm_out" if "ssm" in lp else "attn_out") if part == "mixer" \
+        else ("moe" if "moe" in lp else "mlp")
+    with jax.named_scope(scope):
+        return out.astype(jnp.float32) * cfg.residual_multiplier
+
+
 def block_residual(cfg, lp, x: jax.Array, h: jax.Array,
                    mixer_out: Optional[jax.Array],
                    moe_fn: Optional[Callable], valid, dtype) -> jax.Array:
@@ -365,16 +408,20 @@ def block_residual(cfg, lp, x: jax.Array, h: jax.Array,
     its feed-forward part reads ``h``): sequential (``x + a``, then the
     feed-forward on ``norm2`` of that) or PARALLEL (``cfg.parallel_block``:
     the feed-forward reads the SAME ``h``, and both are added). A layer
-    whose tree has no feed-forward part is ``x + a``."""
+    whose tree has no feed-forward part is ``x + a``. Each branch sum joins
+    the stream through :func:`_branch` (``cfg.residual_multiplier``)."""
+    def ffn(h_in):
+        return _branch(cfg, lp, "ffn",
+                       typed_ffn(cfg, lp, h_in, moe_fn, valid, dtype))
+
     if "moe" not in lp and "mlp" not in lp:
-        return x + mixer_out
+        return x + _branch(cfg, lp, "mixer", mixer_out)
     if mixer_out is None:
-        return x + typed_ffn(cfg, lp, h, moe_fn, valid, dtype)
+        return x + ffn(h)
     if cfg.parallel_block:
-        return x + mixer_out + typed_ffn(cfg, lp, h, moe_fn, valid, dtype)
-    x = x + mixer_out
-    return x + typed_ffn(cfg, lp, tf._norm(cfg, lp["ln2"], x), moe_fn, valid,
-                         dtype)
+        return x + _branch(cfg, lp, "mixer", mixer_out) + ffn(h)
+    x = x + _branch(cfg, lp, "mixer", mixer_out)
+    return x + ffn(tf._norm(cfg, lp["ln2"], x))
 
 
 def ssm_in(cfg, p, h: jax.Array):

@@ -21,8 +21,9 @@ Two forms of ONE scan, picked by the row's width (a shape, not an option):
 - :func:`scan_step` (``c == 1``): the recurrence ``S ← a·S + Δ·x ⊗ B``,
   ``y = S·C + D·x``, elementwise in float32: bound by the state's bytes.
 
-The state pools (:func:`init_state_pools`) hold a slot a sequence and
-state-space layer, flat over the layers as the KV pools are over theirs."""
+The state pools (:func:`init_state_pools`) hold a slot a sequence, ONE
+pool a state-space layer: a launch's one-token pass rewrites a layer's
+whole pool, and a buffer of its own bounds what the compiler may copy."""
 
 from typing import Dict, Tuple
 
@@ -31,23 +32,44 @@ import jax.numpy as jnp
 from jax import lax
 
 
-#: the state pools' names in an engine's arena, beside the KV pools'
+#: the state pools' names in an engine's arena, beside the KV pools': the
+#: state ``ssm<i>`` and the convolution's carried inputs ``conv<i>`` of the
+#: ``i``-th state-space layer (:func:`pool_names`)
 STATE_POOLS = ("ssm", "conv")
 
 
+def pool_names(i: int) -> Tuple[str, str]:
+    """The arena's names of the ``i``-th state-space layer's two pools."""
+    return tuple(f"{kind}{i}" for kind in STATE_POOLS)
+
+
+def is_state_pool(name: str) -> bool:
+    """An arena entry's name is one of :func:`pool_names`'."""
+    return name not in STATE_POOLS and \
+        name.rstrip("0123456789") in STATE_POOLS
+
+
 def init_state_pools(cfg, slots: int, dtype) -> Dict[str, jax.Array]:
-    """``{"ssm": [M·(slots + 1), H, P, N] float32, "conv": [M·(slots + 1),
-    (K − 1)·(d + 2GN)] dtype}`` for the ``M`` state-space layers of
-    ``cfg``: layer ``i`` (among them) keeps sequence slot ``s`` at ``i·(slots
-    + 1) + s``; a region's last slot is its trash (padding rows of a step).
-    A slot's ``K − 1`` convolution inputs lie side by side in one row (a
-    dimension of 3 would be padded to a tile of 8, and the compiler
-    relaid the pool on its way in and out of every program)."""
-    m = sum(1 for kind in cfg.layer_kinds if kind == 3) * (slots + 1)
-    return {"ssm": jnp.zeros((m, cfg.ssm_heads, cfg.ssm_head_dim,
-                              cfg.ssm_state_size), jnp.float32),
-            "conv": jnp.zeros((m, (cfg.ssm_conv_kernel - 1) *
-                               cfg.ssm_conv_dim), dtype)}
+    """``{"ssm<i>": [slots + 1, H, P, N] float32, "conv<i>": [slots + 1,
+    (K − 1)·(d + 2GN)] dtype}`` for the ``i``-th of the state-space layers
+    of ``cfg``: sequence slot ``s`` at row ``s``, the last row the trash
+    (padding rows of a step). A POOL A LAYER, not one flat pool as the KV
+    pools are: a step's one-token pass rewrites a layer's whole pool, and
+    where the compiler cannot show an update to be in place it copies the
+    buffer the update is made on — 2.3 GB a layer of a flat pool at Granite
+    4.0-H's nine layers of 4 MiB states (found on the v5e, PR 45: the
+    64-row split program asked for 16.2 GB), one layer's 0.27 GB here. A
+    slot's ``K − 1`` convolution inputs lie side by side in one row (a
+    dimension of 3 would be padded to a tile of 8, and the compiler relaid
+    the pool on its way in and out of every program)."""
+    pools = {}
+    for i in range(sum(1 for kind in cfg.layer_kinds if kind == 3)):
+        state, conv = pool_names(i)
+        pools[state] = jnp.zeros((slots + 1, cfg.ssm_heads, cfg.ssm_head_dim,
+                                  cfg.ssm_state_size), jnp.float32)
+        pools[conv] = jnp.zeros((slots + 1, (cfg.ssm_conv_kernel - 1) *
+                                 cfg.ssm_conv_dim), dtype)
+    return pools
 
 
 def fresh_rows(starts: jax.Array) -> jax.Array:

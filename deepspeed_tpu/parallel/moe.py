@@ -491,7 +491,7 @@ def moe_layer(cfg, p, x: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Sigmoid / selection-bias routing and the expert-parallel SHARE (serving)
+# The expert-parallel SHARE and its routers (serving)
 # ---------------------------------------------------------------------------
 
 #: capacity of one round of the share's dispatch: rows an expert's buffer
@@ -503,43 +503,65 @@ HELD_ROUND_ROWS = 128
 
 @jax.named_scope("moe_router")
 def route_tokens(cfg, p, xf: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """The sigmoid router over ALL ``cfg.num_experts`` (DeepSeek-V3 /
-    MiMo-V2: a score per expert; the softmax router of the uniform stack
-    is :func:`moe_layer`'s): xf [S, d] → (weights [S, k] float32, expert
-    ids [S, k] int32, best first).
+    """The share's router over ALL ``cfg.num_experts``: xf [S, d] →
+    (weights [S, k] float32, expert ids [S, k] int32, best first), by
+    ``cfg.router_scoring``:
 
-    float32 throughout, as the published gates are (a bf16 logit flips a
-    near-tied selection, and a flipped expert is a different token): the
-    input is upcast, the matmul runs at ``Precision.HIGHEST`` (on a TPU a
-    float32 matmul is otherwise bf16 passes). ``p["router_bias"]``
-    (``router_select_bias``, ``noaux_tc``) is added for the SELECTION
-    only: the weights are the unbiased scores. ``router_groups`` > 1
-    (``n_group`` / ``topk_group``): the picks in equal groups, a group
-    scored by the sum of its two highest, the picks outside the
-    ``router_groups_kept`` best groups set to 0.0 before the top-k (as
-    HF's ``deepseek_v3`` gate masks them). ``norm_topk_prob``: the kept
-    weights renormalised to sum to 1; ``routed_scale`` multiplies them."""
-    if cfg.router_scoring != "sigmoid":
+    - ``"sigmoid"`` (DeepSeek-V3 / MiMo-V2: a score per expert).
+      ``p["router_bias"]`` (``router_select_bias``, ``noaux_tc``) is added
+      for the SELECTION only: the weights are the unbiased scores.
+      ``router_groups`` > 1 (``n_group`` / ``topk_group``): the picks in
+      equal groups, a group scored by the sum of its two highest, the picks
+      outside the ``router_groups_kept`` best groups set to 0.0 before the
+      top-k (as HF's ``deepseek_v3`` gate masks them). ``norm_topk_prob``:
+      the kept weights renormalised to sum to 1.
+    - ``"softmax"``: the ``k`` largest LOGITS are kept; with
+      ``norm_topk_prob`` the weights are the softmax over THOSE ``k``
+      logits alone (Granite's gate; Mixtral's softmax over all,
+      renormalised over the kept, is the same numbers), without it the
+      kept entries of the softmax over all experts. No bias, no groups.
+
+    ``routed_scale`` multiplies the weights. float32 throughout, as the
+    published gates are (a bf16 logit flips a near-tied selection, and a
+    flipped expert is a different token): the input is upcast, the matmul
+    runs at ``Precision.HIGHEST`` (on a TPU a float32 matmul is otherwise
+    bf16 passes). (The uniform stack's capacity router is
+    :func:`moe_layer`'s own; it has no share.)"""
+    if cfg.router_scoring not in ("sigmoid", "softmax"):
         raise NotImplementedError(
-            f"route_tokens is the sigmoid router; router_scoring="
-            f"{cfg.router_scoring!r} goes through moe_layer")
-    scores = jax.nn.sigmoid(jnp.einsum(
+            f"route_tokens builds router_scoring 'sigmoid' and 'softmax'; "
+            f"got {cfg.router_scoring!r}")
+    logits = jnp.einsum(
         "sd,de->se", xf.astype(jnp.float32), p["router"].astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
-    pick = scores
-    if "router_bias" in p:
-        pick = scores + p["router_bias"].astype(jnp.float32)
-    if cfg.router_groups > 1:
-        grouped = pick.reshape(pick.shape[0], cfg.router_groups, -1)
-        _, kept = lax.top_k(lax.top_k(grouped, 2)[0].sum(-1),
-                            cfg.router_groups_kept)            # [S, kept]
-        in_kept = jax.nn.one_hot(kept, cfg.router_groups,
-                                 dtype=jnp.bool_).any(axis=1)  # [S, groups]
-        pick = jnp.where(in_kept[..., None], grouped, 0.0).reshape(pick.shape)
-    _, topi = lax.top_k(pick, cfg.num_experts_per_tok)
-    topw = jnp.take_along_axis(scores, topi, axis=-1)
-    if cfg.norm_topk_prob:
-        topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
+        precision=lax.Precision.HIGHEST)
+    if cfg.router_scoring == "softmax":
+        if "router_bias" in p or cfg.router_groups > 1:
+            raise NotImplementedError(
+                "a softmax router takes no selection bias and no groups")
+        kept, topi = lax.top_k(logits, cfg.num_experts_per_tok)
+        if cfg.norm_topk_prob:
+            topw = jax.nn.softmax(kept, axis=-1)
+        else:
+            topw = jnp.exp(kept - jax.nn.logsumexp(logits, axis=-1,
+                                                   keepdims=True))
+    else:
+        scores = jax.nn.sigmoid(logits)
+        pick = scores
+        if "router_bias" in p:
+            pick = scores + p["router_bias"].astype(jnp.float32)
+        if cfg.router_groups > 1:
+            grouped = pick.reshape(pick.shape[0], cfg.router_groups, -1)
+            _, kept = lax.top_k(lax.top_k(grouped, 2)[0].sum(-1),
+                                cfg.router_groups_kept)        # [S, kept]
+            in_kept = jax.nn.one_hot(
+                kept, cfg.router_groups,
+                dtype=jnp.bool_).any(axis=1)                   # [S, groups]
+            pick = jnp.where(in_kept[..., None], grouped,
+                             0.0).reshape(pick.shape)
+        _, topi = lax.top_k(pick, cfg.num_experts_per_tok)
+        topw = jnp.take_along_axis(scores, topi, axis=-1)
+        if cfg.norm_topk_prob:
+            topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
     if cfg.routed_scale != 1.0:
         topw = topw * cfg.routed_scale
     return topw, topi.astype(jnp.int32)

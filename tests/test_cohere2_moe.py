@@ -126,7 +126,7 @@ def test_reader_builds_the_cut_file_and_its_share():
 
 
 @pytest.mark.parametrize("key,value", [
-    ("use_qk_norm", True), ("attention_bias", True), ("logit_scale", 0.25),
+    ("use_qk_norm", True), ("attention_bias", True),
     ("first_k_dense_replace", 1), ("use_parallel_block", False),
     ("position_embedding_type", "rope_neox"),
     ("shared_expert_combination_strategy", "sum"),
@@ -141,6 +141,21 @@ def test_reader_refuses_by_name_what_is_not_built(key, value):
             "expert_share": "expert_share.router_experts"}.get(key, key)
     with pytest.raises(ValueError, match="cohere2_moe.*" + name):
         config_from_hf(small(**{key: value}))
+
+
+def test_logit_scale_is_read_as_the_heads_divisor():
+    """``logit_scale`` multiplies the logits: the head divides by its
+    inverse (``logits_scaling``, the factor Granite's reader sets too); the
+    published 1 adds no operation."""
+    assert config_from_hf(small()).logits_scaling == 1.0
+    cfg = config_from_hf(small(logit_scale=0.25))
+    assert cfg.logits_scaling == 4.0
+    params = tf.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 3, cfg.hidden_size))
+    base = tf.lm_logits(dataclasses.replace(cfg, logits_scaling=1.0),
+                        params, x)
+    np.testing.assert_allclose(tf.lm_logits(cfg, params, x), base * 0.25,
+                               rtol=1e-6)
 
 
 def test_stack_refuses_what_it_does_not_build(tiny):

@@ -323,11 +323,13 @@ def test_prefill_then_decode_is_the_reference(prompt_len, tiny):
 def test_bf16_serving_keeps_a_float32_state(tiny):
     _, cfg, params, tokens, want = tiny
     eng = engine(cfg, params, dtype="bfloat16")
-    assert eng.arena["ssm"].dtype == jnp.float32 and \
-        eng.arena["conv"].dtype == jnp.bfloat16
-    # a slot a sequence and state-space layer, and each region's trash
-    assert eng.arena["ssm"].shape == (3 * 9, 8, 8, 16) and \
-        eng.arena["conv"].shape == (3 * 9, 3 * 128)
+    # a pool a state-space layer: a slot a sequence, and the trash
+    for i in range(3):
+        assert eng.arena[f"ssm{i}"].dtype == jnp.float32 and \
+            eng.arena[f"conv{i}"].dtype == jnp.bfloat16
+        assert eng.arena[f"ssm{i}"].shape == (9, 8, 8, 16) and \
+            eng.arena[f"conv{i}"].shape == (9, 3 * 128)
+    assert "ssm3" not in eng.arena and "ssm" not in eng.arena
     got = _walk(eng, tokens[:140], 130)
     assert np.abs(got - want[129:140]).max() < BF16_TOL
 
@@ -385,7 +387,7 @@ def test_a_reused_slot_starts_from_zero(tiny):
         _walk(eng, np.random.default_rng(4).integers(0, VOCAB, 150), 140)
         slot = eng.state.seqs[0].slot
         eng.flush(0)
-        stale = np.asarray(eng.arena["ssm"])[slot]
+        stale = np.asarray(eng.arena["ssm0"])[slot]
         assert np.abs(stale).max() > 1e-3       # the pool is NOT cleaned
         got = _walk(eng, tokens[:40], 33, uid=1)
     assert eng.state.seqs[1].slot == slot

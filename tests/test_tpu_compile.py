@@ -764,34 +764,26 @@ _HYBRID_STEPS = {
 }
 
 
-@pytest.mark.parametrize("kind", list(_HYBRID_STEPS))
-def test_hybrid_step_updates_its_state_pools_in_place(
-        kind, one_chip, no_persistent_cache, monkeypatch):
-    """The 64-row decode, split and fresh programs of Nemotron 3 Nano's
-    stack at the published widths, cut to ``ME*M`` (two state-space layers,
-    the held experts, an attention layer), over the cell's arena and a
-    state pool of 2 x 65 slots of 2 MiB: NO copy of a state pool or of a KV
-    pool anywhere in the module — the split program carries the pools
-    through one-trip LOOPS where a conditional copied them twice
-    (``engine_v2._at_capacity``) —, every one of the six ``ssm_*`` scopes
-    on some instruction, the paged kernel under ``attn_history`` in the
-    split program, and temporaries under the measured ones."""
+def _hybrid_step(one_chip, monkeypatch, config, cut, step, mb):
+    """A 64-row step program of a recurrent stack compiled for the chip:
+    the configuration ``config`` with ``cut`` laid over its keys, over the
+    cell's KV arena, a page table ``mb`` pages wide (the cell's
+    ``max_seq_len``) and its state pools (a pool a state-space layer) →
+    (the model, the abstract arena, the compiled program, its text). NO
+    copy of a state pool or of a KV pool anywhere in the module."""
     import json
     import os
     from benchmark.lib import model as model_lib
     from deepspeed_tpu.inference import engine_v2
     from deepspeed_tpu.ops import paged_attention as pa
     from deepspeed_tpu.ops import ssm
-    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     conf = json.load(open(os.path.join(
         os.path.dirname(model_lib.__file__), "..", "configs",
-        "nemotron3-nano-l26-e16-serve.json")))
-    model = model_lib.build_model({**conf, "num_hidden_layers": 4,
-                                   "hybrid_override_pattern": "ME*M"})
-    assert model.layer_kinds == (3, -1, 0, 3) and model.recurrent
-    cb, fresh, capacities, most = _HYBRID_STEPS[kind]
-    nb, mb = 64, 32
+        config + ".json")))
+    model = model_lib.build_model({**conf, **cut})
+    cb, fresh, capacities = step[:3]
+    nb = 64
 
     def serve_step(params, arena, tokens, counts, starts, pt, slots):
         logits, arena = engine_v2.ragged_forward(
@@ -811,9 +803,6 @@ def test_hybrid_step_updates_its_state_pools_in_place(
     arena = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         jax.eval_shape(make_arena))
-    assert arena["ssm"].shape == (130, 64, 64, 128) and \
-        arena["ssm"].dtype == jnp.float32 and \
-        arena["conv"].shape == (130, 3 * 6144)
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
@@ -827,6 +816,30 @@ def test_hybrid_step_updates_its_state_pools_in_place(
         copies = [line.strip()[:160] for line in text.splitlines()
                   if re.search(rf" = {re.escape(shape)}\S* copy\(", line)]
         assert not copies, copies
+    return model, arena, compiled, text
+
+
+@pytest.mark.parametrize("kind", list(_HYBRID_STEPS))
+def test_hybrid_step_updates_its_state_pools_in_place(
+        kind, one_chip, no_persistent_cache, monkeypatch):
+    """The 64-row decode, split and fresh programs of Nemotron 3 Nano's
+    stack at the published widths, cut to ``ME*M`` (two state-space layers,
+    the held experts, an attention layer), over the cell's arena and two
+    state pools of 65 slots of 2 MiB: NO copy of a state pool or of a KV
+    pool anywhere in the module — the split program carries the pools
+    through one-trip LOOPS where a conditional copied them twice
+    (``engine_v2._at_capacity``) —, every one of the six ``ssm_*`` scopes
+    on some instruction, the paged kernel under ``attn_history`` in the
+    split program, and temporaries under the measured ones."""
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    model, arena, compiled, text = _hybrid_step(
+        one_chip, monkeypatch, "nemotron3-nano-l26-e16-serve",
+        {"num_hidden_layers": 4, "hybrid_override_pattern": "ME*M"},
+        _HYBRID_STEPS[kind], 32)
+    assert model.layer_kinds == (3, -1, 0, 3) and model.recurrent
+    assert arena["ssm1"].shape == (65, 64, 64, 128) and \
+        arena["ssm1"].dtype == jnp.float32 and \
+        arena["conv1"].shape == (65, 3 * 6144) and "ssm2" not in arena
     table = scope_table_from_hlo(text)
     scopes = {e["scope"] for e in table.values()}
     assert {"ssm_in", "ssm_conv", "ssm_scan", "ssm_state", "ssm_norm",
@@ -837,4 +850,53 @@ def test_hybrid_step_updates_its_state_pools_in_place(
     # (the split program's two instances are loop bodies, not branches)
     assert not _branches(text) or kind != "split", _branches(text)
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < most, temp
+    assert temp < _HYBRID_STEPS[kind][3], temp
+
+
+# -- the two-part hybrid stack (benchmark/configs/granite-4.0-h-small-l10-e36-
+# serve): every layer a mixer AND the held experts
+
+#: step -> (chunk, ``fresh_prefill``, capacities, most temporaries at the
+#: three layers ``mamba attention mamba``: measured 0.29, 1.49 and 1.36 GB)
+_GRANITE_STEPS = {
+    "decode": (1, False, (), 0.4e9),
+    "split": (128, "split", (1024, 2048), 1.8e9),
+    "fresh": (128, "fresh", (2048,), 1.7e9),
+}
+
+
+@pytest.mark.parametrize("kind", list(_GRANITE_STEPS))
+def test_two_part_hybrid_step_compiles_for_v5e(
+        kind, one_chip, no_persistent_cache, monkeypatch):
+    """The 64-row decode, split and fresh programs of Granite 4.0-H
+    Small's stack at the published widths, cut to ``mamba attention mamba``
+    (36 of 72 experts held in every layer), over the cell's arena and two
+    state pools of 65 slots of 4 MiB: NO copy of a state pool or of a KV
+    pool anywhere in the module, the six ``ssm_*`` scopes, the experts' four
+    and the attention layer's on some instruction of one program, the two
+    0.22 scalings and the head's 1/16 under their parts' scopes (nothing
+    heavy unscoped), and temporaries under the measured ones. (At the
+    cell's ten layers the three compile to 1.77 / 1.64 / 0.19 GB of
+    temporaries beside 12.27 GB of arguments: PERF.md §6, PR 45.)"""
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    model, arena, compiled, text = _hybrid_step(
+        one_chip, monkeypatch, "granite-4.0-h-small-l10-e36-serve",
+        {"num_hidden_layers": 3,
+         "layer_types": ["mamba", "attention", "mamba"]},
+        _GRANITE_STEPS[kind], 8)
+    assert model.layer_kinds == (3, 0, 3) and \
+        model.layer_sparse == (1, 1, 1) and model.experts_held == (0, 36)
+    assert arena["ssm1"].shape == (65, 128, 64, 128) and \
+        arena["ssm1"].dtype == jnp.float32 and \
+        arena["conv1"].shape == (65, 3 * 8448) and "ssm2" not in arena
+    table = scope_table_from_hlo(text)
+    scopes = {e["scope"] for e in table.values()}
+    assert {"ssm_in", "ssm_conv", "ssm_scan", "ssm_state", "ssm_norm",
+            "ssm_out", "moe", "moe_router", "moe_experts", "moe_shared",
+            "attn_qkv", "attn_out", "kv_write", "embed",
+            "lm_head"} <= scopes, scopes
+    heavy = [m.group(1) for m in _HEAVY.finditer(text)]
+    named = [n for n in heavy if table[n]["scope"] is not None]
+    assert len(named) >= 0.95 * len(heavy), sorted(set(heavy) - set(named))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < _GRANITE_STEPS[kind][3], temp

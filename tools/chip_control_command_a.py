@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""A lower-precision control of the Command A+ cell THROUGH THE HARNESS:
+"""A lower-precision control of a typed stack's serving cell (written for
+the Command A+ cell; ``--workload`` names the cell) THROUGH THE HARNESS:
 ``benchmark/run.py`` on the cell as it is, but the engine serves with every
 weight matrix rounded to float8 (e4m3) in place after construction
 (``--control weights``, the default) or with K and V rounded on their way
@@ -65,7 +66,7 @@ def init(self, model, config, params=None, rng=None):
     n = 0
     for group in groups:
         for key in list(group):
-            if group[key].ndim >= 2:
+            if getattr(group[key], "ndim", 0) >= 2:
                 group[key] = float8(group[key])
                 n += 1
     jax.block_until_ready(p)
@@ -74,7 +75,20 @@ def init(self, model, config, params=None, rng=None):
 
 Eng.__init__ = init
 
-ref = importlib.import_module("benchmark.reference.cohere2_moe_decoder")
+def _reference_of(rest):
+    """The reference module of the cell named after ``--workload`` (the
+    tool was written for Command A+'s cell and serves any typed stack's
+    whose reference has ``hidden_and_margins``)."""
+    import json
+    from benchmark.lib import model as model_lib
+    cell = rest[rest.index("--workload") + 1]
+    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+        cells = {w["name"]: w for w in json.load(fh)["workloads"]}
+    return model_lib.load_reference(
+        model_lib.load_config(cells[cell]["config"]))
+
+
+ref = _reference_of(REST)
 _gaps = ref.argmax_gaps
 _walk = ref.hidden_and_margins
 seen = []
@@ -125,8 +139,10 @@ def argmax_gaps(widths, params, prompts, outs, device):
                     "weights, made again"})
     flat = _gaps(widths, tiny.params, prompts, outs, device)
     bench_run.emit({"phase": "control", "margin": ref.UNDECIDED_LOGIT_MARGIN,
+                    # (another reference's head is its own: no table)
                     "by_margin": by_margin(widths, tiny.params, prompts,
-                                           outs, device)})
+                                           outs, device)
+                    if hasattr(ref, "_head") else None})
     return flat
 
 
