@@ -206,10 +206,11 @@ class _TokenLayout:
 
 def _instances(capacities, n: int, c: int) -> Tuple[Tuple[int, int], ...]:
     """``(capacity, chunk_rows)`` of each instance of a packed step's layer
-    loop (:func:`_at_capacity`): the top capacity keeps every row a chunk
-    row (``n``), a smaller one gives the chunk's width to as many rows as
-    its slots hold whole chunks (8 of the 64 x 128 program's rows at 1,024
-    slots) and reads the others as rows of one query."""
+    loop (:func:`_at_capacity`), one a rung of ``capacities``: the top
+    capacity keeps every row a chunk row (``n``), a smaller one gives the
+    chunk's width to as many rows as its slots hold whole chunks (of the 64
+    x 128 program's rows, 4 at 512 slots and 8 at 1,024) and reads the
+    others as rows of one query."""
     return tuple((cap, n if cap == capacities[-1] else max(1, cap // c))
                  for cap in capacities)
 
@@ -243,11 +244,22 @@ def _at_capacity(instances, counts: jax.Array, run, *carried):
     if not carried:
         return lax.switch(index,
                           [partial(run, *inst) for inst in instances])
-    shapes = jax.eval_shape(partial(run, *instances[0]), *carried)
+    # the loops' first carry needs the outputs' shapes: the first instance
+    # is traced ONCE, to a jaxpr that gives them and that its own loop then
+    # replays (an ``eval_shape`` beside the loops was one trace more of
+    # every layer, a second of warm set-up at 26 unrolled layers)
+    first, shapes = jax.make_jaxpr(partial(run, *instances[0]),
+                                   return_shape=True)(*carried)
+
+    def replay(*carried):
+        return jax.tree.unflatten(
+            jax.tree.structure(shapes), jax.core.eval_jaxpr(
+                first.jaxpr, first.consts, *jax.tree.leaves(carried)))
+
     outs = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes[:-1])
     for k, inst in enumerate(instances):
-        def trip(_, carry, inst=inst):
-            *outs, state = run(*inst, *carry[0])
+        def trip(_, carry, fn=partial(run, *inst) if k else replay):
+            *outs, state = fn(*carry[0])
             return (state,), tuple(outs)
 
         carried, outs = lax.fori_loop(
@@ -267,9 +279,12 @@ def _slot_major(t: jax.Array, slots: int) -> jax.Array:
 
 def _write_back_slots(capacities, row_slots: int) -> Tuple[int, int]:
     """``(block, held)`` of a split step's write-back (:func:`_write_back`):
-    the slots ONE scatter updates — the smallest capacity; the row form's
-    ``row_slots`` where the step does not pack — and the slots a layer's
-    chunk K/V are held in until then: the top capacity in whole blocks."""
+    the slots ONE scatter updates — the smallest capacity, so the lowest
+    rung of a ladder is also the write's grain (512 of the 64 x 128
+    program's ``(512, 1024, 2048)``: a step of 370 tokens writes one
+    block); the row form's ``row_slots`` where the step does not pack —
+    and the slots a layer's chunk K/V are held in until then: the top
+    capacity in whole blocks."""
     if not capacities:
         return row_slots, row_slots
     return capacities[0], -(-capacities[-1] // capacities[0]) * capacities[0]
@@ -280,8 +295,9 @@ def _write_back(counts: jax.Array, starts: jax.Array, c: int, capacities,
     """A split step's chunk K/V of every layer into ``pools``, after the
     layer loop: block after block of :func:`_write_back_slots` slots until
     the batch's tokens are written, so a launch's scatters perform the
-    updates of the capacity the step took (1,024 for most steps of a
-    program that also holds 2,048) and none for slots past it. The pools
+    updates of whole blocks up to the step's tokens (512 or 1,024 for most
+    steps of a program that also holds 2,048) and none for slots past
+    them. The pools
     are the carry of ONE loop whose trip count the token count sets: they
     alias through it, where a second ``lax.switch`` over write-backs of
     each capacity copies a pool in every branch's layer loop
@@ -1797,28 +1813,41 @@ class RaggedInferenceEngineTPU:
     def _token_capacities(self, nb: int, cb: int, fresh) -> Tuple[int, ...]:
         """``ragged_forward``'s ``token_capacities`` for the ``(nb, cb,
         fresh)`` step programs — statics derived from what the engine
-        knows, so the program grid and its keys stay as they are. The
-        scheduler hands a step at most ``max_batch_tokens`` tokens, so
-        where the rows hold more slots than that the token-wise sublayers
-        work on ``max_batch_tokens`` packed slots; else ``()``, the row
-        form. A split program whose rows hold at least FOUR times the
-        budget also holds a second, smaller instance of its layer loop at
-        16 slots a row (1,024 of the 64 x 128 program's 8,192): a step of
-        decode rows and the prompt chunks of a few arrivals is a few
-        hundred tokens — under 1,024 in 98.6% / 99.7% of the split steps
-        of the chat and the reasoning traffic of ``benchmark/`` (PERF.md
-        §6, PR 32) — and takes that one. The second instance is 15–17% of
-        those steps and about 2 s of set-up a program (lowering and
-        loading it), which is why rows at twice the budget, already
-        halved by packing, do without. :meth:`_run` applies the same rule
-        to count the slots."""
+        knows (the program's rows, chunk and kind, ``max_batch_tokens``),
+        so the program grid and its keys stay as they are. The scheduler
+        hands a step at most ``max_batch_tokens`` tokens, so where the
+        rows hold more slots than that the token-wise sublayers work on
+        ``max_batch_tokens`` packed slots; else ``()``, the row form.
+
+        A split program whose rows hold at least FOUR times the budget
+        holds a LADDER of instances of its layer loop under the budget:
+        16 slots a row (PR 32) and, under that, 8 slots a row — ``(512,
+        1024, 2048)`` for the 64 x 128 program's 8,192 row slots, each
+        rung half the next, so that no step runs over more than about
+        twice its tokens until the budget. A step of decode rows and the
+        prompt chunks of a few arrivals is a few hundred tokens: 63 rows
+        and one chunk (at most 191) in the reasoning traffic of
+        ``benchmark/``, about 61 rows and 2-3 chunks (about 370) in the
+        chat traffic (PERF.md §6, PR 46, has the histogram). A rung is
+        made only UNDER the next one, and the rung at 8 a row only where
+        it holds a whole chunk beside one token of every other row (``8
+        nb >= cb + nb - 1``): a smaller one would serve no step that
+        carries a full chunk. An instance costs set-up (its layers are
+        traced and lowered once more: about 1 s a scanned program, 2-4 s
+        an unrolled one, warm; docs/kernels.md), which is why rows at
+        twice the budget, already halved by packing, do without, and why
+        there is no rung at 4 a row yet. :meth:`_run` applies the same
+        rule to count the slots."""
         top = self.config.max_batch_tokens
         if cb == 1 or top >= nb * cb:
             return ()
-        small = 16 * nb
-        if fresh == "split" and small < top and 4 * top <= nb * cb:
-            return (small, top)
-        return (top,)
+        if fresh != "split" or 4 * top > nb * cb:
+            return (top,)
+        ladder = (top,)
+        for slots, least in ((16 * nb, 0), (8 * nb, cb + nb - 1)):
+            if least <= slots < ladder[0]:
+                ladder = (slots,) + ladder
+        return ladder
 
     def _run(self, batch: RaggedBatch, mode=None):
         """One step program over ``batch``: pack and upload, launch, count
@@ -2003,7 +2032,11 @@ class RaggedInferenceEngineTPU:
         bucketed rows x chunk width, or ``attn_row_slots`` — the ``P x
         chunk + rows`` of a split launch that took a GROUPED instance
         (:func:`_instances`), which ``dispatch/split_grouped_steps`` counts
-        (not under ``dispatch/steps.``: those are launches);
+        (not under ``dispatch/steps.``: those are launches), as
+        ``dispatch/split_steps_at.<slots>`` counts every split launch at
+        the ``slots`` it ran over — one counter a rung of the program's
+        ladder (:meth:`_token_capacities`; the row slots where it does not
+        pack), which sum to ``dispatch/steps.split``;
         ``chunk_rows`` = the rows that hold more than one token;
         ``kv_write_slots`` = the updates the
         launch's KV scatter performs a pool and layer: the packed slots it
@@ -2047,14 +2080,17 @@ class RaggedInferenceEngineTPU:
             context_slots = nb * page_width * self.config.block_size * \
                 scan_steps
         self.last_program = program
-        for name, by in (("host_calls", 1), ("tokens", tokens),
-                         ("token_slots", slots),
-                         ("kv_write_slots", kv_write_slots),
-                         ("context_tokens", context_tokens),
-                         ("context_slots", context_slots),
-                         ("chunk_rows", chunk_rows),
-                         ("attn_row_slots", row_slots),
-                         (f"steps.{program}", 1)):
+        counted = [("host_calls", 1), ("tokens", tokens),
+                   ("token_slots", slots),
+                   ("kv_write_slots", kv_write_slots),
+                   ("context_tokens", context_tokens),
+                   ("context_slots", context_slots),
+                   ("chunk_rows", chunk_rows),
+                   ("attn_row_slots", row_slots),
+                   (f"steps.{program}", 1)]
+        if program == "split":
+            counted.append((f"split_steps_at.{slots}", 1))
+        for name, by in counted:
             registry.counter("dispatch/" + name).inc(by)
         work = {"program": program, "rows": rows, "rows_bucket": nb,
                 "chunk": chunk, "tokens": tokens, "slots": slots,

@@ -638,7 +638,9 @@ def _packed_stack(stack):
     (a full and a window kind with its sink, K heads of 192 and V of 128,
     a dense layer, then a top-8-of-16 router with 4 experts held) or
     GigaChat3.1's latent stack at its rehearsal widths (one pool of one
-    row a token; the engine makes its arena)."""
+    row a token; the engine makes its arena), or Nemotron 3 Nano's hybrid
+    stack at its rehearsal widths (``ME*M``: state pools of 8 sequence
+    slots beside the attention layer's pages; its steps take ``slots=``)."""
     from deepspeed_tpu.models.transformer import init_params
     if stack == "uniform":
         cfg = llama3_config("tiny")
@@ -658,6 +660,20 @@ def _packed_stack(stack):
             model_lib.build_model(conf, rehearse=True), init_std=0.1)
         assert cfg.typed and cfg.latent and set(cfg.layer_kinds) == {2}
         return cfg, init_params(cfg, jax.random.PRNGKey(3)), None
+    if stack == "recurrent":
+        from deepspeed_tpu.ops import ssm
+        conf = json.load(open(os.path.join(
+            configs, "nemotron3-nano-l26-e16-serve.json")))
+        cfg = dataclasses.replace(
+            model_lib.build_model(conf, rehearse=True), init_std=0.1)
+        assert cfg.recurrent and cfg.layer_kinds == (3, -1, 0, 3)
+
+        def make_arena(nb, bs):
+            arena = pa.init_arena_typed(
+                cfg.layer_kinds, {0: cfg.kv_heads}, nb, bs, cfg.head_dim,
+                cfg.v_dim, jnp.float32)
+            return dict(arena, **ssm.init_state_pools(cfg, 8, jnp.float32))
+        return cfg, init_params(cfg, jax.random.PRNGKey(3)), make_arena
     conf = json.load(open(os.path.join(
         configs, "mimo-v2.5-l7-e16-serve.json")))
     # a window of 24: the histories below pass it, the chunks straddle it

@@ -685,6 +685,9 @@ PARENT = {
                      # every launch but the first: made before the one
                      # ahead of it was collected (ISSUE 44)
                      "launches_ahead": 7,
+                     # (PR 46: a split launch by the slots it ran over —
+                     # here the row form's 2 x 8)
+                     "split_steps_at.16": 2,
                      "steps.decode": 5, "steps.fresh": 1, "steps.split": 2,
                      "token_slots": 56, "tokens": 33}},
     "megastep": {
@@ -697,7 +700,8 @@ PARENT = {
                      "context_slots": 1152, "context_tokens": 192,
                      "host_calls": 5, "kv_write_slots": 57,
                      "megastep_launches": 1, "megastep_tokens": 7,
-                     "scan_steps": 4, "steps.decode": 1, "steps.fresh": 1,
+                     "scan_steps": 4, "split_steps_at.16": 2,
+                     "steps.decode": 1, "steps.fresh": 1,
                      "steps.megastep": 1, "steps.split": 2,
                      "token_slots": 57, "tokens": 33}},
 }

@@ -58,6 +58,29 @@ def _pages(counts, starts, rng, nb=40):
     return pt, nb
 
 
+#: the 8 x 16 program's LADDER (PR 46): three instances, 16 slots with ONE
+#: row at the chunk's width, 32 with two, 64 with all eight
+LADDER = (16, 32, 64)
+#: mix -> (tokens a row feeds, tokens it has cached, the rung it takes):
+#: each rung, and each side of each boundary of tokens and of chunk rows
+LADDER_MIXES = {
+    "low_rung": ([1, 1, 1, 0, 1, 1, 9, 1],
+                 [30, 7, 41, 0, 9, 16, 3, 25], 0),
+    "tokens_fill_the_low_rung": ([1, 1, 1, 1, 1, 1, 9, 1],
+                                 [30, 7, 41, 12, 9, 16, 0, 25], 0),
+    "one_token_over_the_low_rung": ([1, 1, 1, 1, 1, 1, 10, 1],
+                                    [30, 7, 41, 12, 9, 16, 3, 25], 1),
+    "a_chunk_row_over_the_low_group": ([2, 1, 1, 0, 1, 1, 5, 1],
+                                       [30, 7, 41, 0, 9, 16, 3, 25], 1),
+    "tokens_fill_the_middle_rung": ([16, 1, 1, 1, 1, 1, 10, 1],
+                                    [16, 7, 41, 12, 9, 16, 3, 25], 1),
+    "one_token_over_the_middle_rung": ([16, 1, 1, 1, 1, 1, 11, 1],
+                                       [16, 7, 41, 12, 9, 16, 0, 25], 2),
+    "a_chunk_row_over_the_middle_group": ([5, 1, 9, 1, 0, 3, 1, 1],
+                                          [16, 7, 41, 12, 0, 16, 3, 25], 2),
+}
+
+
 def test_instance_rule_is_one_for_the_program_and_the_host():
     """``_at_capacity`` inside a program picks the instance
     ``_instance_index`` names on the host, for every mix: the small one iff
@@ -78,6 +101,66 @@ def test_instance_rule_is_one_for_the_program_and_the_host():
         assert int(ran(jnp.asarray(counts))) == instances[index][0], name
 
 
+def test_instance_index_is_the_same_traced_and_on_the_host():
+    """``_instance_index`` over the WHOLE (tokens, chunk rows) grid of the 8
+    x 16 program's three-rung ladder: traced scalars inside a program give
+    the index that ints give on the host, and it is the first rung whose
+    slots hold the tokens and whose chunk group holds the chunk rows."""
+    instances = engine_v2._instances(LADDER, N, C)
+    assert instances == ((16, 1), (32, 2), (64, N))
+    assert engine_v2._instances((512, 1024, 2048), 64, 128) == \
+        ((512, 4), (1024, 8), (2048, 64))
+    traced = jax.jit(lambda t, r: engine_v2._instance_index(instances, t, r))
+    seen = set()
+    for tokens in range(LADDER[-1] + 1):
+        for rows in range(N + 1):
+            index = engine_v2._instance_index(instances, tokens, rows)
+            assert index == next(
+                i for i, (cap, group) in enumerate(instances)
+                if tokens <= cap and rows <= group), (tokens, rows)
+            assert int(traced(jnp.int32(tokens), jnp.int32(rows))) == index
+            seen.add(index)
+    assert seen == {0, 1, 2}
+    for name, (counts, _starts, rung) in LADDER_MIXES.items():
+        counts = np.asarray(counts)
+        assert engine_v2._instance_index(
+            instances, int(counts.sum()), int((counts > 1).sum())) == rung, \
+            name
+
+
+#: (rows, chunk, kind, ``max_batch_tokens``) -> ``_token_capacities``
+_LADDERS = {
+    "the_cells_64_rows": ((64, 128, "split", 2048), (512, 1024, 2048)),
+    "a_budget_of_16_a_row": ((64, 128, "split", 1024), (512, 1024)),
+    "no_room_for_a_chunk_at_8_a_row": ((4, 96, "split", 80), (64, 80)),
+    "a_chunk_fits_8_a_row": ((16, 96, "split", 320), (128, 256, 320)),
+    "a_chunk_just_fits": ((16, 113, "split", 400), (128, 256, 400)),
+    "a_chunk_just_does_not": ((16, 114, "split", 400), (256, 400)),
+    "rows_at_twice_the_budget": ((32, 128, "split", 2048), (2048,)),
+    "fresh_takes_one": ((64, 128, "fresh", 2048), (2048,)),
+    "rows_hold_the_budget": ((16, 128, "split", 2048), ()),
+    "decode": ((64, 1, False, 2048), ()),
+}
+
+
+@pytest.mark.parametrize("case", list(_LADDERS))
+def test_token_capacities_make_a_rung_only_where_it_serves(case):
+    """``_token_capacities`` from the program's rows, chunk and kind and
+    the budget ALONE: the ladder of the benchmark's 64 x 128 split program;
+    a rung only under the next one; the rung at 8 slots a row only where
+    it holds a whole chunk beside one token of every other row."""
+    import types
+    (nb, cb, fresh, top), want = _LADDERS[case]
+    eng = types.SimpleNamespace(
+        config=types.SimpleNamespace(max_batch_tokens=top))
+    got = RaggedInferenceEngineTPU._token_capacities(eng, nb, cb, fresh)
+    assert got == want
+    assert all(2 * a <= b or b == top for a, b in zip(got, got[1:]))
+    if len(got) == 3:
+        assert got[0] >= cb + nb - 1
+        assert engine_v2._write_back_slots(got, nb * cb)[1] % got[0] == 0
+
+
 @functools.lru_cache(maxsize=None)
 def _stack_steps(stack, dtype):
     """(cfg, params, arena maker, history writer, step(capacities)) of a
@@ -93,14 +176,17 @@ def _stack_steps(stack, dtype):
         params = jax.tree_util.tree_map(
             lambda a: a.astype(jnp.bfloat16)
             if a.dtype == jnp.float32 else a, params)
+    # (a recurrent stack: row i's state in slot i of the pools)
+    kw = {"slots": jnp.arange(N, dtype=jnp.int32)} if cfg.recurrent else {}
     history = jax.jit(lambda arena, toks, counts, pt: ragged_forward(
-        cfg, params, arena, toks, counts, jnp.zeros_like(counts), pt)[1])
+        cfg, params, arena, toks, counts, jnp.zeros_like(counts), pt,
+        **kw)[1])
 
     @functools.lru_cache(maxsize=None)
     def step(capacities):
         return jax.jit(lambda arena, *a: ragged_forward(
             cfg, params, arena, *a, fresh_prefill="split",
-            token_capacities=capacities))
+            token_capacities=capacities, **kw))
     return cfg, make_arena, history, step
 
 
@@ -141,6 +227,46 @@ def test_grouped_split_step_matches_the_all_rows_instance(devices, stack,
         a, b, before = (np.asarray(x[name], np.float32)[kept]
                         for x in (got, want, arena))
         np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
+        assert not np.array_equal(a, before), name     # the step wrote
+
+
+@pytest.mark.parametrize("mix", list(LADDER_MIXES))
+@pytest.mark.parametrize("stack", ["uniform", "typed", "latent", "recurrent"])
+def test_three_rung_program_matches_the_row_form(devices, stack, mix):
+    """A split step through a program that holds THREE instances of its
+    layer loop (16, 32 and 64 slots: one, two and all eight rows at the
+    chunk's width) against the same step in the row form, at a batch for
+    each rung and on each side of each boundary (tokens = capacity,
+    capacity + 1, chunk rows = group + 1): the logits of every row that fed
+    a token and every pool outside the trash pages — a recurrent stack's
+    state pools too, carried through the one-trip loops."""
+    cfg, make_arena, history, step = _stack_steps(stack, "float32")
+    counts, starts, rung = LADDER_MIXES[mix]
+    counts, starts = (np.asarray(a, np.int32) for a in (counts, starts))
+    assert engine_v2._instance_index(
+        engine_v2._instances(LADDER, N, C), int(counts.sum()),
+        int((counts > 1).sum())) == rung
+    rng = np.random.default_rng(len(mix))
+    pt, nb = _pages(counts, starts, rng)
+    toks = lambda *shape: jnp.asarray(
+        rng.integers(0, cfg.vocab_size, shape), jnp.int32)
+    arena = history(make_arena(nb, BS), toks(N, 48), jnp.asarray(starts),
+                    jnp.asarray(pt))
+    args = (toks(N, C), jnp.asarray(counts), jnp.asarray(starts),
+            jnp.asarray(pt))
+    want_logits, want = step(())(arena, *args)
+    got_logits, got = step(LADDER)(arena, *args)
+    live = counts > 0
+    assert np.abs(np.asarray(want_logits)[live]).max() > 0.1
+    np.testing.assert_allclose(np.asarray(got_logits)[live],
+                               np.asarray(want_logits)[live],
+                               rtol=2e-4, atol=2e-4)
+    assert set(got) == set(want)
+    for name in want:
+        kept = np.arange(want[name].shape[0]) % (nb + 1) != nb
+        a, b, before = (np.asarray(x[name], np.float32)[kept]
+                        for x in (got, want, arena))
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
         assert not np.array_equal(a, before), name     # the step wrote
 
 
@@ -253,30 +379,39 @@ def test_history_kernel_at_one_query_a_row_matches_the_xla_reader(window,
     np.testing.assert_allclose(out[seen], out_x[seen], rtol=2e-5, atol=2e-5)
 
 
-#: launch -> (prompts that arrive beside 12 decode rows, is the launch
-#: grouped). 16 rows x chunk 96 over a budget of 320: instances of 256
-#: slots with 2 chunk rows and 320 with 16
-_ENGINE_LAUNCHES = {"one_chunk_row": ((20,), True),
-                    "exactly_p": ((20, 40), True),
-                    "p_plus_1": ((20, 40, 7), False)}
+#: launch -> (prompts that arrive beside 12 decode rows, the rung the launch
+#: takes). 16 rows x chunk 96 over a budget of 320: instances of 128 slots
+#: with 1 chunk row, 256 with 2 and 320 with 16
+_ENGINE_LAUNCHES = {"one_chunk_row": ((20,), 0),
+                    "a_whole_chunk": ((96,), 0),
+                    "exactly_p": ((20, 40), 1),
+                    "p_plus_1": ((20, 40, 7), 2)}
+_ENGINE_INSTANCES = ((128, 1), (256, 2), (320, 16))
+_ENGINE = {"dtype": "float32", "max_sequences": 16, "num_blocks": 64,
+           "block_size": 8, "max_seq_len": 128, "prefill_chunk": 96}
 
 
 @pytest.mark.parametrize("launch", list(_ENGINE_LAUNCHES))
 def test_engine_counts_the_instance_its_split_launch_took(devices, launch):
     """``dispatch/split_grouped_steps``, ``dispatch/chunk_rows``,
-    ``dispatch/attn_row_slots`` (and ``token_slots``, ``context_slots``'
-    own keys) of a split launch by the program's own rule, and the tokens
-    it samples: those of an engine whose programs keep the row form."""
+    ``dispatch/attn_row_slots``, ``dispatch/split_steps_at.<slots>`` (and
+    ``token_slots``, ``context_slots``' own keys) of a split launch by the
+    program's own rule, and the tokens it samples: those of an engine whose
+    programs keep the row form."""
     from deepspeed_tpu import telemetry
     build_mesh(data=1, devices=jax.devices()[:1])
     cfg, params, _ = _packed_stack("uniform")
-    arrivals, grouped = _ENGINE_LAUNCHES[launch]
+    arrivals, rung = _ENGINE_LAUNCHES[launch]
+    slots, group = _ENGINE_INSTANCES[rung]
+    grouped = group < 16
     rng = np.random.default_rng(7)
     decoding = [rng.integers(0, cfg.vocab_size, 3).tolist()
                 for _ in range(12)]
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in arrivals]
     names = ("split_grouped_steps", "chunk_rows", "attn_row_slots",
-             "token_slots", "steps.split")
+             "token_slots", "steps.split", "split_steps_at.128",
+             "split_steps_at.256", "split_steps_at.320",
+             f"split_steps_at.{16 * 96}")
 
     def counters():
         return {n: telemetry.registry.counter("dispatch/" + n).value
@@ -284,9 +419,8 @@ def test_engine_counts_the_instance_its_split_launch_took(devices, launch):
 
     def serve(max_batch_tokens):
         eng = RaggedInferenceEngineTPU(
-            cfg, {"dtype": "float32", "max_sequences": 16, "num_blocks": 64,
-                  "block_size": 8, "max_seq_len": 128, "prefill_chunk": 96,
-                  "max_batch_tokens": max_batch_tokens}, params=params)
+            cfg, dict(_ENGINE, max_batch_tokens=max_batch_tokens),
+            params=params)
         uids = list(range(12))
         eng.scheduler.put(uids, decoding)
         out = eng.step_with_budget(budget=320)
@@ -297,19 +431,66 @@ def test_engine_counts_the_instance_its_split_launch_took(devices, launch):
         out = eng.step_with_budget(budget=320)
         assert eng.last_program == "split"
         return eng, {u: int(t) for u, t in out.items()}, \
-            {n: v - before[n] for n, v in counters().items()}
+            {n: v - before[n] for n, v in counters().items() if
+             v - before[n]}
 
     eng, got, grew = serve(320)
-    assert eng._token_capacities(16, 96, "split") == (256, 320)
-    assert engine_v2._instances((256, 320), 16, 96) == ((256, 2), (320, 16))
+    assert eng._token_capacities(16, 96, "split") == (128, 256, 320)
+    assert engine_v2._instances((128, 256, 320), 16, 96) == \
+        _ENGINE_INSTANCES
     _rows, want, row_form = serve(16 * 96)
     assert got == want and len(got) == 12 + len(arrivals)
-    assert grew == {"split_grouped_steps": int(grouped),
+    assert grew == {**({"split_grouped_steps": 1} if grouped else {}),
                     "chunk_rows": len(arrivals),
-                    "attn_row_slots": 2 * 96 + 16 if grouped else 16 * 96,
-                    "token_slots": 256 if grouped else 320,
+                    "attn_row_slots": group * 96 + 16 if grouped
+                    else 16 * 96,
+                    "token_slots": slots, f"split_steps_at.{slots}": 1,
                     "steps.split": 1}
-    assert row_form == {"split_grouped_steps": 0,
-                        "chunk_rows": len(arrivals),
+    assert row_form == {"chunk_rows": len(arrivals),
                         "attn_row_slots": 16 * 96, "token_slots": 16 * 96,
-                        "steps.split": 1}
+                        f"split_steps_at.{16 * 96}": 1, "steps.split": 1}
+
+
+def test_rung_counters_sum_to_the_split_launches(devices):
+    """Over a run of mixed batches — decode rows beside one, two, three
+    arrivals or none, under a budget that cuts prompts into chunks — the
+    ``dispatch/split_steps_at.<slots>`` counters grow by ``dispatch/
+    steps.split`` together, and the split launches' ``dispatch/
+    token_slots`` by the sum over the rungs of slots x launches; every rung
+    of the ladder is taken."""
+    from deepspeed_tpu.telemetry.registry import registry
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg, params, _ = _packed_stack("uniform")
+    rng = np.random.default_rng(11)
+    eng = RaggedInferenceEngineTPU(
+        cfg, dict(_ENGINE, max_batch_tokens=320, num_blocks=128),
+        params=params)
+    ladder = eng._token_capacities(16, 96, "split")
+    assert ladder == (128, 256, 320)
+
+    def read():
+        names = ["steps.split", "token_slots"] + [
+            f"split_steps_at.{slots}" for slots in ladder]
+        return np.asarray([registry.counter("dispatch/" + n).value
+                           for n in names])
+
+    def prompt(n):
+        return rng.integers(0, cfg.vocab_size, n).tolist()
+    uids = list(range(6))
+    eng.scheduler.put(uids, [prompt(3) for _ in uids])
+    out = eng.step_with_budget(budget=320)
+    split_slots, start, arrivals = 0, read(), iter(
+        [(30,), (), (50, 60), (96,), (20, 30, 40), (110, 100), (), (7,)])
+    for step in range(12):
+        eng.scheduler.put([u for u in uids if u in out],
+                          [[int(out[u])] for u in uids if u in out])
+        for n in next(arrivals, ()):
+            uids.append(len(uids))
+            eng.scheduler.put([uids[-1]], [prompt(n)])
+        before = read()
+        out = eng.step_with_budget(budget=320)
+        if eng.last_program == "split":
+            split_slots += (read() - before)[1]
+    launches, _slots, *at = read() - start
+    assert launches >= 6 and sum(at) == launches and all(at), at
+    assert split_slots == sum(slots * n for slots, n in zip(ladder, at))

@@ -287,43 +287,69 @@ def _abstract_params(model, sharding):
 
 #: the serving cell's three 64-row step programs -> (chunk width,
 #: ``fresh_prefill``, token capacities under the engine's defaults
-#: (``_token_capacities``: 16 a row and ``max_batch_tokens``; a decode step
-#: keeps the row form), most temporaries at two layers (measured 0.00,
-#: 0.46 and 0.11 GB since the chunk's K/V leave the layer loop packed,
-#: ISSUE 36; 0.56 and 0.14 while they left it as rows))
+#: (``_token_capacities``: the split program's ladder of 8 and 16 a row
+#: under ``max_batch_tokens``, ISSUE 46; a decode step keeps the row form),
+#: most temporaries at two layers (measured 0.00, 0.46 and 0.11 GB since
+#: the chunk's K/V leave the layer loop packed, ISSUE 36; 0.56 and 0.14
+#: while they left it as rows))
 _SERVE_STEPS = {
     "decode": (1, False, (), 0.3e9),
-    "split": (128, "split", (1024, 2048), 0.5e9),
+    "split": (128, "split", (512, 1024, 2048), 0.5e9),
     "fresh": (128, "fresh", (2048,), 0.125e9),
 }
+#: the split program's capacities before the rung at 8 a row (PRs 32-45):
+#: what the three-rung program's memory is held against
+_TWO_RUNGS = (1024, 2048)
 
 
 #: stack -> temporaries of its 64-row split program at two layers before
-#: PR 40 (bytes; this compiler, the tree at PR 39). The instance at 1,024
-#: slots now runs attention on two row groups — 8 rows at the chunk's width
-#: and 64 rows of one query — and must not grow them: measured +0.5, +0.6
-#: and +1.1 MB (the groups' index arrays), so 2 MB of room
+#: PR 40 (bytes; this compiler, the tree at PR 39). The TWO-RUNG program's
+#: instance at 1,024 slots runs attention on two row groups — 8 rows at the
+#: chunk's width and 64 rows of one query — and must not grow them:
+#: measured +0.5, +0.6 and +1.1 MB (the groups' index arrays), so 2 MB of
+#: room
 _SPLIT_TEMPS_PR39 = {"uniform": 457330176, "mimo": 1046920704,
                      "latent": 1631744000}
 
 
-def _check_split_groups(stack, compiled, text, kernel, layer_loops):
-    """The 64-row split program of ``stack``: two instances of the layer
-    loop in one executable, the history ``kernel`` called three times a
-    layer loop's layer (all 64 rows at the chunk's width in the top
-    instance; the chunk group and the one-query rows in the small one),
-    and temporaries no larger than before the groups."""
+def _memory(compiled):
+    """``arguments + temporaries`` of a compiled program, bytes."""
+    m = compiled.memory_analysis()
+    return m.argument_size_in_bytes + m.temp_size_in_bytes
+
+
+def _check_ladder_memory(compiled, two_rungs):
+    """The three-rung split program against the two-rung one it replaced,
+    the same stack compiled for the same chip: ``arguments + temporaries``
+    within 0.1 GB (an instance more of the layer loop adds index arrays and
+    no tensor of its own: the branches' temporaries share their space), and
+    under the chip's 15.75 GiB."""
+    assert _memory(compiled) <= _memory(two_rungs) + 0.1e9, \
+        (_memory(compiled), _memory(two_rungs))
+    assert _memory(compiled) < 15.75 * 2 ** 30
+
+
+def _check_split_groups(stack, compiled, text, kernel, layer_loops,
+                        two_rungs):
+    """The 64-row split program of ``stack``: THREE instances of the layer
+    loop in one executable (ISSUE 46), the history ``kernel`` called five
+    times a layer loop's layer (all 64 rows at the chunk's width in the top
+    instance; the chunk group — 8 rows at 1,024 slots, 4 at 512 — and the
+    one-query rows in each of the two under it), memory within 0.1 GB of
+    the two-rung program ``two_rungs`` — whose temporaries are no larger
+    than before the groups."""
     from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
-    assert len(_branches(text)) == 2, _branches(text)
+    assert len(_branches(text)) == 3, _branches(text)
     table = scope_table_from_hlo(text)
     kernels = [n for n in table if n.startswith(kernel)]
-    assert len(kernels) == 3 * layer_loops and \
+    assert len(kernels) == 5 * layer_loops and \
         all(table[n]["scope"] == "attn_history" for n in kernels), kernels
-    temp = compiled.memory_analysis().temp_size_in_bytes
+    _check_ladder_memory(compiled, two_rungs)
+    temp = two_rungs.memory_analysis().temp_size_in_bytes
     assert temp <= _SPLIT_TEMPS_PR39[stack] + 2e6, temp
 
 
-def _serve_step(one_chip, kind="split"):
+def _serve_step(one_chip, kind="split", capacities=None):
     """A 64-row step of the benchmark's serving cell (``serve_decode_r64``,
     ``serve_split_r64_c128``, ``serve_fresh_r64_c128``): chunk 128 or one
     token a row over the default arena (512 pages of 128, ``max_seq_len``
@@ -332,7 +358,8 @@ def _serve_step(one_chip, kind="split"):
     from deepspeed_tpu.inference import engine_v2
     from deepspeed_tpu.ops import paged_attention as pa
     model = _mistral_2l()
-    cb, fresh, capacities, _ = _SERVE_STEPS[kind]
+    cb, fresh, ladder, _ = _SERVE_STEPS[kind]
+    capacities = ladder if capacities is None else capacities
     nb, mb = 64, 32
 
     def serve_step(params, arena, tokens, counts, starts, pt):
@@ -548,7 +575,9 @@ def test_no_serve_step_moves_the_arena(kind, one_chip, no_persistent_cache,
         return
     # one instance of the layer loop a capacity, in ONE executable (the
     # layers are one scan: one kernel call a row group)
-    _check_split_groups("uniform", compiled, text, "paged_attn_lse", 1)
+    two, args = _serve_step(one_chip, kind, _TWO_RUNGS)
+    _check_split_groups("uniform", compiled, text, "paged_attn_lse", 1,
+                        two.lower(*args).compile())
     assert compiled.cost_analysis()["flops"] <= 0.35 * 3.593e12
 
 
@@ -610,7 +639,7 @@ def test_step_program_carries_the_fed_tokens_in_place(kind, one_chip,
 #: chunk (3 x 0.20 GB) and its scores (docs/kernels.md)
 _LATENT_STEPS = {
     "decode": (1, False, (), 0.4e9),
-    "split": (128, "split", (1024, 2048), 4.0e9),
+    "split": (128, "split", (512, 1024, 2048), 4.0e9),
     "fresh": (128, "fresh", (2048,), 4.0e9),
 }
 
@@ -702,15 +731,20 @@ def test_mimo_step_scatters_its_token_slots(kind, one_chip,
         "mimo-v2.5-l7-e16-serve.json")))
     model = model_lib.build_model({**conf, "num_hidden_layers": 2})
     assert model.layer_kinds == (0, 1) and model.head_dim == 192
-    compiled, text = _typed_step(
-        one_chip, model, _SERVE_STEPS[kind], 8, lambda: pa.init_arena_typed(
+    def make_arena():
+        return pa.init_arena_typed(
             model.layer_kinds,
             {a: model.kind_kv_heads(a) for a in set(model.layer_kinds)},
-            512, 128, 256, model.v_dim, jnp.bfloat16))
+            512, 128, 256, model.v_dim, jnp.bfloat16)
+    compiled, text = _typed_step(one_chip, model, _SERVE_STEPS[kind], 8,
+                                 make_arena)
     assert "kv_write" in {e["scope"] for e in
                           scope_table_from_hlo(text).values()}
     if kind == "split":
-        _check_split_groups("mimo", compiled, text, "paged_attn_lse", 2)
+        _check_split_groups(
+            "mimo", compiled, text, "paged_attn_lse", 2, _typed_step(
+                one_chip, model, (128, "split", _TWO_RUNGS), 8,
+                make_arena)[0])
 
 
 @pytest.mark.parametrize("kind", list(_LATENT_STEPS))
@@ -730,9 +764,11 @@ def test_latent_step_reads_the_pool_absorbed(kind, one_chip,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model = _latent_2l()
     most = _LATENT_STEPS[kind][3]
-    compiled, text = _typed_step(
-        one_chip, model, _LATENT_STEPS[kind], 34, lambda: pa.init_arena_typed(
-            model.layer_kinds, {2: 1}, 2176, 128, 640, 0, jnp.bfloat16))
+    def make_arena():
+        return pa.init_arena_typed(model.layer_kinds, {2: 1}, 2176, 128, 640,
+                                   0, jnp.bfloat16)
+    compiled, text = _typed_step(one_chip, model, _LATENT_STEPS[kind], 34,
+                                 make_arena)
     table = scope_table_from_hlo(text)
     kernels = [n for n in table if n.startswith("mla_decode")]
     # (a fresh step reads no pool: its chunk is its whole history)
@@ -745,7 +781,10 @@ def test_latent_step_reads_the_pool_absorbed(kind, one_chip,
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < most, temp
     if kind == "split":
-        _check_split_groups("latent", compiled, text, "mla_decode", 2)
+        _check_split_groups(
+            "latent", compiled, text, "mla_decode", 2, _typed_step(
+                one_chip, model, (128, "split", _TWO_RUNGS), 34,
+                make_arena)[0])
     if kind == "decode":
         expanded = re.findall(r"\[64,4352,64,\d+\]|\[64,64,4352,\d+\]",
                               text)
@@ -759,7 +798,7 @@ def test_latent_step_reads_the_pool_absorbed(kind, one_chip,
 #: four layers ``ME*M``: measured 0.15, 0.89 and 0.81 GB)
 _HYBRID_STEPS = {
     "decode": (1, False, (), 0.3e9),
-    "split": (128, "split", (1024, 2048), 1.2e9),
+    "split": (128, "split", (512, 1024, 2048), 1.2e9),
     "fresh": (128, "fresh", (2048,), 1.1e9),
 }
 
@@ -832,9 +871,9 @@ def test_hybrid_step_updates_its_state_pools_in_place(
     on some instruction, the paged kernel under ``attn_history`` in the
     split program, and temporaries under the measured ones."""
     from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    cut = {"num_hidden_layers": 4, "hybrid_override_pattern": "ME*M"}
     model, arena, compiled, text = _hybrid_step(
-        one_chip, monkeypatch, "nemotron3-nano-l26-e16-serve",
-        {"num_hidden_layers": 4, "hybrid_override_pattern": "ME*M"},
+        one_chip, monkeypatch, "nemotron3-nano-l26-e16-serve", cut,
         _HYBRID_STEPS[kind], 32)
     assert model.layer_kinds == (3, -1, 0, 3) and model.recurrent
     assert arena["ssm1"].shape == (65, 64, 64, 128) and \
@@ -847,10 +886,14 @@ def test_hybrid_step_updates_its_state_pools_in_place(
     kernels = [n for n in table if n.startswith("paged_attn_lse")]
     assert bool(kernels) == (kind == "split") and \
         all(table[n]["scope"] == "attn_history" for n in kernels)
-    # (the split program's two instances are loop bodies, not branches)
+    # (the split program's three instances are loop bodies, not branches)
     assert not _branches(text) or kind != "split", _branches(text)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < _HYBRID_STEPS[kind][3], temp
+    if kind == "split":     # ... and a third loop brings no pool copy
+        _check_ladder_memory(compiled, _hybrid_step(
+            one_chip, monkeypatch, "nemotron3-nano-l26-e16-serve", cut,
+            (128, "split", _TWO_RUNGS), 32)[2])
 
 
 # -- the two-part hybrid stack (benchmark/configs/granite-4.0-h-small-l10-e36-
@@ -860,7 +903,7 @@ def test_hybrid_step_updates_its_state_pools_in_place(
 #: three layers ``mamba attention mamba``: measured 0.29, 1.49 and 1.36 GB)
 _GRANITE_STEPS = {
     "decode": (1, False, (), 0.4e9),
-    "split": (128, "split", (1024, 2048), 1.8e9),
+    "split": (128, "split", (512, 1024, 2048), 1.8e9),
     "fresh": (128, "fresh", (2048,), 1.7e9),
 }
 
@@ -879,10 +922,10 @@ def test_two_part_hybrid_step_compiles_for_v5e(
     cell's ten layers the three compile to 1.77 / 1.64 / 0.19 GB of
     temporaries beside 12.27 GB of arguments: PERF.md §6, PR 45.)"""
     from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    cut = {"num_hidden_layers": 3,
+           "layer_types": ["mamba", "attention", "mamba"]}
     model, arena, compiled, text = _hybrid_step(
-        one_chip, monkeypatch, "granite-4.0-h-small-l10-e36-serve",
-        {"num_hidden_layers": 3,
-         "layer_types": ["mamba", "attention", "mamba"]},
+        one_chip, monkeypatch, "granite-4.0-h-small-l10-e36-serve", cut,
         _GRANITE_STEPS[kind], 8)
     assert model.layer_kinds == (3, 0, 3) and \
         model.layer_sparse == (1, 1, 1) and model.experts_held == (0, 36)
@@ -900,3 +943,12 @@ def test_two_part_hybrid_step_compiles_for_v5e(
     assert len(named) >= 0.95 * len(heavy), sorted(set(heavy) - set(named))
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < _GRANITE_STEPS[kind][3], temp
+    if kind == "split":
+        # three one-trip loops carry the pools as two did: no pool-sized
+        # copy above, and the memory of the two-rung program (at the cell's
+        # ten layers: 12.27 GB of arguments + 1.77 | 1.80 GB of temporaries,
+        # two | three rungs; PERF.md §6, PR 46)
+        assert not _branches(text), _branches(text)
+        _check_ladder_memory(compiled, _hybrid_step(
+            one_chip, monkeypatch, "granite-4.0-h-small-l10-e36-serve", cut,
+            (128, "split", _TWO_RUNGS), 8)[2])

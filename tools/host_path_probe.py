@@ -6,7 +6,14 @@ is ``benchmark/run.py``'s, and the last line printed is one more,
     {"phase": "host_counters", "launches", "host_s", "fetch_wait_s",
      "host_ms_per_launch", "fetch_wait_ms_per_launch", "host_share",
      "launches_ahead", "ahead_share", "ahead_rows_dropped",
-     "window": {<the WORK counters' growth over the window>}}
+     "window": {<the WORK counters' growth over the window>},
+     "split_steps": {<the window's split launches by what they held>}}
+
+and, after a ``--trace 1`` run, a ``split_by_rung`` line before it: the
+traced split launches of device 0 by the token capacity their program ran
+at — launches, the program's mean device time and its scopes, ms a launch
+(PR 46: ``device_by_scope`` sums a program over all its launches, and a
+split program holds an instance of its layer loop a capacity).
 
 ``dispatch/host_seconds`` and ``dispatch/fetch_wait_seconds``
 (``engine_v2._fetch``) less what they read when the window opened, over the
@@ -29,6 +36,13 @@ launches of the window alone counted (``dispatch/steps.split``,
 ``split_grouped_steps``, ``chunk_rows``, ``attn_row_slots``, ``tokens``,
 ``token_slots``): the share of split launches that took a grouped instance
 and what attention worked on (PR 40; a tree without a counter reads 0).
+``split_steps`` (PR 46) is the joint histogram of the window's split
+launches by what ``_count_dispatch`` was handed: ``by_slots`` (launches at
+each token capacity taken), ``hist`` (``"<tokens, in bins of 64>x<chunk
+rows>"`` -> launches), ``tokens_mean``, and ``fits``: the share (%) of them
+that an instance of ``<slots>x<chunk rows>`` would hold, for the rungs a
+ladder of token capacities might have — the same on any tree, since it reads
+the batches and not the programs.
 
     chiprun --chips 1 -- python3 tools/host_path_probe.py \
         --workload <cell> --seed <n> --trace <0|1>
@@ -47,6 +61,8 @@ NAMES = ("dispatch/host_seconds", "dispatch/fetch_wait_seconds",
          "dispatch/ahead_rows_dropped")
 WORK = ("steps.split", "split_grouped_steps", "chunk_rows",
         "attn_row_slots", "tokens", "token_slots")
+#: (slots, chunk rows) of the instances ``split_steps.fits`` asks about
+RUNGS = ((256, 2), (256, 8), (512, 4), (512, 8), (1024, 8))
 
 
 def counters():
@@ -55,14 +71,102 @@ def counters():
             for n in NAMES + tuple("dispatch/" + w for w in WORK)]
 
 
+def split_steps(launches):
+    """The ``split_steps`` field from ``(tokens, chunk rows, slots)`` of
+    each split launch of the window."""
+    from collections import Counter
+    if not launches:
+        return {"launches": 0}
+    n = len(launches)
+    hist = Counter((tokens // 64 * 64, rows) for tokens, rows, _ in launches)
+    return {"launches": n,
+            "by_slots": dict(Counter(str(s) for _, _, s in launches)),
+            "tokens_mean": sum(t for t, _, _ in launches) / n,
+            "chunk_rows_mean": sum(r for _, r, _ in launches) / n,
+            "fits": {f"{cap}x{group}": 100.0 * sum(
+                t <= cap and r <= group for t, r, _ in launches) / n
+                for cap, group in RUNGS},
+            "hist": {f"{tokens}x{rows}": hist[tokens, rows]
+                     for tokens, rows in sorted(hist)}}
+
+
+def split_by_rung(trace, capacities=(256, 512, 1024, 2048)):
+    """The ``split_by_rung`` line from a loaded trace (``reduce.load``'s
+    form): every ``serve_split_*`` launch of device 0, put down to the
+    capacity whose packed shapes (``[1, capacity, ...]`` or ``[capacity,
+    ...]`` results) take most of its time, with its operations' self times
+    by the program's scope table. None without such a launch."""
+    import bisect
+    import re
+    from benchmark.trace import reduce, scopes
+    shape = re.compile(r"^\(?\w+\[(?:1,)?(\d+)[,\]]")
+    for i, plane in reduce.device_planes(trace):
+        if i != 0:
+            continue
+        mods = sorted((e[1], e[1] + e[2], scopes.module_name(e[0]))
+                      for e in reduce.line_events(plane, scopes.MODULES_LINE))
+        mods = [m for m in mods if m[2].startswith("serve_split")]
+        if not mods:
+            return None
+        tables = scopes.program_tables(sorted({m[2] for m in mods}))
+        starts = [m[0] for m in mods]
+        per = [({}, {}) for _ in mods]      # (by scope, by capacity) ns
+        for ev, self_ns in reduce.self_times(
+                reduce.line_events(plane, reduce.OPS_LINE)):
+            k = bisect.bisect_right(starts, ev[1]) - 1
+            if k < 0 or ev[1] >= mods[k][1]:
+                continue
+            entry = tables.get(mods[k][2], {}).get(reduce.op_name(ev)) or {}
+            scope = entry.get("scope") or scopes.NO_SCOPE
+            per[k][0][scope] = per[k][0].get(scope, 0.0) + self_ns
+            m = shape.match(ev[0].split(" = ", 1)[-1])
+            if m and int(m.group(1)) in capacities:
+                cap = int(m.group(1))
+                per[k][1][cap] = per[k][1].get(cap, 0.0) + self_ns
+        rungs = {}
+        for (_t0, _t1, program), (by_scope, by_cap) in zip(mods, per):
+            cap = max(by_cap, key=by_cap.get) if by_cap else 0
+            rung = rungs.setdefault(f"{program}@{cap}", [0, 0.0, {}])
+            rung[0] += 1
+            rung[1] += sum(by_scope.values())
+            for scope, ns in by_scope.items():
+                rung[2][scope] = rung[2].get(scope, 0.0) + ns
+        return {"phase": "split_by_rung", "rungs": {
+            name: {"launches": n, "device_ms": busy / n / 1e6,
+                   "scopes_ms": {scope: round(ns / n / 1e6, 3) for scope, ns
+                                 in sorted(by_scope.items(),
+                                           key=lambda kv: -kv[1])}}
+            for name, (n, busy, by_scope) in sorted(rungs.items())}}
+    return None
+
+
 def main() -> int:
     at_open = []
     open_window = bench_run.Context.open_window
+    from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
+    launches = []
+    count = RaggedInferenceEngineTPU._count_dispatch
+
+    def counted(self, program, rows, nb, chunk, page_width, tokens,
+                *args, **kwargs):
+        if program == "split" and at_open and not at_close:
+            launches.append((tokens, kwargs.get("chunk_rows", 0),
+                             kwargs.get("token_slots") or nb * chunk))
+        return count(self, program, rows, nb, chunk, page_width, tokens,
+                     *args, **kwargs)
+    RaggedInferenceEngineTPU._count_dispatch = counted
 
     def opened(self):
         at_open[:] = counters()
         return open_window(self)
     bench_run.Context.open_window = opened
+    traces = []
+    load_trace = bench_run.Context.load_trace
+
+    def loaded(self):
+        traces.append(load_trace(self))
+        return traces[-1]
+    bench_run.Context.load_trace = loaded
     from deepspeed_tpu.serving import ServingFrontend
     at_close = []
     terminate = ServingFrontend.terminate_inflight
@@ -72,6 +176,10 @@ def main() -> int:
         return terminate(self, *args, **kwargs)
     ServingFrontend.terminate_inflight = closed
     rc = bench_run.main()
+    if traces and traces[-1] is not None:
+        line = split_by_rung(traces[-1])
+        if line is not None:
+            print(json.dumps(line), flush=True)
     if at_open:
         host, wait, calls, ahead, dropped, *work = (
             b - a for a, b in zip(at_open, at_close or counters()))
@@ -85,7 +193,8 @@ def main() -> int:
             "launches_ahead": int(ahead),
             "ahead_share": 100.0 * ahead / max(1, calls),
             "ahead_rows_dropped": int(dropped),
-            "window": dict(zip(WORK, work))}), flush=True)
+            "window": dict(zip(WORK, work)),
+            "split_steps": split_steps(launches)}), flush=True)
     return rc
 
 
