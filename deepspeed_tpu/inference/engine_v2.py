@@ -1897,6 +1897,8 @@ class RaggedInferenceEngineTPU:
             grouped = group_rows < nb
             attn_row_slots = group_rows * cb + nb if grouped else nb * cb
             context_slots = query_tiles = None
+            kv_pages = self._kv_page_work(batch, cb, grouped) \
+                if fresh != "fresh" else None
             if fresh == "split" and self.use_pallas:
                 # the paged reader walks each row's live pages, then the
                 # rows attend their own keys
@@ -1917,7 +1919,8 @@ class RaggedInferenceEngineTPU:
                 kv_write_slots=-(-tokens // write_block) * write_block,
                 chunk_rows=chunk_rows,
                 attn_row_slots=attn_row_slots if grouped else None,
-                state=self._state_work(batch, cb, grouped))
+                state=self._state_work(batch, cb, grouped),
+                kv_pages=kv_pages)
             if sp is not None:      # still the recorded event's arguments
                 sp.update(work)
         return out
@@ -1981,6 +1984,55 @@ class RaggedInferenceEngineTPU:
         held = np.where((fed > 1) | (not grouped), whole, 1)
         return int(held.sum()), int(np.where(fed <= tile_q, 1, whole).sum())
 
+    def _kv_page_work(self, batch: RaggedBatch, chunk: int, grouped: bool):
+        """(pages walked, page DMAs issued) by a launch's paged KERNEL
+        readers over all its attention layers, or None where no kernel
+        reads pages of K and V (a fresh step, the XLA readers, a latent
+        stack's ``mla_decode``): the live pages each row's reader must
+        read — a split step's history ``[0, start)`` of the rows that feed
+        a token, a decode step's keys up to its own, from the window's
+        first page in a window layer —, and the copies ``_paged_kernel``
+        issues for them: one of K and one of V a page and PROGRAM, and a
+        row's pages are walked by ``kv_heads / hp`` programs
+        (``paged_attention.heads_per_program`` of the block the row's call
+        gives it: one query a row in a decode step and for a grouped
+        instance's one-token rows, the chunk's width otherwise). 2 DMAs a
+        page where every call holds all of a row's heads, 16 at 8 KV heads
+        fetched a head at a time. Host arithmetic on the batch's
+        lengths."""
+        model = self.model_config
+        split = chunk > 1
+        if not self.use_pallas or model.latent or \
+                (model.typed and not split):
+            return None
+        bs = self.config.block_size
+        fed = batch.token_counts
+        last = batch.start_positions if split else \
+            batch.start_positions + fed
+        reads = (last > 0) & (fed > 0)
+        wide = (fed > 1) | (split and not grouped)      # the chunk's width
+        kinds = model.layer_kinds if model.typed else \
+            (0,) * model.num_layers
+        walked = fetches = 0
+        for kind in (0, 1):
+            layers = sum(1 for a in kinds if a == kind)
+            if not layers:
+                continue
+            kvh = model.kind_kv_heads(kind)
+            pools = [self.arena[name] for name in pa.KIND_POOLS[kind]]
+            first = 0
+            if model.kind_window(kind) is not None:
+                first = np.maximum(batch.start_positions -
+                                   (model.kind_window(kind) - 1), 0) // bs
+            pages = np.where(reads, -(-last // bs) - first, 0)
+            groups = model.num_heads // kvh
+            programs = np.asarray([kvh // pa.heads_per_program(
+                groups * c, kvh, *(pool.shape[-1] // kvh for pool in pools),
+                bs, pools[0].dtype.itemsize) for c in (1, chunk)])
+            walked += layers * int(pages.sum())
+            fetches += layers * 2 * int((pages * programs[1 * wide]).sum())
+        return walked, fetches
+
     def _attn_pairs(self, batch: RaggedBatch):
         """Live (query, key) pairs of the launch in ONE layer of each
         kind, or None where the model has no window kind: every fed token
@@ -2020,7 +2072,7 @@ class RaggedInferenceEngineTPU:
                         kv_write_slots: Optional[int] = None,
                         chunk_rows: int = 0,
                         attn_row_slots: Optional[int] = None,
-                        state=None) -> Dict[str, Any]:
+                        state=None, kv_pages=None) -> Dict[str, Any]:
         """Count one device program launch, right after its jitted call
         returned (``serving/count``: the device is at work by then; a
         launch that raises is not counted): the
@@ -2060,7 +2112,12 @@ class RaggedInferenceEngineTPU:
         paged kernel) adds ``dispatch/query_tiles`` /
         ``dispatch/query_tiles_live`` — the query tiles the history
         reader's rows hold and those it computes — and the span's
-        arguments of those names. ``state`` (:meth:`_state_work`: a
+        arguments of those names. ``kv_pages`` (:meth:`_kv_page_work`: a
+        launch whose pages the paged kernel reads) adds
+        ``dispatch/kv_pages_walked`` / ``dispatch/kv_page_fetches`` — the
+        live pages its readers must read over all attention layers and the
+        page DMAs the kernel issues for them — and the span's arguments of
+        those names. ``state`` (:meth:`_state_work`: a
         recurrent stack) adds ``dispatch/state_rows``,
         ``dispatch/state_resets`` and ``dispatch/ssm_chunk_tokens`` and the
         span's arguments of those names. A stack with experts adds
@@ -2107,15 +2164,13 @@ class RaggedInferenceEngineTPU:
                         kv_tokens_window_held=held)
         if attn_pairs is not None:
             work.update(attn_pairs)
-        if query_tiles is not None:
-            work["query_tiles"], work["query_tiles_live"] = query_tiles
-            for name in ("query_tiles", "query_tiles_live"):
-                registry.counter("dispatch/" + name).inc(work[name])
         if self.model_config.latent:
             work["kv_tokens_latent"] = context_tokens
-        if state is not None:
-            for name, by in zip(("state_rows", "state_resets",
-                                 "ssm_chunk_tokens"), state):
+        for names, counted in (
+                (("query_tiles", "query_tiles_live"), query_tiles),
+                (("kv_pages_walked", "kv_page_fetches"), kv_pages),
+                (("state_rows", "state_resets", "ssm_chunk_tokens"), state)):
+            for name, by in zip(names, counted or ()):
                 work[name] = by
                 registry.counter("dispatch/" + name).inc(by)
         if self._moe_assignments_per_token:

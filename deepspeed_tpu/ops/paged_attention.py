@@ -10,10 +10,12 @@ never gathered into a contiguous buffer in HBM.
 
 Arena layout: every pool is TOKEN-MAJOR, ``[blocks, block_size, kv_heads *
 head_dim]``: a token's heads side by side on the lanes. It is the layout a
-row scatter writes in place and the one the kernel reads in place (a head's
-page is the lane slice ``[kh * d, (kh + 1) * d)``, which is why the heads
-share the LAST axis: ``[.., kv_heads, head_dim]`` would tile heads over
-sublanes), so no step program relays a pool between its write and its read
+row scatter writes in place and the one the kernel reads in place (a page
+as it lies is every head's, fetched once for all of them where a program's
+VMEM holds them — :func:`heads_per_program` —, and a head's page is the
+lane slice ``[kh * d, (kh + 1) * d)``, which is why the heads share the
+LAST axis: ``[.., kv_heads, head_dim]`` would tile heads over sublanes), so
+no step program relays a pool between its write and its read
 (tests/test_tpu_compile.py counts the arena-shaped copies: none). A layer's
 region is ``num_blocks + 1`` pages; the last is a TRASH page: padded token
 slots and padded page-table entries all point at it, so scatter/gather stay
@@ -443,8 +445,8 @@ def tile_queries(c: int, groups: int) -> int:
     computes a page turn in place of its whole ``groups * c`` block. The
     fewest queries, a power of two, whose ``TILE_Q * groups`` matmul rows
     fill whole bf16 sublane tiles (16 rows: 4 queries at 4 queries a KV
-    head, 2 at 8, 1 at 16): a turn's cost grows with the tile's rows (the
-    table of :func:`_paged_kernel`), and the rows a chunk-wide step carries
+    head, 2 at 8, 1 at 16): a turn's cost grows with the tile's rows
+    (docs/kernels.md, PR 38), and the rows a chunk-wide step carries
     beside its prompt chunks hold ONE live query. The whole chunk where no
     such tile divides it."""
     t = 1
@@ -453,85 +455,164 @@ def tile_queries(c: int, groups: int) -> int:
     return t if c % t == 0 else c
 
 
+#: what a program's blocks, page buffers and carried values may take of VMEM
+#: for it to hold more than one KV head (:func:`heads_per_program`): a
+#: quarter of the 16 MiB a v5e kernel is scoped to. Blocks of up to 64 matmul
+#: rows (a decode row's, a row of one query) take all of 8 heads under it, 128
+#: rows 4, 256 rows 2; a chunk's 512 rows and up walk a head a program, as
+#: before PR 47. VMEM itself holds more (10 MiB: 4 heads at 512 rows, 2 at
+#: 1,024), but there a turn is its matmuls (1.1 us a head at 512 live rows
+#: beside a fetch's fixed 0.36), and every head a program holds is one more
+#: body to trace and lower in each step program of a replica's set-up: at 10
+#: MiB one call moved (8 rows of a whole live chunk, 0.52 → 0.43 ms) and the
+#: chat cell's ``setup_s`` rose 3.8 s of 40 for 1.7 at 4 MiB (my chip runs,
+#: PR 47)
+_FUSED_VMEM_BYTES = 4 * 2 ** 20
+
+
+def heads_per_program(rows: int, kv_heads: int, k_lanes: int, v_lanes: int,
+                      block_size: int, itemsize: int = 2) -> int:
+    """``hp``: the KV heads of one row that ONE program of
+    :func:`_paged_kernel` holds — a page is fetched once for them, ``hp *
+    (k_lanes + v_lanes)`` lanes of it in one copy a pool. The largest
+    divisor of ``kv_heads`` whose VMEM fits ``_FUSED_VMEM_BYTES``: the q /
+    out / lse blocks (``rows`` = queries a KV head x the chunk; each
+    double-buffered by the pipeline, rows padded to a sublane tile, the
+    lse's one lane to 128), the two page buffers a pool, the float32
+    accumulator, maximum and sum each head carries through the walk, and
+    one head's scores and probabilities. A function of the call's shapes
+    alone (``itemsize``: of q, K and V); what the kernel's wrapper and the
+    engine's accounting (``dispatch/kv_page_fetches``) both ask. A decode
+    row's block and a row of one query (4-16 matmul rows) take every head;
+    a chunk's 512 rows and up one — the walk as it was before the heads
+    shared a fetch."""
+    def padded(tile):
+        return -(-rows // tile) * tile
+
+    def vmem(hp):
+        blocks = 2 * hp * (padded(32 // itemsize) * (k_lanes + v_lanes) *
+                           itemsize + padded(8) * 128 * 4)
+        page_buffers = 2 * block_size * hp * (k_lanes + v_lanes) * itemsize
+        carried = hp * padded(8) * (v_lanes + 2 * 128) * 4
+        return blocks + page_buffers + carried + \
+            2 * padded(8) * block_size * 4
+
+    return max(hp for hp in range(1, kv_heads + 1) if kv_heads % hp == 0
+               and (hp == 1 or vmem(hp) <= _FUSED_VMEM_BYTES))
+
+
+# jitted: a program of the kernel below takes this step once a head and
+# walk instance, and the step programs hold the kernel at a dozen shapes of
+# rows — one trace serves them all (a serving replica's set-up traces and
+# lowers every step program: 21 in the benchmark's chat cell)
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _softmax_step(q, k_blk, v_blk, visible, acc, m_prev, l_prev, *, scale):
+    """One online-softmax step of ONE KV head inside :func:`_paged_kernel`:
+    q [r, dk] over a page's keys [bs, dk] and values [bs, dv] under
+    ``visible`` [r, bs] → the head's carried (accumulator [r, dv], maximum
+    [r, 1], sum [r, 1]), float32."""
+    s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    s = jnp.where(visible, s, _NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # float mask arithmetic: a row that has seen no key yet keeps p and its
+    # correction at exactly 0
+    alive = (m_new > _NEG_INF / 2).astype(jnp.float32)
+    p = jnp.exp(s - m_new) * alive
+    corr = jnp.exp(m_prev - m_new) * alive
+    acc = acc * corr + lax.dot_general(
+        p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return acc, m_new, l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+
+
 def _paged_kernel(pt_ref, starts_ref, counts_ref, qcounts_ref, q_ref, k_hbm,
                   v_hbm, o_ref, *rest, block_size: int, groups: int,
                   tile_q: int, scale: float, mb: int,
                   with_lse: bool = False, window: Optional[int] = None):
-    """Grid (n_seq, kvh): ONE program per (sequence, kv head) that walks
-    this sequence's pages with double-buffered manual DMAs from the
-    HBM-resident arena.
+    """Grid (n_seq, kvh / hp): ONE program per sequence and group of ``hp``
+    KV heads that walks this sequence's pages with double-buffered manual
+    DMAs from the HBM-resident arena, each page fetched ONCE for the
+    program's heads.
 
     A (seq, head, page) grid would be thousands of sequential tiny
     programs per layer (measured 310 ms vs 1.5 ms per 1B-model decode
     step); here pages are an in-kernel ``fori_loop`` with the next page's
-    DMA in flight while the current one computes — the reference
+    DMAs in flight while the current one computes — the reference
     blocked_flash/paged-KV structure.
 
-    q_ref block: [1, 1, rows, dh], QUERY-MAJOR (row = j * groups + g: query
+    q_ref block: [1, hp, rows, dk], QUERY-MAJOR (row = j * groups + g: query
     j of the chunk, head g of the KV head's group), so a row's live queries
-    ``j < qcounts[s]`` are the LEADING rows of its block; k_hbm/v_hbm: the
-    FULL arena [NB, bs, kvh * d] left in ANY/HBM memory space (a token's
-    heads side by side, the layout a row scatter writes without a
-    relayout), of which head ``kh``'s page is the lane slice ``[kh * d,
-    (kh + 1) * d)``; k_buf/v_buf: [2, bs, d] VMEM double buffers. With
-    ``with_lse`` an extra [1, 1, rows, 1] f32 output carries each row's
-    logsumexp (the partial-attention merge needs it — fused decode's
-    history part).
-    K and V may differ in width (k_buf [2, bs, dk], v_buf [2, bs, dv]; the
-    output is dv wide). ``window`` (static): key j is visible to query i
-    only when ``i - j < window``, and the walk STARTS at the page that
-    holds the lowest key any query of the row can see.
+    ``j < qcounts[s]`` are the LEADING rows of each head's block;
+    k_hbm/v_hbm: the FULL arena [NB, bs, kvh * d] left in ANY/HBM memory
+    space (a token's heads side by side, the layout a row scatter writes
+    without a relayout), of which the program's heads are the lanes ``[kh0
+    * d, (kh0 + hp) * d)`` — with ``hp == kvh`` the page as it lies, one
+    contiguous copy —; k_buf/v_buf: [2, bs, hp * d] VMEM double
+    buffers, head ``h`` of the program the static lane slice ``[h * d, (h
+    + 1) * d)`` of them (whole 128-lane tiles). With ``with_lse`` an extra
+    [1, hp, rows, 1] f32 output carries each row's logsumexp (the
+    partial-attention merge needs it — fused decode's history part).
+    K and V may differ in width (the output is dv wide). ``window``
+    (static): key j is visible to query i only when ``i - j < window``,
+    and the walk STARTS at the page that holds the lowest key any query of
+    the row can see.
 
     The work follows the row's LIVE queries, and a page is fetched once
     either way: a row of at most ``tile_q`` live queries walks its pages
-    with ONE tile of ``tile_q * groups`` matmul rows (scores, mask, softmax
-    and both matmuls over those rows alone), a row of more with its whole
-    block — two instances of one walk, of which a program runs one. Every
-    query past the live ones, and every query of a row with no live query
-    or no visible page, gets zeros and an lse of -1e30 (a weight of 0 in
-    :func:`merge_attention`).
+    with ONE tile of ``tile_q * groups`` matmul rows a head (scores, mask,
+    softmax and both matmuls over those rows alone), a row of more with its
+    whole block — two instances of one walk, of which a program runs one.
+    Every query past the live ones, and every query of a row with no live
+    query or no visible page, gets zeros and an lse of -1e30 (a weight of 0
+    in :func:`merge_attention`).
 
-    On a v5e (``tools/bench_paged_hist.py``: the kernel alone with the
-    wrapper's reshapes, seed 3800000011; my chip runs, PR 38), us a PAGE
-    TURN (ms a call), the kernel before ``qcounts`` → this one, small tile
-    16 matmul rows at every shape:
+    On a v5e (``tools/bench_paged_hist.py --groups --sweep``: the kernel
+    alone with the wrapper's reshapes, seed 3800000011, the parent's file
+    beside this one's in one process; my chip runs, PR 47), us a PAGE — all
+    of a row's KV heads — (ms a call), a page fetched a head at a time →
+    this kernel:
 
-    - Mistral-7B (64 rows, 32 / 8 heads of 128, contexts 128–2,500, a block
-      of 512 rows). One live query a row: 1.06 → 0.65 (5.27 → 3.26); 61
-      rows of one + 3 of 128: 1.06 → 0.66 (5.26 → 3.30); every row all 128
-      live: 1.09 → 1.12 (4.88 → 5.02).
-    - MiMo-V2.5's window-128 layer (64 rows, 8 KV heads, K 256 / V 128
-      lanes, 1,024 rows; 1–2 pages a row, so a call is its blocks). One:
-      5.32 → 3.94 (4.73 → 3.50); all: 9.96 → 10.1 (3.83 → 3.89). Its full
-      layer (4 KV heads, 2,048 rows). One: 6.69 → 4.06 (5.51 → 3.34); all:
-      8.26 → 8.27 (4.72 → 4.73).
-    - Command A+'s window-4,096 layer (16 rows, 128 / 8 heads of 128,
-      contexts 2.5K–10K, 2,048 rows). One: 2.58 → 0.64 (10.4 → 2.57); 12
-      rows of one + 4 of 128: 2.58 → 1.07 (10.3 → 4.28); all: 2.59 → 2.54
-      (10.1 → 9.88). Its full layer. One: 2.46 → 0.55 (16.1 → 3.59); 12 +
-      4: 2.46 → 0.84 (16.0 → 5.43); all: 2.47 → 2.44 (15.8 → 15.6).
-    - The decode programs' ``paged_attn`` (c = 1, its block is one tile):
-      0.46 → 0.46 (2.32 → 2.32 at the first shape).
+    - Mistral-7B (64 rows, 32 / 8 heads of 128, contexts 128–2,500).
+      Every row as ONE query, ``[64, 1]`` (a grouped split step's call,
+      607 pages): 3.78 → 1.26 (2.29 → 0.767); the decode programs'
+      ``paged_attn`` (624 pages): 3.71 → 1.20 (2.32 → 0.751). By ``hp`` 1 /
+      2 / 4 / 8: 3.81 / 2.22 / 1.61 / 1.28 and 3.71 / 2.16 / 1.55 / 1.22 —
+      a turn costs 0.36 + 0.113 x hp us.
+    - Blocks of a chunk's rows keep ``hp`` 1 (``_FUSED_VMEM_BYTES``). What
+      more would buy, the same runs: Mistral's row form ``[64, 128]`` (512
+      rows), 61 rows of one live query + 3 of 128, by ``hp`` 1 / 2 / 4:
+      5.34 / 3.65 / 3.00 (3.31 / 2.27 / 1.86), 8: out of VMEM; all 128
+      live 8.97 → 6.79 at 4; 8 rows of a whole live chunk (the long-prompt
+      cell) 0.519 / 0.445 / 0.432 / 0.408 ms a call of 48 pages; the chunk
+      group ``[8, 128]`` with 3 live rows 0.44 / 0.45 / 0.43 / 0.43 (its
+      blocks). MiMo-V2.5's window layer's row form (1,024 rows) 3.48 →
+      3.24 at 2, 4: out of VMEM; its full layer's and Command A+'s 2,048
+      rows take no second head.
+    - MiMo-V2.5 (64 query heads, K 256 / V 128 lanes), ``[64, 1]``: the
+      window-128 layer (8 KV heads, 111 pages) 0.724 → 0.468 ms, the full
+      layer (4 KV heads, 206 pages) 0.581 → 0.440.
 
-    With NO live query in any row (nothing walked: the grid, the q / out /
-    lse blocks, the reshapes) a call is 0.90 / 2.76 / 2.82 / 0.88 / 0.88
-    ms at the five shapes: what is left of a one-live-query call is the
-    page fetch and those blocks. A one-live turn by the small tile's rows:
-    0.55 / 0.56 / 0.57 / 0.59 / 0.69 / 0.94 at 16 / 32 / 64 / 128 / 256 /
-    512 (Command A+ full), 0.65 at 4–16 and 0.66 / 0.68 / 0.71 at 32 / 64 /
-    128 (Mistral). Measured and NOT built: an inner loop over the live
-    tiles, ``acc, m, l`` in VMEM scratch, takes a fully live row 2.0–6.3
-    times the whole block's turn (6.88 us at 32-row tiles and 2.18 at 128
-    for Mistral's 1.09; 7.4–8.1 at 128-row tiles and 4.9–5.0 at 512 for
-    Command A+'s 2.5: every tile reloads the page into the MXU and waits
-    out both matmuls); a grid axis over tiles walks the pages once a
-    tile."""
-    rows = q_ref.shape[2]
+    With NO live query a row-form call is 0.91 / 2.75 / 2.83 / 0.88 / 0.44
+    ms (Mistral, MiMo-V2.5 window, full, Command A+, Mistral's 8-row
+    program) on both: the grid and the q / out / lse blocks. What
+    bounds ``[64, 1]`` now (the same call with the compute or the copies
+    taken out): the copies alone 0.560 ms (0.92 us a page of 512 KB: 555
+    GB/s with each program's first, exposed fetch inside), the eight heads'
+    arithmetic alone 0.690 (0.14 us a head and page), together 0.767 — 51%
+    of the call's HBM roofline (607 x 512 KB at 819 GB/s = 0.389 ms; 17%
+    before). Measured and NOT kept: several pages a loop turn (as
+    ``mla_decode`` below) — 2 / 4 pages read 0.751 / 0.709 ms for 0.767
+    on ``[64, 1]`` (-2.1 / -7.5%), 1.79 / 1.74 for 1.86 on the row form,
+    0.437 / 0.469 for 0.435 on the chunk group: eight heads already share
+    a turn's fixed cost."""
+    hp, rows, dk = q_ref.shape[1:]
+    dv = o_ref.shape[3]
     if with_lse:
         lse_ref, *rest = rest
-    k_buf, v_buf, sem_k, sem_v = rest
+    k_buf, v_buf, sem = rest
     s_idx = pl.program_id(0)
-    kh = pl.program_id(1)
+    hg = pl.program_id(1)
     start = starts_ref[s_idx]
     ctx = start + counts_ref[s_idx]
     qcount = qcounts_ref[s_idx]
@@ -545,81 +626,72 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, qcounts_ref, q_ref, k_hbm,
         first_slot = lax.rem(first, 2)
     live = (npages > first) & (qcount > 0)
 
-    def head_page(hbm, buf, page):
+    def heads_page(hbm, buf, page):
         width = buf.shape[-1]
-        return hbm.at[page, :, pl.ds(pl.multiple_of(kh * width, 128), width)]
+        if width == hbm.shape[-1]:
+            return hbm.at[page]
+        return hbm.at[page, :, pl.ds(pl.multiple_of(hg * width, 128), width)]
 
-    def copy_in(page_i, slot):
-        page = pt_ref[s_idx, page_i]
-        pltpu.make_async_copy(head_page(k_hbm, k_buf, page), k_buf.at[slot],
-                              sem_k.at[slot]).start()
-        pltpu.make_async_copy(head_page(v_hbm, v_buf, page), v_buf.at[slot],
-                              sem_v.at[slot]).start()
+    def copies(slot, page_i=None):
+        """The DMAs of the row's page ``page_i`` into ``slot``, K's and V's
+        (no page: copies of their shape, to wait on)."""
+        page = 0 if page_i is None else pt_ref[s_idx, page_i]
+        return [pltpu.make_async_copy(heads_page(hbm, buf, page),
+                                      buf.at[slot], sem.at[p, slot])
+                for p, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf)))]
 
     def write_dead(lo):
-        """Zeros and -1e30 for the block's rows from ``lo`` on."""
-        o_ref[0, 0, lo:, :] = jnp.zeros((rows - lo, o_ref.shape[3]),
-                                        o_ref.dtype)
+        """Zeros and -1e30 for every head's rows from ``lo`` on."""
+        o_ref[0, :, lo:, :] = jnp.zeros((hp, rows - lo, dv), o_ref.dtype)
         if with_lse:
-            lse_ref[0, 0, lo:, :] = jnp.full((rows - lo, 1), _NEG_INF,
+            lse_ref[0, :, lo:, :] = jnp.full((hp, rows - lo, 1), _NEG_INF,
                                              jnp.float32)
 
     def walk(r):
-        """The block's first ``r`` matmul rows over the row's pages."""
-        copy_in(first, first_slot)
-        q = q_ref[0, 0, :r, :]                              # [r, dk]
+        """Each head's first ``r`` matmul rows over the row's pages."""
+        for copy in copies(first_slot, first):
+            copy.start()
+        q = [q_ref[0, h, :r, :] for h in range(hp)]         # [r, dk] each
         # the chunk offset of each matmul row's query
         j = lax.div(lax.broadcasted_iota(jnp.int32, (r, 1), 0),
                     jnp.int32(groups))
         qpos = start + j
 
         def body(b, carry):
-            acc, m_prev, l_prev = carry
             slot = lax.rem(b, 2)
 
             @pl.when(b + 1 < npages)
             def _prefetch():
-                copy_in(b + 1, lax.rem(b + 1, 2))
+                for copy in copies(lax.rem(b + 1, 2), b + 1):
+                    copy.start()
 
-            pltpu.make_async_copy(head_page(k_hbm, k_buf, 0), k_buf.at[slot],
-                                  sem_k.at[slot]).wait()
-            pltpu.make_async_copy(head_page(v_hbm, v_buf, 0), v_buf.at[slot],
-                                  sem_v.at[slot]).wait()
-            k_blk = k_buf[slot]
-            v_blk = v_buf[slot]
-            s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+            for copy in copies(slot):
+                copy.wait()
             kpos = b * block_size + \
                 lax.broadcasted_iota(jnp.int32, (r, block_size), 1)
             visible = (kpos <= qpos) & (kpos < ctx)
             if window is not None:
                 visible = visible & (kpos > qpos - window)
-            s = jnp.where(visible, s, _NEG_INF)
+            return tuple(
+                _softmax_step(q[h], k_buf[slot, :, h * dk:(h + 1) * dk],
+                              v_buf[slot, :, h * dv:(h + 1) * dv], visible,
+                              *head, scale=scale)
+                for h, head in enumerate(carry))
 
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            # float mask arithmetic: a row that has seen no key yet keeps
-            # p and its correction at exactly 0
-            alive = (m_new > _NEG_INF / 2).astype(jnp.float32)
-            p = jnp.exp(s - m_new) * alive
-            corr = jnp.exp(m_prev - m_new) * alive
-            acc = acc * corr + lax.dot_general(
-                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return acc, m_new, l_prev * corr + jnp.sum(p, axis=1,
-                                                       keepdims=True)
-
-        acc, m, l = lax.fori_loop(
+        heads = lax.fori_loop(
             first, npages, body,
-            (jnp.zeros((r, o_ref.shape[3]), jnp.float32),
-             jnp.full((r, 1), _NEG_INF, jnp.float32),
-             jnp.zeros((r, 1), jnp.float32)))
-        l = jnp.maximum(l, 1e-30)
+            ((jnp.zeros((r, dv), jnp.float32),
+              jnp.full((r, 1), _NEG_INF, jnp.float32),
+              jnp.zeros((r, 1), jnp.float32)),) * hp)
         is_live = j < qcount
-        o_ref[0, 0, :r, :] = jnp.where(is_live, acc / l, 0.0) \
-            .astype(o_ref.dtype)
-        if with_lse:
-            lse_ref[0, 0, :r, :] = jnp.where(
-                is_live & (m > _NEG_INF / 2), m + jnp.log(l), _NEG_INF)
+        for h, (acc, m, l) in enumerate(heads):
+            l = jnp.maximum(l, 1e-30)
+            o_ref[0, h, :r, :] = jnp.where(is_live, acc / l, 0.0) \
+                .astype(o_ref.dtype)
+            if with_lse:
+                lse_ref[0, h, :r, :] = jnp.where(
+                    is_live & (m > _NEG_INF / 2), m + jnp.log(l), _NEG_INF)
         if r < rows:
             write_dead(r)
 
@@ -637,16 +709,17 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, qcounts_ref, q_ref, k_hbm,
 # and one lowered function (0.15-0.25 s a call on the serving host; the
 # typed 64-row split program calls it 21 times for 6 shapes)
 @functools.partial(jax.jit, static_argnames=(
-    "with_lse", "interpret", "window", "scale", "tile_q"))
+    "with_lse", "interpret", "window", "scale", "tile_q", "heads"))
 def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
                 with_lse: bool, interpret: bool, window=None, scale=None,
-                qcounts=None, tile_q=None):
+                qcounts=None, tile_q=None, heads=None):
     """The ``pallas_call`` of both wrappers below → (out [n, c, h, dv],
     lse [n, c, h] fp32 or None). The kernel's name in a device trace is
     ``paged_attn_lse`` with the logsumexp output, ``paged_attn`` without.
     ``qcounts`` [n]: each row's live queries (None: all ``c``);
-    ``tile_q``: another small tile than :func:`tile_queries`', for
-    ``tools/bench_paged_hist.py``'s sweep alone."""
+    ``tile_q``, ``heads``: another small tile than :func:`tile_queries`',
+    other KV heads a program than :func:`heads_per_program`'s, for
+    ``tools/bench_paged_hist.py``'s sweeps and the tests alone."""
     bs, lanes = arena_k.shape[1:]
     n, c, h, dh = q.shape
     kvh = lanes // dh
@@ -654,6 +727,7 @@ def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
     groups = h // kvh
     mb = page_table.shape[1]
     rows = groups * c
+    hp = heads or heads_per_program(rows, kvh, dh, dv, bs, q.dtype.itemsize)
     if qcounts is None:
         qcounts = jnp.full((n,), c, jnp.int32)
 
@@ -662,8 +736,8 @@ def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
         .reshape(n, kvh, rows, dh)
 
     def rows_of(width):
-        return pl.BlockSpec((1, 1, rows, width),
-                            lambda s, kh, pt, st, ct, qc: (s, kh, 0, 0))
+        return pl.BlockSpec((1, hp, rows, width),
+                            lambda s, hg, pt, st, ct, qc: (s, hg, 0, 0))
 
     kernel = functools.partial(
         _paged_kernel, block_size=bs, groups=groups, mb=mb,
@@ -680,7 +754,7 @@ def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n, kvh),
+            grid=(n, kvh // hp),
             in_specs=[
                 rows_of(dh),
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -688,10 +762,9 @@ def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
             ],
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((2, bs, dh), arena_k.dtype),
-                pltpu.VMEM((2, bs, dv), arena_v.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((2, bs, hp * dh), arena_k.dtype),
+                pltpu.VMEM((2, bs, hp * dv), arena_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
         out_shape=out_shape,

@@ -576,6 +576,120 @@ def test_history_kernel_computes_the_live_queries(window, groups, widths):
     np.testing.assert_array_equal(whole[1][live], lse[live])
 
 
+#: (rows' live queries, history lengths) of one batch at a chunk of 16 over
+#: pages of 16 (a small tile is 4 queries at 4 queries a KV head): a padded
+#: row, a decode row, a tile, one query past it, the whole chunk twice — a
+#: history that ends mid-page, on a page's edge, of one token, none at all
+_FUSED_ROWS = ((0, 40), (1, 90), (4, 33), (5, 64), (16, 1), (16, 47),
+               (1, 0))
+
+
+@pytest.mark.parametrize("window", [None, 8, 70])
+@pytest.mark.parametrize("widths", ["k128_v128", "k256_v128"])
+@pytest.mark.parametrize("with_lse", [True, False])
+@pytest.mark.parametrize("heads", [1, 2, 4])
+def test_kernel_fetches_a_page_once_for_its_heads(heads, with_lse, widths,
+                                                  window):
+    """``_paged_kernel`` at ``heads`` KV heads a program (1: a page a head,
+    the walk of before PR 47; 4: all of them, the page as it lies) against
+    the XLA readers — the history reader
+    (``with_lse``, ``counts = 0``, rows of 0 / 1 / ``tile_q`` / all live
+    queries) and the decode reader's contract (each row's own keys too) —
+    over a page table padded with the trash page, K wider than V, a window
+    inside a page, across pages and past every history. Every choice of
+    ``heads`` gives the same bits: a head's arithmetic does not know who
+    shares its fetch."""
+    dk, dv = (128, 128) if widths == "k128_v128" else (256, 128)
+    rng = np.random.default_rng(dk + (window or 0))
+    kvh, groups, bs, c, mb, nb = 4, 4, 16, 16, 7, 40
+    qcounts, starts = (np.asarray(a, np.int32) for a in zip(*_FUSED_ROWS))
+    n = len(qcounts)
+    counts = np.zeros_like(qcounts) if with_lse else qcounts
+    pt = np.full((n, mb), nb, np.int32)             # the trash page
+    free = iter(rng.permutation(nb))                # pages out of order
+    for i in range(n):
+        for b in range(-(-(starts[i] + counts[i]) // bs)):
+            pt[i, b] = next(free)
+    ak = jnp.asarray(rng.standard_normal((nb + 1, bs, kvh * dk)), jnp.float32)
+    av = jnp.asarray(rng.standard_normal((nb + 1, bs, kvh * dv)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((n, c, kvh * groups, dk)),
+                    jnp.float32)
+    args = (q, ak, av, jnp.asarray(pt), jnp.asarray(starts),
+            jnp.asarray(counts))
+
+    def kernel(heads):
+        out, lse = pa._paged_call(
+            *args, with_lse=with_lse, interpret=True, window=window,
+            scale=0.1, qcounts=jnp.asarray(qcounts), heads=heads)
+        return np.asarray(out), None if lse is None else np.asarray(lse)
+
+    out, lse = kernel(heads)
+    if with_lse:
+        out_x, lse_x = pa.paged_attention_hist_xla(*args[:5], window=window,
+                                                   scale=0.1)
+    else:
+        out_x, lse_x = pa.paged_attention_xla(*args, window=window,
+                                              scale=0.1, with_lse=True)
+    out_x, lse_x = np.asarray(out_x), np.asarray(lse_x)
+    live = np.arange(c)[None] < qcounts[:, None]                  # [n, c]
+    seen = live[..., None] & (lse_x > -1e29)     # ... and sees some key
+    assert seen.any(axis=(1, 2)).tolist() == [False] + [True] * 5 + \
+        [not with_lse]
+    assert (out[~live] == 0).all() and np.isfinite(out).all()
+    np.testing.assert_allclose(out[seen], out_x[seen], rtol=2e-5, atol=2e-5)
+    if with_lse:
+        assert (lse[~live] <= -1e29).all()
+        np.testing.assert_array_equal(lse > -1e29, seen)
+        np.testing.assert_allclose(lse[seen], lse_x[seen], rtol=2e-5,
+                                   atol=2e-5)
+    if heads > 1:
+        base, base_lse = kernel(1)
+        np.testing.assert_array_equal(out, base)
+        if with_lse:
+            np.testing.assert_array_equal(lse, base_lse)
+
+
+#: (queries a KV head, chunk, KV heads, K lanes, V lanes) of every history
+#: or decode call the benchmark's cells make → the KV heads a program holds
+_HEADS_PER_PROGRAM = {
+    # cells 2 and 9, Mistral-7B: every row as one query and the decode
+    # programs' reader; the chunk group [4 | 8, 128] and the row form
+    "mistral_one_query": ((4, 1, 8, 128, 128), 8),
+    "mistral_chunk": ((4, 128, 8, 128, 128), 1),
+    "mistral_chunk_of_32": ((4, 32, 8, 128, 128), 4),
+    # cell 4, MiMo-V2.5: K 192 padded to 256 lanes, a window layer of 8 KV
+    # heads and a full layer of 4
+    "mimo_window_one_query": ((8, 1, 8, 256, 128), 8),
+    "mimo_full_one_query": ((16, 1, 4, 256, 128), 4),
+    "mimo_window_chunk": ((8, 128, 8, 256, 128), 1),
+    "mimo_full_chunk": ((16, 128, 4, 256, 128), 1),
+    # cell 6, Command A+: 16 queries a KV head, the row form
+    "command_a_chunk": ((16, 128, 8, 128, 128), 1),
+    "command_a_one_query": ((16, 1, 8, 128, 128), 8),
+    # cells 7 and 8, the hybrid stacks' few attention layers: 32 / 2 heads
+    # (Nemotron 3 Nano), 32 / 8 (Granite 4.0-H Small)
+    "nemotron_one_query": ((16, 1, 2, 128, 128), 2),
+    "nemotron_chunk": ((16, 128, 2, 128, 128), 1),
+    "granite_one_query": ((4, 1, 8, 128, 128), 8),
+}
+
+
+@pytest.mark.parametrize("call", list(_HEADS_PER_PROGRAM))
+def test_heads_per_program_by_the_calls_shapes(call):
+    """``heads_per_program`` is a function of the call's shapes alone: the
+    ``hp`` every cell's calls get (a block of a decode row's few matmul
+    rows takes every head, 128 rows four, a chunk's 512 and up one — the
+    walk as it was), a divisor of the KV heads, and never more for a block
+    of more rows."""
+    (groups, c, kvh, dk, dv), want = _HEADS_PER_PROGRAM[call]
+    assert pa.heads_per_program(groups * c, kvh, dk, dv, 128) == want
+    got = [pa.heads_per_program(rows, kvh, dk, dv, 128)
+           for rows in (1, 4, 16, 64, 256, 512, 1024, 2048, 4096, 8192)]
+    assert got[0] == kvh and got[-1] == 1
+    assert all(kvh % hp == 0 for hp in got)
+    assert got == sorted(got, reverse=True)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("reader", ["xla", "kernel"])
 def test_split_step_matches_in_loop_write(devices, monkeypatch, reader,
@@ -884,14 +998,19 @@ def test_engine_serves_through_the_history_kernel_what_the_xla_reader_does(
         return eng, tokens, programs
 
     from deepspeed_tpu import telemetry
-    tiles = lambda: [telemetry.registry.counter(
-        "dispatch/query_tiles" + kind).value for kind in ("", "_live")]
+    tiles = lambda: [telemetry.registry.counter("dispatch/" + name).value
+                     for name in ("query_tiles", "query_tiles_live",
+                                  "kv_pages_walked", "kv_page_fetches")]
     before = tiles()
     xla, want, _ = serve(False)
     assert not calls and tiles() == before     # the XLA reader: no tiles
     got_eng, got, programs = serve(True)
     assert calls and all(calls) and programs.count("split") >= 3
-    held, computed = (now - was for now, was in zip(tiles(), before))
+    held, computed, walked, fetches = (
+        now - was for now, was in zip(tiles(), before))
+    # at these widths every call holds all of a row's KV heads: a page is
+    # one DMA of K and one of V
+    assert 0 < walked and fetches == 2 * walked
     # rows of one live query rode beside the chunks: one tile each
     assert 0 < computed < held and held % (32 // pa.tile_queries(
         32, cfg.num_heads // cfg.kv_heads)) == 0
@@ -906,6 +1025,76 @@ def test_engine_serves_through_the_history_kernel_what_the_xla_reader_does(
                 .reshape(b.shape)
         assert np.abs(a).max() > 0.01, name
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+def _page_work_engine(stack, use_pallas=True):
+    """What ``_kv_page_work`` reads of an engine, at the serving cells'
+    widths over pages of 128: Mistral-7B's 12 layers of 32 / 8 heads of
+    128; MiMo-V2.5's two window layers (64 / 8 heads, a window of 128) and
+    one full layer (4 KV heads), K 256 and V 128 lanes a head; a latent
+    stack."""
+    import types
+    typed = stack != "uniform"
+    model = types.SimpleNamespace(
+        latent=stack == "latent", typed=typed, num_layers=12,
+        layer_kinds=(1, 1, 0) if typed else None,
+        num_heads=64 if typed else 32,
+        kind_kv_heads=lambda kind: (8 if kind == 1 else 4) if typed else 8,
+        kind_window=lambda kind: 128 if kind == 1 else None)
+    dk = 256 if typed else 128
+    pool = lambda kvh, d: jax.ShapeDtypeStruct((9, 128, kvh * d),
+                                               jnp.bfloat16)
+    eng = object.__new__(RaggedInferenceEngineTPU)
+    eng.model_config, eng.use_pallas = model, use_pallas
+    eng.config = types.SimpleNamespace(block_size=128)
+    eng.arena = {"k": pool(4 if typed else 8, dk),
+                 "v": pool(4 if typed else 8, 128),
+                 "k_win": pool(8, dk), "v_win": pool(8, 128)}
+    return eng
+
+
+#: case → (stack, chunk, grouped, rows' starts, rows' fed tokens, want)
+_PAGE_WORK = {
+    # 12 layers x (2 + 1 + 2) pages to each row's own key, all 8 heads a
+    # fetch of K and one of V
+    "decode": ("uniform", 1, False, (200, 127, 128), (1, 1, 1), (60, 120)),
+    # histories of 3 and 2 pages (a fresh row and a padded row read none):
+    # the one-token row's call holds 8 heads a program, the chunk row's 1
+    "split_grouped": ("uniform", 128, True, (300, 0, 256, 128),
+                      (1, 100, 128, 0), (60, 12 * 2 * (3 * 1 + 2 * 8))),
+    # ... and in the row form every row's block is the chunk's
+    "split_rows": ("uniform", 128, False, (300, 0, 256, 128),
+                   (1, 100, 128, 0), (60, 12 * 2 * (3 * 8 + 2 * 8))),
+    # ... of which a chunk of 32 holds 4 heads a program (128 matmul rows)
+    "split_rows_chunk_32": ("uniform", 32, False, (300, 0, 256, 128),
+                            (1, 30, 32, 0), (60, 12 * 2 * (3 * 2 + 2 * 2))),
+    # a window layer walks from its window's first page (pages 4 and 5 of
+    # 700 tokens), the full layer all 6; the chunk row's blocks of 1,024
+    # and 2,048 matmul rows take a head a program
+    "typed_split_grouped": ("typed", 128, True, (700, 100), (1, 128),
+                            (2 * 3 + 7, 2 * 2 * (2 * 1 + 1 * 8) +
+                             2 * (6 * 1 + 1 * 4))),
+    "typed_decode_is_the_xla_reader": ("typed", 1, False, (700,), (1,),
+                                       None),
+    "latent_is_another_kernel": ("latent", 128, True, (700,), (1,), None),
+}
+
+
+@pytest.mark.parametrize("case", list(_PAGE_WORK))
+def test_page_fetches_follow_the_heads_a_program_holds(case):
+    """``dispatch/kv_pages_walked`` / ``dispatch/kv_page_fetches`` of a
+    launch (``engine_v2._kv_page_work``, host arithmetic): the live pages
+    the paged kernel's readers must read over all attention layers, and
+    two DMAs a page and program — ``kv_heads / heads_per_program`` programs
+    a row, by the block its call gives it. Without the kernel: nothing."""
+    from deepspeed_tpu.inference.ragged import RaggedBatch
+    stack, chunk, grouped, starts, fed, want = _PAGE_WORK[case]
+    batch = RaggedBatch(list(range(len(fed))), None, np.asarray(fed),
+                        np.asarray(starts), None)
+    assert _page_work_engine(stack)._kv_page_work(batch, chunk,
+                                                  grouped) == want
+    assert _page_work_engine(stack, use_pallas=False)._kv_page_work(
+        batch, chunk, grouped) is None
 
 
 def test_capacities_the_rows_already_hold_are_refused(devices):

@@ -104,12 +104,13 @@ def _paged(n, c, with_lse, heads=16, pages=8, window=None):
     return hist, (q, arena, arena, pt, vec, vec, vec), 1
 
 
-def _paged_typed(kvh):
+def _paged_typed(kvh, n=64, c=128):
     """The split step's history reader over a TYPED arena at MiMo-V2.5's
     widths: 64 query heads, K heads of 192 padded to 256 lanes, V heads of
     128, pools ``[blocks, 128, kvh * width]`` (5 window layers
-    of 8 KV heads with a window of 128; 2 full layers of 4), 64 rows of
-    chunk 128, ``max_seq_len`` 1024."""
+    of 8 KV heads with a window of 128; 2 full layers of 4), ``n`` rows of
+    chunk ``c`` (64 x 128: the row form; 64 x 1 and 4 x 128: a grouped
+    step's two calls), ``max_seq_len`` 1024."""
     from deepspeed_tpu.ops.paged_attention import paged_attention_with_lse
     layers, window = (5, 128) if kvh == 8 else (2, None)
     blocks = layers * 513
@@ -119,9 +120,9 @@ def _paged_typed(kvh):
             q, ak, av, pt, starts, jnp.zeros_like(starts), window=window,
             scale=192 ** -0.5, qcounts=qcounts)
     bf = jnp.bfloat16
-    return fn, (((64, 128, 64, 256), bf), ((blocks, 128, kvh * 256), bf),
-                ((blocks, 128, kvh * 128), bf), ((64, 8), jnp.int32),
-                ((64,), jnp.int32), ((64,), jnp.int32)), 1
+    return fn, (((n, c, 64, 256), bf), ((blocks, 128, kvh * 256), bf),
+                ((blocks, 128, kvh * 128), bf), ((n, 8), jnp.int32),
+                ((n,), jnp.int32), ((n,), jnp.int32)), 1
 
 
 def _dequant(mode):
@@ -172,10 +173,25 @@ CASES = {
     # Mistral's 32 / 8 heads, 64 rows of chunk 128, max_seq_len 4096
     "paged_hist_n64_c128_lse": lambda: _paged(64, 128, with_lse=True,
                                               heads=32, pages=32),
+    # ... the two calls of its grouped split steps (every row as one query;
+    # the chunk group of the 512- and the 1,024-slot rung), the long-prompt
+    # cell's 8-row program, and its decode program's reader
+    "paged_hist_n64_c1_lse": lambda: _paged(64, 1, with_lse=True, heads=32,
+                                            pages=32),
+    "paged_hist_n4_c128_lse": lambda: _paged(4, 128, with_lse=True,
+                                             heads=32, pages=32),
+    "paged_hist_n8_c128_lse": lambda: _paged(8, 128, with_lse=True,
+                                             heads=32, pages=32),
+    "paged_decode_n64_h32": lambda: _paged(64, 1, with_lse=False, heads=32,
+                                           pages=32),
     # ... and over a typed arena (MiMo-V2.5: unequal K / V widths, a
     # window), both layer kinds
     "paged_hist_typed_window_kv8_lse": lambda: _paged_typed(8),
     "paged_hist_typed_full_kv4_lse": lambda: _paged_typed(4),
+    "paged_hist_typed_window_kv8_c1_lse": lambda: _paged_typed(8, 64, 1),
+    "paged_hist_typed_full_kv4_c1_lse": lambda: _paged_typed(4, 64, 1),
+    "paged_hist_typed_window_kv8_n4_lse": lambda: _paged_typed(8, 4, 128),
+    "paged_hist_typed_full_kv4_n4_lse": lambda: _paged_typed(4, 4, 128),
     # ... and at Command A+'s: 16 rows of chunk 128, 16 queries a KV head
     # (a block of 2,048 query rows), a window of 4,096, 86 pages a row
     "paged_hist_n16_c128_q16_window_lse": lambda: _paged(
@@ -198,7 +214,15 @@ CASES = {
 KERNEL_NAMES = {
     "flash_fwd_2k": ("flash_fwd",),
     "flash_fwd_bwd_2k": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+    "paged_decode_n64_h32": ("paged_attn",),
     "paged_hist_n64_c128_lse": ("paged_attn_lse",),
+    "paged_hist_n64_c1_lse": ("paged_attn_lse",),
+    "paged_hist_n4_c128_lse": ("paged_attn_lse",),
+    "paged_hist_n8_c128_lse": ("paged_attn_lse",),
+    "paged_hist_typed_window_kv8_c1_lse": ("paged_attn_lse",),
+    "paged_hist_typed_full_kv4_c1_lse": ("paged_attn_lse",),
+    "paged_hist_typed_window_kv8_n4_lse": ("paged_attn_lse",),
+    "paged_hist_typed_full_kv4_n4_lse": ("paged_attn_lse",),
     "paged_hist_typed_window_kv8_lse": ("paged_attn_lse",),
     "paged_hist_typed_full_kv4_lse": ("paged_attn_lse",),
     "paged_hist_n16_c128_q16_window_lse": ("paged_attn_lse",),
@@ -231,6 +255,43 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     for name in KERNEL_NAMES.get(case, ()):
         assert re.search(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(", text), (
             f"{case}: no custom-call instruction named {name!r}")
+
+
+#: paged case id -> the KV heads a program of its kernel holds
+#: (``paged_attention.heads_per_program`` of the case's shapes): every head
+#: of a row where the block is a decode row's, one where it is a chunk's
+PAGED_HEADS = {
+    "paged_decode_n16": 8, "paged_decode_n64_h32": 8,
+    "paged_hist_n64_c1_lse": 8,
+    "paged_hist_n4_c128_lse": 1, "paged_hist_n64_c128_lse": 1,
+    "paged_hist_typed_window_kv8_c1_lse": 8,
+    "paged_hist_typed_full_kv4_c1_lse": 4,
+    "paged_hist_typed_window_kv8_lse": 1,
+    "paged_hist_n16_c128_q16_window_lse": 1,
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_HEADS))
+def test_paged_kernel_copies_a_page_whole_where_it_holds_every_head(case):
+    """The page copies of the traced kernel (the ``dma_start``s of its
+    jaxpr: shapes only, nothing compiles): where a program holds every KV
+    head of its row the source of each is a page as it lies, ``[page, :,
+    :]`` — no lane slice, one contiguous copy a pool —, and where it holds
+    fewer, the lanes of ITS heads, ``hp`` times a head's width."""
+    fn, shapes, _ = CASES[case]()
+    text = str(jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(s, d) for s, d in shapes)))
+    copies = re.findall(r"dma_start\(\w+\) \w+\[(\w+,:,[^\]]+)\] ->", text)
+    assert len(copies) >= 4, text[:2000]      # first page + prefetch, K + V
+    kvh = shapes[1][0][2] // shapes[0][0][3]
+    hp = PAGED_HEADS[case]
+    if hp == kvh:
+        assert all(c.endswith(",:,:") for c in copies), copies
+        return
+    widths = {int(w) for c in copies
+              for w in re.findall(r":\w+\+(\d+)$", c)}
+    assert widths == {hp * shapes[pool][0][2] // kvh for pool in (1, 2)}, \
+        copies
 
 
 def test_history_kernel_at_chunk_256_and_16_queries_a_kv_head(

@@ -1,8 +1,10 @@
 #!/usr/bin/env python3
 """Microbenchmark of the split step's history kernel ALONE, at the serving
 cells' shapes: ``paged_attn_lse`` (``ops/paged_attention.py``) over a seeded
-arena, page table and batch of rows — ms a call and us a PAGE TURN (one
-page of one KV head of one row walked) by the rows' live queries.
+arena, page table and batch of rows — ms a call, us a PAGE (one page of one
+row walked, all its KV heads) and us a PAGE TURN (a page of ONE KV head: a
+loop turn of the kernel before PR 47, which fetched a page a head) by the
+rows' live queries.
 
     chiprun --chips 1 -- python3 tools/bench_paged_hist.py \
         --parent-file .parent_tree/deepspeed_tpu/ops/paged_attention.py --sweep
@@ -11,7 +13,9 @@ page of one KV head of one row walked) by the rows' live queries.
 <commit> | tar -x -C .parent_tree``), measured beside this tree's in the
 same process, same inputs; a module without ``qcounts`` is called without.
 ``--sweep``: this tree's kernel at other small tiles (``_paged_call(tile_q=)``)
-beside the code's choice. ``--rehearse``: tiny shapes in interpret mode on
+beside the code's choice; with ``--groups`` at other KV heads a program
+(``heads=``: 1 is the walk of before PR 47; one the kernel's VMEM refuses
+is a line with ``error``). ``--rehearse``: tiny shapes in interpret mode on
 the CPU, control flow only — no time it prints is a device's.
 
 Shapes (PERF.md §4): cell 2 ``mistral7b-l12-serve-chat-closed64`` (64 rows
@@ -19,7 +23,10 @@ of chunk 128, 32 / 8 heads of 128, contexts 128–2,500), cell 4
 ``mimo-v2.5-l7-e16-serve-reason-closed64`` (64 query heads, K 192 padded to
 256 lanes beside V 128; a window-128 layer of 8 KV heads, a full layer of
 4; contexts to 768), cell 6 ``command-a-plus-l4-e16-serve-rag-closed16`` (16
-rows, 128 / 8 heads of 128, window 4,096 and none, contexts 2.5K–10K).
+rows, 128 / 8 heads of 128, window 4,096 and none, contexts 2.5K–10K), cell 7
+``nemotron3-nano-l26-e16-serve-chat-closed64``'s three attention layers (32 /
+2 heads of 128: 16 queries a KV head, both heads one program at one query a
+row; cell 8's Granite layer has cell 2's 32 / 8).
 Mixes: ``cell`` = the live queries of the cell's split step (cell 2: 61
 decode rows of ONE live query + 3 rows of 128; cell 4: every row one; cell
 6: 12 of one + 4 of 128), ``one`` = every row one, ``all`` = every row all
@@ -35,7 +42,11 @@ chunk, the rest riding along dead; cells 4 and 5, whose prompts are one
 chunk: two fresh rows, no history) — beside ``rows``, the row form's call
 at the cell's mix; also cell 5
 ``gigachat3.1-l5-e16-serve-reason-long-closed64``'s ``mla_decode`` (64
-heads over a latent pool 640 lanes wide, contexts 128–4,200)."""
+heads over a latent pool 640 lanes wide, contexts 128–4,200); since PR 47
+cell 9 ``mistral7b-l12-serve-longprompt-closed8``'s call (8 rows, each a
+whole live chunk over 0–3,712 tokens of its own history) and the decode
+programs' reader (``paged_attn``, ``[64, 1]`` with the step's own key),
+every kernel of ``--parent-file`` / ``--sweep`` on the same inputs."""
 
 import argparse
 import importlib.util
@@ -68,10 +79,14 @@ SHAPES = {
                      (2560, 10240), 4),
     "cell6_full": (16, 128, 128, 8, 128, 128, 128, 86, 1376, None,
                    (2560, 10240), 4),
+    "cell9": (8, 128, 32, 8, 128, 128, 128, 32, 512, None, (0, 3712), 8),
+    "cell7": (64, 128, 32, 2, 128, 128, 128, 32, 512, None, (128, 2500), 3),
 }
 TINY = {"tiny": (4, 16, 8, 2, 128, 128, 128, 4, 16, None, (8, 60), 1),
         "tiny_window": (4, 16, 8, 2, 128, 128, 128, 4, 16, 24, (8, 60), 1)}
 SWEEP = (1, 2, 4, 8, 16, 32)
+#: ``--groups --sweep``: (label, ``_paged_call`` arguments)
+HEAD_SWEEP = tuple((f"heads{hp}", {"heads": hp}) for hp in (1, 2, 4, 8))
 
 
 def load(path):
@@ -101,13 +116,17 @@ def inputs(shape, mix, seed, block=BS):
             jnp.asarray(live, jnp.int32))
 
 
-def turns(shape, starts, block=BS):
-    """Page turns of one call: every row's visible pages x KV heads."""
-    kvh, window = shape[3], shape[9]
+def pages(shape, starts, live=None, block=BS):
+    """Pages one call walks: the visible pages of every row that holds a
+    live query (``live`` [n], default all)."""
+    window = shape[9]
+    starts = np.asarray(starts)
     last = -(-starts // block)
     first = 0 if window is None else np.maximum(starts - (window - 1),
                                                 0) // block
-    return int((last - first).sum()) * kvh
+    walked = last - first
+    return int(walked.sum() if live is None else
+               walked[np.asarray(live) > 0].sum())
 
 
 def timed(fn, args, reps, rounds):
@@ -123,7 +142,9 @@ def timed(fn, args, reps, rounds):
     return statistics.median(took), out
 
 
-def reader(mod, shape, interpret, tile_q=None):
+def reader(mod, shape, interpret, **knobs):
+    """The history call of ``mod`` at ``shape``; ``knobs``: the sweeps'
+    arguments of ``_paged_call`` (``tile_q``, ``heads``)."""
     window, scale = shape[9], shape[6] ** -0.5
     if "qcounts" not in inspect.signature(
             mod.paged_attention_with_lse).parameters:
@@ -134,7 +155,7 @@ def reader(mod, shape, interpret, tile_q=None):
     return jax.jit(lambda q, ak, av, pt, st, qc: mod._paged_call(
         q, ak, av, pt, st, jnp.zeros_like(st), with_lse=True,
         interpret=interpret, window=window, scale=scale, qcounts=qc,
-        tile_q=tile_q))
+        **knobs))
 
 
 #: the latent cell: (rows, chunk, heads, pool lanes, value lanes, pages a
@@ -163,29 +184,61 @@ def group_calls(q, pt, st, qc, c):
             (f"chunk{len(ids)}", q[ids], pt[ids], g_st, g_qc))
 
 
-def groups_section(a, say, block):
-    heads = {k: v for k, v in (TINY if a.rehearse else SHAPES).items()
-             if not k.startswith("cell6")}
+def agree(out, lse, live, base):
+    """The live queries' out and lse (an empty history's -1e30 clipped) →
+    (that vector, its largest distance from ``base``: the first kernel
+    measured on these inputs)."""
+    got = np.concatenate(
+        [np.asarray(out, np.float32)[live].ravel(),
+         np.maximum(np.asarray(lse), -99.0)[live].ravel()])
+    base = got if base is None else base
+    return base, float(np.abs(got - base).max(initial=0))
+
+
+def groups_section(a, say, shapes, mods, block):
+    heads = {k: v for k, v in shapes.items() if not k.startswith("cell6")}
     for name, shape in heads.items():
         c = shape[1]
         mix = "cell" if shape[11] else "one"
         q, ak, av, pt, st, qc = inputs(shape, mix, a.seed, block)
-        fn = reader(pa_here, shape, a.rehearse)
-        for label, gq, gpt, gst, gqc in group_calls(q, pt, st, qc, c):
-            sec, _ = timed(fn, (gq, ak, av, gpt, gst, gqc), a.reps,
-                           a.rounds)
-            say(shape=name, mix=mix, call=label, rows=list(gq.shape[:2]),
-                ms_a_call=sec * 1e3)
-    for name, (n, c, h, w, vl, mb, pages, (lo, hi), scale) in \
+        calls = group_calls(q, pt, st, qc, c)
+        if shape[11] == shape[0]:       # every row a whole chunk: no groups
+            calls = calls[:1]
+        for label, gq, gpt, gst, gqc in calls:
+            walked = pages(shape, gst, gqc, block)
+            live = np.arange(gq.shape[1])[None] < np.asarray(gqc)[:, None]
+            base = None
+            for kernel, mod, knobs in mods:
+                if knobs.get("heads", 1) > shape[3] or "tile_q" in knobs:
+                    continue
+                try:
+                    sec, (out, lse) = timed(
+                        reader(mod, shape, a.rehearse, **knobs),
+                        (gq, ak, av, gpt, gst, gqc), a.reps, a.rounds)
+                except Exception as e:              # noqa: BLE001
+                    say(shape=name, mix=mix, call=label, kernel=kernel,
+                        error=str(e)[:300])
+                    continue
+                base, diff = agree(out, lse, live, base)
+                say(shape=name, mix=mix, call=label, kernel=kernel,
+                    rows=list(gq.shape[:2]), ms_a_call=sec * 1e3,
+                    pages=walked,
+                    us_a_page=sec * 1e6 / walked if walked else None,
+                    max_diff_live=diff)
+    if not a.rehearse:
+        decode_reader(a, say, mods)
+    if a.shapes:
+        return
+    for name, (n, c, h, w, vl, mb, pages_, (lo, hi), scale) in \
             (TINY_LATENT if a.rehearse else LATENT).items():
         rng = np.random.default_rng(a.seed)
         st = jnp.asarray(rng.integers(lo, min(hi, mb * block - c) + 1, n),
                          jnp.int32)
-        pt = jnp.asarray(np.stack([rng.permutation(pages)[:mb]
+        pt = jnp.asarray(np.stack([rng.permutation(pages_)[:mb]
                                    for _ in range(n)]), jnp.int32)
         kq, kp = jax.random.split(jax.random.PRNGKey(a.seed % (2 ** 31)))
         q = jax.random.normal(kq, (n, c, h, w), jnp.bfloat16)
-        pool = jax.random.normal(kp, (pages + 1, block, w), jnp.bfloat16)
+        pool = jax.random.normal(kp, (pages_ + 1, block, w), jnp.bfloat16)
         fn = jax.jit(lambda q, pool, pt, st, qc: pa_here.mla_decode(
             q, pool, pt, st, jnp.zeros_like(st), qc, v_lanes=vl,
             scale=scale, interpret=a.rehearse))
@@ -196,55 +249,64 @@ def groups_section(a, say, block):
                 ms_a_call=sec * 1e3)
 
 
+def decode_reader(a, say, mods):
+    """The decode programs' reader: the same kernel at c = 1 without the
+    lse, each row over its history AND the step's own key."""
+    shape = SHAPES["cell2"]
+    q, ak, av, pt, st, _ = inputs(shape, "one", a.seed)
+    one = jnp.ones_like(st)
+    walked = int((-(-(np.asarray(st) + 1) // BS)).sum())
+    for label, mod, knobs in mods:
+        if "tile_q" in knobs:
+            continue
+        fn = mod.paged_attention if not knobs else \
+            (lambda *args, knobs=knobs: mod._paged_call(
+                *args, with_lse=False, interpret=False, **knobs)[0])
+        sec, _ = timed(jax.jit(fn), (q[:, :1], ak, av, pt, st, one), a.reps,
+                       a.rounds)
+        say(shape="cell2_decode_c1", kernel=label, ms_a_call=sec * 1e3,
+            pages=walked, us_a_page=sec * 1e6 / walked,
+            page_turns=walked * shape[3],
+            us_a_turn=sec * 1e6 / walked / shape[3])
+
+
 def turns_section(a, say, shapes, mods, block):
     """PR 38's table: the row form's call by the rows' live queries, every
     kernel of ``mods`` on the same inputs, then the decode programs' reader."""
     for name, shape in shapes.items():
         c, h, kvh = shape[1:4]
         for mix in ("cell", "one", "all", "none"):
-            if mix == "cell" and shape[11] == 0:
-                continue                    # the cell's mix IS "one"
+            if mix == "cell" and shape[11] in (0, shape[0]):
+                continue                    # the cell's mix IS "one" / "all"
             args = inputs(shape, mix, a.seed, block)
-            work = 0 if mix == "none" else \
-                turns(shape, np.asarray(args[4]), block)
+            walked = 0 if mix == "none" else \
+                pages(shape, np.asarray(args[4]), block=block)
+            live = np.arange(c)[None] < np.asarray(args[5])[:, None]
             base = None
-            for label, mod, tile_q in mods:
+            for label, mod, knobs in mods:
+                tile_q = knobs.get("tile_q")
                 if tile_q and (c % tile_q or mix in ("all", "none")):
                     continue        # those mixes never take the small tile
+                if "heads" in knobs:
+                    continue                    # --groups' sweep
                 try:
                     sec, (out, lse) = timed(
-                        reader(mod, shape, a.rehearse, tile_q), args,
+                        reader(mod, shape, a.rehearse, **knobs), args,
                         a.reps, a.rounds)
                 except Exception as e:              # noqa: BLE001
                     say(shape=name, mix=mix, kernel=label,
                         error=str(e)[:300])
                     continue
                 tile_q = tile_q or pa_here.tile_queries(c, h // kvh)
-                # the live queries' out and lse (an empty history's -1e30
-                # clipped) agree with the first kernel measured
-                live = np.arange(c)[None] < np.asarray(args[5])[:, None]
-                got = np.concatenate(
-                    [np.asarray(out, np.float32)[live].ravel(),
-                     np.maximum(np.asarray(lse), -99.0)[live].ravel()])
-                base = got if base is None else base
-                diff = float(np.abs(got - base).max(initial=0))
+                base, diff = agree(out, lse, live, base)
                 say(shape=name, mix=mix, kernel=label, ms_a_call=sec * 1e3,
-                    page_turns=work,
-                    us_a_turn=sec * 1e6 / work if work else None,
+                    pages=walked,
+                    us_a_page=sec * 1e6 / walked if walked else None,
+                    page_turns=walked * kvh,
+                    us_a_turn=sec * 1e6 / walked / kvh if walked else None,
                     tile_rows=tile_q * (h // kvh), max_diff_live=diff)
     if not a.rehearse:
-        # the decode programs' reader: the same kernel at c = 1
-        shape = SHAPES["cell2"]
-        q, ak, av, pt, st, _ = inputs(shape, "one", a.seed)
-        one = jnp.ones_like(st)
-        for label, mod, tile_q in mods:
-            if tile_q:
-                continue
-            sec, _ = timed(jax.jit(mod.paged_attention),
-                           (q[:, :1], ak, av, pt, st, one), a.reps, a.rounds)
-            work = int((-(-(np.asarray(st) + 1) // BS)).sum()) * shape[3]
-            say(shape="cell2_decode_c1", kernel=label, ms_a_call=sec * 1e3,
-                page_turns=work, us_a_turn=sec * 1e6 / work)
+        decode_reader(a, say, mods)
 
 
 def main():
@@ -266,11 +328,12 @@ def main():
     if a.shapes:
         shapes = {k: shapes[k] for k in a.shapes.split(",")}
     block = 16 if a.rehearse else BS
-    mods = [("change", pa_here, None)]
+    mods = [("change", pa_here, {})]
     if a.parent_file:
-        mods.insert(0, ("parent", load(a.parent_file), None))
+        mods.insert(0, ("parent", load(a.parent_file), {}))
     if a.sweep:
-        mods += [(f"tile_q{t}", pa_here, t) for t in SWEEP]
+        mods += [(f"tile_q{t}", pa_here, {"tile_q": t}) for t in SWEEP]
+        mods += [(label, pa_here, knobs) for label, knobs in HEAD_SWEEP]
     os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
     lines = []
 
@@ -280,7 +343,7 @@ def main():
         print(json.dumps(line), flush=True)
 
     if a.groups:
-        groups_section(a, say, block)
+        groups_section(a, say, shapes, mods, block)
     else:
         turns_section(a, say, shapes, mods, block)
     with open(a.out, "w") as f:

@@ -35,7 +35,11 @@ off at one seed (PERF.md section 6, PR 39). ``window`` holds what the
 launches of the window alone counted (``dispatch/steps.split``,
 ``split_grouped_steps``, ``chunk_rows``, ``attn_row_slots``, ``tokens``,
 ``token_slots``): the share of split launches that took a grouped instance
-and what attention worked on (PR 40; a tree without a counter reads 0).
+and what attention worked on (PR 40; a tree without a counter reads 0);
+``kv_pages_walked`` / ``kv_page_fetches`` (PR 47): the live pages the paged
+kernel's readers had to read and the page DMAs issued for them — their
+ratio is 2 where every call fetches a page once for all of a row's KV heads,
+16 at 8 KV heads a head at a time.
 ``split_steps`` (PR 46) is the joint histogram of the window's split
 launches by what ``_count_dispatch`` was handed: ``by_slots`` (launches at
 each token capacity taken), ``hist`` (``"<tokens, in bins of 64>x<chunk
@@ -60,7 +64,8 @@ NAMES = ("dispatch/host_seconds", "dispatch/fetch_wait_seconds",
          "dispatch/host_calls", "dispatch/launches_ahead",
          "dispatch/ahead_rows_dropped")
 WORK = ("steps.split", "split_grouped_steps", "chunk_rows",
-        "attn_row_slots", "tokens", "token_slots")
+        "attn_row_slots", "tokens", "token_slots", "kv_pages_walked",
+        "kv_page_fetches")
 #: (slots, chunk rows) of the instances ``split_steps.fits`` asks about
 RUNGS = ((256, 2), (256, 8), (512, 4), (512, 8), (1024, 8))
 
