@@ -8,9 +8,9 @@ heads of 128, FFN 8192, vocabulary 128,256, tied embeddings; random weights
 from ``--seed``):
 
 - **server** — ``RaggedInferenceEngineTPU`` under ``ServingFrontend``: six
-  requests of mixed prompt length streamed to completion, once stepwise
-  (the default frontend) and once with decode megasteps; every generated
-  token is checked against the plain ``models/transformer.py`` forward;
+  requests of mixed prompt length streamed to completion through the
+  default frontend; every generated token is checked against the plain
+  ``models/transformer.py`` forward;
 - **trainer** — ``ds.build_mesh`` → ``ds.initialize`` → ``train_batch`` with
   ``bench.dense_train_config`` at sequence 2048, global batch 8, on one
   repeated batch: losses fall from ≈ ln(vocab), state lives on the chip,
@@ -63,7 +63,6 @@ FIRST_LOSS_ATOL = 0.5
 PROMPT_LENS = (24, 60, 130, 300, 520, 700)
 NEW_TOKENS = (19, 19, 19, 18, 17, 17)
 SERVE_CONFIG = {"dtype": "bfloat16", "max_seq_len": 1024}
-MEGASTEP_TOKENS = 16
 
 
 class SmokeFailure(RuntimeError):
@@ -289,63 +288,50 @@ def server_phase(args, ledger, device) -> None:
           "new_tokens": list(NEW_TOKENS),
           "engine_init_s": round(init_s, 1),
           "engine_init_compile": ledger.delta(c0, ledger.snapshot())})
-    # two servers over ONE engine: the default frontend (stepwise decode —
-    # the step program that selects the Pallas paged kernel) and the
-    # megastep frontend (the fused decode chunk, XLA history on purpose)
-    for label, k in (("stepwise", 0), ("megastep", MEGASTEP_TOKENS)):
-        fe = ServingFrontend(eng, megastep_tokens=k)
-        warm_prompts = draw()
-        w0 = ledger.snapshot()
-        _outs, warm_wall = serve_pass(fe, warm_prompts, f"{label}/warm-up")
-        w1 = ledger.snapshot()
-        ms0 = registry.counter("dispatch/megastep_tokens").value
-        hc0 = registry.counter("dispatch/host_calls").value
-        prompts = draw()            # same lengths → same schedule, no
-        outs, wall = serve_pass(fe, prompts, label)     # prefix-cache hits
-        w2 = ledger.snapshot()
-        fused = int(registry.counter("dispatch/megastep_tokens").value - ms0)
-        total = sum(len(o) for o in outs)
-        after = ledger.delta(w1, w2)
-        # the reference runs outside the measured window (it compiles)
-        flat = reference_gaps(model, eng.params, prompts, outs)
-        exact = float((flat == 0.0).mean())
-        emit({
-            "phase": f"server/{label}",
-            "requests": len(outs), "tokens": total,
-            "decode_tokens_fused_chunk": fused,
-            "decode_tokens_stepwise": total - fused,
-            "host_calls": int(
-                registry.counter("dispatch/host_calls").value - hc0),
-            "warmup_wall_s": round(warm_wall, 3),
-            "warmup_compile": ledger.delta(w0, w1),
-            "wall_s": round(wall, 3),
-            "compiles_after_warmup": after["compiles"],
-            "traces_after_warmup": after["traces"],
-            "exact_argmax_share": round(exact, 4),
-            "max_gap_below_ref_argmax": round(float(flat.max()), 5),
-            "mean_gap_of_non_argmax": round(float(
-                flat[flat > 0].mean()) if (flat > 0).any() else 0.0, 5),
-            "near_tie_tolerance": NEAR_TIE_LOGITS,
-            "first_tokens": [o[0] for o in outs]})
-        check(after["compiles"] == 0 and after["traces"] == 0,
-              f"server/{label}: {after['compiles']} compile(s), "
-              f"{after['traces']} trace(s) after warm-up")
-        check(bool(np.isfinite(flat).all()),
-              f"server/{label}: non-finite reference logits")
-        check(float(flat.max()) <= NEAR_TIE_LOGITS,
-              f"server/{label}: a generated token is {flat.max():.4f} "
-              f"logits below the reference argmax (tolerance "
-              f"{NEAR_TIE_LOGITS})")
-        check(exact >= MIN_EXACT_ARGMAX,
-              f"server/{label}: only {exact:.3f} of generated tokens are "
-              f"the reference argmax (need {MIN_EXACT_ARGMAX})")
-        if k:
-            check(fused > 0, "server/megastep: no token went through the "
-                             "fused decode chunk")
-        else:
-            check(fused == 0, "server/stepwise: tokens went through the "
-                              "fused chunk with megasteps off")
-        fe.close()
+    # the default frontend: the decode step program selects the Pallas
+    # paged kernel, the pump launches one step ahead of its fetch
+    fe = ServingFrontend(eng)
+    warm_prompts = draw()
+    w0 = ledger.snapshot()
+    _outs, warm_wall = serve_pass(fe, warm_prompts, "serve/warm-up")
+    w1 = ledger.snapshot()
+    hc0 = registry.counter("dispatch/host_calls").value
+    prompts = draw()                # same lengths → same schedule, no
+    outs, wall = serve_pass(fe, prompts, "serve")       # prefix-cache hits
+    w2 = ledger.snapshot()
+    after = ledger.delta(w1, w2)
+    # the reference runs outside the measured window (it compiles)
+    flat = reference_gaps(model, eng.params, prompts, outs)
+    exact = float((flat == 0.0).mean())
+    emit({
+        "phase": "server/serve",
+        "requests": len(outs), "tokens": sum(len(o) for o in outs),
+        "host_calls": int(
+            registry.counter("dispatch/host_calls").value - hc0),
+        "warmup_wall_s": round(warm_wall, 3),
+        "warmup_compile": ledger.delta(w0, w1),
+        "wall_s": round(wall, 3),
+        "compiles_after_warmup": after["compiles"],
+        "traces_after_warmup": after["traces"],
+        "exact_argmax_share": round(exact, 4),
+        "max_gap_below_ref_argmax": round(float(flat.max()), 5),
+        "mean_gap_of_non_argmax": round(float(
+            flat[flat > 0].mean()) if (flat > 0).any() else 0.0, 5),
+        "near_tie_tolerance": NEAR_TIE_LOGITS,
+        "first_tokens": [o[0] for o in outs]})
+    check(after["compiles"] == 0 and after["traces"] == 0,
+          f"server/serve: {after['compiles']} compile(s), "
+          f"{after['traces']} trace(s) after warm-up")
+    check(bool(np.isfinite(flat).all()),
+          "server/serve: non-finite reference logits")
+    check(float(flat.max()) <= NEAR_TIE_LOGITS,
+          f"server/serve: a generated token is {flat.max():.4f} "
+          f"logits below the reference argmax (tolerance "
+          f"{NEAR_TIE_LOGITS})")
+    check(exact >= MIN_EXACT_ARGMAX,
+          f"server/serve: only {exact:.3f} of generated tokens are "
+          f"the reference argmax (need {MIN_EXACT_ARGMAX})")
+    fe.close()
 
     # what each compiled program carries (re-lowered from the live arrays:
     # same module, so with the persistent cache on this is a cache hit)
@@ -365,18 +351,6 @@ def server_phase(args, ledger, device) -> None:
         want = "paged_attn" if kind in ("decode", "False") else "flash_fwd"
         if want not in found["named"]:
             missing.append(f"step n={nb} c={cb} {kind}: no {want}")
-    # the key's fourth element is the width of the page table the call
-    # passed (one compiled program a width, PR 28)
-    for (nb, sb, mode, pw), fn in sorted(eng._fused_fns.items(), key=str):
-        i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32)
-        f32 = jax.ShapeDtypeStruct((), np.float32)
-        text = fn.lower(eng.params, eng.arena, i32(nb), i32(nb), i32(nb),
-                        i32(nb, pw), i32(), i32(nb), i32(nb), f32, f32,
-                        eng._rng_dev).compile().as_text()
-        # reported, not asserted: the fused chunk reads history through
-        # the XLA gather on purpose (engine_v2._fused_decode_fn)
-        programs[f"fused n={nb} steps={sb} pages={pw}"] = kernels_in(
-            text, FLASH_KERNELS + PAGED_KERNELS)
     emit({"phase": "server/programs", "programs": programs,
           "compile": ledger.delta(c0, ledger.snapshot()),
           "hbm": hbm([device]),
@@ -384,7 +358,6 @@ def server_phase(args, ledger, device) -> None:
     check({"fresh", "split", "decode"} <= kinds,
           f"server: expected a fresh-prefill, a continuation and a decode "
           f"step program, compiled {sorted(kinds)}")
-    check(bool(eng._fused_fns), "server: no fused decode program compiled")
     if on_tpu:
         check(not missing,
               f"server: a reference path took a kernel's place: {missing}")

@@ -2,7 +2,7 @@
 cost records and a declared traffic mix.
 
 The hand-picked knobs this replaces — router replica counts,
-prefill/decode pool splits, autoscale floors/ceilings, megastep K,
+prefill/decode pool splits, autoscale floors/ceilings,
 SplitFuse token budgets, hedge delays — all derive from two numbers the
 cost model already predicts: the prefill bucket-step time and the decode
 step time (``engine_v2.cost_records()`` when an engine exists,
@@ -32,7 +32,6 @@ class TrafficMix:
     prompt_tokens: int = 512        #: mean prompt length
     gen_tokens: int = 128           #: mean generated tokens
     swing: float = 4.0              #: peak/trough demand ratio
-    ttft_target_s: float = 0.5      #: TTFT objective (p95)
     utilization: float = 0.6        #: target busy fraction per replica
     headroom: float = 1.25          #: ceiling margin over peak demand
 
@@ -98,8 +97,6 @@ def plan_serving(records: Dict[str, Any], mix: Optional[TrafficMix] = None,
     - floors from the diurnal trough (peak/swing), ceilings at
       ``headroom`` over peak demand;
     - ``queue_high`` at the utilization knee of the decode bucket;
-    - megastep K: the largest decode window that stays within ¼ of the
-      TTFT budget (admission only happens on window boundaries);
     - SplitFuse budget: prefill tokens per mixed step capped so a mixed
       step costs ≲ 2 decode steps (decode-latency protection);
     - hedge delay: 2× the predicted no-queue TTFT (a hedge below the
@@ -129,11 +126,6 @@ def plan_serving(records: Dict[str, Any], mix: Optional[TrafficMix] = None,
     dec_max = max(dec_peak, math.ceil(dec_peak * mix.headroom), dec_min)
     pre_max = max(pre_peak, math.ceil(pre_peak * mix.headroom), pre_min)
 
-    # megastep: admission/shed points land on window boundaries, so the
-    # window must fit well inside the TTFT budget
-    k = int(0.25 * mix.ttft_target_s / t_dec)
-    megastep = min(32, k) if k >= 2 else 0
-
     # SplitFuse: prefill-token budget per mixed step — a mixed step may
     # cost at most ~2 decode steps extra
     tau = t_pre / (nb * chunk)                        # s per prefill token
@@ -142,7 +134,7 @@ def plan_serving(records: Dict[str, Any], mix: Optional[TrafficMix] = None,
     ttft_best = math.ceil(mix.prompt_tokens / chunk) * t_pre + t_dec
     hedge_delay = max(0.05, round(2.0 * ttft_best, 3))
 
-    serving_block = {"megastep_tokens": megastep, "megastep_adaptive": True}
+    serving_block: Dict[str, Any] = {}      # ServingConfig has no key
     router_block = {
         "replicas": pre_peak + dec_peak,
         "affinity_tokens": max(8, min(64, mix.prompt_tokens // 2)),

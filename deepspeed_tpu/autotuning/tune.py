@@ -153,7 +153,6 @@ class TuneReport:
                     f"{pred['decode_step_ms']:.2f} ms/step → replicas "
                     f"prefill {a['prefill_min']}..{a['prefill_max']}, "
                     f"decode {a['decode_min']}..{a['decode_max']}, "
-                    f"megastep {p['serving']['megastep_tokens']}, "
                     f"splitfuse {p['engine']['max_batch_tokens']} tok, "
                     f"hedge {p['router']['hedge_delay_s']}s")
         return "\n".join(out)
@@ -396,8 +395,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--gen-tokens", type=int, default=128)
     ap.add_argument("--swing", type=float, default=4.0,
                     help="diurnal peak/trough demand ratio")
-    ap.add_argument("--ttft", type=float, default=0.5,
-                    help="TTFT p95 target, seconds")
     ap.add_argument("--zero-stages", default=None,
                     help="comma list overriding the ZeRO stages swept")
     ap.add_argument("--smoke", action="store_true",
@@ -420,8 +417,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             base = json.load(fh)
     traffic = TrafficMix(rps_peak=args.rps,
                          prompt_tokens=args.prompt_tokens,
-                         gen_tokens=args.gen_tokens, swing=args.swing,
-                         ttft_target_s=args.ttft)
+                         gen_tokens=args.gen_tokens, swing=args.swing)
     report = run_tune(model, chips=args.chips, platform=args.platform,
                       seq_len=args.seq, space=space, traffic=traffic,
                       include_serving=not args.no_serving,

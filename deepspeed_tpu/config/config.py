@@ -553,22 +553,9 @@ class SLOConfig(TPUConfigModel):
 
 
 class ServingConfig(TPUConfigModel):
-    """``"serving"`` block → deepspeed_tpu/serving (ServingFrontend).
-
-    Decode megasteps: when the SplitFuse selection is decode-only, the
-    frontend may run up to ``megastep_tokens`` single-token iterations in
-    ONE jitted device program (engine_v2 ``_try_megastep``) — the host
-    syncs once per window instead of 2+ round-trips per token. Megastep
-    boundaries are the admission/shed/cancel points, so bigger windows
-    trade TTFT responsiveness for dispatch amortization (docs/serving.md
-    "Decode megasteps")."""
-    #: max decode tokens per device-resident window (0/1 = stepwise;
-    #: ServingFrontend(megastep_tokens=...) overrides)
-    megastep_tokens: int = Field(default=0, ge=0)
-    #: shrink the window dynamically: pending admissions cap it at the
-    #: shallowest remaining budget, a shallow decode backlog and tight
-    #: deadlines (roofline-predicted decode step time) pull it toward 1
-    megastep_adaptive: bool = True
+    """``"serving"`` block → deepspeed_tpu/serving (ServingFrontend). It
+    has no key of its own any more: one that a configuration still carries
+    is warned about by name and the configuration is served."""
 
 
 class RouterConfig(TPUConfigModel):

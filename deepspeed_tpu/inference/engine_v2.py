@@ -394,8 +394,7 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     reads the arena. "split" → history attends the PRE-write arena and
     the within-chunk causal part is merged by logsumexp. Both variants
     remove the per-layer write→read dependency on the ~GB arena, which
-    XLA otherwise serializes (measured 395 → ~200 ms on a 16x512
-    prefill step, v5e 1.27B).
+    XLA otherwise serializes.
 
     ``token_capacities`` (STATIC, ascending, each under ``n * c``; the
     caller promises ``counts.sum()`` never exceeds the last): a step that
@@ -1213,12 +1212,10 @@ class RaggedInferenceEngineTPU:
         #: ONE packed int32 vector (tokens|counts|starts|page_table): one
         #: host→device upload per step instead of four small ones
         self._step_fns: Dict[Any, Any] = {}
-        #: fused decode-loop jit cache keyed on (n_bucket, steps, mode)
-        self._fused_fns: Dict[Any, Any] = {}
         #: jit for prefix-cache copy-on-write page duplication
         self._copy_pages_fn = None
-        #: kind of the last device program launched (``_step_kind`` or
-        #: ``megastep``): the ``program`` of ``serving/engine_step``
+        #: kind of the last device program launched (``_step_kind``): the
+        #: ``program`` of ``serving/engine_step``
         self.last_program: Optional[str] = None
         #: when the last launch's ``device_get`` returned (``perf_counter``);
         #: None once the scheduler has had nothing to run, so that an idle
@@ -1383,40 +1380,15 @@ class RaggedInferenceEngineTPU:
         return self.step_with_budget(mode=None)
 
     def step_with_budget(self, budget: Optional[int] = None,
-                         mode=("argmax",), max_steps: int = 1,
-                         row_limits: Optional[Dict[int, int]] = None,
-                         eos_ids: Optional[Dict[int, int]] = None
-                         ) -> Optional[Dict[int, Any]]:
+                         mode=("argmax",)) -> Optional[Dict[int, Any]]:
         """One engine step packing at most ``budget`` tokens (None → the
-        scheduler's max_batch_tokens; a batch over max_batch_tokens is
-        refused where its step program packs tokens, :meth:`_run`). THE
-        step entry: the serving frontend's, and what :meth:`put`,
-        :meth:`step` and :meth:`generate` loop over. The policy installed
-        on ``self.scheduler`` decides the prefill/decode mix, this just
-        runs whatever it packed. Returns
-        {uid: next_token_id} (or {uid: logits} with mode=None) for rows
-        whose pending tokens were exhausted; None when idle.
-
-        ``max_steps > 1`` arms the decode MEGASTEP: when the scheduler's
-        selection comes back decode-only, up to ``max_steps`` single-token
-        iterations run in ONE device program (the host syncs once per K
-        tokens instead of once per token) and the return value becomes
-        ``{uid: [token, ...]}`` — a list per row, 1..K tokens, every one
-        of them already backed by KV in the arena except the last (which
-        the caller feeds back, exactly like the single-token contract).
-        ``row_limits`` caps the tokens a row may emit (its remaining
-        max_new_tokens budget); ``eos_ids`` maps uid → eos token id so a
-        row retires mid-megastep without burning its tail. Mixed
-        prefill/decode selections, ``mode=None`` (logits), and
-        ``max_steps == 1`` all take the unchanged stepwise path (with
-        lists still returned when ``max_steps > 1`` was requested, so
-        callers see ONE shape).
-
-        A :meth:`launch` and its :meth:`collect` back to back: this entry
-        waits for the program it launched, and continues no row (the caller
-        feeds the tokens back). A launch still in flight is the caller's to
-        collect first.
-        """
+        scheduler's max_batch_tokens), as a :meth:`launch` and its
+        :meth:`collect` back to back: it waits for the program it launched
+        and continues no row. {uid: next_token_id} ({uid: logits} with
+        mode=None) for the rows whose pending tokens were exhausted, which
+        the caller feeds back; None when idle. What :meth:`put` and
+        :meth:`step` loop over; a launch still in flight is the caller's to
+        collect first."""
         if self._launched:
             raise RuntimeError(
                 "step_with_budget waits for its own program: collect() the "
@@ -1424,17 +1396,8 @@ class RaggedInferenceEngineTPU:
         batch = self._schedule(budget)
         if batch is None:
             return None
-        megastep = max_steps > 1 and mode is not None
-        if megastep:
-            out = self._try_megastep(batch, max_steps, mode, row_limits,
-                                     eos_ids)
-            if out is not None:
-                return out
         self._launch(batch, mode)
-        out, _ = self.collect()
-        if megastep:
-            out = {uid: [tok] for uid, tok in out.items()}
-        return out
+        return self.collect()[0]
 
     # -- the step as a launch and a collect ---------------------------------
 
@@ -1548,137 +1511,6 @@ class RaggedInferenceEngineTPU:
         """Forget every launch in flight without waiting for it (after a
         fault: the caller flushes the rows it had continued)."""
         self._launched.clear()
-
-    def _try_megastep(self, batch: RaggedBatch, k: int, mode,
-                      row_limits: Optional[Dict[int, int]],
-                      eos_ids: Optional[Dict[int, int]]
-                      ) -> Optional[Dict[int, List[int]]]:
-        """Run ``batch`` as one fused decode window of up to ``k`` tokens
-        per row; None → not applicable (caller falls through to the
-        stepwise path with the batch ALREADY selected — selecting twice
-        would double-advance the SplitFuse round-robin).
-
-        Applicable iff the selection is pure decode: every row is a
-        single-token chunk covering its whole pending queue (the caller
-        extended the descriptor by the fed token before scheduling), the
-        window has at least two steps, and the arena has its pages.
-        """
-        tracer = self._tracer
-        # the whole way to the launch is serving/pack: whether the window
-        # applies, its pages, the arrays and their upload
-        with tracer.span("serving/pack"):
-            n = len(batch.uids)
-            if n == 0 or batch.token_ids.shape[1] != 1 or \
-                    self.model_config.typed:
-                # (a typed layer stack has no fused decode loop yet: its
-                # decode-only selections take the stepwise program; a
-                # recurrent stack's loop would have to carry its state)
-                return None
-            for i, uid in enumerate(batch.uids):
-                if int(batch.token_counts[i]) != 1 or \
-                        self.state.seqs[uid].pending != 1:
-                    return None
-            # per-row window: requested k, clipped by the row's remaining
-            # token budget and by max_seq_len headroom (len(tokens) already
-            # counts the fed token, and a continuing row feeds one more)
-            lim: List[int] = []
-            for uid in batch.uids:
-                seq = self.state.seqs[uid]
-                r = k
-                if row_limits is not None and uid in row_limits:
-                    r = min(r, int(row_limits[uid]))
-                r = min(r, self.config.max_seq_len - len(seq.tokens))
-                if r < 1:
-                    return None
-                lim.append(r)
-            limit = max(lim)
-            if limit < 2:
-                return None          # degenerate megastep — stepwise wins
-            bs = self.state.allocator.block_size
-            need: List[int] = []
-            for uid, r in zip(batch.uids, lim):
-                seq = self.state.seqs[uid]
-                # KV high-water mark: seen_tokens rows exist, the window adds
-                # up to r more (fed token + r-1 continuation feeds)
-                need.append(-(-(seq.seen_tokens + r) // bs) - len(seq.blocks))
-            if sum(need) > self.state.allocator.free_blocks:
-                return None
-            for uid, c in zip(batch.uids, need):
-                if c > 0:
-                    self.state.seqs[uid].blocks.extend(
-                        self.state.allocator.allocate(c))
-
-            nb = _bucket(n)
-            # pow2 scan buckets: the rng splits once per scan slot incl. dead
-            # ones, so aligned pow2 windows keep sampled streams identical
-            # across K choices
-            sb = _bucket(limit)
-            tokens0 = np.zeros((nb,), np.int32)
-            starts0 = np.zeros((nb,), np.int32)
-            live = np.zeros((nb,), np.int32)
-            bud = np.zeros((nb,), np.int32)
-            eos = np.full((nb,), -1, np.int32)
-            for i, uid in enumerate(batch.uids):
-                seq = self.state.seqs[uid]
-                tokens0[i] = seq.tokens[-1]
-                starts0[i] = seq.seen_tokens
-                live[i] = 1
-                bud[i] = lim[i]
-                if eos_ids is not None and eos_ids.get(uid) is not None:
-                    eos[i] = int(eos_ids[uid])
-            pt = self._page_table(batch.uids, nb)
-            mb_need = int(-(-(int(starts0.max()) + limit) // bs))
-            mb_b = min(self.mb, -(-mb_need // 4) * 4)
-            pt = pt[:, :mb_b]
-            args = (jnp.asarray(tokens0), jnp.asarray(starts0),
-                    jnp.asarray(live), jnp.asarray(pt), jnp.int32(limit),
-                    jnp.asarray(bud), jnp.asarray(eos),
-                    jnp.float32(self._temperature),
-                    jnp.float32(self._top_p))
-        with tracer.span("serving/dispatch", program="megastep",
-                         k=int(limit)) as sp:
-            ys, counts, self._rng_dev, self.arena = self._fused_decode_fn(
-                nb, sb, mode, mb_b)(
-                    self.params, self.arena, *args, self._rng_dev)
-        ys, counts = self._fetch((ys, counts))          # ONE sync for K
-        with tracer.span("serving/count"):
-            ys = np.asarray(ys)
-            counts = np.asarray(counts)
-            # what the launch did is known only now: a row emitted
-            # counts[j] tokens, its i-th one attending starts0[j] + i + 1
-            # cached tokens; the program ran sb scan steps over nb rows,
-            # each attending the whole sliced page table
-            c64 = counts[:n].astype(np.int64)
-            work = self._count_dispatch(
-                "megastep", n, nb, 1, mb_b, int(c64.sum()),
-                int((c64 * starts0[:n] + c64 * (c64 + 1) // 2).sum()),
-                scan_steps=sb)
-            if sp is not None:      # still the recorded event's arguments
-                sp.update(work)
-            _dispatch_count("dispatch/scan_steps", sb)
-            _dispatch_count("dispatch/dead_steps", sb - limit)
-            _dispatch_count("dispatch/megastep_launches")
-        with tracer.span("serving/retire"):
-            self.scheduler.mark_scheduled(batch)      # fed token consumed
-            out: Dict[int, List[int]] = {}
-            emitted_total = 0
-            for j, uid in enumerate(batch.uids):
-                c = int(counts[j])
-                emitted = [int(t) for t in ys[:c, j]]
-                emitted_total += c
-                seq = self.state.seqs[uid]
-                if c > 1:
-                    # every emitted token except the LAST has its KV in
-                    # the arena already; record them on the descriptor so
-                    # seen == len(tokens) == KV rows. The last token
-                    # follows the single-token contract: the caller decides
-                    # whether to feed it back (state.extend) or retire the
-                    # row.
-                    seq.tokens.extend(emitted[:-1])
-                    seq.seen_tokens = len(seq.tokens)
-                out[uid] = emitted
-            _dispatch_count("dispatch/megastep_tokens", emitted_total)
-        return out
 
     def _fetch(self, out):
         """``jax.device_get(out)`` under ``serving/fetch``: the pump's one
@@ -2065,7 +1897,6 @@ class RaggedInferenceEngineTPU:
 
     def _count_dispatch(self, program: str, rows: int, nb: int, chunk: int,
                         page_width: int, tokens: int, context_tokens: int,
-                        scan_steps: int = 1,
                         context_slots: Optional[int] = None,
                         kv_window=None, attn_pairs=None, query_tiles=None,
                         token_slots: Optional[int] = None,
@@ -2096,7 +1927,7 @@ class RaggedInferenceEngineTPU:
         ``context_slots`` = what the attention
         reads: bucketed rows x the page table's width in tokens unless the
         caller knows better, as for a split step whose history goes through
-        the paged kernel; all times the scan steps of a megastep).
+        the paged kernel).
         Always-on ``dispatch/*`` counters; the same numbers are the
         ``serving/dispatch`` span's arguments. ``kv_window`` (a model with
         window layers only: :meth:`_kv_window_tokens`) adds
@@ -2126,7 +1957,7 @@ class RaggedInferenceEngineTPU:
         routers hand the experts' dispatch (all of them, held here or
         not)."""
         from deepspeed_tpu.telemetry.registry import registry
-        row_slots = nb * chunk * scan_steps
+        row_slots = nb * chunk
         slots = row_slots if token_slots is None else token_slots
         if kv_write_slots is None:
             kv_write_slots = row_slots
@@ -2134,8 +1965,7 @@ class RaggedInferenceEngineTPU:
             registry.counter("dispatch/split_grouped_steps").inc()
             row_slots = attn_row_slots
         if context_slots is None:
-            context_slots = nb * page_width * self.config.block_size * \
-                scan_steps
+            context_slots = nb * page_width * self.config.block_size
         self.last_program = program
         counted = [("host_calls", 1), ("tokens", tokens),
                    ("token_slots", slots),
@@ -2180,192 +2010,6 @@ class RaggedInferenceEngineTPU:
                 work["moe_assignments"])
         return work
 
-    # -- fused decode loop (the megastep's program) ------------------------
-
-    #: the decode window :meth:`generate` asks of a step: a power of two,
-    #: so whole windows are whole scans and only a call's last window has
-    #: dead iterations (every row dead: KV to trash, outputs discarded)
-    _FUSED_STEP_BUCKET = 32
-
-    def _fused_decode_fn(self, nb: int, sb: int, mode, pw: int):
-        """jit: up to `sb` single-token decode iterations in ONE device
-        program — the per-token host round-trips of the stepwise loop
-        (2+ per token) collapse to one upload + one [sb, nb] fetch.
-
-        The arena stays OUT of the scan carry: new KV lands in a small
-        per-loop decode buffer ([L, sb, nb, kvh, dh] — a few MB) and each
-        step's attention = merge(history over the READ-ONLY arena,
-        causal attention over the buffer so far) by logsumexp. The
-        buffer is written back into the arena pages in one pass after
-        the loop. Carrying the arena instead forces XLA to copy it every
-        iteration (two ~33MB copies per layer-step profiled on v5e), and
-        a read-only arena also lets the Pallas paged kernel serve the
-        history part — it walks only each sequence's true pages, where
-        the XLA gather path fetches the padded page-table width.
-
-        Per-row dead-masking (all traced, no recompiles): a row goes
-        dead past the scalar `limit`, past its own `budgets[row]`
-        sampled tokens, or one step after sampling `eos_ids[row]`
-        (-1 = no eos). Dead rows stop counting and their buffer slots
-        are clipped by the per-row write-back counts, so finished rows
-        never write KV past their true end — the returned ``counts``
-        is exactly how many sampled tokens per row are valid AND how
-        many KV entries landed in the arena. Dead iterations still
-        split the sampling rng once per scan step, so a K-token window
-        produces the same sample stream whether it runs as one program
-        or several (megastep chunking invariance).
-
-        ``pw`` is the width of the (sliced) page table the call passes:
-        a shape, so part of the jit-cache key and of the program's name.
-
-        Returns ``(ys [sb, nb], counts [nb], rng, arena)``."""
-        key = (nb, sb, mode, pw)
-        if key in self._fused_fns:
-            return self._fused_fns[key]
-        from deepspeed_tpu.telemetry import compile_monitor
-        compile_monitor.count_trace(
-            "serving/fused_decode_fn",
-            detail={"n_bucket": nb, "steps": sb, "mode": str(mode),
-                    "page_width": pw})
-        model = self.model_config
-        from deepspeed_tpu.ops.paged_attention import _masked_attention
-
-        num_layers = model.num_layers
-        kvh, dh = model.kv_heads, model.head_dim
-
-        def fn(params, arena, tokens0, starts0, live, pt, limit, budgets,
-               eos_ids, temp, top_p, rng):
-            stride = arena["k"].shape[0] // num_layers
-            ak_c, av_c = arena["k"], arena["v"]       # read-only in loop
-            kbuf0 = jnp.zeros((num_layers, sb, nb, kvh, dh), self.dtype)
-            vbuf0 = jnp.zeros_like(kbuf0)
-            alive0 = live.astype(bool)
-            counts0 = jnp.zeros((nb,), jnp.int32)
-
-            def step(carry, i):
-                tokens, rng, kbuf, vbuf, alive, counts = carry
-                # a row alive at step i was alive at every step before
-                # it, so counts == i for alive rows and starts0 + i is
-                # its true position; dead rows produce garbage the
-                # write-back clips (counts) and the host slices away
-                step_live = alive & (i < limit)
-                positions = (starts0 + i)[:, None]            # [nb, 1]
-                x = embed_tokens(
-                    model, params["embed"], tokens[:, None],
-                    jnp.minimum(positions,
-                                params["embed"]["pos"].shape[0] - 1)
-                    if model.pos_emb == "learned" else positions,
-                    params.get("embed_norm"))
-                if model.pos_emb == "rope":
-                    sin, cos = rope_table(model, positions)
-                else:
-                    sin = cos = jnp.zeros((nb, 1, 0), x.dtype)
-
-                jdx = jnp.arange(sb, dtype=jnp.int32)
-                dec_mask = (jdx[None, :] <= i)[None, None, None]
-
-                def layer_body(carry_l, layer):
-                    xl, kbuf, vbuf = carry_l
-                    lp, l_idx = layer
-                    pt_l = pt + l_idx * stride
-                    h_in = _norm(model, lp["ln1"], xl)
-                    q, k, v = qkv_project(model, lp["attn"], h_in, sin,
-                                          cos)
-                    # history: keys [0, starts0) straight from the
-                    # arena, through the XLA gather-attend: the Pallas
-                    # kernel's (seq, head) grid is launch-overhead-bound
-                    # at decode widths (268 vs 70 us/layer-step profiled
-                    # at n=16 on v5e)
-                    with jax.named_scope("attn_history"):
-                        out_h, lse_h = pa.paged_attention_hist_xla(
-                            q, ak_c, av_c, pt_l, starts0)
-                    # decode window: this loop's own tokens (incl. self)
-                    with jax.named_scope("kv_write"):
-                        kbuf = lax.dynamic_update_slice(
-                            kbuf, k[:, 0][None, None].astype(kbuf.dtype),
-                            (l_idx, i, 0, 0, 0))
-                        vbuf = lax.dynamic_update_slice(
-                            vbuf, v[:, 0][None, None].astype(vbuf.dtype),
-                            (l_idx, i, 0, 0, 0))
-                    kd = lax.dynamic_index_in_dim(
-                        kbuf, l_idx, 0, keepdims=False)       # [sb,nb,..]
-                    vd = lax.dynamic_index_in_dim(vbuf, l_idx, 0,
-                                                  keepdims=False)
-                    with jax.named_scope("attn_core"):
-                        out_d, lse_d = _masked_attention(
-                            q, kd.transpose(1, 0, 2, 3),
-                            vd.transpose(1, 0, 2, 3), dec_mask, True)
-                    with jax.named_scope("attn_merge"):
-                        out = pa.merge_attention(out_h, lse_h, out_d,
-                                                 lse_d).astype(q.dtype)
-                    attn_out = attn_out_project(model, lp["attn"], out)
-                    h_out, _aux = block_combine(model, lp, xl, h_in,
-                                                attn_out, self._moe_fn)
-                    return (h_out, kbuf, vbuf), None
-
-                # UNROLLED layer loop: under lax.scan every layer's
-                # (packed) weights are dynamic-sliced out of the stacked
-                # params into fresh buffers each step — pure copy traffic
-                # that roughly doubles the weight-bound decode cost.
-                # Unrolling lets XLA feed the kernels from the stacked
-                # arrays directly; compile time stays modest because the
-                # decode graph is small.
-                carry_l = (x, kbuf, vbuf)
-                for l in range(num_layers):
-                    lp = jax.tree.map(lambda a: a[l], params["layers"])
-                    carry_l, _ = layer_body(carry_l, (lp, jnp.int32(l)))
-                x, kbuf, vbuf = carry_l
-                x = _norm(model, params["final_norm"], x)
-                logits = lm_logits(model, params, x)[:, 0]
-                nxt, rng = _sample_tokens(logits, mode, temp, top_p, rng)
-                # rows alive this step emit `nxt`; a row retires AFTER
-                # emitting its eos / last-budget token, so counts ends at
-                # exactly the number of valid tokens == KV rows written
-                # (the eos token itself never writes KV — its KV slot
-                # would belong to the NEXT step's fed token)
-                counts = counts + step_live.astype(jnp.int32)
-                alive = step_live & (nxt != eos_ids) & (counts < budgets)
-                return (nxt, rng, kbuf, vbuf, alive, counts), nxt
-
-            (_, rng, kbuf, vbuf, _, counts), ys = lax.scan(
-                step, (tokens0, rng, kbuf0, vbuf0, alive0, counts0),
-                jnp.arange(sb, dtype=jnp.int32))
-
-            # one write-back pass: buffer rows [0, counts[r]) per row
-            counts_wb = counts
-
-            def wb(carry, inp):
-                ak, av = carry
-                kb, vb, l_idx = inp                  # kb [sb, nb, kvh, dh]
-                pt_l = pt + l_idx * stride
-                with jax.named_scope("kv_write"):
-                    ak, av = pa.write_kv(
-                        ak, av, kb.transpose(1, 0, 2, 3),
-                        vb.transpose(1, 0, 2, 3), pt_l,
-                        *pa.row_slots(starts0, counts_wb, sb),
-                        trash_block=l_idx * stride + stride - 1)
-                return (ak, av), None
-
-            (ak, av), _ = lax.scan(
-                wb, (arena["k"], arena["v"]),
-                (kbuf, vbuf, jnp.arange(num_layers, dtype=jnp.int32)))
-            return ys, counts, rng, {**arena, "k": ak, "v": av}
-
-        # ``serve_megastep_r<rows>_k<scan steps>_p<page-table width>``,
-        # registered for the scope table
-        name = f"serve_megastep_r{nb}_k{sb}_p{pw}" + _mode_suffix(mode)
-        fn.__name__ = fn.__qualname__ = name
-        jitted = jax.jit(fn, donate_argnums=(1,))
-        rows = jax.ShapeDtypeStruct((nb,), jnp.int32)
-        compile_monitor.register_program(name, jitted, (
-            self.params, self.arena, rows, rows, rows,
-            jax.ShapeDtypeStruct((nb, pw), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.int32), rows, rows,
-            jax.ShapeDtypeStruct((), jnp.float32),
-            jax.ShapeDtypeStruct((), jnp.float32), self._rng_dev))
-        self._fused_fns[key] = jitted
-        return jitted
-
     # -- convenience generation loop ---------------------------------------
 
     def _validate_lengths(self, prompts, budget_list) -> None:
@@ -2387,24 +2031,23 @@ class RaggedInferenceEngineTPU:
         """Continuous-batching generation (greedy by default; temperature/
         top-k/top-p sampled on device). ``prompts`` is a list of 1-D int
         arrays (ragged lengths); ``max_new_tokens`` may be per-sequence.
-        Returns the full token sequences. A client of
-        :meth:`step_with_budget`, as the serving frontend is, in ROUNDS:
-        step until the scheduler has nothing queued, then feed every
-        row's last token back at once. The call's rows therefore finish
-        their prompts before any of them decodes and enter decode
-        together; a decode round is a megastep of up to
-        ``_FUSED_STEP_BUCKET`` device-resident tokens a row, and finished
-        sequences RETIRE between rounds (budget exhausted or eos), so a
-        long-tail generation mix only pays for the tokens it actually
-        produces, while a padded static batch computes every row out to
-        the longest request — the continuous batching the padded v1
-        engine can't do."""
-        if any(seq.pending for seq in self.state.seqs.values()):
+        Returns the full token sequences. A client of :meth:`launch` /
+        :meth:`collect`, as the serving frontend's pump is: it launches
+        step n+1 before it collects step n, the engine continues each
+        decoding row on the device, and a row is flushed at its eos or its
+        budget — so a long-tail generation mix pays for the tokens it
+        produces, where a padded static batch computes every row out to
+        the longest request. A row that ends by its eos one launch after
+        it was continued has that launch's token dropped at its collect
+        (``dispatch/ahead_rows_dropped``)."""
+        if self._launched or \
+                any(seq.pending for seq in self.state.seqs.values()):
             # the loop below runs whatever the scheduler holds and keeps
             # only its own rows' tokens: another caller's would be lost
             raise RuntimeError(
                 "generate() while sequences of the streaming put() API "
-                "have tokens queued; step them to the end first")
+                "have tokens queued or a launch in flight; step them to "
+                "the end first")
         if temperature == 0.0:
             mode = ("argmax",)
         else:
@@ -2430,32 +2073,28 @@ class RaggedInferenceEngineTPU:
             self._validate_lengths(prompts, [remaining[u] for u in uids])
         seqs = {u: list(np.asarray(p).reshape(-1).astype(np.int32))
                 for u, p in zip(uids, prompts)}
-        eos_ids = None if eos_token_id is None else \
-            dict.fromkeys(uids, int(eos_token_id))
         try:
             self._put_validated(uids, [seqs[u] for u in uids])
-            fed = True
-            while fed:
-                got: Dict[int, List[int]] = {}
-                while (out := self.step_with_budget(
-                        mode=mode, max_steps=self._FUSED_STEP_BUCKET,
-                        row_limits=remaining, eos_ids=eos_ids)) is not None:
-                    got.update(out)     # a row reports once a round
-                fed = False
-                for u, toks in got.items():
-                    seqs[u].extend(toks)
-                    remaining[u] -= len(toks)
-                    # the step ends a row at its eos or at its budget, so
-                    # only the last token can be either
-                    if remaining[u] <= 0 or toks[-1] == eos_token_id:
+            # ``remaining`` is each row's ``row_limits`` entry: a length
+            # end is known before the launch, so no row is continued past
+            # its budget
+            while self.in_flight or \
+                    self.launch(mode=mode, row_limits=remaining):
+                self.launch(mode=mode, row_limits=remaining)    # step n+1
+                tokens, continued = self.collect()              # step n
+                for u, tok in tokens.items():
+                    seqs[u].append(tok)
+                    remaining[u] -= 1
+                    if remaining[u] <= 0 or tok == eos_token_id:
                         self.flush(u)
-                    else:
-                        self._put_validated([u], [toks[-1:]])
-                        fed = True
+                    elif u not in continued:
+                        self._put_validated([u], [[tok]])
         finally:
             # a failure mid-loop (arena exhausted, over-length) must not
             # leak this call's sequences — their pages/slots would be lost
-            # to every later request; after a whole run none is left
+            # to every later request — nor leave a launch in flight; after
+            # a whole run there is neither
+            self.abandon()
             for u in uids:
                 self.flush(u)
         return [np.asarray(seqs[u], np.int32) for u in uids]

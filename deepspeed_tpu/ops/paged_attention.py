@@ -552,7 +552,7 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, qcounts_ref, q_ref, k_hbm,
     buffers, head ``h`` of the program the static lane slice ``[h * d, (h
     + 1) * d)`` of them (whole 128-lane tiles). With ``with_lse`` an extra
     [1, hp, rows, 1] f32 output carries each row's logsumexp (the
-    partial-attention merge needs it — fused decode's history part).
+    partial-attention merge needs it — a split step's history part).
     K and V may differ in width (the output is dv wide). ``window``
     (static): key j is visible to query i only when ``i - j < window``,
     and the walk STARTS at the page that holds the lowest key any query of
@@ -807,8 +807,8 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
                              qcounts: Optional[jax.Array] = None):
     """Pallas paged attention returning (out, lse [n, c, h] fp32) for the
     partial-attention merge. ``counts=0`` gives HISTORY-only semantics
-    (keys [0, starts)) — the fused decode loop's arena part, where the
-    arena is a read-only input rather than a carried/donated buffer.
+    (keys [0, starts)) — a split step's history part, where the arena
+    is a read-only input rather than a carried/donated buffer.
     K and V may differ in width (q as wide as a K head, the output as
     wide as a V head); ``window``: key j is visible to query i only when
     ``i - j < window`` and the walk starts at the window's first page;

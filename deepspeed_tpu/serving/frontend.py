@@ -96,8 +96,6 @@ class ServingFrontend:
                  emit_every: int = 0, clock=time.monotonic,
                  watchdog=None, http_port: Optional[int] = None,
                  slo_admission: bool = False,
-                 megastep_tokens: Optional[int] = None,
-                 megastep_adaptive: Optional[bool] = None,
                  retry_budget: Optional[int] = None,
                  kvtier=None,
                  config=None):
@@ -141,23 +139,6 @@ class ServingFrontend:
         if self.cache is not None and self.kvtier is not None:
             self.cache.tier = self.kvtier
         self.token_budget = token_budget     # None → engine max_batch_tokens
-        # decode-megastep knobs: explicit kwargs win over a passed
-        # DeepSpeedTPUConfig/dict (its serving.* block), which wins over
-        # the defaults (megasteps off, adaptive K selection on)
-        cfg_ms, cfg_ad = 0, True
-        if config is not None:
-            srv = (config.get("serving") if isinstance(config, dict)
-                   else getattr(config, "serving", None))
-            if isinstance(srv, dict):
-                cfg_ms = int(srv.get("megastep_tokens", cfg_ms))
-                cfg_ad = bool(srv.get("megastep_adaptive", cfg_ad))
-            elif srv is not None:
-                cfg_ms = int(srv.megastep_tokens)
-                cfg_ad = bool(srv.megastep_adaptive)
-        self.megastep_tokens = (cfg_ms if megastep_tokens is None
-                                else int(megastep_tokens))
-        self.megastep_adaptive = (cfg_ad if megastep_adaptive is None
-                                  else bool(megastep_adaptive))
         # engine-fault retry budget (resilience.serving_retry_budget):
         # times ONE request may be requeued after an engine step died
         # under it before it finishes with reason "error"
@@ -173,9 +154,6 @@ class ServingFrontend:
                              else int(retry_budget))
         #: pump iterations — the ``serving_step`` chaos trigger counts these
         self._pump_steps = 0
-        if self.megastep_tokens < 0:
-            raise ValueError("megastep_tokens must be >= 0 "
-                             f"(got {self.megastep_tokens})")
         self.emit_every = emit_every
         self.clock = clock                   # injectable for deadline tests
         self._running: Dict[int, Request] = {}
@@ -427,53 +405,6 @@ class ServingFrontend:
 
     # -- the pump -----------------------------------------------------------
 
-    def _pick_megastep(self, now: float) -> int:
-        """Tokens the next engine step may run device-resident (K).
-
-        Megastep boundaries are the ONLY points where the pump sheds,
-        cancels, admits and re-mixes prefill — so K is the knob trading
-        dispatch overhead (stepwise pays 2+ host round-trips per token)
-        against responsiveness:
-
-        - any running prefill, or K ≤ 1 configured → 1 (stepwise);
-        - K never exceeds the deepest remaining budget (no dead window);
-        - a non-empty admission queue caps K at the SHALLOWEST remaining
-          budget: the next retirement frees the slot/pages the queued
-          request is waiting on, and that boundary is an admission point;
-        - adaptive mode scales K with the decode backlog (shallow batch →
-          short windows keep latency checks frequent) and shrinks K so no
-          running/queued deadline expires mid-window (decode step time
-          from the roofline ``cost_records`` when available).
-        """
-        k = self.megastep_tokens
-        if k <= 1 or self.mode is None or not self._running:
-            return 1
-        dec, pre = self.policy.decode_backlog(self.engine.state)
-        if pre or not dec:
-            return 1                   # prefill in flight → stepwise mix
-        rem = [req.max_new_tokens - len(req.tokens_out)
-               for req in self._running.values()]
-        k = min(k, max(rem))
-        if len(self.queue):
-            k = min(k, max(1, min(rem)))
-        if self.megastep_adaptive:
-            # deep decode-only backlogs amortize dispatch best; a shallow
-            # batch keeps windows short so new arrivals wait less
-            k = min(k, max(1, dec * 8))
-            recs = self.cost_records
-            t_dec = (float(recs.get("decode", {}).get("predicted_s", 0.0))
-                     if recs else 0.0)
-            if t_dec > 0.0:
-                slacks = [req.deadline - now
-                          for req in self._running.values()
-                          if req.deadline is not None]
-                slacks += [req.deadline - now
-                           for req in list(self.queue._q)
-                           if req.deadline is not None]
-                if slacks:
-                    k = min(k, max(1, int(min(slacks) / t_dec)))
-        return max(1, k)
-
     def step(self) -> bool:
         """One pump iteration: shed → cancel → admit → LAUNCH the next
         engine step → COLLECT the one before it → fan its tokens out.
@@ -481,9 +412,7 @@ class ServingFrontend:
         included. The launch made in a call is collected in the NEXT call,
         while the one after it runs: a token reaches its request one
         ``step()`` after the program that sampled it was launched, and the
-        device does not wait for the host in between. (A frontend with
-        ``megastep_tokens > 1`` collects each launch at once: the fused
-        window's length is planned from every row's last token.)
+        device does not wait for the host in between.
 
         One ``serving/step`` span whose children tile it: ``serving/admit``,
         ``serving/plan``, ``serving/engine_step`` (the engine's
@@ -526,25 +455,19 @@ class ServingFrontend:
             now = self.clock()
             progressed = self._admit(now)
         with telemetry.tracer.span("serving/plan"):
-            k = self._pick_megastep(now)
-            eos_map = None
             # what a row may still emit, as this pump counts it: a length
             # end is known BEFORE the launch, so the engine continues no
             # row past its budget
             row_limits = {uid: req.max_new_tokens - len(req.tokens_out)
                           for uid, req in self._running.items()}
-            if k > 1:
-                eos_map = {uid: req.eos_token_id
-                           for uid, req in self._running.items()
-                           if req.eos_token_id is not None}
             if self.watchdog is not None:
                 self.watchdog.arm("serving_step")
             t0 = time.monotonic()
             self._pump_steps += 1
         try:
-            with telemetry.tracer.span("serving/engine_step",
-                                       batch=len(self._running),
-                                       max_steps=k) as span_args:
+            with telemetry.tracer.span(
+                    "serving/engine_step",
+                    batch=len(self._running)) as span_args:
                 # chaos hook: an engine_error entry raises HERE so the
                 # injected fault exercises the same except-path a real
                 # engine failure takes
@@ -555,10 +478,10 @@ class ServingFrontend:
                 fault_injector.fire("serving_step",
                                     serving_step=self._pump_steps,
                                     advisory=False)
-                launched, got = self._engine_step(k, row_limits, eos_map)
+                launched, got = self._engine_step(row_limits)
                 if span_args is not None and launched:
                     # which device program the step launched (decode /
-                    # fresh / split / paged / megastep): known only now
+                    # fresh / split / paged): known only now
                     span_args["program"] = getattr(self.engine,
                                                    "last_program", None)
         except Exception as e:                       # noqa: BLE001
@@ -609,26 +532,16 @@ class ServingFrontend:
             self._update_degraded()
         return True
 
-    def _engine_step(self, k: int, row_limits, eos_map):
+    def _engine_step(self, row_limits):
         """``(launched, collected)`` of one pump iteration: launch the next
         engine step, then collect the launch that was in flight BEFORE it
         (``(tokens, continued uids)``, or None without one). A launch with
-        nothing in flight before it is collected in the next iteration —
-        unless this frontend plans megasteps, whose window needs every
-        row's last token: then each launch is collected at once, and the
-        engine continues no row."""
+        nothing in flight before it is collected in the next iteration."""
         eng = self.engine
-        if k > 1:
-            out = eng.step_with_budget(budget=self.token_budget,
-                                       mode=self.mode, max_steps=k,
-                                       row_limits=row_limits,
-                                       eos_ids=eos_map)
-            return out is not None, None if out is None else (out, ())
-        ahead = self.megastep_tokens <= 1
         waiting = eng.in_flight
         launched = eng.launch(budget=self.token_budget, mode=self.mode,
-                              row_limits=row_limits if ahead else None)
-        return launched, eng.collect() if waiting or not ahead else None
+                              row_limits=row_limits)
+        return launched, eng.collect() if waiting else None
 
     def drain(self) -> None:
         """Collect every launch in flight and deliver its tokens: nothing
@@ -651,12 +564,10 @@ class ServingFrontend:
         finished here that was continued is flushed like any other; the
         token its next program samples is dropped at that collect."""
         now = self.clock()
-        for uid, toks in out.items():
+        for uid, tok in out.items():
             req = self._running.get(uid)
             if req is None:
                 continue
-            if not isinstance(toks, list):
-                toks = [toks]
             if req.first_token_ts is None:
                 req.first_token_ts = now
                 self.metrics.ttft.record(
@@ -667,42 +578,23 @@ class ServingFrontend:
                     # publish them (cache increfs what it keeps)
                     self.cache.insert(
                         req.prompt, self.engine.state.seqs[uid].blocks)
-            if len(toks) > 1:
-                self.metrics.bump("megasteps")
-                self.metrics.megastep_k.record(float(len(toks)))
-                # one marker per fused pump on the request's trace track:
-                # a megastep-starved stream shows sparse pumps, not a
-                # mystery gap between prefill and finish
-                telemetry.reqtrace.instant(
-                    "serving/request/megastep", req.trace, ts=now,
-                    tid=req.uid, k=len(toks))
-            finished = False
-            for tok in toks:
-                tok = int(tok)
-                req.tokens_out.append(tok)
-                self.metrics.bump("tokens_out")
-                if req.stream_cb is not None:
-                    req.stream_cb(tok)
-                # eos outranks length: a megastep row that samples eos on
-                # its last budgeted token finished because of the eos
-                if req.eos_token_id is not None and \
-                        tok == req.eos_token_id:
-                    self._finish(req, "eos", RequestState.FINISHED, now)
-                    finished = True
-                    break
-                if len(req.tokens_out) >= req.max_new_tokens:
-                    self._finish(req, "length", RequestState.FINISHED, now)
-                    finished = True
-                    break
-            if not finished and uid not in continued:
-                # feed the block's LAST token back — every earlier one
-                # already has KV in the arena (megastep wrote it device-
-                # side; the engine advanced the descriptor to match)
+            tok = int(tok)
+            req.tokens_out.append(tok)
+            self.metrics.bump("tokens_out")
+            if req.stream_cb is not None:
+                req.stream_cb(tok)
+            # eos outranks length: a row that samples eos on its last
+            # budgeted token finished because of the eos
+            if req.eos_token_id is not None and tok == req.eos_token_id:
+                self._finish(req, "eos", RequestState.FINISHED, now)
+            elif len(req.tokens_out) >= req.max_new_tokens:
+                self._finish(req, "length", RequestState.FINISHED, now)
+            elif uid not in continued:
                 try:
-                    self.engine.state.extend(uid, [toks[-1]])
+                    self.engine.state.extend(uid, [tok])
                 except RuntimeError:
                     if self.cache is not None and self.cache.evict(1):
-                        self.engine.state.extend(uid, [toks[-1]])
+                        self.engine.state.extend(uid, [tok])
                     else:
                         self._finish(req, "kv_exhausted",
                                      RequestState.FINISHED, now)
@@ -869,8 +761,7 @@ class ServingFrontend:
     def stream(self, req: Request, poll_interval: float = 0.0005,
                stall_timeout: float = 30.0) -> Iterator[int]:
         """Yield ``req``'s tokens as they are produced, driving the pump
-        between yields (single-threaded streaming iterator). Megastep
-        blocks drain in order, K tokens per pump.
+        between yields (single-threaded streaming iterator).
 
         Empty pumps back off (``poll_interval`` doubling to 50 ms) instead
         of busy-spinning the host, and ``stall_timeout`` seconds of zero

@@ -36,9 +36,6 @@ class ServingMetrics:
         self.ttft = Histogram()
         self.tpot = Histogram(lo=1e-5, hi=10.0)
         self.queue_depth = Histogram(lo=1.0, hi=4096.0, n_buckets=13)
-        # decode megastep window sizes actually run (tokens per fused
-        # launch) — the K-selection policy's observable output
-        self.megastep_k = Histogram(lo=1.0, hi=1024.0, n_buckets=11)
         _registry.register("serving/ttft_seconds", self.ttft,
                            help="time to first token (s)", replace=True)
         _registry.register("serving/tpot_seconds", self.tpot,
@@ -46,14 +43,11 @@ class ServingMetrics:
         _registry.register("serving/queue_depth", self.queue_depth,
                            help="admission queue depth at step start",
                            replace=True)
-        _registry.register("serving/megastep_k", self.megastep_k,
-                           help="decode megastep window size (tokens)",
-                           replace=True)
         self.counters: Dict[str, int] = {
             "admitted": 0, "completed": 0, "cancelled": 0, "shed": 0,
             "rejected_queue_full": 0, "rejected_kv_exhausted": 0,
             "rejected_too_long": 0, "rejected_slo": 0, "tokens_out": 0,
-            "prefix_tokens_reused": 0, "engine_steps": 0, "megasteps": 0,
+            "prefix_tokens_reused": 0, "engine_steps": 0,
         }
 
     def bump(self, name: str, by: int = 1) -> None:
@@ -65,8 +59,7 @@ class ServingMetrics:
                ) -> List[Tuple[str, float, int]]:
         ev: List[Tuple[str, float, int]] = []
         for key, h in (("ttft", self.ttft), ("tpot", self.tpot),
-                       ("queue_depth", self.queue_depth),
-                       ("megastep_k", self.megastep_k)):
+                       ("queue_depth", self.queue_depth)):
             if h.count:
                 ev.append((f"serving/{key}_mean", h.mean, step))
                 ev.append((f"serving/{key}_p99", h.percentile(99), step))

@@ -34,9 +34,9 @@ class Request:
     queued request past its deadline is shed, never silently run late.
     ``stream_cb`` is invoked with each generated token id as soon as the
     frontend observes it (same thread as the engine loop — keep it cheap).
-    ``eos_token_id`` retires the request early when sampled — honored
-    ON DEVICE inside decode megasteps (the row stops writing KV
-    mid-window) and host-side on the stepwise path.
+    ``eos_token_id`` retires the request early when sampled (the pump
+    sees the token at its collect; a row the engine had continued by then
+    has its next token dropped).
     """
     prompt: List[int]
     max_new_tokens: int = 16

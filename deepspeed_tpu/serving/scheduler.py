@@ -30,24 +30,6 @@ class TokenBudgetPolicy:
         self._next_arrival = 0
         self._rr = 0                 # decode round-robin offset
 
-    def decode_backlog(self, state) -> Tuple[int, int]:
-        """``(decode_rows, prefill_rows)`` over the live selectable
-        sequences — the frontend's megastep K policy keys off this view
-        of the NEXT selection: any prefill row means the coming batch is
-        mixed (megastep inapplicable, K=1), while a pure decode backlog's
-        depth scales how many tokens one device window may run. Counts
-        every engine sequence, not just frontend-owned ones, because
-        ``select`` packs from the same population."""
-        dec = pre = 0
-        for seq in state.seqs.values():
-            if seq.done or seq.pending == 0:
-                continue
-            if seq.pending == 1:
-                dec += 1
-            else:
-                pre += 1
-        return dec, pre
-
     def note_arrival(self, uid: int) -> None:
         """Frontend stamps admission order (uid values may be arbitrary)."""
         if uid not in self._arrival:

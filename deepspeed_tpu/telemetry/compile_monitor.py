@@ -176,11 +176,11 @@ class CompileMonitor:
         lowered here and no argument buffer is kept.
 
         The function belongs to its engine, and its closure may hold that
-        engine and so its device state (the megastep's does; a step
-        program's holds the model's statics alone), so the function is held
-        WEAKLY: a process that drops an
-        engine frees it, registered or not. Only while the tracer is on is
-        it held strongly (:meth:`hold_programs`): a traced run asks for
+        engine and so its device state (a serving step program's holds the
+        model's statics alone), so the function is held WEAKLY: a process
+        that drops an engine frees it, registered or not. Only while the
+        tracer is on is it held strongly (:meth:`hold_programs`): a traced
+        run asks for
         the table after its work, when the caller may hold the engine no
         longer (the benchmark's readers run after the runner returned).
         One name is one compiled module: the name is the one the trace's

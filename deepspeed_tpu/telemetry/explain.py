@@ -627,36 +627,6 @@ def _fmt_num(v: float) -> str:
     return f"{v:.0f}"
 
 
-def dispatch_waste() -> Optional[Dict[str, float]]:
-    """Fused-decode dispatch accounting from the process-wide ``dispatch/*``
-    counters, or None when no fused launch has run in this process.
-
-    ``dead_fraction`` is the share of scan iterations burned on bucket
-    rounding: fused scan lengths round up to a power of two so distinct
-    window sizes share compiles, and every iteration past the traced
-    ``limit`` runs the full model forward with all rows dead."""
-    scan = _registry.get("dispatch/scan_steps")
-    dead = _registry.get("dispatch/dead_steps")
-    if scan is None or not scan.value:
-        return None
-    dead_v = float(dead.value) if dead is not None else 0.0
-    return {"scan_steps": float(scan.value), "dead_steps": dead_v,
-            "dead_fraction": dead_v / float(scan.value)}
-
-
-def dispatch_note(threshold: float = 0.10) -> Optional[str]:
-    """One grep-able DISPATCH line when fused-decode bucket rounding burns
-    more than ``threshold`` of all scan iterations; None otherwise."""
-    w = dispatch_waste()
-    if w is None or w["dead_fraction"] <= threshold:
-        return None
-    return (f"DISPATCH: {100.0 * w['dead_fraction']:.1f}% of fused decode "
-            f"iterations were dead ({int(w['dead_steps'])} of "
-            f"{int(w['scan_steps'])} scan steps) — window sizes land far "
-            f"below their scan-length bucket; align max_new_tokens /"
-            f" serving.megastep_tokens with the bucket size or lower it")
-
-
 def verdict_line(report: "ExplainReport") -> str:
     """The one-line roofline verdict (rendered last, grep-able)."""
     rl = report.roofline
@@ -737,10 +707,6 @@ def render(report: ExplainReport) -> str:
     for w in report.warnings:
         out.append("")
         out.append(f"WARNING: {w}")
-    note = dispatch_note()
-    if note is not None:
-        out.append("")
-        out.append(note)
     out.append("")
     out.append(verdict_line(report))
     return "\n".join(out)

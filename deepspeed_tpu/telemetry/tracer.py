@@ -195,8 +195,7 @@ class Tracer:
         nothing is computed for a disabled tracer. A dict that held an
         argument when the block ended IS the recorded event's ``args``,
         so it may still be completed right after (a launch's work is
-        counted after its jitted call, a megastep's emitted tokens are
-        known only after the fetch that follows its launch).
+        counted after its jitted call).
 
         Spans that follow one another TILE their parent: the profiler
         annotation opens before the clock is read and closes after the

@@ -32,10 +32,6 @@ def main():
     ap.add_argument("--concurrency", type=int, default=0,
                     help="with --stream: max requests in flight at once "
                          "(0 = engine max_sequences)")
-    ap.add_argument("--megastep", type=int, default=0, metavar="K",
-                    help="with --stream: fuse up to K decode iterations "
-                         "into one device program when the batch is "
-                         "decode-only (docs/serving.md; 0 = stepwise)")
     args = ap.parse_args()
 
     from _common import setup_jax
@@ -72,7 +68,7 @@ def main():
         if args.concurrency:
             eng.config.max_sequences = min(eng.config.max_sequences,
                                            args.concurrency)
-        fe = ServingFrontend(eng, megastep_tokens=args.megastep)
+        fe = ServingFrontend(eng)
 
         def cb_for(i):
             def cb(t):

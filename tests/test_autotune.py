@@ -314,7 +314,7 @@ def _records(t_pre, t_dec, n_bucket=8, chunk=32):
 
 def test_plan_serving_sizing_math():
     mix = TrafficMix(rps_peak=4.0, prompt_tokens=512, gen_tokens=128,
-                     swing=4.0, ttft_target_s=0.5, utilization=0.6,
+                     swing=4.0, utilization=0.6,
                      headroom=1.25)
     plan = plan_serving(_records(t_pre=0.080, t_dec=0.008), mix)
     assert plan["model"] == "roofline"
@@ -327,8 +327,7 @@ def test_plan_serving_sizing_math():
     assert a["decode_min"] <= a["decode_max"]
     assert a["queue_high"] == pytest.approx(4.8)
     assert plan["router"]["replicas"] == 3          # pre_peak + dec_peak
-    # megastep: int(0.25·0.5/0.008) = 15 decode tokens per window
-    assert plan["serving"]["megastep_tokens"] == 15
+    assert plan["serving"] == {}                    # no key left to size
     # SplitFuse: 2 decode steps of prefill tokens = 2·0.008/(0.080/256)
     assert plan["engine"]["max_batch_tokens"] == 51
     ttft_best = math.ceil(512 / 32) * 0.080 + 0.008

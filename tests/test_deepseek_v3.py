@@ -540,7 +540,7 @@ def test_what_the_reader_cannot_honour_refuses_by_name():
 
 def test_what_is_not_built_refuses_by_name(tiny):
     """(f) training, the v1 cache, page export and quantised weights say
-    what they are; the megastep falls back to the stepwise program."""
+    what they are."""
     import deepspeed_tpu as ds
     cfg, params, _w = tiny
     with pytest.raises(NotImplementedError, match="typed layer stack"):
@@ -554,10 +554,6 @@ def test_what_is_not_built_refuses_by_name(tiny):
         eng.export_pages([0])
     with pytest.raises(NotImplementedError, match="typed layer stack"):
         _engine(cfg, params, weight_quant="int8")
-    out = eng._put_tokens([3], [[5, 6, 7]])
-    eng.state.extend(3, [out[3]])
-    stepped = eng.step_with_budget(max_steps=4)  # a megastep is asked for
-    assert list(stepped) == [3] and len(stepped[3]) == 1
 
 
 def test_copy_on_write_copies_a_latent_page(tiny):
@@ -715,15 +711,24 @@ def test_the_check_judges_the_tokens_whose_routing_is_decided(
 
 
 def test_generate_takes_the_reference_argmax(tiny):
-    """generate() on the latent stack: no megastep, the three stepwise
-    programs, and every generated token is the reference's argmax."""
+    """generate() on the latent stack: every generated token is the
+    reference's argmax."""
     cfg, params, w = tiny
     eng = _engine(cfg, params)
     prompt = np.random.default_rng(2).integers(0, VOCAB, 11).tolist()
     (out,) = eng.generate([prompt], max_new_tokens=5)
-    assert not eng._fused_fns and out[:11].tolist() == prompt
+    assert out[:11].tolist() == prompt
     ref, dev = reference(), jax.devices()[0]
     for i in range(11, 16):
         logits = ref.logits_of(w, params, out[:i].tolist(), dev)[-1]
         assert logits[out[i]] > logits.max() - TOL
     assert not eng.state.seqs and eng.state.allocator.free_blocks == 32
+
+
+def test_generate_is_the_stepwise_greedy_run_with_an_eos_inside(tiny):
+    from tests.test_paged import generate_against_stepwise_with_an_eos
+    cfg, params, _w = tiny
+    rng = np.random.default_rng(12)
+    generate_against_stepwise_with_an_eos(
+        lambda: _engine(cfg, params),
+        [rng.integers(0, VOCAB, n).tolist() for n in (11, 3, 19)], 9)

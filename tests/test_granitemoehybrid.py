@@ -496,13 +496,26 @@ def test_the_engine_wrong_in_one_way_is_caught(control, tiny, monkeypatch):
     assert np.abs(got - want[129:140]).max() > 5 * F32_TOL
 
 
-def test_generate_serves_it_without_the_megastep(tiny):
+def test_generate_serves_it_running_ahead(tiny):
+    from deepspeed_tpu.telemetry.registry import registry
     _, cfg, params, tokens, _ = tiny
     eng = engine(cfg, params)
+    ahead = registry.counter("dispatch/launches_ahead")
+    before = ahead.value
     (out,) = eng.generate([tokens[:140].tolist()], max_new_tokens=6)
-    assert len(out) == 146 and not eng._fused_fns
+    assert len(out) == 146 and ahead.value > before
+    assert not eng.state.seqs and len(eng.state._slots) == 8
     logits = uncached(cfg, params, out[:-1])
     assert out[140:].tolist() == logits[139:].argmax(-1).tolist()
+
+
+def test_generate_is_the_stepwise_greedy_run_with_an_eos_inside(tiny):
+    from tests.test_paged import generate_against_stepwise_with_an_eos
+    _, cfg, params, tokens, _ = tiny
+    generate_against_stepwise_with_an_eos(
+        lambda: engine(cfg, params),
+        [tokens[a:b].tolist() for a, b in ((0, 70), (70, 75), (80, 113))],
+        9)
 
 
 def test_the_frontend_serves_it_through_the_run_ahead_pump(tiny):

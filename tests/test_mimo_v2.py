@@ -373,7 +373,7 @@ def test_copy_on_write_copies_a_page_in_every_pool(tiny):
 
 def test_what_is_not_built_refuses_by_name(tiny):
     """(f) training, the v1 cache, page export and quantised weights say
-    what they are; the megastep falls back to the stepwise program."""
+    what they are."""
     import deepspeed_tpu as ds
     cfg, params, _w = tiny
     with pytest.raises(NotImplementedError, match="typed layer stack"):
@@ -389,24 +389,20 @@ def test_what_is_not_built_refuses_by_name(tiny):
         eng.export_pages([0])
     with pytest.raises(NotImplementedError, match="typed layer stack"):
         _engine(cfg, params, weight_quant="int8")
-    out = eng._put_tokens([3], [[5, 6, 7]])
-    eng.state.extend(3, [out[3]])
-    stepped = eng.step_with_budget(max_steps=4)  # a megastep is asked for
-    assert list(stepped) == [3] and len(stepped[3]) == 1
 
 
-def test_generate_on_a_typed_stack_launches_no_megastep(tiny):
-    """generate() asks every step for a decode window; a typed stack has
-    no fused loop, so its decode-only selections take the stepwise
-    program, and the tokens are the reference's argmax."""
+def test_generate_on_a_typed_stack_runs_ahead(tiny):
+    """generate() waits for no program on a typed stack either: every
+    launch but the first is made ahead of the one before it, through the
+    three step programs, and the tokens are the reference's argmax."""
     from deepspeed_tpu.telemetry.registry import registry
     cfg, params, w = tiny
     eng = _engine(cfg, params)
-    launches = registry.counter("dispatch/megastep_launches")
-    before = launches.value
+    ahead = registry.counter("dispatch/launches_ahead")
+    before = ahead.value
     prompt = np.random.default_rng(2).integers(0, VOCAB, 11).tolist()
     (out,) = eng.generate([prompt], max_new_tokens=5)
-    assert launches.value == before and not eng._fused_fns
+    assert ahead.value - before == 2 + 4 - 1    # chunks + decode steps
     assert {fn.__name__ for fn in eng._step_fns.values()} == {
         "serve_fresh_r1_c8", "serve_split_r1_c8", "serve_decode_r1"}
     assert out[:11].tolist() == prompt and len(out) == 16
@@ -415,6 +411,15 @@ def test_generate_on_a_typed_stack_launches_no_megastep(tiny):
         logits = ref.logits_of(w, params, out[:i].tolist(), dev)[-1]
         assert logits[out[i]] > logits.max() - TOL
     assert not eng.state.seqs and eng.state.allocator.free_blocks == 32
+
+
+def test_generate_is_the_stepwise_greedy_run_with_an_eos_inside(tiny):
+    from tests.test_paged import generate_against_stepwise_with_an_eos
+    cfg, params, _w = tiny
+    rng = np.random.default_rng(12)
+    generate_against_stepwise_with_an_eos(
+        lambda: _engine(cfg, params),
+        [rng.integers(0, VOCAB, n).tolist() for n in (11, 3, 19)], 9)
 
 
 def test_config_from_hf_reads_the_cells_file_and_the_published_file():

@@ -242,12 +242,12 @@ def test_ragged_sampling_modes(devices):
 
 def _generate_stepwise(eng, prompts, budgets, temperature=0.0, top_k=0,
                        top_p=1.0):
-    """generate()'s contract driven from outside, ONE token a step: the
-    prompts are queued, ``step_with_budget()`` (no megastep armed) runs
-    whatever the scheduler packs until nothing is queued, then every
-    row's token is fed back at once, until its budget is spent — the
-    rounds of generate(), so rows enter decode together. The reference
-    generate()'s fused windows are held to."""
+    """A generation loop that WAITS: the prompts are queued,
+    ``step_with_budget()`` runs whatever the scheduler packs until nothing
+    is queued, then every row's token is fed back at once through the
+    host, until its budget is spent — in rounds, so rows enter decode
+    together. The reference the pump that runs ahead (generate(), the
+    serving frontend) is held to: greedy tokens are a row's own."""
     mode = ("argmax",)
     if temperature:
         mode = ("sample", int(top_k), top_p < 1.0)
@@ -273,21 +273,56 @@ def _generate_stepwise(eng, prompts, budgets, temperature=0.0, top_k=0,
     return [np.asarray(seqs[u], np.int32) for u in uids]
 
 
-#: ragged prompts of one and of three prefill chunks, in every mode: the
-#: sampled streams must agree through the mixed prefill steps too
-_FUSED_VS_STEPWISE = {
+def generate_against_stepwise_with_an_eos(make_engine, prompts, budget):
+    """generate() on two fresh engines of ``make_engine`` — without an eos,
+    then with one that a row samples inside its stream — against
+    the loop that waits: greedy tokens are a row's own, so every row is
+    the reference's up to and with its first eos. The typed stacks' test
+    files call this on their own fixtures."""
+    from deepspeed_tpu.telemetry.registry import registry
+    prompts = [[int(t) for t in p] for p in prompts]
+    want = [w.tolist() for w in _generate_stepwise(
+        make_engine(), prompts, [budget] * len(prompts))]
+    ahead = registry.counter("dispatch/launches_ahead")
+    before = ahead.value
+    got = make_engine().generate(prompts, max_new_tokens=budget)
+    assert [g.tolist() for g in got] == want
+    assert ahead.value > before         # no program was waited for
+    # the eos: the token that comes FIRST the latest in some row's stream,
+    # short of the row's last (a tiny stack may repeat itself from its
+    # first token on: then that one)
+    tails = [w[len(p):] for w, p in zip(want, prompts)]
+    cut, row = max((i, r) for r, tail in enumerate(tails)
+                   for i, t in enumerate(tail[:-1]) if t not in tail[:i])
+    eos = tails[row][cut]
+    eng = make_engine()
+    got = eng.generate(prompts, max_new_tokens=budget, eos_token_id=eos)
+    assert len(got[row]) == len(prompts[row]) + cut + 1 < len(want[row])
+    for g, p, tail in zip(got, prompts, tails):
+        end = tail.index(eos) + 1 if eos in tail else len(tail)
+        assert g.tolist() == p + tail[:end]
+    assert not eng.state.seqs and eng.in_flight == 0
+    assert eng.state.allocator.free_blocks == eng.config.num_blocks
+
+
+#: ragged prompts of one and of three prefill chunks, in every mode
+_GENERATE_VS_STEPWISE = {
     "argmax": {"temperature": 0.0},
     "top_k": {"temperature": 0.8, "top_k": 8},
     "top_p": {"temperature": 0.7, "top_p": 0.9},
 }
 
 
-@pytest.mark.parametrize("case", list(_FUSED_VS_STEPWISE))
-def test_fused_decode_matches_stepwise(devices, case):
-    """The fused on-device decode loop must produce token-for-token the
-    same output as the engine stepped one token at a time (argmax and
-    sampled modes; the sampled comparison pins the device RNG via a fresh
-    engine)."""
+@pytest.mark.parametrize("case", list(_GENERATE_VS_STEPWISE))
+def test_generate_matches_stepwise(devices, case):
+    """generate() launches ahead and keeps a decoding row's token on the
+    device. Greedy: token for token the loop that waits and feeds back
+    through the host. Sampled: the device key is split once a LAUNCH, and
+    a short prompt's row decodes beside a long one's chunks here (no
+    rounds), so two prompts draw other tokens than the rounds do — but the
+    same key gives the same stream, a single prompt makes the same
+    launches as the loop that waits and so draws ITS stream, and sampling
+    samples."""
     build_mesh(data=1, devices=jax.devices()[:1])
     cfg = llama3_config("tiny", max_seq_len=128, vocab_size=256)
     from deepspeed_tpu.models.transformer import init_params
@@ -297,35 +332,44 @@ def test_fused_decode_matches_stepwise(devices, case):
     eng_cfg = {"dtype": "float32", "num_blocks": 32, "block_size": 16,
                "max_seq_len": 96, "prefill_chunk": 8,
                "max_batch_tokens": 64}
-    launches = registry.counter("dispatch/megastep_launches")
-    kwargs = _FUSED_VS_STEPWISE[case]
+    ahead = registry.counter("dispatch/launches_ahead")
+    kwargs = _GENERATE_VS_STEPWISE[case]
     prompts = [rng.integers(0, 256, size=(n,), dtype=np.int32)
                for n in (7, 19)]
 
-    fused_eng = RaggedInferenceEngineTPU(
-        cfg, eng_cfg, params=params, rng=jax.random.PRNGKey(1))
-    before = launches.value
-    fused = fused_eng.generate(prompts, max_new_tokens=8, **kwargs)
-    # a decode-only selection goes through the one fused program
-    assert launches.value > before
-    assert fused_eng._fused_fns and all(
-        fn.__name__.startswith("serve_megastep_r2_k8_")
-        for fn in fused_eng._fused_fns.values())
-    assert fused_eng.last_program == "megastep"
+    def fresh():
+        return RaggedInferenceEngineTPU(cfg, eng_cfg, params=params,
+                                        rng=jax.random.PRNGKey(1))
 
-    step_eng = RaggedInferenceEngineTPU(
-        cfg, eng_cfg, params=params, rng=jax.random.PRNGKey(1))
-    before = launches.value
-    stepwise = _generate_stepwise(step_eng, prompts, [8, 8], **kwargs)
-    assert launches.value == before and not step_eng._fused_fns
+    eng = fresh()
+    before = ahead.value
+    got = eng.generate(prompts, max_new_tokens=8, **kwargs)
+    # 3 prefill launches and 7 decode launches, all but the first ahead
+    assert ahead.value - before == 9
+    assert eng.last_program == "decode" and eng.in_flight == 0
+    assert [len(g) for g in got] == [15, 27]
 
-    for f, s in zip(fused, stepwise):
-        np.testing.assert_array_equal(f, s)
+    if case == "argmax":
+        before = ahead.value
+        want = _generate_stepwise(fresh(), prompts, [8, 8])
+        assert ahead.value == before
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+        return
+    for g, again in zip(got, fresh().generate(prompts, max_new_tokens=8,
+                                              **kwargs)):
+        np.testing.assert_array_equal(g, again)
+    greedy = fresh().generate(prompts, max_new_tokens=8)
+    assert any(not np.array_equal(g, w) for g, w in zip(got, greedy))
+    for prompt in prompts:
+        (one,) = fresh().generate([prompt], max_new_tokens=8, **kwargs)
+        (want,) = _generate_stepwise(fresh(), [prompt], [8], **kwargs)
+        np.testing.assert_array_equal(one, want)
 
 
-def test_fused_decode_eos_truncation(devices):
-    """With eos_token_id set the fused loop truncates on host; outputs
-    end at (and include) the first eos."""
+def test_generate_eos_truncation(devices):
+    """With eos_token_id set a row is flushed at its eos; outputs end at
+    (and include) the first eos."""
     build_mesh(data=1, devices=jax.devices()[:1])
     cfg = llama3_config("tiny", max_seq_len=128, vocab_size=256)
     eng = RaggedInferenceEngineTPU(
@@ -348,32 +392,35 @@ def test_fused_decode_eos_truncation(devices):
     np.testing.assert_array_equal(outs2[0], outs[0][:len(outs2[0])])
 
 
-def test_fused_decode_falls_back_when_unavailable(devices, monkeypatch):
-    """When the arena cannot cover a decode window's pages the megastep
-    declines (None) and generate() goes on one token a step, with the
-    full output, instead of failing; the window is taken again once a
-    retired row's pages make room, and every page is free at the end."""
+def test_generate_feeds_back_where_the_arena_has_no_page(devices,
+                                                        monkeypatch):
+    """When the arena cannot cover a row's continuation the engine
+    continues nothing (``_continue`` finds no page) and generate()'s
+    feed-back stands — it raises only where the page is still missing
+    when the token is queued; here a retired row's pages make room in
+    time. Outputs are whole and equal the loop that waits; every page is
+    free at the end."""
     build_mesh(data=1, devices=jax.devices()[:1])
     cfg = llama3_config("tiny", max_seq_len=128, vocab_size=256)
     eng_cfg = {"dtype": "float32", "num_blocks": 5, "block_size": 8,
                "max_seq_len": 64, "prefill_chunk": 8,
                "max_batch_tokens": 64}
     eng = RaggedInferenceEngineTPU(cfg, eng_cfg, rng=jax.random.PRNGKey(0))
-    # two rows of 2 pages each leave 1 free; row 1's window of 23 tokens
-    # wants 2 more. Row 0 retires after 7 steps and its pages cover it
+    # two rows of 2 pages each leave 1 free: row 0 ends after 8 tokens,
+    # inside its second page; row 1's 32 tokens want 2 pages more, the
+    # second of them only once row 0 has retired
     prompts, budgets = [[1] * 8, [2] * 8], [8, 24]
-    real, windows = eng._try_megastep, []
+    real, continued = eng._continue, []
 
-    def spy(*args):
-        windows.append(real(*args))
-        return windows[-1]
+    def spy(seq, row_limits):
+        continued.append(real(seq, row_limits))
+        return continued[-1]
 
-    monkeypatch.setattr(eng, "_try_megastep", spy)
+    monkeypatch.setattr(eng, "_continue", spy)
     outs = eng.generate(prompts, max_new_tokens=budgets)
     assert [len(o) for o in outs] == [16, 32]
-    assert windows[0] is None and len(windows) > 2
-    assert windows[-1] is not None and len(windows[-1][1]) == 16
-    assert not eng.state.seqs
+    assert any(at is not None for at in continued)
+    assert not eng.state.seqs and eng.in_flight == 0
     assert eng.state.allocator.free_blocks == 5
 
     ref = _generate_stepwise(
@@ -394,14 +441,14 @@ def test_stepwise_failure_does_not_leak_pages(devices):
               "max_seq_len": 128, "prefill_chunk": 8,
               "max_batch_tokens": 64}, rng=jax.random.PRNGKey(0))
     free_before = eng.state.allocator.free_blocks
-    # 2 prompts x (14 + 60) tokens needs more than 4x16 pages; fused
-    # declines on capacity, the stepwise loop exhausts the arena mid-run
-    # (eos never fires for a random model with eos_token_id=255 unlikely
-    # early... use an id outside the sampled range to be sure)
+    # 2 prompts x (14 + 60) tokens needs more than 4x16 pages: the engine
+    # continues no row without a page, and the feed-back that then has
+    # none exhausts the arena mid-run (an eos id outside the vocabulary
+    # never fires)
     with pytest.raises(RuntimeError, match="arena"):
         eng.generate([[1] * 14, [2] * 14], max_new_tokens=60,
                      eos_token_id=257)
-    assert not eng.state.seqs
+    assert not eng.state.seqs and eng.in_flight == 0
     assert eng.state.allocator.free_blocks == free_before
 
 
@@ -1220,8 +1267,9 @@ def test_generate_refuses_oversized_before_compute(devices):
 
 
 #: the greedy tokens of :func:`test_golden_greedy_tokens`'s flow, a row a
-#: line (first token after the chunked prefill, three from decode steps,
-#: six from one megastep), RECORDED FROM THE PARENT OF PR 34 (head-major
+#: line (first token after the chunked prefill, nine from decode steps —
+#: the last six from one fused window when recorded), RECORDED FROM THE
+#: PARENT OF PR 34 (head-major
 #: pools) before the arena went token-major: a layout moves no token
 _GOLDEN_GREEDY = [
     [153, 69, 181, 12, 181, 12, 69, 236, 86, 229],
@@ -1234,10 +1282,9 @@ _GOLDEN_GREEDY = [
 def test_golden_greedy_tokens(devices):
     """A small uniform-stack engine over a fixed ragged batch — prompts of
     one to four prefill chunks (a ``fresh`` step, then ``split`` steps
-    that mix rows with and without history), three single-token decode
-    steps (the arena in the layer scan's carry, write then read), then one
-    megastep (read-only arena, per-loop buffer, one write-back) — emits
-    the tokens it emitted before PR 34 changed the arena's layout."""
+    that mix rows with and without history), then nine single-token
+    decode steps (the arena in the layer scan's carry, write then read) —
+    emits the tokens it emitted before PR 34 changed the arena's layout."""
     build_mesh(data=1, devices=jax.devices()[:1])
     cfg = llama3_config("tiny", max_seq_len=128, vocab_size=256)
     from deepspeed_tpu.models.transformer import init_params
@@ -1252,20 +1299,17 @@ def test_golden_greedy_tokens(devices):
                              for n in (5, 11, 23, 30)])
     programs = []
 
-    def drain(**kwargs):
-        while (out := eng.step_with_budget(**kwargs)) is not None:
+    def drain():
+        while (out := eng.step_with_budget()) is not None:
             programs.append(eng.last_program)
-            for u, toks in out.items():
-                got[u].extend(toks if isinstance(toks, list) else [toks])
+            for u, tok in out.items():
+                got[u].append(tok)
 
     drain()                                     # the chunked prefill
-    for _ in range(3):                          # decode, one token a step
+    for _ in range(9):                          # decode, one token a step
         eng.scheduler.put(uids, [got[u][-1:] for u in uids])
         drain()
-    eng.scheduler.put(uids, [got[u][-1:] for u in uids])
-    drain(max_steps=8, row_limits=dict.fromkeys(uids, 6))
-    assert programs == ["fresh"] + ["split"] * 3 + ["decode"] * 3 + \
-        ["megastep"]
+    assert programs == ["fresh"] + ["split"] * 3 + ["decode"] * 9
     assert [got[u] for u in uids] == _GOLDEN_GREEDY
 
 

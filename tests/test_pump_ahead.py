@@ -78,7 +78,7 @@ class _WaitingFrontend(ServingFrontend):
     """The pump as it was: each engine step a ``step_with_budget`` call that
     waits for its own program, every token fed back through the host."""
 
-    def _engine_step(self, k, row_limits, eos_map):
+    def _engine_step(self, row_limits):
         out = self.engine.step_with_budget(budget=self.token_budget,
                                            mode=self.mode)
         return out is not None, None if out is None else (out, ())
@@ -358,8 +358,8 @@ def _two_running(fe, eos_of=None):
 
 
 @pytest.mark.parametrize("how", ["run_until_idle", "stream",
-                                 "terminate_inflight", "close",
-                                 "step_reports_work"])
+                                 "stream_cancel", "terminate_inflight",
+                                 "close", "step_reports_work"])
 def test_what_ends_a_pump_leaves_nothing_in_flight(devices, how):
     eng = _engine()
     fe = ServingFrontend(eng, enable_prefix_cache=False)
@@ -383,6 +383,18 @@ def test_what_ends_a_pump_leaves_nothing_in_flight(devices, how):
         assert FED_SENTINEL not in eng.state.seqs[b.uid].tokens
         fe.run_until_idle()
         assert len(b.tokens_out) == 9
+    elif how == "stream_cancel":
+        a, b = _two_running(fe)
+        it = fe.stream(a)
+        got = [next(it) for _ in range(4)]
+        fe.cancel(a)
+        assert got + list(it) == list(a.tokens_out)  # drains, then stops
+        assert a.state.value == "cancelled" and len(a.tokens_out) < 9
+        # the flushed row released its slot and pages; the other goes on
+        assert list(eng.state.seqs) == [b.uid]
+        fe.run_until_idle()
+        assert len(b.tokens_out) == 9
+        assert eng.state.allocator.free_blocks == ENGINE["num_blocks"]
     elif how == "terminate_inflight":
         a, b = _two_running(fe)
         for _ in range(3):
@@ -472,13 +484,16 @@ def test_a_launch_begins_before_the_fetch_before_it_ends(devices, traced):
     assert share == 1 - 1 / len(dispatches) and share > 0.9
 
 
-def test_generate_and_put_launch_nothing_ahead(devices):
+def test_generate_runs_ahead_and_put_does_not(devices):
     eng = _engine()
-    ahead = _counter("launches_ahead")
+    ahead, launches = _counter("launches_ahead"), _launches()
     out = eng.generate([[5, 6, 7], [9, 10]], max_new_tokens=5)
     assert [len(t) for t in out] == [3 + 5, 2 + 5]
+    # one prefill launch and four decode launches: all but the first
+    assert _launches() - launches == 5
+    assert _counter("launches_ahead") == ahead + 4 and eng.in_flight == 0
     eng._put_tokens([77], [[1, 2, 3]])
-    assert _counter("launches_ahead") == ahead and eng.in_flight == 0
+    assert _counter("launches_ahead") == ahead + 4 and eng.in_flight == 0
 
 
 # -- the grid a server warms is the grid the pump launches ---------------------

@@ -192,19 +192,6 @@ def test_step_program_registers_under_its_own_name(kind, monkeypatch):
     assert f"jit_{name}" in jitted.lower(*args).as_text()[:200]
 
 
-def test_megastep_program_is_named_by_rows_scan_steps_and_page_width():
-    eng = _engine()
-    jitted = eng._fused_decode_fn(4, 8, ("argmax",), 4)
-    assert jitted.__name__ == "serve_megastep_r4_k8_p4"
-    assert "serve_megastep_r4_k8_p4" in telemetry.compile_monitor.programs()
-    # another page-table width is another module, so another name
-    assert eng._fused_decode_fn(4, 8, ("argmax",), 8).__name__ == \
-        "serve_megastep_r4_k8_p8"
-    table = telemetry.compile_monitor.scopes("serve_megastep_r4_k8_p4")
-    assert {"attn_history", "attn_core", "attn_merge", "kv_write", "mlp",
-            "sample"} <= {e["scope"] for e in table.values()}
-
-
 def test_the_engine_chooses_no_path_by_the_environment():
     """Which program a batch runs follows from the batch and the config:
     the ragged engine reads no environment variable."""
@@ -538,7 +525,7 @@ def test_packed_launch_counts_the_capacity_it_ran_at(traced):
         80 + 42 + 4 + 4
     assert not [n for n in telemetry.registry.names()
                 if n.startswith("dispatch/steps.") and n.split(".")[1] not in
-                ("fresh", "split", "decode", "paged", "megastep")]
+                ("fresh", "split", "decode", "paged")]
 
 
 #: launch -> (engine overrides, prompt lengths, which launch, what it is:
@@ -625,38 +612,17 @@ def test_untraced_serving_step_counts_and_computes_no_argument(monkeypatch):
     assert eng.last_program == "decode"
 
 
-def test_megastep_launch_counts_the_tokens_it_emitted(traced):
-    from deepspeed_tpu.serving import ServingFrontend
-    eng = _engine()
-    fe = ServingFrontend(eng, megastep_tokens=4)
-    fe.submit(list(range(1, 6)), max_new_tokens=12)
-    for _ in range(4):
-        fe.step()
-    mega = [e["args"] for e in _spans(traced.events(), "serving/dispatch")
-            if e["args"]["program"] == "megastep"]
-    assert mega, [e["args"]["program"] for e in
-                  _spans(traced.events(), "serving/dispatch")]
-    for a in mega:
-        assert 0 < a["tokens"] <= a["slots"]
-        assert a["tokens"] <= a["context_tokens"] <= a["context_slots"]
-
-
 # -- the leaves tile the pump (PR 39) -------------------------------------------
 
 FRONT = ("serving/admit", "serving/plan")
 BACK = ("serving/bookkeeping", "serving/fanout", "serving/bookkeeping")
-#: the leaves of a step that launched AND collected, in order, on either
-#: path: the launch (whose ``serving/retire`` marks the rows scheduled and
-#: continues them), then the fetch of the launch BEFORE it — of its own
-#: launch, in a frontend that plans megasteps — and that one's tokens (a
-#: megastep's work is known only after its fetch, so it is counted there)
+#: the leaves of a step that launched AND collected, in order: the launch
+#: (whose ``serving/retire`` marks the rows scheduled and continues them),
+#: then the fetch of the launch BEFORE it and that one's tokens
 LEAVES = {
     "run": FRONT + ("serving/schedule", "serving/pack", "serving/dispatch",
                     "serving/count", "serving/retire", "serving/fetch",
-                    "serving/retire") + BACK,
-    "megastep": FRONT + ("serving/schedule", "serving/pack",
-                         "serving/dispatch", "serving/fetch",
-                         "serving/count", "serving/retire") + BACK}
+                    "serving/retire") + BACK}
 ENGINE_LEAVES = {"serving/schedule", "serving/pack", "serving/dispatch",
                  "serving/count", "serving/fetch", "serving/retire"}
 #: a step with nothing in flight before its launch: nothing to fan out
@@ -690,20 +656,6 @@ PARENT = {
                      "split_steps_at.16": 2,
                      "steps.decode": 5, "steps.fresh": 1, "steps.split": 2,
                      "token_slots": 56, "tokens": 33}},
-    "megastep": {
-        "launches": [("fresh", 11, 16, 16, 11, 256),
-                     ("split", 9, 16, 16, 20, 256),
-                     ("split", 5, 16, 16, 25, 256),
-                     ("megastep", 7, 8, 8, 111, 256),
-                     ("decode", 1, 1, 1, 25, 128)],
-        "counters": {"attn_row_slots": 57, "chunk_rows": 4,
-                     "context_slots": 1152, "context_tokens": 192,
-                     "host_calls": 5, "kv_write_slots": 57,
-                     "megastep_launches": 1, "megastep_tokens": 7,
-                     "scan_steps": 4, "split_steps_at.16": 2,
-                     "steps.decode": 1, "steps.fresh": 1,
-                     "steps.megastep": 1, "steps.split": 2,
-                     "token_slots": 57, "tokens": 33}},
 }
 PARENT_TOKENS = [[182, 208, 191, 135, 209, 53], [3, 214, 9, 36, 181, 9]]
 HOST_COUNTERS = ("dispatch/host_seconds", "dispatch/fetch_wait_seconds")
@@ -715,11 +667,10 @@ def _dispatch_counters():
             if n.startswith("dispatch/") and n not in HOST_COUNTERS}
 
 
-def _pump(path, steps=9, max_new_tokens=6):
+def _pump(steps=9, max_new_tokens=6):
     """Eight launches in eight steps; the ninth collects the last."""
     from deepspeed_tpu.serving import ServingFrontend
-    fe = ServingFrontend(
-        _engine(), **({"megastep_tokens": 4} if path == "megastep" else {}))
+    fe = ServingFrontend(_engine())
     rng = np.random.default_rng(0)
     reqs = [fe.submit([int(t) for t in p], max_new_tokens=max_new_tokens)
             for p in (rng.integers(1, 255, 20), rng.integers(1, 255, 3))]
@@ -737,7 +688,7 @@ def test_a_step_that_launched_holds_the_leaves_once_in_order(traced, path):
     ``serving/submit`` stands outside every step. The pump that runs ahead
     opens with a step that only launches and closes with one that only
     collects."""
-    _pump(path)
+    _pump()
     events = [e for e in traced.events() if e["ph"] == "X"]
     steps = _spans(events, "serving/step")
     assert len(steps) == 9
@@ -753,25 +704,22 @@ def test_a_step_that_launched_holds_the_leaves_once_in_order(traced, path):
                                      "serving/bookkeeping")
             continue
         if "serving/dispatch" not in order:
-            assert path == "run" and step is steps[-1]
+            assert step is steps[-1]
             assert order == COLLECT_ONLY
             continue
-        (launch,) = (e for e in inside if e["name"] == "serving/dispatch")
-        kind = "megastep" if launch["args"]["program"] == "megastep" \
-            else "run"
         if "serving/fetch" not in order:
-            assert path == "run" and step is steps[0]
+            assert step is steps[0]
             assert order == LAUNCH_ONLY
             continue
-        seen.add(kind)
-        assert order == LEAVES[kind]
+        seen.add(path)
+        assert order == LEAVES[path]
         for a, b in zip(inside, inside[1:]):
             assert a["ts"] + a["dur"] <= b["ts"] + 1e-3, (a, b)
         (engine,) = (e for e in _spans(events, "serving/engine_step")
                      if _inside(e, step))
         for e in inside:
             assert _inside(e, engine) == (e["name"] in ENGINE_LEAVES), e
-    assert seen == ({"run", "megastep"} if path == "megastep" else {"run"})
+    assert seen == {"run"}
     submits = _spans(events, "serving/submit")
     assert len(submits) == 2
     assert not any(_inside(s, step) for s in submits for step in steps)
@@ -806,15 +754,13 @@ def test_each_phase_of_the_pump_runs_under_its_leaf(traced, monkeypatch):
     for attr in ("_kv_window_tokens", "_attn_pairs", "_count_dispatch"):
         stamped(eng, attr, "serving/count")
     stamped(jax_module, "device_get", "serving/fetch")
-    stamped(ServingFrontend, "_pick_megastep", "serving/plan")
     stamped(ServingFrontend, "_update_degraded", "serving/bookkeeping")
     stamped(ServingFrontend, "_fan_out", "serving/fanout")
-    _pump("run", steps=4)
+    _pump(steps=4)
     events = traced.events()
     assert {attr for _leaf, attr, _t in calls} >= {
         "next_batch", "mark_scheduled", "_pack", "_step_fn", "_attn_pairs",
-        "_count_dispatch", "device_get", "_pick_megastep",
-        "_update_degraded", "_fan_out"}
+        "_count_dispatch", "device_get", "_update_degraded", "_fan_out"}
     for leaf, attr, at in calls:
         assert any(s["ts"] <= at <= s["ts"] + s["dur"]
                    for s in _spans(events, leaf)), (attr, leaf)
@@ -832,7 +778,7 @@ def test_launch_arguments_counters_and_tokens_are_the_parents(traced, path):
     PR 39 gave (``attn_row_slots`` and ``chunk_rows`` came with PR 40: in
     the row form what attention works on is ``token_slots``)."""
     before = _dispatch_counters()
-    _fe, reqs = _pump(path)
+    _fe, reqs = _pump()
     after = _dispatch_counters()
     launches = [e["args"] for e in
                 _spans(traced.events(), "serving/dispatch")]
@@ -863,7 +809,7 @@ def test_untraced_launch_computes_no_span_argument_and_still_counts(
             tr.clear()
             del called[:]
             before = _dispatch_counters()
-            _pump("run")
+            _pump()
             after = _dispatch_counters()
             grew[on] = {n: after[n] - before.get(n, 0) for n in after}
             assert len(called) == (8 if on else 0)
