@@ -29,7 +29,8 @@ from jax import lax
 from deepspeed_tpu.config.config_utils import TPUConfigModel
 from deepspeed_tpu.inference.ragged import (DSStateManager, RaggedBatch,
                                             RaggedScheduler)
-from deepspeed_tpu.models.transformer import (DecoderConfig, _mlp, _norm,
+from deepspeed_tpu.models.transformer import (STATE_SPACE_KINDS,
+                                              DecoderConfig, _mlp, _norm,
                                               block_combine,
                                               attn_out_project, embed_tokens,
                                               init_params,
@@ -620,7 +621,7 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     a split step in the heads' space, on the packed tokens. The step's
     shape picks the form: no option does.
 
-    A STATE-SPACE layer (kind 3) has no pages: what its rows carry lives in
+    A STATE-SPACE layer (kinds 3, 4) has no pages: what its rows carry lives in
     the state pools (``ops/ssm.init_state_pools``), a slot a sequence
     (``slots``), read and written by every launch that holds the row, in
     every mode; a row at position 0 starts from zero whatever its slot
@@ -644,7 +645,7 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     seen = dict.fromkeys(of_kind, 0)
     places = []     # a layer's pools, and where its pages lie in them
     for kind in cfg.layer_kinds:
-        if kind == 3:       # the layer's own two pools (ops/ssm.py)
+        if kind in STATE_SPACE_KINDS:   # the layer's own two pools
             places.append(ssm.pool_names(seen[kind]))
             seen[kind] += 1
             continue
@@ -767,10 +768,10 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             out = lay.to_tokens(out)
         return out if own else tl.latent_expand_out(cfg, a, out)
 
-    def state_space(lay, p, place, h_in, pools):
-        """A state-space layer's mixer on its normed input (token-wise
-        form) → its output in the same form; ``pools`` holds the layer's
-        two state pools (``place``: their names), which it reads and
+    def state_space(lay, kind, p, place, h_in, pools):
+        """A state-space layer's mixer (``tl.mixer_forms`` of its ``kind``)
+        on its normed input (token-wise form) → its output in the same
+        form; ``pools`` holds the layer's two state pools (``place``: their names), which it reads and
         writes. Rows of ONE query first, in SLOT order: the layer's whole
         pool takes one elementwise pass (a slot with no live row has ``Δ =
         0`` and keeps its state; a wide row's is reset or left as it is),
@@ -788,7 +789,8 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             # GB of temporaries at Granite 4.0-H's widths)
             pools[sname], pools[cname], h_in = lax.optimization_barrier(
                 (pools[sname], pools[cname], h_in))
-        z, xbc, dt = tl.ssm_in(cfg, p, h_in)
+        forms = tl.mixer_forms(kind, use_pallas)
+        z, xbc, dt = forms.project(cfg, p, h_in)
         fresh_row = ssm.fresh_rows(starts)
         groups = lay.groups()
         outs = [None] * len(groups)
@@ -801,12 +803,14 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                 if group.c > 1:
                     state = ssm.carried(pools[sname][at], reset)
             with jax.named_scope("ssm_conv"):
-                u, tail = ssm.conv_rows(cfg, p, group.take(xbc), tail, live)
-                dt_g = group.take(dt)
+                u, tail = ssm.conv_rows(cfg, p, group.take(xbc), tail, live,
+                                        forms.conv_dtype)
+                dt_g = jax.tree.map(group.take, dt)
+            dt_g = forms.inputs(cfg, p, u, dt_g, live)
             if group.c > 1:
                 with jax.named_scope("ssm_scan"):
-                    outs[i], state = ssm.scan_chunk(cfg, p, u, dt_g, state,
-                                                    live)
+                    outs[i], state = forms.chunk(cfg, p, u, dt_g, state,
+                                                 live)
                 with jax.named_scope("ssm_state"):
                     to = at if group.ids is None else jnp.where(
                         live > 0, at, region)
@@ -823,18 +827,18 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                     return jnp.zeros((region,) + rows.shape[1:],
                                      rows.dtype).at[at].set(rows)
 
-                u, dt_g, live, reset = (by_slot(t)
-                                        for t in (u, dt_g, live, reset))
+                u, dt_g, live, reset = jax.tree.map(
+                    by_slot, (u, dt_g, live, reset))
             with jax.named_scope("ssm_scan"):
                 # (the reset rides in the decay: ``ssm.carried`` over the
                 # pool would be a second pass over it)
-                y, pools[sname] = ssm.scan_step(cfg, p, u, dt_g,
-                                                pools[sname], live, reset)
+                y, pools[sname] = forms.step(cfg, p, u, dt_g, pools[sname],
+                                             live, reset)
             with jax.named_scope("ssm_state"):
                 outs[i] = y[at]
         with jax.named_scope("ssm_scan"):
             y = lay.from_groups(outs)
-        return tl.ssm_out(cfg, p, y, z)
+        return forms.out(cfg, p, y, z)
 
     carried = tuple(name for name in arena if ssm.is_state_pool(name))
 
@@ -854,9 +858,9 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         for kind, lp, place in zip(cfg.layer_kinds, params["layers"],
                                    places):
             h = _norm(cfg, lp["ln1"], x)
-            if kind == 3:
-                out = state_space(lay, lp["ssm"], place, h.astype(dtype),
-                                  pools)
+            if kind in STATE_SPACE_KINDS:
+                out = state_space(lay, kind, lp["ssm"], place,
+                                  h.astype(dtype), pools)
             elif kind < 0:
                 out = None
             else:

@@ -34,7 +34,7 @@ _FAMILIES = ("llama", "mistral", "mixtral", "qwen", "qwen2", "qwen2_moe",
               "gpt_neox", "gemma", "gpt2", "opt", "bloom", "falcon",
               "phi", "phi3", "gpt_bigcode", "gptj", "bert", "distilbert",
               "gpt_neo", "internlm", "mimo_v2", "deepseek_v3",
-              "cohere2_moe", "nemotron_h", "granitemoehybrid")
+              "cohere2_moe", "nemotron_h", "granitemoehybrid", "jamba")
 
 
 def _map_hf_act(act: str) -> str:
@@ -64,6 +64,8 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
         return _nemotron_h_config(hf)
     if mt == "granitemoehybrid":
         return _granitemoehybrid_config(hf)
+    if mt == "jamba":
+        return _jamba_config(hf)
     if mt == "bert":
         return DecoderConfig(
             hidden_size=hf["hidden_size"],
@@ -767,6 +769,54 @@ def _granitemoehybrid_config(hf: Dict[str, Any]) -> DecoderConfig:
         if hf.get("attention_multiplier") is not None else None)
 
 
+def _jamba_config(hf: Dict[str, Any]) -> DecoderConfig:
+    """Jamba's stack (AI21 Jamba2-3B; ``model_type: jamba``): a typed stack
+    (models/typed_layers.py has the equations) whose EVERY layer is a mixer
+    AND a dense SiLU-GLU of ``intermediate_size`` under two RMSNorms
+    (``rms_norm_eps``). Layer ``l`` is attention where ``l %
+    attn_layer_period == attn_layer_offset`` (``num_attention_heads`` /
+    ``num_key_value_heads`` heads of hidden / heads, NO positional term: the
+    family builds none), else a Mamba-1 SELECTIVE-SCAN mixer (kind 4):
+    ``mamba_expand`` x hidden channels, ``mamba_d_state`` states a channel,
+    a step size a channel through ``mamba_dt_rank``, a convolution of
+    ``mamba_d_conv`` taps over the channels alone, RMSNorms on the step's
+    bottleneck, ``B`` and ``C``. ``num_experts: 1`` is the family's dense
+    MLP in every layer (``expert_layer_offset`` / ``expert_layer_period``
+    then choose nothing). Refused by name: ``num_experts`` above 1 (the
+    family's sparse layers are not built), projection biases, a convolution
+    without its bias, another activation, a sliding window."""
+    fam = "jamba"
+    for key, want in (("mamba_proj_bias", False), ("mamba_conv_bias", True),
+                      ("hidden_act", "silu"), ("sliding_window", None),
+                      ("num_experts", 1), ("num_experts_per_tok", 1)):
+        if hf.get(key, want) != want:
+            raise ValueError(f"{fam}: {key}={hf[key]!r} is not built "
+                             f"(expected {want!r})")
+    L = int(hf["num_hidden_layers"])
+    period, offset = int(hf["attn_layer_period"]), int(hf["attn_layer_offset"])
+    return DecoderConfig(
+        hidden_size=hf["hidden_size"], num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        intermediate_size=int(hf["intermediate_size"]),
+        vocab_size=hf["vocab_size"],
+        max_seq_len=hf.get("max_position_embeddings", 262144),
+        norm="rmsnorm", activation="silu_glu", pos_emb="rope",
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        full_attn_rope=False, use_bias=False,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        layer_kinds=tuple(0 if l % period == offset else 4
+                          for l in range(L)),
+        layer_sparse=(0,) * L,
+        # (one "expert" a token: the dense MLP; ``layer_sparse`` is what the
+        # layers read, and no router is built)
+        num_experts=1, num_experts_per_tok=1,
+        ssm_inner_size=int(hf["mamba_expand"]) * int(hf["hidden_size"]),
+        ssm_state_size=int(hf["mamba_d_state"]),
+        ssm_dt_rank=int(hf["mamba_dt_rank"]),
+        ssm_conv_kernel=int(hf["mamba_d_conv"]))
+
+
 def _is_gemma_layout(cfg: DecoderConfig) -> bool:
     return cfg.activation == "gelu_glu" and cfg.scale_embeddings
 
@@ -807,7 +857,8 @@ def config_to_hf(cfg: DecoderConfig) -> Dict[str, Any]:
     if cfg.typed:
         raise NotImplementedError(
             "config_to_hf: a typed layer stack (mimo_v2, deepseek_v3, "
-            "cohere2_moe, nemotron_h, granitemoehybrid) has no exporter")
+            "cohere2_moe, nemotron_h, granitemoehybrid, jamba) has no "
+            "exporter")
     if not cfg.causal or not cfg.prenorm:
         # encoder layouts (BERT/DistilBERT): both flags flip together
         if cfg.causal or cfg.prenorm or cfg.pos_emb != "learned" \

@@ -41,6 +41,11 @@ from jax.sharding import PartitionSpec as P
 Params = Dict[str, Any]
 
 
+#: the layer kinds whose mixer carries a state a sequence (ops/ssm.py): 3 a
+#: Mamba-2 mixer, 4 a Mamba-1 selective scan
+STATE_SPACE_KINDS = (3, 4)
+
+
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     vocab_size: int = 50304
@@ -156,7 +161,10 @@ class DecoderConfig:
     #: MLA: the ``*_lora_rank`` / ``qk_*_head_dim`` widths below; a latent
     #: stack is all latent), 3 = a Mamba-2 state-space mixer (the ``ssm_*``
     #: widths below; what it carries is a fixed-size state a sequence, not
-    #: pages), -1 = NO mixer: the layer is its feed-forward part alone,
+    #: pages), 4 = a Mamba-1 SELECTIVE-SCAN mixer (Jamba's: a step size a
+    #: channel through ``ssm_dt_rank``, ``ssm_inner_size`` channels of
+    #: ``ssm_state_size`` states; a stack has kind 3 or kind 4, not both),
+    #: -1 = NO mixer: the layer is its feed-forward part alone,
     #: under the layer's one norm (Nemotron-H's ``E`` layers).
     #: Set → the stack is NOT one
     #: scanned block: ``params["layers"]`` is a list of per-layer trees
@@ -232,6 +240,12 @@ class DecoderConfig:
     ssm_groups: int = 1
     ssm_state_size: int = 0
     ssm_conv_kernel: int = 4
+    # -- selective-scan layers (kind 4; Mamba-1): ``ssm_inner_size`` channels
+    # with NO heads and NO groups — every channel its own step size, through
+    # a bottleneck of ``ssm_dt_rank`` — of ``ssm_state_size`` states each; the
+    # convolution runs over the channels alone
+    ssm_inner_size: int = 0
+    ssm_dt_rank: int = 0
     # -- Granite's four scalar multipliers (``hf_loader``:
     # ``granitemoehybrid``); 1.0 / None add no operation to a program --
     #: the token embedding times this on the way in (a free factor:
@@ -270,13 +284,20 @@ class DecoderConfig:
                 "latent attention (layer kind 2) needs q_lora_rank, "
                 "kv_lora_rank, qk_nope_head_dim and qk_rope_head_dim, and a "
                 "stack whose layers are all latent")
-        if self.recurrent and not (
+        if self.typed and 3 in self.layer_kinds and not (
                 self.ssm_heads and self.ssm_head_dim and self.ssm_state_size
                 and self.ssm_heads % self.ssm_groups == 0):
             raise ValueError(
                 "a state-space layer (layer kind 3) needs ssm_heads, "
                 "ssm_head_dim and ssm_state_size, and ssm_groups has to "
                 "divide ssm_heads")
+        if self.selective and not (
+                self.ssm_inner_size and self.ssm_dt_rank and
+                self.ssm_state_size and 3 not in self.layer_kinds):
+            raise ValueError(
+                "a selective-scan layer (layer kind 4) needs ssm_inner_size, "
+                "ssm_dt_rank and ssm_state_size, and a stack without layers "
+                "of kind 3 (the ssm_* widths describe one kind of scan)")
         if self.layer_kinds is not None and any(
                 kind == -1 and not self.layer_has_ffn(l)
                 for l, kind in enumerate(self.layer_kinds)):
@@ -306,20 +327,31 @@ class DecoderConfig:
         return self.typed and 2 in self.layer_kinds
 
     @property
+    def selective(self) -> bool:
+        """The stack's state-space layers are Mamba-1 selective scans
+        (kind 4)."""
+        return self.typed and 4 in self.layer_kinds
+
+    @property
     def recurrent(self) -> bool:
-        """The stack holds state-space layers (kind 3): a sequence carries
-        a recurrent state beside its pages, and a prefix of its pages alone
-        is NOT a prefix of the sequence."""
-        return self.typed and 3 in self.layer_kinds
+        """The stack holds state-space layers (``STATE_SPACE_KINDS``): a
+        sequence carries a recurrent state beside its pages, and a prefix of
+        its pages alone is NOT a prefix of the sequence."""
+        return self.typed and any(kind in STATE_SPACE_KINDS
+                                  for kind in self.layer_kinds)
 
     @property
     def ssm_inner(self) -> int:
-        """A state-space mixer's inner width ``d = H·P``."""
-        return self.ssm_heads * self.ssm_head_dim
+        """A state-space mixer's inner width: ``d = H·P``, or a selective
+        scan's channels."""
+        return self.ssm_inner_size or self.ssm_heads * self.ssm_head_dim
 
     @property
     def ssm_conv_dim(self) -> int:
-        """What the mixer's convolution runs over: ``[x | B | C]``."""
+        """What the mixer's convolution runs over: ``[x | B | C]``; a
+        selective scan's: the channels alone."""
+        if self.ssm_inner_size:
+            return self.ssm_inner_size
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
 
     @property
@@ -442,8 +474,8 @@ class DecoderConfig:
             mlp = 3 * d * h
         else:
             mlp = 2 * d * h
+        dense_mlp = mlp
         if self.num_experts:
-            dense_mlp = mlp
             mlp = mlp * self.num_experts + d * self.num_experts  # + router
             if self.shared_expert_size:
                 mlp += 3 * d * self.shared_expert_size \
@@ -460,9 +492,18 @@ class DecoderConfig:
                 + self.ssm_inner * d + self.ssm_inner \
                 + self.ssm_conv_dim * (self.ssm_conv_kernel + 1) \
                 + 3 * self.ssm_heads
+            if self.selective:
+                # in / out, the convolution, W_x and its three norms, W_dt
+                # and its bias, A and D
+                di, n, r = self.ssm_inner, self.ssm_state_size, \
+                    self.ssm_dt_rank
+                ssm = 3 * d * di + di * (self.ssm_conv_kernel + 1) \
+                    + (di + 1) * (r + 2 * n) + (r + 1) * di + di * (n + 1)
             layers = sum(
-                (ssm if kind == 3 else attn if kind >= 0 else 0)
-                + (mlp + d if self.layer_has_ffn(i) else 0)
+                (ssm if kind in STATE_SPACE_KINDS else attn if kind >= 0
+                 else 0)
+                + ((mlp if self.layer_is_sparse(i) else dense_mlp) + d
+                   if self.layer_has_ffn(i) else 0)
                 + (d if kind >= 0 or not self.layer_has_ffn(i) else 0)
                 for i, kind in enumerate(self.layer_kinds))
         emb = v * d + (self.max_seq_len * d if self.pos_emb == "learned"

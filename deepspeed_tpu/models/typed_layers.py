@@ -1,11 +1,11 @@
 """Typed layer stacks: a decoder whose layers are NOT one scanned block.
 
 ``DecoderConfig.layer_kinds`` names each layer's mixer (0 = full causal
-attention, 1 = window, 2 = latent, 3 = a Mamba-2 state-space mixer, -1 =
-none) and ``layer_sparse`` its feed-forward part (1 = sparse experts, 0 = a
-dense MLP: leading dense layers, -1 = none). The kinds differ in
-SHAPE — KV heads, rotary base, a learned sink on the window kind, the dense
-width — so the layers cannot share one stacked tree: ``params["layers"]``
+attention, 1 = window, 2 = latent, 3 = a Mamba-2 state-space mixer, 4 = a
+Mamba-1 selective-scan mixer, -1 = none) and ``layer_sparse`` its
+feed-forward part (1 = sparse experts, 0 = a dense MLP, -1 = none). The
+kinds differ in SHAPE — KV heads, rotary base, a learned sink on the window
+kind, the dense width — so they share no stacked tree: ``params["layers"]``
 is a list of per-layer trees and the layer loop is unrolled. The first
 family built this way is MiMo-V2 (``hf_loader``: ``mimo_v2``); the
 equations, for layer ``l`` of kind ``a``:
@@ -104,6 +104,29 @@ A state-space layer with a feed-forward part has ``ln2`` and ``moe``
 (``shared``) beside ``ssm`` in its tree: :func:`block_residual` and the
 engine's layer loop take both parts by the tree's shape.
 
+A SELECTIVE-SCAN stack (Jamba's, ``hf_loader``: ``jamba``) is the two-part
+layer again — a mixer AND a feed-forward part under two RMSNorms, no
+multipliers — with a DENSE SiLU-GLU in every layer (``layer_sparse`` 0) and,
+beside a few attention layers (kind 0, no positional term, ``1/√Dk``), the
+Mamba-1 mixer (kind 4; ``ops/ssm.py``'s last section): ``d =
+ssm_inner_size`` channels, NO heads and NO groups, ``N = ssm_state_size``,
+``R = ssm_dt_rank``, ``K = ssm_conv_kernel``:
+
+- ``[x′ | z] = h·W_in`` (``x′`` FIRST: the published module's order);
+  ``u_t = silu(Σ_{i<K} w[:, i]·x′_{t−K+1+i} + b)``: the convolution over
+  ``x′`` ALONE;
+- ``[δ_t | B_t | C_t] = u_t·W_x`` (widths ``R``, ``N``, ``N``), each under
+  its own RMSNorm with a learned scale; ``Δ_t = softplus(δ_t·W_dt +
+  b_dt)``: a step size a CHANNEL (scope ``ssm_select``);
+- ``S_t[n, d] = exp(Δ_t[d]·A[n, d])·S_{t−1}[n, d] + Δ_t[d]·u_t[d]·B_t[n]``,
+  ``A = −exp(A_log)`` (float32; the tree holds ``A_log`` as ``[N, d]``:
+  channels on the lanes); ``y_t[d] = Σ_n S_t[n, d]·C_t[n] + D[d]·u_t[d]``;
+- out ``(y_t ⊙ silu(z_t))·W_out``: a gate and NO norm (scope ``ssm_norm``
+  holds the gate).
+
+A sequence carries ``S`` (``[N, d]`` float32) and the last ``K − 1`` rows
+of ``x′``, in the same pools, slots and resets as kind 3.
+
 **The residual stream is float32** whatever the parameters' dtype
 (:func:`residual_stream`): the matmuls take the norms' outputs cast to the
 compute dtype, their results are added in float32, and the router reads
@@ -122,8 +145,9 @@ This module is the uncached forward (``transformer.forward`` routes here)
 and the pieces the paged engine shares with it (``engine_v2``)."""
 
 import dataclasses
+import functools
 import math
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -142,7 +166,9 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     the model has one, ``shared`` {wg, wi, wo}), ``final_norm``, and
     ``lm_head`` unless the head is tied to ``embed``. A state-space layer
     has ``ssm`` {w_in, conv_w, conv_b, dt_bias, A_log, D, norm, w_out} in
-    place of ``attn``; a layer with no mixer has neither; a layer with no
+    place of ``attn`` (a selective scan's: {w_in, conv_w, conv_b, w_x,
+    dt_norm, b_norm, c_norm, w_dt, dt_bias, A_log, D, w_out}); a layer with
+    no mixer has neither; a layer with no
     feed-forward part has no ``mlp`` / ``moe``; un-gated (``relu2``)
     experts have no ``wg``."""
     if not (cfg.is_glu or cfg.activation == "relu2") or cfg.use_bias or \
@@ -174,6 +200,8 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
         lp = {"ln1": tf._norm_params(cfg)}
         if kind == 3:
             lp["ssm"] = _init_ssm(cfg, w, next(keys), out_std)
+        elif kind == 4:
+            lp["ssm"] = _init_selective(cfg, w, next(keys), out_std)
         elif kind == 2:
             ql, kl, nope = cfg.q_lora_rank, cfg.kv_lora_rank, \
                 cfg.qk_nope_head_dim
@@ -244,6 +272,30 @@ def _init_ssm(cfg, w, key, out_std: float):
             "A_log": jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32)),
             "D": jnp.ones((h,), jnp.float32),
             "norm": {"scale": jnp.ones((d,), jnp.float32)},
+            "w_out": w((d, cfg.hidden_size), out_std)}
+
+
+def _init_selective(cfg, w, key, out_std: float):
+    """A selective-scan layer's tree. As Mamba-1 initialises them: ``A[n,
+    :] = n + 1``, ``D = 1``, ``dt_bias`` the inverse softplus of a step
+    drawn log-uniformly in [0.001, 0.1] a channel, ``W_dt`` at ``R **
+    -0.5`` (the step sizes then differ from token to token by a factor of
+    e: selection is exercised); the convolution's taps and bias at ``K **
+    -0.5`` (:func:`_init_ssm` says why); the three inner norms at 1."""
+    d, n, r, k = cfg.ssm_inner, cfg.ssm_state_size, cfg.ssm_dt_rank, \
+        cfg.ssm_conv_kernel
+    step = jnp.exp(jax.random.uniform(key, (d,), jnp.float32,
+                                      math.log(1e-3), math.log(1e-1)))
+    scale = lambda width: {"scale": jnp.ones((width,), jnp.float32)}
+    return {"w_in": w((cfg.hidden_size, 2 * d)),
+            "conv_w": w((d, k), k ** -0.5), "conv_b": w((d,), k ** -0.5),
+            "w_x": w((d, r + 2 * n)), "dt_norm": scale(r),
+            "b_norm": scale(n), "c_norm": scale(n),
+            "w_dt": w((r, d), r ** -0.5),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                1, n + 1, dtype=jnp.float32))[:, None], (n, d)),
+            "D": jnp.ones((d,), jnp.float32),
             "w_out": w((d, cfg.hidden_size), out_std)}
 
 
@@ -424,8 +476,24 @@ def block_residual(cfg, lp, x: jax.Array, h: jax.Array,
     return x + ffn(tf._norm(cfg, lp["ln2"], x))
 
 
+def _linear_f32(x: jax.Array, p, name: str) -> jax.Array:
+    """``x·p[name]`` left in FLOAT32, as the MXU accumulated it (a
+    quantized matrix: :func:`tf.linear_2d`'s result). The SELECTIVE mixer's
+    projections: what they feed is float32 arithmetic — the gate, the
+    norms, the step's softplus, the residual stream — and a result rounded
+    to bf16 on its way there adds an error of 2^-9. Jamba2-3B is the first
+    stack served at its FULL depth, 56 branch sums deep: with bf16 results
+    the program's worst token lay 0.20 under the float32 reference's argmax
+    for the serve runner's limit of 0.25, with these four in float32
+    0.11–0.18 (PERF.md §6, PR 49)."""
+    if name + "_scale" in p:
+        return tf.linear_2d(x, p, name).astype(jnp.float32)
+    return jnp.einsum("...k,kn->...n", x, p[name],
+                      preferred_element_type=jnp.float32)
+
+
 def ssm_in(cfg, p, h: jax.Array):
-    """A state-space layer's input projection, token-wise: h [.., D] →
+    """A Mamba-2 layer's input projection, token-wise: h [.., D] →
     (z [.., d], xBC [.., d + 2GN], dt [.., H])."""
     with jax.named_scope("ssm_in"):
         return ssm.split_in(cfg, tf.linear_2d(h, p, "w_in"))
@@ -440,14 +508,82 @@ def ssm_out(cfg, p, y: jax.Array, z: jax.Array) -> jax.Array:
         return tf.linear_2d(o, p, "w_out")
 
 
-def ssm_rows(cfg, p, xbc: jax.Array, dt: jax.Array, tail: jax.Array,
-             state: jax.Array, counts: jax.Array):
+def selective_in(cfg, p, h: jax.Array):
+    """A selective-scan layer's input projection, token-wise: h [.., D] →
+    (z [.., d] float32, x′ [.., d] in h's dtype — what its convolution's
+    pool holds —, (): what its scan takes beside ``u`` comes from the
+    convolved channels, :func:`ssm_select`)."""
+    with jax.named_scope("ssm_in"):
+        z, x = ssm.selective_split(cfg, _linear_f32(h, p, "w_in"))
+        return z, x.astype(h.dtype), ()
+
+
+def selective_out(cfg, p, y: jax.Array, z: jax.Array) -> jax.Array:
+    """The gate (no norm: scope ``ssm_norm`` holds what is left of it) and
+    the output projection, token-wise → [.., D] float32, as the stream it
+    joins."""
+    with jax.named_scope("ssm_norm"):
+        o = ssm.selective_gate(y, z, p["w_out"].dtype)
+    with jax.named_scope("ssm_out"):
+        return _linear_f32(o, p, "w_out")
+
+
+def ssm_select(cfg, p, u: jax.Array, _rows, counts: jax.Array):
+    """A selective scan's inputs from the convolved channels, token-wise: u
+    [m, c, d] (float32: the scan reads it as the convolution left it; the
+    matmul takes it in the weights' dtype) → (Δ [m, c, d] float32 — 0 past a
+    row's ``counts`` —, B, C [m, c, N] float32): ``W_x``, the three norms,
+    ``W_dt``, softplus."""
+    with jax.named_scope("ssm_select"):
+        dtype = p["w_x"].dtype
+        delta, b, c = ssm.select_norms(
+            cfg, p, _linear_f32(u.astype(dtype), p, "w_x"), dtype)
+        return ssm.step_sizes(p, _linear_f32(delta, p, "w_dt"), counts), \
+            b, c
+
+
+class MixerForms(NamedTuple):
+    """What a state-space layer is made of, by its kind of scan (THE place
+    that tells kind 3 from kind 4; the pools' shape is ``ssm.state_shape``).
+    Both kinds share the pools, slots and resets, the convolution
+    (``ssm.conv_rows``) and the two row groups of a step."""
+    #: (cfg, p, h) → (the gate z, the convolution's input, what the scan
+    #: takes beside ``u`` that is made token-wise: a tree of [.., w])
+    project: Callable
+    #: the convolved channels' dtype (None: the input's)
+    conv_dtype: Any
+    #: (cfg, p, u, that tree's rows, counts) → what the scan takes beside u
+    inputs: Callable
+    #: (cfg, p, u, inputs, state, counts[, reset]) → (y, state): one
+    #: position a row, and a chunk from a carried state
+    step: Callable
+    chunk: Callable
+    #: (cfg, p, y, z) → the mixer's output
+    out: Callable
+
+
+def mixer_forms(kind: int, kernel: bool = False) -> MixerForms:
+    """``kernel``: the selective scan's chunk form as its Pallas kernel."""
+    if kind == 4:
+        return MixerForms(selective_in, jnp.float32, ssm_select,
+                          ssm.selective_step, functools.partial(
+                              ssm.selective_chunk, kernel=kernel),
+                          selective_out)
+    return MixerForms(ssm_in, None, lambda cfg, p, u, dt, counts: dt,
+                      ssm.scan_step, ssm.scan_chunk, ssm_out)
+
+
+def ssm_rows(forms: MixerForms, cfg, p, xbc: jax.Array, dt,
+             tail: jax.Array, state: jax.Array, counts: jax.Array):
     """Convolution and scan of ROWS [m, c, ..] from what they carried in →
-    (y [m, c, d] float32, the tail and the state they carry on)."""
+    (y [m, c, d] float32, the tail and the state they carry on), the scan in
+    the form the rows' width picks."""
     with jax.named_scope("ssm_conv"):
-        u, tail = ssm.conv_rows(cfg, p, xbc, tail, counts)
+        u, tail = ssm.conv_rows(cfg, p, xbc, tail, counts, forms.conv_dtype)
+    dt = forms.inputs(cfg, p, u, dt, counts)
     with jax.named_scope("ssm_scan"):
-        y, state = ssm.scan_rows(cfg, p, u, dt, state, counts)
+        scan = forms.step if u.shape[1] == 1 else forms.chunk
+        y, state = scan(cfg, p, u, dt, state, counts)
     return y, tail, state
 
 
@@ -455,11 +591,12 @@ def ssm_rows(cfg, p, xbc: jax.Array, dt: jax.Array, tail: jax.Array,
 SSM_CHUNK = 128
 
 
-def _ssm_mixer(cfg, p, h: jax.Array) -> jax.Array:
+def _ssm_mixer(cfg, kind: int, p, h: jax.Array) -> jax.Array:
     """Uncached: whole sequences [B, T, D] from a zero state, ``SSM_CHUNK``
     positions at a time, the tail and the state carried between them."""
     b, t = h.shape[:2]
-    z, xbc, dt = ssm_in(cfg, p, h)
+    forms = mixer_forms(kind)
+    z, xbc, dt = forms.project(cfg, p, h)
     c = min(t, SSM_CHUNK)
     steps = -(-t // c)
 
@@ -470,17 +607,16 @@ def _ssm_mixer(cfg, p, h: jax.Array) -> jax.Array:
     def step(carry, inp):
         xbc_c, dt_c, i = inp
         counts = jnp.full((b,), jnp.clip(t - i * c, 0, c), jnp.int32)
-        y, *carry = ssm_rows(cfg, p, xbc_c, dt_c, *carry, counts)
+        y, *carry = ssm_rows(forms, cfg, p, xbc_c, dt_c, *carry, counts)
         return tuple(carry), y
 
     carry = (jnp.zeros((b, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim),
                        h.dtype),
-             jnp.zeros((b, cfg.ssm_heads, cfg.ssm_head_dim,
-                        cfg.ssm_state_size), jnp.float32))
-    _, y = jax.lax.scan(step, carry, (chunks(xbc), chunks(dt),
+             jnp.zeros((b,) + ssm.state_shape(cfg), jnp.float32))
+    _, y = jax.lax.scan(step, carry, (chunks(xbc), jax.tree.map(chunks, dt),
                                       jnp.arange(steps, dtype=jnp.int32)))
     y = y.swapaxes(0, 1).reshape(b, steps * c, -1)[:, :t]
-    return ssm_out(cfg, p, y, z)
+    return forms.out(cfg, p, y, z)
 
 
 def _attention(cfg, kind: int, sink, q, k, v) -> jax.Array:
@@ -508,10 +644,10 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
     for kind, lp in zip(cfg.layer_kinds, params["layers"]):
         h32 = tf._norm(cfg, lp["ln1"], x)
         h = h32.astype(dtype)
-        if kind in (3, -1):
+        if kind in tf.STATE_SPACE_KINDS or kind == -1:
             x = block_residual(
-                cfg, lp, x, h32, _ssm_mixer(cfg, lp["ssm"], h)
-                if kind == 3 else None, moe_fn, None, dtype)
+                cfg, lp, x, h32, _ssm_mixer(cfg, kind, lp["ssm"], h)
+                if kind >= 0 else None, moe_fn, None, dtype)
             continue
         if kind == 2:       # the expanded form: nothing is cached here
             q, k, v = latent_expand_kv(cfg, lp["attn"], *latent_qkv(

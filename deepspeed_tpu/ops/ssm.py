@@ -1,7 +1,10 @@
-"""Mamba-2 state-space mixer: the pieces a typed layer stack's kind-3
-layers are made of (``models/typed_layers.py`` has the equations, the
-uncached forward and the parameter tree; ``inference/engine_v2.py`` the
-served form over the state pools).
+"""State-space mixers: the pieces a typed layer stack's kind-3 (Mamba-2)
+and kind-4 (Mamba-1, the SELECTIVE scan; the last section) layers are made
+of (``models/typed_layers.py`` has the equations, the uncached forward and
+the parameter trees; ``inference/engine_v2.py`` the served form over the
+state pools). What follows describes Mamba-2; a selective scan shares the
+pools, the carry and the convolution, and has its own split, scan and gate
+in its section (``typed_layers.mixer_forms`` picks by the layer's kind).
 
 Plain ``jax.numpy``. What works on a token alone (the input and output
 projections, the gated norm) takes any leading shape; the convolution and
@@ -25,11 +28,14 @@ The state pools (:func:`init_state_pools`) hold a slot a sequence, ONE
 pool a state-space layer: a launch's one-token pass rewrites a layer's
 whole pool, and a buffer of its own bounds what the compiler may copy."""
 
+import functools
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 #: the state pools' names in an engine's arena, beside the KV pools': the
@@ -52,7 +58,8 @@ def is_state_pool(name: str) -> bool:
 def init_state_pools(cfg, slots: int, dtype) -> Dict[str, jax.Array]:
     """``{"ssm<i>": [slots + 1, H, P, N] float32, "conv<i>": [slots + 1,
     (K − 1)·(d + 2GN)] dtype}`` for the ``i``-th of the state-space layers
-    of ``cfg``: sequence slot ``s`` at row ``s``, the last row the trash
+    of ``cfg`` (a selective scan's: :func:`state_shape`, and ``(K − 1)·d``
+    inputs): sequence slot ``s`` at row ``s``, the last row the trash
     (padding rows of a step). A POOL A LAYER, not one flat pool as the KV
     pools are: a step's one-token pass rewrites a layer's whole pool, and
     where the compiler cannot show an update to be in place it copies the
@@ -63,13 +70,22 @@ def init_state_pools(cfg, slots: int, dtype) -> Dict[str, jax.Array]:
     dimension of 3 would be padded to a tile of 8, and the compiler relaid
     the pool on its way in and out of every program)."""
     pools = {}
-    for i in range(sum(1 for kind in cfg.layer_kinds if kind == 3)):
+    for i in range(sum(1 for kind in cfg.layer_kinds if kind in (3, 4))):
         state, conv = pool_names(i)
-        pools[state] = jnp.zeros((slots + 1, cfg.ssm_heads, cfg.ssm_head_dim,
-                                  cfg.ssm_state_size), jnp.float32)
+        pools[state] = jnp.zeros((slots + 1,) + state_shape(cfg),
+                                 jnp.float32)
         pools[conv] = jnp.zeros((slots + 1, (cfg.ssm_conv_kernel - 1) *
                                  cfg.ssm_conv_dim), dtype)
     return pools
+
+
+def state_shape(cfg) -> Tuple[int, ...]:
+    """What ONE sequence carries in a state-space layer, float32: ``[H, P,
+    N]`` (Mamba-2), or a selective scan's ``[N, d]`` — the channels on the
+    lanes, 40 tiles of 128 at Jamba2-3B's 5,120, and not its 16 states."""
+    if cfg.selective:
+        return (cfg.ssm_state_size, cfg.ssm_inner)
+    return (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size)
 
 
 def fresh_rows(starts: jax.Array) -> jax.Array:
@@ -99,13 +115,13 @@ def split_in(cfg, zxbcdt: jax.Array
     return zxbcdt[..., :d], zxbcdt[..., d:d + cd], zxbcdt[..., d + cd:]
 
 
-def conv_rows(cfg, p, xbc: jax.Array, tail: jax.Array, counts: jax.Array
-              ) -> Tuple[jax.Array, jax.Array]:
+def conv_rows(cfg, p, xbc: jax.Array, tail: jax.Array, counts: jax.Array,
+              dtype=None) -> Tuple[jax.Array, jax.Array]:
     """The causal depthwise convolution over time, then SiLU: xbc
     [m, c, Cd] after the rows' carried ``tail`` [m, K − 1, Cd] → (u
-    [m, c, Cd], the tail each row carries on: the ``K − 1`` inputs that end
-    at its last live position; a row with no live position keeps its
-    own)."""
+    [m, c, Cd] in ``dtype`` — None: xbc's —, the tail each row carries on:
+    the ``K − 1`` inputs that end at its last live position; a row with no
+    live position keeps its own)."""
     k = cfg.ssm_conv_kernel
     c = xbc.shape[1]
     seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
@@ -114,7 +130,7 @@ def conv_rows(cfg, p, xbc: jax.Array, tail: jax.Array, counts: jax.Array
     for i in range(k):      # u_t = Σ_i w[:, i]·seq[t + i] (seq[t + K − 1]
         acc = acc + seq[:, i:i + c].astype(jnp.float32) * w[:, i]   # is x_t)
     at = counts[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None]
-    return jax.nn.silu(acc).astype(xbc.dtype), \
+    return jax.nn.silu(acc).astype(dtype or xbc.dtype), \
         jnp.take_along_axis(seq, at[..., None], axis=1)
 
 
@@ -196,12 +212,6 @@ def scan_chunk(cfg, p, u: jax.Array, dt: jax.Array, state: jax.Array,
     return y.reshape(m, c, cfg.ssm_inner), s_out.reshape(state.shape)
 
 
-def scan_rows(cfg, p, u, dt, state, counts):
-    """The scan in the form the rows' width picks."""
-    scan = scan_step if u.shape[1] == 1 else scan_chunk
-    return scan(cfg, p, u, dt, state, counts)
-
-
 def gated_norm(cfg, p, y: jax.Array, z: jax.Array, dtype) -> jax.Array:
     """``w ⊙ GroupRMS(y ⊙ silu(z))``: the gate BEFORE the norm, the norm in
     ``G`` groups of ``d / G``, float32."""
@@ -210,3 +220,191 @@ def gated_norm(cfg, p, y: jax.Array, z: jax.Array, dtype) -> jax.Array:
     var = jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
     normed = (grouped * lax.rsqrt(var + cfg.norm_eps)).reshape(gated.shape)
     return (normed * p["norm"]["scale"].astype(jnp.float32)).astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# The selective scan (Mamba-1; kind 4)
+# ---------------------------------------------------------------------------
+#
+# ``d`` channels of ``N`` states with NO heads: the decay is ``exp(Δ_t[d] ·
+# A[n, d])``, a value a channel AND state, so a chunk is not a masked matmul
+# (``L[t, s]`` would be a matrix a channel and state) and both forms are the
+# recurrence itself, elementwise in float32 on ``S [m, N, d]``:
+#
+#     S_t = exp(Δ_t ⊗ A) ∘ S_{t−1} + (Δ_t ∘ u_t) ⊗ B_t ;  y_t = Σ_n S_t ∘ C_t
+#                                                               + D ∘ u_t
+#
+# with ``Δ_t [d]``, ``B_t``, ``C_t [N]`` from ``tl.ssm_select`` (that triple
+# stands where the functions above take ``dt``). The chunk form carries ``S``
+# through the chunk's positions and never lays ``[c, N, d]`` out.
+
+def selective_split(cfg, xz: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The input projection's columns ``[x′ | z]`` (the published module's
+    order), ``d`` each → (z, x′)."""
+    d = cfg.ssm_inner
+    return xz[..., d:], xz[..., :d]
+
+
+def selective_gate(y: jax.Array, z: jax.Array, dtype) -> jax.Array:
+    """``y ⊙ silu(z)`` in float32: the mixer has a gate and NO norm."""
+    return (y.astype(jnp.float32) *
+            jax.nn.silu(z.astype(jnp.float32))).astype(dtype)
+
+
+def _rms(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last axis, float32 (the mixer's INNER norms: under
+    the caller's scope, not ``norm``)."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                         + eps) * scale.astype(jnp.float32)
+
+
+def select_norms(cfg, p, dbc: jax.Array, dtype):
+    """``u·W_x``'s columns ``[δ | B | C]`` (widths ``R``, ``N``, ``N``),
+    each under its own RMSNorm → (δ in ``dtype``: ``W_dt``'s input; B, C
+    float32)."""
+    r, n = cfg.ssm_dt_rank, cfg.ssm_state_size
+    eps = cfg.norm_eps
+    return _rms(dbc[..., :r], p["dt_norm"]["scale"], eps).astype(dtype), \
+        _rms(dbc[..., r:r + n], p["b_norm"]["scale"], eps), \
+        _rms(dbc[..., r + n:], p["c_norm"]["scale"], eps)
+
+
+def step_sizes(p, dt: jax.Array, counts: jax.Array) -> jax.Array:
+    """``Δ = softplus(dt + dt_bias)`` [m, c, d] float32, 0 past a row's
+    ``counts`` (decay 1, no input: the position advances nothing)."""
+    live = jnp.arange(dt.shape[1], dtype=jnp.int32)[None] < counts[:, None]
+    delta = jax.nn.softplus(dt.astype(jnp.float32) +
+                            p["dt_bias"].astype(jnp.float32))
+    return jnp.where(live[..., None], delta, 0.0)
+
+
+def _selective_update(p, s, delta, du, b, c, reset=None):
+    """One position of every row: ``s`` [m, N, d], ``delta`` and ``du = Δ∘u``
+    [m, d], ``b`` and ``c`` [m, N] → (the state after it, ``Σ_n S∘C`` [m,
+    d]), float32."""
+    decay = jnp.exp(delta[:, None, :] *
+                    -jnp.exp(p["A_log"].astype(jnp.float32)))
+    if reset is not None:
+        decay = jnp.where(reset[:, None, None], 0.0, decay)
+    s = decay * s + du[:, None, :] * b[:, :, None]
+    return s, jnp.sum(s * c[:, :, None], axis=1)
+
+
+def selective_step(cfg, p, u, sel, state, counts, reset=None):
+    """:func:`scan_step`'s part for a selective scan: u [m, 1, d], ``sel``
+    = (Δ [m, 1, d], B, C [m, 1, N]) of ``tl.ssm_select``, state [m, N,
+    d]."""
+    delta, b, c = (t[:, 0] for t in sel)
+    x = u[:, 0].astype(jnp.float32)
+    state, y = _selective_update(p, state, delta, delta * x, b, c, reset)
+    return (y + p["D"].astype(jnp.float32) * x)[:, None], state
+
+
+def selective_chunk(cfg, p, u, sel, state, counts, kernel: bool = False):
+    """:func:`scan_chunk`'s part for a selective scan: u [m, c, d], ``sel`` = (Δ
+    [m, c, d] — 0 past ``counts`` —, B, C [m, c, N]), state [m, N, d] →
+    (y [m, c, d] float32, the state after each row's last live position).
+    ``kernel``: the Pallas kernel (:func:`selective_scan_kernel`: the state
+    in registers from the chunk's first position to its last live one, read
+    from HBM once and written once); else an XLA loop over the positions
+    with the state as its carry, which goes through HBM at every position
+    (the CPU's form, and any width that is not whole lane tiles)."""
+    delta, b, c = sel
+    x = u.astype(jnp.float32)
+    if kernel and x.shape[-1] % 128 == 0:
+        return selective_scan_kernel(
+            -jnp.exp(p["A_log"].astype(jnp.float32)),
+            p["D"].astype(jnp.float32), x, delta, b, c, state, counts)
+
+    def position(s, inp):
+        return _selective_update(p, s, *inp)
+
+    state, y = lax.scan(position, state, tuple(
+        t.swapaxes(0, 1) for t in (delta, delta * x, b, c)))
+    return y.swapaxes(0, 1) + p["D"].astype(jnp.float32) * x, state
+
+
+def _selective_scan_body(counts_ref, delta_ref, x_ref, b_ref, c_ref, a_ref,
+                         d_ref, s_ref, y_ref, so_ref):
+    """Grid (rows, channel blocks): ONE program carries a row's state of
+    ``N`` tiles ``[sb, 128]`` — ``sb x 128`` channels, one vector register a
+    state index at ``sb`` 8 — through the row's LIVE positions
+    (``counts_ref``, scalar-prefetched: a row riding along with one live
+    token takes one turn, not the chunk's 128). ``delta_ref`` / ``x_ref`` /
+    ``y_ref``: ``[1, c, sb, 128]`` blocks (a position is a leading index);
+    ``b_ref`` / ``c_ref``: the row's ``B`` and ``C`` in SMEM, ``[1, 1, c·N]``
+    — a position's ``B_t[n]`` is a SCALAR times a register, where a layout
+    with the states on the sublanes would need it broadcast along the
+    lanes —; ``a_ref`` ``[N, sb, 128]`` (``A`` itself, negative), ``d_ref``
+    ``[sb, 128]``; ``s_ref`` / ``so_ref`` ``[1, N, sb, 128]``, aliased. A
+    position past the live ones gets ``y = 0``."""
+    n_state = a_ref.shape[0]
+    a = [a_ref[n] for n in range(n_state)]
+    skip = d_ref[...]
+    y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+    def position(t, s):
+        delta, x = delta_ref[0, t], x_ref[0, t]
+        du, y = delta * x, skip * x
+        out = []
+        for n in range(n_state):
+            s_n = jnp.exp(delta * a[n]) * s[n] + \
+                du * b_ref[0, 0, t * n_state + n]
+            y = y + s_n * c_ref[0, 0, t * n_state + n]
+            out.append(s_n)
+        y_ref[0, t] = y
+        return tuple(out)
+
+    s = lax.fori_loop(0, counts_ref[pl.program_id(0)], position,
+                      tuple(s_ref[0, n] for n in range(n_state)))
+    for n in range(n_state):
+        so_ref[0, n] = s[n]
+
+
+# jitted (as ``paged_attention._paged_call`` is): a program's unrolled
+# layers call it once a layer and instance, and share ONE trace of the body
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def selective_scan_kernel(a, skip, x, delta, b, c, state, counts,
+                          interpret: bool = False):
+    """The selective scan's chunk form as a Pallas kernel
+    (:func:`_selective_scan_body`; its name in a device trace is
+    ``selective_scan``): ``a`` [N, d] (= ``−exp(A_log)``), ``skip`` [d], x,
+    delta [m, c, d], b, c [m, c, N], state [m, N, d], all float32, counts
+    [m] → (y [m, c, d], the state after each row's last live position). The
+    channels go to the kernel as ``[d / 128, 128]`` tiles, so ``d`` is a
+    multiple of 128; a program holds 1,024 of them (8 sublanes) where
+    ``d / 128`` is a multiple of 8, else all. HBM traffic: delta, x and y
+    once each, the state in once and out once."""
+    m, ch, d = x.shape
+    n = state.shape[1]
+    dt = d // 128
+    sb = 8 if dt % 8 == 0 else dt
+
+    def tiles(t):
+        return t.reshape(t.shape[:-1] + (dt, 128))
+
+    rows = pl.BlockSpec((1, ch, sb, 128), lambda i, j, counts: (i, 0, j, 0))
+    scalars = pl.BlockSpec((1, 1, ch * n), lambda i, j, counts: (i, 0, 0),
+                           memory_space=pltpu.SMEM)
+    held = pl.BlockSpec((1, n, sb, 128), lambda i, j, counts: (i, 0, j, 0))
+    y, state = pl.pallas_call(
+        _selective_scan_body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(m, dt // sb),
+            in_specs=[rows, rows, scalars, scalars,
+                      pl.BlockSpec((n, sb, 128),
+                                   lambda i, j, counts: (0, j, 0)),
+                      pl.BlockSpec((sb, 128), lambda i, j, counts: (j, 0)),
+                      held],
+            out_specs=[rows, held]),
+        out_shape=[jax.ShapeDtypeStruct((m, ch, dt, 128), jnp.float32),
+                   jax.ShapeDtypeStruct((m, n, dt, 128), jnp.float32)],
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret, name="selective_scan",
+    )(counts.astype(jnp.int32), tiles(delta), tiles(x),
+      b.reshape(m, 1, ch * n), c.reshape(m, 1, ch * n), tiles(a),
+      skip.reshape(dt, 128), tiles(state))
+    return y.reshape(m, ch, d), state.reshape(m, n, d)

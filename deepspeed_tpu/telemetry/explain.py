@@ -177,7 +177,8 @@ SCOPE_VOCABULARY = (
     "kv_write", "attn_out", "mlp", "moe", "moe_router", "moe_experts",
     "lm_head", "loss", "grad_clip", "optimizer", "sample", "step_misc",
     "attn_latent", "moe_shared",
-    "ssm_in", "ssm_conv", "ssm_scan", "ssm_state", "ssm_norm", "ssm_out")
+    "ssm_in", "ssm_conv", "ssm_scan", "ssm_state", "ssm_norm", "ssm_out",
+    "ssm_select")
 
 _HLO_NAME_RE = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')

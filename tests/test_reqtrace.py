@@ -576,7 +576,11 @@ def test_reqtrace_e2e_disagg_fleet_acceptance(devices, tmp_path,
     def prompts(base):
         return [[base + i, 2, 3, 4, 5, 6, 7, 8, 9] for i in range(2)]
 
-    new = 4
+    # six slowed decode pumps a request: the router's pump pays the chaos
+    # delay once while the request is still in its prefill leg too, so at 4
+    # tokens decode led prefill by ONE delay (0.8 s against 0.4 s + the
+    # prefill's own time) and a loaded machine turned the order round
+    new = 8
     reqtrace.clear()
     reqtrace.configure(enabled=False)
     router = Router(_disagg_pool(devices), hedge=False,

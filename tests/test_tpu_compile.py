@@ -83,14 +83,14 @@ def _flash(seq, batch, grad):
     return fn, (q, kv, kv), 3 if grad else 1
 
 
-def _paged(n, c, with_lse, heads=16, pages=8, window=None):
+def _paged(n, c, with_lse, heads=16, pages=8, window=None, kv_heads=8):
     """Paged attention over the default serving arena: 16 layers of
-    512 pages (+1 trash page each) of 128 tokens, 8 KV heads of 128 side
-    by side on the lanes; ``pages`` a row (8: ``max_seq_len`` 1024). The
-    split step's history reader (``with_lse``) takes each row's live-query
-    count as the engine passes it."""
+    512 pages (+1 trash page each) of 128 tokens, ``kv_heads`` (8) KV heads
+    of 128 side by side on the lanes; ``pages`` a row (8: ``max_seq_len``
+    1024). The split step's history reader (``with_lse``) takes each row's
+    live-query count as the engine passes it."""
     from deepspeed_tpu.ops import paged_attention as pa
-    arena = ((16 * (512 + 1), 128, 8 * 128), jnp.bfloat16)
+    arena = ((16 * (512 + 1), 128, kv_heads * 128), jnp.bfloat16)
     q = ((n, c, heads, 128), jnp.bfloat16)
     pt = ((n, pages), jnp.int32)
     vec = ((n,), jnp.int32)
@@ -123,6 +123,17 @@ def _paged_typed(kvh, n=64, c=128):
     return fn, (((n, c, 64, 256), bf), ((blocks, 128, kvh * 256), bf),
                 ((blocks, 128, kvh * 128), bf), ((n, 8), jnp.int32),
                 ((n,), jnp.int32), ((n,), jnp.int32)), 1
+
+
+def _selective_scan(m, c=128, d=5120, n=16):
+    """The selective scan's chunk form at Jamba2-3B's widths: ``m`` rows of
+    a ``c``-token chunk, 5,120 channels of 16 states, float32 (the chunk
+    group of a grouped split step: 4 or 8 rows; the row form: 64)."""
+    from deepspeed_tpu.ops import ssm
+    f = jnp.float32
+    return ssm.selective_scan_kernel, (
+        ((n, d), f), ((d,), f), ((m, c, d), f), ((m, c, d), f),
+        ((m, c, n), f), ((m, c, n), f), ((m, n, d), f), ((m,), jnp.int32)), 1
 
 
 def _dequant(mode):
@@ -196,6 +207,21 @@ CASES = {
     # (a block of 2,048 query rows), a window of 4,096, 86 pages a row
     "paged_hist_n16_c128_q16_window_lse": lambda: _paged(
         16, 128, with_lse=True, heads=128, pages=86, window=4096),
+    # ... and at Jamba2-3B's: 20 queries over ONE KV head (a block of 2,560
+    # query rows, between Command A+'s 2,048 and the 4,096 that does not
+    # fit; 20 is no multiple of 8 or 16: the small tile is 4 queries, 80
+    # rows), 86 pages a row; the chunk group of the 1,024-slot instance,
+    # the row form, and every row as one query
+    "paged_hist_n8_c128_q20_mqa_lse": lambda: _paged(
+        8, 128, with_lse=True, heads=20, pages=86, kv_heads=1),
+    "paged_hist_n64_c128_q20_mqa_lse": lambda: _paged(
+        64, 128, with_lse=True, heads=20, pages=86, kv_heads=1),
+    "paged_hist_n64_c1_q20_mqa_lse": lambda: _paged(
+        64, 1, with_lse=True, heads=20, pages=86, kv_heads=1),
+    # the selective scan's chunk form at its two chunk groups and the row form
+    "selective_scan_n4_c128": lambda: _selective_scan(4),
+    "selective_scan_n8_c128": lambda: _selective_scan(8),
+    "selective_scan_n64_c128": lambda: _selective_scan(64),
     "dequant_int8": lambda: _dequant("int8"),
     "dequant_fp8": lambda: _dequant("fp8"),
     "dequant_int4": lambda: _dequant("int4"),
@@ -226,6 +252,12 @@ KERNEL_NAMES = {
     "paged_hist_typed_window_kv8_lse": ("paged_attn_lse",),
     "paged_hist_typed_full_kv4_lse": ("paged_attn_lse",),
     "paged_hist_n16_c128_q16_window_lse": ("paged_attn_lse",),
+    "paged_hist_n8_c128_q20_mqa_lse": ("paged_attn_lse",),
+    "paged_hist_n64_c128_q20_mqa_lse": ("paged_attn_lse",),
+    "paged_hist_n64_c1_q20_mqa_lse": ("paged_attn_lse",),
+    "selective_scan_n4_c128": ("selective_scan",),
+    "selective_scan_n8_c128": ("selective_scan",),
+    "selective_scan_n64_c128": ("selective_scan",),
     "dequant_int8": ("qmm",),
     "dequant_fp8": ("qmm",),
     "dequant_int4": ("qmm_int4",),
@@ -268,6 +300,7 @@ PAGED_HEADS = {
     "paged_hist_typed_full_kv4_c1_lse": 4,
     "paged_hist_typed_window_kv8_lse": 1,
     "paged_hist_n16_c128_q16_window_lse": 1,
+    "paged_hist_n8_c128_q20_mqa_lse": 1, "paged_hist_n64_c1_q20_mqa_lse": 1,
 }
 
 
@@ -864,11 +897,12 @@ _HYBRID_STEPS = {
 }
 
 
-def _hybrid_step(one_chip, monkeypatch, config, cut, step, mb):
+def _hybrid_step(one_chip, monkeypatch, config, cut, step, mb,
+                 num_blocks=512):
     """A 64-row step program of a recurrent stack compiled for the chip:
     the configuration ``config`` with ``cut`` laid over its keys, over the
-    cell's KV arena, a page table ``mb`` pages wide (the cell's
-    ``max_seq_len``) and its state pools (a pool a state-space layer) →
+    cell's KV arena (``num_blocks`` pages), a page table ``mb`` pages wide
+    (the cell's ``max_seq_len``) and its state pools (a pool a state-space layer) →
     (the model, the abstract arena, the compiled program, its text). NO
     copy of a state pool or of a KV pool anywhere in the module."""
     import json
@@ -896,7 +930,7 @@ def _hybrid_step(one_chip, monkeypatch, config, cut, step, mb):
 
     def make_arena():
         arena = pa.init_arena_typed(model.layer_kinds, {0: model.kv_heads},
-                                    512, 128, 128, 128, jnp.bfloat16)
+                                    num_blocks, 128, 128, 128, jnp.bfloat16)
         arena.update(ssm.init_state_pools(model, 64, jnp.bfloat16))
         return arena
 
@@ -1013,3 +1047,69 @@ def test_two_part_hybrid_step_compiles_for_v5e(
         _check_ladder_memory(compiled, _hybrid_step(
             one_chip, monkeypatch, "granite-4.0-h-small-l10-e36-serve", cut,
             (128, "split", _TWO_RUNGS), 8)[2])
+
+
+# -- the selective-scan stack (benchmark/configs/jamba2-3b-l28-serve): Mamba-1
+# mixers and MQA beside a dense MLP in every layer
+
+#: step -> (chunk, ``fresh_prefill``, capacities, most temporaries at the
+#: three layers ``mamba attention mamba``: measured 0.19, 0.72 and 0.66 GB)
+_JAMBA_STEPS = {
+    "decode": (1, False, (), 0.3e9),
+    "split": (128, "split", (512, 1024, 2048), 0.9e9),
+    "fresh": (128, "fresh", (2048,), 0.8e9),
+}
+
+
+@pytest.mark.parametrize("kind", list(_JAMBA_STEPS))
+def test_selective_scan_step_compiles_for_v5e(
+        kind, one_chip, no_persistent_cache, monkeypatch, capsys):
+    """The 64-row decode, split and fresh programs of Jamba2-3B's stack at
+    the published widths, cut to ``mamba attention mamba``, over the cell's
+    arena (5,504 pages, 86 a row) and two state pools of 65 slots of 320
+    KiB: NO copy of a state pool or of a KV pool anywhere in the module;
+    the six ``ssm_*`` scopes AND the new ``ssm_select``, the dense ``mlp``
+    and the attention layer's on some instruction; the ``selective_scan``
+    kernel under ``ssm_scan`` wherever rows take the chunk form, the paged
+    kernel with its 2,560-row block under ``attn_history`` in the split
+    program; temporaries (printed) under the measured ones. The split
+    program's three instances are one-trip loops: 4 and 8 chunk rows beside
+    64 rows of one query, then all 64 at the chunk's width."""
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    cut = {"num_hidden_layers": 3, "attn_layer_period": 2,
+           "attn_layer_offset": 1}
+    model, arena, compiled, text = _hybrid_step(
+        one_chip, monkeypatch, "jamba2-3b-l28-serve", cut,
+        _JAMBA_STEPS[kind], 86, num_blocks=5504)
+    assert model.layer_kinds == (4, 0, 4) and \
+        model.layer_sparse == (0, 0, 0) and model.selective
+    assert arena["ssm1"].shape == (65, 16, 5120) and \
+        arena["ssm1"].dtype == jnp.float32 and \
+        arena["conv1"].shape == (65, 3 * 5120) and "ssm2" not in arena and \
+        arena["k"].shape == (5505, 128, 128)
+    table = scope_table_from_hlo(text)
+    scopes = {e["scope"] for e in table.values()}
+    assert {"ssm_in", "ssm_conv", "ssm_select", "ssm_scan", "ssm_state",
+            "ssm_norm", "ssm_out", "mlp", "attn_qkv", "attn_out",
+            "kv_write", "embed", "lm_head"} <= scopes, scopes
+    scans = [n for n in table if n.startswith("selective_scan")]
+    # a mixer layer and instance (the one-query rows step the recurrence)
+    assert len(scans) == {"decode": 0, "split": 6, "fresh": 2}[kind] and \
+        all(table[n]["scope"] == "ssm_scan" for n in scans), scans
+    kernels = [n for n in table if n.startswith("paged_attn_lse")]
+    # the attention layer: chunk group + one-query rows in two instances,
+    # all rows at the chunk's width in the third
+    assert len(kernels) == (5 if kind == "split" else 0) and \
+        all(table[n]["scope"] == "attn_history" for n in kernels), kernels
+    assert "2560,128" in text or kind != "split"
+    heavy = [m.group(1) for m in _HEAVY.finditer(text)]
+    named = [n for n in heavy if table[n]["scope"] is not None]
+    assert len(named) >= 0.95 * len(heavy), sorted(set(heavy) - set(named))
+    assert not _branches(text) or kind != "split", _branches(text)
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\njamba2-3b {kind} at 3 layers: arguments "
+              f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < _JAMBA_STEPS[kind][3], \
+        mem.temp_size_in_bytes
