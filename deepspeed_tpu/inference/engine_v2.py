@@ -397,7 +397,7 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     remove the per-layer write→read dependency on the ~GB arena, which
     XLA otherwise serializes.
 
-    ``token_capacities`` (STATIC, ascending, each under ``n * c``; the
+    ``token_capacities`` (STATIC, ascending, none over ``n * c``; the
     caller promises ``counts.sum()`` never exceeds the last): a step that
     carries a prompt chunk gives EVERY row the chunk's width, and most
     rows are decode rows with one live token, so ``n * c`` slots hold a
@@ -453,11 +453,11 @@ def ragged_forward(cfg: DecoderConfig, params, arena, tokens: jax.Array,
     n, c = tokens.shape
     split = fresh_prefill == "split" and c > 1
     token_capacities = tuple(token_capacities)
-    if any(cap >= n * c for cap in token_capacities) or \
+    if any(cap > n * c for cap in token_capacities) or \
             (len(token_capacities) > 1 and not split):
         raise ValueError(
             f"token_capacities {token_capacities} for a [{n}, {c}] batch: "
-            f"each must be under {n * c} slots, and only a split step "
+            f"none may pass its {n * c} row slots, and only a split step "
             f"takes more than one")
     if cfg.typed:
         return _ragged_forward_typed(cfg, params, arena, tokens, counts,
@@ -1672,12 +1672,35 @@ class RaggedInferenceEngineTPU:
         traced and lowered once more: about 1 s a scanned program, 2-4 s
         an unrolled one, warm; docs/kernels.md), which is why rows at
         twice the budget, already halved by packing, do without, and why
-        there is no rung at 4 a row yet. :meth:`_run` applies the same
-        rule to count the slots."""
-        top = self.config.max_batch_tokens
-        if cb == 1 or top >= nb * cb:
+        there is no rung at 4 a row yet.
+
+        Where the rows hold NO MORE than the budget the same ladder serves
+        the split program of the engine's FULL row count (``nb`` = the
+        bucket of ``max_sequences``: what a loaded replica runs all day),
+        topped by the row slots themselves: half and a quarter of them, a
+        rung for as long as it holds FOUR whole chunks (its chunk group is
+        ``slots // cb`` rows, :func:`_instances`; one of fewer serves too
+        few steps to pay for its set-up) — ``(512, 1024, 2048)`` for a
+        16-sequence engine at chunk 128, ``(512, 1024)`` for one of eight,
+        the row form for one of four. Of 16 such rows two or three carry a
+        prompt chunk at a time and the others one token: 200-400 tokens in
+        2,048 row slots. The top instance is the packed one with every row
+        a chunk row, as in the 64-row program, so the chunk's K/V wait for
+        the write-back in one layout at every instance. The smaller row
+        buckets of an engine keep the row form: each instance is a layer
+        loop more in every warm-up. :meth:`_run` applies the same rule to
+        count the slots."""
+        top, rows = self.config.max_batch_tokens, nb * cb
+        if cb == 1:
             return ()
-        if fresh != "split" or 4 * top > nb * cb:
+        if top >= rows:
+            if fresh != "split" or \
+                    nb != _bucket(self.config.max_sequences):
+                return ()
+            ladder = tuple(slots for slots in (rows // 4, rows // 2)
+                           if slots >= 4 * cb)
+            return ladder + (rows,) if ladder else ()
+        if fresh != "split" or 4 * top > rows:
             return (top,)
         ladder = (top,)
         for slots, least in ((16 * nb, 0), (8 * nb, cb + nb - 1)):

@@ -1144,9 +1144,10 @@ def test_page_fetches_follow_the_heads_a_program_holds(case):
         batch, chunk, grouped) is None
 
 
-def test_capacities_the_rows_already_hold_are_refused(devices):
-    """``token_capacities`` are under the rows' slots (at or over them the
-    row form is the program), and only a split step switches between two."""
+def test_capacities_past_the_rows_slots_are_refused(devices):
+    """No ``token_capacities`` passes the rows' slots (AT them a split
+    program's top instance packs with every row a chunk row), and only a
+    split step switches between two."""
     cfg = llama3_config("tiny")
     from deepspeed_tpu.models.transformer import init_params
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -1155,7 +1156,7 @@ def test_capacities_the_rows_already_hold_are_refused(devices):
     z = jnp.zeros((2,), jnp.int32)
     args = (cfg, params, arena, jnp.zeros((2, 16), jnp.int32), z, z,
             jnp.full((2, 4), 4, jnp.int32))
-    for mode, capacities in (("split", (32,)), ("split", (8, 40)),
+    for mode, capacities in (("split", (33,)), ("split", (8, 40)),
                              ("fresh", (8, 16)), (False, (8, 16))):
         with pytest.raises(ValueError, match="token_capacities"):
             ragged_forward(*args, fresh_prefill=mode,

@@ -46,16 +46,35 @@ MIXES = {
 }
 
 
-def _pages(counts, starts, rng, nb=40):
+def _pages(counts, starts, rng, nb=40, mb=MB, bs=BS):
     """(page table, pages of the arena): the rows' pages out of order; one
     arena size for every mix, so a stack's programs compile once."""
-    pages = -(-(starts + counts) // BS)
-    assert pages.sum() <= nb
-    pt = np.full((N, MB), nb, np.int32)
+    pages = -(-(starts + counts) // bs)
+    assert pages.sum() <= nb and pages.max() <= mb
+    pt = np.full((len(counts), mb), nb, np.int32)
     free = iter(rng.permutation(nb))
-    for i in range(N):
-        pt[i, :pages[i]] = [next(free) for _ in range(pages[i])]
+    for i, n in enumerate(pages):
+        pt[i, :n] = [next(free) for _ in range(n)]
     return pt, nb
+
+
+def _assert_steps_agree(got, want, arena, counts, nb, tol):
+    """Two runs of one split step, ``(logits, pools)`` each: the logits of
+    every row that fed a token and every pool outside the layers' trash
+    pages agree within ``tol``, and the step wrote every pool."""
+    (got_logits, got), (want_logits, want) = got, want
+    live = counts > 0
+    assert np.abs(np.asarray(want_logits)[live]).max() > 0.1
+    np.testing.assert_allclose(np.asarray(got_logits)[live],
+                               np.asarray(want_logits)[live],
+                               rtol=tol, atol=tol)
+    assert set(got) == set(want)
+    for name in want:
+        kept = np.arange(want[name].shape[0]) % (nb + 1) != nb
+        a, b, before = (np.asarray(x[name], np.float32)[kept]
+                        for x in (got, want, arena))
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
+        assert not np.array_equal(a, before), name     # the step wrote
 
 
 #: the 8 x 16 program's LADDER (PR 46): three instances, 16 slots with ONE
@@ -128,37 +147,91 @@ def test_instance_index_is_the_same_traced_and_on_the_host():
             name
 
 
-#: (rows, chunk, kind, ``max_batch_tokens``) -> ``_token_capacities``
+#: (rows, chunk, kind, ``max_batch_tokens``, ``max_sequences``) ->
+#: ``_token_capacities``
 _LADDERS = {
-    "the_cells_64_rows": ((64, 128, "split", 2048), (512, 1024, 2048)),
-    "a_budget_of_16_a_row": ((64, 128, "split", 1024), (512, 1024)),
-    "no_room_for_a_chunk_at_8_a_row": ((4, 96, "split", 80), (64, 80)),
-    "a_chunk_fits_8_a_row": ((16, 96, "split", 320), (128, 256, 320)),
-    "a_chunk_just_fits": ((16, 113, "split", 400), (128, 256, 400)),
-    "a_chunk_just_does_not": ((16, 114, "split", 400), (256, 400)),
-    "rows_at_twice_the_budget": ((32, 128, "split", 2048), (2048,)),
-    "fresh_takes_one": ((64, 128, "fresh", 2048), (2048,)),
-    "rows_hold_the_budget": ((16, 128, "split", 2048), ()),
-    "decode": ((64, 1, False, 2048), ()),
+    "the_cells_64_rows": ((64, 128, "split", 2048, 64), (512, 1024, 2048)),
+    "a_budget_of_16_a_row": ((64, 128, "split", 1024, 64), (512, 1024)),
+    "no_room_for_a_chunk_at_8_a_row": ((4, 96, "split", 80, 4), (64, 80)),
+    "a_chunk_fits_8_a_row": ((16, 96, "split", 320, 16), (128, 256, 320)),
+    "a_chunk_just_fits": ((16, 113, "split", 400, 16), (128, 256, 400)),
+    "a_chunk_just_does_not": ((16, 114, "split", 400, 16), (256, 400)),
+    "rows_at_twice_the_budget": ((32, 128, "split", 2048, 64), (2048,)),
+    "fresh_takes_one": ((64, 128, "fresh", 2048, 64), (2048,)),
+    # rows that hold NO MORE than the budget: the ladder under the row
+    # slots for the engine's full row count, the row form under it
+    "full_rows_hold_the_budget": ((16, 128, "split", 2048, 16),
+                                  (512, 1024, 2048)),
+    "a_smaller_bucket_holds_the_budget": ((16, 128, "split", 2048, 64), ()),
+    "full_rows_under_the_budget": ((16, 96, "split", 2048, 16),
+                                   (384, 768, 1536)),
+    "eight_full_rows": ((8, 128, "split", 2048, 8), (512, 1024)),
+    "four_full_rows": ((4, 128, "split", 2048, 4), ()),
+    "a_row_count_between_buckets": ((16, 128, "split", 2048, 12),
+                                    (512, 1024, 2048)),
+    "fresh_full_rows": ((16, 128, "fresh", 2048, 16), ()),
+    "decode": ((64, 1, False, 2048, 64), ()),
 }
+
+
+def _engine_of(top, sequences):
+    """What ``_token_capacities`` reads of an engine."""
+    import types
+    return types.SimpleNamespace(config=types.SimpleNamespace(
+        max_batch_tokens=top, max_sequences=sequences))
 
 
 @pytest.mark.parametrize("case", list(_LADDERS))
 def test_token_capacities_make_a_rung_only_where_it_serves(case):
-    """``_token_capacities`` from the program's rows, chunk and kind and
-    the budget ALONE: the ladder of the benchmark's 64 x 128 split program;
-    a rung only under the next one; the rung at 8 slots a row only where
-    it holds a whole chunk beside one token of every other row."""
-    import types
-    (nb, cb, fresh, top), want = _LADDERS[case]
-    eng = types.SimpleNamespace(
-        config=types.SimpleNamespace(max_batch_tokens=top))
-    got = RaggedInferenceEngineTPU._token_capacities(eng, nb, cb, fresh)
+    """``_token_capacities`` from the program's rows, chunk and kind, the
+    budget and the engine's row count ALONE: the ladder of the benchmark's
+    64 x 128 split program; a rung only under the next one; the rung at 8
+    slots a row only where it holds a whole chunk beside one token of every
+    other row; under row slots that hold no more than the budget, halves
+    for as long as a rung holds four whole chunks, for the engine's full
+    row count alone."""
+    (nb, cb, fresh, top, sequences), want = _LADDERS[case]
+    got = RaggedInferenceEngineTPU._token_capacities(
+        _engine_of(top, sequences), nb, cb, fresh)
     assert got == want
     assert all(2 * a <= b or b == top for a, b in zip(got, got[1:]))
-    if len(got) == 3:
+    if got and top >= nb * cb:
+        assert got[-1] == nb * cb and got[0] >= 4 * cb and len(got) > 1
+        assert [cap // cb for cap in got] == \
+            [rows for _, rows in engine_v2._instances(got, nb, cb)]
+    elif len(got) == 3:
         assert got[0] >= cb + nb - 1
+    if len(got) > 1:
         assert engine_v2._write_back_slots(got, nb * cb)[1] % got[0] == 0
+
+
+#: ``max_sequences`` -> {kind: {rows: ladder}} of an engine at chunk 128
+#: under a budget of 2,048 — every program of its grid that is NOT the row
+#: form. The 64-sequence engine's (cells 2, 4, 5, 7, 8, 9 and 10) as they
+#: stood before the full-row rule (PR 46's tree, every key): that rule
+#: reaches the 16- and the 8-sequence engine's full-row split program alone
+_GRIDS = {
+    64: {"split": {64: (512, 1024, 2048), 32: (2048,)},
+         "fresh": {64: (2048,), 32: (2048,)}},
+    16: {"split": {16: (512, 1024, 2048)}},
+    8: {"split": {8: (512, 1024)}},
+    4: {},
+}
+
+
+@pytest.mark.parametrize("sequences,rows,kind", [
+    (sequences, rows, kind) for sequences in _GRIDS
+    for rows in (1, 2, 4, 8, 16, 32, 64) if rows <= sequences
+    for kind in ("split", "fresh", "decode")])
+def test_every_program_of_an_engines_grid_holds_its_ladder(sequences, rows,
+                                                           kind):
+    """``_token_capacities`` of EVERY ``(nb, cb, fresh)`` an engine of
+    ``sequences`` rows can be asked for (its row buckets x split / fresh /
+    decode) at chunk 128 and a budget of 2,048."""
+    cb, fresh = (1, False) if kind == "decode" else (128, kind)
+    got = RaggedInferenceEngineTPU._token_capacities(
+        _engine_of(2048, sequences), rows, cb, fresh)
+    assert got == _GRIDS[sequences].get(kind, {}).get(rows, ())
 
 
 @functools.lru_cache(maxsize=None)
@@ -213,21 +286,9 @@ def test_grouped_split_step_matches_the_all_rows_instance(devices, stack,
     arena = history(arena, toks(N, 48), jnp.asarray(starts), jnp.asarray(pt))
     args = (toks(N, C), jnp.asarray(counts), jnp.asarray(starts),
             jnp.asarray(pt))
-    want_logits, want = step(CAPACITIES[1:])(arena, *args)
-    got_logits, got = step(CAPACITIES)(arena, *args)
-    live = counts > 0
-    tol = 2e-4 if dtype == "float32" else 6e-2
-    assert np.abs(np.asarray(want_logits)[live]).max() > 0.1
-    np.testing.assert_allclose(np.asarray(got_logits)[live],
-                               np.asarray(want_logits)[live],
-                               rtol=tol, atol=tol)
-    assert set(got) == set(want)
-    for name in want:
-        kept = np.arange(want[name].shape[0]) % (nb + 1) != nb
-        a, b, before = (np.asarray(x[name], np.float32)[kept]
-                        for x in (got, want, arena))
-        np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
-        assert not np.array_equal(a, before), name     # the step wrote
+    _assert_steps_agree(step(CAPACITIES)(arena, *args),
+                        step(CAPACITIES[1:])(arena, *args), arena, counts,
+                        nb, 2e-4 if dtype == "float32" else 6e-2)
 
 
 @pytest.mark.parametrize("mix", list(LADDER_MIXES))
@@ -254,20 +315,108 @@ def test_three_rung_program_matches_the_row_form(devices, stack, mix):
                     jnp.asarray(pt))
     args = (toks(N, C), jnp.asarray(counts), jnp.asarray(starts),
             jnp.asarray(pt))
-    want_logits, want = step(())(arena, *args)
-    got_logits, got = step(LADDER)(arena, *args)
-    live = counts > 0
-    assert np.abs(np.asarray(want_logits)[live]).max() > 0.1
-    np.testing.assert_allclose(np.asarray(got_logits)[live],
-                               np.asarray(want_logits)[live],
-                               rtol=2e-4, atol=2e-4)
-    assert set(got) == set(want)
-    for name in want:
-        kept = np.arange(want[name].shape[0]) % (nb + 1) != nb
-        a, b, before = (np.asarray(x[name], np.float32)[kept]
-                        for x in (got, want, arena))
-        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
-        assert not np.array_equal(a, before), name     # the step wrote
+    _assert_steps_agree(step(LADDER)(arena, *args), step(())(arena, *args),
+                        arena, counts, nb, 2e-4)
+
+
+# -- the FULL-ROW program of a 16-sequence engine (PR 50): 16 rows of chunk
+# 128, the ladder (512, 1024, 2048) under row slots that hold the budget
+
+N16, C16, BS16, MB16, WINDOW16 = 16, 128, 16, 16, 40
+LADDER16 = (512, 1024, 2048)
+#: mix -> (tokens a row feeds, tokens it has cached, the rung it takes): 16
+#: rows of a stack shaped like Command A+'s (a window layer, a full layer
+#: with no positional term, 16 queries on one KV head, a parallel block) at
+#: each rung and on each side of each boundary. Histories of 30 and 45
+#: under chunk rows straddle the window of 40 (a chunk's first queries see
+#: the history through it, its last ones their own chunk alone); rows of one
+#: token have histories on both sides of it, and row 15 none
+_MIXES16 = {
+    "two_chunks_beside_fourteen_rows": (
+        [128, 72] + [1] * 14,
+        [30, 45, 100, 7, 41, 64, 9, 16, 3, 25, 80, 12, 39, 40, 55, 0], 0),
+    "tokens_fill_the_low_rung_at_four_chunk_rows": (
+        [128, 128, 128, 116] + [1] * 12,
+        [30, 0, 45, 96, 100, 7, 41, 64, 9, 16, 3, 25, 80, 12, 39, 0], 0),
+    "one_token_over_the_low_rung": (
+        [128, 128, 128, 117] + [1] * 12,
+        [30, 0, 45, 96, 100, 7, 41, 64, 9, 16, 3, 25, 80, 12, 39, 0], 1),
+    "five_chunk_rows": (
+        [2, 90, 1, 3, 1, 128, 1, 1, 0, 1, 7, 1, 1, 1, 1, 1],
+        [30, 45, 100, 7, 41, 64, 9, 16, 0, 25, 80, 12, 39, 40, 55, 0], 1),
+    "tokens_fill_the_middle_rung_at_eight_chunk_rows": (
+        [127] * 8 + [1] * 8,
+        [30, 45, 0, 7, 41, 64, 9, 16, 3, 25, 80, 12, 39, 40, 55, 0], 1),
+    "one_token_over_the_middle_rung": (
+        [128, 127, 127, 127, 127, 127, 127, 127] + [1] * 8,
+        [30, 45, 0, 7, 41, 64, 9, 16, 3, 25, 80, 12, 39, 40, 55, 0], 2),
+    "nine_chunk_rows": (
+        [2] * 9 + [1] * 6 + [0],
+        [30, 45, 100, 7, 41, 64, 9, 16, 3, 25, 80, 12, 39, 40, 55, 0], 2),
+    "every_row_a_whole_chunk": (
+        [128] * 16,
+        [30, 45, 100, 7, 41, 64, 9, 16, 3, 25, 80, 12, 39, 40, 55, 0], 2),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _steps16():
+    """(cfg, arena maker, history writer, step(capacities)) of the small
+    parallel-block stack, each program jitted once for all mixes."""
+    from deepspeed_tpu.models.hf_loader import config_from_hf
+    from deepspeed_tpu.models.transformer import init_params
+    from deepspeed_tpu.parallel.moe import held_experts_moe_layer
+    from tests.test_cohere2_moe import small
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg = config_from_hf(small(
+        num_hidden_layers=2, sliding_window=WINDOW16,
+        layer_types=["sliding_attention", "full_attention"]))
+    assert cfg.layer_kinds == (1, 0) and cfg.parallel_block and \
+        cfg.num_heads // cfg.kv_heads == 16 and not cfg.full_attn_rope
+    params = init_params(cfg, jax.random.PRNGKey(5), jnp.float32)
+
+    def make_arena(nb):
+        return pa.init_arena_typed(
+            cfg.layer_kinds,
+            {a: cfg.kind_kv_heads(a) for a in set(cfg.layer_kinds)}, nb,
+            BS16, cfg.head_dim, cfg.v_dim, jnp.float32)
+    history = jax.jit(lambda arena, toks, counts, pt: ragged_forward(
+        cfg, params, arena, toks, counts, jnp.zeros_like(counts), pt,
+        moe_fn=held_experts_moe_layer)[1])
+
+    @functools.lru_cache(maxsize=None)
+    def step(capacities):
+        return jax.jit(lambda arena, *a: ragged_forward(
+            cfg, params, arena, *a, moe_fn=held_experts_moe_layer,
+            fresh_prefill="split", token_capacities=capacities))
+    return cfg, make_arena, history, step
+
+
+@pytest.mark.parametrize("mix", list(_MIXES16))
+def test_full_row_ladder_matches_the_row_form(devices, mix):
+    """A 16 x 128 split step through the program a 16-sequence engine now
+    holds — instances ``(512, P = 4)``, ``(1024, P = 8)`` and the top at the
+    row slots themselves, packed with every row a chunk row — against the
+    same step in the row form: the logits of every row that fed a token and
+    every pool outside the trash pages."""
+    cfg, make_arena, history, step = _steps16()
+    counts, starts, rung = _MIXES16[mix]
+    counts, starts = (np.asarray(a, np.int32) for a in (counts, starts))
+    instances = engine_v2._instances(LADDER16, N16, C16)
+    assert instances == ((512, 4), (1024, 8), (2048, 16))
+    assert engine_v2._instance_index(
+        instances, int(counts.sum()), int((counts > 1).sum())) == rung
+    rng = np.random.default_rng(len(mix))
+    pt, nb = _pages(counts, starts, rng, N16 * MB16, MB16, BS16)
+    toks = lambda *shape: jnp.asarray(
+        rng.integers(0, cfg.vocab_size, shape), jnp.int32)
+    arena = history(make_arena(nb), toks(N16, 104), jnp.asarray(starts),
+                    jnp.asarray(pt))
+    args = (toks(N16, C16), jnp.asarray(counts), jnp.asarray(starts),
+            jnp.asarray(pt))
+    with jax.default_matmul_precision("highest"):
+        _assert_steps_agree(step(LADDER16)(arena, *args),
+                            step(())(arena, *args), arena, counts, nb, 2e-4)
 
 
 #: layer kind -> (query heads, kv heads, K width, V width, window, sink,
@@ -417,9 +566,9 @@ def test_engine_counts_the_instance_its_split_launch_took(devices, launch):
         return {n: telemetry.registry.counter("dispatch/" + n).value
                 for n in names}
 
-    def serve(max_batch_tokens):
+    def serve(max_batch_tokens, **engine):
         eng = RaggedInferenceEngineTPU(
-            cfg, dict(_ENGINE, max_batch_tokens=max_batch_tokens),
+            cfg, dict(_ENGINE, max_batch_tokens=max_batch_tokens, **engine),
             params=params)
         uids = list(range(12))
         eng.scheduler.put(uids, decoding)
@@ -438,7 +587,8 @@ def test_engine_counts_the_instance_its_split_launch_took(devices, launch):
     assert eng._token_capacities(16, 96, "split") == (128, 256, 320)
     assert engine_v2._instances((128, 256, 320), 16, 96) == \
         _ENGINE_INSTANCES
-    _rows, want, row_form = serve(16 * 96)
+    # (16 rows of an engine of 32: no full-row program, the row form)
+    _rows, want, row_form = serve(16 * 96, max_sequences=32)
     assert got == want and len(got) == 12 + len(arrivals)
     assert grew == {**({"split_grouped_steps": 1} if grouped else {}),
                     "chunk_rows": len(arrivals),

@@ -207,6 +207,15 @@ CASES = {
     # (a block of 2,048 query rows), a window of 4,096, 86 pages a row
     "paged_hist_n16_c128_q16_window_lse": lambda: _paged(
         16, 128, with_lse=True, heads=128, pages=86, window=4096),
+    # ... and what its 16-row program's grouped instances call in that
+    # one's place (PR 50): the chunk group of the 512- and the 1,024-slot
+    # instance, and every row as a row of one query
+    "paged_hist_n4_c128_q16_window_lse": lambda: _paged(
+        4, 128, with_lse=True, heads=128, pages=86, window=4096),
+    "paged_hist_n8_c128_q16_window_lse": lambda: _paged(
+        8, 128, with_lse=True, heads=128, pages=86, window=4096),
+    "paged_hist_n16_c1_q16_window_lse": lambda: _paged(
+        16, 1, with_lse=True, heads=128, pages=86, window=4096),
     # ... and at Jamba2-3B's: 20 queries over ONE KV head (a block of 2,560
     # query rows, between Command A+'s 2,048 and the 4,096 that does not
     # fit; 20 is no multiple of 8 or 16: the small tile is 4 queries, 80
@@ -252,6 +261,9 @@ KERNEL_NAMES = {
     "paged_hist_typed_window_kv8_lse": ("paged_attn_lse",),
     "paged_hist_typed_full_kv4_lse": ("paged_attn_lse",),
     "paged_hist_n16_c128_q16_window_lse": ("paged_attn_lse",),
+    "paged_hist_n4_c128_q16_window_lse": ("paged_attn_lse",),
+    "paged_hist_n8_c128_q16_window_lse": ("paged_attn_lse",),
+    "paged_hist_n16_c1_q16_window_lse": ("paged_attn_lse",),
     "paged_hist_n8_c128_q20_mqa_lse": ("paged_attn_lse",),
     "paged_hist_n64_c128_q20_mqa_lse": ("paged_attn_lse",),
     "paged_hist_n64_c1_q20_mqa_lse": ("paged_attn_lse",),
@@ -300,6 +312,8 @@ PAGED_HEADS = {
     "paged_hist_typed_full_kv4_c1_lse": 4,
     "paged_hist_typed_window_kv8_lse": 1,
     "paged_hist_n16_c128_q16_window_lse": 1,
+    "paged_hist_n4_c128_q16_window_lse": 1,
+    "paged_hist_n16_c1_q16_window_lse": 8,
     "paged_hist_n8_c128_q20_mqa_lse": 1, "paged_hist_n64_c1_q20_mqa_lse": 1,
 }
 
@@ -423,22 +437,28 @@ def _check_ladder_memory(compiled, two_rungs):
     assert _memory(compiled) < 15.75 * 2 ** 30
 
 
-def _check_split_groups(stack, compiled, text, kernel, layer_loops,
-                        two_rungs):
-    """The 64-row split program of ``stack``: THREE instances of the layer
-    loop in one executable (ISSUE 46), the history ``kernel`` called five
-    times a layer loop's layer (all 64 rows at the chunk's width in the top
-    instance; the chunk group — 8 rows at 1,024 slots, 4 at 512 — and the
-    one-query rows in each of the two under it), memory within 0.1 GB of
-    the two-rung program ``two_rungs`` — whose temporaries are no larger
-    than before the groups."""
+def _check_three_rungs(compiled, text, kernel, layer_loops, replaced):
+    """A split program of THREE instances of its layer loop in one
+    executable (ISSUE 46): three branches, the history ``kernel`` called
+    five times a layer loop's layer (every row at the chunk's width in the
+    top instance; the chunk group — 8 rows at 1,024 slots, 4 at 512 — and
+    the one-query rows in each of the two under it), memory within 0.1 GB
+    of the program it ``replaced``."""
     from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
     assert len(_branches(text)) == 3, _branches(text)
     table = scope_table_from_hlo(text)
     kernels = [n for n in table if n.startswith(kernel)]
     assert len(kernels) == 5 * layer_loops and \
         all(table[n]["scope"] == "attn_history" for n in kernels), kernels
-    _check_ladder_memory(compiled, two_rungs)
+    _check_ladder_memory(compiled, replaced)
+
+
+def _check_split_groups(stack, compiled, text, kernel, layer_loops,
+                        two_rungs):
+    """The 64-row split program of ``stack``: the three-rung ladder
+    (:func:`_check_three_rungs`) beside the two-rung program ``two_rungs``
+    — whose temporaries are no larger than before the groups."""
+    _check_three_rungs(compiled, text, kernel, layer_loops, two_rungs)
     temp = two_rungs.memory_analysis().temp_size_in_bytes
     assert temp <= _SPLIT_TEMPS_PR39[stack] + 2e6, temp
 
@@ -762,15 +782,14 @@ def _latent_2l():
         "expert_share": {"router_experts": 256, "first_expert": 0}})
 
 
-def _typed_step(one_chip, model, step, mb, make_arena):
-    """A 64-row step program of a typed stack compiled for the chip, and
-    its text: ``step`` = (chunk, ``fresh_prefill``, capacities, ...) over
-    the abstract ``make_arena()`` and a page table ``mb`` pages wide. NO
-    pool-shaped copy in the module, and no KV scatter of more updates
-    than the step's top capacity (a decode step: its rows)."""
+def _typed_step(one_chip, model, step, mb, make_arena, nb=64):
+    """An ``nb``-row (64) step program of a typed stack compiled for the
+    chip, and its text: ``step`` = (chunk, ``fresh_prefill``, capacities,
+    ...) over the abstract ``make_arena()`` and a page table ``mb`` pages
+    wide. NO pool-shaped copy in the module, and no KV scatter of more
+    updates than the step's top capacity (a decode step: its rows)."""
     from deepspeed_tpu.inference import engine_v2
     cb, fresh, capacities = step[:3]
-    nb = 64
 
     def serve_step(params, arena, tokens, counts, starts, pt):
         logits, arena = engine_v2.ragged_forward(
@@ -839,6 +858,53 @@ def test_mimo_step_scatters_its_token_slots(kind, one_chip,
             "mimo", compiled, text, "paged_attn_lse", 2, _typed_step(
                 one_chip, model, (128, "split", _TWO_RUNGS), 8,
                 make_arena)[0])
+
+
+def test_full_row_split_program_holds_its_ladder_on_v5e(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The program a 16-sequence engine runs all day (PR 50): Command A+'s
+    16-row split step as the cell builds it (benchmark/configs/
+    command-a-plus-l4-e16-serve: four layers, window window window full, 128
+    query heads on 8 KV heads, the parallel block, 16 of 128 experts held;
+    1,376 pages of 128, 86 a row) with the ladder ``(512, 1024, 2048)`` a
+    full-row program holds under row slots that hold the budget — three
+    branches, the history kernel five times a layer (``[16, 128]`` in the
+    top instance, ``[4, 128]`` / ``[8, 128]`` + ``[16, 1]`` in the two
+    under it), the write-back in blocks of 512 — beside the ROW form it
+    replaces: ``arguments + temporaries`` within 0.1 GB of it (measured
+    13.364 against 13.362 GB) and under the chip's 15.75 GiB."""
+    import types
+    from benchmark.lib import model as model_lib
+    from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
+    from deepspeed_tpu.ops import paged_attention as pa
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    conf = model_lib.load_config("command-a-plus-l4-e16-serve")
+    model = model_lib.build_model(conf)
+    engine = conf["engine"]
+    nb, cb = engine["max_sequences"], engine["prefill_chunk"]
+    mb = engine["max_seq_len"] // engine["block_size"]
+    assert model.layer_kinds == (1, 1, 1, 0) and (nb, cb, mb) == (16, 128, 86)
+    ladder = RaggedInferenceEngineTPU._token_capacities(
+        types.SimpleNamespace(config=types.SimpleNamespace(**engine)),
+        nb, cb, "split")
+    assert ladder == (512, 1024, 2048)
+
+    def make_arena():
+        return pa.init_arena_typed(
+            model.layer_kinds,
+            {a: model.kind_kv_heads(a) for a in set(model.layer_kinds)},
+            engine["num_blocks"], engine["block_size"], model.head_dim,
+            model.v_dim, jnp.bfloat16)
+    compiled, text = _typed_step(one_chip, model, (cb, "split", ladder), mb,
+                                 make_arena, nb=nb)
+    rows, _ = _typed_step(one_chip, model, (cb, "split", ()), mb, make_arena,
+                          nb=nb)
+    assert max(_kv_scatter_updates(text, jax.eval_shape(
+        make_arena).values())) == ladder[0]
+    print("full-row split program, ladder | rows: arguments + temporaries",
+          _memory(compiled), _memory(rows))
+    _check_three_rungs(compiled, text, "paged_attn_lse", model.num_layers,
+                       rows)
 
 
 @pytest.mark.parametrize("kind", list(_LATENT_STEPS))
