@@ -1383,9 +1383,17 @@ def chunked_cross_entropy(cfg: DecoderConfig, params: Params, x: jax.Array,
 
     TPU-native equivalent of the reference's tiled logits-loss
     (runtime/sequence_parallel/ulysses_sp.py:TiledFusedLogitsLoss:960):
-    the sequence is scanned in chunks with ``jax.checkpoint`` on the chunk
-    body, so backward recomputes each chunk's logits and peak memory is
-    one chunk — the difference between OOM and training for 128k vocabs.
+    the sequence is scanned in chunks and peak memory is one chunk of
+    logits — the difference between OOM and training for 128k vocabs.
+    Under differentiation the scan takes each chunk's gradients in the
+    pass that computes its logits (a ``jax.custom_vjp`` whose forward rule
+    keeps ``dx``, the summed ``dW`` and ``dbias`` of the chunks' SUMS; the
+    backward rule scales them by cotangent / live targets, in float32), so
+    a step runs the head matmul three times — logits, ``dx``, ``dW`` — and
+    never a fourth to recompute the logits. What is rounded to the head's
+    and the hidden's dtype is O(1) a token, so a float16 trainer's loss
+    scale protects the head as it does every other layer. Everything
+    either rule emits stays under the ``loss`` scope.
     """
     b, t, d = x.shape
     v = cfg.vocab_size
@@ -1404,18 +1412,27 @@ def chunked_cross_entropy(cfg: DecoderConfig, params: Params, x: jax.Array,
     if chunk >= t and chunk_size is None and \
             b * t * v * 4 > _DENSE_LOGITS_BYTES:
         # the whole-T logits fit the CHUNK budget, but an unchunked CE
-        # would also hold them live for backward (no remat) — keep the
-        # scan with at least two chunks instead
+        # would also hold them live for backward — keep the scan with at
+        # least two chunks instead
         chunk = _pick_chunk(t, b, v, budget_bytes, max_chunk=t // 2,
                             elt_bytes=eb)
     if chunk >= t:
         return cross_entropy_loss(
             lm_logits(cfg, params, x, pre_transformed=True), targets,
             ignore_index)
-    w = params["embed"]["tokens"] if cfg.tie_embeddings else params["lm_head"]
+    if cfg.tie_embeddings:
+        w = params["embed"]["tokens"]
+        bias = params["mlm_head"]["vocab_bias"] if mlm else None
+    else:
+        w = params["lm_head"]
+        bias = params.get("lm_head_bias")
     nc = t // chunk
     xs = jnp.moveaxis(x.reshape(b, nc, chunk, d), 1, 0)       # [nc,B,C,D]
     ts = jnp.moveaxis(targets.reshape(b, nc, chunk), 1, 0)    # [nc,B,C]
+    # the live targets are counted BEFORE the scan: the token mean and its
+    # gradients are the chunks' sums times one scalar
+    live = jnp.maximum(jnp.sum(targets != ignore_index), 1) \
+        .astype(jnp.float32)
 
     # logits_dtype=bf16 emits chunk logits in bf16 and upcasts inside the
     # fused reductions: the MXU still accumulates fp32 (preferred_element_
@@ -1423,33 +1440,82 @@ def chunked_cross_entropy(cfg: DecoderConfig, params: Params, x: jax.Array,
     # halves — measured +0.6 MFU points on the v5e bench. Default fp32.
     out_dt = logits_dtype or jnp.float32
 
-    @jax.checkpoint
-    def body(carry, xc_tc):
-        nll_sum, cnt = carry
-        xc, tc = xc_tc
-        if cfg.tie_embeddings:
-            logits = jnp.einsum("bcd,vd->bcv", xc, w,
-                                preferred_element_type=out_dt)
-            if mlm:
-                logits = logits + \
-                    params["mlm_head"]["vocab_bias"].astype(out_dt)
-        else:
-            logits = jnp.einsum("bcd,dv->bcv", xc, w,
-                                preferred_element_type=out_dt)
-            if "lm_head_bias" in params:
-                logits = logits + params["lm_head_bias"].astype(out_dt)
+    # inside a shard_map (the pipeline's) the hidden may vary over a manual
+    # axis the head does not: everything the scans carry is made to vary
+    # as their inputs do, and the head with it, so dW crosses that axis
+    # once, where the cast is transposed, not once a chunk (outside a
+    # shard_map nothing varies and nothing is cast)
+    vma = frozenset().union(*(jax.typeof(a).vma for a in jax.tree.leaves(
+        (xs, w, bias, ts))))
+
+    def vary(a):
+        missing = tuple(sorted(vma - jax.typeof(a).vma))
+        return lax.pcast(a, missing, to="varying") if missing else a
+
+    w, bias, live = jax.tree.map(vary, (w, bias, live))
+
+    def term(xc, w, bias, tc):
+        """One chunk's SUM of token losses (the mean's divisor comes after:
+        what is differentiated is O(1) a token in any 16-bit dtype)."""
+        logits = jnp.einsum(
+            "bcd,vd->bcv" if cfg.tie_embeddings else "bcd,dv->bcv", xc, w,
+            preferred_element_type=out_dt)
+        if bias is not None:
+            logits = logits + bias.astype(out_dt)
         logits = _softcap(cfg, logits)
         mask = tc != ignore_index
         safe = jnp.where(mask, tc, 0)
         logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
         gold = jnp.take_along_axis(logits, safe[..., None],
                                    axis=-1)[..., 0].astype(jnp.float32)
-        nll = jnp.sum((logz - gold) * mask)
-        return (nll_sum + nll, cnt + jnp.sum(mask)), None
+        return jnp.sum((logz - gold) * mask)
 
-    (nll, cnt), _ = lax.scan(body, (jnp.zeros((), jnp.float32),
-                                    jnp.zeros((), jnp.int32)), (xs, ts))
-    return nll / jnp.maximum(cnt, 1)
+    @jax.custom_vjp
+    def scanned(xs, w, bias, ts, live):
+        with jax.named_scope("loss"):
+            return lax.scan(
+                lambda acc, xc_tc: (acc + term(xc_tc[0], w, bias, xc_tc[1]),
+                                    None),
+                vary(jnp.zeros((), jnp.float32)), (xs, ts))[0] / live
+
+    def scanned_fwd(xs, w, bias, ts, live):
+        def body(carry, xc_tc):
+            total, dwb = carry
+            xc, tc = xc_tc
+            lc, (dxc, dwc, dbc) = jax.value_and_grad(
+                term, argnums=(0, 1, 2))(xc, w, bias, tc)
+            dwb = jax.tree.map(lambda a, g: a + g.astype(a.dtype), dwb,
+                               (dwc, dbc))
+            return (total + lc, dwb), dxc
+
+        # the SUM's gradients are kept (softmax - onehot is O(1), so dx and
+        # dW round in range in x's and w's own dtype, float16 under a loss
+        # scale included); dW / dbias are summed over the chunks in float32
+        # whatever the head's dtype (the scan's transpose summed them in
+        # w's own)
+        with jax.named_scope("loss"):
+            zeros = jax.tree.map(
+                lambda p: vary(jnp.zeros(p.shape, jnp.promote_types(
+                    p.dtype, jnp.float32))), (w, bias))
+            (total, dwb), dxs = lax.scan(
+                body, (vary(jnp.zeros((), jnp.float32)), zeros), (xs, ts))
+        return total / live, (dxs, dwb, live)
+
+    head_dtypes = jax.tree.map(lambda p: p.dtype, (w, bias))
+
+    def scanned_bwd(res, g):
+        dxs, dwb, live = res
+        # cotangent and the mean's divisor meet the residuals as ONE
+        # float32 scalar, before anything is cast down
+        with jax.named_scope("loss"):
+            s = g.astype(jnp.float32) / live
+            dxs = (s * dxs).astype(dxs.dtype)
+            dw, dbias = jax.tree.map(lambda r, dt: (s * r).astype(dt),
+                                     dwb, head_dtypes)
+        return dxs, dw, dbias, None, None
+
+    scanned.defvjp(scanned_fwd, scanned_bwd)
+    return scanned(xs, w, bias, ts, live)
 
 
 @jax.named_scope("loss")

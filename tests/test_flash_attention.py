@@ -141,6 +141,156 @@ def test_chunked_cross_entropy_matches_full():
                                    rtol=2e-4, atol=1e-5)
 
 
+def _ce_case(case, dtype=jnp.float32):
+    """(cfg, head params, hidden, targets) of one head form at toy widths."""
+    import dataclasses
+    from deepspeed_tpu.models.llama import llama3_config
+    from deepspeed_tpu.models.transformer import init_params
+    # the fp16 case is a step's worth of tokens (8 x 2,048 live targets) at a
+    # trainer's init_std: the mean's gradients, 1/16,384 of O(|w|), lie
+    # under float16's normal range and only the loss scale holds them up
+    fp16 = case == "fp16_loss_scale"
+    b, t = (8, 2048) if fp16 else (2, 64)
+    cfg = llama3_config("tiny", max_seq_len=t, vocab_size=256)
+    cfg = dataclasses.replace(
+        cfg, init_std=0.02 if fp16 else 0.3, tie_embeddings=case == "tied",
+        logit_softcap=5.0 if case == "softcap" else 0.0)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    head = {"embed": params["embed"]} if cfg.tie_embeddings else \
+        {"lm_head": params["lm_head"]}
+    if case == "bias":
+        head["lm_head_bias"] = jnp.asarray(rng.normal(size=(256,)),
+                                           jnp.float32)
+    x = jnp.asarray(rng.normal(size=(b, t, cfg.hidden_size)), jnp.float32)
+    tgt = rng.integers(0, 256, size=(b, t), dtype=np.int32)
+    if case == "some_ignored":      # a whole chunk dead, and a ragged tail
+        tgt[:, 16:32] = -100
+        tgt[1, 50:] = -100
+    elif case == "all_ignored":
+        tgt[:] = -100
+    head, x = jax.tree.map(lambda a: a.astype(dtype), (head, x))
+    return cfg, head, x, jnp.asarray(tgt)
+
+
+@pytest.mark.parametrize("logits_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", ["untied", "tied", "bias", "softcap",
+                                  "some_ignored", "all_ignored", "scaled",
+                                  "fp16_loss_scale"])
+def test_chunked_cross_entropy_grads_match_dense(case, logits_dtype):
+    """Loss AND d/dx, d/dW, d/dbias of the scanned head equal the dense
+    head's, whatever the head's form, the mask or the upstream cotangent
+    (the chunk's gradients are taken in the forward rule and only scaled
+    in the backward one) — a float16 head under a 2^16 loss scale included:
+    what the forward rule rounds to float16 is the chunk SUM's gradient,
+    and cotangent / live meets it in float32."""
+    from deepspeed_tpu.models.transformer import (chunked_cross_entropy,
+                                                  cross_entropy_loss,
+                                                  lm_logits)
+    fp16 = case == "fp16_loss_scale"
+    cfg, head, x, tgt = _ce_case(case, jnp.float16 if fp16 else jnp.float32)
+    scale = {"scaled": 0.37, "fp16_loss_scale": 2.0 ** 16}.get(case, 1.0)
+    chunk = x.shape[1] // 4
+
+    def chunked(head, x):
+        return scale * chunked_cross_entropy(cfg, head, x, tgt,
+                                             chunk_size=chunk,
+                                             logits_dtype=logits_dtype)
+
+    def dense(head, x):
+        return scale * cross_entropy_loss(lm_logits(cfg, head, x), tgt)
+
+    lc, gc = jax.jit(jax.value_and_grad(chunked, argnums=(0, 1)))(head, x)
+    # the dense head in float32, on the same (float16-valued) numbers
+    ld, gd = jax.value_and_grad(dense, argnums=(0, 1))(*jax.tree.map(
+        lambda a: a.astype(jnp.float32), (head, x)))
+    # bf16 logits round at 2^-9 of |logit| <= ~10 here
+    tol = 1e-5 if logits_dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(float(lc), float(ld), rtol=tol, atol=tol)
+    # the primal (no gradient asked) is the same number
+    np.testing.assert_allclose(float(jax.jit(chunked)(head, x)), float(lc),
+                               rtol=1e-6)
+    assert jax.tree.structure(gc) == jax.tree.structure(gd)
+    # a float16 gradient rounds at 2^-11 of a chunk's own value, no lower
+    gtol, gatol = (max(tol, 2e-3), max(tol, 1e-3)) if fp16 else (tol, tol)
+    for a, b in zip(jax.tree.leaves(gc), jax.tree.leaves(gd)):
+        assert a.dtype == (jnp.float16 if fp16 else b.dtype)
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(
+            a, b, rtol=gtol,
+            atol=gatol * max(np.abs(b).max(), 1e-30))
+    if case == "all_ignored":
+        assert float(lc) == 0.0
+        assert all(not np.asarray(g).any() for g in jax.tree.leaves(gc))
+
+
+def test_chunked_cross_entropy_bf16_head_grads():
+    """A bf16 head (the training cells' dtype): gradients come back in the
+    head's and the hidden's own dtype, the chunks' dW summed in float32."""
+    from deepspeed_tpu.models.transformer import (chunked_cross_entropy,
+                                                  cross_entropy_loss,
+                                                  lm_logits)
+    cfg, head, x, tgt = _ce_case("bias", jnp.bfloat16)
+    gc = jax.jit(jax.grad(lambda h, x: chunked_cross_entropy(
+        cfg, h, x, tgt, chunk_size=16, logits_dtype=jnp.bfloat16),
+        argnums=(0, 1)))(head, x)
+    gd = jax.grad(lambda h, x: cross_entropy_loss(lm_logits(cfg, h, x), tgt),
+                  argnums=(0, 1))(*jax.tree.map(
+                      lambda a: a.astype(jnp.float32), (head, x)))
+    for a, b in zip(jax.tree.leaves(gc), jax.tree.leaves(gd)):
+        assert a.dtype == jnp.bfloat16
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        np.testing.assert_allclose(a, b, atol=3e-2 * np.abs(b).max())
+
+
+def _head_dots(jaxpr, vocab, times=1):
+    """(dot_generals with a ``vocab``-sized dimension, each counted once a
+    trip of the scans around it; checkpoint equations) in a jaxpr."""
+    dots = remats = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and any(
+                vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
+            dots += times
+        remats += eqn.primitive.name in ("checkpoint", "remat", "remat2")
+        inner = times * eqn.params.get("length", 1) \
+            if eqn.primitive.name == "scan" else times
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            d, r = _head_dots(sub, vocab, inner)
+            dots, remats = dots + d, remats + r
+    return dots, remats
+
+
+@pytest.mark.parametrize("case", ["untied", "tied", "softcap"])
+def test_chunked_cross_entropy_runs_three_head_matmuls(case):
+    """The mechanism, on the CPU: a differentiated chunk holds THREE
+    head-sized matmuls (logits, dx, dW) and nothing rematerialised — the
+    checkpointed body held four — and the plain call holds one."""
+    from deepspeed_tpu.models.transformer import chunked_cross_entropy
+    cfg, head, x, tgt = _ce_case(case)
+    chunks = 4
+
+    def loss(head, x):
+        return chunked_cross_entropy(cfg, head, x, tgt,
+                                     chunk_size=64 // chunks)
+
+    grad = jax.grad(loss, argnums=(0, 1))
+    assert _head_dots(jax.make_jaxpr(grad)(head, x).jaxpr, 256) == \
+        (3 * chunks, 0)
+    assert _head_dots(jax.make_jaxpr(loss)(head, x).jaxpr, 256) == \
+        (chunks, 0)
+    # every matmul of both rules still carries the scope the benchmark's
+    # loss_ms_per_step reads, and nothing reads as recomputed
+    import re
+    from deepspeed_tpu.telemetry.explain import scope_of_op_name
+    text = jax.jit(grad).lower(head, x).compile().as_text()
+    dots = set(re.findall(r'op_name="([^"]*dot_general)"', text))
+    assert len(dots) >= 2 and all(
+        scope_of_op_name(n)["scope"] == "loss" for n in dots)
+    assert "rematted_computation" not in text
+
+
 # ---------------------------------------------------------------------------
 # XL (KV-blocked-grid) kernels — the long-context path
 # ---------------------------------------------------------------------------

@@ -442,11 +442,15 @@ def _param_tier_cfg(tmp_path, device="nvme"):
     }
 
 
-def test_param_tier_matches_plain_engine(tmp_path, devices):
+@pytest.mark.parametrize("head", ["dense", "scanned"])
+def test_param_tier_matches_plain_engine(head, tmp_path, devices,
+                                         monkeypatch):
     """VERDICT r3 missing #8: ZeRO-Infinity param tier — params stream
     from the file store layer by layer (peak HBM one layer + acts) and the
     windowed tiered Adam updates master+params in place. Loss trajectory
-    must match the plain on-device engine within streaming round-off."""
+    must match the plain on-device engine within streaming round-off —
+    also where the tier's head_loss takes the scanned head (two chunks)
+    and the plain engine the dense one."""
     from deepspeed_tpu.models.gpt import gpt2_config
     from deepspeed_tpu.parallel.mesh import build_mesh
     from deepspeed_tpu.runtime.engine import initialize
@@ -467,6 +471,11 @@ def test_param_tier_matches_plain_engine(tmp_path, devices):
         rng=jax.random.PRNGKey(11))
     base = [float(e0.train_batch(iter([b]))) for b in batches]
 
+    if head == "scanned":
+        # no logits are small enough for the dense shortcut: whatever is
+        # traced from here on scans at least two chunks
+        from deepspeed_tpu.models import transformer
+        monkeypatch.setattr(transformer, "_DENSE_LOGITS_BYTES", 0)
     build_mesh(data=1, devices=jax.devices()[:1])
     e1, *_ = initialize(model=model, config=_param_tier_cfg(tmp_path),
                         rng=jax.random.PRNGKey(11))

@@ -352,3 +352,65 @@ def test_1f1b_phi_untied_head_bias_grads(devices):
                                  jtu.tree_flatten_with_path(g1)[0]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-4, err_msg=str(path))
+
+
+@pytest.mark.parametrize("family", ["gpt2_tied", "phi_untied_bias"])
+@pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
+def test_pipeline_scanned_head_matches_dense(schedule, family, devices):
+    """A CE budget that forces chunk < t runs the scanned head — a
+    custom_vjp whose forward rule sums dW over the chunks — INSIDE the
+    pipeline's shard_map (under jax.grad in GPipe, under jax.vjp with the
+    tail params invariant on 'pipe' in 1F1B): loss and every gradient
+    equal the same schedule's with the dense head, and the non-pipelined
+    dense model's."""
+    import jax.tree_util as jtu
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.phi import phi_config
+    from deepspeed_tpu.runtime.pipe.pipeline import (
+        pipelined_loss, pipelined_loss_and_grads_1f1b)
+    build_mesh(pipe=2, data=4)
+    make = gpt2_config if family == "gpt2_tied" else phi_config
+    model = make("tiny", max_seq_len=SEQ, vocab_size=VOCAB)
+    p = T.init_params(model, jax.random.PRNGKey(0))
+    if "lm_head_bias" in p:
+        p["lm_head_bias"] = jax.random.normal(
+            jax.random.PRNGKey(1), p["lm_head_bias"].shape, jnp.float32)
+    rng = np.random.default_rng(2)
+    M, B = 4, 8
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (M, B, SEQ), dtype=np.int32))
+    labels = jnp.concatenate(
+        [tokens[:, :, 1:], jnp.full_like(tokens[:, :, :1], -100)], axis=2)
+    # 64 KB of float32 logits: 8 positions of a microbatch's 8 rows (1F1B),
+    # 2 of all 32 rows (GPipe) — four and sixteen chunks of SEQ = 32
+    budget = B * 8 * VOCAB * 4
+    assert T._pick_chunk(SEQ, B, VOCAB, budget) == 8
+    assert T._pick_chunk(SEQ, M * B, VOCAB, budget) == 2
+
+    def run(ce_budget_bytes):
+        if schedule == "gpipe":
+            return jax.jit(jax.value_and_grad(lambda q: pipelined_loss(
+                model, q, tokens, labels, num_stages=2,
+                ce_budget_bytes=ce_budget_bytes)))(p)
+        return jax.jit(lambda q: pipelined_loss_and_grads_1f1b(
+            model, q, tokens, labels, num_stages=2,
+            ce_budget_bytes=ce_budget_bytes))(p)
+
+    def plain(q):
+        hidden, _ = T.forward_hidden(model, q, tokens.reshape(M * B, SEQ))
+        return T.cross_entropy_loss(T.lm_logits(model, q, hidden),
+                                    labels.reshape(M * B, SEQ))
+
+    l_s, g_s = run(budget)
+    l_d, g_d = run(None)        # the default budget: the dense shortcut
+    l_p, g_p = jax.jit(jax.value_and_grad(plain))(p)
+    np.testing.assert_allclose(float(l_s), float(l_d), rtol=1e-5)
+    np.testing.assert_allclose(float(l_s), float(l_p), rtol=1e-5)
+    assert jtu.tree_structure(g_s) == jtu.tree_structure(g_d) == \
+        jtu.tree_structure(g_p)
+    for (path, a), (_, b), (_, c) in zip(
+            *(jtu.tree_flatten_with_path(g)[0] for g in (g_s, g_d, g_p))):
+        a, b, c = (np.asarray(v, np.float32) for v in (a, b, c))
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6,
+                                   err_msg=str(path))
+        np.testing.assert_allclose(a, c, rtol=2e-3, atol=2e-4,
+                                   err_msg=str(path))

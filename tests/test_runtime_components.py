@@ -47,6 +47,43 @@ def test_eigenvalue_per_layer():
     assert abs(out["a"] - 3.0) < 0.05 and abs(out["b"] - 7.0) < 0.05
 
 
+def test_eigenvalue_hvp_through_chunked_cross_entropy():
+    """Forward-over-reverse runs through both rules of the scanned head's
+    custom_vjp: H·v equals the dense head's."""
+    from deepspeed_tpu.models.llama import llama3_config
+    from deepspeed_tpu.models.transformer import (chunked_cross_entropy,
+                                                  cross_entropy_loss,
+                                                  init_params, lm_logits)
+    from deepspeed_tpu.runtime.eigenvalue import _hvp
+    cfg = llama3_config("tiny", max_seq_len=32, vocab_size=128)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    head = {"lm_head": params["lm_head"], "x": jnp.asarray(
+        rng.normal(size=(2, 32, cfg.hidden_size)), jnp.float32)}
+    tgt = jnp.asarray(rng.integers(0, 128, size=(2, 32), dtype=np.int32))
+    v = jax.tree.map(lambda a: jnp.asarray(rng.normal(size=a.shape),
+                                           jnp.float32), head)
+
+    def chunked(p):
+        return chunked_cross_entropy(cfg, p, p["x"], tgt, chunk_size=8)
+
+    def dense(p):
+        return cross_entropy_loss(lm_logits(cfg, p, p["x"]), tgt)
+
+    hc, hd = jax.jit(lambda: _hvp(chunked, head, v))(), _hvp(dense, head, v)
+    for k in head:
+        assert np.abs(np.asarray(hd[k])).max() > 1e-6
+        np.testing.assert_allclose(np.asarray(hc[k]), np.asarray(hd[k]),
+                                   rtol=1e-4, atol=1e-7)
+    ev = Eigenvalue(max_iter=20, tol=1e-3).compute_eigenvalue(
+        chunked, head, jax.random.PRNGKey(1))
+    evd = Eigenvalue(max_iter=20, tol=1e-3).compute_eigenvalue(
+        dense, head, jax.random.PRNGKey(1))
+    assert ev.keys() == evd.keys()
+    for k in ev:
+        assert ev[k] == pytest.approx(evd[k], rel=1e-3)
+
+
 # ---------------------------------------------------------------------------
 # progressive layer drop
 # ---------------------------------------------------------------------------

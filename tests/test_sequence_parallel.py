@@ -133,6 +133,40 @@ def test_ulysses_end_to_end_training(devices):
     np.testing.assert_allclose(ulysses, base, rtol=5e-4, atol=5e-4)
 
 
+def test_ulysses_scanned_head_matches_dense(devices):
+    """SP=4 with a CE budget that forces chunk < t (the scanned head's
+    custom_vjp on a sequence-sharded hidden) trains as the data-parallel
+    engine with the dense head does."""
+    from deepspeed_tpu.models.llama import llama3_config
+    from deepspeed_tpu.models.transformer import _pick_chunk
+    from deepspeed_tpu.runtime.engine import initialize
+
+    model = llama3_config("tiny", max_seq_len=64, vocab_size=1024)
+    assert _pick_chunk(64, 8, 1024, 1 << 20) == 32
+    rng = np.random.default_rng(0)
+    batches = [{"input_ids": rng.integers(0, 1024, size=(8, 64),
+                                          dtype=np.int32)}
+               for _ in range(3)]
+
+    def run(topo, **extra):
+        build_mesh(**topo)
+        cfg = {
+            "train_micro_batch_size_per_gpu": 8 // topo["data"],
+            "optimizer": {"type": "adam", "params": {"lr": 1e-3}},
+            "zero_optimization": {"stage": 1},
+            "sequence_parallel": {"size": topo.get("seq", 1),
+                                  "mode": "ulysses"},
+            **extra,
+        }
+        eng, *_ = initialize(model=model, config=cfg,
+                             rng=jax.random.PRNGKey(5))
+        return [float(eng.train_batch(iter([b]))) for b in batches]
+
+    dense = run(dict(data=8))
+    scanned = run(dict(data=2, seq=4), chunked_ce_budget_mb=1)
+    np.testing.assert_allclose(scanned, dense, rtol=5e-4, atol=5e-4)
+
+
 def test_ring_end_to_end_training(devices):
     from deepspeed_tpu.models.llama import llama3_config
     from deepspeed_tpu.runtime.engine import initialize

@@ -605,7 +605,9 @@ def test_step_program_maps_to_scopes_on_v5e(program, one_chip,
     remat = {e["scope"] for e in table.values() if e["remat"]}
     if program == "fused_step":
         assert {"mlp", "attn_qkv", "loss"} <= backward
-        assert "mlp" in remat and "loss" in remat
+        # the head takes its gradients in the pass that computes its
+        # logits (ISSUE 51): nothing under ``loss`` is computed again
+        assert "mlp" in remat and "loss" not in remat
     else:
         assert not backward - {None} and not remat - {None}
 
