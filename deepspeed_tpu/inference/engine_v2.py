@@ -324,7 +324,7 @@ def _write_back(counts: jax.Array, starts: jax.Array, c: int, capacities,
 
 def _split_attention(lay: _TokenLayout, qkv, history, own, *,
                      scale: Optional[float] = None, sink=None,
-                     q_history=None, expand=None) -> jax.Array:
+                     q_history=None, expand=None, picked=None) -> jax.Array:
     """A split step's attention of ONE layer, token-wise form in and out:
     for each of the layout's row groups (:meth:`_TokenLayout.groups`)
     unpack ``qkv`` to its rows, read the rows' history — ``history(q,
@@ -336,7 +336,11 @@ def _split_attention(lay: _TokenLayout, qkv, history, own, *,
     —, merge the two partials by their logsumexps in float32 (``sink``: a
     learned logit beside them), and pack the groups' results back. The
     layers differ in the reader and the chunk attention they pass; the
-    scopes are the same for all."""
+    scopes are the same for all. ``picked(group)`` (a stack that picks its
+    keys): which of the chunk's OWN keys each query kept — ``[m, c, c]``
+    bool, handed to ``own(q, k, v, picked=)``; for a group of one-query
+    rows ``[m]``: whether the row kept its own key (one that did not
+    leaves its history alone in the merge)."""
     q_dtype = qkv[0].dtype
     groups = lay.groups()
 
@@ -362,8 +366,14 @@ def _split_attention(lay: _TokenLayout, qkv, history, own, *,
         with jax.named_scope("attn_history"):
             out_h, lse_h = history(q_h, group)
         with jax.named_scope("attn_core"):
-            out_c, lse_c = own(q, k, v) if group.c > 1 else \
-                pa.one_key_attention_with_lse(q, k, v, scale)
+            if group.c > 1:
+                out_c, lse_c = own(q, k, v) if picked is None else \
+                    own(q, k, v, picked=picked(group))
+            else:
+                out_c, lse_c = pa.one_key_attention_with_lse(q, k, v, scale)
+                if picked is not None:
+                    lse_c = jnp.where(picked(group)[:, None, None], lse_c,
+                                      pa._NEG_INF)
         partials = (out_h, lse_h, out_c, lse_c)
         outs.append(partials if pack_first else merged(*partials))
     with jax.named_scope("attn_out"):         # ... and tokens again
@@ -621,6 +631,24 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     a split step in the heads' space, on the packed tokens. The step's
     shape picks the form: no option does.
 
+    A latent stack that PICKS ITS KEYS (``cfg.layer_indexer``;
+    ``pick_keys`` below) runs three steps more in a layer that OWNS an
+    indexer: its index keys go into ``pa.INDEX_POOL`` as the latents go
+    into theirs (same page table, same write or write-back); its queries
+    SCORE every key they can see — the row's pages of that pool, and in a
+    split step the chunk's own index keys beside them —; and the exact
+    top ``index_topk`` of those scores are what the layer's softmax runs
+    over. The picks are CARRIED through the layer loop (``sel``): a layer
+    that borrows reads them as the owner below left them. Their form
+    follows the row group: a row of ONE query holds ``index_topk``
+    POSITIONS and reads those rows of the latent pool by token index
+    (``pa.picked_attention``); a chunk's queries hold a MASK over the page
+    table's positions and over their own chunk, under which the history
+    walk (``mla_decode``) and the chunk's own attention run — picks fall on
+    both sides of the chunk's edge, and ``merge_attention`` joins them as
+    it joins the dense partials. With every context at most ``index_topk``
+    every key is picked and the result is the dense stack's.
+
     A STATE-SPACE layer (kinds 3, 4) has no pages: what its rows carry lives in
     the state pools (``ops/ssm.init_state_pools``), a slot a sequence
     (``slots``), read and written by every launch that holds the row, in
@@ -659,15 +687,27 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         # padded entries of the page table → this layer's trash
         places.append((names, page_table + off, off + stride - 1))
 
+    # an owner's region of the index pool, as ``places`` holds a layer's
+    index_places, owners = [], 0
+    for l in range(len(cfg.layer_kinds)):
+        if not cfg.layer_owns_indexer(l):
+            index_places.append(None)
+            continue
+        stride = arena[pa.INDEX_POOL].shape[0] // cfg.indexer_layers
+        off = owners * stride
+        owners += 1
+        index_places.append(((pa.INDEX_POOL,), page_table + off,
+                             off + stride - 1))
+
     held_slots = _write_back_slots(token_capacities,
                                    tokens.shape[0] * c)[1]
 
-    def write(pools, place, slots, *kv):
+    def write(pools, place, slots, *kv, scope="kv_write"):
         """A layer's chunk into its pools, token-wise (``slots``: the
         layout's ``kv_slots()``): (k, v), or a latent layer's one row a
-        token."""
+        token (an indexer's one key: under its own ``scope``)."""
         names, pt_l, trash = place
-        with jax.named_scope("kv_write"):
+        with jax.named_scope(scope):
             if len(names) == 1:
                 pools[names[0]] = pa.write_rows(
                     pools[names[0]], *kv, pt_l, *slots, trash_block=trash)
@@ -676,10 +716,10 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                     pools[names[0]], pools[names[1]], *kv, pt_l, *slots,
                     trash_block=trash)
 
-    def keep(chunk_kv, pools, names, *kv):
+    def keep(chunk_kv, pools, names, *kv, scope="kv_write"):
         """A split step's chunk of one layer, as it waits for the
         write-back."""
-        with jax.named_scope("kv_write"):
+        with jax.named_scope(scope):
             chunk_kv.append(tuple(
                 _slot_major(t.astype(pools[name].dtype), held_slots)
                 for t, name in zip(kv, names)))
@@ -722,17 +762,91 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         with jax.named_scope("attn_out"):     # ... and tokens again
             return lay.to_tokens(out)
 
+    def pick_keys(lay, p, iplace, h_in, c_q, table, pools, chunk_kv):
+        """The three steps of a layer that OWNS an indexer, on its normed
+        input → the picks as the layer and its borrowers read them. Fresh
+        step: ``[n, c, c]`` bool over the chunk's own keys, or None where
+        the chunk is no longer than ``index_topk`` (every key is picked).
+        Decode step (after the index keys' write): ``(positions [n, K],
+        live [n, K])``. Split step, a row group's width → its picks: a
+        group of ONE query ``(positions, live — the picks in the history
+        —, own [m]: the row kept its own key)``, a chunk group
+        ``(history [m, c, positions], own [m, c, c])`` bool. The index
+        pool a split step scores is the one BEFORE its write-back, as the
+        latent pool its history reads."""
+        names, pt_i, _ = iplace
+        topk = cfg.index_topk
+        q_i, k_i, w_i = tl.index_qkw(cfg, p, h_in, c_q, *table)
+        if split:       # (the write-back itself is one loop: kv_write)
+            keep(chunk_kv, pools, names, k_i, scope="attn_index")
+        else:
+            write(pools, iplace, lay.kv_slots(), k_i, scope="attn_index")
+        if fresh_prefill == "fresh":
+            if c <= topk:       # every key of the chunk is picked
+                return None
+            with jax.named_scope("attn_index"):
+                scores = pa.index_scores(*(lay.to_rows(t)
+                                           for t in (q_i, k_i, w_i)))
+            with jax.named_scope("attn_select"):
+                return pa.topk_mask(pa.causal_only(scores), topk)
+        kpos = jnp.arange(pt_i.shape[1] * pools[names[0]].shape[1],
+                          dtype=jnp.int32)
+        if not split:           # the decode step: its key is in the pool
+            with jax.named_scope("attn_index"):
+                scores = pa.index_scores_paged(
+                    lay.to_rows(q_i), lay.to_rows(w_i), pools[names[0]],
+                    pt_i)
+            with jax.named_scope("attn_select"):
+                return pa.topk_picks(jnp.where(
+                    kpos[None] <= starts[:, None], scores[:, 0], -jnp.inf),
+                    topk)
+        sel = {}
+        for group in lay.groups():
+            start = group.of(starts)[:, None, None]
+            with jax.named_scope("attn_index"):
+                q_g, k_g, w_g = (group.take(t) for t in (q_i, k_i, w_i))
+                hist = pa.index_scores_paged(q_g, w_g, pools[names[0]],
+                                             group.of(pt_i))
+                own = pa.index_scores(q_g, k_g, w_g)
+            with jax.named_scope("attn_select"):
+                if group.c == 1:
+                    # the row's own key stands at ITS position, which the
+                    # pool does not hold yet
+                    scores = jnp.where(
+                        kpos < start, hist, jnp.where(kpos == start, own,
+                                                      -jnp.inf))[:, 0]
+                    picks, live = pa.topk_picks(scores, topk)
+                    mine = picks == start[:, 0]
+                    sel[1] = (picks, live & ~mine,
+                              jnp.any(live & mine, axis=-1))
+                    continue
+                both = pa.topk_mask(jnp.concatenate([
+                    jnp.where(kpos < start, hist, -jnp.inf),
+                    pa.causal_only(own)], axis=-1), topk)
+                sel[group.c] = (both[..., :kpos.shape[0]],
+                                both[..., kpos.shape[0]:])
+        return sel
+
     def latent_attention(lay, _kind, a, place, h_in, table, pools,
-                         chunk_kv):
+                         chunk_kv, indexer=None, sel=None):
         """A latent layer's attention on its normed input (token-wise
         form) → the heads' outputs [.., H, v] in the same form. What READS
         THE CACHE is absorbed (the decode step, the split step's history:
         queries in the latent space against the pool's rows, ``W_UV``
         after); a chunk's OWN attention is expanded from the chunk's own
-        latents (short: ``W_kvb`` over its tokens, heads of nope + rope)."""
+        latents (short: ``W_kvb`` over its tokens, heads of nope + rope).
+        ``indexer`` (its tree, its region of the index pool): the layer
+        owns one and picks (``pick_keys``) into ``sel["picks"]``, which a
+        stack that picks its keys carries from layer to layer; the softmax
+        of every form runs over those picks."""
         (pool,), pt_l, _ = place
         kl = cfg.kv_lora_rank
         q_nope, q_rope, latent = tl.latent_qkv(cfg, a, h_in, *table)
+        if indexer is not None:
+            sel["picks"] = pick_keys(
+                lay, *indexer, h_in, tl.latent_query_latent(cfg, a, h_in),
+                table, pools, chunk_kv)
+        picks = sel["picks"] if cfg.picks_keys else None
         own = split or fresh_prefill == "fresh"
         if own:
             qkv = tl.latent_expand_kv(cfg, a, q_nope, q_rope, latent)
@@ -741,22 +855,33 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                                        pools[pool].shape[-1])
         if split:
             def history(q, rows):
+                if picks is not None and rows.c == 1:
+                    return pa.picked_attention(
+                        q, pools[pool], rows.of(pt_l), *picks[1][:2],
+                        v_lanes=kl, scale=scale)
                 return pa.paged_history_with_lse(
                     q, pools[pool], None, rows.of(pt_l), rows.of(starts),
-                    rows.counts, kernel=use_pallas, scale=scale, v_lanes=kl)
+                    rows.counts, kernel=use_pallas, scale=scale, v_lanes=kl,
+                    picked=None if picks is None else picks[rows.c][0])
 
             keep(chunk_kv, pools, place[0], latent)
             return _split_attention(
                 lay, qkv, history,
                 partial(pa.causal_attention_with_lse, scale=scale),
                 scale=scale, q_history=q_lat,
-                expand=partial(tl.latent_expand_out, cfg, a))
+                expand=partial(tl.latent_expand_out, cfg, a),
+                picked=None if picks is None else
+                lambda rows: picks[rows.c][-1])
         write(pools, place, lay.kv_slots(), latent)
         with jax.named_scope("attn_qkv"):     # attention sees rows
             q, *kv = (lay.to_rows(t) for t in (qkv if own else (q_lat,)))
         with jax.named_scope("attn_core"):
             if own:
-                out = pa.causal_attention_with_lse(q, *kv, scale=scale)[0]
+                out = pa.causal_attention_with_lse(q, *kv, scale=scale,
+                                                   picked=picks)[0]
+            elif picks is not None:
+                out = pa.picked_attention(q, pools[pool], pt_l, *picks,
+                                          v_lanes=kl, scale=scale)[0]
             elif use_pallas:
                 out = pa.mla_decode(q, pools[pool], pt_l, starts, counts,
                                     counts, v_lanes=kl, scale=scale)[0]
@@ -855,8 +980,9 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         tables = tl.rope_tables(cfg, lay.positions)
         pools = dict(arena, **(state or {}))
         chunk_kv = []
-        for kind, lp, place in zip(cfg.layer_kinds, params["layers"],
-                                   places):
+        sel = {}        # the picks an owner of an indexer leaves its borrowers
+        for kind, lp, place, iplace in zip(cfg.layer_kinds, params["layers"],
+                                           places, index_places):
             h = _norm(cfg, lp["ln1"], x)
             if kind in STATE_SPACE_KINDS:
                 out = state_space(lay, kind, lp["ssm"], place,
@@ -865,6 +991,9 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                 out = None
             else:
                 attend = latent_attention if kind == 2 else heads_attention
+                if cfg.picks_keys:
+                    attend = partial(attend, sel=sel, indexer=iplace and (
+                        lp["indexer"], iplace))
                 out = tl.typed_attn_out(cfg, lp["attn"], attend(
                     lay, kind, lp["attn"], place, h.astype(dtype),
                     tables[kind], pools, chunk_kv))
@@ -883,8 +1012,11 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     if not split:
         return lm_logits(cfg, params, x_last)[:, 0], out[0]
     chunk_kv, state = out
-    paged = [place for kind, place in zip(cfg.layer_kinds, places)
-             if kind in pa.KIND_POOLS]
+    # in the order the layers kept their chunks: an owner's index keys,
+    # then the layer's own pools
+    paged = [pl for kind, place, iplace in zip(cfg.layer_kinds, places,
+                                               index_places)
+             if kind in pa.KIND_POOLS for pl in (iplace, place) if pl]
 
     def write_layers(pools, slots, take):
         pools = dict(pools)
@@ -1176,7 +1308,11 @@ class RaggedInferenceEngineTPU:
                 model.layer_kinds,
                 {a: model.kind_kv_heads(a) for a in set(model.layer_kinds)},
                 config.num_blocks, config.block_size, self.k_width,
-                model.v_dim, self.dtype)
+                model.v_dim, self.dtype,
+                # a stack that picks its keys: the index pool beside them
+                **(dict(index_layers=model.indexer_layers,
+                        index_width=model.index_head_dim)
+                   if model.picks_keys else {}))
             if model.recurrent:
                 # beside the pages: a float32 state and a convolution tail
                 # a sequence slot and state-space layer; the pool's size
@@ -1779,7 +1915,7 @@ class RaggedInferenceEngineTPU:
                 chunk_rows=chunk_rows,
                 attn_row_slots=attn_row_slots if grouped else None,
                 state=self._state_work(batch, cb, grouped),
-                kv_pages=kv_pages)
+                kv_pages=kv_pages, picked=self._picked_work(batch))
             if sp is not None:      # still the recorded event's arguments
                 sp.update(work)
         return out
@@ -1892,6 +2028,35 @@ class RaggedInferenceEngineTPU:
             fetches += layers * 2 * int((pages * programs[1 * wide]).sum())
         return walked, fetches
 
+    def _picked_work(self, batch: RaggedBatch):
+        """(index pairs scored, latent rows selected, picked pairs) of a
+        launch of a stack that picks its keys, or None for any other.
+        Scored: every fed token times the keys it can see — its row up to
+        itself — summed over the layers that OWN an indexer (what their
+        scorers must compute; a scorer that runs over the page table's
+        width computes more). Selected: the rows of the latent pool the launch's rows
+        must read, ``min(context, index_topk)`` a row after the launch,
+        times the latent layers — beside ``kv_tokens_latent``, the rows
+        HELD. Picked pairs: every fed token times the keys picked for it,
+        ``min(position + 1, index_topk)``, in ONE latent layer (the
+        numerator of a roofline over the picked softmax). Host arithmetic
+        on the batch's lengths."""
+        model = self.model_config
+        if not model.picks_keys:
+            return None
+        start = batch.start_positions.astype(np.int64)
+        fed = batch.token_counts.astype(np.int64)
+        k = model.index_topk
+        # of a row's fed tokens, those that still see at most k keys ...
+        under = np.clip(k - start, 0, fed)
+        return (int((fed * start + fed * (fed + 1) // 2).sum())
+                * model.indexer_layers,
+                int(np.minimum(start + fed, k)[fed > 0].sum())
+                * model.num_layers,
+                # ... pick them all; every later one picks k
+                int((under * start + under * (under + 1) // 2
+                     + (fed - under) * k).sum()))
+
     def _attn_pairs(self, batch: RaggedBatch):
         """Live (query, key) pairs of the launch in ONE layer of each
         kind, or None where the model has no window kind: every fed token
@@ -1930,7 +2095,8 @@ class RaggedInferenceEngineTPU:
                         kv_write_slots: Optional[int] = None,
                         chunk_rows: int = 0,
                         attn_row_slots: Optional[int] = None,
-                        state=None, kv_pages=None) -> Dict[str, Any]:
+                        state=None, kv_pages=None,
+                        picked=None) -> Dict[str, Any]:
         """Count one device program launch, right after its jitted call
         returned (``serving/count``: the device is at work by then; a
         launch that raises is not counted): the
@@ -1966,6 +2132,10 @@ class RaggedInferenceEngineTPU:
         takes the traced launches' spans). A latent stack's span carries
         ``kv_tokens_latent``: the cached rows ONE latent layer holds for
         the batch's rows after the launch (``context_tokens``).
+        ``picked`` (:meth:`_picked_work`: a stack that picks its keys) adds
+        ``dispatch/index_tokens_scored`` / ``dispatch/kv_tokens_selected``
+        and the span's arguments of those names, and the span's
+        ``attn_pairs_selected`` (no counter, as ``attn_pairs``).
         ``query_tiles`` (:meth:`_query_tiles`: a split launch under the
         paged kernel) adds ``dispatch/query_tiles`` /
         ``dispatch/query_tiles_live`` — the query tiles the history
@@ -2023,6 +2193,12 @@ class RaggedInferenceEngineTPU:
             work.update(attn_pairs)
         if self.model_config.latent:
             work["kv_tokens_latent"] = context_tokens
+        if picked is not None:
+            for name, by in zip(("index_tokens_scored",
+                                 "kv_tokens_selected"), picked):
+                work[name] = by
+                registry.counter("dispatch/" + name).inc(by)
+            work["attn_pairs_selected"] = picked[2]
         for names, counted in (
                 (("query_tiles", "query_tiles_live"), query_tiles),
                 (("kv_pages_walked", "kv_page_fetches"), kv_pages),

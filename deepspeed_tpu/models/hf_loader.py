@@ -14,6 +14,7 @@ Supported families: Llama/Mistral (silu_glu, RMSNorm, rope), Mixtral
 as [out, in]; our einsum layout is [in, out], hence the transposes.
 """
 
+import dataclasses
 import json
 import os
 from typing import Any, Dict, Optional, Tuple
@@ -34,7 +35,8 @@ _FAMILIES = ("llama", "mistral", "mixtral", "qwen", "qwen2", "qwen2_moe",
               "gpt_neox", "gemma", "gpt2", "opt", "bloom", "falcon",
               "phi", "phi3", "gpt_bigcode", "gptj", "bert", "distilbert",
               "gpt_neo", "internlm", "mimo_v2", "deepseek_v3",
-              "cohere2_moe", "nemotron_h", "granitemoehybrid", "jamba")
+              "cohere2_moe", "nemotron_h", "granitemoehybrid", "jamba",
+              "glm_moe_dsa")
 
 
 def _map_hf_act(act: str) -> str:
@@ -66,6 +68,8 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
         return _granitemoehybrid_config(hf)
     if mt == "jamba":
         return _jamba_config(hf)
+    if mt == "glm_moe_dsa":
+        return _glm_moe_dsa_config(hf)
     if mt == "bert":
         return DecoderConfig(
             hidden_size=hf["hidden_size"],
@@ -522,6 +526,77 @@ def _deepseek_v3_config(hf: Dict[str, Any]) -> DecoderConfig:
         layer_sparse=tuple(int(l >= dense and l % freq == 0)
                            for l in range(L)),
         **_sigmoid_router(hf, "deepseek_v3"))
+
+
+def _glm_moe_dsa_config(hf: Dict[str, Any]) -> DecoderConfig:
+    """GLM-5.2's block (``model_type: glm_moe_dsa``): DeepSeek-V3's latent
+    block (:func:`_deepseek_v3_config` reads the widths — the file's
+    ``head_dim`` is the NOPE width and is not a head's; a head is
+    ``qk_nope_head_dim + qk_rope_head_dim`` —, the flat sigmoid router and
+    the shared expert) with DeepSeek-V3.2's sparse-attention INDEXER in
+    front of the softmax (models/typed_layers.py has the equations): a
+    layer whose ``indexer_types`` entry is ``full`` owns an indexer
+    (``index_n_heads`` heads of ``index_head_dim``, one key a token), one
+    whose entry is ``shared`` borrows the picks of the nearest ``full``
+    layer below it; a query reads the ``index_topk`` keys picked for it.
+    ``mlp_layer_types`` says which layers are dense and is held against
+    ``first_k_dense_replace`` / ``moe_layer_freq``; the rotary base lies in
+    ``rope_parameters``. Without ``indexer_types`` the list is made from
+    ``index_topk_freq`` / ``index_skip_topk_offset`` as the published one
+    is: the layers below the offset own one, then every ``freq``-th (three
+    borrowers, then an owner).
+    Not built, and refused by name: ``index_topk_pattern``, a rotary
+    scaling, a first layer that borrows. Accepted and not built, as the
+    parent family's: ``num_nextn_predict_layers`` (with
+    ``index_share_for_mtp_iteration``, which speaks of that module alone).
+    ``rope_interleave`` / ``indexer_rope_interleave`` name the published
+    tensors' pair order: HF de-interleaves and rotates halves, a weight
+    loader's permutation (the assumption ``deepseek_v3`` makes too)."""
+    L = int(hf["num_hidden_layers"])
+    params = hf.get("rope_parameters") or {}
+    if hf.get("index_topk_pattern") is not None:
+        raise ValueError(f"glm_moe_dsa: index_topk_pattern="
+                         f"{hf['index_topk_pattern']!r} is not built (one "
+                         f"index_topk for every layer)")
+    if params.get("rope_type", "default") != "default" or \
+            hf.get("rope_scaling"):
+        raise ValueError(f"glm_moe_dsa: rope_parameters {params!r} / "
+                         f"rope_scaling {hf.get('rope_scaling')!r} is not "
+                         f"built (the default rotary alone)")
+    for key in ("index_n_heads", "index_head_dim", "index_topk"):
+        if not hf.get(key):
+            raise ValueError(f"glm_moe_dsa: {key}={hf.get(key)!r} is not "
+                             f"built (the indexer needs every width)")
+    base = _deepseek_v3_config({
+        **{k: v for k, v in hf.items() if k != "rope_parameters"},
+        "rope_theta": params.get("rope_theta", hf.get("rope_theta", 1e4))})
+    mlp = hf.get("mlp_layer_types")
+    if mlp is not None:
+        built = tuple("sparse" if s else "dense" for s in base.layer_sparse)
+        if tuple(mlp) != built:
+            raise ValueError(
+                f"glm_moe_dsa: mlp_layer_types {list(mlp)!r} is not what "
+                f"first_k_dense_replace={hf.get('first_k_dense_replace')!r}"
+                f" / moe_layer_freq={hf.get('moe_layer_freq', 1)!r} build "
+                f"({list(built)!r})")
+    types = hf.get("indexer_types")
+    if types is None:
+        freq = int(hf.get("index_topk_freq") or 1)
+        skip = int(hf.get("index_skip_topk_offset") or 0)
+        types = ["full" if l < skip or (l - skip) % freq == freq - 1
+                 else "shared" for l in range(L)]
+    if len(types) != L or set(types) - {"full", "shared"}:
+        raise ValueError(f"glm_moe_dsa: indexer_types {list(types)!r} is "
+                         f"not built ({L} entries of 'full' / 'shared')")
+    if types[0] != "full":
+        raise ValueError("glm_moe_dsa: indexer_types[0]='shared' is not "
+                         "built (a layer borrows from a 'full' layer "
+                         "below it, and the first has none)")
+    return dataclasses.replace(
+        base, index_heads=int(hf["index_n_heads"]),
+        index_head_dim=int(hf["index_head_dim"]),
+        index_topk=int(hf["index_topk"]),
+        layer_indexer=tuple(int(t == "full") for t in types))
 
 
 def _cohere2_moe_config(hf: Dict[str, Any]) -> DecoderConfig:

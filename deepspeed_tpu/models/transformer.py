@@ -219,6 +219,17 @@ class DecoderConfig:
     #: (factor, original_max_position_embeddings, beta_fast, beta_slow,
     #: mscale, mscale_all_dim). None → plain ``theta ** (-i / half)``.
     rope_yarn: Optional[Tuple[float, int, float, float, float, float]] = None
+    # -- learned sparse attention over the latent cache (DeepSeek-V3.2's
+    # indexer, GLM-5.2's ``glm_moe_dsa``; typed_layers.py has the equations):
+    # a query reads only the ``index_topk`` cached rows its layer's indexer
+    # scores highest. ``layer_indexer``: one entry a layer, 1 = the layer
+    # OWNS an indexer (``index_heads`` heads of ``index_head_dim``, ONE key
+    # of that width a token, cached in a pool of its own), 0 = it BORROWS
+    # the picks of the nearest owner below it. None → every key is read
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    layer_indexer: Optional[Tuple[int, ...]] = None
     # -- Cohere2-MoE's block on the typed stack (``parallel_block`` above) --
     #: rotary pairs are NEIGHBOURS ``(2i, 2i+1)`` (GPT-J's convention;
     #: Cohere ``position_embedding_type: rope_gptj``), not the two halves
@@ -266,7 +277,7 @@ class DecoderConfig:
             # (HF cls.predictions.decoder); an untied lm_head would make
             # lm_logits and the chunked-CE loss decode different heads
             raise ValueError("mlm_head requires tie_embeddings=True")
-        for name in ("layer_kinds", "layer_sparse"):
+        for name in ("layer_kinds", "layer_sparse", "layer_indexer"):
             per_layer = getattr(self, name)
             if per_layer is not None and len(per_layer) != self.num_layers:
                 raise ValueError(
@@ -284,6 +295,17 @@ class DecoderConfig:
                 "latent attention (layer kind 2) needs q_lora_rank, "
                 "kv_lora_rank, qk_nope_head_dim and qk_rope_head_dim, and a "
                 "stack whose layers are all latent")
+        if self.layer_indexer is not None and not (
+                self.latent and self.layer_indexer[0] == 1 and
+                self.index_heads and self.index_head_dim and
+                self.index_topk and
+                self.index_head_dim >= self.qk_rope_head_dim):
+            raise ValueError(
+                "layer_indexer (learned sparse attention) needs a latent "
+                "stack, index_heads, index_head_dim (at least "
+                "qk_rope_head_dim: its leading dims are rotated) and "
+                "index_topk, and a first layer that owns its indexer (a "
+                "borrower takes the picks of an owner BELOW it)")
         if self.typed and 3 in self.layer_kinds and not (
                 self.ssm_heads and self.ssm_head_dim and self.ssm_state_size
                 and self.ssm_heads % self.ssm_groups == 0):
@@ -325,6 +347,23 @@ class DecoderConfig:
     def latent(self) -> bool:
         """The stack's layers are latent attention layers (kind 2)."""
         return self.typed and 2 in self.layer_kinds
+
+    @property
+    def picks_keys(self) -> bool:
+        """The latent layers read the keys an indexer picks
+        (``layer_indexer``), not every cached row."""
+        return self.layer_indexer is not None
+
+    def layer_owns_indexer(self, layer: int) -> bool:
+        """The layer scores and picks for itself and for the borrowers
+        above it (``layer_indexer`` 1); False: it borrows, or the stack
+        picks nothing."""
+        return self.picks_keys and self.layer_indexer[layer] == 1
+
+    @property
+    def indexer_layers(self) -> int:
+        """Layers that own an indexer: the regions of the index-key pool."""
+        return sum(self.layer_indexer) if self.picks_keys else 0
 
     @property
     def selective(self) -> bool:

@@ -40,6 +40,30 @@ projects through two bottlenecks and caches the second:
 - a sparse layer adds ``Shared(h)``, a SiLU-GLU every token takes, beside
   the routed experts (``cfg.shared_expert_size``; scope ``moe_shared``).
 
+A latent stack that PICKS ITS KEYS (``cfg.layer_indexer``; DeepSeek-V3.2's
+sparse-attention indexer, GLM-5.2's ``glm_moe_dsa``) puts a learned top-k
+in front of that softmax. A layer that OWNS an indexer (``layer_indexer``
+1; tree ``indexer`` {wq, wk, k_norm {scale, bias}, ww}), with ``J =
+index_heads`` heads of ``d_I = index_head_dim``:
+
+- ``q^I_{t,j} = RoPE(c_q,t · W^I_q)[j]`` (``c_q`` the latent the queries
+  already use; the leading ``qk_rope_head_dim`` dims of each head
+  rotated, the rest passed through); ``k^I_s = RoPE(LayerNorm(h_s ·
+  W^I_k))``: ONE key of ``d_I`` a token (LayerNorm with scale and bias,
+  eps 1e-6); ``w_t = (h_t · W^I_w) · J^-0.5`` (float32);
+- ``I_{t,s} = d_I^-0.5 · Σ_j w_{t,j} · ReLU(q^I_{t,j} · k^I_s)`` for ``s ≤
+  t`` (float32 sums of bf16 products where the stack computes in bf16);
+- ``S_t`` = the ``min(index_topk, t + 1)`` keys of the highest ``I_{t,·}``
+  (ties to the lower position); the latent softmax runs over ``s ∈ S_t``
+  and nothing else.
+
+A layer that BORROWS (``layer_indexer`` 0) has no indexer weights and
+reads ``S_t`` of the nearest owner below it. While ``t + 1 ≤ index_topk``
+every key is picked and the layer IS the dense latent layer. Served, the
+index keys live in a pool of their own beside the latent pool
+(``pa.INDEX_POOL``: a region an OWNER, the same page table), and the picks
+are carried through the layer loop (``engine_v2``).
+
 A PARALLEL block (``cfg.parallel_block``; Cohere2-MoE's, ``hf_loader``:
 ``cohere2_moe``) has ONE norm a layer and no ``ln2``: attention and the
 experts both read the same ``h``, and nothing re-normalises the stream
@@ -187,6 +211,8 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     # (a layer draws at most 9 keys, 12 with latent attention or a shared
     # expert; the count is part of what a seed gives)
     draws = 13 if cfg.latent or cfg.shared_expert_size else 10
+    if cfg.picks_keys:
+        draws += 3      # an owner's three index projections
     keys = iter(jax.random.split(rng, draws * L + 2))
     glu = ("wg", "wi") if cfg.is_glu else ("wi",)
 
@@ -219,6 +245,13 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
             attn["sink"] = w((H,), 1.0)
         if kind in (0, 1, 2):
             lp["attn"] = attn
+        if cfg.layer_owns_indexer(l):
+            J, di = cfg.index_heads, cfg.index_head_dim
+            lp["indexer"] = {
+                "wq": w((cfg.q_lora_rank, J * di)), "wk": w((d, di)),
+                "k_norm": {"scale": jnp.ones((di,), jnp.float32),
+                           "bias": jnp.zeros((di,), jnp.float32)},
+                "ww": w((d, J))}
         if not cfg.layer_has_ffn(l):
             layers.append(lp)
             continue
@@ -331,6 +364,36 @@ def typed_qkv(cfg, kind: int, p, x: jax.Array, sin, cos
 
 
 @jax.named_scope("attn_qkv")
+def latent_query_latent(cfg, p, x: jax.Array) -> jax.Array:
+    """``c_q = RMSNorm(x·W_qa)`` [B, t, q_lora]: what the heads' queries
+    AND an indexer's are projected from (computed where each needs it: one
+    expression, which the compiler keeps once)."""
+    return tf._norm(cfg, p["q_norm"], tf.linear_2d(x, p, "wq_a"))
+
+
+#: eps of the index key's LayerNorm (DeepSeek-V3.2's reference code)
+INDEX_NORM_EPS = 1e-6
+
+
+@jax.named_scope("attn_index")
+def index_qkw(cfg, p, x: jax.Array, c_q: jax.Array, sin, cos):
+    """An indexer's three projections: x [B, t, D] (the layer's normed
+    input), ``c_q`` [B, t, q_lora] → (q_idx [B, t, J, d_I], k_idx [B, t,
+    d_I], w [B, t, J] float32 with ``J^-0.5 · d_I^-0.5`` folded in): RoPE
+    on the leading rotary dims of every query head and of the one key, the
+    key under its LayerNorm first."""
+    b, t = x.shape[:2]
+    J, di = cfg.index_heads, cfg.index_head_dim
+    q = tf.linear_2d(c_q, p, "wq").reshape(b, t, J, di)
+    k = tf._norm(dataclasses.replace(cfg, norm="layernorm",
+                                     norm_eps=INDEX_NORM_EPS), p["k_norm"],
+                 tf.linear_2d(x, p, "wk"))
+    k = tf.apply_rope(k[:, :, None], sin, cos)[:, :, 0]
+    w = _linear_f32(x, p, "ww") * ((J * di) ** -0.5)
+    return tf.apply_rope(q, sin, cos), k, w
+
+
+@jax.named_scope("attn_qkv")
 def latent_qkv(cfg, p, x: jax.Array, sin, cos):
     """A latent layer's projections: x [B, t, D] → (q_nope [B, t, H, nope],
     q_rope [B, t, H, rope], latent [B, t, kv_lora + rope]): RoPE applied to
@@ -338,7 +401,7 @@ def latent_qkv(cfg, p, x: jax.Array, sin, cos):
     k_rope]`` is the row the cache holds."""
     b, t = x.shape[:2]
     nope, kl = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    c_q = tf._norm(cfg, p["q_norm"], tf.linear_2d(x, p, "wq_a"))
+    c_q = latent_query_latent(cfg, p, x)
     q = tf.linear_2d(c_q, p, "wq_b").reshape(b, t, cfg.num_heads,
                                              cfg.head_dim)
     kv = tf.linear_2d(x, p, "wkv_a")
@@ -619,12 +682,13 @@ def _ssm_mixer(cfg, kind: int, p, h: jax.Array) -> jax.Array:
     return forms.out(cfg, p, y, z)
 
 
-def _attention(cfg, kind: int, sink, q, k, v) -> jax.Array:
+def _attention(cfg, kind: int, sink, q, k, v, picked=None) -> jax.Array:
     """Uncached attention of one layer: q [B, T, H, Dk], k [B, T, KV, Dk],
     v [B, T, KV, Dv] → [B, T, H, Dv]; causal, the kind's window, the
-    sink."""
+    sink; ``picked`` [B, T, T] bool: the keys each query's indexer kept."""
     out, lse = pa.causal_attention_with_lse(
-        q, k, v, window=cfg.kind_window(kind), scale=cfg.attn_scale)
+        q, k, v, window=cfg.kind_window(kind), scale=cfg.attn_scale,
+        picked=picked)
     return apply_sink(out, lse, sink)
 
 
@@ -641,7 +705,7 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
     x, dtype = residual_stream(
         tf.embed_tokens(cfg, params["embed"], tokens, positions))
     tables = rope_tables(cfg, positions)
-    for kind, lp in zip(cfg.layer_kinds, params["layers"]):
+    for l, (kind, lp) in enumerate(zip(cfg.layer_kinds, params["layers"])):
         h32 = tf._norm(cfg, lp["ln1"], x)
         h = h32.astype(dtype)
         if kind in tf.STATE_SPACE_KINDS or kind == -1:
@@ -652,10 +716,20 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
         if kind == 2:       # the expanded form: nothing is cached here
             q, k, v = latent_expand_kv(cfg, lp["attn"], *latent_qkv(
                 cfg, lp["attn"], h, *tables[kind]))
+            if cfg.layer_owns_indexer(l):   # for its borrowers too
+                q_i, k_i, w_i = index_qkw(
+                    cfg, lp["indexer"], h,
+                    latent_query_latent(cfg, lp["attn"], h), *tables[kind])
+                with jax.named_scope("attn_index"):
+                    scores = pa.index_scores(q_i, k_i, w_i)
+                with jax.named_scope("attn_select"):
+                    picked = pa.topk_mask(pa.causal_only(scores),
+                                          cfg.index_topk)
         else:
             q, k, v = typed_qkv(cfg, kind, lp["attn"], h, *tables[kind])
         with jax.named_scope("attn_core"):
-            o = _attention(cfg, kind, lp["attn"].get("sink"), q, k, v)
+            o = _attention(cfg, kind, lp["attn"].get("sink"), q, k, v,
+                           picked if cfg.picks_keys else None)
         x = block_residual(cfg, lp, x, h32,
                            typed_attn_out(cfg, lp["attn"], o), moe_fn, None,
                            dtype)

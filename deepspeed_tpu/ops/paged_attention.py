@@ -32,6 +32,16 @@ pages; its readers visit only the pages the window touches. A LATENT layer
 query head reads; the row is the key and its first ``kv_lora_rank`` lanes
 are the value, so a reader takes a page once and never splits it
 (:func:`write_rows`, ``v_lanes=`` of the XLA readers, :func:`mla_decode`).
+A latent stack that PICKS ITS KEYS (``DecoderConfig.layer_indexer``) has a
+second pool, ``INDEX_POOL``: an indexer's ONE key a token, a region for
+each layer that owns an indexer, under the same page table and the same
+writer. Its readers are the last section of this file: a scorer over a
+row's pages (:func:`index_scores_paged`), the exact top-k
+(:func:`topk_picks` as positions, :func:`topk_mask` as a mask), and the
+reads of the latent pool that follow them — BY TOKEN INDEX for a row of
+one query (:func:`picked_attention`: the rows a query picked and no
+other), under the picks' mask for a chunk's queries (``picked=`` of
+:func:`mla_decode` and the XLA history reader).
 
 Two implementations with identical semantics (tested against each other):
 
@@ -79,11 +89,17 @@ def init_arena(num_layers: int, kv_heads: int, num_blocks: int,
 #: pool names of a typed arena by attention kind (0 full, 1 window, 2
 #: latent: one pool, the row is K and its leading lanes are V)
 KIND_POOLS = {0: ("k", "v"), 1: ("k_win", "v_win"), 2: ("latent",)}
+#: the pool of the index keys beside a latent stack's one pool: ONE key of
+#: ``index_head_dim`` a token in each layer that OWNS an indexer (the i-th
+#: owner's pages are ``i*(num_blocks+1) + b``), the page table the other
+#: pools have
+INDEX_POOL = "index"
 
 
 def init_arena_typed(layer_kinds, kv_heads_by_kind: dict, num_blocks: int,
                      block_size: int, k_width: int, v_width: int,
-                     dtype=jnp.bfloat16) -> dict:
+                     dtype=jnp.bfloat16, index_layers: int = 0,
+                     index_width: int = 0) -> dict:
     """The arena of a typed layer stack: a FLAT dict of pools, one per
     attention kind present and per K/V (``KIND_POOLS``), each
     ``[layers of the kind * (num_blocks + 1), bs, kv_heads of the kind *
@@ -92,8 +108,14 @@ def init_arena_typed(layer_kinds, kv_heads_by_kind: dict, num_blocks: int,
     table addresses every pool (logical page b is the same tokens in
     all). The latent kind's one pool is ``k_width`` lanes wide (its row is
     the key; ``v_width`` is not used). A layer with no attention (a
-    state-space mixer, 3; no mixer at all, -1) has no pages."""
+    state-space mixer, 3; no mixer at all, -1) has no pages.
+    ``index_layers`` layers that own an indexer add ``INDEX_POOL``, a
+    region each, ``index_width`` lanes a token."""
     arena = {}
+    if index_layers:
+        arena[INDEX_POOL] = jnp.zeros(
+            (index_layers * (num_blocks + 1), block_size, index_width),
+            dtype)
     for kind in sorted(set(layer_kinds) & set(KIND_POOLS)):
         pages = sum(1 for a in layer_kinds if a == kind) * (num_blocks + 1)
         if kind == 2:
@@ -351,7 +373,8 @@ def paged_attention_hist_xla(q: jax.Array, arena_k: jax.Array,
                              page_table: jax.Array, starts: jax.Array,
                              window: Optional[int] = None,
                              scale: Optional[float] = None,
-                             v_lanes: Optional[int] = None):
+                             v_lanes: Optional[int] = None,
+                             picked: Optional[jax.Array] = None):
     """HISTORY-only attention: row i's queries attend keys [0, starts[i])
     — the tokens already in the arena BEFORE the current chunk's write.
     Returns (out [n,c,h,dh], lse [n,c,h] fp32).
@@ -364,7 +387,8 @@ def paged_attention_hist_xla(q: jax.Array, arena_k: jax.Array,
     into a continuation batch. ``window``: query j of a row (position
     ``starts + j``) sees only the history keys within the window, and only
     the pages those lie in are gathered. ``v_lanes``: a latent pool, as
-    :func:`paged_attention_xla`.
+    :func:`paged_attention_xla`. ``picked`` [n, c, mb * bs] bool (no
+    window): of those keys, the ones each query's indexer kept.
     """
     bs = arena_k.shape[1]
     mb = page_table.shape[1]
@@ -372,6 +396,8 @@ def paged_attention_hist_xla(q: jax.Array, arena_k: jax.Array,
         ids = page_table
         kpos = jnp.arange(mb * bs, dtype=jnp.int32)
         mask = (kpos[None, :] < starts[:, None])[:, None, None, None, :]
+        if picked is not None:
+            mask = mask & picked[:, None, None]
     else:
         ids, kpos = _window_pages(page_table, starts - (window - 1),
                                   window - 1, bs)
@@ -406,17 +432,23 @@ def merge_attention(out_a, lse_a, out_b, lse_b, sink=None):
 
 def causal_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
                               window: Optional[int] = None,
-                              scale: Optional[float] = None):
+                              scale: Optional[float] = None,
+                              picked: Optional[jax.Array] = None):
     """Plain causal attention over one chunk returning (out, lse) for the
     history merge — XLA path ([n,c,h,dh] layout, GQA via head groups; K
     and V may differ in width). ``window``: key j visible to query i only
-    when ``i - j < window``."""
+    when ``i - j < window``. ``picked`` [n, c, c] bool: of the chunk's
+    keys, the ones each query's indexer kept (a query that kept none of
+    them gives an lse of about -1e30, a weight of 0 in a merge)."""
     c = q.shape[1]
     i = jnp.arange(c, dtype=jnp.int32)
     mask = i[None, :] <= i[:, None]
     if window is not None:
         mask = mask & (i[None, :] > i[:, None] - window)
-    return _masked_attention(q, k, v, mask[None, None, None], True, scale)
+    mask = mask[None, None, None]
+    if picked is not None:
+        mask = mask & picked[:, None, None]
+    return _masked_attention(q, k, v, mask, True, scale)
 
 
 def one_key_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -844,9 +876,9 @@ MLA_PAGES_PER_TURN = 4
 
 
 def _mla_kernel(pt_ref, starts_ref, kcounts_ref, qcounts_ref, q_ref,
-                pool_hbm, o_ref, lse_ref, buf, sem, *, block_size: int,
-                heads: int, tile_q: int, scale: float, mb: int,
-                v_lanes: int, pages: int):
+                *rest, block_size: int, heads: int, tile_q: int,
+                scale: float, mb: int, v_lanes: int, pages: int,
+                picked: bool = False):
     """Grid (n_seq, query tiles): ONE program per sequence and tile of
     ``tile_q`` queries x all heads, walking the sequence's LIVE pages of
     the latent pool ``pages`` a loop turn with double-buffered DMAs, as
@@ -861,7 +893,19 @@ def _mla_kernel(pt_ref, starts_ref, kcounts_ref, qcounts_ref, q_ref,
     query`` and ``kpos < start + kcounts`` (``kcounts`` 0: the history
     before the chunk). A tile whose first query is not live
     (``>= qcounts``), or a row with no visible page, writes zeros and an
-    lse of -1e30 (a weight of 0 in a merge)."""
+    lse of -1e30 (a weight of 0 in a merge).
+
+    ``picked``: one operand more after ``q_ref``, ``bias_ref`` [1, tile_q,
+    positions] float32 — 0 where the tile's query PICKED the key at that
+    position, -1e30 where it did not (:func:`topk_mask` of its indexer's
+    scores), the same for every head —, added to each turn's scores: the
+    softmax runs over the visible keys the query picked and no other. The
+    walk still copies every live page (a chunk's 128 queries pick, between
+    them, most of a history), so a picked walk costs what the dense one
+    does; what reads ONLY the picked rows is :func:`picked_attention`."""
+    if picked:
+        bias_ref, *rest = rest
+    pool_hbm, o_ref, lse_ref, buf, sem = rest
     s_idx = pl.program_id(0)
     tile = pl.program_id(1)
     rows = q_ref.shape[1]
@@ -907,6 +951,13 @@ def _mla_kernel(pt_ref, starts_ref, kcounts_ref, qcounts_ref, q_ref,
             # repeated the last entry, and no query sees those positions)
             s = jnp.where((kpos <= qpos) & (kpos < ctx) &
                           (kpos < mb * block_size), s, _NEG_INF)
+            if picked:
+                # a query's bias, once for each of its heads' rows
+                bias = bias_ref[0, :, pl.ds(pl.multiple_of(b * span, 128),
+                                            span)]
+                s = s + jnp.broadcast_to(
+                    bias[:, None, :], (tile_q, heads, span)
+                ).reshape(rows, span)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
             p = jnp.exp(s - m_new[:, None])
             # float mask arithmetic, as _paged_kernel
@@ -938,7 +989,8 @@ def _mla_kernel(pt_ref, starts_ref, kcounts_ref, qcounts_ref, q_ref,
 @functools.partial(jax.jit, static_argnames=("v_lanes", "scale", "interpret"))
 def mla_decode(q: jax.Array, pool: jax.Array, page_table: jax.Array,
                starts: jax.Array, kcounts: jax.Array, qcounts: jax.Array, *,
-               v_lanes: int, scale: float, interpret: bool = False):
+               v_lanes: int, scale: float, interpret: bool = False,
+               picked: Optional[jax.Array] = None):
     """Absorbed latent attention over the paged latent pool (Pallas): q
     [n, c, H, W] (each head's query in the latent space, ``W`` the pool's
     lanes) → (out [n, c, H, v_lanes] — the softmax-weighted sum of the
@@ -947,13 +999,28 @@ def mla_decode(q: jax.Array, pool: jax.Array, page_table: jax.Array,
     ``kcounts = counts`` is the decode step's read of what it has just
     written, ``kcounts = 0`` the split step's history. ``qcounts`` [n]:
     the live queries of each row; the tiles past them are not computed.
-    Its name in a device trace is ``mla_decode``."""
+    Its name in a device trace is ``mla_decode``. ``picked`` [n, c, mb *
+    bs] bool: of the visible keys, those each query's indexer kept — the
+    softmax runs over them alone, and the trace's name is
+    ``mla_decode_picked``."""
     n, c, h, w = q.shape
     bs = pool.shape[1]
     mb = page_table.shape[1]
     tile_q = next(t for t in (MLA_TILE_QUERIES, 4, 2, 1) if c % t == 0)
     rows = tile_q * h
     pages = min(MLA_PAGES_PER_TURN, mb)
+    operands, specs = [q.reshape(n, c * h, w)], []
+    if picked is not None:
+        span = pages * bs
+        reach = -(-mb * bs // span) * span      # what the turns slice
+        bias = jnp.where(picked, 0.0, _NEG_INF).astype(jnp.float32)
+        operands.append(jnp.pad(bias, ((0, 0), (0, 0),
+                                       (0, reach - bias.shape[-1])),
+                                constant_values=_NEG_INF))
+        specs.append(pl.BlockSpec(
+            (1, tile_q, reach),
+            lambda s, t, pt, st, kc, qc: (
+                s, jnp.where(t * tile_q < qc[s], t, 0), 0)))
 
     def tile_of(width, skip_dead):
         def index(s, t, pt, st, kc, qc):
@@ -965,11 +1032,13 @@ def mla_decode(q: jax.Array, pool: jax.Array, page_table: jax.Array,
 
     out, lse = pl.pallas_call(
         functools.partial(_mla_kernel, block_size=bs, heads=h, tile_q=tile_q,
-                          scale=scale, mb=mb, v_lanes=v_lanes, pages=pages),
+                          scale=scale, mb=mb, v_lanes=v_lanes, pages=pages,
+                          picked=picked is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n, c // tile_q),
-            in_specs=[tile_of(w, True), pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[tile_of(w, True), *specs,
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[tile_of(v_lanes, False), tile_of(1, False)],
             scratch_shapes=[pltpu.VMEM((2, pages * bs, w), pool.dtype),
                             pltpu.SemaphoreType.DMA((2, pages))],
@@ -977,10 +1046,9 @@ def mla_decode(q: jax.Array, pool: jax.Array, page_table: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((n, c * h, v_lanes), q.dtype),
                    jax.ShapeDtypeStruct((n, c * h, 1), jnp.float32)],
         interpret=interpret,
-        name="mla_decode",
+        name="mla_decode" if picked is None else "mla_decode_picked",
     )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
-      kcounts.astype(jnp.int32), qcounts.astype(jnp.int32),
-      q.reshape(n, c * h, w), pool)
+      kcounts.astype(jnp.int32), qcounts.astype(jnp.int32), *operands, pool)
     return out.reshape(n, c, h, v_lanes), lse.reshape(n, c, h)
 
 
@@ -990,7 +1058,8 @@ def paged_history_with_lse(q: jax.Array, arena_k: jax.Array,
                            qcounts: jax.Array, *, kernel: bool,
                            window: Optional[int] = None,
                            scale: Optional[float] = None,
-                           v_lanes: Optional[int] = None):
+                           v_lanes: Optional[int] = None,
+                           picked: Optional[jax.Array] = None):
     """A split step's HISTORY reader under one signature → (out, lse [n,
     c, h] float32): row i's queries (the leading ``qcounts[i]`` are live)
     over the keys ``[0, starts[i])`` the pools held before the step.
@@ -998,18 +1067,156 @@ def paged_history_with_lse(q: jax.Array, arena_k: jax.Array,
     compute its live queries alone — :func:`paged_attention_with_lse`, or
     :func:`mla_decode` over a latent pool (``v_lanes``; ``arena_v`` None)
     —; else :func:`paged_attention_hist_xla`, which gathers the page
-    table's width and computes every query."""
+    table's width and computes every query. ``picked`` [n, c, positions]
+    bool (a latent pool): the keys each query's indexer kept."""
     if not kernel:
         return paged_attention_hist_xla(q, arena_k, arena_v, page_table,
                                         starts, window=window, scale=scale,
-                                        v_lanes=v_lanes)
+                                        v_lanes=v_lanes, picked=picked)
     none = jnp.zeros_like(starts)
     if v_lanes is not None:
         return mla_decode(q, arena_k, page_table, starts, none, qcounts,
-                          v_lanes=v_lanes, scale=scale)
+                          v_lanes=v_lanes, scale=scale, picked=picked)
     return paged_attention_with_lse(q, arena_k, arena_v, page_table, starts,
                                     none, window=window, scale=scale,
                                     qcounts=qcounts)
+
+
+# ---------------------------------------------------------------------------
+# Picked keys (DeepSeek-V3.2's indexer over a latent pool)
+# ---------------------------------------------------------------------------
+
+#: heads of an indexer whose scores are alive at once where a chunk's
+#: queries score a whole page table (:func:`index_scores`): [128 queries, 4
+#: heads, 20,992 keys] float32 is 43 MB where all 32 would be 344
+INDEX_HEADS_PER_TURN = 4
+
+
+def index_scores(q: jax.Array, k: jax.Array, w: jax.Array) -> jax.Array:
+    """``I[n, t, s] = Σ_j w[n, t, j] · ReLU(q[n, t, j] · k[n, s])``,
+    float32: q [n, c, J, d] (an indexer's rotated queries), k [n, S, d]
+    (one key a position), w [n, c, J] float32 (the heads' weights, the two
+    ``^-0.5`` folded in). Products in q's dtype, sums in float32 — the
+    heads' weighted sum elementwise, NOT a matmul: a float32 dot takes the
+    chip's default precision, one bf16 pass. One query a row scores its
+    heads at once; a chunk's queries walk the rows and, in a row, the heads
+    ``INDEX_HEADS_PER_TURN`` at a time, so the scores of all heads are
+    never alive together."""
+    n, c, J, d = q.shape
+    k = k.astype(q.dtype)
+
+    def heads(q, k, w):         # [.., c, j, d], [.., S, d], [.., c, j]
+        s = jnp.einsum("...cjd,...sd->...cjs", q, k,
+                       preferred_element_type=jnp.float32)
+        return jnp.sum(jnp.maximum(s, 0.0) * w[..., None], axis=-2)
+
+    g = INDEX_HEADS_PER_TURN
+    if c == 1 or J % g:
+        return heads(q, k, w)
+    q = jnp.moveaxis(q.reshape(n, c, J // g, g, d), 2, 1)   # [n, J/g, c, ..]
+    w = jnp.moveaxis(w.reshape(n, c, J // g, g), 2, 1)
+
+    def row(args):
+        q_r, k_r, w_r = args
+
+        def turn(acc, qw):
+            return acc + heads(qw[0], k_r, qw[1]), None
+
+        return lax.scan(turn, jnp.zeros((c, k_r.shape[0]), jnp.float32),
+                        (q_r, w_r))[0]
+
+    return lax.map(row, (q, k, w))
+
+
+def index_scores_paged(q: jax.Array, w: jax.Array, pool: jax.Array,
+                       page_table: jax.Array) -> jax.Array:
+    """:func:`index_scores` of each row's queries against the keys its
+    pages of the index pool hold: q [n, c, J, d], w [n, c, J], pool
+    [pages, bs, d], page_table [n, mb] (this layer's region) → [n, c, mb *
+    bs] float32. EVERY position of the page table's width is scored (a
+    padded entry is the trash page); the caller masks what a query cannot
+    see."""
+    n, mb = page_table.shape
+    return index_scores(q, pool[page_table].reshape(n, mb * pool.shape[1],
+                                                    -1), w)
+
+
+def _sortable(x: jax.Array) -> jax.Array:
+    """float32 → uint32 keys in the same order (-0.0 under +0.0)."""
+    u = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+def causal_only(scores: jax.Array) -> jax.Array:
+    """A chunk's scores of its OWN keys [..., c, c] with ``-inf`` where the
+    key comes after the query: what the selections below take as "not
+    visible"."""
+    c = scores.shape[-1]
+    return jnp.where(jnp.tril(jnp.ones((c, c), bool)), scores, -jnp.inf)
+
+
+def topk_mask(scores: jax.Array, k: int) -> jax.Array:
+    """[..., S] float32 (``-inf`` where a key is not visible) → bool [...,
+    S]: the ``k`` highest visible scores of each row, all of them where
+    there are ``k`` or fewer, ties to the LOWER position (what
+    ``lax.top_k`` keeps). Exact, and no sort: the k-th highest value is
+    found bit by bit — 32 passes that count the scores at or above a
+    candidate — and a running count settles the ties at that value, in the
+    launches that have any (float32 scores of 20,000 keys seldom do: the
+    count is a pass of its own, 0.6 ms at ``[512, 21120]`` on a v5e)."""
+    keys = _sortable(scores)
+    visible = scores > -jnp.inf
+    if scores.shape[-1] <= k:
+        return visible
+    kk = jnp.uint32(k)
+
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(keys >= cand[..., None], axis=-1,
+                         dtype=jnp.uint32) >= kk
+        return jnp.where(enough, cand, thr)
+
+    thr = lax.fori_loop(0, 32, bit,
+                        jnp.zeros(scores.shape[:-1], jnp.uint32))[..., None]
+    above = keys > thr
+    at = keys == thr
+    room = kk - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.uint32)
+    tied = jnp.sum(at, axis=-1, keepdims=True, dtype=jnp.uint32) > room
+    at = lax.cond(
+        jnp.any(tied),
+        lambda: at & (jnp.cumsum(at, axis=-1, dtype=jnp.uint32) <= room),
+        lambda: at)
+    return visible & (above | at)
+
+
+def topk_picks(scores: jax.Array, k: int):
+    """[n, S] float32 (``-inf`` where a key is not visible) → (positions
+    [n, K] int32, live [n, K] bool), ``K = min(k, S)``: each row's ``K``
+    highest scores as POSITIONS, ``live`` where the pick is a visible key
+    (a row that sees fewer than ``K`` keys picks them all; the rest of its
+    picks are dead)."""
+    vals, picks = lax.top_k(scores, min(k, scores.shape[-1]))
+    return picks.astype(jnp.int32), vals > -jnp.inf
+
+
+def picked_attention(q: jax.Array, pool: jax.Array, page_table: jax.Array,
+                     picks: jax.Array, live: jax.Array, *, v_lanes: int,
+                     scale: float):
+    """Absorbed latent attention of rows of ONE query over the rows of the
+    latent pool each PICKED, read by token index: q [n, 1, H, W], picks
+    [n, K] positions of each row's sequence, live [n, K] → (out [n, 1, H,
+    v_lanes], lse [n, 1, H] float32). A position becomes (page, offset)
+    through the row's page table and the gather copies ``K`` rows of ``W``
+    lanes a query — ``min(context, index_topk)`` of them live — where
+    :func:`mla_decode` copies every live page; the softmax is over the live
+    picks. A row with none gives an lse of about -1e30."""
+    bs = pool.shape[1]
+    page = jnp.take_along_axis(
+        page_table, jnp.minimum(picks // bs, page_table.shape[1] - 1),
+        axis=1)
+    rows = pool[page, picks % bs][:, :, None]              # [n, K, 1, W]
+    return _masked_attention(q, rows, rows[..., :v_lanes],
+                             live[:, None, None, None], True, scale)
 
 
 def supported(head_dim: int, block_size: int) -> bool:

@@ -3,7 +3,8 @@ against the contract's rules and the files each entry names
 (``benchmark/tests/test_contract.py``), the useful-work arithmetic
 (``test_flops.py``), the statistics (``test_stats.py``), the traffic
 generator (``test_traffic.py``), the latent configuration's counts and
-reference contract (``test_latent_work.py``) and the split of the device's
+reference contract (``test_latent_work.py``), the picking configuration's
+(``test_sparse_latent_work.py``) and the split of the device's
 idle time over the host's spans (``test_host_path.py``). 80-odd cases, three seconds, no subprocess
 and no device, so a PR that breaks the harness's contract — an entry it
 adds to ``BENCHMARK.json``, a configuration file that cuts a width — is
@@ -22,7 +23,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 FILES = ("test_contract", "test_flops", "test_stats", "test_traffic",
-         "test_latent_work", "test_host_path")
+         "test_latent_work", "test_host_path", "test_sparse_latent_work")
 _collected = {}
 for _file in FILES:
     _mod = importlib.import_module(f"benchmark.tests.{_file}")

@@ -1181,3 +1181,165 @@ def test_selective_scan_step_compiles_for_v5e(
               f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
     assert mem.temp_size_in_bytes < _JAMBA_STEPS[kind][3], \
         mem.temp_size_in_bytes
+
+
+# -- the stack that picks its keys (benchmark/configs/glm-5.2-l5-e16-serve):
+# GLM-5.2's published widths, an index pool beside the latent pool
+
+#: step -> (chunk, ``fresh_prefill``, most temporaries at the three layers
+#: ``full shared full``: measured 0.07, 1.19 and 0.34 GB — the split
+#: program's are the TOP rung's picks, where all 16 rows are chunk rows: a
+#: ``[16, 128, 20992]`` float32 score block is 172 MB an owner, and beside it
+#: live its sortable keys, the mask, the kernel's float32 bias of that shape
+#: and the tie count (a rung of 512 slots holds a quarter of each). They
+#: live in HBM as the program's temporaries, sized by the largest rung)
+_PICKING_STEPS = {
+    "decode": (1, False, 0.3e9),
+    "split": (128, "split", 2.6e9),
+    "fresh": (128, "fresh", 0.6e9),
+}
+
+
+def _picking_cell(layers=3):
+    """(model, engine keys) of the cell's configuration cut to ``layers``
+    of its published list: an owner, a borrower, an owner."""
+    from benchmark.lib import model as model_lib
+    conf = model_lib.load_config("glm-5.2-l5-e16-serve")
+    cut = dict(conf, num_hidden_layers=layers,
+               mlp_layer_types=["dense"] + ["sparse"] * (layers - 1),
+               indexer_types=["full", "shared", "full"][:layers])
+    return model_lib.build_model(cut), conf["engine"]
+
+
+@pytest.mark.parametrize("kind", list(_PICKING_STEPS))
+def test_picking_step_reads_both_pools_in_place(kind, one_chip,
+                                                no_persistent_cache,
+                                                monkeypatch, capsys):
+    """The cell's 16-row decode, split and fresh programs at GLM-5.2's
+    published widths (three layers: full, shared, full) over the cell's
+    arena (2,624 pages of 128, 164 a row; latent rows of 640 lanes, index
+    keys of 128) compile for a described v5e: NO copy of either pool; the
+    split program holds its ladder ``(512, 1024, 2048)`` as three branches
+    (beside the selections' tie-count branches) with the masked walk ``mla_decode_picked`` under ``attn_history`` once a
+    layer and chunk group, the scopes ``attn_index`` and ``attn_select``
+    in the decode and split programs (a fresh chunk of 128 is under
+    ``index_topk``: it writes its index keys and scores nothing); every
+    pool's scatter at most the step's top capacity of updates; temporaries
+    printed and bounded."""
+    import types
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
+    from deepspeed_tpu.ops import paged_attention as pa
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, engine = _picking_cell()
+    assert model.layer_indexer == (1, 0, 1) and model.index_topk == 2048
+    nb, mb = engine["max_sequences"], \
+        engine["max_seq_len"] // engine["block_size"]
+    cb, fresh, most = _PICKING_STEPS[kind]
+    capacities = RaggedInferenceEngineTPU._token_capacities(
+        types.SimpleNamespace(config=types.SimpleNamespace(**engine)),
+        nb, cb, fresh)
+    assert capacities == ((512, 1024, 2048) if kind == "split" else ())
+
+    def serve_step(params, arena, tokens, counts, starts, pt):
+        logits, arena = engine_v2.ragged_forward(
+            model, params, arena, tokens, counts, starts, pt,
+            use_pallas=True, moe_fn=None, fresh_prefill=fresh,
+            token_capacities=capacities)
+        out, _ = engine_v2._sample_tokens(logits, ("argmax",), 1.0, 1.0,
+                                          None)
+        return out, arena
+
+    arena = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: pa.init_arena_typed(
+            model.layer_kinds, {2: 1}, engine["num_blocks"], 128, 640, 0,
+            jnp.bfloat16, index_layers=model.indexer_layers,
+            index_width=model.index_head_dim)))
+    assert arena["latent"].shape == (3 * 2625, 128, 640) and \
+        arena[pa.INDEX_POOL].shape == (2 * 2625, 128, 128)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    compiled = jax.jit(serve_step, donate_argnums=(1,)).lower(
+        _abstract_params(model, one_chip), arena, i32(nb, cb), i32(nb),
+        i32(nb), i32(nb, mb)).compile()
+    text = compiled.as_text()
+    for pool in arena.values():
+        shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(rf" = {re.escape(shape)}\S* copy\(", line)]
+        assert not copies, copies
+    updates = _kv_scatter_updates(text, arena.values())
+    # three latent layers and two owners' index keys
+    assert len(updates) == 5 and \
+        max(updates) <= (capacities[-1] if capacities else nb * cb), updates
+    table = scope_table_from_hlo(text)
+    scopes = {e["scope"] for e in table.values()}
+    assert {"attn_latent", "moe_shared", "kv_write"} <= scopes
+    assert ({"attn_index", "attn_select"} <= scopes) == (kind != "fresh"), \
+        scopes
+    walks = [n for n in table if n.startswith("mla_decode_picked")]
+    dense = [n for n in table if n.startswith("mla_decode")
+             and n not in walks]
+    assert not dense, dense         # no layer reads every row
+    if kind == "split":
+        # the ladder's three rungs, and in each rung and owner the two
+        # branches of the selection's tie count (``pa.topk_mask``)
+        assert len(_branches(text)) == 3 + 2 * 2 * 3, _branches(text)
+        # a layer and rung: the chunk group's history (the one-query rows
+        # read by token index, in XLA)
+        assert len(walks) == 3 * 3 and all(
+            table[n]["scope"] == "attn_history" for n in walks), walks
+    else:
+        assert not walks, walks
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nglm-5.2 {kind} at 3 layers: arguments "
+              f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < most, mem.temp_size_in_bytes
+    assert _memory(compiled) < 15.75 * 2 ** 30
+
+
+@pytest.mark.parametrize("shape", ["rows16_one_token", "chunk_group_4x128",
+                                   "packed_chunk_16x128"])
+def test_picked_reads_compile_at_the_cells_shapes(shape, one_chip,
+                                                  no_persistent_cache):
+    """The two reads that follow the picks, alone, at the cell's call
+    shapes over its latent pool (5 layers x 2,625 pages of ``[128, 640]``,
+    164 pages a row, 64 heads): 16 rows of ONE query read 2,048 rows each
+    by token index (``picked_attention``: a gather of ``[16, 2048, 640]``
+    and the absorbed softmax over it — no pool-sized temporary); a chunk
+    group of 4 rows and the top rung's 16 rows x 128 queries walk their
+    pages under the picks' mask (``mla_decode(picked=)`` through Mosaic:
+    its bias block ``[1, 8, 20992]`` float32 beside the page buffers in
+    VMEM)."""
+    from deepspeed_tpu.ops import paged_attention as pa
+    n, c = {"rows16_one_token": (16, 1), "chunk_group_4x128": (4, 128),
+            "packed_chunk_16x128": (16, 128)}[shape]
+    pool = ((5 * 2625, 128, 640), jnp.bfloat16)
+    q = ((n, c, 64, 640), jnp.bfloat16)
+    pt, vec = ((n, 164), jnp.int32), ((n,), jnp.int32)
+    if c == 1:
+        def fn(q, pool, pt, picks, live):
+            return pa.picked_attention(q, pool, pt, picks, live,
+                                       v_lanes=512, scale=0.0625)
+        shapes = (q, pool, pt, ((n, 2048), jnp.int32), ((n, 2048), jnp.bool_))
+    else:
+        def fn(q, pool, pt, starts, qcounts, picked):
+            return pa.mla_decode(q, pool, pt, starts, jnp.zeros_like(starts),
+                                 qcounts, v_lanes=512, scale=0.0625,
+                                 picked=picked)
+        shapes = (q, pool, pt, vec, vec, ((n, c, 164 * 128), jnp.bool_))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert ("mla_decode_picked" in text) == (c > 1)
+    assert not re.search(r" = bf16\[13125,128,640\]\S* copy\(", text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # the gathered rows (42 MB) and their scores; the kernel's bias
+    assert temp < (0.2e9 if c == 1 else 0.05e9 + 1.3 * n * c * 20992 * 4), \
+        temp

@@ -35,14 +35,18 @@ from benchmark.lib import traffic  # noqa: E402
 LADDER = ((512, 4), (1024, 8))
 
 
-def replay(mix, cycle, budget, roll, ms, chunk, window):
+def replay(mix, cycle, budget, roll, ms, chunk, window, ms_history=0.0):
     """One run: (tokens/s, launches, launches in the row form, requests
-    ended) over the window that follows the mix's ramp."""
+    ended) over the window that follows the mix's ramp. ``ms_history``:
+    milliseconds a launch pays for every thousand tokens a chunk row holds
+    already (a history read whose cost grows with the history: a chunk's
+    queries walk it whole), a whole chunk's worth."""
     sizes = np.roll(cycle, -roll, axis=0)
     clients = int(mix["arrival"]["clients"])
     ramp = float(mix["ramp_seconds"])
     drawn = 0
     prompt, out, arrived = [], [], []
+    held = [0] * clients        # prompt tokens a client's row holds
     for c in range(clients):      # traffic.Arrivals._plan's first requests
         p, o = sizes[drawn % len(sizes)]
         drawn += 1
@@ -75,13 +79,15 @@ def replay(mix, cycle, budget, roll, ms, chunk, window):
         chunk_rows = sum(k > 1 for _, k in picks)
         rung = next((i for i, (cap, rows) in enumerate(LADDER)
                      if fed <= cap and chunk_rows <= rows), len(LADDER))
-        t += ms[rung] / 1e3
+        t += (ms[rung] + ms_history * sum(
+            held[c] / 1e3 * k / chunk for c, k in picks if k > 1)) / 1e3
         inside = ramp < t <= ramp + window
         launches += inside
         row_form += inside and rung == len(LADDER)
         for c, k in picks:
             if prompt[c]:
                 prompt[c] -= k
+                held[c] += k
                 if prompt[c]:
                     continue      # more of the prompt to come: no token
             out[c] -= 1
@@ -89,6 +95,7 @@ def replay(mix, cycle, budget, roll, ms, chunk, window):
             if out[c] <= 0:
                 p, o = sizes[drawn % len(sizes)]
                 prompt[c], out[c], arrived[c] = int(p), int(o), clients + drawn
+                held[c] = 0
                 drawn += 1
                 ended += inside
     return tokens / window, launches, row_form, ended
@@ -100,6 +107,10 @@ def main() -> None:
     ap.add_argument("--budgets", type=int, nargs="+", default=[2048, 896])
     ap.add_argument("--ms", type=float, nargs=3, default=[33.0, 53.0, 192.0],
                     metavar=("AT_512", "AT_1024", "ROW_FORM"))
+    ap.add_argument("--ms-history", type=float, default=0.0,
+                    metavar="PER_KTOKEN", help="ms a launch pays for every "
+                    "thousand tokens a chunk row already holds (PR 52: "
+                    "0.71 for GLM-5.2's masked history walk)")
     ap.add_argument("--chunk", type=int, default=128)
     ap.add_argument("--window", type=float, default=45.0)
     args = ap.parse_args()
@@ -109,7 +120,8 @@ def main() -> None:
     cycle = traffic.size_cycle(mix)
     for budget in args.budgets:
         runs = [replay(mix, cycle, budget, roll, args.ms, args.chunk,
-                       args.window) for roll in range(len(cycle))]
+                       args.window, args.ms_history)
+                for roll in range(len(cycle))]
         rates = [r[0] for r in runs]
         q = statistics.quantiles(rates, n=4)
         med = statistics.median(rates)
