@@ -66,8 +66,9 @@ def adopt_cached(engine, cache, uid: int, prompt: List[int]) -> int:
             matched = full_keep * bs
     need = -(-len(prompt) // bs) - len(aliased)
     if need > alloc.free_blocks and cache is not None:
-        cache.evict(need - alloc.free_blocks,
-                    exclude_blocks=aliased + [cow_src])
+        with telemetry.tracer.span("serving/cache_evict"):
+            cache.evict(need - alloc.free_blocks,
+                        exclude_blocks=aliased + [cow_src])
     if need > alloc.free_blocks:
         raise RuntimeError(
             f"KV arena exhausted: want {need} blocks, "
@@ -576,8 +577,9 @@ class ServingFrontend:
                 if self.cache is not None:
                     # prefill done → every prompt page holds valid KV;
                     # publish them (cache increfs what it keeps)
-                    self.cache.insert(
-                        req.prompt, self.engine.state.seqs[uid].blocks)
+                    with telemetry.tracer.span("serving/cache_insert"):
+                        self.cache.insert(
+                            req.prompt, self.engine.state.seqs[uid].blocks)
             tok = int(tok)
             req.tokens_out.append(tok)
             self.metrics.bump("tokens_out")
@@ -822,6 +824,9 @@ class ServingFrontend:
         if self.cache is not None:
             out["prefix_hit_rate"] = self.cache.hit_rate
             out["prefix_pages_cached"] = self.cache.pages_cached
+            out["prefix_evict_calls"] = self.cache.evict_calls
+            out["prefix_evict_scans"] = self.cache.evict_scans
+            out["prefix_pages_evicted"] = self.cache.pages_evicted
         if self.kvtier is not None:
             out["kvtier"] = self.kvtier.stats()
         if self._slo is not None:

@@ -21,6 +21,7 @@ drops that ref, and the page returns to the pool only when no live
 sequence still shares it.
 """
 
+import heapq
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -80,6 +81,12 @@ class PrefixCache:
         #: live sequence keeps the physical page (tiered +1, released +0).
         self.pages_released = 0
         self.pages_tiered = 0
+        #: what making room costs: ``evict`` calls that had pages to drop,
+        #: the walks of the whole trie they made (one a call) and the
+        #: pages they dropped (what ``invalidate`` drops is not evicted)
+        self.evict_calls = 0
+        self.evict_scans = 0
+        self.pages_evicted = 0
 
     # -- lookup ------------------------------------------------------------
 
@@ -125,46 +132,51 @@ class PrefixCache:
     def insert(self, tokens: List[int], blocks: List[int]) -> int:
         """Cache the pages covering ``tokens`` (a fully-prefilled prompt
         whose KV lives in ``blocks``). Increfs every NEWLY cached page;
-        already-cached chunks are left alone. Returns pages added."""
+        already-cached chunks are only re-stamped. Room for the new pages
+        is made ONCE, before any is attached, with the matched prefix
+        excluded (a page on the path being inserted is never evicted — the
+        new child would attach to a detached node and leak its ref); a
+        prompt that needs more room than the cache can give inserts the
+        pages that fit. Returns pages added."""
         bs = self.block_size
         self._clock += 1
         node = self._root
-        added = 0
         n_full = len(tokens) // bs
-        path = set()
-        for i in range(n_full):
-            key = tuple(tokens[i * bs:(i + 1) * bs])
-            child = node.children.get(key)
+        keys = [tuple(tokens[i * bs:(i + 1) * bs]) for i in range(n_full)]
+        matched = 0
+        while matched < n_full:
+            child = node.children.get(keys[matched])
             if child is None:
-                # never evict a page on the path being inserted — the new
-                # child would attach to a detached node and leak its ref
-                if self.pages_cached >= self.max_pages and \
-                        self.evict(1, exclude_blocks=path) == 0:
-                    return added
-                blk = blocks[i]
-                self.allocator.incref([blk])
-                child = _Node(key, blk, node)
-                node.children[key] = child
-                self.pages_cached += 1
-                added += 1
+                break
             child.last_used = self._clock
-            path.add(child.block)
             node = child
-        rem = tokens[n_full * bs:]
-        if rem and len(blocks) > n_full:
-            span = tuple(rem)
-            if span not in node.partials:
-                if self.pages_cached >= self.max_pages and \
-                        self.evict(1, exclude_blocks=path) == 0:
-                    return added
-                blk = blocks[n_full]
-                self.allocator.incref([blk])
-                node.partials[span] = [blk, self._clock]
-                self.pages_cached += 1
-                added += 1
-            else:
-                node.partials[span][1] = self._clock
-        return added
+            matched += 1
+        span = tuple(tokens[n_full * bs:])
+        if not (span and len(blocks) > n_full):
+            span = ()
+        elif matched == n_full and span in node.partials:
+            node.partials[span][1] = self._clock
+            span = ()
+        room = want = n_full - matched + bool(span)
+        # a cache at or over its cap drops one page for each it takes
+        short = min(want, self.pages_cached + want - self.max_pages)
+        if short > 0:
+            path, up = [], node
+            while up.parent is not None:
+                path.append(up.block)
+                up = up.parent
+            room -= short - self.evict(short, exclude_blocks=path)
+        for i in range(matched, min(n_full, matched + room)):
+            self.allocator.incref([blocks[i]])
+            child = _Node(keys[i], blocks[i], node)
+            child.last_used = self._clock
+            node.children[keys[i]] = child
+            node = child
+        if span and room == want:
+            self.allocator.incref([blocks[n_full]])
+            node.partials[span] = [blocks[n_full], self._clock]
+        self.pages_cached += room
+        return room
 
     # -- eviction ----------------------------------------------------------
 
@@ -178,12 +190,16 @@ class PrefixCache:
             node = node.parent
         return [t for chunk in reversed(chunks) for t in chunk]
 
-    def _release(self, block: int, tokens: Optional[List[int]]) -> None:
-        """Drop the cache's ref on one page, optionally capturing its KV
-        into the tier first (the export must happen while the page is
-        still live in the arena). Updates the split eviction accounting."""
-        if self.tier is not None and tokens:
-            if self.tier.capture(tokens, block):
+    def _release(self, block: int, node: _Node, span=()) -> None:
+        """Drop the cache's ref on one page — ``node``'s own, or the
+        partial page ``span`` under it — capturing its KV into the tier
+        first where there is one (the export must happen while the page
+        is still live in the arena; its key, the page's whole token
+        prefix, is rebuilt only then). Updates the split eviction
+        accounting."""
+        if self.tier is not None:
+            tokens = self._token_path(node) + list(span)
+            if tokens and self.tier.capture(tokens, block):
                 self.pages_tiered += 1
         self.pages_released += self.allocator.free([block])
 
@@ -200,29 +216,46 @@ class PrefixCache:
         """Drop the ``n_pages`` least-recently-used LEAF pages (inner trie
         pages are prefixes of live leaves and must outlive them);
         ``exclude_blocks`` protects pages an in-flight match/insert is
-        about to hand out. Returns pages dropped; the allocator reclaims
-        each page only once every sequence sharing it has also let go."""
+        about to hand out, and a protected leaf shields its ancestors.
+        Returns pages dropped; the allocator reclaims each page only once
+        every sequence sharing it has also let go.
+
+        ONE walk of the trie a call: the leaves go onto a heap keyed by
+        ``(last_used, position in the walk)``, and a page whose drop
+        leaves its parent bare puts the parent there under its own
+        ``last_used`` and the dropped page's position. That is where a
+        fresh walk would find the parent among the leaves left, so the
+        victims and their order — ties included, and all pages of one
+        prompt tie — are those of a walk, a filter and a stable sort made
+        anew for every page."""
+        if n_pages <= 0:
+            return 0
         exclude = set(b for b in exclude_blocks if b is not None)
+        self.evict_calls += 1
+        self.evict_scans += 1
+        leaves: List[Tuple[int, object, object]] = []
+        self._leaves(self._root, leaves)
+        heap = [(used, pos, parent, what)
+                for pos, (used, parent, what) in enumerate(leaves)
+                if (what.block if isinstance(what, _Node)
+                    else parent.partials[what][0]) not in exclude]
+        heapq.heapify(heap)
         dropped = 0
-        while dropped < n_pages:
-            leaves: List[Tuple[int, object, object]] = []
-            self._leaves(self._root, leaves)
-            leaves = [t for t in leaves
-                      if (t[2].block if isinstance(t[2], _Node)
-                          else t[1].partials[t[2]][0]) not in exclude]
-            if not leaves:
-                break
-            leaves.sort(key=lambda t: t[0])
-            _, parent, what = leaves[0]
+        while heap and dropped < n_pages:
+            _, pos, parent, what = heapq.heappop(heap)
             if isinstance(what, _Node):
-                self._release(what.block, self._token_path(what))
+                self._release(what.block, what)
                 del parent.children[what.chunk]
             else:                           # partial span key
-                self._release(parent.partials[what][0],
-                              self._token_path(parent) + list(what))
+                self._release(parent.partials[what][0], parent, what)
                 del parent.partials[what]
             self.pages_cached -= 1
             dropped += 1
+            if parent.parent is not None and not parent.children and \
+                    not parent.partials and parent.block not in exclude:
+                heapq.heappush(
+                    heap, (parent.last_used, pos, parent.parent, parent))
+        self.pages_evicted += dropped
         return dropped
 
     def _free_subtree(self, node: _Node) -> Tuple[int, int]:

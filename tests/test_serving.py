@@ -150,6 +150,384 @@ def test_prefix_cache_lru_and_exclude():
 
 
 # ---------------------------------------------------------------------------
+# PrefixCache makes room with one walk a call: the replaced loop as oracle
+# ---------------------------------------------------------------------------
+
+class _ScanAPageCache(PrefixCache):
+    """The cache as it made room before it walked the trie once a call
+    (ISSUE 53): ``evict`` walks, filters and sorts anew for every page it
+    drops and rebuilds the victim's token prefix tier or no tier, and
+    ``insert`` calls ``evict(1)`` for every new page of a full cache. Kept
+    as the plain reference the one-walk cache must agree with, page for
+    page and in order."""
+
+    def _release_page(self, block, tokens):
+        if self.tier is not None and tokens:
+            if self.tier.capture(tokens, block):
+                self.pages_tiered += 1
+        self.pages_released += self.allocator.free([block])
+
+    def insert(self, tokens, blocks):
+        bs = self.block_size
+        self._clock += 1
+        node = self._root
+        added = 0
+        n_full = len(tokens) // bs
+        path = set()
+        for i in range(n_full):
+            key = tuple(tokens[i * bs:(i + 1) * bs])
+            child = node.children.get(key)
+            if child is None:
+                if self.pages_cached >= self.max_pages and \
+                        self.evict(1, exclude_blocks=path) == 0:
+                    return added
+                blk = blocks[i]
+                self.allocator.incref([blk])
+                child = type(node)(key, blk, node)
+                node.children[key] = child
+                self.pages_cached += 1
+                added += 1
+            child.last_used = self._clock
+            path.add(child.block)
+            node = child
+        rem = tokens[n_full * bs:]
+        if rem and len(blocks) > n_full:
+            span = tuple(rem)
+            if span not in node.partials:
+                if self.pages_cached >= self.max_pages and \
+                        self.evict(1, exclude_blocks=path) == 0:
+                    return added
+                blk = blocks[n_full]
+                self.allocator.incref([blk])
+                node.partials[span] = [blk, self._clock]
+                self.pages_cached += 1
+                added += 1
+            else:
+                node.partials[span][1] = self._clock
+        return added
+
+    def evict(self, n_pages, exclude_blocks=()):
+        exclude = set(b for b in exclude_blocks if b is not None)
+        dropped = 0
+        while dropped < n_pages:
+            leaves = []
+            self._leaves(self._root, leaves)
+            leaves = [t for t in leaves
+                      if (t[1].partials[t[2]][0] if isinstance(t[2], tuple)
+                          else t[2].block) not in exclude]
+            if not leaves:
+                break
+            leaves.sort(key=lambda t: t[0])
+            _, parent, what = leaves[0]
+            if isinstance(what, tuple):     # partial span key
+                self._release_page(parent.partials[what][0],
+                                   self._token_path(parent) + list(what))
+                del parent.partials[what]
+            else:
+                self._release_page(what.block, self._token_path(what))
+                del parent.children[what.chunk]
+            self.pages_cached -= 1
+            dropped += 1
+        return dropped
+
+
+class _LoggingAllocator(BlockedAllocator):
+    """Every ``free`` in order: the pages a cache let go, as it let go."""
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.freed = []
+
+    def free(self, blocks):
+        self.freed.extend(blocks)
+        return super().free(blocks)
+
+
+class _RecordingTier:
+    """A page tier that keeps what it was handed and takes two pages in
+    three, so ``pages_tiered`` parts from the pages dropped."""
+
+    def __init__(self):
+        self.captured, self.invalidated = [], []
+
+    def capture(self, tokens, block):
+        self.captured.append((tuple(tokens), block))
+        return block % 3 != 0
+
+    def invalidate(self, tokens):
+        self.invalidated.append(tuple(tokens))
+
+
+def _cache_state(cache):
+    a = cache.allocator
+    return {"pages_cached": cache.pages_cached,
+            "owned": cache.owned_blocks(),
+            "released": cache.pages_released,
+            "tiered": cache.pages_tiered,
+            "freed": list(a.freed), "free_blocks": a.free_blocks,
+            "refs": [a.refcount(b) for b in range(a.num_blocks)],
+            "hits": (cache.lookups, cache.hits, cache.tokens_hit),
+            "captured": list(cache.tier.captured) if cache.tier else None,
+            "invalidated": list(cache.tier.invalidated)
+            if cache.tier else None}
+
+
+class _CachePair:
+    """The one-walk cache and the scan-a-page reference over an allocator
+    each, handed the same calls and compared after every one."""
+
+    def __init__(self, num_blocks, block_size, max_pages, tier=False):
+        self.caches = [
+            cls(_LoggingAllocator(num_blocks, block_size), max_pages,
+                tier=_RecordingTier() if tier else None)
+            for cls in (PrefixCache, _ScanAPageCache)]
+        self.held = []                    # a live sequence's pages, each
+        self.ops = 0
+
+    def both(self, call):
+        got = [call(c) for c in self.caches]
+        self.ops += 1
+        assert got[0] == got[1], (self.ops, got)
+        new, ref = (_cache_state(c) for c in self.caches)
+        assert new == ref, self.ops
+        assert len(new["owned"]) == new["pages_cached"]
+        return got[0]
+
+    def prompt(self, tokens, keep=False):
+        """A request's life: room for its pages (``adopt_cached``'s
+        eviction, its matched pages excluded and aliased), its prompt
+        cached at the first token, its pages let go — or held, so that a
+        later eviction drops a page the allocator does not get back."""
+        bs = self.caches[0].block_size
+
+        def run(cache):
+            a = cache.allocator
+            m = cache.match(tokens)
+            need = -(-len(tokens) // bs) - len(m.full_blocks)
+            evicted = 0
+            if need > a.free_blocks:
+                evicted = cache.evict(
+                    need - a.free_blocks,
+                    exclude_blocks=m.full_blocks + [m.partial_block])
+            if need > a.free_blocks:
+                return ("no room", evicted)
+            a.incref(m.full_blocks)
+            blocks = m.full_blocks + a.allocate(need)
+            added = cache.insert(tokens, blocks)
+            return (evicted, added, blocks)
+        out = self.both(run)
+        if out[0] == "no room":
+            return out
+        for cache in self.caches:
+            if not keep:
+                cache.allocator.free(out[2])
+        if keep:
+            self.held.append(out[2])
+        return out
+
+    def release_held(self):
+        if self.held:
+            blocks = self.held.pop(0)
+            self.both(lambda c: c.allocator.free(blocks))
+
+
+def _unshared(rng, n_tokens):
+    return [int(t) for t in rng.integers(0, 1 << 30, n_tokens)]
+
+
+def _ties_within_one_chain(rng, pair):
+    # every page of a prompt carries one stamp; a match re-stamps a
+    # prefix of the chain, so the chain splits into two runs of ties
+    prompts = [_unshared(rng, int(n)) for n in rng.integers(9, 40, 6)]
+    for p in prompts:
+        pair.prompt(p)
+    for p in prompts[::2]:
+        cut = int(rng.integers(1, len(p)))
+        pair.both(lambda c: c.match(p[:cut]).full_blocks)
+    while pair.caches[0].pages_cached:
+        n = int(rng.integers(1, 4))
+        pair.both(lambda c: c.evict(n))
+
+
+def _stamps_that_tie_across_branches(rng, pair):
+    # the cache's own clock stamps one root path a tick, and no two leaves
+    # lie on one: leaves that tie need stamps put there by hand. With
+    # three values over a branching trie the order among equals is the
+    # walk's, and a parent laid bare stands where its last page stood.
+    trunks = [_unshared(rng, 12) for _ in range(2)]
+    for _ in range(14):
+        trunk = trunks[int(rng.integers(2))]
+        pair.prompt(trunk[:int(rng.integers(0, 13))] +
+                    _unshared(rng, int(rng.integers(1, 14))))
+    stamps = [int(t) for t in rng.integers(0, 3, 96)]
+    for cache in pair.caches:
+        left = iter(stamps)
+
+        def stamp(node):
+            for rec in node.partials.values():
+                rec[1] = next(left)
+            for child in node.children.values():
+                child.last_used = next(left)
+                stamp(child)
+        stamp(cache._root)
+    keep = pair.caches[0].owned_blocks()[5:7]
+    n = 1
+    while pair.both(lambda c: c.evict(n, exclude_blocks=keep)):
+        n = int(rng.integers(1, 4))
+
+
+def _excluded_leaf_shields_ancestors(rng, pair):
+    trunk = _unshared(rng, 16)
+    prompts = [trunk[:int(rng.integers(4, 17))] +
+               _unshared(rng, int(rng.integers(1, 14))) for _ in range(8)]
+    for p in prompts:
+        pair.prompt(p)
+    for _ in range(12):
+        p = prompts[int(rng.integers(len(prompts)))]
+        n = int(rng.integers(1, 6))
+
+        def run(cache):
+            m = cache.match(p)
+            return cache.evict(n, exclude_blocks=m.full_blocks +
+                               [m.partial_block])
+        pair.both(run)
+        pair.prompt(prompts[int(rng.integers(len(prompts)))])
+    # what the exclusion of one whole path leaves is that path
+    p = prompts[0]
+    pair.prompt(p)
+
+    def drain(cache):
+        m = cache.match(p)
+        dropped = cache.evict(10_000, exclude_blocks=m.full_blocks +
+                              [m.partial_block])
+        path = m.full_blocks + [m.partial_block] * (m.partial_len > 0)
+        return dropped, sorted(cache.owned_blocks()) == sorted(path)
+    assert pair.both(drain)[1]
+
+
+def _full_cache_of_unshared_prompts(rng, pair):
+    for i in range(40):
+        pair.prompt(_unshared(rng, int(rng.integers(5, 60))),
+                    keep=rng.random() < 0.3)
+        if rng.random() < 0.3:
+            pair.release_held()
+        if i == 30:
+            # a cap lowered under a full cache: a page out for a page in
+            assert pair.caches[0].pages_cached == 20
+            for cache in pair.caches:
+                cache.max_pages = 14
+    assert pair.caches[0].pages_cached == 20
+
+
+def _prompt_that_fits_only_in_part(rng, pair):
+    cap, bs = pair.caches[0].max_pages, pair.caches[0].block_size
+    for _ in range(6):
+        pair.prompt(_unshared(rng, int(rng.integers(5, 30))))
+    long = _unshared(rng, (cap + 5) * bs + 3)
+    assert pair.prompt(long)[1] == cap     # the pages that fit, no more
+    # its cached head matches, and a longer twin adds nothing it can hold
+    assert pair.prompt(long + _unshared(rng, 9))[1] == 0
+    shared = long[:3 * bs] + _unshared(rng, (cap + 2) * bs)
+    assert pair.prompt(shared)[1] == cap - 3
+    pair.prompt(_unshared(rng, 11))
+
+
+def _everything(rng, pair):
+    trunks = [_unshared(rng, 24) for _ in range(3)]
+    seen = []
+    for _ in range(120):
+        op = rng.random()
+        if op < 0.45 or not seen:
+            trunk = trunks[int(rng.integers(3))]
+            p = (trunk[:int(rng.integers(0, 25))] if rng.random() < 0.6
+                 else []) + _unshared(rng, int(rng.integers(1, 50)))
+            seen.append(p)
+            pair.prompt(p, keep=rng.random() < 0.25)
+        elif op < 0.6:
+            p = seen[int(rng.integers(len(seen)))]
+            p = p[:int(rng.integers(1, len(p) + 1))]
+            pair.both(lambda c: c.match(p).matched(c.block_size))
+        elif op < 0.8:
+            p = seen[int(rng.integers(len(seen)))]
+            n = int(rng.integers(0, 9))
+
+            def run(cache):
+                m = cache.match(p)
+                return cache.evict(n, exclude_blocks=m.full_blocks +
+                                   [m.partial_block])
+            pair.both(run)
+        elif op < 0.9:
+            p = seen[int(rng.integers(len(seen)))]
+            pair.both(lambda c: c.invalidate(p))
+        else:
+            pair.release_held()
+    while pair.held:
+        pair.release_held()
+    pair.both(lambda c: c.clear())
+    assert pair.caches[0].allocator.free_blocks == \
+        pair.caches[0].allocator.num_blocks
+
+
+@pytest.mark.parametrize("scenario,kwargs", [
+    (_ties_within_one_chain, dict(max_pages=64)),
+    (_excluded_leaf_shields_ancestors, dict(max_pages=24)),
+    (_full_cache_of_unshared_prompts, dict(max_pages=20)),
+    (_prompt_that_fits_only_in_part, dict(max_pages=12)),
+    (_everything, dict(max_pages=30, tier=True)),
+    (_everything, dict(max_pages=None)),
+    (_ties_within_one_chain, dict(max_pages=64, tier=True)),
+    (_stamps_that_tie_across_branches, dict(max_pages=80, tier=True)),
+], ids=["ties_within_one_chain", "excluded_leaf_shields_ancestors",
+        "full_cache_of_unshared_prompts", "prompt_that_fits_only_in_part",
+        "tier_random_mix", "random_mix_default_cap", "tier_ties",
+        "stamps_that_tie_across_branches"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_prefix_cache_makes_room_as_the_scan_a_page_loop_did(
+        scenario, kwargs, seed):
+    """Evicted pages and their order, the allocator's state, the hit
+    accounting and a tier's captures, equal to the replaced loop's after
+    every call of a seeded sequence."""
+    pair = _CachePair(96, 4, **kwargs)
+    scenario(np.random.default_rng([seed, 53]), pair)
+    assert pair.ops >= 10
+
+
+def test_prefix_cache_makes_room_with_one_walk_a_call():
+    """Counts, not clocks (ROADMAP C13), at cell 11's arena: 2,624 pages
+    of 128 tokens, 1,312 of them cached prompts nobody shares."""
+    a = BlockedAllocator(2624, 128)
+    cache = PrefixCache(a)
+    assert cache.max_pages == 1312
+    rng = np.random.default_rng(53)
+    walks = []
+    leaves = cache._leaves
+    cache._leaves = lambda node, out: (
+        walks.append(node is cache._root), leaves(node, out))[1]
+    while cache.pages_cached < cache.max_pages:
+        blocks = a.allocate(82)
+        cache.insert(_unshared(rng, 82 * 128), blocks)
+        a.free(blocks)
+    assert (cache.evict_calls, cache.evict_scans, sum(walks)) == (0, 0, 0)
+
+    blocks = a.allocate(160)                # a 20,480-token prompt
+    prompt = _unshared(rng, 160 * 128)
+    before = cache.pages_evicted
+    assert cache.insert(prompt, blocks) == 160
+    assert (cache.evict_calls, cache.evict_scans, sum(walks)) == (1, 1, 1)
+    assert cache.pages_evicted - before == 160
+    assert cache.pages_cached == 1312
+
+    # adopt_cached's room-making at the next admission: evict(k)
+    assert cache.evict(100, exclude_blocks=blocks[:3]) == 100
+    assert (cache.evict_calls, cache.evict_scans, sum(walks)) == (2, 2, 2)
+    assert cache.evict(0) == 0              # nothing to drop: no walk
+    assert (cache.evict_calls, cache.evict_scans, sum(walks)) == (2, 2, 2)
+    # already cached (the 100 were older prompts'): re-stamped, no room made
+    assert cache.insert(prompt, blocks) == 0
+    assert (cache.evict_calls, cache.evict_scans, sum(walks)) == (2, 2, 2)
+
+
+# ---------------------------------------------------------------------------
 # SplitFuse token-budget policy
 # ---------------------------------------------------------------------------
 
@@ -472,6 +850,45 @@ def test_frontend_prefix_hit_skips_prefill_steps(devices):
     assert r2.tokens_out == r1.tokens_out
     assert fe.cache.hit_rate > 0
     assert fe.metrics.counters["prefix_tokens_reused"] == 32
+
+
+def test_frontend_counts_and_spans_the_cache_making_room(devices):
+    """An arena of 12 pages, half of it the cache's, under prompts of 7:
+    every admission past the first evicts for its pages (`serving/cache_evict`, inside
+    `serving/admit`) and every first token publishes a prompt
+    (`serving/cache_insert`, inside `serving/fanout`); `stats()` gives the
+    cache's three counters, one walk a call."""
+    from deepspeed_tpu import telemetry
+    eng = _engine(devices, params_key=3, num_blocks=12)
+    fe = ServingFrontend(eng)
+    rng = np.random.default_rng(53)
+    tr = telemetry.tracer
+    was = tr.enabled
+    tr.configure(enabled=True)
+    tr.clear()
+    try:
+        for _ in range(5):
+            fe.submit([int(t) for t in rng.integers(0, 256, size=52)],
+                      max_new_tokens=2)
+            fe.run_until_idle()
+        spans = [e for e in tr.events() if e.get("ph") == "X"]
+    finally:
+        tr.configure(enabled=was)
+        tr.clear()
+    stats = fe.stats()
+    assert stats["prefix_evict_calls"] == stats["prefix_evict_scans"] >= 3
+    assert stats["prefix_pages_evicted"] >= stats["prefix_evict_calls"]
+
+    def inside(name, parent):
+        outer = [(e["ts"], e["ts"] + e["dur"]) for e in spans
+                 if e["name"] == parent]
+        inner = [e for e in spans if e["name"] == name]
+        return len(inner), all(
+            any(t0 <= e["ts"] and e["ts"] + e["dur"] <= t1
+                for t0, t1 in outer) for e in inner)
+    assert inside("serving/cache_insert", "serving/fanout") == (5, True)
+    n, nested = inside("serving/cache_evict", "serving/admit")
+    assert nested and n >= 3
 
 
 def test_frontend_streaming_iterator_and_cancel(devices):

@@ -48,12 +48,22 @@ that an instance of ``<slots>x<chunk rows>`` would hold, for the rungs a
 ladder of token capacities might have — the same on any tree, since it reads
 the batches and not the programs.
 
+``prefix_cache`` (PR 53) is what the prefix cache's room-making cost the
+window: the growth of the cache's ``evict_calls`` / ``evict_scans`` /
+``pages_evicted`` (None on a tree without them) and, timed here around the
+calls the frontend's spans ``serving/cache_insert`` (inside
+``serving/fanout``) and ``serving/cache_evict`` (``adopt_cached``'s, inside
+``serving/admit``) enclose — so that an untraced run and a tree without
+the spans give them too —, each one's calls, total and longest in ms, and
+the longest ``serving/step`` beside them (``step_ms_max``).
+
     chiprun --chips 1 -- python3 tools/host_path_probe.py \
         --workload <cell> --seed <n> --trace <0|1>
 """
 import json
 import os
 import sys
+import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -68,6 +78,39 @@ WORK = ("steps.split", "split_grouped_steps", "chunk_rows",
         "kv_page_fetches")
 #: (slots, chunk rows) of the instances ``split_steps.fits`` asks about
 RUNGS = ((256, 2), (256, 8), (512, 4), (512, 8), (1024, 8))
+CACHE_COUNTERS = ("evict_calls", "evict_scans", "pages_evicted")
+
+
+class Calls:
+    """Times the OUTERMOST calls of the methods it wraps (those of one
+    ``Calls`` may call each other: an eviction ``insert`` makes itself is
+    inside the insert's time), kept while ``on()`` holds; ``last`` is the
+    object the newest call was made on."""
+
+    def __init__(self, on):
+        self.on, self.depth, self.last = on, 0, None
+
+    def wrap(self, owner, method):
+        """Replace ``owner.method``; the list its calls' seconds go to."""
+        into, inner = [], getattr(owner, method)
+
+        def call(*args, **kwargs):
+            self.last = args[0]
+            self.depth += 1
+            t0 = time.perf_counter()
+            try:
+                return inner(*args, **kwargs)
+            finally:
+                self.depth -= 1
+                if self.depth == 0 and self.on():
+                    into.append(time.perf_counter() - t0)
+        setattr(owner, method, call)
+        return into
+
+
+def calls_ms(seconds):
+    return {"calls": len(seconds), "total_ms": 1e3 * sum(seconds),
+            "longest_ms": 1e3 * max(seconds, default=0.0)}
 
 
 def counters():
@@ -152,17 +195,34 @@ def main() -> int:
     launches = []
     count = RaggedInferenceEngineTPU._count_dispatch
 
+    def in_window():
+        return bool(at_open) and not at_close
+
     def counted(self, program, rows, nb, chunk, page_width, tokens,
                 *args, **kwargs):
-        if program == "split" and at_open and not at_close:
+        if program == "split" and in_window():
             launches.append((tokens, kwargs.get("chunk_rows", 0),
                              kwargs.get("token_slots") or nb * chunk))
         return count(self, program, rows, nb, chunk, page_width, tokens,
                      *args, **kwargs)
     RaggedInferenceEngineTPU._count_dispatch = counted
 
+    from deepspeed_tpu.serving import ServingFrontend
+    from deepspeed_tpu.serving.prefix_cache import PrefixCache
+    steps = Calls(in_window).wrap(ServingFrontend, "step")
+    # `cache_insert` is `_fan_out`'s call, `cache_evict` what is left:
+    # `adopt_cached`'s and `extend`'s last resort, as the spans have it
+    cache_calls = Calls(in_window)
+    inserts = cache_calls.wrap(PrefixCache, "insert")
+    evicts = cache_calls.wrap(PrefixCache, "evict")
+    cache_open = []
+
+    def cache_counters():
+        return [getattr(cache_calls.last, n, None) for n in CACHE_COUNTERS]
+
     def opened(self):
         at_open[:] = counters()
+        cache_open[:] = cache_counters()
         return open_window(self)
     bench_run.Context.open_window = opened
     traces = []
@@ -172,7 +232,6 @@ def main() -> int:
         traces.append(load_trace(self))
         return traces[-1]
     bench_run.Context.load_trace = loaded
-    from deepspeed_tpu.serving import ServingFrontend
     at_close = []
     terminate = ServingFrontend.terminate_inflight
 
@@ -199,7 +258,13 @@ def main() -> int:
             "ahead_share": 100.0 * ahead / max(1, calls),
             "ahead_rows_dropped": int(dropped),
             "window": dict(zip(WORK, work)),
-            "split_steps": split_steps(launches)}), flush=True)
+            "split_steps": split_steps(launches),
+            "prefix_cache": {
+                **{n: None if a is None else b - a for n, a, b in zip(
+                    CACHE_COUNTERS, cache_open, cache_counters())},
+                "cache_insert": calls_ms(inserts),
+                "cache_evict": calls_ms(evicts),
+                "step_ms_max": 1e3 * max(steps, default=0.0)}}), flush=True)
     return rc
 
 
