@@ -6,10 +6,14 @@ Provides the capabilities of DeepSpeed (reference: deepspeed/__init__.py —
 ICI/DCN, Pallas kernels for hot ops.
 """
 
-from deepspeed_tpu.version import __version__
-from deepspeed_tpu import comm  # noqa: F401
-from deepspeed_tpu.config import AUTO, DeepSpeedTPUConfig  # noqa: F401
-from deepspeed_tpu.parallel.mesh import build_mesh, get_mesh, mesh_from_config  # noqa: F401
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()     # ``setup/import`` starts here ...
+
+from deepspeed_tpu.version import __version__  # noqa: E402
+from deepspeed_tpu import comm  # noqa: E402,F401
+from deepspeed_tpu.config import AUTO, DeepSpeedTPUConfig  # noqa: E402,F401
+from deepspeed_tpu.parallel.mesh import build_mesh, get_mesh, mesh_from_config  # noqa: E402,F401
 
 __all__ = ["__version__", "DeepSpeedTPUConfig", "AUTO", "build_mesh",
            "get_mesh", "mesh_from_config", "comm", "initialize",
@@ -111,3 +115,9 @@ def init_inference(*args, **kwargs):
     """Create an inference engine (reference deepspeed/__init__.py:302)."""
     from deepspeed_tpu.inference.engine import init_inference as _init_inference
     return _init_inference(*args, **kwargs)
+
+
+#: ... and ends here: the package's own import on ``time.perf_counter``.
+#: Telemetry may not load with the package, so the first
+#: ``compile_monitor.install()`` publishes it (``setup/import_seconds``)
+_IMPORT_SPAN = (_IMPORT_T0, _time.perf_counter())

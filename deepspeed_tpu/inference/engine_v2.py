@@ -1177,6 +1177,24 @@ class RaggedInferenceEngineTPU:
                  config: Union[Dict[str, Any], RaggedInferenceConfig,
                                None] = None,
                  params=None, rng: Optional[jax.Array] = None):
+        """Construction is timed by part, always on
+        (``telemetry.compile_monitor.setup_part``): the parameters' cast,
+        init and placement (``setup/params``), the arena's allocation
+        (``setup/arena``), the rest (``setup/engine``). Each times the
+        HOST: the arena's ``jnp.zeros`` and a placement are enqueued, and
+        nothing here waits for the device."""
+        # here and not at import, as the tracer below
+        from deepspeed_tpu.telemetry.compile_monitor import (
+            compile_monitor, setup_part)
+        # the build record and its counters, tracer on or off: a serving
+        # process's step programs are named (``_step_fn``)
+        compile_monitor.install()
+        with setup_part("engine"):
+            self._construct(model, config, params, rng, setup_part)
+
+    def _construct(self, model, config, params, rng, setup_part) -> None:
+        """``__init__``'s body, inside ``setup/engine``; ``setup_part`` is
+        handed in because telemetry is imported at the call, not here."""
         if isinstance(config, dict) or config is None:
             config = RaggedInferenceConfig(**(config or {}))
         if not model.causal or model.layer_window_pattern is not None:
@@ -1257,74 +1275,77 @@ class RaggedInferenceEngineTPU:
         self.mb = -(-config.max_seq_len // config.block_size)
 
         rng = rng if rng is not None else jax.random.PRNGKey(0)
-        cast = lambda t: jax.tree.map(
-            lambda x: x.astype(self.dtype)
-            if jnp.issubdtype(x.dtype, jnp.floating) else x, t)
-        from deepspeed_tpu.inference.engine import _is_quantized_tree
-        from deepspeed_tpu.ops.quantized_linear import (
-            cast_quantized_tree, quantize_param_tree)
-        # explicit accelerator target: plain jax.device_put(x) is an
-        # IDENTITY for already-placed arrays, so host-built trees would
-        # silently stay CPU-resident and stream per step. The target is
-        # the caller's ``jax.default_device`` when one is set (a replica
-        # per chip), else the first device
-        dflt = jax.config.jax_default_device
-        dev0 = dflt if isinstance(dflt, jax.Device) else jax.devices()[0]
-        if params is None and config.weight_quant:
-            # init + quantize on HOST, ship only the quantized tree (same
-            # rationale as the v1 engine: int4 llama-8B serves in ~5 GB
-            # but would OOM materialized bf16-first on a 16 GB chip).
-            # NOTE: random init is kept on jax PRNG for weight parity with
-            # the on-device path — slow for 8B-scale demos (single-core
-            # threefry); real large models load checkpoints (hf_loader)
-            # or pre-quantized trees instead.
-            with jax.default_device(jax.local_devices(backend="cpu")[0]):
-                host = quantize_param_tree(cast(init_params(model, rng)),
-                                           mode=config.weight_quant)
-            self.params = jax.tree.map(
-                lambda v: jax.device_put(v, dev0), host)
-        elif params is not None and _is_quantized_tree(params):
-            # pre-quantized (bin/dstpu_quantize / host-quantized) tree:
-            # dtype policy must not touch scales / fp8 / packed planes
-            if config.weight_quant:
-                raise ValueError(
-                    "params are already quantized (scale leaves present); "
-                    "drop weight_quant from the config")
-            self.params = jax.tree.map(
-                lambda v: jax.device_put(v, dev0),
-                cast_quantized_tree(params, self.dtype))
-        else:
-            # random init as ONE jitted program (like the v1 engine): run
-            # eagerly it compiles a program per leaf shape — 27 compiles,
-            # 77 s cold for the 1b preset on a v5e
-            self.params = cast(params) if params is not None else \
-                jax.jit(lambda r: cast(init_params(model, r)))(rng)
-            if config.weight_quant:
-                self.params = quantize_param_tree(self.params,
-                                                  mode=config.weight_quant)
-        if model.typed:
-            # a pool per attention kind and per K/V, one page table
-            self.arena = pa.init_arena_typed(
-                model.layer_kinds,
-                {a: model.kind_kv_heads(a) for a in set(model.layer_kinds)},
-                config.num_blocks, config.block_size, self.k_width,
-                model.v_dim, self.dtype,
-                # a stack that picks its keys: the index pool beside them
-                **(dict(index_layers=model.indexer_layers,
-                        index_width=model.index_head_dim)
-                   if model.picks_keys else {}))
-            if model.recurrent:
-                # beside the pages: a float32 state and a convolution tail
-                # a sequence slot and state-space layer; the pool's size
-                # follows max_sequences
-                self.arena.update(ssm.init_state_pools(
-                    model, config.max_sequences, self.dtype))
-        else:
-            self.arena = pa.init_arena(model.num_layers, model.kv_heads,
-                                       config.num_blocks, config.block_size,
-                                       model.head_dim, self.dtype)
-        self.arena[FED_TOKENS] = jnp.zeros((config.max_sequences + 1,),
-                                           jnp.int32)
+        with setup_part("params"):
+            cast = lambda t: jax.tree.map(
+                lambda x: x.astype(self.dtype)
+                if jnp.issubdtype(x.dtype, jnp.floating) else x, t)
+            from deepspeed_tpu.inference.engine import _is_quantized_tree
+            from deepspeed_tpu.ops.quantized_linear import (
+                cast_quantized_tree, quantize_param_tree)
+            # explicit accelerator target: plain jax.device_put(x) is an
+            # IDENTITY for already-placed arrays, so host-built trees would
+            # silently stay CPU-resident and stream per step. The target is
+            # the caller's ``jax.default_device`` when one is set (a replica
+            # per chip), else the first device
+            dflt = jax.config.jax_default_device
+            dev0 = dflt if isinstance(dflt, jax.Device) else jax.devices()[0]
+            if params is None and config.weight_quant:
+                # init + quantize on HOST, ship only the quantized tree (same
+                # rationale as the v1 engine: int4 llama-8B serves in ~5 GB
+                # but would OOM materialized bf16-first on a 16 GB chip).
+                # NOTE: random init is kept on jax PRNG for weight parity with
+                # the on-device path — slow for 8B-scale demos (single-core
+                # threefry); real large models load checkpoints (hf_loader)
+                # or pre-quantized trees instead.
+                with jax.default_device(jax.local_devices(backend="cpu")[0]):
+                    host = quantize_param_tree(cast(init_params(model, rng)),
+                                               mode=config.weight_quant)
+                self.params = jax.tree.map(
+                    lambda v: jax.device_put(v, dev0), host)
+            elif params is not None and _is_quantized_tree(params):
+                # pre-quantized (bin/dstpu_quantize / host-quantized) tree:
+                # dtype policy must not touch scales / fp8 / packed planes
+                if config.weight_quant:
+                    raise ValueError(
+                        "params are already quantized (scale leaves present); "
+                        "drop weight_quant from the config")
+                self.params = jax.tree.map(
+                    lambda v: jax.device_put(v, dev0),
+                    cast_quantized_tree(params, self.dtype))
+            else:
+                # random init as ONE jitted program (like the v1 engine): run
+                # eagerly it compiles a program per leaf shape — 27 compiles,
+                # 77 s cold for the 1b preset on a v5e
+                self.params = cast(params) if params is not None else \
+                    jax.jit(lambda r: cast(init_params(model, r)))(rng)
+                if config.weight_quant:
+                    self.params = quantize_param_tree(self.params,
+                                                      mode=config.weight_quant)
+        with setup_part("arena"):
+            if model.typed:
+                # a pool per attention kind and per K/V, one page table
+                self.arena = pa.init_arena_typed(
+                    model.layer_kinds,
+                    {a: model.kind_kv_heads(a)
+                     for a in set(model.layer_kinds)},
+                    config.num_blocks, config.block_size, self.k_width,
+                    model.v_dim, self.dtype,
+                    # a stack that picks its keys: the index pool beside them
+                    **(dict(index_layers=model.indexer_layers,
+                            index_width=model.index_head_dim)
+                       if model.picks_keys else {}))
+                if model.recurrent:
+                    # beside the pages: a float32 state and a convolution tail
+                    # a sequence slot and state-space layer; the pool's size
+                    # follows max_sequences
+                    self.arena.update(ssm.init_state_pools(
+                        model, config.max_sequences, self.dtype))
+            else:
+                self.arena = pa.init_arena(
+                    model.num_layers, model.kv_heads, config.num_blocks,
+                    config.block_size, model.head_dim, self.dtype)
+            self.arena[FED_TOKENS] = jnp.zeros((config.max_sequences + 1,),
+                                               jnp.int32)
         moe_fn = None
         if model.typed:
             # routes over every expert, computes the held ones' part

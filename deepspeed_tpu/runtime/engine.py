@@ -115,8 +115,15 @@ class _ParkedShards:
 
 
 class DeepSpeedTPUEngine:
-    """See module docstring. Construct via :func:`initialize`."""
+    """See module docstring. Construct via :func:`initialize`.
 
+    Construction is timed by part, always on (``telemetry.setup_part``):
+    the mesh and the sharding plan (``setup/mesh``), the parameters' init
+    and placement (``setup/params``), the optimizer's state
+    (``setup/optimizer_state``), the rest (``setup/engine``). Each times
+    the HOST: a jitted init returns once it is enqueued."""
+
+    @telemetry.setup_part("engine")
     def __init__(self,
                  model: ModelSpec,
                  config: DeepSpeedTPUConfig,
@@ -127,7 +134,9 @@ class DeepSpeedTPUEngine:
         comm.init_distributed()
         self.model = model
         self.config = config
-        self.mesh = mesh or (get_mesh() if has_mesh() else mesh_from_config(config))
+        with telemetry.setup_part("mesh"):
+            self.mesh = mesh or (get_mesh() if has_mesh()
+                                 else mesh_from_config(config))
         self.dp_world_size = get_data_parallel_world_size(self.mesh)
         config.resolve_batch_sizes(self.dp_world_size)
 
@@ -266,10 +275,11 @@ class DeepSpeedTPUEngine:
                 lambda x: x.astype(dtype)
                 if jnp.issubdtype(x.dtype, jnp.floating) else x, p)
 
-        self._abstract_params = jax.eval_shape(cast_init, rng)
-        base_specs = self._base_specs()
-        self.plan = ZeroShardingPlan(self.mesh, self.zero_stage, base_specs,
-                                     self._abstract_params)
+        with telemetry.setup_part("mesh"):
+            self._abstract_params = jax.eval_shape(cast_init, rng)
+            self.plan = ZeroShardingPlan(
+                self.mesh, self.zero_stage, self._base_specs(),
+                self._abstract_params)
         zcfg = self.config.zero_optimization
         self._zeropp_enabled = bool(zcfg.zero_quantized_weights or
                                     zcfg.zero_quantized_gradients)
@@ -287,14 +297,16 @@ class DeepSpeedTPUEngine:
             from deepspeed_tpu.ops.onebit import validate_onebit
             validate_onebit(self)
         param_sh = self.plan.param_shardings()
-        if params is None:
-            init_jit = jax.jit(cast_init, out_shardings=param_sh)
-            self.params = init_jit(rng)
-        else:
-            self.params = jax.device_put(
-                jax.tree.map(lambda x: x.astype(dtype)
-                             if jnp.issubdtype(x.dtype, jnp.floating) and
-                             dtype != jnp.float32 else x, params), param_sh)
+        with telemetry.setup_part("params"):
+            if params is None:
+                init_jit = jax.jit(cast_init, out_shardings=param_sh)
+                self.params = init_jit(rng)
+            else:
+                self.params = jax.device_put(
+                    jax.tree.map(
+                        lambda x: x.astype(dtype)
+                        if jnp.issubdtype(x.dtype, jnp.floating) and
+                        dtype != jnp.float32 else x, params), param_sh)
         self._param_shardings = param_sh
         if self.offload_enabled:
             # ZeRO-Offload: optimizer state in host DRAM; ZeRO-Infinity:
@@ -352,10 +364,11 @@ class DeepSpeedTPUEngine:
             validate_onebit(self)
             init_onebit_state(self)
             return
-        abstract_state = jax.eval_shape(self.optimizer.init, self.params)
-        state_sh = self.plan.opt_state_shardings(abstract_state)
-        self.opt_state = jax.jit(self.optimizer.init,
-                                 out_shardings=state_sh)(self.params)
+        with telemetry.setup_part("optimizer_state"):
+            abstract_state = jax.eval_shape(self.optimizer.init, self.params)
+            state_sh = self.plan.opt_state_shardings(abstract_state)
+            self.opt_state = jax.jit(self.optimizer.init,
+                                     out_shardings=state_sh)(self.params)
         self._state_shardings = state_sh
 
     # ------------------------------------------------------------- jit build

@@ -89,6 +89,7 @@ def adopt_cached(engine, cache, uid: int, prompt: List[int]) -> int:
 
 class ServingFrontend:
 
+    @telemetry.setup_part("frontend")      # always on: setup/frontend_seconds
     def __init__(self, engine, max_queue: int = 128,
                  enable_prefix_cache: bool = True,
                  cache_pages: Optional[int] = None,
@@ -606,10 +607,13 @@ class ServingFrontend:
         self.engine.flush(req.uid)
         self.policy.forget(req.uid)
         self._running.pop(req.uid, None)
-        req.state = state
         req.finish_reason = reason
         req.finish_ts = now
         self._trace_lifecycle(req, reason, now)
+        # last: a router's thread that sees the request ``done`` decides
+        # its trace's fate at once, and spans that reach the tail sampler
+        # after that are dropped (``trace/late_spans``)
+        req.state = state
         if req.tpot is not None:
             self.metrics.tpot.record(
                 req.tpot,

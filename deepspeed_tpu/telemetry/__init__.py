@@ -67,7 +67,7 @@ from deepspeed_tpu.telemetry.anomaly import (AnomalyDetector,  # noqa: F401
                                              anomaly_detector,
                                              first_flagged_path)
 from deepspeed_tpu.telemetry.compile_monitor import (  # noqa: F401
-    CompileMonitor, compile_monitor)
+    CompileMonitor, compile_monitor, setup_part)
 from deepspeed_tpu.telemetry.endpoint import MetricsServer  # noqa: F401
 from deepspeed_tpu.telemetry.explain import (ExplainReport,  # noqa: F401
                                              FunctionCost, Roofline,
@@ -105,6 +105,7 @@ __all__ = ["tracer", "Tracer", "registry", "MetricsRegistry", "Counter",
            "device_memory_stats", "host_rss_bytes", "configure",
            "metrics_text", "flight_recorder", "FlightRecorder",
            "load_dump", "Watchdog", "compile_monitor", "CompileMonitor",
+           "setup_part",
            "anomaly_detector", "AnomalyDetector", "first_flagged_path",
            "ExplainReport", "FunctionCost", "Roofline", "analyze_fn",
            "explain_engine", "explain_serving", "normalize_cost_analysis",

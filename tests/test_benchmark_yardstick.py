@@ -23,7 +23,8 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 FILES = ("test_contract", "test_flops", "test_stats", "test_traffic",
-         "test_latent_work", "test_host_path", "test_sparse_latent_work")
+         "test_latent_work", "test_host_path", "test_sparse_latent_work",
+         "test_setup_readers")
 _collected = {}
 for _file in FILES:
     _mod = importlib.import_module(f"benchmark.tests.{_file}")

@@ -182,16 +182,25 @@ def test_watchdog_hang_subprocess_kills_within_deadline(tmp_path):
 # ---------------------------------------------------------- compile monitor
 
 def test_compile_monitor_counts_shape_change_recompile():
+    """A retrace of a NAMED, registered function is a build in its record:
+    jax's own trace / lowering / backend events carry its name."""
     cm = CompileMonitor(storm_threshold=100)
-    f = cm.instrument(lambda x: x * 2 + 1, name="unit/f")
-    jf = jax.jit(f)
-    jf(jnp.zeros((4,)))
-    assert cm.retrace_count("unit/f") == 1
-    jf(jnp.ones((4,)))                 # cache hit: wrapper body skipped
-    assert cm.retrace_count("unit/f") == 1
-    jf(jnp.zeros((8,)))                # shape change → retrace
-    assert cm.retrace_count("unit/f") == 2
-    assert cm.summary()["functions"]["unit/f"] == 2
+    cm.install()
+
+    def unit_f(x):
+        return x * 2 + 1
+    jf = jax.jit(unit_f)
+    cm.register_program("unit_f", jf, (jnp.zeros((4,)),))
+    try:
+        jf(jnp.zeros((4,)))
+        assert cm.summary()["programs"]["unit_f"]["builds"] == 1
+        jf(jnp.ones((4,)))                 # cache hit: no event at all
+        assert cm.summary()["programs"]["unit_f"]["builds"] == 1
+        jf(jnp.zeros((8,)))                # shape change → retrace
+        record = cm.summary()["programs"]["unit_f"]
+        assert record["builds"] == 2 == len(record["recent"])
+    finally:
+        cm.uninstall()
 
 
 def test_compile_monitor_jax_monitoring_events():

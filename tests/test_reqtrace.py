@@ -576,24 +576,48 @@ def test_reqtrace_e2e_disagg_fleet_acceptance(devices, tmp_path,
     def prompts(base):
         return [[base + i, 2, 3, 4, 5, 6, 7, 8, 9] for i in range(2)]
 
-    # six slowed decode pumps a request: the router's pump pays the chaos
-    # delay once while the request is still in its prefill leg too, so at 4
-    # tokens decode led prefill by ONE delay (0.8 s against 0.4 s + the
-    # prefill's own time) and a loaded machine turned the order round
+    def worst_ms(reqs):
+        """The slower of time-to-first-token and time a token, as
+        ``Router._finish`` hands them to the tail sampler."""
+        return 1e3 * max(
+            max(r.first_token_ts - r.submit_ts,
+                (r.finish_ts - r.first_token_ts) / (len(r.tokens_out) - 1))
+            for r in reqs)
+
     new = 8
     reqtrace.clear()
     reqtrace.configure(enabled=False)
-    router = Router(_disagg_pool(devices), hedge=False,
-                    chaos_slow_s=0.4, http_port=0)
+    # every step program a batch can ask for, run once on padding rows as
+    # a benchmark cell's set-up does: which of them a request meets depends
+    # on the pump that picks it up (two requests submitted together prefill
+    # as one 2-row batch or as two 1-row batches), and a first-touch compile
+    # of half a second inside a "fast" request reads as a slow one
+    from benchmark.runners.serve import warm_program_grid
+    pool = _disagg_pool(devices)
+    for replica in pool:
+        warm_program_grid(replica.frontend.engine, replica.frontend.mode)
+    router = Router(pool, hedge=False, http_port=0)
     try:
-        # warm up every bucket both legs use, tracing off (first-touch
-        # compiles would read as slow requests)
+        # ... and what else both legs touch first (the handoff's page
+        # export and import), tracing off
         for p in prompts(20):
             router.submit(p, max_new_tokens=new)
         router.run_until_idle(wall_timeout_s=300.0)
 
+        # slow and fast are WALL time, and under the suite's six workers a
+        # fast request took over a fixed 400 ms: the threshold follows what
+        # a fast batch takes on this machine now (four times its worst),
+        # and the chaos delay the threshold. Six of a request's seven
+        # decode gaps pay the delay (the seventh may be the handoff's), so
+        # a slowed request's time a token is at least 6/7 x 2 thresholds,
+        # whatever the load
+        probe = [router.submit(p, max_new_tokens=new) for p in prompts(30)]
+        router.run_until_idle(wall_timeout_s=300.0)
+        retain_ms = max(400.0, 4.0 * worst_ms(probe))
+        router.chaos_slow_s = 2.0 * retain_ms / 1e3
+
         reqtrace.configure(enabled=True, head_sample=0.0,
-                           retain_slow_ms=400.0, buffer_traces=256)
+                           retain_slow_ms=retain_ms, buffer_traces=256)
         d0c = _counter("trace/dropped_ok")
         r0c = _counter("trace/retained")
         fast = [router.submit(p, max_new_tokens=new) for p in prompts(40)]

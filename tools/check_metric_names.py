@@ -49,7 +49,7 @@ _SEGMENT = re.compile(r"^(?:[a-z0-9_]+|\{\})$")
 KNOWN_AREAS = ("anomaly", "autoscale", "comm", "compile", "dispatch",
                "fleet", "goodput", "handoff", "health", "kvtier", "mem",
                "overlap", "resilience", "roofline", "router", "serving",
-               "slo", "trace", "train", "tune")
+               "setup", "slo", "trace", "train", "tune")
 
 #: span-emitting methods (Tracer / ReqTrace) linted by the span-catalog
 #: check below

@@ -57,6 +57,19 @@ calls the frontend's spans ``serving/cache_insert`` (inside
 the spans give them too —, each one's calls, total and longest in ms, and
 the longest ``serving/step`` beside them (``step_ms_max``).
 
+``build_record`` (PR 54), a line of its own before ``host_counters`` and in
+a training cell too, is what set-up built, read when the window OPENS:
+the ``setup/<part>_seconds`` and ``compile/*`` build counters, and
+``compile_monitor.summary()["programs"]`` a row a step program in the order
+they were built — builds, ``trace_s`` / ``lower_s`` / ``backend_s``, the
+newest build's ``cache`` and ``retrieval_s``, and ``gap_s``: from the
+previous build's end to this one's, less this one's three phases, i.e.
+what its first call cost that jax's three events do not cover (the rest of
+lowering and loading, the first run itself). ``covered_s`` is the three
+phases' sum over all of them, ``unnamed`` the rows of the builds under no
+step program's name (``other``; ``setup/<part>``: built inside that part
+of construction). Empty on a tree without the record.
+
     chiprun --chips 1 -- python3 tools/host_path_probe.py \
         --workload <cell> --seed <n> --trace <0|1>
 """
@@ -117,6 +130,37 @@ def counters():
     from deepspeed_tpu.telemetry.registry import registry
     return [registry.counter(n).value
             for n in NAMES + tuple("dispatch/" + w for w in WORK)]
+
+
+def build_record():
+    """The ``build_record`` line's fields (module docstring)."""
+    from deepspeed_tpu.telemetry import compile_monitor
+    from deepspeed_tpu.telemetry.registry import registry
+    programs = dict(compile_monitor.summary().get("programs", {}))
+    # `other` and `setup/<part>`: builds under no step program's name
+    unnamed = {n: programs.pop(n) for n in list(programs)
+               if "recent" not in programs[n]}
+    rows, last = {}, None
+    for name, row in sorted(programs.items(),
+                            key=lambda kv: kv[1]["recent"][-1]["at"]):
+        new = row["recent"][-1]
+        phases = sum(new[k] for k in ("trace_s", "lower_s", "backend_s"))
+        rows[name] = {
+            "builds": row["builds"],
+            **{k: round(row[k], 3) for k in ("trace_s", "lower_s",
+                                             "backend_s")},
+            "cache": new["cache"], "retrieval_s": round(new["retrieval_s"], 3),
+            "gap_s": None if last is None
+            else round(new["at"] - last - phases, 3)}
+        last = new["at"]
+    return {"phase": "build_record",
+            "counters": {n: registry.get(n).value for n in registry.names()
+                         if n.startswith("setup/") or (
+                             n.startswith("compile/") and n.endswith(
+                                 ("_seconds", "_built", "_hits", "_misses")))},
+            "covered_s": sum(r[k] for r in rows.values()
+                             for k in ("trace_s", "lower_s", "backend_s")),
+            "unnamed": unnamed, "programs": rows}
 
 
 def split_steps(launches):
@@ -216,6 +260,7 @@ def main() -> int:
     inserts = cache_calls.wrap(PrefixCache, "insert")
     evicts = cache_calls.wrap(PrefixCache, "evict")
     cache_open = []
+    built = []
 
     def cache_counters():
         return [getattr(cache_calls.last, n, None) for n in CACHE_COUNTERS]
@@ -223,6 +268,7 @@ def main() -> int:
     def opened(self):
         at_open[:] = counters()
         cache_open[:] = cache_counters()
+        built[:] = [build_record()]
         return open_window(self)
     bench_run.Context.open_window = opened
     traces = []
@@ -244,6 +290,8 @@ def main() -> int:
         line = split_by_rung(traces[-1])
         if line is not None:
             print(json.dumps(line), flush=True)
+    if built:
+        print(json.dumps(built[0]), flush=True)
     if at_open:
         host, wait, calls, ahead, dropped, *work = (
             b - a for a, b in zip(at_open, at_close or counters()))
