@@ -1057,6 +1057,23 @@ def _pools(arena: dict) -> dict:
     return {name: a for name, a in arena.items() if name != FED_TOKENS}
 
 
+class _Form(NamedTuple):
+    """What ONE launch of a chunk-width step program runs over, by the
+    program's own rules on the host (:meth:`RaggedInferenceEngineTPU.
+    _launch_form`): the program's rows and ladder, and of the instance the
+    batch takes the token slots, the rows of its chunk group and the row
+    slots its attention works on."""
+    nb: int
+    capacities: Tuple[int, ...]
+    slots: int
+    group_rows: int
+    attn_row_slots: int
+
+    @property
+    def grouped(self) -> bool:
+        return self.group_rows < self.nb
+
+
 class _Launch(NamedTuple):
     """A step program that was launched and not yet collected: its tokens
     (or logits) on the device, its sampling mode, and the rows whose pending
@@ -1844,9 +1861,12 @@ class RaggedInferenceEngineTPU:
         2,048 row slots. The top instance is the packed one with every row
         a chunk row, as in the 64-row program, so the chunk's K/V wait for
         the write-back in one layout at every instance. The smaller row
-        buckets of an engine keep the row form: each instance is a layer
-        loop more in every warm-up. :meth:`_run` applies the same rule to
-        count the slots."""
+        buckets of an engine keep the row form — each instance is a layer
+        loop more in every warm-up — and a split batch of few rows does
+        not stay with them where the full-row program's ladder serves it
+        better: :meth:`_pick_form` hands it to THAT program, which is built
+        anyway and picks its instance from what the batch holds.
+        :meth:`_launch_form` applies the same rule to count the slots."""
         top, rows = self.config.max_batch_tokens, nb * cb
         if cb == 1:
             return ()
@@ -1864,6 +1884,48 @@ class RaggedInferenceEngineTPU:
             if least <= slots < ladder[0]:
                 ladder = (slots,) + ladder
         return ladder
+
+    def _launch_form(self, nb: int, cb: int, fresh, tokens: int,
+                     chunk_rows: int) -> _Form:
+        """What the ``(nb, cb, fresh)`` program runs a batch of ``tokens``
+        and ``chunk_rows`` over — the device's own rules with ints: the
+        instance that holds the batch (:func:`_at_capacity`), or the row
+        form's ``nb x cb`` slots of a program without a ladder."""
+        capacities = self._token_capacities(nb, cb, fresh)
+        instances = _instances(capacities, nb, cb)
+        slots, group_rows = instances[_instance_index(
+            instances, tokens, chunk_rows)] if instances else (nb * cb, nb)
+        return _Form(nb, capacities, slots, group_rows,
+                     group_rows * cb + nb if group_rows < nb else nb * cb)
+
+    def _pick_form(self, nb: int, cb: int, fresh, tokens: int,
+                   chunk_rows: int) -> _Form:
+        """The program a batch of row bucket ``nb`` is packed for, as the
+        :class:`_Form` of its launch: its own bucket's — or, for a SPLIT
+        batch of fewer rows than the engine's full bucket, the FULL-ROW
+        split program where that one's ladder holds the batch on fewer
+        slots. The full-row program is what a loaded replica runs all day,
+        so it is built whatever the load; it picks its instance from
+        ``counts`` inside the program, and :meth:`_pack` pads a batch to
+        any row count with zero-count rows on the trash slot. So a replica
+        far under its ``max_sequences`` — 8 long prompts on a 64-sequence
+        engine: an 8 x 128 row form of 1,024 slots for about 440 tokens —
+        takes the 64-row program's ``(512, 4)`` instance with no program,
+        instance or key added. Lifted only if the full-row instance is a
+        GROUPED one (never its top, where every padding row becomes a
+        chunk row), has strictly fewer token slots than the own program's
+        launch and no more attention row slots; a tie stays — the full
+        bucket's own batches with it — and a fresh or a decode batch never
+        moves, since only a split program's ladder holds a grouped
+        instance. Reads what the step holds and what the engine was built
+        with, nothing of the model."""
+        own = self._launch_form(nb, cb, fresh, tokens, chunk_rows)
+        lifted = self._launch_form(_bucket(self.config.max_sequences), cb,
+                                   fresh, tokens, chunk_rows)
+        if lifted.grouped and lifted.slots < own.slots and \
+                lifted.attn_row_slots <= own.attn_row_slots:
+            return lifted
+        return own
 
     def _run(self, batch: RaggedBatch, mode=None):
         """One step program over ``batch``: pack and upload, launch, count
@@ -1888,7 +1950,9 @@ class RaggedInferenceEngineTPU:
             else:
                 fresh = "split"
             tokens = batch.total_tokens
-            capacities = self._token_capacities(nb, cb, fresh)
+            chunk_rows = int((batch.token_counts > 1).sum())
+            form = self._pick_form(nb, cb, fresh, tokens, chunk_rows)
+            lifted, nb, capacities = form.nb > nb, form.nb, form.capacities
             if capacities and tokens > capacities[-1]:
                 raise ValueError(
                     f"a batch of {tokens} tokens is over max_batch_tokens="
@@ -1904,14 +1968,9 @@ class RaggedInferenceEngineTPU:
                 self.params, self.arena, packed, self._rng_dev)
         with tracer.span("serving/count"):
             # the device's own rules: the instance that holds the batch
-            # (_at_capacity), and the write-back's whole blocks until the
+            # (_launch_form), and the write-back's whole blocks until the
             # tokens are written (_write_back)
-            chunk_rows = int((batch.token_counts > 1).sum())
-            instances = _instances(capacities, nb, cb)
-            capacity, group_rows = instances[_instance_index(
-                instances, tokens, chunk_rows)] if instances else (None, nb)
-            grouped = group_rows < nb
-            attn_row_slots = group_rows * cb + nb if grouped else nb * cb
+            grouped, attn_row_slots = form.grouped, form.attn_row_slots
             context_slots = query_tiles = None
             kv_pages = self._kv_page_work(batch, cb, grouped) \
                 if fresh != "fresh" else None
@@ -1931,9 +1990,9 @@ class RaggedInferenceEngineTPU:
                 # span arguments only: nothing to compute for no span
                 attn_pairs=self._attn_pairs(batch) if sp is not None
                 else None,
-                query_tiles=query_tiles, token_slots=capacity,
+                query_tiles=query_tiles, token_slots=form.slots,
                 kv_write_slots=-(-tokens // write_block) * write_block,
-                chunk_rows=chunk_rows,
+                chunk_rows=chunk_rows, lifted=lifted,
                 attn_row_slots=attn_row_slots if grouped else None,
                 state=self._state_work(batch, cb, grouped),
                 kv_pages=kv_pages, picked=self._picked_work(batch))
@@ -2114,7 +2173,7 @@ class RaggedInferenceEngineTPU:
                         kv_window=None, attn_pairs=None, query_tiles=None,
                         token_slots: Optional[int] = None,
                         kv_write_slots: Optional[int] = None,
-                        chunk_rows: int = 0,
+                        chunk_rows: int = 0, lifted: bool = False,
                         attn_row_slots: Optional[int] = None,
                         state=None, kv_pages=None,
                         picked=None) -> Dict[str, Any]:
@@ -2135,6 +2194,10 @@ class RaggedInferenceEngineTPU:
         ladder (:meth:`_token_capacities`; the row slots where it does not
         pack), which sum to ``dispatch/steps.split``;
         ``chunk_rows`` = the rows that hold more than one token;
+        ``lifted`` = a split launch that took the engine's full-row program
+        in place of its own row bucket's (:meth:`_pick_form`), which
+        ``dispatch/split_lifted_steps`` counts — ``rows_bucket`` is then
+        the program's rows;
         ``kv_write_slots`` = the updates the
         launch's KV scatter performs a pool and layer: the packed slots it
         wrote back, ``row_slots`` where it did not pack;
@@ -2195,6 +2258,7 @@ class RaggedInferenceEngineTPU:
                    (f"steps.{program}", 1)]
         if program == "split":
             counted.append((f"split_steps_at.{slots}", 1))
+            counted.append(("split_lifted_steps", 1 * lifted))
         for name, by in counted:
             registry.counter("dispatch/" + name).inc(by)
         work = {"program": program, "rows": rows, "rows_bucket": nb,

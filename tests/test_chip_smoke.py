@@ -46,9 +46,12 @@ def test_rehearsal_reports_cpu():
     assert not [p for p in phases if "megastep" in str(p)]
     assert phases["server/serve"]["compiles_after_warmup"] == 0
     assert phases["server/serve"]["tokens"] == 109
+    # (six requests on the default 64-sequence engine: their split batches
+    # of one or two chunk rows are packed for the FULL-row program, whose
+    # ladder holds them on a quarter of the 8-row form's slots)
     assert set(phases["server/programs"]["programs"]) == {
         "step n=8 c=1 decode", "step n=8 c=256 fresh",
-        "step n=8 c=256 split"}
+        "step n=64 c=256 split"}
     tr = phases["trainer"]
     assert tr["compiles_after_warmup"] == 0
     assert tr["fused_step_retraces_after_warmup"] == 0

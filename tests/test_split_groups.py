@@ -15,6 +15,7 @@ from deepspeed_tpu.inference import engine_v2
 from deepspeed_tpu.inference.engine_v2 import (RaggedInferenceEngineTPU,
                                                ragged_forward)
 from deepspeed_tpu.ops import paged_attention as pa
+from deepspeed_tpu.ops import ssm
 from deepspeed_tpu.parallel.mesh import build_mesh
 from tests.test_paged import _packed_stack
 
@@ -70,7 +71,10 @@ def _assert_steps_agree(got, want, arena, counts, nb, tol):
                                rtol=tol, atol=tol)
     assert set(got) == set(want)
     for name in want:
-        kept = np.arange(want[name].shape[0]) % (nb + 1) != nb
+        rows = np.arange(want[name].shape[0])
+        # (a state pool's trash is its last slot: a padding row's)
+        kept = rows < rows[-1] if ssm.is_state_pool(name) else \
+            rows % (nb + 1) != nb
         a, b, before = (np.asarray(x[name], np.float32)[kept]
                         for x in (got, want, arena))
         np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
@@ -234,6 +238,80 @@ def test_every_program_of_an_engines_grid_holds_its_ladder(sequences, rows,
     assert got == _GRIDS[sequences].get(kind, {}).get(rows, ())
 
 
+def _rules_of(top, sequences):
+    """The engine's host rules for a launch (``_token_capacities``,
+    ``_launch_form``, ``_pick_form``) over what they read of an engine: an
+    engine never built, its ``config`` alone."""
+    rules = object.__new__(RaggedInferenceEngineTPU)
+    rules.config = _engine_of(top, sequences).config
+    return rules
+
+
+#: case -> ((live rows, rows of more than one token, tokens, kind,
+#: ``max_sequences``), (the program's rows, its instance's token slots and
+#: chunk group)) at chunk 128 under a budget of 2,048
+_PICKS = {
+    # ISSUE 55's: a 64-sequence engine at an eighth and a quarter of its load
+    "8_rows_3_chunks": ((8, 3, 389, "split", 64), (64, 512, 4)),
+    "8_rows_4_chunks_fill_the_rung": ((8, 4, 512, "split", 64), (64, 512, 4)),
+    "8_rows_4_chunks_and_4_ones_tie": ((8, 4, 516, "split", 64), (8, 1024, 8)),
+    "8_rows_5_chunk_rows_tie": ((8, 5, 400, "split", 64), (8, 1024, 8)),
+    "16_rows_6_chunks": ((16, 6, 700, "split", 64), (64, 1024, 8)),
+    "16_rows_3_chunks": ((16, 3, 397, "split", 64), (64, 512, 4)),
+    "16_rows_9_chunks_never_the_top": ((16, 9, 900, "split", 64),
+                                       (16, 2048, 16)),
+    "16_rows_8_chunks_over_1024": ((16, 8, 1032, "split", 64),
+                                   (16, 2048, 16)),
+    "32_rows_8_chunks": ((32, 8, 1000, "split", 64), (64, 1024, 8)),
+    "32_rows_9_chunks": ((32, 9, 1000, "split", 64), (32, 2048, 32)),
+    "between_buckets_5_rows": ((5, 2, 259, "split", 64), (64, 512, 4)),
+    "4_rows_tie_at_512": ((4, 2, 258, "split", 64), (4, 512, 4)),
+    "2_rows": ((2, 1, 129, "split", 64), (2, 256, 2)),
+    "the_full_bucket": ((64, 3, 445, "split", 64), (64, 512, 4)),
+    "over_half_the_full_bucket": ((33, 3, 414, "split", 64), (64, 512, 4)),
+    "fresh_has_no_ladder": ((8, 3, 384, "fresh", 64), (8, 1024, 8)),
+    "decode": ((8, 0, 8, False, 64), (8, 8, 8)),
+    # a 16-sequence engine's full-row program (PR 50) takes its 8-row batches
+    "16_sequences_8_rows_3_chunks": ((8, 3, 389, "split", 16), (16, 512, 4)),
+    "16_sequences_8_rows_5_chunks_tie": ((8, 5, 600, "split", 16),
+                                         (8, 1024, 8)),
+    "16_sequences_4_rows_tie": ((4, 2, 258, "split", 16), (4, 512, 4)),
+    "16_sequences_full": ((16, 6, 700, "split", 16), (16, 1024, 8)),
+    # an 8-sequence engine: (512, 1024), and its 4-row batch ties at 512
+    "8_sequences_4_rows_tie": ((4, 2, 258, "split", 8), (4, 512, 4)),
+    "8_sequences_full": ((8, 3, 389, "split", 8), (8, 512, 4)),
+    "4_sequences_no_ladder": ((2, 1, 129, "split", 4), (2, 256, 2)),
+}
+
+
+@pytest.mark.parametrize("case", list(_PICKS))
+def test_a_small_split_batch_takes_the_full_row_program_only_for_fewer_slots(
+        case):
+    """``_pick_form`` from the batch's rows, tokens and chunk rows and the
+    engine's chunk, budget and row count ALONE: a split batch under the
+    engine's full bucket takes the full-row program iff the instance that
+    program picks for it is a GROUPED one with strictly fewer token slots
+    than the batch's own program would run and no more attention row slots
+    — a tie stays, the top instance is never taken, the full bucket and
+    every fresh or decode batch are as they were."""
+    (rows, chunk_rows, tokens, kind, sequences), want = _PICKS[case]
+    rules = _rules_of(2048, sequences)
+    nb, cb = engine_v2._bucket(rows), 128 if kind else 1
+    own = rules._launch_form(nb, cb, kind, tokens, chunk_rows)
+    form = rules._pick_form(nb, cb, kind, tokens, chunk_rows)
+    assert (form.nb, form.slots, form.group_rows) == want
+    assert form.capacities == rules._token_capacities(form.nb, cb, kind)
+    if form.nb == nb:
+        assert form == own
+    else:
+        assert kind == "split" and \
+            form.nb == engine_v2._bucket(sequences) > nb
+        assert form.grouped and form.slots < own.slots and \
+            form.attn_row_slots == form.group_rows * cb + form.nb <= \
+            own.attn_row_slots
+    assert tokens <= form.slots and chunk_rows <= form.group_rows
+
+
 @functools.lru_cache(maxsize=None)
 def _stack_steps(stack, dtype):
     """(cfg, params, arena maker, history writer, step(capacities)) of a
@@ -256,10 +334,13 @@ def _stack_steps(stack, dtype):
         **kw)[1])
 
     @functools.lru_cache(maxsize=None)
-    def step(capacities):
+    def step(capacities, slots=None):
+        """(``slots``: the rows' state slots where they are not 0..N-1.)"""
+        kws = {"slots": jnp.asarray(slots, jnp.int32)} if kw and slots \
+            else kw
         return jax.jit(lambda arena, *a: ragged_forward(
             cfg, params, arena, *a, fresh_prefill="split",
-            token_capacities=capacities, **kw))
+            token_capacities=capacities, **kws))
     return cfg, make_arena, history, step
 
 
@@ -317,6 +398,82 @@ def test_three_rung_program_matches_the_row_form(devices, stack, mix):
             jnp.asarray(pt))
     _assert_steps_agree(step(LADDER)(arena, *args), step(())(arena, *args),
                         arena, counts, nb, 2e-4)
+
+
+# -- a LIFTED batch (PR 55): few rows, padded to the rows of a program that
+# holds a ladder, against the same rows in their own row-form program
+
+def _assert_a_lifted_batch_agrees(lifted, own, arena, pt, launches, shape,
+                                  tokens, nb, tol):
+    """``launches`` — ``(tokens a row feeds, tokens it has cached)`` of the
+    ``len(pt)`` live rows, one launch after the other — through ``lifted``
+    (a program of ``shape = (rows, chunk)``: the batch as ``_pack`` pads
+    it, zero-count rows on the trash page) and through ``own`` (the live
+    rows alone): the live rows' logits and every pool outside the trash
+    agree at EVERY launch, each side on the arena its own previous launch
+    left — so what a lifted launch wrote is what the next one reads."""
+    n, (rows, c) = len(pt), shape
+
+    def padded(a, fill):
+        return jnp.asarray(np.concatenate([a, np.full(
+            (rows - n,) + a.shape[1:], fill, a.dtype)]))
+    arenas = [arena, arena]
+    for counts, starts in launches:
+        counts, starts = (np.asarray(a, np.int32) for a in (counts, starts))
+        toks = np.asarray(tokens(n, c))
+        logits, got = lifted(arenas[0], padded(toks, 0), padded(counts, 0),
+                             padded(starts, 0), padded(pt, nb))
+        want = own(arenas[1], jnp.asarray(toks), jnp.asarray(counts),
+                   jnp.asarray(starts), jnp.asarray(pt))
+        _assert_steps_agree((logits[:n], got), want, arenas[1], counts, nb,
+                            tol)
+        arenas = [got, want[1]]
+
+
+#: mix -> (the launches of FOUR live rows, the rung of the 8 x 16 program's
+#: ladder the FIRST takes): a first launch at the low and at the middle rung,
+#: then the same rows again — a chunk that goes on, rows of one token over
+#: what the first launch wrote
+LIFTED_MIXES = {
+    "one_chunk_row_then_its_next_chunk": (
+        [([9, 1, 1, 1], [16, 7, 41, 12]), ([7, 1, 1, 1], [25, 8, 42, 13])],
+        0),
+    "two_chunk_rows_then_one_token_rows": (
+        [([16, 1, 9, 1], [16, 7, 41, 12]), ([1, 1, 2, 1], [32, 8, 50, 13])],
+        1),
+}
+
+
+@pytest.mark.parametrize("mix", list(LIFTED_MIXES))
+@pytest.mark.parametrize("stack", ["uniform", "typed", "latent", "recurrent"])
+def test_three_rung_program_matches_the_row_form_of_a_lifted_batch(
+        devices, stack, mix):
+    """A batch of FOUR rows handed to the 8 x 16 program that holds the
+    three-rung ladder — padded as ``_pack`` pads it: zero-count rows whose
+    pages and state slot are the trash — against the same four rows in
+    their own 4 x 16 row-form program, which ``_pick_form`` would have
+    taken them from; two launches, the second over the pages and the state
+    slots the first wrote. Four stacks: the recurrent one's one-token pass
+    then runs over the padding rows' trash slot."""
+    cfg, make_arena, history, step = _stack_steps(stack, "float32")
+    launches, rung = LIFTED_MIXES[mix]
+    n = len(launches[0][0])
+    counts, starts = (np.asarray(a, np.int32) for a in launches[0])
+    assert engine_v2._instance_index(
+        engine_v2._instances(LADDER, N, C), int(counts.sum()),
+        int((counts > 1).sum())) == rung
+    rng = np.random.default_rng(len(mix))
+    held = np.asarray(launches[-1][1]) + np.asarray(launches[-1][0])
+    pt, nb = _pages(np.zeros(N, np.int32), np.pad(held, (0, N - n)), rng)
+    toks = lambda *shape: jnp.asarray(
+        rng.integers(0, cfg.vocab_size, shape), jnp.int32)
+    arena = history(make_arena(nb, BS), toks(N, 48),
+                    jnp.asarray(np.pad(starts, (0, N - n))), jnp.asarray(pt))
+    trash = N       # of the state pools' N + 1 slots
+    _assert_a_lifted_batch_agrees(
+        step(LADDER, tuple(range(n)) + (trash,) * (N - n)),
+        step((), tuple(range(n))), arena, pt[:n], launches, (N, C), toks,
+        nb, 2e-4)
 
 
 # -- the FULL-ROW program of a 16-sequence engine (PR 50): 16 rows of chunk
@@ -417,6 +574,52 @@ def test_full_row_ladder_matches_the_row_form(devices, mix):
     with jax.default_matmul_precision("highest"):
         _assert_steps_agree(step(LADDER16)(arena, *args),
                             step(())(arena, *args), arena, counts, nb, 2e-4)
+
+
+#: mix -> (the launches of EIGHT live rows, the rung of the 16 x 128
+#: program's ladder the first takes): what a 16-sequence engine at half its
+#: load hands ``_pick_form`` — the 8 x 128 row form's 1,024 slots for 389
+#: tokens, or a tie at 1,024 that the rule leaves alone but the program must
+#: still serve. Histories on both sides of the window of 40
+_LIFTED16 = {
+    "three_chunks_beside_five_rows": (
+        [([128, 128, 128, 1, 1, 1, 1, 1], [30, 0, 45, 100, 7, 41, 64, 9]),
+         ([128, 72, 1, 1, 1, 1, 1, 1], [158, 128, 173, 101, 8, 42, 65, 10])],
+        0),
+    "six_chunk_rows": (
+        [([128, 90, 128, 40, 2, 128, 1, 1], [30, 0, 45, 100, 7, 41, 64, 9]),
+         ([1, 1, 128, 1, 1, 17, 1, 1], [158, 90, 173, 140, 9, 169, 65, 10])],
+        1),
+}
+
+
+@pytest.mark.parametrize("mix", list(_LIFTED16))
+def test_full_row_ladder_matches_the_row_form_of_a_lifted_batch(devices, mix):
+    """EIGHT rows handed to the 16 x 128 program a 16-sequence engine holds
+    (padded as ``_pack`` pads them) against the same rows in the 8 x 128
+    row-form program of their own bucket, two launches: the windowed
+    history call of a chunk group, a parallel block, 16 queries a KV
+    head."""
+    cfg, make_arena, history, step = _steps16()
+    launches, rung = _LIFTED16[mix]
+    n = len(launches[0][0])
+    counts, starts = (np.asarray(a, np.int32) for a in launches[0])
+    assert engine_v2._instance_index(
+        engine_v2._instances(LADDER16, N16, C16), int(counts.sum()),
+        int((counts > 1).sum())) == rung
+    rng = np.random.default_rng(len(mix))
+    held = np.asarray(launches[-1][1]) + np.asarray(launches[-1][0])
+    pt, nb = _pages(np.zeros(N16, np.int32), np.pad(held, (0, N16 - n)), rng,
+                    N16 * MB16, 2 * MB16, BS16)
+    toks = lambda *shape: jnp.asarray(
+        rng.integers(0, cfg.vocab_size, shape), jnp.int32)
+    arena = history(make_arena(nb), toks(N16, 104),
+                    jnp.asarray(np.pad(starts, (0, N16 - n))),
+                    jnp.asarray(pt))
+    with jax.default_matmul_precision("highest"):
+        _assert_a_lifted_batch_agrees(
+            step(LADDER16), step(()), arena, pt[:n], launches, (N16, C16),
+            toks, nb, 2e-4)
 
 
 #: layer kind -> (query heads, kv heads, K width, V width, window, sink,
@@ -619,7 +822,7 @@ def test_rung_counters_sum_to_the_split_launches(devices):
     assert ladder == (128, 256, 320)
 
     def read():
-        names = ["steps.split", "token_slots"] + [
+        names = ["steps.split", "token_slots", "split_lifted_steps"] + [
             f"split_steps_at.{slots}" for slots in ladder]
         return np.asarray([registry.counter("dispatch/" + n).value
                            for n in names])
@@ -641,6 +844,91 @@ def test_rung_counters_sum_to_the_split_launches(devices):
         out = eng.step_with_budget(budget=320)
         if eng.last_program == "split":
             split_slots += (read() - before)[1]
-    launches, _slots, *at = read() - start
+    launches, _slots, lifted, *at = read() - start
     assert launches >= 6 and sum(at) == launches and all(at), at
     assert split_slots == sum(slots * n for slots, n in zip(ladder, at))
+    # the first launches hold 6-8 rows: they take the 16-row program's low
+    # rungs (the 8-row program packs into 320); later ones are full-bucket
+    assert 0 < lifted < launches
+
+
+#: launch -> (decode rows, prompts that arrive beside them, is the FIRST
+#: launch lifted — the later ones hold fewer chunk rows as prompts end):
+#: a 16-sequence engine (16 x 96 over a budget of 320: instances of 128
+#: slots with 1 chunk row, 256 with 2, 320 with 16) under half its load —
+#: the 8-row program packs into 320 slots with every row a chunk row
+_LIFTED_LAUNCHES = {"one_chunk_row": (5, (200,), True),
+                    "two_chunk_rows": (5, (150, 230), True),
+                    "three_chunk_rows_stay": (4, (150, 230, 120), False)}
+
+
+@pytest.mark.parametrize("launch", list(_LIFTED_LAUNCHES))
+def test_engine_lifts_a_small_split_batch_and_counts_its_program(devices,
+                                                                 launch):
+    """An engine at half its rows: its split launches of at most 8 rows take
+    the 16-row program's grouped instance where that holds fewer slots
+    (``dispatch/split_lifted_steps``; the ``serving/dispatch`` accounting —
+    ``token_slots``, ``attn_row_slots``, ``split_steps_at.<slots>``,
+    ``kv_write_slots`` — is the program's), over prompts of several chunks
+    so that each launch reads the pages the one before wrote; the tokens
+    are those of an engine that keeps every batch in its own bucket's
+    program."""
+    from deepspeed_tpu.telemetry.registry import registry
+    build_mesh(data=1, devices=jax.devices()[:1])
+    cfg, params, _ = _packed_stack("uniform")
+    decoding, arrivals, lifts = _LIFTED_LAUNCHES[launch]
+    rng = np.random.default_rng(5)
+    first = [rng.integers(0, cfg.vocab_size, 3).tolist()
+             for _ in range(decoding)]
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in arrivals]
+    names = ("steps.split", "split_lifted_steps", "split_grouped_steps",
+             "tokens", "chunk_rows", "token_slots", "attn_row_slots", "kv_write_slots",
+             "split_steps_at.128", "split_steps_at.256",
+             "split_steps_at.320")
+
+    def serve(own_rows):
+        eng = RaggedInferenceEngineTPU(
+            cfg, dict(_ENGINE, max_batch_tokens=320, max_seq_len=256,
+                      num_blocks=128),
+            params=params)
+        if own_rows:
+            eng._pick_form = eng._launch_form
+        uids = list(range(decoding))
+        eng.scheduler.put(uids, first)
+        out = eng.step_with_budget(budget=320)
+        eng.scheduler.put([decoding + i for i in range(len(prompts))],
+                          prompts)
+        tokens, grew = [], []
+        for _ in range(3):      # the prompts' second chunks and on
+            eng.scheduler.put(list(out), [[int(t)] for t in out.values()])
+            before = [registry.counter("dispatch/" + n).value for n in names]
+            out = eng.step_with_budget(budget=320)
+            assert eng.last_program == "split"
+            tokens.append({u: int(t) for u, t in out.items()})
+            grew.append({n: registry.counter("dispatch/" + n).value - b
+                         for n, b in zip(names, before)})
+        return tokens, grew
+
+    got, grew = serve(False)
+    want, own = serve(True)
+    assert got == want and all(len(t) >= decoding for t in got)
+    assert grew[0]["split_lifted_steps"] == lifts
+    for launched, kept in zip(grew, own):
+        slots, group = _ENGINE_INSTANCES[engine_v2._instance_index(
+            _ENGINE_INSTANCES, launched["tokens"], launched["chunk_rows"])]
+        lifts = group < 16      # a grouped instance of the 16-row program
+        assert kept["split_lifted_steps"] == 0 and \
+            kept["token_slots"] == kept["split_steps_at.320"] * 320 == 320
+        assert launched["steps.split"] == 1
+        if not lifts:
+            assert launched == kept
+            continue
+        assert launched["split_lifted_steps"] == 1 == \
+            launched["split_grouped_steps"] == \
+            launched[f"split_steps_at.{slots}"]
+        assert launched["token_slots"] == slots < kept["token_slots"]
+        assert launched["attn_row_slots"] == group * 96 + 16 <= \
+            kept["attn_row_slots"] == 8 * 96
+        # the write-back's whole blocks of the lowest rung
+        assert launched["kv_write_slots"] % 128 == 0 < \
+            launched["kv_write_slots"] <= slots

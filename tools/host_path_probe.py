@@ -70,9 +70,26 @@ phases' sum over all of them, ``unnamed`` the rows of the builds under no
 step program's name (``other``; ``setup/<part>``: built inside that part
 of construction). Empty on a tree without the record.
 
+Arguments of its own (PR 55), taken off before the harness reads the rest:
+``--clients N`` runs the cell's traffic mix with ``N`` closed-loop clients
+for the file's count (a replica under its ``max_sequences``: the mix's
+lengths, cycle and ramp as committed, no file under ``benchmark/``
+touched), ``--traffic NAME`` hands the cell's configuration another
+committed mix, ``--trace-seconds S`` sets how long the traced part of the
+window is, and ``--own-rows`` packs every split batch for its OWN row
+bucket's program, as before ``engine_v2._pick_form`` — the control a lifted
+launch's device time is read against, at the same seed and clients.
+``split_by_rung`` keys a launch by program AND slots
+(``serve_split_r8_c128@1024``, ``serve_split_r64_c128@512``; a program
+with no packed shape, the row form, by its rows x chunk), so a lifted
+launch stands beside the one it replaced in ONE trace where a window holds
+both; ``split_steps.by_program`` counts the window's launches the same way.
+
     chiprun --chips 1 -- python3 tools/host_path_probe.py \
-        --workload <cell> --seed <n> --trace <0|1>
+        --workload <cell> --seed <n> --trace <0|1> [--clients N] \
+        [--traffic NAME] [--trace-seconds S] [--own-rows]
 """
+import argparse
 import json
 import os
 import sys
@@ -88,7 +105,7 @@ NAMES = ("dispatch/host_seconds", "dispatch/fetch_wait_seconds",
          "dispatch/ahead_rows_dropped")
 WORK = ("steps.split", "split_grouped_steps", "chunk_rows",
         "attn_row_slots", "tokens", "token_slots", "kv_pages_walked",
-        "kv_page_fetches")
+        "kv_page_fetches", "split_lifted_steps")
 #: (slots, chunk rows) of the instances ``split_steps.fits`` asks about
 RUNGS = ((256, 2), (256, 8), (512, 4), (512, 8), (1024, 8))
 CACHE_COUNTERS = ("evict_calls", "evict_scans", "pages_evicted")
@@ -164,34 +181,44 @@ def build_record():
 
 
 def split_steps(launches):
-    """The ``split_steps`` field from ``(tokens, chunk rows, slots)`` of
-    each split launch of the window."""
+    """The ``split_steps`` field from ``(tokens, chunk rows, slots, the
+    program's rows)`` of each split launch of the window."""
     from collections import Counter
     if not launches:
         return {"launches": 0}
     n = len(launches)
-    hist = Counter((tokens // 64 * 64, rows) for tokens, rows, _ in launches)
+    hist = Counter((tokens // 64 * 64, rows) for tokens, rows, *_ in launches)
     return {"launches": n,
-            "by_slots": dict(Counter(str(s) for _, _, s in launches)),
-            "tokens_mean": sum(t for t, _, _ in launches) / n,
-            "chunk_rows_mean": sum(r for _, r, _ in launches) / n,
+            "by_slots": dict(Counter(str(s) for _, _, s, _ in launches)),
+            "by_program": dict(Counter(f"r{nb}@{s}"
+                                       for _, _, s, nb in launches)),
+            "tokens_mean": sum(t for t, *_ in launches) / n,
+            "chunk_rows_mean": sum(r for _, r, *_ in launches) / n,
             "fits": {f"{cap}x{group}": 100.0 * sum(
-                t <= cap and r <= group for t, r, _ in launches) / n
+                t <= cap and r <= group for t, r, *_ in launches) / n
                 for cap, group in RUNGS},
             "hist": {f"{tokens}x{rows}": hist[tokens, rows]
                      for tokens, rows in sorted(hist)}}
 
 
-def split_by_rung(trace, capacities=(256, 512, 1024, 2048)):
+def split_by_rung(trace, ladder_of=None,
+                  capacities=(256, 512, 1024, 2048)):
     """The ``split_by_rung`` line from a loaded trace (``reduce.load``'s
     form): every ``serve_split_*`` launch of device 0, put down to the
     capacity whose packed shapes (``[1, capacity, ...]`` or ``[capacity,
     ...]`` results) take most of its time, with its operations' self times
-    by the program's scope table. None without such a launch."""
+    by the program's scope table — under ``<program>@<slots>``.
+    ``ladder_of(rows, chunk)``: the token capacities of that split program
+    (the engine's ``_token_capacities``), so that a shape counts only where
+    it is a rung of the program that ran (an 8 x 128 row form holds
+    ``[256, ...]`` results: 8 rows x 32 heads); a program without a ladder,
+    or a launch with no such shape, ran the row form, its program's rows x
+    chunk. None without such a launch."""
     import bisect
     import re
     from benchmark.trace import reduce, scopes
     shape = re.compile(r"^\(?\w+\[(?:1,)?(\d+)[,\]]")
+    row_form = re.compile(r"_r(\d+)_c(\d+)")
     for i, plane in reduce.device_planes(trace):
         if i != 0:
             continue
@@ -201,6 +228,13 @@ def split_by_rung(trace, capacities=(256, 512, 1024, 2048)):
         if not mods:
             return None
         tables = scopes.program_tables(sorted({m[2] for m in mods}))
+        ladders = {}
+
+        def rungs_of(program):
+            if program not in ladders:
+                ladders[program] = capacities if ladder_of is None else \
+                    ladder_of(*map(int, row_form.search(program).groups()))
+            return ladders[program]
         starts = [m[0] for m in mods]
         per = [({}, {}) for _ in mods]      # (by scope, by capacity) ns
         for ev, self_ns in reduce.self_times(
@@ -212,12 +246,16 @@ def split_by_rung(trace, capacities=(256, 512, 1024, 2048)):
             scope = entry.get("scope") or scopes.NO_SCOPE
             per[k][0][scope] = per[k][0].get(scope, 0.0) + self_ns
             m = shape.match(ev[0].split(" = ", 1)[-1])
-            if m and int(m.group(1)) in capacities:
+            if m and int(m.group(1)) in rungs_of(mods[k][2]):
                 cap = int(m.group(1))
                 per[k][1][cap] = per[k][1].get(cap, 0.0) + self_ns
         rungs = {}
         for (_t0, _t1, program), (by_scope, by_cap) in zip(mods, per):
-            cap = max(by_cap, key=by_cap.get) if by_cap else 0
+            if by_cap:
+                cap = max(by_cap, key=by_cap.get)
+            else:
+                rows, chunk = map(int, row_form.search(program).groups())
+                cap = rows * chunk
             rung = rungs.setdefault(f"{program}@{cap}", [0, 0.0, {}])
             rung[0] += 1
             rung[1] += sum(by_scope.values())
@@ -232,11 +270,42 @@ def split_by_rung(trace, capacities=(256, 512, 1024, 2048)):
     return None
 
 
+def own_arguments():
+    """The probe's own arguments (module docstring), taken off
+    ``sys.argv`` and applied: the mix loader and the engine's choice of
+    program are replaced in this process alone."""
+    ap = argparse.ArgumentParser(add_help=False, allow_abbrev=False)
+    ap.add_argument("--clients", type=int, default=None)
+    ap.add_argument("--traffic", default=None)
+    ap.add_argument("--trace-seconds", type=float, default=None)
+    ap.add_argument("--own-rows", action="store_true")
+    own, sys.argv[1:] = ap.parse_known_args()
+    if (own.clients, own.traffic, own.trace_seconds) != (None,) * 3:
+        from benchmark.lib import traffic
+        load_mix = traffic.load_mix
+
+        def with_overrides(name):
+            mix = load_mix(own.traffic or name)
+            if own.clients is not None:
+                mix["arrival"] = dict(mix["arrival"], clients=own.clients)
+            if own.trace_seconds is not None:
+                mix["trace_seconds"] = own.trace_seconds
+            return mix
+        traffic.load_mix = with_overrides
+    if own.own_rows:
+        from deepspeed_tpu.inference.engine_v2 import \
+            RaggedInferenceEngineTPU as engine
+        if hasattr(engine, "_pick_form"):    # a tree before it: as is
+            engine._pick_form = engine._launch_form
+    return own
+
+
 def main() -> int:
+    own = own_arguments()
     at_open = []
     open_window = bench_run.Context.open_window
     from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
-    launches = []
+    launches, engines = [], []
     count = RaggedInferenceEngineTPU._count_dispatch
 
     def in_window():
@@ -244,9 +313,10 @@ def main() -> int:
 
     def counted(self, program, rows, nb, chunk, page_width, tokens,
                 *args, **kwargs):
+        engines[:] = [self]
         if program == "split" and in_window():
             launches.append((tokens, kwargs.get("chunk_rows", 0),
-                             kwargs.get("token_slots") or nb * chunk))
+                             kwargs.get("token_slots") or nb * chunk, nb))
         return count(self, program, rows, nb, chunk, page_width, tokens,
                      *args, **kwargs)
     RaggedInferenceEngineTPU._count_dispatch = counted
@@ -287,7 +357,11 @@ def main() -> int:
     ServingFrontend.terminate_inflight = closed
     rc = bench_run.main()
     if traces and traces[-1] is not None:
-        line = split_by_rung(traces[-1])
+        engine = engines[-1] if engines else None
+        line = split_by_rung(
+            traces[-1], engine and (lambda rows, chunk:
+                                    engine._token_capacities(rows, chunk,
+                                                             "split")))
         if line is not None:
             print(json.dumps(line), flush=True)
     if built:
@@ -296,7 +370,9 @@ def main() -> int:
         host, wait, calls, ahead, dropped, *work = (
             b - a for a, b in zip(at_open, at_close or counters()))
         print(json.dumps({
-            "phase": "host_counters", "launches": int(calls),
+            "phase": "host_counters", "clients": own.clients,
+            "traffic": own.traffic, "own_rows": own.own_rows,
+            "launches": int(calls),
             "host_s": host, "fetch_wait_s": wait,
             "host_ms_per_launch": 1e3 * host / max(1, calls),
             "fetch_wait_ms_per_launch": 1e3 * wait / max(1, calls),
