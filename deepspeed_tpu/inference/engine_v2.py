@@ -649,7 +649,9 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     it joins the dense partials. With every context at most ``index_topk``
     every key is picked and the result is the dense stack's.
 
-    A STATE-SPACE layer (kinds 3, 4) has no pages: what its rows carry lives in
+    A STATE-SPACE layer (kinds 3, 4; a gated short convolution, kind 5, is
+    one whose rows carry their convolution's tail and NO state: its scan
+    steps below fall away) has no pages: what its rows carry lives in
     the state pools (``ops/ssm.init_state_pools``), a slot a sequence
     (``slots``), read and written by every launch that holds the row, in
     every mode; a row at position 0 starts from zero whatever its slot
@@ -896,25 +898,30 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     def state_space(lay, kind, p, place, h_in, pools):
         """A state-space layer's mixer (``tl.mixer_forms`` of its ``kind``)
         on its normed input (token-wise form) → its output in the same
-        form; ``pools`` holds the layer's two state pools (``place``: their names), which it reads and
-        writes. Rows of ONE query first, in SLOT order: the layer's whole
-        pool takes one elementwise pass (a slot with no live row has ``Δ =
-        0`` and keeps its state; a wide row's is reset or left as it is),
-        the rows' inputs scattered to their slots and their outputs
-        gathered back, both small. Then the rows of the chunk's width:
-        their states gathered, the chunk form, the results scattered (a
-        row riding along with no live query in the chunk group writes
-        nothing)."""
+        form; ``pools`` holds the layer's two state pools (``place``: their
+        names; a mixer with no scan has the convolution's alone), which it
+        reads and writes. Rows of ONE query first, in SLOT order: the
+        layer's whole pool takes one elementwise pass (a slot with no live
+        row has ``Δ = 0`` and keeps its state; a wide row's is reset or
+        left as it is), the rows' inputs scattered to their slots and their
+        outputs gathered back, both small. Then the rows of the chunk's
+        width: their states gathered, the chunk form, the results
+        scattered (a row riding along with no live query in the chunk
+        group writes nothing)."""
         sname, cname = place
-        region = pools[sname].shape[0]
-        with jax.named_scope("ssm_state"):
+        forms = tl.mixer_forms(kind, use_pallas)
+        scans = forms.step is not None
+        held = (sname, cname) if scans else (cname,)
+        state_scope, conv_scope, scan_scope = forms.scopes
+        region = pools[cname].shape[0]
+        with jax.named_scope(state_scope):
             # a layer's pools are read at ITS turn: free of the stream, the
             # compiler gathers every layer's rows at the program's start,
             # side by side (64 chunk rows x 9 layers of 4 MiB states: 2.3
             # GB of temporaries at Granite 4.0-H's widths)
-            pools[sname], pools[cname], h_in = lax.optimization_barrier(
-                (pools[sname], pools[cname], h_in))
-        forms = tl.mixer_forms(kind, use_pallas)
+            *now, h_in = lax.optimization_barrier(
+                (*(pools[name] for name in held), h_in))
+            pools.update(zip(held, now))
         z, xbc, dt = forms.project(cfg, p, h_in)
         fresh_row = ssm.fresh_rows(starts)
         groups = lay.groups()
@@ -922,31 +929,38 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         for i, group in sorted(enumerate(groups), key=lambda g: g[1].c):
             at, reset = group.of(slots), group.of(fresh_row)
             live = group.counts
-            with jax.named_scope("ssm_state"):
+            with jax.named_scope(state_scope):
                 tail = ssm.tail_rows(cfg, ssm.carried(pools[cname][at],
                                                       reset))
-                if group.c > 1:
+                if scans and group.c > 1:
                     state = ssm.carried(pools[sname][at], reset)
-            with jax.named_scope("ssm_conv"):
+            with jax.named_scope(conv_scope):
                 u, tail = ssm.conv_rows(cfg, p, group.take(xbc), tail, live,
                                         forms.conv_dtype)
                 dt_g = jax.tree.map(group.take, dt)
             dt_g = forms.inputs(cfg, p, u, dt_g, live)
             if group.c > 1:
-                with jax.named_scope("ssm_scan"):
-                    outs[i], state = forms.chunk(cfg, p, u, dt_g, state,
-                                                 live)
-                with jax.named_scope("ssm_state"):
+                if scans:
+                    with jax.named_scope(scan_scope):
+                        outs[i], state = forms.chunk(cfg, p, u, dt_g, state,
+                                                     live)
+                else:
+                    outs[i] = u
+                with jax.named_scope(state_scope):
                     to = at if group.ids is None else jnp.where(
                         live > 0, at, region)
-                    pools[sname] = pools[sname].at[to].set(state,
-                                                           mode="drop")
+                    if scans:
+                        pools[sname] = pools[sname].at[to].set(state,
+                                                               mode="drop")
                     pools[cname] = pools[cname].at[to].set(
                         tail.reshape(tail.shape[0], -1), mode="drop")
                 continue
-            with jax.named_scope("ssm_state"):
+            with jax.named_scope(state_scope):
                 pools[cname] = pools[cname].at[at].set(
                     tail.reshape(tail.shape[0], -1))
+                if not scans:       # the taps' multiply-adds were the step
+                    outs[i] = u
+                    continue
 
                 def by_slot(rows):
                     return jnp.zeros((region,) + rows.shape[1:],
@@ -954,14 +968,14 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
 
                 u, dt_g, live, reset = jax.tree.map(
                     by_slot, (u, dt_g, live, reset))
-            with jax.named_scope("ssm_scan"):
+            with jax.named_scope(scan_scope):
                 # (the reset rides in the decay: ``ssm.carried`` over the
                 # pool would be a second pass over it)
                 y, pools[sname] = forms.step(cfg, p, u, dt_g, pools[sname],
                                              live, reset)
-            with jax.named_scope("ssm_state"):
+            with jax.named_scope(state_scope):
                 outs[i] = y[at]
-        with jax.named_scope("ssm_scan"):
+        with jax.named_scope(scan_scope):
             y = lay.from_groups(outs)
         return forms.out(cfg, p, y, z)
 
@@ -985,7 +999,7 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                                            places, index_places):
             h = _norm(cfg, lp["ln1"], x)
             if kind in STATE_SPACE_KINDS:
-                out = state_space(lay, kind, lp["ssm"], place,
+                out = state_space(lay, kind, tl.mixer_tree(kind, lp), place,
                                   h.astype(dtype), pools)
             elif kind < 0:
                 out = None
@@ -1028,6 +1042,34 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                         {name: pool for name, pool in arena.items()
                          if name not in carried}, write_layers)
     return lm_logits(cfg, params, x_last)[:, 0], {**pools, **state}
+
+
+def _paged_reader(model: DecoderConfig, config) -> Tuple[bool, int]:
+    """(the history and decode reads take the paged kernels, the K pool's
+    lanes a head). A typed stack's K heads are zero-padded to whole
+    128-lane tiles for the paged kernel (192 -> 256); the uniform stack's
+    are as wide as the kernel takes them, or it is refused. A latent
+    stack's pool holds one row a token, padded likewise (576 -> 640: the
+    kernel copies whole pages, and a DMA wants whole lane tiles), and what
+    the kernel sums is the row's first kv_lora_rank lanes. Heads of HALF a
+    tile (64) stay as wide as they are: the kernel reads two KV heads as
+    one of 128 lanes (``pa.pairs_heads``), and a padded head would double
+    the bytes a token holds and every page read. ``config.use_pallas`` None:
+    the kernels wherever the backend and the shapes take them."""
+    latent = model.latent
+    width = model.latent_dim if latent else model.head_dim
+    paired = model.typed and not latent and all(
+        pa.pairs_heads(width, model.v_dim, model.kind_kv_heads(kind))
+        for kind in set(model.layer_kinds) & set(pa.KIND_POOLS))
+    lanes = -(-width // 128) * 128 if model.typed and not paired else width
+    if config.use_pallas is not None:
+        use_pallas = bool(config.use_pallas)
+    elif paired:
+        use_pallas = pa.supported(2 * width, config.block_size)
+    else:
+        use_pallas = pa.supported(lanes, config.block_size) and \
+            (model.kv_lora_rank if latent else model.v_dim) % 128 == 0
+    return use_pallas, lanes if use_pallas else width
 
 
 def _bucket(n: int) -> int:
@@ -1264,23 +1306,10 @@ class RaggedInferenceEngineTPU:
                     "InferenceEngineTPU for TP serving.")
         self.dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
                       "float16": jnp.float16}[config.dtype]
-        # a typed stack's K heads are zero-padded to whole 128-lane tiles
-        # for the paged kernel (192 -> 256); the uniform stack's are as wide
-        # as the kernel takes them, or it is refused. A latent stack's pool
-        # holds one row a token, padded likewise (576 -> 640: the kernel
-        # copies whole pages, and a DMA wants whole lane tiles), and what
-        # the kernel sums is the row's first kv_lora_rank lanes
-        latent = model.latent
-        width = model.latent_dim if latent else model.head_dim
-        lanes = -(-width // 128) * 128 if model.typed else width
-        if config.use_pallas is None:
-            self.use_pallas = pa.supported(lanes, config.block_size) and \
-                (model.kv_lora_rank if latent else model.v_dim) % 128 == 0
-        else:
-            self.use_pallas = bool(config.use_pallas)
-        #: width of the K pool (a latent stack: of its one pool): the
-        #: heads' (the row's), or the padded lanes
-        self.k_width = lanes if self.use_pallas else width
+        #: the paged kernels or the XLA readers; width of the K pool (a
+        #: latent stack: of its one pool): the heads' (the row's), or the
+        #: padded lanes
+        self.use_pallas, self.k_width = _paged_reader(model, config)
 
         self.state = DSStateManager(max_sequences=config.max_sequences,
                                     num_blocks=config.num_blocks,

@@ -36,7 +36,7 @@ _FAMILIES = ("llama", "mistral", "mixtral", "qwen", "qwen2", "qwen2_moe",
               "phi", "phi3", "gpt_bigcode", "gptj", "bert", "distilbert",
               "gpt_neo", "internlm", "mimo_v2", "deepseek_v3",
               "cohere2_moe", "nemotron_h", "granitemoehybrid", "jamba",
-              "glm_moe_dsa")
+              "glm_moe_dsa", "lfm2_moe")
 
 
 def _map_hf_act(act: str) -> str:
@@ -70,6 +70,8 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
         return _jamba_config(hf)
     if mt == "glm_moe_dsa":
         return _glm_moe_dsa_config(hf)
+    if mt == "lfm2_moe":
+        return _lfm2_moe_config(hf)
     if mt == "bert":
         return DecoderConfig(
             hidden_size=hf["hidden_size"],
@@ -435,6 +437,23 @@ def _mimo_v2_config(hf: Dict[str, Any]) -> DecoderConfig:
         **_sigmoid_router(hf, "mimo_v2"))
 
 
+def _layer_kinds(hf: Dict[str, Any], fam: str, names: Dict[str, int]
+                 ) -> tuple:
+    """``layer_types`` → ``DecoderConfig.layer_kinds`` by a family's names
+    for its mixers; the list may be longer than ``num_hidden_layers`` (a
+    depth-cut file keeps the published list: the first entries are read). A
+    short list or an unknown name is refused by name."""
+    L = int(hf["num_hidden_layers"])
+    if len(hf["layer_types"]) < L:
+        raise ValueError(f"{fam}: layer_types has "
+                         f"{len(hf['layer_types'])} entries for {L} layers")
+    for name in hf["layer_types"][:L]:
+        if name not in names:
+            raise ValueError(f"{fam}: layer type {name!r} is not built "
+                             f"(expected one of {sorted(names)})")
+    return tuple(names[n] for n in hf["layer_types"][:L])
+
+
 def _rope_type(hf: Dict[str, Any]) -> str:
     scaling = hf.get("rope_scaling") or hf.get("rope_parameters") or {}
     return scaling.get("rope_type", scaling.get("type", "default"))
@@ -639,14 +658,7 @@ def _cohere2_moe_config(hf: Dict[str, Any]) -> DecoderConfig:
         raise ValueError(f"{fam}: rope_type {_rope_type(hf)!r} is not "
                          f"built (expected 'default')")
     L = int(hf["num_hidden_layers"])
-    names = {"full_attention": 0, "sliding_attention": 1}
-    if len(hf["layer_types"]) < L:
-        raise ValueError(f"{fam}: layer_types has "
-                         f"{len(hf['layer_types'])} entries for {L} layers")
-    for name in hf["layer_types"][:L]:
-        if name not in names:
-            raise ValueError(f"{fam}: layer type {name!r} is not built "
-                             f"(expected one of {sorted(names)})")
+    kinds = _layer_kinds(hf, fam, {"full_attention": 0, "sliding_attention": 1})
     share = hf.get("expert_share")
     E = int(hf["num_experts"])
     if share and int(share["router_experts"]) != E:
@@ -673,7 +685,7 @@ def _cohere2_moe_config(hf: Dict[str, Any]) -> DecoderConfig:
         rotary_pct=float(hf.get("rotary_pct", 1.0)),
         rope_interleaved=True, full_attn_rope=False,
         parallel_block=True, parallel_block_norms=1, tie_embeddings=True,
-        layer_kinds=tuple(names[n] for n in hf["layer_types"][:L]),
+        layer_kinds=kinds,
         sliding_window=int(hf["sliding_window"]),
         num_experts=E, num_experts_per_tok=int(hf["num_experts_per_tok"]),
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
@@ -788,14 +800,7 @@ def _granitemoehybrid_config(hf: Dict[str, Any]) -> DecoderConfig:
             raise ValueError(f"{fam}: {key}={hf[key]!r} is not built "
                              f"(expected {want!r})")
     L = int(hf["num_hidden_layers"])
-    names = {"mamba": 3, "attention": 0}
-    if len(hf["layer_types"]) < L:
-        raise ValueError(f"{fam}: layer_types has "
-                         f"{len(hf['layer_types'])} entries for {L} layers")
-    for name in hf["layer_types"][:L]:
-        if name not in names:
-            raise ValueError(f"{fam}: layer type {name!r} is not built "
-                             f"(expected one of {sorted(names)})")
+    kinds = _layer_kinds(hf, fam, {"mamba": 3, "attention": 0})
     heads, p_dim = int(hf["mamba_n_heads"]), int(hf["mamba_d_head"])
     if heads * p_dim != int(hf["mamba_expand"]) * int(hf["hidden_size"]):
         raise ValueError(
@@ -825,7 +830,7 @@ def _granitemoehybrid_config(hf: Dict[str, Any]) -> DecoderConfig:
         norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
         rope_theta=float(hf.get("rope_theta", 10000.0)),
         full_attn_rope=False, use_bias=False, tie_embeddings=True,
-        layer_kinds=tuple(names[n] for n in hf["layer_types"][:L]),
+        layer_kinds=kinds,
         layer_sparse=(1,) * L,
         ssm_heads=heads, ssm_head_dim=p_dim,
         ssm_groups=int(hf["mamba_n_groups"]),
@@ -892,6 +897,74 @@ def _jamba_config(hf: Dict[str, Any]) -> DecoderConfig:
         ssm_conv_kernel=int(hf["mamba_d_conv"]))
 
 
+def _lfm2_moe_config(hf: Dict[str, Any]) -> DecoderConfig:
+    """LFM2-MoE's stack (Liquid AI LFM2-24B-A2B; ``model_type: lfm2_moe``),
+    the published names as they are: a typed stack (models/typed_layers.py
+    has the equations) whose EVERY layer is a mixer AND a feed-forward part
+    under two RMSNorms (``norm_eps``). ``layer_types`` names each layer's
+    mixer: ``conv`` a GATED SHORT CONVOLUTION (kind 5: ``conv_L_cache`` taps
+    over the hidden size, no bias) or ``full_attention``
+    (``num_attention_heads`` / ``num_key_value_heads`` heads of hidden /
+    heads, each q and k head under an RMSNorm before rotate-half RoPE, θ
+    nested in ``rope_parameters``). The first ``num_dense_layers`` layers
+    end in a dense SiLU-GLU of ``intermediate_size``, the others in
+    ``num_experts`` experts of ``moe_intermediate_size`` —
+    ``num_experts_per_tok`` a token by sigmoid scores, ``use_expert_bias``:
+    a bias that moves the pick and never the weight, the kept scores over
+    their sum + 1e-6 (``norm_topk_prob``), times
+    ``routed_scaling_factor``; no shared expert. The head is tied unless
+    ``tie_word_embeddings`` says otherwise (the published file has no such
+    key; HF's ``Lfm2MoeConfig`` ties by default). ONE published key names
+    both the router's width and the expert count, so a share is
+    ``expert_share`` (not a published key: ``{"router_experts",
+    "first_expert", "held_experts"}``, as ``cohere2_moe``). Refused by
+    name: ``conv_bias``, an unknown layer type, any ``rope_type`` but
+    ``default``."""
+    fam = "lfm2_moe"
+    if hf.get("conv_bias", False):
+        raise ValueError(f"{fam}: conv_bias={hf['conv_bias']!r} is not "
+                         f"built (expected False)")
+    if _rope_type(hf) != "default":
+        raise ValueError(f"{fam}: rope_type {_rope_type(hf)!r} is not "
+                         f"built (expected 'default')")
+    L = int(hf["num_hidden_layers"])
+    kinds = _layer_kinds(hf, fam, {"conv": 5, "full_attention": 0})
+    E = int(hf["num_experts"])
+    share = hf.get("expert_share")
+    if share and int(share["router_experts"]) != E:
+        raise ValueError(
+            f"{fam}: expert_share.router_experts="
+            f"{share['router_experts']!r} is not num_experts={E} (the one "
+            f"published key is the router's width; the share's count is "
+            f"expert_share.held_experts)")
+    dense = int(hf.get("num_dense_layers", 0))
+    theta = float((hf.get("rope_parameters") or {}).get(
+        "rope_theta", hf.get("rope_theta", 1000000.0)))
+    return DecoderConfig(
+        hidden_size=hf["hidden_size"], num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        intermediate_size=int(hf["moe_intermediate_size"]),
+        dense_intermediate_size=int(hf["intermediate_size"]),
+        vocab_size=hf["vocab_size"],
+        max_seq_len=hf.get("max_position_embeddings", 128000),
+        norm="rmsnorm", activation="silu_glu", pos_emb="rope",
+        norm_eps=float(hf.get("norm_eps", 1e-5)), rope_theta=theta,
+        use_bias=False, qk_head_norm=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        layer_kinds=kinds,
+        layer_sparse=tuple(0 if l < dense else 1 for l in range(L)),
+        ssm_conv_kernel=int(hf["conv_L_cache"]),
+        num_experts=E, num_experts_per_tok=int(hf["num_experts_per_tok"]),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        router_scoring="sigmoid",
+        router_select_bias=bool(hf.get("use_expert_bias", True)),
+        router_norm_eps=1e-6,
+        routed_scale=float(hf.get("routed_scaling_factor") or 1.0),
+        experts_held=(int(share["first_expert"]),
+                      int(share["held_experts"])) if share else None)
+
+
 def _is_gemma_layout(cfg: DecoderConfig) -> bool:
     return cfg.activation == "gelu_glu" and cfg.scale_embeddings
 
@@ -932,8 +1005,8 @@ def config_to_hf(cfg: DecoderConfig) -> Dict[str, Any]:
     if cfg.typed:
         raise NotImplementedError(
             "config_to_hf: a typed layer stack (mimo_v2, deepseek_v3, "
-            "cohere2_moe, nemotron_h, granitemoehybrid, jamba) has no "
-            "exporter")
+            "cohere2_moe, nemotron_h, granitemoehybrid, jamba, lfm2_moe) "
+            "has no exporter")
     if not cfg.causal or not cfg.prenorm:
         # encoder layouts (BERT/DistilBERT): both flags flip together
         if cfg.causal or cfg.prenorm or cfg.pos_emb != "learned" \

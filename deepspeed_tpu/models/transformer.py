@@ -42,8 +42,9 @@ Params = Dict[str, Any]
 
 
 #: the layer kinds whose mixer carries a state a sequence (ops/ssm.py): 3 a
-#: Mamba-2 mixer, 4 a Mamba-1 selective scan
-STATE_SPACE_KINDS = (3, 4)
+#: Mamba-2 mixer, 4 a Mamba-1 selective scan, 5 a gated short convolution
+#: (its state is the convolution's tail and nothing else)
+STATE_SPACE_KINDS = (3, 4, 5)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,6 +165,9 @@ class DecoderConfig:
     #: pages), 4 = a Mamba-1 SELECTIVE-SCAN mixer (Jamba's: a step size a
     #: channel through ``ssm_dt_rank``, ``ssm_inner_size`` channels of
     #: ``ssm_state_size`` states; a stack has kind 3 or kind 4, not both),
+    #: 5 = a GATED SHORT CONVOLUTION (LFM2's: ``ssm_conv_kernel`` taps over
+    #: ``hidden_size`` channels between two gates; it carries the taps' last
+    #: inputs and NO state; a stack with kind 5 has neither kind 3 nor 4),
     #: -1 = NO mixer: the layer is its feed-forward part alone,
     #: under the layer's one norm (Nemotron-H's ``E`` layers).
     #: Set → the stack is NOT one
@@ -208,6 +212,13 @@ class DecoderConfig:
     router_groups_kept: int = 1
     #: ``routed_scaling_factor``: multiplies the kept (normalised) weights
     routed_scale: float = 1.0
+    #: what a sigmoid router adds to the kept scores' sum before it divides
+    #: by it (``norm_topk_prob``): DeepSeek-V3's 1e-20, LFM2's 1e-6
+    router_norm_eps: float = 1e-20
+    #: an RMSNorm over each q and k HEAD (one learned scale of ``head_dim``
+    #: each, shared by the heads) BEFORE the rotary term, on the attention
+    #: kinds 0 and 1 of a typed stack (LFM2's ``q_layernorm`` / ``k_layernorm``)
+    qk_head_norm: bool = False
     # -- latent attention (kind 2): the cache holds ONE row a token,
     # [RMSNorm(c_kv) (kv_lora_rank) ; RoPE(k_r) (qk_rope_head_dim)], read by
     # every head; ``head_dim`` = qk_nope + qk_rope, ``v_head_dim`` the V head
@@ -320,6 +331,11 @@ class DecoderConfig:
                 "a selective-scan layer (layer kind 4) needs ssm_inner_size, "
                 "ssm_dt_rank and ssm_state_size, and a stack without layers "
                 "of kind 3 (the ssm_* widths describe one kind of scan)")
+        if self.short_conv and set(self.layer_kinds) & {3, 4}:
+            raise ValueError(
+                "a gated short convolution (layer kind 5) needs a stack "
+                "without layers of kinds 3 and 4 (ssm_conv_kernel and the "
+                "convolution's pool describe one kind of mixer)")
         if self.layer_kinds is not None and any(
                 kind == -1 and not self.layer_has_ffn(l)
                 for l, kind in enumerate(self.layer_kinds)):
@@ -372,6 +388,12 @@ class DecoderConfig:
         return self.typed and 4 in self.layer_kinds
 
     @property
+    def short_conv(self) -> bool:
+        """The stack's recurrent layers are gated short convolutions (kind
+        5): a sequence carries their taps' last inputs and no state."""
+        return self.typed and 5 in self.layer_kinds
+
+    @property
     def recurrent(self) -> bool:
         """The stack holds state-space layers (``STATE_SPACE_KINDS``): a
         sequence carries a recurrent state beside its pages, and a prefix of
@@ -388,7 +410,10 @@ class DecoderConfig:
     @property
     def ssm_conv_dim(self) -> int:
         """What the mixer's convolution runs over: ``[x | B | C]``; a
-        selective scan's: the channels alone."""
+        selective scan's: the channels alone; a gated short convolution's:
+        the hidden size."""
+        if self.short_conv:
+            return self.hidden_size
         if self.ssm_inner_size:
             return self.ssm_inner_size
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
@@ -538,6 +563,8 @@ class DecoderConfig:
                     self.ssm_dt_rank
                 ssm = 3 * d * di + di * (self.ssm_conv_kernel + 1) \
                     + (di + 1) * (r + 2 * n) + (r + 1) * di + di * (n + 1)
+            if self.short_conv:     # in (three blocks), out, the taps
+                ssm = 4 * d * d + d * self.ssm_conv_kernel
             layers = sum(
                 (ssm if kind in STATE_SPACE_KINDS else attn if kind >= 0
                  else 0)

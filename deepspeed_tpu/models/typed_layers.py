@@ -2,7 +2,7 @@
 
 ``DecoderConfig.layer_kinds`` names each layer's mixer (0 = full causal
 attention, 1 = window, 2 = latent, 3 = a Mamba-2 state-space mixer, 4 = a
-Mamba-1 selective-scan mixer, -1 = none) and ``layer_sparse`` its
+Mamba-1 selective-scan mixer, 5 = a gated short convolution, -1 = none) and ``layer_sparse`` its
 feed-forward part (1 = sparse experts, 0 = a dense MLP, -1 = none). The
 kinds differ in SHAPE — KV heads, rotary base, a learned sink on the window
 kind, the dense width — so they share no stacked tree: ``params["layers"]``
@@ -151,6 +151,29 @@ ssm_inner_size`` channels, NO heads and NO groups, ``N = ssm_state_size``,
 A sequence carries ``S`` (``[N, d]`` float32) and the last ``K − 1`` rows
 of ``x′``, in the same pools, slots and resets as kind 3.
 
+A SHORT-CONVOLUTION stack (LFM2's, ``hf_loader``: ``lfm2_moe``) is the
+two-part layer once more — a mixer AND a feed-forward part under two
+RMSNorms, no multipliers —: a dense SiLU-GLU of ``dense_intermediate_size``
+in the leading layers, then sigmoid-routed experts (a selection bias that
+moves the PICK and never the weight, the kept scores over their sum +
+``router_norm_eps``, no shared expert), a TIED head. Beside a few attention
+layers (kind 0; ``cfg.qk_head_norm``: ``q ← RMSNorm_Dk(q)``, ``k ←
+RMSNorm_Dk(k)``, one learned scale of ``Dk`` each shared by the heads,
+BEFORE rotate-half RoPE on the whole head) most layers are the GATED SHORT
+CONVOLUTION (kind 5), ``K = ssm_conv_kernel`` taps over ``D = hidden_size``
+channels:
+
+- ``[B | C | x̃] = h·W_in`` (three blocks of ``D``, IN THAT ORDER, no
+  bias); ``u = B ⊙ x̃``;
+- ``c_t = Σ_{i<K} w[:, i] ⊙ u_{t−K+1+i}``: depthwise, causal, no bias and NO
+  activation (``ssm.conv_rows`` on a tree without ``conv_b``);
+- ``y = C ⊙ c``; out ``y·W_out`` (scope ``conv_mixer`` holds all of it).
+
+A sequence carries the last ``K − 1`` rows of ``u`` and NOTHING else: the
+layer has a ``conv<i>`` pool and no ``ssm<i>``, in the same slots and
+resets as kinds 3 and 4 (scope ``conv_state``: the gather of a row's tail,
+a fresh row's reset, the write-back).
+
 **The residual stream is float32** whatever the parameters' dtype
 (:func:`residual_stream`): the matmuls take the norms' outputs cast to the
 compute dtype, their results are added in float32, and the router reads
@@ -191,7 +214,9 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     ``lm_head`` unless the head is tied to ``embed``. A state-space layer
     has ``ssm`` {w_in, conv_w, conv_b, dt_bias, A_log, D, norm, w_out} in
     place of ``attn`` (a selective scan's: {w_in, conv_w, conv_b, w_x,
-    dt_norm, b_norm, c_norm, w_dt, dt_bias, A_log, D, w_out}); a layer with
+    dt_norm, b_norm, c_norm, w_dt, dt_bias, A_log, D, w_out}); a gated short
+    convolution has ``conv`` {w_in, conv_w, w_out}; ``cfg.qk_head_norm``
+    adds ``q_norm`` / ``k_norm`` {scale} to ``attn``; a layer with
     no mixer has neither; a layer with no
     feed-forward part has no ``mlp`` / ``moe``; un-gated (``relu2``)
     experts have no ``wg``."""
@@ -228,6 +253,14 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
             lp["ssm"] = _init_ssm(cfg, w, next(keys), out_std)
         elif kind == 4:
             lp["ssm"] = _init_selective(cfg, w, next(keys), out_std)
+        elif kind == 5:
+            # the taps at torch's ``Conv1d`` default (uniform in ±K^-0.5:
+            # a variance of 1 / 3K), not the matrices' 0.02: the mixer's
+            # output is then of the other branches' order
+            k = cfg.ssm_conv_kernel
+            lp["conv"] = {"w_in": w((d, 3 * d)),
+                          "conv_w": w((d, k), (3 * k) ** -0.5),
+                          "w_out": w((d, d), out_std)}
         elif kind == 2:
             ql, kl, nope = cfg.q_lora_rank, cfg.kv_lora_rank, \
                 cfg.qk_nope_head_dim
@@ -240,6 +273,9 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
         elif kind >= 0:
             attn = {"wq": w((d, H * dk)), "wk": w((d, kvh * dk)),
                     "wv": w((d, kvh * dv)), "wo": w((H * dv, d), out_std)}
+            if cfg.qk_head_norm:
+                attn["q_norm"] = {"scale": jnp.ones((dk,), jnp.float32)}
+                attn["k_norm"] = {"scale": jnp.ones((dk,), jnp.float32)}
         if kind == 1 and cfg.window_sink:
             # not zero at init: a zero sink would make a test of it vacuous
             attn["sink"] = w((H,), 1.0)
@@ -349,7 +385,8 @@ def typed_qkv(cfg, kind: int, p, x: jax.Array, sin, cos
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x [B, t, D] → q [B, t, H, Dk], k [B, t, KV_kind, Dk],
     v [B, t, KV_kind, Dv] (scaled), RoPE applied to q and k (``sin`` None:
-    the kind has no positional term)."""
+    the kind has no positional term); ``cfg.qk_head_norm``: every q and k
+    head under its RMSNorm first."""
     b, t = x.shape[:2]
     kvh = cfg.kind_kv_heads(kind)
     q = tf.linear_2d(x, p, "wq").reshape(b, t, cfg.num_heads, cfg.head_dim)
@@ -357,6 +394,9 @@ def typed_qkv(cfg, kind: int, p, x: jax.Array, sin, cos
     v = tf.linear_2d(x, p, "wv").reshape(b, t, kvh, cfg.v_dim)
     if cfg.value_scale != 1.0:
         v = (v * cfg.value_scale).astype(v.dtype)
+    if cfg.qk_head_norm:
+        q = tf._norm(cfg, p["q_norm"], q)
+        k = tf._norm(cfg, p["k_norm"], k)
     if sin is None:
         return q, k, v
     return tf.apply_rope(q, sin, cos, cfg.rope_interleaved), \
@@ -508,7 +548,8 @@ def _branch(cfg, lp, part: str, out: jax.Array) -> jax.Array:
     "mixer" or "ffn"); at 1.0 as it is, no operation."""
     if cfg.residual_multiplier == 1.0:
         return out
-    scope = ("ssm_out" if "ssm" in lp else "attn_out") if part == "mixer" \
+    scope = ("ssm_out" if "ssm" in lp else "conv_mixer" if "conv" in lp
+             else "attn_out") if part == "mixer" \
         else ("moe" if "moe" in lp else "mlp")
     with jax.named_scope(scope):
         return out.astype(jnp.float32) * cfg.residual_multiplier
@@ -605,10 +646,30 @@ def ssm_select(cfg, p, u: jax.Array, _rows, counts: jax.Array):
             b, c
 
 
+def short_conv_in(cfg, p, h: jax.Array):
+    """A gated short convolution's input projection, token-wise: h [.., D]
+    → (the out gate ``C`` [.., D] float32, ``u = B ⊙ x̃`` in h's dtype —
+    what its convolution reads and its pool holds —, ()): float32 results,
+    as :func:`selective_in`'s (:func:`_linear_f32` says why: this stack is
+    served 80 branch sums deep)."""
+    with jax.named_scope("conv_mixer"):
+        d = cfg.hidden_size
+        bcx = _linear_f32(h, p, "w_in")
+        return bcx[..., d:2 * d], \
+            (bcx[..., :d] * bcx[..., 2 * d:]).astype(h.dtype), ()
+
+
+def short_conv_out(cfg, p, y: jax.Array, z: jax.Array) -> jax.Array:
+    """``(C ⊙ c)·W_out``, token-wise: the convolved channels y [.., D]
+    float32 and the gate → [.., D] float32, as the stream it joins."""
+    with jax.named_scope("conv_mixer"):
+        return _linear_f32((y * z).astype(p["w_out"].dtype), p, "w_out")
+
+
 class MixerForms(NamedTuple):
-    """What a state-space layer is made of, by its kind of scan (THE place
-    that tells kind 3 from kind 4; the pools' shape is ``ssm.state_shape``).
-    Both kinds share the pools, slots and resets, the convolution
+    """What a recurrent layer is made of, by its kind (THE place that tells
+    kinds 3, 4 and 5 apart; the pools' shape is ``ssm.state_shape``). The
+    kinds share the pools, slots and resets, the convolution
     (``ssm.conv_rows``) and the two row groups of a step."""
     #: (cfg, p, h) → (the gate z, the convolution's input, what the scan
     #: takes beside ``u`` that is made token-wise: a tree of [.., w])
@@ -618,15 +679,23 @@ class MixerForms(NamedTuple):
     #: (cfg, p, u, that tree's rows, counts) → what the scan takes beside u
     inputs: Callable
     #: (cfg, p, u, inputs, state, counts[, reset]) → (y, state): one
-    #: position a row, and a chunk from a carried state
-    step: Callable
-    chunk: Callable
+    #: position a row, and a chunk from a carried state; None: the mixer
+    #: has NO scan (kind 5), and its convolution's output is ``y``
+    step: Optional[Callable]
+    chunk: Optional[Callable]
     #: (cfg, p, y, z) → the mixer's output
     out: Callable
+    #: the scopes of what touches the pools, the convolution, the scan
+    scopes: Tuple[str, str, str] = ("ssm_state", "ssm_conv", "ssm_scan")
 
 
 def mixer_forms(kind: int, kernel: bool = False) -> MixerForms:
     """``kernel``: the selective scan's chunk form as its Pallas kernel."""
+    if kind == 5:
+        return MixerForms(short_conv_in, jnp.float32,
+                          lambda cfg, p, u, dt, counts: dt, None, None,
+                          short_conv_out,
+                          ("conv_state", "conv_mixer", "conv_mixer"))
     if kind == 4:
         return MixerForms(selective_in, jnp.float32, ssm_select,
                           ssm.selective_step, functools.partial(
@@ -640,14 +709,22 @@ def ssm_rows(forms: MixerForms, cfg, p, xbc: jax.Array, dt,
              tail: jax.Array, state: jax.Array, counts: jax.Array):
     """Convolution and scan of ROWS [m, c, ..] from what they carried in →
     (y [m, c, d] float32, the tail and the state they carry on), the scan in
-    the form the rows' width picks."""
-    with jax.named_scope("ssm_conv"):
+    the form the rows' width picks (a mixer with no scan: its convolution's
+    output, the state as it came)."""
+    with jax.named_scope(forms.scopes[1]):
         u, tail = ssm.conv_rows(cfg, p, xbc, tail, counts, forms.conv_dtype)
+    if forms.step is None:
+        return u, tail, state
     dt = forms.inputs(cfg, p, u, dt, counts)
     with jax.named_scope("ssm_scan"):
         scan = forms.step if u.shape[1] == 1 else forms.chunk
         y, state = scan(cfg, p, u, dt, state, counts)
     return y, tail, state
+
+
+def mixer_tree(kind: int, lp):
+    """A recurrent layer's mixer tree: ``conv`` (kind 5) or ``ssm``."""
+    return lp["conv" if kind == 5 else "ssm"]
 
 
 #: positions a step of the uncached scan takes at once (Mamba-2's chunk)
@@ -710,7 +787,8 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
         h = h32.astype(dtype)
         if kind in tf.STATE_SPACE_KINDS or kind == -1:
             x = block_residual(
-                cfg, lp, x, h32, _ssm_mixer(cfg, kind, lp["ssm"], h)
+                cfg, lp, x, h32, _ssm_mixer(cfg, kind, mixer_tree(kind, lp),
+                                            h)
                 if kind >= 0 else None, moe_fn, None, dtype)
             continue
         if kind == 2:       # the expanded form: nothing is cached here

@@ -735,6 +735,43 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, qcounts_ref, q_ref, k_hbm,
     pl.when(jnp.logical_not(live))(lambda: write_dead(0))
 
 
+#: a head of HALF a lane tile (LFM2's 64): the kernel below slices a head as
+#: whole 128-lane tiles of a page, so two KV heads are read as ONE of 128
+#: lanes (:func:`pairs_heads`)
+HALF_TILE = 64
+
+
+def pairs_heads(head_dim: int, v_dim: int, kv_heads: int) -> bool:
+    """The paged kernel takes these heads two a lane tile: K and V heads of
+    ``HALF_TILE`` lanes, an even number of them. The pools keep a token's
+    heads side by side UNPADDED (``kv_heads * 64`` lanes: a head padded to
+    128 doubles the bytes a token holds and every page read); the kernel
+    sees ``kv_heads / 2`` heads of 128 lanes, each the group of BOTH its
+    halves' queries (:func:`_pair_queries`)."""
+    return head_dim == v_dim == HALF_TILE and kv_heads % 2 == 0
+
+
+def _pair_queries(q: jax.Array, kvh: int) -> jax.Array:
+    """q [n, c, h, 64] → [n, c, h, 128]: the query of a head whose KV head
+    is the LOW half of its pair in lanes [0, 64), of the high half in [64,
+    128), zeros in the other — its dot with the pair's 128 lanes is its dot
+    with its own KV head, exactly (the zeros add 0.0), at twice the
+    multiply-adds of a matmul that a page's fetch outlasts."""
+    n, c, h, d = q.shape
+    own = jnp.eye(2, dtype=q.dtype).reshape(2, 1, 2, 1)
+    q = q.reshape(n, c, kvh // 2, 2, h // kvh, 1, d) * own
+    return q.reshape(n, c, h, 2 * d)
+
+
+def _unpair_outputs(out: jax.Array, kvh: int) -> jax.Array:
+    """[n, c, h, 128] → [n, c, h, 64]: ``p·V`` over the pair's lanes is
+    ``[p·V_low | p·V_high]``; a head keeps its own KV head's half."""
+    n, c, h, d2 = out.shape
+    out = out.reshape(n, c, kvh // 2, 2, h // kvh, 2, d2 // 2)
+    return jnp.stack([out[:, :, :, 0, :, 0], out[:, :, :, 1, :, 1]],
+                     axis=3).reshape(n, c, h, d2 // 2)
+
+
 # jitted (as mla_decode is): a program whose layers are unrolled calls the
 # kernel once a layer and row group, and a pallas_call traces its kernel
 # body anew every call — under jit the calls of one shape share ONE trace
@@ -756,6 +793,13 @@ def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
     n, c, h, dh = q.shape
     kvh = lanes // dh
     dv = arena_v.shape[-1] // kvh
+    if pairs_heads(dh, dv, kvh):
+        out, lse = _paged_call(
+            _pair_queries(q, kvh), arena_k, arena_v, page_table, starts,
+            counts, with_lse=with_lse, interpret=interpret, window=window,
+            scale=1.0 / math.sqrt(dh) if scale is None else scale,
+            qcounts=qcounts, tile_q=tile_q, heads=heads)
+        return _unpair_outputs(out, kvh), lse
     groups = h // kvh
     mb = page_table.shape[1]
     rows = groups * c

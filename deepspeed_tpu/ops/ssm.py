@@ -26,7 +26,13 @@ Two forms of ONE scan, picked by the row's width (a shape, not an option):
 
 The state pools (:func:`init_state_pools`) hold a slot a sequence, ONE
 pool a state-space layer: a launch's one-token pass rewrites a layer's
-whole pool, and a buffer of its own bounds what the compiler may copy."""
+whole pool, and a buffer of its own bounds what the compiler may copy.
+
+A GATED SHORT CONVOLUTION (kind 5; LFM2's mixer) is the convolution alone —
+:func:`conv_rows` with no bias and no activation, between two gates that act
+on a token alone (``typed_layers.short_conv_in`` / ``short_conv_out``) — and
+carries its tail and nothing else: its layer has a ``conv<i>`` pool and no
+``ssm<i>`` (:func:`init_state_pools`), the same slots and resets."""
 
 import functools
 from typing import Dict, Tuple
@@ -68,12 +74,14 @@ def init_state_pools(cfg, slots: int, dtype) -> Dict[str, jax.Array]:
     64-row split program asked for 16.2 GB), one layer's 0.27 GB here. A
     slot's ``K − 1`` convolution inputs lie side by side in one row (a
     dimension of 3 would be padded to a tile of 8, and the compiler relaid
-    the pool on its way in and out of every program)."""
+    the pool on its way in and out of every program). A gated short
+    convolution (kind 5) has no state: its layer gets ``conv<i>`` alone."""
     pools = {}
-    for i in range(sum(1 for kind in cfg.layer_kinds if kind in (3, 4))):
+    for i in range(sum(1 for kind in cfg.layer_kinds if kind in (3, 4, 5))):
         state, conv = pool_names(i)
-        pools[state] = jnp.zeros((slots + 1,) + state_shape(cfg),
-                                 jnp.float32)
+        if not cfg.short_conv:
+            pools[state] = jnp.zeros((slots + 1,) + state_shape(cfg),
+                                     jnp.float32)
         pools[conv] = jnp.zeros((slots + 1, (cfg.ssm_conv_kernel - 1) *
                                  cfg.ssm_conv_dim), dtype)
     return pools
@@ -82,7 +90,10 @@ def init_state_pools(cfg, slots: int, dtype) -> Dict[str, jax.Array]:
 def state_shape(cfg) -> Tuple[int, ...]:
     """What ONE sequence carries in a state-space layer, float32: ``[H, P,
     N]`` (Mamba-2), or a selective scan's ``[N, d]`` — the channels on the
-    lanes, 40 tiles of 128 at Jamba2-3B's 5,120, and not its 16 states."""
+    lanes, 40 tiles of 128 at Jamba2-3B's 5,120, and not its 16 states; a
+    gated short convolution carries none (an empty carry)."""
+    if cfg.short_conv:
+        return (0,)
     if cfg.selective:
         return (cfg.ssm_state_size, cfg.ssm_inner)
     return (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size)
@@ -121,16 +132,19 @@ def conv_rows(cfg, p, xbc: jax.Array, tail: jax.Array, counts: jax.Array,
     [m, c, Cd] after the rows' carried ``tail`` [m, K − 1, Cd] → (u
     [m, c, Cd] in ``dtype`` — None: xbc's —, the tail each row carries on:
     the ``K − 1`` inputs that end at its last live position; a row with no
-    live position keeps its own)."""
+    live position keeps its own). A tree with no ``conv_b`` is a gated
+    short convolution's: no bias and NO activation (the taps alone; at
+    ``c == 1`` its ``K`` multiply-adds)."""
     k = cfg.ssm_conv_kernel
     c = xbc.shape[1]
     seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
     w = p["conv_w"].astype(jnp.float32)                       # [Cd, K]
-    acc = p["conv_b"].astype(jnp.float32)
+    plain = "conv_b" not in p
+    acc = 0.0 if plain else p["conv_b"].astype(jnp.float32)
     for i in range(k):      # u_t = Σ_i w[:, i]·seq[t + i] (seq[t + K − 1]
         acc = acc + seq[:, i:i + c].astype(jnp.float32) * w[:, i]   # is x_t)
     at = counts[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None]
-    return jax.nn.silu(acc).astype(dtype or xbc.dtype), \
+    return (acc if plain else jax.nn.silu(acc)).astype(dtype or xbc.dtype), \
         jnp.take_along_axis(seq, at[..., None], axis=1)
 
 

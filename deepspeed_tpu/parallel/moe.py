@@ -514,7 +514,8 @@ def route_tokens(cfg, p, xf: jax.Array) -> Tuple[jax.Array, jax.Array]:
       equal groups, a group scored by the sum of its two highest, the picks
       outside the ``router_groups_kept`` best groups set to 0.0 before the
       top-k (as HF's ``deepseek_v3`` gate masks them). ``norm_topk_prob``:
-      the kept weights renormalised to sum to 1.
+      the kept weights over their sum + ``cfg.router_norm_eps`` (1e-20;
+      LFM2's gate adds 1e-6).
     - ``"softmax"``: the ``k`` largest LOGITS are kept; with
       ``norm_topk_prob`` the weights are the softmax over THOSE ``k``
       logits alone (Granite's gate; Mixtral's softmax over all,
@@ -561,7 +562,7 @@ def route_tokens(cfg, p, xf: jax.Array) -> Tuple[jax.Array, jax.Array]:
         _, topi = lax.top_k(pick, cfg.num_experts_per_tok)
         topw = jnp.take_along_axis(scores, topi, axis=-1)
         if cfg.norm_topk_prob:
-            topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
+            topw = topw / (topw.sum(-1, keepdims=True) + cfg.router_norm_eps)
     if cfg.routed_scale != 1.0:
         topw = topw * cfg.routed_scale
     return topw, topi.astype(jnp.int32)

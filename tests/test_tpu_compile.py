@@ -997,8 +997,12 @@ def _hybrid_step(one_chip, monkeypatch, config, cut, step, mb,
         return out, arena
 
     def make_arena():
+        # (heads of half a tile keep their width: ``pa.pairs_heads``)
+        width = engine_v2._paged_reader(
+            model, engine_v2.RaggedInferenceConfig())[1]
         arena = pa.init_arena_typed(model.layer_kinds, {0: model.kv_heads},
-                                    num_blocks, 128, 128, 128, jnp.bfloat16)
+                                    num_blocks, 128, width, model.v_dim,
+                                    jnp.bfloat16)
         arena.update(ssm.init_state_pools(model, 64, jnp.bfloat16))
         return arena
 
@@ -1180,6 +1184,66 @@ def test_selective_scan_step_compiles_for_v5e(
               f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
               f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
     assert mem.temp_size_in_bytes < _JAMBA_STEPS[kind][3], \
+        mem.temp_size_in_bytes
+
+
+# -- the short-convolution stack (benchmark/configs/lfm2-24b-a2b-l40-e8-serve):
+# gated short convolutions and 64-wide GQA beside a dense MLP or held experts
+
+#: step -> (chunk, ``fresh_prefill``, capacities, most temporaries at the
+#: four layers ``conv conv attention conv``: measured 0.28, 0.54 and 0.25 GB;
+#: at all 40 layers 0.36, 0.83 and 0.47 beside 12.91 GB of arguments)
+_LFM2_STEPS = {
+    "decode": (1, False, (), 0.4e9),
+    "split": (128, "split", (512, 1024, 2048), 0.8e9),
+    "fresh": (128, "fresh", (2048,), 0.5e9),
+}
+
+
+@pytest.mark.parametrize("kind", list(_LFM2_STEPS))
+def test_short_conv_step_compiles_for_v5e(
+        kind, one_chip, no_persistent_cache, monkeypatch, capsys):
+    """The 64-row decode, split and fresh programs of LFM2-24B-A2B's stack
+    at the published widths, cut to ``conv conv attention conv`` with one
+    leading dense layer (all three layer shapes), over the cell's arena
+    (2,048 pages, 32 a row) and three pools of 65 convolution tails: NO
+    ``ssm<i>`` pool, NO copy of a pool anywhere in the module, the K and V
+    pools 512 lanes a token (8 heads of 64, UNPADDED); the scopes
+    ``conv_mixer`` and ``conv_state`` and no ``ssm_*``; the paged kernel
+    under ``attn_history`` in the split program, reading two KV heads as
+    one 128-lane tile (its q block ``[.., 4, rows, 128]``); temporaries
+    (printed) under the measured ones."""
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    cut = {"num_hidden_layers": 4, "num_dense_layers": 1,
+           "layer_types": ["conv", "conv", "full_attention", "conv"]}
+    model, arena, compiled, text = _hybrid_step(
+        one_chip, monkeypatch, "lfm2-24b-a2b-l40-e8-serve", cut,
+        _LFM2_STEPS[kind], 32, num_blocks=2048)
+    assert model.layer_kinds == (5, 5, 0, 5) and \
+        model.layer_sparse == (0, 1, 1, 1) and model.short_conv and \
+        model.experts_held == (0, 8)
+    assert sorted(arena) == ["conv0", "conv1", "conv2", "k", "v"] and \
+        arena["conv2"].shape == (65, 2 * 2048) and \
+        arena["k"].shape == arena["v"].shape == (2049, 128, 512)
+    table = scope_table_from_hlo(text)
+    scopes = {e["scope"] for e in table.values()}
+    assert {"conv_mixer", "conv_state", "mlp", "moe", "moe_router",
+            "moe_experts", "attn_qkv", "attn_out", "kv_write", "embed",
+            "lm_head"} <= scopes, scopes
+    assert not {s for s in scopes if s and s.startswith("ssm_")}, scopes
+    kernels = [n for n in table if n.startswith("paged_attn_lse")]
+    assert len(kernels) == (5 if kind == "split" else 0) and \
+        all(table[n]["scope"] == "attn_history" for n in kernels), kernels
+    heavy = [m.group(1) for m in _HEAVY.finditer(text)]
+    named = [n for n in heavy if table[n]["scope"] is not None]
+    assert len(named) >= 0.95 * len(heavy), sorted(set(heavy) - set(named))
+    assert not _branches(text) or kind != "split", _branches(text)
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nlfm2-24b-a2b {kind} at 4 layers: arguments "
+              f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < _LFM2_STEPS[kind][3], \
         mem.temp_size_in_bytes
 
 
