@@ -1409,9 +1409,19 @@ class RaggedInferenceEngineTPU:
         self._moe_fn = moe_fn
         #: (token, expert) assignments ONE fed token makes over the stack's
         #: sparse layers (``dispatch/moe_assignments``); 0: no experts
-        self._moe_assignments_per_token = model.num_experts_per_tok * sum(
-            model.layer_is_sparse(l) for l in range(model.num_layers)) \
-            if model.num_experts else 0
+        sparse = sum(model.layer_is_sparse(l)
+                     for l in range(model.num_layers))
+        self._moe_assignments_per_token = \
+            model.num_experts_per_tok * sparse if model.num_experts else 0
+        #: (most token slots of the held experts' few-token form, rows their
+        #: buffers hold in ONE launch of more over the stack's sparse
+        #: layers: ``dispatch/moe_buffer_rows``); None: not the share's layer
+        self._moe_buffer_rows = None
+        if model.typed and model.num_experts:
+            from deepspeed_tpu.parallel.moe import HELD_ROUND_ROWS
+            self._moe_buffer_rows = (
+                HELD_ROUND_ROWS,
+                HELD_ROUND_ROWS * model.num_held_experts * sparse)
         #: jit cache keyed on (n_bucket, c_bucket, mode, fresh) — the
         #: fresh=True/False split legitimately doubles prefill-shape
         #: compiles (arena-reading vs within-chunk attention programs).
@@ -2265,7 +2275,13 @@ class RaggedInferenceEngineTPU:
         ``dispatch/moe_assignments`` and the span's ``moe_assignments``:
         fed tokens x experts a token x sparse layers, what the launch's
         routers hand the experts' dispatch (all of them, held here or
-        not)."""
+        not); a stack whose sparse layers are the share's
+        (:func:`~deepspeed_tpu.parallel.moe.held_experts_moe_layer`) also
+        ``dispatch/moe_buffer_rows`` and the span's ``moe_buffer_rows``:
+        held experts x ``HELD_ROUND_ROWS`` x sparse layers — the rows the
+        first round's buffers hold — for a launch of more than
+        ``HELD_ROUND_ROWS`` ``slots``, 0 for one of fewer (every held
+        expert computes every token there)."""
         from deepspeed_tpu.telemetry.registry import registry
         row_slots = nb * chunk
         slots = row_slots if token_slots is None else token_slots
@@ -2325,6 +2341,11 @@ class RaggedInferenceEngineTPU:
                 self._moe_assignments_per_token
             registry.counter("dispatch/moe_assignments").inc(
                 work["moe_assignments"])
+        if self._moe_buffer_rows is not None:
+            few, rows = self._moe_buffer_rows
+            work["moe_buffer_rows"] = rows * (slots > few)
+            registry.counter("dispatch/moe_buffer_rows").inc(
+                work["moe_buffer_rows"])
         return work
 
     # -- convenience generation loop ---------------------------------------

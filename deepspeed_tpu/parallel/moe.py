@@ -497,7 +497,8 @@ def moe_layer(cfg, p, x: jax.Array,
 #: capacity of one round of the share's dispatch: rows an expert's buffer
 #: holds (one MXU tile of rows). Up to this many tokens the held experts
 #: simply compute every token (the weights' bytes bound a decode step, not
-#: the rows); beyond, assignments are sorted by expert and served in rounds
+#: the rows); beyond, a held assignment takes the next free row of its
+#: expert's buffer in token order, and the buffers are served in rounds
 HELD_ROUND_ROWS = 128
 
 
@@ -581,6 +582,54 @@ def _held_glu(p, buf: jax.Array) -> jax.Array:
     return jnp.einsum("ech,ehd->ecd", jax.nn.silu(gate) * up, p["wo"])
 
 
+@jax.jit
+def _held_rounds(p, xf: jax.Array, topw: jax.Array, local: jax.Array,
+                 mine: jax.Array) -> jax.Array:
+    """The many-token form of :func:`held_experts_moe_layer`: xf [S, d] in
+    the experts' dtype, the router's weights ``topw`` [S, k] float32, the
+    picks as HELD experts' indices ``local`` [S, k] and which of them count
+    (``mine``: held here and a real token) → [S, d] float32. A jit of its
+    own: a stack's layers of one shape are traced and lowered ONCE."""
+    (s, d), k, held = xf.shape, topw.shape[1], p["wo"].shape[0]
+    cap = HELD_ROUND_ROWS
+    # a token picks an expert at most once: its PLACE in that expert's rows
+    # is the number of earlier tokens that picked it too
+    experts = jnp.arange(held, dtype=jnp.int32)
+    picks = mine[..., None] & (local[..., None] == experts)    # [S, k, H]
+    upto = jnp.cumsum(picks.any(axis=1).astype(jnp.int32), axis=0)  # [S, H]
+    sizes = upto[-1]                                           # [H]
+    # pick-major from here on: a pick's rows of all tokens lie together
+    place = jnp.sum(jnp.where(picks, (upto - 1)[:, None, :], 0),
+                    axis=-1).T                                 # [k, S]
+    local_t, mine_t, topw_t = local.T, mine.T, topw.T
+    slot = jnp.arange(cap, dtype=jnp.int32)
+
+    def one_round(r, out):
+        # the token in slot c of expert e: the first whose count reaches
+        # r·cap + c + 1, i.e. how many tokens' counts are still under it
+        # (a slot past the expert's last row: a token that is never read)
+        tok = jnp.sum(upto.T[:, None, :] <= (r * cap + slot)[None, :, None],
+                      axis=-1, dtype=jnp.int32)                # [H, cap]
+        y = _held_glu(p, xf[jnp.minimum(tok, s - 1)])          # [H, cap, d]
+        # each token reads ITS rows back, best pick first
+        at = place - r * cap
+        here = mine_t & (at >= 0) & (at < cap)                 # [k, S]
+        rows = y.reshape(held * cap, d)[
+            jnp.where(here, local_t * cap + at, 0)]            # [k, S, d]
+        for j in range(k):
+            out = out + jnp.where(
+                here[j][:, None],
+                rows[j].astype(jnp.float32) * topw_t[j][:, None], 0.0)
+        return out
+
+    # round 0 is straight-line code: one round serves every expert unless
+    # routing is badly skewed; the loop is entered for the rest alone
+    out = one_round(0, jnp.zeros((s, d), jnp.float32))
+    rounds = (jnp.max(sizes) + cap - 1) // cap
+    out = lax.fori_loop(1, rounds, one_round, out)
+    return out
+
+
 def held_experts_moe_layer(cfg, p, x: jax.Array,
                            valid: Optional[jax.Array] = None
                            ) -> Tuple[jax.Array, jax.Array]:
@@ -598,11 +647,20 @@ def held_experts_moe_layer(cfg, p, x: jax.Array,
     ``valid`` [B, T] bool marks real tokens (padding slots of a packed
     step are dropped like absent experts). No token of a held expert is
     ever dropped: up to ``HELD_ROUND_ROWS`` tokens every held expert
-    computes every token (weights bound that shape); beyond, the held
-    assignments are sorted by expert and served ``HELD_ROUND_ROWS`` rows
-    an expert at a time, in as many rounds as the fullest expert needs
-    (one, unless routing is badly skewed). Returns (out, 0.0): serving
-    has no balance loss."""
+    computes every token (weights bound that shape); beyond, an
+    assignment's place in its expert's rows is the number of earlier
+    tokens that picked the expert (a prefix sum down the tokens: the
+    order a stable sort by expert would give, without the sort), and the
+    experts are served ``HELD_ROUND_ROWS`` rows each at a time, in as
+    many rounds as the fullest expert needs. A round moves rows by
+    gathers alone — the buffers' rows by a table of token ids, each
+    token's own ``k`` rows back, weighted and summed in float32, best
+    pick first — so no destination repeats and two calls give the same
+    bits. The first round is straight-line code (one round serves every
+    expert unless routing is badly skewed: a layer is then no loop to
+    the scheduler); the rest are a loop that is entered only when some
+    expert has more than ``HELD_ROUND_ROWS`` rows. Returns (out, 0.0):
+    serving has no balance loss."""
     b, t, d = x.shape
     s = b * t
     first, held = cfg.experts_held or (0, cfg.num_experts)
@@ -629,27 +687,5 @@ def held_experts_moe_layer(cfg, p, x: jax.Array,
                          preferred_element_type=jnp.float32)
         return out.astype(x.dtype).reshape(b, t, d), zero
 
-    cap = HELD_ROUND_ROWS
-    key = jnp.where(mine, local, held).reshape(-1)             # [S*k]
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-    begin = jnp.cumsum(sizes) - sizes                          # [H]
-    w_flat = topw.reshape(-1)
-    slot = jnp.arange(cap, dtype=jnp.int32)[None, :]
-
-    def one_round(r, out):
-        pos = r * cap + slot                                   # [1, cap]
-        live = pos < sizes[:, None]                            # [H, cap]
-        src = order[jnp.minimum(begin[:, None] + pos, s * k - 1)]
-        tok = src // k
-        w = jnp.where(live, w_flat[src], 0.0)
-        y = _held_glu(p, xf[tok])                              # [H, cap, d]
-        part = (y.astype(jnp.float32) * w[..., None]).reshape(-1, d)
-        # a dead slot adds its zero to row ``s``, which does not exist
-        return out.at[jnp.where(live, tok, s).reshape(-1)].add(
-            part, mode="drop")
-
-    rounds = (jnp.max(sizes) + cap - 1) // cap
-    out = lax.fori_loop(0, rounds, one_round,
-                        jnp.zeros((s, d), jnp.float32))
+    out = _held_rounds(p, xf, topw, local, mine)
     return out.astype(x.dtype).reshape(b, t, d), zero

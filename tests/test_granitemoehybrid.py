@@ -548,6 +548,45 @@ def test_dispatch_counts_the_experts_assignments(tiny):
         "decode", 3, 4, 1, 32, 3, 90)
 
 
+@pytest.mark.parametrize("launch, rows", [
+    # (program, rows, row bucket, chunk) + token_slots -> buffer rows
+    (("decode", 3, 4, 1, None), 0),
+    (("split", 3, 4, 128, 128), 0),           # 128 slots: the few-token path
+    (("split", 3, 4, 128, 512), 6 * 128 * 4),
+    (("split", 3, 8, 128, None), 6 * 128 * 4),     # the row form: 8 x 128
+    (("fresh", 2, 4, 128, 256), 6 * 128 * 4)])
+def test_dispatch_counts_the_buffer_rows_of_a_many_token_launch(
+        tiny, launch, rows):
+    """``dispatch/moe_buffer_rows``: 6 held experts x ``HELD_ROUND_ROWS`` x
+    4 sparse layers for a launch whose token-wise sublayers run over more
+    than ``HELD_ROUND_ROWS`` slots (the many-token dispatch's first
+    round), 0 for a decode launch and a split launch of 128 slots."""
+    from deepspeed_tpu.parallel.moe import HELD_ROUND_ROWS
+    from deepspeed_tpu.telemetry.registry import registry
+    assert HELD_ROUND_ROWS == 128
+    _, cfg, params, _, _ = tiny
+    eng = engine(cfg, params)
+    counter = registry.counter("dispatch/moe_buffer_rows")
+    before = counter.value
+    program, n, nb, chunk, slots = launch
+    work = eng._count_dispatch(program, n, nb, chunk, 32, 3, 90,
+                               token_slots=slots)
+    assert work["moe_buffer_rows"] == rows == counter.value - before
+
+
+def test_a_stack_without_experts_counts_no_buffer_rows():
+    from deepspeed_tpu.telemetry.registry import registry
+    dense = RaggedInferenceEngineTPU(
+        tf.DecoderConfig(hidden_size=32, num_layers=1, num_heads=2,
+                         intermediate_size=64, vocab_size=VOCAB),
+        dict(ENGINE))
+    counter = registry.counter("dispatch/moe_buffer_rows")
+    before = counter.value
+    work = dense._count_dispatch("split", 3, 8, 128, 32, 3, 90,
+                                 token_slots=512)
+    assert "moe_buffer_rows" not in work and counter.value == before
+
+
 # -- the share ----------------------------------------------------------------
 
 def test_two_shares_add_up_to_the_uncut_layer():

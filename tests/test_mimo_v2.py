@@ -274,8 +274,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 @pytest.mark.parametrize("skewed", [False, True])
 def test_many_tokens_are_served_in_rounds_and_none_is_dropped(skewed):
-    """Beyond ``HELD_ROUND_ROWS`` tokens the held assignments are sorted
-    by expert and served 128 rows an expert a round. 300 tokens of which
+    """Beyond ``HELD_ROUND_ROWS`` tokens a held assignment's place in its
+    expert's rows is the number of earlier tokens that picked the expert,
+    and the experts are served 128 rows each a round. 300 tokens of which
     the last 40 are padding (``valid``); ``skewed``: the bias sends every
     token to held expert 5 as well, so that expert needs three rounds.
     Against the reference on the valid tokens; padding rows come out 0."""
@@ -299,6 +300,121 @@ def test_many_tokens_are_served_in_rounds_and_none_is_dropped(skewed):
     few, _aux = moe_mod.held_experts_moe_layer(cfg, moe, x[:1])
     np.testing.assert_allclose(np.asarray(few).reshape(100, 64),
                                want[:100], atol=5e-6)
+
+
+def _many_tokens(case):
+    """(cfg, the layer's parameters, x [3, 100, 64], valid [3, 100]) of a
+    many-token step: 300 token slots, experts 4-7 of 16 held."""
+    cfg, params = build(TINY)
+    moe = dict(params["layers"][1]["moe"])
+    x = jnp.asarray(np.random.default_rng(8).normal(size=(3, 100, 64)),
+                    jnp.float32)
+    slots = np.arange(300)
+    if case in ("exactly_128", "one_over_129"):
+        # every valid token picks held expert 5: 128 fill round 0 to its
+        # last row, the 129th opens round 1
+        moe["router_bias"] = moe["router_bias"].at[5].set(10.0)
+        valid = slots < (128 if case == "exactly_128" else 129)
+    elif case == "none_held":
+        # (a score is under 1: a bias of -10 takes an expert out)
+        moe["router_bias"] = moe["router_bias"].at[4:8].set(-10.0)
+        valid = slots < 260
+    else:
+        # padding in the MIDDLE of the step, and a run of it
+        valid = (slots % 3 != 1) & ~((slots > 130) & (slots < 170))
+    return cfg, moe, x, jnp.asarray(valid).reshape(3, 100)
+
+
+@pytest.mark.parametrize("case", ["exactly_128", "one_over_129",
+                                  "none_held", "holes"])
+def test_a_place_is_the_count_of_earlier_tokens_of_the_expert(case):
+    """The many-token path's placement at its edges: an expert with
+    exactly ``HELD_ROUND_ROWS`` assignments (one round, its last row
+    used) and with one more (a second round for one row); a step in which
+    NO token picks a held expert (zeros, no NaN); padding between valid
+    tokens. Against the reference on the valid tokens; padding rows 0."""
+    from deepspeed_tpu.parallel import moe as moe_mod
+    ref = reference()
+    cfg, moe, x, valid = _many_tokens(case)
+    _w, topi = moe_mod.route_tokens(cfg, moe, x.reshape(300, 64))
+    picked = np.asarray(topi)[np.asarray(valid).reshape(-1)]
+    most = max(int((picked == e).sum()) for e in range(4, 8))
+    assert {"exactly_128": most == 128, "one_over_129": most == 129,
+            "none_held": most == 0, "holes": 0 < most < 128}[case]
+    out, _aux = moe_mod.held_experts_moe_layer(cfg, moe, x, valid=valid)
+    out = np.asarray(out).reshape(300, 64)
+    want = np.asarray(ref.experts_part(x.reshape(300, 64), moe,
+                                       ref.Widths.from_hf(TINY)))
+    live = np.asarray(valid).reshape(-1)
+    assert np.isfinite(out).all() and not out[~live].any()
+    np.testing.assert_allclose(out[live], want[live], atol=5e-6)
+    if case == "none_held":
+        assert not out.any() and not want[live].any()
+    else:
+        assert np.abs(want[live]).max() > 0.05
+
+
+def test_two_calls_on_one_input_are_the_same_bits():
+    """No scatter-add: a token's rows are summed in a fixed order."""
+    from deepspeed_tpu.parallel import moe as moe_mod
+    cfg, moe, x, valid = _many_tokens("one_over_129")
+    moe = jax.tree.map(lambda a: a.astype(jnp.bfloat16), moe)
+    layer = jax.jit(lambda p, x, v: moe_mod.held_experts_moe_layer(
+        cfg, p, x, valid=v)[0])
+    first, again = np.asarray(layer(moe, x, valid)), \
+        np.asarray(layer(moe, x, valid))
+    assert first.any() and (first == again).all()
+    eager = np.asarray(moe_mod.held_experts_moe_layer(cfg, moe, x,
+                                                      valid=valid)[0])
+    np.testing.assert_allclose(eager, first, atol=2e-2)
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in a jaxpr and the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(inner)
+
+
+@pytest.mark.parametrize("slots", [100, 300])
+def test_the_many_token_path_neither_sorts_nor_scatter_adds(slots):
+    """Structure: the many-token path (300 slots) places by a prefix sum
+    and moves rows by gathers — no ``sort``, no scatter of any kind (the
+    ``bincount`` was one), its rounds after the first one ``while`` —; the
+    few-token path (100 slots) holds none of them (the router gathers its
+    weights in both)."""
+    from deepspeed_tpu.parallel import moe as moe_mod
+    cfg, moe, x, valid = _many_tokens("holes")
+    x, valid = x.reshape(1, 300, 64)[:, :slots], \
+        valid.reshape(1, 300)[:, :slots]
+    found = set(_primitives(jax.make_jaxpr(
+        lambda p, x, v: moe_mod.held_experts_moe_layer(cfg, p, x, valid=v))(
+            moe, x, valid).jaxpr))
+    assert not {n for n in found if "sort" in n or "scatter" in n}, found
+    assert "gather" in found and ("cumsum" in found, "while" in found) == \
+        (slots > moe_mod.HELD_ROUND_ROWS,) * 2, found
+
+
+def test_layers_of_one_shape_trace_their_rounds_once():
+    """Set-up: the many-token rounds are a jit of their own, so a stack's
+    sparse layers (here three, each its own weights) hold ONE traced body
+    between them — the program's trace and lowering do not grow by a
+    round's operations a layer."""
+    from deepspeed_tpu.parallel import moe as moe_mod
+    cfg, moe, x, valid = _many_tokens("holes")
+    layers = [jax.tree.map(lambda a, i=i: a * (1.0 + i), moe)
+              for i in range(3)]
+
+    def stack(layers, x):
+        for p in layers:
+            x = x + moe_mod.held_experts_moe_layer(cfg, p, x, valid=valid)[0]
+        return x
+    calls = [eqn for eqn in jax.make_jaxpr(stack)(layers, x).jaxpr.eqns
+             if eqn.primitive.name in ("pjit", "jit")
+             and eqn.params["name"] == "_held_rounds"]
+    assert len(calls) == 3
+    assert len({id(eqn.params["jaxpr"]) for eqn in calls}) == 1
 
 
 @pytest.mark.parametrize("window", [None, 8])

@@ -414,8 +414,11 @@ _TWO_RUNGS = (1024, 2048)
 #: PR 40 (bytes; this compiler, the tree at PR 39). The TWO-RUNG program's
 #: instance at 1,024 slots runs attention on two row groups — 8 rows at the
 #: chunk's width and 64 rows of one query — and must not grow them:
-#: measured +0.5, +0.6 and +1.1 MB (the groups' index arrays), so 2 MB of
-#: room
+#: measured +0.5, +0.6 and +1.1 MB (the groups' index arrays); since PR 57
+#: the held experts' many-token dispatch keeps its ``[slots, k, H]`` pick
+#: mask (a byte an element, H padded to 128 lanes: 2 MB at 2,048 slots x 8
+#: picks) where the sorted form kept ``[slots·k]`` keys: +2.3 and +2.7 MB
+#: in the two stacks with experts, so 4 MB of room
 _SPLIT_TEMPS_PR39 = {"uniform": 457330176, "mimo": 1046920704,
                      "latent": 1631744000}
 
@@ -460,7 +463,7 @@ def _check_split_groups(stack, compiled, text, kernel, layer_loops,
     — whose temporaries are no larger than before the groups."""
     _check_three_rungs(compiled, text, kernel, layer_loops, two_rungs)
     temp = two_rungs.memory_analysis().temp_size_in_bytes
-    assert temp <= _SPLIT_TEMPS_PR39[stack] + 2e6, temp
+    assert temp <= _SPLIT_TEMPS_PR39[stack] + 4e6, temp
 
 
 def _serve_step(one_chip, kind="split", capacities=None):

@@ -22,7 +22,12 @@ float32 reference's FULL FORWARD of the same tokens
 routing flips and EVERY position is judged, by ``F32_LOGIT_DIFF_LIMIT``.
 Then the same walk through programs WRONG in one way each, which must not
 pass: a row at position 0 left with what its slot held, the q / k head
-norms dropped, bf16 weights.
+norms dropped, bf16 weights. ``--skew`` (PR 57): the selection bias sends
+EVERY token to the first held expert too, in the program and in the
+reference alike, so a split launch of the long prompt (a chunk of 128
+beside the 41 one-token rows) fills that expert's 128 rows and takes a
+second round of the many-token dispatch (``parallel/moe.
+held_experts_moe_layer``); ``--only none`` runs no control.
 
 ``--kernel`` first times the split step's history read at the cell's shapes
 — 64 rows of one query and a chunk group ``[8, 128]``, 8 KV heads of 64
@@ -112,6 +117,7 @@ def main():
     ap.add_argument("--kernel", action="store_true")
     ap.add_argument("--phases", default="float32")
     ap.add_argument("--only", default="")
+    ap.add_argument("--skew", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
 
@@ -157,6 +163,9 @@ def main():
         if "moe" in lp:
             lp["moe"]["router_bias"] = jnp.asarray(
                 rng.normal(0, 0.02, cfg.num_experts), jnp.float32)
+            if args.skew:
+                lp["moe"]["router_bias"] = lp["moe"]["router_bias"].at[
+                    cfg.experts_held[0]].set(10.0)
     vocab = cfg.vocab_size
     judged = {0: rng.integers(0, vocab, args.prompt + args.steps),
               1: rng.integers(0, vocab, 16 + args.steps)}

@@ -85,9 +85,18 @@ with no packed shape, the row form, by its rows x chunk), so a lifted
 launch stands beside the one it replaced in ONE trace where a window holds
 both; ``split_steps.by_program`` counts the window's launches the same way.
 
+Since PR 57 ``window`` also holds ``moe_assignments`` and
+``moe_buffer_rows`` (the rows the held experts' many-token dispatch computed
+in its first rounds; 0 on a tree without the counter), and ``--scope-ops
+moe[,moe_experts]`` adds to each rung of ``split_by_rung`` an ``ops_ms``
+field: the named scopes' device operations of that rung's launches, ms a
+launch, under ``<instruction name without its number> <opcode> <result
+shape>`` — a layer's copies of one operation summed —, the heaviest 30 a
+scope (which of a scope's operations carries its time).
+
     chiprun --chips 1 -- python3 tools/host_path_probe.py \
         --workload <cell> --seed <n> --trace <0|1> [--clients N] \
-        [--traffic NAME] [--trace-seconds S] [--own-rows]
+        [--traffic NAME] [--trace-seconds S] [--own-rows] [--scope-ops a,b]
 """
 import argparse
 import json
@@ -105,7 +114,8 @@ NAMES = ("dispatch/host_seconds", "dispatch/fetch_wait_seconds",
          "dispatch/ahead_rows_dropped")
 WORK = ("steps.split", "split_grouped_steps", "chunk_rows",
         "attn_row_slots", "tokens", "token_slots", "kv_pages_walked",
-        "kv_page_fetches", "split_lifted_steps")
+        "kv_page_fetches", "split_lifted_steps", "moe_assignments",
+        "moe_buffer_rows")
 #: (slots, chunk rows) of the instances ``split_steps.fits`` asks about
 RUNGS = ((256, 2), (256, 8), (512, 4), (512, 8), (1024, 8))
 CACHE_COUNTERS = ("evict_calls", "evict_scans", "pages_evicted")
@@ -201,8 +211,19 @@ def split_steps(launches):
                      for tokens, rows in sorted(hist)}}
 
 
+def op_label(ev):
+    """A device operation's label in the per-operation lists:
+    ``<instruction name without its number> <opcode> <result shape>``, so
+    that a layer's copies of one operation sum."""
+    import re
+    from benchmark.trace import reduce
+    made = re.match(r"^\(?(\w+\[[\d,]*\])", ev[0].split(" = ", 1)[-1])
+    return " ".join((re.sub(r"\.\d+", "", reduce.op_name(ev)),
+                     reduce.opcode(ev), made.group(1) if made else ""))
+
+
 def split_by_rung(trace, ladder_of=None,
-                  capacities=(256, 512, 1024, 2048)):
+                  capacities=(256, 512, 1024, 2048), ops_of=()):
     """The ``split_by_rung`` line from a loaded trace (``reduce.load``'s
     form): every ``serve_split_*`` launch of device 0, put down to the
     capacity whose packed shapes (``[1, capacity, ...]`` or ``[capacity,
@@ -213,7 +234,8 @@ def split_by_rung(trace, ladder_of=None,
     it is a rung of the program that ran (an 8 x 128 row form holds
     ``[256, ...]`` results: 8 rows x 32 heads); a program without a ladder,
     or a launch with no such shape, ran the row form, its program's rows x
-    chunk. None without such a launch."""
+    chunk. ``ops_of``: scopes whose operations a rung also lists
+    (``ops_ms``, module docstring). None without such a launch."""
     import bisect
     import re
     from benchmark.trace import reduce, scopes
@@ -236,7 +258,8 @@ def split_by_rung(trace, ladder_of=None,
                     ladder_of(*map(int, row_form.search(program).groups()))
             return ladders[program]
         starts = [m[0] for m in mods]
-        per = [({}, {}) for _ in mods]      # (by scope, by capacity) ns
+        # (by scope, by capacity, by (scope, operation)) ns
+        per = [({}, {}, {}) for _ in mods]
         for ev, self_ns in reduce.self_times(
                 reduce.line_events(plane, reduce.OPS_LINE)):
             k = bisect.bisect_right(starts, ev[1]) - 1
@@ -245,28 +268,38 @@ def split_by_rung(trace, ladder_of=None,
             entry = tables.get(mods[k][2], {}).get(reduce.op_name(ev)) or {}
             scope = entry.get("scope") or scopes.NO_SCOPE
             per[k][0][scope] = per[k][0].get(scope, 0.0) + self_ns
+            if scope in ops_of:
+                op = (scope, op_label(ev))
+                per[k][2][op] = per[k][2].get(op, 0.0) + self_ns
             m = shape.match(ev[0].split(" = ", 1)[-1])
             if m and int(m.group(1)) in rungs_of(mods[k][2]):
                 cap = int(m.group(1))
                 per[k][1][cap] = per[k][1].get(cap, 0.0) + self_ns
         rungs = {}
-        for (_t0, _t1, program), (by_scope, by_cap) in zip(mods, per):
+        for (_t0, _t1, program), (by_scope, by_cap, by_op) in zip(mods, per):
             if by_cap:
                 cap = max(by_cap, key=by_cap.get)
             else:
                 rows, chunk = map(int, row_form.search(program).groups())
                 cap = rows * chunk
-            rung = rungs.setdefault(f"{program}@{cap}", [0, 0.0, {}])
+            rung = rungs.setdefault(f"{program}@{cap}", [0, 0.0, {}, {}])
             rung[0] += 1
             rung[1] += sum(by_scope.values())
-            for scope, ns in by_scope.items():
-                rung[2][scope] = rung[2].get(scope, 0.0) + ns
+            for into, by in ((rung[2], by_scope), (rung[3], by_op)):
+                for name, ns in by.items():
+                    into[name] = into.get(name, 0.0) + ns
+
+        def heaviest(by, n, top=None):
+            return {name: round(ns / n / 1e6, 3) for name, ns in sorted(
+                by.items(), key=lambda kv: -kv[1])[:top]}
         return {"phase": "split_by_rung", "rungs": {
             name: {"launches": n, "device_ms": busy / n / 1e6,
-                   "scopes_ms": {scope: round(ns / n / 1e6, 3) for scope, ns
-                                 in sorted(by_scope.items(),
-                                           key=lambda kv: -kv[1])}}
-            for name, (n, busy, by_scope) in sorted(rungs.items())}}
+                   "scopes_ms": heaviest(by_scope, n),
+                   **({"ops_ms": {scope: heaviest(
+                       {op: ns for (sc, op), ns in by_op.items()
+                        if sc == scope}, n, 30) for scope in ops_of}}
+                      if ops_of else {})}
+            for name, (n, busy, by_scope, by_op) in sorted(rungs.items())}}
     return None
 
 
@@ -279,6 +312,7 @@ def own_arguments():
     ap.add_argument("--traffic", default=None)
     ap.add_argument("--trace-seconds", type=float, default=None)
     ap.add_argument("--own-rows", action="store_true")
+    ap.add_argument("--scope-ops", default="")
     own, sys.argv[1:] = ap.parse_known_args()
     if (own.clients, own.traffic, own.trace_seconds) != (None,) * 3:
         from benchmark.lib import traffic
@@ -361,7 +395,8 @@ def main() -> int:
         line = split_by_rung(
             traces[-1], engine and (lambda rows, chunk:
                                     engine._token_capacities(rows, chunk,
-                                                             "split")))
+                                                             "split")),
+            ops_of=tuple(filter(None, own.scope_ops.split(","))))
         if line is not None:
             print(json.dumps(line), flush=True)
     if built:
