@@ -991,13 +991,14 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             toks = lay.to_tokens(tokens)
         x, dtype = tl.residual_stream(      # float32, whatever the weights'
             embed_tokens(cfg, params["embed"], toks, lay.positions))
+        x = tl.stream_open(cfg, x)          # (one hidden state a token: x)
         tables = tl.rope_tables(cfg, lay.positions)
         pools = dict(arena, **(state or {}))
         chunk_kv = []
         sel = {}        # the picks an owner of an indexer leaves its borrowers
         for kind, lp, place, iplace in zip(cfg.layer_kinds, params["layers"],
                                            places, index_places):
-            h = _norm(cfg, lp["ln1"], x)
+            h, maps = tl.layer_input(cfg, lp, x)
             if kind in STATE_SPACE_KINDS:
                 out = state_space(lay, kind, tl.mixer_tree(kind, lp), place,
                                   h.astype(dtype), pools)
@@ -1012,8 +1013,9 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                     lay, kind, lp["attn"], place, h.astype(dtype),
                     tables[kind], pools, chunk_kv))
             x = tl.block_residual(cfg, lp, x, h, out, moe_fn, lay.valid,
-                                  dtype)
-        x = _norm(cfg, params["final_norm"], x).astype(dtype)
+                                  dtype, maps)
+        x = _norm(cfg, params["final_norm"],
+                  tl.stream_close(cfg, x)).astype(dtype)
         with jax.named_scope("lm_head"):       # the rows the head projects
             if not split:
                 return lay.last(x), pools
@@ -1422,6 +1424,11 @@ class RaggedInferenceEngineTPU:
             self._moe_buffer_rows = (
                 HELD_ROUND_ROWS,
                 HELD_ROUND_ROWS * model.num_held_experts * sparse)
+        #: the hyper-connection maps ONE token slot's pass solves: two a
+        #: layer of a stream several hidden states wide
+        #: (``dispatch/hc_maps``); 0: the stream is one hidden state
+        self._hc_maps_per_slot = \
+            2 * model.num_layers if model.hc_mult > 1 else 0
         #: jit cache keyed on (n_bucket, c_bucket, mode, fresh) — the
         #: fresh=True/False split legitimately doubles prefill-shape
         #: compiles (arena-reading vs within-chunk attention programs).
@@ -2281,7 +2288,10 @@ class RaggedInferenceEngineTPU:
         held experts x ``HELD_ROUND_ROWS`` x sparse layers — the rows the
         first round's buffers hold — for a launch of more than
         ``HELD_ROUND_ROWS`` ``slots``, 0 for one of fewer (every held
-        expert computes every token there)."""
+        expert computes every token there). A stream several hidden states
+        wide (``hc_mult`` over 1) adds ``dispatch/hc_maps`` and the span's
+        ``hc_maps``: ``slots`` x 2 x layers, the hyper-connection maps —
+        a Sinkhorn solve each — the launch ran."""
         from deepspeed_tpu.telemetry.registry import registry
         row_slots = nb * chunk
         slots = row_slots if token_slots is None else token_slots
@@ -2346,6 +2356,9 @@ class RaggedInferenceEngineTPU:
             work["moe_buffer_rows"] = rows * (slots > few)
             registry.counter("dispatch/moe_buffer_rows").inc(
                 work["moe_buffer_rows"])
+        if self._hc_maps_per_slot:
+            work["hc_maps"] = slots * self._hc_maps_per_slot
+            registry.counter("dispatch/hc_maps").inc(work["hc_maps"])
         return work
 
     # -- convenience generation loop ---------------------------------------

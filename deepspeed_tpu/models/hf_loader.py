@@ -36,7 +36,7 @@ _FAMILIES = ("llama", "mistral", "mixtral", "qwen", "qwen2", "qwen2_moe",
               "phi", "phi3", "gpt_bigcode", "gptj", "bert", "distilbert",
               "gpt_neo", "internlm", "mimo_v2", "deepseek_v3",
               "cohere2_moe", "nemotron_h", "granitemoehybrid", "jamba",
-              "glm_moe_dsa", "lfm2_moe")
+              "glm_moe_dsa", "lfm2_moe", "xing4_0")
 
 
 def _map_hf_act(act: str) -> str:
@@ -72,6 +72,8 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
         return _glm_moe_dsa_config(hf)
     if mt == "lfm2_moe":
         return _lfm2_moe_config(hf)
+    if mt == "xing4_0":
+        return _xing4_config(hf)
     if mt == "bert":
         return DecoderConfig(
             hidden_size=hf["hidden_size"],
@@ -499,8 +501,12 @@ def _deepseek_v3_config(hf: Dict[str, Any]) -> DecoderConfig:
     answers as published whatever the cache holds (one latent row a
     token). Not built, and accepted: ``num_nextn_predict_layers`` (the
     multi-token-prediction module; HF's ``deepseek_v3`` drops those weights
-    on load as well). ``expert_share``: :func:`_sigmoid_router`."""
-    for key, want in (("attention_bias", False), ("ep_size", 1)):
+    on load as well). ``expert_share``: :func:`_sigmoid_router`. A stream
+    of several hidden states (``hc_mult`` over 1) is ``xing4_0``'s
+    (:func:`_xing4_config`, which takes its keys out before it calls this):
+    refused by name here, not ignored."""
+    for key, want in (("attention_bias", False), ("ep_size", 1),
+                      ("hc_mult", 1)):
         if hf.get(key, want) != want:
             raise ValueError(f"deepseek_v3: {key}={hf[key]!r} is not built "
                              f"(expected {want!r})")
@@ -994,6 +1000,43 @@ def _is_neox_layout(cfg: DecoderConfig) -> bool:
             and not cfg.lm_head_bias)
 
 
+#: the residual path's keys of ``xing4_0``, with the rounds' clamp
+_XING4_HC_KEYS = ("hc_mult", "hc_sinkhorn_iters", "hc_eps",
+                  "mhc_h_res_clamp_min", "mhc_h_res_clamp_max")
+
+
+def _xing4_config(hf: Dict[str, Any]) -> DecoderConfig:
+    """Xing4.0's block (XingChen-AGI Xing4.0-29B-A4B; ``model_type:
+    xing4_0``): DeepSeek-V3's latent block (:func:`_deepseek_v3_config`
+    reads every width, the YaRN keys — under ``rope_scaling.type`` —, the
+    one-group sigmoid router and the shared expert) on a residual stream of
+    ``hc_mult`` hidden states a token, mixed by manifold-constrained
+    hyper-connections (models/typed_layers.py has the equations):
+    ``hc_sinkhorn_iters`` rounds a map, each sum + ``hc_eps``, the logits
+    clipped to ``mhc_h_res_clamp_min`` / ``_max``. ``hc_mult`` 1 is the
+    parent family's stream and builds no maps; under 1, or over 1 with no
+    ``hc_sinkhorn_iters``, is refused by name. Accepted and not built, as
+    the parent family's: ``num_nextn_predict_layers``."""
+    n = hf.get("hc_mult", 1)
+    if not isinstance(n, int) or n < 1:
+        raise ValueError(f"xing4_0: hc_mult={n!r} is not built (a whole "
+                         f"number of hidden states a token, at least 1)")
+    if n > 1 and not hf.get("hc_sinkhorn_iters"):
+        raise ValueError(
+            f"xing4_0: hc_sinkhorn_iters={hf.get('hc_sinkhorn_iters')!r} "
+            f"is not built (hc_mult={n} needs the rounds that make H_res "
+            f"doubly stochastic)")
+    base = _deepseek_v3_config({k: v for k, v in hf.items()
+                                if k not in _XING4_HC_KEYS})
+    if n == 1:
+        return base
+    return dataclasses.replace(
+        base, hc_mult=n, hc_sinkhorn_iters=int(hf["hc_sinkhorn_iters"]),
+        hc_eps=float(hf.get("hc_eps", 1e-6)),
+        hc_res_clamp=(float(hf.get("mhc_h_res_clamp_min", -30.0)),
+                      float(hf.get("mhc_h_res_clamp_max", 30.0))))
+
+
 def config_to_hf(cfg: DecoderConfig) -> Dict[str, Any]:
     def act_name(exact_name="gelu", tanh_name="gelu_new"):
         """HF 'gelu' is exact erf; tanh-approx models must export the
@@ -1005,8 +1048,8 @@ def config_to_hf(cfg: DecoderConfig) -> Dict[str, Any]:
     if cfg.typed:
         raise NotImplementedError(
             "config_to_hf: a typed layer stack (mimo_v2, deepseek_v3, "
-            "cohere2_moe, nemotron_h, granitemoehybrid, jamba, lfm2_moe) "
-            "has no exporter")
+            "cohere2_moe, nemotron_h, granitemoehybrid, jamba, lfm2_moe, "
+            "xing4_0) has no exporter")
     if not cfg.causal or not cfg.prenorm:
         # encoder layouts (BERT/DistilBERT): both flags flip together
         if cfg.causal or cfg.prenorm or cfg.pos_emb != "learned" \

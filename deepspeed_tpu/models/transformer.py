@@ -281,6 +281,18 @@ class DecoderConfig:
     #: the scores' factor where the model states one; None →
     #: ``head_dim ** -0.5`` (:attr:`attn_scale`)
     attention_multiplier: Optional[float] = None
+    # -- manifold-constrained hyper-connections (mHC; Xing4.0's ``xing4_0``;
+    # typed_layers.py has the equations): the residual stream between the
+    # layers of a typed stack is ``hc_mult`` hidden states a token, and
+    # every sublayer reads a learned, token-dependent mix of them and writes
+    # back through a doubly stochastic ``hc_mult x hc_mult`` matrix
+    # (``hc_sinkhorn_iters`` rounds of column / row normalisation, each sum
+    # + ``hc_eps``, from ``exp`` of logits clipped to ``hc_res_clamp``).
+    # 1 → ONE hidden state a token, ``x + out``: no operation is added
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     def __post_init__(self):
         if self.mlm_head and not self.tie_embeddings:
@@ -341,6 +353,18 @@ class DecoderConfig:
                 for l, kind in enumerate(self.layer_kinds)):
             raise ValueError("a layer with no mixer (layer kind -1) and no "
                              "feed-forward part (layer_sparse -1) is empty")
+        if self.hc_mult < 1 or (self.hc_mult > 1 and not (
+                self.typed and self.hc_sinkhorn_iters >= 1 and
+                not self.parallel_block and
+                self.residual_multiplier == 1.0 and all(
+                    kind >= 0 and self.layer_has_ffn(l)
+                    for l, kind in enumerate(self.layer_kinds)))):
+            raise ValueError(
+                "hc_mult (a residual stream of several hidden states) is at "
+                "least 1; over 1 it needs a typed stack of sequential "
+                "two-part layers (a mixer AND a feed-forward part, each "
+                "with its own maps; no residual multiplier) and "
+                "hc_sinkhorn_iters >= 1")
         if self.num_experts and self.num_experts % self.router_groups:
             raise ValueError(f"router_groups {self.router_groups} does not "
                              f"divide num_experts {self.num_experts}")

@@ -174,6 +174,41 @@ layer has a ``conv<i>`` pool and no ``ssm<i>``, in the same slots and
 resets as kinds 3 and 4 (scope ``conv_state``: the gather of a row's tail,
 a fresh row's reset, the write-back).
 
+A stack with HYPER-CONNECTIONS (manifold-constrained, mHC; Xing4.0's,
+``hf_loader``: ``xing4_0``; ``cfg.hc_mult`` ``n`` > 1) is DeepSeek-V3's
+latent block on a residual stream of ``n`` hidden states a token, ``X [n,
+C]`` float32 (``C = hidden_size``; here a TUPLE of ``n`` arrays):
+``X₀[i] = E[ids]`` for every ``i`` (:func:`stream_open`). Each layer has
+TWO sublayers ``s`` — attention, the feed-forward part — each with its own
+tree ``hc_attn`` / ``hc_ffn`` {``phi`` ``[nC, n² + 2n]``, ``base`` ``[n² +
+2n]``, ``scale`` ``[3]`` = ``α^pre, α^post, α^res``}:
+
+- ``x̃ = vec(X)·rsqrt(mean(vec(X)²) + norm_eps)``: an RMS norm over all
+  ``nC`` values, no learned scale; ``m = x̃·phi`` (float32,
+  ``Precision.HIGHEST``) → ``m_pre [n]``, ``m_post [n]``, ``m_res [n, n]``;
+- ``H_pre = σ(α^pre·m_pre + b_pre)``; ``H_post = 2·σ(α^post·m_post +
+  b_post)``; ``M⁰ = exp(clip(α^res·m_res + b_res, hc_res_clamp))``, then
+  ``hc_sinkhorn_iters`` times: every column over (its sum + ``hc_eps``),
+  then every row over (its sum + ``hc_eps``); ``H_res`` = the last ``M``,
+  doubly stochastic to the rounds' precision (scope ``hc_maps``:
+  :func:`stream_read`'s first half);
+- ``u = Σ_i H_pre[i]·X[i]``; ``y = F_s(RMSNorm_s(u))``: the latent
+  attention with ``ln1``, or the dense SiLU-GLU / the experts + the shared
+  expert with ``ln2``; ``X'[i] = Σ_j H_res[i, j]·X[j] + H_post[i]·y``
+  (scope ``hc_mix``: :func:`stream_read`'s second half,
+  :func:`stream_write`);
+- after the last layer ``x = Σ_i X[i]`` (:func:`stream_close`), the final
+  RMSNorm, the untied head.
+
+At ``hc_mult`` 1 the four functions ARE ``x``, ``x``, ``x + y`` and ``x``:
+no operation is traced, and every sequential two-part stack above runs
+through them (:func:`layer_input`, :func:`block_residual`, in the uncached
+forward and in every step program of ``inference/engine_v2``). A wider
+stream is built for sequential layers of a mixer AND a feed-forward part —
+a parallel block, a one-part layer and a residual multiplier are refused
+with it by name (``DecoderConfig``): no published stack pairs them. How
+the rounds are laid out for the chip: :func:`_sinkhorn`.
+
 **The residual stream is float32** whatever the parameters' dtype
 (:func:`residual_stream`): the matmuls take the norms' outputs cast to the
 compute dtype, their results are added in float32, and the router reads
@@ -198,6 +233,8 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from deepspeed_tpu.models import transformer as tf
 from deepspeed_tpu.ops import paged_attention as pa
@@ -219,7 +256,9 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     adds ``q_norm`` / ``k_norm`` {scale} to ``attn``; a layer with
     no mixer has neither; a layer with no
     feed-forward part has no ``mlp`` / ``moe``; un-gated (``relu2``)
-    experts have no ``wg``."""
+    experts have no ``wg``; ``cfg.hc_mult`` > 1 adds ``hc_attn`` /
+    ``hc_ffn`` {phi — in ``dtype``, as the matrices are —, base, scale —
+    float32 whatever ``dtype``}."""
     if not (cfg.is_glu or cfg.activation == "relu2") or cfg.use_bias or \
             cfg.ln_bias or cfg.pos_emb != "rope" or \
             (cfg.parallel_block and cfg.parallel_block_norms != 1):
@@ -245,10 +284,31 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
         return (jax.random.normal(next(keys), shape, jnp.float32) * std
                 ).astype(dtype)
 
+    def maps(key):
+        """One sublayer's hyper-connection maps: ``phi`` at the stack's init
+        std — the dynamic term ``α·m`` then has a standard deviation of
+        ``init_std·√(nC)`` a logit: 2.4 at Xing4.0's widths —, ``α`` 1, the
+        pre / post biases 0, ``b_res`` standard normal (a zero ``b_res``
+        would start every ``H_res`` from the uniform matrix where ``m`` is
+        small, and a test of the rounds vacuous). Keys of its own, folded
+        from the stack's: the draws of the trees every stack has stay what
+        a seed gave them."""
+        n = cfg.hc_mult
+        k_phi, k_res = jax.random.split(key)
+        phi = jax.random.normal(k_phi, (n * d, n * n + 2 * n), jnp.float32)
+        return {"phi": (phi * cfg.init_std).astype(dtype),
+                "base": jnp.concatenate([
+                    jnp.zeros((2 * n,), jnp.float32),
+                    jax.random.normal(k_res, (n * n,), jnp.float32)]),
+                "scale": jnp.ones((3,), jnp.float32)}
+
     layers = []
     for l, kind in enumerate(cfg.layer_kinds):
         kvh = cfg.kind_kv_heads(kind)
         lp = {"ln1": tf._norm_params(cfg)}
+        if cfg.hc_mult > 1:
+            lp["hc_attn"], lp["hc_ffn"] = map(maps, jax.random.split(
+                jax.random.fold_in(rng, 1 + l)))
         if kind == 3:
             lp["ssm"] = _init_ssm(cfg, w, next(keys), out_std)
         elif kind == 4:
@@ -516,6 +576,125 @@ def residual_stream(x: jax.Array) -> Tuple[jax.Array, Any]:
     return x.astype(jnp.float32), x.dtype
 
 
+#: ``H_post`` is TWICE a sigmoid: a write-back gate in (0, 2), 1 at a zero
+#: logit (mHC: the plain residual's ``x + y`` is the maps' starting point)
+HC_POST_GAIN = 2.0
+
+
+def stream_open(cfg, x: jax.Array):
+    """The float32 embedding [.., C] as the stream the layers carry: itself,
+    or (``cfg.hc_mult`` ``n`` > 1) copied into ``n`` hidden states a token,
+    a tuple of ``n`` arrays."""
+    if cfg.hc_mult == 1:
+        return x
+    return (x,) * cfg.hc_mult
+
+
+def stream_close(cfg, x) -> jax.Array:
+    """What the final norm reads: the stream, or the sum of its ``n``
+    hidden states (Hyper-Connections' readout)."""
+    if cfg.hc_mult == 1:
+        return x
+    with jax.named_scope("hc_mix"):
+        return functools.reduce(jnp.add, x)
+
+
+def _hc_rms_factor(cfg, x) -> jax.Array:
+    """``rsqrt(mean(vec(X)²) + norm_eps)`` [.., 1]: the RMS norm over ALL
+    ``n·C`` values of a token, no learned scale."""
+    ms = functools.reduce(jnp.add, (
+        jnp.mean(jnp.square(xi), axis=-1, keepdims=True) for xi in x))
+    return lax.rsqrt(ms * (1.0 / len(x)) + cfg.norm_eps)
+
+
+def _sinkhorn(m, n: int, iters: int, eps: float):
+    """``m[n·i + j]``: positive arrays of one shape, the row-major entries
+    of an ``n x n`` matrix a token → the same after ``iters`` rounds of:
+    every column over (its sum + ``eps``), then every row over (its sum +
+    ``eps``). Written on the ``n²`` arrays one by one — sums of ``n``
+    operands, a reciprocal and ``n`` products, the tokens on the lanes — as
+    the body of ONE ``fori_loop``: on the v5e the rounds cost a launch
+    nothing that can be measured in any form (12 sublayers at 64 / 512 /
+    2,048 slots: 0.81 / 1.50 / 5.53 ms, with NO rounds 0.82 / 1.43 / 6.04;
+    ``tools/bench_hc_maps.py``, PERF.md §5), and written out 20 times they
+    were two thirds of a step program's instructions and of its compile
+    time."""
+    def one_round(_, m):
+        m = list(m)
+        for j in range(n):
+            inv = 1.0 / (functools.reduce(
+                jnp.add, (m[n * i + j] for i in range(n))) + eps)
+            for i in range(n):
+                m[n * i + j] = m[n * i + j] * inv
+        for i in range(n):
+            inv = 1.0 / (functools.reduce(jnp.add, m[n * i:n * i + n]) + eps)
+            for j in range(n):
+                m[n * i + j] = m[n * i + j] * inv
+        return tuple(m)
+
+    return lax.fori_loop(0, iters, one_round, tuple(m))
+
+
+def hc_maps(cfg, hc, x):
+    """One sublayer's three maps from the stream ``x`` (a tuple of ``n``
+    [.., C] float32) and its tree ``hc`` {phi, base, scale} → ``(H_pre
+    [n], H_post [n], H_res [n][n])``, each entry a [.., 1] float32 array:
+    the module docstring's first two bullets. The logits are made with the
+    coefficients' index FIRST — ``[n² + 2n, slots]``: each coefficient's
+    values lie with the slots on the lanes through the activations and the
+    rounds (:func:`_sinkhorn`)."""
+    n, lead = cfg.hc_mult, x[0].shape[:-1]
+    with jax.named_scope("hc_maps"):
+        x = [xi.reshape(-1, cfg.hidden_size) for xi in x]
+        phi = hc["phi"].astype(jnp.float32).reshape(n, cfg.hidden_size, -1)
+        # ``x̃·phi = r·(vec(X)·phi)``: a product a hidden state, scaled after
+        m = functools.reduce(jnp.add, (
+            jnp.einsum("sc,cj->js", xi, phi[i],
+                       precision=lax.Precision.HIGHEST)
+            for i, xi in enumerate(x)))
+        alpha = jnp.repeat(hc["scale"], np.asarray([n, n, n * n]),
+                           total_repeat_length=m.shape[0])
+        z = alpha[:, None] * (m * _hc_rms_factor(cfg, x)[:, 0]) + \
+            hc["base"][:, None]
+        pre = jax.nn.sigmoid(z[:n])
+        post = HC_POST_GAIN * jax.nn.sigmoid(z[n:2 * n])
+        res = jnp.exp(jnp.clip(z[2 * n:], *cfg.hc_res_clamp))
+        res = jnp.stack(_sinkhorn([res[k] for k in range(n * n)], n,
+                                  cfg.hc_sinkhorn_iters, cfg.hc_eps))
+        # back to a token a row, one column a coefficient: what scales a
+        # hidden state is [.., 1] beside its [.., C]
+        cols = jnp.concatenate([pre, post, res]).T.reshape(lead + (-1,))
+        col = lambda k: cols[..., k:k + 1]
+        return [col(i) for i in range(n)], [col(n + i) for i in range(n)], \
+            [[col(2 * n + n * i + j) for j in range(n)] for i in range(n)]
+
+
+def stream_read(cfg, hc, x):
+    """What a sublayer's norm reads, and the maps its write-back takes:
+    ``(x, None)``, or (``hc``: the sublayer's maps' tree) ``(Σ_i H_pre[i]·
+    X[i], (H_post, H_res))``."""
+    if hc is None:
+        return x, None
+    pre, post, res = hc_maps(cfg, hc, x)
+    with jax.named_scope("hc_mix"):
+        u = functools.reduce(jnp.add, (p * xi for p, xi in zip(pre, x)))
+    return u, (post, res)
+
+
+def stream_write(cfg, maps, x, y: jax.Array):
+    """The stream after a sublayer's branch sum ``y`` [.., C]: ``x + y``,
+    or (``maps`` from :func:`stream_read`) ``X'[i] = Σ_j H_res[i, j]·X[j] +
+    H_post[i]·y``."""
+    if maps is None:
+        return x + y
+    post, res = maps
+    with jax.named_scope("hc_mix"):
+        y = y.astype(jnp.float32)
+        return tuple(
+            functools.reduce(jnp.add, (h * xj for h, xj in zip(row, x)))
+            + p * y for row, p in zip(res, post))
+
+
 def typed_ffn(cfg, lp, h: jax.Array, moe_fn: Optional[Callable],
               valid: Optional[jax.Array] = None, dtype=None) -> jax.Array:
     """The layer's second half on its normed input ``h`` (float32): the
@@ -555,17 +734,30 @@ def _branch(cfg, lp, part: str, out: jax.Array) -> jax.Array:
         return out.astype(jnp.float32) * cfg.residual_multiplier
 
 
-def block_residual(cfg, lp, x: jax.Array, h: jax.Array,
+def layer_input(cfg, lp, x):
+    """A layer's first norm (float32: what its mixer reads) and what
+    :func:`block_residual` takes beside it: the first sublayer's maps where
+    the stream is several hidden states wide (:func:`stream_read`), else
+    None."""
+    u, maps = stream_read(cfg, lp.get("hc_attn"), x)
+    return tf._norm(cfg, lp["ln1"], u), maps
+
+
+def block_residual(cfg, lp, x, h: jax.Array,
                    mixer_out: Optional[jax.Array],
-                   moe_fn: Optional[Callable], valid, dtype) -> jax.Array:
+                   moe_fn: Optional[Callable], valid, dtype, maps=None):
     """The float32 stream after a layer, given ``h`` (the layer's first
-    norm of ``x``, float32: what the mixer read) and the mixer's output
+    norm, float32: what the mixer read) and ``maps`` (:func:`layer_input`)
+    and the mixer's output
     (attention's or a state-space mixer's; None: the layer has none, and
     its feed-forward part reads ``h``): sequential (``x + a``, then the
     feed-forward on ``norm2`` of that) or PARALLEL (``cfg.parallel_block``:
     the feed-forward reads the SAME ``h``, and both are added). A layer
     whose tree has no feed-forward part is ``x + a``. Each branch sum joins
-    the stream through :func:`_branch` (``cfg.residual_multiplier``)."""
+    the stream through :func:`_branch` (``cfg.residual_multiplier``) and
+    :func:`stream_write` — ``x + a`` on a stream of ONE hidden state; on a
+    wider one each of the two sublayers reads (:func:`stream_read`) and
+    writes through its own maps."""
     def ffn(h_in):
         return _branch(cfg, lp, "ffn",
                        typed_ffn(cfg, lp, h_in, moe_fn, valid, dtype))
@@ -576,8 +768,9 @@ def block_residual(cfg, lp, x: jax.Array, h: jax.Array,
         return x + ffn(h)
     if cfg.parallel_block:
         return x + _branch(cfg, lp, "mixer", mixer_out) + ffn(h)
-    x = x + _branch(cfg, lp, "mixer", mixer_out)
-    return x + ffn(tf._norm(cfg, lp["ln2"], x))
+    x = stream_write(cfg, maps, x, _branch(cfg, lp, "mixer", mixer_out))
+    u, maps = stream_read(cfg, lp.get("hc_ffn"), x)
+    return stream_write(cfg, maps, x, ffn(tf._norm(cfg, lp["ln2"], u)))
 
 
 def _linear_f32(x: jax.Array, p, name: str) -> jax.Array:
@@ -781,9 +974,10 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
             jnp.arange(t, dtype=jnp.int32)[None], (b, t))
     x, dtype = residual_stream(
         tf.embed_tokens(cfg, params["embed"], tokens, positions))
+    x = stream_open(cfg, x)
     tables = rope_tables(cfg, positions)
     for l, (kind, lp) in enumerate(zip(cfg.layer_kinds, params["layers"])):
-        h32 = tf._norm(cfg, lp["ln1"], x)
+        h32, maps = layer_input(cfg, lp, x)
         h = h32.astype(dtype)
         if kind in tf.STATE_SPACE_KINDS or kind == -1:
             x = block_residual(
@@ -810,5 +1004,6 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
                            picked if cfg.picks_keys else None)
         x = block_residual(cfg, lp, x, h32,
                            typed_attn_out(cfg, lp["attn"], o), moe_fn, None,
-                           dtype)
-    return tf._norm(cfg, params["final_norm"], x).astype(dtype)
+                           dtype, maps)
+    return tf._norm(cfg, params["final_norm"],
+                    stream_close(cfg, x)).astype(dtype)

@@ -178,7 +178,8 @@ SCOPE_VOCABULARY = (
     "lm_head", "loss", "grad_clip", "optimizer", "sample", "step_misc",
     "attn_latent", "moe_shared",
     "ssm_in", "ssm_conv", "ssm_scan", "ssm_state", "ssm_norm", "ssm_out",
-    "ssm_select", "attn_index", "attn_select", "conv_mixer", "conv_state")
+    "ssm_select", "attn_index", "attn_select", "conv_mixer", "conv_state",
+    "hc_maps", "hc_mix")
 
 _HLO_NAME_RE = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')

@@ -124,7 +124,7 @@ def test_scope_is_the_innermost_vocabulary_word():
         "attn_history"
     assert scope_of_op_name("jit(f)/flash_fwd/pallas_call")["scope"] is None
     assert scope_of_op_name("")["scope"] is None
-    assert len(set(SCOPE_VOCABULARY)) == len(SCOPE_VOCABULARY) == 31
+    assert len(set(SCOPE_VOCABULARY)) == len(SCOPE_VOCABULARY) == 33
     # a latent layer's words, inside and beside the older ones
     assert scope_of_op_name(
         "jit(f)/attn_latent/bthd,lhd->bthl/dot_general")["scope"] == \

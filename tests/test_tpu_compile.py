@@ -1250,6 +1250,78 @@ def test_short_conv_step_compiles_for_v5e(
         mem.temp_size_in_bytes
 
 
+# -- the stack with a stream four hidden states wide (benchmark/configs/
+# xing4.0-29b-a4b-l6-serve): Xing4.0's published widths, ALL 64 experts
+
+#: step -> (chunk, ``fresh_prefill``, capacities, most temporaries at the
+#: two layers dense + sparse: measured 0.02 and 0.94 GB — the split
+#: program's 2,048-slot rung holds the float32 stream four times over)
+_WIDE_STREAM_STEPS = {
+    "decode": (1, False, (), 0.1e9),
+    "split": (128, "split", (512, 1024, 2048), 1.3e9),
+}
+
+
+@pytest.mark.parametrize("kind", list(_WIDE_STREAM_STEPS))
+def test_wide_stream_step_compiles_for_v5e(kind, one_chip,
+                                           no_persistent_cache, monkeypatch,
+                                           capsys):
+    """The 64-row decode and split programs of Xing4.0-29B-A4B's stack at
+    the published widths, cut to one dense and one sparse layer with ALL 64
+    experts and the whole vocabulary, over the cell's arena (2,048 pages of
+    640-lane latent rows): the scopes ``hc_maps`` and ``hc_mix`` beside the
+    block's own, nothing heavy unnamed, ``mla_decode`` reading the pool, NO
+    copy of the pool, temporaries (printed) under the measured ones; and
+    the rounds are ONE loop a sublayer: the maps of a decode program's
+    four sublayers — norm, ``phi`` product, activations, a ``while`` of 20
+    Sinkhorn rounds — are four loops and under 25 fusions each (written
+    out 20 times they were 46 fusions a sublayer and two thirds of the
+    program's instructions: PERF.md §6, PR 58)."""
+    from benchmark.lib import model as model_lib
+    from deepspeed_tpu.models.hf_loader import config_from_hf
+    from deepspeed_tpu.ops import paged_attention as pa
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hf = model_lib.published_keys(
+        model_lib.load_config("xing4.0-29b-a4b-l6-serve"))
+    model = config_from_hf(dict(hf, num_hidden_layers=2))
+    assert model.layer_sparse == (0, 1) and model.hc_mult == 4 and \
+        model.num_held_experts == 64 and model.vocab_size == 131072
+
+    def make_arena():
+        return pa.init_arena_typed(model.layer_kinds, {2: 1}, 2048, 128, 640,
+                                   0, jnp.bfloat16)
+    compiled, text = _typed_step(one_chip, model, _WIDE_STREAM_STEPS[kind],
+                                 32, make_arena)
+    table = scope_table_from_hlo(text)
+    scopes = {e["scope"] for e in table.values()}
+    assert {"hc_maps", "hc_mix", "attn_latent", "attn_qkv", "attn_out",
+            "kv_write", "mlp", "moe", "moe_router", "moe_experts",
+            "moe_shared", "embed", "lm_head"} <= scopes, scopes
+    kernels = [n for n in table if n.startswith("mla_decode")]
+    want = {"decode": "attn_core", "split": "attn_history"}[kind]
+    assert kernels and all(table[n]["scope"] == want for n in kernels)
+    heavy = [m.group(1) for m in _HEAVY.finditer(text)]
+    named = [n for n in heavy if table[n]["scope"] is not None]
+    assert len(named) >= 0.95 * len(heavy), sorted(set(heavy) - set(named))
+    entry = re.search(r"ENTRY[^\n]*\{\n(.*?)\n\}", text, re.S).group(1)
+    fusions = [line for line in entry.splitlines()
+               if " fusion(" in line and "/hc_maps/" in line]
+    loops = [line for line in entry.splitlines()
+             if " while(" in line and "/hc_maps/" in line]
+    if kind == "decode":        # (a split program's lie in its branches)
+        assert len(loops) == 4 and 0 < len(fusions) <= 25 * 4, \
+            (len(loops), len(fusions))
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nxing4.0-29b-a4b {kind} at 2 layers: arguments "
+              f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB, hc_maps in ENTRY: "
+              f"{len(loops)} loops, {len(fusions)} fusions")
+    assert mem.temp_size_in_bytes < _WIDE_STREAM_STEPS[kind][3], \
+        mem.temp_size_in_bytes
+
+
 # -- the stack that picks its keys (benchmark/configs/glm-5.2-l5-e16-serve):
 # GLM-5.2's published widths, an index pool beside the latent pool
 
