@@ -41,20 +41,165 @@ _NEG_INF = -1e30
 
 
 def _mask_scores(s, q_start, k_start, causal: bool,
-                 window) -> "jax.Array":
+                 window, transposed: bool = False) -> "jax.Array":
     """Apply the causal and/or sliding-window visibility mask to one
-    [BQ, BK] score tile (the ONE home for the mask inequalities — used by
-    every fwd/bwd kernel generation)."""
+    [BQ, BK] score tile — ``transposed``: [BK, BQ] — (the ONE home for the
+    mask inequalities — used by every fwd/bwd kernel generation)."""
     if not causal and window is None:
         return s
-    block_q, block_k = s.shape
-    qpos = q_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    kpos = k_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    qpos = q_start + lax.broadcasted_iota(jnp.int32, s.shape, int(transposed))
+    kpos = k_start + lax.broadcasted_iota(jnp.int32, s.shape,
+                                          int(not transposed))
     ok = (qpos >= kpos) if causal else \
         jnp.full_like(qpos, True, dtype=jnp.bool_)
     if window is not None:
         ok = jnp.logical_and(ok, kpos > qpos - window)
     return jnp.where(ok, s, _NEG_INF)
+
+
+def _div(a, b):
+    """``a`` over a positive ``b`` for host ints and traced int32 scalars.
+    The two round a negative quotient differently (floor / toward zero);
+    every caller clamps it at 0, where they agree."""
+    return a // b if isinstance(a, int) else lax.div(a, jnp.int32(b))
+
+
+def _clamp(x, lo, hi):
+    if all(isinstance(a, int) for a in (x, lo, hi)):
+        return min(max(x, lo), hi)
+    return jnp.minimum(jnp.maximum(x, lo), hi)
+
+
+def _tile_ranges(axis: str, start, block_q: int, block_k: int, n: int,
+                 causal: bool, window, q_offset=0):
+    """The ONE home for a score tile's CLASS. For one q block (``axis``
+    'k': its queries are ``[start, start + block_q)``, ``q_offset``
+    included) the k blocks it walks, or for one k block (``axis`` 'q':
+    keys ``[start, start + block_k)``) the q blocks that walk it, out of
+    ``n``: ``(lo, a, b, hi)`` with
+
+    - ``[a, b)`` INTERIOR: the tile's last key is at or before its first
+      query (``k_start + block_k - 1 <= q_start``, when causal) and its
+      first key is inside its LAST query's window (``k_start > q_start +
+      block_q - 1 - window``, when windowed) — every pair is visible, so
+      `_mask_scores` would be the identity on it;
+    - ``[lo, a)`` and ``[b, hi)`` EDGE: live, and some pair is masked (on
+      the 'k' axis the window's side, then the diagonal; on the 'q' axis
+      the diagonal, then the window's side);
+    - the rest SKIPPED: no pair is visible (no query reaches the tile's
+      first key, or its last key left every query's window).
+
+    ``start`` is a host int (`tile_classes`) or a traced int32 scalar (the
+    kernels): the arithmetic is the same."""
+    bq, bk = block_q, block_k
+    if axis == "k":
+        q_start, q_last = start, start + bq - 1
+        # live: k_start <= q_last  and  k_start + bk - 1 > q_start - window
+        lo = 0 if window is None else \
+            _clamp(_div(q_start - window + 1, bk), 0, n)
+        hi = _clamp(_div(q_last + bk, bk), 0, n) if causal else n
+        # interior: k_start + bk - 1 <= q_start  and  k_start > q_last - window
+        a = lo if window is None else _div(q_last - window + bk, bk)
+        b = _div(q_start + 1, bk) if causal else hi
+    else:
+        k_start, k_last = start, start + bk - 1
+        # live: q_start + bq - 1 >= k_start  and  q_start - window < k_last
+        lo = _clamp(_div(k_start - q_offset, bq), 0, n) if causal else 0
+        hi = n if window is None else \
+            _clamp(_div(k_last + window - 1 - q_offset + bq, bq), 0, n)
+        # interior: q_start >= k_last  and  q_start + bq - 1 - window < k_start
+        a = _div(k_last - q_offset + bq - 1, bq) if causal else lo
+        b = hi if window is None else _div(k_start + window - q_offset, bq)
+    a = _clamp(a, lo, hi)
+    return lo, a, _clamp(b, a, hi), hi
+
+
+def _block_starts(axis, tq, tk, block_q, block_k, q_offset):
+    """(start of each block that walks ``axis``, number of blocks on it)."""
+    if axis == "k":
+        return range(q_offset, q_offset + tq, block_q), tk // block_k
+    return range(0, tk, block_k), tq // block_q
+
+
+#: a range EVERY block of a call walks exactly this many times (a causal
+#: call's diagonal tile at block_q == block_k; an own-chunk call's one
+#: tile) is emitted as straight-line code, not as a loop
+_STRAIGHT_LINE_TILES = 1
+
+
+def _class_trips(axis, tq, tk, block_q, block_k, causal, window, q_offset):
+    """What a kernel emits for each of a block's three ranges (edge ``[lo,
+    a)``, interior ``[a, b)``, edge ``[b, hi)``): 0 = nothing (no block of
+    the call enters it), 1 = the tile body once, straight-line (EVERY block
+    holds exactly one tile there), None = a loop (the count varies by
+    block). Tile counts are static, so a class that cannot occur costs no
+    code: a call of one q block and one k block (a split step's own-chunk
+    attention) is its one masked tile."""
+    starts, n = _block_starts(axis, tq, tk, block_q, block_k, q_offset)
+    rs = [_tile_ranges(axis, s, block_q, block_k, n, causal, window,
+                       q_offset) for s in starts]
+    def emitted(i):
+        counts = {r[i + 1] - r[i] for r in rs}
+        return min(counts) if len(counts) == 1 and \
+            min(counts) <= _STRAIGHT_LINE_TILES else None
+    return tuple(emitted(i) for i in range(3))
+
+
+def tile_classes(tq: int, tk: int, block_q: int, block_k: int, causal: bool,
+                 window=None, q_offset: int = 0) -> Tuple[int, int, int]:
+    """(interior, edge, skipped) score tiles of one head of a call — the
+    kernels' own classification (`_tile_ranges`) on host ints."""
+    starts, n = _block_starts("k", tq, tk, block_q, block_k, q_offset)
+    interior = edge = 0
+    for s in starts:
+        lo, a, b, hi = _tile_ranges("k", s, block_q, block_k, n, causal,
+                                    window)
+        interior += b - a
+        edge += (a - lo) + (hi - b)
+    return interior, edge, len(starts) * n - interior - edge
+
+
+def _count_tiles(tq, tk, block_q, block_k, causal, window, q_offset):
+    """Bump ``flash/tiles_*`` by one head's tiles of one kernel call — at
+    trace time, so once a program build and not a step (lazy import:
+    telemetry pulls in the whole diagnostics stack)."""
+    from deepspeed_tpu.telemetry.registry import registry
+    for name, n in zip(("interior", "edge", "skipped"), tile_classes(
+            tq, tk, block_q, block_k, causal, window, q_offset)):
+        registry.counter("flash/tiles_" + name).inc(n)
+
+
+def _class_fori(ranges, trips, tile, carry):
+    """Run ``tile(masked)``'s loop body over a block's three ranges in
+    order, the carry shared: masked over the edge ranges, unmasked over
+    the interior one; ``trips`` (`_class_trips`) says what each range is
+    emitted as. On the chip a second LOOP costs a q block more than the
+    mask it saves (0.19 us at 512 x 512: its carry of 192 registers is
+    handed over in VMEM and nothing is scheduled across its boundary),
+    which is why the one tile every block holds is straight-line code."""
+    lo, a, b, hi = ranges
+    for (start, stop), n, masked in zip(((lo, a), (a, b), (b, hi)), trips,
+                                        (True, False, True)):
+        if n is None:
+            carry = lax.fori_loop(start, stop, tile(masked), carry)
+        else:
+            for i in range(n):
+                carry = tile(masked)(start + i, carry)
+    return carry
+
+
+def _class_when(j, ranges, trips, tile):
+    """`_class_fori` for the XL grids, whose tiles are grid steps: step
+    ``j`` of a block's row runs ``tile(masked)`` under its class's
+    ``pl.when``, and a skipped step runs nothing."""
+    lo, a, b, hi = ranges
+    live = jnp.logical_and(j >= lo, j < hi)
+    if trips[1] == 0:
+        pl.when(live)(tile(True))
+        return
+    interior = jnp.logical_and(j >= a, j < b)
+    pl.when(interior)(tile(False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(tile(True))
 
 
 # swept on v5e (1.27B llama, seq 2048): 512/512 → 51.3% MFU vs 47.9% at
@@ -70,7 +215,7 @@ DEFAULT_BLOCK_K = 512
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                 scale: float, causal: bool, block_k: int, q_offset: int,
-                window: Optional[int]):
+                window: Optional[int], trips):
     qi = pl.program_id(1)
     block_q = q_ref.shape[1]
     seq_k = k_ref.shape[1]
@@ -81,50 +226,44 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     # fp32 matmuls (measured 12 vs 90+ TF/s on v5e)
     q = q_ref[0]                                           # [BQ, D]
     q_start = qi * block_q + q_offset
-    qpos = q_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    # only k blocks that intersect the causal triangle; blocks left of the
+    # sliding window (Mistral SWA: key kp visible to query qp iff
+    # qp - window < kp <= qp) are SKIPPED, so FLOPs scale with window,
+    # not T²; of the live ones only the EDGE tiles are masked
+    ranges = _tile_ranges("k", q_start, block_q, block_k, seq_k // block_k,
+                          causal, window)
 
-    num_kb = seq_k // block_k
-    if causal:
-        # only blocks that intersect the causal triangle
-        num_kb_dyn = lax.min(
-            jnp.int32(num_kb),
-            lax.div(q_start + block_q + block_k - 1, jnp.int32(block_k)))
-    else:
-        num_kb_dyn = jnp.int32(num_kb)
-    if window is not None:
-        # sliding window (Mistral SWA): key kp visible to query qp iff
-        # qp - window < kp <= qp — blocks left of the window are SKIPPED,
-        # so FLOPs scale with window, not T²
-        kb_start = lax.max(
-            jnp.int32(0),
-            lax.div(q_start - jnp.int32(window) + 1, jnp.int32(block_k)))
-    else:
-        kb_start = jnp.int32(0)
-
-    def body(kb, carry):
-        acc, m, l = carry
-        k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        s = _mask_scores(s, q_start, kb * block_k, causal, window)
-        blk_max = jnp.max(s, axis=1)                        # [BQ]
-        new_m = jnp.maximum(m, blk_max)
-        p = jnp.exp(s - new_m[:, None])
-        # rows with no live key yet: new_m == -inf -> p must be 0
-        alive = new_m > _NEG_INF / 2
-        p = jnp.where(alive[:, None], p, 0.0)
-        corr = jnp.where(alive, jnp.exp(m - new_m), 0.0)
-        acc = acc * corr[:, None] + lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        l = l * corr + jnp.sum(p, axis=1)
-        return acc, new_m, l
+    def tile(masked):
+        def body(kb, carry):
+            acc, m, l = carry
+            k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
+            v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
+            s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = _mask_scores(s, q_start, kb * block_k, causal, window)
+            blk_max = jnp.max(s, axis=1)                    # [BQ]
+            new_m = jnp.maximum(m, blk_max)
+            p = jnp.exp(s - new_m[:, None])
+            corr = jnp.exp(m - new_m)
+            if masked:
+                # rows with no live key yet: new_m == -inf -> p must be 0
+                # (an interior tile's rows see every key: both selects
+                # are the identity there)
+                alive = new_m > _NEG_INF / 2
+                p = jnp.where(alive[:, None], p, 0.0)
+                corr = jnp.where(alive, corr, 0.0)
+            acc = acc * corr[:, None] + lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            l = l * corr + jnp.sum(p, axis=1)
+            return acc, new_m, l
+        return body
 
     acc0 = jnp.zeros((block_q, d), jnp.float32)
     m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
-    acc, m, l = lax.fori_loop(kb_start, num_kb_dyn, body, (acc0, m0, l0))
+    acc, m, l = _class_fori(ranges, trips, tile, (acc0, m0, l0))
 
     safe_l = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / safe_l[:, None]).astype(o_ref.dtype)
@@ -144,11 +283,14 @@ def _fwd(q, k, v, scale, causal, q_offset, block_q, block_k, window,
     bkv, tk, _ = k.shape
     g = bh // bkv
     grid = (bh, tq // block_q)
+    geometry = (tq, tk, block_q, block_k, causal, window, q_offset)
+    _count_tiles(*geometry)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_k=block_k, q_offset=q_offset,
-                          window=window),
+                          window=window,
+                          trips=_class_trips("k", *geometry)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -232,7 +374,7 @@ def _xl_q_index(block_q, block_k, q_offset, causal, window, num_qb,
 
 def _fwd_kernel_xl(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                    l_ref, *, scale: float, causal: bool, q_offset: int,
-                   window: Optional[int], num_kb: int):
+                   window: Optional[int], num_kb: int, trips):
     i = pl.program_id(1)
     j = pl.program_id(2)
     block_q = q_ref.shape[1]
@@ -246,36 +388,35 @@ def _fwd_kernel_xl(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    live = jnp.bool_(True)
-    if causal:   # block intersects the causal triangle
-        live = jnp.logical_and(live, k_start <= q_start + block_q - 1)
-    if window is not None:   # block not entirely left of the window
-        live = jnp.logical_and(live,
-                               k_start + block_k - 1 > q_start - window)
+    def tile(masked):
+        def _compute():
+            q = q_ref[0]
+            k_blk = k_ref[0]
+            v_blk = v_ref[0]
+            s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = _mask_scores(s, q_start, k_start, causal, window)
+            m = m_ref[...]
+            blk_max = jnp.max(s, axis=1)
+            new_m = jnp.maximum(m, blk_max)
+            new_m_col = new_m[:, None]
+            p = jnp.exp(s - new_m_col)
+            corr = jnp.exp(m - new_m)
+            if masked:
+                # Mosaic can't minor-dim-reshape i1 vectors — compare the
+                # already 2-D f32 column instead of reshaping a 1-D bool
+                p = jnp.where(new_m_col > _NEG_INF / 2, p, 0.0)
+                corr = jnp.where(new_m > _NEG_INF / 2, corr, 0.0)
+            acc_ref[...] = acc_ref[...] * corr[:, None] + lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
+            m_ref[...] = new_m
+        return _compute
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        s = _mask_scores(s, q_start, k_start, causal, window)
-        m = m_ref[...]
-        blk_max = jnp.max(s, axis=1)
-        new_m = jnp.maximum(m, blk_max)
-        new_m_col = new_m[:, None]
-        p = jnp.exp(s - new_m_col)
-        # Mosaic can't minor-dim-reshape i1 vectors — compare the already
-        # 2-D f32 column instead of reshaping a 1-D bool
-        p = jnp.where(new_m_col > _NEG_INF / 2, p, 0.0)
-        alive = new_m > _NEG_INF / 2
-        corr = jnp.where(alive, jnp.exp(m - new_m), 0.0)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        m_ref[...] = new_m
+    _class_when(j, _tile_ranges("k", q_start, block_q, block_k, num_kb,
+                                causal, window), trips, tile)
 
     @pl.when(j == num_kb - 1)
     def _flush():
@@ -296,10 +437,13 @@ def _fwd_xl(q, k, v, scale, causal, q_offset, block_q, block_k, window,
     grid = (bh, tq // block_q, num_kb)
     kv_idx = _xl_kv_index(g, block_q, block_k, q_offset, causal, window,
                           num_kb)
+    geometry = (tq, tk, block_q, block_k, causal, window, q_offset)
+    _count_tiles(*geometry)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel_xl, scale=scale, causal=causal,
-                          q_offset=q_offset, window=window, num_kb=num_kb),
+                          q_offset=q_offset, window=window, num_kb=num_kb,
+                          trips=_class_trips("k", *geometry)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -331,7 +475,7 @@ def _fwd_xl(q, k, v, scale, causal, q_offset, block_q, block_k, window,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    *, scale: float, causal: bool, block_k: int,
-                   q_offset: int, window: Optional[int]):
+                   q_offset: int, window: Optional[int], trips):
     qi = pl.program_id(1)
     block_q = q_ref.shape[1]
     seq_k = k_ref.shape[1]
@@ -342,44 +486,34 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)]
     delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q)]
     q_start = qi * block_q + q_offset
-    qpos = q_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    ranges = _tile_ranges("k", q_start, block_q, block_k, seq_k // block_k,
+                          causal, window)
 
-    num_kb = seq_k // block_k
-    if causal:
-        num_kb_dyn = lax.min(
-            jnp.int32(num_kb),
-            lax.div(q_start + block_q + block_k - 1, jnp.int32(block_k)))
-    else:
-        num_kb_dyn = jnp.int32(num_kb)
-    if window is not None:
-        kb_start = lax.max(
-            jnp.int32(0),
-            lax.div(q_start - jnp.int32(window) + 1, jnp.int32(block_k)))
-    else:
-        kb_start = jnp.int32(0)
+    def tile(masked):
+        def body(kb, dq):
+            k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
+            v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
+            s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = _mask_scores(s, q_start, kb * block_k, causal, window)
+            p = jnp.exp(s - lse[:, None])
+            dp = lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta[:, None]) * scale).astype(k_blk.dtype)
+            return dq + lax.dot_general(ds, k_blk, (((1,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+        return body
 
-    def body(kb, dq):
-        k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        s = _mask_scores(s, q_start, kb * block_k, causal, window)
-        p = jnp.exp(s - lse[:, None])
-        dp = lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None]) * scale).astype(k_blk.dtype)
-        dq = dq + lax.dot_general(ds, k_blk, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        return dq
-
-    dq = lax.fori_loop(kb_start, num_kb_dyn, body,
-                       jnp.zeros((block_q, d), jnp.float32))
+    dq = _class_fori(ranges, trips, tile,
+                     jnp.zeros((block_q, d), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *, scale: float, causal: bool,
-                    block_q: int, q_offset: int, window: Optional[int]):
+                    block_q: int, q_offset: int, window: Optional[int],
+                    trips):
     ki = pl.program_id(1)
     block_k = k_ref.shape[1]
     seq_q = q_ref.shape[1]
@@ -388,50 +522,43 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k_blk = k_ref[0]                                       # [BK, D]
     v_blk = v_ref[0]
     k_start = ki * block_k
-    kpos = k_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    # from the first q block whose END reaches this k block's start to the
+    # last one whose window still holds its last key
+    ranges = _tile_ranges("q", k_start, block_q, block_k, seq_q // block_q,
+                          causal, window, q_offset)
 
-    num_qb = seq_q // block_q
-    if causal:
-        # first q block whose END reaches this k block's start
-        first_qb = lax.max(
-            jnp.int32(0),
-            lax.div(k_start - q_offset - block_q + 1 + block_q - 1,
-                    jnp.int32(block_q)))
-    else:
-        first_qb = jnp.int32(0)
-    if window is not None:
-        # queries beyond k_end-1 + window - 1 can't see this k block
-        num_qb_dyn = lax.min(
-            jnp.int32(num_qb),
-            lax.div(k_start + block_k - 1 + jnp.int32(window) - 1
-                    - q_offset, jnp.int32(block_q)) + 1)
-    else:
-        num_qb_dyn = jnp.int32(num_qb)
-
-    def body(qb, carry):
-        dk, dv = carry
-        q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :]
-        do = do_ref[0, pl.ds(qb * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(qb * block_q, block_q)]
-        delta = delta_ref[0, 0, pl.ds(qb * block_q, block_q)]
-        s = lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        s = _mask_scores(s, qb * block_q + q_offset, k_start, causal,
-                         window)
-        p = jnp.exp(s - lse[:, None])
-        dv = dv + lax.dot_general(p.astype(do.dtype), do,
-                                  (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None]) * scale).astype(q_blk.dtype)
-        dk = dk + lax.dot_general(ds, q_blk, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        return dk, dv
+    def tile(masked):
+        def body(qb, carry):
+            # the tile TRANSPOSED, [BK, BQ]: s^T = k·q^T and dp^T = v·do^T
+            # come off the MXU as the LHS the two accumulating matmuls
+            # take, p^T·do and ds^T·q — computed as [BQ, BK] those two
+            # contract over rows and Mosaic transposes p and ds on the
+            # XLU, a tile each (0.29 us of the tile's 1.97 at 512 x 512)
+            dk, dv = carry
+            q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :]
+            do = do_ref[0, pl.ds(qb * block_q, block_q), :]
+            lse = lse_ref[0, :, pl.ds(qb * block_q, block_q)]     # [1, BQ]
+            delta = delta_ref[0, :, pl.ds(qb * block_q, block_q)]
+            s = lax.dot_general(k_blk, q_blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = _mask_scores(s, qb * block_q + q_offset, k_start,
+                                 causal, window, transposed=True)
+            p = jnp.exp(s - lse)
+            dv = dv + lax.dot_general(p.astype(do.dtype), do,
+                                      (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+            dp = lax.dot_general(v_blk, do, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta) * scale).astype(q_blk.dtype)
+            dk = dk + lax.dot_general(ds, q_blk, (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+            return dk, dv
+        return body
 
     dk0 = jnp.zeros((block_k, d), jnp.float32)
     dv0 = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = lax.fori_loop(first_qb, num_qb_dyn, body, (dk0, dv0))
+    dk, dv = _class_fori(ranges, trips, tile, (dk0, dv0))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -447,11 +574,15 @@ def _bwd(q, k, v, out, lse, do, scale, causal, q_offset, block_q, block_k,
     g = bh // bkv
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]                      # [BH, 1, TQ]
+    # the two kernels walk the same tiles, by rows and by columns
+    geometry = (tq, tk, block_q, block_k, causal, window, q_offset)
+    _count_tiles(*geometry)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_k=block_k, q_offset=q_offset,
-                          window=window),
+                          window=window,
+                          trips=_class_trips("k", *geometry)),
         grid=(bh, tq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -471,7 +602,8 @@ def _bwd(q, k, v, out, lse, do, scale, causal, q_offset, block_q, block_k,
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, q_offset=q_offset,
-                          window=window),
+                          window=window,
+                          trips=_class_trips("q", *geometry)),
         grid=(bh, tk // block_k),
         in_specs=[
             pl.BlockSpec((1, tq, d), lambda b, i: (b, 0, 0)),
@@ -507,7 +639,8 @@ def _bwd(q, k, v, out, lse, do, scale, causal, q_offset, block_q, block_k,
 
 def _bwd_dq_kernel_xl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dq_acc_ref, *, scale: float, causal: bool,
-                      q_offset: int, window: Optional[int], num_kb: int):
+                      q_offset: int, window: Optional[int], num_kb: int,
+                      trips):
     i = pl.program_id(1)
     j = pl.program_id(2)
     block_q = q_ref.shape[1]
@@ -519,31 +652,29 @@ def _bwd_dq_kernel_xl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
-    live = jnp.bool_(True)
-    if causal:
-        live = jnp.logical_and(live, k_start <= q_start + block_q - 1)
-    if window is not None:
-        live = jnp.logical_and(live,
-                               k_start + block_k - 1 > q_start - window)
+    def tile(masked):
+        def _compute():
+            q = q_ref[0]
+            do = do_ref[0]
+            lse = lse_ref[0, 0, :]
+            delta = delta_ref[0, 0, :]
+            k_blk = k_ref[0]
+            v_blk = v_ref[0]
+            s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = _mask_scores(s, q_start, k_start, causal, window)
+            p = jnp.exp(s - lse[:, None])
+            dp = lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta[:, None]) * scale).astype(k_blk.dtype)
+            dq_acc_ref[...] = dq_acc_ref[...] + lax.dot_general(
+                ds, k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return _compute
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0, :]
-        delta = delta_ref[0, 0, :]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        s = _mask_scores(s, q_start, k_start, causal, window)
-        p = jnp.exp(s - lse[:, None])
-        dp = lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None]) * scale).astype(k_blk.dtype)
-        dq_acc_ref[...] = dq_acc_ref[...] + lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _class_when(j, _tile_ranges("k", q_start, block_q, block_k, num_kb,
+                                causal, window), trips, tile)
 
     @pl.when(j == num_kb - 1)
     def _flush():
@@ -553,7 +684,7 @@ def _bwd_dq_kernel_xl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dkv_kernel_xl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *,
                        scale: float, causal: bool, q_offset: int,
-                       window: Optional[int], num_qb: int):
+                       window: Optional[int], num_qb: int, trips):
     jk = pl.program_id(1)
     iq = pl.program_id(2)
     block_k = k_ref.shape[1]
@@ -566,34 +697,32 @@ def _bwd_dkv_kernel_xl(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    live = jnp.bool_(True)
-    if causal:   # some query in the block reaches this k block
-        live = jnp.logical_and(live, q_start + block_q - 1 >= k_start)
-    if window is not None:
-        live = jnp.logical_and(live,
-                               k_start + block_k - 1 > q_start - window)
+    def tile(masked):
+        def _compute():
+            k_blk = k_ref[0]
+            v_blk = v_ref[0]
+            q_blk = q_ref[0]
+            do = do_ref[0]
+            lse = lse_ref[0, 0, :]
+            delta = delta_ref[0, 0, :]
+            s = lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = _mask_scores(s, q_start, k_start, causal, window)
+            p = jnp.exp(s - lse[:, None])
+            dv_acc_ref[...] = dv_acc_ref[...] + lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta[:, None]) * scale).astype(q_blk.dtype)
+            dk_acc_ref[...] = dk_acc_ref[...] + lax.dot_general(
+                ds, q_blk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return _compute
 
-    @pl.when(live)
-    def _compute():
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        q_blk = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0, :]
-        delta = delta_ref[0, 0, :]
-        s = lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        s = _mask_scores(s, q_start, k_start, causal, window)
-        p = jnp.exp(s - lse[:, None])
-        dv_acc_ref[...] = dv_acc_ref[...] + lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None]) * scale).astype(q_blk.dtype)
-        dk_acc_ref[...] = dk_acc_ref[...] + lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _class_when(iq, _tile_ranges("q", k_start, block_q, block_k, num_qb,
+                                 causal, window, q_offset), trips, tile)
 
     @pl.when(iq == num_qb - 1)
     def _flush():
@@ -613,9 +742,12 @@ def _bwd_xl(q, k, v, out, lse, do, scale, causal, q_offset, block_q,
 
     kv_idx = _xl_kv_index(g, block_q, block_k, q_offset, causal, window,
                           num_kb)
+    geometry = (tq, tk, block_q, block_k, causal, window, q_offset)
+    _count_tiles(*geometry)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel_xl, scale=scale, causal=causal,
-                          q_offset=q_offset, window=window, num_kb=num_kb),
+                          q_offset=q_offset, window=window, num_kb=num_kb,
+                          trips=_class_trips("k", *geometry)),
         grid=(bh, num_qb, num_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -637,7 +769,8 @@ def _bwd_xl(q, k, v, out, lse, do, scale, causal, q_offset, block_q,
                           num_qb, lse_like=True)
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel_xl, scale=scale, causal=causal,
-                          q_offset=q_offset, window=window, num_qb=num_qb),
+                          q_offset=q_offset, window=window, num_qb=num_qb,
+                          trips=_class_trips("q", *geometry)),
         grid=(bh, num_kb, num_qb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_idx),

@@ -49,6 +49,235 @@ def test_backward_matches_reference():
                                    err_msg=f"d{name} mismatch")
 
 
+# ---------------------------------------------------------------------------
+# Tile classes (PR 59): a score tile is skipped, interior or edge by its
+# block indices, and only an edge tile runs the mask
+# ---------------------------------------------------------------------------
+
+#: (tq, tk, block_q, block_k, causal, window, q_offset)
+GEOMETRIES = [
+    (512, 512, 64, 64, True, None, 0),        # causal alone
+    (512, 512, 128, 64, True, None, 0),       # block_q > block_k
+    (512, 512, 64, 128, True, None, 0),       # block_q < block_k
+    (512, 512, 64, 64, True, 512, 0),         # window = sequence
+    (512, 512, 64, 64, True, 2048, 0),        # window > sequence
+    (512, 512, 64, 64, True, 200, 0),         # window < sequence, unaligned
+    (512, 512, 64, 64, True, 192, 0),         # ... a multiple of the block
+    (512, 512, 64, 64, True, 64, 0),          # ... one block: none interior
+    (512, 512, 64, 64, True, 1, 0),           # ... the diagonal alone
+    (512, 512, 128, 64, True, 200, 0),
+    (512, 512, 64, 128, True, 200, 0),
+    (512, 512, 128, 32, True, 97, 0),
+    (256, 768, 64, 64, True, None, 512),      # a chunk after its prefix
+    (256, 768, 64, 128, True, None, 512),
+    (256, 768, 128, 64, True, 300, 512),
+    (256, 768, 64, 64, True, 128, 512),
+    (128, 640, 64, 64, True, 100, 300),       # q_offset off the block grid
+    (128, 640, 32, 128, True, None, 77),
+    (128, 128, 128, 128, True, None, 0),      # one tile: an own chunk
+    (128, 128, 128, 128, True, 4096, 0),
+    (512, 512, 64, 64, False, None, 0),       # no mask at all
+    (512, 512, 64, 64, False, 200, 0),        # a window with no diagonal
+    (256, 512, 128, 64, False, 130, 100),
+    (4096, 4096, 512, 512, True, 4096, 0),    # the training cells' call
+]
+
+
+def _geometry_id(g):
+    tq, tk, bq, bk, causal, window, off = g
+    return (f"{tq}x{tk}-b{bq}x{bk}-{'causal' if causal else 'full'}"
+            f"-w{window}-off{off}")
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES, ids=_geometry_id)
+def test_tile_classes_match_the_mask(geometry):
+    """The classification against brute force, by rows (forward, dq) and
+    by columns (dkv): a tile is interior IFF `_mask_scores` leaves every
+    pair of it visible, skipped IFF none, and the counts sum to the grid."""
+    from deepspeed_tpu.ops import flash_attention as fa
+    tq, tk, bq, bk, causal, window, off = geometry
+    visible = np.asarray(fa._mask_scores(
+        jnp.zeros((tq, tk), jnp.float32), off, 0, causal, window)) == 0.0
+    nq, nk = tq // bq, tk // bk
+    tiles = visible.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    want = np.where(tiles.all(axis=(2, 3)), "interior",
+                    np.where(tiles.any(axis=(2, 3)), "edge", "skipped"))
+
+    def classes(lo, a, b, hi, n):
+        assert 0 <= lo <= a <= b <= hi <= n
+        return np.array(["skipped"] * lo + ["edge"] * (a - lo) +
+                        ["interior"] * (b - a) + ["edge"] * (hi - b) +
+                        ["skipped"] * (n - hi))
+
+    for i in range(nq):
+        got = classes(*fa._tile_ranges("k", off + i * bq, bq, bk, nk,
+                                       causal, window), nk)
+        assert (got == want[i]).all(), (i, got, want[i])
+    for j in range(nk):
+        got = classes(*fa._tile_ranges("q", j * bk, bq, bk, nq, causal,
+                                       window, off), nq)
+        assert (got == want[:, j]).all(), (j, got, want[:, j])
+    counts = fa.tile_classes(tq, tk, bq, bk, causal, window, off)
+    assert counts == tuple(int((want == c).sum())
+                           for c in ("interior", "edge", "skipped"))
+    assert sum(counts) == nq * nk
+    # what is emitted for a range: nothing where no block enters it, the
+    # tile once where every block holds exactly one, a loop otherwise
+    for axis, rows in (("k", want), ("q", want.T)):
+        trips = fa._class_trips(axis, tq, tk, bq, bk, causal, window, off)
+        for i, n in enumerate(trips):
+            counts = {r[i + 1] - r[i] for r in (
+                fa._tile_ranges(axis, s, bq, bk, rows.shape[1], causal,
+                                window, off)
+                for s in fa._block_starts(axis, tq, tk, bq, bk, off)[0])}
+            assert counts == {n} if n is not None else \
+                (len(counts) > 1 or max(counts) > 1)
+        assert (trips[1] != 0) == (want == "interior").any()
+        assert (trips[0] != 0 or trips[2] != 0) == (want == "edge").any()
+    # ... and traced scalars give the ranges host ints give
+    starts = jnp.arange(nq, dtype=jnp.int32) * bq + off
+    traced = jax.vmap(lambda s: jnp.stack([jnp.asarray(r, jnp.int32) for r in
+                      fa._tile_ranges("k", s, bq, bk, nk, causal, window)]))(
+                          starts)
+    assert np.asarray(traced).tolist() == [
+        list(fa._tile_ranges("k", off + i * bq, bq, bk, nk, causal, window))
+        for i in range(nq)]
+
+
+def _flat_qkv(seed, heads, kv_heads, t=256, d=64):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.normal(size=(n, t, d)) * 0.5, jnp.float32)
+                 for n in (heads, kv_heads, kv_heads))
+
+
+def _plain(q, k, v, causal, window):
+    """The plain reference on the kernels' flat layout: (out, lse)."""
+    g = q.shape[0] // k.shape[0]
+    k, v = jnp.repeat(k, g, axis=0), jnp.repeat(v, g, axis=0)
+    s = jnp.einsum("hqd,hkd->hqk", q, k) / np.sqrt(q.shape[-1])
+    qp = jnp.arange(q.shape[1])[:, None]
+    kp = jnp.arange(k.shape[1])[None, :]
+    ok = (qp >= kp) if causal else jnp.ones_like(qp >= kp)
+    if window is not None:
+        ok = ok & (kp > qp - window)
+    s = jnp.where(ok, s, -jnp.inf)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)
+    return jnp.einsum("hqk,hkd->hqd", jnp.exp(s - lse[..., None]), v), lse
+
+
+def _flash_flat(fa, q, k, v, causal, window, bq, bk):
+    """(out, lse) of the flat kernels under the custom VJP's own rules."""
+    out, (_, _, _, _, lse) = fa._flash_fwd(q, k, v, causal, 0, bq, bk,
+                                           window, True, None, None)
+    return out, lse[:, 0, :]
+
+
+def _fwd_and_grads(fa, q, k, v, causal, window, bq, bk):
+    out, lse = _flash_flat(fa, q, k, v, causal, window, bq, bk)
+    grads = jax.grad(lambda *a: jnp.sum(jnp.square(fa._flash(
+        *a, causal, 0, bq, bk, window, True, None, None))),
+        argnums=(0, 1, 2))(q, k, v)
+    return [np.asarray(x) for x in (out, lse, *grads)]
+
+
+@pytest.mark.parametrize("generation", ["resident", "xl"])
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("mask", [
+    (True, None, 64, 64), (True, 160, 64, 64), (True, 256, 64, 64),
+    (True, 200, 128, 64), (True, None, 64, 128)],
+    ids=["causal", "window_lt_seq", "window_eq_seq", "window_bq128",
+         "causal_bk128"])
+def test_classed_kernels_match_reference(mask, kv_heads, generation,
+                                         monkeypatch):
+    """Forward, lse and dq / dk / dv of both kernel generations against
+    the plain reference where interior, edge AND skipped tiles occur."""
+    from deepspeed_tpu.ops import flash_attention as fa
+    causal, window, bq, bk = mask
+    if generation == "xl":
+        monkeypatch.setattr(fa, "_resident_ok", lambda *a, **k: False)
+    interior, edge, skipped = fa.tile_classes(256, 256, bq, bk, causal,
+                                              window)
+    assert interior and edge and skipped
+    q, k, v = _flat_qkv(5, 4, kv_heads)
+    got = _fwd_and_grads(fa, q, k, v, causal, window, bq, bk)
+    want = (*_plain(q, k, v, causal, window),
+            *jax.grad(lambda *a: jnp.sum(jnp.square(
+                _plain(*a, causal, window)[0])), argnums=(0, 1, 2))(q, k, v))
+    for a, b, name in zip(got, want, ("out", "lse", "dq", "dk", "dv")):
+        tol = 2e-5 if name in ("out", "lse") else 5e-4
+        np.testing.assert_allclose(a, np.asarray(b), rtol=tol, atol=tol,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("generation", ["resident", "xl"])
+@pytest.mark.parametrize("window", [None, 160, 256])
+def test_interior_form_is_the_masked_form_bit_for_bit(window, generation,
+                                                      monkeypatch):
+    """An interior tile's result is what the masked body gives it: the
+    same call with every live tile sent through the masked form (no
+    interior range — the kernels' arithmetic before the classes) returns
+    the same bits, forward, lse and gradients."""
+    from deepspeed_tpu.ops import flash_attention as fa
+    if generation == "xl":
+        monkeypatch.setattr(fa, "_resident_ok", lambda *a, **k: False)
+    q, k, v = _flat_qkv(7, 4, 2)
+    classed = _fwd_and_grads(fa, q, k, v, True, window, 64, 64)
+    ranges = fa._tile_ranges
+
+    def no_interior(*a):
+        lo, _, _, hi = ranges(*a)
+        return lo, hi, hi, hi
+    monkeypatch.setattr(fa, "_tile_ranges", no_interior)
+    assert fa.tile_classes(256, 256, 64, 64, True, window)[0] == 0
+    masked = _fwd_and_grads(fa, q, k, v, True, window, 64, 64)
+    for a, b, name in zip(classed, masked, ("out", "lse", "dq", "dk", "dv")):
+        assert np.array_equal(a, b), name
+
+
+@pytest.mark.parametrize("window", [None, 160, 256])
+def test_multi_tile_matches_one_edge_tile(window):
+    """Sixteen tiles of three classes against the SAME call at block =
+    sequence (one edge tile: the masked body alone), to what the online
+    softmax's order of sums allows."""
+    from deepspeed_tpu.ops import flash_attention as fa
+    q, k, v = _flat_qkv(9, 4, 2)
+    assert fa.tile_classes(256, 256, 256, 256, True, window) == (0, 1, 0)
+    tiled = _fwd_and_grads(fa, q, k, v, True, window, 64, 64)
+    whole = _fwd_and_grads(fa, q, k, v, True, window, 256, 256)
+    for a, b, name in zip(tiled, whole, ("out", "lse", "dq", "dk", "dv")):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("call", [
+    ((4096, 4096, 512, 512, 4096), (28, 8, 28)),
+    ((128, 128, 128, 128, None), (0, 1, 0)),
+    ((16384, 16384, 1024, 1024, 4096), (42, 28, 186))],
+    ids=["cell1_4096", "own_chunk_128", "xl_16k_window"])
+def test_tile_counters_at_trace_time(call):
+    """``flash/tiles_*`` grow by one head's tiles when a kernel call is
+    TRACED (a program build), whatever the batch and heads."""
+    from deepspeed_tpu.ops import flash_attention as fa
+    from deepspeed_tpu.telemetry.registry import registry
+    (tq, tk, bq, bk, window), want = call
+    names = [f"flash/tiles_{c}" for c in ("interior", "edge", "skipped")]
+    q = jax.ShapeDtypeStruct((8, tq, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, tk, 128), jnp.bfloat16)
+
+    def delta(fn, *args):
+        before = [registry.counter(n).value for n in names]
+        jax.eval_shape(fn, *args)
+        return tuple(int(registry.counter(n).value - b)
+                     for n, b in zip(names, before))
+
+    assert fa.tile_classes(tq, tk, bq, bk, True, window) == want
+    assert delta(lambda *a: fa._fwd(*a, 0.1, True, 0, bq, bk, window, True),
+                 q, kv, kv) == want
+    lse = jax.ShapeDtypeStruct((8, 1, tq), jnp.float32)
+    assert delta(lambda q, k, v, lse: fa._bwd(
+        q, k, v, q, lse, q, 0.1, True, 0, bq, bk, window, True),
+        q, kv, kv, lse) == want
+
+
 def test_unsupported_shape_falls_back():
     # T=100 not divisible by any block — must fall back, still correct
     q, k, v = _qkv(t=96)

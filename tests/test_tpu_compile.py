@@ -70,13 +70,14 @@ def no_persistent_cache():
 # -- the 1b preset's shapes (models/llama.py): 16 query / 8 KV heads of 128,
 # hidden 2048, FFN 8192; training at batch 8 x sequence 2048
 
-def _flash(seq, batch, grad):
+def _flash(seq, batch, grad, heads=16, kv_heads=8, window=None):
     from deepspeed_tpu.ops.flash_attention import flash_attention
-    q = ((batch, seq, 16, 128), jnp.bfloat16)
-    kv = ((batch, seq, 8, 128), jnp.bfloat16)
+    q = ((batch, seq, heads, 128), jnp.bfloat16)
+    kv = ((batch, seq, kv_heads, 128), jnp.bfloat16)
 
     def loss(q, k, v):
-        out = flash_attention(q, k, v, causal=True, interpret=False)
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              interpret=False)
         return jnp.sum(out.astype(jnp.float32))
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else loss
@@ -177,6 +178,17 @@ CASES = {
     "flash_fwd_2k": lambda: _flash(2048, 8, grad=False),
     "flash_fwd_bwd_2k": lambda: _flash(2048, 8, grad=True),
     "flash_fwd_bwd_16k_1024_blocks": lambda: _flash(16384, 1, grad=True),
+    # the training cells' call (Mistral 7B: 4 sequences of 4,096 = the
+    # window, 32 / 8 heads of 128: 128 x 4096 x 128 a kernel, GQA 4), a
+    # window SHORTER than the sequence (window-side edge tiles: three
+    # ranges a block), and a split step's own-chunk attention (64 rows of
+    # one 128 x 128 tile)
+    "flash_fwd_bwd_cell1_4k_window4k": lambda: _flash(
+        4096, 4, grad=True, heads=32, kv_heads=8, window=4096),
+    "flash_fwd_bwd_4k_window1k": lambda: _flash(
+        4096, 4, grad=True, heads=32, kv_heads=8, window=1024),
+    "flash_fwd_own_chunk_128": lambda: _flash(
+        128, 64, grad=False, heads=32, kv_heads=8),
     "paged_decode_n16": lambda: _paged(16, 1, with_lse=False),
     "paged_decode_n16_lse": lambda: _paged(16, 1, with_lse=True),
     "paged_prefill_n4_c256": lambda: _paged(4, 256, with_lse=False),
@@ -249,6 +261,11 @@ CASES = {
 KERNEL_NAMES = {
     "flash_fwd_2k": ("flash_fwd",),
     "flash_fwd_bwd_2k": ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+    "flash_fwd_bwd_cell1_4k_window4k": ("flash_fwd", "flash_bwd_dq",
+                                        "flash_bwd_dkv"),
+    "flash_fwd_bwd_4k_window1k": ("flash_fwd", "flash_bwd_dq",
+                                  "flash_bwd_dkv"),
+    "flash_fwd_own_chunk_128": ("flash_fwd",),
     "paged_decode_n64_h32": ("paged_attn",),
     "paged_hist_n64_c128_lse": ("paged_attn_lse",),
     "paged_hist_n64_c1_lse": ("paged_attn_lse",),
@@ -299,6 +316,65 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     for name in KERNEL_NAMES.get(case, ()):
         assert re.search(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(", text), (
             f"{case}: no custom-call instruction named {name!r}")
+
+
+def _kernel_shape(jaxpr, found):
+    """{kernel name: (loops, vector equations)} of the Pallas calls in a
+    jaxpr: the ``while`` / ``scan`` equations of a kernel's body, and its
+    equations with a non-scalar result, after dead code is dropped (the
+    scalar range arithmetic is the scalar core's; the vector count is what
+    the body costs)."""
+    from jax._src.interpreters import partial_eval as pe
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _kernel_shape(sub, found)
+            continue
+        body = eqn.params["jaxpr"]
+        body, _ = pe.dce_jaxpr(body, [True] * len(body.outvars))
+
+        def walk(jp, acc):
+            for e in jp.eqns:
+                acc[0] += e.primitive.name in ("while", "scan")
+                acc[1] += any(getattr(v.aval, "shape", ()) for v in e.outvars)
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    walk(sub, acc)
+            return acc
+        found[eqn.params["name"]] = tuple(walk(body, [0, 0]))
+    return found
+
+
+#: case id -> {kernel: (loops, vector equations)}. A call of ONE tile a head
+#: (the own-chunk attention every serving cell's split programs hold) is its
+#: masked tile body once: no loop at all, and the 52 vector equations of
+#: the parent's body (PR 58, measured on its tree by this function: (1,
+#: 53), the one ``while`` equation and the same 52 in it). The training
+#: cells' kernels are
+#: ONE loop (the interior tiles) and the diagonal tile straight-line; a
+#: window shorter than the sequence adds the window-side edge loop.
+KERNEL_SHAPES = {
+    "flash_fwd_own_chunk_128": {"flash_fwd": (0, 52)},
+    "flash_fwd_bwd_cell1_4k_window4k": {
+        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1},
+    "flash_fwd_bwd_4k_window1k": {
+        "flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2},
+    "flash_fwd_bwd_2k": {
+        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1},
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_SHAPES))
+def test_flash_kernel_emits_only_the_classes_that_occur(case):
+    """Tile counts are static, so a class no block of a call holds costs
+    its kernel no code (shapes only: nothing compiles)."""
+    fn, shapes, _ = CASES[case]()
+    found = _kernel_shape(jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(s, d) for s, d in shapes)).jaxpr, {})
+    for kernel, want in KERNEL_SHAPES[case].items():
+        got = found[kernel]
+        assert (got if isinstance(want, tuple) else got[0]) == want, (
+            f"{case}: {kernel} holds {got[0]} loop(s) and {got[1]} vector "
+            f"equations, expected {want}")
 
 
 #: paged case id -> the KV heads a program of its kernel holds
