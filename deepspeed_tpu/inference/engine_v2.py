@@ -27,6 +27,7 @@ import numpy as np
 from jax import lax
 
 from deepspeed_tpu.config.config_utils import TPUConfigModel
+from deepspeed_tpu.inference import launch_work
 from deepspeed_tpu.inference.ragged import (DSStateManager, RaggedBatch,
                                             RaggedScheduler)
 from deepspeed_tpu.models.transformer import (STATE_SPACE_KINDS,
@@ -1101,23 +1102,6 @@ def _pools(arena: dict) -> dict:
     return {name: a for name, a in arena.items() if name != FED_TOKENS}
 
 
-class _Form(NamedTuple):
-    """What ONE launch of a chunk-width step program runs over, by the
-    program's own rules on the host (:meth:`RaggedInferenceEngineTPU.
-    _launch_form`): the program's rows and ladder, and of the instance the
-    batch takes the token slots, the rows of its chunk group and the row
-    slots its attention works on."""
-    nb: int
-    capacities: Tuple[int, ...]
-    slots: int
-    group_rows: int
-    attn_row_slots: int
-
-    @property
-    def grouped(self) -> bool:
-        return self.group_rows < self.nb
-
-
 class _Launch(NamedTuple):
     """A step program that was launched and not yet collected: its tokens
     (or logits) on the device, its sampling mode, and the rows whose pending
@@ -1409,26 +1393,10 @@ class RaggedInferenceEngineTPU:
             moe_fn = serving_moe_fn(model, config.weight_quant,
                                     self.params, ep=ep)
         self._moe_fn = moe_fn
-        #: (token, expert) assignments ONE fed token makes over the stack's
-        #: sparse layers (``dispatch/moe_assignments``); 0: no experts
-        sparse = sum(model.layer_is_sparse(l)
-                     for l in range(model.num_layers))
-        self._moe_assignments_per_token = \
-            model.num_experts_per_tok * sparse if model.num_experts else 0
-        #: (most token slots of the held experts' few-token form, rows their
-        #: buffers hold in ONE launch of more over the stack's sparse
-        #: layers: ``dispatch/moe_buffer_rows``); None: not the share's layer
-        self._moe_buffer_rows = None
-        if model.typed and model.num_experts:
-            from deepspeed_tpu.parallel.moe import HELD_ROUND_ROWS
-            self._moe_buffer_rows = (
-                HELD_ROUND_ROWS,
-                HELD_ROUND_ROWS * model.num_held_experts * sparse)
-        #: the hyper-connection maps ONE token slot's pass solves: two a
-        #: layer of a stream several hidden states wide
-        #: (``dispatch/hc_maps``); 0: the stream is one hidden state
-        self._hc_maps_per_slot = \
-            2 * model.num_layers if model.hc_mult > 1 else 0
+        #: what every launch's accounting reads of this engine
+        self._site = launch_work.Site(
+            model, config.block_size, self.mb, self.use_pallas,
+            self.k_width, jnp.dtype(self.dtype).itemsize)
         #: jit cache keyed on (n_bucket, c_bucket, mode, fresh) — the
         #: fresh=True/False split legitimately doubles prefill-shape
         #: compiles (arena-reading vs within-chunk attention programs).
@@ -1932,7 +1900,7 @@ class RaggedInferenceEngineTPU:
         return ladder
 
     def _launch_form(self, nb: int, cb: int, fresh, tokens: int,
-                     chunk_rows: int) -> _Form:
+                     chunk_rows: int) -> launch_work.Form:
         """What the ``(nb, cb, fresh)`` program runs a batch of ``tokens``
         and ``chunk_rows`` over — the device's own rules with ints: the
         instance that holds the batch (:func:`_at_capacity`), or the row
@@ -1941,14 +1909,15 @@ class RaggedInferenceEngineTPU:
         instances = _instances(capacities, nb, cb)
         slots, group_rows = instances[_instance_index(
             instances, tokens, chunk_rows)] if instances else (nb * cb, nb)
-        return _Form(nb, capacities, slots, group_rows,
-                     group_rows * cb + nb if group_rows < nb else nb * cb)
+        return launch_work.Form(
+            nb, capacities, slots, group_rows,
+            group_rows * cb + nb if group_rows < nb else nb * cb)
 
     def _pick_form(self, nb: int, cb: int, fresh, tokens: int,
-                   chunk_rows: int) -> _Form:
+                   chunk_rows: int) -> launch_work.Form:
         """The program a batch of row bucket ``nb`` is packed for, as the
-        :class:`_Form` of its launch: its own bucket's — or, for a SPLIT
-        batch of fewer rows than the engine's full bucket, the FULL-ROW
+        :class:`launch_work.Form` of its launch: its own bucket's — or, for a
+        SPLIT batch of fewer rows than the engine's full bucket, the FULL-ROW
         split program where that one's ladder holds the batch on fewer
         slots. The full-row program is what a loaded replica runs all day,
         so it is built whatever the load; it picks its instance from
@@ -1981,7 +1950,6 @@ class RaggedInferenceEngineTPU:
         launch that raises is therefore not counted."""
         tracer = self._tracer
         with tracer.span("serving/pack"):
-            n = len(batch.uids)
             nb, cb = self._buckets(batch)
             # chunk batches avoid the arena READ in attention (the
             # write→read on the ~GB arena serializes the whole layer scan):
@@ -2013,353 +1981,16 @@ class RaggedInferenceEngineTPU:
                 nb, cb, mode, fresh)(
                 self.params, self.arena, packed, self._rng_dev)
         with tracer.span("serving/count"):
-            # the device's own rules: the instance that holds the batch
-            # (_launch_form), and the write-back's whole blocks until the
-            # tokens are written (_write_back)
-            grouped, attn_row_slots = form.grouped, form.attn_row_slots
-            context_slots = query_tiles = None
-            kv_pages = self._kv_page_work(batch, cb, grouped) \
-                if fresh != "fresh" else None
-            if fresh == "split" and self.use_pallas:
-                # the paged reader walks each row's live pages, then the
-                # rows attend their own keys
-                bs = self.config.block_size
-                context_slots = attn_row_slots + \
-                    int((-(-batch.start_positions // bs)).sum()) * bs
-                query_tiles = self._query_tiles(batch, cb, grouped)
-            write_block = _write_back_slots(capacities, nb * cb)[0]
-            work = self._count_dispatch(
-                program, n, nb, cb, self.mb, tokens,
-                int((batch.start_positions + batch.token_counts).sum()),
-                context_slots=context_slots,
-                kv_window=self._kv_window_tokens(batch),
-                # span arguments only: nothing to compute for no span
-                attn_pairs=self._attn_pairs(batch) if sp is not None
-                else None,
-                query_tiles=query_tiles, token_slots=form.slots,
-                kv_write_slots=-(-tokens // write_block) * write_block,
-                chunk_rows=chunk_rows, lifted=lifted,
-                attn_row_slots=attn_row_slots if grouped else None,
-                state=self._state_work(batch, cb, grouped),
-                kv_pages=kv_pages, picked=self._picked_work(batch))
+            # span arguments only: nothing to compute for no span
+            work = launch_work.launch_work(
+                self._site, program, form, cb, batch.start_positions,
+                batch.token_counts, span=sp is not None)
+            launch_work.count_launch(work, grouped=form.grouped,
+                                     lifted=lifted)
+            self.last_program = program
             if sp is not None:      # still the recorded event's arguments
                 sp.update(work)
         return out
-
-    def _state_work(self, batch: RaggedBatch, chunk: int, grouped: bool):
-        """(rows, resets, chunk tokens) of a launch of a recurrent stack in
-        ONE state-space layer, or None for any other: the rows whose state
-        the launch read and wrote, those of them that began at position 0
-        (their state zeroed in the program), and the tokens that took the
-        chunk form — every token of a launch at the chunk's width, but for
-        the one-token rows of a grouped instance, which step the
-        recurrence. Host arithmetic on the batch's lengths."""
-        if not self.model_config.recurrent:
-            return None
-        fed = batch.token_counts
-        if chunk == 1:
-            formed = 0
-        elif grouped:
-            formed = fed[fed > 1].sum()
-        else:
-            formed = fed.sum()
-        return (len(batch.uids),
-                int(((batch.start_positions == 0) & (fed > 0)).sum()),
-                int(formed))
-
-    def _kv_window_tokens(self, batch: RaggedBatch):
-        """(live, held) tokens of the batch's rows in ONE window layer
-        after this step, or None where the model has no window kind. Held:
-        every token of the row (a window layer keeps its whole history in
-        its pages); live: those some query of this step can still see,
-        ``min(held, window + fed - 1)`` a row. Host arithmetic on the
-        batch's lengths: what a later PR that frees pages behind the
-        window would free is held - live."""
-        model = self.model_config
-        if not model.typed or 1 not in model.layer_kinds:
-            return None
-        held = batch.start_positions + batch.token_counts
-        live = np.minimum(held, model.sliding_window +
-                          batch.token_counts - 1)
-        return int(live.sum()), int(held.sum())
-
-    def _query_tiles(self, batch: RaggedBatch, chunk: int, grouped: bool):
-        """(held, computed) query tiles of a split launch's history reader
-        in ONE layer and KV head, or None for a latent stack (another
-        kernel): of the rows that reach ``paged_attn_lse`` with a history
-        and a token, the tiles of ``TILE_Q`` queries their blocks hold
-        (``chunk / TILE_Q`` a row of the chunk's width: what the kernel
-        computed before it took ``qcounts``; ONE for a row read as a row of
-        one query, ``grouped``), and the tiles it computes — ONE for a row
-        whose live queries fit the small tile (a decode row), all of them
-        for a row of more (``paged_attention._paged_kernel``). ``TILE_Q``
-        is the full kind's (``paged_attention.tile_queries``). Host
-        arithmetic on the batch's lengths."""
-        model = self.model_config
-        if model.latent:
-            return None
-        tile_q = pa.tile_queries(chunk, model.num_heads // model.kv_heads)
-        fed = batch.token_counts[(batch.start_positions > 0) &
-                                 (batch.token_counts > 0)]
-        whole = chunk // tile_q
-        held = np.where((fed > 1) | (not grouped), whole, 1)
-        return int(held.sum()), int(np.where(fed <= tile_q, 1, whole).sum())
-
-    def _kv_page_work(self, batch: RaggedBatch, chunk: int, grouped: bool):
-        """(pages walked, page DMAs issued) by a launch's paged KERNEL
-        readers over all its attention layers, or None where no kernel
-        reads pages of K and V (a fresh step, the XLA readers, a latent
-        stack's ``mla_decode``): the live pages each row's reader must
-        read — a split step's history ``[0, start)`` of the rows that feed
-        a token, a decode step's keys up to its own, from the window's
-        first page in a window layer —, and the copies ``_paged_kernel``
-        issues for them: one of K and one of V a page and PROGRAM, and a
-        row's pages are walked by ``kv_heads / hp`` programs
-        (``paged_attention.heads_per_program`` of the block the row's call
-        gives it: one query a row in a decode step and for a grouped
-        instance's one-token rows, the chunk's width otherwise). 2 DMAs a
-        page where every call holds all of a row's heads, 16 at 8 KV heads
-        fetched a head at a time. Host arithmetic on the batch's
-        lengths."""
-        model = self.model_config
-        split = chunk > 1
-        if not self.use_pallas or model.latent or \
-                (model.typed and not split):
-            return None
-        bs = self.config.block_size
-        fed = batch.token_counts
-        last = batch.start_positions if split else \
-            batch.start_positions + fed
-        reads = (last > 0) & (fed > 0)
-        wide = (fed > 1) | (split and not grouped)      # the chunk's width
-        kinds = model.layer_kinds if model.typed else \
-            (0,) * model.num_layers
-        walked = fetches = 0
-        for kind in (0, 1):
-            layers = sum(1 for a in kinds if a == kind)
-            if not layers:
-                continue
-            kvh = model.kind_kv_heads(kind)
-            pools = [self.arena[name] for name in pa.KIND_POOLS[kind]]
-            first = 0
-            if model.kind_window(kind) is not None:
-                first = np.maximum(batch.start_positions -
-                                   (model.kind_window(kind) - 1), 0) // bs
-            pages = np.where(reads, -(-last // bs) - first, 0)
-            groups = model.num_heads // kvh
-            programs = np.asarray([kvh // pa.heads_per_program(
-                groups * c, kvh, *(pool.shape[-1] // kvh for pool in pools),
-                bs, pools[0].dtype.itemsize) for c in (1, chunk)])
-            walked += layers * int(pages.sum())
-            fetches += layers * 2 * int((pages * programs[1 * wide]).sum())
-        return walked, fetches
-
-    def _picked_work(self, batch: RaggedBatch):
-        """(index pairs scored, latent rows selected, picked pairs) of a
-        launch of a stack that picks its keys, or None for any other.
-        Scored: every fed token times the keys it can see — its row up to
-        itself — summed over the layers that OWN an indexer (what their
-        scorers must compute; a scorer that runs over the page table's
-        width computes more). Selected: the rows of the latent pool the launch's rows
-        must read, ``min(context, index_topk)`` a row after the launch,
-        times the latent layers — beside ``kv_tokens_latent``, the rows
-        HELD. Picked pairs: every fed token times the keys picked for it,
-        ``min(position + 1, index_topk)``, in ONE latent layer (the
-        numerator of a roofline over the picked softmax). Host arithmetic
-        on the batch's lengths."""
-        model = self.model_config
-        if not model.picks_keys:
-            return None
-        start = batch.start_positions.astype(np.int64)
-        fed = batch.token_counts.astype(np.int64)
-        k = model.index_topk
-        # of a row's fed tokens, those that still see at most k keys ...
-        under = np.clip(k - start, 0, fed)
-        return (int((fed * start + fed * (fed + 1) // 2).sum())
-                * model.indexer_layers,
-                int(np.minimum(start + fed, k)[fed > 0].sum())
-                * model.num_layers,
-                # ... pick them all; every later one picks k
-                int((under * start + under * (under + 1) // 2
-                     + (fed - under) * k).sum()))
-
-    def _attn_pairs(self, batch: RaggedBatch):
-        """Live (query, key) pairs of the launch in ONE layer of each
-        kind, or None where the model has no window kind: every fed token
-        times the keys it sees — all of its row up to itself in a full
-        layer (``attn_pairs_full``), at most ``sliding_window`` of them in
-        a window layer (``attn_pairs_window``) — and, of those, the pairs
-        INSIDE the fed chunk (``attn_pairs_own_full`` / ``..._own_window``:
-        what a split step's chunk attention takes; the rest is its
-        history reader's). Host arithmetic on the batch's lengths (the
-        numerators of a roofline over the attention's FLOPs)."""
-        model = self.model_config
-        if not model.typed or 1 not in model.layer_kinds:
-            return None
-        w = model.sliding_window
-        start = batch.start_positions.astype(np.int64)
-        fed = batch.token_counts.astype(np.int64)
-
-        def seen(first, n):
-            """Σ over n queries of min(keys before and at the query, w),
-            the first query having ``first`` keys before it."""
-            whole = np.clip(w - first, 0, n)   # queries that see them all
-            return int((whole * first + whole * (whole + 1) // 2 +
-                        (n - whole) * w).sum())
-
-        return {"attn_pairs_full":
-                    int((fed * start + fed * (fed + 1) // 2).sum()),
-                "attn_pairs_window": seen(start, fed),
-                "attn_pairs_own_full": int((fed * (fed + 1) // 2).sum()),
-                "attn_pairs_own_window": seen(np.zeros_like(start), fed)}
-
-    def _count_dispatch(self, program: str, rows: int, nb: int, chunk: int,
-                        page_width: int, tokens: int, context_tokens: int,
-                        context_slots: Optional[int] = None,
-                        kv_window=None, attn_pairs=None, query_tiles=None,
-                        token_slots: Optional[int] = None,
-                        kv_write_slots: Optional[int] = None,
-                        chunk_rows: int = 0, lifted: bool = False,
-                        attn_row_slots: Optional[int] = None,
-                        state=None, kv_pages=None,
-                        picked=None) -> Dict[str, Any]:
-        """Count one device program launch, right after its jitted call
-        returned (``serving/count``: the device is at work by then; a
-        launch that raises is not counted): the
-        useful work (``tokens`` fed, ``context_tokens`` of live KV they
-        attend) against the work attempted (``slots`` = what the sublayers
-        that act on a token alone ran over: ``token_slots``, the capacity
-        the launch packed its tokens into, or bucketed rows x chunk width
-        where it did not pack; ``row_slots`` = what attention works on:
-        bucketed rows x chunk width, or ``attn_row_slots`` — the ``P x
-        chunk + rows`` of a split launch that took a GROUPED instance
-        (:func:`_instances`), which ``dispatch/split_grouped_steps`` counts
-        (not under ``dispatch/steps.``: those are launches), as
-        ``dispatch/split_steps_at.<slots>`` counts every split launch at
-        the ``slots`` it ran over — one counter a rung of the program's
-        ladder (:meth:`_token_capacities`; the row slots where it does not
-        pack), which sum to ``dispatch/steps.split``;
-        ``chunk_rows`` = the rows that hold more than one token;
-        ``lifted`` = a split launch that took the engine's full-row program
-        in place of its own row bucket's (:meth:`_pick_form`), which
-        ``dispatch/split_lifted_steps`` counts — ``rows_bucket`` is then
-        the program's rows;
-        ``kv_write_slots`` = the updates the
-        launch's KV scatter performs a pool and layer: the packed slots it
-        wrote back, ``row_slots`` where it did not pack;
-        ``context_slots`` = what the attention
-        reads: bucketed rows x the page table's width in tokens unless the
-        caller knows better, as for a split step whose history goes through
-        the paged kernel).
-        Always-on ``dispatch/*`` counters; the same numbers are the
-        ``serving/dispatch`` span's arguments. ``kv_window`` (a model with
-        window layers only: :meth:`_kv_window_tokens`) adds
-        ``dispatch/kv_window_live_tokens`` / ``..._held_tokens`` and the
-        span's ``kv_tokens_full`` (what a full layer holds for the rows:
-        ``context_tokens``), ``kv_tokens_window_live`` and
-        ``kv_tokens_window_held``; ``attn_pairs`` (:meth:`_attn_pairs`) a
-        span argument of each of its names (no counter: its one reader
-        takes the traced launches' spans). A latent stack's span carries
-        ``kv_tokens_latent``: the cached rows ONE latent layer holds for
-        the batch's rows after the launch (``context_tokens``).
-        ``picked`` (:meth:`_picked_work`: a stack that picks its keys) adds
-        ``dispatch/index_tokens_scored`` / ``dispatch/kv_tokens_selected``
-        and the span's arguments of those names, and the span's
-        ``attn_pairs_selected`` (no counter, as ``attn_pairs``).
-        ``query_tiles`` (:meth:`_query_tiles`: a split launch under the
-        paged kernel) adds ``dispatch/query_tiles`` /
-        ``dispatch/query_tiles_live`` — the query tiles the history
-        reader's rows hold and those it computes — and the span's
-        arguments of those names. ``kv_pages`` (:meth:`_kv_page_work`: a
-        launch whose pages the paged kernel reads) adds
-        ``dispatch/kv_pages_walked`` / ``dispatch/kv_page_fetches`` — the
-        live pages its readers must read over all attention layers and the
-        page DMAs the kernel issues for them — and the span's arguments of
-        those names. ``state`` (:meth:`_state_work`: a
-        recurrent stack) adds ``dispatch/state_rows``,
-        ``dispatch/state_resets`` and ``dispatch/ssm_chunk_tokens`` and the
-        span's arguments of those names. A stack with experts adds
-        ``dispatch/moe_assignments`` and the span's ``moe_assignments``:
-        fed tokens x experts a token x sparse layers, what the launch's
-        routers hand the experts' dispatch (all of them, held here or
-        not); a stack whose sparse layers are the share's
-        (:func:`~deepspeed_tpu.parallel.moe.held_experts_moe_layer`) also
-        ``dispatch/moe_buffer_rows`` and the span's ``moe_buffer_rows``:
-        held experts x ``HELD_ROUND_ROWS`` x sparse layers — the rows the
-        first round's buffers hold — for a launch of more than
-        ``HELD_ROUND_ROWS`` ``slots``, 0 for one of fewer (every held
-        expert computes every token there). A stream several hidden states
-        wide (``hc_mult`` over 1) adds ``dispatch/hc_maps`` and the span's
-        ``hc_maps``: ``slots`` x 2 x layers, the hyper-connection maps —
-        a Sinkhorn solve each — the launch ran."""
-        from deepspeed_tpu.telemetry.registry import registry
-        row_slots = nb * chunk
-        slots = row_slots if token_slots is None else token_slots
-        if kv_write_slots is None:
-            kv_write_slots = row_slots
-        if attn_row_slots is not None:
-            registry.counter("dispatch/split_grouped_steps").inc()
-            row_slots = attn_row_slots
-        if context_slots is None:
-            context_slots = nb * page_width * self.config.block_size
-        self.last_program = program
-        counted = [("host_calls", 1), ("tokens", tokens),
-                   ("token_slots", slots),
-                   ("kv_write_slots", kv_write_slots),
-                   ("context_tokens", context_tokens),
-                   ("context_slots", context_slots),
-                   ("chunk_rows", chunk_rows),
-                   ("attn_row_slots", row_slots),
-                   (f"steps.{program}", 1)]
-        if program == "split":
-            counted.append((f"split_steps_at.{slots}", 1))
-            counted.append(("split_lifted_steps", 1 * lifted))
-        for name, by in counted:
-            registry.counter("dispatch/" + name).inc(by)
-        work = {"program": program, "rows": rows, "rows_bucket": nb,
-                "chunk": chunk, "tokens": tokens, "slots": slots,
-                "row_slots": row_slots, "chunk_rows": chunk_rows,
-                "kv_write_slots": kv_write_slots,
-                "context_tokens": context_tokens,
-                "context_slots": context_slots}
-        if kv_window is not None:
-            live, held = kv_window
-            registry.counter("dispatch/kv_window_live_tokens").inc(live)
-            registry.counter("dispatch/kv_window_held_tokens").inc(held)
-            work.update(kv_tokens_full=context_tokens,
-                        kv_tokens_window_live=live,
-                        kv_tokens_window_held=held)
-        if attn_pairs is not None:
-            work.update(attn_pairs)
-        if self.model_config.latent:
-            work["kv_tokens_latent"] = context_tokens
-        if picked is not None:
-            for name, by in zip(("index_tokens_scored",
-                                 "kv_tokens_selected"), picked):
-                work[name] = by
-                registry.counter("dispatch/" + name).inc(by)
-            work["attn_pairs_selected"] = picked[2]
-        for names, counted in (
-                (("query_tiles", "query_tiles_live"), query_tiles),
-                (("kv_pages_walked", "kv_page_fetches"), kv_pages),
-                (("state_rows", "state_resets", "ssm_chunk_tokens"), state)):
-            for name, by in zip(names, counted or ()):
-                work[name] = by
-                registry.counter("dispatch/" + name).inc(by)
-        if self._moe_assignments_per_token:
-            work["moe_assignments"] = tokens * \
-                self._moe_assignments_per_token
-            registry.counter("dispatch/moe_assignments").inc(
-                work["moe_assignments"])
-        if self._moe_buffer_rows is not None:
-            few, rows = self._moe_buffer_rows
-            work["moe_buffer_rows"] = rows * (slots > few)
-            registry.counter("dispatch/moe_buffer_rows").inc(
-                work["moe_buffer_rows"])
-        if self._hc_maps_per_slot:
-            work["hc_maps"] = slots * self._hc_maps_per_slot
-            registry.counter("dispatch/hc_maps").inc(work["hc_maps"])
-        return work
 
     # -- convenience generation loop ---------------------------------------
 

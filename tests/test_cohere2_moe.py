@@ -387,18 +387,13 @@ def test_paged_kernel_at_16_queries_a_head_across_the_window_edge():
 def test_dispatch_counts_the_live_pairs(tiny):
     """``attn_pairs_*`` of the ``serving/dispatch`` span against a count
     of the mask itself."""
-    from deepspeed_tpu.inference.ragged import RaggedBatch
-    _, cfg, params, _, _ = tiny
-    eng = RaggedInferenceEngineTPU(cfg, dict(
-        dtype="float32", max_sequences=4, num_blocks=32, block_size=8,
-        max_seq_len=128, max_batch_tokens=64, prefill_chunk=16),
-        params=params)
+    from deepspeed_tpu.inference import launch_work
+    _, cfg, _, _, _ = tiny
+    site = launch_work.Site(cfg, 8, 16, False)
     starts, fed = np.array([40, 0, 20, 63]), np.array([16, 16, 7, 1])
-    batch = RaggedBatch(uids=[0, 1, 2, 3],
-                        token_ids=np.zeros((4, 16), np.int32),
-                        token_counts=fed.astype(np.int32),
-                        start_positions=starts.astype(np.int32),
-                        slots=np.arange(4, dtype=np.int32))
+    launch = launch_work.Launch("split", 16, False, 64, int(fed.sum()),
+                                starts.astype(np.int32),
+                                fed.astype(np.int32))
     want = dict.fromkeys(("attn_pairs_full", "attn_pairs_window",
                           "attn_pairs_own_full", "attn_pairs_own_window"), 0)
     for s, n in zip(starts, fed):
@@ -410,16 +405,18 @@ def test_dispatch_counts_the_live_pairs(tiny):
                 if qpos - kpos < cfg.sliding_window:
                     want["attn_pairs_window"] += 1
                     want["attn_pairs_own_window"] += own
-    assert eng._attn_pairs(batch) == want
-    work = eng._count_dispatch("split", 4, 4, 16, 16, int(fed.sum()),
-                               int((starts + fed).sum()),
-                               kv_window=eng._kv_window_tokens(batch),
-                               attn_pairs=eng._attn_pairs(batch))
+    assert launch_work.attn_pairs(site, launch) == want
+    work = launch_work.launch_work(site, "split",
+                                   launch_work.Form(4, (), 64, 4, 64), 16,
+                                   launch.start, launch.fed)
     assert {k: work[k] for k in want} == want
+    assert (work["kv_tokens_window_held"], work["kv_tokens_full"]) == \
+        (int((starts + fed).sum()),) * 2
     # a stack without a window kind counts none
-    dense = RaggedInferenceEngineTPU(
+    dense = launch_work.Site(
         tf.DecoderConfig(vocab_size=64, hidden_size=32, num_layers=1,
                          num_heads=2, pos_emb="rope", use_bias=False),
-        dict(dtype="float32", max_sequences=2, num_blocks=8, block_size=8,
-             max_seq_len=32, prefill_chunk=8))
-    assert dense._attn_pairs(batch) is None
+        8, 4, False)
+    assert not dense.terms and not set(want) & set(launch_work.launch_work(
+        dense, "split", launch_work.Form(4, (), 64, 4, 64), 16,
+        launch.start, launch.fed))

@@ -670,7 +670,10 @@ def test_the_dispatch_span_counts_what_was_scored_and_selected(tiny):
             sum(s[name] for s in spans)
     # a stack that picks nothing has neither
     plain = dataclasses.replace(cfg, layer_indexer=None)
-    assert _engine(plain, params)._picked_work(None) is None
+    from deepspeed_tpu.inference import launch_work
+    picks = lambda c: launch_work.picked_work in [
+        t.work for t in launch_work.Site(c, 8, 16, False).terms]
+    assert picks(cfg) and not picks(plain)
 
 
 def test_the_new_scopes_are_in_the_programs(tiny):

@@ -535,17 +535,30 @@ def test_the_frontend_serves_it_through_the_run_ahead_pump(tiny):
             logits[len(prompt) - 1:].argmax(-1).tolist()
 
 
+#: a plain stack, with no experts
+DENSE = dict(hidden_size=32, num_layers=1, num_heads=2, intermediate_size=64,
+             vocab_size=VOCAB)
+
+
+def _launched(cfg, program, n, nb, chunk, slots=None):
+    """The work of one launch, counted: ``n`` rows of one token each in
+    a ``nb x chunk`` program that ran over ``slots`` token slots (None: the
+    row form's)."""
+    from deepspeed_tpu.inference import launch_work
+    form = launch_work.Form(nb, (), slots or nb * chunk, nb, nb * chunk)
+    work = launch_work.launch_work(
+        launch_work.Site(cfg, 8, 32, False), program, form, chunk,
+        np.full(n, 29, np.int32), np.ones(n, np.int32))
+    launch_work.count_launch(work)
+    return work
+
+
 def test_dispatch_counts_the_experts_assignments(tiny):
-    _, cfg, params, _, _ = tiny
-    eng = engine(cfg, params)
-    work = eng._count_dispatch("decode", 3, 4, 1, 32, 3, 90)
+    _, cfg, _, _, _ = tiny
+    work = _launched(cfg, "decode", 3, 4, 1)
     assert work["moe_assignments"] == 3 * 4 * 4
-    dense = RaggedInferenceEngineTPU(
-        tf.DecoderConfig(hidden_size=32, num_layers=1, num_heads=2,
-                         intermediate_size=64, vocab_size=VOCAB),
-        dict(ENGINE))
-    assert "moe_assignments" not in dense._count_dispatch(
-        "decode", 3, 4, 1, 32, 3, 90)
+    assert "moe_assignments" not in _launched(
+        tf.DecoderConfig(**DENSE), "decode", 3, 4, 1)
 
 
 @pytest.mark.parametrize("launch, rows", [
@@ -564,26 +577,18 @@ def test_dispatch_counts_the_buffer_rows_of_a_many_token_launch(
     from deepspeed_tpu.parallel.moe import HELD_ROUND_ROWS
     from deepspeed_tpu.telemetry.registry import registry
     assert HELD_ROUND_ROWS == 128
-    _, cfg, params, _, _ = tiny
-    eng = engine(cfg, params)
+    _, cfg, _, _, _ = tiny
     counter = registry.counter("dispatch/moe_buffer_rows")
     before = counter.value
-    program, n, nb, chunk, slots = launch
-    work = eng._count_dispatch(program, n, nb, chunk, 32, 3, 90,
-                               token_slots=slots)
+    work = _launched(cfg, *launch)
     assert work["moe_buffer_rows"] == rows == counter.value - before
 
 
 def test_a_stack_without_experts_counts_no_buffer_rows():
     from deepspeed_tpu.telemetry.registry import registry
-    dense = RaggedInferenceEngineTPU(
-        tf.DecoderConfig(hidden_size=32, num_layers=1, num_heads=2,
-                         intermediate_size=64, vocab_size=VOCAB),
-        dict(ENGINE))
     counter = registry.counter("dispatch/moe_buffer_rows")
     before = counter.value
-    work = dense._count_dispatch("split", 3, 8, 128, 32, 3, 90,
-                                 token_slots=512)
+    work = _launched(tf.DecoderConfig(**DENSE), "split", 3, 8, 128, 512)
     assert "moe_buffer_rows" not in work and counter.value == before
 
 

@@ -450,23 +450,31 @@ def test_generate_is_the_stepwise_greedy_run_with_an_eos_inside(tiny):
 
 
 def test_dispatch_counts_the_state_work(tiny):
+    from deepspeed_tpu.inference import launch_work
     from deepspeed_tpu.inference.ragged import RaggedBatch
     _, cfg, params, _, _ = tiny
+    site = launch_work.Site(cfg, 8, 32, False)
+    fed = np.array([128, 1, 44, 1], np.int32)
+    starts = np.array([0, 60, 128, 0], np.int32)
+
+    def state(chunk, grouped):
+        return tuple(launch_work.state_work(site, launch_work.Launch(
+            "split", chunk, grouped, 4 * chunk, 174, starts, fed)).values())
+    assert state(128, grouped=True) == (4, 2, 172)
+    assert state(128, grouped=False) == (4, 2, 174)
+    assert state(1, grouped=False) == (4, 2, 0)
+    work = launch_work.launch_work(
+        site, "split", launch_work.Form(4, (), 512, 1, 132), 128, starts,
+        fed)
+    assert (work["state_rows"], work["state_resets"],
+            work["ssm_chunk_tokens"]) == (4, 2, 172)
     eng = engine(cfg, params)
     eng._put_validated([0, 1, 2, 3], [[1]] * 4)
     batch = RaggedBatch(
         uids=[0, 1, 2, 3], token_ids=np.zeros((4, 128), np.int32),
-        token_counts=np.array([128, 1, 44, 1], np.int32),
-        start_positions=np.array([0, 60, 128, 0], np.int32),
+        token_counts=fed, start_positions=starts,
         slots=np.asarray([eng.state.seqs[u].slot for u in range(4)],
                          np.int32))
-    assert eng._state_work(batch, 128, grouped=True) == (4, 2, 172)
-    assert eng._state_work(batch, 128, grouped=False) == (4, 2, 174)
-    assert eng._state_work(batch, 1, grouped=False) == (4, 2, 0)
-    work = eng._count_dispatch("split", 4, 4, 128, 32, 174, 362,
-                               state=eng._state_work(batch, 128, True))
-    assert (work["state_rows"], work["state_resets"],
-            work["ssm_chunk_tokens"]) == (4, 2, 172)
     packed = eng._pack(batch, 8, 128)
     assert packed[-8:].tolist() == batch.slots.tolist() + [8] * 4   # trash
     assert sorted(batch.slots.tolist()) == [0, 1, 2, 3]

@@ -1074,30 +1074,24 @@ def test_engine_serves_through_the_history_kernel_what_the_xla_reader_does(
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
 
 
-def _page_work_engine(stack, use_pallas=True):
-    """What ``_kv_page_work`` reads of an engine, at the serving cells'
-    widths over pages of 128: Mistral-7B's 12 layers of 32 / 8 heads of
-    128; MiMo-V2.5's two window layers (64 / 8 heads, a window of 128) and
-    one full layer (4 KV heads), K 256 and V 128 lanes a head; a latent
+def _page_work_site(stack, use_pallas=True):
+    """What ``launch_work.kv_page_work`` reads of a site, at the serving
+    cells' widths over pages of 128: Mistral-7B's 12 layers of 32 / 8 heads
+    of 128; MiMo-V2.5's two window layers (64 / 8 heads, a window of 128)
+    and one full layer (4 KV heads), K 256 and V 128 lanes a head; a latent
     stack."""
     import types
+    from deepspeed_tpu.inference import launch_work
     typed = stack != "uniform"
     model = types.SimpleNamespace(
         latent=stack == "latent", typed=typed, num_layers=12,
         layer_kinds=(1, 1, 0) if typed else None,
-        num_heads=64 if typed else 32,
+        num_heads=64 if typed else 32, v_dim=128,
         kind_kv_heads=lambda kind: (8 if kind == 1 else 4) if typed else 8,
-        kind_window=lambda kind: 128 if kind == 1 else None)
-    dk = 256 if typed else 128
-    pool = lambda kvh, d: jax.ShapeDtypeStruct((9, 128, kvh * d),
-                                               jnp.bfloat16)
-    eng = object.__new__(RaggedInferenceEngineTPU)
-    eng.model_config, eng.use_pallas = model, use_pallas
-    eng.config = types.SimpleNamespace(block_size=128)
-    eng.arena = {"k": pool(4 if typed else 8, dk),
-                 "v": pool(4 if typed else 8, 128),
-                 "k_win": pool(8, dk), "v_win": pool(8, 128)}
-    return eng
+        kind_window=lambda kind: 128 if kind == 1 else None,
+        picks_keys=False, recurrent=False, num_experts=0, hc_mult=1)
+    return launch_work.Site(model, 128, 32, use_pallas,
+                            k_lanes=256 if typed else 128, itemsize=2)
 
 
 #: case → (stack, chunk, grouped, rows' starts, rows' fed tokens, want)
@@ -1130,18 +1124,22 @@ _PAGE_WORK = {
 @pytest.mark.parametrize("case", list(_PAGE_WORK))
 def test_page_fetches_follow_the_heads_a_program_holds(case):
     """``dispatch/kv_pages_walked`` / ``dispatch/kv_page_fetches`` of a
-    launch (``engine_v2._kv_page_work``, host arithmetic): the live pages
+    launch (``launch_work.kv_page_work``, host arithmetic): the live pages
     the paged kernel's readers must read over all attention layers, and
     two DMAs a page and program — ``kv_heads / heads_per_program`` programs
     a row, by the block its call gives it. Without the kernel: nothing."""
-    from deepspeed_tpu.inference.ragged import RaggedBatch
+    from deepspeed_tpu.inference import launch_work
     stack, chunk, grouped, starts, fed, want = _PAGE_WORK[case]
-    batch = RaggedBatch(list(range(len(fed))), None, np.asarray(fed),
-                        np.asarray(starts), None)
-    assert _page_work_engine(stack)._kv_page_work(batch, chunk,
-                                                  grouped) == want
-    assert _page_work_engine(stack, use_pallas=False)._kv_page_work(
-        batch, chunk, grouped) is None
+    launch = launch_work.Launch(
+        "split" if chunk > 1 else "decode", chunk, grouped, 0, sum(fed),
+        np.asarray(starts), np.asarray(fed))
+    site = _page_work_site(stack)
+    reads = lambda at: launch_work.kv_page_work in [t.work for t in at.terms]
+    assert reads(site) == (stack != "latent")
+    got = launch_work.kv_page_work(site, launch) if reads(site) else {}
+    assert (tuple(got.values()) or None) == want
+    assert list(got) == ["kv_pages_walked", "kv_page_fetches"][:len(got)]
+    assert not reads(_page_work_site(stack, use_pallas=False))
 
 
 def test_capacities_past_the_rows_slots_are_refused(devices):

@@ -124,7 +124,7 @@ def test_scope_is_the_innermost_vocabulary_word():
         "attn_history"
     assert scope_of_op_name("jit(f)/flash_fwd/pallas_call")["scope"] is None
     assert scope_of_op_name("")["scope"] is None
-    assert len(set(SCOPE_VOCABULARY)) == len(SCOPE_VOCABULARY) == 33
+    assert len(set(SCOPE_VOCABULARY)) == len(SCOPE_VOCABULARY)
     # a latent layer's words, inside and beside the older ones
     assert scope_of_op_name(
         "jit(f)/attn_latent/bthd,lhd->bthl/dot_general")["scope"] == \
@@ -579,16 +579,15 @@ def test_launch_counts_the_updates_its_kv_write_performs(traced, launch):
 def test_untraced_serving_step_counts_and_computes_no_argument(monkeypatch):
     """With the tracer off the counters still advance by the packed
     batch's sums, and the span arguments are never unpacked."""
-    from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
+    from deepspeed_tpu.inference import launch_work
 
     class Untouchable(dict):
         def keys(self):
             raise AssertionError("span arguments built with the tracer off")
 
-    real = RaggedInferenceEngineTPU._count_dispatch
-    monkeypatch.setattr(
-        RaggedInferenceEngineTPU, "_count_dispatch",
-        lambda self, *a, **k: Untouchable(real(self, *a, **k)))
+    real = launch_work.launch_work
+    monkeypatch.setattr(launch_work, "launch_work",
+                        lambda *a, **k: Untouchable(real(*a, **k)))
     tr = telemetry.tracer
     was = tr.enabled
     tr.configure(enabled=False)
@@ -667,6 +666,18 @@ def _dispatch_counters():
             if n.startswith("dispatch/") and n not in HOST_COUNTERS}
 
 
+def _wrap_term(monkeypatch, name, work):
+    """``launch_work.TERMS`` with the term ``name`` computed by ``work``
+    and applied to every stack, for the engines built from here on (a site
+    picks its terms once)."""
+    from deepspeed_tpu.inference import launch_work
+    names = [t.work.__name__ for t in launch_work.TERMS]
+    assert name in names
+    monkeypatch.setattr(launch_work, "TERMS", tuple(
+        t._replace(applies=lambda site: True, work=work) if n == name else t
+        for n, t in zip(names, launch_work.TERMS)))
+
+
 def _pump(steps=9, max_new_tokens=6):
     """Eight launches in eight steps; the ninth collects the last."""
     from deepspeed_tpu.serving import ServingFrontend
@@ -733,7 +744,7 @@ def test_each_phase_of_the_pump_runs_under_its_leaf(traced, monkeypatch):
     ``serving/retire``, the frontend's own work under ``serving/plan`` and
     ``serving/bookkeeping``."""
     import jax as jax_module
-    from deepspeed_tpu.inference import engine_v2, ragged
+    from deepspeed_tpu.inference import engine_v2, launch_work, ragged
     from deepspeed_tpu.serving.frontend import ServingFrontend
     calls = []
 
@@ -751,23 +762,28 @@ def test_each_phase_of_the_pump_runs_under_its_leaf(traced, monkeypatch):
     stamped(eng, "_buckets", "serving/pack")
     stamped(eng, "_pack", "serving/pack")
     stamped(eng, "_step_fn", "serving/dispatch")
-    for attr in ("_kv_window_tokens", "_attn_pairs", "_count_dispatch"):
-        stamped(eng, attr, "serving/count")
+    for attr in ("launch_work", "count_launch"):
+        stamped(launch_work, attr, "serving/count")
+    for name in ("kv_window_tokens", "attn_pairs"):
+        _wrap_term(monkeypatch, name, lambda site, launch, name=name:
+                   calls.append(("serving/count", name,
+                                 (traced.now() - traced._t0) * 1e6)) or {})
     stamped(jax_module, "device_get", "serving/fetch")
     stamped(ServingFrontend, "_update_degraded", "serving/bookkeeping")
     stamped(ServingFrontend, "_fan_out", "serving/fanout")
     _pump(steps=4)
     events = traced.events()
     assert {attr for _leaf, attr, _t in calls} >= {
-        "next_batch", "mark_scheduled", "_pack", "_step_fn", "_attn_pairs",
-        "_count_dispatch", "device_get", "_update_degraded", "_fan_out"}
+        "next_batch", "mark_scheduled", "_pack", "_step_fn", "attn_pairs",
+        "kv_window_tokens", "launch_work", "count_launch", "device_get",
+        "_update_degraded", "_fan_out"}
     for leaf, attr, at in calls:
         assert any(s["ts"] <= at <= s["ts"] + s["dur"]
                    for s in _spans(events, leaf)), (attr, leaf)
     # the launch comes first, then its accounting
     order = [attr for _leaf, attr, _t in calls
-             if attr in ("_step_fn", "_count_dispatch")]
-    assert order == ["_step_fn", "_count_dispatch"] * 4
+             if attr in ("_step_fn", "launch_work", "count_launch")]
+    assert order == ["_step_fn", "launch_work", "count_launch"] * 4
 
 
 @pytest.mark.parametrize("path", sorted(PARENT))
@@ -794,12 +810,12 @@ def test_launch_arguments_counters_and_tokens_are_the_parents(traced, path):
 
 def test_untraced_launch_computes_no_span_argument_and_still_counts(
         monkeypatch):
-    """``_attn_pairs`` is span arguments only: with the tracer off it is
-    not called, and the always-on counters advance as they do traced."""
-    from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
+    """``launch_work.attn_pairs`` is span arguments only: with the tracer
+    off it is not called, and the always-on counters advance as they do
+    traced."""
     called = []
-    monkeypatch.setattr(RaggedInferenceEngineTPU, "_attn_pairs",
-                        lambda self, batch: called.append(1))
+    _wrap_term(monkeypatch, "attn_pairs",
+               lambda site, launch: called.append(1) or {})
     tr = telemetry.tracer
     was = tr.enabled
     grew = {}

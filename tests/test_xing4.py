@@ -408,22 +408,19 @@ def test_dispatch_counts_the_maps_a_launch_solves(launch, solves):
     """``dispatch/hc_maps`` and the span's ``hc_maps``: token slots x 2
     sublayers x 6 layers."""
     from deepspeed_tpu.telemetry.registry import registry
-    cfg = config_from_hf(small(num_hidden_layers=6))
-    eng = engine(cfg, None)
+    from tests.test_granitemoehybrid import _launched
     counter = registry.counter("dispatch/hc_maps")
     before = counter.value
-    program, n, nb, chunk, slots = launch
-    work = eng._count_dispatch(program, n, nb, chunk, 32, 3, 90,
-                               token_slots=slots)
+    work = _launched(config_from_hf(small(num_hidden_layers=6)), *launch)
     assert work["hc_maps"] == solves == counter.value - before
 
 
 def test_a_stream_of_one_hidden_state_counts_no_maps():
     from deepspeed_tpu.telemetry.registry import registry
-    eng = engine(config_from_hf(small(hc_mult=1)), None)
+    from tests.test_granitemoehybrid import _launched
     counter = registry.counter("dispatch/hc_maps")
     before = counter.value
-    work = eng._count_dispatch("split", 3, 8, 128, 32, 3, 90,
-                               token_slots=512)
+    work = _launched(config_from_hf(small(hc_mult=1)), "split", 3, 8, 128,
+                     512)
     assert "hc_maps" not in work and counter.value == before
 

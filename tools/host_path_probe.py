@@ -41,9 +41,9 @@ kernel's readers had to read and the page DMAs issued for them — their
 ratio is 2 where every call fetches a page once for all of a row's KV heads,
 16 at 8 KV heads a head at a time.
 ``split_steps`` (PR 46) is the joint histogram of the window's split
-launches by what ``_count_dispatch`` was handed: ``by_slots`` (launches at
-each token capacity taken), ``hist`` (``"<tokens, in bins of 64>x<chunk
-rows>"`` -> launches), ``tokens_mean``, and ``fits``: the share (%) of them
+launches by what ``launch_work.launch_work`` returned: ``by_slots``
+(launches at each token capacity taken), ``hist`` (``"<tokens, in bins of
+64>x<chunk rows>"`` -> launches), ``tokens_mean``, and ``fits``: the share (%) of them
 that an instance of ``<slots>x<chunk rows>`` would hold, for the rungs a
 ladder of token capacities might have — the same on any tree, since it reads
 the batches and not the programs.
@@ -338,22 +338,25 @@ def main() -> int:
     own = own_arguments()
     at_open = []
     open_window = bench_run.Context.open_window
-    from deepspeed_tpu.inference.engine_v2 import RaggedInferenceEngineTPU
     launches, engines = [], []
-    count = RaggedInferenceEngineTPU._count_dispatch
 
     def in_window():
         return bool(at_open) and not at_close
 
-    def counted(self, program, rows, nb, chunk, page_width, tokens,
-                *args, **kwargs):
-        engines[:] = [self]
-        if program == "split" and in_window():
-            launches.append((tokens, kwargs.get("chunk_rows", 0),
-                             kwargs.get("token_slots") or nb * chunk, nb))
-        return count(self, program, rows, nb, chunk, page_width, tokens,
-                     *args, **kwargs)
-    RaggedInferenceEngineTPU._count_dispatch = counted
+    try:
+        from deepspeed_tpu.inference import launch_work
+    except ImportError:             # a tree before the module: no histogram
+        launch_work = None
+    if launch_work is not None:
+        work_of = launch_work.launch_work
+
+        def counted(*args, **kwargs):
+            work = work_of(*args, **kwargs)
+            if work["program"] == "split" and in_window():
+                launches.append((work["tokens"], work["chunk_rows"],
+                                 work["slots"], work["rows_bucket"]))
+            return work
+        launch_work.launch_work = counted
 
     from deepspeed_tpu.serving import ServingFrontend
     from deepspeed_tpu.serving.prefix_cache import PrefixCache
@@ -386,6 +389,7 @@ def main() -> int:
     terminate = ServingFrontend.terminate_inflight
 
     def closed(self, *args, **kwargs):
+        engines[:] = [self.engine]
         at_close[:] = at_close or counters()
         return terminate(self, *args, **kwargs)
     ServingFrontend.terminate_inflight = closed
