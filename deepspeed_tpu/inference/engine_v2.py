@@ -616,11 +616,21 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
     (a head of 192 padded to 256 lanes for the kernel) q and k are
     zero-padded to it, and the scores keep the true width's scale.
 
-    On the chip: the split step's history attention is the paged kernel
-    (``paged_attn_lse``) for both kinds; the chunk's own attention, the
-    fresh step's, and the decode step's paged read are the XLA forms (the
-    flash kernels take one head width for Q, K and V; the decode read of a
-    window layer is two pages a row).
+    On the chip (``use_pallas``) whatever reads the pools is the paged
+    kernel, for both kinds: the split step's history (``paged_attn_lse``,
+    under ``attn_history``) and the decode step's read of what it has just
+    written (the same kernel with the step's ``counts``, one query a row and
+    every KV head of the row a program, named ``paged_attn_decode`` under
+    ``attn_core``: each row's live pages from its window's first, where the
+    XLA form gathers the page table's whole width, or the window's two
+    pages, for every row — which a program of few rows over a narrow table
+    still does, where that gather is under ``pa.DECODE_KERNEL_BYTES`` a
+    layer: tens of microseconds, against a kernel body more to trace and
+    lower in every such program of a replica's set-up). The chunk's own
+    attention and the fresh step's are the XLA forms (the flash kernels
+    take one head width for Q, K and V), and so is the paged mode at ``c >
+    1``, the reference the other modes are tested against, which no engine
+    selects.
 
     A LATENT layer (kind 2) has one pool of one row a token (``[c ;
     k_rope]``, zero lanes up to the pool's width) and two forms of one
@@ -757,6 +767,16 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             if fresh_prefill == "fresh":
                 out, lse = pa.causal_attention_with_lse(
                     q, k, v, window=window, scale=scale)
+            elif use_pallas and c == 1 and pa.decode_reads_by_kernel(
+                    q.shape[0], pt_l.shape[1], window, pools[kname].shape[1],
+                    sum(pools[name].shape[-1] * pools[name].dtype.itemsize
+                        for name in place[0])):
+                # the decode step: the row's own key is in the pool by
+                # now, and ``counts`` says so
+                out, lse = pa.paged_attention_with_lse(
+                    q, pools[kname], pools[vname], pt_l, starts, counts,
+                    window=window, scale=scale, qcounts=counts,
+                    name=pa.DECODE_KERNEL)
             else:
                 out, lse = pa.paged_attention_xla(
                     q, pools[kname], pools[vname], pt_l, starts, counts,

@@ -151,16 +151,20 @@ def query_tiles(site: Site, launch: Launch) -> Dict[str, int]:
 
 def kv_page_work(site: Site, launch: Launch) -> Dict[str, int]:
     """The paged KERNEL's reads over all attention layers (none in a fresh
-    step, nor a typed stack's decode step: XLA readers). Walked: the live
-    pages each row's reader must read — a split step's history, a decode
-    step's keys up to its own, from the window's first page in a window
-    layer. Fetches: one DMA of K and one of V a page and PROGRAM, a row's
-    pages walked by ``kv_heads / heads_per_program`` programs of the block
-    its call gives it (one query a row in a decode step and for a grouped
+    step: its chunk is its whole context). Walked: the live pages each
+    row's reader must read — a split step's history, a decode step's keys
+    up to its own (the uniform stack's ``paged_attn``, a typed stack's
+    ``paged_attn_decode`` — in the programs whose rows' gather is worth a
+    kernel, ``pa.decode_reads_by_kernel`` of the launch's row bucket: the
+    others read by the XLA gather and count nothing), from the window's
+    first page in a window layer.
+    Fetches: one DMA of K and one of V a page and PROGRAM, a row's pages
+    walked by ``kv_heads / heads_per_program`` programs of the block its
+    call gives it (one query a row in a decode step and for a grouped
     instance's one-token rows, the chunk's width otherwise)."""
     model, bs, chunk = site.model, site.block_size, launch.chunk
     split = chunk > 1
-    if launch.program == "fresh" or (model.typed and not split):
+    if launch.program == "fresh":
         return {}
     start, fed = launch.start, launch.fed
     last = start if split else start + fed
@@ -172,10 +176,14 @@ def kv_page_work(site: Site, launch: Launch) -> Dict[str, int]:
         layers = sum(1 for a in kinds if a == kind)
         if not layers:
             continue
-        kvh = model.kind_kv_heads(kind)
+        kvh, window = model.kind_kv_heads(kind), model.kind_window(kind)
+        if model.typed and not split and not pa.decode_reads_by_kernel(
+                launch.slots, site.page_width, window, bs,
+                kvh * (site.k_lanes + model.v_dim) * site.itemsize):
+            continue    # this program's rows gather so little: the XLA read
         first = 0
-        if model.kind_window(kind) is not None:
-            first = np.maximum(start - (model.kind_window(kind) - 1), 0) // bs
+        if window is not None:
+            first = np.maximum(start - (window - 1), 0) // bs
         pages = np.where(reads, -(-last // bs) - first, 0)
         groups = model.num_heads // kvh
         programs = np.asarray([kvh // pa.heads_per_program(

@@ -301,6 +301,12 @@ def _masked_attention(q: jax.Array, kg: jax.Array, vg: jax.Array,
     return out, lse.transpose(0, 3, 1, 2).reshape(n, c, h)
 
 
+def _span_pages(mb: int, span: int, bs: int) -> int:
+    """The pages ``span`` positions can touch wherever they start, at most
+    the table's ``mb``."""
+    return min(mb, (max(span, 1) + bs - 2) // bs + 1)
+
+
 def _window_pages(page_table: jax.Array, lowest: jax.Array, span: int,
                   bs: int):
     """The pages a window touches: ``lowest`` [n] is each row's lowest
@@ -309,7 +315,7 @@ def _window_pages(page_table: jax.Array, lowest: jax.Array, span: int,
     positions [n, np*bs]); a page past the table's width repeats the last
     entry under positions no query can see."""
     n, mb = page_table.shape
-    pages = min(mb, (max(span, 1) + bs - 2) // bs + 1)
+    pages = _span_pages(mb, span, bs)
     first = jnp.maximum(lowest, 0) // bs                          # [n]
     idx = first[:, None] + jnp.arange(pages, dtype=jnp.int32)[None]
     ids = jnp.take_along_axis(page_table, jnp.minimum(idx, mb - 1), axis=1)
@@ -735,6 +741,43 @@ def _paged_kernel(pt_ref, starts_ref, counts_ref, qcounts_ref, q_ref, k_hbm,
     pl.when(jnp.logical_not(live))(lambda: write_dead(0))
 
 
+#: the kernel's name where a typed stack's DECODE program reads through it
+#: (``engine_v2._ragged_forward_typed``): not ``paged_attn_lse*``, which a
+#: trace's readers take for a split step's history
+DECODE_KERNEL = "paged_attn_decode"
+
+#: what the XLA reader would COPY for a decode program's rows in one layer
+#: (:func:`decode_reads_by_kernel`) from which that program reads through
+#: the kernel. Both readers' time grows with the rows: the gather copies and
+#: reads again every row's whole table (a window kind: its window's pages),
+#: the kernel walks the live pages at about 4 us a program before its first
+#: page arrives. At 100 MB (MiMo-V2.5's window kind, 64 rows of two pages)
+#: they are level — 0.29 | 0.33 ms a layer in the cell's decode program,
+#: 0.30 | 0.20 as eight chained reads alone — and past it the kernel wins
+#: (0.28 | 0.85 at 201 MB, 0.47 | 2.41 at 537: docs/kernels.md, PR 61);
+#: under it a program's few rows gather in tens of microseconds, and a
+#: kernel body more in the program is 0.6 s of a replica's set-up for each
+#: of its row buckets (cell 4: +8.6 s of a 93 s set-up with the kernel in
+#: all seven decode programs; my chip runs, PR 61)
+DECODE_KERNEL_BYTES = 64 * 2 ** 20
+
+
+def decode_reads_by_kernel(rows: int, table_pages: int,
+                           window: Optional[int], block_size: int,
+                           token_bytes: int) -> bool:
+    """Whether a typed stack's decode program of ``rows`` rows reads a layer
+    of this kind through the kernel: what :func:`paged_attention_xla` would
+    copy of the pools for it — every row's ``table_pages`` (a window kind:
+    the pages its window can touch) of ``block_size`` tokens of
+    ``token_bytes`` (a token's K and V, all KV heads) — is at least
+    ``DECODE_KERNEL_BYTES``. A function of the program's shapes alone, which
+    the engine's program and its accounting (``launch_work.kv_page_work``)
+    both ask."""
+    pages = table_pages if window is None else \
+        _span_pages(table_pages, window, block_size)
+    return rows * pages * block_size * token_bytes >= DECODE_KERNEL_BYTES
+
+
 #: a head of HALF a lane tile (LFM2's 64): the kernel below slices a head as
 #: whole 128-lane tiles of a page, so two KV heads are read as ONE of 128
 #: lanes (:func:`pairs_heads`)
@@ -778,13 +821,17 @@ def _unpair_outputs(out: jax.Array, kvh: int) -> jax.Array:
 # and one lowered function (0.15-0.25 s a call on the serving host; the
 # typed 64-row split program calls it 21 times for 6 shapes)
 @functools.partial(jax.jit, static_argnames=(
-    "with_lse", "interpret", "window", "scale", "tile_q", "heads"))
+    "with_lse", "interpret", "window", "scale", "tile_q", "heads", "name"))
 def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
                 with_lse: bool, interpret: bool, window=None, scale=None,
-                qcounts=None, tile_q=None, heads=None):
+                qcounts=None, tile_q=None, heads=None, name=None):
     """The ``pallas_call`` of both wrappers below → (out [n, c, h, dv],
     lse [n, c, h] fp32 or None). The kernel's name in a device trace is
-    ``paged_attn_lse`` with the logsumexp output, ``paged_attn`` without.
+    ``name``; None: ``paged_attn_lse`` with the logsumexp output (a split
+    step's history: what the benchmark's ``paged_attn_lse*`` readers divide
+    the split steps' bytes by), ``paged_attn`` without (the uniform stack's
+    decode read). A typed stack's decode read returns the logsumexp too, for
+    its sink, and is no history call: it passes ``DECODE_KERNEL``.
     ``qcounts`` [n]: each row's live queries (None: all ``c``);
     ``tile_q``, ``heads``: another small tile than :func:`tile_queries`',
     other KV heads a program than :func:`heads_per_program`'s, for
@@ -798,7 +845,7 @@ def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
             _pair_queries(q, kvh), arena_k, arena_v, page_table, starts,
             counts, with_lse=with_lse, interpret=interpret, window=window,
             scale=1.0 / math.sqrt(dh) if scale is None else scale,
-            qcounts=qcounts, tile_q=tile_q, heads=heads)
+            qcounts=qcounts, tile_q=tile_q, heads=heads, name=name)
         return _unpair_outputs(out, kvh), lse
     groups = h // kvh
     mb = page_table.shape[1]
@@ -845,7 +892,7 @@ def _paged_call(q, arena_k, arena_v, page_table, starts, counts, *,
         ),
         out_shape=out_shape,
         interpret=interpret,
-        name="paged_attn_lse" if with_lse else "paged_attn",
+        name=name or ("paged_attn_lse" if with_lse else "paged_attn"),
     )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
       counts.astype(jnp.int32), qcounts.astype(jnp.int32), qk, arena_k,
       arena_v)
@@ -880,11 +927,14 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
                              interpret: bool = False,
                              window: Optional[int] = None,
                              scale: Optional[float] = None,
-                             qcounts: Optional[jax.Array] = None):
+                             qcounts: Optional[jax.Array] = None,
+                             name: Optional[str] = None):
     """Pallas paged attention returning (out, lse [n, c, h] fp32) for the
     partial-attention merge. ``counts=0`` gives HISTORY-only semantics
     (keys [0, starts)) — a split step's history part, where the arena
-    is a read-only input rather than a carried/donated buffer.
+    is a read-only input rather than a carried/donated buffer; with the
+    step's ``counts`` the rows' own keys are read from the pool too (a typed
+    stack's decode read, after its write: ``name=DECODE_KERNEL``).
     K and V may differ in width (q as wide as a K head, the output as
     wide as a V head); ``window``: key j is visible to query i only when
     ``i - j < window`` and the walk starts at the window's first page;
@@ -893,10 +943,11 @@ def paged_attention_with_lse(q: jax.Array, arena_k: jax.Array,
     LIVE queries of each row, the leading ``qcounts[i]`` of its ``c``
     (default: all ``c``); the kernel's work follows them
     (:func:`_paged_kernel`), and a query past them gets zeros and an lse
-    of -1e30."""
+    of -1e30. ``name``: the kernel's name in a device trace
+    (:func:`_paged_call`)."""
     return _paged_call(q, arena_k, arena_v, page_table, starts, counts,
                        with_lse=True, interpret=interpret, window=window,
-                       scale=scale, qcounts=qcounts)
+                       scale=scale, qcounts=qcounts, name=name)
 
 
 # ---------------------------------------------------------------------------

@@ -33,15 +33,22 @@ LAUNCHES = {
 }
 
 #: (configuration, kind) → what its stack adds, in the order the keys are
-#: laid down: the parent's ``_count_dispatch`` under ``use_pallas``, pages of
-#: 128 and a page table 40 wide
+#: laid down: the engine's ``_count_dispatch`` of before PR 60 under
+#: ``use_pallas``, pages of 128 and a page table 40 wide — and, since a typed
+#: stack's decode program reads through the paged kernel too (PR 61), its
+#: decode launch's pages by hand: the rows' own keys lie in pages 2 + 1 + 2 +
+#: 36 + 1 = 42 of a full layer
 ADDS = {
     ("command-a-plus-l4-e16-serve", "decode"): {
         "kv_tokens_full": 4969, "kv_tokens_window_live": 724,
         "kv_tokens_window_held": 4969, "attn_pairs_full": 4969,
         "attn_pairs_window": 724, "attn_pairs_own_full": 5,
-        "attn_pairs_own_window": 5, "moe_assignments": 40,
-        "moe_buffer_rows": 0},
+        "attn_pairs_own_window": 5,
+        # 42 pages to the rows' own keys in the full layer; 9 from the
+        # window's first page (the row at 4,500: pages 33-35 of a window of
+        # 256) in each of three window layers; every KV head a program
+        "kv_pages_walked": 69, "kv_page_fetches": 138,
+        "moe_assignments": 40, "moe_buffer_rows": 0},
     ("command-a-plus-l4-e16-serve", "split"): {
         "kv_tokens_full": 5741, "kv_tokens_window_live": 1365,
         "kv_tokens_window_held": 5741, "attn_pairs_full": 54769,
@@ -65,8 +72,9 @@ ADDS = {
         "kv_tokens_selected": 288, "attn_pairs_selected": 4168,
         "moe_assignments": 4288, "moe_buffer_rows": 512},
     ("granite-4.0-h-small-l10-e36-serve", "decode"): {
-        "state_rows": 5, "state_resets": 0, "ssm_chunk_tokens": 0,
-        "moe_assignments": 45, "moe_buffer_rows": 0},
+        "kv_pages_walked": 42, "kv_page_fetches": 84, "state_rows": 5,
+        "state_resets": 0, "ssm_chunk_tokens": 0, "moe_assignments": 45,
+        "moe_buffer_rows": 0},
     ("granite-4.0-h-small-l10-e36-serve", "split"): {
         "query_tiles": 35, "query_tiles_live": 35, "kv_pages_walked": 44,
         "kv_page_fetches": 88, "state_rows": 6, "state_resets": 1,
@@ -74,15 +82,16 @@ ADDS = {
         "moe_buffer_rows": 1536},
     # experts in the file, no sparse layer at rehearsal depth
     ("jamba2-3b-l28-serve", "decode"): {
-        "state_rows": 5, "state_resets": 0, "ssm_chunk_tokens": 0,
-        "moe_buffer_rows": 0},
+        "kv_pages_walked": 42, "kv_page_fetches": 84, "state_rows": 5,
+        "state_resets": 0, "ssm_chunk_tokens": 0, "moe_buffer_rows": 0},
     ("jamba2-3b-l28-serve", "split"): {
         "query_tiles": 35, "query_tiles_live": 35, "kv_pages_walked": 44,
         "kv_page_fetches": 88, "state_rows": 6, "state_resets": 1,
         "ssm_chunk_tokens": 265, "moe_buffer_rows": 0},
     ("lfm2-24b-a2b-l40-e8-serve", "decode"): {
-        "state_rows": 5, "state_resets": 0, "ssm_chunk_tokens": 0,
-        "moe_assignments": 30, "moe_buffer_rows": 0},
+        "kv_pages_walked": 42, "kv_page_fetches": 84, "state_rows": 5,
+        "state_resets": 0, "ssm_chunk_tokens": 0, "moe_assignments": 30,
+        "moe_buffer_rows": 0},
     ("lfm2-24b-a2b-l40-e8-serve", "split"): {
         "query_tiles": 19, "query_tiles_live": 19, "kv_pages_walked": 44,
         "kv_page_fetches": 88, "state_rows": 6, "state_resets": 1,
@@ -92,8 +101,11 @@ ADDS = {
         "kv_tokens_full": 4969, "kv_tokens_window_live": 522,
         "kv_tokens_window_held": 4969, "attn_pairs_full": 4969,
         "attn_pairs_window": 522, "attn_pairs_own_full": 5,
-        "attn_pairs_own_window": 5, "moe_assignments": 80,
-        "moe_buffer_rows": 0},
+        "attn_pairs_own_window": 5,
+        # the full layer's 42; 8 in each of two window layers (window 128:
+        # the row at 4,500 walks pages 34 and 35)
+        "kv_pages_walked": 58, "kv_page_fetches": 116,
+        "moe_assignments": 80, "moe_buffer_rows": 0},
     ("mimo-v2.5-l7-e16-serve", "split"): {
         "kv_tokens_full": 5741, "kv_tokens_window_live": 853,
         "kv_tokens_window_held": 5741, "attn_pairs_full": 54769,
@@ -108,8 +120,9 @@ ADDS = {
         "query_tiles": 35, "query_tiles_live": 35, "kv_pages_walked": 88,
         "kv_page_fetches": 176},
     ("nemotron3-nano-l26-e16-serve", "decode"): {
-        "state_rows": 5, "state_resets": 0, "ssm_chunk_tokens": 0,
-        "moe_assignments": 10, "moe_buffer_rows": 0},
+        "kv_pages_walked": 42, "kv_page_fetches": 84, "state_rows": 5,
+        "state_resets": 0, "ssm_chunk_tokens": 0, "moe_assignments": 10,
+        "moe_buffer_rows": 0},
     ("nemotron3-nano-l26-e16-serve", "split"): {
         "query_tiles": 35, "query_tiles_live": 35, "kv_pages_walked": 44,
         "kv_page_fetches": 88, "state_rows": 6, "state_resets": 1,
@@ -149,9 +162,18 @@ def _counters():
 
 
 @pytest.mark.parametrize("name", sorted({n for n, _ in ADDS}))
-def test_a_launchs_work_is_what_the_engine_counted(name):
+def test_a_launchs_work_is_what_the_engine_counted(name, monkeypatch):
     assert len(ADDS) == 2 * 10
     at = site(name)
+    # as the code has it an 8-row program at rehearsal widths gathers too
+    # little for a typed stack's decode read to take the kernel
+    # (``pa.decode_reads_by_kernel``): its launch counts no pages; the
+    # uniform stack's always does
+    form, _, starts, fed, _ = LAUNCHES["decode"]
+    small = launch_work.launch_work(at, "decode", form, 1, np.asarray(starts),
+                                    np.asarray(fed))
+    assert (small.get("kv_pages_walked", 0) > 0) == (not at.model.typed)
+    monkeypatch.setattr(launch_work.pa, "DECODE_KERNEL_BYTES", 0)
     for kind, (form, lifted, starts, fed, base) in LAUNCHES.items():
         want = {**base, **ADDS[name, kind]}
         starts, fed = np.asarray(starts, np.int32), np.asarray(fed, np.int32)

@@ -2,6 +2,7 @@
 tests/unit/inference/v2/ragged/ + kernels/ragged_ops tests)."""
 
 import functools
+import types
 
 import numpy as np
 import pytest
@@ -1074,6 +1075,128 @@ def test_engine_serves_through_the_history_kernel_what_the_xla_reader_does(
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
 
 
+#: case -> (the benchmark configuration whose rehearsal stack it runs, what
+#: replaces of its ``DecoderConfig``, the layer kinds it must hold)
+_TYPED_DECODE = {
+    # a full kind and a window kind with its learned sink, K heads of 192
+    # in pools of 256 lanes (``_paged_reader``'s whole tiles) beside V's 128
+    "full_and_window_with_a_sink": (
+        "mimo-v2.5-l7-e16-serve", {"sliding_window": 24}, (0, 1, 1)),
+    # no sink, three window layers before the full one, the parallel block
+    "window_without_a_sink": (
+        "command-a-plus-l4-e16-serve", {"sliding_window": 24}, (1, 1, 1, 0)),
+    # heads of 64: the kernel reads two KV heads as one of 128 lanes, the
+    # pools unpadded; short convolutions around the attention layer
+    "paired_heads_of_64": (
+        "lfm2-24b-a2b-l40-e8-serve", {}, (5, 5, 0, 5)),
+    # one full layer between state-space layers
+    "full_between_state_spaces": (
+        "nemotron3-nano-l26-e16-serve", {}, (3, -1, 0, 3)),
+}
+
+
+@pytest.mark.parametrize("case", list(_TYPED_DECODE))
+def test_typed_decode_step_reads_through_the_kernel_what_the_xla_read_does(
+        monkeypatch, case):
+    """A typed stack's DECODE program (``c == 1``: it reads what it has
+    just written) with the paged kernel in it (``use_pallas``; interpret
+    mode) against the same program on ``paged_attention_xla``, float32:
+    the kernel is called once an attention layer under the name
+    ``paged_attn_decode`` with the step's ``counts``, and the live rows'
+    logits and every pool come out the same — a row whose own key is all
+    it sees (no page of history), one inside its first page, one whose key
+    opens a new page, one many pages long and past the window, and a
+    padding row (no token, the trash page)."""
+    import dataclasses
+    from benchmark.lib import model as model_lib
+    from deepspeed_tpu.inference import engine_v2
+    from deepspeed_tpu.models.transformer import init_params
+    from deepspeed_tpu.ops import ssm
+    name, replaced, kinds = _TYPED_DECODE[case]
+    # five rows of tiny pages gather a few KB: every program takes the kernel
+    monkeypatch.setattr(pa, "DECODE_KERNEL_BYTES", 0)
+    cfg = dataclasses.replace(
+        model_lib.build_model(model_lib.load_config(name), rehearse=True),
+        init_std=0.1, **replaced)
+    assert cfg.layer_kinds == kinds
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(len(case))
+    bs, mb, hist = 8, 8, 48
+    starts = np.asarray([0, 5, 16, 43, 0], np.int32)
+    counts = np.asarray([1, 1, 1, 1, 0], np.int32)
+    n, live = len(starts), counts > 0
+    pages = np.where(live, -(-(starts + counts) // bs), 0)
+    nb = int(pages.sum())
+    pt = np.full((n, mb), nb, np.int32)
+    free = iter(rng.permutation(nb))
+    for i in range(n):
+        pt[i, :pages[i]] = [next(free) for _ in range(pages[i])]
+    slots = jnp.asarray(np.where(live, np.arange(n), 8), jnp.int32)
+    history = jnp.asarray(rng.integers(0, cfg.vocab_size, (n, hist)),
+                          jnp.int32)
+    step = jnp.asarray(rng.integers(0, cfg.vocab_size, (n, 1)), jnp.int32)
+    calls = []
+
+    def decode_kernel(*args, **kw):
+        calls.append((kw.get("name"), args[0].shape[1],
+                      args[5] is kw.get("qcounts")))
+        return kernel(*args, interpret=True, **kw)
+
+    kernel = pa.paged_attention_with_lse
+    monkeypatch.setattr(pa, "paged_attention_with_lse", decode_kernel)
+
+    def run(use_pallas):
+        _, lanes = engine_v2._paged_reader(cfg, types.SimpleNamespace(
+            use_pallas=use_pallas, block_size=bs))
+        arena = pa.init_arena_typed(
+            cfg.layer_kinds,
+            {a: cfg.kind_kv_heads(a) for a in set(kinds) & set(pa.KIND_POOLS)},
+            nb, bs, lanes, cfg.v_dim, jnp.float32)
+        if cfg.recurrent:
+            arena.update(ssm.init_state_pools(cfg, 8, jnp.float32))
+        kw = {"use_pallas": use_pallas,
+              "slots": slots if cfg.recurrent else None}
+        # the history, through the paged mode's chunk (the XLA read)
+        _, arena = engine_v2.ragged_forward(
+            cfg, params, arena, history, jnp.asarray(starts),
+            jnp.zeros((n,), jnp.int32), jnp.asarray(pt), **kw)
+        assert not calls
+        return engine_v2.ragged_forward(
+            cfg, params, arena, step, jnp.asarray(counts),
+            jnp.asarray(starts), jnp.asarray(pt), **kw), lanes
+
+    (want_logits, want), width = run(False)
+    assert not calls
+    (got_logits, got), lanes = run(True)
+    layers = sum(1 for kind in kinds if kind in (0, 1))
+    assert calls == [(pa.DECODE_KERNEL, 1, True)] * layers
+    # ... and as the code has it, a program whose rows gather this little
+    # keeps the XLA read (``pa.decode_reads_by_kernel``)
+    monkeypatch.undo()
+    del calls[:]
+    run(True)
+    assert not calls
+    assert lanes % 128 == 0 or pa.pairs_heads(
+        cfg.head_dim, cfg.v_dim, cfg.kind_kv_heads(0))
+    assert np.abs(np.asarray(want_logits)[live]).max() > 0.1
+    np.testing.assert_allclose(np.asarray(got_logits)[live],
+                               np.asarray(want_logits)[live],
+                               rtol=2e-4, atol=2e-4)
+    assert set(got) == set(want)
+    for pool in want:
+        a, b = np.asarray(got[pool]), np.asarray(want[pool])
+        if not ssm.is_state_pool(pool):
+            kept = np.arange(b.shape[0]) % (nb + 1) != nb
+            a, b = a[kept], b[kept]
+        else:
+            a, b = a[:-1], b[:-1]
+        if a.shape != b.shape:      # the kernel's K pool: whole lane tiles
+            heads = cfg.kind_kv_heads(1 if pool.endswith("_win") else 0)
+            a = a.reshape(*a.shape[:2], heads, lanes)[..., :width] \
+                .reshape(b.shape)
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=pool)
+
+
 def _page_work_site(stack, use_pallas=True):
     """What ``launch_work.kv_page_work`` reads of a site, at the serving
     cells' widths over pages of 128: Mistral-7B's 12 layers of 32 / 8 heads
@@ -1115,10 +1238,25 @@ _PAGE_WORK = {
     "typed_split_grouped": ("typed", 128, True, (700, 100), (1, 128),
                             (2 * 3 + 7, 2 * 2 * (2 * 1 + 1 * 8) +
                              2 * (6 * 1 + 1 * 4))),
-    "typed_decode_is_the_xla_reader": ("typed", 1, False, (700,), (1,),
-                                       None),
+    # a typed stack's decode program reads through the kernel too: each of
+    # two window layers its window's pages to the row's own key (700: pages
+    # 4 and 5; 127: page 0; 128: its own key opens page 1, the window's
+    # first key lies in page 0), the full layer 6 + 1 + 2; a row of one
+    # query holds every KV head a program; a padded row reads nothing
+    "typed_decode": ("typed", 1, False, (700, 127, 128, 0), (1, 1, 1, 0),
+                     (2 * 5 + 9, 2 * (2 * 5 + 9))),
+    # ... and from the window's first page only: a row far past it
+    "typed_decode_window_walks_two_pages": (
+        "typed", 1, False, (3000,), (1,), (2 * 2 + 24, 2 * (2 * 2 + 24))),
+    # ... in the 64-row program. An 8-row program's window layers would
+    # gather 8 rows x 2 pages x 786 KB = 12.6 MB, under
+    # ``pa.DECODE_KERNEL_BYTES``: they keep the XLA read and count nothing;
+    # its full layer (8 x 32 pages x 393 KB = 100.7 MB) walks its pages
+    "typed_decode_of_8_rows": ("typed", 1, False, (3000,), (1,), (24, 48)),
     "latent_is_another_kernel": ("latent", 128, True, (700,), (1,), None),
 }
+#: a launch's token slots (a decode launch's: its program's rows) where not 64
+_PAGE_WORK_SLOTS = {"typed_decode_of_8_rows": 8}
 
 
 @pytest.mark.parametrize("case", list(_PAGE_WORK))
@@ -1131,8 +1269,9 @@ def test_page_fetches_follow_the_heads_a_program_holds(case):
     from deepspeed_tpu.inference import launch_work
     stack, chunk, grouped, starts, fed, want = _PAGE_WORK[case]
     launch = launch_work.Launch(
-        "split" if chunk > 1 else "decode", chunk, grouped, 0, sum(fed),
-        np.asarray(starts), np.asarray(fed))
+        "split" if chunk > 1 else "decode", chunk, grouped,
+        _PAGE_WORK_SLOTS.get(case, 64), sum(fed), np.asarray(starts),
+        np.asarray(fed))
     site = _page_work_site(stack)
     reads = lambda at: launch_work.kv_page_work in [t.work for t in at.terms]
     assert reads(site) == (stack != "latent")

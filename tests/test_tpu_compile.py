@@ -718,6 +718,38 @@ def _kv_scatter_updates(text, pools):
             if int(np.prod(list(map(int, m.group(1).split(","))))) in sizes]
 
 
+_GATHER = re.compile(r" = \w+\[([\d,]*)\]\S* gather\(.*op_name=\"[^\"]*/"
+                     r"attn_core/")
+
+
+def _attn_core_gathers(text):
+    """The elements of every ``gather`` under ``attn_core`` in a compiled
+    module: what ``paged_attention_xla`` copies of the pools, ``[rows,
+    pages, page, lanes]`` a pool and layer."""
+    return [int(np.prod(list(map(int, m.group(1).split(",")))))
+            for m in _GATHER.finditer(text)]
+
+
+def _check_typed_decode_reads_through_the_kernel(text, layers, nb, mb,
+                                                 xla_read):
+    """A typed stack's DECODE program (ISSUE 61): the paged kernel once an
+    attention layer under ``attn_core`` by the name ``paged_attn_decode``,
+    NO kernel whose name begins ``paged_attn_lse`` (a trace's readers take
+    that for a split step's history), and no gather of a page table's worth
+    of tokens under ``attn_core`` — which ``xla_read``, the XLA reader
+    alone under that scope compiled for the same chip, does hold."""
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    table = scope_table_from_hlo(text)
+    kernels = [n for n in table if n.startswith("paged_attn")]
+    assert len(kernels) == layers and all(
+        n.startswith("paged_attn_decode") and
+        table[n]["scope"] == "attn_core" for n in kernels), kernels
+    assert "paged_attn_lse" not in text
+    wide = nb * mb * 128
+    assert not [g for g in _attn_core_gathers(text) if g >= wide]
+    assert [g for g in _attn_core_gathers(xla_read) if g >= wide]
+
+
 def _top_slots(step, nb=64):
     """The most updates a 64-row program's KV scatter may perform: its top
     capacity, the rows of a decode step. ``step``: an entry of
@@ -939,6 +971,26 @@ def test_mimo_step_scatters_its_token_slots(kind, one_chip,
             "mimo", compiled, text, "paged_attn_lse", 2, _typed_step(
                 one_chip, model, (128, "split", _TWO_RUNGS), 8,
                 make_arena)[0])
+    if kind != "decode":
+        return
+    # the decode program reads what it wrote through the kernel, a full
+    # and a window layer alike; the XLA read of the full layer's pools
+    # alone, for the pattern: 64 rows x 8 pages gathered
+    arena = jax.eval_shape(make_arena)
+
+    def xla_read(q, k, v, pt, starts, counts):
+        with jax.named_scope("attn_core"):
+            return pa.paged_attention_xla(q, k, v, pt, starts, counts,
+                                          with_lse=True)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    i32 = functools.partial(on_chip, dtype=jnp.int32)
+    read = jax.jit(xla_read).lower(
+        on_chip((64, 1, 64, 256), jnp.bfloat16),
+        *(on_chip(arena[name].shape, jnp.bfloat16) for name in ("k", "v")),
+        i32((64, 8)), i32((64,)), i32((64,))).compile().as_text()
+    _check_typed_decode_reads_through_the_kernel(text, 2, 64, 8, read)
 
 
 def test_full_row_split_program_holds_its_ladder_on_v5e(

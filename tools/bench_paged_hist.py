@@ -46,7 +46,20 @@ heads over a latent pool 640 lanes wide, contexts 128–4,200); since PR 47
 cell 9 ``mistral7b-l12-serve-longprompt-closed8``'s call (8 rows, each a
 whole live chunk over 0–3,712 tokens of its own history) and the decode
 programs' reader (``paged_attn``, ``[64, 1]`` with the step's own key),
-every kernel of ``--parent-file`` / ``--sweep`` on the same inputs."""
+every kernel of ``--parent-file`` / ``--sweep`` on the same inputs.
+
+``--decode`` (PR 61): a typed stack's DECODE read at the six typed cells'
+shapes (``DECODE_SHAPES``: the rows of the cell's engine, its page table's
+width, contexts of its mix) — ``[rows, 1]`` with ``counts = 1``, the row's
+own key in the pool — through the kernel as the decode program calls it
+(``paged_attn_decode``: the lse out, every KV head of a row a program, the
+walk from the window's first page) beside ``paged_attention_xla``, which
+gathers the table's whole width (a window kind: the window's pages) for
+every row: ms a call of each, the pages the kernel walks and the pages
+the gather copies, the largest difference of the two on out and lse.
+``--layers N`` (8) reads follow one another in one executable, each one's
+query a function of the read before it, and a call is the Nth part: at 1
+each side carries what an executable's launch costs alone (0.1-0.15 ms)."""
 
 import argparse
 import importlib.util
@@ -82,6 +95,28 @@ SHAPES = {
     "cell9": (8, 128, 32, 8, 128, 128, 128, 32, 512, None, (0, 3712), 8),
     "cell7": (64, 128, 32, 2, 128, 128, 128, 32, 512, None, (128, 2500), 3),
 }
+#: a typed stack's decode read, ``SHAPES``' fields at chunk 1: cell 4
+#: MiMo-V2.5 (contexts to 768 in a table of 8 pages), cell 6 Command A+ (16
+#: rows, 86 pages a row, window 4,096), cell 7 Nemotron 3 Nano (2 KV heads),
+#: cell 8 Granite 4.0-H Small, cell 10 Jamba2-3B (20 queries on ONE KV head,
+#: 86 pages a row), cell 12 LFM2-24B-A2B (8 KV heads of 64, read in pairs)
+DECODE_SHAPES = {
+    "cell4_window": (64, 1, 64, 8, 256, 128, 192, 8, 512, 128, (16, 767), 0),
+    "cell4_full": (64, 1, 64, 4, 256, 128, 192, 8, 512, None, (16, 767), 0),
+    "cell6_window": (16, 1, 128, 8, 128, 128, 128, 86, 1376, 4096,
+                     (2560, 10240), 0),
+    "cell6_full": (16, 1, 128, 8, 128, 128, 128, 86, 1376, None,
+                   (2560, 10240), 0),
+    "cell7": (64, 1, 32, 2, 128, 128, 128, 32, 512, None, (128, 2500), 0),
+    "cell8": (64, 1, 32, 8, 128, 128, 128, 8, 512, None, (16, 767), 0),
+    "cell10": (64, 1, 20, 1, 128, 128, 128, 86, 5504, None, (2560, 10240),
+               0),
+    "cell12": (64, 1, 32, 8, 64, 64, 64, 32, 2048, None, (128, 2500), 0),
+}
+TINY_DECODE = {
+    "tiny_decode": (4, 1, 8, 2, 128, 128, 128, 4, 16, None, (8, 60), 0),
+    "tiny_decode_window": (4, 1, 8, 2, 128, 128, 128, 4, 16, 24, (8, 60), 0),
+    "tiny_decode_pairs": (4, 1, 8, 4, 64, 64, 64, 4, 16, None, (8, 60), 0)}
 TINY = {"tiny": (4, 16, 8, 2, 128, 128, 128, 4, 16, None, (8, 60), 1),
         "tiny_window": (4, 16, 8, 2, 128, 128, 128, 4, 16, 24, (8, 60), 1)}
 SWEEP = (1, 2, 4, 8, 16, 32)
@@ -270,6 +305,53 @@ def decode_reader(a, say, mods):
             us_a_turn=sec * 1e6 / walked / shape[3])
 
 
+def decode_section(a, say, shapes, block):
+    """``--decode``: the typed decode programs' read, kernel beside gather."""
+    for name, shape in shapes.items():
+        n, _, _, kvh, dk, dv, true, mb, _, window = shape[:10]
+        q, ak, av, pt, st, one = inputs(shape, "one", a.seed, block)
+        scale = true ** -0.5
+        readers = {
+            "kernel": lambda *args: pa_here.paged_attention_with_lse(
+                *args, interpret=a.rehearse, window=window, scale=scale,
+                qcounts=args[-1], name=pa_here.DECODE_KERNEL),
+            "xla": lambda *args: pa_here.paged_attention_xla(
+                *args, window=window, scale=scale, with_lse=True)}
+        st_np = np.asarray(st)
+        first_page = 0 if window is None else \
+            np.maximum(st_np - (window - 1), 0) // block
+        walked = int((-(-(st_np + 1) // block) - first_page).sum())
+        gathered = n * (mb if window is None else
+                        pa_here._span_pages(mb, window, block))
+        line, base = {}, None
+        for label, fn in readers.items():
+            def layers(q, ak, av, pt, *rest, fn=fn):
+                """``--layers`` reads in ONE executable, each one's query
+                a function of the read before it and its pages its own
+                (the table shifted: no two gathers alike), as a decode
+                program's layers follow one another: the first's (out,
+                lse), the last's."""
+                first = None
+                for l in range(a.layers):
+                    last = fn(q, ak, av, (pt + 7 * l) % (ak.shape[0] - 1),
+                              *rest)
+                    first = first or last
+                    q = q + (jnp.mean(last[0].astype(jnp.float32)) *
+                             1e-3).astype(q.dtype)
+                return first, last
+
+            sec, ((out, lse), _) = timed(
+                jax.jit(layers), (q, ak, av, pt, st, one), a.reps, a.rounds)
+            base, line["max_diff"] = agree(out, lse, np.ones((n, 1), bool),
+                                           base)
+            line[f"ms_a_call_{label}"] = sec * 1e3 / a.layers
+        say(shape=name, call="decode", layers=a.layers, rows=[n, 1],
+            kv_heads=kvh, lanes=[dk, dv], table_pages=mb, window=window,
+            pages_walked=walked, pages_gathered=gathered,
+            mb_a_page=block * kvh * (dk + dv) * 2 / 1e6,
+            us_a_page_kernel=line["ms_a_call_kernel"] * 1e3 / walked, **line)
+
+
 def turns_section(a, say, shapes, mods, block):
     """PR 38's table: the row form's call by the rows' live queries, every
     kernel of ``mods`` on the same inputs, then the decode programs' reader."""
@@ -315,6 +397,8 @@ def main():
     ap.add_argument("--sweep", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--groups", action="store_true")
+    ap.add_argument("--decode", action="store_true")
+    ap.add_argument("--layers", type=int, default=8)
     ap.add_argument("--seed", type=int, default=3800000011)
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--rounds", type=int, default=5)
@@ -324,7 +408,10 @@ def main():
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not a.rehearse:
         sys.exit("no TPU here: --rehearse runs the control flow on the CPU")
-    shapes = TINY if a.rehearse else SHAPES
+    if a.decode:
+        shapes = TINY_DECODE if a.rehearse else DECODE_SHAPES
+    else:
+        shapes = TINY if a.rehearse else SHAPES
     if a.shapes:
         shapes = {k: shapes[k] for k in a.shapes.split(",")}
     block = 16 if a.rehearse else BS
@@ -342,7 +429,9 @@ def main():
         lines.append(line)
         print(json.dumps(line), flush=True)
 
-    if a.groups:
+    if a.decode:
+        decode_section(a, say, shapes, block)
+    elif a.groups:
         groups_section(a, say, shapes, mods, block)
     else:
         turns_section(a, say, shapes, mods, block)
