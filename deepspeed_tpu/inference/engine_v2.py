@@ -916,12 +916,13 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             out = lay.to_tokens(out)
         return out if own else tl.latent_expand_out(cfg, a, out)
 
-    def state_space(lay, kind, p, place, h_in, pools):
+    def state_space(lay, kind, p, place, h32, dtype, pools):
         """A state-space layer's mixer (``tl.mixer_forms`` of its ``kind``)
-        on its normed input (token-wise form) → its output in the same
-        form; ``pools`` holds the layer's two state pools (``place``: their
-        names; a mixer with no scan has the convolution's alone), which it
-        reads and writes. Rows of ONE query first, in SLOT order: the
+        on its normed input (token-wise form, float32: read in the compute
+        ``dtype`` unless the kind says otherwise, ``tl.mixer_input``) → its
+        output in the same form; ``pools`` holds the layer's two state
+        pools (``place``: their names; a mixer with no scan has the
+        convolution's alone), which it reads and writes. Rows of ONE query first, in SLOT order: the
         layer's whole pool takes one elementwise pass (a slot with no live
         row has ``Δ = 0`` and keeps its state; a wide row's is reset or
         left as it is), the rows' inputs scattered to their slots and their
@@ -931,6 +932,7 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
         group writes nothing)."""
         sname, cname = place
         forms = tl.mixer_forms(kind, use_pallas)
+        h_in = tl.mixer_input(forms, h32, dtype)
         scans = forms.step is not None
         held = (sname, cname) if scans else (cname,)
         state_scope, conv_scope, scan_scope = forms.scopes
@@ -957,7 +959,7 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                     state = ssm.carried(pools[sname][at], reset)
             with jax.named_scope(conv_scope):
                 u, tail = ssm.conv_rows(cfg, p, group.take(xbc), tail, live,
-                                        forms.conv_dtype)
+                                        forms.conv_dtype, forms.conv_silu)
                 dt_g = jax.tree.map(group.take, dt)
             dt_g = forms.inputs(cfg, p, u, dt_g, live)
             if group.c > 1:
@@ -1022,7 +1024,7 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
             h, maps = tl.layer_input(cfg, lp, x)
             if kind in STATE_SPACE_KINDS:
                 out = state_space(lay, kind, tl.mixer_tree(kind, lp), place,
-                                  h.astype(dtype), pools)
+                                  h, dtype, pools)
             elif kind < 0:
                 out = None
             else:
@@ -1032,7 +1034,7 @@ def _ragged_forward_typed(cfg: DecoderConfig, params, arena,
                         lp["indexer"], iplace))
                 out = tl.typed_attn_out(cfg, lp["attn"], attend(
                     lay, kind, lp["attn"], place, h.astype(dtype),
-                    tables[kind], pools, chunk_kv))
+                    tables[kind], pools, chunk_kv), h.astype(dtype))
             x = tl.block_residual(cfg, lp, x, h, out, moe_fn, lay.valid,
                                   dtype, maps)
         x = _norm(cfg, params["final_norm"],

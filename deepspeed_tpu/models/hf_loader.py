@@ -36,7 +36,7 @@ _FAMILIES = ("llama", "mistral", "mixtral", "qwen", "qwen2", "qwen2_moe",
               "phi", "phi3", "gpt_bigcode", "gptj", "bert", "distilbert",
               "gpt_neo", "internlm", "mimo_v2", "deepseek_v3",
               "cohere2_moe", "nemotron_h", "granitemoehybrid", "jamba",
-              "glm_moe_dsa", "lfm2_moe", "xing4_0")
+              "glm_moe_dsa", "lfm2_moe", "xing4_0", "qwen3_next")
 
 
 def _map_hf_act(act: str) -> str:
@@ -74,6 +74,8 @@ def config_from_hf(hf: Dict[str, Any]) -> DecoderConfig:
         return _lfm2_moe_config(hf)
     if mt == "xing4_0":
         return _xing4_config(hf)
+    if mt == "qwen3_next":
+        return _qwen3_next_config(hf)
     if mt == "bert":
         return DecoderConfig(
             hidden_size=hf["hidden_size"],
@@ -971,6 +973,175 @@ def _lfm2_moe_config(hf: Dict[str, Any]) -> DecoderConfig:
                       int(share["held_experts"])) if share else None)
 
 
+def _qwen3_next_config(hf: Dict[str, Any]) -> DecoderConfig:
+    """Qwen3-Next's stack (Qwen3-Next-80B-A3B; ``model_type: qwen3_next``):
+    a typed stack (models/typed_layers.py has the equations) whose EVERY
+    layer is a mixer AND the experts under two ZERO-CENTRED RMSNorms
+    (``rms_norm_eps``; ``x̂·(1 + w)``: :func:`fold_zero_centred` folds the
+    ``1 +`` into the trees' ``scale``). Layer ``l`` is FULL attention where
+    ``(l + 1) % full_attention_interval == 0`` (``layer_types``, where the
+    file has the list, is read in its place: ``full_attention`` /
+    ``linear_attention``) — ``num_attention_heads`` / ``num_key_value_heads``
+    heads of ``head_dim``, a q / k head norm, rotate-half RoPE on
+    ``partial_rotary_factor`` of the head, an output gate from a ``q_proj``
+    twice as wide — and a GATED DELTA RULE (kind 6) everywhere else:
+    ``linear_num_value_heads`` value heads of ``linear_value_head_dim`` over
+    ``linear_num_key_heads`` key heads of ``linear_key_head_dim``, a
+    convolution of ``linear_conv_kernel_dim`` taps over ``[q | k | v]``.
+    Every layer (``decoder_sparse_step`` 1, ``mlp_only_layers`` []) ends in
+    ``num_experts`` SiLU-GLU experts of ``moe_intermediate_size`` —
+    ``num_experts_per_tok`` a token by the softmax over all, renormalised
+    over the kept (``norm_topk_prob``) — beside one shared expert of
+    ``shared_expert_intermediate_size`` behind a sigmoid gate; the head is
+    untied. ONE published key names both the router's width and the expert
+    count, so a share is ``expert_share`` (not a published key:
+    ``{"router_experts", "first_expert", "held_experts"}``, as
+    ``lfm2_moe``). Accepted and not built: ``intermediate_size`` (the width
+    of ``mlp_only_layers``, of which there are none) and the family's
+    multi-token-prediction module (no key of the config describes one).
+    Refused by name: biases, ``rope_scaling``, a sliding window, dense
+    layers (``decoder_sparse_step`` / ``mlp_only_layers``), a stack with no
+    shared expert, another activation, an unknown layer type."""
+    fam = "qwen3_next"
+    for key, want in (("attention_bias", False), ("hidden_act", "silu"),
+                      ("rope_scaling", None), ("use_sliding_window", False),
+                      ("decoder_sparse_step", 1), ("mlp_only_layers", [])):
+        if hf.get(key, want) != want:
+            raise ValueError(f"{fam}: {key}={hf[key]!r} is not built "
+                             f"(expected {want!r})")
+    if not int(hf.get("shared_expert_intermediate_size") or 0):
+        raise ValueError(f"{fam}: shared_expert_intermediate_size="
+                         f"{hf.get('shared_expert_intermediate_size')!r} is "
+                         f"not built (every layer has one shared expert)")
+    L = int(hf["num_hidden_layers"])
+    if hf.get("layer_types") is not None:
+        kinds = _layer_kinds(hf, fam, {"linear_attention": 6,
+                                       "full_attention": 0})
+    else:
+        every = int(hf.get("full_attention_interval", 4))
+        kinds = tuple(0 if (l + 1) % every == 0 else 6 for l in range(L))
+    hv, hk = int(hf["linear_num_value_heads"]), int(hf["linear_num_key_heads"])
+    if hv % hk:
+        raise ValueError(f"{fam}: linear_num_key_heads={hk} does not divide "
+                         f"linear_num_value_heads={hv}")
+    E = int(hf["num_experts"])
+    share = hf.get("expert_share")
+    if share and int(share["router_experts"]) != E:
+        raise ValueError(
+            f"{fam}: expert_share.router_experts="
+            f"{share['router_experts']!r} is not num_experts={E} (the one "
+            f"published key is the router's width; the share's count is "
+            f"expert_share.held_experts)")
+    return DecoderConfig(
+        hidden_size=hf["hidden_size"], num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim_override=int(hf["head_dim"]),
+        intermediate_size=int(hf["moe_intermediate_size"]),
+        vocab_size=hf["vocab_size"],
+        max_seq_len=hf.get("max_position_embeddings", 262144),
+        norm="rmsnorm", activation="silu_glu", pos_emb="rope",
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rotary_pct=float(hf.get("partial_rotary_factor", 1.0)),
+        use_bias=False, qk_head_norm=True, attn_output_gate=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        layer_kinds=kinds, layer_sparse=(1,) * L,
+        ssm_heads=hv, ssm_head_dim=int(hf["linear_value_head_dim"]),
+        ssm_groups=hk, ssm_state_size=int(hf["linear_key_head_dim"]),
+        ssm_conv_kernel=int(hf["linear_conv_kernel_dim"]),
+        num_experts=E, num_experts_per_tok=int(hf["num_experts_per_tok"]),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        router_scoring="softmax", router_select_bias=False,
+        shared_expert_size=int(hf["shared_expert_intermediate_size"]),
+        shared_expert_gate=True,
+        experts_held=(int(share["first_expert"]),
+                      int(share["held_experts"])) if share else None)
+
+
+def fold_zero_centred(w: np.ndarray) -> np.ndarray:
+    """A zero-centred RMSNorm's published weight ``w`` (``x̂·(1 + w)``:
+    Gemma's norm, Qwen3-Next's) → the ``scale`` this repo's norm multiplies
+    by, float32: the ``1 +`` is paid once at load and adds no operation to
+    a program."""
+    return np.asarray(w, np.float32) + 1.0
+
+
+def _load_qwen3_next(cfg: DecoderConfig, get, dtype) -> Params:
+    """The published ``Qwen3NextForCausalLM`` tensors → a typed stack's
+    tree (``typed_layers.init_typed_params``' names and shapes: ``[in,
+    out]`` matrices). Every ``*layernorm`` / ``q_norm`` / ``k_norm`` /
+    ``model.norm`` weight is ZERO-CENTRED and folded
+    (:func:`fold_zero_centred`); ``linear_attn.norm`` is NOT. ``q_proj``'s
+    rows are ``[q | gate]`` a HEAD: split into ``wq`` and ``wq_gate``.
+    ``in_proj_qkvz``'s rows are ``[q | k | v·R | z·R]`` a KEY head (``R``
+    value heads a key head), ``in_proj_ba``'s ``[b·R | a·R]`` likewise:
+    regrouped into ``[q | k | v | z]`` and ``[b | a]`` blocks over all
+    heads; ``conv1d`` ``[C, 1, K]`` → ``conv_w [C, K]`` (its channels are
+    ``[q | k | v]`` already). A share (``cfg.experts_held``) loads its own
+    experts."""
+    def T(name):
+        return np.ascontiguousarray(get(name).T).astype(dtype)
+
+    def folded(name):
+        return {"scale": fold_zero_centred(get(name))}
+
+    d, H, dk = cfg.hidden_size, cfg.num_heads, cfg.head_dim
+    hv, hk, n, p_dim = cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state_size, \
+        cfg.ssm_head_dim
+    r = hv // hk
+    first, held = cfg.experts_held or (0, cfg.num_experts)
+    layers = []
+    for l, kind in enumerate(cfg.layer_kinds):
+        pre = f"model.layers.{l}."
+        lp = {"ln1": folded(pre + "input_layernorm.weight"),
+              "ln2": folded(pre + "post_attention_layernorm.weight")}
+        if kind == 6:
+            a = pre + "linear_attn."
+            qkvz = T(a + "in_proj_qkvz.weight").reshape(
+                d, hk, 2 * n + 2 * r * p_dim)
+            cuts = np.cumsum([n, n, r * p_dim])
+            ba = T(a + "in_proj_ba.weight").reshape(d, hk, 2 * r)
+            lp["ssm"] = {
+                "w_in": np.concatenate(
+                    [part.reshape(d, -1)
+                     for part in np.split(qkvz, cuts, axis=-1)], axis=-1),
+                "w_ba": np.concatenate([ba[..., :r].reshape(d, hv),
+                                        ba[..., r:].reshape(d, hv)], axis=-1),
+                "conv_w": get(a + "conv1d.weight")[:, 0].astype(dtype),
+                "dt_bias": get(a + "dt_bias").astype(np.float32),
+                "A_log": get(a + "A_log").astype(np.float32),
+                "norm": {"scale": get(a + "norm.weight").astype(np.float32)},
+                "w_out": T(a + "out_proj.weight")}
+        else:
+            a = pre + "self_attn."
+            qg = T(a + "q_proj.weight").reshape(d, H, 2 * dk)
+            lp["attn"] = {
+                "wq": qg[..., :dk].reshape(d, H * dk),
+                "wq_gate": qg[..., dk:].reshape(d, H * dk),
+                "wk": T(a + "k_proj.weight"), "wv": T(a + "v_proj.weight"),
+                "wo": T(a + "o_proj.weight"),
+                "q_norm": folded(a + "q_norm.weight"),
+                "k_norm": folded(a + "k_norm.weight")}
+        m = pre + "mlp."
+        experts = range(first, first + held)
+        lp["moe"] = {"router": T(m + "gate.weight"), **{
+            ours: np.stack([T(f"{m}experts.{e}.{theirs}.weight")
+                            for e in experts])
+            for ours, theirs in (("wg", "gate_proj"), ("wi", "up_proj"),
+                                 ("wo", "down_proj"))}}
+        lp["shared"] = {"wg": T(m + "shared_expert.gate_proj.weight"),
+                        "wi": T(m + "shared_expert.up_proj.weight"),
+                        "wo": T(m + "shared_expert.down_proj.weight"),
+                        "gate": T(m + "shared_expert_gate.weight")}
+        layers.append(lp)
+    return {"embed": {"tokens": get("model.embed_tokens.weight"
+                                    ).astype(dtype)},
+            "layers": layers,
+            "final_norm": folded("model.norm.weight"),
+            "lm_head": T("lm_head.weight")}
+
+
 def _is_gemma_layout(cfg: DecoderConfig) -> bool:
     return cfg.activation == "gelu_glu" and cfg.scale_embeddings
 
@@ -1049,7 +1220,7 @@ def config_to_hf(cfg: DecoderConfig) -> Dict[str, Any]:
         raise NotImplementedError(
             "config_to_hf: a typed layer stack (mimo_v2, deepseek_v3, "
             "cohere2_moe, nemotron_h, granitemoehybrid, jamba, lfm2_moe, "
-            "xing4_0) has no exporter")
+            "xing4_0, qwen3_next) has no exporter")
     if not cfg.causal or not cfg.prenorm:
         # encoder layouts (BERT/DistilBERT): both flags flip together
         if cfg.causal or cfg.prenorm or cfg.pos_emb != "learned" \
@@ -1381,6 +1552,8 @@ def params_from_state(cfg: DecoderConfig, hf_cfg: Dict[str, Any], get, names,
     """
     L = cfg.num_layers
     mt = hf_cfg.get("model_type")
+    if mt == "qwen3_next":
+        return _load_qwen3_next(cfg, get, dtype)
     if cfg.typed:
         raise NotImplementedError(
             f"loading {mt!r} weights into a typed layer stack is not "

@@ -43,8 +43,9 @@ Params = Dict[str, Any]
 
 #: the layer kinds whose mixer carries a state a sequence (ops/ssm.py): 3 a
 #: Mamba-2 mixer, 4 a Mamba-1 selective scan, 5 a gated short convolution
-#: (its state is the convolution's tail and nothing else)
-STATE_SPACE_KINDS = (3, 4, 5)
+#: (its state is the convolution's tail and nothing else), 6 a gated delta
+#: rule (a matrix a head that a step decays AND corrects)
+STATE_SPACE_KINDS = (3, 4, 5, 6)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -168,6 +169,11 @@ class DecoderConfig:
     #: 5 = a GATED SHORT CONVOLUTION (LFM2's: ``ssm_conv_kernel`` taps over
     #: ``hidden_size`` channels between two gates; it carries the taps' last
     #: inputs and NO state; a stack with kind 5 has neither kind 3 nor 4),
+    #: 6 = a GATED DELTA-RULE mixer (Qwen3-Next's linear attention:
+    #: ``ssm_heads`` value heads of ``ssm_head_dim`` over ``ssm_groups`` key
+    #: heads of ``ssm_state_size``; it carries a ``[key, value]`` matrix a
+    #: value head that each step decays and corrects; alone of the
+    #: recurrent kinds in its stack),
     #: -1 = NO mixer: the layer is its feed-forward part alone,
     #: under the layer's one norm (Nemotron-H's ``E`` layers).
     #: Set → the stack is NOT one
@@ -219,6 +225,11 @@ class DecoderConfig:
     #: each, shared by the heads) BEFORE the rotary term, on the attention
     #: kinds 0 and 1 of a typed stack (LFM2's ``q_layernorm`` / ``k_layernorm``)
     qk_head_norm: bool = False
+    #: the attention kinds 0 and 1 of a typed stack gate their heads'
+    #: outputs: ``o ← o ⊙ σ(h·W_gate)``, a gate a query head and dim from the
+    #: layer's normed input (Qwen3-Next's ``q_proj`` is twice as wide; the
+    #: tree holds its gate half as ``wq_gate``)
+    attn_output_gate: bool = False
     # -- latent attention (kind 2): the cache holds ONE row a token,
     # [RMSNorm(c_kv) (kv_lora_rank) ; RoPE(k_r) (qk_rope_head_dim)], read by
     # every head; ``head_dim`` = qk_nope + qk_rope, ``v_head_dim`` the V head
@@ -348,6 +359,15 @@ class DecoderConfig:
                 "a gated short convolution (layer kind 5) needs a stack "
                 "without layers of kinds 3 and 4 (ssm_conv_kernel and the "
                 "convolution's pool describe one kind of mixer)")
+        if self.delta_rule and (set(self.layer_kinds) & {3, 4, 5} or not (
+                self.ssm_heads and self.ssm_head_dim and self.ssm_state_size
+                and self.ssm_heads % self.ssm_groups == 0)):
+            raise ValueError(
+                "a gated delta-rule layer (layer kind 6) needs ssm_heads "
+                "(value heads), ssm_head_dim, ssm_state_size (the key head's "
+                "width) and ssm_groups (key heads) dividing ssm_heads, and a "
+                "stack without layers of kinds 3, 4 and 5 (the ssm_* widths "
+                "describe one kind of mixer)")
         if self.layer_kinds is not None and any(
                 kind == -1 and not self.layer_has_ffn(l)
                 for l, kind in enumerate(self.layer_kinds)):
@@ -416,6 +436,14 @@ class DecoderConfig:
         """The stack's recurrent layers are gated short convolutions (kind
         5): a sequence carries their taps' last inputs and no state."""
         return self.typed and 5 in self.layer_kinds
+
+    @property
+    def delta_rule(self) -> bool:
+        """The stack's recurrent layers are gated delta-rule mixers (kind
+        6): the ``ssm_*`` widths read as value heads (``ssm_heads`` of
+        ``ssm_head_dim``) over key heads (``ssm_groups`` of
+        ``ssm_state_size``), and the convolution runs over ``[q | k | v]``."""
+        return self.typed and 6 in self.layer_kinds
 
     @property
     def recurrent(self) -> bool:
@@ -557,7 +585,7 @@ class DecoderConfig:
         d, v, l = self.hidden_size, self.vocab_size, self.num_layers
         h = self.ffn_size
         attn = d * self.q_dim + 2 * d * self.kv_heads * self.head_dim \
-            + self.q_dim * d
+            + self.q_dim * d + (d * self.q_dim if self.attn_output_gate else 0)
         if self.is_glu:
             mlp = 3 * d * h
         else:
@@ -589,6 +617,13 @@ class DecoderConfig:
                     + (di + 1) * (r + 2 * n) + (r + 1) * di + di * (n + 1)
             if self.short_conv:     # in (three blocks), out, the taps
                 ssm = 4 * d * d + d * self.ssm_conv_kernel
+            if self.delta_rule:
+                # [q | k | v | z] and [b | a] in, out, the taps, the decay's
+                # two vectors a value head, the gated norm's one scale a dim
+                ssm = d * (self.ssm_inner + self.ssm_conv_dim +
+                           2 * self.ssm_heads) + self.ssm_inner * d \
+                    + self.ssm_conv_dim * self.ssm_conv_kernel \
+                    + 2 * self.ssm_heads + self.ssm_head_dim
             layers = sum(
                 (ssm if kind in STATE_SPACE_KINDS else attn if kind >= 0
                  else 0)

@@ -2,7 +2,8 @@
 
 ``DecoderConfig.layer_kinds`` names each layer's mixer (0 = full causal
 attention, 1 = window, 2 = latent, 3 = a Mamba-2 state-space mixer, 4 = a
-Mamba-1 selective-scan mixer, 5 = a gated short convolution, -1 = none) and ``layer_sparse`` its
+Mamba-1 selective-scan mixer, 5 = a gated short convolution, 6 = a gated
+delta rule, -1 = none) and ``layer_sparse`` its
 feed-forward part (1 = sparse experts, 0 = a dense MLP, -1 = none). The
 kinds differ in SHAPE — KV heads, rotary base, a learned sink on the window
 kind, the dense width — so they share no stacked tree: ``params["layers"]``
@@ -174,6 +175,49 @@ layer has a ``conv<i>`` pool and no ``ssm<i>``, in the same slots and
 resets as kinds 3 and 4 (scope ``conv_state``: the gather of a row's tail,
 a fresh row's reset, the write-back).
 
+A GATED DELTA-RULE stack (Qwen3-Next's, ``hf_loader``: ``qwen3_next``) is
+the two-part layer again — a mixer AND the experts under two RMSNorms, no
+multipliers, an untied head — with EVERY norm but the mixer's gated one
+ZERO-CENTRED: ``x̂·(1 + w)``; the trees hold ``1 + w`` as ``scale`` (the
+reader's fold: no operation is added). Every layer ends in softmax-routed
+experts (``route_tokens``: the ``k`` highest of the softmax over all,
+renormalised) beside ONE shared expert whose output is times ``σ(h₂·w_s)``,
+one logit a token (``cfg.shared_expert_gate``: the tree's ``shared.gate``).
+One layer in four is attention (kind 0) with three additions:
+``cfg.qk_head_norm`` (above; its scales folded likewise); rotate-half RoPE
+on the FIRST ``cfg.rope_dim`` dims of each head (``cfg.rotary_pct`` of it:
+64 of 256), the rest passed through; and an OUTPUT GATE
+(``cfg.attn_output_gate``): ``o ← o ⊙ σ(h·W_gate)``, a gate a query head and
+dim (the published ``q_proj`` is twice as wide, ``[q | gate]`` a head: the
+tree holds the halves as ``wq`` and ``wq_gate``; scope ``attn_gate``). The
+others are the GATED DELTA RULE (kind 6; ``ops/ssm.py``'s last section):
+``H_v = ssm_heads`` value heads of ``d_v = ssm_head_dim`` over ``G =
+ssm_groups`` key heads of ``d_k = ssm_state_size``, ``K = ssm_conv_kernel``:
+
+- ``[q | k | v | z] = h·W_in`` (widths ``G·d_k``, ``G·d_k``, ``H_v·d_v``,
+  ``H_v·d_v``; the published tensor interleaves them a key head: a loader's
+  matter); ``[b | a] = h·W_ba`` (``H_v`` each);
+- ``[q | k | v] ← silu(conv_K([q | k | v]))``: depthwise, causal, NO bias and
+  a SiLU (``ssm.conv_rows`` with ``MixerForms.conv_silu``);
+- ``β = σ(b)``, ``g = −exp(A_log) ⊙ softplus(a + dt_bias)`` (float32, a value
+  head); ``q ← q / ‖q‖ / √d_k``, ``k ← k / ‖k‖`` a key head, each serving
+  ``H_v / G`` consecutive value heads (``ssm.delta_inputs``);
+- a value head, ``S [d_k, d_v]`` float32 from zero: ``S ← e^{g_t}·S``; ``r =
+  Sᵀk_t``; ``S ← S + k_t ⊗ β_t(v_t − r)``; ``o_t = Sᵀq_t`` — the state is
+  read with ``k`` BEFORE it is written (``ssm.delta_step`` a position,
+  ``ssm.delta_chunk`` a chunk from a carried state: the WY form, a
+  triangular solve inside the chunk; scope ``delta_rule``);
+- ``o ← w ⊙ RMS_{d_v}(o) ⊙ silu(z)`` a head: the norm FIRST, then the gate,
+  ``w`` of ``d_v`` shared by the heads and NOT zero-centred
+  (``ssm.gated_norm`` with ``gate_first`` False); out ``o·W_out``.
+
+A sequence carries ``S`` (``[H_v, d_k, d_v]`` float32: 2 MiB at 32 heads of
+128 x 128) and the last ``K − 1`` rows of ``[q | k | v]`` (float32 too), in
+the same pools, slots and resets as kinds 3 and 4. The mixer's three
+projections take their float32 inputs UNROUNDED (:func:`_linear_wide`: two
+bf16 products each): its norms pass an input's rounding on three times
+over.
+
 A stack with HYPER-CONNECTIONS (manifold-constrained, mHC; Xing4.0's,
 ``hf_loader``: ``xing4_0``; ``cfg.hc_mult`` ``n`` > 1) is DeepSeek-V3's
 latent block on a residual stream of ``n`` hidden states a token, ``X [n,
@@ -251,9 +295,12 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     ``lm_head`` unless the head is tied to ``embed``. A state-space layer
     has ``ssm`` {w_in, conv_w, conv_b, dt_bias, A_log, D, norm, w_out} in
     place of ``attn`` (a selective scan's: {w_in, conv_w, conv_b, w_x,
-    dt_norm, b_norm, c_norm, w_dt, dt_bias, A_log, D, w_out}); a gated short
+    dt_norm, b_norm, c_norm, w_dt, dt_bias, A_log, D, w_out}; a gated delta
+    rule's: {w_in, w_ba, conv_w, dt_bias, A_log, norm, w_out}); a gated short
     convolution has ``conv`` {w_in, conv_w, w_out}; ``cfg.qk_head_norm``
-    adds ``q_norm`` / ``k_norm`` {scale} to ``attn``; a layer with
+    adds ``q_norm`` / ``k_norm`` {scale} to ``attn``,
+    ``cfg.attn_output_gate`` ``wq_gate``, ``cfg.shared_expert_gate``
+    ``gate`` to ``shared``; a layer with
     no mixer has neither; a layer with no
     feed-forward part has no ``mlp`` / ``moe``; un-gated (``relu2``)
     experts have no ``wg``; ``cfg.hc_mult`` > 1 adds ``hc_attn`` /
@@ -277,6 +324,8 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
     draws = 13 if cfg.latent or cfg.shared_expert_size else 10
     if cfg.picks_keys:
         draws += 3      # an owner's three index projections
+    if cfg.attn_output_gate or cfg.shared_expert_gate:
+        draws += 2      # the two gates' matrices
     keys = iter(jax.random.split(rng, draws * L + 2))
     glu = ("wg", "wi") if cfg.is_glu else ("wi",)
 
@@ -313,6 +362,8 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
             lp["ssm"] = _init_ssm(cfg, w, next(keys), out_std)
         elif kind == 4:
             lp["ssm"] = _init_selective(cfg, w, next(keys), out_std)
+        elif kind == 6:
+            lp["ssm"] = _init_delta(cfg, w, next(keys), out_std)
         elif kind == 5:
             # the taps at torch's ``Conv1d`` default (uniform in ±K^-0.5:
             # a variance of 1 / 3K), not the matrices' 0.02: the mixer's
@@ -333,6 +384,8 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
         elif kind >= 0:
             attn = {"wq": w((d, H * dk)), "wk": w((d, kvh * dk)),
                     "wv": w((d, kvh * dv)), "wo": w((H * dv, d), out_std)}
+            if cfg.attn_output_gate:
+                attn["wq_gate"] = w((d, H * dv))
             if cfg.qk_head_norm:
                 attn["q_norm"] = {"scale": jnp.ones((dk,), jnp.float32)}
                 attn["k_norm"] = {"scale": jnp.ones((dk,), jnp.float32)}
@@ -365,6 +418,8 @@ def init_typed_params(cfg, rng: jax.Array, dtype=jnp.float32):
                 fs = cfg.shared_expert_size
                 lp["shared"] = {**{name: w((d, fs)) for name in glu},
                                 "wo": w((fs, d), out_std)}
+                if cfg.shared_expert_gate:      # one logit a token
+                    lp["shared"]["gate"] = w((d, 1))
         else:
             f = cfg.dense_intermediate_size or cfg.ffn_size
             lp["mlp"] = {**{name: w((d, f)) for name in glu},
@@ -428,10 +483,30 @@ def _init_selective(cfg, w, key, out_std: float):
             "w_out": w((d, cfg.hidden_size), out_std)}
 
 
+def _init_delta(cfg, w, key, out_std: float):
+    """A gated delta-rule layer's tree. As the published module initialises
+    them: ``A_log = log(U(0, 16))`` and ``dt_bias`` 1 a value head, the
+    gated norm's scale 1; the convolution's taps at torch's ``Conv1d``
+    default (uniform in ±K^-0.5: a variance of 1 / 3K), no bias."""
+    d, cd, h, k = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_heads, \
+        cfg.ssm_conv_kernel
+    return {"w_in": w((cfg.hidden_size, cd + d)),
+            "w_ba": w((cfg.hidden_size, 2 * h)),
+            "conv_w": w((cd, k), (3 * k) ** -0.5),
+            "dt_bias": jnp.ones((h,), jnp.float32),
+            "A_log": jnp.log(jax.random.uniform(key, (h,), jnp.float32,
+                                                1e-3, 16.0)),
+            "norm": {"scale": jnp.ones((cfg.ssm_head_dim,), jnp.float32)},
+            "w_out": w((d, cfg.hidden_size), out_std)}
+
+
 def rope_tables(cfg, positions: jax.Array) -> dict:
     """{kind: (sin, cos)} for the kinds the stack has: one table a rotary
     base, computed once a step; (None, None) for a kind without a
-    positional term (``cfg.full_attn_rope`` False)."""
+    positional term (``cfg.full_attn_rope`` False). A table is
+    ``cfg.rope_dim // 2`` wide (``cfg.rotary_pct`` of the head:
+    ``tf.apply_rope`` turns a head's first ``rope_dim`` dims and passes the
+    rest through)."""
     tables = {}
     for kind in sorted(set(cfg.layer_kinds)):
         theta = cfg.kind_rope_theta(kind)
@@ -552,11 +627,20 @@ def latent_expand_kv(cfg, p, q_nope: jax.Array, q_rope: jax.Array,
         jnp.concatenate([kv[..., :nope], k_rope], axis=-1), kv[..., nope:]
 
 
-@jax.named_scope("attn_out")
-def typed_attn_out(cfg, p, out: jax.Array) -> jax.Array:
+def typed_attn_out(cfg, p, out: jax.Array,
+                   h: Optional[jax.Array] = None) -> jax.Array:
+    """The heads' outputs [B, t, H, Dv] → [B, t, D]; ``cfg.attn_output_gate``
+    (``h`` [B, t, D]: the layer's normed input, which the queries read):
+    times ``σ(h·W_gate)`` a head and dim first (scope ``attn_gate``)."""
     b, t = out.shape[:2]
-    return tf.linear_2d(out.reshape(b, t, cfg.num_heads * cfg.v_dim), p,
-                        "wo")
+    if cfg.attn_output_gate:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(tf.linear_2d(h, p, "wq_gate").astype(
+                jnp.float32)).reshape(out.shape)
+            out = (out.astype(jnp.float32) * gate).astype(out.dtype)
+    with jax.named_scope("attn_out"):
+        return tf.linear_2d(out.reshape(b, t, cfg.num_heads * cfg.v_dim), p,
+                            "wo")
 
 
 def apply_sink(out: jax.Array, lse: jax.Array,
@@ -800,7 +884,7 @@ def ssm_out(cfg, p, y: jax.Array, z: jax.Array) -> jax.Array:
     """The gated norm and the output projection, token-wise: the scan's y
     [.., d] float32 and the gate z → [.., D]."""
     with jax.named_scope("ssm_norm"):
-        o = ssm.gated_norm(cfg, p, y, z, z.dtype)
+        o = ssm.gated_norm(cfg, p, y, z, z.dtype, cfg.ssm_groups, True)
     with jax.named_scope("ssm_out"):
         return tf.linear_2d(o, p, "w_out")
 
@@ -859,9 +943,62 @@ def short_conv_out(cfg, p, y: jax.Array, z: jax.Array) -> jax.Array:
         return _linear_f32((y * z).astype(p["w_out"].dtype), p, "w_out")
 
 
+def _linear_wide(x: jax.Array, p, name: str) -> jax.Array:
+    """``x·p[name]`` for a FLOAT32 ``x`` that is NOT rounded to the weights'
+    dtype on its way in: ``x = hi + lo``, two values of the weights' dtype
+    (16 bits of mantissa between them where that is bf16), a product each,
+    summed in float32 — twice :func:`_linear_f32`'s passes. The gated delta
+    rule's projections: its unit norms and its gated norm pass a rounding of
+    their inputs on THREE TIMES over (0.11% in, 0.35% of the mixer's output
+    out: PERF.md §6, PR 62), nine such mixers compounded to 6.5% of the
+    stream at Qwen3-Next's widths, and one served token in 200 then lay
+    over the serve runner's near-tie limit under the float32 reference's
+    argmax whatever its routing. (Float32 weights: one product.)"""
+    w = p[name]
+    if name + "_scale" in p or w.dtype == jnp.float32:
+        return _linear_f32(x.astype(w.dtype), p, name)
+    # (``reduce_precision``, not a pair of converts: the TPU compiler folds
+    # float32 → bf16 → float32 away where it may keep excess precision, and
+    # ``lo`` with it)
+    bits = jnp.finfo(w.dtype)
+    hi = lax.reduce_precision(x, bits.nexp, bits.nmant)
+    return _linear_f32(hi.astype(w.dtype), p, name) + \
+        _linear_f32((x - hi).astype(w.dtype), p, name)
+
+
+def delta_in(cfg, p, h: jax.Array):
+    """A gated delta rule's two input projections, token-wise: h [.., D]
+    FLOAT32 (the norm's output as it is: ``MixerForms.wide_input``) → (z
+    [.., H_v·d_v], ``[q | k | v]`` — what its convolution reads and its pool
+    holds —, ``(b, a)`` [.., H_v] each), all float32
+    (:func:`_linear_wide` says why)."""
+    with jax.named_scope("ssm_in"):
+        cd, hv = cfg.ssm_conv_dim, cfg.ssm_heads
+        qkvz = _linear_wide(h, p, "w_in")
+        ba = _linear_wide(h, p, "w_ba")
+        return qkvz[..., cd:], qkvz[..., :cd], (ba[..., :hv], ba[..., hv:])
+
+
+def delta_inputs(cfg, p, u: jax.Array, ba, counts: jax.Array):
+    """``ssm.delta_inputs`` under the scan's scope: the two unit norms, the
+    ``1/√d_k``, ``β`` and ``g``."""
+    with jax.named_scope("delta_rule"):
+        return ssm.delta_inputs(cfg, p, u, ba, counts)
+
+
+def delta_out(cfg, p, y: jax.Array, z: jax.Array) -> jax.Array:
+    """The gated norm — a norm a value head FIRST, then the gate — and the
+    output projection, token-wise → [.., D] float32, as the stream it
+    joins."""
+    with jax.named_scope("ssm_norm"):
+        o = ssm.gated_norm(cfg, p, y, z, jnp.float32, cfg.ssm_heads, False)
+    with jax.named_scope("ssm_out"):
+        return _linear_wide(o, p, "w_out")
+
+
 class MixerForms(NamedTuple):
     """What a recurrent layer is made of, by its kind (THE place that tells
-    kinds 3, 4 and 5 apart; the pools' shape is ``ssm.state_shape``). The
+    kinds 3, 4, 5 and 6 apart; the pools' shape is ``ssm.state_shape``). The
     kinds share the pools, slots and resets, the convolution
     (``ssm.conv_rows``) and the two row groups of a step."""
     #: (cfg, p, h) → (the gate z, the convolution's input, what the scan
@@ -869,6 +1006,12 @@ class MixerForms(NamedTuple):
     project: Callable
     #: the convolved channels' dtype (None: the input's)
     conv_dtype: Any
+    #: the taps' sum passes a SiLU (a gated short convolution's does not)
+    conv_silu: bool
+    #: ``project`` reads the layer's norm in FLOAT32, as the stream's norm
+    #: left it (a gated delta rule's: :func:`_linear_wide`); else in the
+    #: compute dtype
+    wide_input: bool
     #: (cfg, p, u, that tree's rows, counts) → what the scan takes beside u
     inputs: Callable
     #: (cfg, p, u, inputs, state, counts[, reset]) → (y, state): one
@@ -884,17 +1027,22 @@ class MixerForms(NamedTuple):
 
 def mixer_forms(kind: int, kernel: bool = False) -> MixerForms:
     """``kernel``: the selective scan's chunk form as its Pallas kernel."""
+    if kind == 6:
+        return MixerForms(delta_in, jnp.float32, True, True, delta_inputs,
+                          ssm.delta_step, ssm.delta_chunk, delta_out,
+                          ("ssm_state", "ssm_conv", "delta_rule"))
     if kind == 5:
-        return MixerForms(short_conv_in, jnp.float32,
+        return MixerForms(short_conv_in, jnp.float32, False, False,
                           lambda cfg, p, u, dt, counts: dt, None, None,
                           short_conv_out,
                           ("conv_state", "conv_mixer", "conv_mixer"))
     if kind == 4:
-        return MixerForms(selective_in, jnp.float32, ssm_select,
+        return MixerForms(selective_in, jnp.float32, True, False, ssm_select,
                           ssm.selective_step, functools.partial(
                               ssm.selective_chunk, kernel=kernel),
                           selective_out)
-    return MixerForms(ssm_in, None, lambda cfg, p, u, dt, counts: dt,
+    return MixerForms(ssm_in, None, True, False,
+                      lambda cfg, p, u, dt, counts: dt,
                       ssm.scan_step, ssm.scan_chunk, ssm_out)
 
 
@@ -905,11 +1053,12 @@ def ssm_rows(forms: MixerForms, cfg, p, xbc: jax.Array, dt,
     the form the rows' width picks (a mixer with no scan: its convolution's
     output, the state as it came)."""
     with jax.named_scope(forms.scopes[1]):
-        u, tail = ssm.conv_rows(cfg, p, xbc, tail, counts, forms.conv_dtype)
+        u, tail = ssm.conv_rows(cfg, p, xbc, tail, counts, forms.conv_dtype,
+                                forms.conv_silu)
     if forms.step is None:
         return u, tail, state
     dt = forms.inputs(cfg, p, u, dt, counts)
-    with jax.named_scope("ssm_scan"):
+    with jax.named_scope(forms.scopes[2]):
         scan = forms.step if u.shape[1] == 1 else forms.chunk
         y, state = scan(cfg, p, u, dt, state, counts)
     return y, tail, state
@@ -924,12 +1073,19 @@ def mixer_tree(kind: int, lp):
 SSM_CHUNK = 128
 
 
-def _ssm_mixer(cfg, kind: int, p, h: jax.Array) -> jax.Array:
-    """Uncached: whole sequences [B, T, D] from a zero state, ``SSM_CHUNK``
-    positions at a time, the tail and the state carried between them."""
-    b, t = h.shape[:2]
+def mixer_input(forms: MixerForms, h32: jax.Array, dtype) -> jax.Array:
+    """What a recurrent mixer's ``project`` reads of its layer's float32
+    norm: that, or its cast to the compute ``dtype``."""
+    return h32 if forms.wide_input else h32.astype(dtype)
+
+
+def _ssm_mixer(cfg, kind: int, p, h32: jax.Array, dtype) -> jax.Array:
+    """Uncached: whole sequences [B, T, D] (the layer's float32 norm; the
+    compute ``dtype``) from a zero state, ``SSM_CHUNK`` positions at a
+    time, the tail and the state carried between them."""
+    b, t = h32.shape[:2]
     forms = mixer_forms(kind)
-    z, xbc, dt = forms.project(cfg, p, h)
+    z, xbc, dt = forms.project(cfg, p, mixer_input(forms, h32, dtype))
     c = min(t, SSM_CHUNK)
     steps = -(-t // c)
 
@@ -944,7 +1100,7 @@ def _ssm_mixer(cfg, kind: int, p, h: jax.Array) -> jax.Array:
         return tuple(carry), y
 
     carry = (jnp.zeros((b, cfg.ssm_conv_kernel - 1, cfg.ssm_conv_dim),
-                       h.dtype),
+                       xbc.dtype),
              jnp.zeros((b,) + ssm.state_shape(cfg), jnp.float32))
     _, y = jax.lax.scan(step, carry, (chunks(xbc), jax.tree.map(chunks, dt),
                                       jnp.arange(steps, dtype=jnp.int32)))
@@ -982,7 +1138,7 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
         if kind in tf.STATE_SPACE_KINDS or kind == -1:
             x = block_residual(
                 cfg, lp, x, h32, _ssm_mixer(cfg, kind, mixer_tree(kind, lp),
-                                            h)
+                                            h32, dtype)
                 if kind >= 0 else None, moe_fn, None, dtype)
             continue
         if kind == 2:       # the expanded form: nothing is cached here
@@ -1003,7 +1159,7 @@ def forward_hidden_typed(cfg, params, tokens: jax.Array,
             o = _attention(cfg, kind, lp["attn"].get("sink"), q, k, v,
                            picked if cfg.picks_keys else None)
         x = block_residual(cfg, lp, x, h32,
-                           typed_attn_out(cfg, lp["attn"], o), moe_fn, None,
-                           dtype, maps)
+                           typed_attn_out(cfg, lp["attn"], o, h), moe_fn,
+                           None, dtype, maps)
     return tf._norm(cfg, params["final_norm"],
                     stream_close(cfg, x)).astype(dtype)

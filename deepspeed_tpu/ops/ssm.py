@@ -32,7 +32,15 @@ A GATED SHORT CONVOLUTION (kind 5; LFM2's mixer) is the convolution alone —
 :func:`conv_rows` with no bias and no activation, between two gates that act
 on a token alone (``typed_layers.short_conv_in`` / ``short_conv_out``) — and
 carries its tail and nothing else: its layer has a ``conv<i>`` pool and no
-``ssm<i>`` (:func:`init_state_pools`), the same slots and resets."""
+``ssm<i>`` (:func:`init_state_pools`), the same slots and resets.
+
+A GATED DELTA RULE (kind 6; Qwen3-Next's linear attention; the file's last
+section) carries a MATRIX a value head, ``S [d_k, d_v]`` float32, that a step
+decays and then CORRECTS: it reads ``Sᵀk`` before it writes, so neither form
+above computes it. It shares the pools, slots, resets and the convolution
+(:func:`conv_rows` with no bias AND a SiLU) and has its own two forms,
+:func:`delta_step` and :func:`delta_chunk`, and the gated norm with the gate
+AFTER the norm (:func:`gated_norm`)."""
 
 import functools
 from typing import Dict, Tuple
@@ -75,9 +83,15 @@ def init_state_pools(cfg, slots: int, dtype) -> Dict[str, jax.Array]:
     slot's ``K − 1`` convolution inputs lie side by side in one row (a
     dimension of 3 would be padded to a tile of 8, and the compiler relaid
     the pool on its way in and out of every program). A gated short
-    convolution (kind 5) has no state: its layer gets ``conv<i>`` alone."""
+    convolution (kind 5) has no state: its layer gets ``conv<i>`` alone. A
+    gated delta rule's carried inputs are FLOAT32 whatever ``dtype``: its
+    convolution reads ``[q | k | v]`` unrounded
+    (``typed_layers._linear_wide`` says why)."""
+    if cfg.delta_rule:
+        dtype = jnp.float32
     pools = {}
-    for i in range(sum(1 for kind in cfg.layer_kinds if kind in (3, 4, 5))):
+    for i in range(sum(1 for kind in cfg.layer_kinds
+                       if kind in (3, 4, 5, 6))):
         state, conv = pool_names(i)
         if not cfg.short_conv:
             pools[state] = jnp.zeros((slots + 1,) + state_shape(cfg),
@@ -91,9 +105,12 @@ def state_shape(cfg) -> Tuple[int, ...]:
     """What ONE sequence carries in a state-space layer, float32: ``[H, P,
     N]`` (Mamba-2), or a selective scan's ``[N, d]`` — the channels on the
     lanes, 40 tiles of 128 at Jamba2-3B's 5,120, and not its 16 states; a
-    gated short convolution carries none (an empty carry)."""
+    gated delta rule's ``[H_v, d_k, d_v]`` (the value head's dims on the
+    lanes); a gated short convolution carries none (an empty carry)."""
     if cfg.short_conv:
         return (0,)
+    if cfg.delta_rule:
+        return (cfg.ssm_heads, cfg.ssm_state_size, cfg.ssm_head_dim)
     if cfg.selective:
         return (cfg.ssm_state_size, cfg.ssm_inner)
     return (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size)
@@ -127,24 +144,26 @@ def split_in(cfg, zxbcdt: jax.Array
 
 
 def conv_rows(cfg, p, xbc: jax.Array, tail: jax.Array, counts: jax.Array,
-              dtype=None) -> Tuple[jax.Array, jax.Array]:
-    """The causal depthwise convolution over time, then SiLU: xbc
-    [m, c, Cd] after the rows' carried ``tail`` [m, K − 1, Cd] → (u
+              dtype=None, silu: bool = True) -> Tuple[jax.Array, jax.Array]:
+    """The causal depthwise convolution over time, then (``silu``) SiLU:
+    xbc [m, c, Cd] after the rows' carried ``tail`` [m, K − 1, Cd] → (u
     [m, c, Cd] in ``dtype`` — None: xbc's —, the tail each row carries on:
     the ``K − 1`` inputs that end at its last live position; a row with no
-    live position keeps its own). A tree with no ``conv_b`` is a gated
-    short convolution's: no bias and NO activation (the taps alone; at
-    ``c == 1`` its ``K`` multiply-adds)."""
+    live position keeps its own). Whether the taps' sum passes a SiLU is
+    the mixer KIND's to say (``typed_layers.MixerForms.conv_silu``: kinds
+    3, 4 and 6 do, a gated short convolution's taps stand alone — at ``c
+    == 1`` its ``K`` multiply-adds), not the tree's: a bias is added where
+    the tree holds one (``conv_b``; kinds 5 and 6 have none), and a gated
+    delta rule's convolution has no bias AND an activation."""
     k = cfg.ssm_conv_kernel
     c = xbc.shape[1]
     seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
     w = p["conv_w"].astype(jnp.float32)                       # [Cd, K]
-    plain = "conv_b" not in p
-    acc = 0.0 if plain else p["conv_b"].astype(jnp.float32)
+    acc = p["conv_b"].astype(jnp.float32) if "conv_b" in p else 0.0
     for i in range(k):      # u_t = Σ_i w[:, i]·seq[t + i] (seq[t + K − 1]
         acc = acc + seq[:, i:i + c].astype(jnp.float32) * w[:, i]   # is x_t)
     at = counts[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None]
-    return (acc if plain else jax.nn.silu(acc)).astype(dtype or xbc.dtype), \
+    return (jax.nn.silu(acc) if silu else acc).astype(dtype or xbc.dtype), \
         jnp.take_along_axis(seq, at[..., None], axis=1)
 
 
@@ -226,14 +245,26 @@ def scan_chunk(cfg, p, u: jax.Array, dt: jax.Array, state: jax.Array,
     return y.reshape(m, c, cfg.ssm_inner), s_out.reshape(state.shape)
 
 
-def gated_norm(cfg, p, y: jax.Array, z: jax.Array, dtype) -> jax.Array:
-    """``w ⊙ GroupRMS(y ⊙ silu(z))``: the gate BEFORE the norm, the norm in
-    ``G`` groups of ``d / G``, float32."""
-    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    grouped = gated.reshape(gated.shape[:-1] + (cfg.ssm_groups, -1))
+def gated_norm(cfg, p, y: jax.Array, z: jax.Array, dtype, groups: int,
+               gate_first: bool) -> jax.Array:
+    """The mixer's gated RMS norm in ``groups`` groups of ``d / groups``,
+    float32, the gate on the side the mixer KIND says (the caller's, not
+    the tree's). ``gate_first`` (Mamba-2: ``groups = G``): ``w ⊙ GroupRMS(y
+    ⊙ silu(z))``, ``w`` of ``d``. Else (a gated delta rule: ``groups =
+    H_v``, a norm a value head): ``w ⊙ GroupRMS(y) ⊙ silu(z)`` — the norm
+    FIRST, then the gate —, ``w`` of ONE group's width, shared by the
+    groups."""
+    y = y.astype(jnp.float32)
+    gate = jax.nn.silu(z.astype(jnp.float32))
+    if gate_first:
+        y = y * gate
+    grouped = y.reshape(y.shape[:-1] + (groups, -1))
     var = jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
-    normed = (grouped * lax.rsqrt(var + cfg.norm_eps)).reshape(gated.shape)
-    return (normed * p["norm"]["scale"].astype(jnp.float32)).astype(dtype)
+    normed = grouped * lax.rsqrt(var + cfg.norm_eps)
+    scale = p["norm"]["scale"].astype(jnp.float32)
+    if gate_first:
+        return (normed.reshape(y.shape) * scale).astype(dtype)
+    return ((normed * scale).reshape(y.shape) * gate).astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -422,3 +453,183 @@ def selective_scan_kernel(a, skip, x, delta, b, c, state, counts,
       b.reshape(m, 1, ch * n), c.reshape(m, 1, ch * n), tiles(a),
       skip.reshape(dt, 128), tiles(state))
     return y.reshape(m, ch, d), state.reshape(m, n, d)
+
+
+# ---------------------------------------------------------------------------
+# The gated delta rule (Qwen3-Next's linear attention; kind 6)
+# ---------------------------------------------------------------------------
+#
+# ``H_v`` value heads of ``d_v`` over ``G`` key heads of ``d_k`` (key head
+# ``g`` serves the ``R = H_v / G`` consecutive value heads ``g·R ..``), a
+# state ``S [d_k, d_v]`` float32 a value head, from zero:
+#
+#     S ← e^{g_t}·S ;  r = Sᵀk_t ;  S ← S + k_t ⊗ β_t(v_t − r) ;  o_t = Sᵀq_t
+#
+# with ``q``, ``k`` unit vectors a head (``q`` times ``d_k^-1/2``), ``β_t =
+# σ(b_t)`` and ``g_t = −exp(A_log)·softplus(a_t + dt_bias)`` a value head
+# (:func:`delta_inputs`; a position past a row's ``counts`` has ``g = 0`` and
+# ``β = 0``: it advances nothing). The state is read WITH ``k`` before it is
+# written: a chunk is no masked matmul of the inputs alone. Both forms take
+# ``sel = (q, k [m, c, G, d_k], v [m, c, G, R, d_v], β, g [m, c, G, R])``,
+# float32, where the scans above take ``dt``.
+
+#: the chunk form's triangular solve inverts ``I + A`` in diagonal blocks of
+#: this many positions by forward substitution — ``DELTA_SUB_BLOCK − 1``
+#: elementwise steps, every block, row and head at once — and joins pairs of
+#: blocks upward (``[[P, 0], [C, R]]⁻¹ = [[P⁻¹, 0], [−R⁻¹CP⁻¹, R⁻¹]]``: two
+#: batched matmuls a level, two levels at a chunk of 128). On the v5e, 8
+#: rows of a 128-token chunk at Qwen3-Next's widths: 2.35 ms a layer at 16,
+#: 2.03 at 32 (``tools/chip_check_qwen3_next.py --forms``; PERF.md §6, PR 62)
+DELTA_SUB_BLOCK = 32
+
+_HIGHEST = lax.Precision.HIGHEST
+#: the precision of the chunk form's products that do NOT touch the carried
+#: state — ``K·Kᵀ``, ``Q·Kᵀ``, the solve's joins, ``T·[βγK | βV]``,
+#: ``tril(Q·Kᵀ)·V′``: float32 operands in full (PERF.md §6, PR 62 has the
+#: readings beside bf16 passes)
+DELTA_CHUNK_PRECISION = lax.Precision.HIGHEST
+
+
+def delta_inputs(cfg, p, u: jax.Array, ba, counts: jax.Array):
+    """What both forms take, from the convolved channels u [m, c, 2·G·d_k +
+    H_v·d_v] (``[q | k | v]``, float32) and the token-wise ``ba = (b, a)``
+    [m, c, H_v] each: ``q ← q / ‖q‖ / √d_k``, ``k ← k / ‖k‖`` a key head
+    (``x·rsqrt(Σx² + 1e-6)``), ``β = σ(b)``, ``g = −exp(A_log) ⊙ softplus(a
+    + dt_bias)``; β and g 0 past a row's ``counts``."""
+    m, c = u.shape[:2]
+    g_, n, h, hd = cfg.ssm_groups, cfg.ssm_state_size, cfg.ssm_heads, \
+        cfg.ssm_head_dim
+    u = u.astype(jnp.float32)
+
+    def unit(x):
+        x = x.reshape(m, c, g_, n)
+        return x * lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+
+    q = unit(u[..., :g_ * n]) * (n ** -0.5)
+    k = unit(u[..., g_ * n:2 * g_ * n])
+    v = u[..., 2 * g_ * n:].reshape(m, c, g_, h // g_, hd)
+    b, a = (t.astype(jnp.float32) for t in ba)
+    live = (jnp.arange(c, dtype=jnp.int32)[None] < counts[:, None])[..., None]
+    beta = jnp.where(live, jax.nn.sigmoid(b), 0.0)
+    g = jnp.where(live, -jnp.exp(p["A_log"].astype(jnp.float32)) *
+                  jax.nn.softplus(a + p["dt_bias"].astype(jnp.float32)), 0.0)
+    return q, k, v, beta.reshape(m, c, g_, -1), g.reshape(m, c, g_, -1)
+
+
+def delta_step(cfg, p, u, sel, state: jax.Array, counts, reset=None):
+    """:func:`scan_step`'s part for a gated delta rule: rows of ONE
+    position, ``sel`` of :func:`delta_inputs`, state [m, H_v, d_k, d_v]
+    float32 → (o [m, 1, H_v·d_v] float32, the state after it); ``reset``
+    rides in the decay. Elementwise in float32: bound by the state's bytes,
+    2 x 2 MiB a live row at Qwen3-Next's 32 heads of 128 x 128."""
+    q, k, v, beta, g = (t[:, 0] for t in sel)
+    m = q.shape[0]
+    decay = jnp.exp(g)                                      # [m, G, R]
+    if reset is not None:
+        decay = jnp.where(reset[:, None, None], 0.0, decay)
+    s = state.reshape((m,) + g.shape[1:] + state.shape[2:])  # [m,G,R,N,P]
+    s = decay[..., None, None] * s
+    k_col, q_col = k[:, :, None, :, None], q[:, :, None, :, None]
+    delta = beta[..., None] * (v - jnp.sum(s * k_col, axis=-2))  # [m,G,R,P]
+    s = s + k_col * delta[..., None, :]
+    o = jnp.sum(s * q_col, axis=-2)
+    return o.reshape(m, 1, cfg.ssm_inner), s.reshape(state.shape)
+
+
+def _unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """``(I + A)⁻¹`` for ``a`` [..., c, c] STRICTLY lower triangular, ``c`` a
+    power of two times ``DELTA_SUB_BLOCK``, float32. The diagonal blocks by forward
+    substitution a row at a time (row ``i`` of the inverse's strict part is
+    ``−a_i − Σ_{j<i} a_ij·row_j``: elementwise, exact float32), then pairs of
+    inverted blocks joined upward with matmuls at ``Precision.HIGHEST``."""
+    c, b = a.shape[-1], DELTA_SUB_BLOCK
+    lead = a.shape[:-2]
+
+    def diagonal_blocks(blocks):    # [.., n, s, n, s] → [.., n, s, s]
+        return jnp.moveaxis(jnp.diagonal(blocks, axis1=-4, axis2=-2), -1, -3)
+
+    def substitute(i, rows):
+        """ONE loop body, not ``b − 1`` unrolled ones (half a step program's
+        build at Qwen3-Next's nine layers); ``rows`` [b, .., b]: the row
+        index LEADS, so a row's update is in place."""
+        row = lax.dynamic_index_in_dim(rows, i, axis=0, keepdims=False)
+        row = row + jnp.sum(jnp.moveaxis(row, -1, 0)[..., None] * rows,
+                            axis=0)
+        return lax.dynamic_update_index_in_dim(rows, row, i, axis=0)
+
+    t = -diagonal_blocks(a.reshape(lead + (c // b, b, c // b, b)))
+    t = jnp.moveaxis(lax.fori_loop(1, b, substitute,
+                                   jnp.moveaxis(t, -2, 0)), 0, -2)
+    t = t + jnp.eye(b, dtype=a.dtype)
+    s = b
+    while s < c:
+        # [[P, 0], [C, R]]: C is the block at (odd, even) of each pair
+        nb = c // s
+        blocks = a.reshape(lead + (nb // 2, 2, s, nb // 2, 2, s))
+        cross = diagonal_blocks(blocks[..., :, 1, :, :, 0, :])
+        pairs = t.reshape(lead + (nb // 2, 2, s, s))
+        p_inv, r_inv = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        low = -jnp.einsum("...ij,...jk,...kl->...il", r_inv, cross, p_inv,
+                          precision=DELTA_CHUNK_PRECISION)
+        top = jnp.concatenate([p_inv, jnp.zeros_like(p_inv)], axis=-1)
+        t = jnp.concatenate(
+            [top, jnp.concatenate([low, r_inv], axis=-1)], axis=-2)
+        s *= 2
+    return t.reshape(lead + (c, c))
+
+
+def delta_chunk(cfg, p, u, sel, state: jax.Array, counts):
+    """:func:`scan_chunk`'s part for a gated delta rule (the WY form): rows
+    of ``c`` positions from a CARRIED state [m, H_v, d_k, d_v] float32 → (o
+    [m, c, H_v·d_v] float32, the state after each row's last live
+    position). With ``γ_t = exp(Σ_{s≤t} g_s)`` inside the chunk: ``A =
+    tril₋₁(β_t·γ_t/γ_s·k_t·k_s)``, ``T = (I + A)⁻¹``
+    (:func:`_unit_lower_inverse`), ``W = T·(βγ ⊙ K)``, ``U = T·(β ⊙ V)``,
+    ``V′ = U − W·S_in``, ``O = (γ ⊙ Q)·S_in + tril(γ_t/γ_s·Q·Kᵀ)·V′``,
+    ``S_out = γ_end·S_in + (γ_end/γ ⊙ K)ᵀ·V′``. Every ratio of decays is
+    ``exp`` of a difference masked BEFORE the exponential (no division by an
+    underflowed ``γ``). ``K·Kᵀ`` and ``Q·Kᵀ`` are a KEY head's, scaled a
+    value head. What reads or makes the carried state is float32 at
+    ``Precision.HIGHEST`` (``scan_chunk`` says why); the products inside
+    the chunk at ``DELTA_CHUNK_PRECISION`` (a chunk's products are 80 GFLOP
+    a launch of seven chunk rows at Qwen3-Next's widths: PERF.md §5). A chunk whose
+    width is no power of two times ``DELTA_SUB_BLOCK`` is padded with
+    positions that advance nothing."""
+    q, k, v, beta, g = sel
+    m, c = q.shape[:2]
+    blocks = 1
+    while blocks * DELTA_SUB_BLOCK < c:
+        blocks *= 2
+    pad = blocks * DELTA_SUB_BLOCK - c
+    if pad:
+        q, k, v, beta, g = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) *
+                                    (t.ndim - 2)) for t in (q, k, v, beta, g))
+    s_in = state.reshape((m,) + g.shape[2:] + state.shape[2:])  # [m,G,R,N,P]
+    cum = jnp.cumsum(g, axis=1)                             # [m, c, G, R]
+    t = jnp.arange(c + pad, dtype=jnp.int32)
+    seg = jnp.moveaxis(cum, 1, -1)                          # [m, G, R, c]
+    seg = seg[..., :, None] - seg[..., None, :]             # [.., t, s]
+    ratio = jnp.exp(jnp.where(t[:, None] >= t[None], seg, -jnp.inf))
+    strict = (t[:, None] > t[None]).astype(jnp.float32)
+    inner = DELTA_CHUNK_PRECISION
+    kk = jnp.einsum("mtgn,msgn->mgts", k, k, precision=inner)
+    qk = jnp.einsum("mtgn,msgn->mgts", q, k, precision=inner)
+    beta_t = jnp.moveaxis(beta, 1, -1)[..., None]           # [m, G, R, c, 1]
+    inv = _unit_lower_inverse(beta_t * ratio * strict * kk[:, :, None])
+    gamma = jnp.exp(cum)                                    # [m, c, G, R]
+    k_heads = k[:, :, :, None]                              # [m, c, G, 1, N]
+    w = jnp.einsum("mgrts,msgrn->mtgrn", inv,
+                   (beta * gamma)[..., None] * k_heads, precision=inner)
+    uu = jnp.einsum("mgrts,msgrp->mtgrp", inv, beta[..., None] * v,
+                    precision=inner)
+    v_new = uu - jnp.einsum("mtgrn,mgrnp->mtgrp", w, s_in,
+                            precision=_HIGHEST)
+    o = jnp.einsum("mtgrn,mgrnp->mtgrp", gamma[..., None] * q[:, :, :, None],
+                   s_in, precision=_HIGHEST) + \
+        jnp.einsum("mgrts,msgrp->mtgrp", ratio * qk[:, :, None], v_new,
+                   precision=inner)
+    to_end = jnp.exp(cum[:, -1:] - cum)                     # [m, c, G, R]
+    s_out = gamma[:, -1][..., None, None] * s_in + jnp.einsum(
+        "mtgrn,mtgrp->mgrnp", to_end[..., None] * k_heads, v_new,
+        precision=_HIGHEST)
+    return o[:, :c].reshape(m, c, cfg.ssm_inner), s_out.reshape(state.shape)

@@ -179,7 +179,7 @@ SCOPE_VOCABULARY = (
     "attn_latent", "moe_shared",
     "ssm_in", "ssm_conv", "ssm_scan", "ssm_state", "ssm_norm", "ssm_out",
     "ssm_select", "attn_index", "attn_select", "conv_mixer", "conv_state",
-    "hc_maps", "hc_mix")
+    "hc_maps", "hc_mix", "delta_rule", "attn_gate")
 
 _HLO_NAME_RE = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')

@@ -394,7 +394,8 @@ def test_one_token_form_is_three_multiply_adds(tiny):
     rng = np.random.default_rng(4)
     tail = jnp.asarray(rng.normal(size=(2, 2, 128)), jnp.float32)
     u = jnp.asarray(rng.normal(size=(2, 1, 128)), jnp.float32)
-    y, after = ssm.conv_rows(cfg, p, u, tail, jnp.asarray([1, 0], jnp.int32))
+    y, after = ssm.conv_rows(cfg, p, u, tail, jnp.asarray([1, 0], jnp.int32),
+                             silu=False)
     w = np.asarray(p["conv_w"])
     want = w[:, 0] * tail[0, 0] + w[:, 1] * tail[0, 1] + w[:, 2] * u[0, 0]
     assert np.abs(np.asarray(y[0, 0]) - want).max() < 1e-6

@@ -137,6 +137,12 @@ def test_scope_is_the_innermost_vocabulary_word():
     assert scope_of_op_name(
         "jit(f)/ssm_scan/mtsgh,msghp->mtghp/dot_general")["scope"] == \
         "ssm_scan"
+    # a gated delta rule's two forms, and an attention layer's output gate
+    assert scope_of_op_name(
+        "jit(f)/delta_rule/mgrts,msgrp->mtgrp/dot_general")["scope"] == \
+        "delta_rule"
+    assert scope_of_op_name("jit(f)/attn_gate/logistic")["scope"] == \
+        "attn_gate"
 
 
 # -- names and registration -----------------------------------------------------

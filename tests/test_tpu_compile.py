@@ -105,6 +105,25 @@ def _paged(n, c, with_lse, heads=16, pages=8, window=None, kv_heads=8):
     return hist, (q, arena, arena, pt, vec, vec, vec), 1
 
 
+def _paged_wide(n, c):
+    """The split step's history reader at Qwen3-Next's full-attention
+    layers: 16 query heads on 2 KV heads of 256 (8 queries a KV head, two
+    whole lane tiles a head; K and V pools 512 lanes a token, UNPADDED), 3
+    layers of the cell's 5,504 pages, 86 a row; ``n`` rows of chunk ``c``
+    (8 x 128: the 1,024-slot instance's chunk group, a block of 1,024 query
+    rows; 64 x 128: the row form; 64 x 1: every row as one query)."""
+    from deepspeed_tpu.ops.paged_attention import paged_attention_with_lse
+
+    def fn(q, ak, av, pt, starts, qcounts):
+        return paged_attention_with_lse(
+            q, ak, av, pt, starts, jnp.zeros_like(starts),
+            scale=256 ** -0.5, qcounts=qcounts)
+    bf, pool = jnp.bfloat16, (3 * 5505, 128, 2 * 256)
+    return fn, (((n, c, 16, 256), bf), (pool, bf), (pool, bf),
+                ((n, 86), jnp.int32), ((n,), jnp.int32),
+                ((n,), jnp.int32)), 1
+
+
 def _paged_typed(kvh, n=64, c=128):
     """The split step's history reader over a TYPED arena at MiMo-V2.5's
     widths: 64 query heads, K heads of 192 padded to 256 lanes, V heads of
@@ -239,6 +258,10 @@ CASES = {
         64, 128, with_lse=True, heads=20, pages=86, kv_heads=1),
     "paged_hist_n64_c1_q20_mqa_lse": lambda: _paged(
         64, 1, with_lse=True, heads=20, pages=86, kv_heads=1),
+    # ... and at Qwen3-Next's: heads of 256, two lane tiles a head
+    "paged_hist_n8_c128_d256_lse": lambda: _paged_wide(8, 128),
+    "paged_hist_n64_c128_d256_lse": lambda: _paged_wide(64, 128),
+    "paged_hist_n64_c1_d256_lse": lambda: _paged_wide(64, 1),
     # the selective scan's chunk form at its two chunk groups and the row form
     "selective_scan_n4_c128": lambda: _selective_scan(4),
     "selective_scan_n8_c128": lambda: _selective_scan(8),
@@ -284,6 +307,9 @@ KERNEL_NAMES = {
     "paged_hist_n8_c128_q20_mqa_lse": ("paged_attn_lse",),
     "paged_hist_n64_c128_q20_mqa_lse": ("paged_attn_lse",),
     "paged_hist_n64_c1_q20_mqa_lse": ("paged_attn_lse",),
+    "paged_hist_n8_c128_d256_lse": ("paged_attn_lse",),
+    "paged_hist_n64_c128_d256_lse": ("paged_attn_lse",),
+    "paged_hist_n64_c1_d256_lse": ("paged_attn_lse",),
     "selective_scan_n4_c128": ("selective_scan",),
     "selective_scan_n8_c128": ("selective_scan",),
     "selective_scan_n64_c128": ("selective_scan",),
@@ -1315,6 +1341,74 @@ def test_selective_scan_step_compiles_for_v5e(
               f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
               f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
     assert mem.temp_size_in_bytes < _JAMBA_STEPS[kind][3], \
+        mem.temp_size_in_bytes
+
+
+# -- the gated delta-rule stack (benchmark/configs/qwen3-next-80b-a3b-l12-e64-
+# serve): delta-rule mixers and gated 256-wide GQA beside the held experts
+
+#: step -> (chunk, ``fresh_prefill``, capacities, most temporaries at ONE
+#: period ``delta delta delta full``: measured 0.05, 1.61 and 1.43 GB; the
+#: split program at all 12 layers 1.87 beside 12.54 GB of arguments)
+_DELTA_STEPS = {
+    "decode": (1, False, (), 0.2e9),
+    "split": (128, "split", (512, 1024, 2048), 2.0e9),
+    "fresh": (128, "fresh", (2048,), 1.8e9),
+}
+
+
+@pytest.mark.parametrize("kind", list(_DELTA_STEPS))
+def test_delta_rule_step_compiles_for_v5e(
+        kind, one_chip, no_persistent_cache, monkeypatch, capsys):
+    """The 64-row decode, split and fresh programs of Qwen3-Next-80B-A3B's
+    stack at the published widths, cut to ONE period ``delta delta delta
+    full`` with 64 of 512 experts held, over the cell's arena (5,504 pages,
+    86 a row; K and V 512 lanes a token: two heads of 256, UNPADDED) and
+    three state pools of 65 slots of 2 MiB: NO copy of a state pool or of a
+    KV pool anywhere in the module; the five ``ssm_*`` scopes the kind
+    keeps, ``delta_rule`` in place of ``ssm_scan``, ``attn_gate``,
+    ``moe_shared``; the paged kernel with its 1,024-row block of 256 lanes
+    under ``attn_history`` in the split program; the split program's three
+    instances one-trip loops; temporaries (printed) under the measured
+    ones."""
+    from deepspeed_tpu.telemetry.explain import scope_table_from_hlo
+    cut = {"num_hidden_layers": 4}
+    model, arena, compiled, text = _hybrid_step(
+        one_chip, monkeypatch, "qwen3-next-80b-a3b-l12-e64-serve", cut,
+        _DELTA_STEPS[kind], 86, num_blocks=5504)
+    assert model.layer_kinds == (6, 6, 6, 0) and \
+        model.layer_sparse == (1,) * 4 and model.delta_rule and \
+        model.num_held_experts == 64 and model.num_experts == 512
+    assert arena["ssm2"].shape == (65, 32, 128, 128) and \
+        arena["ssm2"].dtype == jnp.float32 and \
+        arena["conv2"].shape == (65, 3 * 8192) and \
+        arena["conv2"].dtype == jnp.float32 and "ssm3" not in arena and \
+        arena["k"].shape == (5505, 128, 512) == arena["v"].shape
+    table = scope_table_from_hlo(text)
+    scopes = {e["scope"] for e in table.values()}
+    assert {"ssm_in", "ssm_conv", "delta_rule", "ssm_state", "ssm_norm",
+            "ssm_out", "moe", "moe_shared", "attn_qkv", "attn_gate",
+            "attn_out", "kv_write", "embed", "lm_head"} <= scopes, scopes
+    assert "ssm_scan" not in scopes
+    kernels = [n for n in table if n.startswith("paged_attn_lse")]
+    assert len(kernels) == (5 if kind == "split" else 0) and \
+        all(table[n]["scope"] == "attn_history" for n in kernels), kernels
+    assert "1024,256" in text or kind != "split"
+    heavy = [m.group(1) for m in _HEAVY.finditer(text)]
+    named = [n for n in heavy if table[n]["scope"] is not None]
+    # (0.93, not the other stacks' 0.95: what has no scope here are index
+    # operations — the experts' gathers' ``AssumeGatherIndicesInBound`` and
+    # bit-packing custom-calls, the scalar index fusions of the pools'
+    # scatter loops —, and this cut has FOUR sparse layers in three
+    # instances: 94.9% of the split program's 3,698 instructions are named)
+    assert len(named) >= 0.93 * len(heavy), sorted(set(heavy) - set(named))
+    assert not _branches(text) or kind != "split", _branches(text)
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nqwen3-next {kind} at 4 layers: arguments "
+              f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+              f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < _DELTA_STEPS[kind][3], \
         mem.temp_size_in_bytes
 
 
