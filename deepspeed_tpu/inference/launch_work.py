@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, NamedTuple, Tuple
 import numpy as np
 
 from deepspeed_tpu.ops import paged_attention as pa
+from deepspeed_tpu.ops import ssm
 from deepspeed_tpu.parallel.moe import HELD_ROUND_ROWS
 
 
@@ -38,8 +39,9 @@ class Form(NamedTuple):
 
 class Launch(NamedTuple):
     """One launch as the terms see it: the program's kind and chunk width,
-    whether its instance is a grouped one, the token slots it ran over, and
-    the rows' tokens (their sum, where each row starts, what each feeds)."""
+    whether its instance is a grouped one, the token slots it ran over, the
+    rows' tokens (their sum, where each row starts, what each feeds), and
+    the rows of the instance's chunk group."""
     program: str
     chunk: int
     grouped: bool
@@ -47,6 +49,7 @@ class Launch(NamedTuple):
     tokens: int
     start: np.ndarray
     fed: np.ndarray
+    group_rows: int = 0
 
 
 class Site:
@@ -212,6 +215,28 @@ def state_work(site: Site, launch: Launch) -> Dict[str, int]:
             "ssm_chunk_tokens": int(formed)}
 
 
+def delta_chunk_positions(site: Site, launch: Launch) -> Dict[str, int]:
+    """The positions the gated delta rule's CHUNK form ran over, summed
+    over the delta-rule layers. All: the chunk group's rows times the
+    chunk's width, what the XLA form computes whatever is live. Live: what
+    the kernel walks (``ssm.delta_chunk_kernel``: a row's fed tokens
+    rounded up to whole turns of ``ssm.DELTA_KERNEL_SUB``, none for a row
+    that feeds none); all of them where the XLA form runs (no kernels, or
+    heads that are not whole lane tiles: ``ssm.delta_kernel_takes``)."""
+    model = site.model
+    if launch.chunk == 1:
+        return {}
+    layers = sum(1 for kind in model.layer_kinds if kind == 6)
+    every = walked = launch.group_rows * launch.chunk
+    if site.use_pallas and ssm.delta_kernel_takes(
+            model.ssm_state_size, model.ssm_head_dim, launch.chunk):
+        fed = launch.fed[launch.fed > 1] if launch.grouped else launch.fed
+        sub = ssm.DELTA_KERNEL_SUB
+        walked = int((-(-fed // sub) * sub).sum())
+    return {"delta_chunk_positions": layers * every,
+            "delta_chunk_positions_live": layers * walked}
+
+
 def moe_assignments(site: Site, launch: Launch) -> Dict[str, int]:
     """Fed tokens x experts a token x sparse layers: what the launch's
     routers hand the experts' dispatch, held here or not."""
@@ -261,6 +286,8 @@ TERMS = (
     Term(_kernel_reads_kv, query_tiles),
     Term(_kernel_reads_kv, kv_page_work),
     Term(lambda site: site.model.recurrent, state_work),
+    Term(lambda site: site.model.recurrent and site.model.delta_rule,
+         delta_chunk_positions),
     Term(lambda site: site.sparse_layers and site.model.num_experts_per_tok,
          moe_assignments),
     # the share's layer (parallel/moe.held_experts_moe_layer)
@@ -297,7 +324,7 @@ def launch_work(site: Site, program: str, form: Form, chunk: int,
             "context_tokens": int((start + fed).sum()),
             "context_slots": context_slots}
     launch = Launch(program, chunk, form.grouped, form.slots, tokens, start,
-                    fed)
+                    fed, form.group_rows)
     for term in site.terms:
         if span or not term.span_only:
             work.update(term.work(site, launch))
@@ -320,6 +347,8 @@ COUNTED = {
     "kv_page_fetches": "kv_page_fetches",
     "state_rows": "state_rows", "state_resets": "state_resets",
     "ssm_chunk_tokens": "ssm_chunk_tokens",
+    "delta_chunk_positions": "delta_chunk_positions",
+    "delta_chunk_positions_live": "delta_chunk_positions_live",
     "moe_assignments": "moe_assignments",
     "moe_buffer_rows": "moe_buffer_rows", "hc_maps": "hc_maps"}
 

@@ -1026,10 +1026,12 @@ class MixerForms(NamedTuple):
 
 
 def mixer_forms(kind: int, kernel: bool = False) -> MixerForms:
-    """``kernel``: the selective scan's chunk form as its Pallas kernel."""
+    """``kernel``: the chunk form of a selective scan or a gated delta rule
+    as its Pallas kernel."""
     if kind == 6:
         return MixerForms(delta_in, jnp.float32, True, True, delta_inputs,
-                          ssm.delta_step, ssm.delta_chunk, delta_out,
+                          ssm.delta_step, functools.partial(
+                              ssm.delta_chunk, kernel=kernel), delta_out,
                           ("ssm_state", "ssm_conv", "delta_rule"))
     if kind == 5:
         return MixerForms(short_conv_in, jnp.float32, False, False,

@@ -578,7 +578,8 @@ def _unit_lower_inverse(a: jax.Array) -> jax.Array:
     return t.reshape(lead + (c, c))
 
 
-def delta_chunk(cfg, p, u, sel, state: jax.Array, counts):
+def delta_chunk(cfg, p, u, sel, state: jax.Array, counts,
+                kernel: bool = False):
     """:func:`scan_chunk`'s part for a gated delta rule (the WY form): rows
     of ``c`` positions from a CARRIED state [m, H_v, d_k, d_v] float32 → (o
     [m, c, H_v·d_v] float32, the state after each row's last live
@@ -594,9 +595,26 @@ def delta_chunk(cfg, p, u, sel, state: jax.Array, counts):
     the chunk at ``DELTA_CHUNK_PRECISION`` (a chunk's products are 80 GFLOP
     a launch of seven chunk rows at Qwen3-Next's widths: PERF.md §5). A chunk whose
     width is no power of two times ``DELTA_SUB_BLOCK`` is padded with
-    positions that advance nothing."""
+    positions that advance nothing. ``kernel``: the same mathematics as ONE
+    Pallas kernel (:func:`delta_chunk_kernel`: every ``[c, c]`` matrix, the
+    solve and the carried state in VMEM, a row's dead positions not
+    computed) where the heads are whole lane tiles and the chunk is whole
+    turns of the kernel's (:func:`delta_kernel_takes`); else what follows,
+    in XLA (the CPU's form, the rehearsal widths, the kernel's
+    reference)."""
     q, k, v, beta, g = sel
     m, c = q.shape[:2]
+    if kernel and delta_kernel_takes(q.shape[-1], v.shape[-1], c):
+        # the values as they lie behind ``[q | k]`` in the convolved
+        # channels where those are float32 and ``[q | k]`` is whole blocks
+        # of a program's lanes (no copy of a slice), else alone
+        alone = v.reshape(m, c, -1)
+        lies = u is not None and u.dtype == jnp.float32 and \
+            (u.shape[-1] - alone.shape[-1]) % (v.shape[3] * v.shape[4]) == 0
+        return delta_chunk_kernel(
+            q.reshape(m, c, -1), k.reshape(m, c, -1), u if lies else alone,
+            beta.reshape(m, c, -1), g.reshape(m, c, -1), state, counts,
+            sub=DELTA_KERNEL_SUB)
     blocks = 1
     while blocks * DELTA_SUB_BLOCK < c:
         blocks *= 2
@@ -633,3 +651,165 @@ def delta_chunk(cfg, p, u, sel, state: jax.Array, counts):
         "mtgrn,mtgrp->mgrnp", to_end[..., None] * k_heads, v_new,
         precision=_HIGHEST)
     return o[:, :c].reshape(m, c, cfg.ssm_inner), s_out.reshape(state.shape)
+
+
+#: positions a turn of :func:`delta_chunk_kernel`'s walk over a chunk row
+#: takes: the row's state stays in VMEM between turns, and the walk ends
+#: with the row's last live position. On the v5e, 8 rows of a 128-token
+#: chunk at Qwen3-Next's widths, ms a layer-call at turns of 32 | 64 | 128:
+#: 0.90 | 0.83 | 1.58 full, 0.85 | 0.79 | 1.49 filled as cell 14's launch
+#: fills them; 64 rows 8.5 | 7.7 | 10.8 (``tools/chip_check_qwen3_next.py --forms``; PERF.md §6, PR 63)
+DELTA_KERNEL_SUB = 64
+
+
+def delta_kernel_takes(d_k: int, d_v: int, c: int) -> bool:
+    """Rows of ``c`` positions at heads of ``d_k`` x ``d_v`` are the
+    kernel's: whole lane tiles a head, whole turns a chunk."""
+    return d_k % 128 == 0 and d_v % 128 == 0 and c % DELTA_KERNEL_SUB == 0
+
+
+def _delta_chunk_body(counts_ref, q_ref, k_ref, v_ref, row_ref, col_ref,
+                      s_ref, o_ref, so_ref, *, sub):
+    """Grid (rows, key heads): ONE program carries the states ``[d_k,
+    d_v]`` of a row's ``R`` value heads on one key head — they share
+    ``K·Kᵀ`` and ``Q·Kᵀ`` — through the row's LIVE positions
+    (``counts_ref``, scalar-prefetched), ``sub`` at a turn: a turn is
+    :func:`delta_chunk`'s mathematics on ``sub`` positions from the state
+    the turn before left in ``so_ref``, so a row of 23 live positions takes
+    one turn and a row with none takes none (its state copied through, its
+    outputs zero, as every position past a row's last turn). ``q_ref`` /
+    ``k_ref`` ``[1, c, d_k]`` (the key head's lanes), ``v_ref`` / ``o_ref``
+    ``[1, c, R·d_v]``; a turn's ``Σg`` from its first position on and
+    ``β``, a head each, as ROWS (``row_ref`` ``[1, 1, c / sub, 2·R, sub]``)
+    and as COLUMNS (``col_ref`` ``[1, 1, c, 2·R]``): a ``[sub, sub]``
+    matrix of decay ratios is a column less a row; ``s_ref`` / ``so_ref``
+    ``[1, R, d_k, d_v]``, aliased."""
+    heads, d_k, d_v = s_ref.shape[1:]
+    f32, inner = jnp.float32, DELTA_CHUNK_PRECISION
+    live = counts_ref[pl.program_id(0)]
+    o_ref[...] = jnp.zeros(o_ref.shape, f32)
+    so_ref[...] = s_ref[...]
+    t_i = lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    s_i = lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    tile_v, tile_t, tile_s = (lax.broadcasted_iota(
+        jnp.int32, (sub // 8, 8, sub), axis) for axis in range(3))
+
+    def dot(x, y, contract, precision):
+        return lax.dot_general(x, y, (contract, ((), ())),
+                               precision=precision,
+                               preferred_element_type=f32)
+
+    nn, nt, tn = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+    def inverse(a):
+        """``(I + a)⁻¹`` a head, ``a`` [R, sub, sub] strictly lower, by
+        forward substitution, exact float32: a finished row ``i`` leaves the
+        rows under it (``row_j −= a_ji·row_i``: a lane of ``a`` times a row,
+        no sum across a tile), in the tiles of 8 rows at and under ``i``
+        alone — ``sub − 1`` steps on what stays in registers, where
+        :func:`_unit_lower_inverse` takes 31 on arrays in HBM and then joins
+        blocks with matmuls. The heads as ONE array: half the equations a
+        trace of the body holds, the same vector operations."""
+        tiles = sub // 8
+        a = a.reshape(heads, tiles, 8, sub)
+        x = jnp.broadcast_to((tile_v * 8 + tile_t == tile_s).astype(f32),
+                             a.shape)
+        for i in range(sub - 1):
+            row = x[:, i // 8, i % 8:i % 8 + 1]              # [R, 1, sub]
+            under = (i + 1) // 8
+            left = x[:, under:] - a[:, under:, :, i:i + 1] * row[:, None]
+            x = jnp.concatenate([x[:, :under], left], axis=1) if under \
+                else left
+        return x.reshape(heads, sub, sub)
+
+    def turn(j):
+        at = pl.multiple_of(j * sub, sub)
+        kj, qj = k_ref[0, pl.ds(at, sub), :], q_ref[0, pl.ds(at, sub), :]
+        both = dot(jnp.concatenate([kj, qj], axis=0), kj, nt, inner)
+        kk, qk = both[:sub], both[sub:]
+        rows, cols = row_ref[0, 0, j], col_ref[0, 0, pl.ds(at, sub), :]
+        ratios, betas, cums = [], [], []
+        for r in range(heads):
+            cum_row = rows[2 * r:2 * r + 1]
+            cum_col, beta_col = (cols[:, 2 * r + i:2 * r + i + 1]
+                                 for i in range(2))
+            # every ratio of decays: masked BEFORE the exponential
+            ratios.append(jnp.exp(jnp.where(t_i >= s_i, cum_col - cum_row,
+                                            -jnp.inf)))
+            betas.append(beta_col)
+            cums.append(cum_col)
+        inv = inverse(jnp.stack([
+            beta_col * jnp.where(t_i > s_i, ratio, 0.0) * kk
+            for beta_col, ratio in zip(betas, ratios)]))
+        for r, (ratio, beta_col, cum_col) in enumerate(
+                zip(ratios, betas, cums)):
+            gamma = jnp.exp(cum_col)
+            vj = v_ref[0, pl.ds(at, sub), r * d_v:(r + 1) * d_v]
+            wu = dot(inv[r], jnp.concatenate(
+                [beta_col * gamma * kj, beta_col * vj], axis=1), nn, inner)
+            s = so_ref[0, r]
+            read = dot(jnp.concatenate([wu[:, :d_k], gamma * qj], axis=0), s,
+                       nn, _HIGHEST)
+            v_new = wu[:, d_k:] - read[:sub]
+            o_ref[0, pl.ds(at, sub), r * d_v:(r + 1) * d_v] = \
+                read[sub:] + dot(ratio * qk, v_new, nn, inner)
+            cum_end = cum_col[sub - 1:sub]
+            so_ref[0, r] = \
+                jnp.exp(jnp.broadcast_to(cum_end, (1, d_v))) * s + dot(
+                    jnp.exp(cum_end - cum_col) * kj, v_new, tn, _HIGHEST)
+
+    lax.fori_loop(0, pl.cdiv(live, sub), lambda j, _: turn(j), None)
+
+
+# jitted, as :func:`selective_scan_kernel` is: ONE trace of the body for a
+# program's unrolled layers and instances of one shape
+@functools.partial(jax.jit, static_argnames=("sub", "interpret"))
+def delta_chunk_kernel(q, k, v, beta, g, state, counts, sub: int,
+                       interpret: bool = False):
+    """The gated delta rule's chunk form as a Pallas kernel
+    (:func:`_delta_chunk_body`; its name in a device trace is
+    ``delta_chunk``): q, k [m, c, G·d_k] (unit vectors a head, as
+    :func:`delta_inputs` makes them), v [m, c, .. + H_v·d_v] — the value
+    heads are its LAST lanes: the convolved channels as they lie, or the
+    values alone —, beta, g [m, c, H_v] (0 past a row's ``counts``), state
+    [m, H_v, d_k, d_v], all float32, counts [m] → (o [m, c, H_v·d_v], the
+    state after each row's last live position). ``d_k`` and ``d_v`` are
+    whole lane tiles and ``c`` whole turns of ``sub`` positions
+    (``DELTA_KERNEL_SUB``). HBM traffic: q, k, v, o once each, the state in
+    once and out once; nothing ``[c, c]``."""
+    m, c, _ = q.shape
+    h_v, d_k, d_v = state.shape[1:]
+    groups = q.shape[-1] // d_k                     # G
+    heads, turns = h_v // groups, c // sub          # R
+    lead = (v.shape[-1] - h_v * d_v) // (heads * d_v)
+    # [m, c, G, 2·R]: a turn's Σg from its first position on, and β
+    vecs = jnp.stack([jnp.cumsum(g.reshape(m, turns, sub, h_v), axis=2)
+                      .reshape(m, c, groups, heads),
+                      beta.reshape(m, c, groups, heads)],
+                     axis=-1).reshape(m, c, groups, 2 * heads)
+    keys = pl.BlockSpec((1, c, d_k), lambda i, j, counts: (i, 0, j))
+    held = pl.BlockSpec((1, heads, d_k, d_v),
+                        lambda i, j, counts: (i, j, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_delta_chunk_body, sub=sub),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(m, groups),
+            in_specs=[keys, keys,
+                      pl.BlockSpec((1, c, heads * d_v),
+                                   lambda i, j, counts: (i, 0, lead + j)),
+                      pl.BlockSpec((1, 1, turns, 2 * heads, sub),
+                                   lambda i, j, counts: (i, j, 0, 0, 0)),
+                      pl.BlockSpec((1, 1, c, 2 * heads),
+                                   lambda i, j, counts: (i, j, 0, 0)),
+                      held],
+            out_specs=[pl.BlockSpec((1, c, heads * d_v),
+                                    lambda i, j, counts: (i, 0, j)), held]),
+        out_shape=[jax.ShapeDtypeStruct((m, c, h_v * d_v), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret, name="delta_chunk",
+    )(counts.astype(jnp.int32), q, k, v,
+      vecs.reshape(m, turns, sub, groups, 2 * heads).transpose(0, 3, 1, 4, 2),
+      vecs.transpose(0, 2, 1, 3), state)

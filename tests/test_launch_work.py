@@ -128,6 +128,18 @@ ADDS = {
         "kv_page_fetches": 88, "state_rows": 6, "state_resets": 1,
         "ssm_chunk_tokens": 265, "moe_assignments": 536,
         "moe_buffer_rows": 512},
+    # three delta-rule layers at rehearsal depth, heads of 32: the XLA form,
+    # every position of the chunk group's 4 rows x 128 in each
+    ("qwen3-next-80b-a3b-l12-e64-serve", "decode"): {
+        "kv_pages_walked": 42, "kv_page_fetches": 84, "state_rows": 5,
+        "state_resets": 0, "ssm_chunk_tokens": 0, "moe_assignments": 40,
+        "moe_buffer_rows": 0},
+    ("qwen3-next-80b-a3b-l12-e64-serve", "split"): {
+        "query_tiles": 35, "query_tiles_live": 35, "kv_pages_walked": 44,
+        "kv_page_fetches": 88, "state_rows": 6, "state_resets": 1,
+        "ssm_chunk_tokens": 265, "delta_chunk_positions": 1536,
+        "delta_chunk_positions_live": 1536, "moe_assignments": 2144,
+        "moe_buffer_rows": 4096},
     ("xing4.0-29b-a4b-l6-serve", "decode"): {
         "kv_tokens_latent": 4969, "moe_assignments": 20,
         "moe_buffer_rows": 0, "hc_maps": 32},
@@ -163,7 +175,7 @@ def _counters():
 
 @pytest.mark.parametrize("name", sorted({n for n, _ in ADDS}))
 def test_a_launchs_work_is_what_the_engine_counted(name, monkeypatch):
-    assert len(ADDS) == 2 * 10
+    assert len(ADDS) == 2 * 11
     at = site(name)
     # as the code has it an 8-row program at rehearsal widths gathers too
     # little for a typed stack's decode read to take the kernel
@@ -202,3 +214,43 @@ def test_a_launchs_work_is_what_the_engine_counted(name, monkeypatch):
                 if v or n not in before} == grew
         # a counter is made even where it stays at 0
         assert set(tally) <= set(after)
+
+
+@pytest.mark.parametrize("form,fed,every,live", [
+    # a grouped instance: its chunk group's 4 rows x 128; the kernel walks
+    # the rows of more than one token in whole turns of 64 — 100 -> 128,
+    # 128, 37 -> 64 — and the one-token rows are the other group's
+    (Form(64, (512, 1024, 2048), 512, 4, 576), (1, 100, 128, 1, 1, 37),
+     512, 320),
+    # the cell's launch: six whole chunks and a piece of 23 in 8 chunk rows
+    (Form(64, (512, 1024, 2048), 1024, 8, 1088),
+     (128,) * 6 + (23,) + (1,) * 41, 1024, 832),
+    # the row form: EVERY row at the chunk's width, a one-token row a turn
+    (Form(8, (), 1024, 8, 1024), (1, 100, 128, 1, 0, 65, 64, 0), 1024, 576),
+])
+def test_the_delta_rules_chunk_positions(form, fed, every, live, monkeypatch):
+    """``delta_chunk_positions`` / ``_live`` at heads of whole lane tiles
+    (the published widths), by hand; both the chunk group's whole width
+    where the kernels do not run."""
+    import dataclasses
+    from deepspeed_tpu.ops import ssm
+    monkeypatch.setattr(ssm, "DELTA_KERNEL_SUB", 64)
+    at = site("qwen3-next-80b-a3b-l12-e64-serve")
+    at.model = dataclasses.replace(at.model, ssm_state_size=128,
+                                   ssm_head_dim=128)
+    layers = at.model.layer_kinds.count(6)
+    fed = np.asarray(fed, np.int32)
+    for kernels, walked in ((True, live), (False, every)):
+        at.use_pallas = kernels
+        work = launch_work.launch_work(at, "split", form, 128,
+                                       np.zeros_like(fed), fed)
+        assert (work["delta_chunk_positions"],
+                work["delta_chunk_positions_live"]) == \
+            (layers * every, layers * walked)
+    assert "delta_chunk_positions" not in launch_work.launch_work(
+        at, "decode", Form(8, (), 8, 8, 8), 1, np.zeros_like(fed),
+        np.minimum(fed, 1))
+    # a stack with no delta-rule layer makes neither
+    assert "delta_chunk_positions" not in launch_work.launch_work(
+        site("jamba2-3b-l28-serve"), "split", form, 128, np.zeros_like(fed),
+        fed)

@@ -599,7 +599,9 @@ def _recurrence(sel, state, counts):
     return out.reshape(m, c, -1), s.reshape(np.asarray(state).shape)
 
 
-def _mixer_inputs(cfg, p, seed, m, c, counts):
+def _mixer_inputs(cfg, p, seed, m, c, counts, channels=False):
+    """(``sel``, state, counts) of seeded rows; ``channels``: the convolved
+    channels ``sel`` was made from, first."""
     rng = np.random.default_rng(seed)
     u = jnp.asarray(rng.normal(0, 1, (m, c, cfg.ssm_conv_dim)), jnp.float32)
     ba = tuple(jnp.asarray(rng.normal(0, 1, (m, c, cfg.ssm_heads)),
@@ -607,7 +609,8 @@ def _mixer_inputs(cfg, p, seed, m, c, counts):
     state = jnp.asarray(rng.normal(0, 1, (m,) + ssm.state_shape(cfg)),
                         jnp.float32)
     counts = jnp.asarray(counts, jnp.int32)
-    return ssm.delta_inputs(cfg, p, u, ba, counts), state, counts
+    rows = ssm.delta_inputs(cfg, p, u, ba, counts), state, counts
+    return (u,) + rows if channels else rows
 
 
 @pytest.mark.parametrize("c", [2, 16, 37, 128])
@@ -670,6 +673,139 @@ def test_the_two_forms_agree_through_a_chain_of_chunks(tiny):
     assert np.abs(np.asarray(jnp.concatenate(outs, 1)) -
                   np.asarray(jnp.concatenate(steps, 1))).max() < 1e-5
     assert np.abs(np.asarray(s_chunk) - np.asarray(s_step)).max() < 1e-5
+
+
+# -- the chunk form's kernel (interpret mode: what it computes, not whether it
+# lowers — tests/test_tpu_compile.py) ------------------------------------------
+
+@pytest.fixture(scope="module")
+def wide():
+    """The delta rule at heads the kernel takes: ONE key head of 128 serving
+    two value heads of 128 (the published ``R`` = 2), an ``A`` that
+    remembers."""
+    cfg = config_from_hf(small(
+        linear_num_key_heads=1, linear_num_value_heads=2,
+        linear_key_head_dim=128, linear_value_head_dim=128))
+    rng = np.random.default_rng(11)
+    return cfg, {"A_log": jnp.log(jnp.asarray(rng.uniform(0.01, 1.0, (2,)),
+                                              jnp.float32)),
+                 "dt_bias": jnp.ones((2,), jnp.float32)}
+
+
+@pytest.fixture()
+def interpreted(monkeypatch):
+    """``delta_chunk(kernel=True)`` reaches the kernel in interpret mode,
+    and counts its calls."""
+    calls = []
+
+    def kernel(*args, **kw):
+        calls.append(kw)
+        return run(*args, interpret=True, **kw)
+    run = ssm.delta_chunk_kernel
+    monkeypatch.setattr(ssm, "delta_chunk_kernel", kernel)
+    return calls
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("whole", [False, True])
+def test_both_chunk_forms_are_the_recurrence_at_lane_wide_heads(
+        kernel, whole, wide, interpreted):
+    """The existing test's rows at ``d_k = d_v = 128``, chunk 128, from a
+    CARRIED state — one full, one of 23 live positions, one of 1, one of 0
+    — through the XLA form and the kernel, the values read from the
+    convolved channels as they lie (``whole``) or handed alone. The row
+    with no live position keeps its state BIT for bit; the kernel leaves
+    its outputs, and every position past a row's last turn, zero."""
+    cfg, p = wide
+    u, sel, state, counts = _mixer_inputs(cfg, p, 128, 4, 128,
+                                          [128, 23, 1, 0], channels=True)
+    with jax.default_matmul_precision("highest"):
+        o, s = ssm.delta_chunk(cfg, p, u if whole else None, sel, state,
+                               counts, kernel=kernel)
+    assert len(interpreted) == kernel
+    want_o, want_s = _recurrence(sel, state, counts)
+    for r in range(4):
+        n = int(counts[r])
+        assert np.abs(np.asarray(o[r, :n]) - want_o[r, :n]).max(initial=0) \
+            < 1e-5
+    assert np.abs(np.asarray(s) - want_s).max() < 1e-5
+    assert np.array_equal(np.asarray(s[3]), np.asarray(state[3]))
+    assert np.abs(want_s[0] - np.asarray(state[0])).max() > 0.1
+    if kernel:
+        turn = ssm.DELTA_KERNEL_SUB
+        assert not np.asarray(o[3]).any() and not np.asarray(o[2, turn:]).any()
+        assert not np.asarray(o[1, -(-23 // turn) * turn:]).any()
+
+
+@pytest.mark.parametrize("sub", [32, 64])
+def test_the_kernel_is_the_xla_form_at_every_turn_width(sub, wide):
+    """A chunk walked in turns of 32 or 64 positions is the SAME
+    recurrence: a turn starts from the state the turn before left."""
+    cfg, p = wide
+    u, sel, state, counts = _mixer_inputs(cfg, p, sub, 3, 128, [128, 70, 0],
+                                          channels=True)
+    with jax.default_matmul_precision("highest"):
+        want_o, want_s = ssm.delta_chunk(cfg, p, u, sel, state, counts)
+    q, k, _, beta, g = (t.reshape(3, 128, -1) for t in sel)
+    o, s = ssm.delta_chunk_kernel(q, k, u, beta, g, state, counts, sub=sub,
+                                  interpret=True)
+    live = (np.arange(128)[None] < np.asarray(counts)[:, None])[..., None]
+    assert np.abs(np.where(live, np.asarray(o) - np.asarray(want_o), 0)
+                  ).max() < 2e-6
+    assert np.abs(np.asarray(s) - np.asarray(want_s)).max() < 1e-5
+
+
+def test_the_kernel_agrees_with_the_steps_through_a_chain_of_chunks(
+        wide, interpreted):
+    """Three chunks of 64 through the KERNEL from the state each left,
+    against 192 steps of ``delta_step``."""
+    cfg, p = wide
+    sel, state, _ = _mixer_inputs(cfg, p, 2, 2, 192, [192, 192])
+    s_chunk, outs = state, []
+    full = jnp.asarray([64, 64], jnp.int32)
+    for i in range(3):
+        part = tuple(t[:, 64 * i:64 * i + 64] for t in sel)
+        o, s_chunk = ssm.delta_chunk(cfg, p, None, part, s_chunk, full,
+                                     kernel=True)
+        outs.append(o)
+    assert len(interpreted) == 3
+    s_step, steps = state, []
+    one = jnp.asarray([1, 1], jnp.int32)
+    for t in range(192):
+        o, s_step = ssm.delta_step(cfg, p, None,
+                                   tuple(x[:, t:t + 1] for x in sel),
+                                   s_step, one)
+        steps.append(o)
+    assert np.abs(np.asarray(jnp.concatenate(outs, 1)) -
+                  np.asarray(jnp.concatenate(steps, 1))).max() < 1e-5
+    assert np.abs(np.asarray(s_chunk) - np.asarray(s_step)).max() < 1e-5
+
+
+def test_what_takes_the_kernel_is_the_heads_width_and_the_engines_word(
+        tiny, wide, interpreted):
+    """``mixer_forms(6, kernel)`` binds the engine's ``use_pallas`` as kind
+    4 does; with it, heads of whole lane tiles and a chunk of whole turns
+    take the kernel — the rehearsal widths (``d_k`` 16 here) and a chunk of
+    96 keep the XLA form, as every call without it does."""
+    for kind in (4, 6):
+        assert tl.mixer_forms(kind).chunk.keywords == {"kernel": False}
+        assert tl.mixer_forms(kind, True).chunk.keywords == {"kernel": True}
+    assert tl.mixer_forms(6, True).step is ssm.delta_step
+    assert ssm.delta_kernel_takes(128, 128, 128) and \
+        not ssm.delta_kernel_takes(32, 128, 128) and \
+        not ssm.delta_kernel_takes(128, 64, 128) and \
+        not ssm.delta_kernel_takes(128, 128, 96)
+    _, cfg, params, _, _ = tiny
+    narrow = params["layers"][0]["ssm"]
+    for at, p, c, kernel in ((cfg, narrow, 128, True), (wide[0], wide[1], 96,
+                                                       True),
+                             (wide[0], wide[1], 128, False)):
+        sel, state, counts = _mixer_inputs(at, p, 4, 2, c, [c, 5])
+        o, s = ssm.delta_chunk(at, p, None, sel, state, counts, kernel=kernel)
+        want_o, want_s = ssm.delta_chunk(at, p, None, sel, state, counts)
+        assert np.array_equal(np.asarray(o), np.asarray(want_o)) and \
+            np.array_equal(np.asarray(s), np.asarray(want_s))
+    assert not interpreted
 
 
 def test_the_unit_lower_inverse_is_an_inverse():

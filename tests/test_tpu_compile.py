@@ -156,6 +156,21 @@ def _selective_scan(m, c=128, d=5120, n=16):
         ((m, c, n), f), ((m, c, n), f), ((m, n, d), f), ((m,), jnp.int32)), 1
 
 
+def _delta_chunk(m, c=128, g=16, r=2, d=128):
+    """The gated delta rule's chunk form at Qwen3-Next's widths: ``m`` rows
+    of a ``c``-token chunk, 16 key heads of 128 serving 32 value heads of
+    128, float32, the values read behind ``[q | k]`` in the convolved
+    channels (a grouped split step's chunk group: 4 or 8 rows; the row
+    form: 64)."""
+    from deepspeed_tpu.ops import ssm
+    f = jnp.float32
+    return functools.partial(
+        ssm.delta_chunk_kernel, sub=ssm.DELTA_KERNEL_SUB), (
+        ((m, c, g * d), f), ((m, c, g * d), f), ((m, c, (2 + r) * g * d), f),
+        ((m, c, g * r), f), ((m, c, g * r), f), ((m, g * r, d, d), f),
+        ((m,), jnp.int32)), 1
+
+
 def _dequant(mode):
     """Weight-only dequant matmul at the decode shape of the 1b FFN up
     projection: 16 rows x [2048, 8192]."""
@@ -266,6 +281,10 @@ CASES = {
     "selective_scan_n4_c128": lambda: _selective_scan(4),
     "selective_scan_n8_c128": lambda: _selective_scan(8),
     "selective_scan_n64_c128": lambda: _selective_scan(64),
+    # the gated delta rule's chunk form likewise
+    "delta_chunk_n4_c128": lambda: _delta_chunk(4),
+    "delta_chunk_n8_c128": lambda: _delta_chunk(8),
+    "delta_chunk_n64_c128": lambda: _delta_chunk(64),
     "dequant_int8": lambda: _dequant("int8"),
     "dequant_fp8": lambda: _dequant("fp8"),
     "dequant_int4": lambda: _dequant("int4"),
@@ -313,6 +332,9 @@ KERNEL_NAMES = {
     "selective_scan_n4_c128": ("selective_scan",),
     "selective_scan_n8_c128": ("selective_scan",),
     "selective_scan_n64_c128": ("selective_scan",),
+    "delta_chunk_n4_c128": ("delta_chunk",),
+    "delta_chunk_n8_c128": ("delta_chunk",),
+    "delta_chunk_n64_c128": ("delta_chunk",),
     "dequant_int8": ("qmm",),
     "dequant_fp8": ("qmm",),
     "dequant_int4": ("qmm_int4",),
@@ -1348,12 +1370,14 @@ def test_selective_scan_step_compiles_for_v5e(
 # serve): delta-rule mixers and gated 256-wide GQA beside the held experts
 
 #: step -> (chunk, ``fresh_prefill``, capacities, most temporaries at ONE
-#: period ``delta delta delta full``: measured 0.05, 1.61 and 1.43 GB; the
-#: split program at all 12 layers 1.87 beside 12.54 GB of arguments)
+#: period ``delta delta delta full``: measured 0.05, 1.38 and 1.33 GB with
+#: the chunk form a kernel (PR 63; its ``[c, c]`` matrices in XLA: 0.05,
+#: 1.61 and 1.43, the split program at all 12 layers 1.87 beside 12.54 GB
+#: of arguments))
 _DELTA_STEPS = {
     "decode": (1, False, (), 0.2e9),
-    "split": (128, "split", (512, 1024, 2048), 2.0e9),
-    "fresh": (128, "fresh", (2048,), 1.8e9),
+    "split": (128, "split", (512, 1024, 2048), 1.7e9),
+    "fresh": (128, "fresh", (2048,), 1.6e9),
 }
 
 
@@ -1367,7 +1391,11 @@ def test_delta_rule_step_compiles_for_v5e(
     three state pools of 65 slots of 2 MiB: NO copy of a state pool or of a
     KV pool anywhere in the module; the five ``ssm_*`` scopes the kind
     keeps, ``delta_rule`` in place of ``ssm_scan``, ``attn_gate``,
-    ``moe_shared``; the paged kernel with its 1,024-row block of 256 lanes
+    ``moe_shared``; the ``delta_chunk`` kernel under ``delta_rule`` once a
+    delta-rule layer and instance of a chunk-width program (4, 8 and 64
+    chunk rows in the split program's three, 64 in the fresh program's
+    one) and never in the decode program; the paged kernel with its
+    1,024-row block of 256 lanes
     under ``attn_history`` in the split program; the split program's three
     instances one-trip loops; temporaries (printed) under the measured
     ones."""
@@ -1394,6 +1422,9 @@ def test_delta_rule_step_compiles_for_v5e(
     assert len(kernels) == (5 if kind == "split" else 0) and \
         all(table[n]["scope"] == "attn_history" for n in kernels), kernels
     assert "1024,256" in text or kind != "split"
+    chunks = [n for n in table if n.startswith("delta_chunk")]
+    assert len(chunks) == {"decode": 0, "split": 9, "fresh": 3}[kind] and \
+        all(table[n]["scope"] == "delta_rule" for n in chunks), chunks
     heavy = [m.group(1) for m in _HEAVY.finditer(text)]
     named = [n for n in heavy if table[n]["scope"] is not None]
     # (0.93, not the other stacks' 0.95: what has no scope here are index

@@ -36,11 +36,14 @@ which must not pass: the state rounded to bfloat16 on its way to the pool,
 the decay ``e^g`` dropped, the gate on the wrong side of the mixer's norm,
 rotary over the whole head, a row at position 0 left with what its slot
 held. ``--forms`` first times the delta rule's two forms on random rows at
-the published widths — the chunk form at 8 and 64 rows of 128 positions, at
-sub-blocks of 16 and 32, with the chunk's own products at full float32 and
-in bf16 passes, each held to the token-by-token recurrence; the one-token
-pass over a pool of 65 slots — (``--phases none``: that alone). One JSON
-object a line; the last says ``ok``."""
+the published widths — the chunk form at 8 and 64 rows of 128 positions (and
+at 8 rows filled as cell 14's launch fills them: six whole, one of 23, one
+empty), in XLA at sub-blocks of 16 and 32 with the chunk's own products at
+full float32 and in bf16 passes, and as the Pallas kernel at turns of 32, 64
+and 128 positions (``the_programs``: the width ``ssm.DELTA_KERNEL_SUB``
+states), every float32 form held to the token-by-token recurrence; the
+one-token pass over a pool of 65 slots — (``--phases none``: that alone).
+One JSON object a line; the last says ``ok``."""
 
 import argparse
 import dataclasses
@@ -95,13 +98,14 @@ def forms_check(args, hf):
     reps = 10 if on_chip else 1
     ok = True
 
-    def rows(m):
+    def rows(m, counts=None):
         u = jax.random.normal(ks[1], (m, c, cd), jnp.float32)
         ba = tuple(jax.random.normal(k, (m, c, hv), jnp.float32)
                    for k in ks[2:4])
         state = jax.random.normal(ks[4], (m,) + ssm.state_shape(cfg),
                                   jnp.float32)
-        counts = jnp.asarray(([c] * (m - 2) + [c // 2, 1])[:m], jnp.int32)
+        counts = jnp.asarray(
+            counts or ([c] * (m - 2) + [c // 2, 1])[:m], jnp.int32)
         return u, ba, state, counts
 
     def timed(fn, *fn_args):
@@ -112,11 +116,11 @@ def forms_check(args, hf):
         jax.block_until_ready(out)
         return out, round((time.perf_counter() - t0) / reps * 1e3, 3)
 
-    def chunk_form():
+    def chunk_form(kernel=False):
         # (a function a variant: jax keeps a trace by the function it traced)
         def chunk(u, ba, state, counts):
             return ssm.delta_chunk(cfg, p, u, ssm.delta_inputs(
-                cfg, p, u, ba, counts), state, counts)
+                cfg, p, u, ba, counts), state, counts, kernel=kernel)
         return jax.jit(chunk)
 
     @jax.jit
@@ -133,14 +137,36 @@ def forms_check(args, hf):
             jnp.arange(c)))
         return o.swapaxes(0, 1), s
 
-    for m in (8, 64) if on_chip else (2,):
-        u, ba, state, counts = rows(m)
+    def held(line, o, s, want_o, want_s, live, judged=True):
+        """A form's largest differences from the recurrence; ``judged`` (a
+        float32 form: float32 sums in another order): ``passes`` within 1e-4
+        of the scale."""
+        line.update(
+            o_max_diff=float(jnp.abs(jnp.where(live, o - want_o, 0.0)).max()),
+            state_max_diff=float(jnp.abs(s - want_s).max()),
+            o_scale=float(jnp.abs(want_o).max()),
+            state_scale=float(jnp.abs(want_s).max()))
+        if judged:
+            line["passes"] = bool(
+                line["o_max_diff"] <= 1e-4 * line["o_scale"] and
+                line["state_max_diff"] <= 1e-4 * line["state_scale"])
+        return line.get("passes", True)
+
+    #: 8 rows as cell 14's launch fills them: six whole chunks, a piece of
+    #: 23, an empty row (PERF.md section 6, PR 62: 6.2 whole-chunk equivalents)
+    cell_rows = [c] * 6 + [23, 0]
+    for m, counts, mix in ((8, None, "full"), (8, cell_rows, "cell14"),
+                           (64, None, "full")) if on_chip else \
+            ((2, None, "full"), (2, [23, 0], "cell14")):
+        u, ba, state, counts = rows(m, counts)
         want_o, want_s = jax.block_until_ready(stepped(u, ba, state, counts))
         live = (jnp.arange(c)[None] < counts[:, None])[..., None]
-        for sub in (16, 32):
+        for sub in (16, 32) if mix == "full" else (32,):
             for name, prec in (("float32", lax.Precision.HIGHEST),
                                ("bf16_3_passes", lax.Precision.HIGH),
                                ("bf16_1_pass", lax.Precision.DEFAULT)):
+                if mix != "full" and name != "float32":
+                    continue
                 kept = ssm.DELTA_SUB_BLOCK, ssm.DELTA_CHUNK_PRECISION
                 ssm.DELTA_SUB_BLOCK, ssm.DELTA_CHUNK_PRECISION = sub, prec
                 try:
@@ -148,19 +174,26 @@ def forms_check(args, hf):
                 finally:
                     ssm.DELTA_SUB_BLOCK, ssm.DELTA_CHUNK_PRECISION = kept
                 line = {"phase": "forms", "form": "chunk", "rows": m,
-                        "sub_block": sub, "inner_products": name, "ms": ms,
-                        "o_max_diff": float(jnp.abs(jnp.where(
-                            live, o - want_o, 0.0)).max()),
-                        "state_max_diff": float(jnp.abs(s - want_s).max()),
-                        "o_scale": float(jnp.abs(want_o).max()),
-                        "state_scale": float(jnp.abs(want_s).max())}
-                if name == "float32":
-                    # float32 sums in another order: 1e-4 of the scale
-                    line["passes"] = bool(
-                        line["o_max_diff"] <= 1e-4 * line["o_scale"] and
-                        line["state_max_diff"] <= 1e-4 * line["state_scale"])
-                    ok = ok and line["passes"]
+                        "mix": mix, "sub_block": sub,
+                        "inner_products": name, "ms": ms}
+                ok = held(line, o, s, want_o, want_s, live,
+                          judged=name == "float32") and ok
                 print(json.dumps(line), flush=True)
+        # the kernel (float32 as the file states it), a turn of its walk at
+        # the width the program takes and at the two beside it
+        for turn in (32, 64, 128) if on_chip else ():
+            kept = ssm.DELTA_KERNEL_SUB
+            ssm.DELTA_KERNEL_SUB = turn
+            try:
+                (o, s), ms = timed(chunk_form(kernel=True), u, ba, state,
+                                   counts)
+            finally:
+                ssm.DELTA_KERNEL_SUB = kept
+            line = {"phase": "forms", "form": "chunk_kernel", "rows": m,
+                    "mix": mix, "turn": turn, "the_programs": turn == kept,
+                    "ms": ms}
+            ok = held(line, o, s, want_o, want_s, live) and ok
+            print(json.dumps(line), flush=True)
     m = 65
     u, ba, state, _ = rows(m)
     live = jnp.ones((m,), jnp.int32).at[-1].set(0)

@@ -39,7 +39,10 @@ and what attention worked on (PR 40; a tree without a counter reads 0);
 ``kv_pages_walked`` / ``kv_page_fetches`` (PR 47): the live pages the paged
 kernel's readers had to read and the page DMAs issued for them — their
 ratio is 2 where every call fetches a page once for all of a row's KV heads,
-16 at 8 KV heads a head at a time.
+16 at 8 KV heads a head at a time. ``delta_chunk_live_share`` (PR 63; None
+for a stack with no delta-rule layer): the window's
+``delta_chunk_positions_live`` over its ``delta_chunk_positions`` — how much
+of the chunk groups' width the delta rule's chunk form still computed.
 ``split_steps`` (PR 46) is the joint histogram of the window's split
 launches by what ``launch_work.launch_work`` returned: ``by_slots``
 (launches at each token capacity taken), ``hist`` (``"<tokens, in bins of
@@ -115,10 +118,17 @@ NAMES = ("dispatch/host_seconds", "dispatch/fetch_wait_seconds",
 WORK = ("steps.split", "split_grouped_steps", "chunk_rows",
         "attn_row_slots", "tokens", "token_slots", "kv_pages_walked",
         "kv_page_fetches", "split_lifted_steps", "moe_assignments",
-        "moe_buffer_rows")
+        "moe_buffer_rows", "delta_chunk_positions",
+        "delta_chunk_positions_live")
 #: (slots, chunk rows) of the instances ``split_steps.fits`` asks about
 RUNGS = ((256, 2), (256, 8), (512, 4), (512, 8), (1024, 8))
 CACHE_COUNTERS = ("evict_calls", "evict_scans", "pages_evicted")
+
+
+def live_share(window):
+    """``delta_chunk_positions_live`` ÷ ``delta_chunk_positions``."""
+    every = window["delta_chunk_positions"]
+    return window["delta_chunk_positions_live"] / every if every else None
 
 
 class Calls:
@@ -408,6 +418,7 @@ def main() -> int:
     if at_open:
         host, wait, calls, ahead, dropped, *work = (
             b - a for a, b in zip(at_open, at_close or counters()))
+        window = dict(zip(WORK, work))
         print(json.dumps({
             "phase": "host_counters", "clients": own.clients,
             "traffic": own.traffic, "own_rows": own.own_rows,
@@ -420,7 +431,8 @@ def main() -> int:
             "launches_ahead": int(ahead),
             "ahead_share": 100.0 * ahead / max(1, calls),
             "ahead_rows_dropped": int(dropped),
-            "window": dict(zip(WORK, work)),
+            "window": window,
+            "delta_chunk_live_share": live_share(window),
             "split_steps": split_steps(launches),
             "prefix_cache": {
                 **{n: None if a is None else b - a for n, a, b in zip(
